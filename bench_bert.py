@@ -13,7 +13,7 @@ matmuls every token:
 * embedding tables: gathers, no matmul     -> 0
 * attention scores/context (fwd+bwd)       -> 12 * L * B * S^2 * D
 
-against v5e bf16 peak 197 TFLOP/s.
+against the chip's published bf16 peak (paddle_tpu/device_peaks.py).
 """
 import json
 import os
@@ -21,30 +21,21 @@ import time
 
 import numpy as np
 
-# batch/chunk probes (BASELINE.md round-4/5 tables): bs64 44.1%, bs128
-# 51.1%, bs192 51.9%, bs256 46.7% at chunk=10; chunk=20: bs128 55.9%;
-# chunk=40: 57.1% same-batch == 57.2% fresh (r5, measured); the r5
-# fresh-data chunk ladder continues 80 -> 58.1%, 160 -> 58.6%,
-# 320 -> 58.9%, 640 -> 59.0% (bs160 gains nothing) — chunk=640 is the
-# shipped default, 76.9 ms/step (the curve's asymptote; deltas halve
-# each doubling).
+# bs128 / S=128 / chunk=640 with fresh per-step batches is the regime
+# BENCH_r05.json's headline (59.0% MFU, 76.96 ms/step) was recorded in.
 BATCH = int(os.environ.get("BENCH_BERT_BATCH", "128"))
 SEQ = int(os.environ.get("BENCH_BERT_SEQ", "128"))
 MASKS = max(1, int(SEQ * 0.15))
 STEPS = int(os.environ.get("BENCH_STEPS", "640"))
 CHUNK = int(os.environ.get("BENCH_CHUNK", "640"))
-PEAK_FLOPS = {"tpu": 197e12, "cpu": 1e12}
 
 
 def run(batch=BATCH, seq=SEQ, steps=STEPS, chunk=CHUNK):
     """Run the benchmark; returns the result dict (no printing)."""
-    import jax
-
     import paddle_tpu as fluid
-    from paddle_tpu import framework, models
+    from paddle_tpu import device_peaks, framework, models
 
-    platform = jax.devices()[0].platform
-    place = fluid.TPUPlace(0) if platform == "tpu" else fluid.CPUPlace()
+    place = fluid.TPUPlace(0)  # a chip bench: no chip, no run
     use_amp = os.environ.get("BENCH_AMP", "1") == "1"
     masks = max(1, int(seq * 0.15))
 
@@ -85,8 +76,8 @@ def run(batch=BATCH, seq=SEQ, steps=STEPS, chunk=CHUNK):
     n_enc = n_params - n_embed - n_mlm - n_head
 
     # CHUNK *distinct* batches, stacked on a leading axis and consumed one
-    # per fori_loop iteration (Executor per_step_feed, VERDICT r4 weakness
-    # #3: the 57.1% headline was a same-batch number).  BENCH_FRESH=0
+    # per fori_loop iteration (Executor per_step_feed: a same-batch chunk
+    # is a different HBM/infeed regime).  BENCH_FRESH=0
     # restores the old same-batch regime for A/B comparison.
     import bench_common
 
@@ -106,15 +97,14 @@ def run(batch=BATCH, seq=SEQ, steps=STEPS, chunk=CHUNK):
 
     scope = fluid.Scope()
     exe = fluid.Executor(place)
-    dev = jax.devices()[0]
+    dev = exe._device()
     # BENCH_FUSED=1 measures the pallas flash kernel; the op's own
-    # default is the XLA-native path (faster at every S that fits HBM —
-    # see fused_attention's docstring / BASELINE.md).  The env override
-    # must cover every exe.run that can TRACE (the flag is read at trace
-    # time, ops/nn_ops.py), but is set/restored around them rather than
-    # left as a process-global side effect — a later library caller's
-    # fused_attention trace must not silently inherit the pallas path
-    # (ADVICE r5).  Force =1 (not setdefault): a leftover =0 export
+    # default is the XLA-native path (see fused_attention's docstring).
+    # The env override must cover every exe.run that can TRACE (the flag
+    # is read at trace time, ops/nn_ops.py), but is set/restored around
+    # them rather than left as a process-global side effect — a later
+    # library caller's fused_attention trace must not silently inherit
+    # the pallas path.  Force =1 (not setdefault): a leftover =0 export
     # would mislabel an XLA measurement as the pallas one.
     prev_flash = os.environ.get("PADDLE_TPU_FLASH_ATTENTION")
     if fused:
@@ -157,7 +147,7 @@ def run(batch=BATCH, seq=SEQ, steps=STEPS, chunk=CHUNK):
         + 6.0 * n_head * batch
         + 12.0 * L * batch * S * S * D
     )
-    mfu = (flops / step_time) / PEAK_FLOPS.get(platform, 197e12)
+    mfu = (flops / step_time) / device_peaks.peak_flops(dev)
     return {
         "metric": "bert_base_pretrain_tokens_per_sec_per_chip",
         "value": round(tokens / step_time, 1),
@@ -171,7 +161,8 @@ def run(batch=BATCH, seq=SEQ, steps=STEPS, chunk=CHUNK):
         "n_embed_params": n_embed,
         "per_step_feed": fresh,
         "chunk": chunk,
-        "platform": platform,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "loss": float(lv),
     }
 

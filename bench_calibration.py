@@ -7,10 +7,10 @@ written directly in jax/lax with no paddle_tpu machinery.  The measured
 `framework_overhead_pct = (framework - pure) / pure` is then a measured,
 driver-visible fact instead of a docstring claim.
 
-Measured context (see BASELINE.md / memory): ResNet-50 @ bs256 on one
-v5e is HBM-bandwidth-bound at ~13% MFU regardless of layout — the gap to
-the 50% MFU target is the XLA ceiling for this model, not framework
-overhead.
+Last recorded (BENCH_r05.json, one v5e chip, NHWC bs256 chunk10 fresh):
+framework 120.92 ms/step vs this yardstick 119.83 ms — 0.91% overhead at
+13.2% MFU.  Where the other 87% goes is for a trace to say (ROADMAP
+Queue 1 item 3).
 """
 import functools
 import time

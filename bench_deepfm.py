@@ -12,13 +12,11 @@ import time
 
 import numpy as np
 
-# measured r5 chunk ladder (BASELINE.md): 127.3k examples/s at chunk5 ->
-# 227.4k at chunk40 -> 238.4k at chunk80 -> 249.6k at chunk160 (dispatch
-# amortization dominates a ~16 ms step)
+# bs4096 / chunk=160 is the regime BENCH_r05.json's deepfm block
+# (248.5k examples/s, 16.48 ms/step) was recorded in.
 BATCH = int(os.environ.get("BENCH_DEEPFM_BATCH", "4096"))
 STEPS = int(os.environ.get("BENCH_DEEPFM_STEPS", "320"))
 CHUNK = int(os.environ.get("BENCH_DEEPFM_CHUNK", "160"))
-PEAK_FLOPS = {"tpu": 197e12, "cpu": 1e12}
 NUM_FEATURES = int(os.environ.get("BENCH_DEEPFM_FEATURES", "1000000"))
 FIELDS = 39
 EMBED = 16
@@ -29,13 +27,10 @@ MESH_DEVICES = int(os.environ.get("BENCH_DEEPFM_MESH", "0"))
 
 
 def run(batch=BATCH, steps=STEPS, chunk=CHUNK):
-    import jax
-
     import paddle_tpu as fluid
-    from paddle_tpu import framework, models
+    from paddle_tpu import device_peaks, framework, models
 
-    platform = jax.devices()[0].platform
-    place = fluid.TPUPlace(0) if platform == "tpu" else fluid.CPUPlace()
+    place = fluid.TPUPlace(0)  # a chip bench: no chip, no run
 
     prog, startup = framework.Program(), framework.Program()
     prog.random_seed = startup.random_seed = 42
@@ -54,8 +49,8 @@ def run(batch=BATCH, steps=STEPS, chunk=CHUNK):
         if "_emb" not in p.name:
             n_fc += int(np.prod([max(1, int(s)) for s in p.shape]))
 
-    # chunk distinct batches per jitted call (per_step_feed; VERDICT r4
-    # weak #3); BENCH_FRESH=0 restores the same-batch regime
+    # chunk distinct batches per jitted call (per_step_feed);
+    # BENCH_FRESH=0 restores the same-batch regime
     import bench_common
 
     fresh = bench_common.fresh_enabled()
@@ -78,7 +73,7 @@ def run(batch=BATCH, steps=STEPS, chunk=CHUNK):
 
     scope = fluid.Scope()
     exe = fluid.Executor(place)
-    dev = jax.devices()[0]
+    dev = exe._device()
     with fluid.scope_guard(scope):
         exe.run(startup)
         stacked = {"ids": idsv, "vals": valsv, "lbl": lblv}
@@ -118,7 +113,7 @@ def run(batch=BATCH, steps=STEPS, chunk=CHUNK):
 
     step_time = dt / done
     flops = 6.0 * n_fc * batch  # deep tower fwd+bwd; lookups aren't matmul
-    mfu = (flops / step_time) / PEAK_FLOPS.get(platform, 197e12)
+    mfu = (flops / step_time) / device_peaks.peak_flops(dev)
     return {
         "metric": "deepfm_ctr_examples_per_sec_per_chip",
         "value": round(batch / step_time, 1),
@@ -133,7 +128,8 @@ def run(batch=BATCH, steps=STEPS, chunk=CHUNK):
         "device_prefetch": True,
         "mesh_devices": MESH_DEVICES,
         "recompiles_after_warmup": int(recompiles),
-        "platform": platform,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "loss": float(lv),
     }
 

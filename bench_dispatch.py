@@ -50,6 +50,11 @@ best round stays under 2% (the control tower must not tax the second
 it attributes) and that the armed ledger's books balance (phases sum
 to the epoch wall clock).
 
+Every mode measures HOST-side cost (dispatch overhead, file I/O,
+instrumentation tax), so each runs on the process default device —
+``Executor()`` with no place — and names its platform in the result
+line; bench.py declares them CPU stages.
+
 Env knobs: BENCH_DISPATCH_LAYERS (default 20 -> ~190 ops with backward
 + sgd), BENCH_DISPATCH_DIM (default 32), BENCH_DISPATCH_ITERS (default
 200), BENCH_DISPATCH_BATCH (default 8; the sharded mode rounds it up to
@@ -104,12 +109,11 @@ def run(layers=LAYERS, dim=DIM, iters=ITERS, batch=BATCH):
     import paddle_tpu as fluid
 
     platform = jax.devices()[0].platform
-    place = fluid.TPUPlace(0) if platform == "tpu" else fluid.CPUPlace()
     prog, startup, loss = build_program(layers, dim)
     n_ops = sum(len(b.ops) for b in prog.blocks)
 
     scope = fluid.Scope()
-    exe = fluid.Executor(place)
+    exe = fluid.Executor()
     dev = jax.devices()[0]
     rng = np.random.RandomState(0)
     # device-resident feed (the prefetch regime): h2d is a passthrough,
@@ -210,7 +214,6 @@ def run_sharded(layers=LAYERS, dim=DIM, iters=ITERS, batch=BATCH,
     from paddle_tpu.parallel.compiled_program import CompiledProgram
 
     platform = jax.devices()[0].platform
-    place = fluid.TPUPlace(0) if platform == "tpu" else fluid.CPUPlace()
     mesh = mesh_lib.data_parallel_mesh()
     n_dev = int(mesh.devices.size)
     batch = ((max(batch, 1) + n_dev - 1) // n_dev) * n_dev  # round UP
@@ -222,7 +225,7 @@ def run_sharded(layers=LAYERS, dim=DIM, iters=ITERS, batch=BATCH,
     host = {"x": rng.rand(batch, dim).astype(np.float32)}
 
     scope = fluid.Scope()
-    exe = fluid.Executor(place)
+    exe = fluid.Executor()
     with fluid.scope_guard(scope):
         exe.run(startup)
 
@@ -339,8 +342,7 @@ def run_sharded_train(layers=LAYERS, dim=DIM, iters=ITERS, batch=BATCH):
     )
 
     platform = jax.devices()[0].platform
-    place = fluid.TPUPlace(0) if platform == "tpu" else fluid.CPUPlace()
-    exe = fluid.Executor(place)
+    exe = fluid.Executor()
     rng = np.random.RandomState(0)
     feed = {"x": rng.rand(batch, dim).astype(np.float32)}
 
@@ -419,8 +421,7 @@ def run_checkpoint(layers=None, dim=None, batch=BATCH):
     layers = layers or int(os.environ.get("BENCH_CKPT_LAYERS", "4"))
     dim = dim or int(os.environ.get("BENCH_CKPT_DIM", "512"))
     platform = jax.devices()[0].platform
-    place = fluid.TPUPlace(0) if platform == "tpu" else fluid.CPUPlace()
-    exe = fluid.Executor(place)
+    exe = fluid.Executor()
     prog, startup, loss, opt = build_train_program(layers, dim, seed=11)
 
     def compiled_for(n):
@@ -517,8 +518,7 @@ def run_train_obs(layers=10, dim=256, batch=256, steps=60, rounds=5):
     from paddle_tpu.monitor import train as mtrain
 
     platform = jax.devices()[0].platform
-    place = fluid.TPUPlace(0) if platform == "tpu" else fluid.CPUPlace()
-    exe = fluid.Executor(place)
+    exe = fluid.Executor()
     prog, startup, loss, _ = build_train_program(layers, dim, seed=13)
     rng = np.random.RandomState(0)
     feeds = [{"x": rng.rand(batch, dim).astype(np.float32)}
@@ -606,7 +606,9 @@ def main():
         # orchestrator sets it in the subprocess env instead)
         os.environ["XLA_FLAGS"] = bench_common.virtual_mesh_env()["XLA_FLAGS"]
 
-    bench_common.configure_compile_cache(bench_common.HOME_CACHE_DIR)
+    from paddle_tpu import compile_cache
+
+    compile_cache.configure()
     if checkpoint:
         bench_common.emit_result(run_checkpoint())
     elif train_obs:

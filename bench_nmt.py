@@ -15,27 +15,20 @@ import time
 
 import numpy as np
 
-# defaults per the measured r5 chunk/batch probes (BASELINE.md): bs64
-# chunk5 165.7k tok/s (17.7% MFU) -> bs128 chunk40 307.0k (32.9%) ->
-# bs128 chunk80 320.2k (34.4%), the shipped default; bs256 measured
-# 302.1k (worse) and the bs64 chunk40 probe blew a 700 s stage budget
-# on compile, so the bigger batch is also the safer compile
+# bs128 / 64+64 / chunk=80 is the regime BENCH_r05.json's nmt block
+# (34.5% MFU, 44.63 ms/step) was recorded in.
 BATCH = int(os.environ.get("BENCH_NMT_BATCH", "128"))
 SRC_LEN = int(os.environ.get("BENCH_NMT_SRC", "64"))
 TGT_LEN = int(os.environ.get("BENCH_NMT_TGT", "64"))
 STEPS = int(os.environ.get("BENCH_NMT_STEPS", "160"))
 CHUNK = int(os.environ.get("BENCH_NMT_CHUNK", "80"))
-PEAK_FLOPS = {"tpu": 197e12, "cpu": 1e12}
 
 
 def run(batch=BATCH, src_len=SRC_LEN, tgt_len=TGT_LEN, steps=STEPS, chunk=CHUNK):
-    import jax
-
     import paddle_tpu as fluid
-    from paddle_tpu import framework, models
+    from paddle_tpu import device_peaks, framework, models
 
-    platform = jax.devices()[0].platform
-    place = fluid.TPUPlace(0) if platform == "tpu" else fluid.CPUPlace()
+    place = fluid.TPUPlace(0)  # a chip bench: no chip, no run
     use_amp = os.environ.get("BENCH_AMP", "1") == "1"
 
     V, D, L, H, DI = 32000, 512, 6, 8, 2048
@@ -74,8 +67,8 @@ def run(batch=BATCH, src_len=SRC_LEN, tgt_len=TGT_LEN, steps=STEPS, chunk=CHUNK)
         else:
             n_dec += n
 
-    # chunk distinct batches per jitted call (per_step_feed; VERDICT r4
-    # weak #3); BENCH_FRESH=0 restores the same-batch regime
+    # chunk distinct batches per jitted call (per_step_feed);
+    # BENCH_FRESH=0 restores the same-batch regime
     import bench_common
 
     fresh = bench_common.fresh_enabled()
@@ -91,7 +84,7 @@ def run(batch=BATCH, src_len=SRC_LEN, tgt_len=TGT_LEN, steps=STEPS, chunk=CHUNK)
 
     scope = fluid.Scope()
     exe = fluid.Executor(place)
-    dev = jax.devices()[0]
+    dev = exe._device()
     with fluid.scope_guard(scope):
         exe.run(startup)
         stacked = {"src": srcv, "tgt": tgtv, "lbl": lblv, "smask": smaskv}
@@ -120,7 +113,7 @@ def run(batch=BATCH, src_len=SRC_LEN, tgt_len=TGT_LEN, steps=STEPS, chunk=CHUNK)
         + 12.0 * L * batch * tgt_len * tgt_len * D      # decoder self
         + 12.0 * L * batch * tgt_len * src_len * D      # cross
     )
-    mfu = (flops / step_time) / PEAK_FLOPS.get(platform, 197e12)
+    mfu = (flops / step_time) / device_peaks.peak_flops(dev)
     return {
         "metric": "transformer_nmt_tokens_per_sec_per_chip",
         "value": round(real_tokens / step_time, 1),
@@ -132,7 +125,8 @@ def run(batch=BATCH, src_len=SRC_LEN, tgt_len=TGT_LEN, steps=STEPS, chunk=CHUNK)
         "tgt_len": tgt_len,
         "per_step_feed": fresh,
         "chunk": chunk,
-        "platform": platform,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "loss": float(lv),
     }
 

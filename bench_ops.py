@@ -14,7 +14,9 @@ Usage:
     python bench_ops.py --check          # compare against OPBENCH.json,
                                          # exit 1 on >25% regression
     python bench_ops.py --config f.json  # external config list
-    BENCH_PLATFORM=cpu python bench_ops.py   # pin backend (e.g. no TPU)
+
+A chip bench: every op runs through ``Executor(TPUPlace(0))`` and the
+run fails without the chip.
 
 A checked-in OPBENCH.json is the regression baseline: re-run with
 --check after touching an op kernel.
@@ -137,13 +139,12 @@ def time_op(key, op_type, inputs, attrs, out_slots, chunk=CHUNK,
 
     prog, feed_specs, fetch = _build_program(op_type, inputs, attrs, out_slots)
     rng = np.random.RandomState(0)
-    dev = jax.devices()[0]
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    dev = exe._device()
     feed = {
         n: jax.device_put(_rand((chunk,) + shape, dtype, rng), dev)
         for n, shape, dtype in feed_specs
     }
-    exe = fluid.Executor(
-        fluid.TPUPlace(0) if dev.platform == "tpu" else fluid.CPUPlace())
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         run = lambda: exe.run(  # noqa: E731
@@ -170,12 +171,6 @@ def main():
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "OPBENCH.json"))
     args = ap.parse_args()
-
-    plat = os.environ.get("BENCH_PLATFORM")
-    if plat:
-        import jax
-
-        jax.config.update("jax_platforms", plat)
 
     if args.config:
         with open(args.config) as f:
@@ -237,6 +232,7 @@ def main():
         f.write("\n")
     print("wrote %s (%d ops, %d failures)"
           % (args.out, len(table), len(failures)))
+    sys.exit(1 if failures else 0)
 
 
 if __name__ == "__main__":

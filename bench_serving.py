@@ -180,6 +180,11 @@ MAX_BATCH = int(os.environ.get("BENCH_SERVING_MAX_BATCH", "16"))
 TIMEOUT_MS = float(os.environ.get("BENCH_SERVING_TIMEOUT_MS", "2"))
 # request sizes cycle through this ladder so batches mix row counts
 REQ_SIZES = (1, 2, 3, 4)
+# Every launched serving child here measures the wire or the control
+# plane on a toy endpoint, so its platform is pinned to the CPU
+# explicitly: a chip belongs to one process, and a child that inherited
+# the parent's accelerator platform would fail or hang behind it.
+CPU_CHILD_ENV = {"JAX_PLATFORMS": "cpu"}
 
 
 def _save_lenet(dirname, precision=None):
@@ -358,7 +363,7 @@ def _bench_endpoint_wire(name, save_fn):
         handle = wire.launch_server(
             d, name="%s-wire" % name, max_batch_size=MAX_BATCH,
             batch_timeout_ms=TIMEOUT_MS,
-            queue_capacity=max(64, THREADS * 8))
+            queue_capacity=max(64, THREADS * 8), env=CPU_CHILD_ENV)
         cli = wire.RemoteClient(handle.address)
         try:
             t0 = time.perf_counter()
@@ -435,9 +440,17 @@ def run_wire():
     for the same endpoints, plus the measured wire tax."""
     import jax
 
-    import bench_common
+    from paddle_tpu import compile_cache
 
-    bench_common.configure_compile_cache(bench_common.HOME_CACHE_DIR)
+    if jax.default_backend() != "cpu":
+        # the tax is in-process minus child latency on ONE platform, and
+        # the children are CPU children (CPU_CHILD_ENV): a parent on an
+        # accelerator would subtract a chip latency from a CPU one
+        raise RuntimeError(
+            "the wire-tax stage is a CPU stage (a host-side latency "
+            "delta): run it with JAX_PLATFORMS=cpu, got backend %r"
+            % jax.default_backend())
+    compile_cache.configure()
     endpoints = {}
     for name, save_fn in (("lenet", _save_lenet), ("deepfm", _save_deepfm)):
         inproc = _bench_endpoint(name, save_fn)
@@ -620,9 +633,9 @@ def run_overload():
     """The ``--overload`` line: the degradation curve past saturation."""
     import jax
 
-    import bench_common
+    from paddle_tpu import compile_cache
 
-    bench_common.configure_compile_cache(bench_common.HOME_CACHE_DIR)
+    compile_cache.configure()
     endpoints = {"lenet": _bench_overload("lenet", _save_lenet)}
     # numeric, not lexicographic: "10x" must beat "5x" for the headline
     last = max(endpoints["lenet"]["stages"], key=lambda k: float(k[:-1]))
@@ -674,9 +687,9 @@ def run():
 
     from paddle_tpu import monitor, profiler
 
-    import bench_common
+    from paddle_tpu import compile_cache
 
-    bench_common.configure_compile_cache(bench_common.HOME_CACHE_DIR)
+    compile_cache.configure()
     trace = os.environ.get("BENCH_SERVING_TRACE")
     trace_out = _trace_out_path()
     recorder = None
@@ -780,7 +793,9 @@ def run_sharded():
         os.environ.update(bench_common.virtual_mesh_env())
     import jax
 
-    bench_common.configure_compile_cache(bench_common.HOME_CACHE_DIR)
+    from paddle_tpu import compile_cache
+
+    compile_cache.configure()
     replicated = _bench_endpoint("lm-replicated", _save_lm_bench(False))
     shard = _bench_endpoint("lm-tp%d" % SHARDED_TP, _save_lm_bench(True))
     stats = shard.get("sharding") or {}
@@ -878,7 +893,9 @@ def run_long_context():
     from paddle_tpu.inference import AnalysisConfig, create_paddle_predictor
     from paddle_tpu.parallel.pipeline_predictor import PipelinePredictor
 
-    bench_common.configure_compile_cache(bench_common.HOME_CACHE_DIR)
+    from paddle_tpu import compile_cache
+
+    compile_cache.configure()
     V = _LC_DIMS[0]
     rng = np.random.RandomState(42)
     x = rng.randint(1, V, (_LC_B, _LC_S)).astype(np.int64)
@@ -1177,7 +1194,8 @@ def _decode_affinity_fleet_block(state):
             d_inner=DI, eos_id=1, max_seq_len=ML, max_slots=4,
             steps_per_tick=4, prefix_cache_bytes=16 << 20)
         fleet = wire.FleetBalancer.from_launch(
-            d, 2, name="decode-affinity", prefix_affinity=True)
+            d, 2, name="decode-affinity", prefix_affinity=True,
+            launch_kwargs={"env": CPU_CHILD_ENV})
         try:
             warmup_compiles = fleet.warmup()
 
@@ -1324,7 +1342,6 @@ def run_decode():
     """The ``--decode`` line: token-level scheduling, measured."""
     import jax
 
-    import bench_common
     from paddle_tpu.decoding import (
         make_transformer_lm_pooled_step_fn,
         random_transformer_lm_state,
@@ -1332,7 +1349,9 @@ def run_decode():
     from paddle_tpu.serving.client import Client
     from paddle_tpu.serving.decode import DecodeServer
 
-    bench_common.configure_compile_cache(bench_common.HOME_CACHE_DIR)
+    from paddle_tpu import compile_cache
+
+    compile_cache.configure()
     n_requests = int(os.environ.get("BENCH_DECODE_REQUESTS", "24"))
     max_slots = int(os.environ.get("BENCH_DECODE_SLOTS", "8"))
     steps = int(os.environ.get("BENCH_DECODE_STEPS", "4"))
@@ -1554,7 +1573,8 @@ def _precision_fleet_block(save_fn, requests=48):
         fleet = wire.FleetBalancer.from_launch(
             d, 2, name="prec-fleet",
             launch_kwargs={"max_batch_size": MAX_BATCH,
-                           "batch_timeout_ms": TIMEOUT_MS})
+                           "batch_timeout_ms": TIMEOUT_MS,
+                           "env": CPU_CHILD_ENV})
         try:
             t0 = time.perf_counter()
             warmup_compiles = fleet.warmup()
@@ -1646,7 +1666,9 @@ def run_precision():
         os.environ.update(bench_common.virtual_mesh_env())
     import jax
 
-    bench_common.configure_compile_cache(bench_common.HOME_CACHE_DIR)
+    from paddle_tpu import compile_cache
+
+    compile_cache.configure()
     endpoints = {}
     for name, save_fn in (("lenet", _save_lenet), ("deepfm", _save_deepfm)):
         fp32 = _bench_endpoint(name + "-fp32", save_fn)
@@ -1916,12 +1938,13 @@ def run_fleet_obs():
     drill, and the cost of watching (QPS with the tower on vs off)."""
     import jax
 
-    import bench_common
     from paddle_tpu import monitor
     from paddle_tpu.monitor import slo as slo_mod
     from paddle_tpu.serving import wire
 
-    bench_common.configure_compile_cache(bench_common.HOME_CACHE_DIR)
+    from paddle_tpu import compile_cache
+
+    compile_cache.configure()
     qps_floor = float(os.environ.get("BENCH_OBS_QPS_FLOOR", "0.98"))
     delay_s = float(os.environ.get("BENCH_OBS_FAULT_DELAY_S", "0.6"))
     slo_name = "fleet-p99-latency"
@@ -1933,7 +1956,8 @@ def run_fleet_obs():
             d, 2, name="obs-fleet",
             launch_kwargs={"max_batch_size": MAX_BATCH,
                            "batch_timeout_ms": TIMEOUT_MS,
-                           "queue_capacity": max(64, THREADS * 8)},
+                           "queue_capacity": max(64, THREADS * 8),
+                           "env": CPU_CHILD_ENV},
             health_interval_s=0.5, scrape_interval_s=0.5)
         engine = None
         try:

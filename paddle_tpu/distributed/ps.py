@@ -9,7 +9,7 @@ TPU-native role: dense parameters live in HBM and sync via ICI
 collectives (no PS needed); the PS remains the right tool for *huge
 sparse embedding tables* that exceed HBM — rows live on host-CPU servers
 sharded by id, trainers prefetch rows before the compiled step and push
-sparse grads after (BASELINE.md DeepFM config).
+sparse grads after (BASELINE.json DeepFM config).
 
 Wire format: length-framed messages of a JSON header plus raw ndarray
 payload bytes — the gRPC+protobuf tensor serde analog (reference:

@@ -92,14 +92,11 @@ def cuda_places(device_ids=None):
     this build the accelerator is the TPU: returns one TPUPlace per
     visible chip (or per requested id)."""
     if device_ids is None:
-        try:
-            import jax
+        import jax
 
-            n = max(
-                1, len([d for d in jax.devices() if d.platform != "cpu"])
-            )
-        except Exception:  # noqa: BLE001 — no accelerator visible
-            n = 1
+        # a CPU-only process still gets one place (reference scripts
+        # index [0]); using it then fails loudly in Executor._device
+        n = max(1, len([d for d in jax.devices() if d.platform != "cpu"]))
         device_ids = range(n)
     return [TPUPlace(int(i)) for i in device_ids]
 

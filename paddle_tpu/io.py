@@ -119,8 +119,11 @@ def load_vars(executor, dirname, main_program=None, vars=None, predicate=None,
     bf16+sharded endpoint sees the cheap host value)."""
     program = main_program or framework.default_main_program()
     scope = scope if scope is not None else global_scope()
-    import jax.numpy as jnp
+    import jax
 
+    # values land on the EXECUTOR's device, not the process default:
+    # replica i of a multi-replica server keeps its params on device i
+    device = executor._device_cached() if executor is not None else None
     with open(os.path.join(dirname, _MANIFEST)) as f:
         manifest = json.load(f)
     packed = None
@@ -145,7 +148,7 @@ def load_vars(executor, dirname, main_program=None, vars=None, predicate=None,
                     "shape mismatch loading %r: checkpoint %s vs program %s"
                     % (name, arr.shape, expect)
                 )
-        scope.set(name, jnp.asarray(arr) if to_device else arr)
+        scope.set(name, jax.device_put(arr, device) if to_device else arr)
 
 
 def load_params(executor, dirname, main_program=None, filename=None):

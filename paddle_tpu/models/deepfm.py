@@ -1,5 +1,5 @@
 """DeepFM CTR model — the sparse/high-dim-lookup benchmark family
-(BASELINE.md "DeepFM / Wide&Deep"; reference serves this class of model via
+(BASELINE.json "DeepFM / Wide&Deep"; reference serves this class of model via
 the distributed lookup table + PSLib path, SURVEY.md §2.10).
 
 TPU design: the embedding table is a dense HBM gather; at scale the table
@@ -27,7 +27,7 @@ def deepfm_ctr(
     """feat_ids: int64 [N, F, 1]; feat_vals: float32 [N, F]; labels [N, 1].
 
     ``distributed_emb=True`` serves both tables from the parameter server
-    (huge-vocab CTR where the tables exceed HBM — BASELINE.md DeepFM;
+    (huge-vocab CTR where the tables exceed HBM — BASELINE.json DeepFM;
     feat_ids must be a feed, bind via
     distributed.bind_distributed_tables).
 

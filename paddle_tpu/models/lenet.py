@@ -1,7 +1,7 @@
 """LeNet-5 MNIST classifier.
 
 Reference: python/paddle/fluid/tests/book/test_recognize_digits.py:90-117
-(the `conv_net` variant). The BASELINE.md "MNIST LeNet" config.
+(the `conv_net` variant). The BASELINE.json "MNIST LeNet" config.
 """
 from __future__ import annotations
 

@@ -1,4 +1,4 @@
-"""Transformer NMT (seq2seq) — the BASELINE.md "Transformer NMT" config.
+"""Transformer NMT (seq2seq) — the BASELINE.json "Transformer NMT" config.
 
 Reference model family: python/paddle/fluid/tests/unittests/
 dist_transformer.py and book test test_machine_translation.py (attention

@@ -56,10 +56,9 @@ def multi_head_attention(
     ``fused=True`` (needs dropout_rate==0 inside attention): the
     ``fused_attention`` op, with padding as ``mask`` [N, S] and
     causality as ``causal=`` instead of a materialized ``attn_bias``.
-    That op defaults to XLA's native fused attention (measured faster
-    at every S that fits HBM); set ``PADDLE_TPU_FLASH_ATTENTION=1`` for
-    the pallas flash kernel when S^2 score tensors would exceed HBM
-    (see the op docstring / BASELINE.md round-5 A/B table).
+    That op defaults to XLA's native fused attention; set
+    ``PADDLE_TPU_FLASH_ATTENTION=1`` for the pallas flash kernel when
+    S^2 score tensors would exceed HBM (see the op docstring).
     """
     d_head = d_model // n_head
     q = _fc3(q_in, d_model, name + "_q")

@@ -53,7 +53,6 @@ from __future__ import annotations
 import collections
 import json
 import math
-import os
 import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional, Tuple
@@ -101,27 +100,25 @@ _STEPS_PS = _registry.REGISTRY.gauge(
 _MFU_RATIO = _registry.REGISTRY.gauge(
     "train_mfu_ratio",
     "model FLOPs utilization estimate: static per-step block FLOPs "
-    "(matmul/conv shapes) x steps/s over the platform peak")
+    "(matmul/conv shapes) x steps/s over the device_kind's published "
+    "peak (never set on a CPU)")
 
 
 # ---------------------------------------------------------------------------
 # Static-FLOPs MFU estimate
 # ---------------------------------------------------------------------------
-def _default_peak_flops() -> float:
-    """Platform peak for the MFU denominator.  Env override first
-    (``PADDLE_TPU_PEAK_FLOPS``), else the bench's convention (v5e bf16
-    for TPU, nominal 1 TFLOP/s for the CPU testbed)."""
-    env = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
-    if env:
-        return float(env)
-    platform = "cpu"
-    try:
-        import jax
+def _default_peak_flops() -> Optional[float]:
+    """Peak for the MFU denominator: the default device's row of the
+    one peaks table (``paddle_tpu.device_peaks``).  None on a CPU — a
+    CPU has no MFU; an accelerator the table does not know raises."""
+    import jax
 
-        platform = jax.default_backend()
-    except Exception:
-        pass
-    return {"tpu": 197e12, "cpu": 1e12}.get(platform, 197e12)
+    from paddle_tpu import device_peaks
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    return device_peaks.peak_flops(dev)
 
 
 def _dim(d, batch: int) -> int:
@@ -267,7 +264,7 @@ class StepPhaseLedger:
         self._step_mark: Dict[str, float] = dict(self.seconds)
         self._sps = 0.0
         self._eps = 0.0
-        self._mfu = 0.0
+        self._mfu: Optional[float] = None  # None = not measured (no peak)
         # resolve the labeled counter children ONCE — the per-step flush
         # must not pay a labels() dict hash per phase
         self._counters = (
@@ -347,7 +344,8 @@ class StepPhaseLedger:
         if self._counters is not None:
             _STEPS_PS.set(self._sps)
             _EXAMPLES_PS.set(self._eps)
-            _MFU_RATIO.set(self._mfu)
+            if self._mfu is not None:
+                _MFU_RATIO.set(self._mfu)
         row: Dict[str, Any] = {
             "step": int(step),
             "duration_s": round(float(duration_s), 6),
@@ -405,7 +403,8 @@ class StepPhaseLedger:
             "examples": self.examples_total,
             "steps_per_second": round(self._sps, 4),
             "examples_per_second": round(self._eps, 4),
-            "mfu_ratio": round(self._mfu, 6),
+            "mfu_ratio": (round(self._mfu, 6)
+                          if self._mfu is not None else None),
             "flops_per_step": self.flops_per_step,
             "peak_flops": self.peak_flops,
             "checkpoint": {

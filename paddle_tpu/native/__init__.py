@@ -3,15 +3,18 @@
 Reference native parts this covers: paddle/fluid/recordio/ (chunked CRC'd
 record files) and the MultiSlot parsing hot path of
 paddle/fluid/framework/data_feed.cc.  The library builds on first use
-with g++ (cached under ``~/.cache/paddle_tpu``); when no toolchain is
-available a pure-Python fallback keeps the API working.
+with g++, from the committed source, into ``<checkout>/.native_build``
+(gitignored) under a name that carries the source's hash — a stale or
+foreign ``.so`` can never be picked up.  When no toolchain is available
+a pure-Python fallback keeps the API working; ``native_available()``
+says which one a run got.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
-import sys
 import tempfile
 from typing import Iterator, List, Optional, Tuple
 
@@ -23,30 +26,50 @@ _lib = None
 _tried = False
 
 
+_BUILD_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".native_build")
+
+
+def _build_and_open(src_name: str, lib_name: str,
+                    extra_flags=()) -> Optional[ctypes.CDLL]:
+    """Compile ``native/<src_name>`` into ``_BUILD_DIR`` and open it;
+    None when there is no working toolchain.  The file name carries the
+    source's sha1, so an existing file IS this source's build; the
+    compile goes to a temp name and is renamed into place, so concurrent
+    first uses never load a half-written library."""
+    src = os.path.join(os.path.dirname(__file__), src_name)
+    with open(src, "rb") as f:
+        digest = hashlib.sha1(f.read()).hexdigest()[:12]
+    so_path = os.path.join(_BUILD_DIR, "%s-%s.so" % (lib_name, digest))
+    if not os.path.exists(so_path):
+        os.makedirs(_BUILD_DIR, exist_ok=True)
+        fd, tmp = tempfile.mkstemp(suffix=".so", dir=_BUILD_DIR)
+        os.close(fd)
+        try:
+            subprocess.run(
+                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", src,
+                 "-o", tmp, *extra_flags],
+                check=True, capture_output=True)
+            os.replace(tmp, so_path)
+        except (OSError, subprocess.CalledProcessError):
+            return None
+        finally:
+            if os.path.exists(tmp):
+                os.remove(tmp)
+    try:
+        return ctypes.CDLL(so_path)
+    except OSError:
+        return None
+
+
 def _build_and_load() -> Optional[ctypes.CDLL]:
     global _lib, _tried
     if _tried:
         return _lib
     _tried = True
-    src = os.path.join(os.path.dirname(__file__), "recordio.cc")
-    cache = os.environ.get(
-        "PADDLE_TPU_CACHE", os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu")
-    )
-    os.makedirs(cache, exist_ok=True)
-    so_path = os.path.join(cache, "libpaddle_tpu_native.so")
-    if not os.path.exists(so_path) or os.path.getmtime(so_path) < os.path.getmtime(src):
-        try:
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", src, "-o", so_path, "-lz"],
-                check=True,
-                capture_output=True,
-            )
-        except (OSError, subprocess.CalledProcessError) as e:
-            sys.stderr.write("paddle_tpu.native: build failed (%s); using Python fallback\n" % e)
-            return None
-    try:
-        lib = ctypes.CDLL(so_path)
-    except OSError:
+    lib = _build_and_open("recordio.cc", "libpaddle_tpu_native", ("-lz",))
+    if lib is None:
         return None
     lib.recordio_writer_create.restype = ctypes.c_void_p
     lib.recordio_writer_create.argtypes = [ctypes.c_char_p, ctypes.c_int, ctypes.c_int]
@@ -249,27 +272,8 @@ def _predictor_lib():
     if _pred_tried:
         return _pred_lib
     _pred_tried = True
-    src = os.path.join(os.path.dirname(__file__), "predictor.cc")
-    cache = os.environ.get(
-        "PADDLE_TPU_CACHE", os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu")
-    )
-    os.makedirs(cache, exist_ok=True)
-    so_path = os.path.join(cache, "libpaddle_tpu_predictor.so")
-    if not os.path.exists(so_path) or os.path.getmtime(so_path) < os.path.getmtime(src):
-        try:
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", src, "-o", so_path],
-                check=True,
-                capture_output=True,
-            )
-        except (OSError, subprocess.CalledProcessError) as e:
-            sys.stderr.write(
-                "paddle_tpu.native: predictor build failed (%s)\n" % e
-            )
-            return None
-    try:
-        lib = ctypes.CDLL(so_path)
-    except OSError:
+    lib = _build_and_open("predictor.cc", "libpaddle_tpu_predictor")
+    if lib is None:
         return None
     lib.ptp_predictor_create.restype = ctypes.c_void_p
     lib.ptp_predictor_create.argtypes = [ctypes.c_char_p]

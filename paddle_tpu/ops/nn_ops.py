@@ -632,23 +632,25 @@ def fused_attention(inputs, attrs):
     [N, H, S, D].
 
     Default path: plain einsum+softmax — XLA's native fused attention.
-    Measured on a v5e chip (r5, fwd+bwd, BERT-base shapes) it beats the
-    pallas flash kernel at every sequence length that fits in HBM:
-    16.5 vs 23.4 ms/call at B16 H12 S1024 D64, and 31.2% vs 12.2% MFU
-    end-to-end at S=1024 (20.7% vs 6.1% at S=4096) — XLA's own
-    softmax-matmul fusion already avoids materializing scores badly
-    enough to lose, and the stock pallas kernel's block schedule does
-    not win on this part.
 
     PADDLE_TPU_FLASH_ATTENTION=1 opts in to the pallas flash kernel
     (jax.experimental.pallas.ops.tpu.flash_attention) — online-softmax
     tiling, no [N, H, S, S] score tensor in HBM — which is the
     memory-capability path: it admits sequence lengths where the
-    einsum path's S^2 tensors exceed HBM.  Padding comes in as
+    einsum path's S^2 tensors exceed HBM.  The flag on a backend that
+    is not a TPU is an error, not a quiet einsum.  Padding comes in as
     ``Mask`` [N, S] (1 = token) and is lowered to segment ids (pad
     positions form their own segment, so real tokens never attend them;
     pad rows' outputs are garbage-by-construction in BOTH impls and must
     be masked downstream, as the reference's padded attention does).
+
+    On the chip (PR 21, tools/chip_bringup.py flash, jax 0.9.0 on a
+    v5e): the kernel lowers and runs at B16 H12 S1024 D64 with and
+    without ``Mask``/``causal`` and agrees with the einsum path to
+    7.4e-3 max abs forward, 5.1e-3 relative on dq/dk/dv.  Which path is
+    faster at which S has not been measured this round; the einsum path
+    stays the default until a cell prices the choice (ROADMAP Queue 3
+    item 5).
 
     Multi-chip long context: when this op is traced under a
     sequence-parallel activation context (a CompiledProgram whose rules
@@ -681,21 +683,21 @@ def fused_attention(inputs, attrs):
         if n_sp > 1 and S % n_sp == 0 and tuple(k.shape) == tuple(q.shape):
             from jax.sharding import PartitionSpec as P
 
-            from paddle_tpu.parallel import mesh as mesh_lib
             from paddle_tpu.parallel.ring_attention import ring_attention
 
             spec = P(None, None, sp, None)
-            ring = mesh_lib.shard_map(
+            ring = jax.shard_map(
                 lambda qq, kk, vv: ring_attention(
                     qq, kk, vv, axis_name=sp, causal=causal, scale=scale),
                 mesh=_act.mesh, in_specs=(spec, spec, spec),
                 out_specs=spec)
             return {"Out": ring(q, k, v)}
-    use_flash = (
-        jax.default_backend() == "tpu"
-        and _os.environ.get("PADDLE_TPU_FLASH_ATTENTION", "0") == "1"
-    )
-    if use_flash:
+    if _os.environ.get("PADDLE_TPU_FLASH_ATTENTION", "0") == "1":
+        if jax.default_backend() != "tpu":
+            raise RuntimeError(
+                "PADDLE_TPU_FLASH_ATTENTION=1 asks for the pallas TPU "
+                "flash kernel, but the backend is %r: unset the flag to "
+                "run the einsum path" % jax.default_backend())
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             SegmentIds, flash_attention)
 

@@ -337,24 +337,12 @@ def make_train_step(cfg: HybridConfig, mesh=None, optimizer=None):
 
     ALL_AXES = ("dp", "pp", "tp", "sp", "ep")
 
-    def replicated_axes(spec):
-        used = {a for a in spec if a is not None}
-        return tuple(a for a in ALL_AXES if a not in used)
-
     def lift_all(x):
-        """pvary x over every mesh axis it isn't already varying on, so
-        downstream vma state is uniform regardless of axis sizes.  On
-        jax releases predating the vma tracking (no jax.typeof /
-        lax.pvary) there is no varying-axis state to normalize — the
-        rep checker there is the coarser check_rep — so this is a
-        no-op."""
-        typeof = getattr(jax, "typeof", None)
-        pvary = getattr(jax.lax, "pvary", None)
-        if typeof is None or pvary is None:
-            return x
-        vma = typeof(x).vma
-        missing = tuple(a for a in ALL_AXES if a not in vma)
-        return pvary(x, missing) if missing else x
+        """Cast x to varying over every mesh axis it isn't already
+        varying on, so downstream vma state is uniform regardless of
+        axis sizes."""
+        missing = tuple(a for a in ALL_AXES if a not in jax.typeof(x).vma)
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
 
     # ---------------- per-stage block (runs under shard_map) -------------
     def stage_fn(sp_idx, tp_idx, ep_idx, stage_params, x):
@@ -482,36 +470,13 @@ def make_train_step(cfg: HybridConfig, mesh=None, optimizer=None):
                     new_aux["%s@%s" % (n, slot)] = out
         return new_p, new_aux
 
-    # pre-vma jax (no lax.pvary / jax.typeof) runs shard_map with
-    # check_rep=False (mesh_lib.shard_map), which disables the automatic
-    # cotangent psum over each input's replication axes — grads come back
-    # as raw per-device partials.  The exact correction: every device
-    # seeds its (replicated) loss output with 1, so the SPMD backward
-    # computes the adjoint of N_mesh identical losses — psum the grad
-    # over the param's replicated axes and divide by the mesh size.
-    pre_vma = (getattr(jax, "typeof", None) is None
-               or getattr(jax.lax, "pvary", None) is None)
-    n_mesh = int(np.prod(list(cfg.mesh_axes().values())))
-
-    def reduce_grads(grads):
-        out = {}
-        for n, g in grads.items():
-            rep = replicated_axes(specs[n])
-            if rep:
-                g = jax.lax.psum(g, rep)
-            out[n] = g / n_mesh
-        return out
-
     def sharded_step(params, aux, tokens, labels):
         # Gradient reduction over each param's replication axes (the
         # reference's NCCL allreduce, details/all_reduce_op_handle.cc) is
         # inserted by shard_map's transpose: under check_vma=True the
         # cotangent of an input that is invariant over an axis is psum'd
-        # over that axis automatically.  Under pre-vma check_rep=False
-        # the reduction is applied explicitly (reduce_grads above).
+        # over that axis automatically.
         loss, grads = jax.value_and_grad(local_loss)(params, tokens, labels)
-        if pre_vma:
-            grads = reduce_grads(grads)
         if optimizer is None:
             new_params = {n: params[n] - cfg.lr * grads[n] for n in params}
             return loss, new_params, aux
@@ -530,7 +495,7 @@ def make_train_step(cfg: HybridConfig, mesh=None, optimizer=None):
         {n: aux_spec_of[n] for n in aux_spec_of},
     )
 
-    smapped = mesh_lib.shard_map(
+    smapped = jax.shard_map(
         sharded_step,
         mesh=mesh,
         in_specs=in_specs,

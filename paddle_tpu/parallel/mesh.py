@@ -23,31 +23,8 @@ from typing import Dict, Optional, Sequence
 import numpy as np
 
 __all__ = ["make_mesh", "default_mesh", "data_parallel_mesh", "MeshGuard",
-           "local_devices", "shard_map"]
+           "local_devices"]
 
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions: new jax exposes it at top
-    level with ``check_vma``; older releases only ship
-    ``jax.experimental.shard_map`` whose analogous knob is
-    ``check_rep``.  On those pre-vma releases the check is forced OFF:
-    without ``lax.pvary`` there is no way to annotate intentional
-    replication, so ``check_rep=True`` rejects valid programs the new
-    checker accepts (it is a static debugging aid, not semantics)."""
-    import jax
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        try:
-            return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        except TypeError:  # top-level alias predating the check_vma rename
-            return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
 
 _current_mesh = None
 
@@ -66,9 +43,17 @@ def local_devices(backend: Optional[str] = None):
 
 def make_mesh(axes: Dict[str, int], devices=None, backend: Optional[str] = None):
     """Build a jax Mesh with named axes; sizes must multiply to #devices
-    (or a divisor thereof — extra devices are left out)."""
+    (or a divisor thereof — extra devices are left out).
+
+    When the mesh spans every local device (and the caller did not pick
+    ``devices`` itself), jax orders them along the physical links
+    (``mesh_utils.create_device_mesh``): on a v5e 2x2 tray the flat id
+    order 0,1,2,3 crosses a diagonal on two of a ring's four hops, the
+    ring order is 0,1,3,2.  For CPU devices that call is the plain
+    reshape.  A caller-chosen list, or a subset, keeps its order."""
     from jax.sharding import Mesh
 
+    whole_backend = devices is None
     if devices is None:
         devices = local_devices(backend)
     sizes = list(axes.values())
@@ -77,15 +62,19 @@ def make_mesh(axes: Dict[str, int], devices=None, backend: Optional[str] = None)
         raise ValueError(
             "mesh %r needs %d devices, have %d" % (axes, n, len(devices))
         )
-    dev_array = np.array(devices[:n]).reshape(sizes)
+    if whole_backend and n == len(devices):
+        from jax.experimental import mesh_utils
+
+        dev_array = mesh_utils.create_device_mesh(sizes, devices=devices)
+    else:
+        dev_array = np.array(devices[:n]).reshape(sizes)
     return Mesh(dev_array, tuple(axes.keys()))
 
 
 def data_parallel_mesh(num_devices: Optional[int] = None, backend: Optional[str] = None):
-    devs = local_devices(backend)
-    if num_devices is not None:
-        devs = devs[:num_devices]
-    return make_mesh({"dp": len(devs)}, devs)
+    if num_devices is None:
+        num_devices = len(local_devices(backend))
+    return make_mesh({"dp": num_devices}, backend=backend)
 
 
 def default_mesh():

@@ -69,9 +69,7 @@ class PipelinePredictor:
 
         self.model_dir = model_dir
         self._scope = fluid.Scope()
-        self._exe = fluid.Executor(fluid.CPUPlace()
-                                   if jax.default_backend() == "cpu"
-                                   else None)
+        self._exe = fluid.Executor()  # the process default device
         with fluid.scope_guard(self._scope):
             self._program, self._feed_names, self._fetch_vars = (
                 io.load_inference_model(model_dir, self._exe,
@@ -273,7 +271,7 @@ class PipelinePredictor:
             # them onto every pp rank (zeros elsewhere contribute 0)
             return [jax.lax.psum(a, "pp") for a in fetch_acc]
 
-        smapped = mesh_lib.shard_map(
+        smapped = jax.shard_map(
             local_run,
             mesh=self._mesh,
             in_specs=(P(), {n: P() for n, _, _ in feed_sig}),

@@ -319,9 +319,7 @@ def build_pipeline_step(program, loss_name: str, plan: Dict[str, Any], mesh):
                             new_state[nm] = v.astype(new_state[nm].dtype)
             return loss, new_state
 
-        from paddle_tpu.parallel import mesh as mesh_lib
-
-        smapped = mesh_lib.shard_map(
+        smapped = jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(P(), {n: P() for n in feeds_mb}),

@@ -63,6 +63,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from paddle_tpu import compile_cache
 from paddle_tpu import faults as _faults
 from paddle_tpu import monitor
 from paddle_tpu.monitor import events as _events
@@ -442,19 +443,12 @@ class DecodeServer:
                            "slots": len(self._slots)}}
 
     # ------------------------------------------------------------------
-    def warmup(self, cache_dir: Optional[str] = None,
-               configure_cache: bool = True) -> int:
+    def warmup(self, configure_cache: bool = True) -> int:
         """Pre-compile chunk/admit/release for every (slot, length) rung
         pair; arms the recompile counter (any executable built after
         this increments ``metrics()['recompiles']``)."""
         if configure_cache:
-            try:
-                import bench_common
-
-                bench_common.configure_compile_cache(
-                    cache_dir or bench_common.HOME_CACHE_DIR)
-            except (ImportError, AttributeError):
-                pass  # standalone use: compile cold
+            compile_cache.configure()
         compiles = self._pool.warmup()
         self._metrics.count("warmup_compiles", compiles)
         self._warmed = True

@@ -51,6 +51,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from paddle_tpu import compile_cache
 from paddle_tpu import faults as _faults
 from paddle_tpu import monitor, profiler
 from paddle_tpu.faults.metrics import BACKEND_HALFOPEN_PROBES
@@ -416,35 +417,27 @@ class InferenceServer:
         return self._admin.server_address if self._admin is not None else None
 
     # ------------------------------------------------------------------
-    def warmup(self, cache_dir: Optional[str] = None,
-               configure_cache: bool = True) -> int:
+    def warmup(self, configure_cache: bool = True) -> int:
         """Pre-compile every bucket rung on EVERY replica (the
         zero-recompile guarantee must hold fleet-wide — a cold replica
         would compile on its first routed batch); returns the total
         number of XLA compiles the warmup performed.  Routes through
         jax's persistent compilation cache
-        (bench_common.configure_compile_cache) when the repo-root helper
-        is importable — replica 2..N of an identical model typically
-        loads replica 1's compiles from the disk cache; synthetic rows
-        are zeros (always in-range for int id feeds).  After warmup the
-        recompile counter arms: any further jit-cache miss on any
-        replica increments ``metrics()['recompiles']``.
+        (``paddle_tpu.compile_cache.configure``) — replica 2..N of an
+        identical model typically loads replica 1's compiles from the
+        disk cache; synthetic rows are zeros (always in-range for int
+        id feeds).  After warmup the recompile counter arms: any
+        further jit-cache miss on any replica increments
+        ``metrics()['recompiles']``.
 
-        NOTE ``configure_cache=True`` mutates PROCESS-GLOBAL state (the
-        JAX_COMPILATION_CACHE_* env vars + jax.config); pass
+        NOTE ``configure_cache=True`` mutates PROCESS-GLOBAL state when
+        ``JAX_COMPILATION_CACHE_DIR`` is unset (it exports the variable
+        and sets ``jax.config`` to ``<checkout>/.jax_cache``); pass
         ``configure_cache=False`` when the embedding application owns
-        its own jax cache configuration.  Any failure to wire the cache
-        (helper missing, or an unrelated ``bench_common`` shadowing it)
-        degrades to cold compiles, never a crashed warmup.
+        its own jax cache configuration.
         """
         if configure_cache:
-            try:
-                import bench_common
-
-                bench_common.configure_compile_cache(
-                    cache_dir or bench_common.HOME_CACHE_DIR)
-            except (ImportError, AttributeError):
-                pass  # standalone use / foreign bench_common: compile cold
+            compile_cache.configure()
         compiles = self._warm_rungs(self._policy.ladder)
         for rep in self._replicas:
             # a mesh-spanning (sharded) replica publishes its per-device

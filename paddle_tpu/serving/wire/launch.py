@@ -375,10 +375,19 @@ def main(argv: Optional[List[str]] = None) -> int:
         )
         from paddle_tpu.serving.server import InferenceServer
 
-        predictors = [
-            create_paddle_predictor(AnalysisConfig(args.model_dir))
-            for _ in range(max(1, args.replicas))
-        ]
+        import jax
+
+        # replica i lives on chip i: N one-chip replicas are N devices
+        # of ONE process (a chip belongs to one process), never N
+        # predictors stacked on device 0.  A CPU process has no device
+        # to choose.
+        on_chip = jax.default_backend() != "cpu"
+        predictors = []
+        for i in range(max(1, args.replicas)):
+            cfg = AnalysisConfig(args.model_dir)
+            if on_chip:
+                cfg.enable_use_gpu(device_id=i)
+            predictors.append(create_paddle_predictor(cfg))
         server = InferenceServer(
             predictors,
             max_batch_size=args.max_batch_size,

@@ -55,7 +55,6 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from paddle_tpu.parallel import mesh as mesh_lib
 from paddle_tpu.sharding import metrics as _sh_metrics
 
 __all__ = ["MeshTable", "MeshTableRuntime", "bind_mesh_tables",
@@ -269,7 +268,7 @@ class MeshTableRuntime:
                     dequantize_rows(shard[safe], scales[safe]), 0.0)
                 return jax.lax.psum(rows, axis)
 
-            smapped = mesh_lib.shard_map(
+            smapped = jax.shard_map(
                 local_lookup, mesh=self.mesh,
                 in_specs=(P(axis, None), P(axis), P()), out_specs=P())
             return jax.jit(smapped)
@@ -285,7 +284,7 @@ class MeshTableRuntime:
             rows = jnp.where(ok[:, None], shard[safe], 0.0)
             return jax.lax.psum(rows, axis)
 
-        smapped = mesh_lib.shard_map(
+        smapped = jax.shard_map(
             local_lookup, mesh=self.mesh,
             in_specs=(P(axis, None), P()), out_specs=P())
         return jax.jit(smapped)
@@ -383,7 +382,7 @@ class MeshTableRuntime:
             out_specs = P(axis, None)
             donate_args = (0,)
 
-        smapped = mesh_lib.shard_map(
+        smapped = jax.shard_map(
             local_push, mesh=self.mesh,
             in_specs=in_specs, out_specs=out_specs)
         from paddle_tpu.executor import _donate_kwargs

@@ -685,11 +685,12 @@ def test_contrib_trainer_checkpoint_rotation(tmp_path):
 
 
 def test_configure_compile_cache_subprocess_contract(tmp_path):
-    """bench_common.configure_compile_cache sets BOTH channels (env for
-    fresh-import subprocesses, jax.config for the current process) and
-    an explicitly empty JAX_COMPILATION_CACHE_DIR disables the cache —
-    checked in subprocesses so this test can't disturb the session's own
-    cache config (tests/conftest.py points it at the shared dir)."""
+    """paddle_tpu.compile_cache.configure — the one definition of where
+    the persistent XLA cache lives.  JAX_COMPILATION_CACHE_DIR set: jax
+    keeps its cache there and the program sets NOTHING in code (jax's
+    own env-backed config already holds it).  Unset: the cache goes to
+    <checkout>/.jax_cache, exported for children.  Checked in fresh
+    subprocesses so the session's own cache config is never disturbed."""
     import json
     import subprocess
     import sys
@@ -698,35 +699,40 @@ def test_configure_compile_cache_subprocess_contract(tmp_path):
     prog = (
         "import os, sys, json\n"
         "sys.path.insert(0, %r)\n"
-        "import bench_common\n"
         "import jax\n"
-        "got = bench_common.configure_compile_cache(sys.argv[1])\n"
-        "print(json.dumps({'ret': got,\n"
+        "from paddle_tpu import compile_cache\n"
+        "calls = []\n"
+        "real = jax.config.update\n"
+        "jax.config.update = lambda k, v: (calls.append(k), real(k, v))\n"
+        "got = compile_cache.configure()\n"
+        "again = compile_cache.configure()\n"
+        "print(json.dumps({'ret': got, 'again': again, 'set_in_code': calls,\n"
         "  'env': os.environ.get('JAX_COMPILATION_CACHE_DIR'),\n"
         "  'cfg': jax.config.jax_compilation_cache_dir}))\n" % repo
     )
 
-    def run(env_override, default_dir):
+    def run(env_override):
         env = {k: v for k, v in os.environ.items()
                if k != "JAX_COMPILATION_CACHE_DIR"}
         env["JAX_PLATFORMS"] = "cpu"
         env.update(env_override)
         out = subprocess.run(
-            [sys.executable, "-c", prog, default_dir],
+            [sys.executable, "-c", prog],
             env=env, capture_output=True, text=True, timeout=120, check=True)
         return json.loads(out.stdout.strip().splitlines()[-1])
 
-    want = str(tmp_path / "xc")
-    # unset env -> the default seeds both channels
-    got = run({}, want)
-    assert got == {"ret": want, "env": want, "cfg": want}
-    # explicit env beats the default
-    other = str(tmp_path / "explicit")
-    got = run({"JAX_COMPILATION_CACHE_DIR": other}, want)
-    assert got == {"ret": other, "env": other, "cfg": other}
-    # explicitly empty -> disabled (config None), env left empty
-    got = run({"JAX_COMPILATION_CACHE_DIR": ""}, want)
-    assert got == {"ret": None, "env": "", "cfg": None}
+    # env set -> that directory, and nothing set in code
+    placed = str(tmp_path / "placed")
+    got = run({"JAX_COMPILATION_CACHE_DIR": placed})
+    assert got == {"ret": placed, "again": placed, "set_in_code": [],
+                   "env": placed, "cfg": placed}
+    # env unset -> <checkout>/.jax_cache through both channels (config
+    # for this process, env for its children); a second call is a no-op
+    want = os.path.join(repo, ".jax_cache")
+    got = run({})
+    assert got == {"ret": want, "again": want,
+                   "set_in_code": ["jax_compilation_cache_dir"],
+                   "env": want, "cfg": want}
 
 
 def test_fleet_top_once_renders_a_live_fleet():

@@ -429,10 +429,8 @@ def test_dgc_sparse_comm_bytes_on_wire():
             )
             return out["ParamOut"], out["UOut"], out["VOut"]
 
-        from paddle_tpu.parallel import mesh as mesh_lib
-
         return jax.jit(
-            mesh_lib.shard_map(
+            jax.shard_map(
                 f, mesh=mesh,
                 in_specs=(P(), P("dp"), P(), P()),
                 out_specs=(P(), P(), P()),

@@ -214,10 +214,8 @@ def test_ring_attention_standalone_parity():
     k = rng.normal(size=(B, H, T, D)).astype("float32")
     v = rng.normal(size=(B, H, T, D)).astype("float32")
 
-    from paddle_tpu.parallel import mesh as mesh_lib
-
     ring = jax.jit(
-        mesh_lib.shard_map(
+        jax.shard_map(
             lambda q, k, v: ring_attention(q, k, v, "sp", causal=True),
             mesh=mesh,
             in_specs=(P(None, None, "sp"), P(None, None, "sp"), P(None, None, "sp")),

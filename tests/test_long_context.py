@@ -101,6 +101,7 @@ def test_ring_attention_matches_full_attention(causal, scale):
     causal AND non-causal masks, default and custom scales, on heads
     whose dim is NOT a power of two (B=2, H=3, D=10, seq 32 ring-split
     4 ways)."""
+    import jax
     from jax.sharding import PartitionSpec as P
 
     B, H, D = 2, 3, 10
@@ -111,16 +112,30 @@ def test_ring_attention_matches_full_attention(causal, scale):
 
     mesh = mesh_lib.make_mesh({"sp": SP})
     spec = P(None, None, "sp", None)
-    ring = mesh_lib.shard_map(
+    ring = jax.shard_map(
         lambda a, b, c: ring_attention(a, b, c, axis_name="sp",
                                        causal=causal, scale=scale),
-        mesh, in_specs=(spec, spec, spec), out_specs=spec)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     got = np.asarray(ring(q, k, v))
 
     want = _full_attention(q, k, v, causal,
                            scale if scale is not None else D ** -0.5)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
     assert got.shape == (B, H, SEQ, D)
+
+
+def test_flash_flag_off_tpu_is_an_error_not_a_quiet_einsum(monkeypatch):
+    """PADDLE_TPU_FLASH_ATTENTION=1 asks for the pallas TPU kernel; on
+    any other backend the op must refuse, not fall back."""
+    from paddle_tpu.ops.nn_ops import fused_attention
+
+    q = np.zeros((1, 2, 8, 4), np.float32)
+    ins = {"Q": [q], "K": [q], "V": [q]}
+    monkeypatch.setenv("PADDLE_TPU_FLASH_ATTENTION", "1")
+    with pytest.raises(RuntimeError, match="PADDLE_TPU_FLASH_ATTENTION"):
+        fused_attention(ins, {"scale": 1.0})
+    monkeypatch.delenv("PADDLE_TPU_FLASH_ATTENTION")
+    assert fused_attention(ins, {"scale": 1.0})["Out"].shape == q.shape
 
 
 # ---------------------------------------------------------------------------

@@ -196,9 +196,7 @@ def test_batch_norm_under_data_parallel_and_sync():
                 fetches, _ = fn(dict(state), {"x": xs, "y": ys})
             return jax.lax.pmean(fetches[0], "dp")
 
-        from paddle_tpu.parallel import mesh as mesh_lib
-
-        sharded = jax.jit(mesh_lib.shard_map(
+        sharded = jax.jit(jax.shard_map(
             step, mesh=mesh, in_specs=(P(), P("dp"), P("dp")), out_specs=P(),
             check_vma=False,
         ))

@@ -68,7 +68,9 @@ def test_ledger_phases_sum_exactly_to_wall():
     snap = led.snapshot()
     assert snap["finished"]
     total = sum(snap["phases"].values())
-    assert total == pytest.approx(snap["wall_s"], rel=1e-6)
+    # snapshot() rounds each phase and the wall to 1e-6 s separately, so
+    # the rounded parts may miss the rounded whole by a few 1e-6
+    assert total == pytest.approx(snap["wall_s"], abs=1e-5)
     assert snap["phases"]["other"] >= 0.01  # the unattributed sleep
 
 
@@ -91,7 +93,7 @@ def test_ledger_window_excludes_nested_charges():
     led.finish_epoch()
     snap = led.snapshot()
     assert sum(snap["phases"].values()) == pytest.approx(
-        snap["wall_s"], rel=1e-6)
+        snap["wall_s"], abs=1e-5)  # per-phase 1e-6 rounding, as above
 
 
 def test_ledger_overcount_fails_loudly():
@@ -241,13 +243,45 @@ def test_watchdog_grad_norm_blowup_and_nonfinite():
 
 
 # ---------------------------------------------------------------------------
+# the peaks table: unknown device = error, explicit peak = MFU
+# ---------------------------------------------------------------------------
+def test_peaks_table_rejects_unknown_device_and_cpu():
+    import types
+
+    import jax
+
+    from paddle_tpu import device_peaks
+
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert device_peaks.peak_flops(v5e) == 197e12
+    unknown = types.SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    with pytest.raises(ValueError, match="TPU v99"):
+        device_peaks.peak_flops(unknown)
+    with pytest.raises(ValueError, match="no published peak"):
+        device_peaks.peak_flops(jax.devices()[0])  # the CPU testbed
+
+
+def test_ledger_reports_mfu_only_against_a_stated_peak():
+    led = mtrain.StepPhaseLedger(metrics=False, flops_per_step=2e6)
+    assert led.peak_flops is None  # CPU default: no peak, no MFU
+    led2 = mtrain.StepPhaseLedger(metrics=False, flops_per_step=2e6,
+                                  peak_flops=1e9)
+    for one in (led, led2):
+        one.begin_epoch()
+        one.charge("device_execute", 0.001)
+        one.step_done(0, 0.001, examples=4)
+    assert led.snapshot()["mfu_ratio"] is None
+    assert led2.snapshot()["mfu_ratio"] > 0.0
+
+
+# ---------------------------------------------------------------------------
 # train_from_dataset end to end
 # ---------------------------------------------------------------------------
-def test_train_epoch_ledger_watchdog_steplog_end_to_end(tmp_path, monkeypatch):
-    """One armed epoch: ledger books balance within 1%, throughput +
-    MFU gauges land, the step log replays to the same totals, and
+def test_train_epoch_ledger_watchdog_steplog_end_to_end(tmp_path):
+    """One armed epoch: ledger books balance within 1%, throughput
+    gauges land (and NO MFU: this runs on a CPU, which has no published
+    peak), the step log replays to the same totals, and
     ``exe.trainz()`` composes it all."""
-    monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "1e6")  # toy-model scale
     prog, startup, loss, _ = _fc_model()
     feeds = _feeds(n=12)
     exe = fluid.Executor(fluid.CPUPlace())
@@ -269,10 +303,11 @@ def test_train_epoch_ledger_watchdog_steplog_end_to_end(tmp_path, monkeypatch):
     assert snap["phases"]["h2d"] > 0.0
     assert snap["steps_per_second"] > 0.0
     assert snap["examples_per_second"] > 0.0
-    # static-FLOPs MFU resolved on the first step from the block shapes
+    # static FLOPs resolved on the first step from the block shapes;
+    # a CPU has no row in the peaks table, so no MFU is reported
     assert snap["flops_per_step"] == pytest.approx(
         mtrain.estimate_block_flops(prog, batch=4))
-    assert snap["mfu_ratio"] > 0.0
+    assert snap["peak_flops"] is None and snap["mfu_ratio"] is None
     # registry surfaces
     assert monitor.counter_value("train_phase_seconds_total",
                                  phase="device_execute") > 0.0
