@@ -1,0 +1,2 @@
+"""Per-layer metric ``prefill_step_share.offline``: see ``benchmark/lib/readers.prefill_step_share``."""
+from benchmark.lib.readers import prefill_step_share as read  # noqa: F401

@@ -1,0 +1,2 @@
+"""Per-layer metric ``tick_ms.offline``: see ``benchmark/lib/readers.tick_ms``."""
+from benchmark.lib.readers import tick_ms as read  # noqa: F401
