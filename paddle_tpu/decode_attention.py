@@ -1,0 +1,303 @@
+"""Decode attention over a slot pool that moves only live KV bytes.
+
+One decode step of the slot pool attends, per slot ``n``, one fresh
+query row to the slot's cached positions ``0..ts[n]``.  The pooled
+cache leaf is ``[slots, T, n_head * d_head]`` (heads folded into the
+lane axis: ``d_head`` = 64 alone would be padded to a 128-lane tile in
+HBM and double the pool).  Per step and layer this module
+
+* **appends in place** — the new K/V row of slot ``n`` lands at position
+  ``ts[n]`` by an O(row) update of the donated leaf (a DMA of the row on
+  the TPU, a scatter elsewhere), never by re-emitting the leaf;
+* **reads ragged** — slot ``n`` touches positions ``0..ts[n]`` rounded up
+  to ``block``; a slot with ``ts[n] < 0`` (idle) touches nothing, is not
+  written, and gets a zero context row.
+
+Two implementations of that one contract, chosen by
+:func:`make_decode_attention` from the backend the pool lives on:
+
+* :func:`ragged_decode_attention` — the Pallas TPU kernel.  The step's
+  live ``(slot, block)`` pairs are flattened into one work list
+  (:func:`decode_work_items`, shared by every layer of a step); the
+  kernel walks it with a dynamic trip count, double-buffering the K/V
+  block DMAs across slot boundaries, online softmax over the blocks
+  read.  K/V stay fp32 in HBM; products are fp32 on the VPU, and the
+  per-head sums ride the MXU as a ``[D, 128]`` 0/1 indicator matmul with
+  the fp32 operand split three ways into bf16 (hi + mid + lo carries 24
+  mantissa bits; the indicator is exact), accumulated in fp32.
+* :func:`masked_decode_attention` — the same math as plain XLA ops
+  (scatter append + masked softmax over the whole T axis): the CPU path
+  and the parity reference of tests/test_decode_attention.py.
+
+``jax.experimental.pallas`` is imported inside the kernel builder only:
+``import paddle_tpu`` and the training cells never pay for it.
+"""
+from __future__ import annotations
+
+import functools
+
+import numpy as np
+
+__all__ = ["KV_BLOCK", "kv_read_block", "decode_work_items",
+           "ragged_decode_attention", "masked_decode_attention",
+           "kernel_supported", "make_decode_attention"]
+
+#: positions per K/V block the kernel moves in one DMA (and the rounding
+#: of ``serving_decode_kv_positions_read_total``)
+KV_BLOCK = 128
+_HEAD_LANES = 128   # heads padded to one lane tile in the score domain
+_MASK = -1e30       # finite: exp(_MASK - m) == 0, no inf - inf
+
+
+def kv_read_block(seq_len: int) -> int:
+    """The block (in positions) a decode step reads a length-``seq_len``
+    rung in: :data:`KV_BLOCK` when it divides the rung, else the rung."""
+    seq_len = int(seq_len)
+    return KV_BLOCK if seq_len % KV_BLOCK == 0 else seq_len
+
+
+def kernel_supported(seq_len: int, d_model: int, n_head: int) -> bool:
+    """Shapes the TPU kernel lowers for: lane-dense rows (``d_model`` a
+    multiple of 128), sublane-aligned blocks, heads within one lane tile."""
+    return (d_model % 128 == 0 and kv_read_block(seq_len) % 8 == 0
+            and n_head <= _HEAD_LANES)
+
+
+def decode_work_items(ts, seq_len: int, block: int):
+    """Flatten the step's live ``(slot, block)`` pairs, slot-major.
+
+    ``ts`` [S] int32 (``< 0`` = idle).  Returns ``(n_items [1], slot
+    [S * seq_len // block], blk [same])`` int32; entries past
+    ``n_items`` are padding.  A slot at position ``ts`` owns blocks
+    ``0..ts // block`` — at least the one its new row lands in."""
+    import jax.numpy as jnp
+
+    S = ts.shape[0]
+    max_items = S * (seq_len // block)
+    nblk = jnp.where(ts >= 0, ts // block + 1, 0).astype(jnp.int32)
+    ends = jnp.cumsum(nblk)
+    slot = jnp.repeat(jnp.arange(S, dtype=jnp.int32), nblk,
+                      total_repeat_length=max_items)
+    blk = jnp.arange(max_items, dtype=jnp.int32) - (ends - nblk)[slot]
+    return ends[-1:].astype(jnp.int32), slot, blk.astype(jnp.int32)
+
+
+@functools.lru_cache(maxsize=None)
+def _indicators(d_model: int, n_head: int):
+    """``E`` [D, 128] with ``E[d, h] = 1`` where lane ``d`` belongs to
+    head ``h`` (a lane-to-head sum as a matmul), and its transpose (a
+    head-to-lanes broadcast)."""
+    e = (np.arange(d_model)[:, None] // (d_model // n_head)
+         == np.arange(_HEAD_LANES)[None, :]).astype(np.float32)
+    return e, np.ascontiguousarray(e.T)
+
+
+def _split3(x):
+    """fp32 -> three bf16 terms whose sum carries x to ~2^-24."""
+    import jax.numpy as jnp
+
+    hi = x.astype(jnp.bfloat16)
+    r = x - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def _dot3(x, w):
+    """``x @ w`` for fp32 ``x`` and an exactly-bf16 0/1 ``w``: three
+    single-pass MXU matmuls, fp32 accumulation."""
+    import jax.numpy as jnp
+
+    return sum(jnp.dot(t, w, preferred_element_type=jnp.float32)
+               for t in _split3(x))
+
+
+def _kernel(n_items_ref, item_slot_ref, item_blk_ref, ts_ref,   # SMEM
+            q_ref, kn_ref, vn_ref, e_ref, et_ref,               # VMEM
+            k_hbm, v_hbm,                                       # HBM (ANY)
+            o_ref, k_out, v_out,                                # outputs
+            kbuf, vbuf, m_ref, l_ref, acc_ref, rsem, wsem, *, block):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_items = n_items_ref[0]
+    o_ref[...] = jnp.zeros_like(o_ref)   # idle slots: zero context
+
+    def read(i, buf):
+        n, b = item_slot_ref[i], item_blk_ref[i]
+        rows = pl.ds(pl.multiple_of(b * block, block), block)
+        return (pltpu.make_async_copy(k_hbm.at[n, rows], kbuf.at[buf],
+                                      rsem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[n, rows], vbuf.at[buf],
+                                      rsem.at[1, buf]))
+
+    @pl.when(n_items > 0)
+    def _():
+        for c in read(0, 0):
+            c.start()
+
+    def item(i, carry):
+        buf = i % 2
+        n, b = item_slot_ref[i], item_blk_ref[i]
+        t = ts_ref[n]
+        last = b == t // block
+
+        @pl.when(i + 1 < n_items)
+        def _():
+            for c in read(i + 1, 1 - buf):
+                c.start()
+
+        for c in read(i, buf):
+            c.wait()
+
+        @pl.when(b == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, _MASK)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        row = pl.ds(n, 1)
+        # the sublane tile of 8 positions the new row falls in: a DMA
+        # moves whole tiles, so the append writes the patched tile back
+        # (its other 7 rows as they were read)
+        t8 = pl.multiple_of(t // 8 * 8, 8)
+        tile = pl.ds(pl.multiple_of(t8 - b * block, 8), 8)
+        writes = (pltpu.make_async_copy(kbuf.at[buf, tile],
+                                        k_out.at[n, pl.ds(t8, 8)],
+                                        wsem.at[0]),
+                  pltpu.make_async_copy(vbuf.at[buf, tile],
+                                        v_out.at[n, pl.ds(t8, 8)],
+                                        wsem.at[1]))
+
+        @pl.when(last)
+        def _():
+            # append: the row is patched into the block just read (so the
+            # read never waits on it) and goes to HBM by its own DMA
+            r = pl.ds(t - b * block, 1)
+            kbuf[buf, r, :] = kn_ref[row, :]
+            vbuf[buf, r, :] = vn_ref[row, :]
+            for c in writes:
+                c.start()
+
+        s = _dot3(kbuf[buf] * q_ref[row, :], e_ref[...])   # [block, 128]
+        pos = b * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        s = jnp.where(pos <= t, s, _MASK)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        m_ref[...] = m_new
+        alpha = jnp.exp(m_prev - m_new)                     # [1, 128]
+        p = jnp.exp(s - m_new)
+        # one expansion matmul for p and alpha: heads -> their lanes
+        x = _dot3(jnp.concatenate(
+            [p, jnp.broadcast_to(alpha, (8, alpha.shape[1]))], axis=0),
+            et_ref[...])                                    # [block+8, D]
+        pe, ae = x[:block], x[block:block + 1]
+        l_ref[...] = ae * l_ref[...] + jnp.sum(pe, axis=0, keepdims=True)
+        acc_ref[...] = ae * acc_ref[...] + jnp.sum(
+            pe * vbuf[buf], axis=0, keepdims=True)
+
+        @pl.when(last)
+        def _():
+            o_ref[row, :] = acc_ref[...] / l_ref[...]
+            for c in writes:
+                c.wait()
+
+        return carry
+
+    jax.lax.fori_loop(0, n_items, item, 0)
+
+
+def ragged_decode_attention(q, k_new, v_new, k_cache, v_cache, ts, work,
+                            *, n_head: int, scale: float, block: int,
+                            interpret=False):
+    """The Pallas TPU kernel (see the module docstring).
+
+    ``q``, ``k_new``, ``v_new`` [S, D] fp32 (scores are ``scale * q.k``
+    per head); ``k_cache``, ``v_cache`` [S, T, D] fp32, updated in
+    place (aliased to the returned leaves); ``ts`` [S] int32; ``work``
+    from :func:`decode_work_items` for the same ``ts``/``block``.
+    Returns ``(ctx [S, D], k_cache, v_cache)``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, T, D = k_cache.shape
+    e, et = _indicators(D, n_head)
+    n_items, item_slot, item_blk = work
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    f32 = jnp.float32
+    # fp32 words the kernel keeps in VMEM: q, k_new, v_new and the
+    # context whole, the two double-buffered blocks, and room for the
+    # block-sized temporaries of the products, their splits and sums
+    resident = 4 * (4 * S * D + 4 * block * D + 8 * (block + 8) * D)
+    return pl.pallas_call(
+        functools.partial(_kernel, block=block),
+        out_shape=(jax.ShapeDtypeStruct((S, D), f32),
+                   jax.ShapeDtypeStruct(k_cache.shape, f32),
+                   jax.ShapeDtypeStruct(v_cache.shape, f32)),
+        in_specs=[smem] * 4 + [vmem] * 5 + [hbm] * 2,
+        out_specs=(vmem, hbm, hbm),
+        scratch_shapes=[
+            pltpu.VMEM((2, block, D), f32),
+            pltpu.VMEM((2, block, D), f32),
+            pltpu.VMEM((1, _HEAD_LANES), f32),
+            pltpu.VMEM((1, D), f32),
+            pltpu.VMEM((1, D), f32),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+        input_output_aliases={9: 1, 10: 2},   # k_cache, v_cache in place
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=min(100 << 20, max(32 << 20, 2 * resident))),
+        name="ragged_decode_attention",
+        interpret=interpret,
+    )(n_items, item_slot, item_blk, ts, q * scale, k_new, v_new,
+      jnp.asarray(e, jnp.bfloat16), jnp.asarray(et, jnp.bfloat16),
+      k_cache, v_cache)
+
+
+def masked_decode_attention(q, k_new, v_new, k_cache, v_cache, ts,
+                            *, n_head: int, scale: float):
+    """The contract as plain XLA ops: scatter the new rows at ``ts``
+    (idle rows dropped), then a masked softmax over the whole T axis.
+    Same arguments and returns as :func:`ragged_decode_attention`."""
+    import jax
+    import jax.numpy as jnp
+
+    S, T, D = k_cache.shape
+    d_head = D // n_head
+    rows = jnp.arange(S)
+    at = jnp.where(ts >= 0, ts, T)          # idle -> out of range, dropped
+    k_cache = k_cache.at[rows, at].set(k_new, mode="drop")
+    v_cache = v_cache.at[rows, at].set(v_new, mode="drop")
+    pos_ok = (jnp.arange(T)[None, :] <= ts[:, None])[:, None, :]  # [S,1,T]
+    scores = jnp.einsum("nhd,nthd->nht", q.reshape(S, n_head, d_head),
+                        k_cache.reshape(S, T, n_head, d_head)) * scale
+    w = jax.nn.softmax(jnp.where(pos_ok, scores, -1e9), axis=-1)
+    ctx = jnp.einsum("nht,nthd->nhd", w,
+                     v_cache.reshape(S, T, n_head, d_head)).reshape(S, D)
+    return jnp.where((ts >= 0)[:, None], ctx, 0.0), k_cache, v_cache
+
+
+def make_decode_attention(ts, seq_len: int, d_model: int, n_head: int,
+                          scale: float):
+    """``attend(q, k_new, v_new, k_cache, v_cache) -> (ctx, k_cache,
+    v_cache)`` for one step at positions ``ts``, shared by its layers:
+    the kernel when the default backend is a TPU and the shapes lower,
+    the XLA ops otherwise."""
+    import jax
+
+    if (jax.default_backend() == "tpu"
+            and kernel_supported(seq_len, d_model, n_head)):
+        block = kv_read_block(seq_len)
+        return functools.partial(
+            ragged_decode_attention, ts=ts,
+            work=decode_work_items(ts, seq_len, block),
+            n_head=n_head, scale=scale, block=block)
+    return functools.partial(masked_decode_attention, ts=ts,
+                             n_head=n_head, scale=scale)

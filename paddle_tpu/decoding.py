@@ -282,68 +282,89 @@ def _lm_forward_one(W, name, cache, x, t, ts, n_layer, n_head, d_head,
     """One incremental transformer-LM forward shared by the scalar-``t``
     and slot-pooled (per-row ``ts``) step fns.  Exactly one of ``t``
     (scalar loop position, all rows aligned) / ``ts`` ([N] int32, each
-    row at its own position) is not None; the cache T axis is read from
-    the cache itself so one builder serves every length rung.
+    row at its own position, ``< 0`` = idle row) is not None; the cache
+    T axis is read from the cache itself so one builder serves every
+    length rung.
 
-    ``kv_int8`` (pooled path only): the cache stores K/V rows int8 with
-    per-(slot, head, position) fp32 scales as sibling ``k_scale``/
-    ``v_scale`` leaves — each fresh row is quantized as it is written
-    (quantize-on-write) and the whole cache is dequantized in registers
-    at attention time (dequant-at-attend), so HBM traffic moves int8
-    bytes while the attention math stays fp32."""
+    What a step reads and writes of the cache, per layer:
+
+    * scalar ``t`` (``[N, H, T, Dh]`` leaves): one
+      ``dynamic_update_index_in_dim`` row per lane, attention masked
+      over the whole T axis.
+    * pooled fp32 (``[N, T, H * Dh]`` leaves): the new K/V row of lane
+      ``n`` is appended IN PLACE at ``ts[n]`` and attention reads
+      positions ``0..ts[n]`` only, rounded up to
+      ``decode_attention.kv_read_block(T)``; an idle lane reads and
+      writes nothing and its logits are garbage the caller discards.
+      On a TPU (and shapes the kernel lowers for) that is the Pallas
+      kernel ``decode_attention.ragged_decode_attention``; elsewhere the
+      same contract as XLA ops (scatter + masked softmax).
+    * pooled int8 (``kv_int8``; ``[N, H, T, Dh]`` int8 leaves with
+      per-(slot, head, position) fp32 scales as sibling ``k_scale``/
+      ``v_scale`` leaves): each fresh row is quantized as it is written
+      (quantize-on-write) through a one-hot select that re-emits the
+      WHOLE leaf, and the whole cache is dequantized at attention time
+      (dequant-at-attend) — O(pool) per step, whatever is live."""
     import jax
     import jax.numpy as jnp
 
-    T = cache[0]["k"].shape[2]
     n = x.shape[0]
-    if ts is None:
-        pos_ok = (jnp.arange(T) <= t)[None, None, :]       # [1,1,T]
-        row_t = None
+    ragged = ts is not None and not kv_int8
+    if ragged:
+        from paddle_tpu.decode_attention import make_decode_attention
+
+        T = cache[0]["k"].shape[1]
+        attend = make_decode_attention(jnp.minimum(ts, T - 1), T, d_model,
+                                       n_head, scale)
     else:
-        pos_ok = (jnp.arange(T)[None, :] <= ts[:, None])[:, None, :]  # [N,1,T]
-        row_t = (jnp.arange(T)[None, :] == ts[:, None])    # [N,T]
+        T = cache[0]["k"].shape[2]
+        if ts is None:
+            pos_ok = (jnp.arange(T) <= t)[None, None, :]   # [1,1,T]
+        else:
+            pos_ok = (jnp.arange(T)[None, :] <= ts[:, None])[:, None, :]
+            row_t = (jnp.arange(T)[None, :] == ts[:, None])    # [N,T]
     if kv_int8:
         from paddle_tpu.quant import dequantize_rows, quantize_rows
     new_cache = []
     for i in range(n_layer):
         p = "%s_dec_%d" % (name, i)
-        q = _fc(W, x, p + "_att_q").reshape(n, n_head, d_head)
-        k = _fc(W, x, p + "_att_k").reshape(n, n_head, d_head)
-        v = _fc(W, x, p + "_att_v").reshape(n, n_head, d_head)
-        if kv_int8:
-            # quantize-on-write: one absmax scale per fresh (row, head)
-            kq, ks = quantize_rows(k)                      # [N,H] scales
-            vq, vs = quantize_rows(v)
-            sel = row_t[:, None, :, None]                  # [N,1,T,1]
-            ssel = row_t[:, None, :]                       # [N,1,T]
-            kc = jnp.where(sel, kq[:, :, None, :], cache[i]["k"])
-            vc = jnp.where(sel, vq[:, :, None, :], cache[i]["v"])
-            ksc = jnp.where(ssel, ks[:, :, None], cache[i]["k_scale"])
-            vsc = jnp.where(ssel, vs[:, :, None], cache[i]["v_scale"])
-            new_cache.append({"k": kc, "k_scale": ksc,
-                              "v": vc, "v_scale": vsc})
-            # dequant-at-attend: int8 bytes leave HBM, fp32 enters the
-            # einsums
-            kcf = dequantize_rows(kc, ksc)
-            vcf = dequantize_rows(vc, vsc)
+        q = _fc(W, x, p + "_att_q")
+        k = _fc(W, x, p + "_att_k")
+        v = _fc(W, x, p + "_att_v")
+        if ragged:
+            ctx, kc, vc = attend(q, k, v, cache[i]["k"], cache[i]["v"])
+            new_cache.append({"k": kc, "v": vc})
         else:
-            if ts is None:
+            q = q.reshape(n, n_head, d_head)
+            k = k.reshape(n, n_head, d_head)
+            v = v.reshape(n, n_head, d_head)
+            if kv_int8:
+                # quantize-on-write: one absmax scale per fresh (row, head)
+                kq, ks = quantize_rows(k)                  # [N,H] scales
+                vq, vs = quantize_rows(v)
+                sel = row_t[:, None, :, None]              # [N,1,T,1]
+                ssel = row_t[:, None, :]                   # [N,1,T]
+                kc = jnp.where(sel, kq[:, :, None, :], cache[i]["k"])
+                vc = jnp.where(sel, vq[:, :, None, :], cache[i]["v"])
+                ksc = jnp.where(ssel, ks[:, :, None], cache[i]["k_scale"])
+                vsc = jnp.where(ssel, vs[:, :, None], cache[i]["v_scale"])
+                new_cache.append({"k": kc, "k_scale": ksc,
+                                  "v": vc, "v_scale": vsc})
+                # dequant-at-attend: int8 bytes leave HBM, fp32 enters
+                # the einsums
+                kcf = dequantize_rows(kc, ksc)
+                vcf = dequantize_rows(vc, vsc)
+            else:
                 kc = jax.lax.dynamic_update_index_in_dim(
                     cache[i]["k"], k, t, axis=2)
                 vc = jax.lax.dynamic_update_index_in_dim(
                     cache[i]["v"], v, t, axis=2)
-            else:
-                # per-row scatter: each lane writes its OWN position —
-                # the one-hot select is O(cache) like the attention
-                sel = row_t[:, None, :, None]              # [N,1,T,1]
-                kc = jnp.where(sel, k[:, :, None, :], cache[i]["k"])
-                vc = jnp.where(sel, v[:, :, None, :], cache[i]["v"])
-            new_cache.append({"k": kc, "v": vc})
-            kcf, vcf = kc, vc
-        scores = jnp.einsum("nhd,nhtd->nht", q, kcf) * scale
-        scores = jnp.where(pos_ok, scores, -1e9)
-        w = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("nht,nhtd->nhd", w, vcf).reshape(n, d_model)
+                new_cache.append({"k": kc, "v": vc})
+                kcf, vcf = kc, vc
+            scores = jnp.einsum("nhd,nhtd->nht", q, kcf) * scale
+            scores = jnp.where(pos_ok, scores, -1e9)
+            w = jax.nn.softmax(scores, axis=-1)
+            ctx = jnp.einsum("nht,nhtd->nhd", w, vcf).reshape(n, d_model)
         att = _fc(W, ctx, p + "_att_out")
         x = _ln(W, x + att, p + "_ln1")
         h = jax.nn.gelu(_fc(W, x, p + "_ffn_fc0"), approximate=False)
@@ -383,30 +404,43 @@ def make_transformer_lm_pooled_step_fn(
     per-row positions: ``step_fn(cache, tokens [N] int32, ts [N] int32)
     -> (logits [N, V], cache)`` where row ``i`` consumes ``tokens[i]``
     at position ``ts[i]`` (cache row ``i`` updated at ``ts[i]``; its
-    attention masked to positions ``<= ts[i]``).
+    attention reads positions ``<= ts[i]``).  ``ts[i] < 0`` marks an
+    IDLE row: nothing of its cache row is read or written and its
+    logits are garbage — ``make_slot_decode_fns`` passes ``-1`` for
+    every slot that is not active, and ``verify_fn``, the draft step
+    and any stand-in step fn follow the same contract.
+
+    What a step moves (fp32): the cache leaves are ``[N, T, d_model]``
+    (heads folded into the lane axis — a ``d_head`` of 64 as the minor
+    axis would be padded to a 128-lane tile in HBM), each new K/V row is
+    appended in place (O(row), the state is donated), and attention
+    reads each row's live positions only, in blocks of
+    ``decode_attention.kv_read_block(T)`` — bytes follow live positions,
+    not the pool's size (see ``_lm_forward_one``).
 
     The cache T axis is read from the cache arrays themselves, so one
     step fn serves every length rung of the slot pool's bucket ladder:
     ``make_cache(n_rows, seq_len)`` allocates the zeroed pytree for one
     (slot-rung, length-rung) pair.  Math is identical to the scalar-t
-    builder — with all rows at the same position the two are exactly
-    equal (parity-tested in tests/test_seq2seq_decode.py).
+    builder — with all rows at the same position the two agree to
+    rounding (parity-tested in tests/test_seq2seq_decode.py).
 
     The pool relies on a write-before-read invariant instead of cache
     zeroing on slot reuse: a sequence at position ``ts`` has itself
     written every cache position ``<= ts`` (prefill consumes each prompt
-    token through the same step), and the mask hides ``> ts`` — stale
-    rows from a previous occupant are never read.
+    token through the same step), and positions ``> ts`` are never read
+    — stale rows from a previous occupant are never read.
 
-    ``kv_dtype="int8"`` stores the cache int8 (per-slot-per-head
-    scales as sibling ``k_scale``/``v_scale`` [N, H, T] fp32 leaves,
-    quantize-on-write / dequant-at-attend — see ``_lm_forward_one``),
-    roughly quartering per-slot KV bytes so a fixed HBM budget holds
-    ~2x+ the concurrent sequences.  The scale leaves keep the slot
-    axis leading and the sequence axis last, so the slot pool's
-    ``resize``/``extract_kv``/``admit_prefix`` carry them exactly like
-    the K/V leaves (``kv_leaf_seq_axis`` qualifies them) — prefix
-    caching and speculative decode compose unchanged.
+    ``kv_dtype="int8"`` stores the cache int8 in ``[N, H, T, Dh]``
+    leaves (per-slot-per-head scales as sibling ``k_scale``/``v_scale``
+    [N, H, T] fp32 leaves, quantize-on-write / dequant-at-attend — see
+    ``_lm_forward_one``), roughly quartering per-slot KV bytes so a
+    fixed HBM budget holds ~2x+ the concurrent sequences; its step
+    still moves the whole pool.  Every leaf keeps the slot axis
+    leading and a sequence axis ``kv_leaf_seq_axis`` finds by shape, so
+    the slot pool's ``resize``/``extract_kv``/``admit_prefix`` carry
+    both layouts unchanged — prefix caching and speculative decode
+    compose unchanged.
     """
     import jax.numpy as jnp
 
@@ -433,14 +467,15 @@ def make_transformer_lm_pooled_step_fn(
             ]
         return [
             {
-                "k": jnp.zeros((n_rows, n_head, seq_len, d_head), "float32"),
-                "v": jnp.zeros((n_rows, n_head, seq_len, d_head), "float32"),
+                "k": jnp.zeros((n_rows, seq_len, d_model), "float32"),
+                "v": jnp.zeros((n_rows, seq_len, d_model), "float32"),
             }
             for _ in range(n_layer)
         ]
 
     def step_fn(cache, tokens, ts):
-        x = W[name + "_word_emb"][tokens] + W[name + "_pos_emb"][ts]
+        x = (W[name + "_word_emb"][tokens]
+             + W[name + "_pos_emb"][jnp.maximum(ts, 0)])
         return _lm_forward_one(W, name, cache, x, None, ts, n_layer,
                                n_head, d_head, d_model, scale,
                                kv_int8=kv_int8)
@@ -474,9 +509,14 @@ def make_transformer_lm_pooled_verify_fn(
     Positions are clamped to the cache T axis like the sequential step
     clamps its buffer indices; a clamped lane is garbage-in-garbage-out
     but such lanes are inactive/finished and their results are never
-    committed.  The K fresh K/V rows are scattered into the cache BEFORE
-    attention (write-before-read, same invariant as the pooled step), so
-    position ``ts + j`` attends to the just-written rows ``ts .. ts + j``.
+    committed.  ``ts[i] < 0`` marks an idle row, as in the pooled step:
+    none of its cache row is written.  The K fresh K/V rows are
+    scattered into the cache BEFORE attention (write-before-read, same
+    invariant as the pooled step), so position ``ts + j`` attends to the
+    just-written rows ``ts .. ts + j``.  fp32 leaves are the pooled
+    step's ``[S, T, d_model]`` and take the K rows by an in-place
+    scatter; the read is masked over the whole T axis (no cell prices a
+    speculative round yet).
 
     ``kv_dtype`` must match the step fn the cache was built for: with
     ``"int8"`` each fresh row is quantized EXACTLY like the sequential
@@ -498,32 +538,41 @@ def make_transformer_lm_pooled_verify_fn(
 
     def verify_fn(cache, tokens, ts):
         S, K = tokens.shape
-        T = cache[0]["k"].shape[2]
-        p = jnp.minimum(ts[:, None] + jnp.arange(K)[None, :], T - 1)
+        # int8 leaves are [S, H, T, Dh], fp32 leaves [S, T, H * Dh]
+        kv_axes = "shtd" if kv_int8 else "sthd"
+        T = cache[0]["k"].shape[2 if kv_int8 else 1]
+        idle = ts < 0
+        p = jnp.minimum(jnp.maximum(ts, 0)[:, None]
+                        + jnp.arange(K)[None, :], T - 1)
         x = W[name + "_word_emb"][tokens] + W[name + "_pos_emb"][p]
-        sel = (jnp.arange(T)[None, None, :] == p[:, :, None])  # [S,K,T]
-        touched = sel.any(axis=1)[:, None, :, None]            # [S,1,T,1]
-        touched_s = sel.any(axis=1)[:, None, :]                # [S,1,T]
         pos_ok = (jnp.arange(T)[None, None, None, :]
                   <= p[:, :, None, None])                      # [S,K,1,T]
+        if kv_int8:
+            sel = ((jnp.arange(T)[None, None, :] == p[:, :, None])
+                   & ~idle[:, None, None])                     # [S,K,T]
+            touched = sel.any(axis=1)[:, None, :, None]        # [S,1,T,1]
+            touched_s = sel.any(axis=1)[:, None, :]            # [S,1,T]
+            selk = sel.astype(jnp.float32)
+        else:
+            rows = jnp.arange(S)[:, None]
+            at = jnp.where(idle[:, None], T, p)    # idle: dropped
         new_cache = []
         for i in range(n_layer):
             pfx = "%s_dec_%d" % (name, i)
             q = _fc(W, x, pfx + "_att_q").reshape(S, K, n_head, d_head)
-            k = _fc(W, x, pfx + "_att_k").reshape(S, K, n_head, d_head)
-            v = _fc(W, x, pfx + "_att_v").reshape(S, K, n_head, d_head)
-            # scatter the K fresh rows at positions p: the one-hot
-            # einsum reduces to an exact copy for the (distinct) live
-            # positions; clamp collisions only happen on lanes past
-            # their buffer, whose rows are never read back
-            selk = sel.astype(jnp.float32)
+            k = _fc(W, x, pfx + "_att_k")
+            v = _fc(W, x, pfx + "_att_v")
             if kv_int8:
-                # quantize each fresh row the way the sequential step
+                # scatter the K fresh rows at positions p: the one-hot
+                # einsum reduces to an exact copy for the (distinct)
+                # live positions; clamp collisions only happen on lanes
+                # past their buffer, whose rows are never read back.
+                # Quantize each fresh row the way the sequential step
                 # does (per-row absmax) BEFORE the scatter: int8 codes
                 # are exact small integers in fp32, so the one-hot
                 # einsum copy round-trips them bit-identically
-                kq, ks = quantize_rows(k)                  # [S,K,H]
-                vq, vs = quantize_rows(v)
+                kq, ks = quantize_rows(k.reshape(S, K, n_head, d_head))
+                vq, vs = quantize_rows(v.reshape(S, K, n_head, d_head))
                 kc = jnp.where(
                     touched,
                     jnp.clip(jnp.einsum("skt,skhd->shtd", selk,
@@ -547,18 +596,18 @@ def make_transformer_lm_pooled_verify_fn(
                 kcf = dequantize_rows(kc, ksc)
                 vcf = dequantize_rows(vc, vsc)
             else:
-                kc = jnp.where(touched,
-                               jnp.einsum("skt,skhd->shtd", selk, k),
-                               cache[i]["k"])
-                vc = jnp.where(touched,
-                               jnp.einsum("skt,skhd->shtd", selk, v),
-                               cache[i]["v"])
+                # in-place append of the K fresh rows (clamp collisions
+                # only on lanes past their buffer, never read back)
+                kc = cache[i]["k"].at[rows, at].set(k, mode="drop")
+                vc = cache[i]["v"].at[rows, at].set(v, mode="drop")
                 new_cache.append({"k": kc, "v": vc})
-                kcf, vcf = kc, vc
-            scores = jnp.einsum("skhd,shtd->skht", q, kcf) * scale
+                kcf = kc.reshape(S, T, n_head, d_head)
+                vcf = vc.reshape(S, T, n_head, d_head)
+            scores = jnp.einsum("skhd,%s->skht" % kv_axes, q, kcf) * scale
             scores = jnp.where(pos_ok, scores, -1e9)
             w = jax.nn.softmax(scores, axis=-1)
-            ctx = jnp.einsum("skht,shtd->skhd", w, vcf).reshape(S, K, d_model)
+            ctx = jnp.einsum("skht,%s->skhd" % kv_axes, w,
+                             vcf).reshape(S, K, d_model)
             att = _fc(W, ctx, pfx + "_att_out")
             x = _ln(W, x + att, pfx + "_ln1")
             h = jax.nn.gelu(_fc(W, x, pfx + "_ffn_fc0"), approximate=False)
@@ -601,8 +650,9 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
     its cache inside the running batch — no separate prefill executable,
     no second compiled shape.  A slot finishes when it emits ``eos_id``
     or reaches ``total_len``; inactive slots are fully masked (their
-    ``pos`` does not advance) and cost only the wasted lane math the
-    bucket ladder already prices in.
+    ``pos`` does not advance), reach the step as ``ts = -1`` so their
+    cache rows are neither read nor written, and cost only the wasted
+    lane math the bucket ladder already prices in.
 
     Extra state leaves pass through untouched (dict-copy semantics), so
     the speculative pool's ``spec`` flag and ``draft_cache`` ride the
@@ -624,7 +674,10 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
         S, T = tokens.shape
         rows = jnp.arange(S)
         tok_in = tokens[rows, jnp.minimum(pos, T - 1)]
-        logits, cache = step_fn(state["cache"], tok_in, pos)
+        # an idle slot keeps its stale ``pos``: tell the step (ts < 0)
+        # so it neither reads nor writes that slot's cache row
+        ts = jnp.where(active, pos, -1)
+        logits, cache = step_fn(state["cache"], tok_in, ts)
         nxt = jnp.argmax(logits, axis=-1).astype("int32")
         write_idx = jnp.minimum(pos + 1, T - 1)
         in_prefill = (pos + 1) < state["prompt_len"]
@@ -644,7 +697,7 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
             n_gen=state["n_gen"] + do_write.astype("int32"))
         if draft_step_fn is not None:
             _, out["draft_cache"] = draft_step_fn(
-                state["draft_cache"], tok_in, pos)
+                state["draft_cache"], tok_in, ts)
         return out
 
     def chunk(state):
@@ -694,9 +747,10 @@ def kv_leaf_seq_axis(shape, n_slots: int, seq_len: int):
     ``n_slots``, or no axis of size ``seq_len`` past it).
 
     Convention: the LAST axis of size ``seq_len`` that is not the final
-    axis, else the final axis — the transformer cache is ``[S, H, T,
-    Dh]`` (T at -2, robust to an ``H == T`` or ``Dh == T`` coincidence)
-    and simple per-position buffers are ``[S, T]`` (T final).  Both the
+    axis, else the final axis — the transformer cache is ``[S, T, H *
+    Dh]`` (fp32) or ``[S, H, T, Dh]`` (int8; T at -2, robust to an
+    ``H == T`` or a width ``== T`` coincidence) and simple per-position
+    buffers are ``[S, T]`` (T final).  Both the
     host extract/pad side and the traced install side resolve the axis
     through this one function so they can never disagree.
     """

@@ -66,6 +66,7 @@ import numpy as np
 from paddle_tpu import compile_cache
 from paddle_tpu import faults as _faults
 from paddle_tpu import monitor
+from paddle_tpu.decode_attention import kv_read_block
 from paddle_tpu.monitor import events as _events
 from paddle_tpu.monitor import spans as _mon_spans
 from paddle_tpu.serving.admission import PRIORITY_NORMAL
@@ -102,6 +103,18 @@ DECODE_TICKS = monitor.counter(
     "serving_decode_ticks_total",
     "decode scheduler ticks (one multi-step chunk dispatch each)",
     _LABELS)
+DECODE_KV_READ = monitor.counter(
+    "serving_decode_kv_positions_read_total",
+    "KV-cache positions the decode steps read: per step and active "
+    "slot its live positions rounded up to the step's read block "
+    "(decode_attention.kv_read_block); the whole rung for an int8 pool "
+    "and for a speculative round, whose reads are masked, not ragged",
+    _LABELS)
+DECODE_KV_POOL = monitor.counter(
+    "serving_decode_kv_positions_pool_total",
+    "KV-cache positions the pool held while those steps ran (slots x "
+    "length rung x steps) — read / pool is the share of the pool a step "
+    "touched", _LABELS)
 DECODE_TTFT = monitor.histogram(
     "serving_decode_ttft_seconds",
     "submit-to-first-token latency per decode request", _LABELS)
@@ -195,12 +208,13 @@ class DecodeRequest(ServingRequest):
 class _Slot:
     """Host-side record of one occupied pool slot."""
 
-    __slots__ = ("req", "prompt_len", "seen", "spec")
+    __slots__ = ("req", "prompt_len", "seen", "spec", "pos")
 
-    def __init__(self, req: DecodeRequest):
+    def __init__(self, req: DecodeRequest, pos: int = 0):
         self.req = req
         self.prompt_len = len(req.prompt)
         self.seen = 0  # generated tokens already streamed to the client
+        self.pos = pos  # positions consumed as of the last tick's view
         self.spec = bool(getattr(req, "speculative", False))
 
 
@@ -263,6 +277,8 @@ class DecodeServer:
         self._tokens_c = DECODE_TOKENS.labels(**lbl)
         self._prefill_c = DECODE_PREFILL_TOKENS.labels(**lbl)
         self._ticks_c = DECODE_TICKS.labels(**lbl)
+        self._kv_read_c = DECODE_KV_READ.labels(**lbl)
+        self._kv_pool_c = DECODE_KV_POOL.labels(**lbl)
         self._ttft_h = DECODE_TTFT.labels(**lbl)
         self._occupancy_g = DECODE_OCCUPANCY.labels(**lbl)
         self._kv_bytes_g = DECODE_KV_BYTES.labels(**lbl)
@@ -367,6 +383,8 @@ class DecodeServer:
             "generated_tokens": int(self._tokens_c.value),
             "prefill_tokens": int(self._prefill_c.value),
             "ticks": int(self._ticks_c.value),
+            "kv_positions_read": int(self._kv_read_c.value),
+            "kv_positions_pool": int(self._kv_pool_c.value),
             "slot_occupancy": float(self._occupancy_g.value),
             "steps_per_tick": self._pool.steps,
             "slot_ladder": list(self._pool.slot_policy.ladder),
@@ -690,7 +708,7 @@ class DecodeServer:
                 self._slots = []
                 self._kv_bytes_g.set(0.0)
                 return
-            self._slots[slot] = _Slot(req)
+            self._slots[slot] = _Slot(req, pre_len)
             # the shared-prefix win, measured where it happens: only the
             # unmatched suffix re-enters prefill
             self._prefill_c.inc(len(req.prompt) - pre_len)
@@ -736,6 +754,7 @@ class DecodeServer:
         # arrays (tokens/pos/flags — KBs, not the KV cache)
         view = {k: np.asarray(state[k])
                 for k in ("tokens", "pos", "active", "finished", "n_gen")}
+        self._count_kv_positions(recs, view, use_spec)
         now = time.perf_counter()
         released: List[int] = []
         for i, rec in recs:
@@ -803,6 +822,32 @@ class DecodeServer:
                     server=self.name, active=len(recs),
                     steps=self._pool.steps)
 
+    def _count_kv_positions(self, recs, view, use_spec: bool) -> None:
+        """Advance the KV read / pool position counters for the chunk
+        just run, from the ``pos`` the tick already fetched: a slot that
+        went from ``p0`` to ``p1`` ran steps at ``ts = p0..p1 - 1``, each
+        reading ``ts + 1`` positions rounded up to the read block."""
+        s, t = view["tokens"].shape
+        idx = np.fromiter((i for i, _ in recs), np.intp, len(recs))
+        p1 = view["pos"][idx].astype(np.int64)
+        steps = 1 if use_spec else self._pool.steps
+        pool = s * t * steps
+        if use_spec or self._pool.kv_dtype != "fp32":
+            read = pool  # masked reads over the whole rung
+        else:
+            blk = kv_read_block(t)
+            p0 = np.fromiter((r.pos for _, r in recs), np.int64, len(recs))
+
+            def blocks_below(p):  # sum of (ts // blk + 1) over ts < p
+                full, rem = np.divmod(p, blk)
+                return blk * full * (full + 1) // 2 + rem * (full + 1)
+
+            read = int((blocks_below(p1) - blocks_below(p0)).sum()) * blk
+        for (_, rec), p in zip(recs, p1.tolist()):
+            rec.pos = p
+        self._kv_pool_c.inc(pool)
+        self._kv_read_c.inc(read)
+
     def _offer_prefix(self, slot: int, rec: _Slot, consumed: int) -> None:
         """Retain a freed slot's prefix KV in the cache (a control-plane
         d2h per NEW entry, on the scheduler thread after the tick's
@@ -847,6 +892,7 @@ class DecodeServer:
         self._batcher.close()
         lbl = {"server": self.name, "instance": self._metrics.instance}
         for metric in (DECODE_TOKENS, DECODE_PREFILL_TOKENS, DECODE_TICKS,
+                       DECODE_KV_READ, DECODE_KV_POOL,
                        DECODE_TTFT, DECODE_OCCUPANCY, DECODE_KV_BYTES):
             metric.remove_labels(**lbl)
         if self._speculative is not None:

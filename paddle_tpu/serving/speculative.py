@@ -157,7 +157,8 @@ def make_spec_chunk_fn(verify_fn, draft_step_fn, eos_id: int, k: int):
             qj = pos + j
             consumed.append(tok)
             dlogits, dcache = draft_step_fn(
-                dcache, tok, jnp.minimum(qj, T - 1))
+                dcache, tok,
+                jnp.where(active, jnp.minimum(qj, T - 1), -1))
             if j < K - 1:
                 prop = jnp.argmax(dlogits, axis=-1).astype("int32")
                 nxt_q = qj + 1
@@ -167,7 +168,8 @@ def make_spec_chunk_fn(verify_fn, draft_step_fn, eos_id: int, k: int):
         ctoks = jnp.stack(consumed, axis=1)  # [S, K]
         # --- verify: ONE K-wide target forward (prefill-shaped);
         # g[:, j] is the target's verified token for position q_j + 1
-        logits, cache = verify_fn(state["cache"], ctoks, pos)
+        logits, cache = verify_fn(state["cache"], ctoks,
+                                  jnp.where(active, pos, -1))
         g = jnp.argmax(logits, axis=-1).astype("int32")  # [S, K]
         # --- greedy-exact acceptance chain + commit
         new_tokens = tokens

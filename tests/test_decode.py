@@ -505,6 +505,55 @@ def test_decode_metrics_series(chain_server):
         assert name in snap, name
 
 
+def test_kv_position_counters_follow_live_blocks():
+    """``serving_decode_kv_positions_{read,pool}_total``: per tick the
+    pool counter advances by slots x T x steps, the read counter by each
+    active slot's live positions rounded up to the read block — so over
+    requests of known lengths their ratio is the block-rounded live share
+    of the pool.  A request of total length L runs the steps
+    ``ts = 0..L - 2``; a step at ``ts`` reads ``ts + 1`` positions."""
+    from paddle_tpu.decode_attention import KV_BLOCK, kv_read_block
+
+    step_fn, make_cache = chain_model()
+    S, T, steps = 4, 2 * KV_BLOCK, 4
+    srv = DecodeServer(step_fn, make_cache, eos_id=V, max_seq_len=T,
+                       max_slots=S, slot_ladder=[S], len_ladder=[T],
+                       steps_per_tick=steps, name="kvcount")
+    srv.warmup(configure_cache=False)
+    try:
+        assert kv_read_block(T) == KV_BLOCK
+        lengths = [(3, KV_BLOCK - 1), (5, KV_BLOCK + 7), (2, 40)]
+        reqs = [srv.submit({"tokens": np.arange(p, dtype=np.int32)},
+                           max_new_tokens=total - p)
+                for p, total in lengths]
+        ticks, pool, seen = 0, 0, []
+        while not all(r.done() for r in reqs):
+            d = srv.metrics()["decode"]
+            if d["ticks"] != ticks:
+                # both counters advance once a tick, by a whole pool
+                assert d["kv_positions_pool"] > pool
+                assert d["kv_positions_read"] > 0
+                ticks, pool = d["ticks"], d["kv_positions_pool"]
+                seen.append(d["kv_positions_read"])
+            time.sleep(0.001)
+        for r in reqs:
+            r.result(timeout=30.0)
+        assert seen == sorted(seen) and len(set(seen)) > 1
+        d = srv.metrics()["decode"]
+        want = sum(-(-(ts + 1) // KV_BLOCK) * KV_BLOCK
+                   for _, total in lengths for ts in range(total - 1))
+        assert d["kv_positions_read"] == want
+        assert d["kv_positions_pool"] == d["ticks"] * S * T * steps
+        snap = monitor.snapshot()
+        assert "serving_decode_kv_positions_read_total" in snap
+        assert "serving_decode_kv_positions_pool_total" in snap
+        share = d["kv_positions_read"] / d["kv_positions_pool"]
+        assert share == pytest.approx(want / (d["ticks"] * S * T * steps))
+        assert 0.0 < share < 0.5
+    finally:
+        srv.stop(drain=False)
+
+
 # ---------------------------------------------------------------------------
 # numeric parity: slot pool vs the scalar cached step fn
 # ---------------------------------------------------------------------------

@@ -1,0 +1,298 @@
+"""paddle_tpu.decode_attention: the pooled decode step's attention.
+
+The Pallas TPU kernel runs here under interpret mode at toy widths
+against the masked-einsum formulation (the CPU path of the pooled step),
+and compiles for a described v5e chip at the benchmark's widths.
+
+Tolerance: both sides compute every product and sum in fp32.  They
+differ only in the ORDER of the sums (online softmax over blocks of 8
+positions against one softmax over T; per-head sums as an indicator
+matmul whose fp32 operand is split into three bf16 terms carrying 24
+mantissa bits) — a few fp32 ulps (6e-8) over sums of at most 32 terms of
+O(1): 2e-6 absolute is ten times the largest difference seen.
+"""
+import numpy as np
+import pytest
+
+from paddle_tpu import decode_attention as da
+from paddle_tpu import decoding
+
+S, T, H, DH, BLOCK = 6, 32, 2, 8, 8
+D = H * DH
+SCALE = 1.0 / np.sqrt(DH)
+ATOL = 2e-6
+
+
+def _inputs(seed, ts):
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    q, kn, vn = (jnp.asarray(rng.randn(len(ts), D), jnp.float32)
+                 for _ in range(3))
+    kc = jnp.asarray(rng.randn(len(ts), T, D), jnp.float32)
+    vc = jnp.asarray(rng.randn(len(ts), T, D), jnp.float32)
+    return q, kn, vn, kc, vc, jnp.asarray(ts, jnp.int32)
+
+
+def _kernel(q, kn, vn, kc, vc, ts, block=BLOCK):
+    return da.ragged_decode_attention(
+        q, kn, vn, kc, vc, ts, da.decode_work_items(ts, T, block),
+        n_head=H, scale=SCALE, block=block, interpret=True)
+
+
+def _masked(q, kn, vn, kc, vc, ts):
+    return da.masked_decode_attention(q, kn, vn, kc, vc, ts, n_head=H,
+                                      scale=SCALE)
+
+
+def _float64_reference(q, kn, vn, kc, vc, ts):
+    q, kn, vn, kc, vc = (np.asarray(a, np.float64)
+                         for a in (q, kn, vn, kc, vc))
+    out = np.zeros((len(ts), D))
+    for n, t in enumerate(np.asarray(ts)):
+        if t < 0:
+            continue
+        k = np.concatenate([kc[n, :t], kn[n:n + 1]]).reshape(t + 1, H, DH)
+        v = np.concatenate([vc[n, :t], vn[n:n + 1]]).reshape(t + 1, H, DH)
+        s = np.einsum("hd,thd->ht", q[n].reshape(H, DH), k) * SCALE
+        w = np.exp(s - s.max(-1, keepdims=True))
+        w /= w.sum(-1, keepdims=True)
+        out[n] = np.einsum("ht,thd->hd", w, v).reshape(D)
+    return out
+
+
+# ts = 0, block - 1, block, block + 1, T - 1 and an idle slot, in two
+# orders (the work list is slot-major, so order moves the block seams)
+RAGGED = [[0, BLOCK - 1, BLOCK, BLOCK + 1, T - 1, -1],
+          [-1, T - 1, 0, -1, BLOCK, 2 * BLOCK - 1],
+          [T - 1] * 6, [0] * 6, [-1] * 6]
+
+
+@pytest.mark.parametrize("ts", RAGGED, ids=lambda ts: "-".join(map(str, ts)))
+def test_kernel_matches_masked_einsum(ts):
+    args = _inputs(0, ts)
+    o, k2, v2 = _kernel(*args)
+    ro, rk, rv = _masked(*args)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ro), rtol=0,
+                               atol=ATOL)
+    # the append is a copy: bit-identical whichever side made it
+    assert np.array_equal(np.asarray(k2), np.asarray(rk))
+    assert np.array_equal(np.asarray(v2), np.asarray(rv))
+
+
+@pytest.mark.parametrize("impl", [_kernel, _masked],
+                         ids=["kernel", "masked"])
+def test_fp32_accumulation_against_float64(impl):
+    args = _inputs(1, RAGGED[0])
+    o, _, _ = impl(*args)
+    np.testing.assert_allclose(np.asarray(o), _float64_reference(*args),
+                               rtol=0, atol=ATOL)
+
+
+@pytest.mark.parametrize("impl", [_kernel, _masked],
+                         ids=["kernel", "masked"])
+def test_idle_slots_read_nothing_and_write_nothing(impl):
+    """An idle slot's rows are NaN: read, they would poison its output;
+    written, they would no longer be NaN.  Its context row is zero."""
+    import jax.numpy as jnp
+
+    ts = [3, -1, BLOCK, -1, T - 1, -1]
+    q, kn, vn, kc, vc, tsa = _inputs(2, ts)
+    idle = np.asarray(ts) < 0
+    kc = kc.at[idle].set(jnp.nan)
+    vc = vc.at[idle].set(jnp.nan)
+    o, k2, v2 = impl(q, kn, vn, kc, vc, tsa)
+    o = np.asarray(o)
+    assert np.all(o[idle] == 0.0)
+    assert np.all(np.isfinite(o))
+    assert np.all(np.isnan(np.asarray(k2)[idle]))
+    assert np.all(np.isnan(np.asarray(v2)[idle]))
+
+
+@pytest.mark.parametrize("impl", [_kernel, _masked],
+                         ids=["kernel", "masked"])
+def test_garbage_beyond_ts_does_not_change_the_output(impl):
+    """Positions past ``ts`` hold a previous occupant's rows: large and
+    finite, they must weigh exactly nothing (bit-equal outputs)."""
+    import jax.numpy as jnp
+
+    ts = RAGGED[0]
+    q, kn, vn, kc, vc, tsa = _inputs(3, ts)
+    beyond = (np.arange(T)[None, :] > np.asarray(ts)[:, None])[:, :, None]
+    clean, _, _ = impl(q, kn, vn, jnp.where(beyond, 0.0, kc),
+                       jnp.where(beyond, 0.0, vc), tsa)
+    dirty, _, _ = impl(q, kn, vn, jnp.where(beyond, 1e30, kc),
+                       jnp.where(beyond, -1e30, vc), tsa)
+    assert np.array_equal(np.asarray(clean), np.asarray(dirty))
+
+
+@pytest.mark.parametrize("impl", [_kernel, _masked],
+                         ids=["kernel", "masked"])
+def test_one_step_writes_one_row_per_active_slot(impl):
+    ts = RAGGED[0]
+    q, kn, vn, kc, vc, tsa = _inputs(4, ts)
+    _, k2, v2 = impl(q, kn, vn, kc, vc, tsa)
+    for new, old, row in ((k2, kc, kn), (v2, vc, vn)):
+        new, old, row = (np.asarray(a) for a in (new, old, row))
+        want = old.copy()
+        for n, t in enumerate(ts):
+            if t >= 0:
+                want[n, t] = row[n]
+        assert np.array_equal(new, want)
+
+
+def test_consecutive_steps_through_the_kernel():
+    """Three steps in a row, each appending where the last left off:
+    the caches stay bit-equal to the masked path's, the contexts
+    within tolerance (the second step reads what the first wrote)."""
+    import jax.numpy as jnp
+
+    ts = np.asarray([0, BLOCK - 2, BLOCK - 1, -1, T - 4, 5])
+    _, _, _, kc, vc, _ = _inputs(5, ts)
+    rk, rv = kc, vc
+    for step in range(3):
+        q, kn, vn, _, _, _ = _inputs(10 + step, ts)
+        tsa = jnp.asarray(np.where(ts >= 0, ts + step, -1), jnp.int32)
+        o, kc, vc = _kernel(q, kn, vn, kc, vc, tsa)
+        ro, rk, rv = _masked(q, kn, vn, rk, rv, tsa)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(ro), rtol=0,
+                                   atol=ATOL)
+        assert np.array_equal(np.asarray(kc), np.asarray(rk))
+        assert np.array_equal(np.asarray(vc), np.asarray(rv))
+
+
+def test_one_block_spanning_the_rung():
+    """A rung the block does not divide is read as one block."""
+    assert da.kv_read_block(512) == da.KV_BLOCK
+    assert da.kv_read_block(T) == T
+    args = _inputs(6, RAGGED[0])
+    o, _, _ = _kernel(*args, block=T)
+    ro, _, _ = _masked(*args)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ro), rtol=0,
+                               atol=ATOL)
+
+
+def test_work_items_are_the_live_blocks_slot_major():
+    import jax.numpy as jnp
+
+    ts = jnp.asarray([0, BLOCK - 1, BLOCK, -1, T - 1, -1], jnp.int32)
+    n_items, slot, blk = (np.asarray(a)
+                          for a in da.decode_work_items(ts, T, BLOCK))
+    n = int(n_items[0])
+    assert slot.shape == blk.shape == (len(ts) * T // BLOCK,)
+    assert list(zip(slot[:n], blk[:n])) == [
+        (0, 0), (1, 0), (2, 0), (2, 1), (4, 0), (4, 1), (4, 2), (4, 3)]
+
+
+_LM = dict(vocab=19, d_model=D, n_layer=2, n_head=H, d_inner=24, max_pos=T)
+
+
+def test_pooled_step_leaves_an_idle_row_alone():
+    """The pooled step under the idle contract (``ts < 0``): the idle
+    row's cache is bit-identical after the step, an active row's logits
+    do not depend on who sits idle beside it."""
+    import jax.numpy as jnp
+
+    state = decoding.random_transformer_lm_state(
+        np.random.RandomState(8), **_LM)
+    step, make_cache = decoding.make_transformer_lm_pooled_step_fn(
+        state, _LM["vocab"], D, _LM["n_layer"], H, _LM["d_inner"])
+    cache = [{k: v + 0.5 for k, v in layer.items()}
+             for layer in make_cache(3, T)]
+    assert cache[0]["k"].shape == (3, T, D)
+    toks = jnp.asarray([3, 4, 5], jnp.int32)
+    both, c2 = step(cache, toks, jnp.asarray([2, -1, 7], jnp.int32))
+    for old, new in zip(cache, c2):
+        for leaf in ("k", "v"):
+            o, n = np.asarray(old[leaf]), np.asarray(new[leaf])
+            assert np.array_equal(o[1], n[1])
+            changed = np.argwhere((o != n).any(axis=-1))
+            assert changed.tolist() == [[0, 2], [2, 7]]
+    alone, _ = step(cache, toks, jnp.asarray([2, 9, 7], jnp.int32))
+    np.testing.assert_allclose(np.asarray(both)[[0, 2]],
+                               np.asarray(alone)[[0, 2]], rtol=0,
+                               atol=1e-6)
+
+
+def test_chunk_hands_idle_slots_to_the_step_as_minus_one():
+    """``make_slot_decode_fns``: the step (and the draft step) see
+    ``ts = pos`` for active slots and ``-1`` for the others, whatever
+    stale ``pos`` those carry."""
+    import jax
+    import jax.numpy as jnp
+
+    def spy(cache, tokens, ts):
+        return jax.nn.one_hot(tokens, 5), {"ts": ts}
+
+    chunk, _, _ = decoding.make_slot_decode_fns(spy, eos_id=99, steps=1,
+                                                draft_step_fn=spy)
+    n = 4
+    state = {
+        "cache": {"ts": jnp.zeros((n,), jnp.int32)},
+        "draft_cache": {"ts": jnp.zeros((n,), jnp.int32)},
+        "tokens": jnp.ones((n, 16), jnp.int32),
+        "pos": jnp.asarray([3, 5, 0, 9], jnp.int32),
+        "prompt_len": jnp.full((n,), 2, jnp.int32),
+        "total_len": jnp.full((n,), 16, jnp.int32),
+        "active": jnp.asarray([True, False, True, False]),
+        "finished": jnp.asarray([False, True, False, False]),
+        "n_gen": jnp.zeros((n,), jnp.int32),
+    }
+    out = chunk(state)
+    assert np.asarray(out["cache"]["ts"]).tolist() == [3, -1, 0, -1]
+    assert np.asarray(out["draft_cache"]["ts"]).tolist() == [3, -1, 0, -1]
+    assert np.asarray(out["pos"]).tolist() == [4, 5, 1, 9]
+
+
+# ---------------------------------------------------------------------------
+# the kernel at the benchmark's widths, compiled for a described v5e chip
+# (no chip attached: nothing runs, the chip's compiler accepts or refuses)
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no compiler
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # an executable for a described chip cannot be read back from the
+    # persistent cache without the chip: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def test_kernel_compiles_for_v5e_at_gpt1_widths(one_chip):
+    """320 slots x 512 positions x 768 (12 heads), blocks of 128: the
+    kernel lowers, and both cache leaves are aliased in place (no
+    temporary of a leaf's size)."""
+    import jax
+    import jax.numpy as jnp
+
+    s, t, d, h = 320, 512, 768, 12
+    block = da.kv_read_block(t)
+    assert da.kernel_supported(t, d, h)
+
+    def f(q, kn, vn, kc, vc, ts):
+        return da.ragged_decode_attention(
+            q, kn, vn, kc, vc, ts, da.decode_work_items(ts, t, block),
+            n_head=h, scale=0.125, block=block)
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(f, donate_argnums=(3, 4)).lower(
+        sd((s, d)), sd((s, d)), sd((s, d)), sd((s, t, d)), sd((s, t, d)),
+        sd((s,), jnp.int32)).compile()
+    assert "ragged_decode_attention" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    leaf = s * t * d * 4
+    assert mem.alias_size_in_bytes >= 2 * leaf
+    assert mem.temp_size_in_bytes < leaf // 8

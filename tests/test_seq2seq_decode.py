@@ -182,10 +182,12 @@ def test_pooled_step_fn_matches_scalar_step_fn():
         lp, pc = p_fn(pc, toks, jnp.full((N,), t, "int32"))
         np.testing.assert_allclose(np.asarray(ls), np.asarray(lp),
                                    rtol=1e-5, atol=1e-5)
+    # the scalar cache is [N, H, T, Dh]; the pooled fp32 cache folds the
+    # heads into the lane axis: [N, T, H * Dh]
     for i in range(_LM["n_layer"]):
-        np.testing.assert_allclose(np.asarray(sc[i]["k"]),
-                                   np.asarray(pc[i]["k"]),
-                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(
+            np.asarray(sc[i]["k"]).transpose(0, 2, 1, 3).reshape(N, ML, -1),
+            np.asarray(pc[i]["k"]), rtol=1e-5, atol=1e-5)
 
 
 def test_pooled_step_fn_rows_at_different_positions():
