@@ -29,6 +29,15 @@ Two implementations of that one contract, chosen by
   (scatter append + masked softmax over the whole T axis): the CPU path
   and the parity reference of tests/test_decode_attention.py.
 
+* :func:`grouped_masked_decode_attention` — the same contract for
+  grouped-query heads (``n_head`` query heads over ``n_kv_head`` K/V
+  heads, query head ``j`` reading K/V head ``j // (n_head // n_kv_head)``)
+  and any storage dtype of the leaves (``[slots, T, n_kv_head * d_head]``,
+  bf16 for the hybrid SSM block): XLA ops on every backend — products in
+  the leaves' dtype, fp32 accumulation and softmax, the read masked over
+  the whole T axis.  A grouped-head bf16 kernel is open work (ROADMAP
+  Queue 2 A).
+
 ``jax.experimental.pallas`` is imported inside the kernel builder only:
 ``import paddle_tpu`` and the training cells never pay for it.
 """
@@ -40,6 +49,7 @@ import numpy as np
 
 __all__ = ["KV_BLOCK", "kv_read_block", "decode_work_items",
            "ragged_decode_attention", "masked_decode_attention",
+           "grouped_masked_decode_attention",
            "kernel_supported", "make_decode_attention"]
 
 #: positions per K/V block the kernel moves in one DMA (and the rounding
@@ -281,6 +291,41 @@ def masked_decode_attention(q, k_new, v_new, k_cache, v_cache, ts,
     w = jax.nn.softmax(jnp.where(pos_ok, scores, -1e9), axis=-1)
     ctx = jnp.einsum("nht,nthd->nhd", w,
                      v_cache.reshape(S, T, n_head, d_head)).reshape(S, D)
+    return jnp.where((ts >= 0)[:, None], ctx, 0.0), k_cache, v_cache
+
+
+def grouped_masked_decode_attention(q, k_new, v_new, k_cache, v_cache, ts,
+                                    *, n_head: int, n_kv_head: int,
+                                    scale: float):
+    """The contract for grouped-query heads as plain XLA ops.
+
+    ``q`` [S, n_head * Dh] and ``k_new``, ``v_new`` [S, n_kv_head * Dh]
+    fp32; ``k_cache``, ``v_cache`` [S, T, n_kv_head * Dh] in their
+    storage dtype (the new rows are rounded to it as they are appended;
+    idle rows ``ts < 0`` dropped).  Scores and the context are products
+    in the storage dtype accumulated in fp32; the softmax is fp32.
+    Returns ``(ctx [S, n_head * Dh] fp32, k_cache, v_cache)``."""
+    import jax
+    import jax.numpy as jnp
+
+    S, T, Dkv = k_cache.shape
+    d_head = Dkv // n_kv_head
+    rep = n_head // n_kv_head
+    dt = k_cache.dtype
+    rows = jnp.arange(S)
+    at = jnp.where(ts >= 0, ts, T)          # idle -> out of range, dropped
+    k_cache = k_cache.at[rows, at].set(k_new.astype(dt), mode="drop")
+    v_cache = v_cache.at[rows, at].set(v_new.astype(dt), mode="drop")
+    pos_ok = (jnp.arange(T)[None, :] <= ts[:, None])[:, None, None, :]
+    qg = (q * scale).astype(dt).reshape(S, n_kv_head, rep, d_head)
+    scores = jnp.einsum("sgrd,stgd->sgrt", qg,
+                        k_cache.reshape(S, T, n_kv_head, d_head),
+                        preferred_element_type=jnp.float32)
+    w = jax.nn.softmax(jnp.where(pos_ok, scores, -1e9), axis=-1)
+    ctx = jnp.einsum("sgrt,stgd->sgrd", w.astype(dt),
+                     v_cache.reshape(S, T, n_kv_head, d_head),
+                     preferred_element_type=jnp.float32)
+    ctx = ctx.reshape(S, n_head * d_head)
     return jnp.where((ts >= 0)[:, None], ctx, 0.0), k_cache, v_cache
 
 

@@ -34,24 +34,31 @@ __all__ = [
     "make_transformer_lm_step_fn",
     "make_transformer_lm_pooled_step_fn", "make_slot_decode_fns",
     "make_transformer_lm_pooled_verify_fn", "make_prefix_admit_fn",
-    "kv_leaf_seq_axis", "normalize_kv_dtype",
+    "make_hybrid_ssm_lm_pooled_step_fn",
+    "kv_leaf_seq_axis", "cache_leaf_seq_axes", "recurrent_leaf_names",
+    "normalize_kv_dtype",
     "random_transformer_lm_state",
 ]
 
-#: KV-cache storage dtypes the pooled builders accept.  "int8" stores
+#: KV-cache storage dtypes the pooled builders know.  "int8" stores
 #: K/V rows quantized (per-slot-per-head-per-position absmax scales as
 #: sibling ``k_scale``/``v_scale`` leaves — see paddle_tpu.quant),
 #: quantize-on-write / dequant-at-attend inside the jitted step.
-KV_DTYPES = ("fp32", "int8")
+#: "bf16" stores them rounded to bfloat16 as they are appended; only the
+#: hybrid SSM builder takes it (the transformer-LM builders take
+#: ``_LM_KV_DTYPES``: their fp32 kernel and int8 layout are what exist).
+KV_DTYPES = ("fp32", "int8", "bf16")
+_LM_KV_DTYPES = ("fp32", "int8")
 
 
-def normalize_kv_dtype(kv_dtype) -> str:
+def normalize_kv_dtype(kv_dtype, supported=KV_DTYPES) -> str:
     d = str(kv_dtype or "fp32").lower()
-    d = {"float32": "fp32", "fp32": "fp32", "int8": "int8"}.get(d)
-    if d is None:
+    d = {"float32": "fp32", "fp32": "fp32", "int8": "int8",
+         "bf16": "bf16", "bfloat16": "bf16"}.get(d)
+    if d not in supported:
         raise ValueError(
             "unsupported kv_dtype %r (supported: %s)"
-            % (kv_dtype, list(KV_DTYPES)))
+            % (kv_dtype, list(supported)))
     return d
 
 
@@ -429,7 +436,11 @@ def make_transformer_lm_pooled_step_fn(
     zeroing on slot reuse: a sequence at position ``ts`` has itself
     written every cache position ``<= ts`` (prefill consumes each prompt
     token through the same step), and positions ``> ts`` are never read
-    — stale rows from a previous occupant are never read.
+    — stale rows from a previous occupant are never read.  The invariant
+    covers leaves WITH a sequence axis, which is every leaf of this
+    builder; recurrent leaves (no sequence axis, read and re-written
+    whole each step) are outside it — see
+    :func:`make_hybrid_ssm_lm_pooled_step_fn`.
 
     ``kv_dtype="int8"`` stores the cache int8 in ``[N, H, T, Dh]``
     leaves (per-slot-per-head scales as sibling ``k_scale``/``v_scale``
@@ -437,14 +448,15 @@ def make_transformer_lm_pooled_step_fn(
     ``_lm_forward_one``), roughly quartering per-slot KV bytes so a
     fixed HBM budget holds ~2x+ the concurrent sequences; its step
     still moves the whole pool.  Every leaf keeps the slot axis
-    leading and a sequence axis ``kv_leaf_seq_axis`` finds by shape, so
-    the slot pool's ``resize``/``extract_kv``/``admit_prefix`` carry
-    both layouts unchanged — prefix caching and speculative decode
+    leading and a sequence axis; this builder declares none, so the slot
+    pool finds it by shape (``kv_leaf_seq_axis``, through
+    :func:`cache_leaf_seq_axes`) and ``extract_kv``/``admit_prefix``
+    carry both layouts unchanged — prefix caching and speculative decode
     compose unchanged.
     """
     import jax.numpy as jnp
 
-    kv_dtype = normalize_kv_dtype(kv_dtype)
+    kv_dtype = normalize_kv_dtype(kv_dtype, _LM_KV_DTYPES)
     kv_int8 = kv_dtype == "int8"
     d_head = d_model // n_head
     W = {k: jnp.asarray(v) for k, v in state.items()}
@@ -479,6 +491,113 @@ def make_transformer_lm_pooled_step_fn(
         return _lm_forward_one(W, name, cache, x, None, ts, n_layer,
                                n_head, d_head, d_model, scale,
                                kv_int8=kv_int8)
+
+    return step_fn, make_cache
+
+
+def make_hybrid_ssm_lm_pooled_step_fn(state, cfg, name: str = "lm",
+                                      kv_dtype: str = "bf16",
+                                      ssm_state_dtype: str = "float32"):
+    """The slot-pooled step of a hybrid SSM + attention decoder
+    (``model_type: falcon_h1``: in every block a Mamba-2 mixer beside
+    grouped-query attention on the same normed input, then SwiGLU; the
+    parts and the equations are ``paddle_tpu.hybrid_ssm``).
+
+    Same contract as :func:`make_transformer_lm_pooled_step_fn`:
+    ``step_fn(cache, tokens [N] int32, ts [N] int32) -> (logits [N, V]
+    fp32, cache)`` with ``ts[i] < 0`` an idle row, and ``make_cache(
+    n_rows, seq_len)``.  ``state``: weights under
+    ``hybrid_ssm.param_shapes(cfg)``, used in the dtype they are given
+    (bf16 as stored: no per-step conversion); ``cfg``: the published
+    config keys (``hybrid_ssm.dims``).
+
+    One layer's cache is two kinds of leaf, and ``make_cache`` DECLARES
+    which is which (``make_cache.leaf_seq_axes``: a pytree shaped like
+    the cache holding each leaf's sequence axis, ``-1`` for none), so
+    the slot pool never guesses from a shape — a length rung equal to
+    ``d_state`` or ``head_dim`` is an ordinary rung:
+
+    * ``k``, ``v`` ``[N, T, n_kv_head * head_dim]`` in ``kv_dtype``
+      (``k`` after rotary): appended in place at ``ts``, read
+      ``0..ts`` (``decode_attention.grouped_masked_decode_attention``).
+      The write-before-read invariant covers these: a reused slot's
+      stale positions are never read.
+    * ``ssm`` ``[N, heads, d_head, d_state]`` in ``ssm_state_dtype`` and
+      ``conv`` ``[N, d_conv - 1, d_xbc]`` fp32: RECURRENT state, read
+      and re-written whole every step, NOT covered by that invariant.
+      The step reads zeros for a row at ``ts == 0``
+      (``hybrid_ssm.starts_fresh``): admit, release + re-admit and a
+      deadline abort all restart a slot at position 0, so none of them
+      can forget the reset, and it costs a select on a load the update
+      makes anyway.  An idle row's state is kept as it was.
+
+    Position 0 is where a sequence starts, so nothing that seats a slot
+    at ``pos > 0`` (a copied prefix) or rolls ``pos`` back (a rejected
+    speculative round) is valid over these leaves; ``KVSlotPool`` refuses
+    both at construction.
+    """
+    import jax.numpy as jnp
+
+    from paddle_tpu import hybrid_ssm as hs
+    from paddle_tpu.decode_attention import grouped_masked_decode_attention
+
+    d = hs.dims(cfg)
+    kv = {"fp32": jnp.float32, "bf16": jnp.bfloat16}[
+        normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
+    ssm_dt = jnp.dtype(ssm_state_dtype)
+    W = {k: jnp.asarray(v) for k, v in state.items()}
+    scale = 1.0 / float(np.sqrt(d.head_dim))
+
+    def make_cache(n_rows: int, seq_len: int):
+        return [
+            {
+                "k": jnp.zeros((n_rows, seq_len, d.d_kv), kv),
+                "v": jnp.zeros((n_rows, seq_len, d.d_kv), kv),
+                "ssm": jnp.zeros((n_rows, d.ssm_heads, d.ssm_head_dim,
+                                  d.d_state), ssm_dt),
+                "conv": jnp.zeros((n_rows, d.d_conv - 1, d.d_xbc),
+                                  jnp.float32),
+            }
+            for _ in range(d.n_layer)
+        ]
+
+    make_cache.leaf_seq_axes = [
+        {"k": 1, "v": 1, "ssm": -1, "conv": -1} for _ in range(d.n_layer)]
+
+    def step_fn(cache, tokens, ts):
+        n = tokens.shape[0]
+        T = cache[0]["k"].shape[1]
+        ts = jnp.minimum(ts, T - 1)     # idle rows stay < 0
+        pos = jnp.maximum(ts, 0)
+        h = W[name + "_emb"][tokens].astype(jnp.float32) \
+            * d.embedding_multiplier
+        new_cache = []
+        for i in range(d.n_layer):
+            p = "%s_l%d_" % (name, i)
+            c = cache[i]
+            u = hs.rms_norm(h, W[p + "norm1"], d.eps)
+            mix, ssm, conv = hs.mamba2_step(
+                d.ssm_in_multiplier * u, W, p, c["ssm"], c["conv"], ts, d)
+            xa = d.attention_in_multiplier * u
+            q = hs.linear(xa, W[p + "attn_q"]).reshape(
+                n, d.n_head, d.head_dim)
+            k = (d.key_multiplier * hs.linear(xa, W[p + "attn_k"])).reshape(
+                n, d.n_kv_head, d.head_dim)
+            ctx, kc, vc = grouped_masked_decode_attention(
+                hs.rotary(q, pos, d.rope_theta).reshape(n, -1),
+                hs.rotary(k, pos, d.rope_theta).reshape(n, -1),
+                hs.linear(xa, W[p + "attn_v"]), c["k"], c["v"], ts,
+                n_head=d.n_head, n_kv_head=d.n_kv_head, scale=scale)
+            new_cache.append({"k": kc, "v": vc, "ssm": ssm, "conv": conv})
+            h = (h + d.ssm_out_multiplier * mix
+                 + d.attention_out_multiplier * hs.linear(
+                     ctx, W[p + "attn_o"]))
+            h = h + hs.swiglu(
+                hs.rms_norm(h, W[p + "norm2"], d.eps), W[p + "mlp_gate"],
+                W[p + "mlp_up"], W[p + "mlp_down"], *d.mlp_multipliers)
+        logits = hs.linear(hs.rms_norm(h, W[name + "_final_norm"], d.eps),
+                           W[name + "_head"]) * d.lm_head_multiplier
+        return logits, new_cache
 
     return step_fn, make_cache
 
@@ -528,7 +647,7 @@ def make_transformer_lm_pooled_verify_fn(
     import jax
     import jax.numpy as jnp
 
-    kv_dtype = normalize_kv_dtype(kv_dtype)
+    kv_dtype = normalize_kv_dtype(kv_dtype, _LM_KV_DTYPES)
     kv_int8 = kv_dtype == "int8"
     d_head = d_model // n_head
     W = {k: jnp.asarray(v) for k, v in state.items()}
@@ -634,7 +753,13 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
 
     The pool state is a dict pytree (every leaf's axis 0 is the slot):
 
-    * ``cache``    — the step fn's KV pytree (T axis read by the step)
+    * ``cache``    — the step fn's cache pytree (T axis read by the
+      step): leaves with a sequence axis (K/V), which the
+      write-before-read invariant covers, and possibly recurrent leaves
+      with none, which the STEP starts from zero for a row at position
+      0 (``make_hybrid_ssm_lm_pooled_step_fn``) — ``admit`` seats every
+      request at ``pos = 0``, so neither ``admit`` nor ``release``
+      touches the cache
     * ``tokens``   — [S, T] int32, position-indexed token buffer
     * ``pos``      — [S] int32, tokens consumed so far (the step eats
       index ``pos`` and produces the token for ``pos + 1``)
@@ -709,7 +834,9 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
         # (padded host-side), prompt_len/total_len () int32 scalars.
         # The cache passes through UNTOUCHED: the write-before-read
         # invariant (see make_transformer_lm_pooled_step_fn) makes
-        # zeroing a reused slot's rows unnecessary.
+        # zeroing a reused slot's K/V rows unnecessary, and a step with
+        # recurrent leaves reads them as zero at the ``pos = 0`` set
+        # here (make_hybrid_ssm_lm_pooled_step_fn).
         mask = slot_mask
         out = dict(state)
         out.update(
@@ -728,7 +855,8 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
     def release(state, slot_mask):
         # deactivate without finishing: the slot becomes seatable again
         # (its request was aborted host-side); tokens/cache stay — the
-        # write-before-read invariant protects the next occupant
+        # write-before-read invariant protects the next occupant's K/V
+        # rows, and its recurrent state starts from zero at its pos 0
         out = dict(state)
         out.update(
             active=state["active"] & ~slot_mask,
@@ -741,18 +869,61 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
 # ---------------------------------------------------------------------------
 # Prefix KV installation (serving.prefix_cache's device half)
 # ---------------------------------------------------------------------------
+def cache_leaf_seq_axes(make_cache, leaves, n_slots: int, seq_len: int):
+    """The sequence axis of each of ``leaves`` (the flattened cache
+    ``make_cache(n_slots, seq_len)`` builds, arrays or shape structs), or
+    None for a leaf with none.
+
+    A builder that knows its leaves DECLARES them:
+    ``make_cache.leaf_seq_axes`` is a pytree shaped like the cache whose
+    leaves are ints — the axis, or ``-1`` for a recurrent leaf with no
+    sequence axis — and nothing is inferred from a shape.  A builder
+    that declares nothing gets :func:`kv_leaf_seq_axis`'s guess by
+    shape, leaf by leaf (the transformer-LM caches and the tests'
+    stand-in steps).  ``KVSlotPool.extract_kv`` / ``admit_prefix`` /
+    ``kv_rung_bytes`` and :func:`make_prefix_admit_fn` all resolve the
+    axis through this one function, so the host side and the traced side
+    cannot disagree."""
+    import jax
+
+    declared = getattr(make_cache, "leaf_seq_axes", None)
+    if declared is None:
+        return [kv_leaf_seq_axis(tuple(l.shape), n_slots, seq_len)
+                for l in leaves]
+    axes = jax.tree.leaves(declared)
+    if len(axes) != len(leaves):
+        raise ValueError(
+            "make_cache.leaf_seq_axes declares %d leaves, the cache has %d"
+            % (len(axes), len(leaves)))
+    return [None if int(a) < 0 else int(a) for a in axes]
+
+
+def recurrent_leaf_names(make_cache):
+    """Tree paths of the leaves ``make_cache`` declares recurrent (no
+    sequence axis: ``-1`` in ``make_cache.leaf_seq_axes``); empty for a
+    builder that declares nothing."""
+    import jax
+
+    declared = getattr(make_cache, "leaf_seq_axes", None)
+    if declared is None:
+        return []
+    return [jax.tree_util.keystr(path) for path, a in
+            jax.tree_util.tree_flatten_with_path(declared)[0] if int(a) < 0]
+
+
 def kv_leaf_seq_axis(shape, n_slots: int, seq_len: int):
-    """The sequence axis of a per-slot KV-cache leaf, or None when the
-    leaf carries no per-slot sequence state (no leading slot axis of
-    ``n_slots``, or no axis of size ``seq_len`` past it).
+    """The GUESS by shape of a per-slot KV-cache leaf's sequence axis,
+    for builders that declare nothing (see :func:`cache_leaf_seq_axes`):
+    None when the leaf carries no per-slot sequence state (no leading
+    slot axis of ``n_slots``, or no axis of size ``seq_len`` past it).
 
     Convention: the LAST axis of size ``seq_len`` that is not the final
     axis, else the final axis — the transformer cache is ``[S, T, H *
     Dh]`` (fp32) or ``[S, H, T, Dh]`` (int8; T at -2, robust to an
     ``H == T`` or a width ``== T`` coincidence) and simple per-position
-    buffers are ``[S, T]`` (T final).  Both the
-    host extract/pad side and the traced install side resolve the axis
-    through this one function so they can never disagree.
+    buffers are ``[S, T]`` (T final).  A leaf whose width or head
+    count happens to equal the rung is taken for a sequence leaf: a
+    builder with such leaves declares them instead.
     """
     if len(shape) < 2 or shape[0] != n_slots:
         return None
@@ -764,7 +935,7 @@ def kv_leaf_seq_axis(shape, n_slots: int, seq_len: int):
     return (non_final[-1] if non_final else cands[-1]) + 1
 
 
-def make_prefix_admit_fn(admit_fn):
+def make_prefix_admit_fn(admit_fn, seq_axes_of=None):
     """Wrap a :func:`make_slot_decode_fns` ``admit`` with shared-prefix
     KV installation: ``admit_prefix(state, slot_mask, prompt,
     prompt_len, total_len, kv_leaves, prefix_len[, spec_flag])`` seats
@@ -777,11 +948,14 @@ def make_prefix_admit_fn(admit_fn):
     (``cache`` plus ``draft_cache`` when present, in tree-flatten
     order), each leaf host-padded along its sequence axis to the
     state's length rung; non-qualifying positions carry a ``(1,)``
-    dummy.  Qualification and the sequence axis are decided by STATIC
-    shapes (:func:`kv_leaf_seq_axis`), so one compiled executable per
-    rung pair serves every cached prefix length — ``prefix_len`` stays
-    a dynamic scalar.  Positional embeddings are absolute, so retained
-    rows are position-correct for any matching prompt.
+    dummy.  Qualification and the sequence axis are STATIC
+    (``seq_axes_of(subtrees, S, T)`` over the ``{"cache": ...,
+    "draft_cache": ...}`` dict — the pool passes its builders'
+    declaration, :func:`cache_leaf_seq_axes`; by default the guess by
+    shape), so one compiled executable per rung pair serves every cached
+    prefix length — ``prefix_len`` stays a dynamic scalar.  Positional
+    embeddings are absolute, so retained rows are position-correct for
+    any matching prompt.
     """
     import jax
     import jax.numpy as jnp
@@ -800,9 +974,10 @@ def make_prefix_admit_fn(admit_fn):
         if "draft_cache" in out:
             sub["draft_cache"] = out["draft_cache"]
         leaves, treedef = jax.tree_util.tree_flatten(sub)
+        axes = (seq_axes_of(sub, S, T) if seq_axes_of is not None
+                else [kv_leaf_seq_axis(l.shape, S, T) for l in leaves])
         new_leaves = []
-        for cur, pre in zip(leaves, kv_leaves):
-            ax = kv_leaf_seq_axis(cur.shape, S, T)
+        for cur, pre, ax in zip(leaves, kv_leaves, axes):
             if ax is None or tuple(pre.shape) != tuple(cur.shape[1:]):
                 new_leaves.append(cur)
                 continue

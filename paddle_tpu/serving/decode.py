@@ -34,7 +34,11 @@ Streaming: :meth:`submit` returns a :class:`DecodeRequest` whose
 frames (``serving.wire``).
 
 Observability: ``serving_decode_*`` metrics (generated/prefill token
-counters, tick counter, TTFT histogram, slot-occupancy gauge) on top of
+counters, tick counter, TTFT histogram, slot-occupancy gauge; the pool's
+bytes as two gauges, ``serving_kv_cache_bytes`` for leaves with a
+sequence axis and ``serving_recurrent_state_bytes`` for those without,
+and ``serving_decode_state_resets_total``, one per admission into a pool
+that has recurrent leaves) on top of
 the standard ``ServingMetrics`` series; per-tick ``serving/decode_tick``
 spans carry the active requests' trace ids; the ``decode.step`` fault
 point injects failures into the tick dispatch for chaos coverage.
@@ -128,6 +132,17 @@ DECODE_KV_BYTES = monitor.gauge(
     "(slot, length) rung pair, from the stored dtype (int8 KV halves "
     "this vs fp32 at the same rungs); 0 while the pool is idle",
     _LABELS)
+
+DECODE_STATE_RESETS = monitor.counter(
+    "serving_decode_state_resets_total",
+    "slots whose recurrent state (cache leaves with no sequence axis: "
+    "SSM and conv state) was started from zero — one per admission into "
+    "a pool that has such leaves, none otherwise", _LABELS)
+DECODE_RECURRENT_BYTES = monitor.gauge(
+    "serving_recurrent_state_bytes",
+    "bytes of the pool's recurrent cache leaves (no sequence axis) at "
+    "its CURRENT slot rung; serving_kv_cache_bytes counts the leaves "
+    "that have one; 0 while the pool is idle", _LABELS)
 
 # safety-net bound while parked on the empty-queue condition (real
 # wakeups are offer()/wake() notifies)
@@ -282,6 +297,8 @@ class DecodeServer:
         self._ttft_h = DECODE_TTFT.labels(**lbl)
         self._occupancy_g = DECODE_OCCUPANCY.labels(**lbl)
         self._kv_bytes_g = DECODE_KV_BYTES.labels(**lbl)
+        self._state_resets_c = DECODE_STATE_RESETS.labels(**lbl)
+        self._recurrent_bytes_g = DECODE_RECURRENT_BYTES.labels(**lbl)
         # decode tier 2, each independently toggleable: ``prefix_cache``
         # (a PrefixKVCache, or a byte budget to own one) retains freed
         # slots' prefix KV for shared-prefix admission; ``speculative``
@@ -392,6 +409,8 @@ class DecodeServer:
             "max_seq_len": self._pool.max_seq_len,
             "kv_dtype": self._pool.kv_dtype,
             "kv_cache_bytes": int(self._kv_bytes_g.value),
+            "recurrent_state_bytes": int(self._recurrent_bytes_g.value),
+            "state_resets": int(self._state_resets_c.value),
             "seq_len_histogram": {
                 str(k): v
                 for k, v in sorted(self.seq_len_histogram().items())},
@@ -600,7 +619,7 @@ class DecodeServer:
                     self._state = None
                     self._slots = []
                     self._occupancy_g.set(0.0)
-                    self._kv_bytes_g.set(0.0)
+                    self._set_pool_bytes(None)
                     cv = self._batcher.queue.cv
                     with cv:
                         if (self._batcher.queue.qsize() == 0
@@ -650,8 +669,7 @@ class DecodeServer:
                     s0 = pool.slot_policy.bucket_for(1)
                     self._state = pool.alloc(s0, need_t)
                     self._slots = [None] * s0
-                    self._kv_bytes_g.set(float(pool.kv_rung_bytes(
-                        s0, need_t)))
+                    self._set_pool_bytes((s0, need_t))
                 else:
                     cur_s, cur_t = pool.state_rungs(self._state)
                     new_t = max(cur_t, need_t)
@@ -661,8 +679,7 @@ class DecodeServer:
                     if (new_s, new_t) != (cur_s, cur_t):
                         self._state = pool.resize(self._state, new_s, new_t)
                         self._slots.extend([None] * (new_s - cur_s))
-                        self._kv_bytes_g.set(float(pool.kv_rung_bytes(
-                            new_s, new_t)))
+                        self._set_pool_bytes((new_s, new_t))
                 slot = next(i for i, s in enumerate(self._slots)
                             if s is None)
                 spec = bool(getattr(req, "speculative", False))
@@ -706,16 +723,30 @@ class DecodeServer:
                 self._fail_in_flight(exc)
                 self._state = None
                 self._slots = []
-                self._kv_bytes_g.set(0.0)
+                self._set_pool_bytes(None)
                 return
             self._slots[slot] = _Slot(req, pre_len)
+            if pool.recurrent_leaves:
+                self._state_resets_c.inc()
             # the shared-prefix win, measured where it happens: only the
             # unmatched suffix re-enters prefill
             self._prefill_c.inc(len(req.prompt) - pre_len)
 
+    def _set_pool_bytes(self, rungs) -> None:
+        """The two byte gauges for the state's rung pair (None: the pool
+        holds nothing)."""
+        pool = self._pool
+        self._kv_bytes_g.set(
+            0.0 if rungs is None else float(pool.kv_rung_bytes(*rungs)))
+        self._recurrent_bytes_g.set(
+            0.0 if rungs is None
+            else float(pool.recurrent_rung_bytes(*rungs)))
+
     def _tick(self) -> None:
         """One scheduler turn: dispatch a multi-step chunk, then
         materialize the (small) scheduler view and stream/complete."""
+        import jax
+
         recs = [(i, s) for i, s in enumerate(self._slots) if s is not None]
         tids = ()
         if _mon_spans.recording():
@@ -746,15 +777,20 @@ class DecodeServer:
             self._fail_in_flight(exc)
             self._state = None
             self._slots = []
-            self._kv_bytes_g.set(0.0)
+            self._set_pool_bytes(None)
             return
         self._state = state
-        self._ticks_c.inc()
         # the scheduler intervention: one d2h of the control-plane
-        # arrays (tokens/pos/flags — KBs, not the KV cache)
-        view = {k: np.asarray(state[k])
-                for k in ("tokens", "pos", "active", "finished", "n_gen")}
+        # arrays (tokens/pos/flags — KBs, not the KV cache); device_get
+        # starts all five copies before it waits for the first, so the
+        # tick pays one transfer's latency, not five in a row
+        view = jax.device_get(
+            {k: state[k]
+             for k in ("tokens", "pos", "active", "finished", "n_gen")})
         self._count_kv_positions(recs, view, use_spec)
+        # after the position counters: whoever sees the tick counted
+        # sees its positions counted too
+        self._ticks_c.inc()
         now = time.perf_counter()
         released: List[int] = []
         for i, rec in recs:
@@ -888,12 +924,16 @@ class DecodeServer:
         self._worker.join(timeout)
         if not self._worker.is_alive():
             self._fail_stragglers()
+            # a stopped server holds no device memory, as an idle one
+            self._state = None
+            self._slots = []
         self._metrics.close()
         self._batcher.close()
         lbl = {"server": self.name, "instance": self._metrics.instance}
         for metric in (DECODE_TOKENS, DECODE_PREFILL_TOKENS, DECODE_TICKS,
                        DECODE_KV_READ, DECODE_KV_POOL,
-                       DECODE_TTFT, DECODE_OCCUPANCY, DECODE_KV_BYTES):
+                       DECODE_TTFT, DECODE_OCCUPANCY, DECODE_KV_BYTES,
+                       DECODE_STATE_RESETS, DECODE_RECURRENT_BYTES):
             metric.remove_labels(**lbl)
         if self._speculative is not None:
             for metric in (SPEC_PROPOSED, SPEC_ACCEPTED):

@@ -15,7 +15,19 @@ prompt/decode storm runs entirely on warmed executables — the pool's
 request-batching path.
 
 The pool state is one dict pytree (slot axis 0 on every leaf; the KV
-cache's T axis read by the step fn).  Buffer donation applies to the
+cache's T axis read by the step fn).  A cache leaf is one of two kinds,
+and the builder says which (``make_cache.leaf_seq_axes``, resolved by
+``decoding.cache_leaf_seq_axes``; a builder that declares nothing gets
+the guess by shape): a leaf WITH a sequence axis (K/V rows) is covered
+by the write-before-read invariant — a reused slot is never zeroed,
+because a sequence reads only positions it wrote itself — and is what
+``extract_kv`` / ``admit_prefix`` slice and ``kv_rung_bytes`` counts; a
+RECURRENT leaf (no sequence axis: an SSM or conv state, read and
+re-written whole each step) is outside that invariant, is started from
+zero by the step itself for a row at position 0, is never sliced, and
+is counted by ``recurrent_rung_bytes``.  A pool over recurrent leaves
+refuses ``prefix=True`` and ``speculative=``: a prefix of positions can
+be neither copied into nor rolled back out of such a state.  Buffer donation applies to the
 state argument on every executable — the multi-MB KV cache updates in
 place in device memory instead of being copied per tick — with the same
 CPU carve-out as the executor (``executor._donate_kwargs``: donation +
@@ -83,9 +95,24 @@ class KVSlotPool:
                  len_multiple: int = 1):
         from paddle_tpu.decoding import (make_prefix_admit_fn,
                                          make_slot_decode_fns,
-                                         normalize_kv_dtype)
+                                         normalize_kv_dtype,
+                                         recurrent_leaf_names)
 
         self._make_cache = make_cache
+        #: tree paths of the cache leaves declared recurrent (no
+        #: sequence axis); empty for a K/V-only cache
+        self.recurrent_leaves = recurrent_leaf_names(make_cache)
+        for what, on in (("prefix=True", prefix),
+                         ("speculative=", speculative is not None)):
+            if on and self.recurrent_leaves:
+                raise ValueError(
+                    "KVSlotPool(%s) over a cache with recurrent leaves "
+                    "(%s ... %d in all): a recurrent state has no "
+                    "positions to copy a prefix into or to roll a "
+                    "rejected round back from, and would serve wrong "
+                    "tokens; state snapshots are not implemented"
+                    % (what, self.recurrent_leaves[0],
+                       len(self.recurrent_leaves)))
         # the cache storage dtype ``make_cache`` allocates (advertised
         # on /healthz; the pool itself is dtype-agnostic — shapes and
         # dtypes all flow from the state spec, so the int8 rung variant
@@ -122,7 +149,8 @@ class KVSlotPool:
                            if speculative is not None else None))
         self._chunk_fn, self._admit_fn, self._release_fn = self._fns
         self._admit_prefix_fn = (
-            make_prefix_admit_fn(self._admit_fn) if self.prefix else None)
+            make_prefix_admit_fn(self._admit_fn, self._kv_seq_axes)
+            if self.prefix else None)
         if speculative is not None:
             from paddle_tpu.serving.speculative import make_spec_chunk_fn
 
@@ -197,6 +225,23 @@ class KVSlotPool:
         leaves, _ = jax.tree_util.tree_flatten(sub)
         return leaves
 
+    def _kv_seq_axes(self, state_or_spec, s: int, t: int):
+        """Sequence axis (or None) of each of :meth:`_kv_subtree_leaves`
+        of ``state_or_spec`` at rung pair ``(s, t)``, as the builders
+        declare them (``decoding.cache_leaf_seq_axes``): the target's
+        leaves first, then the draft's."""
+        import jax
+
+        from paddle_tpu.decoding import cache_leaf_seq_axes
+
+        axes = cache_leaf_seq_axes(
+            self._make_cache, jax.tree.leaves(state_or_spec["cache"]), s, t)
+        if "draft_cache" in state_or_spec:
+            axes += cache_leaf_seq_axes(
+                self.speculative.draft_make_cache,
+                jax.tree.leaves(state_or_spec["draft_cache"]), s, t)
+        return axes
+
     def alloc(self, s: int, t: int) -> Dict[str, object]:
         """A fresh zeroed pool state for rung pair ``(s, t)``, HOST-side
         (plain numpy): device memory is first touched by the executable
@@ -214,7 +259,10 @@ class KVSlotPool:
         returned as numpy for the next executable call (h2d).  A pure
         control-plane move — no XLA compile is ever involved, so the
         zero-recompile guarantee survives rung transitions.  Shrinking
-        assumes the caller vacated the dropped tail slots."""
+        assumes the caller vacated the dropped tail slots.  No axis is
+        looked for: every leaf is cut or padded to the target SPEC's
+        shape, so a recurrent leaf (whose shape follows the slot rung
+        alone) keeps every value whatever the length rungs are."""
         import jax
 
         spec = self._state_spec(new_s, new_t)
@@ -237,18 +285,38 @@ class KVSlotPool:
         s, t = state["tokens"].shape
         return int(s), int(t)
 
+    def _rung_bytes(self, s: int, t: int) -> Tuple[int, int]:
+        """(bytes outside the declared-recurrent leaves, bytes in them)
+        of the cache subtrees at rung pair ``(s, t)``, from the state
+        SPEC's stored dtypes — no allocation."""
+        import jax
+
+        spec = self._state_spec(s, t)
+        total = sum(int(np.prod(l.shape)) * np.dtype(l.dtype).itemsize
+                    for l in self._kv_subtree_leaves(spec))
+        rec = 0
+        if self.recurrent_leaves:
+            axes = jax.tree.leaves(self._make_cache.leaf_seq_axes)
+            rec = sum(int(np.prod(l.shape)) * np.dtype(l.dtype).itemsize
+                      for l, a in zip(jax.tree.leaves(spec["cache"]), axes)
+                      if int(a) < 0)
+        return int(total - rec), int(rec)
+
     def kv_rung_bytes(self, s: int, t: int) -> int:
         """KV bytes one state of rung pair ``(s, t)`` holds (cache +
-        sibling scale leaves + draft cache) — computed from the state
-        SPEC's stored dtypes, no allocation.  This is the pool-
+        sibling scale leaves + draft cache; NOT the leaves declared
+        recurrent — :meth:`recurrent_rung_bytes`).  This is the pool-
         accounting ground truth the ``serving_kv_cache_bytes`` gauge
         and the int8-KV capacity bench read: an int8 pool's rung holds
         ~4x less than fp32's, so a fixed HBM budget seats ~2x+ the
         concurrent sequences at the next slot rung up."""
-        total = 0
-        for leaf in self._kv_subtree_leaves(self._state_spec(s, t)):
-            total += int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-        return int(total)
+        return self._rung_bytes(s, t)[0]
+
+    def recurrent_rung_bytes(self, s: int, t: int) -> int:
+        """Bytes of the leaves declared recurrent (no sequence axis) at
+        rung pair ``(s, t)``: they scale with the slot rung alone.  The
+        ``serving_recurrent_state_bytes`` gauge reads this."""
+        return self._rung_bytes(s, t)[1]
 
     def kv_state_bytes(self, state) -> int:
         """:meth:`kv_rung_bytes` for ``state``'s current rung pair."""
@@ -285,11 +353,9 @@ class KVSlotPool:
         scalar = jax.ShapeDtypeStruct((), i32)
         args = [spec, mask, prompt, scalar, scalar]
         if kind == "admit_prefix":
-            from paddle_tpu.decoding import kv_leaf_seq_axis
-
             kv = []
-            for leaf in self._kv_subtree_leaves(spec):
-                ax = kv_leaf_seq_axis(leaf.shape, s, t)
+            for leaf, ax in zip(self._kv_subtree_leaves(spec),
+                                self._kv_seq_axes(spec, s, t)):
                 kv.append(jax.ShapeDtypeStruct(
                     leaf.shape[1:] if ax is not None else (1,),
                     leaf.dtype if ax is not None
@@ -387,7 +453,8 @@ class KVSlotPool:
         padded host-side to the state's length rung and the slot's
         flags/cursors reset in ONE device dispatch (the cache passes
         through untouched — write-before-read makes zeroing a reused
-        slot unnecessary).  ``spec`` marks the slot for speculative
+        slot's K/V rows unnecessary, and the step starts a recurrent
+        leaf from zero at the position 0 every admit seats).  ``spec`` marks the slot for speculative
         rounds (ignored unless the pool was built with a
         SpeculativeConfig)."""
         s, t = self.state_rungs(state)
@@ -422,18 +489,16 @@ class KVSlotPool:
         by the warmed ``admit_prefix`` executable, and the slot starts
         at ``pos = prefix_len`` — prefill resumes at the unmatched
         suffix.  Requires ``prefix=True`` at construction."""
-        from paddle_tpu.decoding import kv_leaf_seq_axis
-
         if self._admit_prefix_fn is None:
             raise RuntimeError(
                 "pool was built without prefix=True — admit_prefix has "
                 "no warmed executable")
         s, t = self.state_rungs(state)
         mask, buf = self._admit_host_args(s, t, slot, prompt)
-        spec_leaves = self._kv_subtree_leaves(self._state_spec(s, t))
+        spec = self._state_spec(s, t)
         kv = []
-        for sd, ent in zip(spec_leaves, kv_leaves):
-            ax = kv_leaf_seq_axis(sd.shape, s, t)
+        for sd, ent, ax in zip(self._kv_subtree_leaves(spec), kv_leaves,
+                               self._kv_seq_axes(spec, s, t)):
             if ax is None or ent is None:
                 kv.append(np.zeros((1,), np.float32))
                 continue
@@ -460,16 +525,14 @@ class KVSlotPool:
     def extract_kv(self, state, slot: int, m: int):
         """Materialize slot ``slot``'s first ``m`` KV positions as host
         arrays (the prefix cache's retained-entry payload): one list
-        entry per KV subtree leaf (:func:`decoding.kv_leaf_seq_axis`
-        order), ``None`` for leaves carrying no per-slot sequence
-        state.  A control-plane d2h — called when a slot is FREED, off
-        the tick's dispatch path."""
-        from paddle_tpu.decoding import kv_leaf_seq_axis
-
+        entry per KV subtree leaf (tree-flatten order), ``None`` for
+        leaves carrying no per-slot sequence state (recurrent leaves
+        among them: they are never sliced).  A control-plane d2h —
+        called when a slot is FREED, off the tick's dispatch path."""
         s, t = self.state_rungs(state)
         out = []
-        for leaf in self._kv_subtree_leaves(state):
-            ax = kv_leaf_seq_axis(tuple(leaf.shape), s, t)
+        for leaf, ax in zip(self._kv_subtree_leaves(state),
+                            self._kv_seq_axes(state, s, t)):
             if ax is None:
                 out.append(None)
                 continue

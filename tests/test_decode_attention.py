@@ -296,3 +296,53 @@ def test_kernel_compiles_for_v5e_at_gpt1_widths(one_chip):
     leaf = s * t * d * 4
     assert mem.alias_size_in_bytes >= 2 * leaf
     assert mem.temp_size_in_bytes < leaf // 8
+
+
+def test_hybrid_ssm_layer_compiles_for_v5e_at_falcon_h1_widths(one_chip):
+    """80 slots x 1024 positions, one block's Mamba-2 mixer and
+    grouped-query attention at Falcon-H1-34B's widths (bf16 weights and
+    K/V, fp32 state): the SSM, conv and both K/V leaves are aliased in
+    place, no temporary is the size of the 335 MB SSM leaf (the update is
+    one pass), and the update carries its scope's name for the trace."""
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import hybrid_ssm as hs
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "falcon_h1_34b.json")) as fh:
+        cfg = json.load(fh)
+    d = hs.dims(cfg)
+    s, t, p = 80, 1024, "lm_l0_"
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w = {k: sd(shp, jnp.bfloat16 if len(shp) == 2
+               and not k.endswith("conv_w") else jnp.float32)
+         for k, shp in hs.param_shapes(cfg).items() if k.startswith(p)}
+
+    def f(w, ssm, conv, kc, vc, x, ts):
+        mix, ssm, conv = hs.mamba2_step(x, w, p, ssm, conv, ts, d)
+        q = hs.linear(x, w[p + "attn_q"])
+        k = hs.linear(x, w[p + "attn_k"])
+        ctx, kc, vc = da.grouped_masked_decode_attention(
+            q, k, hs.linear(x, w[p + "attn_v"]), kc, vc, ts,
+            n_head=d.n_head, n_kv_head=d.n_kv_head, scale=0.088)
+        return mix, ctx, ssm, conv, kc, vc
+
+    ssm = (s, d.ssm_heads, d.ssm_head_dim, d.d_state)
+    compiled = jax.jit(f, donate_argnums=(1, 2, 3, 4)).lower(
+        w, sd(ssm), sd((s, d.d_conv - 1, d.d_xbc)),
+        sd((s, t, d.d_kv), jnp.bfloat16), sd((s, t, d.d_kv), jnp.bfloat16),
+        sd((s, d.d_model)), sd((s,), jnp.int32)).compile()
+    assert hs.SSM_UPDATE_SCOPE in compiled.as_text()
+    mem = compiled.memory_analysis()
+    ssm_leaf = 4 * s * d.ssm_heads * d.ssm_head_dim * d.d_state
+    kv_leaf = 2 * s * t * d.d_kv
+    assert mem.alias_size_in_bytes >= ssm_leaf + 2 * kv_leaf
+    assert mem.temp_size_in_bytes < ssm_leaf // 2

@@ -51,7 +51,8 @@ def _pooled(state, kv_dtype):
 
 
 def test_kv_dtype_normalization():
-    assert KV_DTYPES == ("fp32", "int8")
+    assert KV_DTYPES == ("fp32", "int8", "bf16")
+    assert normalize_kv_dtype("bfloat16") == "bf16"
     assert normalize_kv_dtype(None) == "fp32"
     assert normalize_kv_dtype("float32") == "fp32"
     assert normalize_kv_dtype("int8") == "int8"
