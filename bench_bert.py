@@ -49,11 +49,13 @@ def run(batch=BATCH, seq=SEQ, steps=STEPS, chunk=CHUNK):
         mpos = fluid.layers.data("mpos", [1], dtype="int64")
         mlab = fluid.layers.data("mlab", [1], dtype="int64")
         nlab = fluid.layers.data("nlab", [1], dtype="int64")
-        fused = os.environ.get("BENCH_FUSED", "0") == "1"
+        # no dropout: each layer's attention is ONE fused_attention op,
+        # whose lowering (Pallas kernel pair / XLA ops) the op picks from
+        # the backend and the shapes (paddle_tpu/fused_attention.py)
         total, mlm_loss, nsp_acc = models.bert_pretrain(
             src, sent, mask, mpos, mlab, nlab,
             vocab_size=V, d_model=D, n_layer=L, n_head=H, d_inner=DI,
-            seq_len=S, dropout_rate=0.0, fused_attention=fused,
+            seq_len=S, dropout_rate=0.0,
         )
         opt = fluid.optimizer.AdamOptimizer(1e-4)
         if use_amp:
@@ -98,46 +100,28 @@ def run(batch=BATCH, seq=SEQ, steps=STEPS, chunk=CHUNK):
     scope = fluid.Scope()
     exe = fluid.Executor(place)
     dev = exe._device()
-    # BENCH_FUSED=1 measures the pallas flash kernel; the op's own
-    # default is the XLA-native path (see fused_attention's docstring).
-    # The env override must cover every exe.run that can TRACE (the flag
-    # is read at trace time, ops/nn_ops.py), but is set/restored around
-    # them rather than left as a process-global side effect — a later
-    # library caller's fused_attention trace must not silently inherit
-    # the pallas path.  Force =1 (not setdefault): a leftover =0 export
-    # would mislabel an XLA measurement as the pallas one.
-    prev_flash = os.environ.get("PADDLE_TPU_FLASH_ATTENTION")
-    if fused:
-        os.environ["PADDLE_TPU_FLASH_ATTENTION"] = "1"
-    try:
-        with fluid.scope_guard(scope):
-            exe.run(startup)
-            stacked = {
-                "src": srcv, "sent": sentv, "mask": maskv,
-                "mpos": mposv, "mlab": mlabv, "nlab": nlabv,
-            }
-            feed, feed1, run_kw = bench_common.stage_feeds(
-                stacked, fresh, chunk, dev)
-            # warmup: 2 single-step runs settle the state avals, then one
-            # chunked (steps=CHUNK fori_loop) call compiles the timed module
-            for _ in range(2):
-                (l,) = exe.run(prog, feed=feed1, fetch_list=[total], return_numpy=False)
-                np.asarray(l)
-            (l,) = exe.run(prog, feed=feed, fetch_list=[total], **run_kw)
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        stacked = {
+            "src": srcv, "sent": sentv, "mask": maskv,
+            "mpos": mposv, "mlab": mlabv, "nlab": nlabv,
+        }
+        feed, feed1, run_kw = bench_common.stage_feeds(
+            stacked, fresh, chunk, dev)
+        # warmup: 2 single-step runs settle the state avals, then one
+        # chunked (steps=CHUNK fori_loop) call compiles the timed module
+        for _ in range(2):
+            (l,) = exe.run(prog, feed=feed1, fetch_list=[total], return_numpy=False)
             np.asarray(l)
-            done = 0
-            t0 = time.perf_counter()
-            while done < steps:
-                (l,) = exe.run(prog, feed=feed, fetch_list=[total], **run_kw)
-                done += chunk
-                lv = np.asarray(l)
-            dt = time.perf_counter() - t0
-    finally:
-        if fused:
-            if prev_flash is None:
-                os.environ.pop("PADDLE_TPU_FLASH_ATTENTION", None)
-            else:
-                os.environ["PADDLE_TPU_FLASH_ATTENTION"] = prev_flash
+        (l,) = exe.run(prog, feed=feed, fetch_list=[total], **run_kw)
+        np.asarray(l)
+        done = 0
+        t0 = time.perf_counter()
+        while done < steps:
+            (l,) = exe.run(prog, feed=feed, fetch_list=[total], **run_kw)
+            done += chunk
+            lv = np.asarray(l)
+        dt = time.perf_counter() - t0
 
     step_time = dt / done
     tokens = batch * S

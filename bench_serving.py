@@ -852,8 +852,7 @@ def _save_lc_lm(n_sp):
             ids = fluid.layers.data("src_ids", [_LC_S], dtype="int64")
             _, logits = models.transformer_lm(
                 ids, None, vocab_size=V, d_model=D, n_layer=L,
-                n_head=H, d_inner=DI, seq_len=_LC_S, max_pos=2 * _LC_S,
-                fused_attention=True)
+                n_head=H, d_inner=DI, seq_len=_LC_S, max_pos=2 * _LC_S)
         exe = fluid.Executor(fluid.CPUPlace())
         kw = {}
         if n_sp > 1:

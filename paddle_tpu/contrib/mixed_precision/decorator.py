@@ -72,6 +72,7 @@ _KEEP_FP32_OUT = {
     "batch_norm": {"MeanOut", "VarianceOut", "SavedMean", "SavedVariance"},
     "layer_norm": {"Mean", "Variance"},
     "group_norm": {"Mean", "Variance"},
+    "fused_attention": {"Lse"},
 }
 
 
@@ -129,7 +130,7 @@ def rewrite_program(main_program, amp_lists: Optional[AutoMixedPrecisionLists] =
 
         if op.type in amp_lists.white_list:
             i += _cast_in(block, i, op, _LOW)
-            _flip_outputs_low(op)
+            _flip_outputs_low(op, keep_out=_KEEP_FP32_OUT.get(op.type, ()))
         elif op.type in amp_lists.gray_list:
             # follow inputs: stay bf16 if anything upstream already is —
             # keeps activation chains (conv→BN→relu→add) in bf16 so HBM

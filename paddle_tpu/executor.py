@@ -637,23 +637,26 @@ class Executor:
             if _rec:
                 _t0 = time.perf_counter()
             fn = lowering.lower_block(block, feed_names, fetch_names, state_out)
-            _act = (compiled.activation_constrainer()
-                    if compiled is not None else None)
-            if _act is not None:
-                # sequence-parallel serving: install the activation
-                # constrainer around the block trace so matched
+            if compiled is not None:
+                # a compiled program's block traces under a marker that
+                # says GSPMD will partition it (ops with a single-device
+                # kernel read it), and, for sequence-parallel serving,
+                # under its activation constrainer, so matched
                 # intermediates get with_sharding_constraint applied
                 # in-trace (trace time = first dispatch of this key —
                 # steady-state dispatches never re-enter fn)
                 _base_fn = fn
 
-                def fn(state, feed, _base=_base_fn, _c=_act):
+                def fn(state, feed, _base=_base_fn,
+                       _c=compiled.activation_constrainer()):
                     from paddle_tpu.sharding import activations as _sh_act
 
-                    _c.begin_trace()
+                    if _c is not None:
+                        _c.begin_trace()
                     with _sh_act.tracing(_c):
                         out = _base(state, feed)
-                    _c.end_trace()
+                    if _c is not None:
+                        _c.end_trace()
                     return out
 
             if steps == 1:

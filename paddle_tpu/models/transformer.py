@@ -33,6 +33,14 @@ def _fc3(x, size, name, num_flatten_dims=2, act=None):
     )
 
 
+def _fuses_attention(dropout_rate: float) -> bool:
+    """The one decision: attention with no dropout inside it is ONE
+    ``fused_attention`` op (its lowering is the op's to choose); with
+    dropout on the probabilities it stays matmul, add, softmax, dropout,
+    matmul."""
+    return not dropout_rate
+
+
 def multi_head_attention(
     q_in,
     kv_in,
@@ -42,23 +50,23 @@ def multi_head_attention(
     attn_bias=None,
     is_test: bool = False,
     name: str = "att",
-    fused: bool = False,
     mask=None,
     causal: bool = False,
 ):
     """Scaled-dot-product multi-head attention over [N, S, d_model].
 
-    Default path: q/k/v projections, [N, H, S, D] batched matmuls
-    (MXU-shaped), optional additive ``attn_bias`` ([S, S] causal or
-    [N, 1, 1, S] padding mask, broadcast into the logits), softmax, and
-    the output projection.
+    q/k/v projections, then over [N, H, S, D] either
 
-    ``fused=True`` (needs dropout_rate==0 inside attention): the
-    ``fused_attention`` op, with padding as ``mask`` [N, S] and
-    causality as ``causal=`` instead of a materialized ``attn_bias``.
-    That op defaults to XLA's native fused attention; set
-    ``PADDLE_TPU_FLASH_ATTENTION=1`` for the pallas flash kernel when
-    S^2 score tensors would exceed HBM (see the op docstring).
+    * ONE ``fused_attention`` op — whenever no dropout sits inside
+      attention, it is self-attention and no materialized ``attn_bias``
+      is handed in: padding goes in as ``mask`` [N, S] (1 = token) and
+      causality as ``causal=``; or
+    * four ops — batched matmul, the additive ``attn_bias`` ([S, S]
+      causal or [N, 1, 1, S] padding, broadcast into the logits),
+      softmax (+ dropout), matmul: attention dropout, cross-attention, or
+      a bias only the caller can build.
+
+    then the output projection.
     """
     d_head = d_model // n_head
     q = _fc3(q_in, d_model, name + "_q")
@@ -71,34 +79,27 @@ def multi_head_attention(
         return layers.transpose(x, perm=[0, 2, 1, 3])
 
     q, k, v = split_heads(q), split_heads(k), split_heads(v)
-    if fused:
-        if dropout_rate:
-            raise ValueError(
-                "fused attention has no in-kernel dropout; build with "
-                "dropout_rate=0 (the reference's inference/pretrain-bench "
-                "configs) or fused=False"
-            )
-        if attn_bias is not None:
-            raise ValueError(
-                "fused attention takes mask=/causal= instead of a "
-                "materialized attn_bias"
-            )
+    if _fuses_attention(dropout_rate) and attn_bias is None and q_in is kv_in:
         from paddle_tpu.layer_helper import LayerHelper
 
         helper = LayerHelper(name + "_fused")
         ctx = helper.create_variable_for_type_inference(q.dtype)
+        lse = helper.create_variable_for_type_inference("float32")
+        lse.stop_gradient = True
         ins = {"Q": [q], "K": [k], "V": [v]}
         if mask is not None:
             ins["Mask"] = [mask]
         helper.append_op(
-            type="fused_attention", inputs=ins, outputs={"Out": [ctx]},
+            type="fused_attention", inputs=ins,
+            outputs={"Out": [ctx], "Lse": [lse]},
             attrs={"causal": bool(causal),
                    "scale": 1.0 / float(np.sqrt(d_head))},
         )
     else:
         if mask is not None or causal:
             raise ValueError(
-                "mask=/causal= are the fused-path inputs; the unfused path "
+                "mask=/causal= describe the masking to the fused op; the "
+                "four-op path (dropout inside attention, cross-attention) "
                 "takes a materialized attn_bias (silently ignoring them "
                 "would drop the masking)"
             )
@@ -130,14 +131,13 @@ def encoder_layer(
     dropout_rate: float = 0.1,
     is_test: bool = False,
     name: str = "enc_0",
-    fused: bool = False,
     mask=None,
     causal: bool = False,
 ):
     """Post-LN transformer block (attention + FFN, residuals)."""
     att = multi_head_attention(
         x, x, d_model, n_head, dropout_rate, attn_bias, is_test,
-        name=name + "_att", fused=fused, mask=mask, causal=causal,
+        name=name + "_att", mask=mask, causal=causal,
     )
     if dropout_rate:
         att = layers.dropout(att, dropout_prob=dropout_rate, is_test=is_test)
@@ -198,14 +198,12 @@ def bert_encoder(
     dropout_rate: float = 0.1,
     is_test: bool = False,
     name: str = "bert",
-    fused_attention: bool = False,
 ):
     """BERT-base encoder; returns the [N, S, d_model] sequence output.
 
-    ``input_mask``: float [N, S] (1 = token, 0 = pad) -> additive bias
-    (or the ``Mask`` input of the fused_attention op when
-    ``fused_attention=True``; that op picks XLA-native vs pallas flash
-    via PADDLE_TPU_FLASH_ATTENTION — see its docstring).
+    ``input_mask``: float [N, S] (1 = token, 0 = pad): the ``Mask`` input
+    of each layer's fused_attention op, or, with dropout inside
+    attention, one materialized additive bias for the four-op build.
     """
     x = _embeddings(src_ids, vocab_size, d_model, max_pos, seq_len, name, sent_ids, 2)
     x = layers.layer_norm(
@@ -216,15 +214,16 @@ def bert_encoder(
     )
     if dropout_rate:
         x = layers.dropout(x, dropout_prob=dropout_rate, is_test=is_test)
+    fused = _fuses_attention(dropout_rate)
     attn_bias = None
-    if input_mask is not None and not fused_attention:
+    if input_mask is not None and not fused:
         m = layers.reshape(input_mask, shape=[-1, 1, 1, seq_len])
         attn_bias = layers.scale(m, scale=1e9, bias=-1e9)  # (m-1)*1e9
     for i in range(n_layer):
         x = encoder_layer(
             x, d_model, n_head, d_inner, attn_bias, dropout_rate, is_test,
-            name="%s_enc_%d" % (name, i), fused=fused_attention,
-            mask=input_mask if fused_attention else None,
+            name="%s_enc_%d" % (name, i),
+            mask=input_mask if fused else None,
         )
     return x
 
@@ -242,25 +241,24 @@ def transformer_lm(
     dropout_rate: float = 0.0,
     is_test: bool = False,
     name: str = "lm",
-    fused_attention: bool = False,
 ):
     """Decoder-only causal LM; returns (avg_loss, logits).
 
     src_ids/labels: int64 [N, S] / [N, S, 1].
 
-    ``fused_attention=True`` (needs dropout_rate=0): causality goes in
-    as the fused op's ``causal=`` attr instead of a materialized [S, S]
-    bias — the build the sequence-parallel (sp) serving layout needs,
-    since only the fused op can dispatch to ring attention (no S^2
-    tensor may exist for the seq axis to shard).
+    With ``dropout_rate=0`` (the default) causality goes in as the fused
+    op's ``causal=`` attr instead of a materialized [S, S] bias — the
+    build the sequence-parallel (sp) serving layout needs, since only
+    the fused op can dispatch to ring attention (no S^2 tensor may exist
+    for the seq axis to shard).
     """
     x = _embeddings(src_ids, vocab_size, d_model, max_pos, seq_len, name)
-    causal = None if fused_attention else _causal_bias(seq_len, x.dtype)
+    fused = _fuses_attention(dropout_rate)
+    causal = None if fused else _causal_bias(seq_len, x.dtype)
     for i in range(n_layer):
         x = encoder_layer(
             x, d_model, n_head, d_inner, causal, dropout_rate, is_test,
-            name="%s_dec_%d" % (name, i), fused=fused_attention,
-            causal=fused_attention,
+            name="%s_dec_%d" % (name, i), causal=fused,
         )
     logits = _fc3(x, vocab_size, name + "_head")
     if labels is None:  # inference/decoding program: logits only
@@ -287,7 +285,6 @@ def bert_pretrain(
     dropout_rate: float = 0.1,
     is_test: bool = False,
     name: str = "bert",
-    fused_attention: bool = False,
 ):
     """BERT pretraining objective: masked-LM + next-sentence prediction
     (BASELINE.json flagship config 3; reference model family:
@@ -302,7 +299,6 @@ def bert_pretrain(
     enc = bert_encoder(
         src_ids, input_mask, sent_ids, vocab_size, d_model, n_layer, n_head,
         d_inner, max_pos, seq_len, dropout_rate, is_test, name,
-        fused_attention=fused_attention,
     )  # [N, S, D]
 
     # ---- masked LM head over gathered positions

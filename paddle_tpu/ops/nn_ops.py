@@ -626,97 +626,170 @@ def sequence_conv(inputs, attrs):
     return {"Out": out}
 
 
-@register_op("fused_attention", no_grad_set={"Mask"})
+def _fused_attention_infer(op, block):
+    """Out is Q's shape and dtype, Lse its leading ``[N, H, S]`` in
+    float32: written out, so that appending the op never traces a
+    lowering (and never counts one)."""
+    q = block.var(op.input("Q")[0])
+    for slot, shape, dtype in (("Out", q.shape, q.dtype),
+                               ("Lse", q.shape and q.shape[:3], "float32")):
+        for n in op.outputs.get(slot, ()):
+            v = block._find_var_recursive(n)
+            if v is not None:
+                v.shape, v.dtype = shape, dtype
+
+
+def _fused_attention_grad_maker(op, block, out_grad_names, req):
+    """ONE ``fused_attention_grad`` op that reads the forward's own
+    context and row statistic, so the compiled step runs the forward
+    kernel once per layer (a ``jax.vjp`` over the forward inside the
+    grad op would be a second, different custom call that XLA cannot
+    merge with the first)."""
+    from paddle_tpu import unique_name
+    from paddle_tpu.framework import grad_var_name
+
+    dout = out_grad_names.get(op.output("Out")[0])
+    if dout is None:
+        return []
+    inputs = {slot: list(names) for slot, names in op.inputs.items()}
+    inputs.update(Out=op.output("Out"), Lse=op.output("Lse"))
+    inputs["Out@GRAD"] = [dout]
+    outputs = {}
+    for slot in ("Q", "K", "V"):
+        name = op.input(slot)[0]
+        if name not in req:
+            continue
+        fwd = block._find_var_recursive(name)
+        grad = unique_name.generate(grad_var_name(name) + "@RENAME@att")
+        block.create_var(name=grad, shape=fwd.shape, dtype=fwd.dtype,
+                         stop_gradient=True)
+        outputs[slot + "@GRAD"] = [grad]
+    if not outputs:
+        return []
+    return [dict(type="fused_attention_grad", inputs=inputs, outputs=outputs,
+                 attrs=dict(op.attrs, op_role="backward"))]
+
+
+def _fused_attention_args(inputs, attrs):
+    """``(q, k, v, mask, causal, scale, path, act)`` of the op or its
+    grad: ``path`` (``"ring"`` | ``"kernel"`` | ``"xla"``) is the
+    lowering for this trace, the same in both, ``act`` the activation
+    context the ring needs."""
+    import jax
+
+    from paddle_tpu.fused_attention import attention_lowering
+    from paddle_tpu.sharding import activations as _sh_act
+
+    q, k, v = one(inputs, "Q"), one(inputs, "K"), one(inputs, "V")
+    mask = maybe(inputs, "Mask")
+    act = _sh_act.current()
+    path = attention_lowering(
+        jax.default_backend(), int(q.shape[2]), int(k.shape[2]),
+        int(q.shape[1]), int(q.shape[3]), q.dtype,
+        partitioned=_sh_act.partitioned())
+    if act is not None and act.sp_axis is not None and mask is None:
+        n_sp = int(act.axis_sizes.get(act.sp_axis, 1))
+        if (n_sp > 1 and int(q.shape[2]) % n_sp == 0
+                and tuple(k.shape) == tuple(q.shape)):
+            path = "ring"
+    return (q, k, v, mask, bool(attrs.get("causal", False)),
+            float(attrs.get("scale", 1.0)), path, act)
+
+
+def _ring_attention(act, causal, scale):
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.parallel.ring_attention import ring_attention
+
+    sp = act.sp_axis
+    spec = P(None, None, sp, None)
+    return jax.shard_map(
+        lambda qq, kk, vv: ring_attention(
+            qq, kk, vv, axis_name=sp, causal=causal, scale=scale),
+        mesh=act.mesh, in_specs=(spec, spec, spec), out_specs=spec)
+
+
+@register_op("fused_attention", no_grad_set={"Mask"},
+             infer_shape=_fused_attention_infer,
+             grad_maker=_fused_attention_grad_maker)
 def fused_attention(inputs, attrs):
-    """Fused scaled-dot-product attention: Q/K/V [N, H, S, D] -> ctx
-    [N, H, S, D].
+    """Fused scaled-dot-product self-attention: Q/K/V [N, H, S, D] ->
+    ``Out`` [N, H, S, D] and ``Lse`` [N, H, S] (float32, the per-row
+    log-sum-exp of the biased scores: what ``fused_attention_grad``
+    rebuilds the probabilities from).
 
-    Default path: plain einsum+softmax — XLA's native fused attention.
+    What ``models.transformer.multi_head_attention`` emits whenever no
+    dropout sits inside attention.  Padding comes in as ``Mask`` [N, S]
+    (1 = token) and masks KEYS only, exactly as the additive
+    ``(mask - 1) * 1e9`` bias of the four-op build: a padded query row
+    still attends every real key.  Scores and softmax are float32;
+    probabilities take V's dtype for the context product.
 
-    PADDLE_TPU_FLASH_ATTENTION=1 opts in to the pallas flash kernel
-    (jax.experimental.pallas.ops.tpu.flash_attention) — online-softmax
-    tiling, no [N, H, S, S] score tensor in HBM — which is the
-    memory-capability path: it admits sequence lengths where the
-    einsum path's S^2 tensors exceed HBM.  The flag on a backend that
-    is not a TPU is an error, not a quiet einsum.  Padding comes in as
-    ``Mask`` [N, S] (1 = token) and is lowered to segment ids (pad
-    positions form their own segment, so real tokens never attend them;
-    pad rows' outputs are garbage-by-construction in BOTH impls and must
-    be masked downstream, as the reference's padded attention does).
+    The lowering is chosen per trace from what the op can see
+    (``paddle_tpu.fused_attention.attention_lowering`` states the rule
+    and the chip numbers that set it) and counted in
+    ``fused_attention_lowered_total{path}``:
 
-    On the chip (PR 21, tools/chip_bringup.py flash, jax 0.9.0 on a
-    v5e): the kernel lowers and runs at B16 H12 S1024 D64 with and
-    without ``Mask``/``causal`` and agrees with the einsum path to
-    7.4e-3 max abs forward, 5.1e-3 relative on dq/dk/dv.  Which path is
-    faster at which S has not been measured this round; the einsum path
-    stays the default until a cell prices the choice (ROADMAP Queue 3
-    item 5).
-
-    Multi-chip long context: when this op is traced under a
-    sequence-parallel activation context (a CompiledProgram whose rules
-    carry sp activation rules — sharding/activations.py), and the
-    sequence divides the sp axis, it dispatches to
-    ``parallel/ring_attention.py``: blockwise exact attention with K/V
-    rotating around the ring, O(S/sp) activation memory per chip.
-    Padding masks and non-divisible lengths fall back to the gathered
-    einsum path (GSPMD inserts the collectives).
+    * ``kernel`` — the Pallas TPU pair of ``paddle_tpu/fused_attention.py``:
+      no score-shaped tensor is written to HBM, forward or backward;
+    * ``xla`` — plain XLA ops, on every backend (``xla_attention``);
+    * ``ring`` — traced under a sequence-parallel activation context (a
+      CompiledProgram whose rules carry sp activation rules —
+      sharding/activations.py) with no ``Mask`` and a sequence the sp
+      axis divides: ``parallel/ring_attention.py``, blockwise exact
+      attention with K/V rotating around the ring, O(S/sp) activation
+      memory per chip.  Padding masks and non-divisible lengths take the
+      gathered ``xla`` path (GSPMD inserts the collectives).  ``Lse`` is
+      zeros there: the ring keeps its own residuals.
     """
-    import os as _os
-
     import jax
     jnp = _jnp()
 
-    q = one(inputs, "Q")
-    k = one(inputs, "K")
-    v = one(inputs, "V")
-    mask = maybe(inputs, "Mask")
-    causal = bool(attrs.get("causal", False))
-    scale = float(attrs.get("scale", 1.0))
+    from paddle_tpu import fused_attention as fa
 
-    from paddle_tpu.sharding import activations as _sh_act
+    q, k, v, mask, causal, scale, path, act = _fused_attention_args(
+        inputs, attrs)
+    fa.LOWERED.labels(path=path).inc()
+    with jax.named_scope("fused_attention"):
+        if path == "ring":
+            return {"Out": _ring_attention(act, causal, scale)(q, k, v),
+                    "Lse": jnp.zeros(q.shape[:3], jnp.float32)}
+        attend = fa.kernel_attention if path == "kernel" else fa.xla_attention
+        out, lse = attend(q, k, v, mask, causal, scale)
+    return {"Out": out, "Lse": lse}
 
-    _act = _sh_act.current()
-    if _act is not None and _act.sp_axis is not None and mask is None:
-        sp = _act.sp_axis
-        n_sp = int(_act.axis_sizes.get(sp, 1))
-        S = int(q.shape[2])
-        if n_sp > 1 and S % n_sp == 0 and tuple(k.shape) == tuple(q.shape):
-            from jax.sharding import PartitionSpec as P
 
-            from paddle_tpu.parallel.ring_attention import ring_attention
+def _fused_attention_grad_infer(op, block):
+    """Nothing to infer: the grad maker made each gradient in its
+    forward input's shape and dtype (and appending the op must not
+    trace a kernel)."""
 
-            spec = P(None, None, sp, None)
-            ring = jax.shard_map(
-                lambda qq, kk, vv: ring_attention(
-                    qq, kk, vv, axis_name=sp, causal=causal, scale=scale),
-                mesh=_act.mesh, in_specs=(spec, spec, spec),
-                out_specs=spec)
-            return {"Out": ring(q, k, v)}
-    if _os.environ.get("PADDLE_TPU_FLASH_ATTENTION", "0") == "1":
-        if jax.default_backend() != "tpu":
-            raise RuntimeError(
-                "PADDLE_TPU_FLASH_ATTENTION=1 asks for the pallas TPU "
-                "flash kernel, but the backend is %r: unset the flag to "
-                "run the einsum path" % jax.default_backend())
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            SegmentIds, flash_attention)
 
-        seg = None
-        if mask is not None:
-            m = mask.astype(jnp.int32)
-            seg = SegmentIds(q=m, kv=m)
-        out = flash_attention(q, k, v, segment_ids=seg, causal=causal,
-                              sm_scale=scale)
-        return {"Out": out.astype(q.dtype)}
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
-    S = q.shape[2]
-    if causal:
-        cm = jnp.where(jnp.arange(S)[None, :] <= jnp.arange(S)[:, None], 0.0, -1e9)
-        s = s + cm
-    if mask is not None:
-        s = s + ((mask.astype(jnp.float32) - 1.0) * 1e9)[:, None, None, :]
-    w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return {"Out": jnp.einsum("bhqk,bhkd->bhqd", w, v)}
+@register_op("fused_attention_grad", infer_shape=_fused_attention_grad_infer)
+def fused_attention_grad(inputs, attrs):
+    """dQ, dK, dV of ``fused_attention`` from its inputs, ``Out``,
+    ``Lse`` and ``Out@GRAD``, by the lowering the forward took: the
+    backward kernel, else ``jax.vjp`` over the XLA or ring form (inside
+    one module XLA merges that forward with the op's own)."""
+    import jax
+
+    from paddle_tpu import fused_attention as fa
+
+    q, k, v, mask, causal, scale, path, act = _fused_attention_args(
+        inputs, attrs)
+    dout = one(inputs, "Out@GRAD").astype(q.dtype)
+    with jax.named_scope("fused_attention_grad"):
+        if path == "kernel":
+            grads = fa.kernel_attention_grad(
+                q, k, v, mask, one(inputs, "Out"), one(inputs, "Lse"), dout,
+                causal, scale)
+        else:
+            attend = (_ring_attention(act, causal, scale) if path == "ring"
+                      else lambda a, b, c: fa.xla_attention(
+                          a, b, c, mask, causal, scale)[0])
+            grads = jax.vjp(attend, q, k, v)[1](dout)
+    return {slot + "@GRAD": g for slot, g in zip("QKV", grads)}
 
 
 # ---------------------------------------------------------------------------

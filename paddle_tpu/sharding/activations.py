@@ -27,7 +27,7 @@ import threading
 from contextlib import contextmanager
 from typing import Any, Dict, Optional
 
-__all__ = ["ActivationConstrainer", "tracing", "current"]
+__all__ = ["ActivationConstrainer", "tracing", "current", "partitioned"]
 
 _TLS = threading.local()
 
@@ -38,15 +38,24 @@ def current() -> Optional["ActivationConstrainer"]:
     return getattr(_TLS, "ctx", None)
 
 
+def partitioned() -> bool:
+    """True while this thread traces the block of a CompiledProgram: the
+    module will be partitioned by GSPMD over the program's mesh, which a
+    Mosaic kernel cannot be (the fused attention op then keeps its XLA
+    form)."""
+    return getattr(_TLS, "partitioned", False)
+
+
 @contextmanager
 def tracing(ctx: Optional["ActivationConstrainer"]):
-    """Install ``ctx`` for the duration of a block trace."""
-    prev = getattr(_TLS, "ctx", None)
-    _TLS.ctx = ctx
+    """Install ``ctx`` (None: a compiled program without activation
+    rules) for the duration of a CompiledProgram's block trace."""
+    prev = getattr(_TLS, "ctx", None), getattr(_TLS, "partitioned", False)
+    _TLS.ctx, _TLS.partitioned = ctx, True
     try:
         yield ctx
     finally:
-        _TLS.ctx = prev
+        _TLS.ctx, _TLS.partitioned = prev
 
 
 class ActivationConstrainer:

@@ -346,3 +346,42 @@ def test_hybrid_ssm_layer_compiles_for_v5e_at_falcon_h1_widths(one_chip):
     kv_leaf = 2 * s * t * d.d_kv
     assert mem.alias_size_in_bytes >= ssm_leaf + 2 * kv_leaf
     assert mem.temp_size_in_bytes < ssm_leaf // 2
+
+
+@pytest.mark.parametrize("shape", [(32, 12, 512, 64), (128, 12, 128, 64)],
+                         ids=["pretrain_s512", "pretrain_s128"])
+def test_fused_attention_kernels_compile_for_v5e_at_bert_widths(one_chip,
+                                                                shape):
+    """The training attention pair (paddle_tpu/fused_attention.py, kept
+    here because one file loads the chip's compiler) in the model's own
+    layout, forward + backward: both kernels lower, the model's head
+    transposes cancel against the op's (the kernels read the
+    projections' ``[N, S, 768]`` as it is: no ``[N, 12, S, 64]`` copy),
+    and nothing score-shaped exists."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import fused_attention as fa
+
+    n, h, s, d = shape
+    assert fa.attention_lowering("tpu", s, s, h, d, jnp.bfloat16) == "kernel"
+
+    def heads(x):
+        return x.reshape(n, s, h, d).transpose(0, 2, 1, 3)
+
+    def merge(x):
+        return x.transpose(0, 2, 1, 3).reshape(n, s, h * d)
+
+    def f(xq, xk, xv, mask, dctx):
+        q, k, v = heads(xq), heads(xk), heads(xv)
+        out, lse = fa.kernel_attention(q, k, v, mask, False, 0.125)
+        grads = fa.kernel_attention_grad(q, k, v, mask, out, lse,
+                                         heads(dctx), False, 0.125)
+        return (merge(out),) + tuple(merge(g) for g in grads)
+
+    x = jax.ShapeDtypeStruct((n, s, h * d), jnp.bfloat16, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((n, s), jnp.float32, sharding=one_chip)
+    text = jax.jit(f).lower(x, x, x, mask, x).compile().as_text()
+    assert "fused_attention_fwd" in text and "fused_attention_bwd" in text
+    assert "[%d,%d,%d,%d]" % (n, h, s, s) not in text
+    assert "[%d,%d,%d,%d]" % (n, h, s, d) not in text

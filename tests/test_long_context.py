@@ -41,7 +41,7 @@ D_MODEL = 32
 SP = 4
 
 
-def _save_lm(dirname, sp_n=0, fused=True):
+def _save_lm(dirname, sp_n=0):
     """The shared fused-attention LM export; ``sp_n > 1`` embeds the
     canonical sp layout + mesh in the manifest."""
     prog, startup = framework.Program(), framework.Program()
@@ -50,8 +50,7 @@ def _save_lm(dirname, sp_n=0, fused=True):
         ids = fluid.layers.data("src_ids", [SEQ], dtype="int64")
         _, logits = models.transformer_lm(
             ids, None, vocab_size=VOCAB, d_model=D_MODEL, n_layer=2,
-            n_head=4, d_inner=64, seq_len=SEQ, max_pos=2 * SEQ,
-            fused_attention=fused)
+            n_head=4, d_inner=64, seq_len=SEQ, max_pos=2 * SEQ)
     exe = fluid.Executor(fluid.CPUPlace())
     kw = {}
     if sp_n > 1:
@@ -124,18 +123,16 @@ def test_ring_attention_matches_full_attention(causal, scale):
     assert got.shape == (B, H, SEQ, D)
 
 
-def test_flash_flag_off_tpu_is_an_error_not_a_quiet_einsum(monkeypatch):
-    """PADDLE_TPU_FLASH_ATTENTION=1 asks for the pallas TPU kernel; on
-    any other backend the op must refuse, not fall back."""
+def test_fused_attention_off_tpu_takes_the_xla_form():
+    """No flag picks the lowering: off a TPU the op's rule gives the XLA
+    form, on the sp ring's shapes too when no sp context is installed."""
+    from paddle_tpu.fused_attention import attention_lowering
     from paddle_tpu.ops.nn_ops import fused_attention
 
     q = np.zeros((1, 2, 8, 4), np.float32)
-    ins = {"Q": [q], "K": [q], "V": [q]}
-    monkeypatch.setenv("PADDLE_TPU_FLASH_ATTENTION", "1")
-    with pytest.raises(RuntimeError, match="PADDLE_TPU_FLASH_ATTENTION"):
-        fused_attention(ins, {"scale": 1.0})
-    monkeypatch.delenv("PADDLE_TPU_FLASH_ATTENTION")
-    assert fused_attention(ins, {"scale": 1.0})["Out"].shape == q.shape
+    outs = fused_attention({"Q": [q], "K": [q], "V": [q]}, {"scale": 1.0})
+    assert outs["Out"].shape == q.shape and outs["Lse"].shape == q.shape[:3]
+    assert attention_lowering("cpu", 512, 512, 12, 64, "bfloat16") == "xla"
 
 
 # ---------------------------------------------------------------------------

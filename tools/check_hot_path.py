@@ -94,6 +94,9 @@ CHECKED_FILES = [
     # every armed train step (ledger-charge) — a blocking sync or event
     # emit creeping in would tax exactly the path the ledger measures
     "paddle_tpu/monitor/train.py",
+    # the training attention kernels and their XLA form are traced into
+    # every BERT / LM step program: nothing here may ever touch the host
+    "paddle_tpu/fused_attention.py",
 ]
 
 # blocking-sync tokens (substring match on code, not comments)

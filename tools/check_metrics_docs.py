@@ -34,6 +34,7 @@ REGISTERING_MODULES = [
     "paddle_tpu.monitor.slo",
     "paddle_tpu.monitor.push",
     "paddle_tpu.executor",
+    "paddle_tpu.fused_attention",
     "paddle_tpu.reader",
     "paddle_tpu.inference",
     "paddle_tpu.serving.metrics",

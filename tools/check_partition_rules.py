@@ -256,8 +256,7 @@ def check_sp() -> List[str]:
         ids = fluid.layers.data("src_ids", [16], dtype="int64")
         models.transformer_lm(
             ids, None, vocab_size=128, d_model=32, n_layer=2,
-            n_head=4, d_inner=64, seq_len=16, max_pos=64,
-            fused_attention=True)
+            n_head=4, d_inner=64, seq_len=16, max_pos=64)
     params = {
         v.name: tuple(v.shape or ())
         for v in prog.list_vars()
