@@ -1079,9 +1079,12 @@ def _decode_spec_block(state, spec_prompts, spec_gen, refs, rollouts):
         state, V, D, L, H, DI)
     verify_fn = make_transformer_lm_pooled_verify_fn(
         state, V, D, L, H, DI)
-    spec = SpeculativeConfig(
-        verify_fn, draft_step_fn,
-        lambda s, t: {"bias": jnp.zeros((s, 1), jnp.float32)}, k=k)
+    def draft_make_cache(s, t):
+        return {"bias": jnp.zeros((s, 1), jnp.float32)}
+
+    draft_make_cache.leaf_seq_axes = {"bias": -1}
+    spec = SpeculativeConfig(verify_fn, draft_step_fn, draft_make_cache,
+                             k=k)
     srv = DecodeServer(step_fn, make_cache, eos_id=1, max_seq_len=ML,
                        max_slots=4, steps_per_tick=1,
                        name="bench-decode-spec", speculative=spec)

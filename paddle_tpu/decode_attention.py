@@ -1,42 +1,50 @@
-"""Decode attention over a slot pool that moves only live KV bytes.
+"""The slot pool's KV-cache format: what a leaf is, how rows are
+appended, how they are read.
 
-One decode step of the slot pool attends, per slot ``n``, one fresh
-query row to the slot's cached positions ``0..ts[n]``.  The pooled
-cache leaf is ``[slots, T, n_head * d_head]`` (heads folded into the
-lane axis: ``d_head`` = 64 alone would be padded to a 128-lane tile in
-HBM and double the pool).  Per step and layer this module
+**The leaf.**  One layer of every pooled decode step keeps ``k`` and
+``v`` leaves ``[slots, T, n_kv_head * d_head]`` in their storage dtype
+(fp32, bf16, int8): slot axis first, sequence axis :data:`KV_SEQ_AXIS`,
+heads folded into the lane axis (``d_head`` = 64 alone would be padded
+to a 128-lane tile in HBM and double the pool).  int8 leaves carry one
+fp32 absmax scale per (slot, position, head) as sibling leaves
+``k_scale``, ``v_scale`` ``[slots, T, n_kv_head]`` (``paddle_tpu.quant``).
+:func:`kv_leaves` allocates a layer; nothing else in the tree spells the
+layout out.
 
-* **appends in place** — the new K/V row of slot ``n`` lands at position
-  ``ts[n]`` by an O(row) update of the donated leaf (a DMA of the row on
-  the TPU, a scatter elsewhere), never by re-emitting the leaf;
-* **reads ragged** — slot ``n`` touches positions ``0..ts[n]`` rounded up
-  to ``block``; a slot with ``ts[n] < 0`` (idle) touches nothing, is not
-  written, and gets a zero context row.
+**The contract.**  A step hands each slot ``n`` at position ``ts[n]``
+``K >= 1`` fresh query / key / value rows (one decode step: ``K = 1``;
+a speculative verify round: ``K`` rows).  Per step and layer
+
+* **append in place** — row ``j`` of slot ``n`` lands at position
+  ``ts[n] + j`` by an O(row) update of the donated leaf (a DMA of the
+  row on the TPU, a scatter elsewhere; int8: quantized as it is
+  written), never by re-emitting the leaf;
+* **read what is live** — row ``j`` attends to positions ``<= ts[n] +
+  j``; ``rep = n_head // n_kv_head`` query heads share a K/V head; a
+  slot with ``ts[n] < 0`` (idle) is not written and gets a zero context
+  row.
 
 Two implementations of that one contract, chosen by
-:func:`make_decode_attention` from the backend the pool lives on:
+:func:`make_decode_attention` from what it can observe:
 
-* :func:`ragged_decode_attention` — the Pallas TPU kernel.  The step's
-  live ``(slot, block)`` pairs are flattened into one work list
-  (:func:`decode_work_items`, shared by every layer of a step); the
+* :func:`ragged_decode_attention` — the Pallas TPU kernel, for fp32
+  leaves, ``rep = 1``, ``K = 1``.  It reads RAGGED: slot ``n`` touches
+  positions ``0..ts[n]`` rounded up to ``block``, an idle slot nothing.
+  The step's live ``(slot, block)`` pairs are flattened into one work
+  list (:func:`decode_work_items`, shared by every layer of a step); the
   kernel walks it with a dynamic trip count, double-buffering the K/V
   block DMAs across slot boundaries, online softmax over the blocks
   read.  K/V stay fp32 in HBM; products are fp32 on the VPU, and the
   per-head sums ride the MXU as a ``[D, 128]`` 0/1 indicator matmul with
   the fp32 operand split three ways into bf16 (hi + mid + lo carries 24
   mantissa bits; the indicator is exact), accumulated in fp32.
-* :func:`masked_decode_attention` — the same math as plain XLA ops
-  (scatter append + masked softmax over the whole T axis): the CPU path
-  and the parity reference of tests/test_decode_attention.py.
-
-* :func:`grouped_masked_decode_attention` — the same contract for
-  grouped-query heads (``n_head`` query heads over ``n_kv_head`` K/V
-  heads, query head ``j`` reading K/V head ``j // (n_head // n_kv_head)``)
-  and any storage dtype of the leaves (``[slots, T, n_kv_head * d_head]``,
-  bf16 for the hybrid SSM block): XLA ops on every backend — products in
-  the leaves' dtype, fp32 accumulation and softmax, the read masked over
-  the whole T axis.  A grouped-head bf16 kernel is open work (ROADMAP
-  Queue 2 A).
+* :func:`grouped_masked_decode_attention` — the contract whole, as plain
+  XLA ops (scatter append + masked softmax over the whole T axis):
+  products in the storage dtype (int8: dequantized to fp32 at the read),
+  fp32 accumulation and softmax.  The CPU path, the path of every step
+  the kernel does not cover (grouped heads, bf16 and int8 leaves, ``K >
+  1``), and the parity reference of tests/test_decode_attention.py.  A
+  grouped-head bf16 kernel is open work (ROADMAP Queue 2 A).
 
 ``jax.experimental.pallas`` is imported inside the kernel builder only:
 ``import paddle_tpu`` and the training cells never pay for it.
@@ -47,10 +55,13 @@ import functools
 
 import numpy as np
 
-__all__ = ["KV_BLOCK", "kv_read_block", "decode_work_items",
-           "ragged_decode_attention", "masked_decode_attention",
+__all__ = ["KV_BLOCK", "KV_SEQ_AXIS", "kv_leaves", "kv_read_block",
+           "decode_work_items", "ragged_decode_attention",
            "grouped_masked_decode_attention",
            "kernel_supported", "make_decode_attention"]
+
+#: the sequence axis of every K/V leaf (and scale sibling)
+KV_SEQ_AXIS = 1
 
 #: positions per K/V block the kernel moves in one DMA (and the rounding
 #: of ``serving_decode_kv_positions_read_total``)
@@ -271,78 +282,121 @@ def ragged_decode_attention(q, k_new, v_new, k_cache, v_cache, ts, work,
       k_cache, v_cache)
 
 
-def masked_decode_attention(q, k_new, v_new, k_cache, v_cache, ts,
-                            *, n_head: int, scale: float):
-    """The contract as plain XLA ops: scatter the new rows at ``ts``
-    (idle rows dropped), then a masked softmax over the whole T axis.
-    Same arguments and returns as :func:`ragged_decode_attention`."""
-    import jax
+def kv_leaves(n_rows: int, seq_len: int, n_kv_head: int, d_head: int,
+              dtype):
+    """One layer's zeroed K/V leaves in the pool's format (the module
+    docstring): ``k``, ``v`` ``[n_rows, seq_len, n_kv_head * d_head]`` in
+    ``dtype``; for int8 also ``k_scale``, ``v_scale`` ``[n_rows, seq_len,
+    n_kv_head]`` fp32.  Every leaf's sequence axis is
+    :data:`KV_SEQ_AXIS`."""
     import jax.numpy as jnp
 
-    S, T, D = k_cache.shape
-    d_head = D // n_head
-    rows = jnp.arange(S)
-    at = jnp.where(ts >= 0, ts, T)          # idle -> out of range, dropped
-    k_cache = k_cache.at[rows, at].set(k_new, mode="drop")
-    v_cache = v_cache.at[rows, at].set(v_new, mode="drop")
-    pos_ok = (jnp.arange(T)[None, :] <= ts[:, None])[:, None, :]  # [S,1,T]
-    scores = jnp.einsum("nhd,nthd->nht", q.reshape(S, n_head, d_head),
-                        k_cache.reshape(S, T, n_head, d_head)) * scale
-    w = jax.nn.softmax(jnp.where(pos_ok, scores, -1e9), axis=-1)
-    ctx = jnp.einsum("nht,nthd->nhd", w,
-                     v_cache.reshape(S, T, n_head, d_head)).reshape(S, D)
-    return jnp.where((ts >= 0)[:, None], ctx, 0.0), k_cache, v_cache
+    rows = (n_rows, seq_len, n_kv_head * d_head)
+    leaves = {"k": jnp.zeros(rows, dtype), "v": jnp.zeros(rows, dtype)}
+    if jnp.dtype(dtype) == jnp.int8:
+        scales = (n_rows, seq_len, n_kv_head)
+        leaves.update(k_scale=jnp.zeros(scales, jnp.float32),
+                      v_scale=jnp.zeros(scales, jnp.float32))
+    return leaves
 
 
-def grouped_masked_decode_attention(q, k_new, v_new, k_cache, v_cache, ts,
+def _append(kv, name, new, rows, at, heads):
+    """Leaf ``name`` of ``kv`` (and its scale sibling) with the rows
+    ``new`` [S, ..., Dkv] written in place at ``[rows, at]``; a position
+    ``>= T`` is dropped."""
+    if name + "_scale" not in kv:
+        return {name: kv[name].at[rows, at].set(
+            new.astype(kv[name].dtype), mode="drop")}
+    from paddle_tpu.quant import quantize_rows
+
+    # quantize-on-write: one absmax scale per fresh (row, head)
+    codes, scales = quantize_rows(new.reshape(new.shape[:-1] + heads))
+    return {name: kv[name].at[rows, at].set(codes.reshape(new.shape),
+                                            mode="drop"),
+            name + "_scale": kv[name + "_scale"].at[rows, at].set(
+                scales, mode="drop")}
+
+
+def _read(kv, name, heads):
+    """Leaf ``name`` as the products see it: ``[S, T, n_kv_head,
+    d_head]``, int8 codes dequantized to fp32 (int8 bytes leave HBM)."""
+    leaf = kv[name].reshape(kv[name].shape[:2] + heads)
+    if name + "_scale" not in kv:
+        return leaf
+    from paddle_tpu.quant import dequantize_rows
+
+    return dequantize_rows(leaf, kv[name + "_scale"])
+
+
+def grouped_masked_decode_attention(q, k_new, v_new, kv, ts,
                                     *, n_head: int, n_kv_head: int,
                                     scale: float):
-    """The contract for grouped-query heads as plain XLA ops.
+    """The contract as plain XLA ops, for every leaf dtype, head grouping
+    and number of fresh rows.
 
-    ``q`` [S, n_head * Dh] and ``k_new``, ``v_new`` [S, n_kv_head * Dh]
-    fp32; ``k_cache``, ``v_cache`` [S, T, n_kv_head * Dh] in their
-    storage dtype (the new rows are rounded to it as they are appended;
-    idle rows ``ts < 0`` dropped).  Scores and the context are products
-    in the storage dtype accumulated in fp32; the softmax is fp32.
-    Returns ``(ctx [S, n_head * Dh] fp32, k_cache, v_cache)``."""
+    ``kv``: one layer's leaves (:func:`kv_leaves`); ``ts`` [S] int32.
+    ``q`` [S, n_head * Dh], ``k_new``, ``v_new`` [S, n_kv_head * Dh] fp32
+    for one fresh row per slot at ``ts``, or ``[S, K, ...]`` for ``K``
+    rows at ``ts .. ts + K - 1``, row ``j`` reading positions ``<= ts +
+    j`` (the rows before it among them).  The new rows are rounded to
+    the storage dtype as they are appended (int8: quantized per row and
+    head); scores and the context are products in the storage dtype
+    (int8: fp32, dequantized at the read) accumulated in fp32; the
+    softmax is fp32 over the whole T axis, masked.  Returns ``(ctx``
+    shaped like ``q``, fp32, ``kv)``."""
     import jax
     import jax.numpy as jnp
 
-    S, T, Dkv = k_cache.shape
-    d_head = Dkv // n_kv_head
+    S, T, Dkv = kv["k"].shape
+    heads = (n_kv_head, Dkv // n_kv_head)
     rep = n_head // n_kv_head
-    dt = k_cache.dtype
-    rows = jnp.arange(S)
-    at = jnp.where(ts >= 0, ts, T)          # idle -> out of range, dropped
-    k_cache = k_cache.at[rows, at].set(k_new.astype(dt), mode="drop")
-    v_cache = v_cache.at[rows, at].set(v_new.astype(dt), mode="drop")
-    pos_ok = (jnp.arange(T)[None, :] <= ts[:, None])[:, None, None, :]
-    qg = (q * scale).astype(dt).reshape(S, n_kv_head, rep, d_head)
-    scores = jnp.einsum("sgrd,stgd->sgrt", qg,
-                        k_cache.reshape(S, T, n_kv_head, d_head),
+    dt = jnp.float32 if "k_scale" in kv else kv["k"].dtype
+    rows, live, pos = jnp.arange(S), ts >= 0, ts
+    if q.ndim == 3:
+        rows, live = rows[:, None], live[:, None]
+        pos = ts[:, None] + jnp.arange(q.shape[1])[None, :]
+    at = jnp.where(live, pos, T)            # idle -> out of range, dropped
+    kv = {**_append(kv, "k", k_new, rows, at, heads),
+          **_append(kv, "v", v_new, rows, at, heads)}
+    pos_ok = (jnp.arange(T)[None, :] <= pos[..., None])[..., None, None, :]
+    qg = (q * scale).astype(dt).reshape(
+        q.shape[:-1] + (n_kv_head, rep, heads[1]))
+    scores = jnp.einsum("s...grd,stgd->s...grt", qg, _read(kv, "k", heads),
                         preferred_element_type=jnp.float32)
     w = jax.nn.softmax(jnp.where(pos_ok, scores, -1e9), axis=-1)
-    ctx = jnp.einsum("sgrt,stgd->sgrd", w.astype(dt),
-                     v_cache.reshape(S, T, n_kv_head, d_head),
+    ctx = jnp.einsum("s...grt,stgd->s...grd", w.astype(dt),
+                     _read(kv, "v", heads),
                      preferred_element_type=jnp.float32)
-    ctx = ctx.reshape(S, n_head * d_head)
-    return jnp.where((ts >= 0)[:, None], ctx, 0.0), k_cache, v_cache
+    return jnp.where(live[..., None], ctx.reshape(q.shape), 0.0), kv
 
 
-def make_decode_attention(ts, seq_len: int, d_model: int, n_head: int,
+def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
                           scale: float):
-    """``attend(q, k_new, v_new, k_cache, v_cache) -> (ctx, k_cache,
-    v_cache)`` for one step at positions ``ts``, shared by its layers:
-    the kernel when the default backend is a TPU and the shapes lower,
-    the XLA ops otherwise."""
+    """``attend(q, k_new, v_new, kv) -> (ctx, kv)`` for one step at
+    positions ``ts``, shared by its layers (``kv``: any one layer's
+    leaves, all alike).  The one place that chooses: the kernel when it
+    exists for what the step is — the default backend a TPU, fp32 leaves
+    of a shape it lowers for, one query head per K/V head, one fresh row
+    per slot — and the XLA form otherwise."""
     import jax
+    import jax.numpy as jnp
 
-    if (jax.default_backend() == "tpu"
-            and kernel_supported(seq_len, d_model, n_head)):
-        block = kv_read_block(seq_len)
-        return functools.partial(
-            ragged_decode_attention, ts=ts,
-            work=decode_work_items(ts, seq_len, block),
+    _, seq_len, width = kv["k"].shape
+    xla = functools.partial(grouped_masked_decode_attention, ts=ts,
+                            n_head=n_head, n_kv_head=n_kv_head, scale=scale)
+    if not (jax.default_backend() == "tpu"
+            and kv["k"].dtype == jnp.float32 and n_kv_head == n_head
+            and kernel_supported(seq_len, width, n_head)):
+        return xla
+    block = kv_read_block(seq_len)
+    work = decode_work_items(ts, seq_len, block)
+
+    def attend(q, k_new, v_new, kv):
+        if q.ndim != 2:     # K fresh rows per slot: no kernel yet
+            return xla(q, k_new, v_new, kv)
+        ctx, k, v = ragged_decode_attention(
+            q, k_new, v_new, kv["k"], kv["v"], ts, work,
             n_head=n_head, scale=scale, block=block)
-    return functools.partial(masked_decode_attention, ts=ts,
-                             n_head=n_head, scale=scale)
+        return ctx, {"k": k, "v": v}
+
+    return attend

@@ -21,6 +21,27 @@ Two regimes:
   trained ``models.transformer.transformer_lm`` Program's weights —
   exact parity with the full-prefix decode
   (tests/test_seq2seq_decode.py::test_cached_decode_*).
+
+The serving slot pool (``serving.kv_pool`` / ``serving.decode``) runs a
+third: ``step_fn(cache, tokens, ts)`` with every row at its OWN position
+``ts``.  Its builders — ``make_transformer_lm_pooled_step_fn``, its
+K-wide twin ``make_transformer_lm_pooled_verify_fn`` and
+``make_hybrid_ssm_lm_pooled_step_fn`` — are made of the same parts:
+
+* ONE cache format, whatever the storage dtype (fp32, bf16, int8):
+  ``paddle_tpu.decode_attention`` says what a K/V leaf is, appends the
+  fresh rows and reads them, by one of its two implementations of one
+  contract (the Pallas TPU kernel, or the XLA form); a builder allocates
+  through ``decode_attention.kv_leaves`` and never spells the layout.
+* ONE transformer-LM block, :func:`_lm_forward_one`, around whatever
+  ``attend`` the builder hands it: the pooled step, the verify forward
+  (the step at K rows, by construction) and the scalar-``t`` builder
+  all run it.  The scalar-``t`` builder keeps a cache and an attention
+  of its own on purpose — it is the independent reference the tests
+  hold the pooled path to.
+* every ``make_cache`` DECLARES its leaves' sequence axes
+  (``make_cache.leaf_seq_axes``, :func:`cache_leaf_seq_axes`); the pool
+  infers nothing from a shape.
 """
 from __future__ import annotations
 
@@ -35,20 +56,24 @@ __all__ = [
     "make_transformer_lm_pooled_step_fn", "make_slot_decode_fns",
     "make_transformer_lm_pooled_verify_fn", "make_prefix_admit_fn",
     "make_hybrid_ssm_lm_pooled_step_fn",
-    "kv_leaf_seq_axis", "cache_leaf_seq_axes", "recurrent_leaf_names",
+    "cache_leaf_seq_axes", "recurrent_leaf_names",
     "normalize_kv_dtype",
     "random_transformer_lm_state",
 ]
 
-#: KV-cache storage dtypes the pooled builders know.  "int8" stores
-#: K/V rows quantized (per-slot-per-head-per-position absmax scales as
-#: sibling ``k_scale``/``v_scale`` leaves — see paddle_tpu.quant),
-#: quantize-on-write / dequant-at-attend inside the jitted step.
-#: "bf16" stores them rounded to bfloat16 as they are appended; only the
-#: hybrid SSM builder takes it (the transformer-LM builders take
-#: ``_LM_KV_DTYPES``: their fp32 kernel and int8 layout are what exist).
+#: KV-cache storage dtypes the pooled builders know (the leaves are
+#: ``paddle_tpu.decode_attention``'s, in every one of them).  "int8"
+#: stores K/V rows quantized (per-slot-per-position-per-head absmax
+#: scales as sibling ``k_scale``/``v_scale`` leaves — see
+#: paddle_tpu.quant), quantize-on-write / dequant-at-read inside the
+#: jitted step.  "bf16" stores them rounded to bfloat16 as they are
+#: appended; only the hybrid SSM builder takes it (the transformer-LM
+#: builders take ``_LM_KV_DTYPES``: no cell has priced bf16 KV under
+#: fp32 weights yet, ROADMAP Queue 1).
 KV_DTYPES = ("fp32", "int8", "bf16")
 _LM_KV_DTYPES = ("fp32", "int8")
+#: what ``decode_attention.kv_leaves`` stores each of them as
+_KV_STORAGE = {"fp32": "float32", "int8": "int8", "bf16": "bfloat16"}
 
 
 def normalize_kv_dtype(kv_dtype, supported=KV_DTYPES) -> str:
@@ -255,6 +280,13 @@ def make_transformer_lm_step_fn(
     key/value cache per layer, so cached decode == full-prefix decode
     bit-for-tolerance (parity-tested).
 
+    All rows sit at the same scalar position ``t``.  The cache and its
+    attention are this builder's own (one ``dynamic_update_index_in_dim``
+    row per lane, a softmax masked over the whole T axis) and share
+    nothing with ``paddle_tpu.decode_attention``: the tests hold the
+    slot-pool path to this one as its independent reference.  The block
+    around the attention is the pooled steps' (:func:`_lm_forward_one`).
+
     Returns ``(step_fn, make_cache)`` where ``make_cache(n_rows)``
     allocates the zeroed cache for ``n_rows = batch * beam`` lanes.
     """
@@ -277,107 +309,46 @@ def make_transformer_lm_step_fn(
 
     def step_fn(cache, tokens, t):
         # tokens [N] int32; t: position being consumed
+        n = tokens.shape[0]
+        pos_ok = (jnp.arange(cache[0]["k"].shape[2]) <= t)[None, None, :]
+
+        def attend(q, k, v, kv):
+            q, k, v = (a.reshape(n, n_head, d_head) for a in (q, k, v))
+            kc = jax.lax.dynamic_update_index_in_dim(kv["k"], k, t, axis=2)
+            vc = jax.lax.dynamic_update_index_in_dim(kv["v"], v, t, axis=2)
+            scores = jnp.einsum("nhd,nhtd->nht", q, kc) * scale
+            w = jax.nn.softmax(jnp.where(pos_ok, scores, -1e9), axis=-1)
+            ctx = jnp.einsum("nht,nhtd->nhd", w, vc).reshape(n, d_model)
+            return ctx, {"k": kc, "v": vc}
+
         x = W[name + "_word_emb"][tokens] + W[name + "_pos_emb"][t]
-        return _lm_forward_one(W, name, cache, x, t, None, n_layer,
-                               n_head, d_head, d_model, scale)
+        return _lm_forward_one(W, name, cache, x, n_layer, attend)
 
     return step_fn, make_cache
 
 
-def _lm_forward_one(W, name, cache, x, t, ts, n_layer, n_head, d_head,
-                    d_model, scale, kv_int8=False):
-    """One incremental transformer-LM forward shared by the scalar-``t``
-    and slot-pooled (per-row ``ts``) step fns.  Exactly one of ``t``
-    (scalar loop position, all rows aligned) / ``ts`` ([N] int32, each
-    row at its own position, ``< 0`` = idle row) is not None; the cache
-    T axis is read from the cache itself so one builder serves every
-    length rung.
+def _lm_forward_one(W, name, cache, x, n_layer, attend):
+    """The transformer-LM forward over fresh rows ``x`` [..., d_model]:
+    ``n_layer`` post-LN blocks (q/k/v, attention, out-projection, LN,
+    exact-GELU FFN, LN) and the head — the ONE definition every step
+    builder of this module runs, whatever its cache.
 
-    What a step reads and writes of the cache, per layer:
-
-    * scalar ``t`` (``[N, H, T, Dh]`` leaves): one
-      ``dynamic_update_index_in_dim`` row per lane, attention masked
-      over the whole T axis.
-    * pooled fp32 (``[N, T, H * Dh]`` leaves): the new K/V row of lane
-      ``n`` is appended IN PLACE at ``ts[n]`` and attention reads
-      positions ``0..ts[n]`` only, rounded up to
-      ``decode_attention.kv_read_block(T)``; an idle lane reads and
-      writes nothing and its logits are garbage the caller discards.
-      On a TPU (and shapes the kernel lowers for) that is the Pallas
-      kernel ``decode_attention.ragged_decode_attention``; elsewhere the
-      same contract as XLA ops (scatter + masked softmax).
-    * pooled int8 (``kv_int8``; ``[N, H, T, Dh]`` int8 leaves with
-      per-(slot, head, position) fp32 scales as sibling ``k_scale``/
-      ``v_scale`` leaves): each fresh row is quantized as it is written
-      (quantize-on-write) through a one-hot select that re-emits the
-      WHOLE leaf, and the whole cache is dequantized at attention time
-      (dequant-at-attend) — O(pool) per step, whatever is live."""
+    ``attend(q, k, v, kv) -> (ctx, kv)`` is the builder's: it appends
+    the rows' keys and values to layer cache ``kv``, attends each row to
+    what it may see and returns the context beside the updated layer.
+    Returns ``(logits [..., V], new_cache)``."""
     import jax
-    import jax.numpy as jnp
 
-    n = x.shape[0]
-    ragged = ts is not None and not kv_int8
-    if ragged:
-        from paddle_tpu.decode_attention import make_decode_attention
-
-        T = cache[0]["k"].shape[1]
-        attend = make_decode_attention(jnp.minimum(ts, T - 1), T, d_model,
-                                       n_head, scale)
-    else:
-        T = cache[0]["k"].shape[2]
-        if ts is None:
-            pos_ok = (jnp.arange(T) <= t)[None, None, :]   # [1,1,T]
-        else:
-            pos_ok = (jnp.arange(T)[None, :] <= ts[:, None])[:, None, :]
-            row_t = (jnp.arange(T)[None, :] == ts[:, None])    # [N,T]
-    if kv_int8:
-        from paddle_tpu.quant import dequantize_rows, quantize_rows
     new_cache = []
     for i in range(n_layer):
         p = "%s_dec_%d" % (name, i)
-        q = _fc(W, x, p + "_att_q")
-        k = _fc(W, x, p + "_att_k")
-        v = _fc(W, x, p + "_att_v")
-        if ragged:
-            ctx, kc, vc = attend(q, k, v, cache[i]["k"], cache[i]["v"])
-            new_cache.append({"k": kc, "v": vc})
-        else:
-            q = q.reshape(n, n_head, d_head)
-            k = k.reshape(n, n_head, d_head)
-            v = v.reshape(n, n_head, d_head)
-            if kv_int8:
-                # quantize-on-write: one absmax scale per fresh (row, head)
-                kq, ks = quantize_rows(k)                  # [N,H] scales
-                vq, vs = quantize_rows(v)
-                sel = row_t[:, None, :, None]              # [N,1,T,1]
-                ssel = row_t[:, None, :]                   # [N,1,T]
-                kc = jnp.where(sel, kq[:, :, None, :], cache[i]["k"])
-                vc = jnp.where(sel, vq[:, :, None, :], cache[i]["v"])
-                ksc = jnp.where(ssel, ks[:, :, None], cache[i]["k_scale"])
-                vsc = jnp.where(ssel, vs[:, :, None], cache[i]["v_scale"])
-                new_cache.append({"k": kc, "k_scale": ksc,
-                                  "v": vc, "v_scale": vsc})
-                # dequant-at-attend: int8 bytes leave HBM, fp32 enters
-                # the einsums
-                kcf = dequantize_rows(kc, ksc)
-                vcf = dequantize_rows(vc, vsc)
-            else:
-                kc = jax.lax.dynamic_update_index_in_dim(
-                    cache[i]["k"], k, t, axis=2)
-                vc = jax.lax.dynamic_update_index_in_dim(
-                    cache[i]["v"], v, t, axis=2)
-                new_cache.append({"k": kc, "v": vc})
-                kcf, vcf = kc, vc
-            scores = jnp.einsum("nhd,nhtd->nht", q, kcf) * scale
-            scores = jnp.where(pos_ok, scores, -1e9)
-            w = jax.nn.softmax(scores, axis=-1)
-            ctx = jnp.einsum("nht,nhtd->nhd", w, vcf).reshape(n, d_model)
-        att = _fc(W, ctx, p + "_att_out")
-        x = _ln(W, x + att, p + "_ln1")
+        ctx, kv = attend(_fc(W, x, p + "_att_q"), _fc(W, x, p + "_att_k"),
+                         _fc(W, x, p + "_att_v"), cache[i])
+        new_cache.append(kv)
+        x = _ln(W, x + _fc(W, ctx, p + "_att_out"), p + "_ln1")
         h = jax.nn.gelu(_fc(W, x, p + "_ffn_fc0"), approximate=False)
         x = _ln(W, x + _fc(W, h, p + "_ffn_fc1"), p + "_ln2")
-    logits = _fc(W, x, name + "_head")
-    return logits, new_cache
+    return _fc(W, x, name + "_head"), new_cache
 
 
 def _fc(W, x, pname):
@@ -391,6 +362,40 @@ def _ln(W, x, pname):
     var = jnp.var(x, axis=-1, keepdims=True)
     y = (x - mean) / jnp.sqrt(var + 1e-5)
     return y * W[pname + "_scale"] + W[pname + "_bias"]
+
+
+def _pooled_lm_parts(state, d_model, n_layer, n_head, name, kv_dtype):
+    """What the pooled step and the K-wide verify forward of one model
+    share: ``forward(cache, x, ts)`` — :func:`_lm_forward_one` over the
+    fresh rows ``x`` ([S, d_model] at ``ts``, or [S, K, d_model] at
+    ``ts .. ts + K - 1``) with ``decode_attention``'s append and read —
+    the weights, and ``make_cache``."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.decode_attention import (KV_SEQ_AXIS, kv_leaves,
+                                             make_decode_attention)
+
+    kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, _LM_KV_DTYPES)]
+    d_head = d_model // n_head
+    W = {k: jnp.asarray(v) for k, v in state.items()}
+    scale = 1.0 / float(np.sqrt(d_head))
+
+    def make_cache(n_rows: int, seq_len: int):
+        return [kv_leaves(n_rows, seq_len, n_head, d_head, kv)
+                for _ in range(n_layer)]
+
+    make_cache.leaf_seq_axes = jax.tree.map(
+        lambda _: KV_SEQ_AXIS, jax.eval_shape(lambda: make_cache(1, 1)))
+
+    def forward(cache, x, ts):
+        T = cache[0]["k"].shape[KV_SEQ_AXIS]
+        attend = make_decode_attention(
+            jnp.minimum(ts, T - 1), cache[0], n_head=n_head,
+            n_kv_head=n_head, scale=scale)
+        return _lm_forward_one(W, name, cache, x, n_layer, attend)
+
+    return forward, W, make_cache
 
 
 def make_transformer_lm_pooled_step_fn(
@@ -417,20 +422,28 @@ def make_transformer_lm_pooled_step_fn(
     every slot that is not active, and ``verify_fn``, the draft step
     and any stand-in step fn follow the same contract.
 
-    What a step moves (fp32): the cache leaves are ``[N, T, d_model]``
-    (heads folded into the lane axis — a ``d_head`` of 64 as the minor
-    axis would be padded to a 128-lane tile in HBM), each new K/V row is
-    appended in place (O(row), the state is donated), and attention
-    reads each row's live positions only, in blocks of
-    ``decode_attention.kv_read_block(T)`` — bytes follow live positions,
-    not the pool's size (see ``_lm_forward_one``).
+    The cache is ``paddle_tpu.decode_attention``'s format in
+    ``kv_dtype`` — per layer ``k``, ``v`` ``[N, T, d_model]`` leaves
+    (``"int8"``: int8 codes beside per-(slot, position, head) fp32
+    scales ``k_scale`` / ``v_scale`` ``[N, T, n_head]``, quantize-on-
+    write / dequant-at-read, roughly quartering per-slot KV bytes so a
+    fixed HBM budget holds ~2x+ the concurrent sequences) — and that
+    module appends and reads it: each new K/V row is written in place
+    (O(row), the state is donated); on a TPU the fp32 step reads each
+    row's live positions only, in blocks of
+    ``decode_attention.kv_read_block(T)`` (the Pallas kernel), every
+    other step reads masked over the whole rung (the XLA form).
 
     The cache T axis is read from the cache arrays themselves, so one
     step fn serves every length rung of the slot pool's bucket ladder:
     ``make_cache(n_rows, seq_len)`` allocates the zeroed pytree for one
-    (slot-rung, length-rung) pair.  Math is identical to the scalar-t
-    builder — with all rows at the same position the two agree to
-    rounding (parity-tested in tests/test_seq2seq_decode.py).
+    (slot-rung, length-rung) pair and DECLARES every leaf's sequence
+    axis (``make_cache.leaf_seq_axes``, see :func:`cache_leaf_seq_axes`),
+    so ``extract_kv`` / ``admit_prefix`` carry both dtypes unchanged and
+    prefix caching and speculative decode compose.  The block around
+    the attention is the scalar-t builder's (:func:`_lm_forward_one`) —
+    with all rows at the same position the two agree to rounding
+    (parity-tested in tests/test_seq2seq_decode.py).
 
     The pool relies on a write-before-read invariant instead of cache
     zeroing on slot reuse: a sequence at position ``ts`` has itself
@@ -441,56 +454,16 @@ def make_transformer_lm_pooled_step_fn(
     builder; recurrent leaves (no sequence axis, read and re-written
     whole each step) are outside it — see
     :func:`make_hybrid_ssm_lm_pooled_step_fn`.
-
-    ``kv_dtype="int8"`` stores the cache int8 in ``[N, H, T, Dh]``
-    leaves (per-slot-per-head scales as sibling ``k_scale``/``v_scale``
-    [N, H, T] fp32 leaves, quantize-on-write / dequant-at-attend — see
-    ``_lm_forward_one``), roughly quartering per-slot KV bytes so a
-    fixed HBM budget holds ~2x+ the concurrent sequences; its step
-    still moves the whole pool.  Every leaf keeps the slot axis
-    leading and a sequence axis; this builder declares none, so the slot
-    pool finds it by shape (``kv_leaf_seq_axis``, through
-    :func:`cache_leaf_seq_axes`) and ``extract_kv``/``admit_prefix``
-    carry both layouts unchanged — prefix caching and speculative decode
-    compose unchanged.
     """
     import jax.numpy as jnp
 
-    kv_dtype = normalize_kv_dtype(kv_dtype, _LM_KV_DTYPES)
-    kv_int8 = kv_dtype == "int8"
-    d_head = d_model // n_head
-    W = {k: jnp.asarray(v) for k, v in state.items()}
-    scale = 1.0 / float(np.sqrt(d_head))
-
-    def make_cache(n_rows: int, seq_len: int):
-        if kv_int8:
-            return [
-                {
-                    "k": jnp.zeros((n_rows, n_head, seq_len, d_head),
-                                   "int8"),
-                    "k_scale": jnp.zeros((n_rows, n_head, seq_len),
-                                         "float32"),
-                    "v": jnp.zeros((n_rows, n_head, seq_len, d_head),
-                                   "int8"),
-                    "v_scale": jnp.zeros((n_rows, n_head, seq_len),
-                                         "float32"),
-                }
-                for _ in range(n_layer)
-            ]
-        return [
-            {
-                "k": jnp.zeros((n_rows, seq_len, d_model), "float32"),
-                "v": jnp.zeros((n_rows, seq_len, d_model), "float32"),
-            }
-            for _ in range(n_layer)
-        ]
+    forward, W, make_cache = _pooled_lm_parts(
+        state, d_model, n_layer, n_head, name, kv_dtype)
 
     def step_fn(cache, tokens, ts):
         x = (W[name + "_word_emb"][tokens]
              + W[name + "_pos_emb"][jnp.maximum(ts, 0)])
-        return _lm_forward_one(W, name, cache, x, None, ts, n_layer,
-                               n_head, d_head, d_model, scale,
-                               kv_int8=kv_int8)
+        return forward(cache, x, ts)
 
     return step_fn, make_cache
 
@@ -519,7 +492,8 @@ def make_hybrid_ssm_lm_pooled_step_fn(state, cfg, name: str = "lm",
 
     * ``k``, ``v`` ``[N, T, n_kv_head * head_dim]`` in ``kv_dtype``
       (``k`` after rotary): appended in place at ``ts``, read
-      ``0..ts`` (``decode_attention.grouped_masked_decode_attention``).
+      ``0..ts`` (``decode_attention``'s format, through its
+      ``make_decode_attention``: grouped heads, so the XLA form).
       The write-before-read invariant covers these: a reused slot's
       stale positions are never read.
     * ``ssm`` ``[N, heads, d_head, d_state]`` in ``ssm_state_dtype`` and
@@ -539,11 +513,10 @@ def make_hybrid_ssm_lm_pooled_step_fn(state, cfg, name: str = "lm",
     import jax.numpy as jnp
 
     from paddle_tpu import hybrid_ssm as hs
-    from paddle_tpu.decode_attention import grouped_masked_decode_attention
+    from paddle_tpu.decode_attention import kv_leaves, make_decode_attention
 
     d = hs.dims(cfg)
-    kv = {"fp32": jnp.float32, "bf16": jnp.bfloat16}[
-        normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
+    kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
     ssm_dt = jnp.dtype(ssm_state_dtype)
     W = {k: jnp.asarray(v) for k, v in state.items()}
     scale = 1.0 / float(np.sqrt(d.head_dim))
@@ -551,8 +524,7 @@ def make_hybrid_ssm_lm_pooled_step_fn(state, cfg, name: str = "lm",
     def make_cache(n_rows: int, seq_len: int):
         return [
             {
-                "k": jnp.zeros((n_rows, seq_len, d.d_kv), kv),
-                "v": jnp.zeros((n_rows, seq_len, d.d_kv), kv),
+                **kv_leaves(n_rows, seq_len, d.n_kv_head, d.head_dim, kv),
                 "ssm": jnp.zeros((n_rows, d.ssm_heads, d.ssm_head_dim,
                                   d.d_state), ssm_dt),
                 "conv": jnp.zeros((n_rows, d.d_conv - 1, d.d_xbc),
@@ -569,6 +541,9 @@ def make_hybrid_ssm_lm_pooled_step_fn(state, cfg, name: str = "lm",
         T = cache[0]["k"].shape[1]
         ts = jnp.minimum(ts, T - 1)     # idle rows stay < 0
         pos = jnp.maximum(ts, 0)
+        attend = make_decode_attention(
+            ts, cache[0], n_head=d.n_head, n_kv_head=d.n_kv_head,
+            scale=scale)
         h = W[name + "_emb"][tokens].astype(jnp.float32) \
             * d.embedding_multiplier
         new_cache = []
@@ -583,12 +558,12 @@ def make_hybrid_ssm_lm_pooled_step_fn(state, cfg, name: str = "lm",
                 n, d.n_head, d.head_dim)
             k = (d.key_multiplier * hs.linear(xa, W[p + "attn_k"])).reshape(
                 n, d.n_kv_head, d.head_dim)
-            ctx, kc, vc = grouped_masked_decode_attention(
+            ctx, kvs = attend(
                 hs.rotary(q, pos, d.rope_theta).reshape(n, -1),
                 hs.rotary(k, pos, d.rope_theta).reshape(n, -1),
-                hs.linear(xa, W[p + "attn_v"]), c["k"], c["v"], ts,
-                n_head=d.n_head, n_kv_head=d.n_kv_head, scale=scale)
-            new_cache.append({"k": kc, "v": vc, "ssm": ssm, "conv": conv})
+                hs.linear(xa, W[p + "attn_v"]),
+                {"k": c["k"], "v": c["v"]})
+            new_cache.append({**kvs, "ssm": ssm, "conv": conv})
             h = (h + d.ssm_out_multiplier * mix
                  + d.attention_out_multiplier * hs.linear(
                      ctx, W[p + "attn_o"]))
@@ -616,123 +591,33 @@ def make_transformer_lm_pooled_verify_fn(
 
     ``verify_fn(cache, tokens [S, K] int32, ts [S] int32) -> (logits
     [S, K, V], cache)``: row ``i`` consumes ``tokens[i, j]`` at position
-    ``ts[i] + j`` for every ``j`` in ONE call — exactly the math of K
-    sequential :func:`make_transformer_lm_pooled_step_fn` steps (same
-    weights dict, same post-LN/gelu blocks), with causal masking among
-    the K fresh positions, so ``argmax(logits[i, j])`` is bit-identical
-    to the token the sequential path would produce after consuming
-    ``tokens[i, :j + 1]``.  That equality is what makes greedy-exact
-    speculative acceptance output-identical (parity-pinned in
-    tests/test_prefix_cache.py).
+    ``ts[i] + j`` for every ``j`` in ONE call.  It IS the pooled step
+    (:func:`make_transformer_lm_pooled_step_fn`, same ``kv_dtype``) at
+    ``K`` fresh rows per slot: the same block and the same append and
+    read (``decode_attention``'s contract at ``K`` rows: all ``K`` rows
+    are written before any is read, row ``j`` attends to positions
+    ``<= ts + j``, an int8 row is quantized as the sequential step
+    quantizes it), so ``argmax(logits[i, j])`` is the token the
+    sequential path produces after consuming ``tokens[i, :j + 1]`` —
+    what makes greedy-exact speculative acceptance output-identical
+    (tests/test_prefix_cache.py).
 
-    Positions are clamped to the cache T axis like the sequential step
-    clamps its buffer indices; a clamped lane is garbage-in-garbage-out
-    but such lanes are inactive/finished and their results are never
-    committed.  ``ts[i] < 0`` marks an idle row, as in the pooled step:
-    none of its cache row is written.  The K fresh K/V rows are
-    scattered into the cache BEFORE attention (write-before-read, same
-    invariant as the pooled step), so position ``ts + j`` attends to the
-    just-written rows ``ts .. ts + j``.  fp32 leaves are the pooled
-    step's ``[S, T, d_model]`` and take the K rows by an in-place
-    scatter; the read is masked over the whole T axis (no cell prices a
-    speculative round yet).
-
-    ``kv_dtype`` must match the step fn the cache was built for: with
-    ``"int8"`` each fresh row is quantized EXACTLY like the sequential
-    step quantizes it (same per-row absmax), scattered as int8 with its
-    scale, and the cache dequantized at attention time — quantization
-    is deterministic, so greedy-exact acceptance still holds
-    bit-for-bit against the int8 sequential path.
+    A row past the cache T axis is not written, and its logits are
+    garbage: such lanes are beyond their sequence's length cap and are
+    never committed.  ``ts[i] < 0`` marks an idle row, as in the pooled
+    step: none of its cache row is written.
     """
-    import jax
     import jax.numpy as jnp
 
-    kv_dtype = normalize_kv_dtype(kv_dtype, _LM_KV_DTYPES)
-    kv_int8 = kv_dtype == "int8"
-    d_head = d_model // n_head
-    W = {k: jnp.asarray(v) for k, v in state.items()}
-    scale = 1.0 / float(np.sqrt(d_head))
-    if kv_int8:
-        from paddle_tpu.quant import dequantize_rows, quantize_rows
+    forward, W, _ = _pooled_lm_parts(
+        state, d_model, n_layer, n_head, name, kv_dtype)
 
     def verify_fn(cache, tokens, ts):
-        S, K = tokens.shape
-        # int8 leaves are [S, H, T, Dh], fp32 leaves [S, T, H * Dh]
-        kv_axes = "shtd" if kv_int8 else "sthd"
-        T = cache[0]["k"].shape[2 if kv_int8 else 1]
-        idle = ts < 0
+        T = cache[0]["k"].shape[1]
         p = jnp.minimum(jnp.maximum(ts, 0)[:, None]
-                        + jnp.arange(K)[None, :], T - 1)
+                        + jnp.arange(tokens.shape[1])[None, :], T - 1)
         x = W[name + "_word_emb"][tokens] + W[name + "_pos_emb"][p]
-        pos_ok = (jnp.arange(T)[None, None, None, :]
-                  <= p[:, :, None, None])                      # [S,K,1,T]
-        if kv_int8:
-            sel = ((jnp.arange(T)[None, None, :] == p[:, :, None])
-                   & ~idle[:, None, None])                     # [S,K,T]
-            touched = sel.any(axis=1)[:, None, :, None]        # [S,1,T,1]
-            touched_s = sel.any(axis=1)[:, None, :]            # [S,1,T]
-            selk = sel.astype(jnp.float32)
-        else:
-            rows = jnp.arange(S)[:, None]
-            at = jnp.where(idle[:, None], T, p)    # idle: dropped
-        new_cache = []
-        for i in range(n_layer):
-            pfx = "%s_dec_%d" % (name, i)
-            q = _fc(W, x, pfx + "_att_q").reshape(S, K, n_head, d_head)
-            k = _fc(W, x, pfx + "_att_k")
-            v = _fc(W, x, pfx + "_att_v")
-            if kv_int8:
-                # scatter the K fresh rows at positions p: the one-hot
-                # einsum reduces to an exact copy for the (distinct)
-                # live positions; clamp collisions only happen on lanes
-                # past their buffer, whose rows are never read back.
-                # Quantize each fresh row the way the sequential step
-                # does (per-row absmax) BEFORE the scatter: int8 codes
-                # are exact small integers in fp32, so the one-hot
-                # einsum copy round-trips them bit-identically
-                kq, ks = quantize_rows(k.reshape(S, K, n_head, d_head))
-                vq, vs = quantize_rows(v.reshape(S, K, n_head, d_head))
-                kc = jnp.where(
-                    touched,
-                    jnp.clip(jnp.einsum("skt,skhd->shtd", selk,
-                                        kq.astype(jnp.float32)),
-                             -127.0, 127.0).astype(jnp.int8),
-                    cache[i]["k"])
-                vc = jnp.where(
-                    touched,
-                    jnp.clip(jnp.einsum("skt,skhd->shtd", selk,
-                                        vq.astype(jnp.float32)),
-                             -127.0, 127.0).astype(jnp.int8),
-                    cache[i]["v"])
-                ksc = jnp.where(touched_s,
-                                jnp.einsum("skt,skh->sht", selk, ks),
-                                cache[i]["k_scale"])
-                vsc = jnp.where(touched_s,
-                                jnp.einsum("skt,skh->sht", selk, vs),
-                                cache[i]["v_scale"])
-                new_cache.append({"k": kc, "k_scale": ksc,
-                                  "v": vc, "v_scale": vsc})
-                kcf = dequantize_rows(kc, ksc)
-                vcf = dequantize_rows(vc, vsc)
-            else:
-                # in-place append of the K fresh rows (clamp collisions
-                # only on lanes past their buffer, never read back)
-                kc = cache[i]["k"].at[rows, at].set(k, mode="drop")
-                vc = cache[i]["v"].at[rows, at].set(v, mode="drop")
-                new_cache.append({"k": kc, "v": vc})
-                kcf = kc.reshape(S, T, n_head, d_head)
-                vcf = vc.reshape(S, T, n_head, d_head)
-            scores = jnp.einsum("skhd,%s->skht" % kv_axes, q, kcf) * scale
-            scores = jnp.where(pos_ok, scores, -1e9)
-            w = jax.nn.softmax(scores, axis=-1)
-            ctx = jnp.einsum("skht,%s->skhd" % kv_axes, w,
-                             vcf).reshape(S, K, d_model)
-            att = _fc(W, ctx, pfx + "_att_out")
-            x = _ln(W, x + att, pfx + "_ln1")
-            h = jax.nn.gelu(_fc(W, x, pfx + "_ffn_fc0"), approximate=False)
-            x = _ln(W, x + _fc(W, h, pfx + "_ffn_fc1"), pfx + "_ln2")
-        logits = _fc(W, x, name + "_head")
-        return logits, new_cache
+        return forward(cache, x, ts)
 
     return verify_fn
 
@@ -869,28 +754,34 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
 # ---------------------------------------------------------------------------
 # Prefix KV installation (serving.prefix_cache's device half)
 # ---------------------------------------------------------------------------
-def cache_leaf_seq_axes(make_cache, leaves, n_slots: int, seq_len: int):
-    """The sequence axis of each of ``leaves`` (the flattened cache
-    ``make_cache(n_slots, seq_len)`` builds, arrays or shape structs), or
-    None for a leaf with none.
+def _declared_seq_axes(make_cache):
+    declared = getattr(make_cache, "leaf_seq_axes", None)
+    if declared is None:
+        raise ValueError(
+            "make_cache declares no leaf_seq_axes: set "
+            "make_cache.leaf_seq_axes to a pytree shaped like the cache "
+            "it builds, holding each leaf's sequence axis (-1 for a leaf "
+            "with none); the slot pool infers nothing from a shape")
+    return declared
 
-    A builder that knows its leaves DECLARES them:
-    ``make_cache.leaf_seq_axes`` is a pytree shaped like the cache whose
-    leaves are ints — the axis, or ``-1`` for a recurrent leaf with no
-    sequence axis — and nothing is inferred from a shape.  A builder
-    that declares nothing gets :func:`kv_leaf_seq_axis`'s guess by
-    shape, leaf by leaf (the transformer-LM caches and the tests'
-    stand-in steps).  ``KVSlotPool.extract_kv`` / ``admit_prefix`` /
+
+def cache_leaf_seq_axes(make_cache, leaves):
+    """The sequence axis of each of ``leaves`` (the flattened cache
+    ``make_cache`` builds, arrays or shape structs), or None for a leaf
+    with none.
+
+    Every builder DECLARES its leaves: ``make_cache.leaf_seq_axes`` is a
+    pytree shaped like the cache whose leaves are ints — the axis, or
+    ``-1`` for a leaf with no sequence axis (recurrent state, or
+    anything the pool should carry and never slice) — and nothing is
+    inferred from a shape; a ``make_cache`` without the attribute is an
+    error.  ``KVSlotPool.extract_kv`` / ``admit_prefix`` /
     ``kv_rung_bytes`` and :func:`make_prefix_admit_fn` all resolve the
     axis through this one function, so the host side and the traced side
     cannot disagree."""
     import jax
 
-    declared = getattr(make_cache, "leaf_seq_axes", None)
-    if declared is None:
-        return [kv_leaf_seq_axis(tuple(l.shape), n_slots, seq_len)
-                for l in leaves]
-    axes = jax.tree.leaves(declared)
+    axes = jax.tree.leaves(_declared_seq_axes(make_cache))
     if len(axes) != len(leaves):
         raise ValueError(
             "make_cache.leaf_seq_axes declares %d leaves, the cache has %d"
@@ -900,42 +791,15 @@ def cache_leaf_seq_axes(make_cache, leaves, n_slots: int, seq_len: int):
 
 def recurrent_leaf_names(make_cache):
     """Tree paths of the leaves ``make_cache`` declares recurrent (no
-    sequence axis: ``-1`` in ``make_cache.leaf_seq_axes``); empty for a
-    builder that declares nothing."""
+    sequence axis: ``-1`` in ``make_cache.leaf_seq_axes``)."""
     import jax
 
-    declared = getattr(make_cache, "leaf_seq_axes", None)
-    if declared is None:
-        return []
     return [jax.tree_util.keystr(path) for path, a in
-            jax.tree_util.tree_flatten_with_path(declared)[0] if int(a) < 0]
+            jax.tree_util.tree_flatten_with_path(
+                _declared_seq_axes(make_cache))[0] if int(a) < 0]
 
 
-def kv_leaf_seq_axis(shape, n_slots: int, seq_len: int):
-    """The GUESS by shape of a per-slot KV-cache leaf's sequence axis,
-    for builders that declare nothing (see :func:`cache_leaf_seq_axes`):
-    None when the leaf carries no per-slot sequence state (no leading
-    slot axis of ``n_slots``, or no axis of size ``seq_len`` past it).
-
-    Convention: the LAST axis of size ``seq_len`` that is not the final
-    axis, else the final axis — the transformer cache is ``[S, T, H *
-    Dh]`` (fp32) or ``[S, H, T, Dh]`` (int8; T at -2, robust to an
-    ``H == T`` or a width ``== T`` coincidence) and simple per-position
-    buffers are ``[S, T]`` (T final).  A leaf whose width or head
-    count happens to equal the rung is taken for a sequence leaf: a
-    builder with such leaves declares them instead.
-    """
-    if len(shape) < 2 or shape[0] != n_slots:
-        return None
-    inner = tuple(shape[1:])
-    cands = [i for i, d in enumerate(inner) if d == seq_len]
-    if not cands:
-        return None
-    non_final = [i for i in cands if i != len(inner) - 1]
-    return (non_final[-1] if non_final else cands[-1]) + 1
-
-
-def make_prefix_admit_fn(admit_fn, seq_axes_of=None):
+def make_prefix_admit_fn(admit_fn, seq_axes_of):
     """Wrap a :func:`make_slot_decode_fns` ``admit`` with shared-prefix
     KV installation: ``admit_prefix(state, slot_mask, prompt,
     prompt_len, total_len, kv_leaves, prefix_len[, spec_flag])`` seats
@@ -949,10 +813,10 @@ def make_prefix_admit_fn(admit_fn, seq_axes_of=None):
     order), each leaf host-padded along its sequence axis to the
     state's length rung; non-qualifying positions carry a ``(1,)``
     dummy.  Qualification and the sequence axis are STATIC
-    (``seq_axes_of(subtrees, S, T)`` over the ``{"cache": ...,
+    (``seq_axes_of(subtrees)`` over the ``{"cache": ...,
     "draft_cache": ...}`` dict — the pool passes its builders'
-    declaration, :func:`cache_leaf_seq_axes`; by default the guess by
-    shape), so one compiled executable per rung pair serves every cached
+    declaration, :func:`cache_leaf_seq_axes`), so one compiled
+    executable per rung pair serves every cached
     prefix length — ``prefix_len`` stays a dynamic scalar.  Positional
     embeddings are absolute, so retained rows are position-correct for
     any matching prompt.
@@ -974,10 +838,8 @@ def make_prefix_admit_fn(admit_fn, seq_axes_of=None):
         if "draft_cache" in out:
             sub["draft_cache"] = out["draft_cache"]
         leaves, treedef = jax.tree_util.tree_flatten(sub)
-        axes = (seq_axes_of(sub, S, T) if seq_axes_of is not None
-                else [kv_leaf_seq_axis(l.shape, S, T) for l in leaves])
         new_leaves = []
-        for cur, pre, ax in zip(leaves, kv_leaves, axes):
+        for cur, pre, ax in zip(leaves, kv_leaves, seq_axes_of(sub)):
             if ax is None or tuple(pre.shape) != tuple(cur.shape[1:]):
                 new_leaves.append(cur)
                 continue

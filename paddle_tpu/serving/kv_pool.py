@@ -17,8 +17,8 @@ request-batching path.
 The pool state is one dict pytree (slot axis 0 on every leaf; the KV
 cache's T axis read by the step fn).  A cache leaf is one of two kinds,
 and the builder says which (``make_cache.leaf_seq_axes``, resolved by
-``decoding.cache_leaf_seq_axes``; a builder that declares nothing gets
-the guess by shape): a leaf WITH a sequence axis (K/V rows) is covered
+``decoding.cache_leaf_seq_axes``; a ``make_cache`` that declares nothing
+is refused): a leaf WITH a sequence axis (K/V rows) is covered
 by the write-before-read invariant — a reused slot is never zeroed,
 because a sequence reads only positions it wrote itself — and is what
 ``extract_kv`` / ``admit_prefix`` slice and ``kv_rung_bytes`` counts; a
@@ -159,6 +159,10 @@ class KVSlotPool:
                 self.eos_id, speculative.k)
         else:
             self._spec_chunk_fn = None
+        # every leaf's kind is known before anything compiles: a
+        # make_cache (the draft's too) that declares no axes, or not as
+        # many as it builds leaves, is refused here
+        self._kv_seq_axes(self._state_spec(*self.rung_pairs()[0]))
         self._exe: Dict[Tuple[str, int, int], object] = {}
         self._lock = threading.Lock()
         self._hits = 0
@@ -225,21 +229,21 @@ class KVSlotPool:
         leaves, _ = jax.tree_util.tree_flatten(sub)
         return leaves
 
-    def _kv_seq_axes(self, state_or_spec, s: int, t: int):
+    def _kv_seq_axes(self, state_or_spec):
         """Sequence axis (or None) of each of :meth:`_kv_subtree_leaves`
-        of ``state_or_spec`` at rung pair ``(s, t)``, as the builders
-        declare them (``decoding.cache_leaf_seq_axes``): the target's
-        leaves first, then the draft's."""
+        of ``state_or_spec``, as the builders declare them
+        (``decoding.cache_leaf_seq_axes``): the target's leaves first,
+        then the draft's."""
         import jax
 
         from paddle_tpu.decoding import cache_leaf_seq_axes
 
         axes = cache_leaf_seq_axes(
-            self._make_cache, jax.tree.leaves(state_or_spec["cache"]), s, t)
+            self._make_cache, jax.tree.leaves(state_or_spec["cache"]))
         if "draft_cache" in state_or_spec:
             axes += cache_leaf_seq_axes(
                 self.speculative.draft_make_cache,
-                jax.tree.leaves(state_or_spec["draft_cache"]), s, t)
+                jax.tree.leaves(state_or_spec["draft_cache"]))
         return axes
 
     def alloc(self, s: int, t: int) -> Dict[str, object]:
@@ -286,21 +290,20 @@ class KVSlotPool:
         return int(s), int(t)
 
     def _rung_bytes(self, s: int, t: int) -> Tuple[int, int]:
-        """(bytes outside the declared-recurrent leaves, bytes in them)
-        of the cache subtrees at rung pair ``(s, t)``, from the state
-        SPEC's stored dtypes — no allocation."""
-        import jax
-
+        """(bytes in the leaves with a sequence axis, bytes in those
+        declared to have none) of the cache subtrees at rung pair
+        ``(s, t)``, from the state SPEC's stored dtypes — no
+        allocation."""
         spec = self._state_spec(s, t)
-        total = sum(int(np.prod(l.shape)) * np.dtype(l.dtype).itemsize
-                    for l in self._kv_subtree_leaves(spec))
-        rec = 0
-        if self.recurrent_leaves:
-            axes = jax.tree.leaves(self._make_cache.leaf_seq_axes)
-            rec = sum(int(np.prod(l.shape)) * np.dtype(l.dtype).itemsize
-                      for l, a in zip(jax.tree.leaves(spec["cache"]), axes)
-                      if int(a) < 0)
-        return int(total - rec), int(rec)
+        seq = rec = 0
+        for leaf, ax in zip(self._kv_subtree_leaves(spec),
+                            self._kv_seq_axes(spec)):
+            n = int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+            if ax is None:
+                rec += n
+            else:
+                seq += n
+        return seq, rec
 
     def kv_rung_bytes(self, s: int, t: int) -> int:
         """KV bytes one state of rung pair ``(s, t)`` holds (cache +
@@ -355,7 +358,7 @@ class KVSlotPool:
         if kind == "admit_prefix":
             kv = []
             for leaf, ax in zip(self._kv_subtree_leaves(spec),
-                                self._kv_seq_axes(spec, s, t)):
+                                self._kv_seq_axes(spec)):
                 kv.append(jax.ShapeDtypeStruct(
                     leaf.shape[1:] if ax is not None else (1,),
                     leaf.dtype if ax is not None
@@ -498,7 +501,7 @@ class KVSlotPool:
         spec = self._state_spec(s, t)
         kv = []
         for sd, ent, ax in zip(self._kv_subtree_leaves(spec), kv_leaves,
-                               self._kv_seq_axes(spec, s, t)):
+                               self._kv_seq_axes(spec)):
             if ax is None or ent is None:
                 kv.append(np.zeros((1,), np.float32))
                 continue
@@ -529,10 +532,9 @@ class KVSlotPool:
         leaves carrying no per-slot sequence state (recurrent leaves
         among them: they are never sliced).  A control-plane d2h —
         called when a slot is FREED, off the tick's dispatch path."""
-        s, t = self.state_rungs(state)
         out = []
         for leaf, ax in zip(self._kv_subtree_leaves(state),
-                            self._kv_seq_axes(state, s, t)):
+                            self._kv_seq_axes(state)):
             if ax is None:
                 out.append(None)
                 continue

@@ -351,6 +351,7 @@ def _chain_decode_server(name):
     def make_cache(n_rows, seq_len):
         return {"z": jnp.zeros((n_rows, seq_len), "float32")}
 
+    make_cache.leaf_seq_axes = {"z": 1}
     srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=16,
                        max_slots=2, steps_per_tick=2, name=name)
     srv.warmup(configure_cache=False)
@@ -426,6 +427,7 @@ def _prefix_decode_server(name):
     def make_cache(n_rows, seq_len):
         return {"z": jnp.zeros((n_rows, seq_len), "float32")}
 
+    make_cache.leaf_seq_axes = {"z": 1}
     cache = PrefixKVCache(capacity_bytes=1 << 20, block_tokens=4,
                           name=name)
     srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=16,

@@ -56,6 +56,7 @@ def chain_model():
     def make_cache(n_rows, seq_len):
         return {"z": jnp.zeros((n_rows, seq_len), "float32")}
 
+    make_cache.leaf_seq_axes = {"z": 1}
     return step_fn, make_cache
 
 
@@ -76,6 +77,7 @@ def slow_chain_model(work=320):
         return {"z": jnp.zeros((n_rows, seq_len), "float32"),
                 "w": jnp.zeros((work, work), "float32")}
 
+    make_cache.leaf_seq_axes = {"z": 1, "w": -1}
     return step_fn, make_cache
 
 
@@ -161,6 +163,27 @@ def test_default_len_ladder_shape():
     assert default_len_ladder(6) == [6]
     with pytest.raises(ValueError):
         default_len_ladder(0)
+
+
+def test_a_make_cache_that_declares_no_leaf_axes_is_refused():
+    """Nothing reads a leaf's sequence axis off its shape: a
+    ``make_cache`` without ``leaf_seq_axes`` is refused at construction,
+    by the pool and by the server, in words that name the attribute."""
+    step_fn, make_cache = chain_model()
+
+    def undeclared(n_rows, seq_len):
+        return make_cache(n_rows, seq_len)
+
+    with pytest.raises(ValueError, match="make_cache.leaf_seq_axes"):
+        KVSlotPool(step_fn, undeclared, eos_id=EOS, max_slots=4,
+                   max_seq_len=32, steps=2)
+    with pytest.raises(ValueError, match="make_cache.leaf_seq_axes"):
+        DecodeServer(step_fn, undeclared, eos_id=EOS, max_seq_len=16,
+                     max_slots=2)
+    undeclared.leaf_seq_axes = {"z": 1, "extra": 1}
+    with pytest.raises(ValueError, match="declares 2 leaves"):
+        KVSlotPool(step_fn, undeclared, eos_id=EOS, max_slots=4,
+                   max_seq_len=32, steps=2)
 
 
 def test_pool_alloc_resize_and_rungs():

@@ -41,8 +41,10 @@ def _kernel(q, kn, vn, kc, vc, ts, block=BLOCK):
 
 
 def _masked(q, kn, vn, kc, vc, ts):
-    return da.masked_decode_attention(q, kn, vn, kc, vc, ts, n_head=H,
-                                      scale=SCALE)
+    ctx, kv = da.grouped_masked_decode_attention(
+        q, kn, vn, {"k": kc, "v": vc}, ts, n_head=H, n_kv_head=H,
+        scale=SCALE)
+    return ctx, kv["k"], kv["v"]
 
 
 def _float64_reference(q, kn, vn, kc, vc, ts):
@@ -139,6 +141,50 @@ def test_one_step_writes_one_row_per_active_slot(impl):
             if t >= 0:
                 want[n, t] = row[n]
         assert np.array_equal(new, want)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("rep", [1, 2])
+def test_k_fresh_rows_equal_k_appends_of_one_row(rep, dtype):
+    """The XLA form at K rows per slot is K calls at one row: the same
+    leaves bit for bit (an append is a copy, or the same quantization of
+    the same row) and the same context to fp32 rounding of sums ordered
+    differently (bf16 products: their rounding is the same on both
+    sides), for grouped heads and every storage dtype."""
+    import jax.numpy as jnp
+
+    K, n_kv = 3, H
+    n_head = n_kv * rep
+    rng = np.random.RandomState(3)
+    ts = jnp.asarray([0, 7, -1, T - K, T - 1, 12], jnp.int32)
+    q = jnp.asarray(rng.randn(S, K, n_head * DH), jnp.float32)
+    kn, vn = (jnp.asarray(rng.randn(S, K, D), jnp.float32)
+              for _ in range(2))
+    kv = da.kv_leaves(S, T, n_kv, DH, dtype)
+    _, kv = da.grouped_masked_decode_attention(   # something to read
+        q[:, 0], kn[:, 0] * 0.5, vn[:, 0] * 0.5, kv,
+        jnp.maximum(ts - 1, -1), n_head=n_head, n_kv_head=n_kv,
+        scale=SCALE)
+    wide, wide_kv = da.grouped_masked_decode_attention(
+        q, kn, vn, kv, ts, n_head=n_head, n_kv_head=n_kv, scale=SCALE)
+    assert wide.shape == q.shape and wide.dtype == jnp.float32
+    seq_kv, in_range = kv, np.asarray(ts)[:, None] + np.arange(K) < T
+    for j in range(K):
+        at = jnp.where((ts >= 0) & (ts + j < T), ts + j, -1)
+        one, seq_kv = da.grouped_masked_decode_attention(
+            q[:, j], kn[:, j], vn[:, j], seq_kv, at, n_head=n_head,
+            n_kv_head=n_kv, scale=SCALE)
+        rows = in_range[:, j]          # slot 4's rows past T: dropped
+        np.testing.assert_allclose(np.asarray(wide[:, j])[rows],
+                                   np.asarray(one)[rows], rtol=0,
+                                   atol=ATOL)
+    assert sorted(wide_kv) == sorted(kv)
+    for name in kv:
+        assert wide_kv[name].dtype == kv[name].dtype
+        assert np.array_equal(np.asarray(wide_kv[name], np.float32),
+                              np.asarray(seq_kv[name], np.float32))
+        assert np.array_equal(np.asarray(wide_kv[name][2], np.float32),
+                              np.asarray(kv[name][2], np.float32))
 
 
 def test_consecutive_steps_through_the_kernel():
@@ -330,10 +376,10 @@ def test_hybrid_ssm_layer_compiles_for_v5e_at_falcon_h1_widths(one_chip):
         mix, ssm, conv = hs.mamba2_step(x, w, p, ssm, conv, ts, d)
         q = hs.linear(x, w[p + "attn_q"])
         k = hs.linear(x, w[p + "attn_k"])
-        ctx, kc, vc = da.grouped_masked_decode_attention(
-            q, k, hs.linear(x, w[p + "attn_v"]), kc, vc, ts,
+        ctx, kv = da.grouped_masked_decode_attention(
+            q, k, hs.linear(x, w[p + "attn_v"]), {"k": kc, "v": vc}, ts,
             n_head=d.n_head, n_kv_head=d.n_kv_head, scale=0.088)
-        return mix, ctx, ssm, conv, kc, vc
+        return mix, ctx, ssm, conv, kv["k"], kv["v"]
 
     ssm = (s, d.ssm_heads, d.ssm_head_dim, d.d_state)
     compiled = jax.jit(f, donate_argnums=(1, 2, 3, 4)).lower(

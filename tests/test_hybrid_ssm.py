@@ -214,23 +214,25 @@ def test_a_reused_slot_serves_as_a_virgin_pool_does(reset, monkeypatch):
 
 @pytest.mark.parametrize("rung_equals", ["mamba_d_state", "head_dim"])
 def test_a_rung_equal_to_a_state_width_is_an_ordinary_rung(rung_equals):
-    """Length rung 16 == d_state (and, in the other case, == head_dim):
-    the shape guess takes the SSM leaf [slots, heads, d_head, d_state]
-    for a KV leaf; the declaration does not, and resize / extract_kv /
-    a further admit leave recurrent leaves alone."""
+    """Length rung 16 == d_state (and, in the other case, == head_dim;
+    mamba_d_head is 16 in both): the SSM leaf [slots, heads, d_head,
+    d_state] has axes as long as the rung, and nothing reads a leaf's
+    kind off its shape — the builder declares it, a ``make_cache`` that
+    declares nothing is refused, and resize / extract_kv / a further
+    admit leave recurrent leaves alone."""
     cfg = tiny_cfg(d_state=16 if rung_equals == "mamba_d_state" else 8,
                    head_dim=16 if rung_equals == "head_dim" else 8)
     assert cfg[rung_equals] == 16
     w = weights(cfg, seed=4)
     pool, make_cache = _pool(cfg, w, [16, 32])
     d = hs.dims(cfg)
-    ssm_shape = (2, d.ssm_heads, d.ssm_head_dim, d.d_state)
-    # mamba_d_head is 16 in both cases, so the guess is wrong in both
-    assert decoding.kv_leaf_seq_axis(ssm_shape, 2, 16) is not None
     import jax
 
     leaves = jax.tree.leaves(jax.eval_shape(lambda: make_cache(2, 16)))
-    axes = decoding.cache_leaf_seq_axes(make_cache, leaves, 2, 16)
+    assert leaves[2].shape == (2, d.ssm_heads, 16, d.d_state)
+    with pytest.raises(ValueError, match="leaf_seq_axes"):
+        decoding.cache_leaf_seq_axes(lambda s, t: make_cache(s, t), leaves)
+    axes = decoding.cache_leaf_seq_axes(make_cache, leaves)
     assert axes == [None, 1, None, 1] * d.n_layer   # conv, k, ssm, v
     assert len(pool.recurrent_leaves) == 2 * d.n_layer
 

@@ -264,8 +264,16 @@ def test_pipeline_predictor_exact_vs_unpipelined(lm_dirs):
     x = _ids(4, seed=7)
     out_p, = pipe.run({"src_ids": x})
     out_r, = ref.run({"src_ids": x})
-    # same ops, same params, same order — GPipe staging must be EXACT
-    assert np.abs(np.asarray(out_p) - np.asarray(out_r)).max() == 0.0
+    # same ops, same params, same op order — but not the same program:
+    # the stages run one-row microbatches where the reference runs the
+    # four rows at once, and executables compiled for different batch
+    # shapes may order a reduction's sum differently.  That is a few
+    # fp32 ulps (1.2e-7 at 1.0) on the O(1) outputs of a two-layer LM —
+    # 1.2e-6 is what this CPU shows; 1e-5 absolute holds it with room
+    # and is still far below any staging fault (a wrong cut, a stale or
+    # swapped microbatch moves outputs by O(0.1)).
+    np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
+                               rtol=0, atol=1e-5)
 
     st = pipe.pipeline_stats()
     assert st["n_stages"] == 2 and st["microbatches_last"] == 4
@@ -324,7 +332,11 @@ def test_pipeline_child_process_advertises_group(lm_dirs):
 def test_kv_pool_len_multiple_rounds_rungs():
     from paddle_tpu.serving.kv_pool import KVSlotPool
 
-    pool = KVSlotPool(lambda *a: None, lambda *a: None, eos_id=0,
+    def make_cache(n_rows, seq_len):
+        return None
+
+    make_cache.leaf_seq_axes = ()   # a cache with no leaves declares none
+    pool = KVSlotPool(lambda *a: None, make_cache, eos_id=0,
                       max_slots=2, max_seq_len=50, len_multiple=4)
     rungs = list(pool.len_policy.ladder)
     assert all(r % 4 == 0 for r in rungs), rungs

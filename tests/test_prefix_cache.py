@@ -290,6 +290,59 @@ def test_speculative_parity_and_telemetry(lm_state, draft_state):
         srv.stop(drain=False)
 
 
+@pytest.mark.parametrize("kv", ["fp32", "int8"])
+def test_verify_at_k_rows_equals_k_sequential_steps(lm_state, kv):
+    """``verify_fn`` is the pooled step at K fresh rows per slot: its
+    logits, and the cache it leaves, are those of K sequential steps —
+    rows at staggered positions, an idle row untouched, a row whose
+    last positions fall past the cache dropped.
+
+    Tolerance: the K-wide and the one-row programs are different
+    executables and may order a sum differently — a few fp32 ulps on
+    O(1) values, 1e-5 absolute; an int8 code computed from such a value
+    may land one step away."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.decoding import make_transformer_lm_pooled_verify_fn
+
+    args = (lm_state, V, LM["d_model"], LM["n_layer"], LM["n_head"],
+            LM["d_inner"])
+    step_fn, make_cache = make_transformer_lm_pooled_step_fn(
+        *args, kv_dtype=kv)
+    verify_fn = make_transformer_lm_pooled_verify_fn(*args, kv_dtype=kv)
+    S, T, K = 4, 16, 3
+    rng = np.random.RandomState(5)
+    toks = rng.randint(0, V, (S, K)).astype(np.int32)
+    ts = np.array([0, 5, -1, T - 2], np.int32)   # row 3: its last row past T
+    step, verify = jax.jit(step_fn), jax.jit(verify_fn)
+    # a cache the slots have written themselves up to ts (write-before-read)
+    cache = make_cache(S, T)
+    for t in range(int(ts.max())):
+        live = np.where(t < ts, t, -1).astype(np.int32)
+        _, cache = step(cache, rng.randint(0, V, S).astype(np.int32), live)
+    wide, wide_cache = verify(cache, toks, ts)
+    seq, seq_cache = [], cache
+    for j in range(K):
+        at = np.where((ts >= 0) & (ts + j < T), ts + j, -1).astype(np.int32)
+        lg, seq_cache = step(seq_cache, toks[:, j], at)
+        seq.append(np.asarray(lg))
+    seq = np.stack(seq, axis=1)
+    valid = (ts[:, None] >= 0) & (ts[:, None] + np.arange(K)[None] < T)
+    assert valid.sum() == 8
+    np.testing.assert_allclose(np.asarray(wide)[valid], seq[valid],
+                               rtol=0, atol=1e-5)
+    assert np.array_equal(np.asarray(wide).argmax(-1)[valid],
+                          seq.argmax(-1)[valid])
+    for a, b, c in zip(jax.tree.leaves(wide_cache),
+                       jax.tree.leaves(seq_cache), jax.tree.leaves(cache)):
+        a, b, c = (np.asarray(x).astype(np.float32) for x in (a, b, c))
+        atol = 1.0 if kv == "int8" and a.shape[-1] == LM["d_model"] else 1e-5
+        np.testing.assert_allclose(a, b, rtol=0, atol=atol)
+        assert np.array_equal(a[2], c[2])          # the idle row
+        assert not np.array_equal(a[1], c[1])      # a live one
+
+
 def test_speculative_submit_without_draft_raises_typed():
     state = random_transformer_lm_state(np.random.RandomState(1), **LM)
     step_fn, make_cache = make_transformer_lm_pooled_step_fn(
