@@ -632,9 +632,11 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
     every active slot by up to ``steps`` tokens in ONE device dispatch
     (a ``fori_loop`` — multi-step dispatch amortizes host overhead
     between scheduler interventions), ``admit(state, slot_mask,
-    prompt, prompt_len, total_len) -> state`` seating one request into a
-    free slot, and ``release(state, slot_mask) -> state`` deactivating
-    slots mid-flight (deadline abort) so their lanes stop advancing.
+    prompts, prompt_len, total_len) -> state`` seating a SET of requests
+    into free slots at once — everything a scheduler turn admits rides
+    one dispatch — and ``release(state, slot_mask) -> state``
+    deactivating slots mid-flight (deadline abort) so their lanes stop
+    advancing.
 
     The pool state is a dict pytree (every leaf's axis 0 is the slot):
 
@@ -671,8 +673,19 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
     ``state["draft_cache"]`` position-synced with the target — a slot
     that alternates plain and speculative rounds never sees a stale
     draft cache (write-before-read covers the rest).  ``admit`` grows an
-    optional trailing ``spec_flag`` scalar marking the seated slot
+    optional trailing ``spec_flag`` marking which of the seated slots are
     speculative.
+
+    ``admit``'s arguments are indexed BY SLOT: ``slot_mask`` [S] bool
+    (the slots being seated), ``prompts`` [S, T] int32 (row ``i`` is slot
+    ``i``'s padded prompt; rows outside the mask are ignored),
+    ``prompt_len`` / ``total_len`` / ``spec_flag`` [S].  Slot-indexed
+    rows keep the argument shapes a function of the rung pair alone, so
+    a batch of one and a batch of S are the SAME executable and no count
+    of admissions is ever a compiled shape.  Each argument also
+    broadcasts from one request's form (``prompts`` [T], scalar
+    lengths), which is how :func:`make_prefix_admit_fn` seats its single
+    request through the same function.
     """
     import jax
     import jax.numpy as jnp
@@ -713,20 +726,21 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
     def chunk(state):
         return jax.lax.fori_loop(0, steps, _body, state)
 
-    def admit(state, slot_mask, prompt, prompt_len, total_len,
+    def admit(state, slot_mask, prompts, prompt_len, total_len,
               spec_flag=None):
-        # slot_mask [S] bool (one admitted slot), prompt [T] int32
-        # (padded host-side), prompt_len/total_len () int32 scalars.
-        # The cache passes through UNTOUCHED: the write-before-read
-        # invariant (see make_transformer_lm_pooled_step_fn) makes
-        # zeroing a reused slot's K/V rows unnecessary, and a step with
-        # recurrent leaves reads them as zero at the ``pos = 0`` set
-        # here (make_hybrid_ssm_lm_pooled_step_fn).
+        # slot_mask [S] bool (every slot seated by this call), prompts
+        # [S, T] int32 (padded host-side, indexed by slot), prompt_len /
+        # total_len / spec_flag [S]; one request's [T] and scalars
+        # broadcast.  The cache passes through UNTOUCHED: the
+        # write-before-read invariant (see
+        # make_transformer_lm_pooled_step_fn) makes zeroing a reused
+        # slot's K/V rows unnecessary, and a step with recurrent leaves
+        # reads them as zero at the ``pos = 0`` set here
+        # (make_hybrid_ssm_lm_pooled_step_fn).
         mask = slot_mask
         out = dict(state)
         out.update(
-            tokens=jnp.where(mask[:, None], prompt[None, :],
-                             state["tokens"]),
+            tokens=jnp.where(mask[:, None], prompts, state["tokens"]),
             pos=jnp.where(mask, 0, state["pos"]),
             prompt_len=jnp.where(mask, prompt_len, state["prompt_len"]),
             total_len=jnp.where(mask, total_len, state["total_len"]),

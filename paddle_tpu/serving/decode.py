@@ -9,7 +9,7 @@ request-at-a-time regime with vLLM-style CONTINUOUS batching:
 * one fused decode step per tick over a persistent **slot pool**
   (``serving.kv_pool.KVSlotPool``): finished sequences (EOS or length
   cap) free their slots mid-flight, and queued prompts join the running
-  batch at the next tick;
+  batch at the next tick, all of a turn's in ONE admit dispatch;
 * **prefill and decode are the same step** — a freshly admitted prompt
   teacher-forces its stored tokens through the shared step fn, filling
   its KV cache inside the running batch (no separate prefill
@@ -37,8 +37,10 @@ Observability: ``serving_decode_*`` metrics (generated/prefill token
 counters, tick counter, TTFT histogram, slot-occupancy gauge; the pool's
 bytes as two gauges, ``serving_kv_cache_bytes`` for leaves with a
 sequence axis and ``serving_recurrent_state_bytes`` for those without,
-and ``serving_decode_state_resets_total``, one per admission into a pool
-that has recurrent leaves) on top of
+``serving_decode_state_resets_total``, one per admission into a pool
+that has recurrent leaves, and ``serving_decode_admitted_total`` over
+``serving_decode_admit_dispatches_total``, the requests one admit
+dispatch carried: a turn seats everything it pops in one) on top of
 the standard ``ServingMetrics`` series; per-tick ``serving/decode_tick``
 spans carry the active requests' trace ids; the ``decode.step`` fault
 point injects failures into the tick dispatch for chaos coverage.
@@ -138,6 +140,16 @@ DECODE_STATE_RESETS = monitor.counter(
     "slots whose recurrent state (cache leaves with no sequence axis: "
     "SSM and conv state) was started from zero — one per admission into "
     "a pool that has such leaves, none otherwise", _LABELS)
+DECODE_ADMIT_DISPATCHES = monitor.counter(
+    "serving_decode_admit_dispatches_total",
+    "device dispatches that seated requests into pool slots: one per "
+    "scheduler turn that admits, plus one per request seated over a "
+    "retained prefix (admit_prefix)", _LABELS)
+DECODE_ADMITTED = monitor.counter(
+    "serving_decode_admitted_total",
+    "requests seated into pool slots; over "
+    "serving_decode_admit_dispatches_total it is the requests one "
+    "admit dispatch carried", _LABELS)
 DECODE_RECURRENT_BYTES = monitor.gauge(
     "serving_recurrent_state_bytes",
     "bytes of the pool's recurrent cache leaves (no sequence axis) at "
@@ -299,6 +311,8 @@ class DecodeServer:
         self._kv_bytes_g = DECODE_KV_BYTES.labels(**lbl)
         self._state_resets_c = DECODE_STATE_RESETS.labels(**lbl)
         self._recurrent_bytes_g = DECODE_RECURRENT_BYTES.labels(**lbl)
+        self._admit_dispatches_c = DECODE_ADMIT_DISPATCHES.labels(**lbl)
+        self._admitted_c = DECODE_ADMITTED.labels(**lbl)
         # decode tier 2, each independently toggleable: ``prefix_cache``
         # (a PrefixKVCache, or a byte budget to own one) retains freed
         # slots' prefix KV for shared-prefix admission; ``speculative``
@@ -411,6 +425,8 @@ class DecodeServer:
             "kv_cache_bytes": int(self._kv_bytes_g.value),
             "recurrent_state_bytes": int(self._recurrent_bytes_g.value),
             "state_resets": int(self._state_resets_c.value),
+            "admit_dispatches": int(self._admit_dispatches_c.value),
+            "admitted": int(self._admitted_c.value),
             "seq_len_histogram": {
                 str(k): v
                 for k, v in sorted(self.seq_len_histogram().items())},
@@ -642,95 +658,119 @@ class DecodeServer:
             self._fail_stragglers()
 
     def _admit_pending(self) -> None:
-        """Seat queued prompts into free slots, growing the slot/length
-        rungs as needed (the bucket ladders bound the shapes, so growth
-        lands on a warmed executable — never a compile)."""
+        """Seat queued prompts into free slots with ONE device dispatch
+        for the whole turn.
+
+        The turn is planned on the host first: requests are popped while
+        a slot is free (or the slot ladder can still grow) and the queue
+        has work — FIFO, each to the lowest free slot, an expired one
+        failed at the pop — and the rung pair the whole batch needs is
+        worked out as the pops go.  Then the pool is resized ONCE (the
+        bucket ladders bound the shapes, so growth lands on a warmed
+        executable — never a compile) and ONE ``pool.admit`` seats every
+        popped request, so the chip waits through one dispatch between
+        two chunks however many slots the last chunk freed.  A request
+        with a prefix-cache hit keeps an ``admit_prefix`` call of its
+        own (it installs KV); one whose installation fails joins the
+        plain batch (degraded, never wrong)."""
         pool = self._pool
-        while True:
-            free = [i for i, s in enumerate(self._slots) if s is None]
-            can_grow = len(self._slots) < pool.max_slots
-            if not free and not can_grow:
-                return
-            with self._batcher.queue.cv:
-                req, expired = self._batcher.queue.pop_locked()
-            for r in expired:
-                self._on_expired(r)
-            if req is None:
-                return
-            if req.expired():
-                self._on_expired(req)
-                continue
-            try:
+        slots = list(self._slots)    # the turn's plan of self._slots
+        cur = (None if self._state is None
+               else pool.state_rungs(self._state))
+        new_s, new_t = cur or (0, 0)
+        popped: List[DecodeRequest] = []
+        seats = []                   # (slot, req, pre_len, pre_kv)
+        try:
+            while None in slots or len(slots) < pool.max_slots:
+                with self._batcher.queue.cv:
+                    req, expired = self._batcher.queue.pop_locked()
+                for r in expired:
+                    self._on_expired(r)
+                if req is None:
+                    break
+                if req.expired():
+                    self._on_expired(req)
+                    continue
+                popped.append(req)
                 pre_len, pre_kv = 0, None
                 if self._prefix is not None:
                     pre_len, pre_kv = self._prefix.probe(req.prompt)
-                need_t = pool.len_policy.bucket_for(req.total_len)
-                if self._state is None:
-                    s0 = pool.slot_policy.bucket_for(1)
-                    self._state = pool.alloc(s0, need_t)
-                    self._slots = [None] * s0
-                    self._set_pool_bytes((s0, need_t))
-                else:
-                    cur_s, cur_t = pool.state_rungs(self._state)
-                    new_t = max(cur_t, need_t)
-                    new_s = cur_s
-                    if not free:
-                        new_s = pool.slot_policy.bucket_for(cur_s + 1)
-                    if (new_s, new_t) != (cur_s, cur_t):
-                        self._state = pool.resize(self._state, new_s, new_t)
-                        self._slots.extend([None] * (new_s - cur_s))
-                        self._set_pool_bytes((new_s, new_t))
-                slot = next(i for i, s in enumerate(self._slots)
-                            if s is None)
-                spec = bool(getattr(req, "speculative", False))
-                if pre_len > 0:
-                    try:
-                        # a corrupted or evicted-mid-admit prefix entry
-                        # must DEGRADE (full prefill), never emit wrong
-                        # tokens: any failure here falls back to the
-                        # plain warmed admit, typed and counted
-                        if _faults.active is not None:  # one is-None gate
-                            _faults.active.faultpoint(
-                                "decode.prefix_admit", server=self.name,
-                                prefix_len=pre_len)
-                        self._state = pool.admit_prefix(
-                            self._state, slot, req.prompt,
-                            len(req.prompt), req.total_len, pre_kv,
-                            pre_len, spec=spec)
-                    except Exception:
-                        self._metrics.count("prefix_fallback")
-                        if self._prefix is not None:
-                            self._prefix.count_fallback()
-                        # degraded-not-wrong is exactly what the event
-                        # ring exists to surface (+ span instant)
-                        _events.emit(
-                            "serving/prefix_fallback", severity="warning",
-                            cat="serving", server=self.name,
-                            prefix_len=pre_len,
-                            trace_id=req.trace_id or None)
-                        pre_len = 0
-                if pre_len == 0:
-                    self._state = pool.admit(
-                        self._state, slot, req.prompt, len(req.prompt),
-                        req.total_len, spec=spec)
-            except BaseException as exc:  # noqa: BLE001 — fail typed,
-                # keep serving (the _tick discipline): the popped
-                # request is in neither the queue nor a slot, so an
-                # escaping exception would strand its caller forever,
-                # and a failure mid-resize may have corrupted the pool
-                req.fail(exc)
-                self._metrics.count("failed")
-                self._fail_in_flight(exc)
-                self._state = None
-                self._slots = []
-                self._set_pool_bytes(None)
+                new_t = max(new_t, pool.len_policy.bucket_for(req.total_len))
+                if None not in slots:
+                    new_s = pool.slot_policy.bucket_for(new_s + 1)
+                    slots.extend([None] * (new_s - len(slots)))
+                slot = slots.index(None)
+                slots[slot] = req
+                seats.append((slot, req, pre_len, pre_kv))
+            if not popped:
                 return
+            if (new_s, new_t) != cur:
+                self._state = (
+                    pool.alloc(new_s, new_t) if cur is None
+                    else pool.resize(self._state, new_s, new_t))
+                self._slots.extend([None] * (new_s - len(self._slots)))
+                self._set_pool_bytes((new_s, new_t))
+            seats = [self._admit_with_prefix(*seat) if seat[2] > 0 else seat
+                     for seat in seats]
+            plain = [(slot, req) for slot, req, pre_len, _ in seats
+                     if pre_len == 0]
+            if plain:
+                at, reqs = zip(*plain)
+                self._state = pool.admit(
+                    self._state, at, [r.prompt for r in reqs],
+                    [len(r.prompt) for r in reqs],
+                    [r.total_len for r in reqs],
+                    spec=[r.speculative for r in reqs])
+                self._admit_dispatches_c.inc()
+        except BaseException as exc:  # noqa: BLE001 — fail typed,
+            # keep serving (the _tick discipline): the popped requests
+            # are in neither the queue nor a slot, so an escaping
+            # exception would strand their callers forever, and a
+            # failure mid-resize may have corrupted the pool
+            for req in popped:
+                req.fail(exc)
+            self._metrics.count("failed", len(popped))
+            self._fail_in_flight(exc)
+            self._state = None
+            self._slots = []
+            self._set_pool_bytes(None)
+            return
+        for slot, req, pre_len, _ in seats:
             self._slots[slot] = _Slot(req, pre_len)
-            if pool.recurrent_leaves:
-                self._state_resets_c.inc()
             # the shared-prefix win, measured where it happens: only the
             # unmatched suffix re-enters prefill
             self._prefill_c.inc(len(req.prompt) - pre_len)
+        self._admitted_c.inc(len(seats))
+        if pool.recurrent_leaves:
+            self._state_resets_c.inc(len(seats))
+
+    def _admit_with_prefix(self, slot, req, pre_len, pre_kv):
+        """Seat ``req`` over its retained prefix KV, in a dispatch of its
+        own; returns its seat, with ``pre_len`` 0 if the installation
+        failed and the request is to take the plain ``admit``."""
+        try:
+            # a corrupted or evicted-mid-admit prefix entry must DEGRADE
+            # (full prefill), never emit wrong tokens: any failure here
+            # falls back to the plain warmed admit, typed and counted
+            if _faults.active is not None:  # one is-None gate
+                _faults.active.faultpoint(
+                    "decode.prefix_admit", server=self.name,
+                    prefix_len=pre_len)
+            self._state = self._pool.admit_prefix(
+                self._state, slot, req.prompt, len(req.prompt),
+                req.total_len, pre_kv, pre_len, spec=req.speculative)
+            self._admit_dispatches_c.inc()
+        except Exception:
+            self._metrics.count("prefix_fallback")
+            self._prefix.count_fallback()
+            # degraded-not-wrong is exactly what the event ring exists
+            # to surface (+ span instant)
+            _events.emit(
+                "serving/prefix_fallback", severity="warning",
+                cat="serving", server=self.name, prefix_len=pre_len,
+                trace_id=req.trace_id or None)
+            pre_len = 0
+        return slot, req, pre_len, None
 
     def _set_pool_bytes(self, rungs) -> None:
         """The two byte gauges for the state's rung pair (None: the pool
@@ -933,7 +973,8 @@ class DecodeServer:
         for metric in (DECODE_TOKENS, DECODE_PREFILL_TOKENS, DECODE_TICKS,
                        DECODE_KV_READ, DECODE_KV_POOL,
                        DECODE_TTFT, DECODE_OCCUPANCY, DECODE_KV_BYTES,
-                       DECODE_STATE_RESETS, DECODE_RECURRENT_BYTES):
+                       DECODE_STATE_RESETS, DECODE_RECURRENT_BYTES,
+                       DECODE_ADMIT_DISPATCHES, DECODE_ADMITTED):
             metric.remove_labels(**lbl)
         if self._speculative is not None:
             for metric in (SPEC_PROPOSED, SPEC_ACCEPTED):

@@ -7,9 +7,10 @@ is shaped by (slot count, cache length), so the pool quantizes both
 onto ladders — ``slot_ladder`` rungs over batch slots x ``len_ladder``
 rungs over sequence length — and AOT-compiles the two pure functions
 the scheduler dispatches (``decoding.make_slot_decode_fns``: the
-multi-step ``chunk`` and the seat-one-request ``admit``/``release``)
-for every rung pair at :meth:`warmup`.  After warmup, a mixed
-prompt/decode storm runs entirely on warmed executables — the pool's
+multi-step ``chunk``, the ``admit`` that seats everything a scheduler
+turn admits in one dispatch, and ``release``) for every rung pair at
+:meth:`warmup`.  After warmup, a mixed prompt/decode storm runs
+entirely on warmed executables — the pool's
 :meth:`jit_cache_stats` is the recompile ground truth the serving
 ``/statusz`` reports, exactly like ``AnalysisPredictor`` on the
 request-batching path.
@@ -147,9 +148,9 @@ class KVSlotPool:
             step_fn, self.eos_id, self.steps,
             draft_step_fn=(speculative.draft_step_fn
                            if speculative is not None else None))
-        self._chunk_fn, self._admit_fn, self._release_fn = self._fns
+        self._chunk_fn, self._seat_fn, self._release_fn = self._fns
         self._admit_prefix_fn = (
-            make_prefix_admit_fn(self._admit_fn, self._kv_seq_axes)
+            make_prefix_admit_fn(self._seat_fn, self._kv_seq_axes)
             if self.prefix else None)
         if speculative is not None:
             from paddle_tpu.serving.speculative import make_spec_chunk_fn
@@ -352,6 +353,9 @@ class KVSlotPool:
         mask = jax.ShapeDtypeStruct((s,), np.dtype(bool))
         if kind == "release":
             return self._lower(kind, spec, mask)
+        if kind == "admit":
+            return self._lower(kind, spec, jax.ShapeDtypeStruct(
+                (s, t + self._seat_columns()), i32))
         prompt = jax.ShapeDtypeStruct((t,), i32)
         scalar = jax.ShapeDtypeStruct((), i32)
         args = [spec, mask, prompt, scalar, scalar]
@@ -449,37 +453,74 @@ class KVSlotPool:
         # hot-path: end kv_chunk
         return out
 
-    def admit(self, state, slot: int, prompt: np.ndarray,
-              prompt_len: int, total_len: int,
-              spec: bool = False) -> Dict[str, object]:
-        """Seat one request into free slot ``slot``: the prompt is
-        padded host-side to the state's length rung and the slot's
-        flags/cursors reset in ONE device dispatch (the cache passes
+    def admit(self, state, slot, prompt, prompt_len, total_len,
+              spec=False) -> Dict[str, object]:
+        """Seat requests into free slots in ONE device dispatch: one
+        request (``slot`` an int, ``prompt`` its tokens, two ints and a
+        bool) or a scheduler turn's whole batch (``slot`` a sequence of
+        distinct slots and every other argument a sequence beside it;
+        ``spec`` may stay one bool for all).  Either way it is the same
+        warmed executable: the seats travel as ONE int32 array indexed by
+        slot (:meth:`_pack_seats`: one h2d transfer whatever the batch),
+        whose shape follows the rung pair and not the number seated.
+
+        Each prompt is padded host-side to the state's length rung and
+        the seated slots' flags and cursors are reset; the cache passes
         through untouched — write-before-read makes zeroing a reused
-        slot's K/V rows unnecessary, and the step starts a recurrent
-        leaf from zero at the position 0 every admit seats).  ``spec`` marks the slot for speculative
-        rounds (ignored unless the pool was built with a
-        SpeculativeConfig)."""
+        slot's K/V rows unnecessary, and the step starts a recurrent leaf
+        from zero at the position 0 every admit seats.  ``spec`` marks a
+        slot for speculative rounds (ignored unless the pool was built
+        with a SpeculativeConfig)."""
+        if np.ndim(slot) == 0:
+            slot, prompt = [slot], [prompt]
+            prompt_len, total_len = [prompt_len], [total_len]
+        if np.ndim(spec) == 0:
+            spec = [spec] * len(slot)
         s, t = self.state_rungs(state)
-        mask, buf = self._admit_host_args(s, t, slot, prompt)
+        seats = self._pack_seats(s, t, slot, prompt, prompt_len,
+                                 total_len, spec)
         # hot-path: begin kv_admit (executable lookup + async dispatch)
         exe = self._get_exe("admit", s, t)
-        args = [state, mask, buf,
-                np.asarray(prompt_len, np.int32),  # hot-ok: host scalar
-                np.asarray(total_len, np.int32)]  # hot-ok: host scalar
-        if self.speculative is not None:
-            args.append(np.asarray(bool(spec)))  # hot-ok: host scalar
-        out = exe(*args)
+        out = exe(state, seats)
         # hot-path: end kv_admit
         return out
 
-    def _admit_host_args(self, s: int, t: int, slot: int, prompt):
-        mask = np.zeros((s,), bool)
-        mask[slot] = True
-        buf = np.zeros((t,), np.int32)
-        n = min(len(prompt), t)
-        buf[:n] = np.asarray(prompt[:n], np.int32)
-        return mask, buf
+    # the columns of a seats array after a row's T prompt tokens
+    _SEATED, _PROMPT_LEN, _TOTAL_LEN, _SPEC = range(4)
+
+    def _seat_columns(self) -> int:
+        return 4 if self.speculative is not None else 3
+
+    def _pack_seats(self, s: int, t: int, slots, prompts, prompt_lens,
+                    total_lens, specs) -> np.ndarray:
+        """The host half of :meth:`admit`: every argument of the pure
+        ``admit`` in one int32 ``[s, t + columns]`` array — row ``i`` is
+        slot ``i``'s padded prompt, then whether it is seated, its two
+        lengths and (speculative pools) its flag; rows of slots not
+        seated stay zero."""
+        seats = np.zeros((s, t + self._seat_columns()), np.int32)
+        for slot, prompt, p_len, tot, spec in zip(
+                slots, prompts, prompt_lens, total_lens, specs):
+            n = min(len(prompt), t)
+            row = seats[slot]
+            row[:n] = prompt[:n]
+            row[t + self._SEATED] = 1
+            row[t + self._PROMPT_LEN] = p_len
+            row[t + self._TOTAL_LEN] = tot
+            if self.speculative is not None:
+                row[t + self._SPEC] = bool(spec)
+        return seats
+
+    def _admit_fn(self, state, seats):
+        """The traced half: unpack :meth:`_pack_seats`' array into the
+        pure ``admit``'s slot-indexed arguments."""
+        t = state["tokens"].shape[1]
+        args = [seats[:, t + self._SEATED] != 0, seats[:, :t],
+                seats[:, t + self._PROMPT_LEN],
+                seats[:, t + self._TOTAL_LEN]]
+        if self.speculative is not None:
+            args.append(seats[:, t + self._SPEC] != 0)
+        return self._seat_fn(state, *args)
 
     def admit_prefix(self, state, slot: int, prompt: np.ndarray,
                      prompt_len: int, total_len: int,
@@ -497,11 +538,15 @@ class KVSlotPool:
                 "pool was built without prefix=True — admit_prefix has "
                 "no warmed executable")
         s, t = self.state_rungs(state)
-        mask, buf = self._admit_host_args(s, t, slot, prompt)
-        spec = self._state_spec(s, t)
+        mask = np.zeros((s,), bool)
+        mask[slot] = True
+        buf = np.zeros((t,), np.int32)
+        n = min(len(prompt), t)
+        buf[:n] = prompt[:n]
+        shapes = self._state_spec(s, t)  # not ``spec``: that is the flag
         kv = []
-        for sd, ent, ax in zip(self._kv_subtree_leaves(spec), kv_leaves,
-                               self._kv_seq_axes(spec)):
+        for sd, ent, ax in zip(self._kv_subtree_leaves(shapes), kv_leaves,
+                               self._kv_seq_axes(shapes)):
             if ax is None or ent is None:
                 kv.append(np.zeros((1,), np.float32))
                 continue

@@ -40,3 +40,32 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if "slow" in item.keywords:
             item.add_marker(skip)
+
+
+@pytest.fixture
+def check_batch_admit():
+    """``check(pool, state, seats)``: ONE ``pool.admit`` of ``seats`` —
+    ``(slot, prompt, total_len, spec)`` each — must leave the pool state
+    leaf-for-leaf what admitting them one call at a time, in the same
+    order, leaves; returns that state."""
+    import jax
+    import numpy as np
+
+    def check(pool, state, seats):
+        one = state
+        for slot, prompt, total, spec in seats:
+            one = pool.admit(one, slot, prompt, len(prompt), total,
+                             spec=spec)
+        many = pool.admit(
+            state, [s[0] for s in seats], [s[1] for s in seats],
+            [len(s[1]) for s in seats], [s[2] for s in seats],
+            spec=[s[3] for s in seats])
+        assert jax.tree.structure(one) == jax.tree.structure(many)
+        for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(one),
+                                jax.tree.leaves(many)):
+            a, b = np.asarray(a), np.asarray(b)
+            assert a.dtype == b.dtype and np.array_equal(a, b), (
+                jax.tree_util.keystr(path))
+        return many
+
+    return check

@@ -9,6 +9,7 @@ transformer-LM (random weights) proves NUMERIC parity of the slot-pool
 path against the scalar cached step fn, and backs the 2-child wire
 fleet acceptance run.
 """
+import contextlib
 import threading
 import time
 
@@ -224,6 +225,35 @@ def test_pool_warmup_covers_every_rung_pair_then_zero_misses():
     stats = pool.jit_cache_stats()
     assert stats["misses"] == 0 and not recompiles
     assert stats["hits"] >= len(pool.rung_pairs()) * 3
+
+
+@pytest.mark.parametrize("kv", ["fp32", "int8"])
+def test_batch_admit_equals_single_admits_in_order(lm_state, kv,
+                                                   check_batch_admit):
+    """ONE admit call over k seats == k admit calls, leaf for leaf —
+    beside a live row, into a reused slot, prompts of unequal length —
+    and it is the same executable either way: nothing compiles."""
+    step_fn, make_cache = make_transformer_lm_pooled_step_fn(
+        lm_state, LM_DIMS["vocab"], LM_DIMS["d_model"], LM_DIMS["n_layer"],
+        LM_DIMS["n_head"], LM_DIMS["d_inner"], kv_dtype=kv)
+    pool = KVSlotPool(step_fn, make_cache, eos_id=EOS, max_slots=4,
+                      max_seq_len=16, slot_ladder=[4], len_ladder=[16],
+                      steps=2, kv_dtype=kv)
+    assert pool.warmup() == 3  # chunk + admit + release: no fourth kind
+    rng = np.random.RandomState(11)
+    st = pool.alloc(4, 16)
+    st = pool.admit(st, 1, rng.randint(2, V, 4).astype(np.int32), 4, 12)
+    st = pool.admit(st, 2, rng.randint(2, V, 2).astype(np.int32), 2, 4)
+    for _ in range(3):  # slot 1 is mid-flight, slot 2 finished: reusable
+        st = pool.chunk(st)
+    seats = [(0, rng.randint(2, V, 5).astype(np.int32), 14, False),
+             (2, rng.randint(2, V, 1).astype(np.int32), 9, False),
+             (3, rng.randint(2, V, 7).astype(np.int32), 16, False)]
+    st = check_batch_admit(pool, st, seats)
+    assert np.asarray(st["active"]).tolist() == [True] * 4
+    assert np.asarray(st["pos"])[[0, 2, 3]].tolist() == [0, 0, 0]
+    assert np.asarray(st["total_len"]).tolist() == [14, 12, 9, 16]
+    assert pool.jit_cache_stats()["misses"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -573,6 +603,191 @@ def test_kv_position_counters_follow_live_blocks():
         share = d["kv_positions_read"] / d["kv_positions_pool"]
         assert share == pytest.approx(want / (d["ticks"] * S * T * steps))
         assert 0.0 < share < 0.5
+    finally:
+        srv.stop(drain=False)
+
+
+# ---------------------------------------------------------------------------
+# admission: one dispatch a scheduler turn
+# ---------------------------------------------------------------------------
+@contextlib.contextmanager
+def turn_held(srv):
+    """Park the scheduler thread at the top of a turn until the block
+    exits: everything submitted inside is queued before ONE
+    ``_admit_pending`` sees any of it.  Yields ``run(n)``, which lets
+    ``n`` whole turns (admission, then a tick if anything is seated) go
+    and returns with the thread parked again."""
+    parked, permits = threading.Semaphore(0), threading.Semaphore(0)
+    real = srv._admit_pending
+
+    def gated():
+        parked.release()
+        permits.acquire(timeout=30.0)
+        real()
+
+    def run(n=1):
+        for _ in range(n):
+            permits.release()
+            assert parked.acquire(timeout=30.0)
+
+    srv._admit_pending = gated
+    srv._batcher.wake()
+    assert parked.acquire(timeout=30.0)
+    try:
+        yield run
+    finally:
+        del srv._admit_pending  # the next turn is the class's again
+        permits.release()
+
+
+def _admits(srv):
+    d = srv.metrics()["decode"]
+    return d["admit_dispatches"], d["admitted"]
+
+
+def test_turn_seats_every_queued_request_in_one_dispatch(lm_state):
+    """k requests queued before a turn: ONE admit dispatch seats them
+    (lowest free slots, FIFO), and what they are served is token for
+    token what request-at-a-time serving gives — k dispatches — and what
+    the scalar step gives."""
+    step_fn, make_cache = make_transformer_lm_pooled_step_fn(
+        lm_state, LM_DIMS["vocab"], LM_DIMS["d_model"], LM_DIMS["n_layer"],
+        LM_DIMS["n_head"], LM_DIMS["d_inner"])
+    srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=32,
+                       max_slots=4, slot_ladder=[1, 2, 4],
+                       len_ladder=[16, 32], steps_per_tick=3, name="lm-turn")
+    srv.warmup(configure_cache=False)
+    try:
+        prompts = [[2, 3, 4], [5], [7, 8], [11, 12, 13, 14]]
+        caps = [10, 6, 12, 8]
+
+        def submit(p, c):
+            return srv.submit({"tokens": np.array(p, np.int32)},
+                              max_new_tokens=c)
+
+        d0, n0 = _admits(srv)
+        alone = [submit(p, c).result(timeout=60.0)[0].tolist()
+                 for p, c in zip(prompts, caps)]
+        d1, n1 = _admits(srv)
+        assert (d1 - d0, n1 - n0) == (4, 4)  # one at a time: one each
+        with turn_held(srv):
+            reqs = [submit(p, c) for p, c in zip(prompts, caps)]
+        together = [r.result(timeout=60.0)[0].tolist() for r in reqs]
+        d2, n2 = _admits(srv)
+        assert (d2 - d1, n2 - n1) == (1, 4)  # one turn: one dispatch
+        assert together == alone
+        for p, c, got in zip(prompts, caps, together):
+            assert got == _ref_continuation(lm_state, p, len(p) + c), p
+        for name in ("serving_decode_admit_dispatches_total",
+                     "serving_decode_admitted_total"):
+            assert monitor.counter_value(name, server="lm-turn") > 0
+        assert srv._pool.jit_cache_stats()["misses"] == 0
+    finally:
+        srv.stop(drain=False)
+
+
+def test_turn_seats_fifo_into_lowest_free_slots_and_stops_when_full():
+    """The turn pops exactly what the request-at-a-time loop popped:
+    FIFO, each to the lowest free slot, while a slot is free or the
+    ladder can grow; the rest stay queued for the turn a slot frees."""
+    step_fn, make_cache = chain_model()
+    srv = DecodeServer(step_fn, make_cache, eos_id=V, max_seq_len=32,
+                       max_slots=4, slot_ladder=[2, 4], len_ladder=[32],
+                       steps_per_tick=1, name="fifo")
+    srv.warmup(configure_cache=False)
+    seated = []
+    real = srv._pool.admit
+    srv._pool.admit = lambda st, slots, prompts, *a, **kw: (
+        seated.append([(i, int(p[0])) for i, p in zip(slots, prompts)]),
+        real(st, slots, prompts, *a, **kw))[1]
+    try:
+        with turn_held(srv):
+            reqs = [srv.submit({"tokens": np.array([i + 1], np.int32)},
+                               max_new_tokens=3 + 2 * i) for i in range(6)]
+        outs = [r.result(timeout=60.0)[0].tolist() for r in reqs]
+        assert outs == [[(i + 2 + j) % V for j in range(3 + 2 * i)]
+                        for i in range(6)]
+        # four slots: four in the first dispatch, in order; the fifth and
+        # sixth as the two shortest free their slots
+        assert seated == [[(0, 1), (1, 2), (2, 3), (3, 4)],
+                          [(0, 5)], [(1, 6)]]
+        assert _admits(srv) == (3, 6)
+    finally:
+        srv.stop(drain=False)
+
+
+def test_growing_ladders_resize_once_a_turn():
+    """A turn whose batch outgrows both the slot rung and the length
+    rung moves the pool to the rung pair the WHOLE batch needs in one
+    resize, then seats it in one dispatch."""
+    step_fn, make_cache = chain_model()
+    srv = DecodeServer(step_fn, make_cache, eos_id=V, max_seq_len=32,
+                       max_slots=4, slot_ladder=[1, 2, 4],
+                       len_ladder=[8, 16, 32], steps_per_tick=1,
+                       name="grow")
+    srv.warmup(configure_cache=False)
+    resizes = []
+    real = srv._pool.resize
+    srv._pool.resize = lambda st, s, t: (resizes.append((s, t)),
+                                         real(st, s, t))[1]
+    try:
+        with turn_held(srv) as run:
+            first = srv.submit({"tokens": np.array([1], np.int32)},
+                               max_new_tokens=6)
+            run()  # seated at rung pair (1, 8), one step taken
+            assert srv._pool.state_rungs(srv._state) == (1, 8)
+            d0, n0 = _admits(srv)
+            more = [srv.submit({"tokens": np.array([2, 3], np.int32)},
+                               max_new_tokens=c) for c in (4, 20, 9)]
+        outs = [r.result(timeout=60.0)[0].tolist() for r in more]
+        assert outs == [[(4 + j) % V for j in range(c)] for c in (4, 20, 9)]
+        assert first.result(timeout=60.0)[0].tolist() == [2, 3, 4, 5, 6, 7]
+        # one slot -> four, length 8 -> 32: ONE move, ONE dispatch
+        assert resizes == [(4, 32)]
+        d1, n1 = _admits(srv)
+        assert (d1 - d0, n1 - n0) == (1, 3)
+        assert srv._pool.jit_cache_stats()["misses"] == 0
+    finally:
+        srv.stop(drain=False)
+
+
+def test_fault_at_admit_fails_the_turns_requests_typed_and_keeps_serving():
+    """An exception out of the turn's one admit: every request the turn
+    popped fails with it (none is stranded in neither queue nor slot),
+    the in-flight one fails, the pool is dropped — and the next request
+    is served from a fresh pool."""
+    step_fn, make_cache = chain_model()
+    srv = DecodeServer(step_fn, make_cache, eos_id=V, max_seq_len=32,
+                       max_slots=4, len_ladder=[32], steps_per_tick=1,
+                       name="admit-fault")
+    srv.warmup(configure_cache=False)
+    real = srv._pool.admit
+    calls = []
+
+    def admit(state, slots, *args, **kw):
+        calls.append(list(np.atleast_1d(slots)))
+        if len(calls) == 2:
+            raise RuntimeError("injected admit fault")
+        return real(state, slots, *args, **kw)
+
+    srv._pool.admit = admit
+    try:
+        failed0 = srv.metrics().get("failed", 0)
+        with turn_held(srv) as run:
+            flying = srv.submit({"tokens": np.array([1], np.int32)},
+                                max_new_tokens=25)
+            run()  # seated, one step taken
+            doomed = [srv.submit({"tokens": np.array([i + 2], np.int32)},
+                                 max_new_tokens=4) for i in range(3)]
+        for r in doomed + [flying]:
+            with pytest.raises(RuntimeError, match="injected admit fault"):
+                r.result(timeout=60.0)
+        assert calls[1] == [1, 2, 3]  # the three rode one call
+        assert srv.metrics()["failed"] - failed0 == 4
+        after = srv.submit({"tokens": np.array([4, 5], np.int32)},
+                           max_new_tokens=3)
+        assert after.result(timeout=60.0)[0].tolist() == [6, 7, 8]
+        assert srv.metrics()["decode"]["admitted"] == 2  # flying + after
     finally:
         srv.stop(drain=False)
 

@@ -267,6 +267,39 @@ def test_a_rung_equal_to_a_state_width_is_an_ordinary_rung(rung_equals):
             + (d.d_conv - 1) * d.d_xbc)
 
 
+def test_batch_admit_into_used_slots_with_recurrent_leaves(
+        check_batch_admit):
+    """Both slots of a pool that has served — their SSM and conv state
+    is whatever the last occupants left — re-seated by ONE admit: the
+    state is leaf for leaf what two admits leave (no cache leaf is
+    touched by either), and each slot then serves what a never-used
+    pool serves: the step starts both states from zero."""
+    import jax
+
+    cfg = tiny_cfg()
+    w = weights(cfg, seed=6)
+    rng = np.random.RandomState(13)
+    a, b, c, d = (rng.randint(0, 97, n).astype(np.int32)
+                  for n in (6, 4, 5, 3))
+    pool, _ = _pool(cfg, w, [16])
+    used, _ = _serve(pool, pool.alloc(2, 16), 0, a, 7)
+    used, _ = _serve(pool, used, 1, b, 9)
+    before = [np.asarray(x) for x in jax.tree.leaves(used["cache"])]
+    ssm = np.asarray(used["cache"][0]["ssm"])
+    assert np.abs(ssm[0]).max() > 0 and np.abs(ssm[1]).max() > 0
+    state = check_batch_admit(pool, used, [(1, c, 12, False),
+                                           (0, d, 10, False)])
+    for x, y in zip(before, jax.tree.leaves(state["cache"])):
+        assert np.array_equal(x, np.asarray(y))
+    while not np.asarray(state["finished"]).all():
+        state = pool.chunk(state)
+    toks = np.asarray(state["tokens"])
+    _, want_c = _serve(pool, pool.alloc(2, 16), 1, c, 7)
+    _, want_d = _serve(pool, pool.alloc(2, 16), 0, d, 7)
+    assert np.array_equal(toks[1, 5:12], want_c)
+    assert np.array_equal(toks[0, 3:10], want_d)
+
+
 @pytest.mark.parametrize("tier", ["prefix", "speculative"])
 def test_prefix_and_speculation_are_refused_over_recurrent_leaves(tier):
     cfg = tiny_cfg()

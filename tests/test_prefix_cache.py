@@ -290,6 +290,59 @@ def test_speculative_parity_and_telemetry(lm_state, draft_state):
         srv.stop(drain=False)
 
 
+def test_batch_admit_carries_each_slots_spec_flag(lm_state, draft_state,
+                                                  check_batch_admit):
+    """A speculative pool's admit seats a mixed batch in one call: the
+    per-slot ``spec`` flags, the draft cache and every other leaf come
+    out as from one admit per request."""
+    from paddle_tpu.serving.kv_pool import KVSlotPool
+
+    step_fn, make_cache = make_transformer_lm_pooled_step_fn(
+        lm_state, V, LM["d_model"], LM["n_layer"], LM["n_head"],
+        LM["d_inner"])
+    pool = KVSlotPool(step_fn, make_cache, eos_id=EOS, max_slots=4,
+                      max_seq_len=16, slot_ladder=[4], len_ladder=[16],
+                      steps=2, speculative=_speculative(lm_state,
+                                                        draft_state))
+    rng = np.random.RandomState(5)
+    st = pool.admit(pool.alloc(4, 16), 2,
+                    rng.randint(2, V, 3).astype(np.int32), 3, 12, spec=True)
+    st = pool.chunk(pool.chunk(st))  # a live speculative row beside
+    st = check_batch_admit(pool, st, [
+        (0, rng.randint(2, V, 4).astype(np.int32), 11, True),
+        (1, rng.randint(2, V, 2).astype(np.int32), 9, False),
+        (3, rng.randint(2, V, 6).astype(np.int32), 16, True)])
+    assert np.asarray(st["spec"]).tolist() == [True, False, True, True]
+    # one bool for the whole batch still means every seat
+    st = pool.admit(st, [0, 1], [np.array([2], np.int32)] * 2, [1, 1],
+                    [5, 5], spec=True)
+    assert np.asarray(st["spec"]).tolist() == [True] * 4
+
+
+@pytest.mark.parametrize("spec", [False, True])
+def test_admit_prefix_keeps_the_requests_own_spec_flag(lm_state,
+                                                       draft_state, spec):
+    """A request seated over a retained prefix is speculative only if it
+    asked to be (the flag was once shadowed by the state's shapes and
+    read as always set)."""
+    from paddle_tpu.serving.kv_pool import KVSlotPool
+
+    step_fn, make_cache = make_transformer_lm_pooled_step_fn(
+        lm_state, V, LM["d_model"], LM["n_layer"], LM["n_head"],
+        LM["d_inner"])
+    pool = KVSlotPool(step_fn, make_cache, eos_id=EOS, max_slots=2,
+                      max_seq_len=16, slot_ladder=[2], len_ladder=[16],
+                      steps=2, prefix=True,
+                      speculative=_speculative(lm_state, draft_state))
+    prompt = np.arange(2, 8, dtype=np.int32)
+    st = pool.admit(pool.alloc(2, 16), 0, prompt, 6, 12)
+    st = pool.chunk(pool.chunk(st))
+    kv = pool.extract_kv(st, 0, 4)
+    st = pool.admit_prefix(st, 1, prompt, 6, 12, kv, 4, spec=spec)
+    assert np.asarray(st["spec"]).tolist() == [False, spec]
+    assert np.asarray(st["pos"]).tolist()[1] == 4
+
+
 @pytest.mark.parametrize("kv", ["fp32", "int8"])
 def test_verify_at_k_rows_equals_k_sequential_steps(lm_state, kv):
     """``verify_fn`` is the pooled step at K fresh rows per slot: its

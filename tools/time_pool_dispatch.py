@@ -1,0 +1,193 @@
+#!/usr/bin/env python
+"""What ONE dispatch of the slot pool costs the host, on the chip.
+
+The decode server's tick is device time plus what the host spends
+between two chunks; this times the host's calls one at a time, with the
+chip idle before each, at a decode cell's real shapes (the
+configuration's widths and layers, its one rung pair, so the executables
+take as many array arguments as the cell's):
+
+* ``admit`` of one request and of a turn's worth (``--batch``, default
+  6): seconds until the call RETURNS (the host's share) and until its
+  result is READY (what the chip waits between two chunks);
+* ``chunk``: the same two, with every slot live;
+* a profiler trace over a few of each, reduced to the host events that
+  ran on the calling thread inside the calls (argument handling, the
+  h2d of host arguments, the runtime's ``Execute``), by name.
+
+    python tools/time_pool_dispatch.py gpt1_117m
+    python tools/time_pool_dispatch.py gpt1_117m --repo .parent_copy \\
+        --one-call-a-request
+
+``--repo`` imports ``paddle_tpu`` and the benchmark's family from
+another checkout; ``--one-call-a-request`` seats a batch the way a
+checkout before PR 30 does, one ``admit`` call each.  ``--rehearse-cpu``
+runs the cell's tiny rehearsal sizes on the CPU to prove the script, and
+prints no number a reader could take for the chip's.  The last line of
+output is one JSON object.
+"""
+import argparse
+import collections
+import glob
+import json
+import os
+import statistics
+import sys
+import tempfile
+import time
+
+
+def _ms(samples):
+    return {"median_ms": statistics.median(samples) * 1e3,
+            "min_ms": min(samples) * 1e3, "max_ms": max(samples) * 1e3,
+            "n": len(samples)}
+
+
+def host_events_inside(xplane_path, prefixes):
+    """{event name: [total seconds, count]} of the host plane's events
+    that lie inside an event whose name starts with one of ``prefixes``
+    (the ``tool/...`` annotations around the timed calls), per prefix."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(xplane_path)
+    out = {p: collections.defaultdict(lambda: [0.0, 0]) for p in prefixes}
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            events = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                      for ev in line.events]
+            outer = [e for e in events if e[2].startswith(tuple(prefixes))]
+            for a, b, name in events:
+                for oa, ob, oname in outer:
+                    if oa <= a and b <= ob and name != oname:
+                        row = out[next(p for p in prefixes
+                                       if oname.startswith(p))][name]
+                        row[0] += (b - a) * 1e-9
+                        row[1] += 1
+                        break
+    return {p: sorted(([k, v[0] / max(v[1], 1) * 1e3, v[1]]
+                       for k, v in rows.items()), key=lambda r: -r[1])[:12]
+            for p, rows in out.items()}
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("config")
+    ap.add_argument("--repo", default=None)
+    ap.add_argument("--batch", type=int, default=6)
+    ap.add_argument("--reps", type=int, default=40)
+    ap.add_argument("--one-call-a-request", action="store_true")
+    ap.add_argument("--rehearse-cpu", action="store_true")
+    args = ap.parse_args()
+
+    root = os.path.abspath(args.repo or os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), ".."))
+    sys.path.insert(0, root)
+    if args.rehearse_cpu:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    import numpy as np
+
+    from benchmark.lib import harness
+    from paddle_tpu import decoding
+    from paddle_tpu.serving.kv_pool import KVSlotPool
+
+    harness.configure_jax(args.rehearse_cpu)
+    dev = jax.devices()[0]
+    if not args.rehearse_cpu and dev.platform != "tpu":
+        sys.exit("time_pool_dispatch: needs the chip (or --rehearse-cpu)")
+    cfg = harness.load_config(os.path.join(
+        root, "benchmark", "configs", args.config + ".json"),
+        args.rehearse_cpu)
+    family = harness.load_py(os.path.join(
+        root, "benchmark", "families", cfg["family"] + ".py"), cfg["family"])
+    if cfg["family"] != "pooled_decode_lm":
+        sys.exit("time_pool_dispatch: only the pooled_decode_lm family")
+    sv, vocab = cfg["serving"], int(cfg["vocab_size"])
+    weights = family.make_weights(cfg, dev)
+    step_fn, make_cache = decoding.make_transformer_lm_pooled_step_fn(
+        weights, vocab, cfg["n_embd"], cfg["n_layer"], cfg["n_head"],
+        cfg["assumed"]["n_inner"], kv_dtype=sv["kv_dtype"])
+    s, t = sv["slot_ladder"][-1], sv["len_ladder"][-1]
+    pool = KVSlotPool(step_fn, make_cache, eos_id=vocab, max_slots=s,
+                      max_seq_len=t, slot_ladder=[s], len_ladder=[t],
+                      steps=sv["steps_per_tick"], kv_dtype=sv["kv_dtype"])
+    t0 = time.perf_counter()
+    pool.warmup()
+    warm_s = time.perf_counter() - t0
+    rng = np.random.RandomState(0)
+
+    def prompt():
+        return rng.randint(0, vocab, rng.randint(8, t // 2)).astype(np.int32)
+
+    def admit(state, slots):
+        prompts = [prompt() for _ in slots]
+        t0 = time.perf_counter()
+        if args.one_call_a_request or len(slots) == 1:
+            for i, p in zip(slots, prompts):
+                state = pool.admit(state, i, p, len(p), t)
+        else:
+            state = pool.admit(state, slots, prompts,
+                               [len(p) for p in prompts], [t] * len(slots))
+        t1 = time.perf_counter()
+        jax.block_until_ready(state["pos"])
+        return state, t1 - t0, time.perf_counter() - t0
+
+    def chunk(state):
+        t0 = time.perf_counter()
+        state = pool.chunk(state)
+        t1 = time.perf_counter()
+        jax.block_until_ready(state["pos"])
+        return state, t1 - t0, time.perf_counter() - t0
+
+    # every slot live (the state crosses to the device with the first
+    # call), then a few calls of each kind outside the samples
+    state = pool.alloc(s, t)
+    for i in range(s):
+        state, _, _ = admit(state, [i])
+    for _ in range(3):
+        state, _, _ = chunk(state)
+    batch = list(range(0, s, max(1, s // args.batch)))[:args.batch]
+    samples = collections.defaultdict(list)
+    for r in range(args.reps):
+        for name, fn in (("admit_1", lambda st: admit(st, [r % s])),
+                         ("admit_%d" % len(batch), lambda st: admit(st, batch)),
+                         ("chunk", chunk)):
+            state, call_s, ready_s = fn(state)
+            samples[name + ".call"].append(call_s)
+            samples[name + ".ready"].append(ready_s)
+
+    trace_dir = tempfile.mkdtemp(prefix="pool_dispatch_trace_")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    for r in range(10):
+        with jax.profiler.TraceAnnotation("tool/admit_1"):
+            state, _, _ = admit(state, [r])
+        with jax.profiler.TraceAnnotation("tool/admit_n"):
+            state, _, _ = admit(state, batch)
+        with jax.profiler.TraceAnnotation("tool/chunk"):
+            state, _, _ = chunk(state)
+    jax.profiler.stop_trace()
+    found = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    inside = (host_events_inside(
+        found[-1], ["tool/admit_1", "tool/admit_n", "tool/chunk"])
+        if found else {})
+
+    n_args = len(jax.tree.leaves(state)) + len(jax.tree.leaves(weights))
+    out = {"config": args.config, "repo": root,
+           "one_call_a_request": args.one_call_a_request,
+           "device": {"platform": dev.platform, "kind": dev.device_kind},
+           "rung_pair": [s, t], "batch": len(batch),
+           "state_and_weight_arrays": n_args, "warmup_s": warm_s,
+           "host_events_inside_ms_each": inside,
+           "times": {k: _ms(v) for k, v in sorted(samples.items())}}
+    if args.rehearse_cpu:
+        print("REHEARSAL on the CPU at tiny sizes: NOT device numbers.")
+    print(json.dumps(out))
+
+
+if __name__ == "__main__":
+    main()
