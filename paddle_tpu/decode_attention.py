@@ -22,9 +22,15 @@ a speculative verify round: ``K`` rows).  Per step and layer
 * **read what is live** — row ``j`` attends to positions ``<= ts[n] +
   j``; ``rep = n_head // n_kv_head`` query heads share a K/V head; a
   slot with ``ts[n] < 0`` (idle) is not written and gets a zero context
-  row.
+  row;
+* or **read the blocks named for this row** — a step whose layer
+  SELECTS what it reads (block-sparse attention) appends the same way
+  (:func:`append_rows`) and hands :func:`grouped_block_decode_attention`
+  per slot and K/V head a list of blocks: the slot attends to the live
+  positions of those blocks and to nothing else, so its read does not
+  grow with the rung.
 
-Two implementations of that one contract, chosen by
+Two implementations of the read-what-is-live contract, chosen by
 :func:`make_decode_attention` from what it can observe:
 
 * :func:`ragged_decode_attention` — the Pallas TPU kernel, for fp32
@@ -57,7 +63,9 @@ import numpy as np
 
 __all__ = ["KV_BLOCK", "KV_SEQ_AXIS", "kv_leaves", "kv_read_block",
            "decode_work_items", "ragged_decode_attention",
-           "grouped_masked_decode_attention",
+           "grouped_masked_decode_attention", "append_rows",
+           "grouped_block_decode_attention", "block_sparse_decode_attention",
+           "block_kernel_supported",
            "kernel_supported", "make_decode_attention"]
 
 #: the sequence axis of every K/V leaf (and scale sibling)
@@ -368,6 +376,233 @@ def grouped_masked_decode_attention(q, k_new, v_new, kv, ts,
                      _read(kv, "v", heads),
                      preferred_element_type=jnp.float32)
     return jnp.where(live[..., None], ctx.reshape(q.shape), 0.0), kv
+
+
+def append_rows(kv, k_new, v_new, ts):
+    """One layer's leaves with one fresh K/V row per slot appended in
+    place at ``ts`` (idle slots, ``ts < 0``, are not written): the
+    append half of the contract alone, for a step that reads by
+    :func:`grouped_block_decode_attention`.  ``k_new``, ``v_new`` ``[S,
+    n_kv_head * Dh]``; unquantized leaves only."""
+    import jax.numpy as jnp
+
+    if "k_scale" in kv:
+        raise ValueError("append_rows: int8 leaves are not supported")
+    S, T, _ = kv["k"].shape
+    rows, at = jnp.arange(S), jnp.where(ts >= 0, ts, T)
+    return {**_append(kv, "k", k_new, rows, at, None),
+            **_append(kv, "v", v_new, rows, at, None)}
+
+
+def block_kernel_supported(kv, n_head: int, n_kv_head: int,
+                           block: int) -> bool:
+    """Shapes and dtypes :func:`block_sparse_decode_attention` lowers
+    for: unquantized leaves, a head's lanes a whole number of lane tiles,
+    blocks and the query heads of a group whole sublane tiles."""
+    import jax.numpy as jnp
+
+    width = kv["k"].shape[-1]
+    rows = 16 if kv["k"].dtype == jnp.bfloat16 else 8
+    return ("k_scale" not in kv and width % n_kv_head == 0
+            and (width // n_kv_head) % 128 == 0 and block % rows == 0
+            and (n_head // n_kv_head) % rows == 0
+            and kv["k"].dtype in (jnp.bfloat16, jnp.float32))
+
+
+def block_sparse_decode_attention(q, k_cache, v_cache, ts, blocks, valid, *,
+                                  n_head: int, n_kv_head: int, scale: float,
+                                  block: int, interpret=False):
+    """The Pallas TPU kernel of "read the blocks named for this row".
+
+    One grid step per (slot, K/V head, run of ``U`` named blocks); the
+    block ids ride in SMEM (scalar prefetch) and the BlockSpecs' index
+    maps turn them into the DMAs, so the pipeline fetches the next run's
+    ``[block, Dh]`` K and V tiles — a head's lanes of a block's rows, cut
+    from the leaves as they lie — while this one is scored.  A run's K
+    tiles are stacked and meet the group's query heads ``[rep, Dh]`` in
+    ONE product on the MXU (``U * block`` keys a step: a step per block
+    spent its time in the latency of seven small products, 4.0 ms a
+    layer and step on the chip against 1.x for this, PR 31); an online
+    softmax runs over the runs of a (slot, head), and the context is
+    written when its last run is in.  ``q`` ``[S, n_head * Dh]`` fp32;
+    ``k_cache``, ``v_cache`` ``[S, T, n_kv_head * Dh]`` (read only:
+    :func:`append_rows` has written the step's rows); ``ts`` ``[S]``;
+    ``blocks``, ``valid`` ``[S, n_kv_head, B]``.  Returns ctx ``[S,
+    n_kv_head, rep, Dh]`` fp32: zeros where nothing may be read."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, T, Dkv = k_cache.shape
+    G, D, rep = n_kv_head, Dkv // n_kv_head, n_head // n_kv_head
+    B = blocks.shape[-1]
+    U = max(u for u in range(1, 17) if B % u == 0)
+    dt, f32 = k_cache.dtype, jnp.float32
+    qg = (q * scale).astype(dt).reshape(S, G, rep, D)
+    named = jnp.where(valid, blocks, -1).astype(jnp.int32).reshape(-1)
+
+    def kernel(blk_ref, ts_ref, q_ref, *refs):
+        k_refs, v_refs = refs[:U], refs[U:2 * U]
+        o_ref, m_ref, l_ref, acc_ref = refs[2 * U:]
+        n, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+        @pl.when(j == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, _MASK)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        kk = jnp.concatenate([r[...] for r in k_refs], axis=0)
+        vv = jnp.concatenate([r[...] for r in v_refs], axis=0)
+        s = jax.lax.dot_general(
+            q_ref[...], kk, (((1,), (1,)), ((), ())),
+            preferred_element_type=f32)                 # [rep, U * block]
+        lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        pos = jnp.full(s.shape, T, jnp.int32)           # T: may not be read
+        for u in range(U):
+            b = blk_ref[(n * G + g) * B + j * U + u]
+            mine = (lane >= u * block) & (lane < (u + 1) * block) & (b >= 0)
+            pos = jnp.where(mine, b * block + lane - u * block, pos)
+        ok = pos <= ts_ref[n]
+        s = jnp.where(ok, s, _MASK)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = jnp.broadcast_to(
+            alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True),
+            l_ref.shape)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(dt), vv, preferred_element_type=f32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+        @pl.when(j == pl.num_programs(2) - 1)
+        def _():
+            o_ref[...] = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+
+    def tile(u):
+        return pl.BlockSpec(
+            (None, block, D), lambda n, g, j, blk_ref, ts_ref: (
+                n, jnp.maximum(blk_ref[(n * G + g) * B + j * U + u], 0), g))
+
+    heads = pl.BlockSpec((None, None, rep, D),
+                         lambda n, g, j, blk_ref, ts_ref: (n, g, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(S, G, B // U),
+            in_specs=[heads] + [tile(u) for u in range(U)] * 2,
+            out_specs=heads,
+            scratch_shapes=[pltpu.VMEM((rep, _HEAD_LANES), f32),
+                            pltpu.VMEM((rep, _HEAD_LANES), f32),
+                            pltpu.VMEM((rep, D), f32)]),
+        out_shape=jax.ShapeDtypeStruct((S, G, rep, D), f32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="block_sparse_decode_attention",
+        interpret=interpret,
+    )(named, ts.astype(jnp.int32), qg,
+      *([k_cache] * U + [v_cache] * U))
+
+
+def _gathered_block_attention(q, kv, ts, blocks, valid, *, n_head, n_kv_head,
+                              scale, block):
+    """The XLA form of the same read: a block gather, then the grouped
+    masked softmax over what was gathered.  ctx ``[S, G, rep, Dh]``."""
+    import jax
+    import jax.numpy as jnp
+
+    S, T, Dkv = kv["k"].shape
+    G, D, rep = n_kv_head, Dkv // n_kv_head, n_head // n_kv_head
+    dt = kv["k"].dtype
+    qg = (q * scale).astype(dt).reshape(S, G, rep, D)
+    # one gathered slice is a block's rows of ONE head's lanes, cut from
+    # the leaf as it lies ([S, T, G * D]: no reshape of the leaf, which
+    # would re-tile and copy the whole rung)
+    at = jnp.stack(jnp.broadcast_arrays(
+        jnp.arange(S)[:, None, None], blocks * block,
+        (jnp.arange(G) * D)[None, :, None]), axis=-1)       # [S, G, B, 3]
+    dn = jax.lax.GatherDimensionNumbers(
+        offset_dims=(3, 4), collapsed_slice_dims=(0,),
+        start_index_map=(0, 1, 2))
+    # [S, G, B * block, D]: the blocks' rows as one run of key rows
+    kg, vg = (jax.lax.gather(kv[name], at, dn, (1, block, D),
+                             mode="promise_in_bounds").reshape(
+                                 S, G, -1, D) for name in ("k", "v"))
+    pos = blocks[..., None] * block + jnp.arange(block)
+    ok = (valid[..., None] & (pos <= ts[:, None, None, None])).reshape(
+        S, G, 1, -1)
+    scores = jnp.einsum("sgrd,sgkd->sgrk", qg, kg,
+                        preferred_element_type=jnp.float32)
+    w = jax.nn.softmax(jnp.where(ok, scores, -1e9), axis=-1)
+    return jnp.einsum("sgrk,sgkd->sgrd", w.astype(dt), vg,
+                      preferred_element_type=jnp.float32)
+
+
+def grouped_block_decode_attention(q, kv, ts, blocks, valid, dense, *,
+                                   n_head: int, n_kv_head: int,
+                                   scale: float, block: int,
+                                   dense_len: int):
+    """Read the blocks named for each row.
+
+    ``q`` ``[S, n_head * Dh]`` fp32, one query row per slot at position
+    ``ts`` (its K/V row already appended: :func:`append_rows`);
+    ``blocks`` ``[S, n_kv_head, B]`` int32 and ``valid`` the same shape:
+    the blocks slot ``s`` reads for K/V head ``g`` (distinct where
+    valid; ``rep`` query heads share a list), of which positions ``<=
+    ts`` count.  A slot with ``dense`` set reads every position ``<=
+    ts`` instead; such a slot is below ``dense_len``, so that branch
+    reads the first ``dense_len`` positions of the rung and runs only in
+    a step that has one (``lax.cond``).  Products in the storage dtype,
+    fp32 accumulation and softmax.  Returns ctx ``[S, n_head * Dh]``
+    fp32, zero for an idle slot.  No branch reads the whole rung: ``B *
+    block`` positions a slot and head, however long ``T`` is.
+
+    Two implementations of the named read, chosen here from what can be
+    observed, as :func:`make_decode_attention` chooses: the Pallas kernel
+    (:func:`block_sparse_decode_attention`) on a TPU for shapes it
+    lowers for (:func:`block_kernel_supported`), else the XLA form (a
+    block gather: the CPU path and the kernel's parity reference —
+    XLA:TPU runs that gather as a serial loop of slices, 64x off the
+    roofline at 12k slices a layer)."""
+    import jax
+    import jax.numpy as jnp
+
+    S, T, Dkv = kv["k"].shape
+    G, D, rep = n_kv_head, Dkv // n_kv_head, n_head // n_kv_head
+    dt = kv["k"].dtype
+    live = ts >= 0
+    named = dict(n_head=n_head, n_kv_head=n_kv_head, scale=scale,
+                 block=block)
+    if (jax.default_backend() == "tpu"
+            and block_kernel_supported(kv, n_head, n_kv_head, block)):
+        ctx = block_sparse_decode_attention(
+            q, kv["k"], kv["v"], ts, blocks, valid, **named)
+    else:
+        ctx = _gathered_block_attention(q, kv, ts, blocks, valid, **named)
+    qg = (q * scale).astype(dt).reshape(S, G, rep, D)
+
+    n_dense = min(int(dense_len), T)
+    use_dense = dense & live
+
+    def dense_read():
+        seen = (jnp.arange(n_dense)[None, :] <= ts[:, None])[:, None]
+        out = []
+        for g in range(G):      # a head's lanes: a tile-aligned slice
+            kd, vd = (kv[name][:, :n_dense, g * D:(g + 1) * D]
+                      for name in ("k", "v"))
+            sc = jnp.einsum("srd,std->srt", qg[:, g], kd,
+                            preferred_element_type=jnp.float32)
+            wd = jax.nn.softmax(jnp.where(seen, sc, -1e9), axis=-1)
+            out.append(jnp.einsum("srt,std->srd", wd.astype(dt), vd,
+                                  preferred_element_type=jnp.float32))
+        return jnp.stack(out, axis=1)
+
+    ctx_dense = jax.lax.cond(jnp.any(use_dense), dense_read,
+                             lambda: jnp.zeros_like(ctx))
+    ctx = jnp.where(use_dense[:, None, None, None], ctx_dense, ctx)
+    return jnp.where(live[:, None], ctx.reshape(S, n_head * D), 0.0)
 
 
 def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,

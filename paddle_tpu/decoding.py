@@ -25,8 +25,10 @@ Two regimes:
 The serving slot pool (``serving.kv_pool`` / ``serving.decode``) runs a
 third: ``step_fn(cache, tokens, ts)`` with every row at its OWN position
 ``ts``.  Its builders — ``make_transformer_lm_pooled_step_fn``, its
-K-wide twin ``make_transformer_lm_pooled_verify_fn`` and
-``make_hybrid_ssm_lm_pooled_step_fn`` — are made of the same parts:
+K-wide twin ``make_transformer_lm_pooled_verify_fn``,
+``make_hybrid_ssm_lm_pooled_step_fn`` and
+``make_sparse_linear_lm_pooled_step_fn`` (which also builds a chunked
+prefill) — are made of the same parts:
 
 * ONE cache format, whatever the storage dtype (fp32, bf16, int8):
   ``paddle_tpu.decode_attention`` says what a K/V leaf is, appends the
@@ -40,8 +42,13 @@ K-wide twin ``make_transformer_lm_pooled_verify_fn`` and
   of its own on purpose — it is the independent reference the tests
   hold the pooled path to.
 * every ``make_cache`` DECLARES its leaves' sequence axes
-  (``make_cache.leaf_seq_axes``, :func:`cache_leaf_seq_axes`); the pool
-  infers nothing from a shape.
+  (``make_cache.leaf_seq_axes``, :func:`cache_leaf_seq_axes`) and, for
+  a leaf whose sequence axis advances one row per several positions,
+  the stride (``make_cache.leaf_seq_strides``); the pool infers nothing
+  from a shape.  A builder that can feed ``C`` prompt tokens of one
+  slot in one call declares that too (``make_cache.prefill_fn``), and
+  what a pool can do follows from the declarations: chunked prefill,
+  and prefix snapshots over recurrent leaves.
 """
 from __future__ import annotations
 
@@ -56,7 +63,8 @@ __all__ = [
     "make_transformer_lm_pooled_step_fn", "make_slot_decode_fns",
     "make_transformer_lm_pooled_verify_fn", "make_prefix_admit_fn",
     "make_hybrid_ssm_lm_pooled_step_fn",
-    "cache_leaf_seq_axes", "recurrent_leaf_names",
+    "make_sparse_linear_lm_pooled_step_fn",
+    "cache_leaf_seq_axes", "cache_leaf_seq_strides", "recurrent_leaf_names",
     "normalize_kv_dtype",
     "random_transformer_lm_state",
 ]
@@ -505,10 +513,13 @@ def make_hybrid_ssm_lm_pooled_step_fn(state, cfg, name: str = "lm",
       can forget the reset, and it costs a select on a load the update
       makes anyway.  An idle row's state is kept as it was.
 
-    Position 0 is where a sequence starts, so nothing that seats a slot
-    at ``pos > 0`` (a copied prefix) or rolls ``pos`` back (a rejected
-    speculative round) is valid over these leaves; ``KVSlotPool`` refuses
-    both at construction.
+    Position 0 is where a sequence starts HERE: this builder has no
+    chunked prefill, so nothing can stop at a boundary to copy the
+    state a prefix of positions would need (a builder that has one gets
+    prefix snapshots: :func:`make_sparse_linear_lm_pooled_step_fn`), and
+    no step can roll ``pos`` back out of a state (a rejected speculative
+    round); ``KVSlotPool`` refuses ``prefix=True`` and ``speculative=``
+    over this builder at construction.
     """
     import jax.numpy as jnp
 
@@ -575,6 +586,210 @@ def make_hybrid_ssm_lm_pooled_step_fn(state, cfg, name: str = "lm",
         return logits, new_cache
 
     return step_fn, make_cache
+
+
+def make_sparse_linear_lm_pooled_step_fn(state, cfg, name: str = "lm",
+                                         kv_dtype: str = "bf16",
+                                         state_dtype: str = "float32",
+                                         prefill_tokens: int = 512):
+    """The slot-pooled step AND the chunked prefill of a decoder that
+    mixes lightning linear-attention layers with InfLLM-v2 block-sparse
+    attention layers (``model_type: minicpm_sala``; the parts and the
+    equations are ``paddle_tpu.sparse_linear_lm``).
+
+    Returns ``(step_fn, make_cache, prefill_fn)``.  ``step_fn`` and
+    ``make_cache`` keep the contract of the two builders above
+    (``step_fn(cache, tokens [N] int32, ts [N] int32) -> (logits [N, V]
+    fp32, cache)``, ``ts[i] < 0`` an idle row).  ``state``: weights
+    under ``sparse_linear_lm.param_shapes(cfg)``, used in the dtype
+    they are given; ``cfg``: the published config keys plus
+    ``sparse_config`` (``sparse_linear_lm.dims``).
+
+    ``make_cache`` declares three kinds of leaf:
+
+    * per sparse layer ``k``, ``v`` ``[N, T, n_kv_head * head_dim]`` in
+      ``kv_dtype`` (``decode_attention``'s format: appended in place at
+      ``ts``, covered by write-before-read) ...
+    * ... and ``ck`` ``[N, T // kernel_stride, n_kv_head * head_dim]``,
+      the compressed keys the selection scores: a sequence leaf that
+      advances ONE ROW PER ``kernel_stride`` POSITIONS
+      (``make_cache.leaf_seq_strides`` says so beside
+      ``leaf_seq_axes``); row ``j`` is written by the step that appends
+      position ``kernel_stride * j + kernel_size - 1`` and read only by
+      queries past it, so write-before-read covers it too;
+    * per lightning layer ``s`` ``[N, heads, d, d]`` in ``state_dtype``:
+      RECURRENT (``-1``), read as zero for a row at ``ts == 0``
+      (``hybrid_ssm.starts_fresh``), kept for an idle row.
+
+    The sparse read is ``decode_attention.grouped_block_decode_attention``
+    over the blocks ``sparse_linear_lm.select_blocks`` names: a row reads
+    at most ``n_sel * block_size`` positions however long the rung.
+
+    ``prefill_fn(cache, row, tokens [C], start, n_valid) -> cache`` (``C
+    = prefill_tokens``, also ``prefill_fn.chunk_tokens``) feeds slot
+    ``row`` its prompt tokens at positions ``start .. start + n_valid -
+    1`` through every layer in ONE call and no logits: lightning layers
+    by ``lightning_chunk`` from the slot's state (zero at ``start ==
+    0``), leaving it at ``start + n_valid``; sparse layers append the
+    chunk's K/V rows and the ``ck`` rows it completes, and every query
+    attends by the step's rule (``chunk_attend``).  ``start`` must be a
+    multiple of ``kernel_stride``.  It equals ``n_valid`` steps leaf for
+    leaf (tests/test_sparse_linear_lm.py).  ``make_cache.prefill_fn``
+    declares it to the pool, which compiles it as one more executable a
+    rung pair and, because a prefill can stop at a boundary, may keep
+    prefix SNAPSHOTS over these recurrent leaves (``KVSlotPool``).
+    ``make_cache.sparse_positions_read(n)`` is what the rule lets a
+    sparse query of context ``n`` read, per sparse layer
+    (``make_cache.sparse_layers`` of them), for the server's counters.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import sparse_linear_lm as sl
+    from paddle_tpu.decode_attention import (append_rows,
+                                             grouped_block_decode_attention,
+                                             kv_leaves)
+
+    d = sl.dims(cfg)
+    kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
+    s_dt = jnp.dtype(state_dtype)
+    W = {k: jnp.asarray(v) for k, v in state.items()}
+    C = int(prefill_tokens)
+    if C % d.kernel_stride or C % d.block_size:
+        raise ValueError("prefill_tokens must be a multiple of "
+                         "kernel_stride and block_size")
+    G, R = d.n_kv_head, d.n_head // d.n_kv_head
+    scale = 1.0 / float(np.sqrt(d.head_dim))
+    sparse_at = [i for i, kind in enumerate(d.kinds) if kind == sl.SPARSE]
+
+    def make_cache(n_rows: int, seq_len: int):
+        if seq_len % d.block_size:
+            raise ValueError("a length rung must be a multiple of "
+                             "block_size %d" % d.block_size)
+        out = []
+        for kind in d.kinds:
+            if kind == sl.LIGHTNING:
+                out.append({"s": jnp.zeros(
+                    (n_rows, d.l_heads, d.l_head_dim, d.l_head_dim), s_dt)})
+            else:
+                out.append({
+                    **kv_leaves(n_rows, seq_len, G, d.head_dim, kv),
+                    "ck": jnp.zeros((n_rows, seq_len // d.kernel_stride,
+                                     d.d_kv), kv)})
+        return out
+
+    make_cache.leaf_seq_axes = [
+        {"s": -1} if kind == sl.LIGHTNING else {"k": 1, "v": 1, "ck": 1}
+        for kind in d.kinds]
+    make_cache.leaf_seq_strides = [
+        {"s": 1} if kind == sl.LIGHTNING
+        else {"k": 1, "v": 1, "ck": d.kernel_stride} for kind in d.kinds]
+    make_cache.sparse_layers = len(sparse_at)
+    make_cache.sparse_positions_read = (
+        lambda n: sl.selected_positions(n, d))
+
+    def close_layer(h, u, o, p):
+        """The gate, the out-projection and the MLP around a mixer's
+        output ``o`` ``[M, width]``."""
+        gate = jax.nn.sigmoid(sl.linear(u, W[p + "attn_g"]))
+        h = h + d.res_scale * sl.linear(gate * o, W[p + "attn_o"])
+        return h + d.res_scale * sl.swiglu(
+            sl.rms_norm(h, W[p + "norm2"], d.eps), W[p + "mlp_gate"],
+            W[p + "mlp_up"], W[p + "mlp_down"], 1.0, 1.0)
+
+    def step_fn(cache, tokens, ts):
+        n = tokens.shape[0]
+        if sparse_at:
+            ts = jnp.minimum(ts, cache[sparse_at[0]]["k"].shape[1] - 1)
+        pos = jnp.maximum(ts, 0)      # idle rows stay < 0 in ``ts``
+        h = W[name + "_emb"][tokens].astype(jnp.float32) * d.scale_emb
+        new_cache = []
+        for i, kind in enumerate(d.kinds):
+            p = "%s_l%d_" % (name, i)
+            c = cache[i]
+            u = sl.rms_norm(h, W[p + "norm1"], d.eps)
+            q, k, v = sl.mixer_inputs(u, W, p, kind, pos, d)
+            if kind == sl.LIGHTNING:
+                o, s = sl.lightning_step(q, k, v, c["s"], ts, d)
+                o = sl.rms_norm(o.reshape(n, -1), W[p + "o_norm"], d.eps)
+                new_cache.append({"s": s})
+            else:
+                kvs = append_rows({"k": c["k"], "v": c["v"]},
+                                  k.reshape(n, -1), v.reshape(n, -1), ts)
+                ck = sl.update_compressed(c["ck"], kvs["k"], ts, d)
+                blocks, valid, dense = sl.select_blocks(
+                    q.reshape(n, G, R, d.head_dim), ck, pos, d)
+                with jax.named_scope(sl.SPARSE_ATTEND_SCOPE):
+                    o = grouped_block_decode_attention(
+                        q.reshape(n, -1), kvs, ts, blocks, valid, dense,
+                        n_head=d.n_head, n_kv_head=G, scale=scale,
+                        block=d.block_size, dense_len=d.dense_len)
+                new_cache.append({**kvs, "ck": ck})
+            h = close_layer(h, u, o, p)
+        logits = sl.linear(
+            sl.rms_norm(h, W[name + "_final_norm"], d.eps) / d.logit_div,
+            W[name + "_head"])
+        return logits, new_cache
+
+    def prefill_layer(c, kind, h, p, row, start, n_valid, pos, ts_q):
+        u = sl.rms_norm(h, W[p + "norm1"], d.eps)
+        q, k, v = sl.mixer_inputs(u, W, p, kind, pos, d)
+        if kind == sl.LIGHTNING:
+            s_in = jnp.where(
+                sl.starts_fresh(start), 0.0,
+                jax.lax.dynamic_index_in_dim(
+                    c["s"], row, 0, keepdims=False).astype(jnp.float32))
+            o, s_out = sl.lightning_chunk(q, k, v, s_in, n_valid, d)
+            o = sl.rms_norm(o.reshape(C, -1), W[p + "o_norm"], d.eps)
+            new = {"s": jax.lax.dynamic_update_index_in_dim(
+                c["s"], s_out.astype(c["s"].dtype), row, 0)}
+            return close_layer(h, u, o, p), new
+        live = (ts_q >= 0)[:, None]
+        new = {}
+        for leaf, fresh in (("k", k), ("v", v)):
+            old = jax.lax.dynamic_slice(
+                c[leaf], (row, start, 0), (1, C, d.d_kv))[0]
+            rows = jnp.where(live, fresh.reshape(C, -1).astype(
+                c[leaf].dtype), old)
+            new[leaf] = jax.lax.dynamic_update_slice(
+                c[leaf], rows[None], (row, start, 0))
+        # the kernels this chunk completes end inside it: they start up
+        # to kernel_size - kernel_stride rows before it
+        back = d.kernel_size - d.kernel_stride
+        lo = jnp.maximum(start - back, 0)
+        win = jax.lax.dynamic_slice(
+            new["k"], (row, lo, 0), (1, C + back, d.d_kv))[0]
+        j0, n_new = lo // d.kernel_stride, C // d.kernel_stride
+        done = (((j0 + jnp.arange(n_new)) * d.kernel_stride
+                 + d.kernel_size) <= start + n_valid)[:, None]
+        old = jax.lax.dynamic_slice(c["ck"], (row, j0, 0),
+                                    (1, n_new, d.d_kv))[0]
+        new["ck"] = jax.lax.dynamic_update_slice(
+            c["ck"], jnp.where(done, sl.compress_keys(win, d).astype(
+                c["ck"].dtype), old)[None], (row, j0, 0))
+        ck_row = jax.lax.dynamic_index_in_dim(new["ck"], row, 0)
+        qh = q.reshape(C, G, R, d.head_dim)
+        blocks, valid, dense = sl.select_blocks(qh, ck_row, pos, d)
+        o = sl.chunk_attend(qh, new["k"], new["v"], row, ts_q, blocks,
+                            valid, dense, start + n_valid, d)
+        return close_layer(h, u, o.reshape(C, -1), p), new
+
+    def prefill_fn(cache, row, tokens, start, n_valid):
+        with jax.named_scope(sl.PREFILL_CHUNK_SCOPE):
+            pos = start + jnp.arange(C)
+            ts_q = jnp.where(jnp.arange(C) < n_valid, pos, -1)
+            h = W[name + "_emb"][tokens].astype(jnp.float32) * d.scale_emb
+            new_cache = []
+            for i, kind in enumerate(d.kinds):
+                h, new = prefill_layer(cache[i], kind, h,
+                                       "%s_l%d_" % (name, i), row, start,
+                                       n_valid, pos, ts_q)
+                new_cache.append(new)
+            return new_cache
+
+    prefill_fn.chunk_tokens = C
+    make_cache.prefill_fn = prefill_fn
+    return step_fn, make_cache, prefill_fn
 
 
 def make_transformer_lm_pooled_verify_fn(
@@ -646,7 +861,9 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
       with none, which the STEP starts from zero for a row at position
       0 (``make_hybrid_ssm_lm_pooled_step_fn``) — ``admit`` seats every
       request at ``pos = 0``, so neither ``admit`` nor ``release``
-      touches the cache
+      touches the cache (a request seated over a prefix SNAPSHOT starts
+      at ``pos = prefix_len`` with its recurrent leaves installed:
+      :func:`make_prefix_admit_fn`)
     * ``tokens``   — [S, T] int32, position-indexed token buffer
     * ``pos``      — [S] int32, tokens consumed so far (the step eats
       index ``pos`` and produces the token for ``pos + 1``)
@@ -656,11 +873,18 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
     * ``n_gen``    — [S] int32 generated-token count (prefill/decode
       ratio accounting reads the deltas host-side)
 
-    Prefill and decode are the SAME step: while ``pos + 1 <
+    Prefill and decode are the SAME step here: while ``pos + 1 <
     prompt_len`` the produced token is discarded in favor of the stored
     prompt token (teacher forcing), so a freshly admitted prompt fills
     its cache inside the running batch — no separate prefill executable,
-    no second compiled shape.  A slot finishes when it emits ``eos_id``
+    no second compiled shape.  That is the whole truth for a builder
+    that declares no chunked prefill; for one that does
+    (``make_cache.prefill_fn``, :func:`make_sparse_linear_lm_pooled_step_fn`)
+    the pool compiles one more function beside these three, which feeds
+    ``C`` prompt tokens of ONE slot a dispatch while the slot is held
+    inactive (so ``chunk`` leaves it alone: an inactive slot is fully
+    masked), and only the remainder shorter than ``C`` is teacher-forced
+    here.  A slot finishes when it emits ``eos_id``
     or reaches ``total_len``; inactive slots are fully masked (their
     ``pos`` does not advance), reach the step as ``ts = -1`` so their
     cache rows are neither read nor written, and cost only the wasted
@@ -803,6 +1027,25 @@ def cache_leaf_seq_axes(make_cache, leaves):
     return [None if int(a) < 0 else int(a) for a in axes]
 
 
+def cache_leaf_seq_strides(make_cache, leaves):
+    """Positions one row of each leaf's sequence axis stands for: 1
+    unless ``make_cache.leaf_seq_strides`` (a pytree shaped like
+    ``leaf_seq_axes``) declares more — a leaf of compressed keys holds
+    one row per ``kernel_stride`` positions, and a prefix of ``P``
+    positions is its first ``P // stride`` rows."""
+    import jax
+
+    declared = getattr(make_cache, "leaf_seq_strides", None)
+    if declared is None:
+        return [1] * len(leaves)
+    strides = [int(x) for x in jax.tree.leaves(declared)]
+    if len(strides) != len(leaves) or min(strides) < 1:
+        raise ValueError(
+            "make_cache.leaf_seq_strides must declare a stride >= 1 for "
+            "each of the cache's %d leaves" % len(leaves))
+    return strides
+
+
 def recurrent_leaf_names(make_cache):
     """Tree paths of the leaves ``make_cache`` declares recurrent (no
     sequence axis: ``-1`` in ``make_cache.leaf_seq_axes``)."""
@@ -813,27 +1056,50 @@ def recurrent_leaf_names(make_cache):
                 _declared_seq_axes(make_cache))[0] if int(a) < 0]
 
 
-def make_prefix_admit_fn(admit_fn, seq_axes_of):
+def make_prefix_admit_fn(admit_fn, seq_axes_of, seq_strides_of=None,
+                         whole_rows: bool = False):
     """Wrap a :func:`make_slot_decode_fns` ``admit`` with shared-prefix
-    KV installation: ``admit_prefix(state, slot_mask, prompt,
+    installation: ``admit_prefix(state, slot_mask, prompt,
     prompt_len, total_len, kv_leaves, prefix_len[, spec_flag])`` seats
     the request as usual, then overwrites the slot's first
-    ``prefix_len`` cache positions with the retained KV blocks and
+    ``prefix_len`` cache positions with the retained rows and
     starts ``pos`` at ``prefix_len`` — prefill resumes at the unmatched
     suffix.
 
-    ``kv_leaves`` is the flattened leaf list of the state's KV subtrees
-    (``cache`` plus ``draft_cache`` when present, in tree-flatten
-    order), each leaf host-padded along its sequence axis to the
-    state's length rung; non-qualifying positions carry a ``(1,)``
-    dummy.  Qualification and the sequence axis are STATIC
-    (``seq_axes_of(subtrees)`` over the ``{"cache": ...,
+    ``kv_leaves`` is the flattened leaf list of the state's cache
+    subtrees (``cache`` plus ``draft_cache`` when present, in
+    tree-flatten order), each leaf shaped like one slot's row of the
+    state's leaf (sequence leaves padded to the length rung); a leaf
+    that is not installed carries a ``(1,)`` dummy.  What is installed
+    is STATIC (``seq_axes_of(subtrees)`` over the ``{"cache": ...,
     "draft_cache": ...}`` dict — the pool passes its builders'
-    declaration, :func:`cache_leaf_seq_axes`), so one compiled
-    executable per rung pair serves every cached
-    prefix length — ``prefix_len`` stays a dynamic scalar.  Positional
-    embeddings are absolute, so retained rows are position-correct for
-    any matching prompt.
+    declaration, :func:`cache_leaf_seq_axes` — and the leaf shapes), so
+    one compiled executable per rung pair serves every cached prefix
+    length — ``prefix_len`` stays a dynamic scalar:
+
+    * a leaf WITH a sequence axis is installed under the position mask:
+      its rows below ``prefix_len // stride`` (``seq_strides_of``, 1
+      where nothing is declared: :func:`cache_leaf_seq_strides`);
+    * a RECURRENT leaf (no sequence axis) given whole is installed
+      whole: the retained entry is then a SNAPSHOT, the state as it
+      stood when exactly ``prefix_len`` positions had been consumed, and
+      the slot does not start a sequence at position 0 — it resumes one
+      at ``prefix_len``, where no step reads the state as zero.  Only a
+      builder with a chunked prefill can stop at a boundary to take
+      such a snapshot (``KVSlotPool``); given a dummy, the leaf is left
+      alone, as before.
+
+    ``whole_rows`` (a pool whose entries are snapshots: every leaf a
+    slot's whole row): the one seated slot's row of every leaf is
+    REPLACED by the snapshot's (a ``dynamic_update_slice`` of that row:
+    82 MB at the ``minicpm_sala`` rung) instead of selected under a mask
+    over the whole pool (5.2 GB read and written there: 16 ms an
+    admission on the chip, PR 31).  The rows past ``prefix_len`` it
+    brings along are whatever the snapshot's slot held; like a reused
+    slot's own stale rows they are never read before they are written.
+
+    Positional embeddings are absolute, so retained rows are
+    position-correct for any matching prompt.
     """
     import jax
     import jax.numpy as jnp
@@ -846,21 +1112,31 @@ def make_prefix_admit_fn(admit_fn, seq_axes_of):
         else:
             out = admit_fn(state, slot_mask, prompt, prompt_len,
                            total_len, spec_flag)
-        S, T = state["tokens"].shape
-        keep = jnp.arange(T) < prefix_len
+        S = state["tokens"].shape[0]
         sub = {"cache": out["cache"]}
         if "draft_cache" in out:
             sub["draft_cache"] = out["draft_cache"]
         leaves, treedef = jax.tree_util.tree_flatten(sub)
+        strides = (seq_strides_of(sub) if seq_strides_of is not None
+                   else [1] * len(leaves))
         new_leaves = []
-        for cur, pre, ax in zip(leaves, kv_leaves, seq_axes_of(sub)):
-            if ax is None or tuple(pre.shape) != tuple(cur.shape[1:]):
+        for cur, pre, ax, stride in zip(leaves, kv_leaves,
+                                        seq_axes_of(sub), strides):
+            if tuple(pre.shape) != tuple(cur.shape[1:]):
                 new_leaves.append(cur)
                 continue
-            kshape = [1] * cur.ndim
-            kshape[ax] = T
-            sel = (slot_mask.reshape((S,) + (1,) * (cur.ndim - 1))
-                   & keep.reshape(kshape))
+            if whole_rows:
+                new_leaves.append(jax.lax.dynamic_update_index_in_dim(
+                    cur, pre.astype(cur.dtype), jnp.argmax(slot_mask), 0))
+                continue
+            sel = slot_mask.reshape((S,) + (1,) * (cur.ndim - 1))
+            if ax is not None:
+                kshape = [1] * cur.ndim
+                kshape[ax] = cur.shape[ax]
+                rows = (prefix_len if stride == 1
+                        else prefix_len // stride)
+                sel = sel & (jnp.arange(cur.shape[ax]) < rows).reshape(
+                    kshape)
             new_leaves.append(
                 jnp.where(sel, pre[None].astype(cur.dtype), cur))
         sub = jax.tree_util.tree_unflatten(treedef, new_leaves)

@@ -13,7 +13,13 @@ request-at-a-time regime with vLLM-style CONTINUOUS batching:
 * **prefill and decode are the same step** — a freshly admitted prompt
   teacher-forces its stored tokens through the shared step fn, filling
   its KV cache inside the running batch (no separate prefill
-  executable, no second compiled shape);
+  executable, no second compiled shape) — unless the step's builder
+  declares a CHUNKED PREFILL (``make_cache.prefill_fn``): then a turn
+  runs at most one ``prefill`` dispatch (``C`` prompt tokens of the
+  oldest seated request that still has a whole chunk to go, which is
+  held out of the decode chunk until its last whole chunk is in)
+  before its decode chunk, and only the remainder shorter than ``C``
+  rides the step;
 * the pool's bucket ladders (slot rungs x length rungs) keep the
   compiled-shape set CLOSED: :meth:`warmup` pre-compiles every rung
   pair, after which a mixed prompt/decode storm performs **zero XLA
@@ -51,7 +57,10 @@ Decode tier 2 (both independently toggleable, see README):
   PrefixKVCache` — freed slots' prompt-prefix KV blocks are retained
   and matching admissions skip the shared prefill (the
   ``decode.prefix_admit`` fault point guards the degraded-not-wrong
-  fallback);
+  fallback); over a builder with a chunked prefill the entries are
+  device SNAPSHOTS of the slot's whole cache row, recurrent state
+  included, taken where a prompt's last whole chunk ends, and a request
+  seated over one starts at ``pos = prefix_len``;
 * ``speculative=`` attaches a :class:`serving.speculative.
   SpeculativeConfig` — requests submitted with ``speculative=True``
   run draft-then-verify rounds, greedy-exact (output-identical) with
@@ -156,6 +165,24 @@ DECODE_RECURRENT_BYTES = monitor.gauge(
     "its CURRENT slot rung; serving_kv_cache_bytes counts the leaves "
     "that have one; 0 while the pool is idle", _LABELS)
 
+DECODE_PREFILL_CHUNKS = monitor.counter(
+    "serving_decode_prefill_chunks_total",
+    "prefill dispatches: one slot's next prefill_tokens prompt tokens "
+    "through the builder's chunked prefill (at most one a scheduler "
+    "turn; 0 for a builder without one)", _LABELS)
+DECODE_SPARSE_READ = monitor.counter(
+    "serving_decode_sparse_positions_read_total",
+    "K/V positions the block-sparse layers' decode reads were told to "
+    "read: per step, active slot and sparse layer the lesser of its "
+    "live positions and what the selection rule names (everything up to "
+    "dense_len; past it the initial, the window and the top-k blocks)",
+    _LABELS)
+DECODE_SPARSE_LIVE = monitor.counter(
+    "serving_decode_sparse_positions_live_total",
+    "K/V positions live for those reads (what dense attention would "
+    "have read) — read / live is how sparse the traffic makes the "
+    "layer", _LABELS)
+
 # safety-net bound while parked on the empty-queue condition (real
 # wakeups are offer()/wake() notifies)
 _IDLE_WAIT_S = 0.5
@@ -235,14 +262,19 @@ class DecodeRequest(ServingRequest):
 class _Slot:
     """Host-side record of one occupied pool slot."""
 
-    __slots__ = ("req", "prompt_len", "seen", "spec", "pos")
+    __slots__ = ("req", "prompt_len", "seen", "spec", "pos", "held", "seq")
 
-    def __init__(self, req: DecodeRequest, pos: int = 0):
+    def __init__(self, req: DecodeRequest, pos: int = 0,
+                 held: bool = False, seq: int = 0):
         self.req = req
         self.prompt_len = len(req.prompt)
         self.seen = 0  # generated tokens already streamed to the client
         self.pos = pos  # positions consumed as of the last tick's view
         self.spec = bool(getattr(req, "speculative", False))
+        # held out of the decode chunk (inactive on the device) while
+        # whole prefill chunks of its prompt remain
+        self.held = held
+        self.seq = seq  # admission order: the oldest held slot goes first
 
 
 class _PoolPredictorView:
@@ -313,6 +345,14 @@ class DecodeServer:
         self._recurrent_bytes_g = DECODE_RECURRENT_BYTES.labels(**lbl)
         self._admit_dispatches_c = DECODE_ADMIT_DISPATCHES.labels(**lbl)
         self._admitted_c = DECODE_ADMITTED.labels(**lbl)
+        self._prefill_chunks_c = DECODE_PREFILL_CHUNKS.labels(**lbl)
+        self._sparse_read_c = DECODE_SPARSE_READ.labels(**lbl)
+        self._sparse_live_c = DECODE_SPARSE_LIVE.labels(**lbl)
+        # what the builder declares of a block-sparse read, for the two
+        # counters above: (positions a query of context n reads, layers)
+        self._sparse_rule = getattr(make_cache, "sparse_positions_read", None)
+        self._sparse_layers = int(getattr(make_cache, "sparse_layers", 0))
+        self._admit_seq = 0
         # decode tier 2, each independently toggleable: ``prefix_cache``
         # (a PrefixKVCache, or a byte budget to own one) retains freed
         # slots' prefix KV for shared-prefix admission; ``speculative``
@@ -337,6 +377,9 @@ class DecodeServer:
             prefix=self._prefix is not None, speculative=speculative,
             kv_dtype=kv_dtype, len_multiple=len_multiple,
             on_recompile=lambda: self._metrics.count("recompiles"))
+        # a pool without a chunked prefill never holds a slot: its
+        # turns run what they always ran
+        self._chunked = self._pool.prefill_tokens > 0
         self._default_max_new = (
             int(max_new_tokens) if max_new_tokens is not None else None)
         # the SAME admission front door as the batching server: EDF +
@@ -427,6 +470,10 @@ class DecodeServer:
             "state_resets": int(self._state_resets_c.value),
             "admit_dispatches": int(self._admit_dispatches_c.value),
             "admitted": int(self._admitted_c.value),
+            "prefill_chunks": int(self._prefill_chunks_c.value),
+            "prefill_chunk_tokens": self._pool.prefill_tokens,
+            "sparse_positions_read": int(self._sparse_read_c.value),
+            "sparse_positions_live": int(self._sparse_live_c.value),
             "seq_len_histogram": {
                 str(k): v
                 for k, v in sorted(self.seq_len_histogram().items())},
@@ -671,8 +718,13 @@ class DecodeServer:
         popped request, so the chip waits through one dispatch between
         two chunks however many slots the last chunk freed.  A request
         with a prefix-cache hit keeps an ``admit_prefix`` call of its
-        own (it installs KV); one whose installation fails joins the
-        plain batch (degraded, never wrong)."""
+        own (it installs KV, or a whole snapshot); one whose
+        installation fails joins the plain batch (degraded, never
+        wrong).  Over a builder with a chunked prefill, the seated
+        requests that still have a whole chunk of prompt to go are then
+        HELD (one ``release`` for all of them: inactive, so the decode
+        chunk leaves them alone) until :meth:`_prefill_turn` has fed
+        them."""
         pool = self._pool
         slots = list(self._slots)    # the turn's plan of self._slots
         cur = (None if self._state is None
@@ -722,6 +774,12 @@ class DecodeServer:
                     [r.total_len for r in reqs],
                     spec=[r.speculative for r in reqs])
                 self._admit_dispatches_c.inc()
+            held = [slot for slot, req, pre_len, _ in seats
+                    if pool.can_prefill(self._state, pre_len,
+                                        len(req.prompt))
+                    ] if self._chunked else ()
+            if held:
+                self._state = pool.release(self._state, held)
         except BaseException as exc:  # noqa: BLE001 — fail typed,
             # keep serving (the _tick discipline): the popped requests
             # are in neither the queue nor a slot, so an escaping
@@ -730,19 +788,20 @@ class DecodeServer:
             for req in popped:
                 req.fail(exc)
             self._metrics.count("failed", len(popped))
-            self._fail_in_flight(exc)
-            self._state = None
-            self._slots = []
-            self._set_pool_bytes(None)
+            self._fail_and_drop_pool(exc)
             return
         for slot, req, pre_len, _ in seats:
-            self._slots[slot] = _Slot(req, pre_len)
+            self._admit_seq += 1
+            self._slots[slot] = _Slot(req, pre_len, slot in held,
+                                      self._admit_seq)
             # the shared-prefix win, measured where it happens: only the
-            # unmatched suffix re-enters prefill
+            # unmatched suffix re-enters prefill (in chunks or by steps)
             self._prefill_c.inc(len(req.prompt) - pre_len)
         self._admitted_c.inc(len(seats))
         if pool.recurrent_leaves:
-            self._state_resets_c.inc(len(seats))
+            # a slot seated over a snapshot resumes a state: no reset
+            self._state_resets_c.inc(
+                sum(1 for seat in seats if seat[2] == 0))
 
     def _admit_with_prefix(self, slot, req, pre_len, pre_kv):
         """Seat ``req`` over its retained prefix KV, in a dispatch of its
@@ -797,6 +856,14 @@ class DecodeServer:
         use_spec = self._speculative is not None and any(
             s.spec for _, s in recs)
         t0 = time.perf_counter()
+        stepped = True
+        if self._chunked:
+            if any(s.held for _, s in recs) and not self._prefill_turn(
+                    recs):
+                return
+            # with every seated slot held there is nothing for a decode
+            # chunk to advance: the turn was its prefill chunk
+            stepped = any(not s.held for _, s in recs)
         try:
             with contextlib.ExitStack() as stack:
                 if tids:
@@ -808,16 +875,15 @@ class DecodeServer:
                     _faults.active.faultpoint(
                         "decode.step", server=self.name,
                         active=len(recs))
-                if use_spec:
+                if not stepped:
+                    state = self._state
+                elif use_spec:
                     state = dispatch_spec_chunk(self._pool, self._state)
                 else:
                     state = self._pool.chunk(self._state)
                 # hot-path: end decode_tick
         except BaseException as exc:  # noqa: BLE001 — fail typed, keep serving
-            self._fail_in_flight(exc)
-            self._state = None
-            self._slots = []
-            self._set_pool_bytes(None)
+            self._fail_and_drop_pool(exc)
             return
         self._state = state
         # the scheduler intervention: one d2h of the control-plane
@@ -827,10 +893,11 @@ class DecodeServer:
         view = jax.device_get(
             {k: state[k]
              for k in ("tokens", "pos", "active", "finished", "n_gen")})
-        self._count_kv_positions(recs, view, use_spec)
-        # after the position counters: whoever sees the tick counted
-        # sees its positions counted too
-        self._ticks_c.inc()
+        if stepped:
+            self._count_kv_positions(recs, view, use_spec)
+            # after the position counters: whoever sees the tick counted
+            # sees its positions counted too
+            self._ticks_c.inc()
         now = time.perf_counter()
         released: List[int] = []
         for i, rec in recs:
@@ -898,6 +965,41 @@ class DecodeServer:
                     server=self.name, active=len(recs),
                     steps=self._pool.steps)
 
+    def _prefill_turn(self, recs) -> bool:
+        """The turn's ONE prefill dispatch, before its decode chunk: the
+        oldest held slot's next ``prefill_tokens`` prompt tokens.  On
+        the slot's last whole chunk the dispatch also hands it to the
+        step (the remainder shorter than a chunk rides the one-token
+        step by teacher forcing), and — where the pool keeps snapshots —
+        the slot's cache row is copied as it stands at that boundary and
+        retained as a prefix entry: K/V and compressed-key rows below
+        it, recurrent state AT it.  Returns False when the dispatch
+        failed (every in-flight request failed typed, the pool
+        dropped)."""
+        pool = self._pool
+        slot, rec = min(((i, s) for i, s in recs if s.held),
+                        key=lambda x: x[1].seq)
+        end = rec.pos + pool.prefill_tokens
+        last = not pool.can_prefill(self._state, end, rec.prompt_len)
+        try:
+            if _faults.active is not None:  # disarmed: one is-None gate
+                _faults.active.faultpoint(
+                    "decode.step", server=self.name, active=len(recs))
+            self._state = pool.prefill(self._state, slot, rec.pos, last)
+        except BaseException as exc:  # noqa: BLE001 — fail typed, keep serving
+            self._fail_and_drop_pool(exc)
+            return False
+        self._prefill_chunks_c.inc()
+        rec.pos, rec.held = end, not last
+        if last and self._prefix is not None and pool.snapshots:
+            try:
+                head = rec.req.prompt[:end]
+                if not self._prefix.holds(head):
+                    self._prefix.put(head, pool.snapshot(self._state, slot))
+            except Exception:
+                self._metrics.count("prefix_store_failed")
+        return True
+
     def _count_kv_positions(self, recs, view, use_spec: bool) -> None:
         """Advance the KV read / pool position counters for the chunk
         just run, from the ``pos`` the tick already fetched: a slot that
@@ -906,19 +1008,28 @@ class DecodeServer:
         s, t = view["tokens"].shape
         idx = np.fromiter((i for i, _ in recs), np.intp, len(recs))
         p1 = view["pos"][idx].astype(np.int64)
+        p0 = np.fromiter((r.pos for _, r in recs), np.int64, len(recs))
         steps = 1 if use_spec else self._pool.steps
         pool = s * t * steps
         if use_spec or self._pool.kv_dtype != "fp32":
             read = pool  # masked reads over the whole rung
         else:
             blk = kv_read_block(t)
-            p0 = np.fromiter((r.pos for _, r in recs), np.int64, len(recs))
 
             def blocks_below(p):  # sum of (ts // blk + 1) over ts < p
                 full, rem = np.divmod(p, blk)
                 return blk * full * (full + 1) // 2 + rem * (full + 1)
 
             read = int((blocks_below(p1) - blocks_below(p0)).sum()) * blk
+        if self._sparse_rule is not None and self._sparse_layers:
+            # the steps this chunk ran, row by row: contexts p0 + 1 .. p1
+            n = p0[:, None] + 1 + np.arange(steps)[None, :]
+            ran = n <= p1[:, None]
+            self._sparse_live_c.inc(
+                int((n * ran).sum()) * self._sparse_layers)
+            self._sparse_read_c.inc(
+                int((self._sparse_rule(n) * ran).sum())
+                * self._sparse_layers)
         for (_, rec), p in zip(recs, p1.tolist()):
             rec.pos = p
         self._kv_pool_c.inc(pool)
@@ -930,7 +1041,10 @@ class DecodeServer:
         materialization — never inside the dispatch hot path).  Failures
         degrade silently: retention is an optimization, losing one entry
         must not take down the loop."""
-        if self._prefix is None:
+        if self._prefix is None or self._pool.snapshots:
+            # a snapshot pool's entries are taken at prefill boundaries
+            # (:meth:`_prefill_turn`): a freed slot's recurrent state
+            # is past every boundary
             return
         try:
             self._prefix.offer(
@@ -939,6 +1053,15 @@ class DecodeServer:
                     self._state, s, m))
         except Exception:
             self._metrics.count("prefix_store_failed")
+
+    def _fail_and_drop_pool(self, exc: BaseException) -> None:
+        """A dispatch failed (or a resize may have corrupted the pool):
+        every seated request fails typed and the pool state goes, so the
+        next admission starts from a fresh one — keep serving."""
+        self._fail_in_flight(exc)
+        self._state = None
+        self._slots = []
+        self._set_pool_bytes(None)
 
     def _fail_in_flight(self, exc: BaseException) -> None:
         n = 0
@@ -974,7 +1097,9 @@ class DecodeServer:
                        DECODE_KV_READ, DECODE_KV_POOL,
                        DECODE_TTFT, DECODE_OCCUPANCY, DECODE_KV_BYTES,
                        DECODE_STATE_RESETS, DECODE_RECURRENT_BYTES,
-                       DECODE_ADMIT_DISPATCHES, DECODE_ADMITTED):
+                       DECODE_ADMIT_DISPATCHES, DECODE_ADMITTED,
+                       DECODE_PREFILL_CHUNKS, DECODE_SPARSE_READ,
+                       DECODE_SPARSE_LIVE):
             metric.remove_labels(**lbl)
         if self._speculative is not None:
             for metric in (SPEC_PROPOSED, SPEC_ACCEPTED):

@@ -26,10 +26,26 @@ because a sequence reads only positions it wrote itself — and is what
 RECURRENT leaf (no sequence axis: an SSM or conv state, read and
 re-written whole each step) is outside that invariant, is started from
 zero by the step itself for a row at position 0, is never sliced, and
-is counted by ``recurrent_rung_bytes``.  A pool over recurrent leaves
-refuses ``prefix=True`` and ``speculative=``: a prefix of positions can
-be neither copied into nor rolled back out of such a state.  Buffer donation applies to the
-state argument on every executable — the multi-MB KV cache updates in
+is counted by ``recurrent_rung_bytes``.  A sequence leaf may advance
+one row per several positions (``make_cache.leaf_seq_strides``: the
+compressed keys of a block-sparse layer).
+
+What else a pool compiles follows from what the builder declares.  A
+builder with a chunked prefill (``make_cache.prefill_fn``) gets one more
+executable a rung pair, ``prefill``: ``C`` prompt tokens of ONE slot in
+one dispatch, for a slot the scheduler holds out of the decode chunk
+until its last whole chunk is in.  Because such a prefill can stop at a
+boundary, ``prefix=True`` over recurrent leaves is then served by
+SNAPSHOTS: the slot's whole cache row — sequence leaves and recurrent
+leaves alike — copied on the device where a prefilled prompt's last
+whole chunk ends (``snapshot``), and installed whole by ``admit_prefix``
+for a later prompt that starts with the same tokens.  A pool over
+recurrent leaves whose builder has NO prefill still refuses
+``prefix=True`` (a prefix of positions cannot rebuild a state, and
+nothing can stop at a boundary to copy one), and every pool over
+recurrent leaves refuses ``speculative=`` (a rejected round cannot be
+rolled back out of a state).  Buffer donation applies to the
+state argument on every executable that returns a state — the multi-MB KV cache updates in
 place in device memory instead of being copied per tick — with the same
 CPU carve-out as the executor (``executor._donate_kwargs``: donation +
 the persistent compile cache corrupts fetches on the CPU backend).
@@ -103,7 +119,9 @@ class KVSlotPool:
         #: tree paths of the cache leaves declared recurrent (no
         #: sequence axis); empty for a K/V-only cache
         self.recurrent_leaves = recurrent_leaf_names(make_cache)
-        for what, on in (("prefix=True", prefix),
+        #: the builder's chunked prefill (None: prompts ride the step)
+        self._prefill = getattr(make_cache, "prefill_fn", None)
+        for what, on in (("prefix=True", prefix and self._prefill is None),
                          ("speculative=", speculative is not None)):
             if on and self.recurrent_leaves:
                 raise ValueError(
@@ -111,7 +129,10 @@ class KVSlotPool:
                     "(%s ... %d in all): a recurrent state has no "
                     "positions to copy a prefix into or to roll a "
                     "rejected round back from, and would serve wrong "
-                    "tokens; state snapshots are not implemented"
+                    "tokens; a prefix over such leaves needs a state "
+                    "SNAPSHOT taken at the boundary, which only a "
+                    "builder with a chunked prefill "
+                    "(make_cache.prefill_fn) can stop at"
                     % (what, self.recurrent_leaves[0],
                        len(self.recurrent_leaves)))
         # the cache storage dtype ``make_cache`` allocates (advertised
@@ -149,8 +170,13 @@ class KVSlotPool:
             draft_step_fn=(speculative.draft_step_fn
                            if speculative is not None else None))
         self._chunk_fn, self._seat_fn, self._release_fn = self._fns
+        #: prefix entries are device snapshots of a slot's whole cache
+        #: row (recurrent leaves included), taken at a prefill boundary
+        self.snapshots = self.prefix and self._prefill is not None
         self._admit_prefix_fn = (
-            make_prefix_admit_fn(self._seat_fn, self._kv_seq_axes)
+            make_prefix_admit_fn(self._seat_fn, self._kv_seq_axes,
+                                 self._kv_seq_strides,
+                                 whole_rows=self.snapshots)
             if self.prefix else None)
         if speculative is not None:
             from paddle_tpu.serving.speculative import make_spec_chunk_fn
@@ -160,6 +186,7 @@ class KVSlotPool:
                 self.eos_id, speculative.k)
         else:
             self._spec_chunk_fn = None
+        self._specs: Dict[Tuple[int, int], dict] = {}
         # every leaf's kind is known before anything compiles: a
         # make_cache (the draft's too) that declares no axes, or not as
         # many as it builds leaves, is refused here
@@ -185,11 +212,21 @@ class KVSlotPool:
                 for t in self.len_policy.ladder]
 
     # ------------------------------------------------------------------
+    @property
+    def prefill_tokens(self) -> int:
+        """Prompt tokens one ``prefill`` dispatch feeds a slot (0: the
+        builder has no chunked prefill)."""
+        return int(self._prefill.chunk_tokens) if self._prefill else 0
+
     def _kinds(self) -> List[str]:
         """Every executable kind this pool compiles per rung pair."""
         kinds = ["chunk", "admit", "release"]
+        if self._prefill is not None:
+            kinds.append("prefill")
         if self.prefix:
             kinds.append("admit_prefix")
+        if self.snapshots:
+            kinds.append("snapshot")
         if self.speculative is not None:
             kinds.append("spec_chunk")
         return kinds
@@ -200,6 +237,8 @@ class KVSlotPool:
         traces ``make_cache`` instead of running it)."""
         import jax
 
+        if (s, t) in self._specs:   # admit_prefix asks on every call
+            return self._specs[s, t]
         cache = jax.eval_shape(lambda: self._make_cache(s, t))
         i32 = np.dtype(np.int32)
         spec = {
@@ -216,6 +255,7 @@ class KVSlotPool:
             spec["spec"] = jax.ShapeDtypeStruct((s,), np.dtype(bool))
             spec["draft_cache"] = jax.eval_shape(
                 lambda: self.speculative.draft_make_cache(s, t))
+        self._specs[s, t] = spec
         return spec
 
     def _kv_subtree_leaves(self, state_or_spec):
@@ -230,22 +270,34 @@ class KVSlotPool:
         leaves, _ = jax.tree_util.tree_flatten(sub)
         return leaves
 
+    def _declared(self, declared_of, state_or_spec):
+        """``declared_of(make_cache, leaves)`` over each of
+        :meth:`_kv_subtree_leaves` of ``state_or_spec``: the target's
+        leaves first, then the draft's."""
+        import jax
+
+        out = declared_of(self._make_cache,
+                          jax.tree.leaves(state_or_spec["cache"]))
+        if "draft_cache" in state_or_spec:
+            out += declared_of(
+                self.speculative.draft_make_cache,
+                jax.tree.leaves(state_or_spec["draft_cache"]))
+        return out
+
     def _kv_seq_axes(self, state_or_spec):
         """Sequence axis (or None) of each of :meth:`_kv_subtree_leaves`
         of ``state_or_spec``, as the builders declare them
-        (``decoding.cache_leaf_seq_axes``): the target's leaves first,
-        then the draft's."""
-        import jax
-
+        (``decoding.cache_leaf_seq_axes``)."""
         from paddle_tpu.decoding import cache_leaf_seq_axes
 
-        axes = cache_leaf_seq_axes(
-            self._make_cache, jax.tree.leaves(state_or_spec["cache"]))
-        if "draft_cache" in state_or_spec:
-            axes += cache_leaf_seq_axes(
-                self.speculative.draft_make_cache,
-                jax.tree.leaves(state_or_spec["draft_cache"]))
-        return axes
+        return self._declared(cache_leaf_seq_axes, state_or_spec)
+
+    def _kv_seq_strides(self, state_or_spec):
+        """Positions per row of each of :meth:`_kv_subtree_leaves`'
+        sequence axes (``decoding.cache_leaf_seq_strides``)."""
+        from paddle_tpu.decoding import cache_leaf_seq_strides
+
+        return self._declared(cache_leaf_seq_strides, state_or_spec)
 
     def alloc(self, s: int, t: int) -> Dict[str, object]:
         """A fresh zeroed pool state for rung pair ``(s, t)``, HOST-side
@@ -351,29 +403,36 @@ class KVSlotPool:
             return self._lower(kind, spec)
         i32 = np.dtype(np.int32)
         mask = jax.ShapeDtypeStruct((s,), np.dtype(bool))
+        scalar = jax.ShapeDtypeStruct((), i32)
         if kind == "release":
             return self._lower(kind, spec, mask)
+        if kind == "prefill":
+            return self._lower(kind, spec, scalar, scalar,
+                               jax.ShapeDtypeStruct((), np.dtype(bool)))
+        if kind == "snapshot":  # reads the state: nothing to donate
+            return self._lower(kind, spec, scalar, donate=False)
         if kind == "admit":
             return self._lower(kind, spec, jax.ShapeDtypeStruct(
                 (s, t + self._seat_columns()), i32))
         prompt = jax.ShapeDtypeStruct((t,), i32)
-        scalar = jax.ShapeDtypeStruct((), i32)
         args = [spec, mask, prompt, scalar, scalar]
         if kind == "admit_prefix":
             kv = []
             for leaf, ax in zip(self._kv_subtree_leaves(spec),
                                 self._kv_seq_axes(spec)):
+                # a snapshot carries every leaf of the slot's row; else
+                # only the leaves with positions, recurrent ones a dummy
+                whole = ax is not None or self.snapshots
                 kv.append(jax.ShapeDtypeStruct(
-                    leaf.shape[1:] if ax is not None else (1,),
-                    leaf.dtype if ax is not None
-                    else np.dtype(np.float32)))
+                    leaf.shape[1:] if whole else (1,),
+                    leaf.dtype if whole else np.dtype(np.float32)))
             args.append(kv)
             args.append(scalar)  # prefix_len
         if self.speculative is not None:
             args.append(jax.ShapeDtypeStruct((), np.dtype(bool)))
         return self._lower(kind, *args)
 
-    def _lower(self, kind: str, *arg_specs):
+    def _lower(self, kind: str, *arg_specs, donate: bool = True):
         """AOT-compile ``kind`` for ``arg_specs`` with every array the
         function closes over — the model weights — HOISTED to an
         executable argument.  A closed-over array is otherwise baked
@@ -404,14 +463,16 @@ class KVSlotPool:
 
         hoisted.__name__ = kind  # names the XLA module and cache entry
         donate = ({"donate_argnums": (1,)}  # the state, after consts
-                  if _donate_kwargs(jax.devices()[0]) else {})
+                  if donate and _donate_kwargs(jax.devices()[0]) else {})
         exe = jax.jit(hoisted, **donate).lower(
             closed.consts, *arg_specs).compile()
         return functools.partial(exe, closed.consts)
 
     # ------------------------------------------------------------------
     def warmup(self) -> int:
-        """AOT-compile chunk + admit + release for EVERY rung pair;
+        """AOT-compile chunk + admit + release (and ``prefill``,
+        ``admit_prefix``, ``snapshot``, ``spec_chunk`` where the pool
+        has them: :meth:`_kinds`) for EVERY rung pair;
         returns the number of compiles performed (0 on a re-warm).
         After this, a storm that stays inside the ladders never builds
         an executable again — :meth:`jit_cache_stats` ``misses`` is the
@@ -419,6 +480,8 @@ class KVSlotPool:
         compiles = 0
         for s, t in self.rung_pairs():
             for kind in self._kinds():
+                if kind == "prefill" and t <= self.prefill_tokens:
+                    continue  # no prompt on this rung holds a chunk
                 key = (kind, s, t)
                 with self._lock:
                     have = key in self._exe
@@ -527,12 +590,14 @@ class KVSlotPool:
                      kv_leaves, prefix_len: int,
                      spec: bool = False) -> Dict[str, object]:
         """Seat a request whose first ``prefix_len`` positions are
-        served from retained KV blocks (``kv_leaves``: the prefix
-        cache's stored leaf list, per :meth:`extract_kv` order): the
-        leaves are host-padded to the current length rung and installed
-        by the warmed ``admit_prefix`` executable, and the slot starts
-        at ``pos = prefix_len`` — prefill resumes at the unmatched
-        suffix.  Requires ``prefix=True`` at construction."""
+        served from a retained entry (``kv_leaves``: the prefix cache's
+        stored leaf list, per :meth:`extract_kv` order — host rows,
+        padded here to the current length rung — or, in a pool that
+        keeps :meth:`snapshot`s, the snapshot's device arrays as they
+        are, recurrent leaves included), installed by the warmed
+        ``admit_prefix`` executable; the slot starts at ``pos =
+        prefix_len`` — prefill resumes at the unmatched suffix.
+        Requires ``prefix=True`` at construction."""
         if self._admit_prefix_fn is None:
             raise RuntimeError(
                 "pool was built without prefix=True — admit_prefix has "
@@ -547,6 +612,15 @@ class KVSlotPool:
         kv = []
         for sd, ent, ax in zip(self._kv_subtree_leaves(shapes), kv_leaves,
                                self._kv_seq_axes(shapes)):
+            if self.snapshots:
+                # a snapshot's leaves are device arrays of this rung's
+                # row shapes already (:meth:`snapshot`): no host copy
+                if tuple(ent.shape) != tuple(sd.shape[1:]):
+                    raise ValueError(
+                        "snapshot leaf %s does not fit rung pair %s"
+                        % (ent.shape, (s, t)))
+                kv.append(ent)
+                continue
             if ax is None or ent is None:
                 kv.append(np.zeros((1,), np.float32))
                 continue
@@ -570,6 +644,76 @@ class KVSlotPool:
         # hot-path: end kv_admit_prefix
         return out
 
+    # ------------------------------------------------------------------
+    # chunked prefill and snapshots (builders that declare a prefill_fn)
+    # ------------------------------------------------------------------
+    def _prefill_fn(self, state, row, start, activate):
+        """The traced ``prefill``: slot ``row``'s prompt tokens ``start
+        .. start + C - 1``, read from the state's own token buffer (the
+        admit put them there), through the builder's ``prefill_fn``;
+        ``pos`` moves to ``start + C``.  The slot is HELD (inactive, so
+        the decode chunk leaves it alone) while whole chunks remain;
+        ``activate`` on its last one hands it to the step.  Nothing is
+        generated: ``n_gen`` is untouched."""
+        import jax
+        import jax.numpy as jnp
+
+        c = self.prefill_tokens
+        toks = jax.lax.dynamic_slice(state["tokens"], (row, start), (1, c))[0]
+        out = dict(state)
+        out.update(
+            cache=self._prefill(state["cache"], row, toks, start,
+                                jnp.int32(c)),
+            pos=state["pos"].at[row].set(start + c),
+            active=state["active"].at[row].set(
+                state["active"][row] | activate))
+        return out
+
+    def _snapshot_fn(self, state, slot):
+        """The traced ``snapshot``: slot ``slot``'s row of every cache
+        leaf (:meth:`_kv_subtree_leaves` order), copied."""
+        import jax
+
+        return [jax.lax.dynamic_index_in_dim(leaf, slot, 0, keepdims=False)
+                for leaf in self._kv_subtree_leaves(state)]
+
+    def can_prefill(self, state, pos: int, prompt_len: int) -> bool:
+        """Whether a slot at ``pos`` of a ``prompt_len``-token prompt
+        takes a ``prefill`` chunk next: the builder has one, the rung is
+        longer than a chunk, and a whole chunk of prompt remains with at
+        least one token after it (the step that eats the LAST prompt
+        token produces the first generated one: it must run)."""
+        c = self.prefill_tokens
+        return (c > 0 and self.state_rungs(state)[1] > c
+                and int(prompt_len) - int(pos) > c)
+
+    def prefill(self, state, slot: int, start: int,
+                activate: bool) -> Dict[str, object]:
+        """Feed slot ``slot`` its next ``prefill_tokens`` prompt tokens
+        (positions ``start ..``) in ONE dispatch; ``activate`` on the
+        slot's last whole chunk."""
+        s, t = self.state_rungs(state)
+        # hot-path: begin kv_prefill (executable lookup + async dispatch)
+        exe = self._get_exe("prefill", s, t)
+        out = exe(state, np.int32(slot), np.int32(start), np.bool_(activate))
+        # hot-path: end kv_prefill
+        return out
+
+    def snapshot(self, state, slot: int):
+        """Slot ``slot``'s whole cache row as DEVICE arrays (one per
+        cache leaf, recurrent leaves included), copied by one warmed
+        dispatch: a prefix snapshot's payload.  Valid as a prefix of
+        ``P`` positions when taken with the slot at ``pos == P``; rows
+        past ``P`` are whatever the slot held and are masked off at
+        installation.  Requires ``prefix=True`` over a builder with a
+        prefill."""
+        if not self.snapshots:
+            raise RuntimeError(
+                "pool keeps no snapshots (prefix=True over a builder "
+                "that declares make_cache.prefill_fn)")
+        s, t = self.state_rungs(state)
+        return self._get_exe("snapshot", s, t)(state, np.int32(slot))
+
     def extract_kv(self, state, slot: int, m: int):
         """Materialize slot ``slot``'s first ``m`` KV positions as host
         arrays (the prefix cache's retained-entry payload): one list
@@ -578,13 +722,14 @@ class KVSlotPool:
         among them: they are never sliced).  A control-plane d2h —
         called when a slot is FREED, off the tick's dispatch path."""
         out = []
-        for leaf, ax in zip(self._kv_subtree_leaves(state),
-                            self._kv_seq_axes(state)):
+        for leaf, ax, stride in zip(self._kv_subtree_leaves(state),
+                                    self._kv_seq_axes(state),
+                                    self._kv_seq_strides(state)):
             if ax is None:
                 out.append(None)
                 continue
             sl = [slice(None)] * (leaf.ndim - 1)
-            sl[ax - 1] = slice(0, int(m))
+            sl[ax - 1] = slice(0, int(m) // stride)
             out.append(np.asarray(leaf[slot][tuple(sl)]))
         return out
 

@@ -12,9 +12,17 @@ by a hash of the prompt-token prefix at ``block_tokens`` boundaries:
   consumed) is hashed and its KV rows extracted (one host materialize
   per retained entry — a control-plane move off the tick's hot path,
   like a rung transition).
-* **Probe** — at admission, the incoming prompt is hashed at descending
-  block boundaries; the longest match hands back retained KV leaves and
-  the admit executable installs them, so prefill drops to the unmatched
+* **Snapshot** (:meth:`put`) — a pool whose builder prefills in chunks
+  hands over, where a prompt's last whole chunk ends, the slot's whole
+  cache row as DEVICE arrays: K/V and compressed-key rows below the
+  boundary and every recurrent leaf's value AT the boundary (40-80 MB
+  an entry at a 20k-token document: nothing crosses to the host).  The
+  byte budget counts device bytes the same way.
+* **Probe** — at admission, the incoming prompt is hashed ONCE, front
+  to back, with the running digest read off at each length some entry
+  has (a 30k-token prompt is one 120 KB pass, not one pass a block);
+  the longest match hands back the retained leaves and the admit
+  executable installs them, so prefill drops to the unmatched
   suffix (the prefill-token counter is the ground truth the tests and
   bench assert on).  Hash collisions cannot serve wrong tokens: every
   entry stores its prefix tokens and a probe compares them exactly.
@@ -56,8 +64,13 @@ PREFIX_EVICTIONS = monitor.counter(
     "prefix KV entries evicted by the byte-budget LRU", _LABELS)
 PREFIX_BYTES = monitor.gauge(
     "serving_prefix_cache_bytes",
-    "bytes of retained prefix KV blocks (tokens + cache leaves) "
-    "currently held by the prefix cache", _LABELS)
+    "bytes of retained prefix KV blocks (tokens + cache leaves, host "
+    "or device) currently held by the prefix cache", _LABELS)
+PREFIX_SNAPSHOTS = monitor.counter(
+    "serving_prefix_snapshots_total",
+    "prefix entries stored as device snapshots of a slot's whole cache "
+    "row (recurrent leaves included), taken where a chunk-prefilled "
+    "prompt's last whole chunk ends", _LABELS)
 
 
 class PrefixKVCache:
@@ -85,6 +98,9 @@ class PrefixKVCache:
         # key -> {"tokens": [m] int32, "leaves": [np arrays | None],
         #         "nbytes": int}
         self._data: "OrderedDict[str, Dict[str, object]]" = OrderedDict()
+        # entry length -> entries of that length: the only lengths a
+        # probe needs a digest at
+        self._lengths: Dict[int, int] = {}
         self._bytes = 0
         self._lock = threading.Lock()
         self._hits = 0
@@ -96,6 +112,7 @@ class PrefixKVCache:
         self._c_misses = PREFIX_MISSES.labels(**lbl)
         self._c_evictions = PREFIX_EVICTIONS.labels(**lbl)
         self._g_bytes = PREFIX_BYTES.labels(**lbl)
+        self._c_snapshots = PREFIX_SNAPSHOTS.labels(**lbl)
 
     # ------------------------------------------------------------------
     def __len__(self) -> int:
@@ -106,44 +123,50 @@ class PrefixKVCache:
         return hashlib.sha1(
             np.ascontiguousarray(tokens, np.int32).tobytes()).hexdigest()
 
+    @staticmethod
+    def _prefix_keys(prompt: np.ndarray, lengths):
+        """``(m, _hash(prompt[:m]))`` for each of the ascending
+        ``lengths``, from ONE pass over the prompt: the running digest is
+        read off at each length."""
+        digest, done = hashlib.sha1(), 0
+        for m in lengths:
+            digest.update(prompt[done:m].tobytes())
+            done = m
+            yield m, digest.copy().hexdigest()
+
     # ------------------------------------------------------------------
     # hot-path: begin prefix_probe (hash + dict probes under the cache
     # lock, on the scheduler thread between ticks — pure host work, no
     # device syncs, no sleeps; the KV install itself is one warmed
     # admit_prefix dispatch)
     def probe(self, prompt) -> Tuple[int, Optional[List[np.ndarray]]]:
-        """Longest retained block-aligned proper prefix of ``prompt``:
-        ``(prefix_len, kv_leaves)``, or ``(0, None)`` on a miss.  The
-        match is capped one token short of the prompt so the suffix
-        always re-enters prefill (the step consuming the LAST prompt
-        token produces the first generated one — it must run).  Stored
+        """Longest retained proper prefix of ``prompt``: ``(prefix_len,
+        kv_leaves)``, or ``(0, None)`` on a miss.  The match is capped
+        one token short of the prompt so the suffix always re-enters
+        prefill (the step consuming the LAST prompt token produces the
+        first generated one — it must run).  The prompt is hashed in ONE
+        pass, the digest read at each length some entry has; stored
         tokens are compared exactly, so a hash collision can never
         install another prompt's KV."""
-        B = self.block_tokens
-        m = ((len(prompt) - 1) // B) * B
-        if m <= 0:
-            self._count_miss()
-            return 0, None
+        prompt = np.ascontiguousarray(prompt, np.int32)
         with self._lock:
-            while m > 0:
-                key = self._hash(prompt[:m])
+            lengths = sorted(m for m in self._lengths if m < len(prompt))
+            best = None
+            for m, key in self._prefix_keys(prompt, lengths):
                 ent = self._data.get(key)
                 if ent is not None and np.array_equal(
                         ent["tokens"], prompt[:m]):
-                    self._data.move_to_end(key)
-                    self._hits += 1
-                    self._c_hits.inc()
-                    return m, list(ent["leaves"])
-                m -= B
+                    best = (m, key, ent)
+            if best is not None:
+                m, key, ent = best
+                self._data.move_to_end(key)
+                self._hits += 1
+                self._c_hits.inc()
+                return m, list(ent["leaves"])
             self._misses += 1
             self._c_misses.inc()
         return 0, None
     # hot-path: end prefix_probe
-
-    def _count_miss(self) -> None:
-        with self._lock:
-            self._misses += 1
-        self._c_misses.inc()
 
     def count_fallback(self) -> None:
         """A prefix admission that fell back to full prefill (fault
@@ -173,8 +196,32 @@ class PrefixKVCache:
             if key in self._data:
                 self._data.move_to_end(key)
                 return False
-        leaves = extract(m)
-        tokens = prompt[:m].copy()
+        return self._store(key, prompt[:m].copy(), extract(m))
+
+    def put(self, tokens, leaves) -> bool:
+        """Retain a SNAPSHOT: ``leaves`` is the whole cache row of a
+        slot that has consumed exactly ``tokens`` (any length: where a
+        chunked prefill stopped), as device arrays — recurrent leaves
+        too, which is what lets a later prompt that starts with
+        ``tokens`` resume at ``len(tokens)`` instead of position 0.
+        Returns True when a new entry was stored."""
+        tokens = np.array(tokens, np.int32).reshape(-1)
+        if len(tokens) < 1:
+            return False
+        stored = self._store(self._hash(tokens), tokens, list(leaves))
+        if stored:
+            self._c_snapshots.inc()
+        return stored
+
+    def holds(self, tokens) -> bool:
+        """Whether an entry for exactly ``tokens`` is retained (the
+        scheduler asks before paying for a snapshot)."""
+        tokens = np.ascontiguousarray(tokens, np.int32).reshape(-1)
+        with self._lock:
+            ent = self._data.get(self._hash(tokens))
+            return ent is not None and np.array_equal(ent["tokens"], tokens)
+
+    def _store(self, key: str, tokens: np.ndarray, leaves) -> bool:
         nbytes = int(tokens.nbytes) + sum(
             int(leaf.nbytes) for leaf in leaves if leaf is not None)
         with self._lock:
@@ -183,11 +230,14 @@ class PrefixKVCache:
                 return False
             self._data[key] = {
                 "tokens": tokens, "leaves": leaves, "nbytes": nbytes}
+            self._lengths[len(tokens)] = self._lengths.get(
+                len(tokens), 0) + 1
             self._bytes += nbytes
             evicted = 0
             while self._bytes > self.capacity_bytes and self._data:
                 _, ev = self._data.popitem(last=False)
                 self._bytes -= int(ev["nbytes"])
+                self._forget_length(len(ev["tokens"]))
                 evicted += 1
             if evicted:
                 self._evictions += evicted
@@ -195,12 +245,20 @@ class PrefixKVCache:
             self._g_bytes.set(float(self._bytes))
         return True
 
+    def _forget_length(self, m: int) -> None:
+        left = self._lengths.get(m, 0) - 1
+        if left > 0:
+            self._lengths[m] = left
+        else:
+            self._lengths.pop(m, None)
+
     # ------------------------------------------------------------------
     def invalidate(self) -> None:
         """Drop every entry — the endpoint-reload path: retained KV from
         the previous weights must never seed a new decode."""
         with self._lock:
             self._data.clear()
+            self._lengths.clear()
             self._bytes = 0
             self._g_bytes.set(0.0)
 
@@ -225,8 +283,9 @@ class PrefixKVCache:
         """Retire this cache's series from the exposition."""
         lbl = {"cache": self.name}
         for metric in (PREFIX_HITS, PREFIX_MISSES, PREFIX_EVICTIONS,
-                       PREFIX_BYTES):
+                       PREFIX_BYTES, PREFIX_SNAPSHOTS):
             metric.remove_labels(**lbl)
         with self._lock:
             self._data.clear()
+            self._lengths.clear()
             self._bytes = 0

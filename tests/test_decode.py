@@ -984,3 +984,157 @@ def test_decode_fleet_two_children_stream_and_zero_recompiles(
         assert all(be.in_flight == 0 for be in fb._backends)
     finally:
         fb.stop(shutdown_backends=True)
+
+
+# ---------------------------------------------------------------------------
+# chunked prefill and prefix snapshots, on a model small enough to check
+# by arithmetic: what a builder DECLARES is what the pool and the
+# scheduler do (the real builder: tests/test_sparse_linear_lm.py)
+# ---------------------------------------------------------------------------
+SUM_V, SUM_C = 13, 4
+
+
+def running_sum_model(with_prefill=True):
+    """next token = (sum of every token consumed so far) % V.  The sum
+    is a RECURRENT leaf ``r`` (zero at position 0, kept for an idle row);
+    ``z`` is a sequence leaf holding the consumed tokens and ``zz`` one
+    that advances a row every 2 positions.  ``prefill_fn`` feeds
+    ``SUM_C`` tokens of one row at once."""
+    import jax
+    import jax.numpy as jnp
+
+    def step_fn(cache, tokens, ts):
+        live, rows = ts >= 0, jnp.arange(tokens.shape[0])
+        r = jnp.where(ts == 0, 0, cache["r"]) + tokens
+        r = jnp.where(live, r, cache["r"])
+        at = jnp.where(live, ts, cache["z"].shape[1])
+        z = cache["z"].at[rows, at].set(tokens.astype("float32"),
+                                        mode="drop")
+        zz = cache["zz"].at[rows, jnp.where(live & (ts % 2 == 1), ts // 2,
+                                            cache["zz"].shape[1])].set(
+            tokens.astype("float32"), mode="drop")
+        return jax.nn.one_hot(r % SUM_V, SUM_V) * 10.0, {
+            "r": r, "z": z, "zz": zz}
+
+    def make_cache(n_rows, seq_len):
+        return {"r": jnp.zeros((n_rows,), "int32"),
+                "z": jnp.zeros((n_rows, seq_len), "float32"),
+                "zz": jnp.zeros((n_rows, seq_len // 2), "float32")}
+
+    def prefill_fn(cache, row, tokens, start, n_valid):
+        r0 = jnp.where(start == 0, 0, cache["r"][row])
+        z = jax.lax.dynamic_update_slice(
+            cache["z"], tokens.astype("float32")[None], (row, start))
+        zz = jax.lax.dynamic_update_slice(
+            cache["zz"], tokens.astype("float32")[None, 1::2],
+            (row, start // 2))
+        return {"r": cache["r"].at[row].set(r0 + tokens.sum()), "z": z,
+                "zz": zz}
+
+    make_cache.leaf_seq_axes = {"r": -1, "z": 1, "zz": 1}
+    make_cache.leaf_seq_strides = {"r": 1, "z": 1, "zz": 2}
+    if with_prefill:
+        prefill_fn.chunk_tokens = SUM_C
+        make_cache.prefill_fn = prefill_fn
+    return step_fn, make_cache
+
+
+def _sum_chain(prompt, n):
+    total, out = int(np.sum(prompt)), []
+    for _ in range(n):
+        out.append(total % SUM_V)
+        total += out[-1]
+    return out
+
+
+def _sum_server(name, **kw):
+    step_fn, make_cache = running_sum_model(kw.pop("with_prefill", True))
+    return DecodeServer(step_fn, make_cache, eos_id=SUM_V, max_seq_len=32,
+                        max_slots=2, slot_ladder=[2], len_ladder=[32],
+                        steps_per_tick=2, name=name, **kw)
+
+
+@pytest.mark.parametrize("n_prompt,chunks", [
+    (3, 0), (SUM_C, 0), (SUM_C + 1, 1), (2 * SUM_C, 1), (3 * SUM_C + 2, 3)])
+def test_prompts_are_prefilled_in_whole_chunks_and_the_rest_by_steps(
+        n_prompt, chunks):
+    """A chunk runs only while a whole one fits with a token to spare
+    (the step that eats the last prompt token makes the first generated
+    one); what it serves is what stepping serves."""
+    prompt = np.arange(1, n_prompt + 1, dtype=np.int32) % SUM_V
+    with _sum_server("sum-%d" % n_prompt) as srv:
+        assert srv.warmup() == 4     # chunk, admit, release + prefill
+        got = srv.submit({"tokens": prompt}, max_new_tokens=6).result(30)
+        assert got[0].tolist() == _sum_chain(prompt, 6)
+        m = srv.metrics()
+        assert m["decode"]["prefill_chunks"] == chunks
+        assert m["decode"]["prefill_tokens"] == n_prompt
+        assert m["decode"]["generated_tokens"] == 6
+        assert m["recompiles"] == 0
+
+
+def test_a_builder_without_a_prefill_keeps_its_three_executables():
+    with _sum_server("sum-plain", with_prefill=False) as srv:
+        assert srv.warmup() == 3
+        prompt = np.arange(1, 12, dtype=np.int32)
+        got = srv.submit({"tokens": prompt}, max_new_tokens=5).result(30)
+        assert got[0].tolist() == _sum_chain(prompt, 5)
+        assert srv.metrics()["decode"]["prefill_chunks"] == 0
+    step_fn, make_cache = running_sum_model(with_prefill=False)
+    with pytest.raises(ValueError, match="make_cache.prefill_fn"):
+        KVSlotPool(step_fn, make_cache, eos_id=SUM_V, max_slots=2,
+                   max_seq_len=32, prefix=True)
+
+
+def test_a_snapshot_carries_the_recurrent_leaf_to_the_next_request():
+    """Two prompts share their first 8 tokens: the first is prefilled in
+    chunks and leaves ONE snapshot at position 8 (its last whole chunk's
+    end), the second starts there — its running sum installed, not
+    rebuilt — and both serve the arithmetic's tokens.  A third, in the
+    slot the second left, shares nothing and starts from zero."""
+    head = np.array([3, 1, 4, 1, 5, 9, 2, 6], np.int32)
+    a = np.concatenate([head, [5, 3]]).astype(np.int32)
+    b = np.concatenate([head, [7]]).astype(np.int32)
+    c = np.array([2, 2, 2], np.int32)
+    with _sum_server("sum-snap", prefix_cache=1 << 20) as srv:
+        assert srv.warmup() == 6     # + admit_prefix and snapshot
+        for prompt, hits, chunks in ((a, 0, 2), (b, 1, 2), (c, 1, 2)):
+            got = srv.submit({"tokens": prompt},
+                             max_new_tokens=5).result(30)
+            assert got[0].tolist() == _sum_chain(prompt, 5)
+            m = srv.metrics()["decode"]
+            assert m["prefix_cache"]["hits"] == hits
+            assert m["prefill_chunks"] == chunks
+        assert m["prefix_cache"]["entries"] == 1
+        assert m["state_resets"] == 2      # a and c; b resumed a state
+        assert m["prefill_tokens"] == len(a) + 1 + len(c)
+
+
+def test_a_strided_leaf_is_installed_by_its_own_row_count():
+    """The legacy (host rows) installation over a leaf that advances a
+    row every 2 positions: a prefix of 6 positions is 3 of its rows."""
+    import jax
+
+    step_fn, make_cache = running_sum_model(with_prefill=False)
+    del make_cache.leaf_seq_axes["r"], make_cache.leaf_seq_strides["r"]
+    plain = lambda n, t: {k: v for k, v in make_cache(n, t).items()
+                          if k != "r"}
+    plain.leaf_seq_axes = make_cache.leaf_seq_axes
+    plain.leaf_seq_strides = make_cache.leaf_seq_strides
+    step = lambda cache, tok, ts: (
+        jax.nn.one_hot(tok % SUM_V, SUM_V), cache)
+    pool = KVSlotPool(step, plain, eos_id=SUM_V, max_slots=2,
+                      max_seq_len=16, slot_ladder=[2], len_ladder=[16],
+                      prefix=True)
+    st = pool.alloc(2, 16)
+    st["cache"]["z"][1, :8] = np.arange(8) + 1
+    st["cache"]["zz"][1, :4] = np.arange(4) + 10
+    kv = pool.extract_kv(st, 1, 6)
+    assert kv[0].tolist() == [1, 2, 3, 4, 5, 6]
+    assert kv[1].tolist() == [10, 11, 12]
+    out = pool.admit_prefix(pool.alloc(2, 16), 0, np.arange(9), 9, 12,
+                            [np.full(16, 7.0, "float32"),
+                             np.full(8, 9.0, "float32")], 6)
+    assert np.asarray(out["cache"]["z"])[0].tolist() == [7.0] * 6 + [0.0] * 10
+    assert np.asarray(out["cache"]["zz"])[0].tolist() == [9.0] * 3 + [0.0] * 5
+    assert int(np.asarray(out["pos"])[0]) == 6

@@ -291,6 +291,62 @@ def test_chunk_hands_idle_slots_to_the_step_as_minus_one():
 
 
 # ---------------------------------------------------------------------------
+# "read the blocks named for this row": the block kernel against the XLA
+# form of the same read
+# ---------------------------------------------------------------------------
+def _named_blocks(rng, ts, n_blocks, b, g, block):
+    """Per slot and head ``b`` distinct blocks, the one its position
+    falls in among them (the rule always names it), some marked
+    invalid."""
+    blocks = np.stack([[rng.permutation(n_blocks)[:b] for _ in range(g)]
+                       for _ in ts]).astype(np.int32)
+    blocks[..., 0] = np.maximum(np.asarray(ts), 0)[:, None] // block
+    for row in blocks.reshape(-1, b):   # keep the named blocks distinct
+        dup = np.flatnonzero(row[1:] == row[0])
+        row[1 + dup] = (row[0] + 1 + np.arange(len(dup))) % n_blocks
+    valid = rng.rand(*blocks.shape) > 0.25
+    valid[..., 0] = True
+    return blocks, valid
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_block_kernel_matches_the_gathered_read(dtype):
+    """Interpret mode, at the kernel's own tile sizes: a head of 128
+    lanes, blocks of 64 rows, 16 query heads a K/V head."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(11)
+    s, t, g, d, rep, block, b = 4, 512, 2, 128, 16, 64, 6
+    ts = np.array([300, -1, 77, 511], np.int32)
+    kv = {n: jnp.asarray(rng.randn(s, t, g * d), dtype) for n in "kv"}
+    q = jnp.asarray(rng.randn(s, g * rep * d), jnp.float32)
+    blocks, valid = _named_blocks(rng, ts, t // block, b, g, block)
+    assert da.block_kernel_supported(kv, g * rep, g, block)
+    named = dict(n_head=g * rep, n_kv_head=g, scale=0.1, block=block)
+    want = da._gathered_block_attention(
+        q, kv, jnp.asarray(ts), jnp.asarray(blocks), jnp.asarray(valid),
+        **named)
+    got = da.block_sparse_decode_attention(
+        q, kv["k"], kv["v"], jnp.asarray(ts), jnp.asarray(blocks),
+        jnp.asarray(valid), interpret=True, **named)
+    live = ts >= 0
+    tol = 1e-5 if dtype == "float32" else 2e-2   # bf16 probabilities
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=tol)
+    assert not np.asarray(got)[~live].any()      # an idle slot reads nothing
+
+
+def test_block_kernel_is_refused_for_shapes_it_cannot_tile():
+    import jax.numpy as jnp
+
+    kv = {"k": jnp.zeros((2, 64, 32), jnp.float32)}
+    assert not da.block_kernel_supported(kv, 4, 2, 8)      # heads of 16
+    kv = {"k": jnp.zeros((2, 64, 256), jnp.bfloat16)}
+    assert not da.block_kernel_supported(kv, 8, 2, 64)     # 4 heads a group
+    assert da.block_kernel_supported(kv, 32, 2, 64)
+
+
+# ---------------------------------------------------------------------------
 # the kernel at the benchmark's widths, compiled for a described v5e chip
 # (no chip attached: nothing runs, the chip's compiler accepts or refuses)
 # ---------------------------------------------------------------------------
@@ -342,6 +398,32 @@ def test_kernel_compiles_for_v5e_at_gpt1_widths(one_chip):
     leaf = s * t * d * 4
     assert mem.alias_size_in_bytes >= 2 * leaf
     assert mem.temp_size_in_bytes < leaf // 8
+
+
+def test_block_kernel_compiles_for_v5e_at_minicpm_sala_widths(one_chip):
+    """64 slots x 32768 positions x 2 K/V heads of 128, 16 query heads a
+    K/V head, 98 named blocks of 64: the kernel lowers and reads the
+    leaves where they lie (no temporary of a leaf's size)."""
+    import jax
+    import jax.numpy as jnp
+
+    s, t, g, d, rep, block, b = 64, 32768, 2, 128, 16, 64, 98
+
+    def f(q, kc, vc, ts, blocks, valid):
+        return da.block_sparse_decode_attention(
+            q, kc, vc, ts, blocks, valid, n_head=g * rep, n_kv_head=g,
+            scale=0.088, block=block)
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(f).lower(
+        sd((s, g * rep * d)), sd((s, t, g * d), jnp.bfloat16),
+        sd((s, t, g * d), jnp.bfloat16), sd((s,), jnp.int32),
+        sd((s, g, b), jnp.int32), sd((s, g, b), jnp.bool_)).compile()
+    assert "block_sparse_decode_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        s * t * g * d * 2) // 8
 
 
 def test_hybrid_ssm_layer_compiles_for_v5e_at_falcon_h1_widths(one_chip):

@@ -151,6 +151,9 @@ def test_hash_collision_never_serves_wrong_tokens(monkeypatch):
         # only thing standing between two different prompts
         monkeypatch.setattr(PrefixKVCache, "_hash",
                             staticmethod(lambda tokens: "same"))
+        monkeypatch.setattr(
+            PrefixKVCache, "_prefix_keys", staticmethod(
+                lambda prompt, lengths: [(m, "same") for m in lengths]))
         a = np.arange(8, dtype=np.int32)
         b = a + 100
         assert c.offer(a, consumed=8, extract=_leaves)
@@ -626,3 +629,62 @@ def test_fleet_two_children_all_modes_zero_recompiles(
             assert st["jit_cache"]["misses"] == 0, st["jit_cache"]
     finally:
         fb.stop(shutdown_backends=True)
+
+
+# ---------------------------------------------------------------------------
+# snapshots and the one-pass probe (PR 31)
+# ---------------------------------------------------------------------------
+def test_probe_hashes_once_at_the_lengths_entries_have():
+    """The running digest read at an entry's length is ``_hash`` of
+    that prefix, whatever lengths came before it."""
+    prompt = np.arange(1000, dtype=np.int32) * 7
+    lengths = [16, 48, 512, 999]
+    keys = dict(PrefixKVCache._prefix_keys(prompt, lengths))
+    assert keys == {m: PrefixKVCache._hash(prompt[:m]) for m in lengths}
+
+
+@pytest.mark.parametrize("n_prompt,want", [(40, 32), (33, 32), (32, 8),
+                                           (20, 8), (8, 0)])
+def test_probe_returns_the_longest_snapshot_that_is_a_proper_prefix(
+        n_prompt, want):
+    """Snapshots sit at whatever length a prefill stopped (here 8 and
+    32, not multiples of ``block_tokens``); the match is capped one
+    token short of the prompt."""
+    c = PrefixKVCache(capacity_bytes=1 << 20, block_tokens=16,
+                      name="u-snap-%d" % n_prompt)
+    try:
+        base = np.arange(64, dtype=np.int32)
+        for m in (8, 32):
+            assert c.put(base[:m], [np.full((2,), m, np.float32)])
+        assert not c.put(base[:8], [np.zeros(2, np.float32)])  # held already
+        assert c.holds(base[:32]) and not c.holds(base[:16])
+        m, kv = c.probe(base[:n_prompt])
+        assert m == want
+        assert (kv is None) if want == 0 else (int(kv[0][0]) == want)
+        other = base.copy()
+        other[3] += 1                    # differs inside every snapshot
+        assert c.probe(other[:n_prompt]) == (0, None)
+    finally:
+        c.close()
+
+
+def test_snapshot_bytes_evictions_and_lengths():
+    """The budget counts the leaves' bytes (device arrays' too), and an
+    evicted entry's length stops being probed."""
+    import jax.numpy as jnp
+
+    c = PrefixKVCache(capacity_bytes=3000, block_tokens=4, name="u-snap-b")
+    try:
+        a, b = np.arange(10, dtype=np.int32), np.arange(100, 120,
+                                                        dtype=np.int32)
+        assert c.put(a, [jnp.zeros((500,), jnp.float32)])    # 2040 bytes
+        assert c.stats()["bytes"] == 2040 and c._lengths == {10: 1}
+        assert c.put(b, [jnp.zeros((600,), jnp.float32), None])
+        s = c.stats()
+        assert (s["entries"], s["evictions"], s["bytes"]) == (1, 1, 2480)
+        assert c._lengths == {20: 1}
+        assert c.probe(np.concatenate([a, [1]]).astype(np.int32))[0] == 0
+        c.invalidate()
+        assert c._lengths == {} and c.stats()["bytes"] == 0
+    finally:
+        c.close()

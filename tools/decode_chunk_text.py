@@ -68,7 +68,8 @@ def canonical(hlo_text: str) -> str:
         lambda m: names.setdefault(m.group(0), "%%v%d" % len(names)), text)
 
 
-def lowered_chunk(repo: str, config: str):
+def lowered_chunk(repo: str, config: str, kind: str = "chunk",
+                  layers: int = 2):
     sys.path.insert(0, repo)
     import jax
     import jax.numpy as jnp
@@ -93,10 +94,25 @@ def lowered_chunk(repo: str, config: str):
         cfg = json.load(fh)
     sv = cfg["serving"]
     (slots,), (seq_len,) = sv["slot_ladder"], sv["len_ladder"]
-    if cfg["family"] == "pooled_hybrid_ssm_lm":
+    if cfg["family"] == "pooled_sparse_linear_lm":
+        from paddle_tpu import sparse_linear_lm
+
+        # one layer of each kind first: the first ``layers`` entries
+        cfg["mixer_types"] = cfg["mixer_types"][:layers]
+        cfg["num_hidden_layers"] = len(cfg["mixer_types"])
+        weights = {n: sd(shp, jnp.bfloat16 if len(shp) == 2
+                         else jnp.float32)
+                   for n, shp in sparse_linear_lm.param_shapes(cfg).items()}
+
+        def build(w):
+            return decoding.make_sparse_linear_lm_pooled_step_fn(
+                w, cfg, kv_dtype=sv["kv_dtype"],
+                state_dtype=cfg["assumed"]["lightning_state_dtype"],
+                prefill_tokens=sv["prefill_tokens"])[:2]
+    elif cfg["family"] == "pooled_hybrid_ssm_lm":
         from paddle_tpu import hybrid_ssm
 
-        cfg["num_hidden_layers"] = 2
+        cfg["num_hidden_layers"] = layers
         # as the family makes them: matrices bf16, vectors and the conv
         # kernel fp32
         weights = {n: sd(shp, jnp.bfloat16 if len(shp) == 2
@@ -110,7 +126,7 @@ def lowered_chunk(repo: str, config: str):
     else:
         import numpy as np
 
-        dims = (cfg["vocab_size"], cfg["n_embd"], 2, cfg["n_head"],
+        dims = (cfg["vocab_size"], cfg["n_embd"], layers, cfg["n_head"],
                 cfg["assumed"]["n_inner"])
         weights = {k: sd(a.shape) for k, a in
                    decoding.random_transformer_lm_state(
@@ -126,6 +142,16 @@ def lowered_chunk(repo: str, config: str):
         return decoding.make_slot_decode_fns(
             step_fn, int(cfg["vocab_size"]), sv["steps_per_tick"])[0](state)
 
+    def prefill(w, state):
+        # one slot's next chunk of prompt tokens, as the pool runs it
+        # (KVSlotPool._prefill_fn) for a builder that declares one
+        fn = build(w)[1].prefill_fn
+        c = fn.chunk_tokens
+        return dict(state, cache=fn(
+            state["cache"], jnp.int32(3),
+            jax.lax.dynamic_slice(state["tokens"], (3, c), (1, c))[0],
+            state["pos"][3], jnp.int32(c)))
+
     i32, flag = jnp.int32, jnp.bool_
     state = {
         "cache": jax.tree.map(
@@ -135,7 +161,8 @@ def lowered_chunk(repo: str, config: str):
         "prompt_len": sd((slots,), i32), "total_len": sd((slots,), i32),
         "active": sd((slots,), flag), "finished": sd((slots,), flag),
         "n_gen": sd((slots,), i32)}
-    return jax.jit(chunk, donate_argnums=(1,)).lower(weights, state)
+    return jax.jit({"chunk": chunk, "prefill": prefill}[kind],
+                   donate_argnums=(1,)).lower(weights, state)
 
 
 def main():
@@ -145,9 +172,17 @@ def main():
     ap.add_argument("out")
     ap.add_argument("--repo", default=os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    ap.add_argument("--kind", default="chunk", choices=("chunk", "prefill"),
+                    help="prefill: the chunked-prefill program of a "
+                    "builder that has one (minicpm_sala)")
+    ap.add_argument("--layers", type=int, default=2,
+                    help="layers compiled (8: the whole minicpm_sala cut, "
+                    "to see that the real program fits the chip)")
     args = ap.parse_args()
-    text = canonical(lowered_chunk(os.path.abspath(args.repo),
-                                   args.config).compile().as_text())
+    compiled = lowered_chunk(os.path.abspath(args.repo), args.config,
+                             args.kind, args.layers).compile()
+    print(compiled.memory_analysis())
+    text = canonical(compiled.as_text())
     with open(args.out, "w") as fh:
         fh.write(text)
     print("%s: %d bytes, %s" % (

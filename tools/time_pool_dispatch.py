@@ -18,6 +18,13 @@ take as many array arguments as the cell's):
     python tools/time_pool_dispatch.py gpt1_117m
     python tools/time_pool_dispatch.py gpt1_117m --repo .parent_copy \\
         --one-call-a-request
+    python tools/time_pool_dispatch.py minicpm_sala
+
+For a pool that prefills in chunks and keeps snapshots (``minicpm_sala``)
+it also times one ``prefill`` chunk, one ``snapshot``, one
+``admit_prefix`` over a snapshot, the scheduler's per-tick fetch of its
+view of the state (``tokens`` is ``[slots, rung]`` int32: 8.4 MB there)
+and, on the host alone, ``PrefixKVCache.probe`` of a 30k-token prompt.
 
 ``--repo`` imports ``paddle_tpu`` and the benchmark's family from
 another checkout; ``--one-call-a-request`` seats a batch the way a
@@ -102,17 +109,28 @@ def main():
         args.rehearse_cpu)
     family = harness.load_py(os.path.join(
         root, "benchmark", "families", cfg["family"] + ".py"), cfg["family"])
-    if cfg["family"] != "pooled_decode_lm":
-        sys.exit("time_pool_dispatch: only the pooled_decode_lm family")
     sv, vocab = cfg["serving"], int(cfg["vocab_size"])
-    weights = family.make_weights(cfg, dev)
-    step_fn, make_cache = decoding.make_transformer_lm_pooled_step_fn(
-        weights, vocab, cfg["n_embd"], cfg["n_layer"], cfg["n_head"],
-        cfg["assumed"]["n_inner"], kv_dtype=sv["kv_dtype"])
+    if cfg["family"] == "pooled_decode_lm":
+        weights = family.make_weights(cfg, dev)
+        step_fn, make_cache = decoding.make_transformer_lm_pooled_step_fn(
+            weights, vocab, cfg["n_embd"], cfg["n_layer"], cfg["n_head"],
+            cfg["assumed"]["n_inner"], kv_dtype=sv["kv_dtype"])
+    elif cfg["family"] == "pooled_sparse_linear_lm":
+        build, parts = family.builder()
+        weights = family.make_weights(cfg, dev, parts)
+        step_fn, make_cache, _ = build(
+            weights, cfg, kv_dtype=sv["kv_dtype"],
+            state_dtype=cfg["assumed"]["lightning_state_dtype"],
+            prefill_tokens=int(sv["prefill_tokens"]))
+    else:
+        sys.exit("time_pool_dispatch: no builder for family %r"
+                 % cfg["family"])
     s, t = sv["slot_ladder"][-1], sv["len_ladder"][-1]
+    snapshots = getattr(make_cache, "prefill_fn", None) is not None
     pool = KVSlotPool(step_fn, make_cache, eos_id=vocab, max_slots=s,
                       max_seq_len=t, slot_ladder=[s], len_ladder=[t],
-                      steps=sv["steps_per_tick"], kv_dtype=sv["kv_dtype"])
+                      steps=sv["steps_per_tick"], kv_dtype=sv["kv_dtype"],
+                      **({"prefix": True} if snapshots else {}))
     t0 = time.perf_counter()
     pool.warmup()
     warm_s = time.perf_counter() - t0
@@ -158,13 +176,52 @@ def main():
             samples[name + ".call"].append(call_s)
             samples[name + ".ready"].append(ready_s)
 
+    if snapshots:
+        from paddle_tpu.serving.prefix_cache import PrefixKVCache
+
+        def timed(name, fn, ready):
+            t0 = time.perf_counter()
+            out = fn()
+            t1 = time.perf_counter()
+            jax.block_until_ready(ready(out))
+            samples[name + ".call"].append(t1 - t0)
+            samples[name + ".ready"].append(time.perf_counter() - t0)
+            return out
+
+        c = pool.prefill_tokens
+        long_prompt = rng.randint(0, vocab, t - 2 * c).astype(np.int32)
+        head = long_prompt[:(len(long_prompt) // c - 1) * c]
+        cache = PrefixKVCache(capacity_bytes=1 << 40, name="tool")
+        for r in range(min(args.reps, 10)):
+            state = pool.admit(state, 0, long_prompt, len(long_prompt), t)
+            state = pool.release(state, [0])
+            jax.block_until_ready(state["pos"])
+            # a chunk in the middle of a long prompt: its attend reads
+            # half the rung
+            state = timed("prefill_mid_rung", lambda: pool.prefill(
+                state, 0, (t // 2 // c) * c, False), lambda st: st["pos"])
+            snap = timed("snapshot", lambda: pool.snapshot(state, 0),
+                         lambda leaves: leaves)
+            cache.invalidate()
+            cache.put(head, snap)
+            timed("probe_%d_tokens" % len(long_prompt),
+                  lambda: cache.probe(long_prompt), lambda hit: hit[1])
+            state = timed("admit_prefix", lambda: pool.admit_prefix(
+                state, 0, long_prompt, len(long_prompt), t, snap,
+                len(head)), lambda st: st["pos"])
+            timed("tick_view_fetch", lambda: jax.device_get(
+                {k: state[k] for k in ("tokens", "pos", "active",
+                                       "finished", "n_gen")}),
+                lambda view: [])
+        cache.close()
+
     trace_dir = tempfile.mkdtemp(prefix="pool_dispatch_trace_")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
     for r in range(10):
         with jax.profiler.TraceAnnotation("tool/admit_1"):
-            state, _, _ = admit(state, [r])
+            state, _, _ = admit(state, [r % s])
         with jax.profiler.TraceAnnotation("tool/admit_n"):
             state, _, _ = admit(state, batch)
         with jax.profiler.TraceAnnotation("tool/chunk"):
