@@ -46,10 +46,13 @@ sequence axis and ``serving_recurrent_state_bytes`` for those without,
 ``serving_decode_state_resets_total``, one per admission into a pool
 that has recurrent leaves, and ``serving_decode_admitted_total`` over
 ``serving_decode_admit_dispatches_total``, the requests one admit
-dispatch carried: a turn seats everything it pops in one) on top of
-the standard ``ServingMetrics`` series; per-tick ``serving/decode_tick``
-spans carry the active requests' trace ids; the ``decode.step`` fault
-point injects failures into the tick dispatch for chaos coverage.
+dispatch carried: a turn seats everything it pops in one, and
+``serving_pool_constants_placed_total``, the host-born constants the
+pool's lowering put on the device once so that no dispatch sends them
+again) on top of the standard ``ServingMetrics`` series; per-tick
+``serving/decode_tick`` spans carry the active requests' trace ids; the
+``decode.step`` fault point injects failures into the tick dispatch for
+chaos coverage.
 
 Decode tier 2 (both independently toggleable, see README):
 
@@ -159,6 +162,13 @@ DECODE_ADMITTED = monitor.counter(
     "requests seated into pool slots; over "
     "serving_decode_admit_dispatches_total it is the requests one "
     "admit dispatch carried", _LABELS)
+POOL_CONSTANTS_PLACED = monitor.counter(
+    "serving_pool_constants_placed_total",
+    "host-born constants (numpy arrays a step closes over, hoisted to "
+    "executable arguments) the slot pool copied to the device ONCE at "
+    "lowering, one per distinct constant, so that no dispatch sends "
+    "them again; 0 for a step that closes over device arrays only",
+    _LABELS)
 DECODE_RECURRENT_BYTES = monitor.gauge(
     "serving_recurrent_state_bytes",
     "bytes of the pool's recurrent cache leaves (no sequence axis) at "
@@ -184,7 +194,8 @@ DECODE_SPARSE_LIVE = monitor.counter(
     "layer", _LABELS)
 
 # safety-net bound while parked on the empty-queue condition (real
-# wakeups are offer()/wake() notifies)
+# wakeups are offer()/wake() notifies); a server with nothing seated
+# that sees no arrival for this long is idle and drops its pool state
 _IDLE_WAIT_S = 0.5
 
 _END = ("end", None)
@@ -345,6 +356,8 @@ class DecodeServer:
         self._recurrent_bytes_g = DECODE_RECURRENT_BYTES.labels(**lbl)
         self._admit_dispatches_c = DECODE_ADMIT_DISPATCHES.labels(**lbl)
         self._admitted_c = DECODE_ADMITTED.labels(**lbl)
+        self._constants_placed_c = POOL_CONSTANTS_PLACED.labels(**lbl)
+        self._constants_placed_seen = 0
         self._prefill_chunks_c = DECODE_PREFILL_CHUNKS.labels(**lbl)
         self._sparse_read_c = DECODE_SPARSE_READ.labels(**lbl)
         self._sparse_live_c = DECODE_SPARSE_LIVE.labels(**lbl)
@@ -376,7 +389,7 @@ class DecodeServer:
             len_ladder=len_ladder, steps=steps_per_tick,
             prefix=self._prefix is not None, speculative=speculative,
             kv_dtype=kv_dtype, len_multiple=len_multiple,
-            on_recompile=lambda: self._metrics.count("recompiles"))
+            on_recompile=self._on_recompile)
         # a pool without a chunked prefill never holds a slot: its
         # turns run what they always ran
         self._chunked = self._pool.prefill_tokens > 0
@@ -543,6 +556,17 @@ class DecodeServer:
                            "slots": len(self._slots)}}
 
     # ------------------------------------------------------------------
+    def _count_constants_placed(self) -> None:
+        """Bring ``serving_pool_constants_placed_total`` up to what the
+        pool has placed (it places at lowering: warmup, or a recompile)."""
+        placed = self._pool.constants_placed
+        self._constants_placed_c.inc(placed - self._constants_placed_seen)
+        self._constants_placed_seen = placed
+
+    def _on_recompile(self) -> None:
+        self._metrics.count("recompiles")
+        self._count_constants_placed()
+
     def warmup(self, configure_cache: bool = True) -> int:
         """Pre-compile chunk/admit/release for every (slot, length) rung
         pair; arms the recompile counter (any executable built after
@@ -550,6 +574,7 @@ class DecodeServer:
         if configure_cache:
             compile_cache.configure()
         compiles = self._pool.warmup()
+        self._count_constants_placed()
         self._metrics.count("warmup_compiles", compiles)
         self._warmed = True
         return compiles
@@ -677,17 +702,24 @@ class DecodeServer:
                     if self._stop.is_set() and (
                             self._abort or self._batcher.qsize() == 0):
                         return
-                    # idle: drop the pool state (frees the KV memory;
-                    # the next admit re-allocs at the smallest rungs)
-                    self._state = None
-                    self._slots = []
                     self._occupancy_g.set(0.0)
-                    self._set_pool_bytes(None)
                     cv = self._batcher.queue.cv
                     with cv:
-                        if (self._batcher.queue.qsize() == 0
-                                and not self._stop.is_set()):
-                            cv.wait(timeout=_IDLE_WAIT_S)
+                        arrived = (self._batcher.queue.qsize() > 0
+                                   or self._stop.is_set()
+                                   or cv.wait(timeout=_IDLE_WAIT_S))
+                    if not arrived:
+                        # idle: drop the pool state (frees the KV
+                        # memory; the next admit re-allocs at the
+                        # smallest rungs).  Only after a whole wait
+                        # with no arrival: a gap between two requests
+                        # is not idleness, and the state is re-made on
+                        # the host and carried to the device again —
+                        # 8 s for gpt1_117m's 12 GB, paid by whoever
+                        # arrives next (v5e chip run, PR 32)
+                        self._state = None
+                        self._slots = []
+                        self._set_pool_bytes(None)
                     continue
                 if self._abort:
                     self._fail_in_flight(
@@ -1098,6 +1130,7 @@ class DecodeServer:
                        DECODE_TTFT, DECODE_OCCUPANCY, DECODE_KV_BYTES,
                        DECODE_STATE_RESETS, DECODE_RECURRENT_BYTES,
                        DECODE_ADMIT_DISPATCHES, DECODE_ADMITTED,
+                       POOL_CONSTANTS_PLACED,
                        DECODE_PREFILL_CHUNKS, DECODE_SPARSE_READ,
                        DECODE_SPARSE_LIVE):
             metric.remove_labels(**lbl)

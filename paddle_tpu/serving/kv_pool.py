@@ -192,6 +192,13 @@ class KVSlotPool:
         # many as it builds leaves, is refused here
         self._kv_seq_axes(self._state_spec(*self.rung_pairs()[0]))
         self._exe: Dict[Tuple[str, int, int], object] = {}
+        # host-born constants :meth:`_lower` hoisted: the pool's one
+        # device copy of each distinct one, how many copies it made, and
+        # per executable kind what it found (count, bytes)
+        self._placed: Dict[tuple, object] = {}
+        #: device copies made of host-born hoisted constants
+        self.constants_placed = 0
+        self._host_born: Dict[str, Tuple[int, int]] = {}
         self._lock = threading.Lock()
         self._hits = 0
         self._misses = 0
@@ -444,9 +451,25 @@ class KVSlotPool:
         Hoisted, every rung pair's executable takes the SAME device
         arrays: one copy of the weights however long the ladders.
 
+        What the trace hoists is not only weights.  A numpy array the
+        step closes over (the two 0/1 indicator matrices a layer that
+        ``decode_attention.ragged_decode_attention`` hands its kernel)
+        comes out HOST-BORN, and an executable bound to it copies it to
+        the device again on every call, one transfer each, before the
+        program can start: ``gpt1_117m``'s ``chunk`` paid 24 of them,
+        2.6-2.8 ms of a 3.4-3.6 ms call with the chip idle (v5e chip
+        runs, PR 30).  So every hoisted constant that is not a
+        ``jax.Array`` already is placed ONCE, here, where the compiled
+        executable expects that argument (:meth:`_place`), and the
+        executable is bound to the device arrays; a ``jax.Array`` (the
+        weights, whatever their sharding) is bound as it is.  The
+        program is lowered from the constants as the trace gave them,
+        so the HLO and its compile-cache key are what they were.
+
         The state argument is DONATED so the KV cache updates in place —
         except on CPU, where donation + the persistent compile cache is
-        known-unsafe (executor._donate_kwargs pins the policy)."""
+        known-unsafe (executor._donate_kwargs pins the policy).  The
+        constants are never donated: they outlive every state."""
         import functools
 
         import jax
@@ -466,7 +489,40 @@ class KVSlotPool:
                   if donate and _donate_kwargs(jax.devices()[0]) else {})
         exe = jax.jit(hoisted, **donate).lower(
             closed.consts, *arg_specs).compile()
-        return functools.partial(exe, closed.consts)
+        wanted = exe.input_shardings[0][0]  # of ``consts``, one each
+        host_born = [np.asarray(c).nbytes for c in closed.consts
+                     if not isinstance(c, jax.Array)]
+        with self._lock:
+            self._host_born[kind] = max(self._host_born.get(kind, (0, 0)),
+                                        (len(host_born), sum(host_born)))
+        return functools.partial(exe, [
+            c if isinstance(c, jax.Array) else self._place(c, sharding)
+            for c, sharding in zip(closed.consts, wanted)])
+
+    def _place(self, const, sharding):
+        """The pool's ONE device copy of the host-born constant
+        ``const`` under ``sharding``: equal constants (shape, dtype and
+        bytes) of every layer, executable kind and rung pair share it —
+        a long ladder would otherwise hold 24 copies of two 196 KB
+        matrices per kind and rung pair."""
+        import jax
+
+        host = np.asarray(const)
+        key = (host.shape, host.dtype.name, host.tobytes(), sharding)
+        with self._lock:
+            placed = self._placed.get(key)
+            if placed is None:
+                placed = self._placed[key] = jax.device_put(host, sharding)
+                self.constants_placed += 1
+        return placed
+
+    def host_born_constants(self) -> Dict[str, Tuple[int, int]]:
+        """``{kind: (count, bytes)}`` of the hoisted constants that were
+        NOT on a device when ``kind`` was lowered (the most over the
+        rung pairs built so far): what every call of that executable
+        would send the chip again had :meth:`_lower` not placed them."""
+        with self._lock:
+            return dict(self._host_born)
 
     # ------------------------------------------------------------------
     def warmup(self) -> int:

@@ -576,19 +576,22 @@ def test_kv_position_counters_follow_live_blocks():
     try:
         assert kv_read_block(T) == KV_BLOCK
         lengths = [(3, KV_BLOCK - 1), (5, KV_BLOCK + 7), (2, 40)]
-        reqs = [srv.submit({"tokens": np.arange(p, dtype=np.int32)},
-                           max_new_tokens=total - p)
-                for p, total in lengths]
         ticks, pool, seen = 0, 0, []
-        while not all(r.done() for r in reqs):
-            d = srv.metrics()["decode"]
-            if d["ticks"] != ticks:
-                # both counters advance once a tick, by a whole pool
-                assert d["kv_positions_pool"] > pool
-                assert d["kv_positions_read"] > 0
-                ticks, pool = d["ticks"], d["kv_positions_pool"]
-                seen.append(d["kv_positions_read"])
-            time.sleep(0.001)
+        # one turn at a time (a poll from here can miss every tick of
+        # so small a model when the machine is busy)
+        with turn_held(srv) as run:
+            reqs = [srv.submit({"tokens": np.arange(p, dtype=np.int32)},
+                               max_new_tokens=total - p)
+                    for p, total in lengths]
+            while not all(r.done() for r in reqs):
+                run(1)
+                d = srv.metrics()["decode"]
+                if d["ticks"] != ticks:
+                    # both counters advance once a tick, by a whole pool
+                    assert d["kv_positions_pool"] > pool
+                    assert d["kv_positions_read"] > 0
+                    ticks, pool = d["ticks"], d["kv_positions_pool"]
+                    seen.append(d["kv_positions_read"])
         for r in reqs:
             r.result(timeout=30.0)
         assert seen == sorted(seen) and len(set(seen)) > 1

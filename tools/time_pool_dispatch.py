@@ -11,6 +11,11 @@ take as many array arguments as the cell's):
   6): seconds until the call RETURNS (the host's share) and until its
   result is READY (what the chip waits between two chunks);
 * ``chunk``: the same two, with every slot live;
+* per executable kind, how many of the constants ``KVSlotPool._lower``
+  hoisted were HOST-BORN (numpy arrays the step closes over) and their
+  bytes: before PR 32 every call sent each of them to the chip again
+  (one ``DevicePut`` of ~0.11 ms each), since then the pool places
+  them once (``constants_placed``: the device copies it made);
 * a profiler trace over a few of each, reduced to the host events that
   ran on the calling thread inside the calls (argument handling, the
   h2d of host arguments, the runtime's ``Execute``), by name.
@@ -19,6 +24,7 @@ take as many array arguments as the cell's):
     python tools/time_pool_dispatch.py gpt1_117m --repo .parent_copy \\
         --one-call-a-request
     python tools/time_pool_dispatch.py minicpm_sala
+    python tools/time_pool_dispatch.py falcon_h1_34b
 
 For a pool that prefills in chunks and keeps snapshots (``minicpm_sala``)
 it also times one ``prefill`` chunk, one ``snapshot``, one
@@ -78,6 +84,68 @@ def host_events_inside(xplane_path, prefixes):
             for p, rows in out.items()}
 
 
+def build_step(root, config, rehearse):
+    """``(cfg, weights, step_fn, make_cache)`` of the decode
+    configuration ``config`` as its benchmark family builds it from the
+    checkout at ``root``: the published widths with the weights on the
+    first device, or (``rehearse``) the configuration's tiny sizes.
+    ``root`` must be importable (``benchmark``, ``paddle_tpu``)."""
+    import jax
+
+    from benchmark.lib import harness
+    from paddle_tpu import decoding
+
+    dev = jax.devices()[0]
+    cfg = harness.load_config(os.path.join(
+        root, "benchmark", "configs", config + ".json"), rehearse)
+    family = harness.load_py(os.path.join(
+        root, "benchmark", "families", cfg["family"] + ".py"), cfg["family"])
+    sv = cfg["serving"]
+    if cfg["family"] == "pooled_decode_lm":
+        weights = family.make_weights(cfg, dev)
+        step_fn, make_cache = decoding.make_transformer_lm_pooled_step_fn(
+            weights, int(cfg["vocab_size"]), cfg["n_embd"], cfg["n_layer"],
+            cfg["n_head"], cfg["assumed"]["n_inner"],
+            kv_dtype=sv["kv_dtype"])
+    elif cfg["family"] == "pooled_hybrid_ssm_lm":
+        build, parts = family.builder()
+        weights = family.make_weights(cfg, dev, parts)
+        step_fn, make_cache = build(
+            weights, cfg, kv_dtype=sv["kv_dtype"],
+            ssm_state_dtype=cfg["assumed"]["ssm_state_dtype"])
+    elif cfg["family"] == "pooled_sparse_linear_lm":
+        build, parts = family.builder()
+        weights = family.make_weights(cfg, dev, parts)
+        step_fn, make_cache, _ = build(
+            weights, cfg, kv_dtype=sv["kv_dtype"],
+            state_dtype=cfg["assumed"]["lightning_state_dtype"],
+            prefill_tokens=int(sv["prefill_tokens"]))
+    else:
+        sys.exit("time_pool_dispatch: no builder for family %r"
+                 % cfg["family"])
+    return cfg, weights, step_fn, make_cache
+
+
+def host_born_constants(pool, s, t):
+    """``{kind: [count, bytes]}`` of the constants each of the pool's
+    executables at rung pair ``(s, t)`` was lowered with that were not
+    on a device (``KVSlotPool._lower`` hoists every closed-over array
+    to an argument): the pool's own record, or, in a checkout before
+    PR 32, what each executable is still bound to and sends again on
+    every call."""
+    import jax
+    import numpy as np
+
+    if hasattr(pool, "host_born_constants"):
+        return {k: list(v) for k, v in pool.host_born_constants().items()}
+    out = {}
+    for (kind, es, et), exe in sorted(pool._exe.items()):
+        if (es, et) == (s, t):
+            host = [c for c in exe.args[0] if not isinstance(c, jax.Array)]
+            out[kind] = [len(host), sum(np.asarray(c).nbytes for c in host)]
+    return out
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("config")
@@ -97,34 +165,15 @@ def main():
     import numpy as np
 
     from benchmark.lib import harness
-    from paddle_tpu import decoding
     from paddle_tpu.serving.kv_pool import KVSlotPool
 
     harness.configure_jax(args.rehearse_cpu)
     dev = jax.devices()[0]
     if not args.rehearse_cpu and dev.platform != "tpu":
         sys.exit("time_pool_dispatch: needs the chip (or --rehearse-cpu)")
-    cfg = harness.load_config(os.path.join(
-        root, "benchmark", "configs", args.config + ".json"),
-        args.rehearse_cpu)
-    family = harness.load_py(os.path.join(
-        root, "benchmark", "families", cfg["family"] + ".py"), cfg["family"])
+    cfg, weights, step_fn, make_cache = build_step(
+        root, args.config, args.rehearse_cpu)
     sv, vocab = cfg["serving"], int(cfg["vocab_size"])
-    if cfg["family"] == "pooled_decode_lm":
-        weights = family.make_weights(cfg, dev)
-        step_fn, make_cache = decoding.make_transformer_lm_pooled_step_fn(
-            weights, vocab, cfg["n_embd"], cfg["n_layer"], cfg["n_head"],
-            cfg["assumed"]["n_inner"], kv_dtype=sv["kv_dtype"])
-    elif cfg["family"] == "pooled_sparse_linear_lm":
-        build, parts = family.builder()
-        weights = family.make_weights(cfg, dev, parts)
-        step_fn, make_cache, _ = build(
-            weights, cfg, kv_dtype=sv["kv_dtype"],
-            state_dtype=cfg["assumed"]["lightning_state_dtype"],
-            prefill_tokens=int(sv["prefill_tokens"]))
-    else:
-        sys.exit("time_pool_dispatch: no builder for family %r"
-                 % cfg["family"])
     s, t = sv["slot_ladder"][-1], sv["len_ladder"][-1]
     snapshots = getattr(make_cache, "prefill_fn", None) is not None
     pool = KVSlotPool(step_fn, make_cache, eos_id=vocab, max_slots=s,
@@ -239,6 +288,8 @@ def main():
            "device": {"platform": dev.platform, "kind": dev.device_kind},
            "rung_pair": [s, t], "batch": len(batch),
            "state_and_weight_arrays": n_args, "warmup_s": warm_s,
+           "host_born_constants": host_born_constants(pool, s, t),
+           "constants_placed": getattr(pool, "constants_placed", None),
            "host_events_inside_ms_each": inside,
            "times": {k: _ms(v) for k, v in sorted(samples.items())}}
     if args.rehearse_cpu:
