@@ -56,6 +56,8 @@ from typing import Callable, Optional, Tuple
 
 import numpy as np
 
+from paddle_tpu.monitor import registry as _registry
+
 __all__ = [
     "beam_search", "greedy_search", "make_program_logits_fn",
     "beam_search_cached", "greedy_search_cached",
@@ -82,6 +84,13 @@ KV_DTYPES = ("fp32", "int8", "bf16")
 _LM_KV_DTYPES = ("fp32", "int8")
 #: what ``decode_attention.kv_leaves`` stores each of them as
 _KV_STORAGE = {"fp32": "float32", "int8": "int8", "bf16": "bfloat16"}
+
+WEIGHT_COPIES = _registry.REGISTRY.counter(
+    "decode_weight_copies_total",
+    "weight matrices a pooled transformer-LM builder copied to bfloat16 "
+    "at build (one per matrix: the dtype the backend takes their products "
+    "in, so no step casts them again); 0 where the step multiplies its "
+    "weights as stored (the CPU, a raised matmul precision)")
 
 
 def normalize_kv_dtype(kv_dtype, supported=KV_DTYPES) -> str:
@@ -360,7 +369,18 @@ def _lm_forward_one(W, name, cache, x, n_layer, attend):
 
 
 def _fc(W, x, pname):
-    return x @ W[pname + "_w"] + W[pname + "_b"]
+    """``x @ w + b`` in the products ``w`` is stored for: a bf16 matrix
+    (a copy :func:`_pooled_lm_parts` made) takes ``x`` rounded to bf16
+    and accumulates in fp32 — what a TPU makes of the fp32 product at
+    its default precision, minus the cast of ``w``; any other matrix is
+    multiplied as the backend multiplies its dtype."""
+    import jax.numpy as jnp
+
+    w = W[pname + "_w"]
+    if w.dtype == jnp.bfloat16:
+        return jnp.dot(x.astype(jnp.bfloat16), w,
+                       preferred_element_type=jnp.float32) + W[pname + "_b"]
+    return x @ w + W[pname + "_b"]
 
 
 def _ln(W, x, pname):
@@ -372,12 +392,46 @@ def _ln(W, x, pname):
     return y * W[pname + "_scale"] + W[pname + "_bias"]
 
 
+def _products_are_bf16(backend: str, precision) -> bool:
+    """Whether ``backend`` rounds both operands of an fp32 matmul to
+    bf16 under the process's ``jax_default_matmul_precision``: a TPU at
+    the default does (one MXU pass, fp32 accumulation); the CPU, and a
+    TPU asked for anything higher, do not."""
+    return backend == "tpu" and precision in (None, "default")
+
+
+def _multiplied_matrices(name, n_layer):
+    """The keys of every matrix :func:`_lm_forward_one` multiplies (its
+    :func:`_fc` calls): six a layer and the head.  The embedding tables
+    are gathered, never multiplied."""
+    return ["%s_dec_%d%s_w" % (name, i, m) for i in range(n_layer)
+            for m in ("_att_q", "_att_k", "_att_v", "_att_out",
+                      "_ffn_fc0", "_ffn_fc1")] + [name + "_head_w"]
+
+
 def _pooled_lm_parts(state, d_model, n_layer, n_head, name, kv_dtype):
     """What the pooled step and the K-wide verify forward of one model
     share: ``forward(cache, x, ts)`` — :func:`_lm_forward_one` over the
     fresh rows ``x`` ([S, d_model] at ``ts``, or [S, K, d_model] at
     ``ts .. ts + K - 1``) with ``decode_attention``'s append and read —
-    the weights, and ``make_cache``."""
+    the weights, and ``make_cache``.
+
+    The weights are held in the dtype their products are taken in.
+    Where the backend would round an fp32 matmul operand to bf16 anyway
+    (:func:`_products_are_bf16`: a TPU at the default matmul precision,
+    read HERE, at build), every fp32 matrix the forward multiplies — q,
+    k, v, out and both FFN matrices of each layer, and the head — is
+    copied to bf16 ONCE, by one jitted cast where the weights live, and
+    the step closes over the copies (:func:`_fc` multiplies a matrix as
+    stored).  XLA:TPU otherwise makes the same copies inside every
+    ``chunk`` call: at ``gpt1_117m`` 73 matrices, 0.47 GB read and 0.23
+    GB written per call, 15% of the chip's busy time at 10 live slots.
+    The copies cost 2 bytes a matrix element of HBM for the builder's
+    life (0.23 GB there) beside the caller's fp32 ``state``, which this
+    function never changes.  Biases, LayerNorm vectors and both
+    embedding tables stay as given; so does every matrix that is not
+    fp32, and everything on a backend that multiplies fp32 as fp32 — no
+    copy, ``decode_weight_copies_total`` unmoved."""
     import jax
     import jax.numpy as jnp
 
@@ -387,6 +441,16 @@ def _pooled_lm_parts(state, d_model, n_layer, n_head, name, kv_dtype):
     kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, _LM_KV_DTYPES)]
     d_head = d_model // n_head
     W = {k: jnp.asarray(v) for k, v in state.items()}
+    if _products_are_bf16(jax.default_backend(),
+                          jax.config.jax_default_matmul_precision):
+        copied = [k for k in _multiplied_matrices(name, n_layer)
+                  if W[k].dtype == jnp.float32]
+        # each copy where its original lives, whatever its sharding
+        W.update(zip(copied, jax.jit(
+            lambda ws: [w.astype(jnp.bfloat16) for w in ws],
+            out_shardings=[W[k].sharding for k in copied])(
+                [W[k] for k in copied])))
+        WEIGHT_COPIES.inc(len(copied))
     scale = 1.0 / float(np.sqrt(d_head))
 
     def make_cache(n_rows: int, seq_len: int):

@@ -764,6 +764,7 @@ class DecodeServer:
         new_s, new_t = cur or (0, 0)
         popped: List[DecodeRequest] = []
         seats = []                   # (slot, req, pre_len, pre_kv)
+        probes = []                  # one per prefix lookup: did it hit
         try:
             while None in slots or len(slots) < pool.max_slots:
                 with self._batcher.queue.cv:
@@ -778,7 +779,8 @@ class DecodeServer:
                 popped.append(req)
                 pre_len, pre_kv = 0, None
                 if self._prefix is not None:
-                    pre_len, pre_kv = self._prefix.probe(req.prompt)
+                    pre_len, pre_kv = self._prefix.lookup(req.prompt)
+                    probes.append(pre_len > 0)
                 new_t = max(new_t, pool.len_policy.bucket_for(req.total_len))
                 if None not in slots:
                     new_s = pool.slot_policy.bucket_for(new_s + 1)
@@ -821,6 +823,8 @@ class DecodeServer:
                 req.fail(exc)
             self._metrics.count("failed", len(popped))
             self._fail_and_drop_pool(exc)
+            for hit in probes:
+                self._prefix.count_probe(hit)
             return
         for slot, req, pre_len, _ in seats:
             self._admit_seq += 1
@@ -829,6 +833,14 @@ class DecodeServer:
             # the shared-prefix win, measured where it happens: only the
             # unmatched suffix re-enters prefill (in chunks or by steps)
             self._prefill_c.inc(len(req.prompt) - pre_len)
+        # the turn's lookups are counted HERE, beside its admissions and
+        # not at the pop: a turn of five prefix admissions is ~12 ms of
+        # hashing and dispatches, and a thread that read both counters
+        # inside it saw five hits that no admission matched yet
+        # (minicpm_sala's window check failed on that: v5e chip run,
+        # PR 35)
+        for hit in probes:
+            self._prefix.count_probe(hit)
         self._admitted_c.inc(len(seats))
         if pool.recurrent_leaves:
             # a slot seated over a snapshot resumes a state: no reset

@@ -140,6 +140,13 @@ class PrefixKVCache:
     # device syncs, no sleeps; the KV install itself is one warmed
     # admit_prefix dispatch)
     def probe(self, prompt) -> Tuple[int, Optional[List[np.ndarray]]]:
+        """:meth:`lookup`, counted at once as a hit or a miss
+        (:meth:`count_probe`)."""
+        hit = self.lookup(prompt)
+        self.count_probe(hit[0] > 0)
+        return hit
+
+    def lookup(self, prompt) -> Tuple[int, Optional[List[np.ndarray]]]:
         """Longest retained proper prefix of ``prompt``: ``(prefix_len,
         kv_leaves)``, or ``(0, None)`` on a miss.  The match is capped
         one token short of the prompt so the suffix always re-enters
@@ -147,7 +154,9 @@ class PrefixKVCache:
         first generated one — it must run).  The prompt is hashed in ONE
         pass, the digest read at each length some entry has; stored
         tokens are compared exactly, so a hash collision can never
-        install another prompt's KV."""
+        install another prompt's KV.  Counts nothing: the caller owes
+        one :meth:`count_probe` (``DecodeServer`` pays it where it
+        counts the admission, so the two counters move together)."""
         prompt = np.ascontiguousarray(prompt, np.int32)
         with self._lock:
             lengths = sorted(m for m in self._lengths if m < len(prompt))
@@ -160,12 +169,18 @@ class PrefixKVCache:
             if best is not None:
                 m, key, ent = best
                 self._data.move_to_end(key)
+                return m, list(ent["leaves"])
+        return 0, None
+
+    def count_probe(self, hit: bool) -> None:
+        """One :meth:`lookup` counted: a hit or a miss."""
+        with self._lock:
+            if hit:
                 self._hits += 1
                 self._c_hits.inc()
-                return m, list(ent["leaves"])
-            self._misses += 1
-            self._c_misses.inc()
-        return 0, None
+            else:
+                self._misses += 1
+                self._c_misses.inc()
     # hot-path: end prefix_probe
 
     def count_fallback(self) -> None:

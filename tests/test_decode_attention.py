@@ -476,6 +476,37 @@ def test_hybrid_ssm_layer_compiles_for_v5e_at_falcon_h1_widths(one_chip):
     assert mem.temp_size_in_bytes < ssm_leaf // 2
 
 
+def test_gpt1_chunk_compiles_for_v5e_with_no_cast_of_a_weight(one_chip,
+                                                             monkeypatch):
+    """The slot pool's ``chunk`` of ``gpt1_117m`` (its one rung pair, the
+    published widths, two layers) as ``tools/decode_chunk_text.py``
+    builds it for a TPU: the builder holds bf16 copies of the matrices
+    it multiplies, so the chip's compiler leaves no instruction that
+    casts a whole weight-shaped matrix to bf16 (13 before the copies:
+    six a layer and the head, run again on every call), and the ragged
+    kernel is still the attention."""
+    import importlib.util
+    import os
+    import sys
+
+    import jax
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "decode_chunk_text", os.path.join(root, "tools",
+                                          "decode_chunk_text.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    # the tool answers "tpu" for the backend it compiles for and puts
+    # the checkout on the path: both undone when the test ends
+    monkeypatch.setattr(jax, "default_backend", jax.default_backend)
+    monkeypatch.setattr(sys, "path", list(sys.path))
+    lowered = tool.lowered_chunk(root, "gpt1_117m")
+    text = lowered.compile().as_text()
+    assert "ragged_decode_attention" in text
+    assert tool.weight_casts(lowered, text) == 0
+
+
 @pytest.mark.parametrize("shape", [(32, 12, 512, 64), (128, 12, 128, 64)],
                          ids=["pretrain_s512", "pretrain_s128"])
 def test_fused_attention_kernels_compile_for_v5e_at_bert_widths(one_chip,

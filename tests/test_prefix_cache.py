@@ -264,6 +264,58 @@ def test_shared_prefix_admit_cuts_prefill_and_keeps_parity(lm_state):
         srv.stop(drain=False)
 
 
+def test_prefix_hits_are_counted_beside_the_admissions_they_seat(lm_state):
+    """A thread that reads ``serving_prefix_cache_hits_total`` and
+    ``serving_decode_admitted_total`` while a turn is being seated (its
+    ``admit_prefix`` dispatches run one after another) sees the two
+    move together: no hit is counted before the admissions of its turn
+    are (a benchmark's window check compares their deltas)."""
+    from paddle_tpu import monitor
+
+    step_fn, make_cache = make_transformer_lm_pooled_step_fn(
+        lm_state, V, LM["d_model"], LM["n_layer"], LM["n_head"],
+        LM["d_inner"])
+    srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=24,
+                       max_slots=4, steps_per_tick=2, name="lm-prefix-c",
+                       prefix_cache=PrefixKVCache(
+                           capacity_bytes=1 << 20, block_tokens=4,
+                           name="lm-prefix-c"))
+
+    def counters():
+        return (monitor.counter_value("serving_prefix_cache_hits_total",
+                                      cache="lm-prefix-c"),
+                monitor.counter_value("serving_decode_admitted_total",
+                                      server="lm-prefix-c"))
+
+    try:
+        srv.warmup(configure_cache=False)
+        prefix = np.random.RandomState(5).randint(2, V, 8).astype(np.int32)
+        first = np.concatenate([prefix, [3, 5]]).astype(np.int32)
+        srv.submit({"tokens": first}, max_new_tokens=4).result(timeout=60.0)
+        assert _wait(lambda: srv.prefix_cache.stats()["entries"] >= 1)
+        h0, a0 = counters()
+        seen = []
+        inner = srv._pool.admit_prefix
+
+        def watched(*args, **kwargs):
+            h, a = counters()
+            seen.append((h - h0, a - a0))
+            return inner(*args, **kwargs)
+
+        srv._pool.admit_prefix = watched
+        reqs = [srv.submit({"tokens": np.concatenate(
+            [prefix, [7, t]]).astype(np.int32)}, max_new_tokens=4)
+            for t in (2, 4, 6)]
+        for r in reqs:
+            r.result(timeout=60.0)
+        assert len(seen) == 3
+        assert all(h == a for h, a in seen), seen
+        h1, a1 = counters()
+        assert h1 - h0 == a1 - a0 == 3
+    finally:
+        srv.stop(drain=False)
+
+
 # ---------------------------------------------------------------------------
 # speculative decoding: greedy-exact parity on a real LM
 # ---------------------------------------------------------------------------

@@ -25,6 +25,15 @@ source position taken out:
 another checkout (this file need not exist there).  Nothing runs: equal
 text says the two checkouts hand the chip the same program, not how fast
 it is.
+
+The last line also counts the instructions that cast a whole
+weight-shaped matrix to bf16: work a ``chunk`` call repeats when its
+step does not hold its weights in the dtype of their products.
+``gpt1_117m`` (two layers): 13 before PR 35 (six a layer and the head),
+0 since — its builder makes those copies once, at build, which is why
+the tool builds THAT step outside the program and hoists what it closes
+over, as the pool does; ``falcon_h1_34b`` and ``minicpm_sala`` multiply
+weights as stored: 0, and their text was equal at PR 35 and its parent.
 """
 import argparse
 import base64
@@ -128,14 +137,18 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
 
         dims = (cfg["vocab_size"], cfg["n_embd"], layers, cfg["n_head"],
                 cfg["assumed"]["n_inner"])
-        weights = {k: sd(a.shape) for k, a in
-                   decoding.random_transformer_lm_state(
-                       np.random.RandomState(0), *dims,
-                       cfg["n_positions"]).items()}
+        # this builder may work at BUILD (one bf16 copy of every matrix
+        # it multiplies), so it is built outside the program, over
+        # weights that exist (here, on the CPU), and the program takes
+        # what the step closes over as arguments (``hoisted`` below)
+        parts = decoding.make_transformer_lm_pooled_step_fn(
+            decoding.random_transformer_lm_state(
+                np.random.RandomState(0), *dims, cfg["n_positions"]),
+            *dims, kv_dtype=sv["kv_dtype"])
+        weights = {}
 
         def build(w):
-            return decoding.make_transformer_lm_pooled_step_fn(
-                w, *dims, kv_dtype=sv["kv_dtype"])
+            return parts
 
     def chunk(w, state):
         step_fn, _ = build(w)
@@ -161,8 +174,32 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
         "prompt_len": sd((slots,), i32), "total_len": sd((slots,), i32),
         "active": sd((slots,), flag), "finished": sd((slots,), flag),
         "n_gen": sd((slots,), i32)}
-    return jax.jit({"chunk": chunk, "prefill": prefill}[kind],
-                   donate_argnums=(1,)).lower(weights, state)
+    # what the program closes over — the step's own weights where it
+    # was built outside, the numpy constants of every step — is hoisted
+    # to arguments, as the pool does (``KVSlotPool._lower``), instead of
+    # being baked into the text
+    closed, out = jax.make_jaxpr({"chunk": chunk, "prefill": prefill}[kind],
+                                 return_shape=True)(weights, state)
+
+    def hoisted(consts, w, st):
+        return jax.tree.unflatten(jax.tree.structure(out), jax.core.eval_jaxpr(
+            closed.jaxpr, consts, *jax.tree.leaves((w, st))))
+
+    return jax.jit(hoisted, donate_argnums=(2,)).lower(
+        [sd(c.shape, c.dtype) for c in closed.consts], weights, state)
+
+
+def weight_casts(lowered, text: str) -> int:
+    """How many instructions of the compiled program cast to bf16 a
+    whole matrix shaped like one of the program's weight arguments
+    (``bf16[a,b] convert(...)``): what a step repeats on every call
+    when it does not hold its weights in the dtype of their products."""
+    import jax
+
+    shapes = {tuple(a.shape) for a in jax.tree.leaves(
+        lowered.args_info[0][:2]) if len(a.shape) == 2}  # consts, weights
+    return sum((int(a), int(b)) in shapes for a, b in re.findall(
+        r"= bf16\[(\d+),(\d+)\]\S* convert\(", text))
 
 
 def main():
@@ -179,15 +216,17 @@ def main():
                     help="layers compiled (8: the whole minicpm_sala cut, "
                     "to see that the real program fits the chip)")
     args = ap.parse_args()
-    compiled = lowered_chunk(os.path.abspath(args.repo), args.config,
-                             args.kind, args.layers).compile()
+    lowered = lowered_chunk(os.path.abspath(args.repo), args.config,
+                            args.kind, args.layers)
+    compiled = lowered.compile()
     print(compiled.memory_analysis())
     text = canonical(compiled.as_text())
     with open(args.out, "w") as fh:
         fh.write(text)
-    print("%s: %d bytes, %s" % (
+    print("%s: %d bytes, %s, %d whole-matrix casts to bf16" % (
         args.out, len(text), "ragged_decode_attention kernel"
-        if "ragged_decode_attention" in text else "no kernel"))
+        if "ragged_decode_attention" in text else "no kernel",
+        weight_casts(lowered, text)))
 
 
 if __name__ == "__main__":

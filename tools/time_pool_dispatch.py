@@ -16,6 +16,13 @@ take as many array arguments as the cell's):
   bytes: before PR 32 every call sent each of them to the chip again
   (one ``DevicePut`` of ~0.11 ms each), since then the pool places
   them once (``constants_placed``: the device copies it made);
+* ``weight_copies``: the matrices the builder copied to the dtype of
+  their products at build (``decode_weight_copies_total``; since PR 35
+  ``gpt1_117m`` 73 on the chip, where a ``chunk`` used to cast them
+  all again), and ``tokens_sha1``: a digest of every slot's tokens
+  after the timed calls, which are the same calls over the same prompts
+  whatever the checkout — equal digests at two checkouts say the chip
+  served the same tokens;
 * a profiler trace over a few of each, reduced to the host events that
   ran on the calling thread inside the calls (argument handling, the
   h2d of host arguments, the runtime's ``Execute``), by name.
@@ -42,6 +49,7 @@ output is one JSON object.
 import argparse
 import collections
 import glob
+import hashlib
 import json
 import os
 import statistics
@@ -165,6 +173,7 @@ def main():
     import numpy as np
 
     from benchmark.lib import harness
+    from paddle_tpu import monitor
     from paddle_tpu.serving.kv_pool import KVSlotPool
 
     harness.configure_jax(args.rehearse_cpu)
@@ -290,6 +299,10 @@ def main():
            "state_and_weight_arrays": n_args, "warmup_s": warm_s,
            "host_born_constants": host_born_constants(pool, s, t),
            "constants_placed": getattr(pool, "constants_placed", None),
+           "weight_copies": monitor.counter_value(
+               "decode_weight_copies_total"),
+           "tokens_sha1": hashlib.sha1(np.ascontiguousarray(
+               jax.device_get(state["tokens"])).tobytes()).hexdigest(),
            "host_events_inside_ms_each": inside,
            "times": {k: _ms(v) for k, v in sorted(samples.items())}}
     if args.rehearse_cpu:
