@@ -1,0 +1,2 @@
+"""Per-layer metric ``turn_deliver_ms.offline``: see ``benchmark/lib/readers_turn.turn_deliver_ms``."""
+from benchmark.lib.readers_turn import turn_deliver_ms as read  # noqa: F401

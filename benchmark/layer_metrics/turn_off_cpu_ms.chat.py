@@ -1,0 +1,2 @@
+"""Per-layer metric ``turn_off_cpu_ms.chat``: see ``benchmark/lib/readers_turn.turn_off_cpu_ms``."""
+from benchmark.lib.readers_turn import turn_off_cpu_ms as read  # noqa: F401

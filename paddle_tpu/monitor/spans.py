@@ -40,11 +40,29 @@ span recorded after-the-fact picks up ``current_parent()``.  The stack
 also accepts a FOREIGN id — a wire server pushes the remote parent
 parsed from the request's W3C ``traceparent`` header, so a
 cross-process span tree keeps one connected hierarchy per trace id.
+
+Two clocks.  A span recorded after the fact (``record_span``: the
+``executor/*`` and ``predictor/*`` phases, ``serving/queue_wait``, the
+wire spans) lives on the host's clock only: jax's profiler starts its
+own clock with each profile, so a wall-clock ``ts`` cannot be joined to
+an ``.xplane.pb`` afterwards.  A span that is OPENED (``open_span``, and
+``span()`` over it) is also entered and left as a
+``jax.profiler.TraceAnnotation`` on the calling thread, so under
+``jax.profiler.start_trace`` it lands on that thread's line of the
+``/host:CPU`` plane, on the clock the device's ops share: the phases of
+a ``DecodeServer`` turn are opened this way, and an xprof / Perfetto
+view of the profile shows them above the device's ops.  A span that
+ENCLOSES other opened spans is opened with ``annotate=False`` (the
+turn's ``serving/decode_tick``): a reader that names a device gap after
+the host event covering most of it would give every gap the enclosing
+span's name.
 """
 from __future__ import annotations
 
 import collections
 import contextlib
+import itertools
+import os
 import threading
 import time
 import uuid
@@ -52,7 +70,8 @@ from typing import Deque, Dict, List, Optional, Sequence
 
 __all__ = [
     "recording", "start_recording", "stop_recording", "record_span",
-    "record_instant", "span", "session_dropped", "dropped_total",
+    "record_instant", "span", "open_span",
+    "session_dropped", "dropped_total",
     "trace_context", "current_trace_ids", "capture",
     "set_thread_lane", "thread_lanes",
     "new_span_id", "push_parent", "pop_parent", "current_parent",
@@ -198,10 +217,63 @@ def record_instant(name: str, cat: str = "host", **args) -> None:
     record_span(name, time.perf_counter(), 0.0, cat=cat, instant=True, **args)
 
 
+class open_span:
+    """A span opened now (or as of ``t0``, an earlier perf_counter
+    reading) that the caller ``close``s (or ``cancel``s).  The one place
+    a span is put on the profiler's clock as well (module docstring): it
+    is entered as a ``jax.profiler.TraceAnnotation`` unless ``annotate``
+    is false.  While it is open its id is on the thread's parent stack,
+    so what the thread records meanwhile nests under it; open and close
+    it on one thread, innermost first.  For call sites that gate on
+    ``recording()`` themselves and enter no context manager when nothing
+    records; ``span()`` is the context-manager form."""
+
+    __slots__ = ("name", "cat", "id", "t0", "_cpu0", "_annotation")
+
+    def __init__(self, name: str, cat: str = "host", annotate: bool = True,
+                 cpu: bool = False, t0: Optional[float] = None):
+        self.name, self.cat = name, cat
+        self._annotation = None
+        if annotate:
+            import jax
+
+            self._annotation = jax.profiler.TraceAnnotation(name)
+            self._annotation.__enter__()
+        self.id = push_parent()
+        self._cpu0 = time.thread_time() if cpu else None
+        self.t0 = time.perf_counter() if t0 is None else t0
+
+    def close(self, error: bool = False, end: Optional[float] = None,
+              **args) -> float:
+        """Record the span, ``args`` attached, and return where it
+        ended: now, or at ``end`` (a perf_counter reading).  Spans that
+        tile an enclosing one share their edges: each is opened at the
+        ``t0`` the last one's ``close`` returned, and the enclosing one
+        is closed at that ``end``.  One opened with ``cpu=True`` also
+        carries ``cpu_s``, the thread's CPU seconds over it: ``dur -
+        cpu_s`` is the time the thread wanted to run and did not (the
+        interpreter lock, the machine)."""
+        if end is None:
+            end = time.perf_counter()
+        if self._cpu0 is not None:
+            args["cpu_s"] = time.thread_time() - self._cpu0
+        self.cancel()
+        record_span(self.name, self.t0, end - self.t0, cat=self.cat,
+                    error=error, span_id=self.id, **args)
+        return end
+
+    def cancel(self) -> None:
+        """Leave the span without recording it."""
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        pop_parent()
+
+
 @contextlib.contextmanager
 def span(name: str, cat: str = "host", **args):
-    """Context-manager form; spans that exit via exception are flagged
-    ``error=True``.  Near-zero-cost when no session is active.
+    """Context-manager form of ``open_span``; spans that exit via
+    exception are flagged ``error=True``.  Near-zero-cost when no
+    session is active.
 
     The span's id is pushed onto the parent stack while the body runs,
     so spans recorded inside nest under it (a real parent edge, not a
@@ -209,8 +281,7 @@ def span(name: str, cat: str = "host", **args):
     if not recording():
         yield
         return
-    t0 = time.perf_counter()
-    sid = push_parent()
+    sp = open_span(name, cat)
     err = False
     try:
         yield
@@ -218,9 +289,7 @@ def span(name: str, cat: str = "host", **args):
         err = True
         raise
     finally:
-        pop_parent()
-        record_span(name, t0, time.perf_counter() - t0, cat=cat, error=err,
-                    span_id=sid, **args)
+        sp.close(error=err, **args)
 
 
 # ---------------------------------------------------------------------------
@@ -252,9 +321,24 @@ def current_trace_ids() -> tuple:
 # ---------------------------------------------------------------------------
 # span hierarchy: per-thread parent stack
 # ---------------------------------------------------------------------------
+_id_counter = itertools.count(1)
+_id_process = ""
+
+
+def _new_id_process() -> None:
+    global _id_process
+    _id_process = uuid.uuid4().hex[:8]
+
+
+_new_id_process()
+os.register_at_fork(after_in_child=_new_id_process)
+
+
 def new_span_id() -> str:
-    """Mint a 16-hex span id (same shape as a trace id, distinct space)."""
-    return uuid.uuid4().hex[:16]
+    """Mint a 16-hex span id (same shape as a trace id, distinct
+    space): eight digits drawn once a process, eight from a process
+    counter.  An opened span mints one, so it is no ``uuid4()`` each."""
+    return "%s%08x" % (_id_process, next(_id_counter) & 0xFFFFFFFF)
 
 
 def push_parent(span_id: Optional[str] = None) -> str:

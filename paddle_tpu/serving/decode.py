@@ -49,10 +49,19 @@ that has recurrent leaves, and ``serving_decode_admitted_total`` over
 dispatch carried: a turn seats everything it pops in one, and
 ``serving_pool_constants_placed_total``, the host-born constants the
 pool's lowering put on the device once so that no dispatch sends them
-again) on top of the standard ``ServingMetrics`` series; per-tick
-``serving/decode_tick`` spans carry the active requests' trace ids; the
+again, and ``serving_decode_idle_drops_total`` beside the
+``serving/pool_dropped`` event, one per drop of an idle server's pool
+state) on top of the standard ``ServingMetrics`` series; the
 ``decode.step`` fault point injects failures into the tick dispatch for
-chaos coverage.
+chaos coverage.  While a span sink is live every scheduler turn is ONE
+``serving/decode_tick`` span (it carries the active requests' trace
+ids) tiled by leaf spans, one a phase and in this order:
+``serving/decode/admit_plan``, ``/admit_dispatch``, ``/prefill``,
+``/dispatch``, ``/wait``, ``/copy``, ``/deliver``; an empty server's
+wait is ``serving/decode/idle_wait``, a turn of its own.  The leaves
+are on the device trace's clock too (``monitor.spans.open_span``); with
+no sink live a turn reads the sink's flag once and nothing else is
+different (:class:`_Turn`).
 
 Decode tier 2 (both independently toggleable, see README):
 
@@ -169,6 +178,12 @@ POOL_CONSTANTS_PLACED = monitor.counter(
     "lowering, one per distinct constant, so that no dispatch sends "
     "them again; 0 for a step that closes over device arrays only",
     _LABELS)
+DECODE_IDLE_DROPS = monitor.counter(
+    "serving_decode_idle_drops_total",
+    "times a server with nothing seated and no arrival for a whole idle "
+    "wait dropped its pool state (the device memory goes; whoever "
+    "arrives next pays for a fresh one); each also leaves a "
+    "serving/pool_dropped event", _LABELS)
 DECODE_RECURRENT_BYTES = monitor.gauge(
     "serving_recurrent_state_bytes",
     "bytes of the pool's recurrent cache leaves (no sequence axis) at "
@@ -197,6 +212,9 @@ DECODE_SPARSE_LIVE = monitor.counter(
 # wakeups are offer()/wake() notifies); a server with nothing seated
 # that sees no arrival for this long is idle and drops its pool state
 _IDLE_WAIT_S = 0.5
+
+# what a turn fetches of the pool state: the scheduler's view
+_VIEW = ("tokens", "pos", "active", "finished", "n_gen")
 
 _END = ("end", None)
 _ERR = ("err", None)
@@ -288,6 +306,52 @@ class _Slot:
         self.seq = seq  # admission order: the oldest held slot goes first
 
 
+class _Turn:
+    """The spans of ONE traced scheduler turn: the ``serving/decode_tick``
+    parent and the phase that is open.  Made only while a span sink is
+    live; an untraced turn has ``None`` in its place and pays an
+    ``is not None`` a phase, nothing else (no clock read, no
+    ``thread_time``, no ``block_until_ready``, no annotation)."""
+
+    __slots__ = ("tick", "leaf", "edge", "leaves", "active", "tids")
+
+    def __init__(self):
+        # not annotated: a span around the whole turn would give every
+        # device gap its name (monitor.spans, "Two clocks")
+        self.tick = _mon_spans.open_span(
+            "serving/decode_tick", cat="serving", annotate=False)
+        self.leaf = None
+        # the leaves tile the tick: each starts where the last ended
+        self.edge = self.tick.t0
+        self.leaves = 0      # leaves recorded under this tick
+        self.active = 0
+        self.tids = ()
+
+    def enter(self, phase: str, cpu: bool = False) -> None:
+        self.leaf = _mon_spans.open_span(
+            "serving/decode/" + phase, cat="serving", cpu=cpu, t0=self.edge)
+
+    def leave(self, error: bool = False, **args) -> None:
+        self.edge = self.leaf.close(error=error, **args)
+        self.leaf = None
+        self.leaves += 1
+
+    def skip(self) -> None:
+        """Leave a phase that turned out to have nothing in it."""
+        self.leaf.cancel()
+        self.leaf = None
+
+    def close(self, server: "DecodeServer") -> None:
+        """End of the turn: record the parent, unless nothing ran under
+        it (an empty server's turn is its ``idle_wait``)."""
+        if not self.leaves:
+            self.tick.cancel()
+            return
+        with _mon_spans.trace_context(self.tids):
+            self.tick.close(end=self.edge, server=server.name,
+                            active=self.active, steps=server._pool.steps)
+
+
 class _PoolPredictorView:
     """The predictor-shaped facade the admin/wire surfaces read
     (``statusz``/``healthz`` expect a ``_predictor`` with names +
@@ -356,6 +420,7 @@ class DecodeServer:
         self._recurrent_bytes_g = DECODE_RECURRENT_BYTES.labels(**lbl)
         self._admit_dispatches_c = DECODE_ADMIT_DISPATCHES.labels(**lbl)
         self._admitted_c = DECODE_ADMITTED.labels(**lbl)
+        self._idle_drops_c = DECODE_IDLE_DROPS.labels(**lbl)
         self._constants_placed_c = POOL_CONSTANTS_PLACED.labels(**lbl)
         self._constants_placed_seen = 0
         self._prefill_chunks_c = DECODE_PREFILL_CHUNKS.labels(**lbl)
@@ -483,6 +548,7 @@ class DecodeServer:
             "state_resets": int(self._state_resets_c.value),
             "admit_dispatches": int(self._admit_dispatches_c.value),
             "admitted": int(self._admitted_c.value),
+            "idle_drops": int(self._idle_drops_c.value),
             "prefill_chunks": int(self._prefill_chunks_c.value),
             "prefill_chunk_tokens": self._pool.prefill_tokens,
             "sparse_positions_read": int(self._sparse_read_c.value),
@@ -697,35 +763,40 @@ class DecodeServer:
         _mon_spans.set_thread_lane("serving/%s/decode" % self.name)
         try:
             while True:
-                self._admit_pending()
+                # the turn's ONE read of the span sink: every phase
+                # below asks ``turn is not None``
+                turn = _Turn() if _mon_spans.recording() else None
+                self._admit_pending(turn)
                 if self._active_count() == 0:
+                    if turn is not None:
+                        turn.close(self)
                     if self._stop.is_set() and (
                             self._abort or self._batcher.qsize() == 0):
                         return
                     self._occupancy_g.set(0.0)
+                    idle = None if turn is None else _mon_spans.open_span(
+                        "serving/decode/idle_wait", cat="serving")
+                    loaded = self._state is not None
+                    if loaded:
+                        t_idle = time.perf_counter()
                     cv = self._batcher.queue.cv
                     with cv:
                         arrived = (self._batcher.queue.qsize() > 0
                                    or self._stop.is_set()
                                    or cv.wait(timeout=_IDLE_WAIT_S))
-                    if not arrived:
-                        # idle: drop the pool state (frees the KV
-                        # memory; the next admit re-allocs at the
-                        # smallest rungs).  Only after a whole wait
-                        # with no arrival: a gap between two requests
-                        # is not idleness, and the state is re-made on
-                        # the host and carried to the device again —
-                        # 8 s for gpt1_117m's 12 GB, paid by whoever
-                        # arrives next (v5e chip run, PR 32)
-                        self._state = None
-                        self._slots = []
-                        self._set_pool_bytes(None)
+                    if loaded and not arrived:
+                        self._drop_idle_pool(time.perf_counter() - t_idle)
+                    if idle is not None:
+                        idle.close(server=self.name,
+                                   dropped=loaded and not arrived)
                     continue
                 if self._abort:
                     self._fail_in_flight(
                         ServerClosed("server %r is stopped" % self.name))
                     return
-                self._tick()
+                self._tick(turn)
+                if turn is not None:
+                    turn.close(self)
         finally:
             # a crash escaping the loop must not leave a zombie front
             # door: close the server so later submits fail typed instead
@@ -736,7 +807,28 @@ class DecodeServer:
                 ServerClosed("server %r stopped" % self.name))
             self._fail_stragglers()
 
-    def _admit_pending(self) -> None:
+    def _drop_idle_pool(self, idle_s: float) -> None:
+        """Idle: drop the pool state (frees the KV memory; the next
+        admit re-allocs at the smallest rungs).  Only after a whole
+        wait with no arrival: a gap between two requests is not
+        idleness, and the state is re-made on the host and carried to
+        the device again — 8 s for gpt1_117m's 12 GB, paid by whoever
+        arrives next (v5e chip run, PR 32).  Counted, and left in the
+        event ring with the seconds since the last turn ended: a wait
+        that outlasted ``_IDLE_WAIT_S`` by much is a process that
+        stood still."""
+        rungs = self._pool.state_rungs(self._state)
+        freed = (self._pool.kv_rung_bytes(*rungs)
+                 + self._pool.recurrent_rung_bytes(*rungs))
+        self._state = None
+        self._slots = []
+        self._set_pool_bytes(None)
+        self._idle_drops_c.inc()
+        _events.emit("serving/pool_dropped", cat="serving",
+                     server=self.name, bytes=int(freed),
+                     idle_s=float(idle_s))
+
+    def _admit_pending(self, turn: Optional[_Turn]) -> None:
         """Seat queued prompts into free slots with ONE device dispatch
         for the whole turn.
 
@@ -765,6 +857,9 @@ class DecodeServer:
         popped: List[DecodeRequest] = []
         seats = []                   # (slot, req, pre_len, pre_kv)
         probes = []                  # one per prefix lookup: did it hit
+        if turn is not None:
+            expired0 = self._metrics.counted("expired")
+            turn.enter("admit_plan", cpu=True)
         try:
             while None in slots or len(slots) < pool.max_slots:
                 with self._batcher.queue.cv:
@@ -788,8 +883,16 @@ class DecodeServer:
                 slot = slots.index(None)
                 slots[slot] = req
                 seats.append((slot, req, pre_len, pre_kv))
+            if turn is not None:
+                if popped or self._metrics.counted("expired") > expired0:
+                    turn.leave(popped=len(popped), lookups=len(probes))
+                else:
+                    turn.skip()
             if not popped:
                 return
+            if turn is not None:
+                dispatches0 = self._admit_dispatches_c.value
+                turn.enter("admit_dispatch")
             if (new_s, new_t) != cur:
                 self._state = (
                     pool.alloc(new_s, new_t) if cur is None
@@ -825,6 +928,8 @@ class DecodeServer:
             self._fail_and_drop_pool(exc)
             for hit in probes:
                 self._prefix.count_probe(hit)
+            if turn is not None and turn.leaf is not None:
+                turn.leave(error=True)
             return
         for slot, req, pre_len, _ in seats:
             self._admit_seq += 1
@@ -846,6 +951,11 @@ class DecodeServer:
             # a slot seated over a snapshot resumes a state: no reset
             self._state_resets_c.inc(
                 sum(1 for seat in seats if seat[2] == 0))
+        if turn is not None:
+            # the turn's bookkeeping above rides this leaf: the phases
+            # tile the turn
+            turn.leave(seated=len(seats), dispatches=int(
+                self._admit_dispatches_c.value - dispatches0))
 
     def _admit_with_prefix(self, slot, req, pre_len, pre_kv):
         """Seat ``req`` over its retained prefix KV, in a dispatch of its
@@ -885,29 +995,33 @@ class DecodeServer:
             0.0 if rungs is None
             else float(pool.recurrent_rung_bytes(*rungs)))
 
-    def _tick(self) -> None:
+    def _tick(self, turn: Optional[_Turn]) -> None:
         """One scheduler turn: dispatch a multi-step chunk, then
         materialize the (small) scheduler view and stream/complete."""
         import jax
 
         recs = [(i, s) for i, s in enumerate(self._slots) if s is not None]
         tids = ()
-        if _mon_spans.recording():
+        if turn is not None:
             tids = tuple(s.req.trace_id for _, s in recs if s.req.trace_id)
+            turn.active, turn.tids = len(recs), tids
         # a speculative round only when an opted-in slot is live: a pool
         # with a draft attached but no speculative traffic ticks the
         # plain chunk (one executable kind per tick, both warmed)
         use_spec = self._speculative is not None and any(
             s.spec for _, s in recs)
-        t0 = time.perf_counter()
         stepped = True
         if self._chunked:
             if any(s.held for _, s in recs) and not self._prefill_turn(
-                    recs):
+                    recs, turn):
                 return
             # with every seated slot held there is nothing for a decode
             # chunk to advance: the turn was its prefill chunk
             stepped = any(not s.held for _, s in recs)
+        if turn is not None:
+            kind = ("none" if not stepped
+                    else "spec_chunk" if use_spec else "chunk")
+            turn.enter("dispatch")
         try:
             with contextlib.ExitStack() as stack:
                 if tids:
@@ -927,16 +1041,34 @@ class DecodeServer:
                     state = self._pool.chunk(self._state)
                 # hot-path: end decode_tick
         except BaseException as exc:  # noqa: BLE001 — fail typed, keep serving
+            if turn is not None:
+                turn.leave(error=True, kind=kind)
             self._fail_and_drop_pool(exc)
             return
         self._state = state
+        if turn is not None:
+            turn.leave(kind=kind)
+            # tell the chunk's rest from the copies without delaying
+            # either: queue the five copies behind the chunk NOW, as the
+            # device_get below does in an untraced turn (waiting first
+            # and asking for them afterwards cost a traced chat tick
+            # 0.5 ms: v5e chip run, PR 36), then wait on the smallest of
+            # the five — outputs of one execution, ready together
+            turn.enter("wait")
+            for k in _VIEW:
+                state[k].copy_to_host_async()
+            jax.block_until_ready(state["n_gen"])
+            turn.leave()
+            turn.enter("copy")
         # the scheduler intervention: one d2h of the control-plane
         # arrays (tokens/pos/flags — KBs, not the KV cache); device_get
         # starts all five copies before it waits for the first, so the
         # tick pays one transfer's latency, not five in a row
-        view = jax.device_get(
-            {k: state[k]
-             for k in ("tokens", "pos", "active", "finished", "n_gen")})
+        view = jax.device_get({k: state[k] for k in _VIEW})
+        if turn is not None:
+            turn.leave(bytes=sum(v.nbytes for v in view.values()))
+            turn.enter("deliver", cpu=True)
+            tokens0 = self._tokens_c.value
         if stepped:
             self._count_kv_positions(recs, view, use_spec)
             # after the position counters: whoever sees the tick counted
@@ -1002,14 +1134,12 @@ class DecodeServer:
         if self._slots:
             self._occupancy_g.set(
                 self._active_count() / float(len(self._slots)))
-        if tids or _mon_spans.recording():
-            with _mon_spans.trace_context(tids):
-                _mon_spans.record_span(
-                    "serving/decode_tick", t0, now - t0, cat="serving",
-                    server=self.name, active=len(recs),
-                    steps=self._pool.steps)
+        if turn is not None:
+            turn.leave(
+                fresh_tokens=int(self._tokens_c.value - tokens0),
+                finished=sum(1 for i, _ in recs if self._slots[i] is None))
 
-    def _prefill_turn(self, recs) -> bool:
+    def _prefill_turn(self, recs, turn: Optional[_Turn]) -> bool:
         """The turn's ONE prefill dispatch, before its decode chunk: the
         oldest held slot's next ``prefill_tokens`` prompt tokens.  On
         the slot's last whole chunk the dispatch also hands it to the
@@ -1025,12 +1155,16 @@ class DecodeServer:
                         key=lambda x: x[1].seq)
         end = rec.pos + pool.prefill_tokens
         last = not pool.can_prefill(self._state, end, rec.prompt_len)
+        if turn is not None:
+            turn.enter("prefill")
         try:
             if _faults.active is not None:  # disarmed: one is-None gate
                 _faults.active.faultpoint(
                     "decode.step", server=self.name, active=len(recs))
             self._state = pool.prefill(self._state, slot, rec.pos, last)
         except BaseException as exc:  # noqa: BLE001 — fail typed, keep serving
+            if turn is not None:
+                turn.leave(error=True, slot=slot, last=last)
             self._fail_and_drop_pool(exc)
             return False
         self._prefill_chunks_c.inc()
@@ -1042,6 +1176,8 @@ class DecodeServer:
                     self._prefix.put(head, pool.snapshot(self._state, slot))
             except Exception:
                 self._metrics.count("prefix_store_failed")
+        if turn is not None:
+            turn.leave(slot=slot, last=last)
         return True
 
     def _count_kv_positions(self, recs, view, use_spec: bool) -> None:
@@ -1142,7 +1278,7 @@ class DecodeServer:
                        DECODE_TTFT, DECODE_OCCUPANCY, DECODE_KV_BYTES,
                        DECODE_STATE_RESETS, DECODE_RECURRENT_BYTES,
                        DECODE_ADMIT_DISPATCHES, DECODE_ADMITTED,
-                       POOL_CONSTANTS_PLACED,
+                       DECODE_IDLE_DROPS, POOL_CONSTANTS_PLACED,
                        DECODE_PREFILL_CHUNKS, DECODE_SPARSE_READ,
                        DECODE_SPARSE_LIVE):
             metric.remove_labels(**lbl)

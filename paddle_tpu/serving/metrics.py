@@ -144,6 +144,10 @@ class ServingMetrics:
     def count(self, key: str, n: int = 1) -> None:
         self._c[key].inc(n)
 
+    def counted(self, key: str) -> float:
+        """What ``count(key)`` has come to."""
+        return self._c[key].value
+
     def count_precision(self, dtype: str, n: int = 1) -> None:
         """``n`` requests served by the ``dtype`` compiled variant.
         Child creation is under the instance lock — replica workers

@@ -623,10 +623,10 @@ def turn_held(srv):
     parked, permits = threading.Semaphore(0), threading.Semaphore(0)
     real = srv._admit_pending
 
-    def gated():
+    def gated(turn):
         parked.release()
         permits.acquire(timeout=30.0)
-        real()
+        real(turn)
 
     def run(n=1):
         for _ in range(n):
@@ -1141,3 +1141,250 @@ def test_a_strided_leaf_is_installed_by_its_own_row_count():
     assert np.asarray(out["cache"]["z"])[0].tolist() == [7.0] * 6 + [0.0] * 10
     assert np.asarray(out["cache"]["zz"])[0].tolist() == [9.0] * 3 + [0.0] * 5
     assert int(np.asarray(out["pos"])[0]) == 6
+
+
+# ---------------------------------------------------------------------------
+# a scheduler turn's spans: one serving/decode_tick tiled by its phases
+# ---------------------------------------------------------------------------
+PHASES = ["serving/decode/" + p for p in (
+    "admit_plan", "admit_dispatch", "prefill", "dispatch", "wait", "copy",
+    "deliver")]
+
+
+def _served_under_recording(srv, prompts, max_new):
+    """Serve ``prompts`` with a span session live, stop the server (the
+    scheduler thread has then closed every span it opened) and return
+    the session's spans."""
+    from paddle_tpu.monitor import spans as mon_spans
+
+    mon_spans.start_recording()
+    try:
+        reqs = [srv.submit({"tokens": np.asarray(p, np.int32)},
+                           max_new_tokens=max_new) for p in prompts]
+        for r in reqs:
+            r.result(timeout=60.0)
+    finally:
+        srv.stop()
+        spans = mon_spans.stop_recording()
+    return spans
+
+
+def _assert_ticks_are_tiled(spans):
+    """Every ``serving/decode_tick`` has children whose ``parent`` is
+    its id, in the order the turn runs its phases, that do not overlap
+    and cover it; returns the leaf names seen."""
+    ticks = [s for s in spans if s["name"] == "serving/decode_tick"]
+    assert ticks
+    seen = set()
+    for tick in ticks:
+        kids = sorted((s for s in spans if s.get("parent") == tick["id"]
+                       and s["name"].startswith("serving/decode/")),
+                      key=lambda s: s["ts"])
+        order = [PHASES.index(s["name"]) for s in kids]
+        assert order == sorted(set(order)), [s["name"] for s in kids]
+        assert {"serving/decode/dispatch", "serving/decode/wait",
+                "serving/decode/copy", "serving/decode/deliver"} <= {
+                    s["name"] for s in kids}
+        # a ``ts`` is a wall-clock double: a quarter of a microsecond
+        for a, b in zip(kids, kids[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 1e-6
+        assert kids[0]["ts"] >= tick["ts"] - 1e-6
+        assert (kids[-1]["ts"] + kids[-1]["dur"]
+                <= tick["ts"] + tick["dur"] + 1e-6)
+        assert sum(s["dur"] for s in kids) >= 0.95 * tick["dur"]
+        assert set(tick["args"]) == {"server", "active", "steps"}
+        seen.update(s["name"] for s in kids)
+    # a leaf is never recorded outside a tick
+    ids = {t["id"] for t in ticks}
+    assert all(s.get("parent") in ids for s in spans if s["name"] in PHASES)
+    return seen
+
+
+def test_a_traced_turn_is_one_tick_tiled_by_its_phases():
+    step_fn, make_cache = chain_model()
+    srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=16,
+                       max_slots=4, steps_per_tick=2, name="chain-spans")
+    srv.warmup(configure_cache=False)
+    spans = _served_under_recording(
+        srv, [[10, 11], [12], [10, 11, 12], [13], [14, 15], [11]], 6)
+    seen = _assert_ticks_are_tiled(spans)
+    assert seen == set(PHASES) - {"serving/decode/prefill"}
+    by = {}
+    for s in spans:
+        by.setdefault(s["name"], []).append(s.get("args", {}))
+    assert sum(a["popped"] for a in by["serving/decode/admit_plan"]) == 6
+    assert sum(a["seated"] for a in by["serving/decode/admit_dispatch"]) == 6
+    assert all(a["cpu_s"] >= 0 for a in by["serving/decode/admit_plan"]
+               + by["serving/decode/deliver"])
+    assert sum(a["fresh_tokens"] for a in by["serving/decode/deliver"]) == 36
+    assert sum(a["finished"] for a in by["serving/decode/deliver"]) == 6
+    assert {a["kind"] for a in by["serving/decode/dispatch"]} == {"chunk"}
+    assert all(a["bytes"] > 0 for a in by["serving/decode/copy"])
+
+
+def test_a_chunked_builders_turn_has_a_prefill_phase():
+    srv = _sum_server("sum-spans")
+    assert srv.warmup() == 4
+    prompt = np.arange(1, 3 * SUM_C + 3, dtype=np.int32) % SUM_V
+    spans = _served_under_recording(srv, [prompt], 4)
+    assert "serving/decode/prefill" in _assert_ticks_are_tiled(spans)
+    chunks = [s["args"] for s in spans
+              if s["name"] == "serving/decode/prefill"]
+    assert [c["last"] for c in chunks] == [False, False, True]
+    # every slot held: the turn was its prefill chunk, nothing stepped
+    assert "none" in {s["args"]["kind"] for s in spans
+                      if s["name"] == "serving/decode/dispatch"}
+
+
+def _idle_server_dropped_its_pool(monkeypatch, name):
+    """A served request, then no arrival for a (shortened) idle wait:
+    returns the stopped server once it has dropped its pool state."""
+    from paddle_tpu.serving import decode as decode_mod
+
+    monkeypatch.setattr(decode_mod, "_IDLE_WAIT_S", 0.05)
+    step_fn, make_cache = chain_model()
+    srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=16,
+                       max_slots=2, steps_per_tick=2, name=name)
+    srv.warmup(configure_cache=False)
+    try:
+        srv.submit({"tokens": np.array([10], np.int32)},
+                   max_new_tokens=3).result(timeout=60.0)
+        assert srv.metrics()["decode"]["kv_cache_bytes"] > 0
+        deadline = time.monotonic() + 30.0
+        while (monitor.counter_value("serving_decode_idle_drops_total",
+                                     server=name) < 1
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        # the dropped server waits on: its next waits drop nothing
+        time.sleep(0.15)
+        drops = srv.metrics()["decode"]["idle_drops"]
+        held = srv.metrics()["decode"]["kv_cache_bytes"]
+    finally:
+        srv.stop()
+    return drops, held
+
+
+def test_an_idle_server_counts_its_drop_and_leaves_an_event(monkeypatch):
+    drops, held = _idle_server_dropped_its_pool(monkeypatch, "chain-idle")
+    assert (drops, held) == (1, 0)
+    (ev,) = [e for e in monitor.eventz()["events"]
+             if e["kind"] == "serving/pool_dropped"
+             and e["server"] == "chain-idle"]
+    assert ev["severity"] == "info"
+    # the chain model's one leaf, [1, 8] float32 at the smallest rungs
+    assert ev["bytes"] == 32 and ev["idle_s"] >= 0.05
+
+
+def test_an_empty_servers_wait_is_a_span_of_its_own(monkeypatch):
+    from paddle_tpu.monitor import spans as mon_spans
+
+    mon_spans.start_recording()
+    try:
+        _idle_server_dropped_its_pool(monkeypatch, "chain-idle-spans")
+    finally:
+        spans = mon_spans.stop_recording()
+    waits = [s for s in spans if s["name"] == "serving/decode/idle_wait"
+             and s["args"]["server"] == "chain-idle-spans"]
+    assert waits and all("parent" not in s for s in waits)
+    assert [s["args"]["dropped"] for s in waits].count(True) == 1
+    # the drop's event is mirrored into the span stream under its wait
+    (inst,) = [s for s in spans if s["name"] == "serving/pool_dropped"
+               and s["args"]["server"] == "chain-idle-spans"]
+    assert inst["parent"] in {s["id"] for s in waits}
+    _assert_ticks_are_tiled(spans)
+
+
+def test_the_phases_are_on_the_profilers_clock_and_the_tick_is_not(
+        tmp_path):
+    """Under ``jax.profiler.start_trace`` the leaves land on a Python
+    thread's line of the ``/host:CPU`` plane (the clock the device's ops
+    share); the enclosing ``serving/decode_tick`` must not, or a reader
+    that names a device gap after the host event covering most of it
+    would call every gap a tick."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    step_fn, make_cache = chain_model()
+    srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=16,
+                       max_slots=2, steps_per_tick=2, name="chain-xplane")
+    srv.warmup(configure_cache=False)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        spans = _served_under_recording(srv, [[10], [11, 12]], 5)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    on_python_lines, elsewhere = set(), set()
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            names = {ev.name for ev in line.events
+                     if ev.name.startswith("serving/")}
+            if plane.name == "/host:CPU" and line.name.startswith("python"):
+                on_python_lines |= names
+            else:
+                elsewhere |= names
+    assert set(PHASES) - {"serving/decode/prefill"} <= on_python_lines
+    assert "serving/decode_tick" not in on_python_lines | elsewhere
+    assert any(s["name"] == "serving/decode_tick" for s in spans)
+
+
+def test_an_untraced_turn_reads_the_sink_once_and_one_clock(monkeypatch):
+    """What tracing costs while nothing records, by COUNTING what the
+    scheduler thread calls over N whole turns: one ``recording()`` and
+    one ``perf_counter`` (the stamp of the turn's tokens) a turn, and no
+    ``thread_time``, ``block_until_ready`` or ``TraceAnnotation`` at
+    all.  (Before the phases were spans a turn read the sink twice and
+    the clock twice, the second for a span nobody recorded.)"""
+    import types
+
+    import jax
+
+    from paddle_tpu.monitor import spans as mon_spans
+    from paddle_tpu.serving import decode as decode_mod
+
+    step_fn, make_cache = chain_model()
+    srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=16,
+                       max_slots=4, steps_per_tick=2, name="chain-count")
+    srv.warmup(configure_cache=False)
+    calls = {}
+
+    def counted(name, fn):
+        def wrapper(*a, **kw):
+            if threading.current_thread() is srv._worker:
+                calls[name] = calls.get(name, 0) + 1
+            return fn(*a, **kw)
+        return wrapper
+
+    # decode.py's own clock reads: its ``time`` alone is wrapped, so the
+    # admission queue's and the metrics' reads are not in the count
+    monkeypatch.setattr(decode_mod, "time", types.SimpleNamespace(
+        perf_counter=counted("perf_counter", time.perf_counter),
+        thread_time=counted("thread_time", time.thread_time),
+        monotonic=time.monotonic))
+    monkeypatch.setattr(mon_spans, "recording",
+                        counted("recording", mon_spans.recording))
+    monkeypatch.setattr(jax, "block_until_ready",
+                        counted("block_until_ready", jax.block_until_ready))
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", counted(
+        "TraceAnnotation", jax.profiler.TraceAnnotation))
+    ticks = counted("tick", srv._tick)
+    srv._tick = ticks
+    n = 5
+    try:
+        assert not mon_spans.recording()
+        with turn_held(srv) as run:
+            reqs = [srv.submit({"tokens": np.array(p, np.int32)},
+                               max_new_tokens=14) for p in ([10], [11, 12])]
+            run(1)          # seated; from here every turn is a whole one
+            calls.clear()
+            run(n)
+            got = dict(calls)
+        for r in reqs:
+            r.result(timeout=60.0)
+    finally:
+        srv.stop(drain=False)
+    assert got == {"tick": n, "recording": n, "perf_counter": n}

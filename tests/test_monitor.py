@@ -153,60 +153,48 @@ def test_spans_off_outside_session():
     assert monitor.stop_recording() == []  # nothing buffered
 
 
-def test_instrumentation_overhead_when_idle():
-    """With no trace session and nothing scraping the registry, the
-    instrumentation on Executor.run must cost <1% of the un-instrumented
-    run time.  The jit counters are collect-on-read (the registry sums
-    the pre-existing ``_cache_stats`` dicts at SCRAPE time), so the only
-    hot-path additions are one dict increment (``runs``), one
-    ``recording()`` gate call, and a handful of flag checks — measure
-    exactly those against the measured per-run time.  (Two end-to-end
-    timings of near-identical code paths differ by scheduler noise far
-    larger than the real delta; bounding the components is exact.)"""
+def test_instrumentation_overhead_when_idle(monkeypatch):
+    """With no trace session and nothing scraping the registry, what the
+    instrumentation adds to a warmed ``Executor.run`` is COUNTED, not
+    timed (two timings of near-identical code differ by scheduler noise
+    far larger than the real delta, and under five other test workers
+    they failed the suite): the jit counters are collect-on-read, so a
+    run makes ONE ``recording()`` gate call, reads the clock twice (the
+    dispatch-overhead counter's pair), never calls ``record_span`` and
+    leaves nothing in the span buffer."""
+    import types
+
+    from paddle_tpu import executor as executor_mod
     from paddle_tpu.monitor import spans as mon_spans
 
     prog, startup, loss = _small_program(seed=7)
     exe = fluid.Executor(fluid.CPUPlace())
     feed = {"x": np.zeros((2, 8), "float32")}
+    calls = {}
+
+    def counted(name, fn):
+        def wrapper(*a, **kw):
+            calls[name] = calls.get(name, 0) + 1
+            return fn(*a, **kw)
+        return wrapper
+
     with fluid.scope_guard(fluid.Scope()):
         exe.run(startup)
-        for _ in range(10):  # warm the jit cache + the dispatch path
+        for _ in range(3):  # warm the jit cache + the dispatch path
             exe.run(prog, feed=feed, fetch_list=[loss])
-
-        def timed_run(n=150):
-            t0 = time.perf_counter()
-            for _ in range(n):
-                exe.run(prog, feed=feed, fetch_list=[loss])
-            return (time.perf_counter() - t0) / n
-
-        run_s = min(timed_run() for _ in range(5))
-
-    # per-run instrumentation, exactly as Executor.run executes it:
-    # the runs-dict increment + recording() + the 6 `if _rec:` checks
-    stats = {"hits": 0, "misses": 0, "runs": 0}
-    n = 100_000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        stats["runs"] += 1
-        _rec = mon_spans.recording()
-        if _rec:
-            pass
-        if _rec:
-            pass
-        if _rec:
-            pass
-        if _rec:
-            pass
-        if _rec:
-            pass
-        if _rec:
-            pass
-    instr_s = (time.perf_counter() - t0) / n
-    overhead = instr_s / (run_s - instr_s)
-    assert overhead < 0.01, (
-        "idle instrumentation overhead %.4f%% (%.2fus per %.1fus run)"
-        % (overhead * 100, instr_s * 1e6, run_s * 1e6))
-    assert not mon_spans.recording()  # the premise: no active session
+        assert not mon_spans.recording()  # the premise: no active session
+        # executor.py's own clock reads: its ``time`` alone is wrapped
+        monkeypatch.setattr(executor_mod, "time", types.SimpleNamespace(
+            perf_counter=counted("perf_counter", time.perf_counter)))
+        monkeypatch.setattr(mon_spans, "recording",
+                            counted("recording", mon_spans.recording))
+        monkeypatch.setattr(mon_spans, "record_span",
+                            counted("record_span", mon_spans.record_span))
+        n = 20
+        for _ in range(n):
+            exe.run(prog, feed=feed, fetch_list=[loss])
+    assert calls == {"recording": n, "perf_counter": 2 * n}
+    assert not mon_spans._buffer
 
 
 # ---------------------------------------------------------------------------
@@ -708,6 +696,50 @@ def test_span_parent_ids_from_nesting():
     assert "parent" not in by["outer"]
     # the stack is clean after the session
     assert _spans.current_parent() is None
+
+
+def test_open_spans_nest_tile_and_cancel():
+    """``open_span`` is ``span()`` without the context manager: what is
+    recorded while one is open nests under it, ``cpu=True`` adds the
+    thread's CPU seconds, spans that tile share their edges (``t0=`` /
+    ``end=``), and a cancelled one leaves nothing but a clean stack."""
+    from paddle_tpu.monitor import spans as _spans
+
+    with monitor.trace_session() as sess:
+        outer = _spans.open_span("outer", cat="serving", annotate=False)
+        first = _spans.open_span("first", cpu=True, t0=outer.t0)
+        monitor.record_span("inside", time.perf_counter(), 0.0)
+        edge = first.close(n=1)
+        second = _spans.open_span("second", t0=edge)
+        edge = second.close(error=True)
+        _spans.open_span("nothing in it").cancel()
+        assert outer.close(end=edge, k="v") == edge
+    by = {s["name"]: s for s in sess.spans}
+    assert set(by) == {"outer", "first", "inside", "second"}
+    assert by["first"]["parent"] == by["second"]["parent"] == by["outer"]["id"]
+    assert by["inside"]["parent"] == by["first"]["id"]
+    assert "parent" not in by["outer"] and by["outer"]["cat"] == "serving"
+    assert by["first"]["args"]["n"] == 1
+    assert by["first"]["args"]["cpu_s"] >= 0.0
+    assert "cpu_s" not in by["second"].get("args", {})
+    assert by["second"]["error"] and by["outer"]["args"] == {"k": "v"}
+    # the three share their edges: the two tile the outer exactly
+    assert by["first"]["ts"] == by["outer"]["ts"]
+    assert by["first"]["dur"] + by["second"]["dur"] == pytest.approx(
+        by["outer"]["dur"], abs=1e-9)
+    assert _spans.current_parent() is None
+
+
+def test_span_ids_are_distinct_16_hex_from_a_process_counter():
+    from paddle_tpu.monitor import spans as _spans
+
+    ids = [monitor.new_span_id() for _ in range(1000)]
+    assert len(set(ids)) == 1000
+    assert all(len(i) == 16 and int(i, 16) >= 0 for i in ids)
+    # eight digits drawn once a process (anew in a forked child), then
+    # a counter: the next id is the last one's successor
+    assert {i[:8] for i in ids} == {_spans._id_process}
+    assert int(ids[-1][8:], 16) - int(ids[0][8:], 16) == 999
 
 
 def test_span_remote_parent_graft():

@@ -2,21 +2,82 @@
 """Run one benchmark cell with ``--trace 1`` and keep what the harness
 throws away: every traced instruction with its self seconds, so that a
 per-layer reader's shape needles can be checked against what the chip
-really ran.
+really ran, and the program's own spans over the traced stretch.
 
     chiprun -- python tools/bench_keep_trace.py --workload <cell> --seed <n>
 
 Arguments are ``benchmark/run.py``'s.  The cell's normal output is
 printed as always; ``chiprun_out/<cell>.instructions.json`` gets
 ``[[self seconds, instruction text], ...]``, longest first, and the
-modules' run times.  A development aid: the driver never runs it.
+modules' run times; ``chiprun_out/<cell>.spans.json`` gets, per span
+name, how many were recorded and their seconds (a ``DecodeServer``
+turn's phases: seconds a ``serving/decode_tick``, and the share of the
+ticks their leaves cover), every device gap's name with its seconds,
+and the ``serving/...`` events found on the trace's own host lines.  A
+development aid: the driver never runs it.
 """
+import collections
 import json
 import os
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
+
+
+def spans_summary(spans, trace):
+    """Counts and seconds per span name over everything recorded; for
+    the turns of a ``DecodeServer`` that the profile saw (the ticks the
+    ``turn_*_ms`` metrics read) also what each phase costs a tick and
+    how much of the ticks the phases cover."""
+    from benchmark.lib import readers_turn
+
+    by = collections.defaultdict(lambda: [0, 0.0, 0.0])
+    for s in spans:
+        row = by[s["name"]]
+        row[0] += 1
+        row[1] += s["dur"]
+        row[2] += s.get("args", {}).get("cpu_s", 0.0)
+    read = readers_turn.ticks_read(trace, spans)
+    ticks = {s["id"]: s["dur"] for s in spans if s["id"] in read}
+    under = collections.Counter()
+    for s in spans:
+        if s.get("parent") in ticks:
+            under[s["name"]] += s["dur"]
+    n = max(len(ticks), 1)
+    return {
+        "by_name": {k: {"count": v[0], "seconds": v[1], "cpu_s": v[2]}
+                    for k, v in sorted(by.items())},
+        "ticks": len(ticks),
+        "tick_ms": 1e3 * sum(ticks.values()) / n,
+        "leaf_ms_a_tick": {k: 1e3 * v / n for k, v in sorted(under.items())},
+        "leaves_cover": (sum(under.values()) / sum(ticks.values())
+                         if ticks else None),
+        "idle_waits_that_dropped": sum(
+            1 for s in spans if s["name"] == "serving/decode/idle_wait"
+            and s["args"]["dropped"]),
+    }
+
+
+def host_events(path):
+    """The ``serving/...`` events on the Python threads' lines of the
+    trace's host plane: {name: [count, seconds]}."""
+    from jax.profiler import ProfileData
+
+    from benchmark.lib import xplane
+
+    out = collections.defaultdict(lambda: [0, 0.0])
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != xplane.HOST_PLANE:
+            continue
+        for line in plane.lines:
+            if not line.name.startswith("python"):
+                continue
+            for ev in line.events:
+                if ev.name.startswith("serving/"):
+                    out[ev.name][0] += 1
+                    out[ev.name][1] += ev.duration_ns * 1e-9
+    return dict(out)
 
 
 def main():
@@ -41,6 +102,11 @@ def main():
                         ([v, k[:600]] for k, v in
                          trace.instructions.items()), reverse=True)[:400]},
                     f, indent=0)
+            with open(os.path.join(out, cell + ".spans.json"), "w") as f:
+                json.dump(dict(spans_summary(self.spans, trace),
+                               gaps=trace.top_gaps(40),
+                               trace_host_events=host_events(path)),
+                          f, indent=1)
         cleanup(self)
 
     harness.Tracer.cleanup = keep
