@@ -35,15 +35,21 @@ Two implementations of the read-what-is-live contract, chosen by
 
 * :func:`ragged_decode_attention` — the Pallas TPU kernel, for fp32
   leaves, ``rep = 1``, ``K = 1``.  It reads RAGGED: slot ``n`` touches
-  positions ``0..ts[n]`` rounded up to ``block``, an idle slot nothing.
-  The step's live ``(slot, block)`` pairs are flattened into one work
-  list (:func:`decode_work_items`, shared by every layer of a step); the
-  kernel walks it with a dynamic trip count, double-buffering the K/V
-  block DMAs across slot boundaries, online softmax over the blocks
-  read.  K/V stay fp32 in HBM; products are fp32 on the VPU, and the
-  per-head sums ride the MXU as a ``[D, 128]`` 0/1 indicator matmul with
-  the fp32 operand split three ways into bf16 (hi + mid + lo carries 24
-  mantissa bits; the indicator is exact), accumulated in fp32.
+  positions ``0..ts[n]`` rounded up to :data:`KV_TAIL` rows
+  (:func:`kv_positions_read`), an idle slot nothing.  The step's live
+  ``(slot, block)`` pairs are flattened into one work list
+  (:func:`decode_work_items`, shared by every layer of a step) that
+  carries each pair's rows: a slot's blocks before its last are whole
+  ``block``-row items, its LAST block is read and computed only as far
+  as it is live, in classes of :data:`KV_TAIL` rows (a DMA's length is
+  static, so the kernel holds one body a class and an item takes the
+  body of its rows).  The kernel walks the list with a dynamic trip
+  count, the K/V reads of the next ``_READS_AHEAD`` items in flight
+  across slot boundaries, online softmax over the rows read.  K/V stay
+  fp32 in HBM; products are fp32 on the VPU, and the per-head sums ride
+  the MXU as a ``[D, 128]`` 0/1 indicator matmul with the fp32 operand
+  split three ways into bf16 (hi + mid + lo carries 24 mantissa bits;
+  the indicator is exact), accumulated in fp32.
 * :func:`grouped_masked_decode_attention` — the contract whole, as plain
   XLA ops (scatter append + masked softmax over the whole T axis):
   products in the storage dtype (int8: dequantized to fp32 at the read),
@@ -61,8 +67,9 @@ import functools
 
 import numpy as np
 
-__all__ = ["KV_BLOCK", "KV_SEQ_AXIS", "kv_leaves", "kv_read_block",
-           "decode_work_items", "ragged_decode_attention",
+__all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
+           "kv_read_block", "kv_positions_read", "decode_work_items",
+           "ragged_decode_attention",
            "grouped_masked_decode_attention", "append_rows",
            "grouped_block_decode_attention", "block_sparse_decode_attention",
            "block_kernel_supported",
@@ -71,18 +78,39 @@ __all__ = ["KV_BLOCK", "KV_SEQ_AXIS", "kv_leaves", "kv_read_block",
 #: the sequence axis of every K/V leaf (and scale sibling)
 KV_SEQ_AXIS = 1
 
-#: positions per K/V block the kernel moves in one DMA (and the rounding
-#: of ``serving_decode_kv_positions_read_total``)
+#: positions per K/V block the kernel moves in one DMA
 KV_BLOCK = 128
+#: rows a slot's LAST block is read and computed in: the rounding of what
+#: a step reads (:func:`kv_positions_read`).  64 of 16 / 32 / 64 on the
+#: chip: an item's time is mostly fixed (0.21 us of ~1.1 for 32 rows), so
+#: finer classes buy less than their branches and bodies cost
+KV_TAIL = 64
+#: items whose K/V reads are in flight ahead of the one computed: a short
+#: tail cannot hide the next whole block's read behind its own products,
+#: two items can
+_READS_AHEAD = 2
 _HEAD_LANES = 128   # heads padded to one lane tile in the score domain
 _MASK = -1e30       # finite: exp(_MASK - m) == 0, no inf - inf
 
 
 def kv_read_block(seq_len: int) -> int:
     """The block (in positions) a decode step reads a length-``seq_len``
-    rung in: :data:`KV_BLOCK` when it divides the rung, else the rung."""
+    rung in: :data:`KV_BLOCK` when it divides the rung, else the rung.
+    A slot's blocks before its last are read whole; its last one as far
+    as :func:`kv_positions_read` says."""
     seq_len = int(seq_len)
     return KV_BLOCK if seq_len % KV_BLOCK == 0 else seq_len
+
+
+def kv_positions_read(ts, block: int):
+    """Positions of a slot the kernel reads in a step at ``ts >= 0``
+    (``ts``: an int or an integer array, numpy or jax), in blocks of
+    ``block``: ``ts + 1`` rounded up to :data:`KV_TAIL`, or to the block
+    where :data:`KV_TAIL` does not divide it.  THE rounding: the work
+    list (:func:`decode_work_items`) and the server's
+    ``serving_decode_kv_positions_read_total`` both take it from here."""
+    tail = KV_TAIL if block % KV_TAIL == 0 else block
+    return (ts // tail + 1) * tail
 
 
 def kernel_supported(seq_len: int, d_model: int, n_head: int) -> bool:
@@ -96,9 +124,12 @@ def decode_work_items(ts, seq_len: int, block: int):
     """Flatten the step's live ``(slot, block)`` pairs, slot-major.
 
     ``ts`` [S] int32 (``< 0`` = idle).  Returns ``(n_items [1], slot
-    [S * seq_len // block], blk [same])`` int32; entries past
-    ``n_items`` are padding.  A slot at position ``ts`` owns blocks
-    ``0..ts // block`` — at least the one its new row lands in."""
+    [S * seq_len // block], blk [same], rows [same])`` int32; entries
+    past ``n_items`` are padding.  A slot at position ``ts`` owns blocks
+    ``0..ts // block`` — at least the one its new row lands in — and
+    ``rows`` is how many of a block's rows the item reads: the whole
+    block, or for the slot's last block what is left of
+    :func:`kv_positions_read`."""
     import jax.numpy as jnp
 
     S = ts.shape[0]
@@ -108,7 +139,10 @@ def decode_work_items(ts, seq_len: int, block: int):
     slot = jnp.repeat(jnp.arange(S, dtype=jnp.int32), nblk,
                       total_repeat_length=max_items)
     blk = jnp.arange(max_items, dtype=jnp.int32) - (ends - nblk)[slot]
-    return ends[-1:].astype(jnp.int32), slot, blk.astype(jnp.int32)
+    rows = jnp.minimum(kv_positions_read(ts[slot], block) - blk * block,
+                       block)
+    return (ends[-1:].astype(jnp.int32), slot, blk.astype(jnp.int32),
+            rows.astype(jnp.int32))
 
 
 @functools.lru_cache(maxsize=None)
@@ -141,7 +175,8 @@ def _dot3(x, w):
                for t in _split3(x))
 
 
-def _kernel(n_items_ref, item_slot_ref, item_blk_ref, ts_ref,   # SMEM
+def _kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
+            ts_ref,                                             # SMEM
             q_ref, kn_ref, vn_ref, e_ref, et_ref,               # VMEM
             k_hbm, v_hbm,                                       # HBM (ANY)
             o_ref, k_out, v_out,                                # outputs
@@ -153,32 +188,53 @@ def _kernel(n_items_ref, item_slot_ref, item_blk_ref, ts_ref,   # SMEM
 
     n_items = n_items_ref[0]
     o_ref[...] = jnp.zeros_like(o_ref)   # idle slots: zero context
+    # the row counts an item may have (decode_work_items): one static
+    # body each, since a DMA's length is static
+    tail = kv_positions_read(0, block)
+    classes = list(range(tail, block + 1, tail))
 
-    def read(i, buf):
+    def by_rows(i, body):
+        """``body(rows)`` for the class item ``i`` is of, found by
+        halving (a ``lax.switch`` lowers to a cascade that costs every
+        item a branch a class)."""
+        rows = item_rows_ref[i]
+
+        def pick(cs):
+            if len(cs) == 1:
+                return functools.partial(body, cs[0])
+            lo, hi = cs[:len(cs) // 2], cs[len(cs) // 2:]
+            return lambda: jax.lax.cond(rows >= hi[0], pick(hi), pick(lo))
+
+        pick(classes)()
+
+    def read(i, buf, rows):
         n, b = item_slot_ref[i], item_blk_ref[i]
-        rows = pl.ds(pl.multiple_of(b * block, block), block)
-        return (pltpu.make_async_copy(k_hbm.at[n, rows], kbuf.at[buf],
+        src = pl.ds(pl.multiple_of(b * block, block), rows)
+        dst = pl.ds(0, rows)
+        return (pltpu.make_async_copy(k_hbm.at[n, src], kbuf.at[buf, dst],
                                       rsem.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[n, rows], vbuf.at[buf],
+                pltpu.make_async_copy(v_hbm.at[n, src], vbuf.at[buf, dst],
                                       rsem.at[1, buf]))
 
-    @pl.when(n_items > 0)
-    def _():
-        for c in read(0, 0):
-            c.start()
+    def start_read(i, buf):
+        def start(rows):
+            for c in read(i, buf, rows):
+                c.start()
+        by_rows(i, start)
 
-    def item(i, carry):
-        buf = i % 2
+    nbuf = kbuf.shape[0]            # _READS_AHEAD + 1 buffers a leaf
+    for j in range(nbuf - 1):
+        pl.when(n_items > j)(functools.partial(start_read, j, j))
+
+    def attend(i, rows):
+        """Item ``i`` over the first ``rows`` rows of its block."""
+        buf = i % nbuf
         n, b = item_slot_ref[i], item_blk_ref[i]
         t = ts_ref[n]
         last = b == t // block
 
-        @pl.when(i + 1 < n_items)
-        def _():
-            for c in read(i + 1, 1 - buf):
-                c.start()
-
-        for c in read(i, buf):
+        # the wait takes the descriptor the read was started with
+        for c in read(i, buf, rows):
             c.wait()
 
         @pl.when(b == 0)
@@ -190,7 +246,8 @@ def _kernel(n_items_ref, item_slot_ref, item_blk_ref, ts_ref,   # SMEM
         row = pl.ds(n, 1)
         # the sublane tile of 8 positions the new row falls in: a DMA
         # moves whole tiles, so the append writes the patched tile back
-        # (its other 7 rows as they were read)
+        # (its other 7 rows as they were read); it lies inside the rows
+        # read, which reach past ``t``
         t8 = pl.multiple_of(t // 8 * 8, 8)
         tile = pl.ds(pl.multiple_of(t8 - b * block, 8), 8)
         writes = (pltpu.make_async_copy(kbuf.at[buf, tile],
@@ -210,7 +267,8 @@ def _kernel(n_items_ref, item_slot_ref, item_blk_ref, ts_ref,   # SMEM
             for c in writes:
                 c.start()
 
-        s = _dot3(kbuf[buf] * q_ref[row, :], e_ref[...])   # [block, 128]
+        live = pl.ds(0, rows)
+        s = _dot3(kbuf[buf, live] * q_ref[row, :], e_ref[...])  # [rows, 128]
         pos = b * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         s = jnp.where(pos <= t, s, _MASK)
         m_prev = m_ref[...]
@@ -221,11 +279,11 @@ def _kernel(n_items_ref, item_slot_ref, item_blk_ref, ts_ref,   # SMEM
         # one expansion matmul for p and alpha: heads -> their lanes
         x = _dot3(jnp.concatenate(
             [p, jnp.broadcast_to(alpha, (8, alpha.shape[1]))], axis=0),
-            et_ref[...])                                    # [block+8, D]
-        pe, ae = x[:block], x[block:block + 1]
+            et_ref[...])                                    # [rows+8, D]
+        pe, ae = x[:rows], x[rows:rows + 1]
         l_ref[...] = ae * l_ref[...] + jnp.sum(pe, axis=0, keepdims=True)
         acc_ref[...] = ae * acc_ref[...] + jnp.sum(
-            pe * vbuf[buf], axis=0, keepdims=True)
+            pe * vbuf[buf, live], axis=0, keepdims=True)
 
         @pl.when(last)
         def _():
@@ -233,6 +291,12 @@ def _kernel(n_items_ref, item_slot_ref, item_blk_ref, ts_ref,   # SMEM
             for c in writes:
                 c.wait()
 
+    def item(i, carry):
+        @pl.when(i + nbuf - 1 < n_items)
+        def _():
+            start_read(i + nbuf - 1, (i + nbuf - 1) % nbuf)
+
+        by_rows(i, functools.partial(attend, i))
         return carry
 
     jax.lax.fori_loop(0, n_items, item, 0)
@@ -248,46 +312,69 @@ def ragged_decode_attention(q, k_new, v_new, k_cache, v_cache, ts, work,
     place (aliased to the returned leaves); ``ts`` [S] int32; ``work``
     from :func:`decode_work_items` for the same ``ts``/``block``.
     Returns ``(ctx [S, D], k_cache, v_cache)``."""
+    import jax.numpy as jnp
+
+    # the indicators stay the CALLER's constants (a pool hoists and
+    # places them once: KVSlotPool._lower), the call itself is one jit
+    e, et = _indicators(k_cache.shape[-1], n_head)
+    return _kernel_call()(
+        work, ts, q * scale, k_new, v_new, jnp.asarray(e, jnp.bfloat16),
+        jnp.asarray(et, jnp.bfloat16), k_cache, v_cache, block=block,
+        interpret=interpret)
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_call():
+    """:func:`_call` under ``jax.jit`` (built once, jax imported late):
+    the layers and steps of a chunk program then share ONE trace and ONE
+    lowered function of the kernel instead of tracing and lowering its
+    bodies at every call site in every process (a cache hit on the
+    executable does not spare the lowering; fused_attention._jitted)."""
+    import jax
+
+    return jax.jit(_call, static_argnames=("block", "interpret"))
+
+
+def _call(work, ts, q, k_new, v_new, e, et, k_cache, v_cache, *, block,
+          interpret):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     S, T, D = k_cache.shape
-    e, et = _indicators(D, n_head)
-    n_items, item_slot, item_blk = work
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     f32 = jnp.float32
+    nbuf = _READS_AHEAD + 1
     # fp32 words the kernel keeps in VMEM: q, k_new, v_new and the
-    # context whole, the two double-buffered blocks, and room for the
-    # block-sized temporaries of the products, their splits and sums
-    resident = 4 * (4 * S * D + 4 * block * D + 8 * (block + 8) * D)
+    # context whole, the K and V blocks being read and computed, and room
+    # for the block-sized temporaries of the products, their splits and
+    # sums
+    resident = 4 * (4 * S * D + 2 * nbuf * block * D + 8 * (block + 8) * D)
     return pl.pallas_call(
         functools.partial(_kernel, block=block),
         out_shape=(jax.ShapeDtypeStruct((S, D), f32),
                    jax.ShapeDtypeStruct(k_cache.shape, f32),
                    jax.ShapeDtypeStruct(v_cache.shape, f32)),
-        in_specs=[smem] * 4 + [vmem] * 5 + [hbm] * 2,
+        in_specs=[smem] * 5 + [vmem] * 5 + [hbm] * 2,
         out_specs=(vmem, hbm, hbm),
         scratch_shapes=[
-            pltpu.VMEM((2, block, D), f32),
-            pltpu.VMEM((2, block, D), f32),
+            pltpu.VMEM((nbuf, block, D), f32),
+            pltpu.VMEM((nbuf, block, D), f32),
             pltpu.VMEM((1, _HEAD_LANES), f32),
             pltpu.VMEM((1, D), f32),
             pltpu.VMEM((1, D), f32),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA((2, nbuf)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        input_output_aliases={9: 1, 10: 2},   # k_cache, v_cache in place
+        input_output_aliases={10: 1, 11: 2},  # k_cache, v_cache in place
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=min(100 << 20, max(32 << 20, 2 * resident))),
         name="ragged_decode_attention",
         interpret=interpret,
-    )(n_items, item_slot, item_blk, ts, q * scale, k_new, v_new,
-      jnp.asarray(e, jnp.bfloat16), jnp.asarray(et, jnp.bfloat16),
-      k_cache, v_cache)
+    )(*work, ts, q, k_new, v_new, e, et, k_cache, v_cache)
 
 
 def kv_leaves(n_rows: int, seq_len: int, n_kv_head: int, d_head: int,

@@ -503,8 +503,9 @@ def make_transformer_lm_pooled_step_fn(
     module appends and reads it: each new K/V row is written in place
     (O(row), the state is donated); on a TPU the fp32 step reads each
     row's live positions only, in blocks of
-    ``decode_attention.kv_read_block(T)`` (the Pallas kernel), every
-    other step reads masked over the whole rung (the XLA form).
+    ``decode_attention.kv_read_block(T)`` and its last block as far as
+    it is live (the Pallas kernel; ``kv_positions_read``), every other
+    step reads masked over the whole rung (the XLA form).
 
     The cache T axis is read from the cache arrays themselves, so one
     step fn serves every length rung of the slot pool's bucket ladder:

@@ -93,7 +93,7 @@ import numpy as np
 from paddle_tpu import compile_cache
 from paddle_tpu import faults as _faults
 from paddle_tpu import monitor
-from paddle_tpu.decode_attention import kv_read_block
+from paddle_tpu.decode_attention import kv_positions_read, kv_read_block
 from paddle_tpu.monitor import events as _events
 from paddle_tpu.monitor import spans as _mon_spans
 from paddle_tpu.serving.admission import PRIORITY_NORMAL
@@ -133,10 +133,16 @@ DECODE_TICKS = monitor.counter(
 DECODE_KV_READ = monitor.counter(
     "serving_decode_kv_positions_read_total",
     "KV-cache positions the decode steps read: per step and active "
-    "slot its live positions rounded up to the step's read block "
-    "(decode_attention.kv_read_block); the whole rung for an int8 pool "
-    "and for a speculative round, whose reads are masked, not ragged",
+    "slot its live positions rounded up as the ragged kernel rounds "
+    "them (decode_attention.kv_positions_read: the slot's last block "
+    "in classes of KV_TAIL rows); the whole rung for an int8 pool and "
+    "for a speculative round, whose reads are masked, not ragged",
     _LABELS)
+DECODE_KV_LIVE = monitor.counter(
+    "serving_decode_kv_positions_live_total",
+    "KV-cache positions that were live in the steps run (ts + 1 per "
+    "step and active slot) — read / live is what the read's rounding "
+    "costs", _LABELS)
 DECODE_KV_POOL = monitor.counter(
     "serving_decode_kv_positions_pool_total",
     "KV-cache positions the pool held while those steps ran (slots x "
@@ -412,6 +418,7 @@ class DecodeServer:
         self._prefill_c = DECODE_PREFILL_TOKENS.labels(**lbl)
         self._ticks_c = DECODE_TICKS.labels(**lbl)
         self._kv_read_c = DECODE_KV_READ.labels(**lbl)
+        self._kv_live_c = DECODE_KV_LIVE.labels(**lbl)
         self._kv_pool_c = DECODE_KV_POOL.labels(**lbl)
         self._ttft_h = DECODE_TTFT.labels(**lbl)
         self._occupancy_g = DECODE_OCCUPANCY.labels(**lbl)
@@ -536,6 +543,7 @@ class DecodeServer:
             "prefill_tokens": int(self._prefill_c.value),
             "ticks": int(self._ticks_c.value),
             "kv_positions_read": int(self._kv_read_c.value),
+            "kv_positions_live": int(self._kv_live_c.value),
             "kv_positions_pool": int(self._kv_pool_c.value),
             "slot_occupancy": float(self._occupancy_g.value),
             "steps_per_tick": self._pool.steps,
@@ -1181,35 +1189,32 @@ class DecodeServer:
         return True
 
     def _count_kv_positions(self, recs, view, use_spec: bool) -> None:
-        """Advance the KV read / pool position counters for the chunk
-        just run, from the ``pos`` the tick already fetched: a slot that
-        went from ``p0`` to ``p1`` ran steps at ``ts = p0..p1 - 1``, each
-        reading ``ts + 1`` positions rounded up to the read block."""
+        """Advance the KV read / live / pool position counters for the
+        chunk just run, from the ``pos`` the tick already fetched: a slot
+        that went from ``p0`` to ``p1`` ran steps at ``ts = p0..p1 - 1``,
+        each with ``ts + 1`` live positions, of which the ragged kernel
+        reads what :func:`kv_positions_read` says."""
         s, t = view["tokens"].shape
         idx = np.fromiter((i for i, _ in recs), np.intp, len(recs))
         p1 = view["pos"][idx].astype(np.int64)
         p0 = np.fromiter((r.pos for _, r in recs), np.int64, len(recs))
         steps = 1 if use_spec else self._pool.steps
         pool = s * t * steps
+        # the steps this chunk ran, row by row: ts = p0 .. p1 - 1
+        ts = p0[:, None] + np.arange(steps)[None, :]
+        ran = ts < p1[:, None]
         if use_spec or self._pool.kv_dtype != "fp32":
             read = pool  # masked reads over the whole rung
         else:
-            blk = kv_read_block(t)
-
-            def blocks_below(p):  # sum of (ts // blk + 1) over ts < p
-                full, rem = np.divmod(p, blk)
-                return blk * full * (full + 1) // 2 + rem * (full + 1)
-
-            read = int((blocks_below(p1) - blocks_below(p0)).sum()) * blk
+            read = int((kv_positions_read(ts, kv_read_block(t)) * ran).sum())
         if self._sparse_rule is not None and self._sparse_layers:
-            # the steps this chunk ran, row by row: contexts p0 + 1 .. p1
-            n = p0[:, None] + 1 + np.arange(steps)[None, :]
-            ran = n <= p1[:, None]
+            n = ts + 1                              # contexts p0 + 1 .. p1
             self._sparse_live_c.inc(
                 int((n * ran).sum()) * self._sparse_layers)
             self._sparse_read_c.inc(
                 int((self._sparse_rule(n) * ran).sum())
                 * self._sparse_layers)
+        self._kv_live_c.inc(int((p1 * (p1 + 1) - p0 * (p0 + 1)).sum()) // 2)
         for (_, rec), p in zip(recs, p1.tolist()):
             rec.pos = p
         self._kv_pool_c.inc(pool)
@@ -1274,7 +1279,7 @@ class DecodeServer:
         self._batcher.close()
         lbl = {"server": self.name, "instance": self._metrics.instance}
         for metric in (DECODE_TOKENS, DECODE_PREFILL_TOKENS, DECODE_TICKS,
-                       DECODE_KV_READ, DECODE_KV_POOL,
+                       DECODE_KV_READ, DECODE_KV_LIVE, DECODE_KV_POOL,
                        DECODE_TTFT, DECODE_OCCUPANCY, DECODE_KV_BYTES,
                        DECODE_STATE_RESETS, DECODE_RECURRENT_BYTES,
                        DECODE_ADMIT_DISPATCHES, DECODE_ADMITTED,

@@ -559,13 +559,18 @@ def test_decode_metrics_series(chain_server):
 
 
 def test_kv_position_counters_follow_live_blocks():
-    """``serving_decode_kv_positions_{read,pool}_total``: per tick the
-    pool counter advances by slots x T x steps, the read counter by each
-    active slot's live positions rounded up to the read block — so over
-    requests of known lengths their ratio is the block-rounded live share
-    of the pool.  A request of total length L runs the steps
-    ``ts = 0..L - 2``; a step at ``ts`` reads ``ts + 1`` positions."""
-    from paddle_tpu.decode_attention import KV_BLOCK, kv_read_block
+    """``serving_decode_kv_positions_{read,live,pool}_total``: per tick
+    the pool counter advances by slots x T x steps, the live counter by
+    each active slot's live positions, the read counter by those rounded
+    up as the ragged kernel rounds them (``kv_positions_read``: a slot's
+    last block in classes of ``KV_TAIL`` rows) — so over requests of
+    known lengths read / pool is the rounded live share of the pool and
+    read / live what the rounding costs.  A request of total length L
+    runs the steps ``ts = 0..L - 2``; a step at ``ts`` has ``ts + 1``
+    live positions."""
+    from paddle_tpu.decode_attention import (KV_BLOCK, KV_TAIL,
+                                             kv_positions_read,
+                                             kv_read_block)
 
     step_fn, make_cache = chain_model()
     S, T, steps = 4, 2 * KV_BLOCK, 4
@@ -596,18 +601,32 @@ def test_kv_position_counters_follow_live_blocks():
             r.result(timeout=30.0)
         assert seen == sorted(seen) and len(set(seen)) > 1
         d = srv.metrics()["decode"]
-        want = sum(-(-(ts + 1) // KV_BLOCK) * KV_BLOCK
-                   for _, total in lengths for ts in range(total - 1))
+        every_ts = [ts for _, total in lengths for ts in range(total - 1)]
+        want = sum(kv_positions_read(ts, KV_BLOCK) for ts in every_ts)
         assert d["kv_positions_read"] == want
+        # the shared function rounds a last block to its tail class ...
+        assert kv_positions_read(KV_BLOCK + 6, KV_BLOCK) == (
+            KV_BLOCK + KV_TAIL)
+        # ... so the counter lies between the live and the block-rounded
+        live = sum(ts + 1 for ts in every_ts)
+        assert d["kv_positions_live"] == live
+        assert live < want < sum(-(-(ts + 1) // KV_BLOCK) * KV_BLOCK
+                                 for ts in every_ts)
         assert d["kv_positions_pool"] == d["ticks"] * S * T * steps
         snap = monitor.snapshot()
         assert "serving_decode_kv_positions_read_total" in snap
+        assert "serving_decode_kv_positions_live_total" in snap
         assert "serving_decode_kv_positions_pool_total" in snap
         share = d["kv_positions_read"] / d["kv_positions_pool"]
         assert share == pytest.approx(want / (d["ticks"] * S * T * steps))
         assert 0.0 < share < 0.5
     finally:
         srv.stop(drain=False)
+    # a stopped server leaves none of the three series behind
+    for kind in ("read", "live", "pool"):
+        assert monitor.counter_value(
+            "serving_decode_kv_positions_%s_total" % kind, None,
+            server="kvcount") is None
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,13 @@ positions against one softmax over T; per-head sums as an indicator
 matmul whose fp32 operand is split into three bf16 terms carrying 24
 mantissa bits) — a few fp32 ulps (6e-8) over sums of at most 32 terms of
 O(1): 2e-6 absolute is ten times the largest difference seen.
+
+Two geometries.  ``RAGGED``: rungs of 32 in blocks of 8, which
+``KV_TAIL`` does not divide, so every item is a whole block (one body).
+``TAILS``: rungs of 256 in blocks of ``KV_BLOCK``, where a slot's last
+block is read in classes of ``KV_TAIL`` rows — every class, both edges
+of each, with and without a whole block before the tail (sums of up to
+256 terms there: the same tolerance holds).
 """
 import numpy as np
 import pytest
@@ -23,20 +30,22 @@ SCALE = 1.0 / np.sqrt(DH)
 ATOL = 2e-6
 
 
-def _inputs(seed, ts):
+def _inputs(seed, ts, t=T):
     import jax.numpy as jnp
 
     rng = np.random.RandomState(seed)
     q, kn, vn = (jnp.asarray(rng.randn(len(ts), D), jnp.float32)
                  for _ in range(3))
-    kc = jnp.asarray(rng.randn(len(ts), T, D), jnp.float32)
-    vc = jnp.asarray(rng.randn(len(ts), T, D), jnp.float32)
+    kc = jnp.asarray(rng.randn(len(ts), t, D), jnp.float32)
+    vc = jnp.asarray(rng.randn(len(ts), t, D), jnp.float32)
     return q, kn, vn, kc, vc, jnp.asarray(ts, jnp.int32)
 
 
-def _kernel(q, kn, vn, kc, vc, ts, block=BLOCK):
+def _kernel(q, kn, vn, kc, vc, ts, block=None):
+    t = kc.shape[1]
+    block = block or (BLOCK if t == T else da.kv_read_block(t))
     return da.ragged_decode_attention(
-        q, kn, vn, kc, vc, ts, da.decode_work_items(ts, T, block),
+        q, kn, vn, kc, vc, ts, da.decode_work_items(ts, t, block),
         n_head=H, scale=SCALE, block=block, interpret=True)
 
 
@@ -69,10 +78,31 @@ RAGGED = [[0, BLOCK - 1, BLOCK, BLOCK + 1, T - 1, -1],
           [-1, T - 1, 0, -1, BLOCK, 2 * BLOCK - 1],
           [T - 1] * 6, [0] * 6, [-1] * 6]
 
+# a slot's last block in classes of G rows: both edges of every class
+# (``ts % B`` at 0, G - 1, G, 2G - 1, ... B - 1), in the rung's first
+# block and behind a whole one; the orders move the seams between a
+# short item and the next slot's
+G, B = da.KV_TAIL, da.KV_BLOCK
+T2 = 2 * B
+_EDGES = [c * G + e for c in range(B // G) for e in (0, G - 1)]
+TAILS = [_EDGES + [-1],
+         [B + e for e in _EDGES] + [-1],
+         [-1] + [e + B * (i % 2) for i, e in enumerate(reversed(_EDGES))],
+         [T2 - 1, 5, B - 1, B + 1, G, -1]]
 
-@pytest.mark.parametrize("ts", RAGGED, ids=lambda ts: "-".join(map(str, ts)))
+
+def _rung(ts):
+    """The rung a case is run at: T, or for a TAILS case T2."""
+    return T2 if any(ts is case for case in TAILS) else T
+
+
+def _ids(ts):
+    return "-".join(map(str, ts))
+
+
+@pytest.mark.parametrize("ts", RAGGED + TAILS, ids=_ids)
 def test_kernel_matches_masked_einsum(ts):
-    args = _inputs(0, ts)
+    args = _inputs(0, ts, _rung(ts))
     o, k2, v2 = _kernel(*args)
     ro, rk, rv = _masked(*args)
     np.testing.assert_allclose(np.asarray(o), np.asarray(ro), rtol=0,
@@ -82,24 +112,29 @@ def test_kernel_matches_masked_einsum(ts):
     assert np.array_equal(np.asarray(v2), np.asarray(rv))
 
 
+@pytest.mark.parametrize("ts", RAGGED[:1] + TAILS, ids=_ids)
 @pytest.mark.parametrize("impl", [_kernel, _masked],
                          ids=["kernel", "masked"])
-def test_fp32_accumulation_against_float64(impl):
-    args = _inputs(1, RAGGED[0])
+def test_fp32_accumulation_against_float64(impl, ts):
+    args = _inputs(1, ts, _rung(ts))
     o, _, _ = impl(*args)
     np.testing.assert_allclose(np.asarray(o), _float64_reference(*args),
                                rtol=0, atol=ATOL)
 
 
+@pytest.mark.parametrize("t,ts", [
+    (T, [3, -1, BLOCK, -1, T - 1, -1]),
+    (T2, [G - 1, -1, G, -1, T2 - 1, -1]),
+    (T2, [-1, B + G - 1, -1, B - G, -1, B])], ids=["blocks", "tails",
+                                                       "tails_idle_first"])
 @pytest.mark.parametrize("impl", [_kernel, _masked],
                          ids=["kernel", "masked"])
-def test_idle_slots_read_nothing_and_write_nothing(impl):
+def test_idle_slots_read_nothing_and_write_nothing(impl, t, ts):
     """An idle slot's rows are NaN: read, they would poison its output;
     written, they would no longer be NaN.  Its context row is zero."""
     import jax.numpy as jnp
 
-    ts = [3, -1, BLOCK, -1, T - 1, -1]
-    q, kn, vn, kc, vc, tsa = _inputs(2, ts)
+    q, kn, vn, kc, vc, tsa = _inputs(2, ts, t)
     idle = np.asarray(ts) < 0
     kc = kc.at[idle].set(jnp.nan)
     vc = vc.at[idle].set(jnp.nan)
@@ -111,16 +146,18 @@ def test_idle_slots_read_nothing_and_write_nothing(impl):
     assert np.all(np.isnan(np.asarray(v2)[idle]))
 
 
+@pytest.mark.parametrize("ts", RAGGED[:1] + TAILS, ids=_ids)
 @pytest.mark.parametrize("impl", [_kernel, _masked],
                          ids=["kernel", "masked"])
-def test_garbage_beyond_ts_does_not_change_the_output(impl):
+def test_garbage_beyond_ts_does_not_change_the_output(impl, ts):
     """Positions past ``ts`` hold a previous occupant's rows: large and
-    finite, they must weigh exactly nothing (bit-equal outputs)."""
+    finite, they must weigh exactly nothing (bit-equal outputs) — those
+    inside the rows a tail item reads and those it never touches."""
     import jax.numpy as jnp
 
-    ts = RAGGED[0]
-    q, kn, vn, kc, vc, tsa = _inputs(3, ts)
-    beyond = (np.arange(T)[None, :] > np.asarray(ts)[:, None])[:, :, None]
+    t = _rung(ts)
+    q, kn, vn, kc, vc, tsa = _inputs(3, ts, t)
+    beyond = (np.arange(t)[None, :] > np.asarray(ts)[:, None])[:, :, None]
     clean, _, _ = impl(q, kn, vn, jnp.where(beyond, 0.0, kc),
                        jnp.where(beyond, 0.0, vc), tsa)
     dirty, _, _ = impl(q, kn, vn, jnp.where(beyond, 1e30, kc),
@@ -128,11 +165,11 @@ def test_garbage_beyond_ts_does_not_change_the_output(impl):
     assert np.array_equal(np.asarray(clean), np.asarray(dirty))
 
 
+@pytest.mark.parametrize("ts", RAGGED[:1] + TAILS, ids=_ids)
 @pytest.mark.parametrize("impl", [_kernel, _masked],
                          ids=["kernel", "masked"])
-def test_one_step_writes_one_row_per_active_slot(impl):
-    ts = RAGGED[0]
-    q, kn, vn, kc, vc, tsa = _inputs(4, ts)
+def test_one_step_writes_one_row_per_active_slot(impl, ts):
+    q, kn, vn, kc, vc, tsa = _inputs(4, ts, _rung(ts))
     _, k2, v2 = impl(q, kn, vn, kc, vc, tsa)
     for new, old, row in ((k2, kc, kn), (v2, vc, vn)):
         new, old, row = (np.asarray(a) for a in (new, old, row))
@@ -187,17 +224,22 @@ def test_k_fresh_rows_equal_k_appends_of_one_row(rep, dtype):
                               np.asarray(kv[name][2], np.float32))
 
 
-def test_consecutive_steps_through_the_kernel():
+@pytest.mark.parametrize("t,ts", [
+    (T, [0, BLOCK - 2, BLOCK - 1, -1, T - 4, 5]),
+    # steps that cross a class's edge, a block's, and neither
+    (T2, [G - 2, B - 2, B + G - 1, -1, T2 - 4, 2 * G - 3])],
+    ids=["blocks", "tails"])
+def test_consecutive_steps_through_the_kernel(t, ts):
     """Three steps in a row, each appending where the last left off:
     the caches stay bit-equal to the masked path's, the contexts
     within tolerance (the second step reads what the first wrote)."""
     import jax.numpy as jnp
 
-    ts = np.asarray([0, BLOCK - 2, BLOCK - 1, -1, T - 4, 5])
-    _, _, _, kc, vc, _ = _inputs(5, ts)
+    ts = np.asarray(ts)
+    _, _, _, kc, vc, _ = _inputs(5, ts, t)
     rk, rv = kc, vc
     for step in range(3):
-        q, kn, vn, _, _, _ = _inputs(10 + step, ts)
+        q, kn, vn, _, _, _ = _inputs(10 + step, ts, t)
         tsa = jnp.asarray(np.where(ts >= 0, ts + step, -1), jnp.int32)
         o, kc, vc = _kernel(q, kn, vn, kc, vc, tsa)
         ro, rk, rv = _masked(q, kn, vn, rk, rv, tsa)
@@ -211,6 +253,7 @@ def test_one_block_spanning_the_rung():
     """A rung the block does not divide is read as one block."""
     assert da.kv_read_block(512) == da.KV_BLOCK
     assert da.kv_read_block(T) == T
+    assert da.kv_positions_read(0, T) == da.kv_positions_read(T - 1, T) == T
     args = _inputs(6, RAGGED[0])
     o, _, _ = _kernel(*args, block=T)
     ro, _, _ = _masked(*args)
@@ -222,12 +265,41 @@ def test_work_items_are_the_live_blocks_slot_major():
     import jax.numpy as jnp
 
     ts = jnp.asarray([0, BLOCK - 1, BLOCK, -1, T - 1, -1], jnp.int32)
-    n_items, slot, blk = (np.asarray(a)
-                          for a in da.decode_work_items(ts, T, BLOCK))
+    n_items, slot, blk, rows = (np.asarray(a)
+                                for a in da.decode_work_items(ts, T, BLOCK))
     n = int(n_items[0])
-    assert slot.shape == blk.shape == (len(ts) * T // BLOCK,)
+    assert slot.shape == blk.shape == rows.shape == (len(ts) * T // BLOCK,)
     assert list(zip(slot[:n], blk[:n])) == [
         (0, 0), (1, 0), (2, 0), (2, 1), (4, 0), (4, 1), (4, 2), (4, 3)]
+    # KV_TAIL does not divide a block of 8: every item is a whole block
+    assert rows[:n].tolist() == [BLOCK] * n
+
+
+@pytest.mark.parametrize("ts", TAILS, ids=_ids)
+def test_work_items_carry_the_rows_of_a_last_block(ts):
+    """Blocks of ``KV_BLOCK``: a slot's blocks before its last are whole,
+    its last one has the rows ``kv_positions_read`` leaves it — ``ts %
+    B + 1`` rounded up to ``KV_TAIL`` — and a step's rows add up to what
+    the counter's function says it reads."""
+    import jax.numpy as jnp
+
+    n_items, slot, blk, rows = (
+        np.asarray(a) for a in da.decode_work_items(
+            jnp.asarray(ts, jnp.int32), T2, B))
+    items = list(zip(*(a[:int(n_items[0])].tolist()
+                       for a in (slot, blk, rows))))
+    want = []
+    for n, t in enumerate(ts):
+        if t < 0:
+            continue
+        want += [(n, b, B) for b in range(t // B)]
+        want.append((n, t // B, -(-(t % B + 1) // G) * G))
+    assert items == want
+    assert {r for _, _, r in items} <= set(range(G, B + 1, G))
+    for n, t in enumerate(ts):
+        if t >= 0:
+            assert sum(r for m, _, r in items if m == n) == (
+                da.kv_positions_read(t, B))
 
 
 _LM = dict(vocab=19, d_model=D, n_layer=2, n_head=H, d_inner=24, max_pos=T)
