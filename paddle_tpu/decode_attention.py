@@ -70,7 +70,8 @@ import numpy as np
 __all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
            "kv_read_block", "kv_positions_read", "decode_work_items",
            "ragged_decode_attention",
-           "grouped_masked_decode_attention", "append_rows",
+           "grouped_masked_decode_attention",
+           "lane_masked_decode_attention", "append_rows",
            "grouped_block_decode_attention", "block_sparse_decode_attention",
            "block_kernel_supported",
            "kernel_supported", "make_decode_attention"]
@@ -465,6 +466,48 @@ def grouped_masked_decode_attention(q, k_new, v_new, kv, ts,
     return jnp.where(live[..., None], ctx.reshape(q.shape), 0.0), kv
 
 
+def lane_masked_decode_attention(q, k_new, v_new, kv, ts, *, n_head: int,
+                                 n_kv_head: int, scale: float):
+    """The contract of :func:`grouped_masked_decode_attention` for one
+    fresh row per slot over unquantized leaves, with the leaves read AS
+    THEY LIE: no ``[S, T, n_kv_head, Dh]`` view of them is ever made.
+
+    For a head narrower than a lane tile (``Dh`` 64) that view is not a
+    bitcast on a TPU: the compiler re-tiles the leaf — a copy of the
+    whole rung, K and V, every layer and step (seen in the compiled
+    chunk of ``lfm2_24b_a2b``: four 537 MB copies a step, PR 40).  Here
+    each query head is instead laid into its K/V head's ``Dh`` lanes of
+    a row as wide as the leaf (zeros elsewhere), so the score product
+    contracts the leaf's whole last axis and the context product yields
+    whole-width rows of which the head keeps its own lanes: ``n_kv_head``
+    times the arithmetic of a step that is bound by the leaves' bytes,
+    and the same sums (the added terms are exact zeros)."""
+    import jax
+    import jax.numpy as jnp
+
+    S, T, Dkv = kv["k"].shape
+    heads = (n_kv_head, Dkv // n_kv_head)
+    rep, D, dt = n_head // n_kv_head, heads[1], kv["k"].dtype
+    rows, live = jnp.arange(S), ts >= 0
+    at = jnp.where(live, ts, T)             # idle -> out of range, dropped
+    kv = {**_append(kv, "k", k_new, rows, at, heads),
+          **_append(kv, "v", v_new, rows, at, heads)}
+    # own[h, c]: lane c of a leaf row belongs to query head h's K/V head
+    own = (jnp.arange(n_head)[:, None] // rep
+           == jnp.arange(Dkv)[None, :] // D)
+    qh = (q * scale).reshape(S, n_head, D)
+    qw = jnp.where(own[None], jnp.tile(qh, (1, 1, n_kv_head)), 0.0)
+    scores = jnp.einsum("shc,stc->sht", qw.astype(dt), kv["k"],
+                        preferred_element_type=jnp.float32)
+    pos_ok = jnp.arange(T)[None, None, :] <= ts[:, None, None]
+    w = jax.nn.softmax(jnp.where(pos_ok, scores, -1e9), axis=-1)
+    wide = jnp.einsum("sht,stc->shc", w.astype(dt), kv["v"],
+                      preferred_element_type=jnp.float32)
+    ctx = jnp.sum(jnp.where(own[None], wide, 0.0).reshape(
+        S, n_head, n_kv_head, D), axis=2)
+    return jnp.where(live[:, None], ctx.reshape(S, n_head * D), 0.0), kv
+
+
 def append_rows(kv, k_new, v_new, ts):
     """One layer's leaves with one fresh K/V row per slot appended in
     place at ``ts`` (idle slots, ``ts < 0``, are not written): the
@@ -699,13 +742,26 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
     leaves, all alike).  The one place that chooses: the kernel when it
     exists for what the step is — the default backend a TPU, fp32 leaves
     of a shape it lowers for, one query head per K/V head, one fresh row
-    per slot — and the XLA form otherwise."""
+    per slot — and an XLA form otherwise: on a TPU, for grouped heads
+    narrower than a lane tile over unquantized leaves, the one that reads
+    the leaves as they lie (:func:`lane_masked_decode_attention`), else
+    :func:`grouped_masked_decode_attention`."""
     import jax
     import jax.numpy as jnp
 
     _, seq_len, width = kv["k"].shape
     xla = functools.partial(grouped_masked_decode_attention, ts=ts,
                             n_head=n_head, n_kv_head=n_kv_head, scale=scale)
+    if (jax.default_backend() == "tpu" and "k_scale" not in kv
+            and n_kv_head < n_head and (width // n_kv_head) % _HEAD_LANES):
+        # grouped heads narrower than a lane tile: a view of the leaf by
+        # heads would be a copy of the rung (lane_masked_decode_attention)
+        lanes = functools.partial(
+            lane_masked_decode_attention, ts=ts, n_head=n_head,
+            n_kv_head=n_kv_head, scale=scale)
+        return lambda q, k_new, v_new, kv: (
+            lanes(q, k_new, v_new, kv) if q.ndim == 2
+            else xla(q, k_new, v_new, kv))
     if not (jax.default_backend() == "tpu"
             and kv["k"].dtype == jnp.float32 and n_kv_head == n_head
             and kernel_supported(seq_len, width, n_head)):

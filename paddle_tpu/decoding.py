@@ -26,9 +26,11 @@ The serving slot pool (``serving.kv_pool`` / ``serving.decode``) runs a
 third: ``step_fn(cache, tokens, ts)`` with every row at its OWN position
 ``ts``.  Its builders — ``make_transformer_lm_pooled_step_fn``, its
 K-wide twin ``make_transformer_lm_pooled_verify_fn``,
-``make_hybrid_ssm_lm_pooled_step_fn`` and
+``make_hybrid_ssm_lm_pooled_step_fn``,
 ``make_sparse_linear_lm_pooled_step_fn`` (which also builds a chunked
-prefill) — are made of the same parts:
+prefill) and ``make_routed_conv_lm_pooled_step_fn`` (layers that hold
+different leaves, routed experts, counts made on the device) — are made
+of the same parts:
 
 * ONE cache format, whatever the storage dtype (fp32, bf16, int8):
   ``paddle_tpu.decode_attention`` says what a K/V leaf is, appends the
@@ -66,6 +68,7 @@ __all__ = [
     "make_transformer_lm_pooled_verify_fn", "make_prefix_admit_fn",
     "make_hybrid_ssm_lm_pooled_step_fn",
     "make_sparse_linear_lm_pooled_step_fn",
+    "make_routed_conv_lm_pooled_step_fn",
     "cache_leaf_seq_axes", "cache_leaf_seq_strides", "recurrent_leaf_names",
     "normalize_kv_dtype",
     "random_transformer_lm_state",
@@ -855,6 +858,135 @@ def make_sparse_linear_lm_pooled_step_fn(state, cfg, name: str = "lm",
     prefill_fn.chunk_tokens = C
     make_cache.prefill_fn = prefill_fn
     return step_fn, make_cache, prefill_fn
+
+
+def make_routed_conv_lm_pooled_step_fn(state, cfg, name: str = "lm",
+                                       kv_dtype: str = "bf16", held=None):
+    """The slot-pooled step of a decoder whose layers are a gated short
+    convolution or grouped-query attention, each followed by a dense
+    SwiGLU (the leading ``num_dense_layers``) or a mixture of routed
+    experts (``model_type: lfm2_moe``; the parts and the equations are
+    ``paddle_tpu.routed_experts``, the experts' grouped product
+    ``paddle_tpu.grouped_matmul``).
+
+    Same contract as the builders above: ``step_fn(cache, tokens [N]
+    int32, ts [N] int32) -> (logits [N, V] fp32, cache)`` with ``ts[i] <
+    0`` an idle row, and ``make_cache(n_rows, seq_len)``.  ``state``:
+    weights under ``routed_experts.param_shapes(cfg)``, multiplied in
+    the dtype they are given (bf16 as stored: no per-step conversion;
+    the router stays float32); ``cfg``: the published config keys
+    (``routed_experts.dims``); ``held``: the contiguous range ``(lo,
+    hi)`` of experts whose matrices ``state`` holds (default: all) —
+    every layer routes over all ``num_experts`` and adds what the held
+    ones give.  The output head is the embedding (tied).
+
+    The cache is ``{"layers": [...], "expert_stats": ...}`` and
+    ``make_cache.leaf_seq_axes`` declares every leaf, layer by layer,
+    because the layers hold DIFFERENT leaves:
+
+    * an attention layer ``k``, ``v`` ``[N, T, n_kv_head * head_dim]``
+      in ``kv_dtype`` (``k`` after its per-head norm and rotary),
+      ``decode_attention``'s format through ``make_decode_attention``
+      (grouped heads: the XLA form), covered by write-before-read;
+    * a conv layer ``conv`` ``[N, conv_L_cache - 1, d_model]`` fp32, the
+      row's last inputs of the depthwise convolution: RECURRENT (``-1``),
+      read as zero for a row at ``ts == 0`` (``hybrid_ssm.starts_fresh``),
+      kept for an idle row;
+    * ``expert_stats`` ``[expert layers, 4]`` int32 (``-1``: the pool
+      carries it and never slices it): what the steps so far counted on
+      the DEVICE, per expert layer, in ``routed_experts.STAT_NAMES``'
+      order — (row, choice) pairs of live rows, experts that got at
+      least one, the largest group, steps with a live row — summed over
+      steps (it wraps as a uint32 does).  Which experts a step touches
+      is known only there; ``make_cache.expert_stats(cache)`` picks the
+      leaf, and a server that finds the attribute fetches it with the
+      scheduler's view, in the same ``device_get``.
+
+    Prompts walk the one-token step (no chunked prefill), so, as over
+    :func:`make_hybrid_ssm_lm_pooled_step_fn`, ``KVSlotPool`` refuses
+    ``prefix=True`` and ``speculative=`` over this builder.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import routed_experts as rx
+    from paddle_tpu.decode_attention import kv_leaves, make_decode_attention
+
+    d = rx.dims(cfg)
+    kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
+    W = {k: jnp.asarray(v) for k, v in state.items()}
+    scale = 1.0 / float(np.sqrt(d.head_dim))
+    attn_at = [i for i, kind in enumerate(d.kinds) if kind == rx.ATTENTION]
+    n_stats = len(rx.STAT_NAMES)
+
+    def make_cache(n_rows: int, seq_len: int):
+        return {
+            "layers": [
+                kv_leaves(n_rows, seq_len, d.n_kv_head, d.head_dim, kv)
+                if kind == rx.ATTENTION else
+                {"conv": jnp.zeros((n_rows, d.conv_len - 1, d.d_model),
+                                   jnp.float32)}
+                for kind in d.kinds],
+            "expert_stats": jnp.zeros((len(d.expert_layers), n_stats),
+                                      jnp.int32)}
+
+    make_cache.leaf_seq_axes = {
+        "layers": [{"k": 1, "v": 1} if kind == rx.ATTENTION else {"conv": -1}
+                   for kind in d.kinds],
+        "expert_stats": -1}
+    make_cache.expert_stats = lambda cache: cache["expert_stats"]
+    make_cache.n_expert = d.n_expert
+
+    def step_fn(cache, tokens, ts):
+        n = tokens.shape[0]
+        layers = cache["layers"]
+        attend = None
+        if attn_at:
+            ts = jnp.minimum(ts, layers[attn_at[0]]["k"].shape[1] - 1)
+            attend = make_decode_attention(
+                ts, layers[attn_at[0]], n_head=d.n_head,
+                n_kv_head=d.n_kv_head, scale=scale)
+        pos = jnp.maximum(ts, 0)      # idle rows stay < 0 in ``ts``
+        emb = W[name + "_emb"]
+        h = emb[tokens].astype(jnp.float32)
+        new_layers, stats = [], []
+        for i, kind in enumerate(d.kinds):
+            p = "%s_l%d_" % (name, i)
+            c = layers[i]
+            r = rx.rms_norm(h, W[p + "operator_norm"], d.eps)
+            if kind == rx.CONV:
+                o, conv = rx.short_conv_step(r, W, p, c["conv"], ts, d)
+                new_layers.append({"conv": conv})
+            else:
+                q = rx.rms_norm(rx.linear(r, W[p + "attn_q"]).reshape(
+                    n, d.n_head, d.head_dim), W[p + "q_layernorm"], d.eps)
+                k = rx.rms_norm(rx.linear(r, W[p + "attn_k"]).reshape(
+                    n, d.n_kv_head, d.head_dim), W[p + "k_layernorm"], d.eps)
+                ctx, kvs = attend(
+                    rx.rotary(q, pos, d.rope_theta).reshape(n, -1),
+                    rx.rotary(k, pos, d.rope_theta).reshape(n, -1),
+                    rx.linear(r, W[p + "attn_v"]), c)
+                o = rx.linear(ctx, W[p + "attn_o"])
+                new_layers.append(kvs)
+            h = h + o
+            f = rx.rms_norm(h, W[p + "ffn_norm"], d.eps)
+            if i < d.n_dense:
+                h = h + rx.swiglu(f, W[p + "ffn_gate"], W[p + "ffn_up"],
+                                  W[p + "ffn_down"], 1.0, 1.0)
+            else:
+                y, st = rx.expert_layer(f, W, p, ts, d, held)
+                h = h + y
+                stats.append(st)
+        x = rx.rms_norm(h, W[name + "_embedding_norm"], d.eps)
+        logits = jax.lax.dot_general(
+            x.astype(emb.dtype), emb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        counted = cache["expert_stats"]
+        if stats:
+            counted = counted + jnp.stack(stats)
+        return logits, {"layers": new_layers, "expert_stats": counted}
+
+    return step_fn, make_cache
 
 
 def make_transformer_lm_pooled_verify_fn(

@@ -214,6 +214,29 @@ DECODE_SPARSE_LIVE = monitor.counter(
     "have read) — read / live is how sparse the traffic makes the "
     "layer", _LABELS)
 
+_EXPERT_STATS_HELP = (
+    " — counted on the device by a step whose builder declares "
+    "make_cache.expert_stats, summed over its expert layers, fetched "
+    "with the scheduler's view once a tick; 0 for a builder without "
+    "routed experts")
+DECODE_EXPERT_COUNTERS = tuple(
+    monitor.counter("serving_decode_%s_total" % name, text
+                    + _EXPERT_STATS_HELP, _LABELS)
+    for name, text in (
+        ("expert_assignments",
+         "(row, choice) pairs of live rows routed to an expert this pool "
+         "holds"),
+        ("experts_touched",
+         "experts that got at least one row, per step and expert layer: "
+         "over expert_layer_steps it is the matrices a layer streams a "
+         "step"),
+        ("expert_peak_load",
+         "rows of the largest group, per step and expert layer: times "
+         "the expert count over expert_assignments it is the peak over "
+         "the mean group (1.0 = even routing)"),
+        ("expert_layer_steps",
+         "steps with a live row times expert layers")))
+
 # safety-net bound while parked on the empty-queue condition (real
 # wakeups are offer()/wake() notifies); a server with nothing seated
 # that sees no arrival for this long is idle and drops its pool state
@@ -437,6 +460,13 @@ class DecodeServer:
         # counters above: (positions a query of context n reads, layers)
         self._sparse_rule = getattr(make_cache, "sparse_positions_read", None)
         self._sparse_layers = int(getattr(make_cache, "sparse_layers", 0))
+        # what the builder's steps count on the device (routed experts):
+        # the leaf rides the tick's one device_get, the deltas go to the
+        # four counters, in routed_experts.STAT_NAMES' order
+        self._expert_stats_of = getattr(make_cache, "expert_stats", None)
+        self._n_expert = int(getattr(make_cache, "n_expert", 0))
+        self._expert_cs = [c.labels(**lbl) for c in DECODE_EXPERT_COUNTERS]
+        self._expert_seen = None     # the leaf as last fetched
         self._admit_seq = 0
         # decode tier 2, each independently toggleable: ``prefix_cache``
         # (a PrefixKVCache, or a byte budget to own one) retains freed
@@ -561,6 +591,10 @@ class DecodeServer:
             "prefill_chunk_tokens": self._pool.prefill_tokens,
             "sparse_positions_read": int(self._sparse_read_c.value),
             "sparse_positions_live": int(self._sparse_live_c.value),
+            "expert_assignments": int(self._expert_cs[0].value),
+            "experts_touched": int(self._expert_cs[1].value),
+            "expert_peak_load": int(self._expert_cs[2].value),
+            "expert_layer_steps": int(self._expert_cs[3].value),
             "seq_len_histogram": {
                 str(k): v
                 for k, v in sorted(self.seq_len_histogram().items())},
@@ -902,6 +936,8 @@ class DecodeServer:
                 dispatches0 = self._admit_dispatches_c.value
                 turn.enter("admit_dispatch")
             if (new_s, new_t) != cur:
+                if cur is None:
+                    self._expert_seen = None   # a fresh state counts from 0
                 self._state = (
                     pool.alloc(new_s, new_t) if cur is None
                     else pool.resize(self._state, new_s, new_t))
@@ -1054,6 +1090,9 @@ class DecodeServer:
             self._fail_and_drop_pool(exc)
             return
         self._state = state
+        fetch = {k: state[k] for k in _VIEW}
+        if self._expert_stats_of is not None:
+            fetch["expert_stats"] = self._expert_stats_of(state["cache"])
         if turn is not None:
             turn.leave(kind=kind)
             # tell the chunk's rest from the copies without delaying
@@ -1063,8 +1102,8 @@ class DecodeServer:
             # 0.5 ms: v5e chip run, PR 36), then wait on the smallest of
             # the five — outputs of one execution, ready together
             turn.enter("wait")
-            for k in _VIEW:
-                state[k].copy_to_host_async()
+            for v in fetch.values():
+                v.copy_to_host_async()
             jax.block_until_ready(state["n_gen"])
             turn.leave()
             turn.enter("copy")
@@ -1072,12 +1111,15 @@ class DecodeServer:
         # arrays (tokens/pos/flags — KBs, not the KV cache); device_get
         # starts all five copies before it waits for the first, so the
         # tick pays one transfer's latency, not five in a row
-        view = jax.device_get({k: state[k] for k in _VIEW})
+        view = jax.device_get(fetch)
         if turn is not None:
             turn.leave(bytes=sum(v.nbytes for v in view.values()))
             turn.enter("deliver", cpu=True)
             tokens0 = self._tokens_c.value
+        experts = {}
         if stepped:
+            if "expert_stats" in view:
+                experts = self._count_experts(view["expert_stats"])
             self._count_kv_positions(recs, view, use_spec)
             # after the position counters: whoever sees the tick counted
             # sees its positions counted too
@@ -1145,7 +1187,8 @@ class DecodeServer:
         if turn is not None:
             turn.leave(
                 fresh_tokens=int(self._tokens_c.value - tokens0),
-                finished=sum(1 for i, _ in recs if self._slots[i] is None))
+                finished=sum(1 for i, _ in recs if self._slots[i] is None),
+                **experts)
 
     def _prefill_turn(self, recs, turn: Optional[_Turn]) -> bool:
         """The turn's ONE prefill dispatch, before its decode chunk: the
@@ -1187,6 +1230,25 @@ class DecodeServer:
         if turn is not None:
             turn.leave(slot=slot, last=last)
         return True
+
+    def _count_experts(self, stats) -> Dict[str, float]:
+        """Advance the four expert counters by what the chunk just run
+        added to the builder's ``expert_stats`` leaf (``[expert layers,
+        4]`` int32 sums that wrap as uint32 does), and return the
+        ``deliver`` span's fields: experts a layer touched a step, and
+        the largest group over the mean group."""
+        now = np.asarray(stats).astype(np.uint32)
+        seen = np.zeros_like(now) if self._expert_seen is None \
+            else self._expert_seen
+        self._expert_seen = now
+        delta = (now - seen).astype(np.int64).sum(axis=0)   # modulo 2**32
+        for c, n in zip(self._expert_cs, delta.tolist()):
+            c.inc(n)
+        pairs, touched, peak, layer_steps = delta.tolist()
+        return {
+            "experts_touched": touched / layer_steps if layer_steps else 0.0,
+            "peak_over_mean": (self._n_expert * peak / pairs
+                               if pairs else 0.0)}
 
     def _count_kv_positions(self, recs, view, use_spec: bool) -> None:
         """Advance the KV read / live / pool position counters for the

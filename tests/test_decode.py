@@ -1255,6 +1255,108 @@ def test_a_chunked_builders_turn_has_a_prefill_phase():
                       if s["name"] == "serving/decode/dispatch"}
 
 
+def counting_chain_model(experts=8):
+    """The chain model with counts made on the device, declared as a
+    routed-experts builder declares them (``make_cache.expert_stats``):
+    every step counts, in ONE "expert layer", two pairs a live row, as
+    many experts touched as rows are live (at most ``experts``), a peak
+    of 2 and itself."""
+    import jax
+    import jax.numpy as jnp
+
+    def step_fn(cache, tokens, ts):
+        live = jnp.sum(ts >= 0).astype(jnp.int32)
+        add = jnp.stack([2 * live, jnp.minimum(live, experts),
+                         2 * (live > 0), 1 * (live > 0)]).astype(jnp.int32)
+        logits = jax.nn.one_hot((tokens + 1) % V, V) * 10.0
+        return logits, {"z": cache["z"], "counts": cache["counts"] + add}
+
+    def make_cache(n_rows, seq_len):
+        return {"z": jnp.zeros((n_rows, seq_len), "float32"),
+                "counts": jnp.zeros((1, 4), jnp.int32)}
+
+    make_cache.leaf_seq_axes = {"z": 1, "counts": -1}
+    make_cache.expert_stats = lambda cache: cache["counts"]
+    make_cache.n_expert = experts
+    return step_fn, make_cache
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_counts_a_builder_makes_on_the_device_reach_the_four_counters(
+        traced):
+    """What the steps count rides the tick's one fetch and lands, as
+    deltas, in ``serving_decode_expert_*_total`` and ``metrics()``; a
+    traced turn also puts experts touched a layer-step and the peak over
+    the mean group on its ``deliver`` span."""
+    from paddle_tpu.monitor import spans as mon_spans
+
+    name = "chain-counts-%d" % traced
+    step_fn, make_cache = counting_chain_model()
+    srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=16,
+                       max_slots=4, steps_per_tick=2, name=name)
+    srv.warmup(configure_cache=False)
+    prompts = [[10, 11], [12], [10, 11, 12], [13]]
+    if traced:
+        spans = _served_under_recording(srv, prompts, 6)
+    else:
+        reqs = [srv.submit({"tokens": np.asarray(p, np.int32)},
+                           max_new_tokens=6) for p in prompts]
+        for r in reqs:
+            r.result(timeout=60.0)
+        spans = []
+    m = srv.metrics()["decode"]
+    srv.stop()
+    # a row-step is a consumed position: prompt + generated - 1 each
+    row_steps = sum(len(p) + 6 - 1 for p in prompts)
+    assert m["expert_assignments"] == 2 * row_steps
+    assert m["experts_touched"] == row_steps
+    assert m["expert_peak_load"] == 2 * m["expert_layer_steps"] > 0
+    for key in ("expert_assignments", "experts_touched", "expert_peak_load",
+                "expert_layer_steps"):
+        assert monitor.counter_value("serving_decode_%s_total" % key,
+                                     server=name) == m[key]
+    delivered = [s["args"] for s in spans
+                 if s["name"] == "serving/decode/deliver"]
+    assert bool(delivered) == traced
+    for a in delivered:
+        # 8 experts x a peak of 2 over 2 pairs a live row
+        assert 1.0 <= a["experts_touched"] <= 4.0
+        assert a["peak_over_mean"] == pytest.approx(
+            8.0 / a["experts_touched"])
+    if traced:
+        copied = {s["args"]["bytes"] for s in spans
+                  if s["name"] == "serving/decode/copy"}
+        # the five arrays of the view and the 16 bytes of counts
+        s_, t_ = 4, 16
+        assert copied == {s_ * t_ * 4 + 2 * s_ * 4 + 2 * s_ + 16}
+
+
+def test_counts_start_again_with_a_fresh_pool_state(monkeypatch):
+    """A server that dropped its idle pool allocates a zeroed state: the
+    counts it then fetches are deltas from zero, not from what the
+    dropped state had reached."""
+    from paddle_tpu.serving import decode as decode_mod
+
+    monkeypatch.setattr(decode_mod, "_IDLE_WAIT_S", 0.05)
+    step_fn, make_cache = counting_chain_model()
+    srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=16,
+                       max_slots=2, steps_per_tick=2, name="chain-recount")
+    srv.warmup(configure_cache=False)
+    try:
+        for want in (1, 2):
+            srv.submit({"tokens": np.array([10, 11], np.int32)},
+                       max_new_tokens=5).result(timeout=60.0)
+            m = srv.metrics()["decode"]
+            assert m["expert_assignments"] == want * 2 * 6
+            deadline = time.monotonic() + 30.0
+            while (srv.metrics()["decode"]["idle_drops"] < want
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)
+            assert srv.metrics()["decode"]["idle_drops"] == want
+    finally:
+        srv.stop()
+
+
 def _idle_server_dropped_its_pool(monkeypatch, name):
     """A served request, then no arrival for a (shortened) idle wait:
     returns the stopped server once it has dropped its pool state."""

@@ -419,6 +419,70 @@ def test_block_kernel_is_refused_for_shapes_it_cannot_tile():
 
 
 # ---------------------------------------------------------------------------
+# grouped heads narrower than a lane tile: the leaves read as they lie
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_lane_masked_form_equals_the_grouped_form(dtype):
+    """Each query head laid into its K/V head's lanes of a whole-width
+    row gives the sums the per-head view gives (the added terms are
+    exact zeros): same context, same appended leaves, idle rows
+    untouched.  float32 differs in the order of sums only; bf16 rounds
+    the same operands on both sides."""
+    import jax.numpy as jnp
+
+    s, t, g, rep, dh = 5, 24, 2, 4, 8
+    rng = np.random.RandomState(3)
+    q = jnp.asarray(rng.randn(s, g * rep * dh), jnp.float32)
+    kn, vn = (jnp.asarray(rng.randn(s, g * dh), jnp.float32)
+              for _ in range(2))
+    kv = {n: jnp.asarray(rng.randn(s, t, g * dh), dtype) for n in "kv"}
+    ts = jnp.asarray([0, 7, -1, 23, 12], jnp.int32)
+    kw = dict(n_head=g * rep, n_kv_head=g, scale=0.35)
+    want, kv_want = da.grouped_masked_decode_attention(q, kn, vn, kv, ts,
+                                                       **kw)
+    got, kv_got = da.lane_masked_decode_attention(q, kn, vn, kv, ts, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6 if dtype == "float32" else 2e-2)
+    for n in "kv":
+        assert np.array_equal(np.asarray(kv_got[n].astype("float32")),
+                              np.asarray(kv_want[n].astype("float32")))
+    assert not np.asarray(got)[2].any()
+
+
+def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
+        monkeypatch):
+    """On a TPU, grouped heads of 64 over bf16 leaves read the leaves as
+    they lie; heads of 128 (falcon_h1), one query head a K/V head
+    (gpt1), int8 leaves, K fresh rows and every CPU run keep the grouped
+    form."""
+    import jax
+    import jax.numpy as jnp
+
+    ts = jnp.zeros((2,), jnp.int32)
+    narrow = da.kv_leaves(2, 16, 2, 64, jnp.bfloat16)
+    wide = da.kv_leaves(2, 16, 2, 128, jnp.bfloat16)
+    name = lambda f: getattr(f, "func", f).__name__
+    cpu = da.make_decode_attention(ts, narrow, n_head=8, n_kv_head=2,
+                                   scale=1.0)
+    assert name(cpu) == "grouped_masked_decode_attention"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for kv, heads in ((wide, (8, 2)), (narrow, (2, 2)),
+                      (da.kv_leaves(2, 16, 2, 64, jnp.int8), (8, 2))):
+        f = da.make_decode_attention(ts, kv, n_head=heads[0],
+                                     n_kv_head=heads[1], scale=1.0)
+        assert name(f) == "grouped_masked_decode_attention"
+    seen = []
+    monkeypatch.setattr(da, "lane_masked_decode_attention",
+                        lambda q, *a, **k: seen.append("lane") or (q, a[2]))
+    monkeypatch.setattr(da, "grouped_masked_decode_attention",
+                        lambda q, *a, **k: seen.append("grouped") or (q, a[2]))
+    f = da.make_decode_attention(ts, narrow, n_head=8, n_kv_head=2, scale=1.0)
+    f(jnp.zeros((2, 512)), None, None, narrow)
+    f(jnp.zeros((2, 3, 512)), None, None, narrow)   # K fresh rows
+    assert seen == ["lane", "grouped"]
+
+
+# ---------------------------------------------------------------------------
 # the kernel at the benchmark's widths, compiled for a described v5e chip
 # (no chip attached: nothing runs, the chip's compiler accepts or refuses)
 # ---------------------------------------------------------------------------
@@ -616,3 +680,62 @@ def test_fused_attention_kernels_compile_for_v5e_at_bert_widths(one_chip,
     assert "fused_attention_fwd" in text and "fused_attention_bwd" in text
     assert "[%d,%d,%d,%d]" % (n, h, s, s) not in text
     assert "[%d,%d,%d,%d]" % (n, h, s, d) not in text
+
+
+def test_grouped_matmul_compiles_for_v5e_at_lfm2_widths(one_chip):
+    """1024 sorted (row, choice) pairs against 64 experts' stacked
+    matrices, both products of the gated FFN: the kernel lowers, and no
+    expert matrix is copied (no temporary of a stacked matrix's size)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import grouped_matmul as gm
+
+    m, d, f, e = 1024, 2048, 1536, 64
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def ffn(rows, w13, w2, sizes):
+        p = gm.plan(sizes, m)
+        gu = gm.kernel_grouped_matmul(rows, w13, p)
+        act = jax.nn.silu(gu[:, :f]) * gu[:, f:]
+        return gm.kernel_grouped_matmul(act.astype(w2.dtype), w2, p)
+
+    assert gm.lowering("tpu", sd((m, d)), sd((e, d, 2 * f))) == "kernel"
+    assert gm.lowering("tpu", sd((m, f)), sd((e, f, d))) == "kernel"
+    compiled = jax.jit(ffn).lower(
+        sd((m, d)), sd((e, d, 2 * f)), sd((e, f, d)),
+        sd((e,), jnp.int32)).compile()
+    assert compiled.as_text().count(gm.KERNEL_NAME) >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        e * f * d * 2) // 4
+
+
+def test_lane_masked_attention_makes_no_copy_of_a_rung_on_v5e(one_chip):
+    """256 slots x 2048 positions x 8 K/V heads of 64, 4 query heads a
+    K/V head, bf16: the form that reads the leaves as they lie compiles
+    with temporaries far under a leaf's size; the per-head view of the
+    same leaves re-tiles them (a copy of each, which is why the step
+    does not take it)."""
+    import jax
+    import jax.numpy as jnp
+
+    s, t, g, rep, dh = 256, 2048, 8, 4, 64
+    leaf = s * t * g * dh * 2
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def temp(form):
+        def f(q, kn, vn, k, v, ts):
+            return form(q, kn, vn, {"k": k, "v": v}, ts, n_head=g * rep,
+                        n_kv_head=g, scale=0.125)
+        return jax.jit(f, donate_argnums=(3, 4)).lower(
+            sd((s, g * rep * dh)), sd((s, g * dh)), sd((s, g * dh)),
+            sd((s, t, g * dh), jnp.bfloat16), sd((s, t, g * dh), jnp.bfloat16),
+            sd((s,), jnp.int32)).compile().memory_analysis(
+            ).temp_size_in_bytes
+
+    assert temp(da.lane_masked_decode_attention) < leaf // 4
+    assert temp(da.grouped_masked_decode_attention) >= leaf

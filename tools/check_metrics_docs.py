@@ -35,6 +35,7 @@ REGISTERING_MODULES = [
     "paddle_tpu.monitor.push",
     "paddle_tpu.executor",
     "paddle_tpu.fused_attention",
+    "paddle_tpu.grouped_matmul",
     "paddle_tpu.decoding",
     "paddle_tpu.reader",
     "paddle_tpu.inference",

@@ -118,6 +118,21 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
                 w, cfg, kv_dtype=sv["kv_dtype"],
                 state_dtype=cfg["assumed"]["lightning_state_dtype"],
                 prefill_tokens=sv["prefill_tokens"])[:2]
+    elif cfg["family"] == "pooled_routed_conv_lm":
+        from paddle_tpu import routed_experts
+
+        # the first ``layers`` of the cut (2: the dense conv layer and an
+        # attention layer with its experts; 9: the whole cut)
+        cfg["layer_types"] = cfg["layer_types"][:layers]
+        cfg["num_hidden_layers"] = len(cfg["layer_types"])
+        # as the family makes them: matrices bf16, the rest fp32
+        weights = {n: sd(shp, jnp.float32 if n.endswith(
+            routed_experts.FLOAT32_PARAMS) else jnp.bfloat16)
+            for n, shp in routed_experts.param_shapes(cfg).items()}
+
+        def build(w):
+            return decoding.make_routed_conv_lm_pooled_step_fn(
+                w, cfg, kv_dtype=sv["kv_dtype"])
     elif cfg["family"] == "pooled_hybrid_ssm_lm":
         from paddle_tpu import hybrid_ssm
 
@@ -225,7 +240,8 @@ def main():
         fh.write(text)
     print("%s: %d bytes, %s, %d whole-matrix casts to bf16" % (
         args.out, len(text), "ragged_decode_attention kernel"
-        if "ragged_decode_attention" in text else "no kernel",
+        if "ragged_decode_attention" in text else "grouped_matmul kernel"
+        if "grouped_matmul" in text else "no kernel",
         weight_casts(lowered, text)))
 
 

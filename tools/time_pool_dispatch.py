@@ -32,6 +32,7 @@ take as many array arguments as the cell's):
         --one-call-a-request
     python tools/time_pool_dispatch.py minicpm_sala
     python tools/time_pool_dispatch.py falcon_h1_34b
+    python tools/time_pool_dispatch.py lfm2_24b_a2b
 
 For a pool that prefills in chunks and keeps snapshots (``minicpm_sala``)
 it also times one ``prefill`` chunk, one ``snapshot``, one
@@ -128,6 +129,10 @@ def build_step(root, config, rehearse):
             weights, cfg, kv_dtype=sv["kv_dtype"],
             state_dtype=cfg["assumed"]["lightning_state_dtype"],
             prefill_tokens=int(sv["prefill_tokens"]))
+    elif cfg["family"] == "pooled_routed_conv_lm":
+        build, parts = family.builder()
+        weights = family.make_weights(cfg, dev, parts)
+        step_fn, make_cache = build(weights, cfg, kv_dtype=sv["kv_dtype"])
     else:
         sys.exit("time_pool_dispatch: no builder for family %r"
                  % cfg["family"])
