@@ -28,7 +28,12 @@ a speculative verify round: ``K`` rows).  Per step and layer
   (:func:`append_rows`) and hands :func:`grouped_block_decode_attention`
   per slot and K/V head a list of blocks: the slot attends to the live
   positions of those blocks and to nothing else, so its read does not
-  grow with the rung.
+  grow with the rung.  The caller may DECLARE stretches of its lists
+  that every head holds alike and that name consecutive blocks
+  (``shared_runs``: a rule's forced window); the TPU kernel
+  (:func:`block_sparse_decode_attention`) then reads each as ONE copy a
+  leaf of whole-width rows and every other block as a head's own tile,
+  one slot after another with the next reads in flight.
 
 Two implementations of the read-what-is-live contract, chosen by
 :func:`make_decode_attention` from what it can observe:
@@ -67,14 +72,24 @@ import functools
 
 import numpy as np
 
+from paddle_tpu.monitor import registry as _registry
+
 __all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
            "kv_read_block", "kv_positions_read", "decode_work_items",
            "ragged_decode_attention",
            "grouped_masked_decode_attention",
            "lane_masked_decode_attention", "append_rows",
            "grouped_block_decode_attention", "block_sparse_decode_attention",
-           "block_kernel_supported",
+           "block_kernel_supported", "BLOCK_SPARSE_LOWERED",
            "kernel_supported", "make_decode_attention"]
+
+BLOCK_SPARSE_LOWERED = _registry.REGISTRY.counter(
+    "block_sparse_lowered_total",
+    "reads of the blocks named for a row lowered (traced into a program "
+    "or run eagerly), by the lowering chosen: kernel (Pallas TPU: the "
+    "declared runs as whole-width slabs, a head's other blocks as tiles, "
+    "the reads in flight by hand) | xla (a block gather and a masked "
+    "softmax)", ("path",))
 
 #: the sequence axis of every K/V leaf (and scale sibling)
 KV_SEQ_AXIS = 1
@@ -91,6 +106,19 @@ KV_TAIL = 64
 #: two items can
 _READS_AHEAD = 2
 _HEAD_LANES = 128   # heads padded to one lane tile in the score domain
+#: tile reads the block kernel issues a turn of its loop (2 to 32 read
+#: alike on the chip, one a turn 4% slower: the copies set the pace)
+_TILE_UNROLL = 4
+#: keys of a unit the block kernel scores at a time (whole blocks): the
+#: kernel's body holds ONE ``[rep, _SCORE_ROWS]`` chain of products and
+#: softmax a unit kind, looped over the unit.  A chunk costs ~0.5 us
+#: whatever it holds (256 / 512 / 1024 / 4096 keys: 1.50 / 0.82 / 0.53 /
+#: 0.27 ms a call of arithmetic at the cell's shapes, where the copies
+#: take 0.60: chip runs, PR 42), so a unit of the cell (2,176 and 4,096
+#: keys) is one chunk; longer lists loop
+_SCORE_ROWS = 4096
+#: VMEM a kernel may use before it has to ask for more (v5e's compiler)
+_VMEM_DEFAULT = 16 << 20
 _MASK = -1e30       # finite: exp(_MASK - m) == 0, no inf - inf
 
 
@@ -527,38 +555,296 @@ def append_rows(kv, k_new, v_new, ts):
 def block_kernel_supported(kv, n_head: int, n_kv_head: int,
                            block: int) -> bool:
     """Shapes and dtypes :func:`block_sparse_decode_attention` lowers
-    for: unquantized leaves, a head's lanes a whole number of lane tiles,
-    blocks and the query heads of a group whole sublane tiles."""
+    for: unquantized leaves of whole blocks, a head's lanes a whole
+    number of lane tiles, blocks and the query heads of a group whole
+    sublane tiles."""
     import jax.numpy as jnp
 
     width = kv["k"].shape[-1]
     rows = 16 if kv["k"].dtype == jnp.bfloat16 else 8
     return ("k_scale" not in kv and width % n_kv_head == 0
             and (width // n_kv_head) % 128 == 0 and block % rows == 0
+            and kv["k"].shape[KV_SEQ_AXIS] % block == 0
             and (n_head // n_kv_head) % rows == 0
             and kv["k"].dtype in (jnp.bfloat16, jnp.float32))
 
 
+def _score_chunks(rows: int, block: int, score_rows: int):
+    """``(chunk, chunks)``: a unit of ``rows`` keys is scored ``chunk``
+    at a time (whole blocks, ``score_rows`` at most but a block at
+    least), chunk ``c`` from row ``min(c * chunk, rows - chunk)``: the
+    last one is drawn back inside the unit, and the rows it shares with
+    the one before count once."""
+    chunk = min(rows, max(score_rows // block, 1) * block)
+    return chunk, -(-rows // chunk)
+
+
+def _block_part(q, k, v, ok, m, l, acc):
+    """One chunk of keys into a (slot, K/V head)'s online softmax: ``q``
+    ``[rep, Dh]`` against ``k``, ``v`` ``[N, Dh]`` in the storage dtype,
+    ``ok`` ``[rep, N]`` the positions that may be read; ``m``, ``l``
+    ``[rep, 1]`` and ``acc`` ``[rep, Dh]`` fp32 the max, the sum and the
+    weighted rows so far (``(_MASK, 0, 0)``: nothing yet).  Returns the
+    three with the chunk taken in."""
+    import jax
+    import jax.numpy as jnp
+
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    s = jnp.where(ok, s, _MASK)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+    return (m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True),
+            alpha * acc + jnp.dot(p.astype(v.dtype), v,
+                                  preferred_element_type=jnp.float32))
+
+
+def _block_kernel(ts_ref, plan_ref,                             # SMEM
+                  q_ref, pos_ref,                               # VMEM
+                  k_hbm, v_hbm,                                 # HBM (ANY)
+                  o_ref,                                        # output
+                  fk, fv, tk, tv, m_ref, l_ref, acc_ref, sem,
+                  *, runs, n_tiles, block, score_rows, tile_unroll):
+    """One slot after another, a slot in ``1 + G`` units: the declared
+    runs (both leaves' whole-width slabs, every head scored from them),
+    then each K/V head's own tiles.  The reads of the next
+    ``min(2, units - 1)`` units are in flight while one is scored.
+
+    The body is written ONCE a unit kind — one routine that starts a
+    kind's reads, one that scores it — and reached through ONE loop over
+    (slot, unit) with the unit a loop variable (a head is a leading
+    index of the tile buffers, a lane offset into the slabs; the turns
+    before slot 0 only start reads, so there is no prologue); a unit is
+    scored ``score_rows`` keys at a time in a loop, the online softmax's
+    state in VMEM; the positions of a head's tiles come in from the
+    wrapper (``pos_ref``).  What a process pays to TRACE a kernel grows
+    with the equations of its body, compile cache hit or not: the same
+    read with the units, heads and prologue written out and the tiles'
+    positions put together from 64 scalars a head in the body was 2,317
+    equations for these 277 and cost every process of the cell 8-9 s
+    (PR 41; ``tests/test_decode_attention.py`` holds the count)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, G, rep, D = q_ref.shape
+    T = k_hbm.shape[1]
+    dt, f32, i32 = fk.dtype, jnp.float32, jnp.int32
+    # a slot's line of the plan: the runs' first rows, the first and the
+    # last positions that count in them, then every head's tiles
+    width = 3 * len(runs) + G * n_tiles
+
+    def run_at(n, i, what):
+        return plan_ref[n * width + what * len(runs) + i]
+
+    def tile_at(n, g, j):
+        return plan_ref[n * width + 3 * len(runs) + g * n_tiles + j]
+
+    # a slot's units: the runs' slabs (if any were declared: unit 0),
+    # then a head's tiles (if any entry lies outside the runs)
+    slabs = int(bool(runs))
+    units = slabs + (G if n_tiles else 0)
+    ahead = min(2, units - 1)
+    spans = [sum(runs[:i]) for i in range(len(runs) + 1)]   # rows in fk, fv
+
+    def start_runs(n):
+        for i in range(len(runs)):
+            src = pl.ds(pl.multiple_of(run_at(n, i, 0), block), runs[i])
+            dst = pl.ds(spans[i], runs[i])
+            pltpu.make_async_copy(k_hbm.at[n, src], fk.at[dst],
+                                  sem.at[0, 0]).start()
+            pltpu.make_async_copy(v_hbm.at[n, src], fv.at[dst],
+                                  sem.at[1, 0]).start()
+
+    def start_tiles(n, g):
+        lanes = pl.ds(pl.multiple_of(g * D, D), D)  # a head's lane tiles
+
+        def some(i, carry):
+            for j in (i * turn + u for u in range(turn)):
+                b = jnp.maximum(tile_at(n, g, j), 0)
+                src = pl.ds(pl.multiple_of(b * block, block), block)
+                dst = pl.ds(pl.multiple_of(j * block, block), block)
+                pltpu.make_async_copy(k_hbm.at[n, src, lanes], tk.at[g, dst],
+                                      sem.at[0, 1 + g]).start()
+                pltpu.make_async_copy(v_hbm.at[n, src, lanes], tv.at[g, dst],
+                                      sem.at[1, 1 + g]).start()
+            return carry
+
+        # Mosaic unrolls a loop wholly or not at all: a turn's reads are
+        # written out
+        turn = max(u for u in range(1, tile_unroll + 1) if n_tiles % u == 0)
+        jax.lax.fori_loop(0, n_tiles // turn, some, 0)
+
+    def of_kind(u, do_runs, do_tiles):
+        """Unit ``u`` (traced) of a slot handed to its kind's routine."""
+        if runs:
+            pl.when(u == 0)(do_runs)
+        if n_tiles:
+            pl.when(u >= slabs)(lambda: do_tiles(u - slabs))
+
+    def wait(buf, leaf, at):
+        """ONE wait a leaf, sized as the unit's whole buffer, takes in
+        all of its reads (they share the semaphore)."""
+        pltpu.make_async_copy(buf, buf, sem.at[leaf, at]).wait()
+
+    def score(n, g, kbuf, vbuf, rows, pos_of, first):
+        """Head ``g``'s queries of slot ``n`` against the ``rows`` keys
+        of a unit (``kbuf``, ``vbuf`` ``[rows, D]``), a chunk at a time
+        (:func:`_score_chunks`), into the head's online softmax (begun
+        here if ``first``).  ``pos_of(c, r0, chunk)``: the positions
+        ``[1, chunk]`` of chunk ``c``'s rows, which start at ``r0``; ``T``
+        for a row that may not be read or that an earlier chunk counted."""
+        chunk, n_chunks = _score_chunks(rows, block, score_rows)
+        t, q = ts_ref[n], q_ref[n, g]
+        if first:
+            m_ref[g] = jnp.full((rep, _HEAD_LANES), _MASK, f32)
+            l_ref[g] = jnp.zeros((rep, _HEAD_LANES), f32)
+            acc_ref[g] = jnp.zeros((rep, D), f32)
+
+        def some(c, carry):
+            # the last chunk is drawn back inside the unit
+            r0 = 0 if n_chunks == 1 else pl.multiple_of(
+                jnp.minimum(c * chunk, rows - chunk), block)
+            ok = jnp.broadcast_to(pos_of(c, r0, chunk), (rep, chunk)) <= t
+            m, l, acc = _block_part(
+                q, kbuf[pl.ds(r0, chunk), :], vbuf[pl.ds(r0, chunk), :], ok,
+                m_ref[g, :, :1], l_ref[g, :, :1], acc_ref[g])
+            m_ref[g] = jnp.broadcast_to(m, (rep, _HEAD_LANES))
+            l_ref[g] = jnp.broadcast_to(l, (rep, _HEAD_LANES))
+            acc_ref[g] = acc
+            return carry
+
+        if n_chunks == 1:
+            some(0, 0)
+        else:
+            jax.lax.fori_loop(0, n_chunks, some, 0)
+
+    def finish(n, g):
+        o_ref[n, g] = acc_ref[g] / jnp.maximum(l_ref[g, :, :1], 1e-30)
+
+    def score_runs(n):
+        wait(fk, 0, 0)
+        wait(fv, 1, 0)
+
+        def pos_of(c, r0, chunk):
+            """Row ``r`` of run ``i`` is position ``row_i + r``: the
+            run's blocks lie one after another."""
+            row = r0 + jax.lax.broadcasted_iota(i32, (1, chunk), 1)
+            pos = jnp.full(row.shape, T, i32)
+            for i in range(len(runs)):
+                at = run_at(n, i, 0) + row - spans[i]
+                mine = ((row >= spans[i]) & (row < spans[i + 1])
+                        & (at >= run_at(n, i, 1)) & (at <= run_at(n, i, 2)))
+                pos = jnp.where(mine, at, pos)
+            if spans[-1] % chunk:   # rows the chunk before has counted
+                pos = jnp.where(row >= c * chunk, pos, T)
+            return pos
+
+        def head(g, carry):
+            lanes = pl.ds(pl.multiple_of(g * D, D), D)
+            score(n, g, fk.at[:, lanes], fv.at[:, lanes], spans[-1], pos_of,
+                  first=True)
+            if not n_tiles:
+                finish(n, g)
+            return carry
+
+        jax.lax.fori_loop(0, G, head, 0)
+
+    def score_tiles(n, g):
+        wait(tk.at[g], 0, 1 + g)
+        wait(tv.at[g], 1, 1 + g)
+
+        _, n_chunks = _score_chunks(n_tiles * block, block, score_rows)
+
+        def pos_of(c, r0, chunk):
+            # the wrapper laid them out a line a (slot, head, chunk)
+            return pos_ref[pl.ds((n * G + g) * n_chunks + c, 1), :]
+
+        score(n, g, tk.at[g], tv.at[g], n_tiles * block, pos_of,
+              first=not runs)
+        finish(n, g)
+
+    def step(_, at):
+        # ``at``: the (slot, unit) scored this turn; the turns before
+        # slot 0 only start reads
+        n, u = at
+        over = (u + ahead >= units).astype(i32)
+        n2, u2 = n + over, u + ahead - units * over
+
+        @pl.when(n2 < S)
+        def _():
+            of_kind(u2, lambda: start_runs(n2), lambda g: start_tiles(n2, g))
+
+        @pl.when(n >= 0)
+        def _():
+            of_kind(u, lambda: score_runs(n), lambda g: score_tiles(n, g))
+
+        last = (u + 1 == units).astype(i32)
+        return n + last, (u + 1) * (1 - last)
+
+    first = (jnp.int32(-1), jnp.int32(units - ahead)) if ahead else (
+        jnp.int32(0), jnp.int32(0))
+    jax.lax.fori_loop(0, S * units + ahead, step, first)
+
+
 def block_sparse_decode_attention(q, k_cache, v_cache, ts, blocks, valid, *,
                                   n_head: int, n_kv_head: int, scale: float,
-                                  block: int, interpret=False):
+                                  block: int, shared_runs=(),
+                                  interpret=False):
     """The Pallas TPU kernel of "read the blocks named for this row".
 
-    One grid step per (slot, K/V head, run of ``U`` named blocks); the
-    block ids ride in SMEM (scalar prefetch) and the BlockSpecs' index
-    maps turn them into the DMAs, so the pipeline fetches the next run's
-    ``[block, Dh]`` K and V tiles — a head's lanes of a block's rows, cut
-    from the leaves as they lie — while this one is scored.  A run's K
-    tiles are stacked and meet the group's query heads ``[rep, Dh]`` in
-    ONE product on the MXU (``U * block`` keys a step: a step per block
-    spent its time in the latency of seven small products, 4.0 ms a
-    layer and step on the chip against 1.x for this, PR 31); an online
-    softmax runs over the runs of a (slot, head), and the context is
-    written when its last run is in.  ``q`` ``[S, n_head * Dh]`` fp32;
-    ``k_cache``, ``v_cache`` ``[S, T, n_kv_head * Dh]`` (read only:
-    :func:`append_rows` has written the step's rows); ``ts`` ``[S]``;
-    ``blocks``, ``valid`` ``[S, n_kv_head, B]``.  Returns ctx ``[S,
-    n_kv_head, rep, Dh]`` fp32: zeros where nothing may be read."""
+    ``q`` ``[S, n_head * Dh]`` fp32; ``k_cache``, ``v_cache`` ``[S, T,
+    n_kv_head * Dh]`` (read only: :func:`append_rows` has written the
+    step's rows); ``ts`` ``[S]``; ``blocks``, ``valid`` ``[S, n_kv_head,
+    B]``.  Returns ctx ``[S, n_kv_head, rep, Dh]`` fp32: zeros where
+    nothing may be read.
+
+    ``shared_runs``: ``((first entry, entries), ...)`` — what the CALLER
+    declares of its lists (static: a DMA's length is): those entries are
+    the same in every head's list and, where valid, name consecutive
+    blocks in rising order, the valid ones next to each other.  Such a
+    run lies in a leaf as ONE stretch of rows, so it is read as it lies:
+    whole-width ``[entries * block, n_kv_head * Dh]`` slabs, K and V one
+    copy each a slot, from which every head's lanes are cut in VMEM
+    (a 128-lane slice is a tile).  The stretch starts at the first valid
+    block's first row, or as far before it as keeps it inside the leaf;
+    its rows count by position (no earlier than that block, no later
+    than the last valid one's end, ``<= ts``), so ids clamped to the
+    rung's end and entries masked off (a window that meets the first
+    block) are not read twice.  Every other entry is a head's own
+    ``[block, Dh]`` tile, fetched by its own DMA into the head's buffer.
+
+    The kernel walks the slots in ``1 + n_kv_head`` units each — the
+    runs' slabs, then each head's tiles — with the next two units' reads
+    in flight while one is scored (the ragged kernel's ``_READS_AHEAD``,
+    by hand here too).  A unit is scored :data:`_SCORE_ROWS` keys at a
+    time: a chunk's keys against the group's ``[rep, Dh]`` query heads
+    on the MXU, its softmax, one more product for the weighted rows, all
+    taken into the head's online softmax, which a head's units share.
+    One jitted entry point for every call site (:func:`_kernel_call`
+    says why), and a body that a process traces and lowers in about the
+    time of the BlockSpec kernel it replaced (:func:`_block_kernel`)."""
+    return _block_call()(
+        q, k_cache, v_cache, ts, blocks, valid, n_head=n_head,
+        n_kv_head=n_kv_head, scale=float(scale), block=block,
+        shared_runs=tuple((int(a), int(b)) for a, b in shared_runs),
+        score_rows=_SCORE_ROWS, tile_unroll=_TILE_UNROLL, interpret=interpret)
+
+
+@functools.lru_cache(maxsize=None)
+def _block_call():
+    import jax
+
+    return jax.jit(_block_sparse, static_argnames=(
+        "n_head", "n_kv_head", "scale", "block", "shared_runs", "score_rows",
+        "tile_unroll", "interpret"))
+
+
+def _block_sparse(q, k_cache, v_cache, ts, blocks, valid, *, n_head,
+                  n_kv_head, scale, block, shared_runs, score_rows,
+                  tile_unroll, interpret):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -567,73 +853,82 @@ def block_sparse_decode_attention(q, k_cache, v_cache, ts, blocks, valid, *,
     S, T, Dkv = k_cache.shape
     G, D, rep = n_kv_head, Dkv // n_kv_head, n_head // n_kv_head
     B = blocks.shape[-1]
-    U = max(u for u in range(1, 17) if B % u == 0)
-    dt, f32 = k_cache.dtype, jnp.float32
-    qg = (q * scale).astype(dt).reshape(S, G, rep, D)
-    named = jnp.where(valid, blocks, -1).astype(jnp.int32).reshape(-1)
-
-    def kernel(blk_ref, ts_ref, q_ref, *refs):
-        k_refs, v_refs = refs[:U], refs[U:2 * U]
-        o_ref, m_ref, l_ref, acc_ref = refs[2 * U:]
-        n, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-        @pl.when(j == 0)
-        def _():
-            m_ref[...] = jnp.full_like(m_ref, _MASK)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        kk = jnp.concatenate([r[...] for r in k_refs], axis=0)
-        vv = jnp.concatenate([r[...] for r in v_refs], axis=0)
-        s = jax.lax.dot_general(
-            q_ref[...], kk, (((1,), (1,)), ((), ())),
-            preferred_element_type=f32)                 # [rep, U * block]
-        lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        pos = jnp.full(s.shape, T, jnp.int32)           # T: may not be read
-        for u in range(U):
-            b = blk_ref[(n * G + g) * B + j * U + u]
-            mine = (lane >= u * block) & (lane < (u + 1) * block) & (b >= 0)
-            pos = jnp.where(mine, b * block + lane - u * block, pos)
-        ok = pos <= ts_ref[n]
-        s = jnp.where(ok, s, _MASK)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-        l_ref[...] = jnp.broadcast_to(
-            alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True),
-            l_ref.shape)
-        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
-            p.astype(dt), vv, preferred_element_type=f32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-
-        @pl.when(j == pl.num_programs(2) - 1)
-        def _():
-            o_ref[...] = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
-
-    def tile(u):
-        return pl.BlockSpec(
-            (None, block, D), lambda n, g, j, blk_ref, ts_ref: (
-                n, jnp.maximum(blk_ref[(n * G + g) * B + j * U + u], 0), g))
-
-    heads = pl.BlockSpec((None, None, rep, D),
-                         lambda n, g, j, blk_ref, ts_ref: (n, g, 0, 0))
+    dt, f32, i32 = k_cache.dtype, jnp.float32, jnp.int32
+    # a run longer than the leaf is no stretch of it: its entries are tiles
+    shared_runs = tuple((a, n) for a, n in shared_runs
+                        if 0 < n * block <= T)
+    named = jnp.clip(jnp.where(valid, blocks, -1).astype(i32), -1,
+                     T // block - 1)
+    # of[i, e]: entry e of a list belongs to run i; lone[e]: to none (a
+    # head's own tile)
+    of = np.zeros((len(shared_runs), B), bool)
+    for i, (a, n) in enumerate(shared_runs):
+        of[i, a:a + n] = True
+    lone = ~of.any(axis=0)
+    plan = []       # a slot's line: _block_kernel reads it by run_at, tile_at
+    if shared_runs:
+        ids = jnp.where(of[None], named[:, :1], -1)         # [S, runs, B]
+        first = jnp.min(jnp.where(ids >= 0, ids, T), axis=2) * block
+        room = T - block * np.asarray([n for _, n in shared_runs])
+        plan = [jnp.clip(first, 0, room[None]), first,
+                jnp.max(ids, axis=2) * block + block - 1]
+    # the entries outside the runs, in the list's order: a head's own tiles
+    n_tiles = int(lone.sum())
+    tiles = named[:, :, np.flatnonzero(lone)]                # [S, G, tiles]
+    plan = jnp.concatenate(plan + [tiles.reshape(S, -1)], axis=1)
+    # the position of every row of a head's tiles as they will lie in its
+    # buffer (T, which no slot reaches, under an entry that names
+    # nothing), a line a chunk the kernel scores (_score_chunks: a row the
+    # chunk before has counted reads T in the last, drawn-back one)
+    tile_pos = jnp.zeros((8, _HEAD_LANES), i32)     # no tiles: not read
+    if n_tiles:
+        flat = (jnp.where(tiles >= 0, tiles * block, T)[..., None]
+                + jnp.arange(block, dtype=i32)).reshape(S * G, -1)
+        chunk, n_chunks = _score_chunks(n_tiles * block, block, score_rows)
+        tile_pos = jnp.stack([
+            jnp.where(np.arange(a, a + chunk) >= c * chunk,
+                      flat[:, a:a + chunk], T)
+            for c, a in enumerate(min(c * chunk, n_tiles * block - chunk)
+                                  for c in range(n_chunks))],
+            axis=1).reshape(-1, chunk)
+    runs = tuple(n * block for _, n in shared_runs)     # rows of a slab
+    run_rows = sum(runs)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    size = jnp.dtype(dt).itemsize
+    # bytes the kernel keeps in VMEM: q, the tiles' positions and the
+    # context whole, every unit's buffers, a chunk's scores a few times
+    resident = (S * G * rep * D * (size + 4) + 4 * tile_pos.size
+                + 2 * size * (run_rows * Dkv + G * n_tiles * block * D)
+                + 4 * 4 * rep * min(score_rows, max(run_rows,
+                                                    n_tiles * block)))
     return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(S, G, B // U),
-            in_specs=[heads] + [tile(u) for u in range(U)] * 2,
-            out_specs=heads,
-            scratch_shapes=[pltpu.VMEM((rep, _HEAD_LANES), f32),
-                            pltpu.VMEM((rep, _HEAD_LANES), f32),
-                            pltpu.VMEM((rep, D), f32)]),
+        functools.partial(_block_kernel, runs=runs, n_tiles=n_tiles,
+                          block=block, score_rows=score_rows,
+                          tile_unroll=tile_unroll),
         out_shape=jax.ShapeDtypeStruct((S, G, rep, D), f32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        in_specs=[smem] * 2 + [vmem] * 2 + [hbm] * 2,
+        out_specs=vmem,
+        scratch_shapes=[
+            pltpu.VMEM((max(run_rows, 8), Dkv), dt),
+            pltpu.VMEM((max(run_rows, 8), Dkv), dt),
+            pltpu.VMEM((G, max(n_tiles * block, 8), D), dt),
+            pltpu.VMEM((G, max(n_tiles * block, 8), D), dt),
+            pltpu.VMEM((G, rep, _HEAD_LANES), f32),     # a head's max,
+            pltpu.VMEM((G, rep, _HEAD_LANES), f32),     # sum
+            pltpu.VMEM((G, rep, D), f32),               # and weighted rows
+            pltpu.SemaphoreType.DMA((2, 1 + G)),
+        ],
+        # asked for only by lists longer than the compiler's default holds
+        compiler_params=(pltpu.CompilerParams(
+            vmem_limit_bytes=min(100 << 20, 2 * resident))
+            if resident > _VMEM_DEFAULT * 3 // 4 else None),
         name="block_sparse_decode_attention",
         interpret=interpret,
-    )(named, ts.astype(jnp.int32), qg,
-      *([k_cache] * U + [v_cache] * U))
+    )(ts.astype(i32), plan.reshape(-1),
+      (q * scale).astype(dt).reshape(S, G, rep, D),
+      tile_pos, k_cache, v_cache)
 
 
 def _gathered_block_attention(q, kv, ts, blocks, valid, *, n_head, n_kv_head,
@@ -673,7 +968,7 @@ def _gathered_block_attention(q, kv, ts, blocks, valid, *, n_head, n_kv_head,
 def grouped_block_decode_attention(q, kv, ts, blocks, valid, dense, *,
                                    n_head: int, n_kv_head: int,
                                    scale: float, block: int,
-                                   dense_len: int):
+                                   dense_len: int, shared_runs=()):
     """Read the blocks named for each row.
 
     ``q`` ``[S, n_head * Dh]`` fp32, one query row per slot at position
@@ -688,6 +983,16 @@ def grouped_block_decode_attention(q, kv, ts, blocks, valid, dense, *,
     fp32 accumulation and softmax.  Returns ctx ``[S, n_head * Dh]``
     fp32, zero for an idle slot.  No branch reads the whole rung: ``B *
     block`` positions a slot and head, however long ``T`` is.
+
+    ``shared_runs`` (static) is what the caller KNOWS of its lists and
+    may declare: ``((first entry, entries), ...)``, each a stretch of
+    entries that every head's list holds alike and that, where valid,
+    name consecutive blocks in rising order with no invalid entry
+    between two valid ones (a rule's forced blocks: the first ones, the
+    window).  It changes nothing that is read — the lists say that — only
+    how the kernel fetches it
+    (:func:`block_sparse_decode_attention`); the XLA form takes the
+    lists as they are.
 
     Two implementations of the named read, chosen here from what can be
     observed, as :func:`make_decode_attention` chooses: the Pallas kernel
@@ -707,9 +1012,12 @@ def grouped_block_decode_attention(q, kv, ts, blocks, valid, dense, *,
                  block=block)
     if (jax.default_backend() == "tpu"
             and block_kernel_supported(kv, n_head, n_kv_head, block)):
+        BLOCK_SPARSE_LOWERED.labels(path="kernel").inc()
         ctx = block_sparse_decode_attention(
-            q, kv["k"], kv["v"], ts, blocks, valid, **named)
+            q, kv["k"], kv["v"], ts, blocks, valid,
+            shared_runs=shared_runs, **named)
     else:
+        BLOCK_SPARSE_LOWERED.labels(path="xla").inc()
         ctx = _gathered_block_attention(q, kv, ts, blocks, valid, **named)
     qg = (q * scale).astype(dt).reshape(S, G, rep, D)
 

@@ -791,7 +791,8 @@ def make_sparse_linear_lm_pooled_step_fn(state, cfg, name: str = "lm",
                     o = grouped_block_decode_attention(
                         q.reshape(n, -1), kvs, ts, blocks, valid, dense,
                         n_head=d.n_head, n_kv_head=G, scale=scale,
-                        block=d.block_size, dense_len=d.dense_len)
+                        block=d.block_size, dense_len=d.dense_len,
+                        shared_runs=sl.forced_runs(d))
                 new_cache.append({**kvs, "ck": ck})
             h = close_layer(h, u, o, p)
         logits = sl.linear(

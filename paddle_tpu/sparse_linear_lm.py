@@ -47,8 +47,9 @@ from paddle_tpu.hybrid_ssm import (linear, rms_norm, rotary, starts_fresh,
 __all__ = ["SPARSE", "LIGHTNING", "dims", "param_shapes", "random_state",
            "mixer_inputs", "lightning_step", "lightning_chunk",
            "compress_keys", "update_compressed", "select_blocks",
-           "selected_positions", "chunk_attend", "linear", "rms_norm",
-           "rotary", "swiglu", "starts_fresh", "LINEAR_STATE_SCOPE",
+           "forced_runs", "selected_positions", "chunk_attend", "linear",
+           "rms_norm", "rotary", "swiglu", "starts_fresh",
+           "LINEAR_STATE_SCOPE",
            "SPARSE_SELECT_SCOPE", "SPARSE_ATTEND_SCOPE",
            "PREFILL_CHUNK_SCOPE"]
 
@@ -358,6 +359,17 @@ def select_blocks(q, ck, ts, d):
                               (m_q, g, fixed.shape[1])), vals >= 0.0],
             axis=-1)
         return jnp.minimum(blocks, n_b - 1), valid, n <= d.dense_len
+
+
+def forced_runs(d):
+    """The stretches of :func:`select_blocks`' list that every K/V head
+    holds alike and that name consecutive blocks where valid — the first
+    ``init_blocks`` entries, then the window's — as ``((first entry,
+    entries), ...)``: what the step declares to
+    ``decode_attention.grouped_block_decode_attention``
+    (``shared_runs``), which then reads each as one stretch of rows."""
+    return ((0, d.init_blocks),
+            (d.init_blocks, d.window_size // d.block_size + 1))
 
 
 def chunk_attend(q, k_leaf, v_leaf, row, ts, blocks, valid, dense, n_live,

@@ -408,6 +408,155 @@ def test_block_kernel_matches_the_gathered_read(dtype):
     assert not np.asarray(got)[~live].any()      # an idle slot reads nothing
 
 
+# the rule's own lists (``sparse_linear_lm.select_blocks``: the first
+# block, the window's five, the top 4 of the rest) at positions that put
+# the declared runs to the test, four slots a case; ``topk`` rewrites the
+# last entries of both heads' lists where the case is about them
+_RULE = dict(block_size=64, kernel_size=32, kernel_stride=16, init_blocks=1,
+             window_size=256, topk=4, dense_len=0)
+_RULE_CASES = {
+    # the window's last block holds ts: rows past it are not read
+    "run_ends_in_a_partly_live_block": ([700, 333, 950, 527], None),
+    # under a window's length the window meets the first block, which the
+    # list masks off in the window's entries: its rows count once
+    "run_meets_the_first_block": ([200, 100, 63, 255], None),
+    # 0, 1 or 3 blocks between the first and the window: entries of the
+    # top-k that name nothing
+    "fewer_blocks_than_topk": ([400, 340, 520, 450], None),
+    "an_idle_slot_between_live_ones": ([900, -1, 600, -1], None),
+    "both_heads_name_the_same_blocks": (
+        [900, 1000, 800, 960], [[1, 3, 5, 7], [1, 3, 5, 7]]),
+    "the_heads_name_disjoint_blocks": (
+        [900, 1000, 800, 960], [[1, 3, 5, 7], [2, 4, 6, 0]]),
+    "ts_on_a_blocks_first_and_last_row": ([640, 703, 704, 767], None),
+    # the window's ids are clamped to the rung's last block: the run read
+    # from its first id would leave the leaf
+    "window_at_the_rungs_end": ([1023, 1000, 961, 960], None),
+}
+# keys scored at a time (``_SCORE_ROWS``) against a case's 384 rows of
+# slabs and 256 of a head's tiles: one chunk each (the module's 512),
+# the slabs in one and a half (256: the last chunk drawn back, its shared
+# rows counted once), the tiles in one and a third (192), both in whole
+# chunks (128)
+_SCORE_ROWS = {"run_ends_in_a_partly_live_block": (256, 192, 128),
+               "run_meets_the_first_block": (256,),
+               "fewer_blocks_than_topk": (192,),
+               "window_at_the_rungs_end": (256, 192)}
+_RULE_RUNS = [(case, None) for case in sorted(_RULE_CASES)] + [
+    (case, rows) for case in sorted(_SCORE_ROWS) for rows in _SCORE_ROWS[case]]
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("case,score_rows", _RULE_RUNS)
+def test_block_kernel_reads_the_declared_runs_as_the_lists_say(
+        case, score_rows, dtype, monkeypatch):
+    """The kernel told which stretches of the lists are shared runs of
+    consecutive blocks (``shared_runs``) against the XLA form, which
+    takes the lists as they are; a unit scored in one chunk and in
+    several, whole and not."""
+    import types
+
+    import jax.numpy as jnp
+
+    from paddle_tpu import sparse_linear_lm as sl
+
+    d = types.SimpleNamespace(**_RULE)
+    ts, topk = _RULE_CASES[case]
+    ts = np.asarray(ts, np.int32)
+    rng = np.random.RandomState(sorted(_RULE_CASES).index(case))
+    s, t, g, dh, rep = len(ts), 1024, 2, 128, 16
+    kv = {n: jnp.asarray(rng.randn(s, t, g * dh), dtype) for n in "kv"}
+    q = jnp.asarray(rng.randn(s, g * rep * dh), jnp.float32)
+    ck = jnp.asarray(rng.randn(s, t // d.kernel_stride, g * dh), dtype)
+    blocks, valid, _ = sl.select_blocks(
+        q.reshape(s, g, rep, dh), ck, jnp.maximum(ts, 0), d)
+    if topk is not None:
+        blocks = blocks.at[..., -d.topk:].set(jnp.asarray(topk)[None])
+    runs = sl.forced_runs(d)
+    assert runs == ((0, 1), (1, 5)) and blocks.shape[-1] == 10
+    named = dict(n_head=g * rep, n_kv_head=g, scale=0.1, block=d.block_size)
+    want = da._gathered_block_attention(q, kv, jnp.asarray(ts), blocks,
+                                        valid, **named)
+    if score_rows is not None:
+        monkeypatch.setattr(da, "_SCORE_ROWS", score_rows)
+    got = da.block_sparse_decode_attention(
+        q, kv["k"], kv["v"], jnp.asarray(ts), blocks, valid,
+        shared_runs=runs, interpret=True, **named)
+    live = ts >= 0
+    tol = 1e-5 if dtype == "float32" else 2e-2   # bf16 probabilities
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=tol)
+    assert not np.asarray(got)[~live].any()
+    if case == "fewer_blocks_than_topk":
+        assert (~np.asarray(valid)[..., -d.topk:]).any()
+    if case == "window_at_the_rungs_end":    # clamped: not consecutive
+        assert np.asarray(blocks)[0, 0, 1:6].tolist() == [12, 13, 14, 15, 15]
+
+
+@pytest.mark.parametrize("runs", [((0, 1), (1, 3)), ((1, 3),), ()])
+def test_block_kernel_takes_lists_of_runs_only_and_of_tiles_only(runs):
+    """A slot's units are the declared runs' slabs (if any) and a head's
+    tiles (if any entry lies outside the runs): lists that are all runs,
+    one run between tiles, and no run at all read alike."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(5)
+    s, t, g, dh, rep, block = 3, 512, 2, 128, 16, 64
+    kv = {n: jnp.asarray(rng.randn(s, t, g * dh), jnp.float32) for n in "kv"}
+    q = jnp.asarray(rng.randn(s, g * rep * dh), jnp.float32)
+    ts = jnp.asarray([400, 130, 255])
+    # the first block and a window of three, both heads alike
+    blocks = jnp.asarray([[[0, 4, 5, 6]] * 2, [[0, 0, 1, 2]] * 2,
+                          [[0, 1, 2, 3]] * 2], jnp.int32)
+    valid = jnp.asarray([[[1, 1, 1, 1]] * 2, [[1, 0, 1, 1]] * 2,
+                         [[1, 1, 1, 1]] * 2], bool)
+    named = dict(n_head=g * rep, n_kv_head=g, scale=0.1, block=block)
+    want = da._gathered_block_attention(q, kv, ts, blocks, valid, **named)
+    got = da.block_sparse_decode_attention(
+        q, kv["k"], kv["v"], ts, blocks, valid, shared_runs=runs,
+        interpret=True, **named)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+def test_grouped_block_read_counts_its_lowering(path, monkeypatch):
+    """``block_sparse_lowered_total{path}`` counts one per call lowered;
+    on a TPU (here: said to be one, the kernel in interpret mode) the
+    declared runs reach the kernel and the contexts are the XLA form's."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(3)
+    s, t, g, dh, rep, block = 2, 512, 2, 128, 16, 64
+    kv = {n: jnp.asarray(rng.randn(s, t, g * dh), jnp.float32) for n in "kv"}
+    q = jnp.asarray(rng.randn(s, g * rep * dh), jnp.float32)
+    ts = jnp.asarray([400, 130])
+    # the first block, a window of three, one more block a head
+    blocks = jnp.asarray([[[0, 4, 5, 6, 2], [0, 4, 5, 6, 3]],
+                          [[0, 0, 1, 2, 0], [0, 0, 1, 2, 0]]], jnp.int32)
+    valid = jnp.asarray([[[1, 1, 1, 1, 1]] * 2, [[1, 0, 1, 1, 0]] * 2], bool)
+    call = functools.partial(
+        da.grouped_block_decode_attention, q, kv, ts, blocks, valid,
+        jnp.zeros((s,), bool), n_head=g * rep, n_kv_head=g, scale=0.1,
+        block=block, dense_len=0, shared_runs=((0, 1), (1, 3)))
+    want = call()
+    seen = []
+    if path == "kernel":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        kernel = da.block_sparse_decode_attention
+        monkeypatch.setattr(
+            da, "block_sparse_decode_attention",
+            lambda *a, **kw: seen.append(kw["shared_runs"]) or kernel(
+                *a, interpret=True, **kw))
+    before = da.BLOCK_SPARSE_LOWERED.labels(path=path).value
+    got = call()
+    assert da.BLOCK_SPARSE_LOWERED.labels(path=path).value == before + 1
+    assert seen == ([((0, 1), (1, 3))] if path == "kernel" else [])
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
 def test_block_kernel_is_refused_for_shapes_it_cannot_tile():
     import jax.numpy as jnp
 
@@ -538,8 +687,10 @@ def test_kernel_compiles_for_v5e_at_gpt1_widths(one_chip):
 
 def test_block_kernel_compiles_for_v5e_at_minicpm_sala_widths(one_chip):
     """64 slots x 32768 positions x 2 K/V heads of 128, 16 query heads a
-    K/V head, 98 named blocks of 64: the kernel lowers and reads the
-    leaves where they lie (no temporary of a leaf's size)."""
+    K/V head, 98 named blocks of 64 of which the first and the window's
+    33 are declared runs: the kernel lowers, reads the leaves where they
+    lie (no temporary of a leaf's size), and its buffers stay inside the
+    VMEM a kernel gets without asking (it asks for none)."""
     import jax
     import jax.numpy as jnp
 
@@ -548,18 +699,86 @@ def test_block_kernel_compiles_for_v5e_at_minicpm_sala_widths(one_chip):
     def f(q, kc, vc, ts, blocks, valid):
         return da.block_sparse_decode_attention(
             q, kc, vc, ts, blocks, valid, n_head=g * rep, n_kv_head=g,
-            scale=0.088, block=block)
+            scale=0.088, block=block, shared_runs=((0, 1), (1, 33)))
 
     def sd(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = jax.jit(f).lower(
+    lowered = jax.jit(f).lower(
         sd((s, g * rep * d)), sd((s, t, g * d), jnp.bfloat16),
         sd((s, t, g * d), jnp.bfloat16), sd((s,), jnp.int32),
-        sd((s, g, b), jnp.int32), sd((s, g, b), jnp.bool_)).compile()
+        sd((s, g, b), jnp.int32), sd((s, g, b), jnp.bool_))
+    # the kernel asked for no more VMEM than the compiler's default, and
+    # the chip's compiler accepts it so
+    assert "scoped_memory_configs" not in lowered.as_text()
+    compiled = lowered.compile()
     assert "block_sparse_decode_attention" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < (
         s * t * g * d * 2) // 8
+
+
+def _two_sparse_layers(sharding=None):
+    """``(f, abstract arguments, equations)``: the block kernel called on
+    two layers' leaves at ``minicpm_sala``'s shapes (64 slots x 32768 x
+    256 bf16, 98 blocks of 64, the rule's runs declared), as a step with
+    two sparse layers calls it, and the count of a jaxpr's equations:
+    the program ``tools/time_block_sparse.py --build`` times."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "time_block_sparse", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "tools", "time_block_sparse.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    f, args = tool.two_layer_program(
+        da, (64, 32768, 2, 128, 16, 64, 98), "bfloat16", ((0, 1), (1, 33)),
+        interpret=False, sharding=sharding)
+    return f, args, tool.equations
+
+
+#: equations of the block kernel's body at the cell's shapes: 277 as
+#: written (PR 42), half as many again allowed.  PR 41's body read 2,317
+#: on this count and cost every process of the cell 8-9 s of set-up,
+#: compile cache hit or not: what a kernel's trace and lowering cost
+#: grows with its body, and no compile time shows it.
+_BLOCK_KERNEL_EQUATIONS_MAX = 415
+
+
+def test_block_kernel_is_traced_once_and_its_body_stays_small():
+    """What building the kernel costs a process, as a count (a clock
+    would not be steady under six workers): a step's two sparse layers
+    share ONE traced function, and its body holds a unit's start and its
+    scoring once a unit kind — not once a unit, head and prologue, nor a
+    unit's whole key axis written out."""
+    import jax
+
+    f, args, equations = _two_sparse_layers()
+    calls = [e for e in jax.make_jaxpr(f)(*args).jaxpr.eqns
+             if "jaxpr" in e.params]
+    assert len(calls) == 2      # one a layer ...
+    # ... of one function traced once
+    assert calls[0].params["jaxpr"] is calls[1].params["jaxpr"]
+    kernels = [e for e in calls[0].params["jaxpr"].jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 1
+    body = equations(kernels[0].params["jaxpr"])
+    assert 100 < body <= _BLOCK_KERNEL_EQUATIONS_MAX, body
+
+
+def test_block_kernel_is_lowered_once_for_two_layers_on_v5e(one_chip):
+    """Lowered for the chip, two sparse layers are two calls of ONE
+    function that holds the ONE kernel of the module."""
+    import re
+
+    import jax
+
+    f, args, _ = _two_sparse_layers(one_chip)
+    text = jax.jit(f).trace(*args).lower().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(r"func\.func private @_block_sparse\b", text)) == 1
+    assert len(re.findall(r"call @_block_sparse\b", text)) == 2
 
 
 def test_hybrid_ssm_layer_compiles_for_v5e_at_falcon_h1_widths(one_chip):

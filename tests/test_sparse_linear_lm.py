@@ -177,6 +177,33 @@ def test_select_blocks_against_a_literal_loop(t):
         for b in want[0])
 
 
+@pytest.mark.parametrize("t", [9, 25, 46, 90, 127])
+def test_forced_runs_are_what_select_blocks_lists_them_as(t):
+    """What the step declares to the block read (``forced_runs``) holds
+    of the lists it hands over: in each run every head's entries are
+    alike, and the valid ones lie next to each other and name
+    consecutive blocks in rising order — at the rung's end too (127),
+    where the ids are clamped, and where the window meets the first
+    block (9, 25)."""
+    d = sl.dims(CFG)
+    rng = np.random.RandomState(200 + t)
+    q = jnp.asarray(rng.randn(1, 2, 2, 8), jnp.float32)
+    ck = jnp.asarray(rng.randn(1, T // 2, 16), jnp.float32)
+    blocks, valid, _ = sl.select_blocks(q, ck, jnp.asarray([t]), d)
+    blocks, valid = np.asarray(blocks)[0], np.asarray(valid)[0]
+    runs = sl.forced_runs(d)
+    assert runs == ((0, 1), (1, 3))
+    assert sum(n for _, n in runs) + d.topk == blocks.shape[-1]
+    for a, n in runs:
+        np.testing.assert_array_equal(blocks[0, a:a + n], blocks[1, a:a + n])
+        np.testing.assert_array_equal(valid[0, a:a + n], valid[1, a:a + n])
+        at = np.flatnonzero(valid[0, a:a + n])
+        if len(at):
+            assert at.tolist() == list(range(at[0], at[-1] + 1))
+            ids = blocks[0, a:a + n][at]
+            assert ids.tolist() == list(range(ids[0], ids[0] + len(at)))
+
+
 def test_compressed_keys_are_written_as_kernels_complete(built):
     """The step's ``ck`` leaf against compress_keys over the K rows it
     cached: row j exists once position stride * j + kernel - 1 is in."""

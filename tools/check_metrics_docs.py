@@ -36,6 +36,7 @@ REGISTERING_MODULES = [
     "paddle_tpu.executor",
     "paddle_tpu.fused_attention",
     "paddle_tpu.grouped_matmul",
+    "paddle_tpu.decode_attention",
     "paddle_tpu.decoding",
     "paddle_tpu.reader",
     "paddle_tpu.inference",

@@ -19,7 +19,10 @@ take as many array arguments as the cell's):
 * ``weight_copies``: the matrices the builder copied to the dtype of
   their products at build (``decode_weight_copies_total``; since PR 35
   ``gpt1_117m`` 73 on the chip, where a ``chunk`` used to cast them
-  all again), and ``tokens_sha1``: a digest of every slot's tokens
+  all again), ``block_sparse_lowered``: the reads of named blocks the
+  builder lowered, by path (``block_sparse_lowered_total``:
+  ``minicpm_sala`` 2 ``kernel`` on the chip, one a sparse layer of its
+  ``chunk``; ``xla`` here), and ``tokens_sha1``: a digest of every slot's tokens
   after the timed calls, which are the same calls over the same prompts
   whatever the checkout — equal digests at two checkouts say the chip
   served the same tokens;
@@ -306,6 +309,10 @@ def main():
            "constants_placed": getattr(pool, "constants_placed", None),
            "weight_copies": monitor.counter_value(
                "decode_weight_copies_total"),
+           "block_sparse_lowered": {
+               path: monitor.counter_value("block_sparse_lowered_total",
+                                           path=path)
+               for path in ("kernel", "xla")},
            "tokens_sha1": hashlib.sha1(np.ascontiguousarray(
                jax.device_get(state["tokens"])).tobytes()).hexdigest(),
            "host_events_inside_ms_each": inside,

@@ -39,6 +39,7 @@ import sys
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)    # decode_attention registers a counter
 
 
 def load_module(repo, tail, ahead):
