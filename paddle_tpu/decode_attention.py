@@ -9,7 +9,23 @@ to a 128-lane tile in HBM and double the pool).  int8 leaves carry one
 fp32 absmax scale per (slot, position, head) as sibling leaves
 ``k_scale``, ``v_scale`` ``[slots, T, n_kv_head]`` (``paddle_tpu.quant``).
 :func:`kv_leaves` allocates a layer; nothing else in the tree spells the
-layout out.
+layout out.  With the pool's recurrent leaves (no sequence axis: a
+builder's own) the leaf kinds are three: SEQUENCE (above: position ``p``
+in row ``p``, ``T`` the length rung), RING, and recurrent.
+
+**The ring leaf.**  A layer whose queries read only the last ``W``
+positions (a sliding window that counts the query's own) keeps
+``kv_leaves(..., window=W)``: the same leaves ``min(T, W)`` rows long,
+position ``p`` in row ``p mod W``.  A one-token step appends there and
+reads the rows ``< min(ts + 1, W)``: once a slot is past the window
+every row of its ring is live and none of them is older than the window
+(K was rotated at its own position and softmax does not care about
+order, so the masked forms serve unchanged but for where they write).
+``K`` fresh rows read the OLD ring and themselves before they overwrite
+it (:func:`ring_positions` says which position each old row holds).  A
+wrapped row cannot be sliced by positions or rolled back, so the pool
+carries a ring leaf whole (``make_cache.leaf_seq_windows`` declares it:
+``KVSlotPool``) and refuses what would slice it.
 
 **The contract.**  A step hands each slot ``n`` at position ``ts[n]``
 ``K >= 1`` fresh query / key / value rows (one decode step: ``K = 1``;
@@ -81,7 +97,8 @@ __all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
            "lane_masked_decode_attention", "append_rows",
            "grouped_block_decode_attention", "block_sparse_decode_attention",
            "block_kernel_supported", "BLOCK_SPARSE_LOWERED",
-           "kernel_supported", "make_decode_attention"]
+           "kernel_supported", "make_decode_attention", "ring_positions",
+           "RING_LOWERED"]
 
 BLOCK_SPARSE_LOWERED = _registry.REGISTRY.counter(
     "block_sparse_lowered_total",
@@ -90,6 +107,13 @@ BLOCK_SPARSE_LOWERED = _registry.REGISTRY.counter(
     "declared runs as whole-width slabs, a head's other blocks as tiles, "
     "the reads in flight by hand) | xla (a block gather and a masked "
     "softmax)", ("path",))
+
+RING_LOWERED = _registry.REGISTRY.counter(
+    "decode_attention_ring_lowered_total",
+    "appends-and-reads over a RING leaf lowered (traced into a program "
+    "or run eagerly), by the form: step (one fresh row a slot, written "
+    "at its position modulo the window) | rows (K fresh rows that read "
+    "the old ring and themselves before they overwrite it)", ("form",))
 
 #: the sequence axis of every K/V leaf (and scale sibling)
 KV_SEQ_AXIS = 1
@@ -407,14 +431,17 @@ def _call(work, ts, q, k_new, v_new, e, et, k_cache, v_cache, *, block,
 
 
 def kv_leaves(n_rows: int, seq_len: int, n_kv_head: int, d_head: int,
-              dtype):
+              dtype, window=None):
     """One layer's zeroed K/V leaves in the pool's format (the module
     docstring): ``k``, ``v`` ``[n_rows, seq_len, n_kv_head * d_head]`` in
     ``dtype``; for int8 also ``k_scale``, ``v_scale`` ``[n_rows, seq_len,
     n_kv_head]`` fp32.  Every leaf's sequence axis is
-    :data:`KV_SEQ_AXIS`."""
+    :data:`KV_SEQ_AXIS`.  ``window``: a RING leaf, ``min(seq_len,
+    window)`` rows long, position ``p`` in row ``p mod window``."""
     import jax.numpy as jnp
 
+    if window is not None:
+        seq_len = min(int(seq_len), int(window))
     rows = (n_rows, seq_len, n_kv_head * d_head)
     leaves = {"k": jnp.zeros(rows, dtype), "v": jnp.zeros(rows, dtype)}
     if jnp.dtype(dtype) == jnp.int8:
@@ -452,9 +479,63 @@ def _read(kv, name, heads):
     return dequantize_rows(leaf, kv[name + "_scale"])
 
 
+def ring_positions(ts, rows: int):
+    """The position each row of a ring leaf of ``rows`` rows holds
+    BEFORE a step at ``ts`` (``[...]`` int32) writes: ``[..., rows]``,
+    the largest ``p < ts`` with ``p mod rows == r``; negative where the
+    row holds nothing of this sequence yet."""
+    import jax.numpy as jnp
+
+    last = ts[..., None] - 1
+    return last - (last - jnp.arange(rows, dtype=ts.dtype)) % rows
+
+
+def _ring_rows_attention(q, k_new, v_new, kv, ts, *, n_head, n_kv_head,
+                         scale, window):
+    """``K`` fresh rows a slot over RING leaves: row ``j`` (position
+    ``ts + j``) reads the old ring's rows that hold a position inside
+    its window and the fresh rows ``<= j`` inside it, scored as one run
+    of ``rows + K`` keys; then the fresh rows are written at their
+    positions modulo the ring (of more than ``rows`` fresh rows the last
+    ``rows`` stay).  Unquantized leaves only."""
+    import jax
+    import jax.numpy as jnp
+
+    if "k_scale" in kv:
+        raise ValueError("K fresh rows over int8 ring leaves are not "
+                         "supported")
+    S, L, Dkv = kv["k"].shape
+    K = q.shape[1]
+    heads = (n_kv_head, Dkv // n_kv_head)
+    rep, dt = n_head // n_kv_head, kv["k"].dtype
+    live = ts >= 0
+    pos = ts[:, None] + jnp.arange(K)[None, :]                  # [S, K]
+    held = ring_positions(jnp.maximum(ts, 0), L)                # [S, L]
+    old_ok = ((held >= 0)[:, None, :]
+              & (pos[:, :, None] - held[:, None, :] < window))
+    new_ok = ((pos[:, None, :] <= pos[:, :, None])
+              & (pos[:, :, None] - pos[:, None, :] < window))
+    ok = jnp.concatenate([old_ok, new_ok], axis=-1)[:, :, None, None, :]
+    # the old ring, then the fresh rows as the leaf will hold them
+    keys, vals = (jnp.concatenate([kv[n], x.astype(dt)], axis=1).reshape(
+        (S, L + K) + heads) for n, x in (("k", k_new), ("v", v_new)))
+    qg = (q * scale).astype(dt).reshape(S, K, n_kv_head, rep, heads[1])
+    scores = jnp.einsum("skgrd,stgd->skgrt", qg, keys,
+                        preferred_element_type=jnp.float32)
+    w = jax.nn.softmax(jnp.where(ok, scores, -1e9), axis=-1)
+    ctx = jnp.einsum("skgrt,stgd->skgrd", w.astype(dt), vals,
+                     preferred_element_type=jnp.float32)
+    rows = jnp.arange(S)[:, None]
+    keep = live[:, None] & (jnp.arange(K)[None, :] >= K - L)
+    at = jnp.where(keep, pos % L, L)            # dropped: out of range
+    kv = {**_append(kv, "k", k_new, rows, at, heads),
+          **_append(kv, "v", v_new, rows, at, heads)}
+    return jnp.where(live[:, None, None], ctx.reshape(q.shape), 0.0), kv
+
+
 def grouped_masked_decode_attention(q, k_new, v_new, kv, ts,
                                     *, n_head: int, n_kv_head: int,
-                                    scale: float):
+                                    scale: float, window=None):
     """The contract as plain XLA ops, for every leaf dtype, head grouping
     and number of fresh rows.
 
@@ -467,10 +548,21 @@ def grouped_masked_decode_attention(q, k_new, v_new, kv, ts,
     head); scores and the context are products in the storage dtype
     (int8: fp32, dequantized at the read) accumulated in fp32; the
     softmax is fp32 over the whole T axis, masked.  Returns ``(ctx``
-    shaped like ``q``, fp32, ``kv)``."""
+    shaped like ``q``, fp32, ``kv)``.
+
+    ``window`` (the leaves are RING leaves, ``kv_leaves(...,
+    window=window)``): one fresh row is written at ``ts`` modulo the
+    ring's rows and reads the rows ``<= ts``, which past the window is
+    all of them; ``K`` rows go through :func:`_ring_rows_attention`."""
     import jax
     import jax.numpy as jnp
 
+    if window is not None:
+        RING_LOWERED.labels(form="step" if q.ndim == 2 else "rows").inc()
+        if q.ndim == 3:
+            return _ring_rows_attention(
+                q, k_new, v_new, kv, ts, n_head=n_head, n_kv_head=n_kv_head,
+                scale=scale, window=int(window))
     S, T, Dkv = kv["k"].shape
     heads = (n_kv_head, Dkv // n_kv_head)
     rep = n_head // n_kv_head
@@ -480,6 +572,10 @@ def grouped_masked_decode_attention(q, k_new, v_new, kv, ts,
         rows, live = rows[:, None], live[:, None]
         pos = ts[:, None] + jnp.arange(q.shape[1])[None, :]
     at = jnp.where(live, pos, T)            # idle -> out of range, dropped
+    if window is not None:
+        # where to write apart from how many rows are live: the mask
+        # below (rows <= ts) is every row once ts has passed the ring
+        at = jnp.where(live, pos % T, T)
     kv = {**_append(kv, "k", k_new, rows, at, heads),
           **_append(kv, "v", v_new, rows, at, heads)}
     pos_ok = (jnp.arange(T)[None, :] <= pos[..., None])[..., None, None, :]
@@ -1044,10 +1140,14 @@ def grouped_block_decode_attention(q, kv, ts, blocks, valid, dense, *,
 
 
 def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
-                          scale: float):
+                          scale: float, window=None):
     """``attend(q, k_new, v_new, kv) -> (ctx, kv)`` for one step at
-    positions ``ts``, shared by its layers (``kv``: any one layer's
-    leaves, all alike).  The one place that chooses: the kernel when it
+    positions ``ts``, shared by its layers of one leaf kind and length
+    (``kv``: any one of those layers' leaves, all alike; a step whose
+    layers hold sequence leaves AND ring leaves makes one ``attend`` for
+    each).  ``window``: the leaves are RING leaves of that window (what
+    the builder allocated them as), read by the XLA form.  The one place
+    that chooses: the kernel when it
     exists for what the step is — the default backend a TPU, fp32 leaves
     of a shape it lowers for, one query head per K/V head, one fresh row
     per slot — and an XLA form otherwise: on a TPU, for grouped heads
@@ -1060,6 +1160,8 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
     _, seq_len, width = kv["k"].shape
     xla = functools.partial(grouped_masked_decode_attention, ts=ts,
                             n_head=n_head, n_kv_head=n_kv_head, scale=scale)
+    if window is not None:
+        return functools.partial(xla, window=int(window))
     if (jax.default_backend() == "tpu" and "k_scale" not in kv
             and n_kv_head < n_head and (width // n_kv_head) % _HEAD_LANES):
         # grouped heads narrower than a lane tile: a view of the leaf by

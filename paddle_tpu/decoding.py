@@ -69,7 +69,10 @@ __all__ = [
     "make_hybrid_ssm_lm_pooled_step_fn",
     "make_sparse_linear_lm_pooled_step_fn",
     "make_routed_conv_lm_pooled_step_fn",
-    "cache_leaf_seq_axes", "cache_leaf_seq_strides", "recurrent_leaf_names",
+    "make_windowed_routed_lm_pooled_step_fn",
+    "cache_leaf_seq_axes", "cache_leaf_seq_strides", "cache_leaf_seq_windows",
+    "cache_leaf_slotless", "NO_SLOT_AXIS",
+    "recurrent_leaf_names", "ring_leaf_names",
     "normalize_kv_dtype",
     "random_transformer_lm_state",
 ]
@@ -990,6 +993,203 @@ def make_routed_conv_lm_pooled_step_fn(state, cfg, name: str = "lm",
     return step_fn, make_cache
 
 
+def make_windowed_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
+                                            kv_dtype: str = "bf16",
+                                            held=None,
+                                            prefill_tokens: int = 512):
+    """The slot-pooled step AND the chunked prefill of a decoder whose
+    blocks are grouped-query attention over a sliding WINDOW (rotary) or
+    over the whole context (no positions), each followed by a mixture of
+    routed experts whose router reads the block's input before attention
+    (``model_name: smallthinker_*``; the parts and the equations are
+    ``paddle_tpu.windowed_routed_lm``, the expert layer
+    ``paddle_tpu.routed_experts``).
+
+    Returns ``(step_fn, make_cache, prefill_fn)`` with the contract of
+    :func:`make_sparse_linear_lm_pooled_step_fn` (``step_fn(cache, tokens
+    [N] int32, ts [N] int32) -> (logits [N, V] fp32, cache)``, ``ts[i] <
+    0`` an idle row; ``prefill_fn(cache, row, tokens [C], start, n_valid)
+    -> cache``).  ``state``: weights under
+    ``windowed_routed_lm.param_shapes(cfg)``, multiplied in the dtype
+    they are given (the router stays float32); ``cfg``: the published
+    config keys (``windowed_routed_lm.dims``); ``held``: the contiguous
+    range of experts whose matrices ``state`` holds, as
+    :func:`make_routed_conv_lm_pooled_step_fn`.
+
+    The cache is ``{"layers": [...], "expert_stats": ...}`` and its
+    layers' leaves differ in LENGTH (``make_cache.leaf_seq_windows``
+    says so beside ``leaf_seq_axes``):
+
+    * a global layer ``k``, ``v`` ``[N, T, n_kv_head * head_dim]`` in
+      ``kv_dtype``: sequence leaves of the length rung (``k`` bare: a
+      global layer has no positions);
+    * a window layer ``k``, ``v`` ``[N, min(T, window), ...]``: RING
+      leaves (``decode_attention.kv_leaves(..., window=)``; ``k`` after
+      rotary), position ``p`` in row ``p mod window``;
+    * ``expert_stats`` ``[layers, 4]`` int32, as
+      :func:`make_routed_conv_lm_pooled_step_fn` but declared
+      ``NO_SLOT_AXIS``: this pool keeps snapshots of a slot's row, and a
+      leaf of counts has no slot's row (the prefill's chunks are not
+      counted: the counts are of steps).
+
+    The step takes its length rung from a GLOBAL layer's leaf and makes
+    two ``attend``s (``decode_attention.make_decode_attention``: over
+    the ring, over the whole rung).  ``prefill_fn`` feeds slot ``row``
+    ``C = prefill_tokens`` prompt tokens at ``start .. start + n_valid -
+    1`` through every layer in one call and no logits: a global layer
+    writes the chunk's rows and every query reads keys ``0 .. its own``;
+    a window layer's queries read the OLD ring and the chunk's own rows
+    (``decode_attention.ring_positions``) before the chunk overwrites
+    ``C`` of its rows; both through ``windowed_routed_lm.chunk_attend``
+    (key blocks, online softmax: no temporary grows with the rung); the
+    chunk's ``C`` rows go through the expert layer as a step's rows do
+    (``C * top_k`` pairs).  ``C`` must divide the window and ``start`` be
+    a multiple of ``C``, so that a chunk's rows lie in the ring without a
+    wrap.  It equals ``n_valid`` steps leaf for leaf, but for the
+    summation order (tests/test_windowed_routed_lm.py).
+    ``make_cache.window_positions_read(n)`` is what a window layer's
+    query of context ``n`` reads (``make_cache.window_layers`` of them),
+    for the server's counters.
+
+    Ring leaves cannot be sliced by positions: ``KVSlotPool`` serves
+    ``prefix=True`` over this builder by SNAPSHOTS (it has a prefill)
+    and refuses ``speculative=``.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import routed_experts as rx
+    from paddle_tpu import windowed_routed_lm as wr
+    from paddle_tpu.decode_attention import (kv_leaves, make_decode_attention,
+                                             ring_positions)
+
+    d = wr.dims(cfg)
+    kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
+    W = {k: jnp.asarray(v) for k, v in state.items()}
+    C = int(prefill_tokens)
+    if d.window % C:
+        raise ValueError("prefill_tokens must divide sliding_window_size")
+    scale = 1.0 / float(np.sqrt(d.head_dim))
+    global_at = [i for i, kind in enumerate(d.kinds) if kind == wr.GLOBAL]
+    window_at = [i for i, kind in enumerate(d.kinds) if kind == wr.WINDOW]
+    n_stats = len(rx.STAT_NAMES)
+
+    def make_cache(n_rows: int, seq_len: int):
+        return {
+            "layers": [
+                kv_leaves(n_rows, seq_len, d.n_kv_head, d.head_dim, kv,
+                          window=d.window if kind == wr.WINDOW else None)
+                for kind in d.kinds],
+            "expert_stats": jnp.zeros((d.n_layer, n_stats), jnp.int32)}
+
+    make_cache.leaf_seq_axes = {
+        "layers": [{"k": 1, "v": 1} for _ in d.kinds],
+        "expert_stats": NO_SLOT_AXIS}   # a snapshot must not carry counts
+    make_cache.leaf_seq_windows = {
+        "layers": [{"k": d.window, "v": d.window} if kind == wr.WINDOW
+                   else {"k": 0, "v": 0} for kind in d.kinds],
+        "expert_stats": 0}
+    make_cache.expert_stats = lambda cache: cache["expert_stats"]
+    make_cache.n_expert = d.n_expert
+    make_cache.window_layers = len(window_at)
+    make_cache.window_positions_read = lambda n: np.minimum(n, d.window)
+
+    def close_layer(h, r, o, p, ts):
+        """The out-projection and the expert layer (routed by the
+        block's normed input ``r``) around a mixer's output ``o``."""
+        h = h + wr.linear(o, W[p + "attn_o"])
+        f = wr.rms_norm(h, W[p + "ffn_norm"], d.eps)
+        y, st = rx.expert_layer(f, W, p, ts, d, held, router_input=r)
+        return h + y, st
+
+    def step_fn(cache, tokens, ts):
+        layers = cache["layers"]
+        attend = {}
+        if global_at:
+            ts = jnp.minimum(ts, layers[global_at[0]]["k"].shape[1] - 1)
+            attend[wr.GLOBAL] = make_decode_attention(
+                ts, layers[global_at[0]], n_head=d.n_head,
+                n_kv_head=d.n_kv_head, scale=scale)
+        if window_at:
+            attend[wr.WINDOW] = make_decode_attention(
+                ts, layers[window_at[0]], n_head=d.n_head,
+                n_kv_head=d.n_kv_head, scale=scale, window=d.window)
+        pos = jnp.maximum(ts, 0)      # idle rows stay < 0 in ``ts``
+        n = tokens.shape[0]
+        h = W[name + "_emb"][tokens].astype(jnp.float32)
+        new_layers, stats = [], []
+        for i, kind in enumerate(d.kinds):
+            p = "%s_l%d_" % (name, i)
+            r = wr.rms_norm(h, W[p + "input_norm"], d.eps)
+            q, k, v = wr.attention_inputs(r, W, p, kind, pos, d)
+            with jax.named_scope(wr.WINDOW_ATTEND_SCOPE if kind == wr.WINDOW
+                                 else wr.GLOBAL_ATTEND_SCOPE):
+                ctx, kvs = attend[kind](q.reshape(n, -1), k.reshape(n, -1),
+                                        v, layers[i])
+            new_layers.append(kvs)
+            h, st = close_layer(h, r, ctx, p, ts)
+            stats.append(st)
+        logits = wr.linear(wr.rms_norm(h, W[name + "_final_norm"], d.eps),
+                           W[name + "_head"])
+        return logits, {"layers": new_layers,
+                        "expert_stats": cache["expert_stats"]
+                        + jnp.stack(stats)}
+
+    def prefill_layer(c, kind, h, p, row, start, n_valid, pos, ts_q):
+        r = wr.rms_norm(h, W[p + "input_norm"], d.eps)
+        q, k, v = wr.attention_inputs(r, W, p, kind, pos, d)
+        live = (ts_q >= 0)[:, None]
+        rows = c["k"].shape[1]
+        # a chunk's rows lie in a leaf without a wrap: C divides the
+        # window and the rungs a chunk is fed on are longer than it
+        at = start % rows if kind == wr.WINDOW else start
+        old = {leaf: jax.lax.dynamic_index_in_dim(c[leaf], row, 0,
+                                                  keepdims=False)
+               for leaf in ("k", "v")}
+        fresh = {"k": k.reshape(C, -1).astype(kv), "v": v.astype(kv)}
+        new = {}
+        for leaf in ("k", "v"):
+            kept = jax.lax.dynamic_slice(old[leaf], (at, 0), (C, d.d_kv))
+            new[leaf] = jax.lax.dynamic_update_slice(
+                c[leaf], jnp.where(live, fresh[leaf], kept)[None],
+                (row, at, 0))
+        if kind == wr.WINDOW:
+            # the OLD ring's rows at the positions they hold, then the
+            # chunk's own
+            with jax.named_scope(wr.WINDOW_ATTEND_SCOPE):
+                o = wr.chunk_attend(
+                    q, jnp.concatenate([old["k"], fresh["k"]]),
+                    jnp.concatenate([old["v"], fresh["v"]]), ts_q,
+                    jnp.concatenate([ring_positions(start, rows), ts_q]),
+                    rows + C, d, window=d.window)
+        else:
+            with jax.named_scope(wr.GLOBAL_ATTEND_SCOPE):
+                o = wr.chunk_attend(
+                    q, jax.lax.dynamic_index_in_dim(new["k"], row, 0, False),
+                    jax.lax.dynamic_index_in_dim(new["v"], row, 0, False),
+                    ts_q, jnp.arange(rows), start + n_valid, d)
+        h, _ = close_layer(h, r, o, p, ts_q)
+        return h, new
+
+    def prefill_fn(cache, row, tokens, start, n_valid):
+        with jax.named_scope(wr.PREFILL_CHUNK_SCOPE):
+            pos = start + jnp.arange(C)
+            ts_q = jnp.where(jnp.arange(C) < n_valid, pos, -1)
+            h = W[name + "_emb"][tokens].astype(jnp.float32)
+            new_layers = []
+            for i, kind in enumerate(d.kinds):
+                h, new = prefill_layer(cache["layers"][i], kind, h,
+                                       "%s_l%d_" % (name, i), row, start,
+                                       n_valid, pos, ts_q)
+                new_layers.append(new)
+            return {"layers": new_layers,
+                    "expert_stats": cache["expert_stats"]}
+
+    prefill_fn.chunk_tokens = C
+    make_cache.prefill_fn = prefill_fn
+    return step_fn, make_cache, prefill_fn
+
+
 def make_transformer_lm_pooled_verify_fn(
     state,
     vocab_size: int,
@@ -1209,8 +1409,11 @@ def cache_leaf_seq_axes(make_cache, leaves):
     Every builder DECLARES its leaves: ``make_cache.leaf_seq_axes`` is a
     pytree shaped like the cache whose leaves are ints — the axis, or
     ``-1`` for a leaf with no sequence axis (recurrent state, or
-    anything the pool should carry and never slice) — and nothing is
-    inferred from a shape; a ``make_cache`` without the attribute is an
+    anything the pool should carry and never slice), or
+    :data:`NO_SLOT_AXIS` for a leaf that has no SLOT axis either (counts
+    a step keeps for the whole pool: carried, never sliced and never
+    part of a slot's snapshot: :func:`cache_leaf_slotless`) — and
+    nothing is inferred from a shape; a ``make_cache`` without the attribute is an
     error.  ``KVSlotPool.extract_kv`` / ``admit_prefix`` /
     ``kv_rung_bytes`` and :func:`make_prefix_admit_fn` all resolve the
     axis through this one function, so the host side and the traced side
@@ -1223,6 +1426,21 @@ def cache_leaf_seq_axes(make_cache, leaves):
             "make_cache.leaf_seq_axes declares %d leaves, the cache has %d"
             % (len(axes), len(leaves)))
     return [None if int(a) < 0 else int(a) for a in axes]
+
+
+#: in ``make_cache.leaf_seq_axes``: a leaf with neither a sequence axis
+#: nor a slot axis
+NO_SLOT_AXIS = -2
+
+
+def cache_leaf_slotless(make_cache, leaves):
+    """Whether each of ``leaves`` is declared :data:`NO_SLOT_AXIS`: its
+    axis 0 is NOT the slot, so no slot's row of it exists to snapshot or
+    to install."""
+    import jax
+
+    return [int(a) == NO_SLOT_AXIS
+            for a in jax.tree.leaves(_declared_seq_axes(make_cache))]
 
 
 def cache_leaf_seq_strides(make_cache, leaves):
@@ -1244,6 +1462,27 @@ def cache_leaf_seq_strides(make_cache, leaves):
     return strides
 
 
+def cache_leaf_seq_windows(make_cache, leaves):
+    """The window of each leaf's sequence axis: ``None`` for a leaf as
+    long as the length rung, ``W`` for a RING leaf — ``min(rung, W)``
+    rows, position ``p`` in row ``p mod W``
+    (``decode_attention.kv_leaves(..., window=W)``).  Declared by the
+    builder as ``make_cache.leaf_seq_windows``, a pytree shaped like
+    ``leaf_seq_axes`` whose leaves are ints (``0``: no window); a
+    ``make_cache`` without the attribute has no ring leaves."""
+    import jax
+
+    declared = getattr(make_cache, "leaf_seq_windows", None)
+    if declared is None:
+        return [None] * len(leaves)
+    windows = [int(x) for x in jax.tree.leaves(declared)]
+    if len(windows) != len(leaves) or min(windows) < 0:
+        raise ValueError(
+            "make_cache.leaf_seq_windows must declare a window >= 0 (0: "
+            "none) for each of the cache's %d leaves" % len(leaves))
+    return [w or None for w in windows]
+
+
 def recurrent_leaf_names(make_cache):
     """Tree paths of the leaves ``make_cache`` declares recurrent (no
     sequence axis: ``-1`` in ``make_cache.leaf_seq_axes``)."""
@@ -1252,6 +1491,18 @@ def recurrent_leaf_names(make_cache):
     return [jax.tree_util.keystr(path) for path, a in
             jax.tree_util.tree_flatten_with_path(
                 _declared_seq_axes(make_cache))[0] if int(a) < 0]
+
+
+def ring_leaf_names(make_cache):
+    """Tree paths of the leaves ``make_cache`` declares RING leaves (a
+    window > 0 in ``make_cache.leaf_seq_windows``)."""
+    import jax
+
+    declared = getattr(make_cache, "leaf_seq_windows", None)
+    if declared is None:
+        return []
+    return [jax.tree_util.keystr(path) for path, w in
+            jax.tree_util.tree_flatten_with_path(declared)[0] if int(w) > 0]
 
 
 def make_prefix_admit_fn(admit_fn, seq_axes_of, seq_strides_of=None,

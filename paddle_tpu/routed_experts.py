@@ -22,6 +22,15 @@ sigmoid and the selection run in float32 (the router's matrix is kept
 float32 and multiplied at precision "highest": a bf16 router picks other
 experts); norms, rotary angles and the conv state are float32.
 
+What differs between the families that share the expert layer is read
+from ``d`` (each family's ``dims`` sets it from its configuration):
+``d.scoring`` — :data:`SIGMOID_BIAS` (above) or :data:`SOFTMAX_CHOSEN`
+(``sel = top_k(z)`` on the LOGITS, ``g = softmax(z[sel])`` over the
+chosen alone; ``paddle_tpu.windowed_routed_lm``) — and ``d.gate_act``,
+the gate's activation (:data:`SILU` | :data:`RELU`); the router may read
+another input than the experts do (``expert_layer(..., router_input=)``:
+a router placed before attention).
+
 An expert layer is told which experts it HOLDS (``held``: a contiguous
 range ``(lo, hi)`` of the published count): it routes over all of them,
 computes what its own give for the rows routed to them and adds nothing
@@ -44,6 +53,7 @@ from paddle_tpu.hybrid_ssm import (linear, rms_norm, rotary, starts_fresh,
 __all__ = ["dims", "param_shapes", "random_state", "route", "dispatch",
            "expert_layer", "short_conv_step", "CONV", "ATTENTION",
            "ROUTE_SCOPE", "EXPERTS_SCOPE", "SHORT_CONV_SCOPE", "STAT_NAMES",
+           "SIGMOID_BIAS", "SOFTMAX_CHOSEN", "SILU", "RELU",
            "linear", "rms_norm", "rotary", "swiglu", "starts_fresh"]
 
 CONV, ATTENTION = "conv", "full_attention"
@@ -57,6 +67,11 @@ SHORT_CONV_SCOPE = "short_conv"
 #: choice) pairs of live rows routed to a held expert; held experts that
 #: got at least one; the largest group; 1 if any row was live
 STAT_NAMES = ("assignments", "experts_touched", "peak_load", "layer_steps")
+
+#: ``d.scoring``: how the router's logits become a choice and its weights
+SIGMOID_BIAS, SOFTMAX_CHOSEN = "sigmoid_bias", "softmax_chosen"
+#: ``d.gate_act``: the activation of an expert's gate
+SILU, RELU = "silu", "relu"
 
 _WEIGHT_SUM_EPS = 1e-6
 
@@ -80,7 +95,8 @@ def dims(cfg) -> SimpleNamespace:
         rope_theta=float(cfg["rope_parameters"]["rope_theta"]),
         norm_topk=bool(cfg.get("norm_topk_prob", True)),
         routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
-        expert_bias=bool(cfg.get("use_expert_bias", True)))
+        expert_bias=bool(cfg.get("use_expert_bias", True)),
+        scoring=SIGMOID_BIAS, gate_act=SILU)
     if len(kinds) != o.n_layer or set(kinds) - {CONV, ATTENTION}:
         raise ValueError("layer_types must name num_hidden_layers layers, "
                          "each %r or %r" % (CONV, ATTENTION))
@@ -163,12 +179,23 @@ def random_state(rng, cfg, name: str = "lm", std: float = 0.02,
 
 def route(f, w_router, bias, d):
     """Which experts each row chose and how it weighs them, over ALL
-    ``d.n_expert``: ``(sel [N, top_k] int32, gate [N, top_k] float32)``.
-    The bias enters the choice and never the weights."""
+    ``d.n_expert``: ``(sel [N, top_k] int32, gate [N, top_k] float32)``,
+    by ``d.scoring``.  :data:`SIGMOID_BIAS`: the bias enters the choice
+    and never the weights.  :data:`SOFTMAX_CHOSEN`: the choice is made on
+    the logits and the softmax runs over the chosen alone (``bias`` is
+    not read)."""
     import jax
     import jax.numpy as jnp
 
     f32 = jnp.float32
+    if d.scoring == SOFTMAX_CHOSEN:
+        z, sel = jax.lax.top_k(jnp.dot(
+            f.astype(f32), w_router.astype(f32), precision="highest",
+            preferred_element_type=f32), d.top_k)
+        gate = jax.nn.softmax(z, axis=-1)
+        if d.norm_topk:
+            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        return sel.astype(jnp.int32), gate * d.routed_scale
     s = jax.nn.sigmoid(jnp.dot(
         f.astype(f32), w_router.astype(f32), precision="highest",
         preferred_element_type=f32))
@@ -198,16 +225,24 @@ def dispatch(sel, live, held, n_expert: int):
     return order, sizes, kept
 
 
-def expert_layer(f, w, p: str, ts, d, held=None):
+def expert_layer(f, w, p: str, ts, d, held=None, router_input=None):
     """The held experts' part of a mixture layer for one token per row.
 
     ``f`` ``[N, d_model]`` (the normed residual); ``w`` the weight dict,
     ``p`` the layer's key prefix — ``experts_w13`` / ``experts_w2`` hold
     the HELD experts only, in order (``hi - lo`` of them), the router
-    and its bias all ``d.n_expert``; ``ts`` ``[N]`` (``< 0`` idle: routed
-    nowhere, counted nowhere); ``held`` ``(lo, hi)``, default all.
-    Returns ``(out [N, d_model] float32, stats [4] int32)`` with
-    ``stats`` as :data:`STAT_NAMES`."""
+    (and its bias, where ``d.expert_bias``) all ``d.n_expert``; ``ts``
+    ``[N]`` (``< 0`` idle: routed nowhere, counted nowhere); ``held``
+    ``(lo, hi)``, default all; ``router_input`` ``[N, d_model]``: what
+    the router reads where that is not ``f`` (a router placed before
+    attention reads the block's normed input).  Returns ``(out [N,
+    d_model] float32, stats [4] int32)`` with ``stats`` as
+    :data:`STAT_NAMES`.
+
+    The sorted pairs are padded to a whole ``grouped_matmul.ROW_TILE``
+    (pairs of no group, as an idle row's are: 40 rows x 6 = 240 -> 256),
+    so that a step whose pairs are not a multiple of it keeps the kernel
+    instead of falling to ``ragged_dot``."""
     import jax
     import jax.numpy as jnp
 
@@ -217,14 +252,20 @@ def expert_layer(f, w, p: str, ts, d, held=None):
     n, k = f.shape[0], d.top_k
     live = ts >= 0
     with jax.named_scope(ROUTE_SCOPE):
-        sel, gate = route(f, w[p + "router"], w[p + "expert_bias"], d)
+        sel, gate = route(f if router_input is None else router_input,
+                          w[p + "router"], w.get(p + "expert_bias"), d)
         order, sizes, kept = dispatch(sel, live, held, d.n_expert)
         w13, w2 = w[p + "experts_w13"], w[p + "experts_w2"]
-        rows = f.astype(w13.dtype)[order // k]
-        visits = gm.plan(sizes, n * k)
+        pad = -(n * k) % gm.ROW_TILE
+        taken = order
+        if pad:     # rows past the groups' total: multiplied by nothing
+            taken = jnp.concatenate([order, jnp.zeros(pad, jnp.int32)])
+        rows = f.astype(w13.dtype)[taken // k]
+        visits = gm.plan(sizes, n * k + pad)
     with jax.named_scope(EXPERTS_SCOPE):
         gu = gm.grouped_matmul(rows, w13, visits)
-        act = jax.nn.silu(gu[:, :d.d_expert]) * gu[:, d.d_expert:]
+        act_fn = jax.nn.relu if d.gate_act == RELU else jax.nn.silu
+        act = act_fn(gu[:, :d.d_expert]) * gu[:, d.d_expert:]
         y = gm.grouped_matmul(act.astype(w2.dtype), w2, visits)
     with jax.named_scope(ROUTE_SCOPE):
         # back to (row, choice) order, weighed, summed over the choices

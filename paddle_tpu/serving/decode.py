@@ -214,6 +214,30 @@ DECODE_SPARSE_LIVE = monitor.counter(
     "have read) — read / live is how sparse the traffic makes the "
     "layer", _LABELS)
 
+DECODE_WINDOW_LIVE = monitor.counter(
+    "serving_decode_window_positions_live_total",
+    "K/V positions live for the window layers' decode reads: per step, "
+    "active slot and window layer (make_cache.window_layers) the slot's "
+    "context, from the pos the tick already fetched; 0 for a builder "
+    "without window layers", _LABELS)
+DECODE_WINDOW_READ = monitor.counter(
+    "serving_decode_window_positions_read_total",
+    "K/V positions those reads may read: the lesser of the context and "
+    "the window (make_cache.window_positions_read) — read / live is how "
+    "much of a context the window layers leave unread (and unheld)",
+    _LABELS)
+DECODE_KV_BYTES_HELD = monitor.gauge(
+    "serving_decode_kv_bytes_held",
+    "bytes of the pool's sequence leaves at its CURRENT rung pair, ring "
+    "leaves at their own length (what KVSlotPool.kv_rung_bytes counts); "
+    "0 while the pool is idle", _LABELS)
+DECODE_KV_BYTES_ONE_LENGTH = monitor.gauge(
+    "serving_decode_kv_bytes_one_length",
+    "what those leaves would hold if every one were as long as the "
+    "length rung (no window): held / one_length is what two cache "
+    "lengths in one pool save; equal where no leaf is a ring leaf",
+    _LABELS)
+
 _EXPERT_STATS_HELP = (
     " — counted on the device by a step whose builder declares "
     "make_cache.expert_stats, summed over its expert layers, fetched "
@@ -460,6 +484,15 @@ class DecodeServer:
         # counters above: (positions a query of context n reads, layers)
         self._sparse_rule = getattr(make_cache, "sparse_positions_read", None)
         self._sparse_layers = int(getattr(make_cache, "sparse_layers", 0))
+        # what the builder declares of its window layers, for the two
+        # position counters: (positions a query of context n may read,
+        # layers); and what two cache lengths in one pool hold and save
+        self._window_read_c = DECODE_WINDOW_READ.labels(**lbl)
+        self._window_live_c = DECODE_WINDOW_LIVE.labels(**lbl)
+        self._window_rule = getattr(make_cache, "window_positions_read", None)
+        self._window_layers = int(getattr(make_cache, "window_layers", 0))
+        self._kv_held_g = DECODE_KV_BYTES_HELD.labels(**lbl)
+        self._kv_one_length_g = DECODE_KV_BYTES_ONE_LENGTH.labels(**lbl)
         # what the builder's steps count on the device (routed experts):
         # the leaf rides the tick's one device_get, the deltas go to the
         # four counters, in routed_experts.STAT_NAMES' order
@@ -591,6 +624,10 @@ class DecodeServer:
             "prefill_chunk_tokens": self._pool.prefill_tokens,
             "sparse_positions_read": int(self._sparse_read_c.value),
             "sparse_positions_live": int(self._sparse_live_c.value),
+            "window_positions_read": int(self._window_read_c.value),
+            "window_positions_live": int(self._window_live_c.value),
+            "kv_bytes_held": int(self._kv_held_g.value),
+            "kv_bytes_one_length": int(self._kv_one_length_g.value),
             "expert_assignments": int(self._expert_cs[0].value),
             "experts_touched": int(self._expert_cs[1].value),
             "expert_peak_load": int(self._expert_cs[2].value),
@@ -1038,6 +1075,10 @@ class DecodeServer:
         self._recurrent_bytes_g.set(
             0.0 if rungs is None
             else float(pool.recurrent_rung_bytes(*rungs)))
+        self._kv_held_g.set(self._kv_bytes_g.value)
+        self._kv_one_length_g.set(
+            0.0 if rungs is None
+            else float(pool.kv_rung_bytes_one_length(*rungs)))
 
     def _tick(self, turn: Optional[_Turn]) -> None:
         """One scheduler turn: dispatch a multi-step chunk, then
@@ -1116,11 +1157,13 @@ class DecodeServer:
             turn.leave(bytes=sum(v.nbytes for v in view.values()))
             turn.enter("deliver", cpu=True)
             tokens0 = self._tokens_c.value
-        experts = {}
+        fields = {}     # of the deliver span: what the chunk counted
         if stepped:
             if "expert_stats" in view:
-                experts = self._count_experts(view["expert_stats"])
-            self._count_kv_positions(recs, view, use_spec)
+                fields = self._count_experts(view["expert_stats"])
+            window_rows = self._count_kv_positions(recs, view, use_spec)
+            if self._window_layers:
+                fields["window_rows"] = window_rows
             # after the position counters: whoever sees the tick counted
             # sees its positions counted too
             self._ticks_c.inc()
@@ -1188,7 +1231,7 @@ class DecodeServer:
             turn.leave(
                 fresh_tokens=int(self._tokens_c.value - tokens0),
                 finished=sum(1 for i, _ in recs if self._slots[i] is None),
-                **experts)
+                **fields)
 
     def _prefill_turn(self, recs, turn: Optional[_Turn]) -> bool:
         """The turn's ONE prefill dispatch, before its decode chunk: the
@@ -1250,12 +1293,14 @@ class DecodeServer:
             "peak_over_mean": (self._n_expert * peak / pairs
                                if pairs else 0.0)}
 
-    def _count_kv_positions(self, recs, view, use_spec: bool) -> None:
+    def _count_kv_positions(self, recs, view, use_spec: bool) -> int:
         """Advance the KV read / live / pool position counters for the
         chunk just run, from the ``pos`` the tick already fetched: a slot
         that went from ``p0`` to ``p1`` ran steps at ``ts = p0..p1 - 1``,
         each with ``ts + 1`` live positions, of which the ragged kernel
-        reads what :func:`kv_positions_read` says."""
+        reads what :func:`kv_positions_read` says.  Returns the positions
+        the chunk's window layers read (0 for a builder without them:
+        the ``deliver`` span's ``window_rows``)."""
         s, t = view["tokens"].shape
         idx = np.fromiter((i for i, _ in recs), np.intp, len(recs))
         p1 = view["pos"][idx].astype(np.int64)
@@ -1276,11 +1321,20 @@ class DecodeServer:
             self._sparse_read_c.inc(
                 int((self._sparse_rule(n) * ran).sum())
                 * self._sparse_layers)
+        window_rows = 0
+        if self._window_rule is not None and self._window_layers:
+            n = ts + 1
+            window_rows = (int((self._window_rule(n) * ran).sum())
+                           * self._window_layers)
+            self._window_live_c.inc(
+                int((n * ran).sum()) * self._window_layers)
+            self._window_read_c.inc(window_rows)
         self._kv_live_c.inc(int((p1 * (p1 + 1) - p0 * (p0 + 1)).sum()) // 2)
         for (_, rec), p in zip(recs, p1.tolist()):
             rec.pos = p
         self._kv_pool_c.inc(pool)
         self._kv_read_c.inc(read)
+        return window_rows
 
     def _offer_prefix(self, slot: int, rec: _Slot, consumed: int) -> None:
         """Retain a freed slot's prefix KV in the cache (a control-plane

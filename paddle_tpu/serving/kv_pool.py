@@ -16,8 +16,8 @@ entirely on warmed executables — the pool's
 request-batching path.
 
 The pool state is one dict pytree (slot axis 0 on every leaf; the KV
-cache's T axis read by the step fn).  A cache leaf is one of two kinds,
-and the builder says which (``make_cache.leaf_seq_axes``, resolved by
+cache's T axis read by the step fn).  A cache leaf is one of three kinds
+(sequence, ring, recurrent), and the builder says which (``make_cache.leaf_seq_axes``, resolved by
 ``decoding.cache_leaf_seq_axes``; a ``make_cache`` that declares nothing
 is refused): a leaf WITH a sequence axis (K/V rows) is covered
 by the write-before-read invariant — a reused slot is never zeroed,
@@ -28,7 +28,19 @@ re-written whole each step) is outside that invariant, is started from
 zero by the step itself for a row at position 0, is never sliced, and
 is counted by ``recurrent_rung_bytes``.  A sequence leaf may advance
 one row per several positions (``make_cache.leaf_seq_strides``: the
-compressed keys of a block-sparse layer).
+compressed keys of a block-sparse layer).  A RING leaf
+(``make_cache.leaf_seq_windows`` declares its window ``W``, resolved by
+``decoding.cache_leaf_seq_windows``; the pool infers nothing) is a
+sequence leaf of ``min(rung, W)`` rows in which position ``p`` lives in
+row ``p mod W``: a window layer's K/V, so that the sequence leaves of
+ONE rung differ in length.  It is allocated, resized (cut or padded to
+``min(new rung, W)`` rows), counted by ``kv_rung_bytes`` and carried
+whole by ``snapshot`` / ``admit_prefix`` like any other; what it cannot
+be is SLICED by positions or rolled back (a wrapped row holds a later
+position than the one its index names), so ``extract_kv``, a
+``prefix=True`` that would call it (a builder without a chunked
+prefill) and ``speculative=`` are refused over ring leaves, as they are
+over recurrent ones.
 
 What else a pool compiles follows from what the builder declares.  A
 builder with a chunked prefill (``make_cache.prefill_fn``) gets one more
@@ -113,7 +125,8 @@ class KVSlotPool:
         from paddle_tpu.decoding import (make_prefix_admit_fn,
                                          make_slot_decode_fns,
                                          normalize_kv_dtype,
-                                         recurrent_leaf_names)
+                                         recurrent_leaf_names,
+                                         ring_leaf_names)
 
         self._make_cache = make_cache
         #: tree paths of the cache leaves declared recurrent (no
@@ -135,6 +148,22 @@ class KVSlotPool:
                     "(make_cache.prefill_fn) can stop at"
                     % (what, self.recurrent_leaves[0],
                        len(self.recurrent_leaves)))
+        #: tree paths of the cache leaves declared RING leaves (a window
+        #: in ``make_cache.leaf_seq_windows``); empty for most builders
+        self.ring_leaves = ring_leaf_names(make_cache)
+        for what, on in (("prefix=True", prefix and self._prefill is None),
+                         ("speculative=", speculative is not None)):
+            if on and self.ring_leaves:
+                raise ValueError(
+                    "KVSlotPool(%s) over a cache with ring leaves (%s ... "
+                    "%d in all): position p of a ring leaf lives in row p "
+                    "mod its window, so a wrapped row cannot be sliced as "
+                    "a prefix of positions or rolled back after a rejected "
+                    "round; a prefix over such leaves needs a whole-row "
+                    "SNAPSHOT taken at a boundary, which only a builder "
+                    "with a chunked prefill (make_cache.prefill_fn) can "
+                    "stop at" % (what, self.ring_leaves[0],
+                                 len(self.ring_leaves)))
         # the cache storage dtype ``make_cache`` allocates (advertised
         # on /healthz; the pool itself is dtype-agnostic — shapes and
         # dtypes all flow from the state spec, so the int8 rung variant
@@ -262,6 +291,17 @@ class KVSlotPool:
             spec["spec"] = jax.ShapeDtypeStruct((s,), np.dtype(bool))
             spec["draft_cache"] = jax.eval_shape(
                 lambda: self.speculative.draft_make_cache(s, t))
+        for leaf, ax, window in zip(self._kv_subtree_leaves(spec),
+                                    self._kv_seq_axes(spec),
+                                    self._kv_seq_windows(spec)):
+            # nothing is inferred: a declared ring leaf must BE one
+            if window is not None and (
+                    ax is None or leaf.shape[ax] != min(t, window)):
+                raise ValueError(
+                    "make_cache.leaf_seq_windows declares a window of %d "
+                    "for a leaf shaped %s at length rung %d: a ring leaf "
+                    "has min(rung, window) rows on its sequence axis"
+                    % (window, leaf.shape, t))
         self._specs[s, t] = spec
         return spec
 
@@ -306,6 +346,21 @@ class KVSlotPool:
 
         return self._declared(cache_leaf_seq_strides, state_or_spec)
 
+    def _kv_slotless(self, state_or_spec):
+        """Whether each of :meth:`_kv_subtree_leaves` is declared to have
+        no slot axis (``decoding.cache_leaf_slotless``): such a leaf is
+        no part of a slot's snapshot."""
+        from paddle_tpu.decoding import cache_leaf_slotless
+
+        return self._declared(cache_leaf_slotless, state_or_spec)
+
+    def _kv_seq_windows(self, state_or_spec):
+        """The window (or None) of each of :meth:`_kv_subtree_leaves`'
+        sequence axes (``decoding.cache_leaf_seq_windows``)."""
+        from paddle_tpu.decoding import cache_leaf_seq_windows
+
+        return self._declared(cache_leaf_seq_windows, state_or_spec)
+
     def alloc(self, s: int, t: int) -> Dict[str, object]:
         """A fresh zeroed pool state for rung pair ``(s, t)``, HOST-side
         (plain numpy): device memory is first touched by the executable
@@ -326,7 +381,10 @@ class KVSlotPool:
         assumes the caller vacated the dropped tail slots.  No axis is
         looked for: every leaf is cut or padded to the target SPEC's
         shape, so a recurrent leaf (whose shape follows the slot rung
-        alone) keeps every value whatever the length rungs are."""
+        alone) keeps every value whatever the length rungs are, and a
+        ring leaf goes to ``min(new_t, window)`` rows (below its window
+        position ``p`` is row ``p``, so growing pads; at or past it
+        nothing moves)."""
         import jax
 
         spec = self._state_spec(new_s, new_t)
@@ -349,21 +407,25 @@ class KVSlotPool:
         s, t = state["tokens"].shape
         return int(s), int(t)
 
-    def _rung_bytes(self, s: int, t: int) -> Tuple[int, int]:
+    def _rung_bytes(self, s: int, t: int) -> Tuple[int, int, int]:
         """(bytes in the leaves with a sequence axis, bytes in those
-        declared to have none) of the cache subtrees at rung pair
+        declared to have none, what the first would be were every ring
+        leaf as long as the rung) of the cache subtrees at rung pair
         ``(s, t)``, from the state SPEC's stored dtypes — no
         allocation."""
         spec = self._state_spec(s, t)
-        seq = rec = 0
-        for leaf, ax in zip(self._kv_subtree_leaves(spec),
-                            self._kv_seq_axes(spec)):
+        seq = rec = whole = 0
+        for leaf, ax, stride, window in zip(
+                self._kv_subtree_leaves(spec), self._kv_seq_axes(spec),
+                self._kv_seq_strides(spec), self._kv_seq_windows(spec)):
             n = int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
             if ax is None:
                 rec += n
-            else:
-                seq += n
-        return seq, rec
+                continue
+            seq += n
+            whole += (n if window is None
+                      else n // leaf.shape[ax] * (t // stride))
+        return seq, rec, whole
 
     def kv_rung_bytes(self, s: int, t: int) -> int:
         """KV bytes one state of rung pair ``(s, t)`` holds (cache +
@@ -374,6 +436,13 @@ class KVSlotPool:
         ~4x less than fp32's, so a fixed HBM budget seats ~2x+ the
         concurrent sequences at the next slot rung up."""
         return self._rung_bytes(s, t)[0]
+
+    def kv_rung_bytes_one_length(self, s: int, t: int) -> int:
+        """What :meth:`kv_rung_bytes` would count if every sequence leaf
+        held the whole length rung: the same where no leaf is a ring
+        leaf.  The ``serving_decode_kv_bytes_one_length`` gauge reads
+        this; over it, :meth:`kv_rung_bytes` is what the windows save."""
+        return self._rung_bytes(s, t)[2]
 
     def recurrent_rung_bytes(self, s: int, t: int) -> int:
         """Bytes of the leaves declared recurrent (no sequence axis) at
@@ -425,11 +494,12 @@ class KVSlotPool:
         args = [spec, mask, prompt, scalar, scalar]
         if kind == "admit_prefix":
             kv = []
-            for leaf, ax in zip(self._kv_subtree_leaves(spec),
-                                self._kv_seq_axes(spec)):
+            for leaf, ax, slotless in zip(self._kv_subtree_leaves(spec),
+                                          self._kv_seq_axes(spec),
+                                          self._kv_slotless(spec)):
                 # a snapshot carries every leaf of the slot's row; else
                 # only the leaves with positions, recurrent ones a dummy
-                whole = ax is not None or self.snapshots
+                whole = ax is not None or (self.snapshots and not slotless)
                 kv.append(jax.ShapeDtypeStruct(
                     leaf.shape[1:] if whole else (1,),
                     leaf.dtype if whole else np.dtype(np.float32)))
@@ -666,12 +736,13 @@ class KVSlotPool:
         buf[:n] = prompt[:n]
         shapes = self._state_spec(s, t)  # not ``spec``: that is the flag
         kv = []
-        for sd, ent, ax in zip(self._kv_subtree_leaves(shapes), kv_leaves,
-                               self._kv_seq_axes(shapes)):
+        for sd, ent, ax, slotless in zip(
+                self._kv_subtree_leaves(shapes), kv_leaves,
+                self._kv_seq_axes(shapes), self._kv_slotless(shapes)):
             if self.snapshots:
                 # a snapshot's leaves are device arrays of this rung's
                 # row shapes already (:meth:`snapshot`): no host copy
-                if tuple(ent.shape) != tuple(sd.shape[1:]):
+                if not slotless and tuple(ent.shape) != tuple(sd.shape[1:]):
                     raise ValueError(
                         "snapshot leaf %s does not fit rung pair %s"
                         % (ent.shape, (s, t)))
@@ -727,11 +798,16 @@ class KVSlotPool:
 
     def _snapshot_fn(self, state, slot):
         """The traced ``snapshot``: slot ``slot``'s row of every cache
-        leaf (:meth:`_kv_subtree_leaves` order), copied."""
+        leaf (:meth:`_kv_subtree_leaves` order), copied; a ``(1,)``
+        dummy for a leaf declared to have no slot axis."""
         import jax
+        import jax.numpy as jnp
 
-        return [jax.lax.dynamic_index_in_dim(leaf, slot, 0, keepdims=False)
-                for leaf in self._kv_subtree_leaves(state)]
+        return [jnp.zeros((1,), jnp.float32) if slotless
+                else jax.lax.dynamic_index_in_dim(leaf, slot, 0,
+                                                  keepdims=False)
+                for leaf, slotless in zip(self._kv_subtree_leaves(state),
+                                          self._kv_slotless(state))]
 
     def can_prefill(self, state, pos: int, prompt_len: int) -> bool:
         """Whether a slot at ``pos`` of a ``prompt_len``-token prompt
@@ -776,7 +852,16 @@ class KVSlotPool:
         entry per KV subtree leaf (tree-flatten order), ``None`` for
         leaves carrying no per-slot sequence state (recurrent leaves
         among them: they are never sliced).  A control-plane d2h —
-        called when a slot is FREED, off the tick's dispatch path."""
+        called when a slot is FREED, off the tick's dispatch path.
+        Refused over ring leaves: a prefix of positions is not a prefix
+        of a wrapped leaf's rows."""
+        if self.ring_leaves:
+            raise ValueError(
+                "extract_kv over a cache with ring leaves (%s ... %d in "
+                "all): position p of a ring leaf lives in row p mod its "
+                "window, so its first m positions are not its first m "
+                "rows; copy the slot's whole row at a boundary instead "
+                "(snapshot)" % (self.ring_leaves[0], len(self.ring_leaves)))
         out = []
         for leaf, ax, stride in zip(self._kv_subtree_leaves(state),
                                     self._kv_seq_axes(state),

@@ -133,6 +133,23 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
         def build(w):
             return decoding.make_routed_conv_lm_pooled_step_fn(
                 w, cfg, kv_dtype=sv["kv_dtype"])
+    elif cfg["family"] == "pooled_windowed_routed_lm":
+        from paddle_tpu import windowed_routed_lm
+
+        # the first ``layers`` of the cut (2: a global and a window
+        # layer, each with its experts; 8: the whole cut)
+        for key in ("sliding_window_layout", "rope_layout"):
+            cfg[key] = cfg[key][:layers]
+        cfg["num_hidden_layers"] = layers
+        # as the family makes them: matrices bf16, norms and routers fp32
+        weights = {n: sd(shp, jnp.float32 if n.endswith(
+            windowed_routed_lm.FLOAT32_PARAMS) else jnp.bfloat16)
+            for n, shp in windowed_routed_lm.param_shapes(cfg).items()}
+
+        def build(w):
+            return decoding.make_windowed_routed_lm_pooled_step_fn(
+                w, cfg, kv_dtype=sv["kv_dtype"],
+                prefill_tokens=sv["prefill_tokens"])[:2]
     elif cfg["family"] == "pooled_hybrid_ssm_lm":
         from paddle_tpu import hybrid_ssm
 
@@ -226,10 +243,12 @@ def main():
         os.path.abspath(__file__))))
     ap.add_argument("--kind", default="chunk", choices=("chunk", "prefill"),
                     help="prefill: the chunked-prefill program of a "
-                    "builder that has one (minicpm_sala)")
+                    "builder that has one (minicpm_sala, "
+                    "smallthinker_21b_a3b)")
     ap.add_argument("--layers", type=int, default=2,
-                    help="layers compiled (8: the whole minicpm_sala cut, "
-                    "to see that the real program fits the chip)")
+                    help="layers compiled (8: the whole minicpm_sala or "
+                    "smallthinker_21b_a3b cut, to see that the real "
+                    "program fits the chip)")
     args = ap.parse_args()
     lowered = lowered_chunk(os.path.abspath(args.repo), args.config,
                             args.kind, args.layers)
