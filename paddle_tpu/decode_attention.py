@@ -51,7 +51,7 @@ a speculative verify round: ``K`` rows).  Per step and layer
   leaf of whole-width rows and every other block as a head's own tile,
   one slot after another with the next reads in flight.
 
-Two implementations of the read-what-is-live contract, chosen by
+Three implementations of the read-what-is-live contract, chosen by
 :func:`make_decode_attention` from what it can observe:
 
 * :func:`ragged_decode_attention` — the Pallas TPU kernel, for fp32
@@ -71,13 +71,30 @@ Two implementations of the read-what-is-live contract, chosen by
   the MXU as a ``[D, 128]`` 0/1 indicator matmul with the fp32 operand
   split three ways into bf16 (hi + mid + lo carries 24 mantissa bits;
   the indicator is exact), accumulated in fp32.
+* :func:`grouped_decode_attention` — the Pallas TPU kernel for GROUPED
+  heads (``rep > 1``) over unquantized sequence leaves, bf16 or fp32,
+  whose heads are whole lane tiles (``Dh`` a multiple of 128), ``K =
+  1``.  The same ragged read from the same planner with its own sizes
+  (:func:`step_read_sizes`: blocks of ``_GROUPED_BLOCK`` positions, a
+  slot's last one in ``_GROUPED_CLASSES`` classes), the append left to
+  :func:`append_rows`' in-place scatter before it.  An item is BOTH
+  leaves' whole-width ``[rows, n_kv_head * Dh]`` slabs in one copy each,
+  handed over as they lie (no ``[S, T, G, Dh]`` view), the next
+  ``_GROUPED_AHEAD`` items' reads in flight across slot boundaries; the
+  K/V heads are scored from their own lanes of the slab by
+  :func:`_block_part` (``rep`` padded to a sublane tile in VMEM only),
+  products in the storage dtype, fp32 accumulation and online softmax.
+  How many heads one product scores is a parameter of the q layout (a
+  head's lane offset and width): heads narrower than a lane tile (two
+  of 64 lanes a tile) are the same kernel, not shipped yet.
 * :func:`grouped_masked_decode_attention` — the contract whole, as plain
   XLA ops (scatter append + masked softmax over the whole T axis):
   products in the storage dtype (int8: dequantized to fp32 at the read),
   fp32 accumulation and softmax.  The CPU path, the path of every step
-  the kernel does not cover (grouped heads, bf16 and int8 leaves, ``K >
-  1``), and the parity reference of tests/test_decode_attention.py.  A
-  grouped-head bf16 kernel is open work (ROADMAP Queue 2 A).
+  no kernel covers (int8 leaves, ``K > 1``, ring leaves, grouped heads
+  narrower than a lane tile — those through
+  :func:`lane_masked_decode_attention` on a TPU), and the parity
+  reference of tests/test_decode_attention.py.
 
 ``jax.experimental.pallas`` is imported inside the kernel builder only:
 ``import paddle_tpu`` and the training cells never pay for it.
@@ -92,13 +109,14 @@ from paddle_tpu.monitor import registry as _registry
 
 __all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
            "kv_read_block", "kv_positions_read", "decode_work_items",
-           "ragged_decode_attention",
+           "step_read_sizes", "step_positions_read",
+           "ragged_decode_attention", "grouped_decode_attention",
            "grouped_masked_decode_attention",
            "lane_masked_decode_attention", "append_rows",
            "grouped_block_decode_attention", "block_sparse_decode_attention",
            "block_kernel_supported", "BLOCK_SPARSE_LOWERED",
            "kernel_supported", "make_decode_attention", "ring_positions",
-           "RING_LOWERED"]
+           "RING_LOWERED", "GROUPED_LOWERED"]
 
 BLOCK_SPARSE_LOWERED = _registry.REGISTRY.counter(
     "block_sparse_lowered_total",
@@ -114,6 +132,14 @@ RING_LOWERED = _registry.REGISTRY.counter(
     "or run eagerly), by the form: step (one fresh row a slot, written "
     "at its position modulo the window) | rows (K fresh rows that read "
     "the old ring and themselves before they overwrite it)", ("form",))
+
+GROUPED_LOWERED = _registry.REGISTRY.counter(
+    "decode_attention_grouped_lowered_total",
+    "one-row appends-and-reads of grouped heads (fewer K/V heads than "
+    "query heads) over SEQUENCE leaves lowered (traced into a program or "
+    "run eagerly), by the lowering chosen: kernel (Pallas TPU: the live "
+    "(slot, block) pairs as whole-width slabs, the reads in flight by "
+    "hand) | xla (a masked softmax over the whole rung)", ("path",))
 
 #: the sequence axis of every K/V leaf (and scale sibling)
 KV_SEQ_AXIS = 1
@@ -141,6 +167,29 @@ _TILE_UNROLL = 4
 #: take 0.60: chip runs, PR 42), so a unit of the cell (2,176 and 4,096
 #: keys) is one chunk; longer lists loop
 _SCORE_ROWS = 4096
+#: positions of a (slot, block) pair the grouped kernel moves in one DMA a
+#: leaf (a rung shorter than it is one block).  256 / 512 / 1024 / 2048
+#: read alike at ``[40,16384,512]`` (1.24 ms a call: the copies set the
+#: pace, 705 GB/s); at ``[80,1024,512]``, where a slot is one or two
+#: items, 512 reads 0.160-0.168 ms for 1024's 0.183 (finer rounding) and
+#: 256's 0.182 (more items): chip runs, PR 44, tools/time_grouped_decode.py
+_GROUPED_BLOCK = 512
+#: classes a slot's last block is read in (its ``tail`` is the block over
+#: them, 64 rows: the rounding of what a step of the grouped kernel
+#: reads, 1.003 of the live positions at 10.7k contexts, 1.10 at ~330);
+#: 4 and 8 read alike
+_GROUPED_CLASSES = 8
+#: items whose reads are in flight ahead of the one the grouped kernel
+#: scores (2 / 3 / 5 read alike)
+_GROUPED_AHEAD = 2
+#: K/V heads the grouped kernel scores in one product (0: all of them).
+#: A block's chain of products and softmax costs ~0.5 us whatever it
+#: holds, so four heads in ONE block-diagonal product (the MXU's work is
+#: the same: a head's 8 rows or the unit's 32 fill a fraction of its
+#: columns) is 0.62 ms a call of arithmetic at ``[40,16384,512]`` where a
+#: head a product is 1.65 — over the copies' 1.24, which it then sets back
+#: to 1.82
+_GROUPED_HEADS = 0
 #: VMEM a kernel may use before it has to ask for more (v5e's compiler)
 _VMEM_DEFAULT = 16 << 20
 _MASK = -1e30       # finite: exp(_MASK - m) == 0, no inf - inf
@@ -155,14 +204,18 @@ def kv_read_block(seq_len: int) -> int:
     return KV_BLOCK if seq_len % KV_BLOCK == 0 else seq_len
 
 
-def kv_positions_read(ts, block: int):
-    """Positions of a slot the kernel reads in a step at ``ts >= 0``
+def kv_positions_read(ts, block: int, tail=None):
+    """Positions of a slot a kernel reads in a step at ``ts >= 0``
     (``ts``: an int or an integer array, numpy or jax), in blocks of
-    ``block``: ``ts + 1`` rounded up to :data:`KV_TAIL`, or to the block
-    where :data:`KV_TAIL` does not divide it.  THE rounding: the work
-    list (:func:`decode_work_items`) and the server's
+    ``block`` whose last is read in classes of ``tail`` rows: ``ts + 1``
+    rounded up to ``tail``.  ``tail`` unsaid is the ragged kernel's:
+    :data:`KV_TAIL`, or the block where :data:`KV_TAIL` does not divide
+    it; the grouped kernel's comes with its block from
+    :func:`step_read_sizes`.  THE rounding: the work list
+    (:func:`decode_work_items`) and the server's
     ``serving_decode_kv_positions_read_total`` both take it from here."""
-    tail = KV_TAIL if block % KV_TAIL == 0 else block
+    if tail is None:
+        tail = KV_TAIL if block % KV_TAIL == 0 else block
     return (ts // tail + 1) * tail
 
 
@@ -173,7 +226,42 @@ def kernel_supported(seq_len: int, d_model: int, n_head: int) -> bool:
             and n_head <= _HEAD_LANES)
 
 
-def decode_work_items(ts, seq_len: int, block: int):
+def step_read_sizes(seq_len: int, width: int, dtype, *, n_head: int,
+                    n_kv_head: int, backend=None):
+    """``(block, tail)`` the grouped kernel reads a one-row step's
+    unquantized sequence leaves ``[S, seq_len, width]`` of ``dtype`` in
+    (a slot's blocks before its last whole, its last in classes of
+    ``tail`` rows: :func:`kv_positions_read`), or None where that step
+    is not the kernel's: the backend (``jax.default_backend()`` unsaid)
+    no TPU, no grouping, a head's lanes no whole lane tiles, a dtype
+    other than bf16 and fp32, a rung its block does not divide."""
+    import jax
+    import jax.numpy as jnp
+
+    block = min(int(seq_len), _GROUPED_BLOCK)
+    tail = block // _GROUPED_CLASSES
+    if ((backend or jax.default_backend()) != "tpu"
+            or not 0 < n_kv_head < n_head or n_head % n_kv_head
+            or width % n_kv_head or (width // n_kv_head) % _HEAD_LANES
+            or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32)
+            or seq_len % block or block % _GROUPED_CLASSES or tail % 16):
+        return None
+    return block, tail
+
+
+def step_positions_read(ts, seq_len: int, **leaves):
+    """Positions a one-row step at ``ts >= 0`` reads of a slot's sequence
+    leaves (``leaves``: what :func:`step_read_sizes` takes after the
+    rung): the grouped kernel's rounding where it serves them, else the
+    whole rung (an XLA form).  What a builder of grouped heads declares
+    as ``make_cache.kv_positions_read`` for the server's counter."""
+    sizes = step_read_sizes(seq_len, **leaves)
+    if sizes is None:
+        return np.full_like(ts, seq_len)
+    return kv_positions_read(ts, *sizes)
+
+
+def decode_work_items(ts, seq_len: int, block: int, tail=None):
     """Flatten the step's live ``(slot, block)`` pairs, slot-major.
 
     ``ts`` [S] int32 (``< 0`` = idle).  Returns ``(n_items [1], slot
@@ -182,7 +270,7 @@ def decode_work_items(ts, seq_len: int, block: int):
     ``0..ts // block`` — at least the one its new row lands in — and
     ``rows`` is how many of a block's rows the item reads: the whole
     block, or for the slot's last block what is left of
-    :func:`kv_positions_read`."""
+    :func:`kv_positions_read` (in classes of ``tail`` rows, as there)."""
     import jax.numpy as jnp
 
     S = ts.shape[0]
@@ -192,8 +280,8 @@ def decode_work_items(ts, seq_len: int, block: int):
     slot = jnp.repeat(jnp.arange(S, dtype=jnp.int32), nblk,
                       total_repeat_length=max_items)
     blk = jnp.arange(max_items, dtype=jnp.int32) - (ends - nblk)[slot]
-    rows = jnp.minimum(kv_positions_read(ts[slot], block) - blk * block,
-                       block)
+    rows = jnp.minimum(
+        kv_positions_read(ts[slot], block, tail) - blk * block, block)
     return (ends[-1:].astype(jnp.int32), slot, blk.astype(jnp.int32),
             rows.astype(jnp.int32))
 
@@ -228,6 +316,21 @@ def _dot3(x, w):
                for t in _split3(x))
 
 
+def _by_rows(rows, classes, body):
+    """``body(c)`` for the class ``c`` of ``classes`` (static, rising)
+    that ``rows`` (traced) equals, found by halving (a ``lax.switch``
+    lowers to a cascade that costs every item a branch a class)."""
+    import jax
+
+    def pick(cs):
+        if len(cs) == 1:
+            return functools.partial(body, cs[0])
+        lo, hi = cs[:len(cs) // 2], cs[len(cs) // 2:]
+        return lambda: jax.lax.cond(rows >= hi[0], pick(hi), pick(lo))
+
+    pick(classes)()
+
+
 def _kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
             ts_ref,                                             # SMEM
             q_ref, kn_ref, vn_ref, e_ref, et_ref,               # VMEM
@@ -247,18 +350,7 @@ def _kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
     classes = list(range(tail, block + 1, tail))
 
     def by_rows(i, body):
-        """``body(rows)`` for the class item ``i`` is of, found by
-        halving (a ``lax.switch`` lowers to a cascade that costs every
-        item a branch a class)."""
-        rows = item_rows_ref[i]
-
-        def pick(cs):
-            if len(cs) == 1:
-                return functools.partial(body, cs[0])
-            lo, hi = cs[:len(cs) // 2], cs[len(cs) // 2:]
-            return lambda: jax.lax.cond(rows >= hi[0], pick(hi), pick(lo))
-
-        pick(classes)()
+        _by_rows(item_rows_ref[i], classes, body)
 
     def read(i, buf, rows):
         n, b = item_slot_ref[i], item_blk_ref[i]
@@ -1027,6 +1119,203 @@ def _block_sparse(q, k_cache, v_cache, ts, blocks, valid, *, n_head,
       tile_pos, k_cache, v_cache)
 
 
+def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
+                    ts_ref,                                     # SMEM
+                    q_ref,                                      # VMEM
+                    k_hbm, v_hbm,                               # HBM (ANY)
+                    o_ref,                                      # output
+                    kbuf, vbuf, m_ref, l_ref, acc_ref, sem,
+                    *, block, tail, heads):
+    """The work list's items one after another, the reads of the next
+    ``ahead`` in flight: an item is BOTH leaves' whole-width ``[rows,
+    n_kv_head * Dh]`` slabs, one copy each, scored a UNIT of ``heads``
+    K/V heads at a time from the unit's lanes of the slabs
+    (:func:`_block_part`; the online softmax's state in VMEM, begun at a
+    slot's block 0 and written out at its last).
+
+    ``q_ref`` ``[S, units, R, L]``: a unit's query rows against its ``L =
+    heads * Dh`` lanes — head ``h`` of the unit in rows ``h * rep_p ..``,
+    its own ``Dh`` lanes filled and the unit's other lanes zero, so one
+    product scores the unit's heads and one more weighs their rows (the
+    zeros add exact zeros; a head keeps its own lanes of the result).
+
+    Written once: ONE loop over the turns (turn ``j`` starts item
+    ``j``'s reads and scores item ``j - ahead``, so the turns before
+    item 0 are the prologue), one class switch for the starts and one
+    for the waits (a DMA's length is static), ONE scoring routine over
+    the whole block whatever the item's rows (rows past them are masked
+    by position; the V buffers are zeroed once so that no stale row
+    weighs in as ``0 * nan``)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    _, units, R, L = q_ref.shape
+    rep_p, D = o_ref.shape[2:]
+    nbuf = kbuf.shape[0]
+    ahead = nbuf - 1
+    n_items = n_items_ref[0]
+    classes = list(range(tail, block + 1, tail))
+    o_ref[...] = jnp.zeros_like(o_ref)      # idle slots: zero context
+    vbuf[...] = jnp.zeros_like(vbuf)
+
+    def reads(i, rows):
+        n, b, buf = item_slot_ref[i], item_blk_ref[i], jax.lax.rem(i, nbuf)
+        src = pl.ds(pl.multiple_of(b * block, block), rows)
+        dst = pl.ds(0, rows)
+        return (pltpu.make_async_copy(k_hbm.at[n, src], kbuf.at[buf, dst],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[n, src], vbuf.at[buf, dst],
+                                      sem.at[1, buf]))
+
+    def start(i, rows):
+        for c in reads(i, rows):
+            c.start()
+
+    def wait(i, rows):  # takes the descriptor the read was started with
+        for c in reads(i, rows):
+            c.wait()
+
+    def score(i):
+        n, b, buf = item_slot_ref[i], item_blk_ref[i], jax.lax.rem(i, nbuf)
+        t = ts_ref[n]
+
+        @pl.when(b == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, _MASK)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        ok = b * block + jax.lax.broadcasted_iota(
+            jnp.int32, (R, block), 1) <= t
+
+        def unit(u, carry):
+            lanes = slice(None) if units == 1 else pl.ds(
+                pl.multiple_of(u * L, L), L)
+            m, l, acc = _block_part(
+                q_ref[n, u], kbuf[buf, :, lanes], vbuf[buf, :, lanes], ok,
+                m_ref[u, :, :1], l_ref[u, :, :1], acc_ref[u])
+            m_ref[u] = jnp.broadcast_to(m, (R, _HEAD_LANES))
+            l_ref[u] = jnp.broadcast_to(l, (R, _HEAD_LANES))
+            acc_ref[u] = acc
+
+            @pl.when(b == t // block)
+            def _():
+                for h in range(heads):  # a head's own rows and lanes
+                    rows = slice(h * rep_p, (h + 1) * rep_p)
+                    o_ref[n, u * heads + h] = (
+                        acc[rows, h * D:(h + 1) * D] / l[rows])
+
+            return carry
+
+        if units == 1:
+            unit(0, 0)
+        else:
+            jax.lax.fori_loop(0, units, unit, 0)
+
+    def turn(j, carry):
+        @pl.when(j < n_items)
+        def _():
+            _by_rows(item_rows_ref[j], classes, functools.partial(start, j))
+
+        i = j - ahead
+
+        @pl.when(i >= 0)
+        def _():
+            _by_rows(item_rows_ref[i], classes, functools.partial(wait, i))
+            score(i)
+
+        return carry
+
+    jax.lax.fori_loop(0, n_items + ahead, turn, 0)
+
+
+def grouped_decode_attention(q, k_cache, v_cache, ts, work, *, n_head: int,
+                             n_kv_head: int, scale: float, block: int,
+                             tail: int, interpret=False):
+    """The Pallas TPU kernel of read-what-is-live for grouped heads over
+    unquantized sequence leaves (see the module docstring).
+
+    ``q`` ``[S, n_head * Dh]`` fp32; ``k_cache``, ``v_cache`` ``[S, T,
+    n_kv_head * Dh]`` bf16 or fp32, handed over AS THEY LIE and read only
+    (:func:`append_rows` has written the step's rows); ``ts`` ``[S]``;
+    ``work`` from :func:`decode_work_items` for the same ``ts``,
+    ``block`` and ``tail`` (:func:`step_read_sizes`).  Returns ctx ``[S,
+    n_head * Dh]`` fp32, zero for an idle slot.  ``q`` is scaled, then
+    rounded to the leaves' dtype; the weights are rounded to it before
+    they meet V (un-normalised, the fp32 sum of the unrounded ones
+    divides at the end); every sum is fp32.  One jitted entry point for
+    every call site (:func:`_kernel_call` says why)."""
+    return _grouped_call()(
+        work, ts, q, k_cache, v_cache, n_head=n_head, n_kv_head=n_kv_head,
+        scale=float(scale), block=block, tail=tail, heads=_GROUPED_HEADS,
+        ahead=_GROUPED_AHEAD, interpret=interpret)
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_call():
+    import jax
+
+    return jax.jit(_grouped, static_argnames=(
+        "n_head", "n_kv_head", "scale", "block", "tail", "heads", "ahead",
+        "interpret"))
+
+
+def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
+             block, tail, heads, ahead, interpret):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, T, Dkv = k_cache.shape
+    G, D, rep = n_kv_head, Dkv // n_kv_head, n_head // n_kv_head
+    dt, f32 = k_cache.dtype, jnp.float32
+    size = jnp.dtype(dt).itemsize
+    heads = heads if heads and G % heads == 0 else G    # K/V heads a unit
+    units, L = G // heads, heads * D
+    rep_p = -(-rep // 8) * 8            # a head's rows: whole fp32 tiles
+    sub = 32 // size                    # rows of the leaves' sublane tile
+    R = -(-heads * rep_p // sub) * sub
+    qg = jnp.pad((q * scale).astype(dt).reshape(S, units, heads, rep, D),
+                 ((0, 0),) * 3 + ((0, rep_p - rep), (0, 0)))
+    if heads > 1:   # head h of a unit: its own D lanes of the unit's L
+        own = np.eye(heads, dtype=bool)[:, None, :, None]
+        qg = jnp.where(own, qg[:, :, :, :, None, :], jnp.zeros((), dt))
+    qg = jnp.pad(qg.reshape(S, units, heads * rep_p, L),
+                 ((0, 0), (0, 0), (0, R - heads * rep_p), (0, 0)))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    nbuf = ahead + 1
+    # bytes the kernel keeps in VMEM: q and the context whole, the slabs
+    # being read and scored, a block's scores a few times over
+    resident = (qg.size * size + 4 * S * G * rep_p * D
+                + 2 * nbuf * block * Dkv * size + 4 * 4 * R * block)
+    ctx = pl.pallas_call(
+        functools.partial(_grouped_kernel, block=block, tail=tail,
+                          heads=heads),
+        out_shape=jax.ShapeDtypeStruct((S, G, rep_p, D), f32),
+        in_specs=[smem] * 5 + [vmem] + [hbm] * 2,
+        out_specs=vmem,
+        scratch_shapes=[
+            pltpu.VMEM((nbuf, block, Dkv), dt),
+            pltpu.VMEM((nbuf, block, Dkv), dt),
+            pltpu.VMEM((units, R, _HEAD_LANES), f32),   # a unit's max,
+            pltpu.VMEM((units, R, _HEAD_LANES), f32),   # sum
+            pltpu.VMEM((units, R, L), f32),             # and weighted rows
+            pltpu.SemaphoreType.DMA((2, nbuf)),
+        ],
+        compiler_params=(pltpu.CompilerParams(
+            vmem_limit_bytes=min(100 << 20, 2 * resident))
+            if resident > _VMEM_DEFAULT * 3 // 4 else None),
+        name="grouped_decode_attention",
+        interpret=interpret,
+    )(*work, ts.astype(jnp.int32), qg, k_cache, v_cache)
+    return ctx[:, :, :rep].reshape(S, n_head * D)
+
+
 def _gathered_block_attention(q, kv, ts, blocks, valid, *, n_head, n_kv_head,
                               scale, block):
     """The XLA form of the same read: a block gather, then the grouped
@@ -1147,13 +1436,18 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
     layers hold sequence leaves AND ring leaves makes one ``attend`` for
     each).  ``window``: the leaves are RING leaves of that window (what
     the builder allocated them as), read by the XLA form.  The one place
-    that chooses: the kernel when it
-    exists for what the step is — the default backend a TPU, fp32 leaves
-    of a shape it lowers for, one query head per K/V head, one fresh row
-    per slot — and an XLA form otherwise: on a TPU, for grouped heads
-    narrower than a lane tile over unquantized leaves, the one that reads
-    the leaves as they lie (:func:`lane_masked_decode_attention`), else
-    :func:`grouped_masked_decode_attention`."""
+    that chooses, from what it can observe: a kernel when one exists for
+    what the step is — the default backend a TPU, one fresh row per
+    slot, unquantized leaves, and either one query head per K/V head
+    over fp32 leaves of a shape :func:`ragged_decode_attention` lowers
+    for, or grouped heads that are whole lane tiles over bf16 / fp32
+    leaves (:func:`step_read_sizes`: :func:`grouped_decode_attention`)
+    — and an XLA form otherwise: on a TPU, for grouped heads narrower
+    than a lane tile over unquantized leaves, the one that reads the
+    leaves as they lie (:func:`lane_masked_decode_attention`), else
+    :func:`grouped_masked_decode_attention` (``K > 1`` fresh rows, int8
+    leaves, every CPU run).  A grouped-head step over sequence leaves
+    counts itself in ``decode_attention_grouped_lowered_total{path}``."""
     import jax
     import jax.numpy as jnp
 
@@ -1162,18 +1456,35 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
                             n_head=n_head, n_kv_head=n_kv_head, scale=scale)
     if window is not None:
         return functools.partial(xla, window=int(window))
-    if (jax.default_backend() == "tpu" and "k_scale" not in kv
-            and n_kv_head < n_head and (width // n_kv_head) % _HEAD_LANES):
-        # grouped heads narrower than a lane tile: a view of the leaf by
-        # heads would be a copy of the rung (lane_masked_decode_attention)
-        lanes = functools.partial(
-            lane_masked_decode_attention, ts=ts, n_head=n_head,
-            n_kv_head=n_kv_head, scale=scale)
-        return lambda q, k_new, v_new, kv: (
-            lanes(q, k_new, v_new, kv) if q.ndim == 2
-            else xla(q, k_new, v_new, kv))
-    if not (jax.default_backend() == "tpu"
-            and kv["k"].dtype == jnp.float32 and n_kv_head == n_head
+    tpu = jax.default_backend() == "tpu" and "k_scale" not in kv
+    if n_kv_head < n_head:
+        sizes = step_read_sizes(
+            seq_len, width, kv["k"].dtype, n_head=n_head,
+            n_kv_head=n_kv_head) if tpu else None
+        one_row = xla
+        if sizes is not None:
+            work = decode_work_items(ts, seq_len, *sizes)
+
+            def one_row(q, k_new, v_new, kv):
+                kv = append_rows(kv, k_new, v_new, ts)
+                return grouped_decode_attention(
+                    q, kv["k"], kv["v"], ts, work, n_head=n_head,
+                    n_kv_head=n_kv_head, scale=scale, block=sizes[0],
+                    tail=sizes[1]), kv
+        elif tpu and (width // n_kv_head) % _HEAD_LANES:
+            # narrower than a lane tile: a view of the leaf by heads
+            # would be a copy of the rung (lane_masked_decode_attention)
+            one_row = functools.partial(
+                lane_masked_decode_attention, ts=ts, n_head=n_head,
+                n_kv_head=n_kv_head, scale=scale)
+
+        def attend(q, k_new, v_new, kv):
+            kernel = sizes is not None and q.ndim == 2
+            GROUPED_LOWERED.labels(path="kernel" if kernel else "xla").inc()
+            return (one_row if q.ndim == 2 else xla)(q, k_new, v_new, kv)
+
+        return attend
+    if not (tpu and kv["k"].dtype == jnp.float32 and n_kv_head == n_head
             and kernel_supported(seq_len, width, n_head)):
         return xla
     block = kv_read_block(seq_len)

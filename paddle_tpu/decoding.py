@@ -54,6 +54,7 @@ of the same parts:
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional, Tuple
 
 import numpy as np
@@ -595,7 +596,8 @@ def make_hybrid_ssm_lm_pooled_step_fn(state, cfg, name: str = "lm",
     import jax.numpy as jnp
 
     from paddle_tpu import hybrid_ssm as hs
-    from paddle_tpu.decode_attention import kv_leaves, make_decode_attention
+    from paddle_tpu.decode_attention import (kv_leaves, make_decode_attention,
+                                             step_positions_read)
 
     d = hs.dims(cfg)
     kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
@@ -617,6 +619,9 @@ def make_hybrid_ssm_lm_pooled_step_fn(state, cfg, name: str = "lm",
 
     make_cache.leaf_seq_axes = [
         {"k": 1, "v": 1, "ssm": -1, "conv": -1} for _ in range(d.n_layer)]
+    make_cache.kv_positions_read = functools.partial(
+        step_positions_read, width=d.d_kv, dtype=kv, n_head=d.n_head,
+        n_kv_head=d.n_kv_head)
 
     def step_fn(cache, tokens, ts):
         n = tokens.shape[0]
@@ -1061,7 +1066,8 @@ def make_windowed_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
     from paddle_tpu import routed_experts as rx
     from paddle_tpu import windowed_routed_lm as wr
     from paddle_tpu.decode_attention import (kv_leaves, make_decode_attention,
-                                             ring_positions)
+                                             ring_positions,
+                                             step_positions_read)
 
     d = wr.dims(cfg)
     kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
@@ -1093,6 +1099,9 @@ def make_windowed_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
     make_cache.n_expert = d.n_expert
     make_cache.window_layers = len(window_at)
     make_cache.window_positions_read = lambda n: np.minimum(n, d.window)
+    make_cache.kv_positions_read = functools.partial(
+        step_positions_read, width=d.d_kv, dtype=kv, n_head=d.n_head,
+        n_kv_head=d.n_kv_head)
 
     def close_layer(h, r, o, p, ts):
         """The out-projection and the expert layer (routed by the

@@ -133,10 +133,13 @@ DECODE_TICKS = monitor.counter(
 DECODE_KV_READ = monitor.counter(
     "serving_decode_kv_positions_read_total",
     "KV-cache positions the decode steps read: per step and active "
-    "slot its live positions rounded up as the ragged kernel rounds "
-    "them (decode_attention.kv_positions_read: the slot's last block "
-    "in classes of KV_TAIL rows); the whole rung for an int8 pool and "
-    "for a speculative round, whose reads are masked, not ragged",
+    "slot its live positions rounded up as the kernel that serves the "
+    "step rounds them (decode_attention.kv_positions_read: the slot's "
+    "last block in classes of KV_TAIL rows for the ragged kernel, of "
+    "the grouped kernel's tail where the builder declares "
+    "make_cache.kv_positions_read); the whole rung for a step an XLA "
+    "form serves (an int8 pool, grouped heads off the TPU) and for a "
+    "speculative round, whose reads are masked, not ragged",
     _LABELS)
 DECODE_KV_LIVE = monitor.counter(
     "serving_decode_kv_positions_live_total",
@@ -490,6 +493,10 @@ class DecodeServer:
         self._window_read_c = DECODE_WINDOW_READ.labels(**lbl)
         self._window_live_c = DECODE_WINDOW_LIVE.labels(**lbl)
         self._window_rule = getattr(make_cache, "window_positions_read", None)
+        # what a one-row step at ts reads of a slot's sequence leaves
+        # on a rung, where the builder knows (grouped heads: a kernel's
+        # rounding or the whole rung, by what serves them)
+        self._kv_rule = getattr(make_cache, "kv_positions_read", None)
         self._window_layers = int(getattr(make_cache, "window_layers", 0))
         self._kv_held_g = DECODE_KV_BYTES_HELD.labels(**lbl)
         self._kv_one_length_g = DECODE_KV_BYTES_ONE_LENGTH.labels(**lbl)
@@ -1310,8 +1317,12 @@ class DecodeServer:
         # the steps this chunk ran, row by row: ts = p0 .. p1 - 1
         ts = p0[:, None] + np.arange(steps)[None, :]
         ran = ts < p1[:, None]
-        if use_spec or self._pool.kv_dtype != "fp32":
+        if use_spec:
             read = pool  # masked reads over the whole rung
+        elif self._kv_rule is not None:
+            read = int((self._kv_rule(ts, t) * ran).sum())
+        elif self._pool.kv_dtype != "fp32":
+            read = pool
         else:
             read = int((kv_positions_read(ts, kv_read_block(t)) * ran).sum())
         if self._sparse_rule is not None and self._sparse_layers:

@@ -598,37 +598,232 @@ def test_lane_masked_form_equals_the_grouped_form(dtype):
     assert not np.asarray(got)[2].any()
 
 
+def _grouped_counts():
+    return {path: da.GROUPED_LOWERED.labels(path=path).value
+            for path in ("kernel", "xla")}
+
+
 def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
         monkeypatch):
-    """On a TPU, grouped heads of 64 over bf16 leaves read the leaves as
-    they lie; heads of 128 (falcon_h1), one query head a K/V head
-    (gpt1), int8 leaves, K fresh rows and every CPU run keep the grouped
-    form."""
+    """On a TPU a one-row step of grouped heads over unquantized
+    sequence leaves takes the grouped kernel where the heads are whole
+    lane tiles (falcon_h1, smallthinker's global layers: bf16 and fp32)
+    and reads the leaves as they lie where they are 64 wide (lfm2); one
+    query head a K/V head (gpt1), int8 leaves, a rung the kernel's block
+    does not divide, K fresh rows, ring leaves and every CPU run keep
+    the grouped XLA form.  Every grouped-head step over sequence leaves
+    counts itself by the path it took; a ring step never does."""
     import jax
     import jax.numpy as jnp
 
     ts = jnp.zeros((2,), jnp.int32)
-    narrow = da.kv_leaves(2, 16, 2, 64, jnp.bfloat16)
-    wide = da.kv_leaves(2, 16, 2, 128, jnp.bfloat16)
-    name = lambda f: getattr(f, "func", f).__name__
-    cpu = da.make_decode_attention(ts, narrow, n_head=8, n_kv_head=2,
-                                   scale=1.0)
-    assert name(cpu) == "grouped_masked_decode_attention"
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    for kv, heads in ((wide, (8, 2)), (narrow, (2, 2)),
-                      (da.kv_leaves(2, 16, 2, 64, jnp.int8), (8, 2))):
-        f = da.make_decode_attention(ts, kv, n_head=heads[0],
-                                     n_kv_head=heads[1], scale=1.0)
-        assert name(f) == "grouped_masked_decode_attention"
+    narrow = da.kv_leaves(2, 128, 2, 64, jnp.bfloat16)
+    wide = da.kv_leaves(2, 128, 2, 128, jnp.bfloat16)
     seen = []
+    kernel = da.grouped_decode_attention
     monkeypatch.setattr(da, "lane_masked_decode_attention",
                         lambda q, *a, **k: seen.append("lane") or (q, a[2]))
     monkeypatch.setattr(da, "grouped_masked_decode_attention",
                         lambda q, *a, **k: seen.append("grouped") or (q, a[2]))
-    f = da.make_decode_attention(ts, narrow, n_head=8, n_kv_head=2, scale=1.0)
-    f(jnp.zeros((2, 512)), None, None, narrow)
-    f(jnp.zeros((2, 3, 512)), None, None, narrow)   # K fresh rows
-    assert seen == ["lane", "grouped"]
+    monkeypatch.setattr(
+        da, "grouped_decode_attention",
+        lambda *a, **k: seen.append("kernel") or kernel(
+            *a, interpret=True, **k))
+
+    def took(kv, heads, rows=None, **kw):
+        """What a step over ``kv`` called, and what it counted."""
+        before, n = _grouped_counts(), len(seen)
+        f = da.make_decode_attention(ts, kv, n_head=heads[0],
+                                     n_kv_head=heads[1], scale=1.0, **kw)
+        width = kv["k"].shape[-1]
+        q = jnp.zeros((2,) + (() if rows is None else (rows,))
+                      + (width * heads[0] // heads[1],))
+        new = jnp.zeros(q.shape[:-1] + (width,))
+        f(q, new, new, kv)
+        after = _grouped_counts()
+        return seen[n:], {p: after[p] - before[p] for p in after}
+
+    assert took(narrow, (8, 2)) == (["grouped"], {"kernel": 0, "xla": 1})
+    assert took(wide, (8, 2)) == (["grouped"], {"kernel": 0, "xla": 1})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for kv in (wide, da.kv_leaves(2, 128, 2, 128, jnp.float32),
+               da.kv_leaves(2, 2 * da._GROUPED_BLOCK, 2, 128, jnp.bfloat16)):
+        assert took(kv, (8, 2)) == (["kernel"], {"kernel": 1, "xla": 0})
+        assert took(kv, (10, 2)) == (["kernel"], {"kernel": 1, "xla": 0})
+    assert took(narrow, (8, 2)) == (["lane"], {"kernel": 0, "xla": 1})
+    for kv, heads, kw in (
+            (wide, (8, 2), {"rows": 3}),            # K fresh rows
+            (narrow, (8, 2), {"rows": 3}),
+            (da.kv_leaves(2, 128, 2, 128, jnp.int8), (8, 2), {}),
+            (da.kv_leaves(2, da._GROUPED_BLOCK + 128, 2, 128, jnp.bfloat16),
+             (8, 2), {})):                          # no whole blocks
+        assert took(kv, heads, **kw) == (["grouped"],
+                                         {"kernel": 0, "xla": 1})
+    # not grouped, or a ring: no count, and never the kernel
+    nothing = {"kernel": 0, "xla": 0}
+    assert took(narrow, (2, 2)) == (["grouped"], nothing)
+    assert took(wide, (2, 2)) == (["grouped"], nothing)
+    ring = da.kv_leaves(2, 128, 2, 128, jnp.bfloat16, window=64)
+    assert took(ring, (8, 2), window=64) == (["grouped"], nothing)
+    assert took(ring, (8, 2), rows=3, window=64)[1] == nothing
+
+
+# ---------------------------------------------------------------------------
+# grouped heads that are whole lane tiles: the kernel that reads what is
+# live, under interpret mode at toy rungs (two blocks of 128, a slot's
+# last block in classes of 16 rows)
+# ---------------------------------------------------------------------------
+GB, GT = 128, 16        # the toy block and tail
+GROUPED_TS = {
+    # idle, 0, both sides of the block's edge and of a class's, T - 1
+    "edges": [-1, 0, GB - 1, GB, GB + 1, 2 * GB - 1, GT - 1, GT, GT + 1],
+    "all_idle": [-1] * 4,
+    "one_live": [-1, GB + 40, -1, -1],
+}
+
+
+def _grouped_case(rep, dtype, ts, g=4, dh=128):
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(5)
+    s, t = len(ts), 2 * GB
+    q = jnp.asarray(rng.randn(s, g * rep * dh), jnp.float32)
+    kn, vn = (jnp.asarray(rng.randn(s, g * dh), jnp.float32)
+              for _ in range(2))
+    kv = {n: jnp.asarray(rng.randn(s, t, g * dh), dtype) for n in "kv"}
+    return q, kn, vn, kv, jnp.asarray(ts, jnp.int32), dict(
+        n_head=g * rep, n_kv_head=g, scale=dh ** -0.5)
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED_TS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rep", [5, 7, 8])
+def test_grouped_kernel_matches_the_grouped_form(rep, dtype, case):
+    """The append and the kernel's read against the XLA form: the same
+    context (fp32: the order of the sums differs; bf16: the weights are
+    rounded before they are normalised, not after), bit-equal leaves,
+    zero rows for idle slots."""
+    q, kn, vn, kv, ts, kw = _grouped_case(rep, dtype, GROUPED_TS[case])
+    want, kv_want = da.grouped_masked_decode_attention(q, kn, vn, kv, ts,
+                                                       **kw)
+    kv_got = da.append_rows(kv, kn, vn, ts)
+    got = da.grouped_decode_attention(
+        q, kv_got["k"], kv_got["v"], ts,
+        da.decode_work_items(ts, 2 * GB, GB, GT), block=GB, tail=GT,
+        interpret=True, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=2e-6 if dtype == "float32" else 2e-2)
+    for n in "kv":
+        assert np.array_equal(np.asarray(kv_got[n].astype("float32")),
+                              np.asarray(kv_want[n].astype("float32")))
+    assert not np.asarray(got)[np.asarray(ts) < 0].any()
+
+
+@pytest.mark.parametrize("heads", [1, 2])
+def test_grouped_kernel_scores_any_number_of_heads_in_one_product(
+        heads, monkeypatch):
+    """The K/V heads a unit holds are a parameter of the q layout alone
+    (a head's lane offset and width): one, two or all four heads a
+    product give the same context."""
+    q, kn, vn, kv, ts, kw = _grouped_case(7, "bfloat16",
+                                          GROUPED_TS["edges"])
+    kv = da.append_rows(kv, kn, vn, ts)
+    args = (q, kv["k"], kv["v"], ts, da.decode_work_items(ts, 2 * GB, GB, GT))
+    named = dict(block=GB, tail=GT, interpret=True, **kw)
+    want = da.grouped_decode_attention(*args, **named)
+    monkeypatch.setattr(da, "_GROUPED_HEADS", heads)
+    got = da.grouped_decode_attention(*args, **named)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("seq_len", [1024, 16384, 512])
+def test_grouped_work_list_reads_what_kv_positions_read_says(seq_len):
+    """The one rounding: with the grouped kernel's sizes a slot's items
+    add up to ``kv_positions_read`` (whole blocks, then the last in
+    classes of the tail), which is also what a builder's
+    ``make_cache.kv_positions_read`` hands the server's counter; off the
+    TPU that rule says the whole rung."""
+    import jax.numpy as jnp
+
+    leaves = dict(width=512, dtype="bfloat16", n_head=28, n_kv_head=4)
+    assert da.step_read_sizes(seq_len, **leaves) is None        # the CPU
+    block, tail = da.step_read_sizes(seq_len, backend="tpu", **leaves)
+    assert seq_len % block == 0 and block % tail == 0 and tail % 16 == 0
+    ts = np.asarray([-1, 0, tail - 1, tail, block - 1, block,
+                     seq_len - 1, seq_len // 2 + 3], np.int32)
+    n, slot, _, rows = (np.asarray(x) for x in da.decode_work_items(
+        jnp.asarray(ts), seq_len, block, tail))
+    read = np.bincount(slot[:n[0]], weights=rows[:n[0]],
+                       minlength=len(ts)).astype(int)
+    want = np.where(ts >= 0, da.kv_positions_read(ts, block, tail), 0)
+    assert read.tolist() == want.tolist()
+    assert np.all(want[1:] >= ts[1:] + 1) and np.all(want[1:] - ts[1:] <= tail)
+    assert np.all(rows[:n[0]] % tail == 0) and rows[:n[0]].max() <= block
+    live = ts[1:]
+    assert da.step_positions_read(live, seq_len, backend="tpu",
+                                  **leaves).tolist() == want[1:].tolist()
+    assert da.step_positions_read(live, seq_len, **leaves).tolist() == [
+        seq_len] * len(live)
+    # no kernel for narrow heads, no grouping, int8, a ragged rung
+    for change in (dict(width=256), dict(n_head=4), dict(dtype="int8")):
+        assert da.step_read_sizes(seq_len, backend="tpu",
+                                  **{**leaves, **change}) is None
+    assert da.step_read_sizes(da._GROUPED_BLOCK + 128, backend="tpu",
+                              **leaves) is None
+
+
+def _two_grouped_layers(sharding=None):
+    """``(f, abstract arguments, equations)``: a step's two global layers
+    at ``smallthinker_21b_a3b``'s shapes (40 slots x 16,384 x 512 bf16, 7
+    query heads a K/V head) through the kernel, as
+    ``tools/time_grouped_decode.py --build`` builds them."""
+    import importlib.util
+    import os
+    import sys
+
+    tools = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools")
+    sys.path.insert(0, tools)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "time_grouped_decode", os.path.join(tools,
+                                                "time_grouped_decode.py"))
+        tool = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(tool)
+    finally:
+        sys.path.remove(tools)
+    f, args = tool.two_layer_program(da, tool.SHAPES["smallthinker"],
+                                     "bfloat16", False, sharding)
+    return f, args, tool.equations
+
+
+#: equations of the grouped kernel's body at the cell's shapes: 256 as
+#: written (PR 44: 0.88 s to trace and 0.30 s to lower two layers in a
+#: fresh process on the chip), half as many again allowed (what a process
+#: pays to trace and lower a kernel grows with its body:
+#: _BLOCK_KERNEL_EQUATIONS_MAX)
+_GROUPED_KERNEL_EQUATIONS_MAX = 384
+
+
+def test_grouped_kernel_is_traced_once_and_its_body_stays_small():
+    """A step's two global layers share ONE traced function whose body
+    holds one loop over the items, a class switch for the starts and one
+    for the waits, and ONE scoring routine."""
+    import jax
+
+    f, args, equations = _two_grouped_layers()
+    calls = [e for e in jax.make_jaxpr(f)(*args).jaxpr.eqns
+             if "jaxpr" in e.params
+             and e.params.get("name") == "_grouped"]
+    assert len(calls) == 2      # one a layer ...
+    # ... of one function traced once
+    assert calls[0].params["jaxpr"] is calls[1].params["jaxpr"]
+    kernels = [e for e in calls[0].params["jaxpr"].jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 1
+    body = equations(kernels[0].params["jaxpr"])
+    assert 60 < body <= _GROUPED_KERNEL_EQUATIONS_MAX, body
 
 
 # ---------------------------------------------------------------------------
@@ -715,6 +910,31 @@ def test_block_kernel_compiles_for_v5e_at_minicpm_sala_widths(one_chip):
     assert "block_sparse_decode_attention" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < (
         s * t * g * d * 2) // 8
+
+
+def test_grouped_kernel_compiles_for_v5e_at_smallthinker_widths(one_chip):
+    """40 slots x 16,384 positions x 4 K/V heads of 128, 7 query heads a
+    K/V head, bf16: two layers' append-and-read lower to ONE kernel
+    called twice, both layers' leaves are appended in place and handed
+    to the kernel as they lie (the benchmark's shape needle finds the
+    call; no temporary of a leaf's size)."""
+    import re
+
+    import jax
+
+    f, args, _ = _two_grouped_layers(one_chip)
+    lowered = jax.jit(f, donate_argnums=(3, 4, 5, 6)).trace(*args).lower()
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(r"call @_grouped\b", text)) == 2
+    compiled = lowered.compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "grouped_decode_attention" in line and "custom-call(" in line]
+    assert len(calls) == 2 and all("bf16[40,16384,512]" in c for c in calls)
+    leaf = 40 * 16384 * 512 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * leaf
+    assert mem.temp_size_in_bytes < leaf // 8
 
 
 def _two_sparse_layers(sharding=None):

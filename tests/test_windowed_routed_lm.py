@@ -473,6 +473,38 @@ def test_decode_server_end_to_end_with_snapshots_and_the_window_counters():
         srv.stop(drain=False, timeout=30.0)
 
 
+def test_the_server_counts_kv_reads_by_the_builders_rule():
+    """The builder of grouped heads declares what a one-row step reads
+    of a slot's sequence leaves (``make_cache.kv_positions_read``: the
+    grouped kernel's rounding where it serves them, the whole rung off
+    the TPU), and the server's read counter follows that rule, whatever
+    the pool's dtype."""
+    cfg = rehearse_cfg()
+    step, make_cache, _ = decoding.make_windowed_routed_lm_pooled_step_fn(
+        weights(cfg, seed=7), cfg, kv_dtype="fp32", prefill_tokens=CHUNK)
+    assert make_cache.kv_positions_read(np.asarray([0, 40]), 64).tolist() \
+        == [64, 64]
+    rungs = []
+    make_cache.kv_positions_read = lambda ts, t: (
+        rungs.append(t) or (ts // 8 + 1) * 8)
+    name = "windowed-kv-rule"
+    srv = DecodeServer(step, make_cache, eos_id=V, max_seq_len=64,
+                       max_slots=2, slot_ladder=(2,), len_ladder=(64,),
+                       steps_per_tick=4, kv_dtype="fp32", name=name)
+    try:
+        srv.warmup()
+        prompt = np.random.RandomState(3).randint(0, V, 5).astype(np.int32)
+        srv.submit({"tokens": prompt}, max_new_tokens=9).result(120)
+        m = srv.metrics()["decode"]
+        live = monitor.counter_value(
+            "serving_decode_kv_positions_live_total", server=name)
+        assert set(rungs) == {64}
+        assert 0 < live <= m["kv_positions_read"] < m["kv_positions_pool"]
+        assert m["kv_positions_read"] % 8 == 0
+    finally:
+        srv.stop(drain=False, timeout=30.0)
+
+
 def test_a_traced_turn_says_what_its_window_layers_read():
     """``window_rows`` on the ``deliver`` span: the positions the chunk's
     window layers read, summing to the counter."""

@@ -257,10 +257,12 @@ def main():
     text = canonical(compiled.as_text())
     with open(args.out, "w") as fh:
         fh.write(text)
+    kernels = [name for name in (
+        "ragged_decode_attention", "grouped_decode_attention",
+        "block_sparse_decode_attention", "grouped_matmul") if name in text]
     print("%s: %d bytes, %s, %d whole-matrix casts to bf16" % (
-        args.out, len(text), "ragged_decode_attention kernel"
-        if "ragged_decode_attention" in text else "grouped_matmul kernel"
-        if "grouped_matmul" in text else "no kernel",
+        args.out, len(text),
+        " + ".join(kernels) + " kernel" if kernels else "no kernel",
         weight_casts(lowered, text)))
 
 
