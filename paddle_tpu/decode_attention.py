@@ -113,6 +113,7 @@ __all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
            "ragged_decode_attention", "grouped_decode_attention",
            "grouped_masked_decode_attention",
            "lane_masked_decode_attention", "append_rows",
+           "fresh_prompt_attention", "write_prompt_rows",
            "grouped_block_decode_attention", "block_sparse_decode_attention",
            "block_kernel_supported", "BLOCK_SPARSE_LOWERED",
            "kernel_supported", "make_decode_attention", "ring_positions",
@@ -722,6 +723,87 @@ def lane_masked_decode_attention(q, k_new, v_new, kv, ts, *, n_head: int,
     ctx = jnp.sum(jnp.where(own[None], wide, 0.0).reshape(
         S, n_head, n_kv_head, D), axis=2)
     return jnp.where(live[:, None], ctx.reshape(S, n_head * D), 0.0), kv
+
+
+def fresh_prompt_attention(q, k_new, v_new, like, *, n_head: int,
+                           n_kv_head: int, scale: float):
+    """The read half of the contract for ``C`` fresh rows a slot that
+    START a sequence: row ``j`` of group row ``g`` is position ``j`` of
+    its slot, so nothing any leaf holds is read — row ``j`` attends to
+    the fresh rows ``<= j`` as the leaves will hold them (rounded to the
+    storage dtype; int8: quantized per row and head as :func:`_append`
+    quantizes them, dequantized at the read).  Returns ``(ctx`` shaped
+    like ``q``, fp32, ``stored)``: the rows in storage form, a dict
+    keyed like the layer's leaves (``k``, ``v`` ``[G, C, n_kv_head *
+    Dh]``; int8 also ``k_scale``, ``v_scale`` ``[G, C, n_kv_head]``),
+    for :func:`write_prompt_rows` — apart from the read, so that a
+    forward can run its layers as ONE scanned body and write afterwards.
+
+    ``q`` ``[G, C, n_head * Dh]``, ``k_new``, ``v_new`` ``[G, C,
+    n_kv_head * Dh]`` fp32; ``like``: one layer's leaves, read for
+    their dtype and for whether they carry scales, never for a value.
+    Products in the storage dtype (int8: fp32), fp32 ones as fp32
+    (``Precision.HIGHEST``: what the ragged kernel's step takes them
+    in), fp32 accumulation and softmax."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.quant import dequantize_rows, quantize_rows
+
+    G, C, _ = q.shape
+    heads = (n_kv_head, like["k"].shape[-1] // n_kv_head)
+    rep, quantized = n_head // n_kv_head, "k_scale" in like
+    dt = jnp.float32 if quantized else like["k"].dtype
+    stored = {}
+    for name, new in (("k", k_new), ("v", v_new)):
+        if quantized:
+            codes, scales = quantize_rows(new.reshape((G, C) + heads))
+            stored[name] = codes.reshape(new.shape)
+            stored[name + "_scale"] = scales
+        else:
+            stored[name] = new.astype(like[name].dtype)
+
+    def seen(name):
+        x = stored[name].reshape((G, C) + heads)
+        return (dequantize_rows(x, stored[name + "_scale"]) if quantized
+                else x)
+
+    exact = jax.lax.Precision.HIGHEST if dt == jnp.float32 else None
+    qg = (q * scale).astype(dt).reshape((G, C, n_kv_head, rep, heads[1]))
+    scores = jnp.einsum("gkhrd,gthd->gkhrt", qg, seen("k"), precision=exact,
+                        preferred_element_type=jnp.float32)
+    ok = jnp.arange(C)[None, :] <= jnp.arange(C)[:, None]       # [k, t]
+    w = jax.nn.softmax(
+        jnp.where(ok[None, :, None, None, :], scores, -1e9), axis=-1)
+    ctx = jnp.einsum("gkhrt,gthd->gkhrd", w.astype(dt), seen("v"),
+                     precision=exact, preferred_element_type=jnp.float32)
+    return ctx.reshape(q.shape), stored
+
+
+def write_prompt_rows(kv, stored, rows):
+    """The append half: one layer's leaves with ``stored``
+    (:func:`fresh_prompt_attention`'s rows in storage form, ``[G, C,
+    ...]`` a leaf) written at positions ``0 .. C - 1`` of the slots
+    ``rows`` ``[G]`` (each a slot; a caller with fewer seats than ``G``
+    repeats one, which writes the same rows twice) — ONE
+    ``dynamic_update_slice`` a leaf and group row, which touches ``C``
+    rows of the rung and not the slot's whole row of it.  ``C`` may
+    pass a prompt's end: what lands past it is
+    rewritten by the step that reaches that position before anything
+    reads it (the pool's write-before-read invariant).  Sequence leaves
+    only (a ring leaf shorter than ``C`` would wrap)."""
+    import jax
+
+    C, T = stored["k"].shape[1], kv["k"].shape[KV_SEQ_AXIS]
+    if C > T:
+        raise ValueError("write_prompt_rows: %d fresh rows a slot over "
+                         "leaves of %d positions" % (C, T))
+    out = dict(kv)
+    for name, fresh in stored.items():
+        for g in range(fresh.shape[0]):
+            out[name] = jax.lax.dynamic_update_slice(
+                out[name], fresh[g][None], (rows[g], 0, 0))
+    return out
 
 
 def append_rows(kv, k_new, v_new, ts):

@@ -48,9 +48,12 @@ of the same parts:
   a leaf whose sequence axis advances one row per several positions,
   the stride (``make_cache.leaf_seq_strides``); the pool infers nothing
   from a shape.  A builder that can feed ``C`` prompt tokens of one
-  slot in one call declares that too (``make_cache.prefill_fn``), and
-  what a pool can do follows from the declarations: chunked prefill,
-  and prefix snapshots over recurrent leaves.
+  slot in one call declares that too (``make_cache.prefill_fn``), as
+  does one that can feed several slots their whole prompts from
+  position 0 in one call (``make_cache.prefill_rows_fn``), and what a
+  pool can do follows from the declarations: a request seated and
+  prefilled in one dispatch, chunked prefill, and prefix snapshots over
+  recurrent leaves.
 """
 from __future__ import annotations
 
@@ -361,18 +364,31 @@ def _lm_forward_one(W, name, cache, x, n_layer, attend):
     the rows' keys and values to layer cache ``kv``, attends each row to
     what it may see and returns the context beside the updated layer.
     Returns ``(logits [..., V], new_cache)``."""
-    import jax
-
     new_cache = []
     for i in range(n_layer):
-        p = "%s_dec_%d" % (name, i)
-        ctx, kv = attend(_fc(W, x, p + "_att_q"), _fc(W, x, p + "_att_k"),
-                         _fc(W, x, p + "_att_v"), cache[i])
+        x, kv = _lm_block(W, "%s_dec_%d" % (name, i), cache[i], x, attend)
         new_cache.append(kv)
-        x = _ln(W, x + _fc(W, ctx, p + "_att_out"), p + "_ln1")
-        h = jax.nn.gelu(_fc(W, x, p + "_ffn_fc0"), approximate=False)
-        x = _ln(W, x + _fc(W, h, p + "_ffn_fc1"), p + "_ln2")
     return _fc(W, x, name + "_head"), new_cache
+
+
+#: the parameters of one block, by their names after the layer's prefix
+_BLOCK_PARAMS = tuple(
+    m + s for m in ("_att_q", "_att_k", "_att_v", "_att_out", "_ffn_fc0",
+                    "_ffn_fc1") for s in ("_w", "_b")) + tuple(
+    ln + s for ln in ("_ln1", "_ln2") for s in ("_scale", "_bias"))
+
+
+def _lm_block(W, p, kv, x, attend):
+    """One post-LN block of :func:`_lm_forward_one` over the parameters
+    ``W[p + ...]`` (:data:`_BLOCK_PARAMS`): returns the rows out and
+    whatever ``attend`` returned beside the context."""
+    import jax
+
+    ctx, kv = attend(_fc(W, x, p + "_att_q"), _fc(W, x, p + "_att_k"),
+                     _fc(W, x, p + "_att_v"), kv)
+    x = _ln(W, x + _fc(W, ctx, p + "_att_out"), p + "_ln1")
+    h = jax.nn.gelu(_fc(W, x, p + "_ffn_fc0"), approximate=False)
+    return _ln(W, x + _fc(W, h, p + "_ffn_fc1"), p + "_ln2"), kv
 
 
 def _fc(W, x, pname):
@@ -416,12 +432,34 @@ def _multiplied_matrices(name, n_layer):
                       "_ffn_fc0", "_ffn_fc1")] + [name + "_head_w"]
 
 
+def _stacked_layers(W, name, n_layer):
+    """``{param: [n_layer, ...]}``: each of a block's parameters
+    (:data:`_BLOCK_PARAMS`) stacked over the layers, as ``W`` holds them
+    (a bf16 copy stays bf16) — what a ``lax.scan`` over the blocks
+    takes, made by one jitted call.  A second copy of the matrices for
+    the builder's life (0.17 GB at ``gpt1_117m`` on a TPU, beside the 12
+    GB pool)."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(lambda by_param: jax.tree.map(
+        lambda *per_layer: jnp.stack(per_layer), *by_param))(
+            [{param: W["%s_dec_%d%s" % (name, i, param)]
+              for param in _BLOCK_PARAMS} for i in range(n_layer)])
+
+
 def _pooled_lm_parts(state, d_model, n_layer, n_head, name, kv_dtype):
     """What the pooled step and the K-wide verify forward of one model
     share: ``forward(cache, x, ts)`` — :func:`_lm_forward_one` over the
     fresh rows ``x`` ([S, d_model] at ``ts``, or [S, K, d_model] at
     ``ts .. ts + K - 1``) with ``decode_attention``'s append and read —
-    the weights, and ``make_cache``.
+    ``prefill(cache, rows, x, layers)`` — the same block
+    (:func:`_lm_block`) over ``x`` [G, C, d_model] at positions ``0 .. C
+    - 1`` of the slots ``rows``, which start their sequences there
+    (``decode_attention.fresh_prompt_attention``), scanned over
+    ``layers`` (the blocks' parameters stacked:
+    :func:`_stacked_layers`); returns the cache — the weights, and
+    ``make_cache``.
 
     The weights are held in the dtype their products are taken in.
     Where the backend would round an fp32 matmul operand to bf16 anyway
@@ -442,8 +480,11 @@ def _pooled_lm_parts(state, d_model, n_layer, n_head, name, kv_dtype):
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.decode_attention import (KV_SEQ_AXIS, kv_leaves,
-                                             make_decode_attention)
+    from paddle_tpu.decode_attention import (KV_SEQ_AXIS,
+                                             fresh_prompt_attention,
+                                             kv_leaves,
+                                             make_decode_attention,
+                                             write_prompt_rows)
 
     kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, _LM_KV_DTYPES)]
     d_head = d_model // n_head
@@ -474,7 +515,22 @@ def _pooled_lm_parts(state, d_model, n_layer, n_head, name, kv_dtype):
             n_kv_head=n_head, scale=scale)
         return _lm_forward_one(W, name, cache, x, n_layer, attend)
 
-    return forward, W, make_cache
+    def prefill(cache, rows, x, layers):
+        def block(x, layer):
+            return _lm_block(
+                layer, "", None, x, lambda q, k, v, _: fresh_prompt_attention(
+                    q, k, v, cache[0], n_head=n_head, n_kv_head=n_head,
+                    scale=scale))
+
+        # the layers as ONE scanned body (a program of this many
+        # unrolled would be as large as the step's own a width, and as
+        # slow to build and load); no head: no logits are made
+        _, stored = jax.lax.scan(block, x, layers)
+        return [write_prompt_rows(
+            kv, {leaf: rows_[i] for leaf, rows_ in stored.items()}, rows)
+            for i, kv in enumerate(cache)]
+
+    return forward, prefill, W, make_cache
 
 
 def make_transformer_lm_pooled_step_fn(
@@ -534,10 +590,26 @@ def make_transformer_lm_pooled_step_fn(
     builder; recurrent leaves (no sequence axis, read and re-written
     whole each step) are outside it — see
     :func:`make_hybrid_ssm_lm_pooled_step_fn`.
+
+    A prompt need not walk that step.  ``make_cache.prefill_rows_fn(
+    cache, rows [G] int32, tokens [G, C] int32) -> cache`` is the
+    builder's BATCHED PREFILL: ``tokens[g, j]`` is fed at position ``j``
+    of slot ``rows[g]`` for every ``j`` in one ``C``-wide forward of the
+    same block (every ``rows[g]`` a slot: a caller with fewer seats than
+    ``G`` repeats one), the K/V rows it appends are the rows the step would have
+    appended (``decode_attention.fresh_prompt_attention``: the slots
+    START their sequences, so no row of the leaves is read), the blocks
+    run as one scanned body over a stacked copy of their parameters
+    made at build, and no logits are made.  ``G`` and ``C`` are whatever the caller traces it
+    at; positions past a prompt's end may be fed any token (the
+    invariant above covers what lands there).  A pool that finds the
+    declaration seats a request and feeds it all of its prompt but the
+    last token in ONE dispatch (``KVSlotPool.seat_prefill``), and the
+    slot's first step eats that last token.
     """
     import jax.numpy as jnp
 
-    forward, W, make_cache = _pooled_lm_parts(
+    forward, prefill, W, make_cache = _pooled_lm_parts(
         state, d_model, n_layer, n_head, name, kv_dtype)
 
     def step_fn(cache, tokens, ts):
@@ -545,6 +617,14 @@ def make_transformer_lm_pooled_step_fn(
              + W[name + "_pos_emb"][jnp.maximum(ts, 0)])
         return forward(cache, x, ts)
 
+    layers = _stacked_layers(W, name, n_layer)
+
+    def prefill_rows_fn(cache, rows, tokens):
+        x = (W[name + "_word_emb"][tokens]
+             + W[name + "_pos_emb"][jnp.arange(tokens.shape[1])][None])
+        return prefill(cache, rows, x, layers)
+
+    make_cache.prefill_rows_fn = prefill_rows_fn
     return step_fn, make_cache
 
 
@@ -1231,7 +1311,7 @@ def make_transformer_lm_pooled_verify_fn(
     """
     import jax.numpy as jnp
 
-    forward, W, _ = _pooled_lm_parts(
+    forward, _, W, _ = _pooled_lm_parts(
         state, d_model, n_layer, n_head, name, kv_dtype)
 
     def verify_fn(cache, tokens, ts):
@@ -1280,18 +1360,26 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
     * ``n_gen``    — [S] int32 generated-token count (prefill/decode
       ratio accounting reads the deltas host-side)
 
-    Prefill and decode are the SAME step here: while ``pos + 1 <
-    prompt_len`` the produced token is discarded in favor of the stored
-    prompt token (teacher forcing), so a freshly admitted prompt fills
-    its cache inside the running batch — no separate prefill executable,
-    no second compiled shape.  That is the whole truth for a builder
-    that declares no chunked prefill; for one that does
-    (``make_cache.prefill_fn``, :func:`make_sparse_linear_lm_pooled_step_fn`)
-    the pool compiles one more function beside these three, which feeds
-    ``C`` prompt tokens of ONE slot a dispatch while the slot is held
-    inactive (so ``chunk`` leaves it alone: an inactive slot is fully
-    masked), and only the remainder shorter than ``C`` is teacher-forced
-    here.  A slot finishes when it emits ``eos_id``
+    In THESE three functions prefill and decode are the same step:
+    while ``pos + 1 < prompt_len`` the produced token is discarded in
+    favor of the stored prompt token (teacher forcing), so a prompt
+    seated at ``pos = 0`` fills its cache inside the running batch — no
+    separate prefill executable, no second compiled shape.  That is the
+    whole truth for a builder that declares no prefill.  For one that
+    declares a BATCHED prefill (``make_cache.prefill_rows_fn``,
+    :func:`make_transformer_lm_pooled_step_fn`) the pool compiles one
+    more function, which takes ``admit``'s place at a turn's admission:
+    it seats the turn's requests AND feeds each all of its prompt but
+    the last token in one dispatch, so a slot enters ``chunk`` at ``pos
+    = prompt_len - 1`` and its first step here produces its first token
+    (``KVSlotPool.seat_prefill``).  For one that declares a CHUNKED
+    prefill (``make_cache.prefill_fn``,
+    :func:`make_sparse_linear_lm_pooled_step_fn`) the pool compiles one
+    more function beside these three, which feeds ``C`` prompt tokens
+    of ONE slot a dispatch while the slot is held inactive (so ``chunk``
+    leaves it alone: an inactive slot is fully masked), and only the
+    remainder shorter than ``C`` is teacher-forced here.  A slot
+    finishes when it emits ``eos_id``
     or reaches ``total_len``; inactive slots are fully masked (their
     ``pos`` does not advance), reach the step as ``ts = -1`` so their
     cache rows are neither read nor written, and cost only the wasted

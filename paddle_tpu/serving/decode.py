@@ -10,11 +10,19 @@ request-at-a-time regime with vLLM-style CONTINUOUS batching:
   (``serving.kv_pool.KVSlotPool``): finished sequences (EOS or length
   cap) free their slots mid-flight, and queued prompts join the running
   batch at the next tick, all of a turn's in ONE admit dispatch;
-* **prefill and decode are the same step** — a freshly admitted prompt
+* **prefill follows from what the step's builder declares.**  Nothing:
+  prefill and decode are the same step — a freshly admitted prompt
   teacher-forces its stored tokens through the shared step fn, filling
   its KV cache inside the running batch (no separate prefill
-  executable, no second compiled shape) — unless the step's builder
-  declares a CHUNKED PREFILL (``make_cache.prefill_fn``): then a turn
+  executable, no second compiled shape).  A BATCHED PREFILL
+  (``make_cache.prefill_rows_fn``: the transformer LM): the turn's ONE
+  admission dispatch is ``KVSlotPool.seat_prefill``, which seats every
+  request the turn popped AND feeds each all of its prompt but the last
+  token, before the turn's decode chunk is dispatched; the slot joins
+  that chunk at ``pos = prompt_len - 1`` and its first step produces
+  its first token (a request seated over a retained prefix steps
+  through its suffix; a server with a draft model attached does not
+  engage).  A CHUNKED PREFILL (``make_cache.prefill_fn``): a turn
   runs at most one ``prefill`` dispatch (``C`` prompt tokens of the
   oldest seated request that still has a whole chunk to go, which is
   held out of the decode chunk until its last whole chunk is in)
@@ -40,7 +48,9 @@ Streaming: :meth:`submit` returns a :class:`DecodeRequest` whose
 frames (``serving.wire``).
 
 Observability: ``serving_decode_*`` metrics (generated/prefill token
-counters, tick counter, TTFT histogram, slot-occupancy gauge; the pool's
+counters, ``serving_decode_prefill_chunk_tokens_total`` for the prompt
+tokens a prefill dispatch fed and not the step, tick counter, TTFT
+histogram, slot-occupancy gauge; the pool's
 bytes as two gauges, ``serving_kv_cache_bytes`` for leaves with a
 sequence axis and ``serving_recurrent_state_bytes`` for those without,
 ``serving_decode_state_resets_total``, one per admission into a pool
@@ -202,8 +212,17 @@ DECODE_RECURRENT_BYTES = monitor.gauge(
 DECODE_PREFILL_CHUNKS = monitor.counter(
     "serving_decode_prefill_chunks_total",
     "prefill dispatches: one slot's next prefill_tokens prompt tokens "
-    "through the builder's chunked prefill (at most one a scheduler "
-    "turn; 0 for a builder without one)", _LABELS)
+    "through a builder's chunked prefill (at most one a scheduler "
+    "turn), or one seat-and-prefill pass of a builder with a batched "
+    "prefill (every seat of a turn, 16 a pass); 0 for a builder with "
+    "neither", _LABELS)
+DECODE_PREFILL_CHUNK_TOKENS = monitor.counter(
+    "serving_decode_prefill_chunk_tokens_total",
+    "prompt tokens a prefill dispatch fed (a chunked builder's whole "
+    "chunks; all of a prompt but its last token where a request is "
+    "seated and prefilled in one dispatch) — over "
+    "serving_decode_prefill_tokens_total it is the share of prompt "
+    "tokens that did NOT ride the one-token step", _LABELS)
 DECODE_SPARSE_READ = monitor.counter(
     "serving_decode_sparse_positions_read_total",
     "K/V positions the block-sparse layers' decode reads were told to "
@@ -481,6 +500,8 @@ class DecodeServer:
         self._constants_placed_c = POOL_CONSTANTS_PLACED.labels(**lbl)
         self._constants_placed_seen = 0
         self._prefill_chunks_c = DECODE_PREFILL_CHUNKS.labels(**lbl)
+        self._prefill_chunk_tokens_c = DECODE_PREFILL_CHUNK_TOKENS.labels(
+            **lbl)
         self._sparse_read_c = DECODE_SPARSE_READ.labels(**lbl)
         self._sparse_live_c = DECODE_SPARSE_LIVE.labels(**lbl)
         # what the builder declares of a block-sparse read, for the two
@@ -629,6 +650,8 @@ class DecodeServer:
             "idle_drops": int(self._idle_drops_c.value),
             "prefill_chunks": int(self._prefill_chunks_c.value),
             "prefill_chunk_tokens": self._pool.prefill_tokens,
+            "prefill_chunk_tokens_total": int(
+                self._prefill_chunk_tokens_c.value),
             "sparse_positions_read": int(self._sparse_read_c.value),
             "sparse_positions_live": int(self._sparse_live_c.value),
             "window_positions_read": int(self._window_read_c.value),
@@ -991,13 +1014,24 @@ class DecodeServer:
                      for seat in seats]
             plain = [(slot, req) for slot, req, pre_len, _ in seats
                      if pre_len == 0]
+            fed = {}     # slot: prompt tokens a prefill pass fed it
             if plain:
                 at, reqs = zip(*plain)
+                prompts = [r.prompt for r in reqs]
+                totals = [r.total_len for r in reqs]
+            if plain and pool.seats_prefilled:
+                # seated AND fed all but the prompt's last token, in the
+                # same dispatch (a pass; 16 seats each)
+                self._state, passes = pool.seat_prefill(
+                    self._state, at, prompts, totals)
+                self._admit_dispatches_c.inc(passes)
+                self._prefill_chunks_c.inc(passes)
+                fed = {slot: len(p) - 1 for slot, p in zip(at, prompts)}
+                self._prefill_chunk_tokens_c.inc(sum(fed.values()))
+            elif plain:
                 self._state = pool.admit(
-                    self._state, at, [r.prompt for r in reqs],
-                    [len(r.prompt) for r in reqs],
-                    [r.total_len for r in reqs],
-                    spec=[r.speculative for r in reqs])
+                    self._state, at, prompts, [len(p) for p in prompts],
+                    totals, spec=[r.speculative for r in reqs])
                 self._admit_dispatches_c.inc()
             held = [slot for slot, req, pre_len, _ in seats
                     if pool.can_prefill(self._state, pre_len,
@@ -1021,8 +1055,8 @@ class DecodeServer:
             return
         for slot, req, pre_len, _ in seats:
             self._admit_seq += 1
-            self._slots[slot] = _Slot(req, pre_len, slot in held,
-                                      self._admit_seq)
+            self._slots[slot] = _Slot(req, pre_len + fed.get(slot, 0),
+                                      slot in held, self._admit_seq)
             # the shared-prefix win, measured where it happens: only the
             # unmatched suffix re-enters prefill (in chunks or by steps)
             self._prefill_c.inc(len(req.prompt) - pre_len)
@@ -1043,7 +1077,8 @@ class DecodeServer:
             # the turn's bookkeeping above rides this leaf: the phases
             # tile the turn
             turn.leave(seated=len(seats), dispatches=int(
-                self._admit_dispatches_c.value - dispatches0))
+                self._admit_dispatches_c.value - dispatches0),
+                rows=len(fed), tokens=sum(fed.values()))
 
     def _admit_with_prefix(self, slot, req, pre_len, pre_kv):
         """Seat ``req`` over its retained prefix KV, in a dispatch of its
@@ -1269,6 +1304,7 @@ class DecodeServer:
             self._fail_and_drop_pool(exc)
             return False
         self._prefill_chunks_c.inc()
+        self._prefill_chunk_tokens_c.inc(pool.prefill_tokens)
         rec.pos, rec.held = end, not last
         if last and self._prefix is not None and pool.snapshots:
             try:
@@ -1411,8 +1447,8 @@ class DecodeServer:
                        DECODE_STATE_RESETS, DECODE_RECURRENT_BYTES,
                        DECODE_ADMIT_DISPATCHES, DECODE_ADMITTED,
                        DECODE_IDLE_DROPS, POOL_CONSTANTS_PLACED,
-                       DECODE_PREFILL_CHUNKS, DECODE_SPARSE_READ,
-                       DECODE_SPARSE_LIVE):
+                       DECODE_PREFILL_CHUNKS, DECODE_PREFILL_CHUNK_TOKENS,
+                       DECODE_SPARSE_READ, DECODE_SPARSE_LIVE):
             metric.remove_labels(**lbl)
         if self._speculative is not None:
             for metric in (SPEC_PROPOSED, SPEC_ACCEPTED):

@@ -42,8 +42,19 @@ position than the one its index names), so ``extract_kv``, a
 prefill) and ``speculative=`` are refused over ring leaves, as they are
 over recurrent ones.
 
-What else a pool compiles follows from what the builder declares.  A
-builder with a chunked prefill (``make_cache.prefill_fn``) gets one more
+What else a pool compiles follows from what the builder declares, and
+the two prefills are separate paths below that one chooser (their needs
+conflict: one-pass seating here, snapshot boundaries and held slots
+there).  A builder with a BATCHED prefill (``make_cache.prefill_rows_fn``:
+several slots' prompts from position 0 in one ``C``-wide forward) gets
+one more executable a rung pair, ``seat_prefill``: a scheduler turn's
+seats travel as compact rows, are seated by scatter and fed all of
+their prompts but the last token in the same dispatch, in three widths
+(:meth:`KVSlotPool.prefill_classes`), each a loop with as many forwards
+as the turn's seats of that width need — so a prompt never walks the
+one-token step, and the slot's first step produces its first token.
+Not with a draft model attached (nothing would feed the draft's cache).
+A builder with a chunked prefill (``make_cache.prefill_fn``) gets one more
 executable a rung pair, ``prefill``: ``C`` prompt tokens of ONE slot in
 one dispatch, for a slot the scheduler holds out of the decode chunk
 until its last whole chunk is in.  Because such a prefill can stop at a
@@ -134,6 +145,12 @@ class KVSlotPool:
         self.recurrent_leaves = recurrent_leaf_names(make_cache)
         #: the builder's chunked prefill (None: prompts ride the step)
         self._prefill = getattr(make_cache, "prefill_fn", None)
+        #: the builder's batched prefill (None: as above).  Not taken
+        #: with a draft attached: the plain chunk keeps ``draft_cache``
+        #: position-synced by feeding it every consumed token, and
+        #: nothing here feeds the draft a prompt
+        self._prefill_rows = (getattr(make_cache, "prefill_rows_fn", None)
+                              if speculative is None else None)
         for what, on in (("prefix=True", prefix and self._prefill is None),
                          ("speculative=", speculative is not None)):
             if on and self.recurrent_leaves:
@@ -259,6 +276,8 @@ class KVSlotPool:
         kinds = ["chunk", "admit", "release"]
         if self._prefill is not None:
             kinds.append("prefill")
+        if self._prefill_rows is not None:
+            kinds.append("seat_prefill")
         if self.prefix:
             kinds.append("admit_prefix")
         if self.snapshots:
@@ -490,6 +509,9 @@ class KVSlotPool:
         if kind == "admit":
             return self._lower(kind, spec, jax.ShapeDtypeStruct(
                 (s, t + self._seat_columns()), i32))
+        if kind == "seat_prefill":
+            return self._lower(kind, spec, jax.ShapeDtypeStruct(
+                (self._packed_seats_size(s, t),), i32))
         prompt = jax.ShapeDtypeStruct((t,), i32)
         args = [spec, mask, prompt, scalar, scalar]
         if kind == "admit_prefix":
@@ -596,9 +618,9 @@ class KVSlotPool:
 
     # ------------------------------------------------------------------
     def warmup(self) -> int:
-        """AOT-compile chunk + admit + release (and ``prefill``,
-        ``admit_prefix``, ``snapshot``, ``spec_chunk`` where the pool
-        has them: :meth:`_kinds`) for EVERY rung pair;
+        """AOT-compile chunk + admit + release (and ``seat_prefill``,
+        ``prefill``, ``admit_prefix``, ``snapshot``, ``spec_chunk`` where
+        the pool has them: :meth:`_kinds`) for EVERY rung pair;
         returns the number of compiles performed (0 on a re-warm).
         After this, a storm that stays inside the ladders never builds
         an executable again — :meth:`jit_cache_stats` ``misses`` is the
@@ -633,7 +655,9 @@ class KVSlotPool:
     # ------------------------------------------------------------------
     def chunk(self, state) -> Dict[str, object]:
         """Advance every active slot by up to ``steps`` tokens in ONE
-        device dispatch (prefill and decode interleaved inside)."""
+        device dispatch (a prompt that was not prefilled at its
+        admission steps through its tokens inside, beside the rows that
+        decode)."""
         s, t = self.state_rungs(state)
         # hot-path: begin kv_chunk (executable lookup + async dispatch;
         # the scheduler materializes results OUTSIDE this region)
@@ -770,6 +794,153 @@ class KVSlotPool:
         out = exe(*args)
         # hot-path: end kv_admit_prefix
         return out
+
+    # ------------------------------------------------------------------
+    # seat and prefill in one dispatch (builders that declare a
+    # prefill_rows_fn)
+    # ------------------------------------------------------------------
+    #: most rows (seats and the repeats that fill a group) one
+    #: ``seat_prefill`` dispatch carries; more seats take more passes
+    _SEAT_ROWS = 16
+
+    @property
+    def seats_prefilled(self) -> bool:
+        """Whether :meth:`seat_prefill` is how this pool seats a request
+        that starts at position 0: the builder declares a batched
+        prefill (``make_cache.prefill_rows_fn``) and no draft model
+        rides along."""
+        return self._prefill_rows is not None
+
+    @staticmethod
+    def prefill_classes(t: int) -> List[Tuple[int, int]]:
+        """The ``(C, G)`` shapes a ``seat_prefill`` program of length
+        rung ``t`` feeds prompts in, narrowest first: ``G`` seats of at
+        most ``C`` fed positions each a forward.  Widths a quarter, a
+        half and the whole of the rung, so a 96-token prompt does not
+        pay for 384; ``G * C`` about half a rung of tokens, enough that
+        a forward is not bound by reading the weights (two seats a
+        forward at the narrowest width)."""
+        widths = sorted({max(1, t // 4), max(1, t // 2), t})
+        return [(c, max(1, t // 2 // c)) for c in widths]
+
+    def _seat_rows(self, s: int, t: int) -> int:
+        return max(min(self._SEAT_ROWS, s),
+                   max(g for _, g in self.prefill_classes(t)))
+
+    # a compact seat's columns are :meth:`_pack_seats`' (no draft: three),
+    # but for the first: the row's slot
+    _SLOT = _SEATED
+
+    def _packed_seats_size(self, s: int, t: int) -> int:
+        return (self._seat_rows(s, t) * (t + self._seat_columns())
+                + 2 * len(self.prefill_classes(t)))
+
+    def _pack_seat_passes(self, s: int, t: int, slots, prompts, total_lens):
+        """The host half of :meth:`seat_prefill`: the seats of one turn
+        as one int32 array a pass — ``_seat_rows`` COMPACT rows (a
+        row's padded prompt, then its slot and its two lengths: 33 KB at
+        16 rows of a 512 rung where :meth:`_pack_seats`' slot-indexed
+        array is 659 KB at 320 slots), then per class of
+        :meth:`prefill_classes` the first row of its seats and how many
+        forwards they take.  Seats are ordered by class (the narrowest
+        width that holds all but the prompt's last token) and laid out
+        in the class's groups of ``G`` rows; what does not fit
+        ``_seat_rows`` rows goes to the next pass.  EVERY row names a
+        seat: a row no seat took (the rest of a group that is not full,
+        the rows past the last group) REPEATS the pass's first seat, so
+        the traced half scatters in bounds and twice the same where it
+        scatters twice — no row is "idle", and nothing leans on an
+        out-of-range index being dropped (on a TPU a ``[4, T]`` token
+        buffer lost slot 0's row that way: v5e chip run, PR 45)."""
+        classes = self.prefill_classes(t)
+        rows, width = self._seat_rows(s, t), t + self._seat_columns()
+        todo = [[] for _ in classes]
+        for i, prompt in enumerate(prompts):
+            todo[next((k for k, (c, _) in enumerate(classes)
+                       if len(prompt) - 1 <= c), -1)].append(i)
+        passes = []
+        while any(todo):
+            packed = np.zeros(self._packed_seats_size(s, t), np.int32)
+            seats = packed[:rows * width].reshape(rows, width)
+            plan = packed[rows * width:].reshape(len(classes), 2)
+            seated = np.zeros(rows, bool)
+            row = 0
+            for k, (_, g) in enumerate(classes):
+                groups = min(-(-len(todo[k]) // g), (rows - row) // g)
+                plan[k] = row, groups
+                take, todo[k] = todo[k][:groups * g], todo[k][groups * g:]
+                for at, i in enumerate(take, row):
+                    n = min(len(prompts[i]), t)
+                    seats[at, :n] = prompts[i][:n]
+                    seats[at, t + self._SLOT] = slots[i]
+                    seats[at, t + self._PROMPT_LEN] = len(prompts[i])
+                    seats[at, t + self._TOTAL_LEN] = total_lens[i]
+                seated[row:row + len(take)] = True
+                row += groups * g
+            seats[~seated] = seats[np.argmax(seated)]
+            passes.append(packed)
+        return passes
+
+    def _seat_prefill_fn(self, state, packed):
+        """The traced ``seat_prefill``: seat every row of one of
+        :meth:`_pack_seat_passes`' arrays (what ``admit`` does, by
+        scatter from compact rows) at ``pos = prompt_len - 1`` and feed
+        each its prompt from position 0 through the builder's batched
+        prefill — per class a loop of as many ``[G, C]`` forwards as the
+        plan says, none for a class no seat fell in — so the slot's
+        first step eats the prompt's last token and produces the first
+        generated one."""
+        import jax
+        import jax.numpy as jnp
+
+        s, t = state["tokens"].shape
+        classes = self.prefill_classes(t)
+        rows, width = self._seat_rows(s, t), t + self._seat_columns()
+        seats = packed[:rows * width].reshape(rows, width)
+        plan = packed[rows * width:].reshape(len(classes), 2)
+        at = seats[:, t + self._SLOT]   # every row a seat: in bounds
+
+        def seated(name, value):
+            return state[name].at[at].set(value)
+
+        out = dict(state)
+        out.update(
+            tokens=seated("tokens", seats[:, :t]),
+            pos=seated("pos",
+                       jnp.maximum(seats[:, t + self._PROMPT_LEN] - 1, 0)),
+            prompt_len=seated("prompt_len", seats[:, t + self._PROMPT_LEN]),
+            total_len=seated("total_len", seats[:, t + self._TOTAL_LEN]),
+            active=seated("active", True),
+            finished=seated("finished", False),
+            n_gen=seated("n_gen", 0))
+        cache = state["cache"]
+        for k, (c, g) in enumerate(classes):
+            def forward(i, cache, k=k, c=c, g=g):
+                group = jax.lax.dynamic_slice(
+                    seats, (plan[k, 0] + i * g, 0), (g, width))
+                return self._prefill_rows(
+                    cache, group[:, t + self._SLOT], group[:, :c])
+
+            cache = jax.lax.fori_loop(0, plan[k, 1], forward, cache)
+        out["cache"] = cache
+        return out
+
+    def seat_prefill(self, state, slots, prompts, total_lens):
+        """Seat requests that start at position 0 AND feed each all of
+        its prompt but the last token, in one ``seat_prefill`` dispatch
+        for up to ``_seat_rows`` seats (a pass; a turn that seats more,
+        or whose groups leave too many rows unused, takes more passes —
+        degraded, never wrong).  Returns ``(state, passes)``.  Every
+        seated slot is active at ``pos = len(prompt) - 1`` afterwards."""
+        s, t = self.state_rungs(state)
+        passes = self._pack_seat_passes(s, t, slots, prompts, total_lens)
+        # hot-path: begin kv_seat_prefill (executable lookup + async
+        # dispatch a pass)
+        exe = self._get_exe("seat_prefill", s, t)
+        for packed in passes:
+            state = exe(state, packed)
+        # hot-path: end kv_seat_prefill
+        return state, len(passes)
 
     # ------------------------------------------------------------------
     # chunked prefill and snapshots (builders that declare a prefill_fn)

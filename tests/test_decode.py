@@ -239,7 +239,8 @@ def test_batch_admit_equals_single_admits_in_order(lm_state, kv,
     pool = KVSlotPool(step_fn, make_cache, eos_id=EOS, max_slots=4,
                       max_seq_len=16, slot_ladder=[4], len_ladder=[16],
                       steps=2, kv_dtype=kv)
-    assert pool.warmup() == 3  # chunk + admit + release: no fourth kind
+    # chunk + admit + release, and this builder's seat_prefill (PR 45)
+    assert pool.warmup() == 4
     rng = np.random.RandomState(11)
     st = pool.alloc(4, 16)
     st = pool.admit(st, 1, rng.randint(2, V, 4).astype(np.int32), 4, 12)

@@ -94,7 +94,7 @@ def test_fc_over_an_fp32_matrix_is_the_plain_product():
 def test_the_builder_copies_exactly_the_multiplied_matrices(
         lm_state, products_are_bf16):
     before = monitor.counter_value(COUNTER)
-    _, W, _ = _parts(lm_state)
+    _, _, W, _ = _parts(lm_state)
     copied = {k for k, v in W.items() if v.dtype == jnp.bfloat16}
     assert copied == _matrices()
     assert len(copied) == 6 * DIMS["n_layer"] + 1
@@ -115,7 +115,7 @@ def test_a_matrix_that_is_not_fp32_is_taken_as_stored(lm_state,
     state = dict(lm_state)
     state["lm_head_w"] = jnp.asarray(state["lm_head_w"], jnp.bfloat16)
     before = monitor.counter_value(COUNTER)
-    _, W, _ = _parts(state)
+    _, _, W, _ = _parts(state)
     assert W["lm_head_w"] is state["lm_head_w"]
     assert monitor.counter_value(COUNTER) - before == 6 * DIMS["n_layer"]
 
@@ -179,7 +179,7 @@ def test_on_the_cpu_the_builder_makes_no_copy(lm_state):
     before = monitor.counter_value(COUNTER)
     step_fn, make_cache = decoding.make_transformer_lm_pooled_step_fn(
         lm_state, *DIMS.values())
-    _, W, _ = _parts(lm_state)
+    _, _, W, _ = _parts(lm_state)
     assert {str(v.dtype) for v in W.values()} == {"float32"}
     assert monitor.counter_value(COUNTER) == before
     tokens = jnp.array([5, 9], jnp.int32)

@@ -317,7 +317,10 @@ def test_a_pool_without_a_prefill_compiles_three():
         w, 50, 16, 1, 2, 32)
     pool = KVSlotPool(step, make_cache, eos_id=50, max_slots=2,
                       max_seq_len=32, slot_ladder=[2], len_ladder=[32])
-    assert pool.warmup() == 3 and pool.prefill_tokens == 0
+    # chunk, admit, release and (PR 45) this builder's own seat_prefill:
+    # a BATCHED prefill is another declaration; no ``prefill`` kind
+    assert pool.warmup() == 4 and pool.prefill_tokens == 0
+    assert "prefill" not in pool._kinds()
     assert not pool.can_prefill(pool.alloc(2, 32), 0, 31)
 
 

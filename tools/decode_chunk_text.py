@@ -197,6 +197,20 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
             jax.lax.dynamic_slice(state["tokens"], (3, c), (1, c))[0],
             state["pos"][3], jnp.int32(c)))
 
+    def seat_prefill(w, state, packed):
+        # a turn's seats seated and fed their prompts, as the pool runs
+        # it (KVSlotPool._seat_prefill_fn) for a builder that declares a
+        # batched prefill (gpt1_117m)
+        return pool_of(w)._seat_prefill_fn(state, packed)
+
+    def pool_of(w):
+        from paddle_tpu.serving.kv_pool import KVSlotPool
+
+        return KVSlotPool(
+            *build(w)[:2], eos_id=int(cfg["vocab_size"]), max_slots=slots,
+            max_seq_len=seq_len, slot_ladder=(slots,), len_ladder=(seq_len,),
+            steps=sv["steps_per_tick"], kv_dtype=sv["kv_dtype"])
+
     i32, flag = jnp.int32, jnp.bool_
     state = {
         "cache": jax.tree.map(
@@ -210,15 +224,18 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
     # was built outside, the numpy constants of every step — is hoisted
     # to arguments, as the pool does (``KVSlotPool._lower``), instead of
     # being baked into the text
-    closed, out = jax.make_jaxpr({"chunk": chunk, "prefill": prefill}[kind],
-                                 return_shape=True)(weights, state)
+    more = ([sd((pool_of(weights)._packed_seats_size(slots, seq_len),), i32)]
+            if kind == "seat_prefill" else [])
+    closed, out = jax.make_jaxpr(
+        {"chunk": chunk, "prefill": prefill, "seat_prefill": seat_prefill}[
+            kind], return_shape=True)(weights, state, *more)
 
-    def hoisted(consts, w, st):
+    def hoisted(consts, w, st, *more):
         return jax.tree.unflatten(jax.tree.structure(out), jax.core.eval_jaxpr(
-            closed.jaxpr, consts, *jax.tree.leaves((w, st))))
+            closed.jaxpr, consts, *jax.tree.leaves((w, st, more))))
 
     return jax.jit(hoisted, donate_argnums=(2,)).lower(
-        [sd(c.shape, c.dtype) for c in closed.consts], weights, state)
+        [sd(c.shape, c.dtype) for c in closed.consts], weights, state, *more)
 
 
 def weight_casts(lowered, text: str) -> int:
@@ -241,10 +258,13 @@ def main():
     ap.add_argument("out")
     ap.add_argument("--repo", default=os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    ap.add_argument("--kind", default="chunk", choices=("chunk", "prefill"),
+    ap.add_argument("--kind", default="chunk",
+                    choices=("chunk", "prefill", "seat_prefill"),
                     help="prefill: the chunked-prefill program of a "
                     "builder that has one (minicpm_sala, "
-                    "smallthinker_21b_a3b)")
+                    "smallthinker_21b_a3b); seat_prefill: the "
+                    "seat-and-prefill program of one with a batched "
+                    "prefill (gpt1_117m)")
     ap.add_argument("--layers", type=int, default=2,
                     help="layers compiled (8: the whole minicpm_sala or "
                     "smallthinker_21b_a3b cut, to see that the real "

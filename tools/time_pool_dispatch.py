@@ -11,6 +11,10 @@ take as many array arguments as the cell's):
   6): seconds until the call RETURNS (the host's share) and until its
   result is READY (what the chip waits between two chunks);
 * ``chunk``: the same two, with every slot live;
+* where the pool seats and prefills in one dispatch (``gpt1_117m``
+  since PR 45), one ``seat_prefill`` pass of one seat at the narrowest
+  and at the widest width, of a full pass of seats and of an offline
+  turn's nine: the same two;
 * per executable kind, how many of the constants ``KVSlotPool._lower``
   hoisted were HOST-BORN (numpy arrays the step closes over) and their
   bytes: before PR 32 every call sent each of them to the chip again
@@ -241,6 +245,39 @@ def main():
             state, call_s, ready_s = fn(state)
             samples[name + ".call"].append(call_s)
             samples[name + ".ready"].append(ready_s)
+
+    if getattr(pool, "seats_prefilled", False):
+        # one seat-and-prefill dispatch (PR 45): a chat turn's one seat
+        # at the narrowest width and at the widest, a full pass of
+        # seats, and an offline turn's nine
+        def seat_prefill(state, lens):
+            prompts = [rng.randint(0, vocab, n).astype(np.int32)
+                       for n in lens]
+            t0 = time.perf_counter()
+            state, passes = pool.seat_prefill(
+                state, list(range(len(lens))), prompts, [t] * len(lens))
+            t1 = time.perf_counter()
+            jax.block_until_ready(state["pos"])
+            if passes != 1:
+                sys.exit("time_pool_dispatch: %d passes for %d seats"
+                         % (passes, len(lens)))
+            return state, t1 - t0, time.perf_counter() - t0
+
+        full = pool._seat_rows(s, t)
+        narrow = pool.prefill_classes(t)[0][0]
+        mixes = {
+            "seat_prefill_1": [min(narrow, 3 * t // 16)],
+            "seat_prefill_1_widest": [t * 5 // 8],
+            "seat_prefill_%d" % full: [
+                int(n) for n in rng.randint(max(2, t // 32), narrow, full)],
+            "seat_prefill_9_offline": [int(n) for n in np.clip(np.exp(
+                rng.normal(np.log(t / 8.0), 0.5, 9)), t // 32, t // 2)]}
+        for r in range(args.reps + 3):
+            for name, lens in mixes.items():
+                state, call_s, ready_s = seat_prefill(state, lens)
+                if r >= 3:
+                    samples[name + ".call"].append(call_s)
+                    samples[name + ".ready"].append(ready_s)
 
     if snapshots:
         from paddle_tpu.serving.prefix_cache import PrefixKVCache
