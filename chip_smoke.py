@@ -4,7 +4,7 @@ Drives Program -> Executor -> DecodeServer once, in ONE process on ONE
 chip, through the public package surface, at the full width of the
 repo's BERT-base configuration (weights random, from a seed):
 
-* train leg:  BERT-base pretraining exactly as bench_bert.py builds it
+* train leg:  BERT-base pretraining at the ``bert_base`` cells' widths
   (V=30522, D=768, L=12, H=12, d_inner=3072, S=128, batch 128, Adam
   under bf16 AMP) -> ``Executor(TPUPlace(0))`` -> startup, two single
   steps, one ``run(steps=8, per_step_feed=True)`` chunk, then the same

@@ -25,10 +25,10 @@ def calibrate_int8_program(program, executor, calibration_feeds,
     ``program`` is cloned (never mutated); ``calibration_feeds`` is a
     non-empty sequence of feed dicts run through the transformed
     program so the moving-average activation scales converge on real
-    data (bench_calibration.py-style: representative batches, not the
-    training set).  Weights are read from ``base_scope`` (default: the
-    current global scope), COPIED into a scratch scope, and frozen to
-    int8 there — the caller's fp32 state is untouched.
+    data (representative batches, not the training set).  Weights are
+    read from ``base_scope`` (default: the current global scope), COPIED
+    into a scratch scope, and frozen to int8 there — the caller's fp32
+    state is untouched.
 
     ``moving_rate`` defaults to 0.5 (not QAT's 0.9): post-training
     calibration sees a handful of batches, and the faster decay lets

@@ -733,8 +733,8 @@ class Executor:
                 # state is never written back by the jitted call)
                 scope.set(n, v)
         # everything above is the host's per-dispatch rent; on a plan +
-        # jit cache hit it must stay "almost nothing" (the new
-        # bench_dispatch.py pins it)
+        # jit cache hit it must stay "almost nothing"
+        # (tests/test_dispatch_fastpath.py reads this accounting)
         _overhead = time.perf_counter() - _t_run0
         stats["dispatch_overhead_s"] += _overhead
         if _rec:

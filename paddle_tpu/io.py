@@ -319,7 +319,7 @@ def _export_precision_variant(dirname, pruned, feed_names, fetch_names,
             raise mp_inf.PrecisionPolicyError(
                 "precision_policy dtype 'int8' needs calibration data "
                 "(policy['calibration'] = [feed dicts] — "
-                "bench_calibration.py-style representative batches)")
+                "representative batches)")
         variant, vscope = calibrate_int8_program(
             pruned, executor, calibration, fetch_names)
     # parity gate: fp32 vs variant on every parity feed, worst rel err

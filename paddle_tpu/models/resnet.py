@@ -7,8 +7,8 @@ ResNet-50 (paddle/contrib/float16/float16_benchmark.md:40-52).
 
 TPU notes: NCHW layout is the API-surface default for reference parity;
 ``data_format="NHWC"`` runs the whole network channels-last (the layout
-TPUs prefer — bench.py's BENCH_LAYOUT knob probes both).  Use bf16 via
-the AMP decorator (contrib/mixed_precision) for benchmark runs.
+TPUs prefer).  Use bf16 via the AMP decorator (contrib/mixed_precision)
+for benchmark runs.
 """
 from __future__ import annotations
 

@@ -45,8 +45,8 @@ health attribution (is this run OK?):
 
 Everything gates on the proven one-is-None-check pattern: a disarmed
 train loop pays a single attribute check per step, and the armed ledger
-is plain float arithmetic (no allocation, no locking) — the
-``bench_dispatch.py --train-obs`` leg pins the armed tax under 2%.
+is plain float arithmetic (no allocation, no locking);
+tests/test_train_observability.py holds that its books balance.
 """
 from __future__ import annotations
 

@@ -227,7 +227,7 @@ class ServingMetrics:
             self._valid_rows += valid
             waste = 1.0 - self._valid_rows / self._padded_rows
         # cumulative padding waste — the measured number the autotuned
-        # ladder must strictly reduce (bench_serving reports it)
+        # ladder must strictly reduce
         self._waste_gauge.set(round(waste, 6))
         event = {
             "event": "serving.batch",

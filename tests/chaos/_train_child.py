@@ -36,10 +36,11 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 if "--sharded" in sys.argv or "--mesh-tables" in sys.argv:
     # the fsdp/mp mesh needs virtual CPU devices; must land in the env
     # before jax initializes its backend (imports below stay lazy) —
-    # one shared definition with every CPU-mesh bench stage
-    import bench_common
-
-    os.environ.update(bench_common.virtual_mesh_env())
+    # the same flag tests/conftest.py sets for in-process tests
+    _flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in _flags:
+        os.environ["XLA_FLAGS"] = (
+            _flags + " --xla_force_host_platform_device_count=8").strip()
 
 import numpy as np  # noqa: E402
 

@@ -262,8 +262,8 @@ def test_trainer_desc_wired_into_train_from_dataset():
 def test_executor_multi_step_parity():
     """run(steps=N) — one jitted fori_loop over N optimizer steps — must
     match N single-step run() calls exactly (the dispatch-amortizing path
-    bench.py uses; analog of the reference DeviceWorker multi-batch
-    loop)."""
+    the ``bert_base`` cells train through; analog of the reference
+    DeviceWorker multi-batch loop)."""
     import paddle_tpu as fluid
     from paddle_tpu import framework
 

@@ -16,9 +16,19 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import framework
 
-# repo root is on sys.path (tests/conftest.py); one measurement
-# definition shared with the micro-bench
-from bench_dispatch import median_overhead_s
+
+def median_overhead_s(exe, one_run, iters):
+    """Median per-run host dispatch overhead (seconds) over ``iters``
+    runs, read from the executor's own ``dispatch_overhead_s``
+    accounting: the one measurement definition behind both bars below."""
+    stats = exe._cache_stats
+    samples = []
+    for _ in range(iters):
+        o0 = stats["dispatch_overhead_s"]
+        one_run()
+        samples.append(stats["dispatch_overhead_s"] - o0)
+    samples.sort()
+    return samples[len(samples) // 2]
 
 
 def _build_chain(layers=20, dim=32, seed=7):
@@ -138,6 +148,28 @@ def test_plan_reanalysis_on_structural_edit():
 # ---------------------------------------------------------------------------
 # sharded dispatch (PR 4): mesh-fed cached dispatch stays cheap
 # ---------------------------------------------------------------------------
+def _measure_cached(exe, prog, loss, feed, run_kwargs, iters):
+    """Warm the jit/plan caches, then return the median cached host
+    overhead (seconds), the plan-hit count and the jit-miss count over
+    the measured runs.  Each run BLOCKS on its fetch before the next
+    (outside the measured pre-dispatch window): the async device
+    compute of the 8-way virtual mesh otherwise contends with the next
+    run's host section."""
+
+    def one_run():
+        (out,) = exe.run(prog, feed=feed, fetch_list=[loss],
+                         return_numpy=False, **run_kwargs)
+        out.block_until_ready()
+
+    for _ in range(3):  # compile + settle state avals
+        one_run()
+    h0 = exe._cache_stats["plan_hits"]
+    m0 = exe.jit_cache_stats()["misses"]
+    cached = median_overhead_s(exe, one_run, iters)
+    return (cached, exe._cache_stats["plan_hits"] - h0,
+            exe.jit_cache_stats()["misses"] - m0)
+
+
 def test_sharded_dispatch_overhead_within_2x_of_single_device():
     """The scale-out acceptance bar: per-STEP host overhead of the
     sharded pipeline (device_buffered(compiled=...) chunks -> steps=N
@@ -147,18 +179,50 @@ def test_sharded_dispatch_overhead_within_2x_of_single_device():
     sharding the feed must not reintroduce O(n_devices) hot-path work.
     Also pins the mechanism: the steady state re-stages NOTHING (the
     prefetcher's per-shard placement passes straight through)."""
-    from bench_dispatch import run_sharded
+    import jax
 
-    res = run_sharded(iters=60)
-    assert res["n_devices"] == 8, res  # conftest's virtual CPU mesh
-    assert res["recompiles_during_measure"] == 0, res
-    assert res["steady_passthrough"] is True, res
-    assert res["plan_cache_hits"] == 60, res
-    ratio = res["value"] / res["single_device_overhead_us"]
+    from paddle_tpu import reader as _reader
+    from paddle_tpu.parallel import mesh as mesh_lib
+    from paddle_tpu.parallel.compiled_program import CompiledProgram
+
+    iters, chunk = 60, 4
+    mesh = mesh_lib.data_parallel_mesh()
+    n_dev = int(mesh.devices.size)
+    assert n_dev == 8  # conftest's virtual CPU mesh
+    prog, startup, loss = _build_chain()
+    compiled = CompiledProgram(prog).with_mesh(mesh)
+    host = {"x": np.random.RandomState(0).rand(n_dev, 32).astype(np.float32)}
+
+    exe = fluid.Executor()
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        # single-device yardstick first: once the compiled path runs,
+        # the scope state is mesh-sharded and single-device runs of the
+        # same program would see mismatched devices
+        feed1 = {"x": jax.device_put(host["x"], jax.devices()[0])}
+        single_s, _, _ = _measure_cached(exe, prog, loss, feed1, {}, iters)
+
+        # the sharded production regime: per_step_feed chunks straight
+        # from the sharded prefetcher
+        gen = _reader.device_buffered(
+            (host for _ in iter(int, 1)), size=2, steps=chunk,
+            compiled=compiled)()
+        try:
+            chunk_s, plan_hits, recompiles = _measure_cached(
+                exe, compiled, loss, next(gen),
+                dict(steps=chunk, per_step_feed=True), iters)
+        finally:
+            gen.close()
+
+    assert recompiles == 0
+    assert len(compiled._steady_tokens) >= 1  # nothing re-staged
+    assert plan_hits == iters
+    per_step_s = chunk_s / chunk
+    ratio = per_step_s / single_s
     assert ratio <= 2.0, (
         "sharded per-step dispatch overhead %.1fus vs single-device "
-        "%.1fus — %.2fx exceeds the 2x scale-out bar (full result: %r)"
-        % (res["value"], res["single_device_overhead_us"], ratio, res))
+        "%.1fus — %.2fx exceeds the 2x scale-out bar"
+        % (per_step_s * 1e6, single_s * 1e6, ratio))
 
 
 # ---------------------------------------------------------------------------

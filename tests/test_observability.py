@@ -346,6 +346,36 @@ def test_fleet_admin_tier_federates_stub_children():
             assert be_doc["statusz"]["metrics"]["completed"] >= 0
         assert "counters" in doc["aggregate"]
 
+        # exact federation, checked while the fleet is idle: every
+        # child ``serving_*`` counter series appears verbatim under
+        # that child's backend label, and the aggregate is their sum
+        children = {
+            be.name: parse_exposition(be.transport.get_text("/metrics"))
+            for be in fleet._backends}
+        fleet.scrape_once()
+        fed = parse_exposition(
+            _admin_get(addr, "/metrics")[1].decode("utf-8"))
+        agg = json.loads(
+            _admin_get(addr, "/statusz")[1])["aggregate"]["counters"]
+        fed_index = {
+            (name, tuple(sorted(labels.items()))): value
+            for fam in fed.values() if fam["type"] == "counter"
+            for name, labels, value in fam["samples"]}
+        sums = {}
+        for backend, child_fams in children.items():
+            for fam_name, fam in child_fams.items():
+                if (fam["type"] != "counter"
+                        or not fam_name.startswith("serving_")):
+                    continue
+                for name, labels, value in fam["samples"]:
+                    key = (name, tuple(sorted(
+                        dict(labels, backend=backend).items())))
+                    assert fed_index.get(key) == value, (key, value)
+                    sums[fam_name] = sums.get(fam_name, 0.0) + value
+        assert sums.get("serving_completed_total", 0.0) > 0, sums
+        for fam_name, want in sums.items():
+            assert agg.get(fam_name) == want, fam_name
+
         st, body = _admin_get(addr, "/tracez")
         doc = json.loads(body)
         assert st == 200 and doc["role"] == "balancer"
@@ -434,6 +464,82 @@ def test_admin_surfaces_survive_concurrent_hammering():
             sp.stop()
     assert errors == [], errors[:5]
     assert eng._ticks > 0  # the evaluator actually ran during the storm
+
+
+def test_injected_dispatch_delay_fires_and_clears_the_fast_burn_alert():
+    """The fire/clear drill end to end, over the admin tier's own HTTP
+    surfaces: a delay fault armed on the balancer's dispatch makes
+    every routed request miss a latency objective's threshold, the
+    fast-burn pair fires in ``/sloz``; disarmed, clean completions
+    drain the short window and it clears; both transitions land in
+    ``/eventz``, the firing one critical."""
+    from paddle_tpu import faults
+
+    slo_name = "drill-p99-latency"
+    sps = [_stub_wire_server("obsdrill-%d" % i) for i in range(2)]
+    fleet = wire.FleetBalancer(
+        [sp.address for sp in sps], name="obsdrill",
+        health_interval_s=0.2, admin_port=0, scrape_interval_s=0.1)
+    # window_scale 0.001 -> 5m = 0.3 s, 1h = 3.6 s of real time
+    slo_mod.install(
+        [slo_mod.latency(
+            slo_name, histogram="serving_request_latency_seconds",
+            threshold_s=0.1, target=0.99, server="obsdrill")],
+        interval_s=0.05, window_scale=0.001)
+    addr = fleet.admin_address
+    stop = threading.Event()
+
+    def fast_alert():
+        doc = json.loads(_admin_get(addr, "/sloz")[1])
+        for obj in doc.get("objectives") or ():
+            if obj.get("name") == slo_name:
+                return next(a for a in obj["alerts"] if a["pair"] == "fast")
+        return None
+
+    def wait_for(firing):
+        deadline = time.monotonic() + 30.0
+        while time.monotonic() < deadline:
+            alert = fast_alert()
+            if alert is not None and bool(alert["firing"]) == firing:
+                return True
+            time.sleep(0.05)
+        return False
+
+    def injector(seed):
+        # continuous completions keep the scaled short window
+        # populated: an empty window reads as burn 0
+        i = 0
+        while not stop.is_set():
+            try:
+                fleet.infer({"x": _rows(1, seed=seed + i)},
+                            timeout_ms=30000)
+            except Exception:  # noqa: BLE001 — only completions matter
+                pass
+            i += 1
+
+    injectors = [threading.Thread(target=injector, args=(100 * i,))
+                 for i in range(4)]
+    try:
+        with faults.armed("fleet.dispatch=delay:0.3"):
+            for t in injectors:
+                t.start()
+            assert wait_for(True), "fast-burn alert never fired in /sloz"
+        assert wait_for(False), "fast-burn alert never cleared in /sloz"
+    finally:
+        stop.set()
+        for t in injectors:
+            t.join(timeout=30)
+        events = json.loads(_admin_get(addr, "/eventz")[1])["events"]
+        slo_mod.uninstall()
+        fleet.stop()
+        for sp in sps:
+            sp.stop()
+    transitions = {
+        e["kind"]: e for e in events
+        if e.get("kind") in ("slo/fired", "slo/cleared")
+        and e.get("slo") == slo_name and e.get("pair") == "fast"}
+    assert set(transitions) == {"slo/fired", "slo/cleared"}, events
+    assert transitions["slo/fired"]["severity"] == "critical"
 
 
 # ---------------------------------------------------------------------------

@@ -4,6 +4,7 @@ manifest ride, per-request fp32 opt-out, and the zero-recompile
 guarantee across both compiled ladders.
 """
 import os
+import threading
 
 import numpy as np
 import pytest
@@ -69,7 +70,7 @@ _LM_V, _LM_D, _LM_S = 128, 16, 8
 
 def _build_lm():
     """The transformer-LM decode endpoint's logits program (the same
-    family bench_serving --sharded serves)."""
+    family tests/test_sharded_serving.py serves as a tp group)."""
     ids = fluid.layers.data("src_ids", [_LM_S], dtype="int64")
     _, logits = models.transformer_lm(
         ids, None, vocab_size=_LM_V, d_model=_LM_D, n_layer=1, n_head=2,
@@ -309,3 +310,50 @@ def test_wire_loopback_precision(tmp_path):
     finally:
         cli.close()
         sp.stop(drain=True)
+
+
+def test_fleet_children_rebuild_the_variant_and_never_recompile(tmp_path):
+    """A REAL 2-child wire fleet over one bf16-manifest endpoint dir:
+    every child reconstructs the variant from the manifest (its
+    ``/healthz`` advertises it), the fleet-wide warmup compiles both
+    ladders in both processes, and after a storm mixing policy-default
+    and fp32-opt-out requests each child's ``/statusz`` counts zero
+    recompiles."""
+    from paddle_tpu.serving import wire
+
+    d = _export(tmp_path / "ep", _build_lenet, precision={"dtype": "bf16"})
+    fleet = wire.FleetBalancer.from_launch(
+        d, 2, name="prec-fleet",
+        launch_kwargs={"max_batch_size": 4, "batch_timeout_ms": 2})
+    try:
+        fleet.warmup()
+        for be in fleet._backends:
+            h = be.transport.get_json("/healthz")
+            assert h["precision"] == "bf16"
+            assert h["precision_dtypes"] == ["bf16", "fp32"]
+        errors = []
+
+        def storm(t):
+            try:
+                for i in range(8):
+                    n = 1 + (t + i) % 4
+                    kw = {"precision": "fp32"} if (t + i) % 4 == 0 else {}
+                    (out,) = fleet.infer(
+                        _lenet_feed(n=n, seed=10 * t + i), **kw)
+                    assert out.shape[0] == n
+            except Exception as e:  # noqa: BLE001 — assertion target
+                errors.append(e)
+
+        threads = [threading.Thread(target=storm, args=(t,))
+                   for t in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert errors == []
+        for be in fleet._backends:
+            status = be.transport.get_json("/statusz")
+            assert status["metrics"]["completed"] > 0  # both children served
+            assert status["metrics"]["recompiles"] == 0, status["metrics"]
+    finally:
+        fleet.stop(shutdown_backends=True)

@@ -674,6 +674,10 @@ def test_fleet_two_children_all_modes_zero_recompiles(
             pc = h.get("prefix_cache") or {}
             hits += int(pc.get("hits", 0))
         assert hits >= 1
+        # ... because the balancer sent the returning head back to the
+        # child that held it
+        assert sum(s["affinity_hits"]
+                   for s in fb.backend_stats().values()) >= 1
         # the whole storm compiled nothing after warmup, on BOTH
         # children — /statusz is the ground truth
         for be in fb._backends:

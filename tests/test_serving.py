@@ -276,6 +276,12 @@ def test_zero_recompiles_after_warmup_mixed_concurrent_sizes(predictor):
         m = server.metrics()
         assert m["recompiles"] == 0
         assert m["completed"] == len(sizes)
+        # the registry's series says the same (read before stop(),
+        # which retires this server's series from the exposition)
+        from paddle_tpu import monitor
+
+        assert monitor.counter_value(
+            "serving_recompiles_total", default=-1, server="warm") == 0
     finally:
         server.stop()
 

@@ -244,6 +244,8 @@ def test_shard_wise_checkpoint_resume_and_teardown(lm, golden, tmp_path):
             fetch_list=[lm["loss"]], checkpoint_dir=run_dir,
             checkpoint_every=4, resume_from=run_dir)
         assert exe.last_resume_step == 8
+        # the same mesh re-places each shard directly: no exchange
+        assert exe.last_restore_stats["exchanged"] == 0
     resumed = [float(np.asarray(o[0])) for o in out]
     assert len(resumed) == STEPS - 8
     np.testing.assert_allclose(resumed, golden[8:], rtol=2e-4)
