@@ -134,6 +134,13 @@ RING_LOWERED = _registry.REGISTRY.counter(
     "at its position modulo the window) | rows (K fresh rows that read "
     "the old ring and themselves before they overwrite it)", ("form",))
 
+ROWS_LOWERED = _registry.REGISTRY.counter(
+    "decode_attention_rows_lowered_total",
+    "appends-and-reads of K > 1 fresh rows a slot lowered (a speculative "
+    "round's verify, a drafting module's pass), by the kind of leaf they "
+    "read: ring (the old ring's rows and the fresh ones, "
+    "_ring_rows_attention) | sequence (the whole rung, masked: the XLA "
+    "form; no kernel takes K rows)", ("leaf",))
 GROUPED_LOWERED = _registry.REGISTRY.counter(
     "decode_attention_grouped_lowered_total",
     "one-row appends-and-reads of grouped heads (fewer K/V heads than "
@@ -626,6 +633,48 @@ def _ring_rows_attention(q, k_new, v_new, kv, ts, *, n_head, n_kv_head,
     return jnp.where(live[:, None, None], ctx.reshape(q.shape), 0.0), kv
 
 
+def _grouped_rows_attention(q, k_new, v_new, kv, ts, *, n_head, n_kv_head,
+                            scale):
+    """``K`` fresh rows a slot of GROUPED heads over sequence leaves:
+    the contract of :func:`grouped_masked_decode_attention`, with the
+    ``K`` rows of a slot laid beside the ``rep`` query heads of their
+    K/V head (``[S, n_kv_head, K * rep, Dh]``), so that both products
+    have the one-row form's shape and read the leaves AS THEY LIE.  With
+    the rows on an axis of their own the compiler re-lays the leaves out
+    instead — a copy of the whole rung, K and V, every layer and round
+    (seen in the compiled round of ``k_exaone_236b_a23b``: four 1.07 GB
+    copies and 2.4 GB of temporaries a call where this form has 0.54
+    GB, PR 47)."""
+    import jax
+    import jax.numpy as jnp
+
+    S, T, Dkv = kv["k"].shape
+    K = q.shape[1]
+    heads = (n_kv_head, Dkv // n_kv_head)
+    rep = n_head // n_kv_head
+    dt = jnp.float32 if "k_scale" in kv else kv["k"].dtype
+    live = ts >= 0
+    pos = ts[:, None] + jnp.arange(K)[None, :]
+    at = jnp.where(live[:, None], pos, T)   # idle -> out of range, dropped
+    rows = jnp.arange(S)[:, None]
+    kv = {**_append(kv, "k", k_new, rows, at, heads),
+          **_append(kv, "v", v_new, rows, at, heads)}
+    ok = jnp.arange(T)[None, None, :] <= pos[..., None]         # [S, K, T]
+    ok = jnp.broadcast_to(ok[:, None, :, None, :],
+                          (S, n_kv_head, K, rep, T)).reshape(
+                              S, n_kv_head, K * rep, T)
+    qg = (q * scale).astype(dt).reshape(S, K, n_kv_head, rep, heads[1])
+    qg = jnp.swapaxes(qg, 1, 2).reshape(S, n_kv_head, K * rep, heads[1])
+    scores = jnp.einsum("sgrd,stgd->sgrt", qg, _read(kv, "k", heads),
+                        preferred_element_type=jnp.float32)
+    w = jax.nn.softmax(jnp.where(ok, scores, -1e9), axis=-1)
+    ctx = jnp.einsum("sgrt,stgd->sgrd", w.astype(dt), _read(kv, "v", heads),
+                     preferred_element_type=jnp.float32)
+    ctx = jnp.swapaxes(ctx.reshape(S, n_kv_head, K, rep, heads[1]),
+                       1, 2).reshape(q.shape)
+    return jnp.where(live[:, None, None], ctx, 0.0), kv
+
+
 def grouped_masked_decode_attention(q, k_new, v_new, kv, ts,
                                     *, n_head: int, n_kv_head: int,
                                     scale: float, window=None):
@@ -650,12 +699,19 @@ def grouped_masked_decode_attention(q, k_new, v_new, kv, ts,
     import jax
     import jax.numpy as jnp
 
+    if q.ndim == 3:
+        ROWS_LOWERED.labels(
+            leaf="sequence" if window is None else "ring").inc()
     if window is not None:
         RING_LOWERED.labels(form="step" if q.ndim == 2 else "rows").inc()
         if q.ndim == 3:
             return _ring_rows_attention(
                 q, k_new, v_new, kv, ts, n_head=n_head, n_kv_head=n_kv_head,
                 scale=scale, window=int(window))
+    elif q.ndim == 3 and n_kv_head < n_head:
+        return _grouped_rows_attention(
+            q, k_new, v_new, kv, ts, n_head=n_head, n_kv_head=n_kv_head,
+            scale=scale)
     S, T, Dkv = kv["k"].shape
     heads = (n_kv_head, Dkv // n_kv_head)
     rep = n_head // n_kv_head

@@ -74,6 +74,7 @@ __all__ = [
     "make_sparse_linear_lm_pooled_step_fn",
     "make_routed_conv_lm_pooled_step_fn",
     "make_windowed_routed_lm_pooled_step_fn",
+    "make_mtp_routed_lm_pooled_step_fn",
     "cache_leaf_seq_axes", "cache_leaf_seq_strides", "cache_leaf_seq_windows",
     "cache_leaf_slotless", "NO_SLOT_AXIS",
     "recurrent_leaf_names", "ring_leaf_names",
@@ -1279,6 +1280,292 @@ def make_windowed_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
     return step_fn, make_cache, prefill_fn
 
 
+def make_mtp_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
+                                       kv_dtype: str = "bf16", held=None,
+                                       prefill_tokens: int = 512):
+    """The slot-pooled step, the K-wide verify, the multi-token-
+    prediction module's K-wide pass and the chunked prefill of a decoder
+    whose blocks are grouped-query attention over a short sliding WINDOW
+    (rotary) or the whole context (no positions), each branch closed by
+    its norm, then a dense SwiGLU or routed experts BESIDE A SHARED
+    EXPERT, with ONE module that drafts for the model from its own last
+    hidden state (``model_type: exaone_moe``; the parts and the
+    equations are ``paddle_tpu.mtp_routed_lm``, the expert layer
+    ``paddle_tpu.routed_experts``).
+
+    Returns ``(step_fn, make_cache, prefill_fn)`` with the contract of
+    :func:`make_windowed_routed_lm_pooled_step_fn`.  ``state``: weights
+    under ``mtp_routed_lm.param_shapes(cfg, held=held)``, multiplied in
+    the dtype they are given (router, bias and norms float32); ``held``:
+    the contiguous range of experts whose matrices ``state`` holds —
+    every sparse layer routes over all ``num_experts_all`` and adds what
+    the held ones give, plus its shared expert.
+
+    What a self-drafting round needs rides ``make_cache`` (both ``None``
+    where the configuration has no module):
+
+    * ``make_cache.verify_fn(cache, tokens [S, K], ts [S]) -> (logits
+      [S, K, V], hidden [S, K, d_model], cache)``: IS the step at ``K``
+      fresh rows a slot (all ``K`` written before any is read, row ``j``
+      at position ``ts + j``), and also yields the last block's output;
+    * ``make_cache.mtp_fn(cache, hidden [S, K, d_model], next_tokens
+      [S, K], ts [S]) -> (logits [S, K, V], cache)``: the module at
+      positions ``ts .. ts + K - 1``, row ``j`` fed ``hidden[:, j]`` and
+      the embedding of ``next_tokens[:, j]`` (the token at ``ts + j +
+      1``), through its own block over its own leaves, the model's final
+      norm and head: logits for the token at ``ts + j + 2``.
+
+    The cache is ``{"layers": [...], "mtp": {...}, "expert_stats": ...}``:
+    a window layer's ``k``, ``v`` are RING leaves of ``sliding_window``
+    rows, a global layer's and the module's sequence leaves of the length
+    rung (``make_cache.leaf_seq_windows``); ``expert_stats`` ``[sparse
+    layers + 1, 4]`` int32 (``NO_SLOT_AXIS``), the module's expert layer
+    in the last row — a step or a verify counts every row it computed,
+    drafted ones included; the plain step leaves the module's leaves and
+    its row of counts alone.
+
+    ``prefill_fn(cache, row, tokens [C + 1], start, n_valid) -> cache``
+    feeds slot ``row`` ``C = prefill_tokens`` prompt tokens through every
+    layer AND the module (``prefill_fn.lookahead`` = 1: the module's row
+    at position ``i`` needs the token at ``i + 1``, so the pool hands it
+    one token more than it feeds).  A ring row is taken from the chunk
+    where the chunk holds the last position of that row and kept
+    otherwise, so the window may be SMALLER than the chunk (128 under
+    512) or larger; ``start`` is a multiple of ``C``.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import mtp_routed_lm as mr
+    from paddle_tpu import routed_experts as rx
+    from paddle_tpu.decode_attention import (kv_leaves, make_decode_attention,
+                                             ring_positions,
+                                             step_positions_read)
+
+    d = mr.dims(cfg)
+    kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
+    W = {k: jnp.asarray(v) for k, v in state.items()}
+    C = int(prefill_tokens)
+    scale = 1.0 / float(np.sqrt(d.head_dim))
+    global_at = [i for i, kind in enumerate(d.kinds) if kind == mr.GLOBAL]
+    window_at = [i for i, kind in enumerate(d.kinds) if kind == mr.WINDOW]
+    if not (global_at or d.n_mtp):
+        raise ValueError("no layer holds the whole length rung")
+    n_stats = len(rx.STAT_NAMES)
+    stat_rows = len(d.expert_layers) + (1 if d.n_mtp else 0)
+    p_mtp = mr.layer_prefix(name, mr.MTP_LAYER)
+    f32 = jnp.float32
+
+    def make_cache(n_rows: int, seq_len: int):
+        cache = {
+            "layers": [
+                kv_leaves(n_rows, seq_len, d.n_kv_head, d.head_dim, kv,
+                          window=d.window if kind == mr.WINDOW else None)
+                for kind in d.kinds],
+            "expert_stats": jnp.zeros((stat_rows, n_stats), jnp.int32)}
+        if d.n_mtp:
+            cache["mtp"] = kv_leaves(n_rows, seq_len, d.n_kv_head,
+                                     d.head_dim, kv)
+        return cache
+
+    make_cache.leaf_seq_axes = {
+        "layers": [{"k": 1, "v": 1} for _ in d.kinds],
+        "expert_stats": NO_SLOT_AXIS}
+    make_cache.leaf_seq_windows = {
+        "layers": [{"k": d.window, "v": d.window} if kind == mr.WINDOW
+                   else {"k": 0, "v": 0} for kind in d.kinds],
+        "expert_stats": 0}
+    if d.n_mtp:
+        make_cache.leaf_seq_axes["mtp"] = {"k": 1, "v": 1}
+        make_cache.leaf_seq_windows["mtp"] = {"k": 0, "v": 0}
+    make_cache.expert_stats = lambda cache: cache["expert_stats"]
+    make_cache.n_expert = (d.n_expert if held is None
+                           else int(held[1]) - int(held[0]))
+    make_cache.window_layers = len(window_at)
+    make_cache.window_positions_read = lambda n: np.minimum(n, d.window)
+    make_cache.kv_positions_read = functools.partial(
+        step_positions_read, width=d.d_kv, dtype=kv, n_head=d.n_head,
+        n_kv_head=d.n_kv_head)
+
+    def rung_of(cache):
+        whole = cache["mtp"] if d.n_mtp else cache["layers"][global_at[0]]
+        return whole["k"].shape[1]
+
+    def rows_of(ts, shape):
+        """``(ts_rows [N], pos_rows [N])`` of a ``shape`` = ``[S]`` or
+        ``[S, K]`` of fresh rows: row ``j`` of a live slot sits at ``ts +
+        j``; an idle slot's rows are ``< 0`` in ``ts_rows``."""
+        pos = jnp.maximum(ts, 0)
+        if len(shape) == 2:
+            pos = pos[:, None] + jnp.arange(shape[1])[None, :]
+            ts = jnp.where(ts[:, None] >= 0, pos, -1)
+        return ts.reshape(-1), pos.reshape(-1)
+
+    def block(h, c, p, kind, dense, attend, shape, ts_rows, pos_rows):
+        """One block over the fresh rows ``h`` ``[N, d_model]`` (``N`` =
+        the product of ``shape``); returns ``(h, leaves, stats)``."""
+        n = h.shape[0]
+        q, k, v = mr.attention_inputs(h, W, p, kind, pos_rows, d)
+        by = tuple(shape) + (-1,)
+        with jax.named_scope(mr.WINDOW_ATTEND_SCOPE if kind == mr.WINDOW
+                             else mr.GLOBAL_ATTEND_SCOPE):
+            ctx, kvs = attend(q.reshape(by), k.reshape(by), v.reshape(by), c)
+        h = h + mr.rms_norm(mr.linear(ctx.reshape(n, -1), W[p + "attn_o"]),
+                            W[p + "post_attn_norm"], d.eps)
+        return close_ffn(h, p, dense, ts_rows) + (kvs,)
+
+    def close_ffn(h, p, dense, ts_rows):
+        if dense:
+            y, st = mr.swiglu(h, W[p + "ffn_gate"], W[p + "ffn_up"],
+                              W[p + "ffn_down"], 1.0, 1.0), None
+        else:
+            y, st = rx.expert_layer(h, W, p, ts_rows, d, held)
+        return h + mr.rms_norm(y, W[p + "post_ffn_norm"], d.eps), st
+
+    def head(h):
+        return mr.linear(mr.rms_norm(h, W[name + "_final_norm"], d.eps),
+                         W[name + "_head"])
+
+    def counted(cache, stats=None, module=None):
+        """``expert_stats`` advanced by the sparse layers' ``stats`` and
+        the module's (None: as they were)."""
+        zero = jnp.zeros(n_stats, jnp.int32)
+        add = (list(stats) if stats is not None
+               else [zero] * len(d.expert_layers))
+        if d.n_mtp:
+            add.append(zero if module is None else module)
+        return (cache["expert_stats"] + jnp.stack(add) if add
+                else cache["expert_stats"])
+
+    def forward(cache, tokens, ts):
+        """The layers over ``tokens`` ``[S]`` (one fresh row a slot: the
+        kernels where they exist) or ``[S, K]``."""
+        layers = cache["layers"]
+        ts = jnp.minimum(ts, rung_of(cache) - 1)
+        attend = {}
+        if global_at:
+            attend[mr.GLOBAL] = make_decode_attention(
+                ts, layers[global_at[0]], n_head=d.n_head,
+                n_kv_head=d.n_kv_head, scale=scale)
+        if window_at:
+            attend[mr.WINDOW] = make_decode_attention(
+                ts, layers[window_at[0]], n_head=d.n_head,
+                n_kv_head=d.n_kv_head, scale=scale, window=d.window)
+        ts_rows, pos_rows = rows_of(ts, tokens.shape)
+        h = W[name + "_emb"][tokens.reshape(-1)].astype(f32)
+        new_layers, stats = [], []
+        for i, kind in enumerate(d.kinds):
+            h, st, kvs = block(h, layers[i], mr.layer_prefix(name, i), kind,
+                               d.dense[i], attend[kind], tokens.shape,
+                               ts_rows, pos_rows)
+            new_layers.append(kvs)
+            if st is not None:
+                stats.append(st)
+        out = dict(cache, layers=new_layers,
+                   expert_stats=counted(cache, stats))
+        by = tuple(tokens.shape) + (-1,)
+        return head(h).reshape(by), h.reshape(by), out
+
+    def step_fn(cache, tokens, ts):
+        logits, _, cache = forward(cache, tokens, ts)
+        return logits, cache
+
+    def verify_fn(cache, tokens, ts):
+        with jax.named_scope(mr.SPEC_VERIFY_SCOPE):
+            return forward(cache, tokens, ts)
+
+    def mtp_fn(cache, hidden, next_tokens, ts):
+        with jax.named_scope(mr.MTP_MODULE_SCOPE):
+            ts = jnp.minimum(ts, rung_of(cache) - 1)
+            shape = next_tokens.shape
+            ts_rows, pos_rows = rows_of(ts, shape)
+            u = mr.module_input(
+                hidden.reshape(-1, d.d_model),
+                W[name + "_emb"][next_tokens.reshape(-1)].astype(f32),
+                W, p_mtp, d)
+            attend = make_decode_attention(
+                ts, cache["mtp"], n_head=d.n_head, n_kv_head=d.n_kv_head,
+                scale=scale)
+            u, st, kvs = block(u, cache["mtp"], p_mtp, mr.GLOBAL, False,
+                               attend, shape, ts_rows, pos_rows)
+            out = dict(cache, mtp=kvs,
+                       expert_stats=counted(cache, None, st))
+            return head(u).reshape(tuple(shape) + (-1,)), out
+
+    def prefill_layer(c, kind, dense, h, p, row, start, n_valid, pos, ts_q):
+        q, k, v = mr.attention_inputs(h, W, p, kind, pos, d)
+        rows = c["k"].shape[1]
+        old = {leaf: jax.lax.dynamic_index_in_dim(c[leaf], row, 0,
+                                                  keepdims=False)
+               for leaf in ("k", "v")}
+        fresh = {"k": k.reshape(C, -1).astype(kv), "v": v.astype(kv)}
+        new = {}
+        if kind == mr.WINDOW:
+            # a ring row takes the chunk's row where the chunk holds the
+            # last position that row will hold, and stays otherwise
+            holds = ring_positions(jnp.asarray(start + n_valid), rows)
+            take = (holds >= start)[:, None]
+            src = jnp.clip(holds - start, 0, C - 1)
+            for leaf in ("k", "v"):
+                new[leaf] = jax.lax.dynamic_update_slice(
+                    c[leaf], jnp.where(take, fresh[leaf][src],
+                                       old[leaf])[None], (row, 0, 0))
+            # the OLD ring's rows at the positions they hold, then the
+            # chunk's own
+            with jax.named_scope(mr.WINDOW_ATTEND_SCOPE):
+                o = mr.chunk_attend(
+                    q, jnp.concatenate([old["k"], fresh["k"]]),
+                    jnp.concatenate([old["v"], fresh["v"]]), ts_q,
+                    jnp.concatenate([ring_positions(jnp.asarray(start),
+                                                    rows), ts_q]),
+                    rows + C, d, window=d.window)
+        else:
+            live = (ts_q >= 0)[:, None]
+            for leaf in ("k", "v"):
+                kept = jax.lax.dynamic_slice(old[leaf], (start, 0),
+                                             (C, d.d_kv))
+                new[leaf] = jax.lax.dynamic_update_slice(
+                    c[leaf], jnp.where(live, fresh[leaf], kept)[None],
+                    (row, start, 0))
+            with jax.named_scope(mr.GLOBAL_ATTEND_SCOPE):
+                o = mr.chunk_attend(
+                    q, jax.lax.dynamic_index_in_dim(new["k"], row, 0, False),
+                    jax.lax.dynamic_index_in_dim(new["v"], row, 0, False),
+                    ts_q, jnp.arange(rows), start + n_valid, d)
+        h = h + mr.rms_norm(mr.linear(o, W[p + "attn_o"]),
+                            W[p + "post_attn_norm"], d.eps)
+        return close_ffn(h, p, dense, ts_q)[0], new
+
+    def prefill_fn(cache, row, tokens, start, n_valid):
+        with jax.named_scope(mr.PREFILL_CHUNK_SCOPE):
+            pos = start + jnp.arange(C)
+            ts_q = jnp.where(jnp.arange(C) < n_valid, pos, -1)
+            emb = W[name + "_emb"]
+            h = emb[tokens[:C]].astype(f32)
+            new_layers = []
+            for i, kind in enumerate(d.kinds):
+                h, new = prefill_layer(
+                    cache["layers"][i], kind, d.dense[i], h,
+                    mr.layer_prefix(name, i), row, start, n_valid, pos, ts_q)
+                new_layers.append(new)
+            out = dict(cache, layers=new_layers)
+            if d.n_mtp:
+                with jax.named_scope(mr.MTP_MODULE_SCOPE):
+                    u = mr.module_input(h, emb[tokens[1:]].astype(f32), W,
+                                        p_mtp, d)
+                    _, out["mtp"] = prefill_layer(
+                        cache["mtp"], mr.GLOBAL, False, u, p_mtp, row,
+                        start, n_valid, pos, ts_q)
+            return out
+
+    prefill_fn.chunk_tokens = C
+    prefill_fn.lookahead = 1 if d.n_mtp else 0
+    make_cache.prefill_fn = prefill_fn
+    make_cache.verify_fn = verify_fn
+    make_cache.mtp_fn = mtp_fn if d.n_mtp else None
+    return step_fn, make_cache, prefill_fn
+
+
 def make_transformer_lm_pooled_verify_fn(
     state,
     vocab_size: int,
@@ -1580,14 +1867,17 @@ def cache_leaf_seq_windows(make_cache, leaves):
     return [w or None for w in windows]
 
 
-def recurrent_leaf_names(make_cache):
+def recurrent_leaf_names(make_cache, slotless: bool = True):
     """Tree paths of the leaves ``make_cache`` declares recurrent (no
-    sequence axis: ``-1`` in ``make_cache.leaf_seq_axes``)."""
+    sequence axis: ``-1`` in ``make_cache.leaf_seq_axes``);
+    ``slotless=False`` leaves out those declared :data:`NO_SLOT_AXIS`
+    (counts a step keeps for the whole pool: no sequence's state)."""
     import jax
 
     return [jax.tree_util.keystr(path) for path, a in
             jax.tree_util.tree_flatten_with_path(
-                _declared_seq_axes(make_cache))[0] if int(a) < 0]
+                _declared_seq_axes(make_cache))[0]
+            if int(a) < 0 and (slotless or int(a) != NO_SLOT_AXIS)]
 
 
 def ring_leaf_names(make_cache):

@@ -40,6 +40,15 @@ layer (tests/test_routed_experts.py).  The experts' product is GROUPED:
 the (row, choice) pairs sorted by expert, each expert's rows against its
 own matrices once (``paddle_tpu.grouped_matmul``), never every row
 against every expert.
+
+A layer may also have a SHARED expert (``d.n_shared`` > 0;
+``paddle_tpu.mtp_routed_lm``): a gated FFN of the same width that EVERY
+row takes, unweighed, added after the routed sum — ``shared_w13``
+``[d_model, 2 * n_shared * width]`` and ``shared_w2`` beside the routed
+matrices (:func:`shared_expert`).  Every chip of an expert-parallel
+deployment holds it and computes the same, so where shares are summed it
+counts ONCE: ``expert_layer(..., shared=False)`` gives a share's routed
+part alone.
 """
 from __future__ import annotations
 
@@ -51,8 +60,9 @@ from paddle_tpu.hybrid_ssm import (linear, rms_norm, rotary, starts_fresh,
                                    swiglu)
 
 __all__ = ["dims", "param_shapes", "random_state", "route", "dispatch",
-           "expert_layer", "short_conv_step", "CONV", "ATTENTION",
-           "ROUTE_SCOPE", "EXPERTS_SCOPE", "SHORT_CONV_SCOPE", "STAT_NAMES",
+           "expert_layer", "shared_expert", "short_conv_step", "CONV",
+           "ATTENTION", "ROUTE_SCOPE", "EXPERTS_SCOPE", "SHARED_EXPERT_SCOPE",
+           "SHORT_CONV_SCOPE", "STAT_NAMES",
            "SIGMOID_BIAS", "SOFTMAX_CHOSEN", "SILU", "RELU",
            "linear", "rms_norm", "rotary", "swiglu", "starts_fresh"]
 
@@ -61,6 +71,7 @@ CONV, ATTENTION = "conv", "full_attention"
 #: ``jax.named_scope`` names, for the device trace
 ROUTE_SCOPE = "moe_route"
 EXPERTS_SCOPE = "moe_experts"
+SHARED_EXPERT_SCOPE = "shared_expert"
 SHORT_CONV_SCOPE = "short_conv"
 
 #: what :func:`expert_layer` counts of one step, in this order: (row,
@@ -225,7 +236,22 @@ def dispatch(sel, live, held, n_expert: int):
     return order, sizes, kept
 
 
-def expert_layer(f, w, p: str, ts, d, held=None, router_input=None):
+def shared_expert(f, w, p: str, d):
+    """The shared expert's term for every row of ``f`` ``[N, d_model]``:
+    ``W2 (act(W1 f) * W3 f)`` with ``shared_w13`` / ``shared_w2``,
+    unweighed; ``[N, d_model]`` float32."""
+    import jax
+
+    with jax.named_scope(SHARED_EXPERT_SCOPE):
+        w13, w2 = w[p + "shared_w13"], w[p + "shared_w2"]
+        gu = linear(f, w13)
+        half = w13.shape[-1] // 2
+        act_fn = jax.nn.relu if d.gate_act == RELU else jax.nn.silu
+        return linear(act_fn(gu[:, :half]) * gu[:, half:], w2)
+
+
+def expert_layer(f, w, p: str, ts, d, held=None, router_input=None,
+                 shared: bool = True):
     """The held experts' part of a mixture layer for one token per row.
 
     ``f`` ``[N, d_model]`` (the normed residual); ``w`` the weight dict,
@@ -235,8 +261,10 @@ def expert_layer(f, w, p: str, ts, d, held=None, router_input=None):
     ``[N]`` (``< 0`` idle: routed nowhere, counted nowhere); ``held``
     ``(lo, hi)``, default all; ``router_input`` ``[N, d_model]``: what
     the router reads where that is not ``f`` (a router placed before
-    attention reads the block's normed input).  Returns ``(out [N,
-    d_model] float32, stats [4] int32)`` with ``stats`` as
+    attention reads the block's normed input); ``shared``: whether the
+    layer's shared expert (``d.n_shared``; none: nothing to add) is
+    added here — False for a share that is summed with others.  Returns
+    ``(out [N, d_model] float32, stats [4] int32)`` with ``stats`` as
     :data:`STAT_NAMES`.
 
     The sorted pairs are padded to a whole ``grouped_matmul.ROW_TILE``
@@ -276,6 +304,8 @@ def expert_layer(f, w, p: str, ts, d, held=None, router_input=None):
                       axis=1)
         stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
                            jnp.max(sizes), jnp.any(live).astype(jnp.int32)])
+    if shared and getattr(d, "n_shared", 0):
+        out = out + shared_expert(f, w, p, d)
     return out, stats.astype(jnp.int32)
 
 

@@ -86,7 +86,12 @@ Decode tier 2 (both independently toggleable, see README):
 * ``speculative=`` attaches a :class:`serving.speculative.
   SpeculativeConfig` — requests submitted with ``speculative=True``
   run draft-then-verify rounds, greedy-exact (output-identical) with
-  acceptance telemetry.
+  acceptance telemetry.  TWO kinds of draft: a separate small model
+  with a cache of its own, or (:class:`serving.speculative.
+  SelfDraftConfig`) the target's own multi-token-prediction module,
+  whose proposals a request may ask to keep (``keep_drafts=True``:
+  ``DecodeRequest.draft_tokens`` at completion, one more fetch for
+  that request alone).
 """
 from __future__ import annotations
 
@@ -120,6 +125,8 @@ from paddle_tpu.serving.prefix_cache import PrefixKVCache
 from paddle_tpu.serving.speculative import (
     SPEC_ACCEPTED,
     SPEC_PROPOSED,
+    SPEC_ROUNDS,
+    SPEC_ROW_ROUNDS,
     dispatch_spec_chunk,
 )
 
@@ -310,7 +317,8 @@ class DecodeRequest(ServingRequest):
                  trace_id: Optional[str] = None,
                  parent_span: Optional[str] = None,
                  priority: int = PRIORITY_NORMAL,
-                 speculative: bool = False):
+                 speculative: bool = False,
+                 keep_drafts: bool = False):
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         super().__init__({"tokens": prompt[None, :]}, 1, deadline,
                          trace_id=trace_id, parent_span=parent_span,
@@ -318,6 +326,10 @@ class DecodeRequest(ServingRequest):
         self.prompt = prompt
         self.total_len = int(total_len)
         self.speculative = bool(speculative)
+        #: a self-drafting server's proposals for this request's
+        #: generated positions, on request (None otherwise)
+        self.keep_drafts = bool(keep_drafts)
+        self.draft_tokens: Optional[np.ndarray] = None
         self.first_token_t: Optional[float] = None  # perf_counter stamp
         self._chunks: "queue.Queue" = queue.Queue()
 
@@ -546,6 +558,8 @@ class DecodeServer:
         if speculative is not None:
             self._spec_proposed_c = SPEC_PROPOSED.labels(**lbl)
             self._spec_accepted_c = SPEC_ACCEPTED.labels(**lbl)
+            self._spec_rounds_c = SPEC_ROUNDS.labels(**lbl)
+            self._spec_row_rounds_c = SPEC_ROW_ROUNDS.labels(**lbl)
         self._pool = KVSlotPool(
             step_fn, make_cache, eos_id=eos_id, max_slots=max_slots,
             max_seq_len=max_seq_len, slot_ladder=slot_ladder,
@@ -674,8 +688,11 @@ class DecodeServer:
                 hist = dict(self._accept_len_hist)
             snap["decode"]["speculative"] = {
                 "k": self._speculative.k,
+                "kind": self._speculative.kind,
                 "proposed_tokens": int(self._spec_proposed_c.value),
                 "accepted_tokens": int(self._spec_accepted_c.value),
+                "rounds": int(self._spec_rounds_c.value),
+                "row_rounds": int(self._spec_row_rounds_c.value),
                 "accepted_len_histogram": {
                     str(a): n for a, n in sorted(hist.items())},
             }
@@ -760,7 +777,8 @@ class DecodeServer:
                parent_span: Optional[str] = None,
                priority: int = PRIORITY_NORMAL,
                max_new_tokens: Optional[int] = None,
-               speculative: bool = False) -> DecodeRequest:
+               speculative: bool = False,
+               keep_drafts: bool = False) -> DecodeRequest:
         """Enqueue one prompt; returns its streaming future.
 
         ``feed``: ``{"tokens": [L] or [1, L] int}`` (or the positional
@@ -771,8 +789,11 @@ class DecodeServer:
         ``speculative=True`` opts the request into draft-then-verify
         rounds (requires a server-side ``SpeculativeConfig``; the
         output is bit-identical either way — speculation only changes
-        speed).  Deadline, priority, shedding, and trace-id semantics
-        match ``InferenceServer.submit``."""
+        speed).  ``keep_drafts=True`` (a self-drafting server): the
+        module's proposal for each generated position is fetched when
+        the request completes (``DecodeRequest.draft_tokens``; no other
+        request's tick pays for it).  Deadline, priority, shedding, and
+        trace-id semantics match ``InferenceServer.submit``."""
         if self._closed:
             raise ServerClosed("server %r is stopped" % self.name)
         if speculative and self._speculative is None:
@@ -799,7 +820,8 @@ class DecodeServer:
             if timeout_ms is not None else None)
         req = DecodeRequest(prompt, total, deadline, trace_id=trace_id,
                             parent_span=parent_span, priority=priority,
-                            speculative=bool(speculative))
+                            speculative=bool(speculative),
+                            keep_drafts=bool(keep_drafts and speculative))
         try:
             self._batcher.offer(req)
         except Exception:
@@ -1200,6 +1222,12 @@ class DecodeServer:
             turn.enter("deliver", cpu=True)
             tokens0 = self._tokens_c.value
         fields = {}     # of the deliver span: what the chunk counted
+        self_draft = use_spec and self._speculative.kind == "self"
+        if use_spec and stepped:
+            proposed0 = self._spec_proposed_c.value
+            accepted0 = self._spec_accepted_c.value
+            # before the position counters move ``rec.pos``
+            self._count_spec_round(recs, view, self_draft)
         if stepped:
             if "expert_stats" in view:
                 fields = self._count_experts(view["expert_stats"])
@@ -1223,7 +1251,7 @@ class DecodeServer:
                         exemplar=({"trace_id": rec.req.trace_id}
                                   if rec.req.trace_id else None))
                 rec.req.push_tokens(chunk, now)
-                if use_spec and rec.spec and rec.seen > 0:
+                if use_spec and not self_draft and rec.spec and rec.seen > 0:
                     # a pure decode-phase round (the slot had emitted
                     # before, so no prefill teacher-forcing inflated
                     # ``fresh``): the round's first token is the
@@ -1242,6 +1270,11 @@ class DecodeServer:
                 out = view["tokens"][
                     i, rec.prompt_len:rec.prompt_len + n_gen].copy()
                 self._offer_prefix(i, rec, int(view["pos"][i]))
+                if rec.req.keep_drafts and "proposals" in self._state:
+                    # this request alone pays the fetch, at its end
+                    rec.req.draft_tokens = np.asarray(jax.device_get(
+                        self._state["proposals"]))[
+                            i, rec.prompt_len:rec.prompt_len + n_gen].copy()
                 rec.req.complete([out])
                 self._metrics.observe_request(
                     now - rec.req.submit_t, trace_id=rec.req.trace_id)
@@ -1270,6 +1303,11 @@ class DecodeServer:
             self._occupancy_g.set(
                 self._active_count() / float(len(self._slots)))
         if turn is not None:
+            if use_spec and stepped:
+                fields["proposed"] = int(
+                    self._spec_proposed_c.value - proposed0)
+                fields["accepted"] = int(
+                    self._spec_accepted_c.value - accepted0)
             turn.leave(
                 fresh_tokens=int(self._tokens_c.value - tokens0),
                 finished=sum(1 for i, _ in recs if self._slots[i] is None),
@@ -1317,6 +1355,37 @@ class DecodeServer:
             turn.leave(slot=slot, last=last)
         return True
 
+    def _count_spec_round(self, recs, view, self_draft: bool) -> None:
+        """One speculative round's counters, from the ``pos`` the tick
+        already fetched (before the position counters move ``rec.pos``):
+        the round, the slots it advanced and, where the draft is the
+        target's own module, its proposals and acceptances — a
+        speculative slot whose round began at ``p0`` with the prompt's
+        last token behind or under it (``p0 + 1 >= prompt_len``) consumed
+        ONE drafted token, accepted iff the slot advanced by two; a round
+        that teacher-forced a prompt token as its second row proposed
+        nothing.  (A separate draft model's are counted at delivery, by
+        the tokens a slot emitted.)"""
+        advanced = proposed = accepted = 0
+        for i, rec in recs:
+            gone = int(view["pos"][i]) - rec.pos
+            if gone <= 0:
+                continue
+            advanced += 1
+            if self_draft and rec.spec and rec.pos + 1 >= rec.prompt_len:
+                proposed += 1
+                accepted += gone - 1
+        self._spec_rounds_c.inc()
+        self._spec_row_rounds_c.inc(advanced)
+        if proposed:
+            self._spec_proposed_c.inc(proposed)
+            self._spec_accepted_c.inc(accepted)
+            with self._seq_len_lock:
+                for took, n in ((1, accepted), (0, proposed - accepted)):
+                    if n:
+                        self._accept_len_hist[took] = (
+                            self._accept_len_hist.get(took, 0) + n)
+
     def _count_experts(self, stats) -> Dict[str, float]:
         """Advance the four expert counters by what the chunk just run
         added to the builder's ``expert_stats`` leaf (``[expert layers,
@@ -1350,9 +1419,13 @@ class DecodeServer:
         p0 = np.fromiter((r.pos for _, r in recs), np.int64, len(recs))
         steps = 1 if use_spec else self._pool.steps
         pool = s * t * steps
-        # the steps this chunk ran, row by row: ts = p0 .. p1 - 1
-        ts = p0[:, None] + np.arange(steps)[None, :]
-        ran = ts < p1[:, None]
+        # the steps this chunk ran, row by row: ts = p0 .. p1 - 1; a
+        # speculative round COMPUTED all its k rows for every slot that
+        # advanced, whatever it kept (the sparse and window counters
+        # count reads, not commits)
+        rows = self._speculative.k if use_spec else steps
+        ts = p0[:, None] + np.arange(rows)[None, :]
+        ran = (p1 > p0)[:, None] & (ts < t) if use_spec else ts < p1[:, None]
         if use_spec:
             read = pool  # masked reads over the whole rung
         elif self._kv_rule is not None:
@@ -1451,7 +1524,8 @@ class DecodeServer:
                        DECODE_SPARSE_READ, DECODE_SPARSE_LIVE):
             metric.remove_labels(**lbl)
         if self._speculative is not None:
-            for metric in (SPEC_PROPOSED, SPEC_ACCEPTED):
+            for metric in (SPEC_PROPOSED, SPEC_ACCEPTED, SPEC_ROUNDS,
+                           SPEC_ROW_ROUNDS):
                 metric.remove_labels(**lbl)
         if self._prefix is not None and self._prefix_owned:
             self._prefix.close()

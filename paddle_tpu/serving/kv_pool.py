@@ -37,10 +37,15 @@ ONE rung differ in length.  It is allocated, resized (cut or padded to
 ``min(new rung, W)`` rows), counted by ``kv_rung_bytes`` and carried
 whole by ``snapshot`` / ``admit_prefix`` like any other; what it cannot
 be is SLICED by positions or rolled back (a wrapped row holds a later
-position than the one its index names), so ``extract_kv``, a
+position than the one its index names), so ``extract_kv`` and a
 ``prefix=True`` that would call it (a builder without a chunked
-prefill) and ``speculative=`` are refused over ring leaves, as they are
-over recurrent ones.
+prefill) are refused over ring leaves, as they are over recurrent ones;
+``speculative=`` is carried over ring leaves exactly where a rejected
+round cannot harm, at ``k <= 2``, and refused beyond (a round writes
+ring rows ``pos .. pos + k - 1`` over positions ``pos - W ..``; after a
+rejection at ``j = 1`` the next query, at ``pos + 1``, reads ``pos + 2
+- W ..``, which a third row would have overwritten: ``k > 2`` needs ``k
+- 2`` spare rows a ring does not have).
 
 What else a pool compiles follows from what the builder declares, and
 the two prefills are separate paths below that one chooser (their needs
@@ -151,9 +156,14 @@ class KVSlotPool:
         #: nothing here feeds the draft a prompt
         self._prefill_rows = (getattr(make_cache, "prefill_rows_fn", None)
                               if speculative is None else None)
-        for what, on in (("prefix=True", prefix and self._prefill is None),
-                         ("speculative=", speculative is not None)):
-            if on and self.recurrent_leaves:
+        # a leaf of counts with no slot axis is no sequence's state: a
+        # rejected round has nothing of it to roll back
+        for what, on, leaves in (
+                ("prefix=True", prefix and self._prefill is None,
+                 self.recurrent_leaves),
+                ("speculative=", speculative is not None,
+                 recurrent_leaf_names(make_cache, slotless=False))):
+            if on and leaves:
                 raise ValueError(
                     "KVSlotPool(%s) over a cache with recurrent leaves "
                     "(%s ... %d in all): a recurrent state has no "
@@ -163,24 +173,31 @@ class KVSlotPool:
                     "SNAPSHOT taken at the boundary, which only a "
                     "builder with a chunked prefill "
                     "(make_cache.prefill_fn) can stop at"
-                    % (what, self.recurrent_leaves[0],
-                       len(self.recurrent_leaves)))
+                    % (what, leaves[0], len(leaves)))
         #: tree paths of the cache leaves declared RING leaves (a window
         #: in ``make_cache.leaf_seq_windows``); empty for most builders
         self.ring_leaves = ring_leaf_names(make_cache)
-        for what, on in (("prefix=True", prefix and self._prefill is None),
-                         ("speculative=", speculative is not None)):
-            if on and self.ring_leaves:
-                raise ValueError(
-                    "KVSlotPool(%s) over a cache with ring leaves (%s ... "
-                    "%d in all): position p of a ring leaf lives in row p "
-                    "mod its window, so a wrapped row cannot be sliced as "
-                    "a prefix of positions or rolled back after a rejected "
-                    "round; a prefix over such leaves needs a whole-row "
-                    "SNAPSHOT taken at a boundary, which only a builder "
-                    "with a chunked prefill (make_cache.prefill_fn) can "
-                    "stop at" % (what, self.ring_leaves[0],
-                                 len(self.ring_leaves)))
+        if prefix and self._prefill is None and self.ring_leaves:
+            raise ValueError(
+                "KVSlotPool(prefix=True) over a cache with ring leaves (%s "
+                "... %d in all): position p of a ring leaf lives in row p "
+                "mod its window, so a wrapped row cannot be sliced as a "
+                "prefix of positions; a prefix over such leaves needs a "
+                "whole-row SNAPSHOT taken at a boundary, which only a "
+                "builder with a chunked prefill (make_cache.prefill_fn) "
+                "can stop at" % (self.ring_leaves[0], len(self.ring_leaves)))
+        if (speculative is not None and speculative.k > 2
+                and self.ring_leaves):
+            raise ValueError(
+                "KVSlotPool(speculative= with k = %d) over a cache with "
+                "ring leaves (%s ... %d in all): a round writes ring rows "
+                "pos .. pos + k - 1 over positions pos - W ..; after a "
+                "rejection the next query, at pos + 1, reads pos + 2 - W "
+                "..., which a round of k > 2 rows has overwritten and "
+                "cannot be rolled back: a ring of W rows carries k <= 2, "
+                "more needs k - 2 spare rows"
+                % (speculative.k, self.ring_leaves[0],
+                   len(self.ring_leaves)))
         # the cache storage dtype ``make_cache`` allocates (advertised
         # on /healthz; the pool itself is dtype-agnostic — shapes and
         # dtypes all flow from the state spec, so the int8 rung variant
@@ -224,7 +241,17 @@ class KVSlotPool:
                                  self._kv_seq_strides,
                                  whole_rows=self.snapshots)
             if self.prefix else None)
-        if speculative is not None:
+        #: the draft is the target's own module: its proposal and the
+        #: proposals' record ride the state, no ``draft_cache`` does
+        self._self_draft = (speculative is not None
+                            and speculative.kind == "self")
+        if self._self_draft:
+            from paddle_tpu.serving.speculative import (
+                make_self_draft_chunk_fn)
+
+            self._spec_chunk_fn = make_self_draft_chunk_fn(
+                speculative.verify_fn, speculative.module_fn, self.eos_id)
+        elif speculative is not None:
             from paddle_tpu.serving.speculative import make_spec_chunk_fn
 
             self._spec_chunk_fn = make_spec_chunk_fn(
@@ -308,6 +335,10 @@ class KVSlotPool:
         }
         if self.speculative is not None:
             spec["spec"] = jax.ShapeDtypeStruct((s,), np.dtype(bool))
+        if self._self_draft:
+            spec["draft"] = jax.ShapeDtypeStruct((s,), i32)
+            spec["proposals"] = jax.ShapeDtypeStruct((s, t), i32)
+        elif self.speculative is not None:
             spec["draft_cache"] = jax.eval_shape(
                 lambda: self.speculative.draft_make_cache(s, t))
         for leaf, ax, window in zip(self._kv_subtree_leaves(spec),
@@ -957,7 +988,12 @@ class KVSlotPool:
         import jax.numpy as jnp
 
         c = self.prefill_tokens
-        toks = jax.lax.dynamic_slice(state["tokens"], (row, start), (1, c))[0]
+        # a builder that feeds a drafting module too asks for the token
+        # after the chunk (``lookahead``): a whole chunk is fed only
+        # while a prompt token follows it
+        toks = jax.lax.dynamic_slice(
+            state["tokens"], (row, start),
+            (1, c + getattr(self._prefill, "lookahead", 0)))[0]
         out = dict(state)
         out.update(
             cache=self._prefill(state["cache"], row, toks, start,

@@ -33,9 +33,42 @@ round (their chain dies at ``j = 1`` by construction); the scheduler
 only dispatches ``spec_chunk`` on ticks where some active slot opted
 in, so a pool with speculation enabled but unused runs plain chunks.
 
+A SECOND kind of draft is the target's own multi-token-prediction
+module (:class:`SelfDraftConfig`, DeepSeek-V3's form;
+``decoding.make_mtp_routed_lm_pooled_step_fn``).  It has no token-only
+step and no cache of its own to keep position-synced: it consumes the
+target's last hidden state ``h_i`` with the token ``t_{i+1}`` of
+positions the target has VERIFIED, keeps K/V rows for them among the
+target's own cache leaves, and its output at the last kept position is
+the NEXT round's proposal, carried in the pool state.  The round (``k``
+= 2), per slot with ``pos`` tokens consumed and ``d`` last round's
+proposal:
+
+* consume ``c0 = tokens[pos]`` and ``c1`` = the stored prompt token while
+  ``pos + 1 < prompt_len``, else ``d``; verify ``[c0, c1]`` at ``pos,
+  pos + 1`` in one 2-wide target forward -> ``g0, g1, h0, h1``;
+* ``c1`` is kept iff it is a prompt token or (a speculative slot's)
+  ``d == g0``; emit ``g0``, and ``g1`` if ``c1`` was kept; advance by 1
+  or 2 — the served tokens are the plain step's, token for token;
+* run the module on ``(h0, t_{pos+1})`` and ``(h1, t_{pos+2})`` (the
+  second row is garbage after a rejection: its K/V row is re-written
+  next round before anything reads it); the new ``d`` is its argmax at
+  the last kept row.  Both rows' proposals are also written, by the
+  position they predict, into the state's ``proposals`` ``[S, T]``
+  buffer, which nothing fetches unless a request asked to keep them.
+
+Over RING leaves a rejected round must leave every row a later query
+reads unchanged: a round writes ring rows ``pos .. pos + k - 1`` over
+positions ``pos - W ..``; after a rejection at ``j = 1`` the next query,
+at ``pos + 1``, reads ``pos + 2 - W ..``: with ``k = 2`` nothing it reads
+was overwritten, with ``k >= 3`` position ``pos + 2 - W`` was.
+``KVSlotPool`` therefore carries ``k <= 2`` over a ring and refuses more.
+
 Telemetry: ``serving_spec_tokens_{proposed,accepted}_total`` counters
-(labeled like the decode series) and the per-server accepted-length
-histogram in ``DecodeServer.metrics()``.
+(labeled like the decode series), ``serving_spec_rounds_total`` /
+``serving_spec_row_rounds_total`` (rounds dispatched; slots that
+advanced in one) and the per-server accepted-length histogram in
+``DecodeServer.metrics()``.
 """
 from __future__ import annotations
 
@@ -45,9 +78,11 @@ import numpy as np
 
 from paddle_tpu import monitor
 
-__all__ = ["SpeculativeConfig", "make_lm_speculative",
-           "make_spec_chunk_fn", "dispatch_spec_chunk",
-           "SPEC_PROPOSED", "SPEC_ACCEPTED"]
+__all__ = ["SpeculativeConfig", "SelfDraftConfig", "make_lm_speculative",
+           "make_self_draft", "make_spec_chunk_fn",
+           "make_self_draft_chunk_fn", "dispatch_spec_chunk",
+           "SPEC_PROPOSED", "SPEC_ACCEPTED", "SPEC_ROUNDS",
+           "SPEC_ROW_ROUNDS"]
 
 _LABELS = ("server", "instance")
 SPEC_PROPOSED = monitor.counter(
@@ -59,6 +94,14 @@ SPEC_ACCEPTED = monitor.counter(
     "draft tokens accepted by greedy-exact verification (acceptance "
     "rate = accepted / proposed; the speculative speedup lever)",
     _LABELS)
+SPEC_ROUNDS = monitor.counter(
+    "serving_spec_rounds_total",
+    "speculative rounds dispatched (one spec_chunk executable call: "
+    "every active slot's k rows verified at once)", _LABELS)
+SPEC_ROW_ROUNDS = monitor.counter(
+    "serving_spec_row_rounds_total",
+    "slots that advanced in a speculative round, summed over rounds "
+    "(generated tokens over this = tokens a row a round)", _LABELS)
 
 
 class SpeculativeConfig:
@@ -74,6 +117,9 @@ class SpeculativeConfig:
     ``save_decode_endpoint`` (the per-endpoint ``draft`` block).
     """
 
+    #: what drafts: a separate model with a cache of its own
+    kind = "model"
+
     def __init__(self, verify_fn: Callable, draft_step_fn: Callable,
                  draft_make_cache: Callable, k: int = 4,
                  draft_meta: Optional[Dict[str, object]] = None):
@@ -86,6 +132,48 @@ class SpeculativeConfig:
         self.draft_make_cache = draft_make_cache
         self.k = int(k)
         self.draft_meta = dict(draft_meta or {})
+
+
+class SelfDraftConfig(SpeculativeConfig):
+    """The draft is the target's own multi-token-prediction module.
+
+    ``verify_fn(cache, tokens [S, K], ts [S]) -> (logits [S, K, V],
+    hidden [S, K, D], cache)``: the target's K-wide forward, which also
+    yields the last block's output.  ``module_fn(cache, hidden [S, K,
+    D], next_tokens [S, K], ts [S]) -> (logits [S, K, V], cache)``: the
+    module at positions ``ts .. ts + K - 1`` over K/V leaves that are
+    part of the target's ``cache``.  ``k`` is 2: ONE module proposes one
+    token a round (a chain of modules would carry more)."""
+
+    kind = "self"
+
+    def __init__(self, verify_fn: Callable, module_fn: Callable, k: int = 2,
+                 draft_meta: Optional[Dict[str, object]] = None):
+        if int(k) != 2:
+            raise ValueError(
+                "a self-drafting round verifies k = 2 rows: one module "
+                "proposes one token (a chain of modules is not "
+                "supported), got k = %r" % k)
+        self.verify_fn = verify_fn
+        self.module_fn = module_fn
+        self.draft_step_fn = None
+        self.draft_make_cache = None
+        self.k = 2
+        self.draft_meta = dict(draft_meta or {})
+
+
+def make_self_draft(make_cache) -> SelfDraftConfig:
+    """The :class:`SelfDraftConfig` of a builder that declares its
+    verify and its module (``make_cache.verify_fn`` / ``.mtp_fn``:
+    ``decoding.make_mtp_routed_lm_pooled_step_fn``)."""
+    verify_fn = getattr(make_cache, "verify_fn", None)
+    module_fn = getattr(make_cache, "mtp_fn", None)
+    if verify_fn is None or module_fn is None:
+        raise ValueError(
+            "make_cache declares no verify_fn / mtp_fn: this builder has "
+            "no multi-token-prediction module to draft with")
+    return SelfDraftConfig(verify_fn, module_fn,
+                           draft_meta={"kind": "self", "k": 2})
 
 
 def make_lm_speculative(target_state, *, vocab_size: int, d_model: int,
@@ -124,6 +212,45 @@ def make_lm_speculative(target_state, *, vocab_size: int, d_model: int,
         })
 
 
+def _commit_chain(state, ctoks, g, eos_id: int):
+    """The greedy-exact acceptance chain and its commit, shared by both
+    kinds of round.  ``ctoks`` ``[S, K]`` the tokens consumed at ``pos ..
+    pos + K - 1``, ``g`` ``[S, K]`` the target's argmax after each: a
+    stored prompt token is right by construction, a drafted one must
+    equal the target's own prediction for its position (and only
+    speculative slots draft at all); ``g[:, j]`` is emitted while the
+    chain is alive and past the prompt.  Returns ``(tokens, adv,
+    n_emit, newly_fin)``; the chain is unrolled over ``j`` (``K`` is a
+    compile-time constant)."""
+    import jax.numpy as jnp
+
+    tokens, pos = state["tokens"], state["pos"]
+    prompt_len, total_len = state["prompt_len"], state["total_len"]
+    S, T = tokens.shape
+    rows = jnp.arange(S)
+    alive = state["active"]
+    newly_fin = jnp.zeros((S,), bool)
+    n_emit = jnp.zeros((S,), jnp.int32)
+    adv = jnp.zeros((S,), jnp.int32)
+    for j in range(ctoks.shape[1]):
+        qj = pos + j
+        if j > 0:
+            alive = alive & jnp.where(
+                qj < prompt_len, True,
+                state["spec"] & (ctoks[:, j] == g[:, j - 1]))
+        adv = adv + alive.astype(jnp.int32)
+        wr = qj + 1
+        emit = alive & (wr >= prompt_len) & (wr < total_len)
+        wclamp = jnp.minimum(wr, T - 1)
+        tokens = tokens.at[rows, wclamp].set(
+            jnp.where(emit, g[:, j], tokens[rows, wclamp]))
+        n_emit = n_emit + emit.astype(jnp.int32)
+        fin = emit & ((g[:, j] == eos_id) | ((qj + 2) >= total_len))
+        newly_fin = newly_fin | fin
+        alive = alive & ~fin
+    return tokens, adv, n_emit, newly_fin
+
+
 def make_spec_chunk_fn(verify_fn, draft_step_fn, eos_id: int, k: int):
     """The pure per-round function the pool compiles as ``spec_chunk``
     for each rung pair: draft ``k - 1`` proposals, verify all ``k``
@@ -138,9 +265,7 @@ def make_spec_chunk_fn(verify_fn, draft_step_fn, eos_id: int, k: int):
         tokens = state["tokens"]
         pos = state["pos"]
         active = state["active"]
-        spec = state["spec"]
         prompt_len = state["prompt_len"]
-        total_len = state["total_len"]
         S, T = tokens.shape
         rows = jnp.arange(S)
         # --- draft phase: K sequential small steps.  Consumption c_0 is
@@ -171,33 +296,8 @@ def make_spec_chunk_fn(verify_fn, draft_step_fn, eos_id: int, k: int):
         logits, cache = verify_fn(state["cache"], ctoks,
                                   jnp.where(active, pos, -1))
         g = jnp.argmax(logits, axis=-1).astype("int32")  # [S, K]
-        # --- greedy-exact acceptance chain + commit
-        new_tokens = tokens
-        alive = active
-        newly_fin = jnp.zeros((S,), bool)
-        n_emit = jnp.zeros((S,), jnp.int32)
-        adv = jnp.zeros((S,), jnp.int32)
-        for j in range(K):
-            qj = pos + j
-            if j > 0:
-                # a stored prompt token is correct by construction; a
-                # drafted one must equal the target's own prediction
-                # for its position (and only spec slots draft at all)
-                corr = jnp.where(qj < prompt_len,
-                                 jnp.ones((S,), bool),
-                                 spec & (ctoks[:, j] == g[:, j - 1]))
-                alive = alive & corr
-            adv = adv + alive.astype(jnp.int32)
-            wr = qj + 1
-            emit = alive & (wr >= prompt_len) & (wr < total_len)
-            wclamp = jnp.minimum(wr, T - 1)
-            cur = new_tokens[rows, wclamp]
-            new_tokens = new_tokens.at[rows, wclamp].set(
-                jnp.where(emit, g[:, j], cur))
-            n_emit = n_emit + emit.astype(jnp.int32)
-            fin = emit & ((g[:, j] == eos_id) | ((qj + 2) >= total_len))
-            newly_fin = newly_fin | fin
-            alive = alive & ~fin
+        new_tokens, adv, n_emit, newly_fin = _commit_chain(
+            state, ctoks, g, eos_id)
         out = dict(state)
         out.update(
             cache=cache,
@@ -207,6 +307,64 @@ def make_spec_chunk_fn(verify_fn, draft_step_fn, eos_id: int, k: int):
             active=active & ~newly_fin,
             finished=state["finished"] | newly_fin,
             n_gen=state["n_gen"] + n_emit)
+        return out
+
+    return spec_chunk
+
+
+def make_self_draft_chunk_fn(verify_fn, module_fn, eos_id: int):
+    """The pure per-round function the pool compiles as ``spec_chunk``
+    where the draft is the target's own module
+    (:class:`SelfDraftConfig`; the module docstring has the algebra).
+    State leaves beside :func:`make_spec_chunk_fn`'s: ``draft`` ``[S]``
+    int32 (the proposal for the token at ``pos + 1``) and ``proposals``
+    ``[S, T]`` int32 (the module's argmax by the position it
+    predicts)."""
+    import jax.numpy as jnp
+
+    def spec_chunk(state):
+        tokens = state["tokens"]
+        pos = state["pos"]
+        active = state["active"]
+        prompt_len = state["prompt_len"]
+        S, T = tokens.shape
+        rows = jnp.arange(S)
+
+        def at(buf, q):
+            return buf[rows, jnp.minimum(q, T - 1)]
+
+        forced = (pos + 1) < prompt_len      # c1 is a stored prompt token
+        ctoks = jnp.stack([at(tokens, pos),
+                           jnp.where(forced, at(tokens, pos + 1),
+                                     state["draft"])], axis=1)
+        ts = jnp.where(active, pos, -1)
+        logits, hidden, cache = verify_fn(state["cache"], ctoks, ts)
+        g = jnp.argmax(logits, axis=-1).astype("int32")          # [S, 2]
+        new_tokens, adv, n_emit, newly_fin = _commit_chain(
+            state, ctoks, g, eos_id)
+        # --- the module, on what the target just verified: row j holds
+        # (h_{pos+j}, t_{pos+j+1}) and predicts the token at pos + j + 2
+        nxt = jnp.stack([at(new_tokens, pos + 1), at(new_tokens, pos + 2)],
+                        axis=1)
+        mlogits, cache = module_fn(cache, hidden, nxt, ts)
+        prop = jnp.argmax(mlogits, axis=-1).astype("int32")      # [S, 2]
+        proposals = state["proposals"]
+        for j in range(2):
+            wrote = active if j == 0 else active & (adv >= 2)
+            q = jnp.minimum(pos + j + 2, T - 1)
+            proposals = proposals.at[rows, q].set(
+                jnp.where(wrote, prop[:, j], proposals[rows, q]))
+        out = dict(state)
+        out.update(
+            cache=cache,
+            tokens=new_tokens,
+            pos=pos + adv,
+            active=active & ~newly_fin,
+            finished=state["finished"] | newly_fin,
+            n_gen=state["n_gen"] + n_emit,
+            draft=jnp.where(active, jnp.where(adv >= 2, prop[:, 1],
+                                              prop[:, 0]), state["draft"]),
+            proposals=proposals)
         return out
 
     return spec_chunk

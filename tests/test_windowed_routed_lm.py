@@ -381,7 +381,8 @@ def test_a_snapshot_carries_ring_leaves_whole_and_leaves_the_counts():
     assert np.array_equal(np.asarray(state["tokens"])[1, :40], want)
 
 
-@pytest.mark.parametrize("tier", ["positions", "speculative"])
+@pytest.mark.parametrize("tier", ["positions", "speculative_k2",
+                                  "speculative_k3"])
 def test_what_would_slice_or_roll_back_a_ring_is_refused(tier):
     cfg = rehearse_cfg()
     w = weights(cfg)
@@ -397,13 +398,22 @@ def test_what_would_slice_or_roll_back_a_ring_is_refused(tier):
     bare.leaf_seq_windows = make_cache.leaf_seq_windows["layers"]
     kw = dict(eos_id=V, max_slots=2, max_seq_len=64, slot_ladder=[2],
               len_ladder=[64])
-    if tier == "speculative":
+    if tier.startswith("speculative"):
         from paddle_tpu.serving.speculative import SpeculativeConfig
 
+        def spec(k):
+            return SpeculativeConfig(lambda c, t, ts: (None, c), step, bare,
+                                     k=k)
+
+        if tier == "speculative_k2":
+            # a ring of W rows carries two rows a round: nothing the
+            # query after a rejection reads was overwritten
+            assert KVSlotPool(step, bare, speculative=spec(2),
+                              **kw).ring_leaves
+            return
         with pytest.raises(ValueError, match=r"speculative=.*ring leaves.*"
-                           r"rolled back"):
-            KVSlotPool(step, bare, speculative=SpeculativeConfig(
-                lambda c, t, ts: (None, c), step, bare, k=2), **kw)
+                           r"rolled back.*k - 2 spare rows"):
+            KVSlotPool(step, bare, speculative=spec(3), **kw)
         return
     # a builder with ring leaves and no prefill cannot keep a prefix ...
     with pytest.raises(ValueError, match=r"prefix=True.*ring leaves.*"

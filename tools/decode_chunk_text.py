@@ -150,6 +150,25 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
             return decoding.make_windowed_routed_lm_pooled_step_fn(
                 w, cfg, kv_dtype=sv["kv_dtype"],
                 prefill_tokens=sv["prefill_tokens"])[:2]
+    elif cfg["family"] == "pooled_mtp_routed_lm":
+        from paddle_tpu import mtp_routed_lm
+
+        # the first ``layers`` of the cut (2: the dense window layer and
+        # a sparse window layer; 5: the whole cut) and the module
+        for key in ("layer_types", "mlp_layer_types", "sliding_windows"):
+            cfg[key] = cfg[key][:layers]
+        cfg["num_hidden_layers"] = layers
+        held = tuple(cfg["experts_held"])
+        # as the family makes them: matrices bf16, norms, routers and
+        # biases fp32
+        weights = {n: sd(shp, jnp.float32 if n.endswith(
+            mtp_routed_lm.FLOAT32_PARAMS) else jnp.bfloat16)
+            for n, shp in mtp_routed_lm.param_shapes(cfg, held=held).items()}
+
+        def build(w):
+            return decoding.make_mtp_routed_lm_pooled_step_fn(
+                w, cfg, kv_dtype=sv["kv_dtype"], held=held,
+                prefill_tokens=sv["prefill_tokens"])[:2]
     elif cfg["family"] == "pooled_hybrid_ssm_lm":
         from paddle_tpu import hybrid_ssm
 
@@ -194,8 +213,18 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
         c = fn.chunk_tokens
         return dict(state, cache=fn(
             state["cache"], jnp.int32(3),
-            jax.lax.dynamic_slice(state["tokens"], (3, c), (1, c))[0],
+            jax.lax.dynamic_slice(
+                state["tokens"], (3, c),
+                (1, c + getattr(fn, "lookahead", 0)))[0],
             state["pos"][3], jnp.int32(c)))
+
+    def spec_chunk(w, state):
+        # one self-drafting round, as the pool runs it for a builder
+        # that declares its verify and its module (k_exaone_236b_a23b)
+        from paddle_tpu.serving.speculative import make_self_draft
+
+        return pool_of(w, speculative=make_self_draft(
+            build(w)[1]))._spec_chunk_fn(state)
 
     def seat_prefill(w, state, packed):
         # a turn's seats seated and fed their prompts, as the pool runs
@@ -203,13 +232,13 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
         # batched prefill (gpt1_117m)
         return pool_of(w)._seat_prefill_fn(state, packed)
 
-    def pool_of(w):
+    def pool_of(w, **kw):
         from paddle_tpu.serving.kv_pool import KVSlotPool
 
         return KVSlotPool(
             *build(w)[:2], eos_id=int(cfg["vocab_size"]), max_slots=slots,
             max_seq_len=seq_len, slot_ladder=(slots,), len_ladder=(seq_len,),
-            steps=sv["steps_per_tick"], kv_dtype=sv["kv_dtype"])
+            steps=sv["steps_per_tick"], kv_dtype=sv["kv_dtype"], **kw)
 
     i32, flag = jnp.int32, jnp.bool_
     state = {
@@ -220,6 +249,9 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
         "prompt_len": sd((slots,), i32), "total_len": sd((slots,), i32),
         "active": sd((slots,), flag), "finished": sd((slots,), flag),
         "n_gen": sd((slots,), i32)}
+    if kind == "spec_chunk":
+        state.update(spec=sd((slots,), flag), draft=sd((slots,), i32),
+                     proposals=sd((slots, seq_len), i32))
     # what the program closes over — the step's own weights where it
     # was built outside, the numpy constants of every step — is hoisted
     # to arguments, as the pool does (``KVSlotPool._lower``), instead of
@@ -227,8 +259,9 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
     more = ([sd((pool_of(weights)._packed_seats_size(slots, seq_len),), i32)]
             if kind == "seat_prefill" else [])
     closed, out = jax.make_jaxpr(
-        {"chunk": chunk, "prefill": prefill, "seat_prefill": seat_prefill}[
-            kind], return_shape=True)(weights, state, *more)
+        {"chunk": chunk, "prefill": prefill, "seat_prefill": seat_prefill,
+         "spec_chunk": spec_chunk}[kind],
+        return_shape=True)(weights, state, *more)
 
     def hoisted(consts, w, st, *more):
         return jax.tree.unflatten(jax.tree.structure(out), jax.core.eval_jaxpr(
@@ -259,16 +292,19 @@ def main():
     ap.add_argument("--repo", default=os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     ap.add_argument("--kind", default="chunk",
-                    choices=("chunk", "prefill", "seat_prefill"),
+                    choices=("chunk", "prefill", "seat_prefill",
+                             "spec_chunk"),
                     help="prefill: the chunked-prefill program of a "
                     "builder that has one (minicpm_sala, "
                     "smallthinker_21b_a3b); seat_prefill: the "
                     "seat-and-prefill program of one with a batched "
-                    "prefill (gpt1_117m)")
+                    "prefill (gpt1_117m); spec_chunk: the self-drafting "
+                    "round of a builder with a multi-token-prediction "
+                    "module (k_exaone_236b_a23b)")
     ap.add_argument("--layers", type=int, default=2,
                     help="layers compiled (8: the whole minicpm_sala or "
-                    "smallthinker_21b_a3b cut, to see that the real "
-                    "program fits the chip)")
+                    "smallthinker_21b_a3b cut, 5: k_exaone_236b_a23b's, "
+                    "to see that the real program fits the chip)")
     args = ap.parse_args()
     lowered = lowered_chunk(os.path.abspath(args.repo), args.config,
                             args.kind, args.layers)
