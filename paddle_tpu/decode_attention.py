@@ -73,28 +73,34 @@ Three implementations of the read-what-is-live contract, chosen by
   the indicator is exact), accumulated in fp32.
 * :func:`grouped_decode_attention` — the Pallas TPU kernel for GROUPED
   heads (``rep > 1``) over unquantized sequence leaves, bf16 or fp32,
-  whose heads are whole lane tiles (``Dh`` a multiple of 128), ``K =
-  1``.  The same ragged read from the same planner with its own sizes
-  (:func:`step_read_sizes`: blocks of ``_GROUPED_BLOCK`` positions, a
-  slot's last one in ``_GROUPED_CLASSES`` classes), the append left to
-  :func:`append_rows`' in-place scatter before it.  An item is BOTH
-  leaves' whole-width ``[rows, n_kv_head * Dh]`` slabs in one copy each,
-  handed over as they lie (no ``[S, T, G, Dh]`` view), the next
-  ``_GROUPED_AHEAD`` items' reads in flight across slot boundaries; the
-  K/V heads are scored from their own lanes of the slab by
-  :func:`_block_part` (``rep`` padded to a sublane tile in VMEM only),
-  products in the storage dtype, fp32 accumulation and online softmax.
-  How many heads one product scores is a parameter of the q layout (a
-  head's lane offset and width): heads narrower than a lane tile (two
-  of 64 lanes a tile) are the same kernel, not shipped yet.
+  whose heads are whole lane tiles (``Dh`` a multiple of 128), ``K >=
+  1`` fresh rows a slot (``K`` is read from ``q``'s shape; at ``K = 1``
+  the program is the one-row kernel's).  The same ragged read from the
+  same planner with its own sizes (:func:`step_read_sizes`: blocks of
+  ``_GROUPED_BLOCK`` positions, a slot's last one in
+  ``_GROUPED_CLASSES`` classes), made for the slot's LAST fresh row
+  (:func:`last_fresh_row`); the append left to :func:`append_rows`'
+  in-place scatter before it.  An item is BOTH leaves' whole-width
+  ``[rows, n_kv_head * Dh]`` slabs in one copy each, handed over as
+  they lie (no ``[S, T, G, Dh]`` view), the next ``_GROUPED_AHEAD``
+  items' reads in flight across slot boundaries; the K/V heads are
+  scored from their own lanes of the slab by :func:`_block_part` — a
+  head's ``K * rep`` query rows (fresh row major: the ``K`` rows of a
+  slot lie BESIDE the query heads of their K/V head, padded to a sublane
+  tile in VMEM only), each masked at its own position — products in the
+  storage dtype, fp32 accumulation and online softmax.  How many heads
+  one product scores is a parameter of the q layout (a head's lane
+  offset and width) that follows from the shape (:func:`_unit_heads`);
+  heads narrower than a lane tile (two of 64 lanes a tile) are the same
+  kernel, not shipped yet.
 * :func:`grouped_masked_decode_attention` — the contract whole, as plain
   XLA ops (scatter append + masked softmax over the whole T axis):
   products in the storage dtype (int8: dequantized to fp32 at the read),
   fp32 accumulation and softmax.  The CPU path, the path of every step
-  no kernel covers (int8 leaves, ``K > 1``, ring leaves, grouped heads
-  narrower than a lane tile — those through
-  :func:`lane_masked_decode_attention` on a TPU), and the parity
-  reference of tests/test_decode_attention.py.
+  no kernel covers (int8 leaves, ring leaves, ``K > 1`` over leaves of
+  one query head a K/V head, grouped heads narrower than a lane tile —
+  those at one row through :func:`lane_masked_decode_attention` on a
+  TPU), and the parity reference of tests/test_decode_attention.py.
 
 ``jax.experimental.pallas`` is imported inside the kernel builder only:
 ``import paddle_tpu`` and the training cells never pay for it.
@@ -109,7 +115,7 @@ from paddle_tpu.monitor import registry as _registry
 
 __all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
            "kv_read_block", "kv_positions_read", "decode_work_items",
-           "step_read_sizes", "step_positions_read",
+           "step_read_sizes", "step_positions_read", "last_fresh_row",
            "ragged_decode_attention", "grouped_decode_attention",
            "grouped_masked_decode_attention",
            "lane_masked_decode_attention", "append_rows",
@@ -139,15 +145,18 @@ ROWS_LOWERED = _registry.REGISTRY.counter(
     "appends-and-reads of K > 1 fresh rows a slot lowered (a speculative "
     "round's verify, a drafting module's pass), by the kind of leaf they "
     "read: ring (the old ring's rows and the fresh ones, "
-    "_ring_rows_attention) | sequence (the whole rung, masked: the XLA "
-    "form; no kernel takes K rows)", ("leaf",))
+    "_ring_rows_attention) | sequence (what is live, through the grouped "
+    "kernel, or the whole rung, masked, through the XLA form: "
+    "decode_attention_grouped_lowered_total says which for grouped "
+    "heads)", ("leaf",))
 GROUPED_LOWERED = _registry.REGISTRY.counter(
     "decode_attention_grouped_lowered_total",
-    "one-row appends-and-reads of grouped heads (fewer K/V heads than "
-    "query heads) over SEQUENCE leaves lowered (traced into a program or "
-    "run eagerly), by the lowering chosen: kernel (Pallas TPU: the live "
-    "(slot, block) pairs as whole-width slabs, the reads in flight by "
-    "hand) | xla (a masked softmax over the whole rung)", ("path",))
+    "appends-and-reads of grouped heads (fewer K/V heads than query "
+    "heads; one fresh row a slot or K) over SEQUENCE leaves lowered "
+    "(traced into a program or run eagerly), by the lowering chosen: "
+    "kernel (Pallas TPU: the live (slot, block) pairs as whole-width "
+    "slabs, the reads in flight by hand) | xla (a masked softmax over "
+    "the whole rung)", ("path",))
 
 #: the sequence axis of every K/V leaf (and scale sibling)
 KV_SEQ_AXIS = 1
@@ -190,7 +199,8 @@ _GROUPED_CLASSES = 8
 #: items whose reads are in flight ahead of the one the grouped kernel
 #: scores (2 / 3 / 5 read alike)
 _GROUPED_AHEAD = 2
-#: K/V heads the grouped kernel scores in one product (0: all of them).
+#: K/V heads the grouped kernel scores in one product (0: by the rule of
+#: :func:`_unit_heads`; a number: the tool's experiments).
 #: A block's chain of products and softmax costs ~0.5 us whatever it
 #: holds, so four heads in ONE block-diagonal product (the MXU's work is
 #: the same: a head's 8 rows or the unit's 32 fill a fraction of its
@@ -198,6 +208,21 @@ _GROUPED_AHEAD = 2
 #: head a product is 1.65 — over the copies' 1.24, which it then sets back
 #: to 1.82
 _GROUPED_HEADS = 0
+#: query rows a unit of the grouped kernel may hold (a head's ``K * rep``
+#: rows in whole sublane tiles, times the heads of the unit): a unit's
+#: product multiplies ``heads - 1`` zeros for every number, so past this
+#: the MXU's time passes the copies'.  One-row steps of four heads are
+#: 32 rows, one unit (``[40,16384,512]`` rep 7, ``[80,1024,512]`` rep 5:
+#: the timings above).  ``[128,4096,1024]`` rep 8 at ``K = 2`` (16 rows a
+#: head, 8 heads), ms a call with / without the copies' / without the
+#: arithmetic's part — 8 heads a unit (128 rows x 1024 lanes) 0.897 /
+#: 0.789 / 0.882; **4 (64 x 512) 0.842 / 0.654 / 0.834**; 2 0.908 / 0.838
+#: / 0.828; 1 1.301 / 1.241 / 0.812: at four heads the arithmetic hides
+#: behind the copies (545 GB/s on what is live, slots of one to three
+#: items), at eight the zeros cost as much as the copies, at two and one
+#: the chains do (chip run, PR 48, tools/time_grouped_decode.py; the XLA
+#: form of the same read: 5.07)
+_GROUPED_UNIT_ROWS = 64
 #: VMEM a kernel may use before it has to ask for more (v5e's compiler)
 _VMEM_DEFAULT = 16 << 20
 _MASK = -1e30       # finite: exp(_MASK - m) == 0, no inf - inf
@@ -236,8 +261,9 @@ def kernel_supported(seq_len: int, d_model: int, n_head: int) -> bool:
 
 def step_read_sizes(seq_len: int, width: int, dtype, *, n_head: int,
                     n_kv_head: int, backend=None):
-    """``(block, tail)`` the grouped kernel reads a one-row step's
-    unquantized sequence leaves ``[S, seq_len, width]`` of ``dtype`` in
+    """``(block, tail)`` the grouped kernel reads a step's (one fresh
+    row a slot or ``K``) unquantized sequence leaves ``[S, seq_len,
+    width]`` of ``dtype`` in
     (a slot's blocks before its last whole, its last in classes of
     ``tail`` rows: :func:`kv_positions_read`), or None where that step
     is not the kernel's: the backend (``jax.default_backend()`` unsaid)
@@ -258,11 +284,12 @@ def step_read_sizes(seq_len: int, width: int, dtype, *, n_head: int,
 
 
 def step_positions_read(ts, seq_len: int, **leaves):
-    """Positions a one-row step at ``ts >= 0`` reads of a slot's sequence
-    leaves (``leaves``: what :func:`step_read_sizes` takes after the
-    rung): the grouped kernel's rounding where it serves them, else the
-    whole rung (an XLA form).  What a builder of grouped heads declares
-    as ``make_cache.kv_positions_read`` for the server's counter."""
+    """Positions a step whose (last) fresh row is at ``ts >= 0`` reads
+    of a slot's sequence leaves (``leaves``: what :func:`step_read_sizes`
+    takes after the rung): the grouped kernel's rounding where it serves
+    them, else the whole rung (an XLA form).  What a builder of grouped
+    heads declares as ``make_cache.kv_positions_read`` for the server's
+    counter (a ``K``-row round: at :func:`last_fresh_row`)."""
     sizes = step_read_sizes(seq_len, **leaves)
     if sizes is None:
         return np.full_like(ts, seq_len)
@@ -863,17 +890,23 @@ def write_prompt_rows(kv, stored, rows):
 
 
 def append_rows(kv, k_new, v_new, ts):
-    """One layer's leaves with one fresh K/V row per slot appended in
-    place at ``ts`` (idle slots, ``ts < 0``, are not written): the
-    append half of the contract alone, for a step that reads by
-    :func:`grouped_block_decode_attention`.  ``k_new``, ``v_new`` ``[S,
-    n_kv_head * Dh]``; unquantized leaves only."""
+    """One layer's leaves with the fresh K/V rows appended in place —
+    ``k_new``, ``v_new`` ``[S, n_kv_head * Dh]``: one a slot at ``ts``;
+    ``[S, K, ...]``: row ``j`` at ``ts + j`` (idle slots, ``ts < 0``,
+    are not written; a row at or past the rung's end is dropped): the
+    append half of the contract alone, for a step that reads by a kernel
+    that takes the leaves as they lie (:func:`grouped_decode_attention`,
+    :func:`grouped_block_decode_attention`).  Unquantized leaves only."""
     import jax.numpy as jnp
 
     if "k_scale" in kv:
         raise ValueError("append_rows: int8 leaves are not supported")
     S, T, _ = kv["k"].shape
-    rows, at = jnp.arange(S), jnp.where(ts >= 0, ts, T)
+    rows, live, at = jnp.arange(S), ts >= 0, ts
+    if k_new.ndim == 3:
+        rows, live = rows[:, None], live[:, None]
+        at = ts[:, None] + jnp.arange(k_new.shape[1])[None, :]
+    at = jnp.where(live, at, T)             # out of range: dropped
     return {**_append(kv, "k", k_new, rows, at, None),
             **_append(kv, "v", v_new, rows, at, None)}
 
@@ -1260,10 +1293,7 @@ def _block_sparse(q, k_cache, v_cache, ts, blocks, valid, *, n_head,
 def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
                     ts_ref,                                     # SMEM
                     q_ref,                                      # VMEM
-                    k_hbm, v_hbm,                               # HBM (ANY)
-                    o_ref,                                      # output
-                    kbuf, vbuf, m_ref, l_ref, acc_ref, sem,
-                    *, block, tail, heads):
+                    *refs, block, tail, heads, fresh):
     """The work list's items one after another, the reads of the next
     ``ahead`` in flight: an item is BOTH leaves' whole-width ``[rows,
     n_kv_head * Dh]`` slabs, one copy each, scored a UNIT of ``heads``
@@ -1277,6 +1307,20 @@ def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
     product scores the unit's heads and one more weighs their rows (the
     zeros add exact zeros; a head keeps its own lanes of the result).
 
+    ``fresh`` (static) is ``K``, the fresh rows a slot: row ``r`` of a
+    head is the slot's fresh row ``r // rep`` and reads the positions
+    ``<= ts + r // rep``.  Which fresh row a query row is comes in as one
+    more VMEM operand after ``q_ref`` (``[R, 128]`` int32, a row's index
+    across its lanes) where ``K > 1``; at ``K = 1`` there is none and
+    the program is the one-row kernel's.  The work list is the LAST
+    row's, so a slot's state is written out at the block that holds
+    ``ts + K - 1`` (the rung's last where that passes its end).  A block
+    an earlier row cannot see at all is a fully masked block for that
+    row: every score is the finite ``_MASK``, the running maximum —
+    begun at block 0, where every live row sees position 0 — stays what
+    it was, ``alpha`` is 1 and every weight an exact 0 (``_block_part``
+    masks ``p`` too), so the row's sums are unchanged, not NaN.
+
     Written once: ONE loop over the turns (turn ``j`` starts item
     ``j``'s reads and scores item ``j - ahead``, so the turns before
     item 0 are the prologue), one class switch for the starts and one
@@ -1289,8 +1333,13 @@ def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    row_ref = refs[0] if fresh > 1 else None
+    (k_hbm, v_hbm,                                              # HBM (ANY)
+     o_ref,                                                     # output
+     kbuf, vbuf, m_ref, l_ref, acc_ref, sem) = refs[fresh > 1:]
     _, units, R, L = q_ref.shape
     rep_p, D = o_ref.shape[2:]
+    T = k_hbm.shape[1]
     nbuf = kbuf.shape[0]
     ahead = nbuf - 1
     n_items = n_items_ref[0]
@@ -1325,8 +1374,12 @@ def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
+        # a query row reads up to its own position; the slot is done at
+        # the block of its last row's
+        at = t if row_ref is None else t + row_ref[:, :1]
+        last = t if fresh == 1 else jnp.minimum(t + fresh - 1, T - 1)
         ok = b * block + jax.lax.broadcasted_iota(
-            jnp.int32, (R, block), 1) <= t
+            jnp.int32, (R, block), 1) <= at
 
         def unit(u, carry):
             lanes = slice(None) if units == 1 else pl.ds(
@@ -1338,7 +1391,7 @@ def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
             l_ref[u] = jnp.broadcast_to(l, (R, _HEAD_LANES))
             acc_ref[u] = acc
 
-            @pl.when(b == t // block)
+            @pl.when(b == last // block)
             def _():
                 for h in range(heads):  # a head's own rows and lanes
                     rows = slice(h * rep_p, (h + 1) * rep_p)
@@ -1375,20 +1428,37 @@ def grouped_decode_attention(q, k_cache, v_cache, ts, work, *, n_head: int,
     """The Pallas TPU kernel of read-what-is-live for grouped heads over
     unquantized sequence leaves (see the module docstring).
 
-    ``q`` ``[S, n_head * Dh]`` fp32; ``k_cache``, ``v_cache`` ``[S, T,
+    ``q`` ``[S, n_head * Dh]`` fp32 (one fresh row a slot, at ``ts``) or
+    ``[S, K, n_head * Dh]`` (``K`` rows, row ``j`` at ``ts + j`` reading
+    the positions ``<= ts + j``); ``k_cache``, ``v_cache`` ``[S, T,
     n_kv_head * Dh]`` bf16 or fp32, handed over AS THEY LIE and read only
     (:func:`append_rows` has written the step's rows); ``ts`` ``[S]``;
-    ``work`` from :func:`decode_work_items` for the same ``ts``,
-    ``block`` and ``tail`` (:func:`step_read_sizes`).  Returns ctx ``[S,
-    n_head * Dh]`` fp32, zero for an idle slot.  ``q`` is scaled, then
-    rounded to the leaves' dtype; the weights are rounded to it before
-    they meet V (un-normalised, the fp32 sum of the unrounded ones
+    ``work`` from :func:`decode_work_items` for the slots' LAST rows
+    (:func:`last_fresh_row` of ``ts``; ``ts`` itself at one row) with
+    ``block`` and ``tail`` (:func:`step_read_sizes`).  Returns ctx
+    shaped like ``q``, fp32, zero for an idle slot.  ``q`` is scaled,
+    then rounded to the leaves' dtype; the weights are rounded to it
+    before they meet V (un-normalised, the fp32 sum of the unrounded ones
     divides at the end); every sum is fp32.  One jitted entry point for
-    every call site (:func:`_kernel_call` says why)."""
+    every call site (:func:`_kernel_call` says why): the leaves of one
+    shape and ``K`` share one trace."""
     return _grouped_call()(
         work, ts, q, k_cache, v_cache, n_head=n_head, n_kv_head=n_kv_head,
         scale=float(scale), block=block, tail=tail, heads=_GROUPED_HEADS,
         ahead=_GROUPED_AHEAD, interpret=interpret)
+
+
+def last_fresh_row(ts, fresh: int, seq_len: int):
+    """The position of a slot's LAST fresh row that the leaves hold
+    (``ts + fresh - 1``, the rung's last where that passes its end; idle
+    slots stay ``< 0``): what the work list of a ``fresh``-row read is
+    made for, and what :func:`kv_positions_read` rounds for the server's
+    counter.  ``ts``: an integer array, numpy or jax."""
+    if fresh == 1:
+        return ts
+    last, end = ts + fresh - 1, seq_len - 1
+    last = last - (last > end) * (last - end)   # the lesser, in arithmetic
+    return (ts >= 0) * (last + 1) - 1           # that numpy and jax share
 
 
 @functools.lru_cache(maxsize=None)
@@ -1400,6 +1470,18 @@ def _grouped_call():
         "interpret"))
 
 
+def _unit_heads(n_kv_head: int, head_rows: int, heads: int = 0) -> int:
+    """K/V heads the grouped kernel scores in one product: ``heads``
+    where it is said and divides them (the tool's experiments), else as
+    many — a divisor of ``n_kv_head`` — as keep a unit's query rows
+    (``head_rows`` a head: ``K * rep`` in whole sublane tiles) within
+    :data:`_GROUPED_UNIT_ROWS`."""
+    if heads and n_kv_head % heads == 0:
+        return heads
+    return max(h for h in range(1, n_kv_head + 1) if n_kv_head % h == 0
+               and (h == 1 or h * head_rows <= _GROUPED_UNIT_ROWS))
+
+
 def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
              block, tail, heads, ahead, interpret):
     import jax
@@ -1409,20 +1491,29 @@ def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
 
     S, T, Dkv = k_cache.shape
     G, D, rep = n_kv_head, Dkv // n_kv_head, n_head // n_kv_head
-    dt, f32 = k_cache.dtype, jnp.float32
+    K = 1 if q.ndim == 2 else q.shape[1]    # fresh rows a slot
+    dt, f32, i32 = k_cache.dtype, jnp.float32, jnp.int32
     size = jnp.dtype(dt).itemsize
-    heads = heads if heads and G % heads == 0 else G    # K/V heads a unit
+    # a head's rows: its K * rep (fresh row, query head) pairs, fresh row
+    # major, in whole fp32 tiles
+    rep_p = -(-K * rep // 8) * 8
+    heads = _unit_heads(G, rep_p, heads)                # K/V heads a unit
     units, L = G // heads, heads * D
-    rep_p = -(-rep // 8) * 8            # a head's rows: whole fp32 tiles
     sub = 32 // size                    # rows of the leaves' sublane tile
     R = -(-heads * rep_p // sub) * sub
-    qg = jnp.pad((q * scale).astype(dt).reshape(S, units, heads, rep, D),
-                 ((0, 0),) * 3 + ((0, rep_p - rep), (0, 0)))
+    qg = (q * scale).astype(dt).reshape(S, K, units, heads, rep, D)
+    qg = jnp.moveaxis(qg, 1, 3).reshape(S, units, heads, K * rep, D)
+    qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, rep_p - K * rep), (0, 0)))
     if heads > 1:   # head h of a unit: its own D lanes of the unit's L
         own = np.eye(heads, dtype=bool)[:, None, :, None]
         qg = jnp.where(own, qg[:, :, :, :, None, :], jnp.zeros((), dt))
     qg = jnp.pad(qg.reshape(S, units, heads * rep_p, L),
                  ((0, 0), (0, 0), (0, R - heads * rep_p), (0, 0)))
+    # which fresh row each of a unit's query rows is (rows of padding:
+    # the last), across a lane tile: an operand where there are several
+    fresh_of = () if K == 1 else (jnp.broadcast_to(jnp.minimum(
+        jnp.arange(R, dtype=i32) % rep_p // rep, K - 1)[:, None],
+        (R, _HEAD_LANES)),)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -1433,9 +1524,9 @@ def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
                 + 2 * nbuf * block * Dkv * size + 4 * 4 * R * block)
     ctx = pl.pallas_call(
         functools.partial(_grouped_kernel, block=block, tail=tail,
-                          heads=heads),
+                          heads=heads, fresh=K),
         out_shape=jax.ShapeDtypeStruct((S, G, rep_p, D), f32),
-        in_specs=[smem] * 5 + [vmem] + [hbm] * 2,
+        in_specs=[smem] * 5 + [vmem] * (1 + len(fresh_of)) + [hbm] * 2,
         out_specs=vmem,
         scratch_shapes=[
             pltpu.VMEM((nbuf, block, Dkv), dt),
@@ -1450,8 +1541,9 @@ def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
             if resident > _VMEM_DEFAULT * 3 // 4 else None),
         name="grouped_decode_attention",
         interpret=interpret,
-    )(*work, ts.astype(jnp.int32), qg, k_cache, v_cache)
-    return ctx[:, :, :rep].reshape(S, n_head * D)
+    )(*work, ts.astype(i32), qg, *fresh_of, k_cache, v_cache)
+    ctx = ctx[:, :, :K * rep].reshape(S, G, K, rep, D)
+    return jnp.moveaxis(ctx, 2, 1).reshape(q.shape)
 
 
 def _gathered_block_attention(q, kv, ts, blocks, valid, *, n_head, n_kv_head,
@@ -1574,18 +1666,22 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
     layers hold sequence leaves AND ring leaves makes one ``attend`` for
     each).  ``window``: the leaves are RING leaves of that window (what
     the builder allocated them as), read by the XLA form.  The one place
-    that chooses, from what it can observe: a kernel when one exists for
-    what the step is — the default backend a TPU, one fresh row per
-    slot, unquantized leaves, and either one query head per K/V head
-    over fp32 leaves of a shape :func:`ragged_decode_attention` lowers
-    for, or grouped heads that are whole lane tiles over bf16 / fp32
-    leaves (:func:`step_read_sizes`: :func:`grouped_decode_attention`)
-    — and an XLA form otherwise: on a TPU, for grouped heads narrower
+    that chooses, from what it can observe (the backend, the leaves'
+    dtype and shape, the head grouping, ``q.ndim``): a kernel when one
+    exists for what the step is — the default backend a TPU, unquantized
+    leaves, and either one query head per K/V head over fp32 leaves of a
+    shape :func:`ragged_decode_attention` lowers for and one fresh row
+    per slot, or grouped heads that are whole lane tiles over bf16 /
+    fp32 leaves (:func:`step_read_sizes`:
+    :func:`grouped_decode_attention`, one fresh row or ``K``) — and an
+    XLA form otherwise: on a TPU, for one row of grouped heads narrower
     than a lane tile over unquantized leaves, the one that reads the
     leaves as they lie (:func:`lane_masked_decode_attention`), else
-    :func:`grouped_masked_decode_attention` (``K > 1`` fresh rows, int8
-    leaves, every CPU run).  A grouped-head step over sequence leaves
-    counts itself in ``decode_attention_grouped_lowered_total{path}``."""
+    :func:`grouped_masked_decode_attention` (int8 leaves, ``K`` rows
+    where no kernel takes them, every CPU run).  A grouped-head step
+    over sequence leaves counts itself in
+    ``decode_attention_grouped_lowered_total{path}``, a ``K``-row one
+    also in ``decode_attention_rows_lowered_total{leaf}``."""
     import jax
     import jax.numpy as jnp
 
@@ -1599,17 +1695,23 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
         sizes = step_read_sizes(
             seq_len, width, kv["k"].dtype, n_head=n_head,
             n_kv_head=n_kv_head) if tpu else None
-        one_row = xla
-        if sizes is not None:
-            work = decode_work_items(ts, seq_len, *sizes)
+        works = {}      # the work list of a K-row read, made once a K
 
-            def one_row(q, k_new, v_new, kv):
-                kv = append_rows(kv, k_new, v_new, ts)
-                return grouped_decode_attention(
-                    q, kv["k"], kv["v"], ts, work, n_head=n_head,
-                    n_kv_head=n_kv_head, scale=scale, block=sizes[0],
-                    tail=sizes[1]), kv
-        elif tpu and (width // n_kv_head) % _HEAD_LANES:
+        def kernel(q, k_new, v_new, kv):
+            fresh = 1 if q.ndim == 2 else q.shape[1]
+            if fresh not in works:
+                works[fresh] = decode_work_items(
+                    last_fresh_row(ts, fresh, seq_len), seq_len, *sizes)
+            if fresh > 1:
+                ROWS_LOWERED.labels(leaf="sequence").inc()
+            kv = append_rows(kv, k_new, v_new, ts)
+            return grouped_decode_attention(
+                q, kv["k"], kv["v"], ts, works[fresh], n_head=n_head,
+                n_kv_head=n_kv_head, scale=scale, block=sizes[0],
+                tail=sizes[1]), kv
+
+        one_row = xla
+        if sizes is None and tpu and (width // n_kv_head) % _HEAD_LANES:
             # narrower than a lane tile: a view of the leaf by heads
             # would be a copy of the rung (lane_masked_decode_attention)
             one_row = functools.partial(
@@ -1617,8 +1719,10 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
                 n_kv_head=n_kv_head, scale=scale)
 
         def attend(q, k_new, v_new, kv):
-            kernel = sizes is not None and q.ndim == 2
-            GROUPED_LOWERED.labels(path="kernel" if kernel else "xla").inc()
+            GROUPED_LOWERED.labels(
+                path="xla" if sizes is None else "kernel").inc()
+            if sizes is not None:
+                return kernel(q, k_new, v_new, kv)
             return (one_row if q.ndim == 2 else xla)(q, k_new, v_new, kv)
 
         return attend

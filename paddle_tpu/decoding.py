@@ -1438,8 +1438,9 @@ def make_mtp_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
                 else cache["expert_stats"])
 
     def forward(cache, tokens, ts):
-        """The layers over ``tokens`` ``[S]`` (one fresh row a slot: the
-        kernels where they exist) or ``[S, K]``."""
+        """The layers over ``tokens`` ``[S]`` (one fresh row a slot) or
+        ``[S, K]``: the kernels where they exist (the global layers'
+        grouped kernel takes either, ``make_decode_attention``)."""
         layers = cache["layers"]
         ts = jnp.minimum(ts, rung_of(cache) - 1)
         attend = {}

@@ -108,7 +108,8 @@ import numpy as np
 from paddle_tpu import compile_cache
 from paddle_tpu import faults as _faults
 from paddle_tpu import monitor
-from paddle_tpu.decode_attention import kv_positions_read, kv_read_block
+from paddle_tpu.decode_attention import (kv_positions_read, kv_read_block,
+                                         last_fresh_row)
 from paddle_tpu.monitor import events as _events
 from paddle_tpu.monitor import spans as _mon_spans
 from paddle_tpu.serving.admission import PRIORITY_NORMAL
@@ -154,9 +155,11 @@ DECODE_KV_READ = monitor.counter(
     "step rounds them (decode_attention.kv_positions_read: the slot's "
     "last block in classes of KV_TAIL rows for the ragged kernel, of "
     "the grouped kernel's tail where the builder declares "
-    "make_cache.kv_positions_read); the whole rung for a step an XLA "
-    "form serves (an int8 pool, grouped heads off the TPU) and for a "
-    "speculative round, whose reads are masked, not ragged",
+    "make_cache.kv_positions_read: a speculative round then counts that "
+    "rule ONCE a slot that advanced, at its last fresh row); the whole "
+    "rung for a step an XLA form serves (an int8 pool, grouped heads off "
+    "the TPU) and for a speculative round of a builder that declares no "
+    "rule, whose reads are masked, not ragged",
     _LABELS)
 DECODE_KV_LIVE = monitor.counter(
     "serving_decode_kv_positions_live_total",
@@ -1426,7 +1429,12 @@ class DecodeServer:
         rows = self._speculative.k if use_spec else steps
         ts = p0[:, None] + np.arange(rows)[None, :]
         ran = (p1 > p0)[:, None] & (ts < t) if use_spec else ts < p1[:, None]
-        if use_spec:
+        if use_spec and self._kv_rule is not None:
+            # ONE read a round, made for its last row (the earlier rows
+            # see less of the same blocks): what the rule rounds that to
+            last = last_fresh_row(p0, rows, t)
+            read = int((self._kv_rule(last, t) * (p1 > p0)).sum())
+        elif use_spec:
             read = pool  # masked reads over the whole rung
         elif self._kv_rule is not None:
             read = int((self._kv_rule(ts, t) * ran).sum())

@@ -18,6 +18,8 @@ block is read in classes of ``KV_TAIL`` rows — every class, both edges
 of each, with and without a whole block before the tail (sums of up to
 256 terms there: the same tolerance holds).
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -180,37 +182,41 @@ def test_one_step_writes_one_row_per_active_slot(impl, ts):
         assert np.array_equal(new, want)
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
-@pytest.mark.parametrize("rep", [1, 2])
-def test_k_fresh_rows_equal_k_appends_of_one_row(rep, dtype):
-    """The XLA form at K rows per slot is K calls at one row: the same
-    leaves bit for bit (an append is a copy, or the same quantization of
-    the same row) and the same context to fp32 rounding of sums ordered
-    differently (bf16 products: their rounding is the same on both
-    sides), for grouped heads and every storage dtype."""
+@pytest.mark.parametrize("path,rep,dtype", [
+    ("xla", rep, dtype) for rep in (1, 2)
+    for dtype in ("float32", "bfloat16", "int8")] + [
+    ("kernel", 2, "float32"), ("kernel", 2, "bfloat16")])
+def test_k_fresh_rows_equal_k_appends_of_one_row(path, rep, dtype):
+    """K rows per slot are K calls at one row, through the XLA form and
+    through the grouped kernel (whole-lane-tile heads: its shapes): the
+    same leaves bit for bit (an append is a copy, or the same
+    quantization of the same row) and the same context to fp32 rounding
+    of sums ordered differently (bf16 products: their rounding is the
+    same on both sides), for grouped heads and every storage dtype."""
     import jax.numpy as jnp
 
     K, n_kv = 3, H
     n_head = n_kv * rep
+    dh = DH if path == "xla" else 128
+    attend = (da.grouped_masked_decode_attention if path == "xla"
+              else functools.partial(_grouped_rows_kernel, block=T, tail=16))
     rng = np.random.RandomState(3)
     ts = jnp.asarray([0, 7, -1, T - K, T - 1, 12], jnp.int32)
-    q = jnp.asarray(rng.randn(S, K, n_head * DH), jnp.float32)
-    kn, vn = (jnp.asarray(rng.randn(S, K, D), jnp.float32)
+    q = jnp.asarray(rng.randn(S, K, n_head * dh), jnp.float32)
+    kn, vn = (jnp.asarray(rng.randn(S, K, n_kv * dh), jnp.float32)
               for _ in range(2))
-    kv = da.kv_leaves(S, T, n_kv, DH, dtype)
+    kv = da.kv_leaves(S, T, n_kv, dh, dtype)
+    named = dict(n_head=n_head, n_kv_head=n_kv, scale=1.0 / np.sqrt(dh))
     _, kv = da.grouped_masked_decode_attention(   # something to read
         q[:, 0], kn[:, 0] * 0.5, vn[:, 0] * 0.5, kv,
-        jnp.maximum(ts - 1, -1), n_head=n_head, n_kv_head=n_kv,
-        scale=SCALE)
-    wide, wide_kv = da.grouped_masked_decode_attention(
-        q, kn, vn, kv, ts, n_head=n_head, n_kv_head=n_kv, scale=SCALE)
+        jnp.maximum(ts - 1, -1), **named)
+    wide, wide_kv = attend(q, kn, vn, kv, ts, **named)
     assert wide.shape == q.shape and wide.dtype == jnp.float32
     seq_kv, in_range = kv, np.asarray(ts)[:, None] + np.arange(K) < T
     for j in range(K):
         at = jnp.where((ts >= 0) & (ts + j < T), ts + j, -1)
-        one, seq_kv = da.grouped_masked_decode_attention(
-            q[:, j], kn[:, j], vn[:, j], seq_kv, at, n_head=n_head,
-            n_kv_head=n_kv, scale=SCALE)
+        one, seq_kv = attend(q[:, j], kn[:, j], vn[:, j], seq_kv, at,
+                             **named)
         rows = in_range[:, j]          # slot 4's rows past T: dropped
         np.testing.assert_allclose(np.asarray(wide[:, j])[rows],
                                    np.asarray(one)[rows], rtol=0,
@@ -608,11 +614,14 @@ def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
     """On a TPU a one-row step of grouped heads over unquantized
     sequence leaves takes the grouped kernel where the heads are whole
     lane tiles (falcon_h1, smallthinker's global layers: bf16 and fp32)
-    and reads the leaves as they lie where they are 64 wide (lfm2); one
-    query head a K/V head (gpt1), int8 leaves, a rung the kernel's block
-    does not divide, K fresh rows, ring leaves and every CPU run keep
-    the grouped XLA form.  Every grouped-head step over sequence leaves
-    counts itself by the path it took; a ring step never does."""
+    and reads the leaves as they lie where they are 64 wide (lfm2); K
+    fresh rows over whole-lane-tile heads take the same kernel (a
+    self-drafting round's verify: k_exaone); one query head a K/V head
+    (gpt1), int8 leaves, a rung the kernel's block does not divide, K
+    rows of 64-wide heads, ring leaves and every CPU run keep the
+    grouped XLA form.  Every grouped-head step over sequence leaves
+    counts itself by the path it took, a K-row one also by its leaf; a
+    ring step never counts a path."""
     import jax
     import jax.numpy as jnp
 
@@ -629,6 +638,9 @@ def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
         da, "grouped_decode_attention",
         lambda *a, **k: seen.append("kernel") or kernel(
             *a, interpret=True, **k))
+
+    def rows_counted():
+        return da.ROWS_LOWERED.labels(leaf="sequence").value
 
     def took(kv, heads, rows=None, **kw):
         """What a step over ``kv`` called, and what it counted."""
@@ -651,8 +663,14 @@ def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
         assert took(kv, (8, 2)) == (["kernel"], {"kernel": 1, "xla": 0})
         assert took(kv, (10, 2)) == (["kernel"], {"kernel": 1, "xla": 0})
     assert took(narrow, (8, 2)) == (["lane"], {"kernel": 0, "xla": 1})
+    # K fresh rows: the kernel's where the heads are whole lane tiles
+    n_rows = rows_counted()
+    for kv in (wide, da.kv_leaves(2, 128, 2, 128, jnp.float32)):
+        for rows in (2, 3):
+            assert took(kv, (8, 2), rows=rows) == (
+                ["kernel"], {"kernel": 1, "xla": 0})
+    assert rows_counted() == n_rows + 4
     for kv, heads, kw in (
-            (wide, (8, 2), {"rows": 3}),            # K fresh rows
             (narrow, (8, 2), {"rows": 3}),
             (da.kv_leaves(2, 128, 2, 128, jnp.int8), (8, 2), {}),
             (da.kv_leaves(2, da._GROUPED_BLOCK + 128, 2, 128, jnp.bfloat16),
@@ -666,6 +684,17 @@ def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
     ring = da.kv_leaves(2, 128, 2, 128, jnp.bfloat16, window=64)
     assert took(ring, (8, 2), window=64) == (["grouped"], nothing)
     assert took(ring, (8, 2), rows=3, window=64)[1] == nothing
+    # off the TPU a K-row call is the XLA form's, counted as such
+    monkeypatch.undo()
+    before, n_rows = _grouped_counts(), rows_counted()
+    q = jnp.zeros((2, 3, 8 * 128))
+    new = jnp.zeros((2, 3, 2 * 128))
+    da.make_decode_attention(ts, wide, n_head=8, n_kv_head=2, scale=1.0)(
+        q, new, new, wide)
+    after = _grouped_counts()
+    assert {p: after[p] - before[p] for p in after} == {"kernel": 0,
+                                                        "xla": 1}
+    assert rows_counted() == n_rows + 1
 
 
 # ---------------------------------------------------------------------------
@@ -737,6 +766,140 @@ def test_grouped_kernel_scores_any_number_of_heads_in_one_product(
                                atol=1e-5)
 
 
+#: ``ts`` of K-row cases (two blocks of GB, T = 2 * GB), by the edge named
+GROUPED_ROWS_TS = {
+    # the K rows straddle the block's edge: of the slot's LAST block the
+    # earlier rows see nothing (ts = GB - 1) or only its first positions
+    "straddle_a_block": lambda k: [GB - 1, GB - k + 1, GB, GT - 1],
+    # ts + K - 1 at the rung's end and past it (the rows past it dropped)
+    "rung_end": lambda k: [2 * GB - k, 2 * GB - k + 1, 2 * GB - 1],
+    "from_zero": lambda k: [0, 0, 1],
+    # idle slots between live ones: zero context, leaf untouched
+    "idle_between": lambda k: [-1, 40, -1, GB + 3, -1],
+}
+
+
+def _grouped_rows_case(rep, dtype, ts, k, g=4, dh=128, seed=5):
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    s, t = len(ts), 2 * GB
+    q = jnp.asarray(rng.randn(s, k, g * rep * dh), jnp.float32)
+    kn, vn = (jnp.asarray(rng.randn(s, k, g * dh), jnp.float32)
+              for _ in range(2))
+    kv = {n: jnp.asarray(rng.randn(s, t, g * dh), dtype) for n in "kv"}
+    return q, kn, vn, kv, jnp.asarray(ts, jnp.int32), dict(
+        n_head=g * rep, n_kv_head=g, scale=dh ** -0.5)
+
+
+def _grouped_rows_kernel(q, kn, vn, kv, ts, block=GB, tail=GT, **kw):
+    """One fresh row a slot or ``K`` through the append and the kernel,
+    as ``make_decode_attention`` sends them on a TPU (interpret mode)."""
+    fresh, t = 1 if q.ndim == 2 else q.shape[1], kv["k"].shape[1]
+    kv = da.append_rows(kv, kn, vn, ts)
+    return da.grouped_decode_attention(
+        q, kv["k"], kv["v"], ts, da.decode_work_items(
+            da.last_fresh_row(ts, fresh, t), t, block, tail),
+        block=block, tail=tail, interpret=True, **kw), kv
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED_ROWS_TS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rep", [7, 8])
+@pytest.mark.parametrize("k", [2, 3])
+def test_grouped_kernel_takes_k_fresh_rows(k, rep, dtype, case):
+    """``K`` fresh rows a slot through the kernel against the XLA form
+    of the same layout (``_grouped_rows_attention``): the same context
+    for every row — those past the rung's end too, which read every
+    position and are not written — bit-equal leaves, zero rows and
+    untouched leaves for idle slots, and no NaN where a row's last block
+    is fully masked for it."""
+    ts = GROUPED_ROWS_TS[case](k)
+    q, kn, vn, kv, ts, kw = _grouped_rows_case(rep, dtype, ts, k)
+    want, kv_want = da.grouped_masked_decode_attention(q, kn, vn, kv, ts,
+                                                       **kw)
+    got, kv_got = _grouped_rows_kernel(q, kn, vn, kv, ts, **kw)
+    assert got.shape == q.shape and np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=2e-6 if dtype == "float32" else 2e-2)
+    idle = np.asarray(ts) < 0
+    for n in "kv":
+        assert np.array_equal(np.asarray(kv_got[n].astype("float32")),
+                              np.asarray(kv_want[n].astype("float32")))
+        assert np.array_equal(np.asarray(kv_got[n].astype("float32"))[idle],
+                              np.asarray(kv[n].astype("float32"))[idle])
+    assert not np.asarray(got)[idle].any()
+
+
+@pytest.mark.parametrize("k", [2, 3])
+def test_grouped_kernel_row_reads_nothing_past_its_own_position(k):
+    """Row ``j`` reads the positions ``<= ts + j``: garbage in the leaf
+    beyond the slot's last fresh row changes no row's context, and other
+    fresh rows after ``j`` change nothing of rows ``<= j``."""
+    import jax.numpy as jnp
+
+    ts = [3, GB - 1, GB + GT, 2 * GB - k - 1]
+    q, kn, vn, kv, ts, kw = _grouped_rows_case(8, "float32", ts, k)
+    want, _ = _grouped_rows_kernel(q, kn, vn, kv, ts, **kw)
+    beyond = (jnp.arange(2 * GB)[None, :] >= (ts + k)[:, None])[..., None]
+    spoiled = {n: jnp.where(beyond, 1e4, kv[n]) for n in "kv"}
+    got, _ = _grouped_rows_kernel(q, kn, vn, spoiled, ts, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for j in range(k - 1):      # later fresh rows replaced
+        later = jnp.arange(k)[None, :, None] > j
+        got, _ = _grouped_rows_kernel(
+            q, jnp.where(later, 7.0, kn), jnp.where(later, -7.0, vn), kv,
+            ts, **kw)
+        np.testing.assert_array_equal(np.asarray(got)[:, :j + 1],
+                                      np.asarray(want)[:, :j + 1])
+
+
+@pytest.mark.parametrize("k", [2, 3])
+def test_grouped_work_list_of_k_rows_is_the_last_rows(k):
+    """The work list of a ``K``-row read is the one-row list at the
+    slot's LAST fresh row (the rung's last where that passes the end):
+    its items add up to ``kv_positions_read`` there — what a builder's
+    ``make_cache.kv_positions_read`` hands the server's counter for a
+    speculative round."""
+    import jax.numpy as jnp
+
+    seq_len = 4096
+    leaves = dict(width=1024, dtype="bfloat16", n_head=64, n_kv_head=8)
+    block, tail = da.step_read_sizes(seq_len, backend="tpu", **leaves)
+    ts = np.asarray([-1, 0, tail - k, tail - 1, block - 1, block,
+                     seq_len - k, seq_len - 1, 1500], np.int32)
+    last = da.last_fresh_row(ts, k, seq_len)
+    assert last.tolist() == [
+        -1 if t < 0 else min(t + k - 1, seq_len - 1) for t in ts.tolist()]
+    assert np.array_equal(
+        np.asarray(da.last_fresh_row(jnp.asarray(ts), k, seq_len)), last)
+    assert da.last_fresh_row(ts, 1, seq_len) is ts
+    n, slot, _, rows = (np.asarray(x) for x in da.decode_work_items(
+        jnp.asarray(last), seq_len, block, tail))
+    read = np.bincount(slot[:n[0]], weights=rows[:n[0]],
+                       minlength=len(ts)).astype(int)
+    want = np.where(ts >= 0, da.kv_positions_read(last, block, tail), 0)
+    assert read.tolist() == want.tolist()
+    assert np.all(want[1:] >= last[1:] + 1)
+    assert da.step_positions_read(last[1:], seq_len, backend="tpu",
+                                  **leaves).tolist() == want[1:].tolist()
+
+
+def test_unit_width_follows_from_the_shape():
+    """K/V heads a product: all four at smallthinker's and Falcon's
+    one-row shapes (8 rows a head), a unit of at most
+    ``_GROUPED_UNIT_ROWS`` query rows at K-EXAONE's two-row one (16 rows
+    a head, 8 heads), one head where a single head passes it; a number
+    said (the tool's knob) is taken where it divides the heads."""
+    assert da._unit_heads(4, 8) == 4
+    rows = da._GROUPED_UNIT_ROWS
+    assert da._unit_heads(8, 16) == max(
+        h for h in (1, 2, 4, 8) if h * 16 <= rows or h == 1)
+    assert da._unit_heads(8, 2 * rows) == 1
+    assert da._unit_heads(8, 16, 8) == 8 and da._unit_heads(8, 16, 2) == 2
+    assert da._unit_heads(8, 16, 3) == da._unit_heads(8, 16)
+
+
 @pytest.mark.parametrize("seq_len", [1024, 16384, 512])
 def test_grouped_work_list_reads_what_kv_positions_read_says(seq_len):
     """The one rounding: with the grouped kernel's sizes a slot's items
@@ -773,11 +936,14 @@ def test_grouped_work_list_reads_what_kv_positions_read_says(seq_len):
                               **leaves) is None
 
 
-def _two_grouped_layers(sharding=None):
+def _two_grouped_layers(sharding=None, shape="smallthinker"):
     """``(f, abstract arguments, equations)``: a step's two global layers
     at ``smallthinker_21b_a3b``'s shapes (40 slots x 16,384 x 512 bf16, 7
     query heads a K/V head) through the kernel, as
-    ``tools/time_grouped_decode.py --build`` builds them."""
+    ``tools/time_grouped_decode.py --build`` builds them; ``shape``
+    ``k_exaone``: a self-drafting round's two rung-long leaves (128
+    slots x 4,096 x 1,024 bf16, 8 query heads a K/V head, TWO fresh rows
+    a slot)."""
     import importlib.util
     import os
     import sys
@@ -793,8 +959,8 @@ def _two_grouped_layers(sharding=None):
         spec.loader.exec_module(tool)
     finally:
         sys.path.remove(tools)
-    f, args = tool.two_layer_program(da, tool.SHAPES["smallthinker"],
-                                     "bfloat16", False, sharding)
+    f, args = tool.two_layer_program(da, tool.SHAPES[shape], "bfloat16",
+                                     False, sharding, tool.ROWS[shape])
     return f, args, tool.equations
 
 
@@ -806,13 +972,15 @@ def _two_grouped_layers(sharding=None):
 _GROUPED_KERNEL_EQUATIONS_MAX = 384
 
 
-def test_grouped_kernel_is_traced_once_and_its_body_stays_small():
-    """A step's two global layers share ONE traced function whose body
-    holds one loop over the items, a class switch for the starts and one
-    for the waits, and ONE scoring routine."""
+@pytest.mark.parametrize("shape", ["smallthinker", "k_exaone"])
+def test_grouped_kernel_is_traced_once_and_its_body_stays_small(shape):
+    """A step's two global layers — a self-drafting round's two
+    rung-long leaves, read at two fresh rows a slot — share ONE traced
+    function whose body holds one loop over the items, a class switch
+    for the starts and one for the waits, and ONE scoring routine."""
     import jax
 
-    f, args, equations = _two_grouped_layers()
+    f, args, equations = _two_grouped_layers(shape=shape)
     calls = [e for e in jax.make_jaxpr(f)(*args).jaxpr.eqns
              if "jaxpr" in e.params
              and e.params.get("name") == "_grouped"]
@@ -932,6 +1100,39 @@ def test_grouped_kernel_compiles_for_v5e_at_smallthinker_widths(one_chip):
              if "grouped_decode_attention" in line and "custom-call(" in line]
     assert len(calls) == 2 and all("bf16[40,16384,512]" in c for c in calls)
     leaf = 40 * 16384 * 512 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * leaf
+    assert mem.temp_size_in_bytes < leaf // 8
+
+
+def test_grouped_kernel_compiles_for_v5e_at_k_exaone_widths(one_chip):
+    """128 slots x 4,096 positions x 8 K/V heads of 128, 8 query heads a
+    K/V head, bf16, TWO fresh rows a slot (``k_exaone_236b_a23b``'s
+    global layer and its module in one self-drafting round): the two
+    append-and-reads lower to ONE kernel called twice, the leaves are
+    appended in place and handed to the kernel as they lie — no copy of
+    a rung-sized operand in the compiled text, no temporary of a leaf's
+    size — and the unit is the rule's (four heads: 64 rows x 512
+    lanes)."""
+    import re
+
+    import jax
+
+    f, args, _ = _two_grouped_layers(one_chip, "k_exaone")
+    assert args[0].shape == (128, 2, 64 * 128)
+    lowered = jax.jit(f, donate_argnums=(3, 4, 5, 6)).trace(*args).lower()
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(r"call @_grouped\b", text)) == 2
+    compiled = lowered.compile()
+    lines = compiled.as_text().splitlines()
+    calls = [line for line in lines
+             if "grouped_decode_attention" in line and "custom-call(" in line]
+    assert len(calls) == 2 and all("bf16[128,4096,1024]" in c for c in calls)
+    assert all("bf16[128,2,64,512]" in c for c in calls)    # q: units, R, L
+    assert not [line for line in lines
+                if " copy(" in line and "[128,4096," in line]
+    leaf = 128 * 4096 * 1024 * 2
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 4 * leaf
     assert mem.temp_size_in_bytes < leaf // 8

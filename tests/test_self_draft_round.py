@@ -251,6 +251,49 @@ def test_decode_server_serves_speculative_and_plain_requests_alike():
     assert d["window_positions_read"] <= d["window_positions_live"]
 
 
+def test_a_speculative_round_counts_its_read_by_the_builders_rule():
+    """A self-drafting round reads the rung-long leaves ONCE for its K
+    rows: where the builder declares ``make_cache.kv_positions_read``
+    the server's read counter takes what that rule says at the slot's
+    LAST fresh row (the rung's last where that passes the end), once a
+    slot that advanced — under the pool's positions, at or over the live
+    ones; a builder with no rule counts the whole pool a round, as
+    before.  The live and pool counters count as they always did: the
+    same with the rule and without."""
+    _, (step, make_cache, _) = _builder(seed=4)
+    prompts, n_new = _prompts(3, seed=11), RUNG - 12
+    asked = []
+
+    def rule(ts, t):
+        asked.append((np.asarray(ts).copy(), t))
+        return (ts // 8 + 1) * 8
+
+    counted = {}
+    for ruled in (True, False):
+        make_cache.kv_positions_read = rule if ruled else None
+        srv = _server(step, make_cache, "spec-kv-%s" % ruled,
+                      speculative=make_self_draft(make_cache))
+        try:
+            srv.warmup()
+            _serve(srv, prompts, n_new, speculative=True)
+            counted[ruled] = d = srv.metrics()["decode"]
+        finally:
+            srv.stop(drain=False, timeout=60.0)
+        rounds = d["speculative"]["rounds"]
+        assert rounds > 0       # slots x T a round
+        assert d["kv_positions_pool"] == rounds * 4 * RUNG
+    with_rule, without = counted[True], counted[False]
+    assert with_rule["kv_positions_live"] == without["kv_positions_live"] > 0
+    assert without["kv_positions_read"] == without["kv_positions_pool"]
+    assert all(t == RUNG for _, t in asked)
+    last = np.concatenate([ts.ravel() for ts, _ in asked])
+    # one figure a slot and round: the position of its SECOND row
+    assert 1 <= last.min() and last.max() < RUNG
+    assert with_rule["kv_positions_live"] < with_rule["kv_positions_read"] \
+        < with_rule["kv_positions_pool"]
+    assert with_rule["kv_positions_read"] % 8 == 0
+
+
 def test_a_prefix_snapshot_is_served_with_a_self_draft_attached():
     """``prefix=True`` over this builder keeps SNAPSHOTS (it has a
     prefill, which feeds the module's leaves too): a second request

@@ -5,7 +5,7 @@ chip: the Pallas kernel (``paddle_tpu/decode_attention.py``:
 (``grouped_masked_decode_attention``), turn and turn about in one
 process over the same leaves.
 
-Two shapes (``--shape``, both unless said), every slot's position drawn
+Three shapes (``--shape``, all unless said), every slot's position drawn
 as the cell's traffic file leaves them (a prompt or a document and a
 question, then a step drawn evenly over the answer's life):
 
@@ -13,7 +13,16 @@ question, then a step drawn evenly over the answer's life):
   layers: 40 slots x 16,384 positions x 512 lanes of bf16, 4 K/V heads
   of 128, 7 query heads a K/V head;
 * ``falcon`` — ``falcon_h1_34b.long_answers_batch``: 80 slots x 1,024 x
-  512, 4 K/V heads of 128, 5 query heads a K/V head.
+  512, 4 K/V heads of 128, 5 query heads a K/V head;
+* ``k_exaone`` — ``k_exaone_236b_a23b.long_answers_mtp_4k``'s global
+  layer and module: 128 slots x 4,096 x 1,024, 8 K/V heads of 128, 8
+  query heads a K/V head, TWO fresh rows a slot (a self-drafting
+  round's verify, and its module's pass).
+
+``--rows K`` hands every slot of every shape ``K`` fresh rows (row ``j``
+at ``ts + j``) instead of its cell's: the XLA form of ``K`` rows is the
+comparison and the parity reference, a checkout whose kernel takes one
+row runs that form alone.
 
 One jitted program runs a form ``--calls`` times in a row on the same
 (donated) leaves; its time on the host's clock over the calls is a
@@ -70,9 +79,13 @@ from time_block_sparse import (equations, load_module, no_arithmetic,  # noqa: E
 SHAPES = {
     "smallthinker": (40, 16384, 4, 128, 7, "shared_docs_qa_16k"),
     "falcon": (80, 1024, 4, 128, 5, "long_answers_batch"),
+    "k_exaone": (128, 4096, 8, 128, 8, "long_answers_mtp_4k"),
 }
 REHEARSAL = {"smallthinker": (4, 512, 4, 128, 7, "shared_docs_qa_16k"),
-             "falcon": (6, 256, 4, 128, 5, "long_answers_batch")}
+             "falcon": (6, 256, 4, 128, 5, "long_answers_batch"),
+             "k_exaone": (4, 1024, 8, 128, 8, "long_answers_mtp_4k")}
+#: fresh rows a slot, as the shape's cell hands them
+ROWS = {"smallthinker": 1, "falcon": 1, "k_exaone": 2}
 KNOBS = ("block", "classes", "ahead", "heads")
 
 
@@ -108,19 +121,28 @@ def positions(rng, slots, rung, traffic, rehearse):
     return np.minimum(ts, min(mix["max_total"], rung) - 1).astype(np.int32)
 
 
-def forms(mod, shape, interpret):
+def last_rows(mod, ts, rows, rung):
+    """The slots' last fresh rows, by the checkout's own rule (one that
+    takes one row a slot has none: ``ts``)."""
+    return ts if rows == 1 else mod.last_fresh_row(ts, rows, rung)
+
+
+def forms(mod, shape, interpret, rows=1):
     """``{name: attend(q, k_new, v_new, kv, ts) -> (ctx, kv)}`` of the
-    checkout ``mod``: its XLA form, and its kernel if it has one."""
+    checkout ``mod``: its XLA form, and its kernel if it has one that
+    takes ``rows`` fresh rows a slot."""
     S, T, G, D, R, _ = shape
     kw = dict(n_head=G * R, n_kv_head=G, scale=D ** -0.5)
     out = {"xla": lambda q, kn, vn, kv, ts:
            mod.grouped_masked_decode_attention(q, kn, vn, kv, ts, **kw)}
-    if hasattr(mod, "grouped_decode_attention"):
+    if hasattr(mod, "grouped_decode_attention") and (
+            rows == 1 or hasattr(mod, "last_fresh_row")):
         sizes = kernel_sizes(mod, shape)
 
         def kernel(q, kn, vn, kv, ts):
             kv = mod.append_rows(kv, kn, vn, ts)
-            work = mod.decode_work_items(ts, T, *sizes)
+            work = mod.decode_work_items(last_rows(mod, ts, rows, T), T,
+                                         *sizes)
             return mod.grouped_decode_attention(
                 q, kv["k"], kv["v"], ts, work, block=sizes[0],
                 tail=sizes[1], interpret=interpret, **kw), kv
@@ -139,43 +161,45 @@ def kernel_sizes(mod, shape):
                                n_kv_head=G, backend="tpu")
 
 
-def read_positions(mod, form, ts, shape):
+def read_positions(mod, form, ts, shape, rows=1):
     """Positions a step of ``form`` reads, summed over the slots."""
     import numpy as np
 
     if form == "xla":
         return int(shape[1] * len(ts))
-    return int(np.sum(mod.kv_positions_read(ts, *kernel_sizes(mod, shape))))
+    return int(np.sum(mod.kv_positions_read(
+        last_rows(mod, ts, rows, shape[1]), *kernel_sizes(mod, shape))))
 
 
-def abstract(shape, dtype, sharding=None):
+def abstract(shape, dtype, sharding=None, rows=1):
     import jax
     import jax.numpy as jnp
 
     S, T, G, D, R, _ = shape
+    by = (S,) if rows == 1 else (S, rows)
 
     def sd(shp, dt=jnp.float32):
         return jax.ShapeDtypeStruct(shp, dt, sharding=sharding)
 
     leaf = sd((S, T, G * D), jnp.dtype(dtype))
-    return (sd((S, G * R * D)), sd((S, G * D)), sd((S, G * D)), leaf, leaf,
-            leaf, leaf, sd((S,), jnp.int32))
+    return (sd(by + (G * R * D,)), sd(by + (G * D,)), sd(by + (G * D,)),
+            leaf, leaf, leaf, leaf, sd((S,), jnp.int32))
 
 
-def two_layer_program(mod, shape, dtype, interpret, sharding=None):
+def two_layer_program(mod, shape, dtype, interpret, sharding=None, rows=1):
     """``(f, abstract arguments)``: a step's two grouped layers through
     the kernel of ``mod`` at ``shape``, both layers' leaves donated."""
-    attend = forms(mod, shape, interpret)["kernel"]
+    attend = forms(mod, shape, interpret, rows)["kernel"]
 
     def f(q, kn, vn, k0, v0, k1, v1, ts):
         ctx, a = attend(q, kn, vn, {"k": k0, "v": v0}, ts)
         ctx, b = attend(q + ctx, kn, vn, {"k": k1, "v": v1}, ts)
         return ctx, a, b
 
-    return f, abstract(shape, dtype, sharding)
+    return f, abstract(shape, dtype, sharding, rows)
 
 
-def build_cost(repo, shape, dtype, knobs, rehearse):
+def build_cost(repo, shape, dtype, knobs, rehearse, rows=1):
     """Trace and lower (no compile) a step's two grouped layers with the
     kernel of the checkout at ``repo``, in THIS process, which is a
     fresh one (``--build-child``).  Returns the row."""
@@ -195,7 +219,7 @@ def build_cost(repo, shape, dtype, knobs, rehearse):
                                             topology_name="v5e:2x2")
         sharding, target = SingleDeviceSharding(topo.devices[0]), "v5e described"
     f, args = two_layer_program(load(repo, knobs), shape, dtype, rehearse,
-                                sharding)
+                                sharding, rows)
     t0 = time.perf_counter()
     traced = jax.jit(f, donate_argnums=(3, 4, 5, 6)).trace(*args)
     t1 = time.perf_counter()
@@ -219,6 +243,9 @@ def main(argv=None):
         ap.add_argument("--" + knob, action="append", type=int, default=None,
                         help="the module's _GROUPED_%s, set before tracing"
                         % knob.upper())
+    ap.add_argument("--rows", type=int, default=None,
+                    help="fresh rows a slot (a speculative round's K); "
+                         "unsaid: as the shape's cell hands them")
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--calls", type=int, default=16)
     ap.add_argument("--reps", type=int, default=20)
@@ -236,7 +263,7 @@ def main(argv=None):
         os.environ["JAX_PLATFORMS"] = "cpu"
         args.calls, args.reps = 2, 1
     shapes = {name: (REHEARSAL if args.rehearse_cpu else SHAPES)[name]
-              for name in args.shape or ["smallthinker", "falcon"]}
+              for name in args.shape or list(SHAPES)}
     sets = [(b, c, a, h) for b in args.block or [None]
             for c in args.classes or [None] for a in args.ahead or [None]
             for h in args.heads or [None]]
@@ -247,7 +274,8 @@ def main(argv=None):
             [sys.executable, os.path.abspath(__file__), "--build-child",
              json.dumps({"repo": repo, "shape": next(iter(shapes.values())),
                          "dtype": args.dtype, "knobs": sets[0],
-                         "rehearse": args.rehearse_cpu})],
+                         "rehearse": args.rehearse_cpu,
+                         "rows": args.rows or ROWS[next(iter(shapes))]})],
             capture_output=True, text=True)
         if child.returncode:
             print("--build: the child for %r failed:\n%s"
@@ -274,17 +302,19 @@ def main(argv=None):
     rows = []
     for shape_name, shape in shapes.items():
         S, T, G, D, R, traffic = shape
+        fresh = args.rows or ROWS[shape_name]
         variants = []       # (repo, form, cut, module, attend)
         for repo in args.repo or ["."]:
             mod = load(repo, sets[0])   # nothing of the XLA form to set
-            variants.append((repo, "xla", "none", mod,
-                             forms(mod, shape, args.rehearse_cpu)["xla"]))
+            variants.append((repo, "xla", "none", mod, forms(
+                mod, shape, args.rehearse_cpu, fresh)["xla"]))
             for knobs in sets:
                 for cut in args.cut or ["none"]:
                     mod = load(repo, knobs)
                     if cut == "arithmetic":
                         mod._block_part = no_arithmetic
-                    attend = forms(mod, shape, args.rehearse_cpu).get("kernel")
+                    attend = forms(mod, shape, args.rehearse_cpu,
+                                   fresh).get("kernel")
                     if attend is not None:
                         variants.append((repo, "kernel", cut, mod, attend))
 
@@ -299,8 +329,9 @@ def main(argv=None):
 
         programs = [program(v[-1]) for v in variants]
         rng = np.random.RandomState(args.seed)
-        q = jnp.asarray(rng.randn(S, G * R * D), jnp.float32)
-        kn, vn = (jnp.asarray(rng.randn(S, G * D), jnp.float32)
+        by = (S,) if fresh == 1 else (S, fresh)
+        q = jnp.asarray(rng.randn(*by, G * R * D), jnp.float32)
+        kn, vn = (jnp.asarray(rng.randn(*by, G * D), jnp.float32)
                   for _ in range(2))
         k, v = (jax.random.normal(key, (S, T, G * D), jnp.dtype(args.dtype))
                 for key in jax.random.split(jax.random.PRNGKey(args.seed)))
@@ -325,16 +356,24 @@ def main(argv=None):
                     np.testing.assert_allclose(np.asarray(ctx), first,
                                                rtol=0, atol=2e-2)
         row_bytes = 2 * G * D * jnp.dtype(args.dtype).itemsize  # K and V
-        live = int(np.sum(ts_host + 1)) * row_bytes
+        # what is live: every position the slot's LAST row may read
+        live = int(np.sum(np.minimum(ts_host + fresh, T))) * row_bytes
         for (repo, form, cut, mod, _), tt in zip(variants, times):
             tt = [None] if args.rehearse_cpu else tt  # no interpreter's time
             ms = (lambda x: None if x is None else x / args.calls * 1e3)
             call_ms = ms(statistics.median(tt))
-            read = read_positions(mod, form, ts_host, shape) * row_bytes
+            read = read_positions(mod, form, ts_host, shape,
+                                  fresh) * row_bytes
             rows.append({
-                "shape": shape_name, "repo": repo, "form": form, "cut": cut,
+                "shape": shape_name, "fresh_rows": fresh, "repo": repo,
+                "form": form, "cut": cut,
                 **{name: getattr(mod, "_GROUPED_" + name.upper(), None)
                    for name in KNOBS},
+                # K/V heads a product scored (the knob, else the rule)
+                "unit_heads": (mod._unit_heads(
+                    G, -(-fresh * R // 8) * 8, mod._GROUPED_HEADS)
+                    if form == "kernel" and hasattr(mod, "_unit_heads")
+                    else None),
                 "live_bytes": live, "read_bytes": read,
                 "read_over_live": read / live,
                 "call_ms": call_ms, "call_ms_min": ms(min(tt)),
