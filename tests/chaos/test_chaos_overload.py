@@ -17,6 +17,8 @@ import time
 import numpy as np
 import pytest
 
+from conftest import WAIT
+
 import paddle_tpu as fluid
 from paddle_tpu import faults, framework, monitor
 from paddle_tpu.serving import (
@@ -75,7 +77,7 @@ def test_server_admit_fault_point_injects_typed_error():
         assert plan.triggers()["server.admit"] == 2
         faults.disarm()
         # healed: admission is clean again and the request completes
-        out, = srv.submit({"x": _rows(2, seed=3)}).result()
+        out, = srv.submit({"x": _rows(2, seed=3)}).result(timeout=WAIT)
         assert out.shape == (2, 1)
     finally:
         srv.stop(drain=True)
@@ -90,7 +92,7 @@ def test_server_admit_delay_mode_slows_not_breaks():
             t0 = time.perf_counter()
             req = srv.submit({"x": _rows(1)})
             assert time.perf_counter() - t0 >= 0.05
-            req.result()
+            req.result(timeout=WAIT)
     finally:
         srv.stop(drain=True)
 
@@ -156,7 +158,7 @@ def test_chaos_overload_storm_goodput_floor_and_priority_order(
                 except (ServerOverloaded, DeadlineExceeded):
                     time.sleep(0.005)
 
-        threads = [threading.Thread(target=closed, args=(t,))
+        threads = [threading.Thread(target=closed, args=(t,), daemon=True)
                    for t in range(n_sat)]
         t0 = time.perf_counter()
         for t in threads:
@@ -164,7 +166,8 @@ def test_chaos_overload_storm_goodput_floor_and_priority_order(
         time.sleep(1.5)
         stop.set()
         for t in threads:
-            t.join()
+            t.join(WAIT)
+        assert not any(t.is_alive() for t in threads)
         sat_rps = sum(sat_done) / (time.perf_counter() - t0)
         assert sat_rps > 0
 
@@ -221,16 +224,17 @@ def test_chaos_overload_storm_goodput_floor_and_priority_order(
                         errs.append(repr(e))
                     return
 
-        threads = [threading.Thread(target=storm, args=(t,))
+        threads = [threading.Thread(target=storm, args=(t,), daemon=True)
                    for t in range(n_threads)]
-        threads.append(threading.Thread(target=sampler))
+        threads.append(threading.Thread(target=sampler, daemon=True))
         t0 = time.perf_counter()
         for t in threads:
             t.start()
         time.sleep(3.0)
         stop.set()
         for t in threads:
-            t.join()
+            t.join(WAIT)
+        assert not any(t.is_alive() for t in threads)
         elapsed = time.perf_counter() - t0
 
         # zero lost accepted requests: every submission ended in a

@@ -13,6 +13,8 @@ import time
 import numpy as np
 import pytest
 
+from conftest import WAIT
+
 import paddle_tpu as fluid
 from paddle_tpu import faults, framework, monitor
 from paddle_tpu.serving import InferenceServer, wire
@@ -166,7 +168,7 @@ def test_replica_dispatch_fault_requeues_without_losing_requests():
         # replica), the third heals — the request completes via requeue
         with faults.armed("replica.dispatch=error:RuntimeError,times=2"):
             x = _rows(2, seed=2)
-            out, = srv.submit({"x": x}, timeout_ms=15000).result()
+            out, = srv.submit({"x": x}, timeout_ms=15000).result(timeout=WAIT)
         np.testing.assert_allclose(out, x.sum(axis=1, keepdims=True),
                                    rtol=1e-6)
         assert monitor.counter_value(
@@ -188,13 +190,14 @@ def test_retired_replica_readmitted_half_open():
         with faults.armed("replica.dispatch=error:RuntimeError,times=3"):
             for _ in range(3):
                 with pytest.raises(RuntimeError, match="injected fault"):
-                    srv.submit({"x": _rows(1)}, timeout_ms=5000).result()
+                    srv.submit({"x": _rows(1)},
+                               timeout_ms=5000).result(timeout=WAIT)
         assert srv.num_replicas == 0
         time.sleep(0.4)  # cooldown
         # the next submitted request IS the half-open probe (the fault
         # healed, so it succeeds and fully re-admits the replica)
         out, = srv.submit({"x": _rows(1, seed=6)},
-                          timeout_ms=5000).result()
+                          timeout_ms=5000).result(timeout=WAIT)
         assert out.shape == (1, 1)
         assert srv.num_replicas == 1
         assert monitor.counter_value(
@@ -220,7 +223,9 @@ def test_requeue_expired_deadline_fails_fast_without_burning_slots():
         with faults.armed("replica.dispatch=delay:0.08;"
                           "replica.dispatch=error:RuntimeError,times=1"):
             with pytest.raises(DeadlineExceeded):
-                srv.submit({"x": _rows(1)}, timeout_ms=50).result()
+                # no timeout of the wait's own: the request's 50 ms bound it
+                srv.submit({"x": _rows(1)},
+                           timeout_ms=50).result(timeout=None)
             # the future raises at ITS deadline; the server reaches the
             # requeue decision ~30ms later — wait for it to land
             deadline = time.monotonic() + 5
@@ -294,12 +299,13 @@ def test_chaos_fleet_storm_corruption_delay_kill_readmission(mlp_model_dir):
                     errs.append(repr(e))
                     return
 
-        threads = [threading.Thread(target=storm, args=(t,))
+        threads = [threading.Thread(target=storm, args=(t,), daemon=True)
                    for t in range(4)]
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(WAIT)
+        assert not any(t.is_alive() for t in threads)
         # zero lost accepted requests, every fault actually landed
         assert errs == [], "accepted requests were lost: %s" % errs[:3]
         assert completed[0] == 64

@@ -10,6 +10,8 @@ import time
 import numpy as np
 import pytest
 
+from conftest import WAIT
+
 from paddle_tpu import monitor, serving
 from paddle_tpu.serving import (
     PRIORITY_HIGH,
@@ -392,7 +394,7 @@ def test_batcher_default_hooks_fail_typed():
     b.offer(first)
     b.offer(_sreq(priority=PRIORITY_HIGH))  # evicts `first`
     with pytest.raises(ServerOverloaded) as ei:
-        first.result()
+        first.result(timeout=WAIT)
     assert ei.value.retry_after_ms is not None
     b.close()
 
@@ -425,15 +427,15 @@ def test_server_sheds_low_priority_for_high_under_pressure():
         outcomes = []
         for r in queued:
             try:
-                r.result()
+                r.result(timeout=WAIT)
                 outcomes.append("ok")
             except ServerOverloaded as e:
                 outcomes.append("shed")
                 assert e.retry_after_ms is not None and e.retry_after_ms >= 1
         assert outcomes.count("shed") == 1  # exactly one low evicted
-        vip.result()      # the high-priority request completed
+        vip.result(timeout=WAIT)      # the high-priority request completed
         for r in pipelined:
-            r.result()
+            r.result(timeout=WAIT)
         m = srv.metrics()
         assert m["shed"] == 1
     finally:
@@ -466,8 +468,9 @@ def test_brownout_level3_sheds_lowest_class_at_the_door():
             srv.submit({"x": _rows(1)}, priority=PRIORITY_LOW)
         assert ei.value.retry_after_ms is not None
         # normal and high still pass at L3 (only the lowest class sheds)
-        srv.submit({"x": _rows(1)}, priority=PRIORITY_NORMAL).result()
-        srv.submit({"x": _rows(1)}, priority=PRIORITY_HIGH).result()
+        for priority in (PRIORITY_NORMAL, PRIORITY_HIGH):
+            srv.submit({"x": _rows(1)},
+                       priority=priority).result(timeout=WAIT)
     finally:
         srv.stop(drain=True)
 
@@ -486,7 +489,8 @@ def test_brownout_descends_under_low_priority_only_traffic():
         accepted = False
         while time.monotonic() < deadline:
             try:
-                srv.submit({"x": _rows(1)}, priority=PRIORITY_LOW).result()
+                srv.submit({"x": _rows(1)},
+                           priority=PRIORITY_LOW).result(timeout=WAIT)
                 accepted = True
                 break
             except ServerOverloaded:
@@ -680,12 +684,13 @@ def test_fleet_retry_throttle_denial_counts_and_propagates():
                 with lock:
                     results.append("shed")
 
-        threads = [threading.Thread(target=one, args=(i,))
+        threads = [threading.Thread(target=one, args=(i,), daemon=True)
                    for i in range(8)]
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(WAIT)
+        assert not any(t.is_alive() for t in threads)
         assert "shed" in results, results
         assert "ok" in results, results
         # a burst-0 bucket denies every paced retry: the shed propagated

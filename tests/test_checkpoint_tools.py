@@ -13,6 +13,8 @@ import sys
 import numpy as np
 import pytest
 
+from conftest import WAIT
+
 import paddle_tpu as fluid
 from paddle_tpu import framework, sharding
 from paddle_tpu.faults.checkpoint import hash_file
@@ -171,11 +173,12 @@ def test_cli_exit_codes(run_dir, tmp_path):
                PYTHONPATH=REPO_ROOT + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
     tool = os.path.join(REPO_ROOT, "tools", "check_checkpoint.py")
-    ok = subprocess.run([sys.executable, tool, run_dir],
+    ok = subprocess.run([sys.executable, tool, run_dir], timeout=WAIT,
                         capture_output=True, text=True, env=env)
     assert ok.returncode == 0, ok.stderr
     assert "OK" in ok.stdout
     bad = subprocess.run([sys.executable, tool, str(tmp_path / "nope")],
+                         timeout=WAIT,
                          capture_output=True, text=True, env=env)
     assert bad.returncode == 1
     assert "does not exist" in bad.stderr

@@ -15,7 +15,7 @@ def _run(*args):
     # session's compile cache
     return subprocess.run(
         [sys.executable, SMOKE, *args], cwd=REPO_ROOT,
-        capture_output=True, text=True, timeout=600)
+        capture_output=True, text=True, timeout=240)
 
 
 def test_rehearsal_runs_every_leg_and_is_never_a_chip_result():

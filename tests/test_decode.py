@@ -16,6 +16,8 @@ import time
 import numpy as np
 import pytest
 
+from conftest import WAIT
+
 from paddle_tpu import monitor
 from paddle_tpu.decoding import (
     make_transformer_lm_pooled_step_fn,
@@ -263,16 +265,16 @@ def test_batch_admit_equals_single_admits_in_order(lm_state, kv,
 def test_generation_eos_and_cap_termination(chain_server):
     # EOS mid-stream: prompt ends at 5 -> 6, 7, 8, 9(EOS)
     req = chain_server.submit({"tokens": np.array([4, 5], np.int32)})
-    assert req.result()[0].tolist() == [6, 7, 8, 9]
+    assert req.result(timeout=WAIT)[0].tolist() == [6, 7, 8, 9]
     # cap termination: chain from 10 never hits EOS before the cap
     req = chain_server.submit({"tokens": np.array([10], np.int32)},
                               max_new_tokens=5)
-    assert req.result()[0].tolist() == [11, 12, 13, 14, 15]
+    assert req.result(timeout=WAIT)[0].tolist() == [11, 12, 13, 14, 15]
     # 2-D [1, L] and positional feeds accepted
     req = chain_server.submit({"tokens": np.array([[4, 5]], np.int32)})
-    assert req.result()[0].tolist() == [6, 7, 8, 9]
-    assert chain_server.submit(
-        [np.array([5], np.int32)]).result()[0].tolist() == [6, 7, 8, 9]
+    assert req.result(timeout=WAIT)[0].tolist() == [6, 7, 8, 9]
+    req = chain_server.submit([np.array([5], np.int32)])
+    assert req.result(timeout=WAIT)[0].tolist() == [6, 7, 8, 9]
 
 
 def test_seq_len_histogram_feeds_kv_ladder_proposal(chain_server):
@@ -285,7 +287,7 @@ def test_seq_len_histogram_feeds_kv_ladder_proposal(chain_server):
     before = chain_server.seq_len_histogram().get(8, 0)
     req = chain_server.submit({"tokens": np.array([10, 11, 12], np.int32)},
                               max_new_tokens=5)  # total = 3 + 5 = 8
-    req.result()
+    req.result(timeout=WAIT)
     hist = chain_server.seq_len_histogram()
     assert hist.get(8, 0) == before + 1
     assert chain_server.metrics()["decode"]["seq_len_histogram"]["8"] >= 1
@@ -324,7 +326,7 @@ def test_stream_yields_chunks_before_completion(slow_server):
     got = [t for c in [first] + rest for t in c.tolist()]
     assert got == expected_chain([10], 21)
     assert len(rest) >= 1  # chunked, not one blob
-    assert req.result()[0].tolist() == got
+    assert req.result(timeout=WAIT)[0].tolist() == got
 
 
 def test_mixed_storm_zero_recompiles_and_isolation(chain_server):
@@ -348,16 +350,18 @@ def test_mixed_storm_zero_recompiles_and_isolation(chain_server):
             else:
                 got = chain_server.submit(
                     {"tokens": prompt},
-                    max_new_tokens=cap).result()[0].tolist()
+                    max_new_tokens=cap).result(timeout=WAIT)[0].tolist()
             results[i] = (prompt.tolist(), cap, got)
         except Exception as e:  # noqa: BLE001
             errs.append(e)
 
-    threads = [threading.Thread(target=one, args=(i,)) for i in range(24)]
+    threads = [threading.Thread(target=one, args=(i,),
+                                daemon=True) for i in range(24)]
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(WAIT)
+    assert not any(t.is_alive() for t in threads)
     assert not errs
     assert len(results) == 24
     for prompt, cap, got in results.values():
@@ -393,7 +397,7 @@ def test_continuous_batching_beats_request_at_a_time(chain_server):
         group = [chain_server.submit({"tokens": p}, max_new_tokens=c)
                  for p, c in workload()[g:g + chain_server.max_batch_size]]
         for r in group:
-            r.result()
+            r.result(timeout=WAIT)
     rat_ticks = ticks() - t0
 
     # continuous: submit everything; finished sequences free slots
@@ -401,7 +405,7 @@ def test_continuous_batching_beats_request_at_a_time(chain_server):
     t0 = ticks()
     reqs = [chain_server.submit({"tokens": p}, max_new_tokens=c)
             for p, c in workload()]
-    outs = [r.result()[0].tolist() for r in reqs]
+    outs = [r.result(timeout=WAIT)[0].tolist() for r in reqs]
     cont_ticks = ticks() - t0
 
     for (p, c), got in zip(workload(), outs):
@@ -478,7 +482,9 @@ def test_abandoned_stream_never_started_frees_slot(slow_server):
     g0 = gen_tokens()
     gen = Client(slow_server).infer_stream(
         {"tokens": np.array([10], np.int32)}, max_new_tokens=40)
+    deadline = time.monotonic() + 10.0
     while not slow_server._active_count():
+        assert time.monotonic() < deadline
         time.sleep(0.005)
     del gen
     gc.collect()
@@ -527,7 +533,8 @@ def test_stop_drain_finishes_queued_and_abort_fails_typed():
                        max_new_tokens=4) for i in range(6)]
     srv.stop(drain=True, timeout=30.0)
     for i, r in enumerate(reqs):
-        assert r.result()[0].tolist() == expected_chain([10 + i], 5)
+        assert r.result(timeout=WAIT)[0].tolist() == expected_chain(
+            [10 + i], 5)
     with pytest.raises(ServerClosed):
         srv.submit({"tokens": np.array([2], np.int32)})
 
@@ -539,13 +546,13 @@ def test_stop_drain_finishes_queued_and_abort_fails_typed():
     srv2.stop(drain=False, timeout=30.0)
     for r in reqs:
         with pytest.raises(ServerClosed):
-            r.result()
+            r.result(timeout=WAIT)
 
 
 def test_decode_metrics_series(chain_server):
     req = chain_server.submit({"tokens": np.array([2, 3, 4], np.int32)},
                               max_new_tokens=4)
-    req.result()
+    req.result(timeout=WAIT)
     d = chain_server.metrics()["decode"]
     assert d["generated_tokens"] > 0 and d["prefill_tokens"] > 0
     assert d["ticks"] > 0
@@ -925,9 +932,10 @@ def test_wire_stream_closed_from_other_thread_keeps_conn_usable():
         it = rc.infer_stream({"tokens": np.array([2], np.int32)},
                              max_new_tokens=12)
         next(it)  # stream live: this thread's pooled body is half-read
-        t = threading.Thread(target=it.close)
+        t = threading.Thread(target=it.close, daemon=True)
         t.start()
-        t.join()
+        t.join(WAIT)
+        assert not t.is_alive()
         # the SAME thread that opened the stream must get a clean
         # exchange (auto-reopened conn, not the desynced one)
         out, = rc.infer({"tokens": np.array([4, 5], np.int32)})
@@ -977,12 +985,13 @@ def test_decode_fleet_two_children_stream_and_zero_recompiles(
             except Exception as e:  # noqa: BLE001
                 errs.append(e)
 
-        threads = [threading.Thread(target=one, args=(i,))
+        threads = [threading.Thread(target=one, args=(i,), daemon=True)
                    for i in range(12)]
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(WAIT)
+        assert not any(t.is_alive() for t in threads)
         assert not errs
         for got, n_chunks in streamed:
             assert got == ref

@@ -757,7 +757,8 @@ def _run_dense_ps_parity(opt_factory, steps=6, rtol=2e-4):
                 ls.append(float(np.asarray(l)))
         results[tid] = ls
 
-    threads = [threading.Thread(target=trainer, args=(tid,)) for tid in (0, 1)]
+    threads = [threading.Thread(target=trainer, args=(tid,),
+                                daemon=True) for tid in (0, 1)]
     for th in threads:
         th.start()
     for th in threads:

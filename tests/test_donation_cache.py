@@ -31,7 +31,7 @@ def _run_child(cache_dir):
         REPO_ROOT + os.pathsep + prev if prev else REPO_ROOT)
     proc = subprocess.run(
         [sys.executable, _CHILD, str(cache_dir)],
-        capture_output=True, text=True, timeout=300, env=env)
+        capture_output=True, text=True, timeout=240, env=env)
     assert proc.returncode == 0, (
         "donation child failed (rc=%s):\n%s" % (proc.returncode,
                                                 proc.stderr[-4000:]))

@@ -13,6 +13,8 @@ import time
 import numpy as np
 import pytest
 
+from conftest import WAIT
+
 import paddle_tpu as fluid
 from paddle_tpu import faults, framework, monitor
 from paddle_tpu.faults.retry import RetryPolicy
@@ -764,7 +766,7 @@ def test_checkpoint_save_async_hides_write_cost(tmp_path):
             assert returned_in < 0.3, returned_in  # write cost hidden
             assert ck.in_flight
             assert ck.latest() is None  # not committed yet
-            path = ck.wait()
+            path = ck.wait(WAIT)
         assert path.endswith("ckpt-000005")
         assert ck.latest() == path
         scope2 = fluid.Scope()
@@ -796,7 +798,7 @@ def test_checkpoint_async_snapshot_is_copy_on_write(tmp_path):
             ck.save_async(prog, scope, step=1)
             # mutate the live scope while the writer is mid-save
             exe.run(prog, feed=feed, fetch_list=[loss])
-            ck.wait()
+            ck.wait(WAIT)
     scope2 = fluid.Scope()
     ck.restore(prog, scope2)
     for name, val in at_snapshot.items():
@@ -815,11 +817,11 @@ def test_checkpoint_async_write_error_reraises_at_wait(tmp_path):
         with faults.armed("checkpoint.commit=error:OSError"):
             ck.save_async(prog, scope, step=1)
             with pytest.raises(OSError):
-                ck.wait()
+                ck.wait(WAIT)
         # the failed attempt committed nothing; a clean retry succeeds
         assert ck.latest() is None
         ck.save_async(prog, scope, step=1)
-        assert ck.wait().endswith("ckpt-000001")
+        assert ck.wait(WAIT).endswith("ckpt-000001")
 
 
 def test_checkpoint_async_serializes_with_next_save(tmp_path):
@@ -836,7 +838,7 @@ def test_checkpoint_async_serializes_with_next_save(tmp_path):
         with faults.armed("checkpoint.commit=delay:0.2,times=1"):
             ck.save_async(prog, scope, step=1)
             ck.save_async(prog, scope, step=2)  # joins step-1 first
-            ck.wait()
+            ck.wait(WAIT)
     names = sorted(d for d in os.listdir(str(tmp_path))
                    if d.startswith("ckpt-"))
     assert names == ["ckpt-000001", "ckpt-000002"]

@@ -5,7 +5,7 @@ The Pallas TPU kernel pair runs here under interpret mode at toy widths
 held to a float32 softmax reference on sequences padded to unequal
 lengths, at EVERY position: a padded query row attends the real keys.
 (The kernels' compile for a described v5e at the BERT cells' widths is
-in tests/test_decode_attention.py, the one file that loads the chip's
+in tests/test_v5e_compile.py, the one file that loads the chip's
 compiler.)
 
 Tolerances, from the dtypes.  Both forms take bf16 operands, keep scores

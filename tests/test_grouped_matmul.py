@@ -6,7 +6,7 @@ form (``jax.lax.ragged_dot``) and against a plain loop over the groups,
 at ragged group sizes: empty groups (first, last, several in a row), a
 group that straddles row tiles, one group holding every row, no group
 holding any, rows that belong to no group.  It compiles for a described
-v5e chip at the benchmark's widths in tests/test_decode_attention.py
+v5e chip at the benchmark's widths in tests/test_v5e_compile.py
 (one file loads the TPU's compiler).
 
 Tolerance: every product and sum is float32 on both sides; they differ

@@ -17,6 +17,8 @@ Pinned here:
 import numpy as np
 import pytest
 
+from conftest import WAIT
+
 import jax.numpy as jnp
 
 from paddle_tpu.decoding import (
@@ -141,7 +143,7 @@ def test_int8_pool_zero_recompiles_and_resize_carries_scales(lm_state):
 def _greedy_tokens(srv, prompt, n):
     req = srv.submit({"tokens": np.asarray(prompt, np.int32)},
                      max_new_tokens=n)
-    return req.result()[0].tolist()
+    return req.result(timeout=WAIT)[0].tolist()
 
 
 def test_decode_server_int8_parity_and_kv_bytes_gauge(lm_state):
@@ -210,7 +212,7 @@ def test_int8_prefix_and_speculative_compose(lm_state):
         assert _greedy_tokens(srv, prompt, 8) == want
         req = srv.submit({"tokens": np.asarray(prompt, np.int32)},
                          max_new_tokens=8, speculative=True)
-        assert req.result()[0].tolist() == want
+        assert req.result(timeout=WAIT)[0].tolist() == want
         assert _greedy_tokens(srv, prompt, 8) == want
         assert srv._pool.jit_cache_stats()["misses"] == misses0
         assert srv.metrics().get("recompiles", 0) == 0

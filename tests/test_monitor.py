@@ -13,6 +13,8 @@ import urllib.request
 import numpy as np
 import pytest
 
+from conftest import WAIT
+
 import paddle_tpu as fluid
 from paddle_tpu import framework, models, monitor, profiler
 from paddle_tpu.inference import AnalysisConfig, create_paddle_predictor
@@ -217,7 +219,8 @@ def test_jsonl_trace_concurrent_emit_and_restart(tmp_path):
             errors.append(exc)
 
     threads = [
-        threading.Thread(target=emitter, args=(t,)) for t in range(n_emitters)
+        threading.Thread(target=emitter, args=(t,),
+                         daemon=True) for t in range(n_emitters)
     ]
     for t in threads:
         t.start()
@@ -231,7 +234,8 @@ def test_jsonl_trace_concurrent_emit_and_restart(tmp_path):
     profiler.stop_jsonl_trace()
     stop.set()
     for t in threads:
-        t.join()
+        t.join(WAIT)
+    assert not any(t.is_alive() for t in threads)
     assert not errors, errors
     total = 0
     for p in paths:

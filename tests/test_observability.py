@@ -446,8 +446,8 @@ def test_admin_surfaces_survive_concurrent_hammering():
                 errors.append("%s: %r" % (path, e))
                 return
 
-    threads = [threading.Thread(target=traffic) for _ in range(2)]
-    threads += [threading.Thread(target=hammer, args=(p,))
+    threads = [threading.Thread(target=traffic, daemon=True) for _ in range(2)]
+    threads += [threading.Thread(target=hammer, args=(p,), daemon=True)
                 for p in ("/metrics", "/tracez", "/sloz",
                           "/statusz", "/eventz")]
     try:
@@ -517,7 +517,8 @@ def test_injected_dispatch_delay_fires_and_clears_the_fast_burn_alert():
                 pass
             i += 1
 
-    injectors = [threading.Thread(target=injector, args=(100 * i,))
+    injectors = [threading.Thread(target=injector, args=(100 * i,),
+                                  daemon=True)
                  for i in range(4)]
     try:
         with faults.armed("fleet.dispatch=delay:0.3"):
@@ -591,7 +592,7 @@ def test_deadline_miss_lands_in_child_and_federated_tracez(mlp_model_dir):
         with monitor.flight_recorder(slow_ms=1e9):
             blocker = threading.Thread(
                 target=lambda: fleet.infer(
-                    {"x": _rows(1, seed=5)}, timeout_ms=30000))
+                    {"x": _rows(1, seed=5)}, timeout_ms=30000), daemon=True)
             blocker.start()
             time.sleep(0.08)
             with pytest.raises(DeadlineExceeded):

@@ -9,6 +9,8 @@ import threading
 import numpy as np
 import pytest
 
+from conftest import WAIT
+
 import paddle_tpu as fluid
 from paddle_tpu import framework, models, serving
 from paddle_tpu.contrib.mixed_precision import inference as mp_inf
@@ -344,12 +346,13 @@ def test_fleet_children_rebuild_the_variant_and_never_recompile(tmp_path):
             except Exception as e:  # noqa: BLE001 — assertion target
                 errors.append(e)
 
-        threads = [threading.Thread(target=storm, args=(t,))
+        threads = [threading.Thread(target=storm, args=(t,), daemon=True)
                    for t in range(4)]
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(WAIT)
+        assert not any(t.is_alive() for t in threads)
         assert errors == []
         for be in fleet._backends:
             status = be.transport.get_json("/statusz")

@@ -10,6 +10,8 @@ import time
 import numpy as np
 import pytest
 
+from conftest import WAIT
+
 import paddle_tpu as fluid
 from paddle_tpu import framework, profiler, serving
 from paddle_tpu.inference import AnalysisConfig, create_paddle_predictor
@@ -103,14 +105,16 @@ def test_batch_coalescing_under_concurrent_submitters(predictor):
         start = threading.Barrier(n_req)
 
         def go(i):
-            start.wait()
+            start.wait(WAIT)
             (results[i],) = cli.infer({"x": xb})
 
-        threads = [threading.Thread(target=go, args=(i,)) for i in range(n_req)]
+        threads = [threading.Thread(target=go, args=(i,),
+                                    daemon=True) for i in range(n_req)]
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(WAIT)
+        assert not any(t.is_alive() for t in threads)
         # a coalesced request runs in an 8-row (or smaller) bucket's
         # executable, ``want`` in the 1-row one: executables compiled
         # for different batch shapes may order a sum differently, so
@@ -162,7 +166,8 @@ def test_deadline_expiry_is_timeout_error_not_hang():
         fut = server.submit({"x": _rows(1)}, timeout_ms=40)
         t0 = time.monotonic()
         with pytest.raises(DeadlineExceeded):
-            fut.result()
+            # no timeout of the wait's own: the request's 40 ms bound it
+            fut.result(timeout=None)
         assert time.monotonic() - t0 < 5.0  # error, not a hang
         blocker.result(timeout=5)
         # the worker eventually pops the expired request and sheds it
@@ -263,12 +268,14 @@ def test_zero_recompiles_after_warmup_mixed_concurrent_sizes(predictor):
                 errors.append(e)
 
         threads = [
-            threading.Thread(target=go, args=(i, n)) for i, n in enumerate(sizes)
+            threading.Thread(target=go, args=(i, n),
+                             daemon=True) for i, n in enumerate(sizes)
         ]
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(WAIT)
+        assert not any(t.is_alive() for t in threads)
         assert not errors
         stats = predictor.jit_cache_stats()
         assert stats["misses"] == misses0, (
@@ -493,12 +500,13 @@ def test_multi_replica_warmup_compiles_every_replica(predictor, tmp_path):
             except Exception as e:  # noqa: BLE001
                 errors.append(e)
 
-        threads = [threading.Thread(target=go, args=(i, n))
+        threads = [threading.Thread(target=go, args=(i, n), daemon=True)
                    for i, n in enumerate(sizes)]
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(WAIT)
+        assert not any(t.is_alive() for t in threads)
         assert not errors
         assert [predictor.jit_cache_stats()["misses"],
                 second.jit_cache_stats()["misses"]] == misses0, (
@@ -526,7 +534,7 @@ def test_idle_batcher_sleeps_on_condition_not_poll():
     def worker():
         got.append(b.next_batch(stop, lambda r: None, block=True))
 
-    t = threading.Thread(target=worker)
+    t = threading.Thread(target=worker, daemon=True)
     t.start()
     time.sleep(0.15)  # worker is parked on the condition
     t0 = time.perf_counter()
@@ -538,7 +546,7 @@ def test_idle_batcher_sleeps_on_condition_not_poll():
 
     # wake() releases a parked consumer once stopped
     got.clear()
-    t = threading.Thread(target=worker)
+    t = threading.Thread(target=worker, daemon=True)
     t.start()
     time.sleep(0.05)
     stop.set()
@@ -657,7 +665,7 @@ def test_flight_recorder_retains_deadline_missed_requests():
             fut = server.submit({"x": _rows(1)},
                                 timeout_ms=40, trace_id="dead000011112222")
             with pytest.raises(DeadlineExceeded):
-                fut.result()
+                fut.result(timeout=None)  # its 40 ms deadline is the bound
             blocker.result(timeout=5)
             deadline = time.monotonic() + 5
             while (rec.get_record("dead000011112222") is None

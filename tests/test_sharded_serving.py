@@ -22,6 +22,8 @@ import threading
 import numpy as np
 import pytest
 
+from conftest import WAIT
+
 import paddle_tpu as fluid
 from paddle_tpu import framework, models, monitor, serving, sharding
 from paddle_tpu.inference import AnalysisConfig, create_paddle_predictor
@@ -200,12 +202,13 @@ def test_sharded_server_storm_zero_recompiles(lm_dirs):
                 except Exception as e:  # noqa: BLE001
                     errs.append(e)
 
-        threads = [threading.Thread(target=storm, args=(t,))
+        threads = [threading.Thread(target=storm, args=(t,), daemon=True)
                    for t in range(4)]
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(WAIT)
+        assert not any(t.is_alive() for t in threads)
         assert errs == []
 
         # the zero-recompile guarantee holds for a mesh-spanning group
@@ -263,18 +266,19 @@ def test_sharded_fleet_serves_groups(lm_dirs):
                 except Exception as e:  # noqa: BLE001
                     errs.append(e)
 
-        threads = [threading.Thread(target=storm, args=(t,))
+        threads = [threading.Thread(target=storm, args=(t,), daemon=True)
                    for t in range(3)]
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(WAIT)
+        assert not any(t.is_alive() for t in threads)
         assert errs == []
 
         for be in fleet._backends:
             host, port = be.transport.address
             doc = json.load(urllib.request.urlopen(
-                "http://%s:%d/statusz" % (host, port)))
+                "http://%s:%d/statusz" % (host, port), timeout=WAIT))
             assert doc["metrics"]["recompiles"] == 0
             sh = doc["sharding"]["r0"]
             assert sh["sharded"] and sh["n_sharded"] >= 20

@@ -1,0 +1,350 @@
+"""The kernels at the benchmark's widths, compiled for a described v5e chip.
+
+No chip is attached: nothing runs, the chip's compiler accepts or
+refuses.  One file loads that compiler (libtpu, in the test's own
+process; skipped where the topology cannot be described), so the
+compile cases of ``paddle_tpu/decode_attention.py``,
+``fused_attention.py``, ``grouped_matmul.py``, ``hybrid_ssm.py`` and the
+gpt1 chunk share its module-scoped ``one_chip`` here, in a file of
+their own: under ``--dist loadfile`` a file is one worker's job, and the
+kernel tests of ``tests/test_decode_attention.py`` (interpret mode, 167
+cases) are another's.
+"""
+import pytest
+
+from test_decode_attention import _two_grouped_layers, _two_sparse_layers
+
+from paddle_tpu import decode_attention as da
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no compiler
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # an executable for a described chip cannot be read back from the
+    # persistent cache without the chip: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def test_kernel_compiles_for_v5e_at_gpt1_widths(one_chip):
+    """320 slots x 512 positions x 768 (12 heads), blocks of 128: the
+    kernel lowers, and both cache leaves are aliased in place (no
+    temporary of a leaf's size)."""
+    import jax
+    import jax.numpy as jnp
+
+    s, t, d, h = 320, 512, 768, 12
+    block = da.kv_read_block(t)
+    assert da.kernel_supported(t, d, h)
+
+    def f(q, kn, vn, kc, vc, ts):
+        return da.ragged_decode_attention(
+            q, kn, vn, kc, vc, ts, da.decode_work_items(ts, t, block),
+            n_head=h, scale=0.125, block=block)
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(f, donate_argnums=(3, 4)).lower(
+        sd((s, d)), sd((s, d)), sd((s, d)), sd((s, t, d)), sd((s, t, d)),
+        sd((s,), jnp.int32)).compile()
+    assert "ragged_decode_attention" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    leaf = s * t * d * 4
+    assert mem.alias_size_in_bytes >= 2 * leaf
+    assert mem.temp_size_in_bytes < leaf // 8
+
+
+def test_block_kernel_compiles_for_v5e_at_minicpm_sala_widths(one_chip):
+    """64 slots x 32768 positions x 2 K/V heads of 128, 16 query heads a
+    K/V head, 98 named blocks of 64 of which the first and the window's
+    33 are declared runs: the kernel lowers, reads the leaves where they
+    lie (no temporary of a leaf's size), and its buffers stay inside the
+    VMEM a kernel gets without asking (it asks for none)."""
+    import jax
+    import jax.numpy as jnp
+
+    s, t, g, d, rep, block, b = 64, 32768, 2, 128, 16, 64, 98
+
+    def f(q, kc, vc, ts, blocks, valid):
+        return da.block_sparse_decode_attention(
+            q, kc, vc, ts, blocks, valid, n_head=g * rep, n_kv_head=g,
+            scale=0.088, block=block, shared_runs=((0, 1), (1, 33)))
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = jax.jit(f).lower(
+        sd((s, g * rep * d)), sd((s, t, g * d), jnp.bfloat16),
+        sd((s, t, g * d), jnp.bfloat16), sd((s,), jnp.int32),
+        sd((s, g, b), jnp.int32), sd((s, g, b), jnp.bool_))
+    # the kernel asked for no more VMEM than the compiler's default, and
+    # the chip's compiler accepts it so
+    assert "scoped_memory_configs" not in lowered.as_text()
+    compiled = lowered.compile()
+    assert "block_sparse_decode_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        s * t * g * d * 2) // 8
+
+
+def test_grouped_kernel_compiles_for_v5e_at_smallthinker_widths(one_chip):
+    """40 slots x 16,384 positions x 4 K/V heads of 128, 7 query heads a
+    K/V head, bf16: two layers' append-and-read lower to ONE kernel
+    called twice, both layers' leaves are appended in place and handed
+    to the kernel as they lie (the benchmark's shape needle finds the
+    call; no temporary of a leaf's size)."""
+    import re
+
+    import jax
+
+    f, args, _ = _two_grouped_layers(one_chip)
+    lowered = jax.jit(f, donate_argnums=(3, 4, 5, 6)).trace(*args).lower()
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(r"call @_grouped\b", text)) == 2
+    compiled = lowered.compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "grouped_decode_attention" in line and "custom-call(" in line]
+    assert len(calls) == 2 and all("bf16[40,16384,512]" in c for c in calls)
+    leaf = 40 * 16384 * 512 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * leaf
+    assert mem.temp_size_in_bytes < leaf // 8
+
+
+def test_grouped_kernel_compiles_for_v5e_at_k_exaone_widths(one_chip):
+    """128 slots x 4,096 positions x 8 K/V heads of 128, 8 query heads a
+    K/V head, bf16, TWO fresh rows a slot (``k_exaone_236b_a23b``'s
+    global layer and its module in one self-drafting round): the two
+    append-and-reads lower to ONE kernel called twice, the leaves are
+    appended in place and handed to the kernel as they lie — no copy of
+    a rung-sized operand in the compiled text, no temporary of a leaf's
+    size — and the unit is the rule's (four heads: 64 rows x 512
+    lanes)."""
+    import re
+
+    import jax
+
+    f, args, _ = _two_grouped_layers(one_chip, "k_exaone")
+    assert args[0].shape == (128, 2, 64 * 128)
+    lowered = jax.jit(f, donate_argnums=(3, 4, 5, 6)).trace(*args).lower()
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(r"call @_grouped\b", text)) == 2
+    compiled = lowered.compile()
+    lines = compiled.as_text().splitlines()
+    calls = [line for line in lines
+             if "grouped_decode_attention" in line and "custom-call(" in line]
+    assert len(calls) == 2 and all("bf16[128,4096,1024]" in c for c in calls)
+    assert all("bf16[128,2,64,512]" in c for c in calls)    # q: units, R, L
+    assert not [line for line in lines
+                if " copy(" in line and "[128,4096," in line]
+    leaf = 128 * 4096 * 1024 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * leaf
+    assert mem.temp_size_in_bytes < leaf // 8
+
+
+def test_block_kernel_is_lowered_once_for_two_layers_on_v5e(one_chip):
+    """Lowered for the chip, two sparse layers are two calls of ONE
+    function that holds the ONE kernel of the module."""
+    import re
+
+    import jax
+
+    f, args, _ = _two_sparse_layers(one_chip)
+    text = jax.jit(f).trace(*args).lower().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(r"func\.func private @_block_sparse\b", text)) == 1
+    assert len(re.findall(r"call @_block_sparse\b", text)) == 2
+
+
+def test_hybrid_ssm_layer_compiles_for_v5e_at_falcon_h1_widths(one_chip):
+    """80 slots x 1024 positions, one block's Mamba-2 mixer and
+    grouped-query attention at Falcon-H1-34B's widths (bf16 weights and
+    K/V, fp32 state): the SSM, conv and both K/V leaves are aliased in
+    place, no temporary is the size of the 335 MB SSM leaf (the update is
+    one pass), and the update carries its scope's name for the trace."""
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import hybrid_ssm as hs
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "falcon_h1_34b.json")) as fh:
+        cfg = json.load(fh)
+    d = hs.dims(cfg)
+    s, t, p = 80, 1024, "lm_l0_"
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w = {k: sd(shp, jnp.bfloat16 if len(shp) == 2
+               and not k.endswith("conv_w") else jnp.float32)
+         for k, shp in hs.param_shapes(cfg).items() if k.startswith(p)}
+
+    def f(w, ssm, conv, kc, vc, x, ts):
+        mix, ssm, conv = hs.mamba2_step(x, w, p, ssm, conv, ts, d)
+        q = hs.linear(x, w[p + "attn_q"])
+        k = hs.linear(x, w[p + "attn_k"])
+        ctx, kv = da.grouped_masked_decode_attention(
+            q, k, hs.linear(x, w[p + "attn_v"]), {"k": kc, "v": vc}, ts,
+            n_head=d.n_head, n_kv_head=d.n_kv_head, scale=0.088)
+        return mix, ctx, ssm, conv, kv["k"], kv["v"]
+
+    ssm = (s, d.ssm_heads, d.ssm_head_dim, d.d_state)
+    compiled = jax.jit(f, donate_argnums=(1, 2, 3, 4)).lower(
+        w, sd(ssm), sd((s, d.d_conv - 1, d.d_xbc)),
+        sd((s, t, d.d_kv), jnp.bfloat16), sd((s, t, d.d_kv), jnp.bfloat16),
+        sd((s, d.d_model)), sd((s,), jnp.int32)).compile()
+    assert hs.SSM_UPDATE_SCOPE in compiled.as_text()
+    mem = compiled.memory_analysis()
+    ssm_leaf = 4 * s * d.ssm_heads * d.ssm_head_dim * d.d_state
+    kv_leaf = 2 * s * t * d.d_kv
+    assert mem.alias_size_in_bytes >= ssm_leaf + 2 * kv_leaf
+    assert mem.temp_size_in_bytes < ssm_leaf // 2
+
+
+def test_gpt1_chunk_compiles_for_v5e_with_no_cast_of_a_weight(one_chip,
+                                                             monkeypatch):
+    """The slot pool's ``chunk`` of ``gpt1_117m`` (its one rung pair, the
+    published widths, two layers) as ``tools/decode_chunk_text.py``
+    builds it for a TPU: the builder holds bf16 copies of the matrices
+    it multiplies, so the chip's compiler leaves no instruction that
+    casts a whole weight-shaped matrix to bf16 (13 before the copies:
+    six a layer and the head, run again on every call), and the ragged
+    kernel is still the attention."""
+    import importlib.util
+    import os
+    import sys
+
+    import jax
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "decode_chunk_text", os.path.join(root, "tools",
+                                          "decode_chunk_text.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    # the tool answers "tpu" for the backend it compiles for and puts
+    # the checkout on the path: both undone when the test ends
+    monkeypatch.setattr(jax, "default_backend", jax.default_backend)
+    monkeypatch.setattr(sys, "path", list(sys.path))
+    lowered = tool.lowered_chunk(root, "gpt1_117m")
+    text = lowered.compile().as_text()
+    assert "ragged_decode_attention" in text
+    assert tool.weight_casts(lowered, text) == 0
+
+
+@pytest.mark.parametrize("shape", [(32, 12, 512, 64), (128, 12, 128, 64)],
+                         ids=["pretrain_s512", "pretrain_s128"])
+def test_fused_attention_kernels_compile_for_v5e_at_bert_widths(one_chip,
+                                                                shape):
+    """The training attention pair (paddle_tpu/fused_attention.py, kept
+    here because one file loads the chip's compiler) in the model's own
+    layout, forward + backward: both kernels lower, the model's head
+    transposes cancel against the op's (the kernels read the
+    projections' ``[N, S, 768]`` as it is: no ``[N, 12, S, 64]`` copy),
+    and nothing score-shaped exists."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import fused_attention as fa
+
+    n, h, s, d = shape
+    assert fa.attention_lowering("tpu", s, s, h, d, jnp.bfloat16) == "kernel"
+
+    def heads(x):
+        return x.reshape(n, s, h, d).transpose(0, 2, 1, 3)
+
+    def merge(x):
+        return x.transpose(0, 2, 1, 3).reshape(n, s, h * d)
+
+    def f(xq, xk, xv, mask, dctx):
+        q, k, v = heads(xq), heads(xk), heads(xv)
+        out, lse = fa.kernel_attention(q, k, v, mask, False, 0.125)
+        grads = fa.kernel_attention_grad(q, k, v, mask, out, lse,
+                                         heads(dctx), False, 0.125)
+        return (merge(out),) + tuple(merge(g) for g in grads)
+
+    x = jax.ShapeDtypeStruct((n, s, h * d), jnp.bfloat16, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((n, s), jnp.float32, sharding=one_chip)
+    text = jax.jit(f).lower(x, x, x, mask, x).compile().as_text()
+    assert "fused_attention_fwd" in text and "fused_attention_bwd" in text
+    assert "[%d,%d,%d,%d]" % (n, h, s, s) not in text
+    assert "[%d,%d,%d,%d]" % (n, h, s, d) not in text
+
+
+def test_grouped_matmul_compiles_for_v5e_at_lfm2_widths(one_chip):
+    """1024 sorted (row, choice) pairs against 64 experts' stacked
+    matrices, both products of the gated FFN: the kernel lowers, and no
+    expert matrix is copied (no temporary of a stacked matrix's size)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import grouped_matmul as gm
+
+    m, d, f, e = 1024, 2048, 1536, 64
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def ffn(rows, w13, w2, sizes):
+        p = gm.plan(sizes, m)
+        gu = gm.kernel_grouped_matmul(rows, w13, p)
+        act = jax.nn.silu(gu[:, :f]) * gu[:, f:]
+        return gm.kernel_grouped_matmul(act.astype(w2.dtype), w2, p)
+
+    assert gm.lowering("tpu", sd((m, d)), sd((e, d, 2 * f))) == "kernel"
+    assert gm.lowering("tpu", sd((m, f)), sd((e, f, d))) == "kernel"
+    compiled = jax.jit(ffn).lower(
+        sd((m, d)), sd((e, d, 2 * f)), sd((e, f, d)),
+        sd((e,), jnp.int32)).compile()
+    assert compiled.as_text().count(gm.KERNEL_NAME) >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        e * f * d * 2) // 4
+
+
+def test_lane_masked_attention_makes_no_copy_of_a_rung_on_v5e(one_chip):
+    """256 slots x 2048 positions x 8 K/V heads of 64, 4 query heads a
+    K/V head, bf16: the form that reads the leaves as they lie compiles
+    with temporaries far under a leaf's size; the per-head view of the
+    same leaves re-tiles them (a copy of each, which is why the step
+    does not take it)."""
+    import jax
+    import jax.numpy as jnp
+
+    s, t, g, rep, dh = 256, 2048, 8, 4, 64
+    leaf = s * t * g * dh * 2
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def temp(form):
+        def f(q, kn, vn, k, v, ts):
+            return form(q, kn, vn, {"k": k, "v": v}, ts, n_head=g * rep,
+                        n_kv_head=g, scale=0.125)
+        return jax.jit(f, donate_argnums=(3, 4)).lower(
+            sd((s, g * rep * dh)), sd((s, g * dh)), sd((s, g * dh)),
+            sd((s, t, g * dh), jnp.bfloat16), sd((s, t, g * dh), jnp.bfloat16),
+            sd((s,), jnp.int32)).compile().memory_analysis(
+            ).temp_size_in_bytes
+
+    assert temp(da.lane_masked_decode_attention) < leaf // 4
+    assert temp(da.grouped_masked_decode_attention) >= leaf

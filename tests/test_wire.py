@@ -15,6 +15,8 @@ import urllib.request
 import numpy as np
 import pytest
 
+from conftest import WAIT
+
 import paddle_tpu as fluid
 from paddle_tpu import framework, monitor
 from paddle_tpu.monitor import flight as _flight
@@ -235,7 +237,8 @@ def test_wire_deadline_and_overload_are_end_states():
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(WAIT)
+        assert not any(t.is_alive() for t in threads)
         assert "overload" in outcomes, outcomes
     finally:
         cli.close()
@@ -247,16 +250,20 @@ def test_wire_admin_surfaces():
     try:
         host, port = sp.address
         base = "http://%s:%d" % (host, port)
-        h = json.load(urllib.request.urlopen(base + "/healthz"))
+
+        def get(path):
+            return urllib.request.urlopen(base + path, timeout=WAIT)
+
+        h = json.load(get("/healthz"))
         assert h["ok"] and h["live_replicas"] == 1
-        text = urllib.request.urlopen(base + "/metrics").read().decode()
+        text = get("/metrics").read().decode()
         assert "wire_requests_total" in text
-        st = json.load(urllib.request.urlopen(base + "/statusz"))
+        st = json.load(get("/statusz"))
         assert st["server"] == "admin"
-        tz = json.load(urllib.request.urlopen(base + "/tracez"))
+        tz = json.load(get("/tracez"))
         assert "requests" in tz
         with pytest.raises(urllib.error.HTTPError):
-            urllib.request.urlopen(base + "/nope")
+            get("/nope")
     finally:
         sp.stop()
 
@@ -332,7 +339,8 @@ def test_fleet_requeues_off_dead_backend_without_losing_requests():
                 errs.append(repr(e))
                 return
 
-    threads = [threading.Thread(target=storm, args=(t,)) for t in range(4)]
+    threads = [threading.Thread(target=storm, args=(t,),
+                                daemon=True) for t in range(4)]
     for t in threads:
         t.start()
     time.sleep(0.25)
@@ -341,7 +349,8 @@ def test_fleet_requeues_off_dead_backend_without_losing_requests():
     time.sleep(0.5)
     stop.set()
     for t in threads:
-        t.join()
+        t.join(WAIT)
+    assert not any(t.is_alive() for t in threads)
     try:
         assert errs == []  # no accepted request was lost
         assert done[0] > 0
@@ -407,7 +416,7 @@ def mlp_model_dir(tmp_path_factory):
 def _backend_statusz(be):
     host, port = be.transport.address
     return json.load(urllib.request.urlopen(
-        "http://%s:%d/statusz" % (host, port)))
+        "http://%s:%d/statusz" % (host, port), timeout=WAIT))
 
 
 def test_process_fleet_end_to_end(mlp_model_dir):
@@ -482,7 +491,7 @@ def test_process_fleet_end_to_end(mlp_model_dir):
                     errs.append(repr(e))
                     return
 
-        threads = [threading.Thread(target=storm, args=(t,))
+        threads = [threading.Thread(target=storm, args=(t,), daemon=True)
                    for t in range(4)]
         for t in threads:
             t.start()
@@ -494,7 +503,8 @@ def test_process_fleet_end_to_end(mlp_model_dir):
         time.sleep(1.5)
         stop_flag.set()
         for t in threads:
-            t.join()
+            t.join(WAIT)
+        assert not any(t.is_alive() for t in threads)
         assert errs == [], "accepted requests were lost: %s" % errs[:3]
         assert completed[0] > 20
         requeued = monitor.counter_value(
@@ -513,7 +523,7 @@ def test_process_fleet_end_to_end(mlp_model_dir):
         # the child's own /tracez carries hierarchical trees too
         host, port = survivor.transport.address
         tz = json.load(urllib.request.urlopen(
-            "http://%s:%d/tracez" % (host, port)))
+            "http://%s:%d/tracez" % (host, port), timeout=WAIT))
         assert tz["retained"] > 0
         assert any(r.get("tree") for r in tz["requests"])
     finally:
