@@ -98,9 +98,12 @@ Three implementations of the read-what-is-live contract, chosen by
   products in the storage dtype (int8: dequantized to fp32 at the read),
   fp32 accumulation and softmax.  The CPU path, the path of every step
   no kernel covers (int8 leaves, ring leaves, ``K > 1`` over leaves of
-  one query head a K/V head, grouped heads narrower than a lane tile —
-  those at one row through :func:`lane_masked_decode_attention` on a
-  TPU), and the parity reference of tests/test_decode_attention.py.
+  one query head a K/V head, and — at one row through
+  :func:`lane_masked_decode_attention` on a TPU — grouped heads narrower
+  than a lane tile and ONE query head a K/V head over bf16 leaves,
+  ``olmo_hybrid``'s full layers: the whole rung is read whatever of it
+  is live, and ``decode_attention_ungrouped_lowered_total{path}`` counts
+  the form), and the parity reference of tests/test_decode_attention.py.
 
 ``jax.experimental.pallas`` is imported inside the kernel builder only:
 ``import paddle_tpu`` and the training cells never pay for it.
@@ -123,7 +126,7 @@ __all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
            "grouped_block_decode_attention", "block_sparse_decode_attention",
            "block_kernel_supported", "BLOCK_SPARSE_LOWERED",
            "kernel_supported", "make_decode_attention", "ring_positions",
-           "RING_LOWERED", "GROUPED_LOWERED"]
+           "RING_LOWERED", "GROUPED_LOWERED", "UNGROUPED_LOWERED"]
 
 BLOCK_SPARSE_LOWERED = _registry.REGISTRY.counter(
     "block_sparse_lowered_total",
@@ -157,6 +160,16 @@ GROUPED_LOWERED = _registry.REGISTRY.counter(
     "kernel (Pallas TPU: the live (slot, block) pairs as whole-width "
     "slabs, the reads in flight by hand) | xla (a masked softmax over "
     "the whole rung)", ("path",))
+
+UNGROUPED_LOWERED = _registry.REGISTRY.counter(
+    "decode_attention_ungrouped_lowered_total",
+    "appends-and-reads of ONE query head per K/V head over SEQUENCE "
+    "leaves lowered (traced into a program or run eagerly), by the "
+    "lowering chosen: kernel (Pallas TPU ragged_decode_attention: fp32 "
+    "leaves, one fresh row a slot, what is live) | xla (a masked softmax "
+    "over the whole rung: int8 leaves, K rows, the CPU; bf16 leaves, on "
+    "a TPU at one row through the form that reads them as they lie)",
+    ("path",))
 
 #: the sequence axis of every K/V leaf (and scale sibling)
 KV_SEQ_AXIS = 1
@@ -1675,19 +1688,25 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
     fp32 leaves (:func:`step_read_sizes`:
     :func:`grouped_decode_attention`, one fresh row or ``K``) — and an
     XLA form otherwise: on a TPU, for one row of grouped heads narrower
-    than a lane tile over unquantized leaves, the one that reads the
-    leaves as they lie (:func:`lane_masked_decode_attention`), else
+    than a lane tile over unquantized leaves or of one query head per
+    K/V head over bf16 leaves, the one that reads the leaves as they lie
+    (:func:`lane_masked_decode_attention`), else
     :func:`grouped_masked_decode_attention` (int8 leaves, ``K`` rows
     where no kernel takes them, every CPU run).  A grouped-head step
     over sequence leaves counts itself in
     ``decode_attention_grouped_lowered_total{path}``, a ``K``-row one
-    also in ``decode_attention_rows_lowered_total{leaf}``."""
+    also in ``decode_attention_rows_lowered_total{leaf}``, a step of one
+    query head per K/V head over sequence leaves in
+    ``decode_attention_ungrouped_lowered_total{path}``."""
     import jax
     import jax.numpy as jnp
 
     _, seq_len, width = kv["k"].shape
     xla = functools.partial(grouped_masked_decode_attention, ts=ts,
                             n_head=n_head, n_kv_head=n_kv_head, scale=scale)
+    # one fresh row over unquantized leaves, read as they lie
+    lane = functools.partial(lane_masked_decode_attention, ts=ts,
+                             n_head=n_head, n_kv_head=n_kv_head, scale=scale)
     if window is not None:
         return functools.partial(xla, window=int(window))
     tpu = jax.default_backend() == "tpu" and "k_scale" not in kv
@@ -1714,9 +1733,7 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
         if sizes is None and tpu and (width // n_kv_head) % _HEAD_LANES:
             # narrower than a lane tile: a view of the leaf by heads
             # would be a copy of the rung (lane_masked_decode_attention)
-            one_row = functools.partial(
-                lane_masked_decode_attention, ts=ts, n_head=n_head,
-                n_kv_head=n_kv_head, scale=scale)
+            one_row = lane
 
         def attend(q, k_new, v_new, kv):
             GROUPED_LOWERED.labels(
@@ -1726,15 +1743,25 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
             return (one_row if q.ndim == 2 else xla)(q, k_new, v_new, kv)
 
         return attend
-    if not (tpu and kv["k"].dtype == jnp.float32 and n_kv_head == n_head
-            and kernel_supported(seq_len, width, n_head)):
-        return xla
-    block = kv_read_block(seq_len)
-    work = decode_work_items(ts, seq_len, block)
+    ragged = (tpu and kv["k"].dtype == jnp.float32 and n_kv_head == n_head
+              and kernel_supported(seq_len, width, n_head))
+    one_row = xla
+    if ragged:
+        block = kv_read_block(seq_len)
+        work = decode_work_items(ts, seq_len, block)
+    elif tpu and kv["k"].dtype == jnp.bfloat16:
+        # with ONE query row a K/V head the compiler takes the score
+        # product of the per-head view off the matrix unit and first
+        # copies each leaf to float32 in another layout (2 x a leaf of
+        # temporaries a leaf and step): read the leaves as they lie
+        one_row = lane
 
     def attend(q, k_new, v_new, kv):
-        if q.ndim != 2:     # K fresh rows per slot: no kernel yet
-            return xla(q, k_new, v_new, kv)
+        # K fresh rows per slot: no kernel yet
+        kernel = ragged and q.ndim == 2
+        UNGROUPED_LOWERED.labels(path="kernel" if kernel else "xla").inc()
+        if not kernel:
+            return (one_row if q.ndim == 2 else xla)(q, k_new, v_new, kv)
         ctx, k, v = ragged_decode_attention(
             q, k_new, v_new, kv["k"], kv["v"], ts, work,
             n_head=n_head, scale=scale, block=block)

@@ -28,9 +28,10 @@ third: ``step_fn(cache, tokens, ts)`` with every row at its OWN position
 K-wide twin ``make_transformer_lm_pooled_verify_fn``,
 ``make_hybrid_ssm_lm_pooled_step_fn``,
 ``make_sparse_linear_lm_pooled_step_fn`` (which also builds a chunked
-prefill) and ``make_routed_conv_lm_pooled_step_fn`` (layers that hold
-different leaves, routed experts, counts made on the device) — are made
-of the same parts:
+prefill), ``make_routed_conv_lm_pooled_step_fn`` (layers that hold
+different leaves, routed experts, counts made on the device) and
+``make_delta_hybrid_lm_pooled_step_fn`` (a recurrent state that is read
+before it is written) — are made of the same parts:
 
 * ONE cache format, whatever the storage dtype (fp32, bf16, int8):
   ``paddle_tpu.decode_attention`` says what a K/V leaf is, appends the
@@ -75,6 +76,7 @@ __all__ = [
     "make_routed_conv_lm_pooled_step_fn",
     "make_windowed_routed_lm_pooled_step_fn",
     "make_mtp_routed_lm_pooled_step_fn",
+    "make_delta_hybrid_lm_pooled_step_fn",
     "cache_leaf_seq_axes", "cache_leaf_seq_strides", "cache_leaf_seq_windows",
     "cache_leaf_slotless", "NO_SLOT_AXIS",
     "recurrent_leaf_names", "ring_leaf_names",
@@ -1565,6 +1567,104 @@ def make_mtp_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
     make_cache.verify_fn = verify_fn
     make_cache.mtp_fn = mtp_fn if d.n_mtp else None
     return step_fn, make_cache, prefill_fn
+
+
+def make_delta_hybrid_lm_pooled_step_fn(state, cfg, name: str = "lm",
+                                        kv_dtype: str = "bf16"):
+    """The slot-pooled step of a decoder whose layers are a GATED
+    DELTA-RULE linear attention or full multi-head attention, each
+    branch closed by its norm, every layer followed by a SwiGLU
+    (``model_type: olmo_hybrid``; the parts and the equations are
+    ``paddle_tpu.delta_hybrid_lm``).
+
+    Same contract as the builders above: ``step_fn(cache, tokens [N]
+    int32, ts [N] int32) -> (logits [N, V] fp32, cache)`` with ``ts[i] <
+    0`` an idle row, and ``make_cache(n_rows, seq_len)``.  ``state``:
+    weights under ``delta_hybrid_lm.param_shapes(cfg)``, multiplied in
+    the dtype they are given (bf16 as stored: no per-step conversion;
+    norms, gates' biases and the conv kernel float32); ``cfg``: the
+    published config keys (``delta_hybrid_lm.dims``).
+
+    The cache is a list, a dict a layer, and ``make_cache.leaf_seq_axes``
+    declares every leaf, because the layers hold DIFFERENT leaves:
+
+    * a full layer ``k``, ``v`` ``[N, T, n_kv_head * head_dim]`` in
+      ``kv_dtype`` (``k`` after its norm), ``decode_attention``'s format
+      through ``make_decode_attention`` — ONE query head per K/V head in
+      ``olmo_hybrid``, so over bf16 leaves an XLA form that reads the
+      whole rung (on a TPU the one that reads the leaves as they lie;
+      ``decode_attention_ungrouped_lowered_total{path}`` says which form
+      a program took); covered by write-before-read;
+    * a linear layer ``state`` ``[N, H / g, dk, g * dv]`` float32 (``g``
+      heads side by side in the lanes: ``delta_hybrid_lm.heads_per_
+      tile``) and ``conv`` ``[N, K - 1, 2 H dk + H dv]`` float32:
+      RECURRENT (``-1``), read as zero for a row at ``ts == 0``
+      (``hybrid_ssm.starts_fresh``), kept for an idle row.
+
+    Prompts walk the one-token step (no chunked prefill: the delta
+    rule's chunkwise form is not built), so, as over
+    :func:`make_hybrid_ssm_lm_pooled_step_fn`, ``KVSlotPool`` refuses
+    ``prefix=True`` and ``speculative=`` over this builder.
+    """
+    import jax.numpy as jnp
+
+    from paddle_tpu import delta_hybrid_lm as dh
+    from paddle_tpu.decode_attention import (kv_leaves, make_decode_attention,
+                                             step_positions_read)
+
+    d = dh.dims(cfg)
+    kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
+    W = {k: jnp.asarray(v) for k, v in state.items()}
+    scale = 1.0 / float(np.sqrt(d.head_dim))
+    full_at = [i for i, kind in enumerate(d.kinds) if kind == dh.FULL]
+
+    def make_cache(n_rows: int, seq_len: int):
+        return [
+            kv_leaves(n_rows, seq_len, d.n_kv_head, d.head_dim, kv)
+            if kind == dh.FULL else
+            {"state": jnp.zeros((n_rows,) + d.state_shape, jnp.float32),
+             "conv": jnp.zeros((n_rows, d.conv_len - 1, d.d_qkv),
+                               jnp.float32)}
+            for kind in d.kinds]
+
+    make_cache.leaf_seq_axes = [
+        {"k": 1, "v": 1} if kind == dh.FULL else {"state": -1, "conv": -1}
+        for kind in d.kinds]
+    make_cache.kv_positions_read = functools.partial(
+        step_positions_read, width=d.d_kv, dtype=kv, n_head=d.n_head,
+        n_kv_head=d.n_kv_head)
+
+    def step_fn(cache, tokens, ts):
+        attend = None
+        if full_at:
+            ts = jnp.minimum(ts, cache[full_at[0]]["k"].shape[1] - 1)
+            attend = make_decode_attention(
+                ts, cache[full_at[0]], n_head=d.n_head,
+                n_kv_head=d.n_kv_head, scale=scale)
+        pos = jnp.maximum(ts, 0)      # idle rows stay < 0 in ``ts``
+        h = W[name + "_emb"][tokens].astype(jnp.float32)
+        new_cache = []
+        for i, kind in enumerate(d.kinds):
+            p = "%s_l%d_" % (name, i)
+            c = cache[i]
+            if kind == dh.LINEAR:
+                o, s, conv = dh.delta_layer_step(h, W, p, c["state"],
+                                                 c["conv"], ts, d)
+                new_cache.append({"state": s, "conv": conv})
+            else:
+                ctx, kvs = attend(*dh.full_attention_rows(h, W, p, pos, d), c)
+                o = dh.linear(ctx, W[p + "attn_o"])
+                new_cache.append(kvs)
+            h = h + dh.rms_norm(o, W[p + "mixer_norm"], d.eps)
+            h = h + dh.rms_norm(
+                dh.swiglu(h, W[p + "mlp_gate"], W[p + "mlp_up"],
+                          W[p + "mlp_down"], 1.0, 1.0),
+                W[p + "mlp_norm"], d.eps)
+        logits = dh.linear(dh.rms_norm(h, W[name + "_final_norm"], d.eps),
+                           W[name + "_head"])
+        return logits, new_cache
+
+    return step_fn, make_cache
 
 
 def make_transformer_lm_pooled_verify_fn(

@@ -1,0 +1,318 @@
+"""The parts of a decoder whose layers are a GATED DELTA-RULE linear
+attention or full multi-head attention, each followed by a SwiGLU
+(``model_type: olmo_hybrid``), as small functions of ONE token per row.
+
+    h = x + RMS(mixer(x))            # no norm BEFORE a branch: the
+    h = h + RMS(SwiGLU(h))           # branch is closed by one
+
+A *linear* layer, per head (``dk`` key lanes, ``dv`` value lanes, state
+``S`` ``[dk, dv]``) and token::
+
+    [q; k; v] = silu(depthwise causal conv, kernel K, of [W_q; W_k; W_v] x)
+    q = q / ||q|| * dk^-1/2,   k = k / ||k||
+    beta  = sigmoid(W_b x)      (x 2 where ``linear_allow_neg_eigval``)
+    alpha = exp(-exp(A_log) * softplus(W_a x + dt_bias))
+    S <- alpha S
+    u  = S^T k                 # what the decayed state returns for k: READ
+    S <- S + k (beta (v - u))^T                                   # WRITE
+    o  = S^T q
+    y  = RMS_dv(o) * silu(W_g x),   out = W_o [y of every head]
+
+(Yang, Kautz, Hatamizadeh, "Gated Delta Networks", arXiv:2412.06464.)
+Unlike a state that only decays and adds (``hybrid_ssm.mamba2_step``,
+``sparse_linear_lm.lightning_step``) the write needs ``S^T k`` of the
+WHOLE head state first.  A *full* layer is causal softmax attention of
+``n_head`` heads over ``n_kv_head`` K/V heads, q and k RMS-normed over
+the whole projection before the heads are split, rotary only where the
+configuration gives a ``rope_theta`` (``olmo_hybrid`` gives ``null``:
+positions enter through the linear layers' decay and convolution).
+
+``decoding.make_delta_hybrid_lm_pooled_step_fn`` strings the parts into
+the slot-pooled step; nothing here knows a pool or a server.  Weights
+are multiplied in the dtype they are given (``hybrid_ssm.linear``);
+norms, gates, the convolution and the recurrence run in float32.
+
+Per linear layer a row carries two RECURRENT leaves, read as zero for a
+row at ``ts == 0`` (``hybrid_ssm.starts_fresh``) and kept for an idle
+row (``ts < 0``):
+
+* ``state`` ``[N, H / g, dk, g * dv]`` float32: ``g`` heads side by side
+  in the lane axis (:func:`heads_per_tile`), so that a row of the leaf
+  is whole 128-lane tiles — ``dv`` = 192 alone would be padded to 256
+  lanes in HBM, a third more of the largest thing a step moves after the
+  weights; two heads are 384 = 3 tiles and nothing is padded;
+* ``conv`` ``[N, K - 1, 2 H dk + H dv]`` float32: the last ``K - 1``
+  projected rows ``[q; k; v]`` before the convolution.
+"""
+from __future__ import annotations
+
+import functools
+from types import SimpleNamespace
+
+import numpy as np
+
+from paddle_tpu.hybrid_ssm import (linear, rms_norm, rotary, starts_fresh,
+                                   swiglu)
+
+__all__ = ["LINEAR", "FULL", "DELTA_UPDATE_SCOPE", "SHORT_CONV_SCOPE",
+           "FLOAT32_PARAMS", "dims", "param_shapes", "random_state",
+           "heads_per_tile", "qkv_conv_step", "l2_norm", "decay_and_step_gates",
+           "gated_delta_step", "gated_output_norm", "delta_layer_step",
+           "full_attention_rows", "linear", "rms_norm", "rotary",
+           "starts_fresh", "swiglu"]
+
+#: ``layer_types`` entries
+LINEAR, FULL = "linear_attention", "full_attention"
+
+#: ``jax.named_scope`` names, for the device trace
+DELTA_UPDATE_SCOPE = "delta_state_update"
+SHORT_CONV_SCOPE = "delta_short_conv"
+
+#: endings of the parameters kept in float32 whatever the matrices are
+FLOAT32_PARAMS = ("norm", "lin_conv_w", "lin_A_log", "lin_dt_bias")
+
+_LANES = 128
+_L2_EPS = 1e-6
+
+
+def heads_per_tile(n_head: int, dv: int) -> int:
+    """How many heads the state leaf lays side by side in its lane axis:
+    the fewest whose ``dv`` lanes together are whole 128-lane tiles, if
+    the head count divides into such groups, else 1 (the leaf is then
+    padded by the tiled layout: tiny test sizes)."""
+    g = _LANES // int(np.gcd(dv, _LANES))
+    return g if n_head % g == 0 else 1
+
+
+def dims(cfg) -> SimpleNamespace:
+    """The decoder's sizes from an ``olmo_hybrid`` config dict (the
+    published key names).  ``head_dim`` is absent there: ``hidden_size /
+    num_attention_heads``."""
+    g = cfg.get
+    rope = (g("rope_parameters") or {}).get("rope_theta")
+    o = SimpleNamespace(
+        vocab=int(cfg["vocab_size"]), d_model=int(cfg["hidden_size"]),
+        n_layer=int(cfg["num_hidden_layers"]),
+        kinds=tuple(cfg["layer_types"]),
+        n_head=int(cfg["num_attention_heads"]),
+        n_kv_head=int(cfg["num_key_value_heads"]),
+        d_mlp=int(cfg["intermediate_size"]),
+        lin_heads=int(cfg["linear_num_value_heads"]),
+        dk=int(cfg["linear_key_head_dim"]),
+        dv=int(cfg["linear_value_head_dim"]),
+        conv_len=int(cfg["linear_conv_kernel_dim"]),
+        neg_eigval=bool(g("linear_allow_neg_eigval", False)),
+        eps=float(g("rms_norm_eps", 1e-6)),
+        rope_theta=None if rope is None else float(rope))
+    o.head_dim = int(g("head_dim") or o.d_model // o.n_head)
+    if len(o.kinds) != o.n_layer or set(o.kinds) - {LINEAR, FULL}:
+        raise ValueError("layer_types must name %d layers as %r or %r"
+                         % (o.n_layer, LINEAR, FULL))
+    if int(cfg["linear_num_key_heads"]) != o.lin_heads:
+        raise ValueError("linear_num_key_heads != linear_num_value_heads: "
+                         "value heads grouped over key heads are not built")
+    if o.n_head % o.n_kv_head:
+        raise ValueError("heads must divide into their K/V heads")
+    o.d_q, o.d_kv = o.n_head * o.head_dim, o.n_kv_head * o.head_dim
+    o.d_key, o.d_value = o.lin_heads * o.dk, o.lin_heads * o.dv
+    o.d_qkv = 2 * o.d_key + o.d_value
+    o.tile_heads = heads_per_tile(o.lin_heads, o.dv)
+    o.state_shape = (o.lin_heads // o.tile_heads, o.dk, o.tile_heads * o.dv)
+    return o
+
+
+def param_shapes(cfg, name: str = "lm") -> dict:
+    """Names and shapes of every weight the step reads: the one place
+    the schema lives.  Matrices are ``[in, out]``; the depthwise conv
+    kernel is ``[K, channels]`` over ``[q; k; v]``, oldest tap first."""
+    d = dims(cfg)
+    out = {name + "_emb": (d.vocab, d.d_model),
+           name + "_final_norm": (d.d_model,),
+           name + "_head": (d.d_model, d.vocab)}
+    for i, kind in enumerate(d.kinds):
+        p = "%s_l%d_" % (name, i)
+        if kind == LINEAR:
+            out.update({
+                p + "lin_q": (d.d_model, d.d_key),
+                p + "lin_k": (d.d_model, d.d_key),
+                p + "lin_v": (d.d_model, d.d_value),
+                p + "lin_conv_w": (d.conv_len, d.d_qkv),
+                p + "lin_a": (d.d_model, d.lin_heads),
+                p + "lin_b": (d.d_model, d.lin_heads),
+                p + "lin_A_log": (d.lin_heads,),
+                p + "lin_dt_bias": (d.lin_heads,),
+                p + "lin_g": (d.d_model, d.d_value),
+                p + "lin_norm": (d.dv,),
+                p + "lin_o": (d.d_value, d.d_model)})
+        else:
+            out.update({
+                p + "attn_q": (d.d_model, d.d_q),
+                p + "attn_k": (d.d_model, d.d_kv),
+                p + "attn_v": (d.d_model, d.d_kv),
+                p + "attn_q_norm": (d.d_q,), p + "attn_k_norm": (d.d_kv,),
+                p + "attn_o": (d.d_q, d.d_model)})
+        out.update({
+            p + "mixer_norm": (d.d_model,), p + "mlp_norm": (d.d_model,),
+            p + "mlp_gate": (d.d_model, d.d_mlp),
+            p + "mlp_up": (d.d_model, d.d_mlp),
+            p + "mlp_down": (d.d_mlp, d.d_model)})
+    return out
+
+
+def random_state(rng, cfg, name: str = "lm", std: float = 0.02,
+                 dtype="float32") -> dict:
+    """Seeded random weights under :func:`param_shapes` (tests): normal
+    matrices, a unit-variance embedding (every branch is closed by a
+    norm of weight 1, so the stream the first layer reads has to be of
+    that size too), unit norms, the reference layer's ``A_log`` = log
+    U(1e-3, 16) and ``dt_bias`` = inverse softplus of a step log-uniform in
+    [1e-3, 1e-1].  Vectors and the conv kernel stay fp32; matrices take
+    ``dtype``."""
+    import jax.numpy as jnp
+
+    w = {}
+    for k, shp in param_shapes(cfg, name).items():
+        if k.endswith("norm"):
+            w[k] = np.ones(shp, "float32")
+        elif k.endswith("lin_A_log"):
+            w[k] = np.log(rng.uniform(1e-3, 16.0, shp)).astype("float32")
+        elif k.endswith("lin_dt_bias"):
+            dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), shp))
+            w[k] = (dt + np.log(-np.expm1(-dt))).astype("float32")
+        elif k.endswith("lin_conv_w"):
+            w[k] = rng.uniform(-1, 1, shp).astype("float32") / np.sqrt(shp[0])
+        else:
+            s = 1.0 if k.endswith("_emb") else std
+            w[k] = jnp.asarray((rng.randn(*shp) * s).astype("float32"), dtype)
+    return w
+
+
+def qkv_conv_step(x, w_conv, conv, ts):
+    """The causal depthwise convolution over the projected row ``x``
+    ``[N, C]`` (``[q; k; v]`` before it) and the row's window ``conv``
+    ``[N, K - 1, C]``; SiLU after it, no bias.  Returns ``(activated
+    [N, C], conv)``."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    live, fresh = ts >= 0, starts_fresh(ts)
+    with jax.named_scope(SHORT_CONV_SCOPE):
+        prev = jnp.where(fresh[:, None, None], 0.0, conv.astype(f32))
+        window = jnp.concatenate([prev, x[:, None, :]], axis=1)
+        y = jax.nn.silu(jnp.sum(window * w_conv.astype(f32)[None], axis=1))
+        conv_new = jnp.where(live[:, None, None], window[:, 1:],
+                             conv.astype(f32)).astype(conv.dtype)
+    return y, conv_new
+
+
+def l2_norm(x):
+    """``x / ||x||_2`` over the last axis (a head's lanes)."""
+    import jax
+    import jax.numpy as jnp
+
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                             + _L2_EPS)
+
+
+def decay_and_step_gates(x, w, p: str, d):
+    """``(alpha, beta)`` ``[N, H]`` float32: the state's per-token decay
+    in (0, 1) and the write's step in (0, 1), or (0, 2) where the
+    configuration allows negative eigenvalues."""
+    import jax
+    import jax.numpy as jnp
+
+    dt = jax.nn.softplus(linear(x, w[p + "lin_a"]) + w[p + "lin_dt_bias"])
+    alpha = jnp.exp(-jnp.exp(w[p + "lin_A_log"]) * dt)
+    beta = jax.nn.sigmoid(linear(x, w[p + "lin_b"]))
+    return alpha, beta * 2.0 if d.neg_eigval else beta
+
+
+def _over_lanes(x, g: int, dv: int):
+    """Per-head values ``x`` ``[N, H, K]`` laid against the state leaf:
+    ``[N, H / g, K, g * dv]`` holding head ``G * g + c // dv``'s value in
+    lane ``c`` — broadcasts and selects on a constant lane map, so the
+    state-sized product it feeds is made inside that product's fusion
+    (a ``[.., g, dv] -> [.., g * dv]`` reshape of it would be a copy)."""
+    import jax.numpy as jnp
+
+    n, h, k = x.shape
+    xg = x.reshape(n, h // g, g, k)
+    out = xg[:, :, 0, :, None]
+    head_of_lane = np.arange(g * dv) // dv
+    for j in range(1, g):
+        out = jnp.where(head_of_lane == j, xg[:, :, j, :, None], out)
+    return out
+
+
+def gated_delta_step(q, k, v, alpha, beta, s, ts):
+    """One token of the gated delta rule for every row and head.
+
+    ``q``, ``k`` ``[N, H, dk]`` (normalised), ``v`` ``[N, H, dv]``,
+    ``alpha``, ``beta`` ``[N, H]``, all float32; ``s`` the state leaf
+    ``[N, H / g, dk, g * dv]`` (``g`` read from its shape); ``ts`` ``[N]``
+    (``< 0`` idle: state kept, ``0`` fresh: state read as zero).  Returns
+    ``(o [N, H, dv], s)``."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    n, h, dv = v.shape
+    g = s.shape[-1] // dv
+    live, fresh = ts >= 0, starts_fresh(ts)
+    with jax.named_scope(DELTA_UPDATE_SCOPE):
+        wide = functools.partial(_over_lanes, g=g, dv=dv)
+        kk, qq = wide(k), wide(q)
+        s_prev = jnp.where(fresh[:, None, None, None], 0.0, s.astype(f32))
+        s_dec = wide(alpha[..., None]) * s_prev
+        u = jnp.sum(s_dec * kk, axis=2)                 # [N, H / g, g * dv]
+        delta = wide(beta[..., None])[:, :, 0] * (v.reshape(u.shape) - u)
+        s_new = s_dec + kk * delta[:, :, None, :]
+        o = jnp.sum(s_new * qq, axis=2).reshape(n, h, dv)
+        s_out = jnp.where(live[:, None, None, None], s_new,
+                          s.astype(f32)).astype(s.dtype)
+    return o, s_out
+
+
+def gated_output_norm(o, gate, w_norm, eps: float):
+    """``RMSNorm_dv(o) * silu(gate)`` per head: ``o``, ``gate`` ``[N, H,
+    dv]``, ``w_norm`` ``[dv]`` (one weight for every head)."""
+    import jax
+
+    return rms_norm(o, w_norm, eps) * jax.nn.silu(gate)
+
+
+def delta_layer_step(x, w, p: str, state, conv, ts, d):
+    """One token of a linear layer for every row: ``x`` ``[N, d_model]``
+    (the residual as it stands), ``state`` / ``conv`` the row's leaves.
+    Returns ``(out [N, d_model], state, conv)``."""
+    import jax.numpy as jnp
+
+    n = x.shape[0]
+    qkv = jnp.concatenate([linear(x, w[p + "lin_q"]), linear(x, w[p + "lin_k"]),
+                           linear(x, w[p + "lin_v"])], axis=-1)
+    qkv, conv = qkv_conv_step(qkv, w[p + "lin_conv_w"], conv, ts)
+    q = l2_norm(qkv[:, :d.d_key].reshape(n, d.lin_heads, d.dk)) \
+        * float(d.dk) ** -0.5
+    k = l2_norm(qkv[:, d.d_key:2 * d.d_key].reshape(n, d.lin_heads, d.dk))
+    v = qkv[:, 2 * d.d_key:].reshape(n, d.lin_heads, d.dv)
+    alpha, beta = decay_and_step_gates(x, w, p, d)
+    o, state = gated_delta_step(q, k, v, alpha, beta, state, ts)
+    gate = linear(x, w[p + "lin_g"]).reshape(n, d.lin_heads, d.dv)
+    y = gated_output_norm(o, gate, w[p + "lin_norm"], d.eps)
+    return linear(y.reshape(n, d.d_value), w[p + "lin_o"]), state, conv
+
+
+def full_attention_rows(x, w, p: str, pos, d):
+    """The fresh ``(q, k, v)`` rows of a full layer, ``[N, heads *
+    head_dim]`` float32: q and k RMS-normed over the whole projection,
+    rotated at ``pos`` only where the configuration has a ``rope_theta``."""
+    n = x.shape[0]
+    q = rms_norm(linear(x, w[p + "attn_q"]), w[p + "attn_q_norm"], d.eps)
+    k = rms_norm(linear(x, w[p + "attn_k"]), w[p + "attn_k_norm"], d.eps)
+    if d.rope_theta is not None:
+        q = rotary(q.reshape(n, d.n_head, d.head_dim), pos,
+                   d.rope_theta).reshape(n, d.d_q)
+        k = rotary(k.reshape(n, d.n_kv_head, d.head_dim), pos,
+                   d.rope_theta).reshape(n, d.d_kv)
+    return q, k, linear(x, w[p + "attn_v"])

@@ -618,10 +618,13 @@ def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
     and reads the leaves as they lie where they are 64 wide (lfm2); K
     fresh rows over whole-lane-tile heads take the same kernel (a
     self-drafting round's verify: k_exaone); one query head a K/V head
-    (gpt1), int8 leaves, a rung the kernel's block does not divide, K
-    rows of 64-wide heads, ring leaves and every CPU run keep the
-    grouped XLA form.  Every grouped-head step over sequence leaves
-    counts itself by the path it took, a K-row one also by its leaf; a
+    over bf16 leaves (olmo_hybrid's full layers) reads them as they lie
+    too at one row (the per-head view would be copied to float32); int8
+    leaves, a rung the kernel's block does not divide, K rows of 64-wide
+    heads or of one query head a K/V head, ring leaves and every CPU run
+    keep the grouped XLA form.  Every grouped-head step over sequence
+    leaves counts itself by the path it took, a K-row one also by its
+    leaf, a step of one query head a K/V head in a counter of its own; a
     ring step never counts a path."""
     import jax
     import jax.numpy as jnp
@@ -678,10 +681,15 @@ def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
              (8, 2), {})):                          # no whole blocks
         assert took(kv, heads, **kw) == (["grouped"],
                                          {"kernel": 0, "xla": 1})
-    # not grouped, or a ring: no count, and never the kernel
+    # not grouped, or a ring: no grouped count, and never that kernel
     nothing = {"kernel": 0, "xla": 0}
-    assert took(narrow, (2, 2)) == (["grouped"], nothing)
-    assert took(wide, (2, 2)) == (["grouped"], nothing)
+    ungrouped = da.UNGROUPED_LOWERED.labels(path="xla").value
+    assert took(narrow, (2, 2)) == (["lane"], nothing)
+    assert took(wide, (2, 2)) == (["lane"], nothing)
+    assert took(wide, (2, 2), rows=3) == (["grouped"], nothing)
+    assert took(da.kv_leaves(2, 128, 2, 128, jnp.int8), (2, 2)) == (
+        ["grouped"], nothing)
+    assert da.UNGROUPED_LOWERED.labels(path="xla").value == ungrouped + 4
     ring = da.kv_leaves(2, 128, 2, 128, jnp.bfloat16, window=64)
     assert took(ring, (8, 2), window=64) == (["grouped"], nothing)
     assert took(ring, (8, 2), rows=3, window=64)[1] == nothing

@@ -1,0 +1,440 @@
+"""The gated delta-rule hybrid decoder (``model_type: olmo_hybrid``) on
+the pooled decode path: ``decoding.make_delta_hybrid_lm_pooled_step_fn``
+-> ``KVSlotPool`` -> ``DecodeServer``, at tiny sizes on the CPU (seeded:
+``dk != dv``, a head count that is no power of two), against the
+benchmark's plain reference (``benchmark/configs/
+olmo_hybrid_7b_reference.py``: float32, full forward, no cache, the rule
+a scan over time).
+
+What is new under the pool: a recurrent state that is READ (``S^T k``)
+before it is written, per-token decay and step gates, one short
+convolution over ``[q; k; v]``, a state leaf that lays several heads in
+one row of lanes, and full attention of one query head a K/V head.
+"""
+import importlib.util
+import os
+
+import numpy as np
+import pytest
+
+from conftest import WAIT
+from test_routed_conv_lm import _staggered
+
+from paddle_tpu import decode_attention as da
+from paddle_tpu import decoding
+from paddle_tpu import delta_hybrid_lm as dh
+from paddle_tpu.serving.decode import DecodeServer
+from paddle_tpu.serving.kv_pool import KVSlotPool
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = _load(os.path.join(ROOT, "benchmark", "configs",
+                         "olmo_hybrid_7b_reference.py"), "olmo_reference")
+
+V = 211
+#: (linear heads, dk, dv): two heads a lane tile (64 + 64 lanes, as the
+#: published 192 + 192 are three), and one head a (padded) row
+LAYOUTS = {"two_heads_a_row": (6, 24, 64), "one_head_a_row": (3, 12, 20)}
+
+
+def tiny_cfg(layout="two_heads_a_row", **over):
+    heads, dk, dv = LAYOUTS[layout]
+    cfg = dict(
+        model_type="olmo_hybrid", vocab_size=V, hidden_size=64,
+        intermediate_size=96, num_hidden_layers=4, num_attention_heads=4,
+        num_key_value_heads=4, rms_norm_eps=1e-6,
+        layer_types=[dh.LINEAR] * 3 + [dh.FULL],
+        linear_num_key_heads=heads, linear_num_value_heads=heads,
+        linear_key_head_dim=dk, linear_value_head_dim=dv,
+        linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+        rope_parameters={"rope_theta": None})
+    cfg.update(over)
+    return cfg
+
+
+def weights(cfg, seed=0, dtype="float32"):
+    return dh.random_state(np.random.RandomState(seed), cfg, std=0.1,
+                           dtype=dtype)
+
+
+def _reference_leaves(w, toks, cfg):
+    """What the pool's recurrent leaves must hold after ``toks`` [S]: per
+    linear layer the rule's state ``[H, dk, dv]`` and the last three
+    projected rows, by the reference's own equations (a second scan that
+    keeps the state the reference's ``gated_delta_net`` throws away)."""
+    import jax
+    import jax.numpy as jnp
+
+    out, h = [], ref.embed(w, jnp.asarray(toks)[None], cfg)
+    heads, dk, dv = (cfg["linear_num_value_heads"],
+                     cfg["linear_key_head_dim"], cfg["linear_value_head_dim"])
+    with jax.default_matmul_precision("highest"):
+        for i, kind in enumerate(cfg["layer_types"]):
+            p = "lm_l%d_" % i
+            if kind == dh.LINEAR:
+                x = h[0]
+                qkv = jnp.concatenate([x @ w[p + "lin_q"], x @ w[p + "lin_k"],
+                                       x @ w[p + "lin_v"]], -1)
+                pad = jnp.pad(qkv, ((3, 0), (0, 0)))
+                act = jax.nn.silu(sum(pad[j:j + len(toks)]
+                                      * w[p + "lin_conv_w"][j]
+                                      for j in range(4)))
+                k = ref._l2(act[:, heads * dk:2 * heads * dk].reshape(
+                    -1, heads, dk))
+                v = act[:, 2 * heads * dk:].reshape(-1, heads, dv)
+                beta = 2 * jax.nn.sigmoid(x @ w[p + "lin_b"])
+                alpha = jnp.exp(-jnp.exp(w[p + "lin_A_log"]) * jax.nn.softplus(
+                    x @ w[p + "lin_a"] + w[p + "lin_dt_bias"]))
+                s = jnp.zeros((heads, dk, dv))
+                for t in range(len(toks)):
+                    s = alpha[t][:, None, None] * s
+                    u = jnp.einsum("hkv,hk->hv", s, k[t])
+                    s = s + k[t][..., None] * (
+                        beta[t][:, None] * (v[t] - u))[:, None, :]
+                out.append((np.asarray(s), np.asarray(qkv[-3:])))
+            h, _ = ref.block(w, i, h, cfg, kind)
+    return out
+
+
+def _state_by_head(leaf, d):
+    """A row of the pool's state leaf ``[H / g, dk, g * dv]`` as the
+    rule's ``[H, dk, dv]``."""
+    g = d.tile_heads
+    return np.asarray(leaf).reshape(d.lin_heads // g, d.dk, g, d.dv) \
+        .transpose(0, 2, 1, 3).reshape(d.lin_heads, d.dk, d.dv)
+
+
+# fp32: the step and the reference differ in the order of float32 sums.
+# bf16: the step multiplies bf16 weights by activations rounded to bf16
+# and keeps K/V in bf16 (relative 2**-9 an operand, twelve products
+# deep); the reference upcasts the same weights and keeps everything
+# else in float32.
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("dtype,kv_dtype,tol", [
+    ("float32", "fp32", 2e-5), ("bfloat16", "bf16", 3e-2)])
+def test_prefill_then_decode_equals_the_full_forward(layout, dtype, kv_dtype,
+                                                     tol):
+    import jax.numpy as jnp
+
+    cfg = tiny_cfg(layout)
+    d = dh.dims(cfg)
+    assert d.tile_heads == (2 if layout == "two_heads_a_row" else 1)
+    w = weights(cfg, seed=3, dtype=dtype)
+    step, make_cache = decoding.make_delta_hybrid_lm_pooled_step_fn(
+        w, cfg, kv_dtype=kv_dtype)
+    toks = np.random.RandomState(5).randint(0, V, (3, 12)).astype(np.int32)
+    want = np.asarray(ref.forward(w, jnp.asarray(toks), cfg))
+    got, cache = _staggered(step, make_cache, toks)
+    assert np.abs(got - want).max() <= tol * (want.max() - want.min())
+    # the row that was idle throughout was neither written nor started
+    for layer in cache:
+        for leaf in layer.values():
+            assert float(jnp.abs(leaf[3].astype("float32")).max()) == 0.0
+    assert [sorted(layer) for layer in cache] == (
+        [["conv", "state"]] * 3 + [["k", "v"]])
+    assert cache[3]["k"].dtype == jnp.dtype(
+        {"fp32": "float32", "bf16": "bfloat16"}[kv_dtype])
+    assert cache[0]["state"].dtype == cache[0]["conv"].dtype == jnp.float32
+    assert cache[0]["state"].shape == (4,) + d.state_shape
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_every_leaf_holds_what_the_references_equations_leave(layout):
+    """After a row's twelve tokens its state leaf is the rule's ``S`` of
+    every head (whichever way the heads share a row of lanes), its conv
+    leaf the last three projected rows, its K/V rows the normed keys and
+    the values of every position."""
+    import jax.numpy as jnp
+
+    cfg = tiny_cfg(layout)
+    d = dh.dims(cfg)
+    w = weights(cfg, seed=3)
+    step, make_cache = decoding.make_delta_hybrid_lm_pooled_step_fn(
+        w, cfg, kv_dtype="fp32")
+    toks = np.random.RandomState(5).randint(0, V, (3, 12)).astype(np.int32)
+    _, cache = _staggered(step, make_cache, toks)
+    for b in range(3):
+        for i, (s, conv) in enumerate(_reference_leaves(w, toks[b], cfg)):
+            got = _state_by_head(cache[i]["state"][b], d)
+            assert np.abs(got - s).max() <= 1e-5 * np.abs(s).max()
+            assert np.abs(np.asarray(cache[i]["conv"][b]) - conv).max() \
+                <= 1e-5 * np.abs(conv).max()
+    # the full layer's rows: k after its norm, v as projected
+    h = ref.embed(w, jnp.asarray(toks), cfg)
+    for i in range(3):
+        h, _ = ref.block(w, i, h, cfg, dh.LINEAR)
+    k = ref._rms(h @ w["lm_l3_attn_k"], w["lm_l3_attn_k_norm"], 1e-6)
+    for name, rows in (("k", k), ("v", h @ w["lm_l3_attn_v"])):
+        rows = np.asarray(rows)
+        assert np.abs(np.asarray(cache[3][name])[:3, :12] - rows).max() \
+            <= 2e-5 * np.abs(rows).max()
+
+
+def test_the_step_gate_reaches_past_one_in_these_weights():
+    """``beta = 2 sigmoid(W_b x)``: were it never above 1 here, a step
+    without the factor 2 could not be told from one with it — and it is
+    told: served with ``linear_allow_neg_eigval`` false the logits leave
+    the reference's."""
+    import jax.numpy as jnp
+
+    cfg = tiny_cfg()
+    w = weights(cfg, seed=3)
+    toks = np.random.RandomState(5).randint(0, V, (3, 12)).astype(np.int32)
+    x = ref.embed(w, jnp.asarray(toks), cfg)
+    _, beta = dh.decay_and_step_gates(x.reshape(-1, 64), w, "lm_l0_",
+                                      dh.dims(cfg))
+    beta = np.asarray(beta)
+    assert beta.max() > 1.3 and beta.min() < 0.7
+    assert (beta > 1).mean() > 0.25
+    want = np.asarray(ref.forward(w, jnp.asarray(toks), cfg))
+    step, make_cache = decoding.make_delta_hybrid_lm_pooled_step_fn(
+        w, dict(cfg, linear_allow_neg_eigval=False), kv_dtype="fp32")
+    got, _ = _staggered(step, make_cache, toks)
+    assert np.abs(got - want).max() > 1e-2 * (want.max() - want.min())
+
+
+def test_gated_delta_step_reads_the_state_before_it_writes_it():
+    """One head, one row, by hand: with ``alpha`` = 1 and ``beta`` = 1
+    the rule REPLACES what the state returns for ``k`` by ``v`` (the
+    delta rule), where a state that only adds would return their sum."""
+    import jax.numpy as jnp
+
+    k = jnp.asarray([[[1.0, 0.0]]])                  # [N=1, H=1, dk=2]
+    v0 = jnp.asarray([[[1.0, 2.0, 3.0]]])
+    v1 = jnp.asarray([[[5.0, 5.0, 5.0]]])
+    one = jnp.ones((1, 1))
+    s = jnp.zeros((1, 1, 2, 3))
+    ts = jnp.asarray([1], jnp.int32)
+    o, s = dh.gated_delta_step(k, k, v0, one, one, s, ts)
+    assert np.allclose(o, v0)
+    o, s = dh.gated_delta_step(k, k, v1, one, one, s, ts)
+    assert np.allclose(o, v1) and np.allclose(s[0, 0, 0], v1[0, 0])
+    # beta = 2 overshoots to the other side (a negative eigenvalue)
+    o, s = dh.gated_delta_step(k, k, v0, one, 2 * one, s, ts)
+    assert np.allclose(o, 2 * v0 - v1)
+    # a decay of 1/2 halves what is read before the write
+    o, _ = dh.gated_delta_step(k, k, jnp.zeros_like(v0), 0.5 * one,
+                               0 * one, s, ts)
+    assert np.allclose(o, 0.5 * (2 * v0 - v1))
+
+
+# ---------------------------------------------------------------------------
+# the pool: different leaves a layer, a reused slot, refused tiers
+# ---------------------------------------------------------------------------
+def _pool(cfg, w, len_ladder, **kw):
+    step, make_cache = decoding.make_delta_hybrid_lm_pooled_step_fn(
+        w, cfg, kv_dtype="fp32")
+    return KVSlotPool(step, make_cache, eos_id=V, max_slots=2,
+                      max_seq_len=len_ladder[-1], slot_ladder=[2],
+                      len_ladder=len_ladder, steps=2, kv_dtype="fp32",
+                      **kw), make_cache
+
+
+def _serve(pool, state, slot, prompt, n_new):
+    state = pool.admit(state, slot, prompt, len(prompt), len(prompt) + n_new)
+    while not bool(np.asarray(state["finished"])[slot]):
+        state = pool.chunk(state)
+    toks = np.asarray(state["tokens"])[slot]
+    return state, toks[len(prompt):len(prompt) + n_new].copy()
+
+
+@pytest.mark.parametrize("reset", [True, False])
+def test_a_reused_slot_starts_its_state_and_conv_window_from_zero(
+        reset, monkeypatch):
+    """Request B in the slot request A left gets B's tokens and leaves
+    exactly as a pool that never held A gives them; with the reset taken
+    out of the step it starts from A's state and conv window and does
+    not."""
+    import jax.numpy as jnp
+
+    if not reset:
+        monkeypatch.setattr(dh, "starts_fresh",
+                            lambda ts: jnp.zeros(ts.shape, bool))
+    cfg = tiny_cfg()
+    w = weights(cfg, seed=11)
+    rng = np.random.RandomState(2)
+    a, b = (rng.randint(0, V, n).astype(np.int32) for n in (9, 4))
+    pool, _ = _pool(cfg, w, [16])
+    virgin, want_toks = _serve(pool, pool.alloc(2, 16), 0, b, 8)
+    used, _ = _serve(pool, pool.alloc(2, 16), 0, a, 7)
+    for name in ("state", "conv"):
+        assert np.abs(np.asarray(used["cache"][0][name])[0]).max() > 0
+    used, got_toks = _serve(pool, used, 0, b, 8)
+    same = all(np.array_equal(np.asarray(u[name])[0], np.asarray(v[name])[0])
+               for u, v in zip(used["cache"], virgin["cache"])
+               for name in ("state", "conv") if name in u)
+    if reset:
+        assert same and np.array_equal(got_toks, want_toks)
+    else:
+        assert not same
+
+
+def test_an_idle_row_keeps_its_state_and_its_conv_window():
+    """A slot that finished sits idle while its neighbour runs on: the
+    steps it does not take leave both of its recurrent leaves as they
+    were."""
+    cfg = tiny_cfg()
+    w = weights(cfg, seed=11)
+    rng = np.random.RandomState(4)
+    a, b = (rng.randint(0, V, n).astype(np.int32) for n in (5, 6))
+    pool, _ = _pool(cfg, w, [32])
+    state, _ = _serve(pool, pool.alloc(2, 32), 0, a, 4)
+    before = [{k: np.asarray(v)[0].copy() for k, v in layer.items()}
+              for layer in state["cache"]]
+    assert np.abs(before[0]["state"]).max() > 0
+    state, _ = _serve(pool, state, 1, b, 20)
+    for layer, was in zip(state["cache"], before):
+        for name in ("state", "conv"):
+            if name in was:
+                assert np.array_equal(np.asarray(layer[name])[0], was[name])
+
+
+def test_layers_that_hold_different_leaves_are_declared_leaf_by_leaf():
+    """A full layer holds k and v and no state, a linear layer a state
+    and a conv window and no K/V: ``cache_leaf_seq_axes`` /
+    ``recurrent_leaf_names`` read the declarations, ``resize`` keeps the
+    recurrent leaves whatever the length rung, and the bytes follow."""
+    import jax
+
+    cfg = tiny_cfg()
+    w = weights(cfg, seed=4)
+    pool, make_cache = _pool(cfg, w, [16, 32])
+    d = dh.dims(cfg)
+    leaves = jax.tree.leaves(jax.eval_shape(lambda: make_cache(2, 16)))
+    with pytest.raises(ValueError, match="leaf_seq_axes"):
+        decoding.cache_leaf_seq_axes(lambda s, t: make_cache(s, t), leaves)
+    # flattened: (conv, state) x 3, then k, v
+    assert decoding.cache_leaf_seq_axes(make_cache, leaves) == (
+        [None, None] * 3 + [1, 1])
+    assert decoding.recurrent_leaf_names(make_cache) == [
+        "[%d]['%s']" % (i, n) for i in range(3) for n in ("conv", "state")]
+    assert pool.recurrent_leaves == decoding.recurrent_leaf_names(make_cache)
+
+    rng = np.random.RandomState(9)
+    p0, p1 = (rng.randint(0, V, n).astype(np.int32) for n in (5, 3))
+    want = pool.admit(pool.alloc(2, 32), 0, p0, 5, 24)
+    want = pool.chunk(pool.chunk(want))
+    want = pool.admit(want, 1, p1, 3, 12)
+    for _ in range(12):
+        want = pool.chunk(want)
+    state = pool.admit(pool.alloc(2, 16), 0, p0, 5, 24)
+    state = pool.chunk(pool.chunk(state))
+    held = np.asarray(state["cache"][0]["state"])
+    state = pool.resize(state, 2, 32)
+    assert np.array_equal(np.asarray(state["cache"][0]["state"]), held)
+    state = pool.admit(state, 1, p1, 3, 12)
+    for _ in range(12):
+        state = pool.chunk(state)
+    assert np.array_equal(np.asarray(state["tokens"]),
+                          np.asarray(want["tokens"]))
+    # bytes: K/V of the ONE full layer scale with the rung; the three
+    # states and conv windows do not
+    assert pool.kv_rung_bytes(2, 32) == 2 * pool.kv_rung_bytes(2, 16) == (
+        2 * 2 * 32 * d.d_kv * 4)
+    assert pool.recurrent_rung_bytes(2, 32) == pool.recurrent_rung_bytes(
+        2, 16) == 3 * 2 * 4 * (d.lin_heads * d.dk * d.dv
+                               + (d.conv_len - 1) * d.d_qkv)
+
+
+@pytest.mark.parametrize("tier", ["prefix", "speculative"])
+def test_prefix_and_speculation_are_refused_over_this_builder(tier):
+    cfg = tiny_cfg()
+    w = weights(cfg)
+    if tier == "prefix":
+        kw = {"prefix": True}
+    else:
+        from paddle_tpu.serving.speculative import SpeculativeConfig
+
+        step, make_cache = decoding.make_delta_hybrid_lm_pooled_step_fn(
+            w, cfg, kv_dtype="fp32")
+        kw = {"speculative": SpeculativeConfig(
+            lambda c, t, ts: (None, c), step, make_cache, k=2)}
+    with pytest.raises(ValueError, match=r"recurrent leaves .*a recurrent "
+                       r"state has no"):
+        _pool(cfg, w, [16], **kw)
+    if tier == "prefix":
+        step, make_cache = decoding.make_delta_hybrid_lm_pooled_step_fn(
+            w, cfg, kv_dtype="fp32")
+        with pytest.raises(ValueError, match="recurrent leaves"):
+            DecodeServer(step, make_cache, eos_id=V, max_seq_len=16,
+                         max_slots=2, prefix_cache=1 << 20)
+
+
+def test_a_config_this_builder_cannot_serve_is_refused_by_name():
+    with pytest.raises(ValueError, match="layer_types"):
+        dh.dims(tiny_cfg(layer_types=[dh.LINEAR, "sliding_attention"] * 2))
+    with pytest.raises(ValueError, match="linear_num_key_heads"):
+        dh.dims(tiny_cfg(linear_num_key_heads=3))
+
+
+# ---------------------------------------------------------------------------
+# the chooser's count of the form one query head a K/V head lowered to
+# ---------------------------------------------------------------------------
+def test_the_full_layers_count_the_form_they_lowered():
+    """``make_decode_attention`` counts a step of one query head a K/V
+    head over sequence leaves by the form it took: on the CPU the XLA
+    form, once a full layer of a traced step; the read rule the builder
+    declares for the server's counter is then the whole rung."""
+    import jax
+
+    cfg = tiny_cfg(layer_types=[dh.LINEAR, dh.FULL] * 2)
+    w = weights(cfg)
+    step, make_cache = decoding.make_delta_hybrid_lm_pooled_step_fn(
+        w, cfg, kv_dtype="bf16")
+    count = lambda path: da.UNGROUPED_LOWERED.labels(path=path).value
+    before = count("xla"), count("kernel")
+    jax.eval_shape(step, make_cache(2, 16), np.zeros(2, np.int32),
+                   np.zeros(2, np.int32))
+    assert (count("xla") - before[0], count("kernel") - before[1]) == (2, 0)
+    assert make_cache.kv_positions_read(np.array([0, 7, 15]), 16).tolist() \
+        == [16, 16, 16]
+
+
+# ---------------------------------------------------------------------------
+# the server
+# ---------------------------------------------------------------------------
+def test_decode_server_end_to_end_with_slot_reuse():
+    """Six requests through two slots: every one gets the tokens the
+    reference's full forward ranks first (greedy, fp32), each reused
+    slot's state was started from zero, and the server counted one reset
+    an admission."""
+    import jax.numpy as jnp
+
+    cfg = tiny_cfg()
+    w = weights(cfg, seed=21)
+    step, make_cache = decoding.make_delta_hybrid_lm_pooled_step_fn(
+        w, cfg, kv_dtype="fp32")
+    srv = DecodeServer(step, make_cache, eos_id=V, max_seq_len=32,
+                       max_slots=2, slot_ladder=[2], len_ladder=[32],
+                       steps_per_tick=2, kv_dtype="fp32", name="delta-e2e")
+    try:
+        srv.warmup()
+        rng = np.random.RandomState(8)
+        prompts = [rng.randint(0, V, n).astype(np.int32)
+                   for n in (5, 9, 3, 7, 4, 6)]
+        reqs = [srv.submit({"tokens": p}, max_new_tokens=6 + i)
+                for i, p in enumerate(prompts)]
+        outs = [r.result(timeout=WAIT)[0] for r in reqs]
+        m = srv.metrics()["decode"]
+    finally:
+        srv.stop(drain=False, timeout=30)
+    for i, (p, out) in enumerate(zip(prompts, outs)):
+        assert len(out) == 6 + i
+        full = np.concatenate([p, out])[None, :]
+        logits = np.asarray(ref.forward(w, jnp.asarray(full), cfg))[0]
+        for j, tok in enumerate(out):
+            row = logits[len(p) + j - 1]
+            assert row.max() - row[tok] <= 1e-5 * (row.max() - row.min())
+    assert m["state_resets"] == len(prompts)
+    # an fp32 pool without the builder's rule would count the ragged
+    # kernel's rounding; with it, the whole rung a step and active slot
+    assert m["kv_positions_read"] == 32 * sum(
+        len(p) + len(o) - 1 for p, o in zip(prompts, outs))

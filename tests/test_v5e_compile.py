@@ -10,6 +10,7 @@ their own: under ``--dist loadfile`` a file is one worker's job, and the
 kernel tests of ``tests/test_decode_attention.py`` (interpret mode, 167
 cases) are another's.
 """
+import numpy as np
 import pytest
 
 from test_decode_attention import _two_grouped_layers, _two_sparse_layers
@@ -321,16 +322,25 @@ def test_grouped_matmul_compiles_for_v5e_at_lfm2_widths(one_chip):
         e * f * d * 2) // 4
 
 
-def test_lane_masked_attention_makes_no_copy_of_a_rung_on_v5e(one_chip):
-    """256 slots x 2048 positions x 8 K/V heads of 64, 4 query heads a
-    K/V head, bf16: the form that reads the leaves as they lie compiles
-    with temporaries far under a leaf's size; the per-head view of the
-    same leaves re-tiles them (a copy of each, which is why the step
-    does not take it)."""
+@pytest.mark.parametrize("s,t,g,rep,dh,copied", [
+    (256, 2048, 8, 4, 64, 1), (80, 1024, 30, 1, 128, 2)],
+    ids=["lfm2_64_wide_heads", "olmo_hybrid_one_head_a_kv_head"])
+def test_lane_masked_attention_makes_no_copy_of_a_rung_on_v5e(
+        one_chip, s, t, g, rep, dh, copied):
+    """Why ``make_decode_attention`` sends two groupings through the form
+    that reads bf16 leaves as they lie, which compiles with temporaries
+    far under a leaf's size.  256 slots x 2048 positions x 8 K/V heads of
+    64, 4 query heads a K/V head (``lfm2_24b_a2b``): the per-head view
+    of the same leaves re-tiles them, a copy of each.  80 x 1024 x 30
+    heads of 128, ONE query head a K/V head (``olmo_hybrid_7b``'s full
+    layers): with one query row a K/V head the compiler takes the score
+    product off the matrix unit and first re-lays each leaf out in
+    float32, a temporary TWICE a leaf's size a leaf (in the compiled
+    ``chunk``, PR 50: six 1.26 GB copies a step, 3.0 GB of temporaries;
+    0.5 GB with the lane form)."""
     import jax
     import jax.numpy as jnp
 
-    s, t, g, rep, dh = 256, 2048, 8, 4, 64
     leaf = s * t * g * dh * 2
 
     def sd(shape, dtype=jnp.float32):
@@ -347,4 +357,57 @@ def test_lane_masked_attention_makes_no_copy_of_a_rung_on_v5e(one_chip):
             ).temp_size_in_bytes
 
     assert temp(da.lane_masked_decode_attention) < leaf // 4
-    assert temp(da.grouped_masked_decode_attention) >= leaf
+    assert temp(da.grouped_masked_decode_attention) >= copied * leaf
+
+
+def test_delta_rule_layer_compiles_for_v5e_at_olmo_hybrid_widths(one_chip):
+    """80 slots of one gated delta-rule layer at Olmo-Hybrid-7B's widths
+    (30 heads of 96 key and 192 value lanes, bf16 weights, fp32 state):
+    the state leaf is declared two heads a row (384 lanes = 3 tiles), so
+    the tiled layout pads nothing of it — the arguments are the weights,
+    the leaves and the row, to the byte, where ``[80, 30, 96, 192]``
+    would hold a third more — both leaves are aliased in place, no
+    temporary is the size of the 177 MB state (XLA's rule is two passes
+    over it, neither materialised), and the rule carries its scope's
+    name for the trace."""
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import delta_hybrid_lm as dh
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "olmo_hybrid_7b.json")) as fh:
+        cfg = json.load(fh)
+    d = dh.dims(cfg)
+    assert d.state_shape == (15, 96, 384)
+    s, p = 80, "lm_l0_"
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w = {k: sd(shp, jnp.float32 if k.endswith(dh.FLOAT32_PARAMS)
+               else jnp.bfloat16)
+         for k, shp in dh.param_shapes(cfg).items()
+         if k.startswith(p + "lin_")}
+
+    def f(w, state, conv, x, ts):
+        return dh.delta_layer_step(x, w, p, state, conv, ts, d)
+
+    compiled = jax.jit(f, donate_argnums=(1, 2)).lower(
+        w, sd((s,) + d.state_shape), sd((s, d.conv_len - 1, d.d_qkv)),
+        sd((s, d.d_model)), sd((s,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert dh.DELTA_UPDATE_SCOPE in text and dh.SHORT_CONV_SCOPE in text
+    mem = compiled.memory_analysis()
+    state_leaf = 4 * s * d.lin_heads * d.dk * d.dv
+    conv_leaf = 4 * s * (d.conv_len - 1) * d.d_qkv
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in w.values())
+    assert mem.alias_size_in_bytes >= state_leaf + conv_leaf
+    assert mem.argument_size_in_bytes < (weights + state_leaf + conv_leaf
+                                         + (2 << 20))
+    assert mem.temp_size_in_bytes < state_leaf // 2

@@ -169,6 +169,21 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
             return decoding.make_mtp_routed_lm_pooled_step_fn(
                 w, cfg, kv_dtype=sv["kv_dtype"], held=held,
                 prefill_tokens=sv["prefill_tokens"])[:2]
+    elif cfg["family"] == "pooled_delta_hybrid_lm":
+        from paddle_tpu import delta_hybrid_lm
+
+        # the first ``layers`` of the cut (4: one period of three linear
+        # layers and a full one; 12: the whole cut)
+        cfg["layer_types"] = cfg["layer_types"][:layers]
+        cfg["num_hidden_layers"] = len(cfg["layer_types"])
+        # as the family makes them: matrices bf16, the rest fp32
+        weights = {n: sd(shp, jnp.float32 if n.endswith(
+            delta_hybrid_lm.FLOAT32_PARAMS) else jnp.bfloat16)
+            for n, shp in delta_hybrid_lm.param_shapes(cfg).items()}
+
+        def build(w):
+            return decoding.make_delta_hybrid_lm_pooled_step_fn(
+                w, cfg, kv_dtype=sv["kv_dtype"])
     elif cfg["family"] == "pooled_hybrid_ssm_lm":
         from paddle_tpu import hybrid_ssm
 
@@ -304,7 +319,8 @@ def main():
     ap.add_argument("--layers", type=int, default=2,
                     help="layers compiled (8: the whole minicpm_sala or "
                     "smallthinker_21b_a3b cut, 5: k_exaone_236b_a23b's, "
-                    "to see that the real program fits the chip)")
+                    "12: olmo_hybrid_7b's, to see that the real program "
+                    "fits the chip)")
     args = ap.parse_args()
     lowered = lowered_chunk(os.path.abspath(args.repo), args.config,
                             args.kind, args.layers)
