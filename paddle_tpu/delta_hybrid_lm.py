@@ -43,6 +43,29 @@ row (``ts < 0``):
   weights; two heads are 384 = 3 tiles and nothing is padded;
 * ``conv`` ``[N, K - 1, 2 H dk + H dv]`` float32: the last ``K - 1``
   projected rows ``[q; k; v]`` before the convolution.
+
+The rule itself (:func:`gated_delta_step`) has two lowerings of its one
+contract, chosen by :func:`lowering` from what the call can see (never a
+flag):
+
+* the Pallas TPU kernel (:func:`kernel_gated_delta_step`): a grid step
+  brings a block of the state leaf — some slots of one row of its second
+  axis, ``[bn, 1, dk, g * dv]``, as the leaf is declared — into VMEM
+  ONCE, does decay, ``S^T k``, the write and ``S^T q`` on it there, on
+  the vector unit in float32, and writes it back ONCE, in place.  ``k``
+  and ``q`` go in as columns with the slot in the lane axis and the
+  kernel makes the lane map of a slot by one gathered lane (and a select
+  where a lane tile holds two heads); ``v`` as rows of the leaf's lane
+  axis, the gates as scalars in SMEM: kilobytes a block, nothing the
+  size of the state;
+* the XLA form (:func:`xla_gated_delta_step`): two fusions, one reads
+  the state and reduces to ``S^T k``, one reads it again, writes it and
+  reduces to ``S^T q`` (XLA cannot fuse a reduction with the consumer of
+  its own result) — three moves of the state for the rule's two.  The
+  CPU, a leaf that is not float32, a leaf padded by the tiled layout.
+
+``delta_update_lowered_total{path}`` (``kernel`` | ``xla``) counts the
+updates traced, by the form taken.
 """
 from __future__ import annotations
 
@@ -53,11 +76,14 @@ import numpy as np
 
 from paddle_tpu.hybrid_ssm import (linear, rms_norm, rotary, starts_fresh,
                                    swiglu)
+from paddle_tpu.monitor import registry as _registry
 
 __all__ = ["LINEAR", "FULL", "DELTA_UPDATE_SCOPE", "SHORT_CONV_SCOPE",
            "FLOAT32_PARAMS", "dims", "param_shapes", "random_state",
            "heads_per_tile", "qkv_conv_step", "l2_norm", "decay_and_step_gates",
-           "gated_delta_step", "gated_output_norm", "delta_layer_step",
+           "gated_delta_step", "xla_gated_delta_step",
+           "kernel_gated_delta_step", "lowering", "LOWERED", "KERNEL_NAME",
+           "gated_output_norm", "delta_layer_step",
            "full_attention_rows", "linear", "rms_norm", "rotary",
            "starts_fresh", "swiglu"]
 
@@ -73,6 +99,17 @@ FLOAT32_PARAMS = ("norm", "lin_conv_w", "lin_A_log", "lin_dt_bias")
 
 _LANES = 128
 _L2_EPS = 1e-6
+#: the kernel's name in the device trace
+KERNEL_NAME = "gated_delta_update"
+#: most bytes of the state one buffer of a grid step holds
+_BLOCK_BYTES = 5 << 19
+
+LOWERED = _registry.REGISTRY.counter(
+    "delta_update_lowered_total",
+    "gated delta-rule state updates lowered (traced into a program or "
+    "run eagerly), by the lowering chosen: kernel (Pallas TPU: each "
+    "block of the state leaf read once and written once, in place) | "
+    "xla (two fusions: the state read twice and written once)", ("path",))
 
 
 def heads_per_tile(n_head: int, dv: int) -> int:
@@ -245,6 +282,32 @@ def _over_lanes(x, g: int, dv: int):
     return out
 
 
+def _block_slots(n: int, dk: int, lanes: int) -> int:
+    """Slots a grid step of the kernel holds of a float32 leaf ``[n, .,
+    dk, lanes]``: the most that divide both ``n`` and a lane tile (the
+    block's columns of ``k`` and ``q`` lie in ONE tile of the
+    slot-in-lanes operand), in whole sublane tiles (the block's rows of
+    ``v`` and ``o``), within :data:`_BLOCK_BYTES` of state a buffer; 0 if
+    none does."""
+    return max((b for b in range(8, _LANES + 1, 8)
+                if n % b == 0 and _LANES % b == 0
+                and b * 4 * dk * lanes <= _BLOCK_BYTES), default=0)
+
+
+def lowering(backend: str, s, dv: int) -> str:
+    """``"kernel"`` or ``"xla"`` for one update of the state leaf ``s``
+    ``[N, H / g, dk, g * dv]``.  The kernel needs a TPU, a float32 leaf
+    whose rows are whole lane tiles (``g * dv``) of whole sublane tiles
+    (``dk``), and a slot count that is a whole number of blocks."""
+    import jax.numpy as jnp
+
+    n, _, dk, lanes = s.shape
+    ok = (backend == "tpu" and s.dtype == jnp.float32
+          and lanes % _LANES == 0 and lanes % dv == 0 and dk % 8 == 0
+          and _block_slots(n, dk, lanes))
+    return "kernel" if ok else "xla"
+
+
 def gated_delta_step(q, k, v, alpha, beta, s, ts):
     """One token of the gated delta rule for every row and head.
 
@@ -252,26 +315,178 @@ def gated_delta_step(q, k, v, alpha, beta, s, ts):
     ``alpha``, ``beta`` ``[N, H]``, all float32; ``s`` the state leaf
     ``[N, H / g, dk, g * dv]`` (``g`` read from its shape); ``ts`` ``[N]``
     (``< 0`` idle: state kept, ``0`` fresh: state read as zero).  Returns
-    ``(o [N, H, dv], s)``."""
+    ``(o [N, H, dv], s)``.  :func:`lowering` chooses the form."""
     import jax
+
+    path = lowering(jax.default_backend(), s, v.shape[-1])
+    LOWERED.labels(path=path).inc()
+    with jax.named_scope(DELTA_UPDATE_SCOPE):
+        if path == "kernel":
+            return kernel_gated_delta_step(q, k, v, alpha, beta, s, ts)
+        return xla_gated_delta_step(q, k, v, alpha, beta, s, ts)
+
+
+def xla_gated_delta_step(q, k, v, alpha, beta, s, ts):
+    """The rule as XLA fuses it: two passes over the state (one reduces
+    to ``S^T k``, one reads it again, writes it and reduces to ``S^T
+    q``).  Every backend, every leaf; the kernel's reference."""
     import jax.numpy as jnp
 
     f32 = jnp.float32
     n, h, dv = v.shape
     g = s.shape[-1] // dv
     live, fresh = ts >= 0, starts_fresh(ts)
-    with jax.named_scope(DELTA_UPDATE_SCOPE):
-        wide = functools.partial(_over_lanes, g=g, dv=dv)
-        kk, qq = wide(k), wide(q)
-        s_prev = jnp.where(fresh[:, None, None, None], 0.0, s.astype(f32))
-        s_dec = wide(alpha[..., None]) * s_prev
-        u = jnp.sum(s_dec * kk, axis=2)                 # [N, H / g, g * dv]
-        delta = wide(beta[..., None])[:, :, 0] * (v.reshape(u.shape) - u)
-        s_new = s_dec + kk * delta[:, :, None, :]
-        o = jnp.sum(s_new * qq, axis=2).reshape(n, h, dv)
-        s_out = jnp.where(live[:, None, None, None], s_new,
-                          s.astype(f32)).astype(s.dtype)
+    wide = functools.partial(_over_lanes, g=g, dv=dv)
+    kk, qq = wide(k), wide(q)
+    s_prev = jnp.where(fresh[:, None, None, None], 0.0, s.astype(f32))
+    s_dec = wide(alpha[..., None]) * s_prev
+    u = jnp.sum(s_dec * kk, axis=2)                     # [N, H / g, g * dv]
+    delta = wide(beta[..., None])[:, :, 0] * (v.reshape(u.shape) - u)
+    s_new = s_dec + kk * delta[:, :, None, :]
+    o = jnp.sum(s_new * qq, axis=2).reshape(n, h, dv)
+    s_out = jnp.where(live[:, None, None, None], s_new,
+                      s.astype(f32)).astype(s.dtype)
     return o, s_out
+
+
+def kernel_gated_delta_step(q, k, v, alpha, beta, s, ts,
+                            interpret: bool = False):
+    """The rule as ONE Pallas TPU kernel: a block of the state leaf is
+    brought into VMEM once, decayed, read for ``S^T k``, written and read
+    for ``S^T q`` there, and goes back once, in place (the leaf is an
+    ``input_output_aliases`` pair)."""
+    import jax.numpy as jnp
+
+    n, _, dk, lanes = s.shape
+    # a row's kind by the one definition of each (here, not under the
+    # shared trace): 0 fresh (its state is read as zero), 1 live, -1 idle
+    # (its state is kept)
+    kinds = jnp.where(starts_fresh(ts), 0, jnp.where(ts >= 0, 1, -1))
+    return _kernel_call()(q, k, v, alpha, beta, s, kinds.astype(jnp.int32),
+                          block=_block_slots(n, dk, lanes),
+                          interpret=interpret)
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_call():
+    """:func:`_delta_update` under ONE ``jax.jit`` (built once, jax
+    imported late): the linear layers and the steps of a chunk program
+    share one trace and one lowered function of the kernel
+    (decode_attention._kernel_call)."""
+    import jax
+
+    return jax.jit(_delta_update, static_argnames=("block", "interpret"))
+
+
+def _delta_update(q, k, v, alpha, beta, s, kinds, *, block, interpret):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    n, h, dv = v.shape
+    _, pairs, dk, lanes = s.shape
+    g, bn = h // pairs, block
+    # what a grid step needs besides its block of the state, in layouts
+    # that cost kilobytes a block.  ``k`` and ``q`` are wanted with ``dk``
+    # along SUBLANES: their columns go in with the slot in the lane axis,
+    # [H / g, N / 128, 2 g, dk, 128] (a tile of 128 slots a block index),
+    # and the kernel broadcasts a slot's lane over a tile.  ``v`` is a row
+    # of the leaf's own lane axis, [H / g, N, g * dv]; the gates are
+    # scalars a (slot, head), in SMEM beside the rows' kinds.
+    tiles = -(-n // _LANES)
+    cols = jnp.concatenate([k.reshape(n, pairs, g, dk),
+                            q.reshape(n, pairs, g, dk)], axis=2)
+    cols = jnp.pad(cols.transpose(1, 2, 3, 0),
+                   ((0, 0),) * 3 + ((0, tiles * _LANES - n),))
+    cols = cols.reshape(pairs, 2 * g, dk, tiles, _LANES).transpose(
+        0, 3, 1, 2, 4)
+    rows = v.reshape(n, pairs, lanes).transpose(1, 0, 2)
+
+    def kernel(kind_ref, alpha_ref, beta_ref, cols_ref, v_ref, s_ref, o_ref,
+               s_out_ref):
+        pair, first = pl.program_id(0), pl.program_id(1) * bn
+        lane = jax.lax.broadcasted_iota(jnp.int32, (dk, _LANES), 1)
+        row_lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+
+        def gate_row(ref, m):
+            """The (slot, head) scalars of ``ref`` against the leaf's
+            row: ``[1, g * dv]``, head ``l // dv``'s in lane ``l``."""
+            at = (first + m) * h + pair * g
+            out = jnp.full((1, lanes), ref[at], f32)
+            for head in range(1, g):
+                out = jnp.where(row_lane >= head * dv, ref[at + head], out)
+            return out
+
+        def over_lanes(head0, at):
+            """Heads ``head0 ..`` of ``cols_ref``, the slot in lane
+            ``at``, against the leaf's row: ``[dk, g * dv]`` holding head
+            ``l // dv``'s value in lane ``l`` — a lane tile at a time,
+            one gathered lane, and a select where a tile holds the end
+            of one head and the start of the next."""
+            tile = []
+            for j in range(lanes // _LANES):
+                lo, hi = j * _LANES // dv, (j * _LANES + _LANES - 1) // dv
+                out = jnp.take_along_axis(cols_ref[head0 + lo], at, axis=1)
+                for head in range(lo + 1, hi + 1):
+                    out = jnp.where(
+                        lane >= head * dv - j * _LANES,
+                        jnp.take_along_axis(cols_ref[head0 + head], at,
+                                            axis=1), out)
+                tile.append(out)
+            return jnp.concatenate(tile, axis=1)
+
+        def slot(m, carry):
+            kind = kind_ref[first + m]
+            at = jnp.full((dk, _LANES), first % _LANES + m, jnp.int32)
+            kk, qq = over_lanes(0, at), over_lanes(g, at)
+            a, b = gate_row(alpha_ref, m), gate_row(beta_ref, m)
+            s_old = s_ref[m]
+            s_dec = a * jnp.where(kind == 0, 0.0, s_old)
+            u = jnp.sum(s_dec * kk, axis=0, keepdims=True)
+            s_new = s_dec + kk * (b * (v_ref[pl.ds(m, 1), :] - u))
+            o_ref[pl.ds(m, 1), :] = jnp.sum(s_new * qq, axis=0,
+                                            keepdims=True)
+            s_out_ref[m] = jnp.where(kind >= 0, s_new, s_old)
+            return carry
+
+        jax.lax.fori_loop(0, bn, slot, 0)
+
+    def leaf_block(j, i, *scalars):
+        return (i, j, 0, 0)
+
+    def row_block(j, i, *scalars):
+        return (j, i, 0)
+
+    o, s_out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            # slot blocks innermost: a head group's columns of k and q
+            # are fetched once for all of them
+            grid=(pairs, n // bn),
+            in_specs=[
+                pl.BlockSpec((None, None, 2 * g, dk, _LANES),
+                             lambda j, i, *scalars: (j, i * bn // _LANES, 0,
+                                                     0, 0)),
+                pl.BlockSpec((None, bn, lanes), row_block),
+                pl.BlockSpec((bn, None, dk, lanes), leaf_block)],
+            out_specs=[
+                pl.BlockSpec((None, bn, lanes), row_block),
+                pl.BlockSpec((bn, None, dk, lanes), leaf_block)]),
+        out_shape=[jax.ShapeDtypeStruct((pairs, n, lanes), f32),
+                   jax.ShapeDtypeStruct(s.shape, f32)],
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # both buffers of the state block in and out, and as much
+            # again for a slot's values
+            vmem_limit_bytes=8 * bn * dk * lanes * 4 + (8 << 20)),
+        name=KERNEL_NAME,
+        interpret=interpret,
+    )(kinds, alpha.reshape(-1), beta.reshape(-1), cols, rows, s)
+    return o.transpose(1, 0, 2).reshape(n, h, dv), s_out
 
 
 def gated_output_norm(o, gate, w_norm, eps: float):

@@ -227,6 +227,179 @@ def test_gated_delta_step_reads_the_state_before_it_writes_it():
 
 
 # ---------------------------------------------------------------------------
+# the rule's two lowerings: the Pallas kernel (interpret mode here) and
+# the XLA form, against the rule written out in numpy
+# ---------------------------------------------------------------------------
+#: Olmo-Hybrid-7B's head shape (96 key lanes, 192 value lanes: two heads
+#: a row of 384 lanes), few heads and slots
+OLMO_HEAD = (4, 96, 192)
+
+
+def _numpy_rule(q, k, v, alpha, beta, s, ts):
+    """One token of the rule, head by head and row by row, over the
+    state as ``[N, H, dk, dv]`` float64."""
+    q, k, v, alpha, beta = (np.asarray(a, np.float64)
+                            for a in (q, k, v, alpha, beta))
+    s = np.array(s, np.float64)
+    o = np.zeros(v.shape)
+    for n in range(s.shape[0]):
+        for h in range(s.shape[1]):
+            prev = s[n, h] if ts[n] != 0 else np.zeros_like(s[n, h])
+            dec = alpha[n, h] * prev
+            u = dec.T @ k[n, h]
+            new = dec + np.outer(k[n, h], beta[n, h] * (v[n, h] - u))
+            o[n, h] = new.T @ q[n, h]
+            if ts[n] >= 0:
+                s[n, h] = new
+    return o, s
+
+
+def _leaf_by_head(leaf, heads, dk, dv):
+    g = dh.heads_per_tile(heads, dv)
+    return np.asarray(leaf).reshape(-1, heads // g, dk, g, dv) \
+        .transpose(0, 1, 3, 2, 4).reshape(-1, heads, dk, dv)
+
+
+def _rule_inputs(rng, n, heads, dk, dv):
+    import jax.numpy as jnp
+
+    q = dh.l2_norm(jnp.asarray(rng.randn(n, heads, dk), jnp.float32)) \
+        * dk ** -0.5
+    k = dh.l2_norm(jnp.asarray(rng.randn(n, heads, dk), jnp.float32))
+    return (q, k, jnp.asarray(rng.randn(n, heads, dv), jnp.float32),
+            jnp.asarray(rng.uniform(0.5, 1.0, (n, heads)), jnp.float32),
+            jnp.asarray(rng.uniform(0.0, 2.0, (n, heads)), jnp.float32))
+
+
+@pytest.mark.parametrize("case,slots,first_ts,steps", [
+    ("olmo_head_shape", 8, [3, 9, 1, 500, 17, 2, 64, 5], 1),
+    ("fresh_rows", 8, [0, 4, 0, 0, 7, 0, 1, 0], 1),
+    ("idle_rows", 8, [-1, 4, -1, 0, -1, -1, 2, -1], 1),
+    ("mixed_over_steps", 16, [0, 3, -1, 5] * 4, 4)])
+def test_the_kernel_is_the_xla_form_and_the_rule(case, slots, first_ts, steps):
+    """The Pallas kernel (interpret mode) against the XLA form and the
+    rule in numpy at Olmo-Hybrid-7B's head shape: live rows, fresh rows
+    (a state of garbage read as zero), idle rows (their state bit-equal
+    afterwards, whatever the step computed), and a mixed batch whose
+    written state feeds the next steps' reads."""
+    import jax.numpy as jnp
+
+    heads, dk, dv = OLMO_HEAD
+    g = dh.heads_per_tile(heads, dv)
+    assert g == 2
+    rng = np.random.RandomState(len(case))
+    start = jnp.asarray(rng.randn(slots, heads // g, dk, g * dv),
+                        jnp.float32)
+    assert dh.lowering("tpu", start, dv) == "kernel"
+    ts = np.asarray(first_ts, np.int32)
+    by_kernel = by_xla = start
+    by_hand = _leaf_by_head(start, heads, dk, dv)
+    for _ in range(steps):
+        args = _rule_inputs(rng, slots, heads, dk, dv)
+        o_k, by_kernel = dh.kernel_gated_delta_step(
+            *args, by_kernel, jnp.asarray(ts), interpret=True)
+        o_x, by_xla = dh.xla_gated_delta_step(*args, by_xla, jnp.asarray(ts))
+        o_n, by_hand = _numpy_rule(*args, by_hand, ts)
+        live = ts >= 0
+        np.testing.assert_allclose(np.asarray(o_k)[live],
+                                   np.asarray(o_x)[live], rtol=0, atol=2e-6)
+        np.testing.assert_allclose(np.asarray(o_k)[live], o_n[live], rtol=0,
+                                   atol=5e-6)
+        np.testing.assert_allclose(by_kernel, by_xla, rtol=0, atol=5e-6)
+        np.testing.assert_allclose(
+            _leaf_by_head(by_kernel, heads, dk, dv), by_hand, rtol=0,
+            atol=1e-5)
+        # an idle row's state is the bits it was
+        assert np.array_equal(np.asarray(by_kernel)[~live],
+                              np.asarray(start)[~live])
+        ts = np.where(live, ts + 1, ts)
+
+
+@pytest.mark.parametrize("why,backend,dtype,layout,slots,want", [
+    ("a_tpu_and_whole_tiles", "tpu", "float32", "two_heads_a_row", 8,
+     "kernel"),
+    ("a_cpu_backend", "cpu", "float32", "two_heads_a_row", 8, "xla"),
+    ("a_leaf_that_is_not_float32", "tpu", "bfloat16", "two_heads_a_row", 8,
+     "xla"),
+    ("one_head_a_padded_row", "tpu", "float32", "one_head_a_row", 8, "xla"),
+    ("slots_that_are_no_whole_block", "tpu", "float32", "two_heads_a_row", 6,
+     "xla")])
+def test_lowering_chooses_from_what_the_call_can_see(why, backend, dtype,
+                                                     layout, slots, want):
+    import jax
+    import jax.numpy as jnp
+
+    d = dh.dims(tiny_cfg(layout))
+    leaf = jax.ShapeDtypeStruct((slots,) + d.state_shape, jnp.dtype(dtype))
+    assert dh.lowering(backend, leaf, d.dv) == want
+    assert (d.tile_heads == 1) == (layout == "one_head_a_row")
+
+
+def test_olmo_widths_take_sixteen_slots_a_block():
+    """The block is a rule on the shapes: at the cell's leaf (80 slots of
+    147 KB a head pair) the most slots that divide the slots and a lane
+    tile within the budget of a buffer."""
+    assert dh._block_slots(80, 96, 384) == 16
+    assert dh._block_slots(8, 96, 384) == 8
+    assert dh._block_slots(80, 128, 4096) == 0      # a state too large
+    assert dh._block_slots(12, 96, 384) == 0        # no whole sublanes
+
+
+@pytest.mark.parametrize("backend,path", [("cpu", "xla"), ("tpu", "kernel")])
+def test_delta_update_lowered_total_counts_the_form_taken(backend, path,
+                                                          monkeypatch):
+    """A traced step bumps ``delta_update_lowered_total{path}`` once a
+    linear layer, by the form :func:`lowering` chose — and on a TPU at
+    whole tiles the traced program holds the kernel's call."""
+    import jax
+
+    cfg = tiny_cfg()
+    step, make_cache = decoding.make_delta_hybrid_lm_pooled_step_fn(
+        weights(cfg), cfg, kv_dtype="fp32")
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    count = lambda p: dh.LOWERED.labels(path=p).value
+    other = {"xla": "kernel", "kernel": "xla"}[path]
+    before = count(path), count(other)
+    jaxpr = jax.make_jaxpr(step)(make_cache(8, 16), np.zeros(8, np.int32),
+                                 np.zeros(8, np.int32))
+    assert (count(path) - before[0], count(other) - before[1]) == (3, 0)
+    assert (dh.KERNEL_NAME in str(jaxpr)) == (path == "kernel")
+
+
+#: equations of the kernel's body at the cell's shapes: 139 as written,
+#: half as many again allowed (tests/test_decode_attention.py says why a
+#: body is held by a count: what a trace and a lowering cost a process
+#: grows with it, compile cache hit or not)
+_DELTA_KERNEL_EQUATIONS_MAX = 208
+
+
+def test_the_kernel_is_traced_once_and_its_body_stays_small():
+    """A step's linear layers share ONE traced function of the kernel,
+    and its body holds one loop over a block's slots."""
+    import importlib.util
+
+    import jax
+
+    spec = importlib.util.spec_from_file_location(
+        "time_delta_update", os.path.join(ROOT, "tools",
+                                          "time_delta_update.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    f, args = tool.two_layer_program(dh, tool.SHAPE, interpret=False)
+    calls = [e for e in jax.make_jaxpr(f)(*args).jaxpr.eqns
+             if "jaxpr" in e.params
+             and e.params.get("name") == "_delta_update"]
+    assert len(calls) == 2      # one a layer ...
+    # ... of one function traced once
+    assert calls[0].params["jaxpr"] is calls[1].params["jaxpr"]
+    kernels = [e for e in calls[0].params["jaxpr"].jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 1
+    body = tool.equations(kernels[0].params["jaxpr"])
+    assert 40 < body <= _DELTA_KERNEL_EQUATIONS_MAX, body
+
+
+# ---------------------------------------------------------------------------
 # the pool: different leaves a layer, a reused slot, refused tiers
 # ---------------------------------------------------------------------------
 def _pool(cfg, w, len_ladder, **kw):

@@ -360,16 +360,41 @@ def test_lane_masked_attention_makes_no_copy_of_a_rung_on_v5e(
     assert temp(da.grouped_masked_decode_attention) >= copied * leaf
 
 
-def test_delta_rule_layer_compiles_for_v5e_at_olmo_hybrid_widths(one_chip):
-    """80 slots of one gated delta-rule layer at Olmo-Hybrid-7B's widths
-    (30 heads of 96 key and 192 value lanes, bf16 weights, fp32 state):
-    the state leaf is declared two heads a row (384 lanes = 3 tiles), so
-    the tiled layout pads nothing of it — the arguments are the weights,
-    the leaves and the row, to the byte, where ``[80, 30, 96, 192]``
-    would hold a third more — both leaves are aliased in place, no
-    temporary is the size of the 177 MB state (XLA's rule is two passes
-    over it, neither materialised), and the rule carries its scope's
-    name for the trace."""
+def _reads_of(text, shape):
+    """The instructions of a compiled program that take a value of
+    ``shape`` as an operand and do work on it: not the plumbing that
+    only hands it on (parameters, tuples and their elements, bitcasts,
+    the loop itself), and not the inside of a fusion (the fusion is the
+    instruction)."""
+    import re
+
+    plumbing = {"parameter", "tuple", "get-tuple-element", "bitcast",
+                "while", "conditional", "call", "opt-barrier"}
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    rows, inside = [], None
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        made = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.*)", line)
+        if head:
+            inside = head.group(1)
+        elif made and inside not in fused:
+            name, rest = made.groups()
+            depth = end = 0     # the type: one word, or a tuple in brackets
+            while depth or not rest[end].isspace():
+                depth += {"(": 1, ")": -1}.get(rest[end], 0)
+                end += 1
+            op, _, operands = rest[end + 1:].partition("(")
+            rows.append((name, rest[:end], op, operands, line.strip()))
+    holders = {name for name, typ, _, _, _ in rows if typ.startswith(shape)}
+    return [line for _, _, op, operands, line in rows if op not in plumbing
+            and holders & set(re.findall(r"%[\w.\-]+", operands))]
+
+
+def _olmo_delta_layer(one_chip):
+    """Compiled: 80 slots of one gated delta-rule layer at
+    Olmo-Hybrid-7B's widths (30 heads of 96 key and 192 value lanes,
+    bf16 weights, fp32 state), both leaves donated, compiled for the
+    described chip in the form the backend in force gives."""
     import json
     import os
 
@@ -400,8 +425,12 @@ def test_delta_rule_layer_compiles_for_v5e_at_olmo_hybrid_widths(one_chip):
     compiled = jax.jit(f, donate_argnums=(1, 2)).lower(
         w, sd((s,) + d.state_shape), sd((s, d.conv_len - 1, d.d_qkv)),
         sd((s, d.d_model)), sd((s,), jnp.int32)).compile()
-    text = compiled.as_text()
-    assert dh.DELTA_UPDATE_SCOPE in text and dh.SHORT_CONV_SCOPE in text
+    assert dh.DELTA_UPDATE_SCOPE in compiled.as_text()
+    assert dh.SHORT_CONV_SCOPE in compiled.as_text()
+    # whatever implements the rule: both leaves aliased in place, no
+    # temporary the size of the 177 MB state, the arguments the weights,
+    # the leaves and the row to the byte (``[80, 30, 96, 192]`` would hold
+    # a third more: the tiled layout pads 192 lanes to 256)
     mem = compiled.memory_analysis()
     state_leaf = 4 * s * d.lin_heads * d.dk * d.dv
     conv_leaf = 4 * s * (d.conv_len - 1) * d.d_qkv
@@ -411,3 +440,82 @@ def test_delta_rule_layer_compiles_for_v5e_at_olmo_hybrid_widths(one_chip):
     assert mem.argument_size_in_bytes < (weights + state_leaf + conv_leaf
                                          + (2 << 20))
     assert mem.temp_size_in_bytes < state_leaf // 2
+    return compiled
+
+
+def test_delta_rule_layer_compiles_for_v5e_at_olmo_hybrid_widths(one_chip):
+    """The layer as the CPU's backend builds it (the XLA form): the state
+    leaf is declared two heads a row (384 lanes = 3 tiles), so the tiled
+    layout pads nothing of it, both leaves are aliased in place, no
+    temporary is the size of the state, the rule carries its scope's name
+    for the trace — and it is TWO passes over the leaf, neither
+    materialised."""
+    compiled = _olmo_delta_layer(one_chip)
+    reads = _reads_of(compiled.as_text(), "f32[80,15,96,384]")
+    assert len(reads) == 2 and all(" fusion(" in r for r in reads), reads
+
+
+def test_delta_rule_layer_takes_the_kernel_on_v5e_at_olmo_hybrid_widths(
+        one_chip, monkeypatch):
+    """The same layer built for a TPU: the rule is ONE custom call whose
+    operands hold the state leaf as declared (``f32[80,15,96,384]``: what
+    the benchmark's readers find it by), NOTHING else reads a tensor of
+    that shape — the state is read once — both leaves are still aliased
+    in place, no temporary is the size of the state, and the arguments
+    are still unpadded to the byte."""
+    import jax
+
+    from paddle_tpu import delta_hybrid_lm as dh
+
+    # no chip is attached: the answer is given for the backend compiled for
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    count = lambda path: dh.LOWERED.labels(path=path).value
+    before = count("kernel"), count("xla")
+    compiled = _olmo_delta_layer(one_chip)
+    assert (count("kernel") - before[0], count("xla") - before[1]) == (1, 0)
+    reads = _reads_of(compiled.as_text(), "f32[80,15,96,384]")
+    assert len(reads) == 1, reads
+    assert "tpu_custom_call" in reads[0] and dh.KERNEL_NAME in reads[0]
+
+
+def test_olmo_hybrid_chunk_holds_nine_kernel_calls_a_step_on_v5e(
+        one_chip, monkeypatch):
+    """The slot pool's ``chunk`` of ``olmo_hybrid_7b`` (its one rung
+    pair, the published widths, the whole 12-layer cut) as
+    ``tools/decode_chunk_text.py`` builds it for a TPU: nine calls of the
+    kernel a step, one a linear layer, each over a state leaf as
+    declared, no second read of a state leaf, every leaf aliased in
+    place, and the counter says ``kernel`` nine times a traced step and
+    ``xla`` never."""
+    import importlib.util
+    import os
+    import sys
+
+    import jax
+
+    from paddle_tpu import delta_hybrid_lm as dh
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "decode_chunk_text", os.path.join(root, "tools",
+                                          "decode_chunk_text.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    # the tool answers "tpu" for the backend it compiles for and puts
+    # the checkout on the path: both undone when the test ends
+    monkeypatch.setattr(jax, "default_backend", jax.default_backend)
+    monkeypatch.setattr(sys, "path", list(sys.path))
+    count = lambda path: dh.LOWERED.labels(path=path).value
+    before = count("kernel"), count("xla")
+    compiled = tool.lowered_chunk(root, "olmo_hybrid_7b", layers=12).compile()
+    traced = count("kernel") - before[0]
+    assert traced >= 9 and traced % 9 == 0 and count("xla") == before[1]
+    text = compiled.as_text()
+    reads = _reads_of(text, "f32[80,15,96,384]")
+    assert len(reads) == 9, reads
+    assert all("tpu_custom_call" in r and dh.KERNEL_NAME in r for r in reads)
+    mem = compiled.memory_analysis()
+    # nine states, nine conv windows and the three full layers' K and V
+    pool = 9 * 4 * 80 * (15 * 96 * 384 + 3 * 11520) + 6 * 2 * 80 * 1024 * 3840
+    assert mem.alias_size_in_bytes >= pool
+    assert mem.temp_size_in_bytes < 4 * 80 * 15 * 96 * 384 * 4

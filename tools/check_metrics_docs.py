@@ -37,6 +37,7 @@ REGISTERING_MODULES = [
     "paddle_tpu.fused_attention",
     "paddle_tpu.grouped_matmul",
     "paddle_tpu.decode_attention",
+    "paddle_tpu.delta_hybrid_lm",
     "paddle_tpu.decoding",
     "paddle_tpu.reader",
     "paddle_tpu.inference",

@@ -331,7 +331,8 @@ def main():
         fh.write(text)
     kernels = [name for name in (
         "ragged_decode_attention", "grouped_decode_attention",
-        "block_sparse_decode_attention", "grouped_matmul") if name in text]
+        "block_sparse_decode_attention", "grouped_matmul",
+        "gated_delta_update") if name in text]
     print("%s: %d bytes, %s, %d whole-matrix casts to bf16" % (
         args.out, len(text),
         " + ".join(kernels) + " kernel" if kernels else "no kernel",
