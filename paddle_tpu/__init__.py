@@ -13,6 +13,10 @@ Design: a Python graph IR (framework.py) lowers wholesale into single
 jitted XLA modules (core/lowering.py, executor.py); distributed training
 uses jax.sharding meshes + GSPMD instead of NCCL rings (parallel/).
 """
+import time as _time
+
+_T_IMPORT = _time.perf_counter()  # before the first import below
+
 from paddle_tpu import framework
 from paddle_tpu.framework import (
     CPUPlace,
@@ -108,6 +112,14 @@ from paddle_tpu.parallel.strategy import (
 )
 
 __version__ = "0.1.0"
+
+# what importing this package cost the process (jax's own import too,
+# where this import is the first to load jax): of a start-up's import
+# time, the rest is the interpreter, jax and libtpu coming up
+monitor.gauge(
+    "paddle_tpu_import_seconds",
+    "wall seconds the import of the paddle_tpu package took in this "
+    "process").set(_time.perf_counter() - _T_IMPORT)
 
 
 def CUDAPinnedPlace():  # API parity shim

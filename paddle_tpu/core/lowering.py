@@ -11,12 +11,11 @@ vars) is threaded functionally and donated, giving in-place param updates.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from paddle_tpu import compile_cache
 from paddle_tpu.core import registry
 from paddle_tpu.core.registry import EMPTY_VAR_NAME
-from paddle_tpu.monitor import spans as _mon_spans
 
 __all__ = ["lower_block", "trace_ops"]
 
@@ -84,19 +83,21 @@ def lower_block(
 
     def fn(state: Dict[str, Any], feed: Dict[str, Any]):
         # the host-side cost of tracing the whole block through the op
-        # kernels — this runs under jax.jit tracing on the first dispatch
-        # of a cache key, so the span lands nested inside the executor's
-        # jit_compile span (run-phase observability, paddle_tpu/monitor)
-        _t0 = time.perf_counter() if _mon_spans.recording() else None
-        env = dict(state)
-        env.update(feed)
-        trace_ops(ops, env, block)
-        fetches = [env[n] for n in fetch_names]
-        new_state = {n: env[n] for n in state_names if n in env}
-        if _t0 is not None:
-            _mon_spans.record_span(
-                "lowering/trace_block", _t0, time.perf_counter() - _t0,
-                cat="lower", n_ops=len(ops))
+        # kernels.  This body runs only while jax traces it (the first
+        # dispatch of a cache key), never on a steady-state step, so it
+        # always reads the clock: the seconds go to the build record as
+        # stage ``trace`` of the dispatch's ``executor_step`` build (of
+        # no build where jax re-traces a cached entry whose arguments
+        # changed type: the program is named here), and the span nests
+        # inside the build while a sink is live
+        with compile_cache.build_stage("executor_step", "trace",
+                                       span="lowering/trace_block",
+                                       n_ops=len(ops)):
+            env = dict(state)
+            env.update(feed)
+            trace_ops(ops, env, block)
+            fetches = [env[n] for n in fetch_names]
+            new_state = {n: env[n] for n in state_names if n in env}
         return fetches, new_state
 
     return fn

@@ -63,6 +63,7 @@ from typing import Callable, Optional, Tuple
 
 import numpy as np
 
+from paddle_tpu import compile_cache
 from paddle_tpu.monitor import registry as _registry
 
 __all__ = [
@@ -497,10 +498,12 @@ def _pooled_lm_parts(state, d_model, n_layer, n_head, name, kv_dtype):
         copied = [k for k in _multiplied_matrices(name, n_layer)
                   if W[k].dtype == jnp.float32]
         # each copy where its original lives, whatever its sharding
-        W.update(zip(copied, jax.jit(
-            lambda ws: [w.astype(jnp.bfloat16) for w in ws],
-            out_shardings=[W[k].sharding for k in copied])(
-                [W[k] for k in copied])))
+        with compile_cache.build("weight_copies", rest="first_run",
+                                 matrices=len(copied)):
+            W.update(zip(copied, jax.jit(
+                lambda ws: [w.astype(jnp.bfloat16) for w in ws],
+                out_shardings=[W[k].sharding for k in copied])(
+                    [W[k] for k in copied])))
         WEIGHT_COPIES.inc(len(copied))
     scale = 1.0 / float(np.sqrt(d_head))
 

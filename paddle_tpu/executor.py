@@ -29,6 +29,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from paddle_tpu import compile_cache
 from paddle_tpu import framework
 from paddle_tpu import faults as _faults
 from paddle_tpu.core import lowering
@@ -634,8 +635,6 @@ class Executor:
             stats["misses"] += 1
             block = program.global_block()
             state_out = plan.state_out
-            if _rec:
-                _t0 = time.perf_counter()
             fn = lowering.lower_block(block, feed_names, fetch_names, state_out)
             if compiled is not None:
                 # a compiled program's block traces under a marker that
@@ -707,13 +706,6 @@ class Executor:
                     )
                 )
             entry = jax.jit(stepfn, **jit_kwargs)
-            if _rec:
-                # closure construction only; the block actually traces
-                # inside the first dispatch (the lowering/trace_block
-                # span nested in executor/jit_compile below)
-                _mon_spans.record_span(
-                    "executor/lower", _t0, time.perf_counter() - _t0,
-                    cat="lower", n_ops=len(block.ops))
             if use_program_cache:
                 self._cache[key] = entry
 
@@ -745,7 +737,16 @@ class Executor:
             _MON_DISPATCH_HIST.observe(
                 _overhead, exemplar={"trace_id": _ids[0]} if _ids else None)
             _t0 = time.perf_counter()
-        fetches, new_state = entry(mut_state, ro_state, feed_arrays)
+        if first_dispatch:
+            # jax.jit is lazy: a novel cache key's first dispatch is
+            # where the block traces (lowering/trace_block), XLA builds
+            # or loads the module (the build record's listener) and the
+            # program runs once; the rest of its wall is first_run
+            with compile_cache.build("executor_step", rest="first_run",
+                                     steps=steps):
+                fetches, new_state = entry(mut_state, ro_state, feed_arrays)
+        else:
+            fetches, new_state = entry(mut_state, ro_state, feed_arrays)
         # hot-path: end dispatch (the jitted call is async; everything
         # below is allowed to sync)
         if _rec:

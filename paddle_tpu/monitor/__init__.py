@@ -11,7 +11,7 @@ paths:
   endpoint).  Every subsystem registers at import and increments on the
   hot path (a lock + an add; always on).
 * **Run-phase spans** (``spans.py``) — Executor.run emits per-phase
-  spans (lower / jit_compile on first dispatch per cache key / h2d feed
+  spans (jit_compile on first dispatch per cache key / h2d feed
   transfer / device execute / d2h fetch), RecordEvent blocks mirror in,
   serving batches ride the profiler JSONL stream.  Recording is
   opt-in; when off, instrumentation is a single flag check.

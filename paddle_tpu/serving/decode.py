@@ -61,7 +61,9 @@ dispatch carried: a turn seats everything it pops in one, and
 pool's lowering put on the device once so that no dispatch sends them
 again, and ``serving_decode_idle_drops_total`` beside the
 ``serving/pool_dropped`` event, one per drop of an idle server's pool
-state) on top of the standard ``ServingMetrics`` series; the
+state, and its twin ``serving/pool_placed`` beside
+``serving_pool_state_seconds_total``, one per fresh state brought to
+the device) on top of the standard ``ServingMetrics`` series; the
 ``decode.step`` fault point injects failures into the tick dispatch for
 chaos coverage.  While a span sink is live every scheduler turn is ONE
 ``serving/decode_tick`` span (it carries the active requests' trace
@@ -213,6 +215,26 @@ DECODE_IDLE_DROPS = monitor.counter(
     "wait dropped its pool state (the device memory goes; whoever "
     "arrives next pays for a fresh one); each also leaves a "
     "serving/pool_dropped event", _LABELS)
+# the pool state's birth and the warm-up's wall are a process's history,
+# read after a server stopped (the benchmark's set-up readers): by
+# server alone, and not retired with the instance's series at stop()
+POOL_STATE_SECONDS = monitor.counter(
+    "serving_pool_state_seconds_total",
+    "seconds a fresh pool state cost, at start-up and after every idle "
+    "drop: stage alloc is KVSlotPool.alloc (zeros on the host), stage "
+    "place runs from its end until the first turn over that state has "
+    "delivered (the executable that first takes the zeros carries them "
+    "to the device), less what that stretch spent building executables",
+    ("server", "stage"))
+POOL_STATE_BYTES_PLACED = monitor.counter(
+    "serving_pool_state_bytes_placed_total",
+    "bytes of the fresh pool states those seconds brought to the device "
+    "(every leaf of the state); each also leaves a serving/pool_placed "
+    "event", ("server",))
+WARMUP_SECONDS = monitor.gauge(
+    "serving_warmup_seconds",
+    "wall seconds of the server's last DecodeServer.warmup that built "
+    "anything (a re-warm that builds nothing leaves it)", ("server",))
 DECODE_RECURRENT_BYTES = monitor.gauge(
     "serving_recurrent_state_bytes",
     "bytes of the pool's recurrent cache leaves (no sequence axis) at "
@@ -597,6 +619,10 @@ class DecodeServer:
         self._stop = threading.Event()
         self._warmed = False
         self._state = None               # tick-thread owned pool state
+        # a fresh state not yet delivered over: (perf_counter at the end
+        # of its alloc, the alloc's seconds, its bytes, the tick
+        # thread's build seconds then); None once its birth is booked
+        self._born = None
         self._slots: List[Optional[_Slot]] = []
         self._worker = threading.Thread(
             target=self._loop, name="serving-decode-%s" % name, daemon=True)
@@ -766,9 +792,18 @@ class DecodeServer:
         """Pre-compile chunk/admit/release for every (slot, length) rung
         pair; arms the recompile counter (any executable built after
         this increments ``metrics()['recompiles']``)."""
-        if configure_cache:
-            compile_cache.configure()
-        compiles = self._pool.warmup()
+        t0 = time.perf_counter()
+        with _mon_spans.parent_scope() as span_id:
+            if configure_cache:
+                compile_cache.configure()
+            compiles = self._pool.warmup()
+        wall = time.perf_counter() - t0
+        if compiles:
+            WARMUP_SECONDS.labels(server=self.name).set(wall)
+        _mon_spans.record_span(
+            "serving/warmup", t0, wall, cat="serving", span_id=span_id,
+            server=self.name, compiles=compiles,
+            rung_pairs=len(self._pool.rung_pairs()))
         self._count_constants_placed()
         self._metrics.count("warmup_compiles", compiles)
         self._warmed = True
@@ -955,12 +990,48 @@ class DecodeServer:
         freed = (self._pool.kv_rung_bytes(*rungs)
                  + self._pool.recurrent_rung_bytes(*rungs))
         self._state = None
+        self._born = None
         self._slots = []
         self._set_pool_bytes(None)
         self._idle_drops_c.inc()
         _events.emit("serving/pool_dropped", cat="serving",
                      server=self.name, bytes=int(freed),
                      idle_s=float(idle_s))
+
+    def _alloc_state(self, s: int, t: int):
+        """A fresh pool state, its birth timed: ``alloc``'s zeros on the
+        host here; what carrying them to the device costs is known only
+        when the first turn over them has delivered
+        (:meth:`_pool_placed`)."""
+        import jax
+
+        t0 = time.perf_counter()
+        state = self._pool.alloc(s, t)
+        t1 = time.perf_counter()
+        POOL_STATE_SECONDS.labels(server=self.name, stage="alloc").inc(
+            t1 - t0)
+        self._born = (t1, t1 - t0,
+                      sum(leaf.nbytes for leaf in jax.tree.leaves(state)),
+                      compile_cache.thread_build_seconds())
+        return state
+
+    def _pool_placed(self, now: float) -> None:
+        """The first turn over a fresh state has delivered (``now`` is
+        the stamp of its tokens, after the tick's fetch): the state is
+        on the device.  The twin of :meth:`_drop_idle_pool`'s event — a
+        far-off run can be asked what bringing 12 GB back cost."""
+        t_alloc_end, alloc_s, nbytes, built0 = self._born
+        self._born = None
+        # an unwarmed server builds its executables inside this stretch:
+        # those seconds are the build record's
+        place_s = max(0.0, now - t_alloc_end - (
+            compile_cache.thread_build_seconds() - built0))
+        POOL_STATE_SECONDS.labels(server=self.name, stage="place").inc(
+            place_s)
+        POOL_STATE_BYTES_PLACED.labels(server=self.name).inc(nbytes)
+        _events.emit("serving/pool_placed", cat="serving",
+                     server=self.name, bytes=int(nbytes),
+                     alloc_s=float(alloc_s), place_s=float(place_s))
 
     def _admit_pending(self, turn: Optional[_Turn]) -> None:
         """Seat queued prompts into free slots with ONE device dispatch
@@ -1030,9 +1101,9 @@ class DecodeServer:
             if (new_s, new_t) != cur:
                 if cur is None:
                     self._expert_seen = None   # a fresh state counts from 0
-                self._state = (
-                    pool.alloc(new_s, new_t) if cur is None
-                    else pool.resize(self._state, new_s, new_t))
+                    self._state = self._alloc_state(new_s, new_t)
+                else:
+                    self._state = pool.resize(self._state, new_s, new_t)
                 self._slots.extend([None] * (new_s - len(self._slots)))
                 self._set_pool_bytes((new_s, new_t))
             seats = [self._admit_with_prefix(*seat) if seat[2] > 0 else seat
@@ -1241,6 +1312,8 @@ class DecodeServer:
             # sees its positions counted too
             self._ticks_c.inc()
         now = time.perf_counter()
+        if self._born is not None:
+            self._pool_placed(now)
         released: List[int] = []
         for i, rec in recs:
             n_gen = int(view["n_gen"][i])
@@ -1489,6 +1562,7 @@ class DecodeServer:
         next admission starts from a fresh one — keep serving."""
         self._fail_in_flight(exc)
         self._state = None
+        self._born = None   # never delivered over: no birth to book
         self._slots = []
         self._set_pool_bytes(None)
 

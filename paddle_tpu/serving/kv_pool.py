@@ -93,6 +93,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from paddle_tpu import compile_cache
 from paddle_tpu.serving.bucketing import BucketPolicy
 
 __all__ = ["KVSlotPool", "default_len_ladder"]
@@ -592,35 +593,60 @@ class KVSlotPool:
         The state argument is DONATED so the KV cache updates in place —
         except on CPU, where donation + the persistent compile cache is
         known-unsafe (executor._donate_kwargs pins the policy).  The
-        constants are never donated: they outlive every state."""
+        constants are never donated: they outlive every state.
+
+        The four stages in a row — the trace (where every Pallas body
+        is walked), the lowering, the compile or cache load, the
+        placing — are each a ``compile_cache.build_stage`` of one
+        ``compile_cache.build``: their seconds are in
+        ``program_build_seconds_total{program=kind}`` whether or not
+        anything records."""
         import functools
 
         import jax
 
         from paddle_tpu.executor import _donate_kwargs
 
-        closed, out_shape = jax.make_jaxpr(
-            getattr(self, "_%s_fn" % kind), return_shape=True)(*arg_specs)
-        out_tree = jax.tree.structure(out_shape)
+        spec = arg_specs[0]  # the state's: its rung pair names the build
+        with compile_cache.build(
+                kind, rungs=list(spec["tokens"].shape)) as built:
+            with compile_cache.build_stage(kind, "trace"):
+                closed, out_shape = jax.make_jaxpr(
+                    getattr(self, "_%s_fn" % kind),
+                    return_shape=True)(*arg_specs)
+            out_tree = jax.tree.structure(out_shape)
 
-        def hoisted(consts, *args):
-            return jax.tree.unflatten(out_tree, jax.core.eval_jaxpr(
-                closed.jaxpr, consts, *jax.tree.leaves(args)))
+            def hoisted(consts, *args):
+                return jax.tree.unflatten(out_tree, jax.core.eval_jaxpr(
+                    closed.jaxpr, consts, *jax.tree.leaves(args)))
 
-        hoisted.__name__ = kind  # names the XLA module and cache entry
-        donate = ({"donate_argnums": (1,)}  # the state, after consts
-                  if donate and _donate_kwargs(jax.devices()[0]) else {})
-        exe = jax.jit(hoisted, **donate).lower(
-            closed.consts, *arg_specs).compile()
-        wanted = exe.input_shardings[0][0]  # of ``consts``, one each
-        host_born = [np.asarray(c).nbytes for c in closed.consts
-                     if not isinstance(c, jax.Array)]
-        with self._lock:
-            self._host_born[kind] = max(self._host_born.get(kind, (0, 0)),
-                                        (len(host_born), sum(host_born)))
-        return functools.partial(exe, [
-            c if isinstance(c, jax.Array) else self._place(c, sharding)
-            for c, sharding in zip(closed.consts, wanted)])
+            hoisted.__name__ = kind  # names the XLA module and cache entry
+            donate = ({"donate_argnums": (1,)}  # the state, after consts
+                      if donate and _donate_kwargs(jax.devices()[0]) else {})
+            with compile_cache.build_stage(kind, "lower"):
+                lowered = jax.jit(hoisted, **donate).lower(
+                    closed.consts, *arg_specs)
+            with compile_cache.build_stage(kind, "compile"):
+                exe = lowered.compile()
+            with compile_cache.build_stage(kind, "place"):
+                wanted = exe.input_shardings[0][0]  # of ``consts``, one each
+                host_born = [np.asarray(c).nbytes for c in closed.consts
+                             if not isinstance(c, jax.Array)]
+                with self._lock:
+                    self._host_born[kind] = max(
+                        self._host_born.get(kind, (0, 0)),
+                        (len(host_born), sum(host_born)))
+                consts = [
+                    c if isinstance(c, jax.Array)
+                    else self._place(c, sharding)
+                    for c, sharding in zip(closed.consts, wanted)]
+            if built.traced:
+                built.args.update(
+                    equations=compile_cache.equations(closed.jaxpr),
+                    constants=len(consts),
+                    constant_bytes=int(sum(c.nbytes for c in consts)),
+                    host_born=len(host_born))
+        return functools.partial(exe, consts)
 
     def _place(self, const, sharding):
         """The pool's ONE device copy of the host-born constant

@@ -133,8 +133,14 @@ def test_executor_phase_spans_and_jit_counters():
     assert names.count("executor/device_execute") == 1
     assert names.count("executor/h2d_feed") == 2
     assert names.count("executor/d2h_fetch") == 2
-    assert "executor/lower" in names
-    assert "lowering/trace_block" in names  # the in-jit trace of the block
+    # the first dispatch is one build: the in-jit trace of the block
+    # and XLA's compile (or cache load) hang under build/executor_step
+    assert names.count("build/executor_step") == 1
+    (build,) = [s for s in sess.spans if s["name"] == "build/executor_step"]
+    inside = {s["name"] for s in sess.spans
+              if s.get("parent") == build["id"]}
+    assert "lowering/trace_block" in inside
+    assert inside & {"build/compile", "build/cache_load"}
     for s in sess.spans:
         assert s["dur"] >= 0 and "ts" in s and "tid" in s
     # registry counters move in lockstep with the executor's own stats
@@ -308,9 +314,10 @@ def test_merged_chrome_trace_lenet_train_plus_serving(tmp_path):
     events = data["traceEvents"]
     names = {e["name"] for e in events}
     # the distinct run phases, all in ONE file
-    assert {"executor/lower", "executor/jit_compile", "executor/device_execute",
-            "executor/h2d_feed", "executor/d2h_fetch",
-            "lowering/trace_block"} <= names
+    assert {"build/executor_step", "executor/jit_compile",
+            "executor/device_execute", "executor/h2d_feed",
+            "executor/d2h_fetch", "lowering/trace_block"} <= names
+    assert names & {"build/compile", "build/cache_load"}
     # RecordEvent spans (serving warmup/batch) merged in
     assert "serving/traced/warmup" in names
     # the JSONL stream (serving.batch discrete events) merged in

@@ -32,7 +32,22 @@ take as many array arguments as the cell's):
   served the same tokens;
 * a profiler trace over a few of each, reduced to the host events that
   ran on the calling thread inside the calls (argument handling, the
-  h2d of host arguments, the runtime's ``Execute``), by name.
+  h2d of host arguments, the runtime's ``Execute``), by name;
+* ``build_table`` (PR 52), printed as a table right after the build and
+  kept in the last line: per program (the pool's executable kinds,
+  ``weight_copies``, ``unscoped``: the family's jitted weight draw) the
+  seconds of each stage — ``trace``, ``lower``, ``compile``,
+  ``cache_load``, ``place``, ``first_run`` — from
+  ``program_build_seconds_total``, the executables built
+  (``program_builds_total``: hit / miss / off), and from the
+  ``build/<program>`` spans of the same builds the jaxpr's equations and
+  the kernel sites walked (which ``*_lowered_total{path}`` moved during
+  the trace); ``booked_share`` is the stage seconds of the pool's kinds
+  over the wall of ``pool.warmup()``.  ``--build-only`` stops there (a
+  set-up ``perf_opt``'s loop: no timed call, any decode family);
+  ``--no-record`` builds with no span sink live (the table then has the
+  counters' columns only): a recorded start-up against one that is not
+  is what the recording costs.
 
     python tools/time_pool_dispatch.py gpt1_117m
     python tools/time_pool_dispatch.py gpt1_117m --repo .parent_copy \\
@@ -140,6 +155,12 @@ def build_step(root, config, rehearse):
         build, parts = family.builder()
         weights = family.make_weights(cfg, dev, parts)
         step_fn, make_cache = build(weights, cfg, kv_dtype=sv["kv_dtype"])
+    elif cfg["family"] == "pooled_windowed_routed_lm":
+        build, parts = family.builder()
+        weights = family.make_weights(cfg, dev, parts)
+        step_fn, make_cache, _ = build(
+            weights, cfg, kv_dtype=sv["kv_dtype"],
+            prefill_tokens=int(sv["prefill_tokens"]))
     else:
         sys.exit("time_pool_dispatch: no builder for family %r"
                  % cfg["family"])
@@ -166,9 +187,54 @@ def host_born_constants(pool, s, t):
     return out
 
 
+def build_table(monitor, build_spans):
+    """``{program: {stage: seconds, "builds": {cache: n}, "equations":
+    [...], "kernels": {site: n}}}`` from the build record's two counters
+    and (where a sink was live) its ``build/<program>`` spans."""
+    snap = monitor.snapshot()
+    table = collections.defaultdict(dict)
+    for series in snap.get("program_build_seconds_total",
+                           {"series": []})["series"]:
+        lbl = series["labels"]
+        table[lbl["program"]][lbl["stage"]] = series["value"]
+    for series in snap.get("program_builds_total", {"series": []})["series"]:
+        lbl = series["labels"]
+        table[lbl["program"]].setdefault("builds", {})[lbl["cache"]] = int(
+            series["value"])
+    for sp in build_spans:
+        program = sp["name"][len("build/"):]
+        if program not in table or "equations" not in sp.get("args", {}):
+            continue
+        row = table[program]
+        row.setdefault("equations", []).append(sp["args"]["equations"])
+        for site, n in sp["args"].get("kernels", {}).items():
+            kernels = row.setdefault("kernels", {})
+            kernels[site] = kernels.get(site, 0) + n
+    return dict(table)
+
+
+def print_build_table(table, stages):
+    print("%-14s %s  builds  equations  kernel sites walked" % (
+        "program", " ".join("%10s" % st for st in stages)))
+    for program, row in sorted(table.items()):
+        print("%-14s %s  %-6s  %-9s  %s" % (
+            program,
+            " ".join("%10.3f" % row.get(st, 0.0) for st in stages),
+            "+".join("%d%s" % (n, c[0]) for c, n in sorted(
+                row.get("builds", {}).items())) or "-",
+            ",".join(str(n) for n in row.get("equations", [])) or "-",
+            " ".join("%s=%d" % kv for kv in sorted(
+                row.get("kernels", {}).items())) or "-"))
+    print("%-14s %s" % ("all", " ".join(
+        "%10.3f" % sum(row.get(st, 0.0) for row in table.values())
+        for st in stages)), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("config")
+    ap.add_argument("--build-only", action="store_true")
+    ap.add_argument("--no-record", action="store_true")
     ap.add_argument("--repo", default=None)
     ap.add_argument("--batch", type=int, default=6)
     ap.add_argument("--reps", type=int, default=40)
@@ -192,8 +258,16 @@ def main():
     dev = jax.devices()[0]
     if not args.rehearse_cpu and dev.platform != "tpu":
         sys.exit("time_pool_dispatch: needs the chip (or --rehearse-cpu)")
+    # the build's own spans, where the checkout has a build record
+    record = not args.no_record and monitor.REGISTRY.get(
+        "program_build_seconds_total") is not None
+    if record:
+        monitor.start_recording()
+    t_build0 = time.perf_counter()
     cfg, weights, step_fn, make_cache = build_step(
         root, args.config, args.rehearse_cpu)
+    jax.block_until_ready(weights)
+    weights_s = time.perf_counter() - t_build0
     sv, vocab = cfg["serving"], int(cfg["vocab_size"])
     s, t = sv["slot_ladder"][-1], sv["len_ladder"][-1]
     snapshots = getattr(make_cache, "prefill_fn", None) is not None
@@ -204,6 +278,27 @@ def main():
     t0 = time.perf_counter()
     pool.warmup()
     warm_s = time.perf_counter() - t0
+    build_spans = [sp for sp in (monitor.stop_recording() if record else [])
+                   if sp["name"].startswith("build/")]
+    table = build_table(monitor, build_spans)
+    stages = ("trace", "lower", "compile", "cache_load", "place",
+              "first_run")
+    built = {"config": args.config, "recorded": record,
+             "device": {"platform": dev.platform, "kind": dev.device_kind},
+             "weights_and_step_s": weights_s, "warmup_s": warm_s,
+             "build_spans": len(build_spans),
+             "booked_share": (sum(
+                 v for kind in pool._kinds()
+                 for st, v in table.get(kind, {}).items() if st in stages)
+                 / warm_s if table else None),
+             "build_table": table}
+    if table:
+        print_build_table(table, stages)
+    if args.build_only:
+        if args.rehearse_cpu:
+            print("REHEARSAL on the CPU at tiny sizes: NOT device numbers.")
+        print(json.dumps(built))
+        return
     rng = np.random.RandomState(0)
 
     def prompt():
@@ -342,6 +437,7 @@ def main():
            "device": {"platform": dev.platform, "kind": dev.device_kind},
            "rung_pair": [s, t], "batch": len(batch),
            "state_and_weight_arrays": n_args, "warmup_s": warm_s,
+           "booked_share": built["booked_share"], "build_table": table,
            "host_born_constants": host_born_constants(pool, s, t),
            "constants_placed": getattr(pool, "constants_placed", None),
            "weight_copies": monitor.counter_value(
