@@ -75,9 +75,12 @@ Three implementations of the read-what-is-live contract, chosen by
   heads (``rep > 1``) over unquantized sequence leaves, bf16 or fp32,
   whose heads are whole lane tiles (``Dh`` a multiple of 128), ``K >=
   1`` fresh rows a slot (``K`` is read from ``q``'s shape; at ``K = 1``
-  the program is the one-row kernel's).  The same ragged read from the
+  the program is the one-row kernel's) — and for ONE query head a K/V
+  head (``rep = 1``) over bf16 leaves of such heads at one fresh row
+  (``olmo_hybrid``'s full layers).  The same ragged read from the
   same planner with its own sizes (:func:`step_read_sizes`: blocks of
-  ``_GROUPED_BLOCK`` positions, a slot's last one in
+  ``_GROUPED_BLOCK`` positions, fewer where a leaf's slab of them would
+  pass ``_GROUPED_SLAB`` bytes, a slot's last one in
   ``_GROUPED_CLASSES`` classes), made for the slot's LAST fresh row
   (:func:`last_fresh_row`); the append left to :func:`append_rows`'
   in-place scatter before it.  An item is BOTH leaves' whole-width
@@ -90,8 +93,13 @@ Three implementations of the read-what-is-live contract, chosen by
   tile in VMEM only), each masked at its own position — products in the
   storage dtype, fp32 accumulation and online softmax.  How many heads
   one product scores is a parameter of the q layout (a head's lane
-  offset and width) that follows from the shape (:func:`_unit_heads`);
-  heads narrower than a lane tile (two of 64 lanes a tile) are the same
+  offset and width) that follows from the shape (:func:`_unit_heads`).
+  A head with ONE query row is one ROW of its unit (:func:`_head_rows`:
+  the unit's heads consecutive rows, padded to a sublane tile once a
+  unit and not once a head, each row's own ``Dh`` lanes filled by the
+  kernel from a ``[heads, Dh]`` operand, the context written back a row
+  a head): the same block-diagonal product at an eighth of the rows.
+  Heads narrower than a lane tile (two of 64 lanes a tile) are the same
   kernel, not shipped yet.
 * :func:`grouped_masked_decode_attention` — the contract whole, as plain
   XLA ops (scatter append + masked softmax over the whole T axis):
@@ -99,11 +107,12 @@ Three implementations of the read-what-is-live contract, chosen by
   fp32 accumulation and softmax.  The CPU path, the path of every step
   no kernel covers (int8 leaves, ring leaves, ``K > 1`` over leaves of
   one query head a K/V head, and — at one row through
-  :func:`lane_masked_decode_attention` on a TPU — grouped heads narrower
-  than a lane tile and ONE query head a K/V head over bf16 leaves,
-  ``olmo_hybrid``'s full layers: the whole rung is read whatever of it
-  is live, and ``decode_attention_ungrouped_lowered_total{path}`` counts
-  the form), and the parity reference of tests/test_decode_attention.py.
+  :func:`lane_masked_decode_attention` on a TPU — heads narrower than a
+  lane tile, grouped or over bf16 leaves one query head a K/V head: the
+  whole rung is read whatever of it is live;
+  ``decode_attention_grouped_lowered_total{path}`` and
+  ``decode_attention_ungrouped_lowered_total{path}`` count the form),
+  and the parity reference of tests/test_decode_attention.py.
 
 ``jax.experimental.pallas`` is imported inside the kernel builder only:
 ``import paddle_tpu`` and the training cells never pay for it.
@@ -165,10 +174,12 @@ UNGROUPED_LOWERED = _registry.REGISTRY.counter(
     "decode_attention_ungrouped_lowered_total",
     "appends-and-reads of ONE query head per K/V head over SEQUENCE "
     "leaves lowered (traced into a program or run eagerly), by the "
-    "lowering chosen: kernel (Pallas TPU ragged_decode_attention: fp32 "
-    "leaves, one fresh row a slot, what is live) | xla (a masked softmax "
-    "over the whole rung: int8 leaves, K rows, the CPU; bf16 leaves, on "
-    "a TPU at one row through the form that reads them as they lie)",
+    "lowering chosen: kernel (Pallas TPU, one fresh row a slot, what is "
+    "live: ragged_decode_attention over fp32 leaves, "
+    "grouped_decode_attention over bf16 leaves of whole-lane-tile heads) "
+    "| xla (a masked softmax over the whole rung: int8 leaves, K rows, "
+    "the CPU; bf16 leaves no kernel takes, on a TPU at one row through "
+    "the form that reads them as they lie)",
     ("path",))
 
 #: the sequence axis of every K/V leaf (and scale sibling)
@@ -204,6 +215,14 @@ _SCORE_ROWS = 4096
 #: items, 512 reads 0.160-0.168 ms for 1024's 0.183 (finer rounding) and
 #: 256's 0.182 (more items): chip runs, PR 44, tools/time_grouped_decode.py
 _GROUPED_BLOCK = 512
+#: bytes one leaf's slab of a block may hold; a wider leaf's block is
+#: halved until it does (:func:`step_read_sizes`).  Where a slab is
+#: megabytes an item's fixed cost no longer shows and the finer rounding
+#: of a shorter block wins: ``[80,1024,3840]`` bf16 (30 one-row heads,
+#: contexts ~330) reads 0.638 ms a call in blocks of 256 (2.0 MB a slab,
+#: 1.055 of the live bytes) for 0.669 in blocks of 512 (3.9 MB, 1.109),
+#: whatever the heads a unit (chip run, PR 53, tools/time_grouped_decode.py)
+_GROUPED_SLAB = 2 << 20
 #: classes a slot's last block is read in (its ``tail`` is the block over
 #: them, 64 rows: the rounding of what a step of the grouped kernel
 #: reads, 1.003 of the live positions at 10.7k contexts, 1.10 at ~330);
@@ -280,17 +299,24 @@ def step_read_sizes(seq_len: int, width: int, dtype, *, n_head: int,
     (a slot's blocks before its last whole, its last in classes of
     ``tail`` rows: :func:`kv_positions_read`), or None where that step
     is not the kernel's: the backend (``jax.default_backend()`` unsaid)
-    no TPU, no grouping, a head's lanes no whole lane tiles, a dtype
-    other than bf16 and fp32, a rung its block does not divide."""
+    no TPU, a head's lanes no whole lane tiles, a dtype other than bf16
+    and fp32, a rung its block does not divide, or ONE query head a K/V
+    head over leaves that are not bf16 (fp32 ones are
+    :func:`ragged_decode_attention`'s: bit-exact fp32 products)."""
     import jax
     import jax.numpy as jnp
 
-    block = min(int(seq_len), _GROUPED_BLOCK)
+    block = _GROUPED_BLOCK
+    while (block * width * jnp.dtype(dtype).itemsize > _GROUPED_SLAB
+           and block > 16 * _GROUPED_CLASSES):
+        block //= 2
+    block = min(int(seq_len), block)
     tail = block // _GROUPED_CLASSES
     if ((backend or jax.default_backend()) != "tpu"
-            or not 0 < n_kv_head < n_head or n_head % n_kv_head
+            or not 0 < n_kv_head <= n_head or n_head % n_kv_head
             or width % n_kv_head or (width // n_kv_head) % _HEAD_LANES
             or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32)
+            or (n_kv_head == n_head and jnp.dtype(dtype) != jnp.bfloat16)
             or seq_len % block or block % _GROUPED_CLASSES or tail % 16):
         return None
     return block, tail
@@ -301,8 +327,9 @@ def step_positions_read(ts, seq_len: int, **leaves):
     of a slot's sequence leaves (``leaves``: what :func:`step_read_sizes`
     takes after the rung): the grouped kernel's rounding where it serves
     them, else the whole rung (an XLA form).  What a builder of grouped
-    heads declares as ``make_cache.kv_positions_read`` for the server's
-    counter (a ``K``-row round: at :func:`last_fresh_row`)."""
+    heads, or of one query head a K/V head over bf16 leaves, declares as
+    ``make_cache.kv_positions_read`` for the server's counter (a
+    ``K``-row round: at :func:`last_fresh_row`)."""
     sizes = step_read_sizes(seq_len, **leaves)
     if sizes is None:
         return np.full_like(ts, seq_len)
@@ -1306,7 +1333,7 @@ def _block_sparse(q, k_cache, v_cache, ts, blocks, valid, *, n_head,
 def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
                     ts_ref,                                     # SMEM
                     q_ref,                                      # VMEM
-                    *refs, block, tail, heads, fresh):
+                    *refs, block, tail, heads, head_rows, fresh):
     """The work list's items one after another, the reads of the next
     ``ahead`` in flight: an item is BOTH leaves' whole-width ``[rows,
     n_kv_head * Dh]`` slabs, one copy each, scored a UNIT of ``heads``
@@ -1319,6 +1346,13 @@ def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
     its own ``Dh`` lanes filled and the unit's other lanes zero, so one
     product scores the unit's heads and one more weighs their rows (the
     zeros add exact zeros; a head keeps its own lanes of the result).
+    ``head_rows`` (static, :func:`_head_rows`) is ``rep_p``.  At ONE row
+    a head the unit's heads are consecutive rows and ``q_ref`` is ``[S,
+    units, R, Dh]``: the rows are laid into their lanes here (a select
+    over ``heads`` copies side by side: 60 vector selects an item at 30
+    heads, where the wide rows as an operand were 20 MB of VMEM and 4-6%
+    of a call), and the context goes out ``[S, units, R, Dh]``, row ``h``
+    its head's own lanes of the weighted rows.
 
     ``fresh`` (static) is ``K``, the fresh rows a slot: row ``r`` of a
     head is the slot's fresh row ``r // rep`` and reads the positions
@@ -1350,8 +1384,9 @@ def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
     (k_hbm, v_hbm,                                              # HBM (ANY)
      o_ref,                                                     # output
      kbuf, vbuf, m_ref, l_ref, acc_ref, sem) = refs[fresh > 1:]
-    _, units, R, L = q_ref.shape
-    rep_p, D = o_ref.shape[2:]
+    rep_p, D = head_rows, o_ref.shape[-1]
+    _, units, R, _ = q_ref.shape
+    L = heads * D
     T = k_hbm.shape[1]
     nbuf = kbuf.shape[0]
     ahead = nbuf - 1
@@ -1397,20 +1432,38 @@ def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
         def unit(u, carry):
             lanes = slice(None) if units == 1 else pl.ds(
                 pl.multiple_of(u * L, L), L)
+            q = q_ref[n, u]
+            if q.shape[1] < L:      # [R, D]: head h's row, its lanes alone
+                lane = jax.lax.broadcasted_iota(jnp.int32, (R, L), 1)
+                row = jax.lax.broadcasted_iota(jnp.int32, (R, L), 0) * D
+                q = jnp.where((lane >= row) & (lane < row + D),
+                              jnp.concatenate([q] * heads, axis=1),
+                              jnp.zeros((), q.dtype))
             m, l, acc = _block_part(
-                q_ref[n, u], kbuf[buf, :, lanes], vbuf[buf, :, lanes], ok,
+                q, kbuf[buf, :, lanes], vbuf[buf, :, lanes], ok,
                 m_ref[u, :, :1], l_ref[u, :, :1], acc_ref[u])
             m_ref[u] = jnp.broadcast_to(m, (R, _HEAD_LANES))
             l_ref[u] = jnp.broadcast_to(l, (R, _HEAD_LANES))
             acc_ref[u] = acc
 
-            @pl.when(b == last // block)
-            def _():
+            def head_tiles():
                 for h in range(heads):  # a head's own rows and lanes
                     rows = slice(h * rep_p, (h + 1) * rep_p)
                     o_ref[n, u * heads + h] = (
                         acc[rows, h * D:(h + 1) * D] / l[rows])
 
+            def unit_rows():        # one row a head: row h, its own lanes
+                row = jax.lax.broadcasted_iota(jnp.int32, (R, D), 0)
+
+                def head_row(h, ctx):
+                    return jnp.where(row == h, acc_ref[u, :, pl.ds(
+                        pl.multiple_of(h * D, D), D)], ctx)
+
+                o_ref[n, u] = jax.lax.fori_loop(
+                    0, heads, head_row, jnp.zeros((R, D), jnp.float32)) / l
+
+            pl.when(b == last // block)(
+                head_tiles if rep_p > 1 else unit_rows)
             return carry
 
         if units == 1:
@@ -1438,7 +1491,8 @@ def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
 def grouped_decode_attention(q, k_cache, v_cache, ts, work, *, n_head: int,
                              n_kv_head: int, scale: float, block: int,
                              tail: int, interpret=False):
-    """The Pallas TPU kernel of read-what-is-live for grouped heads over
+    """The Pallas TPU kernel of read-what-is-live for grouped heads —
+    and for ONE query head a K/V head at one fresh row — over
     unquantized sequence leaves (see the module docstring).
 
     ``q`` ``[S, n_head * Dh]`` fp32 (one fresh row a slot, at ``ts``) or
@@ -1483,12 +1537,28 @@ def _grouped_call():
         "interpret"))
 
 
+def _head_rows(fresh: int, rep: int) -> int:
+    """Query rows a K/V head takes of a unit of the grouped kernel: its
+    ``fresh * rep`` (fresh row, query head) pairs, fresh row major, in
+    whole fp32 tiles — or ONE where it has one (one query head a K/V
+    head, one fresh row): the heads of a unit are then consecutive rows,
+    padded to a tile once a unit, and the context comes back a row a
+    head."""
+    return 1 if fresh * rep == 1 else -(-fresh * rep // 8) * 8
+
+
 def _unit_heads(n_kv_head: int, head_rows: int, heads: int = 0) -> int:
     """K/V heads the grouped kernel scores in one product: ``heads``
     where it is said and divides them (the tool's experiments), else as
     many — a divisor of ``n_kv_head`` — as keep a unit's query rows
-    (``head_rows`` a head: ``K * rep`` in whole sublane tiles) within
-    :data:`_GROUPED_UNIT_ROWS`."""
+    (``head_rows`` a head, :func:`_head_rows`) within
+    :data:`_GROUPED_UNIT_ROWS`.  One-row heads: every head of up to 64
+    in ONE unit — at 30 heads of 128 the copies set the pace whatever the
+    unit (0.637-0.654 ms a call at 5 / 6 / 10 / 15 / 30 heads a unit) and
+    the arithmetic alone falls with the units (0.573 / 0.502 / 0.401 /
+    0.366 / 0.329: a block's chain once a unit), so the widest unit
+    leaves the most room behind the copies (chip run, PR 53,
+    tools/time_grouped_decode.py)."""
     if heads and n_kv_head % heads == 0:
         return heads
     return max(h for h in range(1, n_kv_head + 1) if n_kv_head % h == 0
@@ -1507,9 +1577,7 @@ def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
     K = 1 if q.ndim == 2 else q.shape[1]    # fresh rows a slot
     dt, f32, i32 = k_cache.dtype, jnp.float32, jnp.int32
     size = jnp.dtype(dt).itemsize
-    # a head's rows: its K * rep (fresh row, query head) pairs, fresh row
-    # major, in whole fp32 tiles
-    rep_p = -(-K * rep // 8) * 8
+    rep_p = _head_rows(K, rep)
     heads = _unit_heads(G, rep_p, heads)                # K/V heads a unit
     units, L = G // heads, heads * D
     sub = 32 // size                    # rows of the leaves' sublane tile
@@ -1517,10 +1585,12 @@ def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
     qg = (q * scale).astype(dt).reshape(S, K, units, heads, rep, D)
     qg = jnp.moveaxis(qg, 1, 3).reshape(S, units, heads, K * rep, D)
     qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, rep_p - K * rep), (0, 0)))
-    if heads > 1:   # head h of a unit: its own D lanes of the unit's L
+    if heads > 1 and rep_p > 1:
+        # head h of a unit: its own D lanes of the unit's L (one-row
+        # heads: the kernel lays a unit's [heads, D] rows out itself)
         own = np.eye(heads, dtype=bool)[:, None, :, None]
         qg = jnp.where(own, qg[:, :, :, :, None, :], jnp.zeros((), dt))
-    qg = jnp.pad(qg.reshape(S, units, heads * rep_p, L),
+    qg = jnp.pad(qg.reshape(S, units, heads * rep_p, -1),
                  ((0, 0), (0, 0), (0, R - heads * rep_p), (0, 0)))
     # which fresh row each of a unit's query rows is (rows of padding:
     # the last), across a lane tile: an operand where there are several
@@ -1531,14 +1601,16 @@ def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     nbuf = ahead + 1
+    # the context: a head's rows, or at one row a head a unit's rows
+    out = (S, G, rep_p, D) if rep_p > 1 else (S, units, R, D)
     # bytes the kernel keeps in VMEM: q and the context whole, the slabs
     # being read and scored, a block's scores a few times over
-    resident = (qg.size * size + 4 * S * G * rep_p * D
+    resident = (qg.size * size + 4 * int(np.prod(out))
                 + 2 * nbuf * block * Dkv * size + 4 * 4 * R * block)
     ctx = pl.pallas_call(
         functools.partial(_grouped_kernel, block=block, tail=tail,
-                          heads=heads, fresh=K),
-        out_shape=jax.ShapeDtypeStruct((S, G, rep_p, D), f32),
+                          heads=heads, head_rows=rep_p, fresh=K),
+        out_shape=jax.ShapeDtypeStruct(out, f32),
         in_specs=[smem] * 5 + [vmem] * (1 + len(fresh_of)) + [hbm] * 2,
         out_specs=vmem,
         scratch_shapes=[
@@ -1555,6 +1627,8 @@ def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
         name="grouped_decode_attention",
         interpret=interpret,
     )(*work, ts.astype(i32), qg, *fresh_of, k_cache, v_cache)
+    if rep_p == 1:
+        return ctx[:, :, :heads].reshape(q.shape)
     ctx = ctx[:, :, :K * rep].reshape(S, G, K, rep, D)
     return jnp.moveaxis(ctx, 2, 1).reshape(q.shape)
 
@@ -1684,15 +1758,16 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
     exists for what the step is — the default backend a TPU, unquantized
     leaves, and either one query head per K/V head over fp32 leaves of a
     shape :func:`ragged_decode_attention` lowers for and one fresh row
-    per slot, or grouped heads that are whole lane tiles over bf16 /
-    fp32 leaves (:func:`step_read_sizes`:
-    :func:`grouped_decode_attention`, one fresh row or ``K``) — and an
-    XLA form otherwise: on a TPU, for one row of grouped heads narrower
-    than a lane tile over unquantized leaves or of one query head per
-    K/V head over bf16 leaves, the one that reads the leaves as they lie
-    (:func:`lane_masked_decode_attention`), else
-    :func:`grouped_masked_decode_attention` (int8 leaves, ``K`` rows
-    where no kernel takes them, every CPU run).  A grouped-head step
+    per slot, or heads that are whole lane tiles
+    (:func:`step_read_sizes`: :func:`grouped_decode_attention` — grouped
+    heads over bf16 / fp32 leaves, one fresh row or ``K``; one query
+    head per K/V head over bf16 leaves, one fresh row) — and an XLA form
+    otherwise: on a TPU, for one row over unquantized leaves of grouped
+    heads narrower than a lane tile, or over bf16 leaves of one query
+    head per K/V head that the kernel's sizes do not fit, the one that
+    reads the leaves as they lie (:func:`lane_masked_decode_attention`),
+    else :func:`grouped_masked_decode_attention` (int8 leaves, ``K``
+    rows where no kernel takes them, every CPU run).  A grouped-head step
     over sequence leaves counts itself in
     ``decode_attention_grouped_lowered_total{path}``, a ``K``-row one
     also in ``decode_attention_rows_lowered_total{leaf}``, a step of one
@@ -1710,25 +1785,25 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
     if window is not None:
         return functools.partial(xla, window=int(window))
     tpu = jax.default_backend() == "tpu" and "k_scale" not in kv
+    sizes = step_read_sizes(
+        seq_len, width, kv["k"].dtype, n_head=n_head,
+        n_kv_head=n_kv_head) if tpu else None
+    works = {}      # the work list of a K-row read, made once a K
+
+    def kernel(q, k_new, v_new, kv):
+        fresh = 1 if q.ndim == 2 else q.shape[1]
+        if fresh not in works:
+            works[fresh] = decode_work_items(
+                last_fresh_row(ts, fresh, seq_len), seq_len, *sizes)
+        if fresh > 1:
+            ROWS_LOWERED.labels(leaf="sequence").inc()
+        kv = append_rows(kv, k_new, v_new, ts)
+        return grouped_decode_attention(
+            q, kv["k"], kv["v"], ts, works[fresh], n_head=n_head,
+            n_kv_head=n_kv_head, scale=scale, block=sizes[0],
+            tail=sizes[1]), kv
+
     if n_kv_head < n_head:
-        sizes = step_read_sizes(
-            seq_len, width, kv["k"].dtype, n_head=n_head,
-            n_kv_head=n_kv_head) if tpu else None
-        works = {}      # the work list of a K-row read, made once a K
-
-        def kernel(q, k_new, v_new, kv):
-            fresh = 1 if q.ndim == 2 else q.shape[1]
-            if fresh not in works:
-                works[fresh] = decode_work_items(
-                    last_fresh_row(ts, fresh, seq_len), seq_len, *sizes)
-            if fresh > 1:
-                ROWS_LOWERED.labels(leaf="sequence").inc()
-            kv = append_rows(kv, k_new, v_new, ts)
-            return grouped_decode_attention(
-                q, kv["k"], kv["v"], ts, works[fresh], n_head=n_head,
-                n_kv_head=n_kv_head, scale=scale, block=sizes[0],
-                tail=sizes[1]), kv
-
         one_row = xla
         if sizes is None and tpu and (width // n_kv_head) % _HEAD_LANES:
             # narrower than a lane tile: a view of the leaf by heads
@@ -1749,19 +1824,26 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
     if ragged:
         block = kv_read_block(seq_len)
         work = decode_work_items(ts, seq_len, block)
+    elif sizes is not None:
+        # bf16 leaves of whole-lane-tile heads: the grouped kernel's read
+        # with a head's ONE query row a row of a unit
+        one_row = kernel
     elif tpu and kv["k"].dtype == jnp.bfloat16:
-        # with ONE query row a K/V head the compiler takes the score
-        # product of the per-head view off the matrix unit and first
-        # copies each leaf to float32 in another layout (2 x a leaf of
-        # temporaries a leaf and step): read the leaves as they lie
+        # no kernel's shape (heads narrower than a lane tile, a rung the
+        # block does not divide).  With ONE query row a K/V head the
+        # compiler takes the score product of the per-head view off the
+        # matrix unit and first copies each leaf to float32 in another
+        # layout (2 x a leaf of temporaries a leaf and step): read the
+        # leaves as they lie
         one_row = lane
 
     def attend(q, k_new, v_new, kv):
-        # K fresh rows per slot: no kernel yet
-        kernel = ragged and q.ndim == 2
-        UNGROUPED_LOWERED.labels(path="kernel" if kernel else "xla").inc()
-        if not kernel:
-            return (one_row if q.ndim == 2 else xla)(q, k_new, v_new, kv)
+        one = q.ndim == 2       # K fresh rows per slot: no kernel yet
+        UNGROUPED_LOWERED.labels(
+            path="kernel" if one and (ragged or one_row is kernel)
+            else "xla").inc()
+        if not (ragged and one):
+            return (one_row if one else xla)(q, k_new, v_new, kv)
         ctx, k, v = ragged_decode_attention(
             q, k_new, v_new, kv["k"], kv["v"], ts, work,
             n_head=n_head, scale=scale, block=block)

@@ -1594,10 +1594,13 @@ def make_delta_hybrid_lm_pooled_step_fn(state, cfg, name: str = "lm",
     * a full layer ``k``, ``v`` ``[N, T, n_kv_head * head_dim]`` in
       ``kv_dtype`` (``k`` after its norm), ``decode_attention``'s format
       through ``make_decode_attention`` — ONE query head per K/V head in
-      ``olmo_hybrid``, so over bf16 leaves an XLA form that reads the
-      whole rung (on a TPU the one that reads the leaves as they lie;
-      ``decode_attention_ungrouped_lowered_total{path}`` says which form
-      a program took); covered by write-before-read;
+      ``olmo_hybrid``: over bf16 leaves on a TPU the grouped kernel's
+      read of what is live, a head one row of a unit (heads of whole
+      lane tiles, a rung its block divides; the server's read counter
+      is told that rounding: ``make_cache.kv_positions_read``), else an
+      XLA form that reads the whole rung
+      (``decode_attention_ungrouped_lowered_total{path}`` says which
+      form a program took); covered by write-before-read;
     * a linear layer ``state`` ``[N, H / g, dk, g * dv]`` float32 (``g``
       heads side by side in the lanes: ``delta_hybrid_lm.heads_per_
       tile``) and ``conv`` ``[N, K - 1, 2 H dk + H dv]`` float32:

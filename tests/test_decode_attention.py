@@ -618,14 +618,16 @@ def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
     and reads the leaves as they lie where they are 64 wide (lfm2); K
     fresh rows over whole-lane-tile heads take the same kernel (a
     self-drafting round's verify: k_exaone); one query head a K/V head
-    over bf16 leaves (olmo_hybrid's full layers) reads them as they lie
-    too at one row (the per-head view would be copied to float32); int8
-    leaves, a rung the kernel's block does not divide, K rows of 64-wide
-    heads or of one query head a K/V head, ring leaves and every CPU run
-    keep the grouped XLA form.  Every grouped-head step over sequence
-    leaves counts itself by the path it took, a K-row one also by its
-    leaf, a step of one query head a K/V head in a counter of its own; a
-    ring step never counts a path."""
+    over bf16 leaves of whole-lane-tile heads (olmo_hybrid's full layers)
+    takes that kernel too at one row — a head one row of a unit — and
+    where the heads are 64 wide or the block does not divide the rung it
+    reads the leaves as they lie (the per-head view would be copied to
+    float32); int8 leaves, a rung the kernel's block does not divide, K
+    rows of 64-wide heads or of one query head a K/V head, ring leaves
+    and every CPU run keep the grouped XLA form.  Every grouped-head step
+    over sequence leaves counts itself by the path it took, a K-row one
+    also by its leaf, a step of one query head a K/V head in a counter of
+    its own; a ring step never counts a path."""
     import jax
     import jax.numpy as jnp
 
@@ -683,13 +685,31 @@ def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
                                          {"kernel": 0, "xla": 1})
     # not grouped, or a ring: no grouped count, and never that kernel
     nothing = {"kernel": 0, "xla": 0}
-    ungrouped = da.UNGROUPED_LOWERED.labels(path="xla").value
+
+    def ungrouped():
+        return [da.UNGROUPED_LOWERED.labels(path=path).value
+                for path in ("kernel", "xla")]
+
+    by_kernel, by_xla = ungrouped()
     assert took(narrow, (2, 2)) == (["lane"], nothing)
-    assert took(wide, (2, 2)) == (["lane"], nothing)
+    assert took(wide, (2, 2)) == (["kernel"], nothing)
+    assert took(da.kv_leaves(2, 2 * da._GROUPED_BLOCK, 6, 128, jnp.bfloat16),
+                (6, 6)) == (["kernel"], nothing)
+    assert ungrouped() == [by_kernel + 2, by_xla + 1]
+    assert took(da.kv_leaves(2, da._GROUPED_BLOCK + 128, 2, 128,
+                             jnp.bfloat16), (2, 2)) == (["lane"], nothing)
     assert took(wide, (2, 2), rows=3) == (["grouped"], nothing)
     assert took(da.kv_leaves(2, 128, 2, 128, jnp.int8), (2, 2)) == (
         ["grouped"], nothing)
-    assert da.UNGROUPED_LOWERED.labels(path="xla").value == ungrouped + 4
+    assert ungrouped() == [by_kernel + 2, by_xla + 4]
+    # fp32 leaves of one query head a K/V head: the ragged kernel's
+    ragged = []
+    monkeypatch.setattr(
+        da, "ragged_decode_attention",
+        lambda q, kn, vn, k, v, *a, **kw: ragged.append(1) or (q, k, v))
+    assert took(da.kv_leaves(2, 128, 2, 128, jnp.float32), (2, 2)) == (
+        [], nothing)
+    assert ragged == [1] and ungrouped() == [by_kernel + 3, by_xla + 4]
     ring = da.kv_leaves(2, 128, 2, 128, jnp.bfloat16, window=64)
     assert took(ring, (8, 2), window=64) == (["grouped"], nothing)
     assert took(ring, (8, 2), rows=3, window=64)[1] == nothing
@@ -717,14 +737,20 @@ GROUPED_TS = {
     "edges": [-1, 0, GB - 1, GB, GB + 1, 2 * GB - 1, GT - 1, GT, GT + 1],
     "all_idle": [-1] * 4,
     "one_live": [-1, GB + 40, -1, -1],
+    # a rung of ONE block: idle, 0, a class's edge, the rung's end
+    "one_block_rung": [-1, 0, GT - 1, GT, GB - GT, GB - 1],
 }
+#: (query heads a K/V head, K/V heads): the grouped cells' groupings at
+#: four heads, and ONE query head a K/V head — a head one row of a unit —
+#: at 30 heads (olmo_hybrid's: no power of two), 2 and 6
+GROUPINGS = [(5, 4), (7, 4), (8, 4), (1, 30), (1, 2), (1, 6)]
 
 
-def _grouped_case(rep, dtype, ts, g=4, dh=128):
+def _grouped_case(rep, dtype, ts, g=4, dh=128, t=2 * GB):
     import jax.numpy as jnp
 
     rng = np.random.RandomState(5)
-    s, t = len(ts), 2 * GB
+    s = len(ts)
     q = jnp.asarray(rng.randn(s, g * rep * dh), jnp.float32)
     kn, vn = (jnp.asarray(rng.randn(s, g * dh), jnp.float32)
               for _ in range(2))
@@ -735,19 +761,23 @@ def _grouped_case(rep, dtype, ts, g=4, dh=128):
 
 @pytest.mark.parametrize("case", sorted(GROUPED_TS))
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("rep", [5, 7, 8])
-def test_grouped_kernel_matches_the_grouped_form(rep, dtype, case):
+@pytest.mark.parametrize("rep,g", GROUPINGS)
+def test_grouped_kernel_matches_the_grouped_form(rep, g, dtype, case):
     """The append and the kernel's read against the XLA form: the same
     context (fp32: the order of the sums differs; bf16: the weights are
     rounded before they are normalised, not after), bit-equal leaves,
-    zero rows for idle slots."""
-    q, kn, vn, kv, ts, kw = _grouped_case(rep, dtype, GROUPED_TS[case])
+    zero rows for idle slots — for grouped heads and for ONE query head
+    a K/V head (the heads of a unit consecutive rows), over a rung of two
+    blocks and of one."""
+    t = GB if case == "one_block_rung" else 2 * GB
+    q, kn, vn, kv, ts, kw = _grouped_case(rep, dtype, GROUPED_TS[case], g=g,
+                                          t=t)
     want, kv_want = da.grouped_masked_decode_attention(q, kn, vn, kv, ts,
                                                        **kw)
     kv_got = da.append_rows(kv, kn, vn, ts)
     got = da.grouped_decode_attention(
         q, kv_got["k"], kv_got["v"], ts,
-        da.decode_work_items(ts, 2 * GB, GB, GT), block=GB, tail=GT,
+        da.decode_work_items(ts, t, GB, GT), block=GB, tail=GT,
         interpret=True, **kw)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
                                atol=2e-6 if dtype == "float32" else 2e-2)
@@ -757,14 +787,16 @@ def test_grouped_kernel_matches_the_grouped_form(rep, dtype, case):
     assert not np.asarray(got)[np.asarray(ts) < 0].any()
 
 
-@pytest.mark.parametrize("heads", [1, 2])
+@pytest.mark.parametrize("rep,g,heads", [(7, 4, 1), (7, 4, 2), (1, 6, 1),
+                                         (1, 6, 3)])
 def test_grouped_kernel_scores_any_number_of_heads_in_one_product(
-        heads, monkeypatch):
+        rep, g, heads, monkeypatch):
     """The K/V heads a unit holds are a parameter of the q layout alone
     (a head's lane offset and width): one, two or all four heads a
-    product give the same context."""
-    q, kn, vn, kv, ts, kw = _grouped_case(7, "bfloat16",
-                                          GROUPED_TS["edges"])
+    product give the same context, and so do one, three or all six where
+    a head is ONE row of its unit."""
+    q, kn, vn, kv, ts, kw = _grouped_case(rep, "bfloat16",
+                                          GROUPED_TS["edges"], g=g)
     kv = da.append_rows(kv, kn, vn, ts)
     args = (q, kv["k"], kv["v"], ts, da.decode_work_items(ts, 2 * GB, GB, GT))
     named = dict(block=GB, tail=GT, interpret=True, **kw)
@@ -907,6 +939,36 @@ def test_unit_width_follows_from_the_shape():
     assert da._unit_heads(8, 2 * rows) == 1
     assert da._unit_heads(8, 16, 8) == 8 and da._unit_heads(8, 16, 2) == 2
     assert da._unit_heads(8, 16, 3) == da._unit_heads(8, 16)
+    # ONE query row a head: a head is a row, so olmo_hybrid's 30 heads
+    # are one unit (grouped heads and K rows keep whole fp32 tiles a head)
+    assert [da._head_rows(k, r) for k, r in ((1, 1), (1, 5), (1, 8), (2, 8),
+                                             (2, 1))] == [1, 8, 8, 16, 8]
+    assert da._unit_heads(30, da._head_rows(1, 1)) == 30
+    assert da._unit_heads(2 * rows, 1) == rows
+    assert da._unit_heads(30, 1, 6) == 6
+
+
+def test_block_follows_from_the_slab_a_leaf_holds():
+    """Blocks of ``_GROUPED_BLOCK`` positions where a leaf's slab of
+    them is within ``_GROUPED_SLAB`` bytes (every grouped cell: 512
+    lanes of bf16 at Falcon and smallthinker, 1,024 at K-EXAONE), halved
+    until it is where the leaf is wider (olmo_hybrid's 3,840 lanes:
+    256), never under a tail of 16 rows; the tail is the block over the
+    classes."""
+    def sizes(width, g, rep=1, dtype="bfloat16", seq_len=1024):
+        return da.step_read_sizes(seq_len, width, dtype, n_head=g * rep,
+                                  n_kv_head=g, backend="tpu")
+
+    block, classes = da._GROUPED_BLOCK, da._GROUPED_CLASSES
+    for width, g, rep in ((512, 4, 5), (512, 4, 7), (1024, 8, 8)):
+        assert sizes(width, g, rep, seq_len=4096) == (block, block // classes)
+    assert sizes(3840, 30) == (256, 256 // classes)
+    assert 256 * 3840 * 2 <= da._GROUPED_SLAB < 512 * 3840 * 2
+    assert sizes(1024, 8, 8, "float32") == (block, block // classes)
+    assert sizes(2048, 16, 4, "float32") == (block // 2, block // 2 // classes)
+    # however wide: whole sublane tiles of bf16 a class
+    assert sizes(128 * 1024, 1024, 2) == (16 * classes, 16)
+    assert sizes(3840, 30, seq_len=128) == (128, 128 // classes)
 
 
 @pytest.mark.parametrize("seq_len", [1024, 16384, 512])
@@ -937,10 +999,18 @@ def test_grouped_work_list_reads_what_kv_positions_read_says(seq_len):
                                   **leaves).tolist() == want[1:].tolist()
     assert da.step_positions_read(live, seq_len, **leaves).tolist() == [
         seq_len] * len(live)
-    # no kernel for narrow heads, no grouping, int8, a ragged rung
-    for change in (dict(width=256), dict(n_head=4), dict(dtype="int8")):
+    # no kernel for narrow heads, int8, a ragged rung, nor for ONE query
+    # head a K/V head over fp32 leaves (the ragged kernel's) — over bf16
+    # ones it is the same read
+    for change in (dict(width=256), dict(dtype="int8"),
+                   dict(n_head=4, dtype="float32"), dict(n_head=2)):
         assert da.step_read_sizes(seq_len, backend="tpu",
                                   **{**leaves, **change}) is None
+    assert da.step_read_sizes(seq_len, backend="tpu",
+                              **{**leaves, "n_head": 4}) == (block, tail)
+    assert da.step_positions_read(
+        live, seq_len, backend="tpu",
+        **{**leaves, "n_head": 4}).tolist() == want[1:].tolist()
     assert da.step_read_sizes(da._GROUPED_BLOCK + 128, backend="tpu",
                               **leaves) is None
 
@@ -952,7 +1022,8 @@ def _two_grouped_layers(sharding=None, shape="smallthinker"):
     ``tools/time_grouped_decode.py --build`` builds them; ``shape``
     ``k_exaone``: a self-drafting round's two rung-long leaves (128
     slots x 4,096 x 1,024 bf16, 8 query heads a K/V head, TWO fresh rows
-    a slot)."""
+    a slot); ``olmo``: two of ``olmo_hybrid_7b``'s full layers (80 slots
+    x 1,024 x 3,840 bf16, 30 K/V heads, ONE query head each)."""
     import importlib.util
     import os
     import sys
@@ -981,12 +1052,14 @@ def _two_grouped_layers(sharding=None, shape="smallthinker"):
 _GROUPED_KERNEL_EQUATIONS_MAX = 384
 
 
-@pytest.mark.parametrize("shape", ["smallthinker", "k_exaone"])
+@pytest.mark.parametrize("shape", ["smallthinker", "k_exaone", "olmo"])
 def test_grouped_kernel_is_traced_once_and_its_body_stays_small(shape):
     """A step's two global layers — a self-drafting round's two
-    rung-long leaves, read at two fresh rows a slot — share ONE traced
-    function whose body holds one loop over the items, a class switch
-    for the starts and one for the waits, and ONE scoring routine."""
+    rung-long leaves, read at two fresh rows a slot; two full layers of
+    one query head a K/V head — share ONE traced function whose body
+    holds one loop over the items, a class switch for the starts and one
+    for the waits, and ONE scoring routine (30 one-row heads add a loop
+    over a unit's rows, not a line a head)."""
     import jax
 
     f, args, equations = _two_grouped_layers(shape=shape)

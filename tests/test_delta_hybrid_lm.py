@@ -551,24 +551,35 @@ def test_a_config_this_builder_cannot_serve_is_refused_by_name():
 # ---------------------------------------------------------------------------
 # the chooser's count of the form one query head a K/V head lowered to
 # ---------------------------------------------------------------------------
-def test_the_full_layers_count_the_form_they_lowered():
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_the_full_layers_count_the_form_they_lowered(backend, monkeypatch):
     """``make_decode_attention`` counts a step of one query head a K/V
-    head over sequence leaves by the form it took: on the CPU the XLA
-    form, once a full layer of a traced step; the read rule the builder
-    declares for the server's counter is then the whole rung."""
+    head over sequence leaves by the form it took, once a full layer of
+    a traced step, and the read rule the builder declares for the
+    server's counter follows the same choice: on the CPU the XLA form
+    and the whole rung; built for a TPU over bf16 leaves of
+    whole-lane-tile heads the grouped kernel (a head one row of a unit)
+    and its rounding — a rung of one block in classes of an eighth."""
     import jax
 
-    cfg = tiny_cfg(layer_types=[dh.LINEAR, dh.FULL] * 2)
+
+    tpu = backend == "tpu"
+    if tpu:     # no chip here: the answer is given for the backend built for
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = tiny_cfg(layer_types=[dh.LINEAR, dh.FULL] * 2,
+                   **(dict(head_dim=128) if tpu else {}))
     w = weights(cfg)
     step, make_cache = decoding.make_delta_hybrid_lm_pooled_step_fn(
         w, cfg, kv_dtype="bf16")
     count = lambda path: da.UNGROUPED_LOWERED.labels(path=path).value
     before = count("xla"), count("kernel")
-    jax.eval_shape(step, make_cache(2, 16), np.zeros(2, np.int32),
+    rung = 128 if tpu else 16
+    jax.eval_shape(step, make_cache(2, rung), np.zeros(2, np.int32),
                    np.zeros(2, np.int32))
-    assert (count("xla") - before[0], count("kernel") - before[1]) == (2, 0)
-    assert make_cache.kv_positions_read(np.array([0, 7, 15]), 16).tolist() \
-        == [16, 16, 16]
+    assert (count("xla") - before[0], count("kernel") - before[1]) == (
+        (0, 2) if tpu else (2, 0))
+    read = make_cache.kv_positions_read(np.array([0, 7, 15, rung - 1]), rung)
+    assert read.tolist() == ([16, 16, 16, 128] if tpu else [rung] * 4)
 
 
 # ---------------------------------------------------------------------------

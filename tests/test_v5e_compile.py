@@ -158,6 +158,44 @@ def test_grouped_kernel_compiles_for_v5e_at_k_exaone_widths(one_chip):
     assert mem.temp_size_in_bytes < leaf // 8
 
 
+def test_grouped_kernel_compiles_for_v5e_at_olmo_hybrid_widths(one_chip):
+    """80 slots x 1,024 positions x 30 K/V heads of 128, ONE query head
+    each, bf16 (two of ``olmo_hybrid_7b``'s full layers): the two
+    append-and-reads lower to ONE kernel called twice over the leaves as
+    they lie, the 30 heads one unit of 32 rows handed over ``[heads,
+    Dh]`` (not as rows 3,840 lanes wide), in blocks of 256 — and the
+    chip's compiler grants the VMEM the kernel asks for, which stays
+    under a third of the chip's (the wide-row operand and blocks of 512
+    asked for 90 MB)."""
+    import re
+
+    import jax
+
+    f, args, _ = _two_grouped_layers(one_chip, "olmo")
+    assert args[0].shape == (80, 30 * 128)
+    assert da.step_read_sizes(1024, 3840, "bfloat16", n_head=30,
+                              n_kv_head=30, backend="tpu") == (256, 32)
+    lowered = jax.jit(f, donate_argnums=(3, 4, 5, 6)).trace(*args).lower()
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert len(re.findall(r"call @_grouped\b", text)) == 2
+    asked = [int(n) for n in re.findall(
+        r'scoped_memory_configs[^\]]*?size[^0-9]*([0-9]+)', text)]
+    assert asked and max(asked) < 40 << 20, asked
+    compiled = lowered.compile()        # raises where the chip would
+    lines = compiled.as_text().splitlines()
+    calls = [line for line in lines
+             if "grouped_decode_attention" in line and "custom-call(" in line]
+    assert len(calls) == 2 and all("bf16[80,1024,3840]" in c for c in calls)
+    assert all("bf16[80,1,32,128]" in c for c in calls)     # q: units, R, Dh
+    assert not [line for line in lines
+                if " copy(" in line and "[80,1024," in line]
+    leaf = 80 * 1024 * 3840 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * leaf
+    assert mem.temp_size_in_bytes < leaf // 8
+
+
 def test_block_kernel_is_lowered_once_for_two_layers_on_v5e(one_chip):
     """Lowered for the chip, two sparse layers are two calls of ONE
     function that holds the ONE kernel of the module."""
@@ -478,15 +516,13 @@ def test_delta_rule_layer_takes_the_kernel_on_v5e_at_olmo_hybrid_widths(
     assert "tpu_custom_call" in reads[0] and dh.KERNEL_NAME in reads[0]
 
 
-def test_olmo_hybrid_chunk_holds_nine_kernel_calls_a_step_on_v5e(
-        one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def olmo_chunk(one_chip):
     """The slot pool's ``chunk`` of ``olmo_hybrid_7b`` (its one rung
     pair, the published widths, the whole 12-layer cut) as
-    ``tools/decode_chunk_text.py`` builds it for a TPU: nine calls of the
-    kernel a step, one a linear layer, each over a state leaf as
-    declared, no second read of a state leaf, every leaf aliased in
-    place, and the counter says ``kernel`` nine times a traced step and
-    ``xla`` never."""
+    ``tools/decode_chunk_text.py`` builds it for a TPU, compiled ONCE for
+    the tests below: ``(text, memory analysis, counted)`` — ``counted``
+    what the trace added to the two lowering counters, by path."""
     import importlib.util
     import os
     import sys
@@ -501,21 +537,66 @@ def test_olmo_hybrid_chunk_holds_nine_kernel_calls_a_step_on_v5e(
                                           "decode_chunk_text.py"))
     tool = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(tool)
+
+    def counts():
+        return {(name, path): c.labels(path=path).value
+                for name, c in (("delta", dh.LOWERED),
+                                ("full", da.UNGROUPED_LOWERED))
+                for path in ("kernel", "xla")}
+
     # the tool answers "tpu" for the backend it compiles for and puts
-    # the checkout on the path: both undone when the test ends
-    monkeypatch.setattr(jax, "default_backend", jax.default_backend)
-    monkeypatch.setattr(sys, "path", list(sys.path))
-    count = lambda path: dh.LOWERED.labels(path=path).value
-    before = count("kernel"), count("xla")
-    compiled = tool.lowered_chunk(root, "olmo_hybrid_7b", layers=12).compile()
-    traced = count("kernel") - before[0]
-    assert traced >= 9 and traced % 9 == 0 and count("xla") == before[1]
-    text = compiled.as_text()
+    # the checkout on the path: both undone when the chunk is compiled
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", jax.default_backend)
+        patch.setattr(sys, "path", list(sys.path))
+        before = counts()
+        compiled = tool.lowered_chunk(root, "olmo_hybrid_7b",
+                                      layers=12).compile()
+        counted = {k: v - before[k] for k, v in counts().items()}
+    return compiled.as_text(), compiled.memory_analysis(), counted
+
+
+def test_olmo_hybrid_chunk_holds_nine_kernel_calls_a_step_on_v5e(olmo_chunk):
+    """Nine calls of the delta rule's kernel a step, one a linear layer,
+    each over a state leaf as declared, no second read of a state leaf,
+    every leaf aliased in place, and the counter says ``kernel`` nine
+    times a traced step and ``xla`` never."""
+    from paddle_tpu import delta_hybrid_lm as dh
+
+    text, mem, counted = olmo_chunk
+    traced = counted["delta", "kernel"]
+    assert traced >= 9 and traced % 9 == 0 and not counted["delta", "xla"]
     reads = _reads_of(text, "f32[80,15,96,384]")
     assert len(reads) == 9, reads
     assert all("tpu_custom_call" in r and dh.KERNEL_NAME in r for r in reads)
-    mem = compiled.memory_analysis()
     # nine states, nine conv windows and the three full layers' K and V
     pool = 9 * 4 * 80 * (15 * 96 * 384 + 3 * 11520) + 6 * 2 * 80 * 1024 * 3840
     assert mem.alias_size_in_bytes >= pool
     assert mem.temp_size_in_bytes < 4 * 80 * 15 * 96 * 384 * 4
+
+
+def test_olmo_hybrid_chunk_reads_the_full_layers_by_the_kernel_on_v5e(
+        olmo_chunk):
+    """The same chunk's three full layers (ONE query head a K/V head
+    over bf16 leaves): THREE calls of the attention kernel a step, each
+    over both ``bf16[80,1024,3840]`` leaves as they lie; nothing
+    float32 of a leaf's shape anywhere in the program (the per-head XLA
+    form copied each leaf to one, the lane form held scores over the
+    whole rung), what else touches a leaf is its append; every K/V leaf
+    is still aliased in place; the counter says ``kernel`` three times a
+    traced step and ``xla`` never."""
+    text, mem, counted = olmo_chunk
+    traced = counted["full", "kernel"]
+    assert traced >= 3 and traced % 3 == 0 and not counted["full", "xla"]
+    calls = [line for line in text.splitlines()
+             if "grouped_decode_attention" in line and "custom-call(" in line]
+    assert len(calls) == 3, len(calls)
+    assert all(c.count("bf16[80,1024,3840]") >= 2 for c in calls)
+    assert "f32[80,1024,3840]" not in text and "f32[80,30,1024]" not in text
+    reads = _reads_of(text, "bf16[80,1024,3840]")
+    others = [r for r in reads if "grouped_decode_attention" not in r]
+    assert len(reads) - len(others) == 3
+    assert len(others) == 6 and all(      # the append: a row a leaf
+        " scatter(" in r or " fusion(" in r or "dynamic-update-slice(" in r
+        for r in others), others
+    assert mem.alias_size_in_bytes >= 6 * 80 * 1024 * 3840 * 2

@@ -5,7 +5,7 @@ chip: the Pallas kernel (``paddle_tpu/decode_attention.py``:
 (``grouped_masked_decode_attention``), turn and turn about in one
 process over the same leaves.
 
-Three shapes (``--shape``, all unless said), every slot's position drawn
+Four shapes (``--shape``, all unless said), every slot's position drawn
 as the cell's traffic file leaves them (a prompt or a document and a
 question, then a step drawn evenly over the answer's life):
 
@@ -17,7 +17,12 @@ question, then a step drawn evenly over the answer's life):
 * ``k_exaone`` — ``k_exaone_236b_a23b.long_answers_mtp_4k``'s global
   layer and module: 128 slots x 4,096 x 1,024, 8 K/V heads of 128, 8
   query heads a K/V head, TWO fresh rows a slot (a self-drafting
-  round's verify, and its module's pass).
+  round's verify, and its module's pass);
+* ``olmo`` — ``olmo_hybrid_7b.long_answers_batch``'s full layers: 80
+  slots x 1,024 x 3,840, 30 K/V heads of 128, ONE query head each (a
+  head is one row of a unit: ``--heads`` 5 / 6 / 10 / 15 / 30 a unit);
+  the comparison and parity reference is the form that reads the leaves
+  as they lie, ``lane_masked_decode_attention``, over the whole rung.
 
 ``--rows K`` hands every slot of every shape ``K`` fresh rows (row ``j``
 at ``ts + j``) instead of its cell's: the XLA form of ``K`` rows is the
@@ -38,10 +43,13 @@ rows ``<= ts``) and on the bytes the form READS (the kernel: what
 ``--repo`` loads ``paddle_tpu/decode_attention.py`` from another checkout
 (several may be given: all run in this one process, so they share the
 chip and its clock); a checkout without the kernel runs the XLA form
-alone.  ``--block``, ``--classes``, ``--ahead`` and ``--heads`` set the
-module's ``_GROUPED_BLOCK``, ``_GROUPED_CLASSES``, ``_GROUPED_AHEAD`` and
-``_GROUPED_HEADS`` before the kernel is traced (the experiments that
-chose them).  ``--cut copies`` traces the kernel with its DMAs left out
+alone.  ``--block``, ``--classes``, ``--ahead``, ``--heads`` and
+``--slab`` set the module's ``_GROUPED_BLOCK``, ``_GROUPED_CLASSES``,
+``_GROUPED_AHEAD``, ``_GROUPED_HEADS`` and ``_GROUPED_SLAB`` before the
+kernel is traced (the experiments that chose them; a block is halved
+until a leaf's slab of it fits ``_GROUPED_SLAB`` bytes, so a block of
+512 at ``olmo`` is ``--block 512 --slab 4194304``; a row's
+``read_block`` says what was read in).  ``--cut copies`` traces the kernel with its DMAs left out
 (what the arithmetic costs alone, over whatever the buffers hold),
 ``--cut arithmetic`` with a block's products and softmax left out (what
 the copies cost alone); neither is compared.  Every other variant's
@@ -80,13 +88,15 @@ SHAPES = {
     "smallthinker": (40, 16384, 4, 128, 7, "shared_docs_qa_16k"),
     "falcon": (80, 1024, 4, 128, 5, "long_answers_batch"),
     "k_exaone": (128, 4096, 8, 128, 8, "long_answers_mtp_4k"),
+    "olmo": (80, 1024, 30, 128, 1, "long_answers_batch"),
 }
 REHEARSAL = {"smallthinker": (4, 512, 4, 128, 7, "shared_docs_qa_16k"),
              "falcon": (6, 256, 4, 128, 5, "long_answers_batch"),
-             "k_exaone": (4, 1024, 8, 128, 8, "long_answers_mtp_4k")}
+             "k_exaone": (4, 1024, 8, 128, 8, "long_answers_mtp_4k"),
+             "olmo": (6, 256, 6, 128, 1, "long_answers_batch")}
 #: fresh rows a slot, as the shape's cell hands them
-ROWS = {"smallthinker": 1, "falcon": 1, "k_exaone": 2}
-KNOBS = ("block", "classes", "ahead", "heads")
+ROWS = {"smallthinker": 1, "falcon": 1, "k_exaone": 2, "olmo": 1}
+KNOBS = ("block", "classes", "ahead", "heads", "slab")
 
 
 def load(repo, knobs):
@@ -133,8 +143,13 @@ def forms(mod, shape, interpret, rows=1):
     takes ``rows`` fresh rows a slot."""
     S, T, G, D, R, _ = shape
     kw = dict(n_head=G * R, n_kv_head=G, scale=D ** -0.5)
-    out = {"xla": lambda q, kn, vn, kv, ts:
-           mod.grouped_masked_decode_attention(q, kn, vn, kv, ts, **kw)}
+    # one row of ONE query head a K/V head: the form that reads the
+    # leaves as they lie (the per-head view's is six float32 copies of a
+    # leaf a call at olmo's widths)
+    masked = (mod.lane_masked_decode_attention if R == 1 and rows == 1
+              and hasattr(mod, "lane_masked_decode_attention")
+              else mod.grouped_masked_decode_attention)
+    out = {"xla": lambda q, kn, vn, kv, ts: masked(q, kn, vn, kv, ts, **kw)}
     if hasattr(mod, "grouped_decode_attention") and (
             rows == 1 or hasattr(mod, "last_fresh_row")):
         sizes = kernel_sizes(mod, shape)
@@ -264,9 +279,9 @@ def main(argv=None):
         args.calls, args.reps = 2, 1
     shapes = {name: (REHEARSAL if args.rehearse_cpu else SHAPES)[name]
               for name in args.shape or list(SHAPES)}
-    sets = [(b, c, a, h) for b in args.block or [None]
+    sets = [(b, c, a, h, x) for b in args.block or [None]
             for c in args.classes or [None] for a in args.ahead or [None]
-            for h in args.heads or [None]]
+            for h in args.heads or [None] for x in args.slab or [None]]
     builds = {}
     for repo in (args.repo or ["."]) if args.build else []:
         # before this process touches jax: the child may need the chip
@@ -369,9 +384,13 @@ def main(argv=None):
                 "form": form, "cut": cut,
                 **{name: getattr(mod, "_GROUPED_" + name.upper(), None)
                    for name in KNOBS},
+                "read_block": (kernel_sizes(mod, shape)[0]
+                               if form == "kernel" else None),
                 # K/V heads a product scored (the knob, else the rule)
                 "unit_heads": (mod._unit_heads(
-                    G, -(-fresh * R // 8) * 8, mod._GROUPED_HEADS)
+                    G, mod._head_rows(fresh, R)
+                    if hasattr(mod, "_head_rows") else -(-fresh * R // 8) * 8,
+                    mod._GROUPED_HEADS)
                     if form == "kernel" and hasattr(mod, "_unit_heads")
                     else None),
                 "live_bytes": live, "read_bytes": read,
