@@ -10,8 +10,26 @@ fp32 absmax scale per (slot, position, head) as sibling leaves
 ``k_scale``, ``v_scale`` ``[slots, T, n_kv_head]`` (``paddle_tpu.quant``).
 :func:`kv_leaves` allocates a layer; nothing else in the tree spells the
 layout out.  With the pool's recurrent leaves (no sequence axis: a
-builder's own) the leaf kinds are three: SEQUENCE (above: position ``p``
-in row ``p``, ``T`` the length rung), RING, and recurrent.
+builder's own) the leaf kinds are four: SEQUENCE (above: position ``p``
+in row ``p``, ``T`` the length rung), RING, LATENT, and recurrent.
+
+**The latent leaf.**  A layer of latent attention keeps no ``k`` / ``v``
+pair and no head axis: :func:`latent_leaves` allocates ONE ``latent``
+leaf ``[slots, T, kv_lora_rank + rope]`` (the compressed row every head
+shares: 512 + 64 lanes, zero-padded to whole 128-lane tiles: 640) and
+ONE ``index_k`` leaf ``[slots, T, index_head_dim]`` (the key a learned
+scorer reads), both sequence leaves
+to the pool (position ``p`` in row ``p``: sliced, snapshotted and seated
+like any other), appended in place by :func:`append_latent_rows`.  They
+are read by SELECTION: the step scores a slot's index keys, names at
+most ``k`` single positions a slot (the same for every head), and
+:func:`selected_latent_attention` attends to those rows of the latent
+leaf and to nothing else, in the ABSORBED form (queries already
+projected into the latent space; the context comes back in it) — the
+leaf is never expanded to heads.  One lowering today, plain XLA ops (a
+row gather and a masked softmax: ``decode_attention_latent_lowered_total
+{path}``); :func:`masked_latent_attention` is the contract whole over
+the rung, the tests' parity reference.
 
 **The ring leaf.**  A layer whose queries read only the last ``W``
 positions (a sliding window that counts the query's own) keeps
@@ -135,7 +153,10 @@ __all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
            "grouped_block_decode_attention", "block_sparse_decode_attention",
            "block_kernel_supported", "BLOCK_SPARSE_LOWERED",
            "kernel_supported", "make_decode_attention", "ring_positions",
-           "RING_LOWERED", "GROUPED_LOWERED", "UNGROUPED_LOWERED"]
+           "RING_LOWERED", "GROUPED_LOWERED", "UNGROUPED_LOWERED",
+           "latent_leaves", "append_latent_rows",
+           "selected_latent_attention", "masked_latent_attention",
+           "pad_lanes", "LATENT_LOWERED"]
 
 BLOCK_SPARSE_LOWERED = _registry.REGISTRY.counter(
     "block_sparse_lowered_total",
@@ -181,6 +202,13 @@ UNGROUPED_LOWERED = _registry.REGISTRY.counter(
     "the CPU; bf16 leaves no kernel takes, on a TPU at one row through "
     "the form that reads them as they lie)",
     ("path",))
+
+LATENT_LOWERED = _registry.REGISTRY.counter(
+    "decode_attention_latent_lowered_total",
+    "reads of the positions named for a row over a LATENT leaf lowered "
+    "(traced into a program or run eagerly), by the lowering chosen: xla "
+    "(a gather of the named rows and a masked softmax over them, "
+    "absorbed: the leaf is never expanded to heads)", ("path",))
 
 #: the sequence axis of every K/V leaf (and scale sibling)
 KV_SEQ_AXIS = 1
@@ -949,6 +977,120 @@ def append_rows(kv, k_new, v_new, ts):
     at = jnp.where(live, at, T)             # out of range: dropped
     return {**_append(kv, "k", k_new, rows, at, None),
             **_append(kv, "v", v_new, rows, at, None)}
+
+
+#: lanes of one tile of a leaf's minor axis in HBM
+_LANE_TILE = 128
+
+
+def _whole_tiles(lanes: int) -> int:
+    return -(-int(lanes) // _LANE_TILE) * _LANE_TILE
+
+
+def latent_leaves(n_rows: int, seq_len: int, d_latent: int, d_index: int,
+                  dtype):
+    """One latent-attention layer's zeroed leaves: ``latent`` ``[n_rows,
+    seq_len, lanes]`` (the compressed row all heads share, its rotated
+    lanes last) and ``index_k`` ``[n_rows, seq_len, lanes]`` (the
+    scorer's key), in ``dtype``; every leaf's sequence axis is
+    :data:`KV_SEQ_AXIS`.  ``lanes`` is the width rounded UP to whole
+    128-lane tiles, the rest zeros (576 -> 640): the chip pads a row to
+    whole tiles anyway, and a leaf DECLARED with a ragged last tile is
+    re-laid sequence-minor inside the step's loop and copied whole there
+    and back every dispatch (five 0.96 GB copies in the ``deepseek_v3_2``
+    chunk, seen in its described-v5e compile, PR 54).  Two leaves and not
+    one of both widths: the scorer reads every live ``index_k`` row and
+    the attend only the selected ``latent`` rows, so neither read drags
+    the other's lanes."""
+    import jax.numpy as jnp
+
+    return {"latent": jnp.zeros((n_rows, seq_len, _whole_tiles(d_latent)),
+                                dtype),
+            "index_k": jnp.zeros((n_rows, seq_len, _whole_tiles(d_index)),
+                                 dtype)}
+
+
+def pad_lanes(x, lanes: int):
+    """``x`` with its last axis zero-padded to ``lanes`` (a latent leaf's
+    row is whole 128-lane tiles: what meets it is padded to match)."""
+    import jax.numpy as jnp
+
+    pad = lanes - x.shape[-1]
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def append_latent_rows(kv, latent_new, index_new, ts):
+    """A latent layer's leaves with the fresh rows appended in place:
+    ``latent_new`` ``[S, d_latent]``, ``index_new`` ``[S, d_index]``, one
+    a slot at ``ts`` (idle slots, ``ts < 0``, are not written; a row at
+    or past the rung's end is dropped); each is zero-padded to its leaf's
+    whole tiles."""
+    import jax.numpy as jnp
+
+    S, T, _ = kv["latent"].shape
+    at = jnp.where(ts >= 0, ts, T)          # out of range: dropped
+    rows = jnp.arange(S)
+    return {name: _append(kv, name, pad_lanes(new, kv[name].shape[2]),
+                          rows, at, None)[name]
+            for name, new in (("latent", latent_new),
+                              ("index_k", index_new))}
+
+
+def _latent_softmax(s, ok, vals, d_value):
+    """``softmax(s)`` over the allowed places times ``vals[..., :d_value]``:
+    ``s`` ``[S, H, K]`` float32, ``ok`` ``[S, K]``, ``vals`` ``[S, K, D]``;
+    a slot that may read nothing gets zeros."""
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    ok = ok[:, None, :]
+    s = jnp.where(ok, s, _MASK)
+    pr = jnp.exp(s - s.max(axis=-1, keepdims=True)) * ok
+    pr = pr / jnp.maximum(pr.sum(axis=-1, keepdims=True), 1e-30)
+    return jnp.einsum("shk,skc->shc", pr.astype(vals.dtype),
+                      vals[..., :d_value], preferred_element_type=f32)
+
+
+def selected_latent_attention(q, kv, ts, sel, valid, *, d_value: int,
+                              scale: float):
+    """Read the positions named for each slot, ABSORBED: ``q`` ``[S, H,
+    d_latent]`` float32 (each head's query already in the latent space,
+    its rotated lanes last), ``kv`` a layer's latent leaves with this
+    step's rows in (:func:`append_latent_rows`), ``sel`` ``[S, K]`` int32
+    the positions slot ``s`` reads — the same for every head —, ``valid``
+    ``[S, K]`` which of the list count (a named position past ``ts`` does
+    not, whatever ``valid`` says).  Scores are ``scale * q . row`` over
+    the whole row, the context is the weighed sum of the rows' first
+    ``d_value`` lanes: ``[S, H, d_value]`` float32, zeros for a slot that
+    reads nothing (idle: ``ts < 0``).  Products in the storage dtype,
+    float32 accumulation and softmax; ``S * K`` rows leave HBM, not the
+    rung."""
+    import jax.numpy as jnp
+
+    LATENT_LOWERED.labels(path="xla").inc()
+    leaf = kv["latent"]
+    rows = jnp.take_along_axis(leaf, sel[:, :, None], axis=1)   # [S, K, D]
+    q = pad_lanes((q * scale).astype(leaf.dtype), leaf.shape[2])
+    s = jnp.einsum("shd,skd->shk", q, rows,
+                   preferred_element_type=jnp.float32)
+    ok = valid & (sel <= ts[:, None])
+    return _latent_softmax(s, ok, rows, d_value)
+
+
+def masked_latent_attention(q, kv, allowed, *, d_value: int, scale: float):
+    """The selected read's contract as a plain masked softmax over the
+    WHOLE rung: slot ``s`` reads the positions ``allowed`` ``[S, T]``
+    marks.  The parity reference of :func:`selected_latent_attention`
+    (tests/test_latent_attention.py); no step takes it."""
+    import jax.numpy as jnp
+
+    leaf = kv["latent"]
+    q = pad_lanes((q * scale).astype(leaf.dtype), leaf.shape[2])
+    s = jnp.einsum("shd,std->sht", q, leaf,
+                   preferred_element_type=jnp.float32)
+    return _latent_softmax(s, allowed, leaf, d_value)
 
 
 def block_kernel_supported(kv, n_head: int, n_kv_head: int,

@@ -31,7 +31,9 @@ K-wide twin ``make_transformer_lm_pooled_verify_fn``,
 prefill), ``make_routed_conv_lm_pooled_step_fn`` (layers that hold
 different leaves, routed experts, counts made on the device) and
 ``make_delta_hybrid_lm_pooled_step_fn`` (a recurrent state that is read
-before it is written) — are made of the same parts:
+before it is written) and ``make_latent_sparse_lm_pooled_step_fn``
+(latent leaves read through a learned per-row selection) — are made of
+the same parts:
 
 * ONE cache format, whatever the storage dtype (fp32, bf16, int8):
   ``paddle_tpu.decode_attention`` says what a K/V leaf is, appends the
@@ -78,6 +80,7 @@ __all__ = [
     "make_windowed_routed_lm_pooled_step_fn",
     "make_mtp_routed_lm_pooled_step_fn",
     "make_delta_hybrid_lm_pooled_step_fn",
+    "make_latent_sparse_lm_pooled_step_fn",
     "cache_leaf_seq_axes", "cache_leaf_seq_strides", "cache_leaf_seq_windows",
     "cache_leaf_slotless", "NO_SLOT_AXIS",
     "recurrent_leaf_names", "ring_leaf_names",
@@ -1671,6 +1674,183 @@ def make_delta_hybrid_lm_pooled_step_fn(state, cfg, name: str = "lm",
         return logits, new_cache
 
     return step_fn, make_cache
+
+
+def make_latent_sparse_lm_pooled_step_fn(state, cfg, name: str = "lm",
+                                          kv_dtype: str = "bf16", held=None,
+                                          prefill_tokens: int = 512):
+    """The slot-pooled step AND the chunked prefill of a decoder whose
+    every block is multi-head LATENT attention read through a learned
+    top-k selection (a lightning indexer), then a dense SwiGLU or
+    group-limited routed experts beside a shared expert (``model_type:
+    deepseek_v32``; the parts and the equations are
+    ``paddle_tpu.latent_sparse_lm``, the expert layer
+    ``paddle_tpu.routed_experts``, the leaves and the selected read
+    ``paddle_tpu.decode_attention``).
+
+    Returns ``(step_fn, make_cache, prefill_fn)`` with the contract of
+    :func:`make_windowed_routed_lm_pooled_step_fn`.  ``state``: weights
+    under ``latent_sparse_lm.param_shapes(cfg, held=held)``, multiplied in
+    the dtype they are given (router, biases and norms float32);
+    ``held``: the contiguous range of experts whose matrices ``state``
+    holds — every sparse layer routes over all ``n_routed_experts_all``
+    (inside the best groups) and adds what the held ones give, plus its
+    shared expert.
+
+    The cache is ``{"layers": [...], "expert_stats": ...}``: every layer
+    ``decode_attention.latent_leaves`` — ``latent`` ``[N, T, kv_lora_rank
+    + rope]`` and ``index_k`` ``[N, T, index_head_dim]`` in ``kv_dtype``,
+    each zero-padded to whole 128-lane tiles (576 -> 640), both sequence
+    leaves of the length rung; ``expert_stats`` ``[sparse
+    layers, 4]`` int32 (``NO_SLOT_AXIS``), as
+    :func:`make_windowed_routed_lm_pooled_step_fn`'s.
+
+    The step is ABSORBED: a layer appends its row ``(c, kR)`` and its
+    index key in place, scores the slot's index keys against the fresh
+    query's (the whole rung, masked to what is live), takes the
+    ``min(index_topk, ts + 1)`` best positions, and reads those rows of
+    the latent leaf alone with queries projected into the latent space
+    (``decode_attention.selected_latent_attention``): the cache is never
+    expanded to heads.  ``prefill_fn`` feeds slot ``row`` ``C =
+    prefill_tokens`` prompt tokens at ``start .. start + n_valid - 1``
+    through every layer in one call and no logits, EXPANDED: each query
+    selects among the keys ``0 .. its own`` (the chunk's rows written
+    first) and attends to what it selected, a key block at a time
+    (``latent_sparse_lm.chunk_select``, ``chunk_attend_expanded``); the
+    chunk's rows go through the expert layer as a step's rows do.  It
+    equals ``n_valid`` steps leaf for leaf, but for the summation order
+    (tests/test_latent_sparse_lm.py).
+
+    ``make_cache.latent_layers`` and
+    ``make_cache.latent_positions_selected(n)`` (what a query of context
+    ``n`` reads of a layer: the lesser of ``n`` and ``index_topk``; it
+    SCORES all ``n``) are for the server's counters.  All leaves are
+    sequence leaves: ``KVSlotPool`` serves ``prefix=True`` over this
+    builder by snapshots (it has a prefill).
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import latent_sparse_lm as ls
+    from paddle_tpu import routed_experts as rx
+    from paddle_tpu.decode_attention import (append_latent_rows,
+                                             latent_leaves, pad_lanes,
+                                             selected_latent_attention)
+
+    d = ls.dims(cfg)
+    kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
+    W = {k: jnp.asarray(v) for k, v in state.items()}
+    C = int(prefill_tokens)
+    n_stats = len(rx.STAT_NAMES)
+    f32 = jnp.float32
+
+    def make_cache(n_rows: int, seq_len: int):
+        return {
+            "layers": [latent_leaves(n_rows, seq_len, d.d_latent, d.d_index,
+                                     kv) for _ in range(d.n_layer)],
+            "expert_stats": jnp.zeros((len(d.expert_layers), n_stats),
+                                      jnp.int32)}
+
+    make_cache.leaf_seq_axes = {
+        "layers": [{"latent": 1, "index_k": 1} for _ in range(d.n_layer)],
+        "expert_stats": NO_SLOT_AXIS}   # a snapshot must not carry counts
+    make_cache.expert_stats = lambda cache: cache["expert_stats"]
+    make_cache.n_expert = (d.n_expert if held is None
+                           else int(held[1]) - int(held[0]))
+    make_cache.latent_layers = d.n_layer
+    make_cache.latent_positions_selected = (
+        lambda n: np.minimum(n, d.index_topk))
+
+    def close_layer(h, o, p, dense, ts_rows):
+        """The residual around a mixer's output ``o`` and the layer's
+        FFN; ``(h, stats or None)``."""
+        h = h + o
+        f = ls.rms_norm(h, W[p + "ffn_norm"], d.eps)
+        if dense:
+            return h + ls.swiglu(f, W[p + "ffn_gate"], W[p + "ffn_up"],
+                                 W[p + "ffn_down"], 1.0, 1.0), None
+        y, st = rx.expert_layer(f, W, p, ts_rows, d, held)
+        return h + y, st
+
+    def projections(h, p, pos):
+        with jax.named_scope(ls.LATENT_PROJECT_SCOPE):
+            x = ls.rms_norm(h, W[p + "input_norm"], d.eps)
+            cq, qc, qr, row = ls.latent_inputs(x, W, p, pos, d)
+            qi, ki, wi = ls.index_inputs(x, cq, W, p, pos, d)
+        return qc, qr, row, qi, ki, wi
+
+    def step_fn(cache, tokens, ts):
+        layers = cache["layers"]
+        ts = jnp.minimum(ts, layers[0]["latent"].shape[1] - 1)
+        pos = jnp.maximum(ts, 0)      # idle rows stay < 0 in ``ts``
+        h = W[name + "_emb"][tokens].astype(f32)
+        new_layers, stats = [], []
+        for i in range(d.n_layer):
+            p = "%s_l%d_" % (name, i)
+            qc, qr, row, qi, ki, wi = projections(h, p, pos)
+            with jax.named_scope(ls.LATENT_PROJECT_SCOPE):
+                q = ls.absorb_queries(qc, qr, W, p, d)
+                leaves = append_latent_rows(layers[i], row, ki, ts)
+            with jax.named_scope(ls.INDEX_SCORE_SCOPE):
+                scores = ls.index_scores(qi, wi, leaves["index_k"])
+            with jax.named_scope(ls.INDEX_SELECT_SCOPE):
+                sel, valid = ls.select_positions(scores, ts, d.index_topk)
+            with jax.named_scope(ls.LATENT_ATTEND_SCOPE):
+                u = selected_latent_attention(q, leaves, ts, sel, valid,
+                                              d_value=d.d_c, scale=d.scale)
+                o = ls.attend_out(u, W, p, d)
+            new_layers.append(leaves)
+            h, st = close_layer(h, o, p, d.dense[i], ts)
+            if st is not None:
+                stats.append(st)
+        logits = ls.linear(ls.rms_norm(h, W[name + "_final_norm"], d.eps),
+                           W[name + "_head"])
+        counts = cache["expert_stats"]
+        return logits, {"layers": new_layers,
+                        "expert_stats": counts + jnp.stack(stats)
+                        if stats else counts}
+
+    def prefill_layer(c, h, p, dense, row, start, n_valid, pos, ts_q):
+        qc, qr, fresh, qi, ki, wi = projections(h, p, pos)
+        live = (ts_q >= 0)[:, None]
+        new, mine = {}, {}
+        for leaf, rows in (("latent", fresh), ("index_k", ki)):
+            lanes = c[leaf].shape[2]        # whole tiles: zero-padded
+            rows = pad_lanes(rows, lanes)
+            old = jax.lax.dynamic_slice(c[leaf], (row, start, 0),
+                                        (1, C, lanes))[0]
+            new[leaf] = jax.lax.dynamic_update_slice(
+                c[leaf], jnp.where(live, rows.astype(kv), old)[None],
+                (row, start, 0))
+            mine[leaf] = jax.lax.dynamic_index_in_dim(new[leaf], row, 0,
+                                                      False)
+        n_keys = start + n_valid
+        with jax.named_scope(ls.INDEX_SCORE_SCOPE):
+            member = ls.chunk_select(qi, wi, mine["index_k"], ts_q, n_keys,
+                                     d.index_topk)
+        with jax.named_scope(ls.LATENT_ATTEND_SCOPE):
+            o = ls.linear(ls.chunk_attend_expanded(
+                qc, qr, mine["latent"], member, n_keys, W, p, d),
+                W[p + "attn_o"])
+        return close_layer(h, o, p, dense, ts_q)[0], new
+
+    def prefill_fn(cache, row, tokens, start, n_valid):
+        with jax.named_scope(ls.PREFILL_CHUNK_SCOPE):
+            pos = start + jnp.arange(C)
+            ts_q = jnp.where(jnp.arange(C) < n_valid, pos, -1)
+            h = W[name + "_emb"][tokens].astype(f32)
+            new_layers = []
+            for i in range(d.n_layer):
+                h, new = prefill_layer(cache["layers"][i], h,
+                                       "%s_l%d_" % (name, i), d.dense[i],
+                                       row, start, n_valid, pos, ts_q)
+                new_layers.append(new)
+            return {"layers": new_layers,
+                    "expert_stats": cache["expert_stats"]}
+
+    prefill_fn.chunk_tokens = C
+    make_cache.prefill_fn = prefill_fn
+    return step_fn, make_cache, prefill_fn
 
 
 def make_transformer_lm_pooled_verify_fn(

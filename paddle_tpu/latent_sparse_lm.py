@@ -1,0 +1,463 @@
+"""The parts of a decoder whose every block is multi-head LATENT
+attention read through a learned selection (a "lightning indexer"), then
+a dense SwiGLU (the leading layers) or group-limited routed experts
+beside a shared expert (``model_type: deepseek_v32``).  Pre-norm, no
+bias anywhere but the indexer's LayerNorm:
+
+    x = RMS(h; input_norm)
+    queries:  cq = RMS(x W_dq; q_a_norm)                       [q_lora_rank]
+              q_i = cq W_uq,i = [qC_i (nope) ; qR_i (rope)]    per head i
+              qR_i rotated at its position
+    cached:   [c ; kR] = x W_dkv;  c = RMS(c; kv_a_norm);  kR rotated,
+              ONE head shared by all: the layer's cache row is (c, kR)
+    indexer:  qI_j = (cq W_iq)_j  for index_n_heads heads, the first rope
+              lanes of each rotated;  kI = LayerNorm(x W_ik), the first
+              rope lanes rotated: the INDEX KEY, cached
+              w = (x W_iw) * index_n_heads^-0.5 * index_head_dim^-0.5
+              I_t,s = sum_j w_t,j relu(qI_t,j . kI_s)   float32, s <= t
+              S_t = the min(index_topk, t + 1) positions of largest I_t,s
+              (ties: the lowest position first), alike for every head
+    expanded: [kC_s,i ; v_s,i] = c_s W_ukv,i
+              a_t,s,i = scale (qC_t,i . kC_s,i + qR_t,i . kR_s)
+              o_t = concat_i(sum_{s in S_t} softmax_s(a_t,s,i) v_s,i) W_o
+    absorbed: qA_t,i = qC_t,i W_uk,i^T  [kv_lora_rank]
+              a_t,s,i = scale (qA_t,i . c_s + qR_t,i . kR_s)
+              u_t,i = sum_{s in S_t} p_t,s,i c_s;  o_t = concat_i(u_t,i W_uv,i) W_o
+    h = h + o;   f = RMS(h; ffn_norm)
+    dense layer:  h = h + W2 (silu(W1 f) * W3 f)
+    sparse layer: routed_experts.expert_layer (sigmoid scores, a bias
+                  that chooses, GROUP-LIMITED choice, a routed scale)
+                  + the shared expert
+    logits = RMS(h; final_norm) W_head            (the head is untied)
+
+The two forms are the same function (tests/test_latent_sparse_lm.py).
+The rotary is YaRN's over the rope lanes (:func:`yarn_inv_freq`), the
+softmax scale carries its ``mscale`` squared (:func:`softmax_scale`);
+lanes are paired half-split (rotate-half) in attention and indexer alike.
+
+``decoding.make_latent_sparse_lm_pooled_step_fn`` strings them into the
+slot-pooled step (absorbed: the cache is never expanded) and the chunked
+prefill (expanded, a key block at a time); nothing here knows a pool or
+a server.  The cache is ``decode_attention``'s latent leaves; the up
+projections are stored as they are multiplied (``attn_uk`` ``[heads,
+nope, kv_lora_rank]``, ``attn_uv`` ``[heads, kv_lora_rank, v]``: a head a
+batch of one product, no relayout a step).  Weights are multiplied in
+the dtype they are given (bf16 as stored), accumulated in float32; the
+router, the index scores' sum, norms and rotary angles are float32.
+"""
+from __future__ import annotations
+
+from types import SimpleNamespace
+
+import numpy as np
+
+from paddle_tpu.hybrid_ssm import linear, rms_norm, swiglu
+from paddle_tpu.routed_experts import SIGMOID_BIAS, SILU
+
+__all__ = ["dims", "param_shapes", "random_state", "yarn_inv_freq",
+           "softmax_scale", "rotate", "latent_inputs", "index_inputs",
+           "index_scores", "select_positions", "absorb_queries",
+           "attend_out", "chunk_select", "chunk_attend_expanded",
+           "LATENT_PROJECT_SCOPE", "INDEX_SCORE_SCOPE", "INDEX_SELECT_SCOPE",
+           "LATENT_ATTEND_SCOPE", "PREFILL_CHUNK_SCOPE", "FLOAT32_PARAMS",
+           "linear", "rms_norm", "swiglu"]
+
+#: ``jax.named_scope`` names, for the device trace
+LATENT_PROJECT_SCOPE = "latent_project"
+INDEX_SCORE_SCOPE = "index_score"
+INDEX_SELECT_SCOPE = "index_select"
+LATENT_ATTEND_SCOPE = "latent_attend"
+PREFILL_CHUNK_SCOPE = "prefill_chunk"
+
+#: parameters kept float32 whatever the matrices' dtype, by name ending
+FLOAT32_PARAMS = ("_norm", "_norm_bias", "router", "expert_bias")
+
+_LN_EPS = 1e-6
+_MASK = -1e30
+
+
+def yarn_inv_freq(cfg) -> np.ndarray:
+    """The rotary's inverse frequencies over ``qk_rope_head_dim`` lanes
+    (``[lanes / 2]`` float32): YaRN — each frequency a blend of the
+    extrapolated ``theta^(-2i/lanes)`` and that over ``factor``, by a
+    linear ramp between the two correction dims of ``beta_fast`` and
+    ``beta_slow``; plain rotary without ``rope_scaling``."""
+    lanes = int(cfg["qk_rope_head_dim"])
+    base = float(cfg["rope_theta"])
+    extra = base ** (-np.arange(0, lanes, 2, dtype=np.float64) / lanes)
+    sc = cfg.get("rope_scaling")
+    if not sc:
+        return extra.astype(np.float32)
+    if sc.get("type", sc.get("rope_type")) != "yarn":
+        raise ValueError("only yarn rope_scaling is supported")
+    factor = float(sc["factor"])
+    orig = float(sc["original_max_position_embeddings"])
+
+    def correction_dim(rotations):
+        return (lanes * np.log(orig / (rotations * 2 * np.pi))
+                / (2 * np.log(base)))
+
+    low = max(np.floor(correction_dim(float(sc["beta_fast"]))), 0)
+    high = min(np.ceil(correction_dim(float(sc["beta_slow"]))), lanes - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(lanes // 2) - low) / (high - low), 0, 1)
+    return (extra / factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def softmax_scale(cfg) -> float:
+    """``(nope + rope)^-0.5 * m^2`` with YaRN's ``m = 0.1 *
+    mscale_all_dim * ln(factor) + 1`` (1 without ``rope_scaling``)."""
+    width = int(cfg["qk_nope_head_dim"]) + int(cfg["qk_rope_head_dim"])
+    sc = cfg.get("rope_scaling") or {}
+    m = 1.0
+    if sc.get("mscale_all_dim") and float(sc.get("factor", 1)) > 1:
+        m = 0.1 * float(sc["mscale_all_dim"]) * np.log(float(sc["factor"])) + 1
+    return float(width ** -0.5 * m * m)
+
+
+def dims(cfg) -> SimpleNamespace:
+    """The block's sizes and scalars from a ``deepseek_v32`` config dict
+    (the published key names).  ``n_routed_experts`` may count the
+    experts HELD here; the router's width is then
+    ``n_routed_experts_all``."""
+    o = SimpleNamespace(
+        vocab=int(cfg["vocab_size"]), d_model=int(cfg["hidden_size"]),
+        n_layer=int(cfg["num_hidden_layers"]),
+        n_dense=int(cfg["first_k_dense_replace"]),
+        n_head=int(cfg["num_attention_heads"]),
+        q_rank=int(cfg["q_lora_rank"]), d_c=int(cfg["kv_lora_rank"]),
+        d_nope=int(cfg["qk_nope_head_dim"]),
+        d_rope=int(cfg["qk_rope_head_dim"]), d_v=int(cfg["v_head_dim"]),
+        n_index_head=int(cfg["index_n_heads"]),
+        d_index=int(cfg["index_head_dim"]),
+        index_topk=int(cfg["index_topk"]),
+        d_mlp=int(cfg["intermediate_size"]),
+        d_expert=int(cfg["moe_intermediate_size"]),
+        n_expert=int(cfg.get("n_routed_experts_all",
+                             cfg["n_routed_experts"])),
+        top_k=int(cfg["num_experts_per_tok"]),
+        n_shared=int(cfg.get("n_shared_experts", 0)),
+        n_group=int(cfg.get("n_group", 1)),
+        topk_group=int(cfg.get("topk_group", 1)),
+        eps=float(cfg.get("rms_norm_eps", 1e-6)), ln_eps=_LN_EPS,
+        norm_topk=bool(cfg.get("norm_topk_prob", True)),
+        routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+        inv_freq=yarn_inv_freq(cfg), scale=softmax_scale(cfg),
+        # what routed_experts.route / expert_layer read
+        scoring=SIGMOID_BIAS, gate_act=SILU, expert_bias=True)
+    if cfg.get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError("only scoring_func = sigmoid is supported")
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError("only hidden_act = silu is supported")
+    if cfg.get("tie_word_embeddings", False):
+        raise ValueError("a tied head is not supported")
+    if cfg.get("attention_bias", False):
+        raise ValueError("attention_bias is not supported")
+    if int(cfg.get("moe_layer_freq", 1)) != 1:
+        raise ValueError("only moe_layer_freq = 1 is supported")
+    if int(cfg.get("num_nextn_predict_layers", 0)):
+        raise ValueError("a multi-token-prediction module is not held: "
+                         "num_nextn_predict_layers must be 0")
+    if o.n_expert % o.n_group or o.topk_group > o.n_group:
+        raise ValueError("n_group must divide the experts and hold "
+                         "topk_group")
+    if o.n_group > 1 and o.n_expert // o.n_group < 2:
+        raise ValueError("a group is scored by its two best experts")
+    if o.d_rope % 2 or o.d_rope > o.d_index:
+        raise ValueError("the rotated lanes must be even and fit an "
+                         "index head")
+    o.d_latent = o.d_c + o.d_rope
+    o.d_qk = o.d_nope + o.d_rope
+    o.dense = tuple(i < o.n_dense for i in range(o.n_layer))
+    o.expert_layers = tuple(i for i in range(o.n_layer) if not o.dense[i])
+    return o
+
+
+def param_shapes(cfg, name: str = "lm", held=None) -> dict:
+    """Names and shapes of every weight the step reads: the one place
+    the schema lives.  Matrices are ``[in, out]``; the latent's up
+    projections are kept a head a batch, as multiplied: ``attn_uk``
+    ``[heads, nope, kv_lora_rank]`` (``kC_i = c W_uk,i^T``; absorbed:
+    ``qA_i = qC_i W_uk,i``) and ``attn_uv`` ``[heads, kv_lora_rank, v]``;
+    an expert layer's gate and up matrices are ONE ``[held experts,
+    d_model, 2 * width]`` (gate columns first), its shared expert's ONE
+    ``[d_model, 2 * width]``; ``held = (lo, hi)``: the experts whose
+    matrices are held (default all); the router and its bias keep their
+    whole width."""
+    d = dims(cfg)
+    n_held = d.n_expert if held is None else int(held[1]) - int(held[0])
+    out = {name + "_emb": (d.vocab, d.d_model),
+           name + "_final_norm": (d.d_model,),
+           name + "_head": (d.d_model, d.vocab)}
+    for i in range(d.n_layer):
+        p = "%s_l%d_" % (name, i)
+        out.update({
+            p + "input_norm": (d.d_model,), p + "ffn_norm": (d.d_model,),
+            p + "attn_q_a": (d.d_model, d.q_rank),
+            p + "q_a_norm": (d.q_rank,),
+            p + "attn_q_b": (d.q_rank, d.n_head * d.d_qk),
+            p + "attn_kv_a": (d.d_model, d.d_latent),
+            p + "kv_a_norm": (d.d_c,),
+            p + "attn_uk": (d.n_head, d.d_nope, d.d_c),
+            p + "attn_uv": (d.n_head, d.d_c, d.d_v),
+            p + "attn_o": (d.n_head * d.d_v, d.d_model),
+            p + "index_q": (d.q_rank, d.n_index_head * d.d_index),
+            p + "index_k": (d.d_model, d.d_index),
+            p + "index_k_norm": (d.d_index,),
+            p + "index_k_norm_bias": (d.d_index,),
+            p + "index_w": (d.d_model, d.n_index_head)})
+        if d.dense[i]:
+            out.update({p + "ffn_gate": (d.d_model, d.d_mlp),
+                        p + "ffn_up": (d.d_model, d.d_mlp),
+                        p + "ffn_down": (d.d_mlp, d.d_model)})
+            continue
+        out.update({p + "router": (d.d_model, d.n_expert),
+                    p + "expert_bias": (d.n_expert,),
+                    p + "experts_w13": (n_held, d.d_model, 2 * d.d_expert),
+                    p + "experts_w2": (n_held, d.d_expert, d.d_model)})
+        if d.n_shared:
+            out.update({p + "shared_w13": (d.d_model,
+                                           2 * d.n_shared * d.d_expert),
+                        p + "shared_w2": (d.n_shared * d.d_expert,
+                                          d.d_model)})
+    return out
+
+
+def random_state(rng, cfg, name: str = "lm", std: float = 0.02,
+                 dtype="float32", bias_range: float = 0.05,
+                 held=None) -> dict:
+    """Seeded random weights under :func:`param_shapes` (tests, tools):
+    normal matrices in ``dtype``, norm weights near 1 and the indexer's
+    LayerNorm bias NOT zero (a norm that is skipped shows), a float32
+    router and an ``expert_bias`` uniform in ``+-bias_range``."""
+    import jax.numpy as jnp
+
+    w = {}
+    for k, shp in param_shapes(cfg, name, held).items():
+        if k.endswith("_norm"):
+            w[k] = (1.0 + 0.1 * rng.randn(*shp)).astype("float32")
+        elif k.endswith("_norm_bias"):
+            w[k] = (0.1 * rng.randn(*shp)).astype("float32")
+        elif k.endswith("expert_bias"):
+            w[k] = rng.uniform(-bias_range, bias_range, shp).astype("float32")
+        elif k.endswith("router"):
+            w[k] = (rng.randn(*shp) * std).astype("float32")
+        else:
+            w[k] = jnp.asarray((rng.randn(*shp) * std).astype("float32"),
+                               dtype)
+    return w
+
+
+def rotate(x, pos, inv_freq):
+    """Rotate-half rotary over the WHOLE last axis of ``x`` ``[N, ...,
+    2 * len(inv_freq)]`` at per-row positions ``pos`` ``[N]``, float32."""
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    half = x.shape[-1] // 2
+    ang = pos.astype(f32)[:, None] * jnp.asarray(inv_freq, f32)[None, :]
+    ang = ang.reshape((x.shape[0],) + (1,) * (x.ndim - 2) + (half,))
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
+    x = x.astype(f32)
+    rot = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return x * cos + rot * sin
+
+
+def latent_inputs(x, w, p: str, pos, d):
+    """What attention takes of the normed rows ``x`` ``[N, d_model]`` at
+    positions ``pos``: ``(cq [N, q_rank], qC [N, heads, nope], qR [N,
+    heads, rope], row [N, kv_lora_rank + rope])`` float32 — ``row`` is
+    the layer's cache row ``(c, kR)``: ``c`` normed, ``kR`` rotated."""
+    import jax.numpy as jnp
+
+    n = x.shape[0]
+    cq = rms_norm(linear(x, w[p + "attn_q_a"]), w[p + "q_a_norm"], d.eps)
+    q = linear(cq, w[p + "attn_q_b"]).reshape(n, d.n_head, d.d_qk)
+    ckr = linear(x, w[p + "attn_kv_a"])
+    row = jnp.concatenate(
+        [rms_norm(ckr[:, :d.d_c], w[p + "kv_a_norm"], d.eps),
+         rotate(ckr[:, d.d_c:], pos, d.inv_freq)], axis=-1)
+    return (cq, q[..., :d.d_nope],
+            rotate(q[..., d.d_nope:], pos, d.inv_freq), row)
+
+
+def _rotate_head(x, pos, d):
+    import jax.numpy as jnp
+
+    return jnp.concatenate([rotate(x[..., :d.d_rope], pos, d.inv_freq),
+                            x[..., d.d_rope:].astype(jnp.float32)], axis=-1)
+
+
+def index_inputs(x, cq, w, p: str, pos, d):
+    """The lightning indexer's inputs for the rows ``x`` (normed) and
+    their query latents ``cq``: ``(qI [N, index heads, index dim], kI [N,
+    index dim], wI [N, index heads])`` float32; ``kI`` is the row's INDEX
+    KEY (LayerNorm with weight and bias, then the first rope lanes
+    rotated), ``wI`` carries both ``^-0.5`` factors."""
+    import jax.numpy as jnp
+
+    n = x.shape[0]
+    q = linear(cq, w[p + "index_q"]).reshape(n, d.n_index_head, d.d_index)
+    k = linear(x, w[p + "index_k"])
+    mu = jnp.mean(k, axis=-1, keepdims=True)
+    var = jnp.mean((k - mu) ** 2, axis=-1, keepdims=True)
+    k = ((k - mu) / jnp.sqrt(var + d.ln_eps)
+         * w[p + "index_k_norm"].astype(jnp.float32)
+         + w[p + "index_k_norm_bias"].astype(jnp.float32))
+    wi = linear(x, w[p + "index_w"]) * float(
+        d.n_index_head ** -0.5 * d.d_index ** -0.5)
+    return _rotate_head(q, pos, d), _rotate_head(k, pos, d), wi
+
+
+def index_scores(qi, wi, keys):
+    """``I[n, s] = sum_j wi[n, j] relu(qi[n, j] . keys[n, s])`` in
+    float32: ``qi`` ``[N, heads, D]``, ``wi`` ``[N, heads]``, ``keys``
+    ``[N, T, D]`` (one row's keys a query) or ``[T, D]`` (the same keys
+    for every query) in their storage dtype (the products are taken in
+    it, accumulated in float32).  Returns ``[N, T]``; the caller masks
+    what is not live."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.decode_attention import pad_lanes
+
+    f32 = jnp.float32
+    form = "nhd,ntd->nht" if keys.ndim == 3 else "nhd,td->nht"
+    # a leaf's row is whole lane tiles: the query is padded to match
+    qi = pad_lanes(qi.astype(keys.dtype), keys.shape[-1])
+    s = jnp.einsum(form, qi, keys, preferred_element_type=f32)
+    return jnp.sum(jax.nn.relu(s) * wi.astype(f32)[:, :, None], axis=1)
+
+
+def select_positions(scores, ts, top_k: int):
+    """The positions a row at ``ts`` reads: ``(sel [N, k] int32, valid
+    [N, k] bool)``, ``k = min(top_k, T)`` — the ``min(top_k, ts + 1)``
+    positions ``<= ts`` of largest ``scores`` ``[N, T]`` (ties: the
+    lowest position first, ``lax.top_k``'s order), the rest of the list
+    not ``valid`` (an idle row, ``ts < 0``: none is)."""
+    import jax
+    import jax.numpy as jnp
+
+    t = scores.shape[1]
+    live = jnp.arange(t)[None, :] <= ts[:, None]
+    top, sel = jax.lax.top_k(jnp.where(live, scores, -jnp.inf),
+                             min(int(top_k), t))
+    return sel.astype(jnp.int32), top > -jnp.inf
+
+
+def absorb_queries(qc, qr, w, p: str, d):
+    """The absorbed queries ``[N, heads, kv_lora_rank + rope]`` float32:
+    ``qA_i = qC_i W_uk,i`` beside ``qR_i``, to be scored against cache
+    rows ``(c, kR)`` as they lie."""
+    import jax.numpy as jnp
+
+    uk = w[p + "attn_uk"]
+    qa = jnp.einsum("nhd,hdc->nhc", qc.astype(uk.dtype), uk,
+                    preferred_element_type=jnp.float32)
+    return jnp.concatenate([qa, qr], axis=-1)
+
+
+def attend_out(u, w, p: str, d):
+    """``concat_i(u_i W_uv,i) W_o`` of the absorbed contexts ``u`` ``[N,
+    heads, kv_lora_rank]``: ``[N, d_model]`` float32."""
+    import jax.numpy as jnp
+
+    uv = w[p + "attn_uv"]
+    o = jnp.einsum("nhc,hcd->nhd", u.astype(uv.dtype), uv,
+                   preferred_element_type=jnp.float32)
+    return linear(o.reshape(u.shape[0], -1), w[p + "attn_o"])
+
+
+def _blocks(n: int, block: int) -> int:
+    kb = min(int(block), n)
+    while n % kb:
+        kb -= 1                        # tiny test rungs: a divisor
+    return kb
+
+
+def chunk_select(qi, wi, keys, q_pos, n_keys, top_k: int,
+                 key_block: int = 1024):
+    """The prefill chunk's selection: for ``C`` queries of ONE row (``qi``
+    ``[C, heads, D]``, ``wi`` ``[C, heads]``, at positions ``q_pos``
+    ``[C]``, ``< 0``: no query) over that row's index keys ``keys`` ``[T,
+    D]`` (the first ``n_keys`` are scored, in whole blocks: no ``[C,
+    heads, T]`` is ever held), WHICH positions each query reads: ``[C,
+    T]`` bool — the ``min(top_k, q_pos + 1)`` positions ``<= q_pos`` of
+    largest score, ties the lowest position first (what
+    :func:`select_positions` lists, as a mask)."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    c, t = qi.shape[0], keys.shape[0]
+    kb = _blocks(t, key_block)
+
+    def body(i, scores):
+        block = jax.lax.dynamic_slice(keys, (i * kb, 0), (kb, keys.shape[1]))
+        return jax.lax.dynamic_update_slice(
+            scores, index_scores(qi, wi, block), (0, i * kb))
+
+    scores = jax.lax.fori_loop(0, (n_keys + kb - 1) // kb, body,
+                               jnp.full((c, t), -jnp.inf, f32))
+    live = jnp.arange(t)[None, :] <= q_pos[:, None]
+    scores = jnp.where(live, scores, -jnp.inf)
+    k = min(int(top_k), t)
+    # the k-th largest score; what ties with it enters lowest first
+    least = jax.lax.top_k(scores, k)[0][:, -1:]
+    above = scores > least
+    ties = (scores == least) & live
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    return (above | (ties & (jnp.cumsum(ties, axis=-1) <= room))) & live
+
+
+def chunk_attend_expanded(qc, qr, rows, member, n_keys, w, p: str, d,
+                          key_block: int = 512):
+    """The prefill chunk's attend, EXPANDED: ``C`` queries of ONE row
+    (``qc`` ``[C, heads, nope]``, ``qr`` ``[C, heads, rope]`` float32)
+    against that row's cache rows ``rows`` ``[T, kv_lora_rank + rope]``
+    (storage dtype; lanes past them, a leaf's padding, are not read),
+    each query reading the positions ``member`` ``[C,
+    T]`` names: a ``key_block`` of rows at a time is expanded to its
+    heads' keys and values (``c W_uk``, ``c W_uv``) and met with an online
+    softmax, so no expanded K/V of the history and no temporary that
+    grows with the rung is held.  Returns ``[C, heads * v]`` float32 (a
+    query that reads nothing: zeros)."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    c, t = qc.shape[0], rows.shape[0]
+    kb = _blocks(t, key_block)
+    dt = rows.dtype
+    uk, uv = w[p + "attn_uk"], w[p + "attn_uv"]
+    qcs, qrs = (qc * d.scale).astype(dt), (qr * d.scale).astype(dt)
+
+    def body(i, carry):
+        m, l, acc = carry
+        at = i * kb
+        block = jax.lax.dynamic_slice(rows, (at, 0), (kb, rows.shape[1]))
+        lat = block[:, :d.d_c].astype(uk.dtype)
+        kc = jnp.einsum("kc,hdc->khd", lat, uk,
+                        preferred_element_type=f32).astype(dt)
+        vv = jnp.einsum("kc,hcd->khd", lat, uv,
+                        preferred_element_type=f32).astype(dt)
+        ok = jax.lax.dynamic_slice(member, (0, at), (c, kb))[:, None, :]
+        s = (jnp.einsum("chd,khd->chk", qcs, kc, preferred_element_type=f32)
+             + jnp.einsum("chr,kr->chk", qrs, block[:, d.d_c:d.d_latent],
+                          preferred_element_type=f32))
+        s = jnp.where(ok, s, _MASK)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        pr = jnp.exp(s - m_new[..., None]) * ok
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "chk,khd->chd", pr.astype(dt), vv, preferred_element_type=f32)
+        return m_new, alpha * l + pr.sum(axis=-1), acc
+
+    h = d.n_head
+    _, l, acc = jax.lax.fori_loop(
+        0, (n_keys + kb - 1) // kb, body,
+        (jnp.full((c, h), _MASK, f32), jnp.zeros((c, h), f32),
+         jnp.zeros((c, h, d.d_v), f32)))
+    return (acc / jnp.maximum(l, 1e-30)[..., None]).reshape(c, -1)

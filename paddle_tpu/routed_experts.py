@@ -14,6 +14,10 @@ row.  Pre-norm, no bias anywhere:
                   sel = top_k(s + b)            b chooses, it does not weigh
                   g_e = s_e / (sum_{e in sel} s_e + 1e-6) * scaling
                   h = h + sum_{e in sel, e held here} g_e W2_e(silu(W1_e f) * W3_e f)
+    group-limited (``d.n_group`` > 1; ``paddle_tpu.latent_sparse_lm``):
+                  the experts are n_group consecutive groups; a group is
+                  scored by the sum of its two largest s + b, and top_k
+                  runs inside the d.topk_group best groups alone
 
 ``decoding.make_routed_conv_lm_pooled_step_fn`` strings them into the
 slot-pooled step; nothing here knows a pool or a server.  Weights are
@@ -192,9 +196,11 @@ def route(f, w_router, bias, d):
     """Which experts each row chose and how it weighs them, over ALL
     ``d.n_expert``: ``(sel [N, top_k] int32, gate [N, top_k] float32)``,
     by ``d.scoring``.  :data:`SIGMOID_BIAS`: the bias enters the choice
-    and never the weights.  :data:`SOFTMAX_CHOSEN`: the choice is made on
-    the logits and the softmax runs over the chosen alone (``bias`` is
-    not read)."""
+    and never the weights; with ``d.n_group`` > 1 (absent: 1) the choice
+    is made inside the ``d.topk_group`` best groups
+    (:func:`_within_best_groups`).  :data:`SOFTMAX_CHOSEN`: the choice is
+    made on the logits and the softmax runs over the chosen alone
+    (``bias`` is not read)."""
     import jax
     import jax.numpy as jnp
 
@@ -211,12 +217,32 @@ def route(f, w_router, bias, d):
         f.astype(f32), w_router.astype(f32), precision="highest",
         preferred_element_type=f32))
     chosen = s + bias.astype(f32) if d.expert_bias else s
+    n_group = int(getattr(d, "n_group", 1))
+    if n_group > 1:
+        chosen = _within_best_groups(chosen, n_group, int(d.topk_group))
     _, sel = jax.lax.top_k(chosen, d.top_k)
     gate = jnp.take_along_axis(s, sel, axis=-1)
     if d.norm_topk:
         gate = gate / (jnp.sum(gate, axis=-1, keepdims=True)
                        + _WEIGHT_SUM_EPS)
     return sel.astype(jnp.int32), gate * d.routed_scale
+
+
+def _within_best_groups(chosen, n_group: int, topk_group: int):
+    """``chosen`` ``[N, n_expert]`` with every expert outside the row's
+    ``topk_group`` best groups at ``-inf``: the experts are ``n_group``
+    consecutive groups of equal size, a group is scored by the sum of
+    its two largest ``chosen`` (ties: the lower group first)."""
+    import jax
+    import jax.numpy as jnp
+
+    n = chosen.shape[0]
+    by_group = chosen.reshape(n, n_group, -1)
+    score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(score, topk_group)
+    kept = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :],
+                   axis=1)
+    return jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(n, -1)
 
 
 def dispatch(sel, live, held, n_expert: int):

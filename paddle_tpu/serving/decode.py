@@ -280,6 +280,18 @@ DECODE_WINDOW_READ = monitor.counter(
     "the window (make_cache.window_positions_read) — read / live is how "
     "much of a context the window layers leave unread (and unheld)",
     _LABELS)
+DECODE_INDEX_SCORED = monitor.counter(
+    "serving_decode_index_positions_scored_total",
+    "index keys the latent layers' learned selection scored: per step, "
+    "active slot and latent layer (make_cache.latent_layers) the slot's "
+    "context — every live position is scored before any is read; 0 for "
+    "a builder without latent layers", _LABELS)
+DECODE_LATENT_SELECTED = monitor.counter(
+    "serving_decode_latent_positions_selected_total",
+    "latent rows those steps' reads were told to read: the lesser of "
+    "the context and the selection's top-k "
+    "(make_cache.latent_positions_selected) — selected / scored is how "
+    "sparse the traffic makes the read", _LABELS)
 DECODE_KV_BYTES_HELD = monitor.gauge(
     "serving_decode_kv_bytes_held",
     "bytes of the pool's sequence leaves at its CURRENT rung pair, ring "
@@ -556,6 +568,13 @@ class DecodeServer:
         # rounding or the whole rung, by what serves them)
         self._kv_rule = getattr(make_cache, "kv_positions_read", None)
         self._window_layers = int(getattr(make_cache, "window_layers", 0))
+        # what the builder declares of its latent layers' selected read:
+        # (positions a query of context n reads of a layer, layers)
+        self._index_scored_c = DECODE_INDEX_SCORED.labels(**lbl)
+        self._latent_selected_c = DECODE_LATENT_SELECTED.labels(**lbl)
+        self._latent_rule = getattr(make_cache, "latent_positions_selected",
+                                    None)
+        self._latent_layers = int(getattr(make_cache, "latent_layers", 0))
         self._kv_held_g = DECODE_KV_BYTES_HELD.labels(**lbl)
         self._kv_one_length_g = DECODE_KV_BYTES_ONE_LENGTH.labels(**lbl)
         # what the builder's steps count on the device (routed experts):
@@ -699,6 +718,9 @@ class DecodeServer:
             "sparse_positions_live": int(self._sparse_live_c.value),
             "window_positions_read": int(self._window_read_c.value),
             "window_positions_live": int(self._window_live_c.value),
+            "index_positions_scored": int(self._index_scored_c.value),
+            "latent_positions_selected": int(
+                self._latent_selected_c.value),
             "kv_bytes_held": int(self._kv_held_g.value),
             "kv_bytes_one_length": int(self._kv_one_length_g.value),
             "expert_assignments": int(self._expert_cs[0].value),
@@ -1530,6 +1552,13 @@ class DecodeServer:
             self._window_live_c.inc(
                 int((n * ran).sum()) * self._window_layers)
             self._window_read_c.inc(window_rows)
+        if self._latent_rule is not None and self._latent_layers:
+            n = ts + 1
+            self._index_scored_c.inc(
+                int((n * ran).sum()) * self._latent_layers)
+            self._latent_selected_c.inc(
+                int((self._latent_rule(n) * ran).sum())
+                * self._latent_layers)
         self._kv_live_c.inc(int((p1 * (p1 + 1) - p0 * (p0 + 1)).sum()) // 2)
         for (_, rec), p in zip(recs, p1.tolist()):
             rec.pos = p

@@ -326,3 +326,64 @@ def test_param_shapes_hold_gate_and_up_as_one_matrix():
     assert "lm_l0_experts_w13" not in shapes and "lm_l0_ffn_gate" in shapes
     assert "lm_l1_attn_q" in shapes and "lm_l1_conv_in" not in shapes
     assert "lm_head" not in shapes      # tied to lm_emb
+
+
+# ---------------------------------------------------------------------------
+# group-limited selection (``d.n_group`` > 1: ``latent_sparse_lm``)
+# ---------------------------------------------------------------------------
+def _grouped(d, n_group, topk_group):
+    from types import SimpleNamespace
+
+    return SimpleNamespace(**dict(vars(d), n_group=n_group,
+                                  topk_group=topk_group))
+
+
+@pytest.mark.parametrize("n_group,topk_group", [(8, 4), (4, 1), (16, 3)])
+def test_group_limited_route_equals_a_plain_loop(n_group, topk_group):
+    """Row by row in numpy: a group's score is the sum of its two largest
+    ``s + b``, the best ``topk_group`` groups stay, the top-k runs inside
+    them, and the weights are the bare sigmoids of the chosen."""
+    import jax.numpy as jnp
+
+    cfg = tiny_cfg()
+    d = _grouped(rx.dims(cfg), n_group, topk_group)
+    w = weights(cfg, seed=2, bias_range=0.3)
+    f, _ = _layer_inputs(cfg, n=16, seed=3)
+    sel, gate = (np.asarray(a) for a in rx.route(
+        f, w[P + "router"], w[P + "expert_bias"], d))
+    s = 1 / (1 + np.exp(-(np.asarray(f, np.float64)
+                          @ np.asarray(w[P + "router"], np.float64))))
+    z = s + np.asarray(w[P + "expert_bias"], np.float64)
+    size = d.n_expert // n_group
+    for n in range(16):
+        score = [np.sort(z[n, g * size:(g + 1) * size])[-2:].sum()
+                 for g in range(n_group)]
+        best = set(np.argsort(score)[::-1][:topk_group].tolist())
+        inside = [e for e in range(d.n_expert) if e // size in best]
+        want = sorted(inside, key=lambda e: -z[n, e])[:d.top_k]
+        assert sel[n].tolist() == want
+        np.testing.assert_allclose(gate[n], s[n, want] / (s[n, want].sum()
+                                                          + 1e-6), rtol=1e-5)
+    assert {int(e) // size for e in sel.reshape(-1)} <= set(range(n_group))
+    assert all(len({int(e) // size for e in row}) <= topk_group
+               for row in sel)
+
+
+def test_one_group_is_todays_route_bit_for_bit():
+    """``n_group`` 1 (or absent) leaves the function as it was: the same
+    choices and the same float32 weights, bit for bit."""
+    cfg = tiny_cfg()
+    d = rx.dims(cfg)
+    w = weights(cfg, seed=5, bias_range=0.3)
+    f, _ = _layer_inputs(cfg, n=32, seed=7)
+    assert not hasattr(d, "n_group")
+    sel0, gate0 = rx.route(f, w[P + "router"], w[P + "expert_bias"], d)
+    sel1, gate1 = rx.route(f, w[P + "router"], w[P + "expert_bias"],
+                           _grouped(d, 1, 1))
+    assert np.array_equal(np.asarray(sel0), np.asarray(sel1))
+    assert np.asarray(gate0).tobytes() == np.asarray(gate1).tobytes()
+    # ... and all the groups kept is no limit at all
+    sel8, gate8 = rx.route(f, w[P + "router"], w[P + "expert_bias"],
+                           _grouped(d, 8, 8))
+    assert np.array_equal(np.asarray(sel0), np.asarray(sel8))
+    assert np.asarray(gate0).tobytes() == np.asarray(gate8).tobytes()

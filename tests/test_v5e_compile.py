@@ -600,3 +600,51 @@ def test_olmo_hybrid_chunk_reads_the_full_layers_by_the_kernel_on_v5e(
         " scatter(" in r or " fusion(" in r or "dynamic-update-slice(" in r
         for r in others), others
     assert mem.alias_size_in_bytes >= 6 * 80 * 1024 * 3840 * 2
+
+
+@pytest.mark.parametrize("lanes,copied", [(640, False), (576, True)],
+                         ids=["whole_tiles", "as_wide_as_the_row"])
+def test_a_latent_leaf_of_whole_lane_tiles_is_not_copied_on_v5e(
+        one_chip, lanes, copied):
+    """Why ``latent_leaves`` rounds a row up to whole 128-lane tiles.  24
+    slots x 32768 positions of a 512 + 64 lane latent row
+    (``deepseek_v3_2``), eight steps of append, index scoring, top-2048
+    and selected read in one loop, as the pool's ``chunk`` runs them: a
+    leaf DECLARED 576 lanes wide (4.5 tiles) is re-laid sequence-minor
+    inside the loop and copied whole there and back (five 0.96 GB copies
+    in the cell's compiled chunk, which then did not fit the chip: PR
+    54); at 640 lanes the step's temporaries are a fraction of a leaf and
+    the index scoring's ``[24, 64, 32768]`` products never reach HBM."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import latent_sparse_lm as ls
+
+    s, t, h, row_w, top_k = 24, 32768, 128, 576, 2048
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def f(q, row, ki, qi, wi, latent, index_k, ts):
+        def body(i, carry):
+            kv = da.append_latent_rows(
+                {"latent": carry[0], "index_k": carry[1]}, row, ki, ts + i)
+            scores = ls.index_scores(qi, wi, kv["index_k"])
+            sel, valid = ls.select_positions(scores, ts + i, top_k)
+            u = da.selected_latent_attention(q, kv, ts + i, sel, valid,
+                                             d_value=512, scale=0.135)
+            return kv["latent"], kv["index_k"], carry[2] + u
+        return jax.lax.fori_loop(0, 8, body, (
+            latent, index_k, jnp.zeros((s, h, 512), jnp.float32)))
+
+    compiled = jax.jit(f, donate_argnums=(5, 6)).lower(
+        sd((s, h, row_w)), sd((s, row_w)), sd((s, 128)), sd((s, 64, 128)),
+        sd((s, 64)), sd((s, t, lanes), jnp.bfloat16),
+        sd((s, t, 128), jnp.bfloat16), sd((s,), jnp.int32)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    leaf = s * t * lanes * 2
+    assert (temp >= leaf) == copied
+    if not copied:
+        assert temp < leaf // 4
+        # the per-head index products stay inside their fusion
+        assert not _reads_of(compiled.as_text(), "f32[24,64,32768]")

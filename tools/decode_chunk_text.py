@@ -169,6 +169,24 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
             return decoding.make_mtp_routed_lm_pooled_step_fn(
                 w, cfg, kv_dtype=sv["kv_dtype"], held=held,
                 prefill_tokens=sv["prefill_tokens"])[:2]
+    elif cfg["family"] == "pooled_latent_sparse_lm":
+        from paddle_tpu import latent_sparse_lm
+
+        # the first ``layers`` of the cut (2: the dense layer and a sparse
+        # one, each latent attention behind its indexer; 5: the whole cut)
+        cfg["num_hidden_layers"] = layers
+        held = tuple(cfg["experts_held"])
+        # as the family makes them: matrices bf16, norms, the indexer's
+        # LayerNorm, routers and biases fp32
+        weights = {n: sd(shp, jnp.float32 if n.endswith(
+            latent_sparse_lm.FLOAT32_PARAMS) else jnp.bfloat16)
+            for n, shp in latent_sparse_lm.param_shapes(
+                cfg, held=held).items()}
+
+        def build(w):
+            return decoding.make_latent_sparse_lm_pooled_step_fn(
+                w, cfg, kv_dtype=sv["kv_dtype"], held=held,
+                prefill_tokens=sv["prefill_tokens"])[:2]
     elif cfg["family"] == "pooled_delta_hybrid_lm":
         from paddle_tpu import delta_hybrid_lm
 
@@ -311,16 +329,17 @@ def main():
                              "spec_chunk"),
                     help="prefill: the chunked-prefill program of a "
                     "builder that has one (minicpm_sala, "
-                    "smallthinker_21b_a3b); seat_prefill: the "
+                    "smallthinker_21b_a3b, deepseek_v3_2); seat_prefill: "
+                    "the "
                     "seat-and-prefill program of one with a batched "
                     "prefill (gpt1_117m); spec_chunk: the self-drafting "
                     "round of a builder with a multi-token-prediction "
                     "module (k_exaone_236b_a23b)")
     ap.add_argument("--layers", type=int, default=2,
                     help="layers compiled (8: the whole minicpm_sala or "
-                    "smallthinker_21b_a3b cut, 5: k_exaone_236b_a23b's, "
-                    "12: olmo_hybrid_7b's, to see that the real program "
-                    "fits the chip)")
+                    "smallthinker_21b_a3b cut, 5: k_exaone_236b_a23b's or "
+                    "deepseek_v3_2's, 12: olmo_hybrid_7b's, to see that "
+                    "the real program fits the chip)")
     args = ap.parse_args()
     lowered = lowered_chunk(os.path.abspath(args.repo), args.config,
                             args.kind, args.layers)
