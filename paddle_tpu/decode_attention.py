@@ -22,14 +22,18 @@ scorer reads), both sequence leaves
 to the pool (position ``p`` in row ``p``: sliced, snapshotted and seated
 like any other), appended in place by :func:`append_latent_rows`.  They
 are read by SELECTION: the step scores a slot's index keys, names at
-most ``k`` single positions a slot (the same for every head), and
+most ``k`` single positions a slot (the same for every head; a SET, in
+ascending position order: ``latent_sparse_lm.select_positions`` finds it
+by a threshold and a compaction, no sort of the rung —
+``decode_attention_index_select_lowered_total{path}``), and
 :func:`selected_latent_attention` attends to those rows of the latent
 leaf and to nothing else, in the ABSORBED form (queries already
 projected into the latent space; the context comes back in it) — the
 leaf is never expanded to heads.  One lowering today, plain XLA ops (a
-row gather and a masked softmax: ``decode_attention_latent_lowered_total
-{path}``); :func:`masked_latent_attention` is the contract whole over
-the rung, the tests' parity reference.
+row gather told its list is sorted and unique, and a masked softmax:
+``decode_attention_latent_lowered_total{path}``);
+:func:`masked_latent_attention` is the contract whole over the rung, the
+tests' parity reference.
 
 **The ring leaf.**  A layer whose queries read only the last ``W``
 positions (a sliding window that counts the query's own) keeps
@@ -156,7 +160,7 @@ __all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
            "RING_LOWERED", "GROUPED_LOWERED", "UNGROUPED_LOWERED",
            "latent_leaves", "append_latent_rows",
            "selected_latent_attention", "masked_latent_attention",
-           "pad_lanes", "LATENT_LOWERED"]
+           "pad_lanes", "LATENT_LOWERED", "INDEX_SELECT_LOWERED"]
 
 BLOCK_SPARSE_LOWERED = _registry.REGISTRY.counter(
     "block_sparse_lowered_total",
@@ -209,6 +213,15 @@ LATENT_LOWERED = _registry.REGISTRY.counter(
     "(traced into a program or run eagerly), by the lowering chosen: xla "
     "(a gather of the named rows and a masked softmax over them, "
     "absorbed: the leaf is never expanded to heads)", ("path",))
+
+INDEX_SELECT_LOWERED = _registry.REGISTRY.counter(
+    "decode_attention_index_select_lowered_total",
+    "selections of the positions a row reads over a latent layer's index "
+    "scores lowered (latent_sparse_lm.select_positions, traced into a "
+    "program or run eagerly), by the lowering chosen: threshold (the "
+    "k-th largest score found by counting, ties the lowest position "
+    "first, the chosen listed in ascending position order by "
+    "compaction: no sort of the rung)", ("path",))
 
 #: the sequence axis of every K/V leaf (and scale sibling)
 KV_SEQ_AXIS = 1
@@ -1066,12 +1079,20 @@ def selected_latent_attention(q, kv, ts, sel, valid, *, d_value: int,
     ``d_value`` lanes: ``[S, H, d_value]`` float32, zeros for a slot that
     reads nothing (idle: ``ts < 0``).  Products in the storage dtype,
     float32 accumulation and softmax; ``S * K`` rows leave HBM, not the
-    rung."""
+    rung.
+
+    PRECONDITION: every slot's list is ASCENDING, UNIQUE and IN RANGE
+    (``0 <= sel[s, j] < sel[s, j + 1] < T``), the places that are not
+    ``valid`` included — what ``latent_sparse_lm.select_positions``
+    returns.  The gather is told so (sorted, unique, in bounds); a list
+    in any other order reads rows nobody named."""
     import jax.numpy as jnp
 
     LATENT_LOWERED.labels(path="xla").inc()
     leaf = kv["latent"]
-    rows = jnp.take_along_axis(leaf, sel[:, :, None], axis=1)   # [S, K, D]
+    rows = leaf.at[jnp.arange(leaf.shape[0])[:, None], sel].get(
+        indices_are_sorted=True, unique_indices=True,
+        mode="promise_in_bounds")                               # [S, K, D]
     q = pad_lanes((q * scale).astype(leaf.dtype), leaf.shape[2])
     s = jnp.einsum("shd,skd->shk", q, rows,
                    preferred_element_type=jnp.float32)

@@ -56,8 +56,9 @@ from paddle_tpu.routed_experts import SIGMOID_BIAS, SILU
 
 __all__ = ["dims", "param_shapes", "random_state", "yarn_inv_freq",
            "softmax_scale", "rotate", "latent_inputs", "index_inputs",
-           "index_scores", "select_positions", "absorb_queries",
-           "attend_out", "chunk_select", "chunk_attend_expanded",
+           "index_scores", "top_members", "select_positions",
+           "absorb_queries", "attend_out", "chunk_select",
+           "chunk_attend_expanded",
            "LATENT_PROJECT_SCOPE", "INDEX_SCORE_SCOPE", "INDEX_SELECT_SCOPE",
            "LATENT_ATTEND_SCOPE", "PREFILL_CHUNK_SCOPE", "FLOAT32_PARAMS",
            "linear", "rms_norm", "swiglu"]
@@ -331,20 +332,134 @@ def index_scores(qi, wi, keys):
     return jnp.sum(jax.nn.relu(s) * wi.astype(f32)[:, :, None], axis=1)
 
 
+#: positions a block of the selection's two-level count: a lane tile (128
+#: of 128 / 256 on the chip: 0.106 / 0.120 ms a selection at ``[24,
+#: 32768]``, ``tools/time_index_select.py``, PR 55); at most 256 — a
+#: block's running counts pass through bf16, exact that far
+_SELECT_BLOCK = 128
+#: bits of a key settled by one pass of the threshold's search: a pass
+#: counts ``2 ** _RADIX_BITS - 1`` candidate thresholds in one read (2 of
+#: 1 / 2 / 4 on the chip: 0.125 / 0.105 / 0.162 ms)
+_RADIX_BITS = 2
+
+
+def _order_keys(scores):
+    """``scores`` float32 as int32 keys whose integer order is the
+    floats' TOTAL order (``-0.0`` under ``0.0``, as ``lax.top_k`` ranks
+    them)."""
+    import jax
+    import jax.numpy as jnp
+
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7fffffff), bits)
+
+
+def _kth_largest(keys, k: int):
+    """The ``k``-th largest of each row of int32 ``keys`` ``[N, T]``
+    (``k <= T``), ``[N, 1]``, without sorting: the largest ``t`` with
+    ``count(keys >= t) >= k``, its bits settled from the top down, each
+    pass one compare-and-count over the rows."""
+    import jax.numpy as jnp
+
+    u32 = jnp.uint32
+    ukeys = keys.astype(u32) ^ u32(0x80000000)      # unsigned, same order
+    least = jnp.zeros((keys.shape[0], 1), u32)
+    steps = jnp.arange(1, 2 ** _RADIX_BITS, dtype=u32)[:, None, None]
+    for shift in range(32 - _RADIX_BITS, -1, -_RADIX_BITS):
+        # candidates least | j << shift, j = 1 ..: counts fall with j
+        cands = least[None] | (steps << u32(shift))
+        enough = jnp.sum(ukeys[None] >= cands, axis=-1,
+                         dtype=jnp.int32) >= k           # [J, N]
+        least = least | (jnp.sum(enough, axis=0, dtype=u32)[:, None]
+                         << u32(shift))
+    return (least ^ u32(0x80000000)).astype(jnp.int32)
+
+
+def top_members(scores, live, k: int):
+    """WHICH positions of each row are its ``min(k, live)`` of largest
+    score — THE threshold and THE tie rule of this family, the step's
+    list (:func:`select_positions`) and the chunk's mask
+    (:func:`chunk_select`) alike: ``scores`` ``[N, T]`` float32, ``live``
+    ``[N, T]`` bool (what is not live is never chosen), ``k <= T``.  A
+    position is chosen if it is live and its score is above the row's
+    ``k``-th largest live score, or equal to it and among the first such
+    positions that still fit (ties: the LOWEST position first,
+    ``lax.top_k``'s rule; scores compare in the floats' total order).  No
+    sort: the threshold is searched by counting (:func:`_kth_largest`),
+    the ties are ranked by a two-level running count.
+
+    Returns ``(member, within, counts)`` over blocks of ``B`` positions
+    (a divisor of ``T``, a lane tile where it divides): ``member`` ``[N,
+    T / B, B]`` bool, ``within`` ``[N, T / B, B]`` int32 the chosen
+    positions of a block up to and with each place, ``counts`` ``[N, T /
+    B]`` int32 a block's chosen."""
+    import jax.numpy as jnp
+
+    n, t = scores.shape
+    b = _blocks(t, _SELECT_BLOCK)
+    keys = _order_keys(jnp.where(live, scores, -jnp.inf))
+    least = _kth_largest(keys, k)
+    above = (keys > least).reshape(n, t // b, b)
+    ties = ((keys == least) & live).reshape(n, t // b, b)
+    # both masks' running counts inside a block: one product with a
+    # triangle of ones (0 / 1 in, integers <= B out: exact)
+    upto = (jnp.arange(b)[:, None] <= jnp.arange(b)[None, :]).astype(
+        jnp.bfloat16)
+    above_run, ties_run = jnp.einsum(
+        "cnbl,lm->cnbm", jnp.stack([above, ties]).astype(jnp.bfloat16),
+        upto, preferred_element_type=jnp.float32).astype(jnp.int32)
+    room = k - jnp.sum(above_run[..., -1], axis=-1)[:, None, None]
+    ties_a_block = ties_run[..., -1]
+    earlier = (jnp.cumsum(ties_a_block, axis=-1) - ties_a_block)[..., None]
+    rank = earlier + ties_run           # a tie's place among its row's ties
+    member = above | (ties & (rank <= room))
+    within = (above_run + jnp.minimum(rank, room)
+              - jnp.minimum(earlier, room))
+    return member, within, within[..., -1]
+
+
 def select_positions(scores, ts, top_k: int):
     """The positions a row at ``ts`` reads: ``(sel [N, k] int32, valid
     [N, k] bool)``, ``k = min(top_k, T)`` — the ``min(top_k, ts + 1)``
     positions ``<= ts`` of largest ``scores`` ``[N, T]`` (ties: the
-    lowest position first, ``lax.top_k``'s order), the rest of the list
-    not ``valid`` (an idle row, ``ts < 0``: none is)."""
-    import jax
+    lowest position first), as a SET IN ASCENDING POSITION ORDER: the
+    valid part of the list is strictly ascending, and the whole list is
+    ascending, unique and in range whatever ``ts`` (a row with fewer
+    than ``k`` live positions, an idle row at ``ts < 0`` among them,
+    lists ``arange(k)``, the first ``ts + 1`` of them ``valid``).  The
+    order is part of the contract: ``selected_latent_attention`` tells
+    the compiler its list is sorted and unique.  It is NOT the order of
+    the scores — nothing ranks the chosen (attention over a list does not
+    depend on its order).  The set is :func:`top_members`'s, found by a
+    threshold and listed by compaction, no sort and no scatter: a slot
+    ``j`` of the list finds its block by comparing with the blocks'
+    running counts, a one-hot product picks that block's running count,
+    and a comparison over its places finds the position."""
     import jax.numpy as jnp
 
+    from paddle_tpu.decode_attention import INDEX_SELECT_LOWERED
+
+    INDEX_SELECT_LOWERED.labels(path="threshold").inc()
+    i32 = jnp.int32
     t = scores.shape[1]
+    k = min(int(top_k), t)
     live = jnp.arange(t)[None, :] <= ts[:, None]
-    top, sel = jax.lax.top_k(jnp.where(live, scores, -jnp.inf),
-                             min(int(top_k), t))
-    return sel.astype(jnp.int32), top > -jnp.inf
+    _, within, counts = top_members(scores, live, k)
+    b = within.shape[2]
+    listed = jnp.arange(k, dtype=i32)[None, :]                  # [1, k]
+    slots = listed[..., None]
+    ends = jnp.cumsum(counts, axis=-1)[:, None, :]              # [N, 1, nb]
+    starts = ends - counts[:, None, :]
+    mine = (starts <= slots) & (slots < ends)                   # [N, k, nb]
+    block = jnp.sum(ends <= slots, axis=-1, dtype=i32)
+    place = listed - jnp.max(jnp.where(mine, starts, 0), axis=-1)
+    run = jnp.einsum("nkb,nbl->nkl", mine.astype(jnp.bfloat16),
+                     within.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32).astype(i32)
+    lane = jnp.sum(run <= place[..., None], axis=-1, dtype=i32)
+    # at most k live: every live position, as they lie
+    sel = jnp.where(ts[:, None] < k, listed, block * b + lane)
+    return sel, listed <= ts[:, None]
 
 
 def absorb_queries(qc, qr, w, p: str, d):
@@ -402,14 +517,7 @@ def chunk_select(qi, wi, keys, q_pos, n_keys, top_k: int,
     scores = jax.lax.fori_loop(0, (n_keys + kb - 1) // kb, body,
                                jnp.full((c, t), -jnp.inf, f32))
     live = jnp.arange(t)[None, :] <= q_pos[:, None]
-    scores = jnp.where(live, scores, -jnp.inf)
-    k = min(int(top_k), t)
-    # the k-th largest score; what ties with it enters lowest first
-    least = jax.lax.top_k(scores, k)[0][:, -1:]
-    above = scores > least
-    ties = (scores == least) & live
-    room = k - jnp.sum(above, axis=-1, keepdims=True)
-    return (above | (ties & (jnp.cumsum(ties, axis=-1) <= room))) & live
+    return top_members(scores, live, min(int(top_k), t))[0].reshape(c, t)
 
 
 def chunk_attend_expanded(qc, qr, rows, member, n_keys, w, p: str, d,

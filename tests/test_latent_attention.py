@@ -53,9 +53,10 @@ def test_the_selected_read_equals_the_masked_form_and_numpy(dtype, atol):
     q = jnp.asarray(rng.randn(S, H, DC + DR).astype("float32"))
     ts = jnp.asarray([30, 3, -1, 39], jnp.int32)
     k = 8
-    # a list of distinct positions a slot: some past ts, some not valid
-    sel = np.stack([rng.permutation(T)[:k] for _ in range(S)]).astype(
-        np.int32)
+    # a slot's list as ``select_positions`` gives it — ascending, unique,
+    # in range (the read's precondition): some past ts, some not valid
+    sel = np.stack([np.sort(rng.permutation(T)[:k])
+                    for _ in range(S)]).astype(np.int32)
     valid = rng.rand(S, k) < 0.8
     before = da.LATENT_LOWERED.labels(path="xla").value
     got = np.asarray(da.selected_latent_attention(
@@ -81,3 +82,38 @@ def test_the_selected_read_equals_the_masked_form_and_numpy(dtype, atol):
         want = (p / p.sum(-1, keepdims=True)) @ rows[s, at, :DC]
         np.testing.assert_allclose(got[s], want, atol=max(atol, 1e-5) * 3)
     assert got.shape == (S, H, DC) and not got[2].any()
+
+
+@pytest.mark.parametrize("dtype,atol", [("float32", 2e-6),
+                                        ("bfloat16", 2e-2)])
+def test_the_read_of_a_selected_list_equals_the_masked_form(dtype, atol):
+    """The step's pair as it runs: ``select_positions`` lists a slot's
+    best positions in ascending order (its lowering counted: ``threshold``)
+    and the selected read, told the list is sorted and unique, gives what
+    the masked form gives over the same SET — rows idle, under ``k`` live
+    and far past it."""
+    import jax.numpy as jnp
+
+    from paddle_tpu import latent_sparse_lm as ls
+
+    kv, rng = _filled(dtype, seed=2)
+    q = jnp.asarray(rng.randn(S, H, DC + DR).astype("float32"))
+    ts = jnp.asarray([30, 3, -1, 39], jnp.int32)
+    k = 8
+    scores = jnp.asarray(np.round(rng.randn(S, T) * 2) / 2, jnp.float32)
+    before = da.INDEX_SELECT_LOWERED.labels(path="threshold").value
+    sel, valid = ls.select_positions(scores, ts, k)
+    assert da.INDEX_SELECT_LOWERED.labels(
+        path="threshold").value == before + 1
+    got = np.asarray(da.selected_latent_attention(
+        q, kv, ts, sel, valid, d_value=DC, scale=0.3))
+    sel, valid = np.asarray(sel), np.asarray(valid)
+    assert (np.diff(sel, axis=1) > 0).all()
+    allowed = np.zeros((S, T), bool)
+    for s in range(S):
+        allowed[s, sel[s][valid[s]]] = True
+    assert allowed.sum(1).tolist() == [8, 4, 0, 8]
+    masked = np.asarray(da.masked_latent_attention(
+        q, kv, jnp.asarray(allowed), d_value=DC, scale=0.3))
+    np.testing.assert_allclose(got, masked, atol=atol)
+    assert not got[2].any()

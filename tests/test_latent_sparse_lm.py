@@ -242,6 +242,66 @@ def test_the_chunks_selection_is_the_steps_list_as_a_mask():
     assert member.sum(-1).tolist() == [4, 6, 6, 6, 6, 6, 6, 0]
 
 
+def _scores(kind, n, t, rng):
+    x = rng.randn(n, t)
+    if kind == "halves":            # thousands of ties at the threshold
+        x = np.round(x * 2) / 2
+    elif kind == "few_values":
+        x = rng.randint(0, 3, (n, t)) - 1.0
+    elif kind == "all_equal":
+        x = np.full((n, t), 0.25)
+    elif kind == "negative":
+        x = -np.abs(x) - 0.5
+    elif kind == "mixed_sign":      # ties on both sides of zero
+        x = np.round(x * 3) * 1e3
+    elif kind == "signed_zeros":    # the floats' total order: -0.0 < 0.0
+        x = np.where(rng.rand(n, t) < 0.5, 0.0, -0.0) * (
+            rng.rand(n, t) < 0.7) + (rng.rand(n, t) < 0.05) * x
+    return x.astype("float32")
+
+
+@pytest.mark.parametrize("kind", ["random", "halves", "few_values",
+                                  "all_equal", "negative", "mixed_sign",
+                                  "signed_zeros"])
+@pytest.mark.parametrize("t,k", [(256, 32), (200, 24), (40, 6), (96, 200)],
+                         ids=["rung256", "rung200_no_power_of_two",
+                              "rung40_one_block", "top_k_over_the_rung"])
+def test_the_selection_is_lax_top_ks_set_in_position_order(kind, t, k):
+    """``select_positions`` against ``lax.top_k`` of the masked scores
+    (ties: the lowest position first): the same SET a row — rows idle, at
+    ``ts`` 0, one under ``top_k`` live, exactly ``top_k`` live, one over,
+    mid-rung and at the rung's end —; ``sel[valid]`` strictly ascending,
+    the WHOLE list ascending (so unique) and in range, ``valid`` the first
+    ``min(k, ts + 1)`` places; and the counter of its lowering moves."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import decode_attention as da
+
+    kk = min(k, t)
+    ts = np.minimum(np.asarray([-1, 0, kk - 2, kk - 1, kk, t // 2 + 3, t - 1],
+                               np.int32), t - 1)
+    rng = np.random.RandomState(len(kind) * 1000 + t)
+    scores = jnp.asarray(_scores(kind, len(ts), t, rng))
+    before = da.INDEX_SELECT_LOWERED.labels(path="threshold").value
+    sel, valid = (np.asarray(x) for x in ls.select_positions(
+        scores, jnp.asarray(ts), k))
+    assert da.INDEX_SELECT_LOWERED.labels(
+        path="threshold").value == before + 1
+    live = np.arange(t)[None, :] <= ts[:, None]
+    top, want = jax.lax.top_k(jnp.where(live, scores, -jnp.inf), kk)
+    top, want = np.asarray(top), np.asarray(want)
+    assert sel.shape == valid.shape == (len(ts), kk)
+    assert sel.dtype == np.int32 and valid.dtype == bool
+    for r in range(len(ts)):
+        n_live = min(kk, max(int(ts[r]) + 1, 0))
+        assert valid[r].sum() == n_live and valid[r, :n_live].all()
+        assert sel[r][valid[r]].tolist() == sorted(
+            want[r][top[r] > -np.inf].tolist())
+        assert (np.diff(sel[r]) > 0).all(), sel[r]
+        assert sel[r, 0] >= 0 and sel[r, -1] < t
+
+
 def test_the_shares_of_the_grouped_layer_add_up():
     """model-configs section 4's test at this family's routing: the
     shares four chips holding 4 experts each give, the shared expert

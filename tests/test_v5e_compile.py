@@ -614,7 +614,11 @@ def test_a_latent_leaf_of_whole_lane_tiles_is_not_copied_on_v5e(
     inside the loop and copied whole there and back (five 0.96 GB copies
     in the cell's compiled chunk, which then did not fit the chip: PR
     54); at 640 lanes the step's temporaries are a fraction of a leaf and
-    the index scoring's ``[24, 64, 32768]`` products never reach HBM."""
+    the index scoring's ``[24, 64, 32768]`` products never reach HBM.
+    Since PR 55 the selection is a threshold and a compaction: no
+    ``sort`` of the rung is left in the loop (there were 75.7 MB of
+    temporaries with ``lax.top_k``'s, most of them the sort's operands),
+    and the read's gather is told its list is sorted."""
     import jax
     import jax.numpy as jnp
 
@@ -644,7 +648,12 @@ def test_a_latent_leaf_of_whole_lane_tiles_is_not_copied_on_v5e(
     temp = compiled.memory_analysis().temp_size_in_bytes
     leaf = s * t * lanes * 2
     assert (temp >= leaf) == copied
+    text = compiled.as_text()
+    assert " sort(" not in text
+    gathers = [line for line in text.splitlines() if " gather(" in line]
+    assert gathers and all("indices_are_sorted=true" in g for g in gathers)
     if not copied:
         assert temp < leaf // 4
+        assert temp <= 75_700_000       # what the sorting form held
         # the per-head index products stay inside their fusion
-        assert not _reads_of(compiled.as_text(), "f32[24,64,32768]")
+        assert not _reads_of(text, "f32[24,64,32768]")
