@@ -80,6 +80,7 @@ __all__ = [
     "make_windowed_routed_lm_pooled_step_fn",
     "make_mtp_routed_lm_pooled_step_fn",
     "make_delta_hybrid_lm_pooled_step_fn",
+    "make_kda_routed_lm_pooled_step_fn",
     "make_latent_sparse_lm_pooled_step_fn",
     "cache_leaf_seq_axes", "cache_leaf_seq_strides", "cache_leaf_seq_windows",
     "cache_leaf_slotless", "NO_SLOT_AXIS",
@@ -1672,6 +1673,123 @@ def make_delta_hybrid_lm_pooled_step_fn(state, cfg, name: str = "lm",
         logits = dh.linear(dh.rms_norm(h, W[name + "_final_norm"], d.eps),
                            W[name + "_head"])
         return logits, new_cache
+
+    return step_fn, make_cache
+
+
+def make_kda_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
+                                      kv_dtype: str = "bf16", held=None):
+    """The slot-pooled step of a decoder whose layers are KIMI DELTA
+    ATTENTION (a gated delta rule whose decay is one factor a key CHANNEL
+    of a head) or gated position-free grouped-query attention, pre-norm,
+    EVERY layer followed by routed experts beside a shared expert
+    (``model_type: solar_open2``; the parts and the equations are
+    ``paddle_tpu.delta_hybrid_lm``'s ``kda_*``, the expert layer
+    ``paddle_tpu.routed_experts``).
+
+    Same contract as the builders above: ``step_fn(cache, tokens [N]
+    int32, ts [N] int32) -> (logits [N, V] fp32, cache)`` with ``ts[i] <
+    0`` an idle row, and ``make_cache(n_rows, seq_len)``.  ``state``:
+    weights under ``delta_hybrid_lm.kda_param_shapes(cfg, held=held)``,
+    multiplied in the dtype they are given (router, bias, norms, the conv
+    kernel, ``A_log`` and ``dt_bias`` float32); ``held``: the contiguous
+    range of experts whose matrices ``state`` holds — every layer routes
+    over all of them and adds what the held ones give, plus its shared
+    expert.
+
+    The cache is ``{"layers": [...], "expert_stats": ...}``:
+
+    * a G layer ``k``, ``v`` ``[N, T, n_kv_head * head_dim]`` in
+      ``kv_dtype``, ``decode_attention``'s format through
+      ``make_decode_attention`` (grouped heads of whole lane tiles over
+      bf16 leaves on a TPU: the grouped kernel's read of what is live;
+      ``make_cache.kv_positions_read`` tells the server its rounding);
+      the sigmoid gate is applied to the read's output;
+    * a K layer ``state`` ``[N, H / g, dk, g * dv]`` float32 and ``conv``
+      ``[N, K - 1, 2 H dk + H dv]`` float32: RECURRENT (``-1``), as
+      :func:`make_delta_hybrid_lm_pooled_step_fn`'s;
+    * ``expert_stats`` ``[layers, 4]`` int32 (``NO_SLOT_AXIS``), as
+      :func:`make_routed_conv_lm_pooled_step_fn`'s; ``make_cache.n_expert``
+      counts the experts HELD (what the counts' groups are over).
+
+    Prompts walk the one-token step (the delta rule's chunkwise form is
+    not built), so ``KVSlotPool`` refuses ``prefix=True`` and
+    ``speculative=`` over this builder.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import delta_hybrid_lm as dh
+    from paddle_tpu import routed_experts as rx
+    from paddle_tpu.decode_attention import (kv_leaves, make_decode_attention,
+                                             step_positions_read)
+
+    d = dh.kda_dims(cfg)
+    kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
+    W = {k: jnp.asarray(v) for k, v in state.items()}
+    scale = 1.0 / float(np.sqrt(d.head_dim))
+    full_at = [i for i, kind in enumerate(d.kinds) if kind == dh.FULL]
+    n_stats = len(rx.STAT_NAMES)
+
+    def make_cache(n_rows: int, seq_len: int):
+        return {
+            "layers": [
+                kv_leaves(n_rows, seq_len, d.n_kv_head, d.head_dim, kv)
+                if kind == dh.FULL else
+                {"state": jnp.zeros((n_rows,) + d.state_shape, jnp.float32),
+                 "conv": jnp.zeros((n_rows, d.conv_len - 1, d.d_qkv),
+                                   jnp.float32)}
+                for kind in d.kinds],
+            "expert_stats": jnp.zeros((d.n_layer, n_stats), jnp.int32)}
+
+    make_cache.leaf_seq_axes = {
+        "layers": [{"k": 1, "v": 1} if kind == dh.FULL
+                   else {"state": -1, "conv": -1} for kind in d.kinds],
+        "expert_stats": NO_SLOT_AXIS}
+    make_cache.expert_stats = lambda cache: cache["expert_stats"]
+    make_cache.n_expert = (d.n_expert if held is None
+                           else int(held[1]) - int(held[0]))
+    make_cache.kv_positions_read = functools.partial(
+        step_positions_read, width=d.d_kv, dtype=kv, n_head=d.n_head,
+        n_kv_head=d.n_kv_head)
+
+    def step_fn(cache, tokens, ts):
+        layers = cache["layers"]
+        attend = None
+        if full_at:
+            ts = jnp.minimum(ts, layers[full_at[0]]["k"].shape[1] - 1)
+            attend = make_decode_attention(
+                ts, layers[full_at[0]], n_head=d.n_head,
+                n_kv_head=d.n_kv_head, scale=scale)
+        pos = jnp.maximum(ts, 0)      # idle rows stay < 0 in ``ts``
+        h = W[name + "_emb"][tokens].astype(jnp.float32)
+        new_layers, stats = [], []
+        for i, kind in enumerate(d.kinds):
+            p = "%s_l%d_" % (name, i)
+            c = layers[i]
+            r = dh.rms_norm(h, W[p + "mixer_norm"], d.eps)
+            if kind == dh.LINEAR:
+                o, s, conv = dh.kda_layer_step(r, W, p, c["state"], c["conv"],
+                                               ts, d)
+                new_layers.append({"state": s, "conv": conv})
+            else:
+                with jax.named_scope(dh.FULL_ATTENTION_SCOPE):
+                    q, k, v, gate = dh.gated_attention_rows(r, W, p, pos, d)
+                    ctx, kvs = attend(q, k, v, c)
+                    if gate is not None:
+                        ctx = ctx * gate
+                o = dh.linear(ctx, W[p + "attn_o"])
+                new_layers.append(kvs)
+            h = h + o
+            y, st = rx.expert_layer(dh.rms_norm(h, W[p + "ffn_norm"], d.eps),
+                                    W, p, ts, d, held)
+            h = h + y
+            stats.append(st)
+        logits = dh.linear(dh.rms_norm(h, W[name + "_final_norm"], d.eps),
+                           W[name + "_head"])
+        return logits, {"layers": new_layers,
+                        "expert_stats": cache["expert_stats"]
+                        + jnp.stack(stats)}
 
     return step_fn, make_cache
 

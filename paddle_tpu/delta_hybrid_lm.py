@@ -65,7 +65,37 @@ flag):
   CPU, a leaf that is not float32, a leaf padded by the tiled layout.
 
 ``delta_update_lowered_total{path}`` (``kernel`` | ``xla``) counts the
-updates traced, by the form taken.
+updates traced, by the form taken, and ``delta_update_decay_total{decay}``
+(``head`` | ``channel``) by the decay's contract.
+
+KIMI DELTA ATTENTION beside gated position-free GQA and routed experts
+(``model_type: solar_open2``; the ``kda_*`` functions below; Kimi Linear,
+arXiv:2510.26692) is the same rule with a decay a CHANNEL.  Pre-norm,
+eps 1e-5, a final RMSNorm, an untied head, no bias anywhere::
+
+    h = x + mixer(RMS(x));   y = h + moe(RMS(h))
+
+    K layer, per head (dk = dv), state S [dk, dv]:
+      q, k, v = silu(causal depthwise conv, kernel K, of W_q x, W_k x, W_v x)
+      q = q / ||q|| * dk^-1/2,   k = k / ||k||
+      alpha = exp(-exp(A_log[h]) * softplus(W_fb (W_fa x) + dt_bias))
+                                       # in (0, 1)^dk: one factor a CHANNEL
+      beta  = sigmoid(W_b x)           (x 2 where ``kda_allow_neg_eigval``)
+      S <- Diag(alpha) S               # row i of S times alpha_i
+      u = S^T k;  S <- S + k (beta (v - u))^T;  o = S^T q
+      out = W_o [RMS_dv(o) * sigmoid(W_gb (W_ga x))]
+    G layer: q = W_q x (n_head x Dh), k, v = W_k x, W_v x (n_kv_head x Dh),
+      NO rotary (``use_rope: false``), causal softmax at Dh^-1/2,
+      out = W_o [attn * sigmoid(W_g x)]      (``use_gqa_gate``)
+    MoE, after EVERY mixer: ``routed_experts.expert_layer`` (sigmoid
+      scores, a selection bias, the top_k's scores normalised) beside ONE
+      shared expert.
+
+The two low-rank pairs (``W_fa``/``W_fb`` for the decay, ``W_ga``/``W_gb``
+for the output gate: d_model -> head_dim -> H dk; ``kda_use_full_proj``
+false) are two plain products each; the three convolutions are ONE
+(:func:`qkv_conv_step`) over the concatenated channels.
+``decoding.make_kda_routed_lm_pooled_step_fn`` strings these parts.
 """
 from __future__ import annotations
 
@@ -77,14 +107,18 @@ import numpy as np
 from paddle_tpu.hybrid_ssm import (linear, rms_norm, rotary, starts_fresh,
                                    swiglu)
 from paddle_tpu.monitor import registry as _registry
+from paddle_tpu.routed_experts import SIGMOID_BIAS, SILU
 
 __all__ = ["LINEAR", "FULL", "DELTA_UPDATE_SCOPE", "SHORT_CONV_SCOPE",
            "FLOAT32_PARAMS", "dims", "param_shapes", "random_state",
            "heads_per_tile", "qkv_conv_step", "l2_norm", "decay_and_step_gates",
            "gated_delta_step", "xla_gated_delta_step",
            "kernel_gated_delta_step", "lowering", "LOWERED", "KERNEL_NAME",
-           "gated_output_norm", "delta_layer_step",
-           "full_attention_rows", "linear", "rms_norm", "rotary",
+           "gated_output_norm", "conv_qkv", "delta_layer_step",
+           "full_attention_rows", "DECAY", "CHANNEL_GATES_SCOPE",
+           "FULL_ATTENTION_SCOPE", "KDA_FLOAT32_PARAMS", "kda_dims",
+           "kda_param_shapes", "kda_random_state", "channel_decay",
+           "kda_layer_step", "gated_attention_rows", "linear", "rms_norm", "rotary",
            "starts_fresh", "swiglu"]
 
 #: ``layer_types`` entries
@@ -93,6 +127,8 @@ LINEAR, FULL = "linear_attention", "full_attention"
 #: ``jax.named_scope`` names, for the device trace
 DELTA_UPDATE_SCOPE = "delta_state_update"
 SHORT_CONV_SCOPE = "delta_short_conv"
+CHANNEL_GATES_SCOPE = "delta_channel_gates"
+FULL_ATTENTION_SCOPE = "gated_full_attention"
 
 #: endings of the parameters kept in float32 whatever the matrices are
 FLOAT32_PARAMS = ("norm", "lin_conv_w", "lin_A_log", "lin_dt_bias")
@@ -110,6 +146,11 @@ LOWERED = _registry.REGISTRY.counter(
     "run eagerly), by the lowering chosen: kernel (Pallas TPU: each "
     "block of the state leaf read once and written once, in place) | "
     "xla (two fusions: the state read twice and written once)", ("path",))
+DECAY = _registry.REGISTRY.counter(
+    "delta_update_decay_total",
+    "gated delta-rule state updates lowered, by the decay's contract: head "
+    "(one factor a head, alpha [N, H]) | channel (one a key channel of a "
+    "head, alpha [N, H, dk]: Kimi Delta Attention)", ("decay",))
 
 
 def heads_per_tile(n_head: int, dv: int) -> int:
@@ -196,6 +237,21 @@ def param_shapes(cfg, name: str = "lm") -> dict:
     return out
 
 
+def _random_vector(rng, key: str, shp):
+    """A float32 parameter (:data:`FLOAT32_PARAMS`) of a random state:
+    unit norms, the reference layer's ``A_log`` = log U(1e-3, 16) and
+    ``dt_bias`` = inverse softplus of a step log-uniform in [1e-3, 1e-1],
+    a conv kernel uniform in +-1 / sqrt(K)."""
+    if key.endswith("norm"):
+        return np.ones(shp, "float32")
+    if key.endswith("lin_A_log"):
+        return np.log(rng.uniform(1e-3, 16.0, shp)).astype("float32")
+    if key.endswith("lin_dt_bias"):
+        dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), shp))
+        return (dt + np.log(-np.expm1(-dt))).astype("float32")
+    return rng.uniform(-1, 1, shp).astype("float32") / np.sqrt(shp[0])
+
+
 def random_state(rng, cfg, name: str = "lm", std: float = 0.02,
                  dtype="float32") -> dict:
     """Seeded random weights under :func:`param_shapes` (tests): normal
@@ -209,15 +265,8 @@ def random_state(rng, cfg, name: str = "lm", std: float = 0.02,
 
     w = {}
     for k, shp in param_shapes(cfg, name).items():
-        if k.endswith("norm"):
-            w[k] = np.ones(shp, "float32")
-        elif k.endswith("lin_A_log"):
-            w[k] = np.log(rng.uniform(1e-3, 16.0, shp)).astype("float32")
-        elif k.endswith("lin_dt_bias"):
-            dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), shp))
-            w[k] = (dt + np.log(-np.expm1(-dt))).astype("float32")
-        elif k.endswith("lin_conv_w"):
-            w[k] = rng.uniform(-1, 1, shp).astype("float32") / np.sqrt(shp[0])
+        if k.endswith(FLOAT32_PARAMS):
+            w[k] = _random_vector(rng, k, shp)
         else:
             s = 1.0 if k.endswith("_emb") else std
             w[k] = jnp.asarray((rng.randn(*shp) * s).astype("float32"), dtype)
@@ -312,14 +361,18 @@ def gated_delta_step(q, k, v, alpha, beta, s, ts):
     """One token of the gated delta rule for every row and head.
 
     ``q``, ``k`` ``[N, H, dk]`` (normalised), ``v`` ``[N, H, dv]``,
-    ``alpha``, ``beta`` ``[N, H]``, all float32; ``s`` the state leaf
-    ``[N, H / g, dk, g * dv]`` (``g`` read from its shape); ``ts`` ``[N]``
-    (``< 0`` idle: state kept, ``0`` fresh: state read as zero).  Returns
-    ``(o [N, H, dv], s)``.  :func:`lowering` chooses the form."""
+    ``beta`` ``[N, H]``, all float32; ``alpha`` the decay, ``[N, H]`` (one
+    factor a head: ``S <- alpha S``) or ``[N, H, dk]`` (one a key channel:
+    ``S <- Diag(alpha) S``, row ``i`` of a head's state times
+    ``alpha_i``) — read from its shape, never a flag; ``s`` the state
+    leaf ``[N, H / g, dk, g * dv]`` (``g`` read from its shape); ``ts``
+    ``[N]`` (``< 0`` idle: state kept, ``0`` fresh: state read as zero).
+    Returns ``(o [N, H, dv], s)``.  :func:`lowering` chooses the form."""
     import jax
 
     path = lowering(jax.default_backend(), s, v.shape[-1])
     LOWERED.labels(path=path).inc()
+    DECAY.labels(decay="channel" if alpha.ndim == 3 else "head").inc()
     with jax.named_scope(DELTA_UPDATE_SCOPE):
         if path == "kernel":
             return kernel_gated_delta_step(q, k, v, alpha, beta, s, ts)
@@ -339,7 +392,7 @@ def xla_gated_delta_step(q, k, v, alpha, beta, s, ts):
     wide = functools.partial(_over_lanes, g=g, dv=dv)
     kk, qq = wide(k), wide(q)
     s_prev = jnp.where(fresh[:, None, None, None], 0.0, s.astype(f32))
-    s_dec = wide(alpha[..., None]) * s_prev
+    s_dec = wide(alpha if alpha.ndim == 3 else alpha[..., None]) * s_prev
     u = jnp.sum(s_dec * kk, axis=2)                     # [N, H / g, g * dv]
     delta = wide(beta[..., None])[:, :, 0] * (v.reshape(u.shape) - u)
     s_new = s_dec + kk * delta[:, :, None, :]
@@ -394,18 +447,26 @@ def _delta_update(q, k, v, alpha, beta, s, kinds, *, block, interpret):
     # [H / g, N / 128, 2 g, dk, 128] (a tile of 128 slots a block index),
     # and the kernel broadcasts a slot's lane over a tile.  ``v`` is a row
     # of the leaf's own lane axis, [H / g, N, g * dv]; the gates are
-    # scalars a (slot, head), in SMEM beside the rows' kinds.
+    # scalars a (slot, head), in SMEM beside the rows' kinds.  A decay a
+    # CHANNEL is no scalar: it rides as a third column beside k and q
+    # ([.., 3 g, dk, 128]) and is broadcast over a head's lanes as they are.
+    channel = alpha.ndim == 3
     tiles = -(-n // _LANES)
-    cols = jnp.concatenate([k.reshape(n, pairs, g, dk),
-                            q.reshape(n, pairs, g, dk)], axis=2)
+    cols = jnp.concatenate(
+        [x.reshape(n, pairs, g, dk)
+         for x in ((k, q, alpha) if channel else (k, q))], axis=2)
     cols = jnp.pad(cols.transpose(1, 2, 3, 0),
                    ((0, 0),) * 3 + ((0, tiles * _LANES - n),))
-    cols = cols.reshape(pairs, 2 * g, dk, tiles, _LANES).transpose(
+    n_cols = cols.shape[1]
+    cols = cols.reshape(pairs, n_cols, dk, tiles, _LANES).transpose(
         0, 3, 1, 2, 4)
     rows = v.reshape(n, pairs, lanes).transpose(1, 0, 2)
 
-    def kernel(kind_ref, alpha_ref, beta_ref, cols_ref, v_ref, s_ref, o_ref,
-               s_out_ref):
+    def kernel(kind_ref, *refs):
+        # the gates in SMEM (alpha where it is one a head, beta), then the
+        # blocks
+        gate_refs, (cols_ref, v_ref, s_ref, o_ref, s_out_ref) = (
+            refs[:-5], refs[-5:])
         pair, first = pl.program_id(0), pl.program_id(1) * bn
         lane = jax.lax.broadcasted_iota(jnp.int32, (dk, _LANES), 1)
         row_lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
@@ -441,7 +502,10 @@ def _delta_update(q, k, v, alpha, beta, s, kinds, *, block, interpret):
             kind = kind_ref[first + m]
             at = jnp.full((dk, _LANES), first % _LANES + m, jnp.int32)
             kk, qq = over_lanes(0, at), over_lanes(g, at)
-            a, b = gate_row(alpha_ref, m), gate_row(beta_ref, m)
+            if channel:
+                a, b = over_lanes(2 * g, at), gate_row(gate_refs[0], m)
+            else:
+                a, b = gate_row(gate_refs[0], m), gate_row(gate_refs[1], m)
             s_old = s_ref[m]
             s_dec = a * jnp.where(kind == 0, 0.0, s_old)
             u = jnp.sum(s_dec * kk, axis=0, keepdims=True)
@@ -453,23 +517,26 @@ def _delta_update(q, k, v, alpha, beta, s, kinds, *, block, interpret):
 
         jax.lax.fori_loop(0, bn, slot, 0)
 
-    def leaf_block(j, i, *scalars):
+    def leaf_block(j, i, *_):
         return (i, j, 0, 0)
 
-    def row_block(j, i, *scalars):
+    def row_block(j, i, *_):
         return (j, i, 0)
+
+    scalars = ((kinds, beta.reshape(-1)) if channel
+               else (kinds, alpha.reshape(-1), beta.reshape(-1)))
 
     o, s_out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(scalars),
             # slot blocks innermost: a head group's columns of k and q
             # are fetched once for all of them
             grid=(pairs, n // bn),
             in_specs=[
-                pl.BlockSpec((None, None, 2 * g, dk, _LANES),
-                             lambda j, i, *scalars: (j, i * bn // _LANES, 0,
-                                                     0, 0)),
+                pl.BlockSpec((None, None, n_cols, dk, _LANES),
+                             lambda j, i, *_: (j, i * bn // _LANES, 0, 0,
+                                               0)),
                 pl.BlockSpec((None, bn, lanes), row_block),
                 pl.BlockSpec((bn, None, dk, lanes), leaf_block)],
             out_specs=[
@@ -477,7 +544,7 @@ def _delta_update(q, k, v, alpha, beta, s, kinds, *, block, interpret):
                 pl.BlockSpec((bn, None, dk, lanes), leaf_block)]),
         out_shape=[jax.ShapeDtypeStruct((pairs, n, lanes), f32),
                    jax.ShapeDtypeStruct(s.shape, f32)],
-        input_output_aliases={5: 1},
+        input_output_aliases={len(scalars) + 2: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             # both buffers of the state block in and out, and as much
@@ -485,22 +552,25 @@ def _delta_update(q, k, v, alpha, beta, s, kinds, *, block, interpret):
             vmem_limit_bytes=8 * bn * dk * lanes * 4 + (8 << 20)),
         name=KERNEL_NAME,
         interpret=interpret,
-    )(kinds, alpha.reshape(-1), beta.reshape(-1), cols, rows, s)
+    )(*scalars, cols, rows, s)
     return o.transpose(1, 0, 2).reshape(n, h, dv), s_out
 
 
-def gated_output_norm(o, gate, w_norm, eps: float):
-    """``RMSNorm_dv(o) * silu(gate)`` per head: ``o``, ``gate`` ``[N, H,
-    dv]``, ``w_norm`` ``[dv]`` (one weight for every head)."""
+def gated_output_norm(o, gate, w_norm, eps: float, act=None):
+    """``RMSNorm_dv(o) * act(gate)`` per head: ``o``, ``gate`` ``[N, H,
+    dv]``, ``w_norm`` ``[dv]`` (one weight for every head); ``act`` the
+    gate's activation, SiLU where none is given (``olmo_hybrid``; KDA
+    gives a sigmoid)."""
     import jax
 
-    return rms_norm(o, w_norm, eps) * jax.nn.silu(gate)
+    return rms_norm(o, w_norm, eps) * (act or jax.nn.silu)(gate)
 
 
-def delta_layer_step(x, w, p: str, state, conv, ts, d):
-    """One token of a linear layer for every row: ``x`` ``[N, d_model]``
-    (the residual as it stands), ``state`` / ``conv`` the row's leaves.
-    Returns ``(out [N, d_model], state, conv)``."""
+def conv_qkv(x, w, p: str, conv, ts, d):
+    """The rule's ``(q [N, H, dk], k [N, H, dk], v [N, H, dv], conv)`` of
+    a linear layer's input rows ``x``: the three projections through the
+    ONE short convolution, q and k L2-normed per head, q scaled by
+    ``dk^-1/2`` (both kinds of delta-rule layer)."""
     import jax.numpy as jnp
 
     n = x.shape[0]
@@ -511,6 +581,15 @@ def delta_layer_step(x, w, p: str, state, conv, ts, d):
         * float(d.dk) ** -0.5
     k = l2_norm(qkv[:, d.d_key:2 * d.d_key].reshape(n, d.lin_heads, d.dk))
     v = qkv[:, 2 * d.d_key:].reshape(n, d.lin_heads, d.dv)
+    return q, k, v, conv
+
+
+def delta_layer_step(x, w, p: str, state, conv, ts, d):
+    """One token of a linear layer for every row: ``x`` ``[N, d_model]``
+    (the residual as it stands), ``state`` / ``conv`` the row's leaves.
+    Returns ``(out [N, d_model], state, conv)``."""
+    n = x.shape[0]
+    q, k, v, conv = conv_qkv(x, w, p, conv, ts, d)
     alpha, beta = decay_and_step_gates(x, w, p, d)
     o, state = gated_delta_step(q, k, v, alpha, beta, state, ts)
     gate = linear(x, w[p + "lin_g"]).reshape(n, d.lin_heads, d.dv)
@@ -531,3 +610,198 @@ def full_attention_rows(x, w, p: str, pos, d):
         k = rotary(k.reshape(n, d.n_kv_head, d.head_dim), pos,
                    d.rope_theta).reshape(n, d.d_kv)
     return q, k, linear(x, w[p + "attn_v"])
+
+
+# --- Kimi Delta Attention beside gated GQA and routed experts
+#     (``model_type: solar_open2``) ------------------------------------
+
+#: endings of the ``solar_open2`` parameters kept in float32
+KDA_FLOAT32_PARAMS = FLOAT32_PARAMS + ("router", "expert_bias")
+
+
+def kda_dims(cfg) -> SimpleNamespace:
+    """The decoder's sizes from a ``solar_open2`` config dict (the
+    published key names: ``linear_attn_config.*``, ``gqa_layers``,
+    ``n_routed_experts``, ...).  ``n_routed_experts`` may count the
+    experts HELD here; the router's width is then
+    ``n_routed_experts_all``.  Carries what ``routed_experts.route`` /
+    ``expert_layer`` / ``shared_expert`` read of a ``dims``."""
+    g, lin = cfg.get, cfg["linear_attn_config"]
+    n_layer = int(cfg["num_hidden_layers"])
+    gqa = set(int(i) for i in cfg["gqa_layers"])
+    o = SimpleNamespace(
+        vocab=int(cfg["vocab_size"]), d_model=int(cfg["hidden_size"]),
+        n_layer=n_layer,
+        kinds=tuple(FULL if i in gqa else LINEAR for i in range(n_layer)),
+        n_head=int(cfg["num_attention_heads"]),
+        n_kv_head=int(cfg["num_key_value_heads"]),
+        head_dim=int(cfg["head_dim"]),
+        lin_heads=int(lin["num_heads"]), dk=int(lin["head_dim"]),
+        dv=int(lin["head_dim"]),
+        conv_len=int(lin["short_conv_kernel_size"]),
+        neg_eigval=bool(g("kda_allow_neg_eigval", False)),
+        attn_gate=bool(g("use_gqa_gate", False)),
+        eps=float(g("rms_norm_eps", 1e-5)),
+        rope_theta=float(cfg["rope_theta"]) if g("use_rope", True) else None,
+        d_expert=int(cfg["moe_intermediate_size"]),
+        n_expert=int(g("n_routed_experts_all", cfg["n_routed_experts"])),
+        top_k=int(cfg["num_experts_per_tok"]),
+        n_shared=int(g("n_shared_experts", 0)),
+        norm_topk=bool(g("norm_topk_prob", True)),
+        routed_scale=float(g("routed_scaling_factor", 1.0)),
+        scoring=SIGMOID_BIAS, gate_act=SILU, expert_bias=True)
+    if gqa - set(range(n_layer)):
+        raise ValueError("gqa_layers names a layer past num_hidden_layers")
+    if lin.get("num_kv_heads") not in (None, o.lin_heads):
+        raise ValueError("linear_attn_config.num_kv_heads: value heads "
+                         "grouped over key heads are not built")
+    if g("kda_use_full_proj", False):
+        raise ValueError("kda_use_full_proj: only the low-rank gate "
+                         "projections are built")
+    if int(g("first_k_dense_replace", 0)):
+        raise ValueError("first_k_dense_replace: leading dense layers are "
+                         "not built")
+    if g("tie_word_embeddings", False):
+        raise ValueError("a tied head is not supported")
+    if float(g("partial_rotary_factor", 1)) != 1 and o.rope_theta is not None:
+        raise ValueError("a partial rotary is not supported")
+    if o.n_head % o.n_kv_head:
+        raise ValueError("query heads must be a multiple of their KV heads")
+    o.d_q, o.d_kv = o.n_head * o.head_dim, o.n_kv_head * o.head_dim
+    o.d_key = o.d_value = o.lin_heads * o.dk
+    o.d_qkv = 2 * o.d_key + o.d_value
+    o.d_rank = o.dk            # the low rank of both gate pairs: head_dim
+    o.tile_heads = heads_per_tile(o.lin_heads, o.dv)
+    o.state_shape = (o.lin_heads // o.tile_heads, o.dk, o.tile_heads * o.dv)
+    o.expert_layers = tuple(range(n_layer))
+    return o
+
+
+def kda_param_shapes(cfg, name: str = "lm", held=None) -> dict:
+    """Names and shapes of every weight the ``solar_open2`` step reads.
+    Matrices are ``[in, out]``; the conv kernel and the experts' matrices
+    as :func:`param_shapes` / ``routed_experts.param_shapes`` lay them;
+    ``held = (lo, hi)``: the experts whose matrices are held (default
+    all); the router and its bias keep their whole width."""
+    d = kda_dims(cfg)
+    n_held = d.n_expert if held is None else int(held[1]) - int(held[0])
+    out = {name + "_emb": (d.vocab, d.d_model),
+           name + "_final_norm": (d.d_model,),
+           name + "_head": (d.d_model, d.vocab)}
+    for i, kind in enumerate(d.kinds):
+        p = "%s_l%d_" % (name, i)
+        if kind == LINEAR:
+            out.update({
+                p + "lin_q": (d.d_model, d.d_key),
+                p + "lin_k": (d.d_model, d.d_key),
+                p + "lin_v": (d.d_model, d.d_value),
+                p + "lin_conv_w": (d.conv_len, d.d_qkv),
+                p + "lin_fa": (d.d_model, d.d_rank),
+                p + "lin_fb": (d.d_rank, d.d_key),
+                p + "lin_b": (d.d_model, d.lin_heads),
+                p + "lin_A_log": (d.lin_heads,),
+                p + "lin_dt_bias": (d.d_key,),
+                p + "lin_ga": (d.d_model, d.d_rank),
+                p + "lin_gb": (d.d_rank, d.d_value),
+                p + "lin_norm": (d.dv,),
+                p + "lin_o": (d.d_value, d.d_model)})
+        else:
+            out.update({
+                p + "attn_q": (d.d_model, d.d_q),
+                p + "attn_k": (d.d_model, d.d_kv),
+                p + "attn_v": (d.d_model, d.d_kv),
+                p + "attn_o": (d.d_q, d.d_model)})
+            if d.attn_gate:
+                out[p + "attn_gate"] = (d.d_model, d.d_q)
+        out.update({
+            p + "mixer_norm": (d.d_model,), p + "ffn_norm": (d.d_model,),
+            p + "router": (d.d_model, d.n_expert),
+            p + "expert_bias": (d.n_expert,),
+            p + "experts_w13": (n_held, d.d_model, 2 * d.d_expert),
+            p + "experts_w2": (n_held, d.d_expert, d.d_model)})
+        if d.n_shared:
+            out.update({
+                p + "shared_w13": (d.d_model, 2 * d.n_shared * d.d_expert),
+                p + "shared_w2": (d.n_shared * d.d_expert, d.d_model)})
+    return out
+
+
+def kda_random_state(rng, cfg, name: str = "lm", std: float = 0.02,
+                     dtype="float32", held=None, gate_std: float = 1.0,
+                     bias_range: float = 0.05) -> dict:
+    """Seeded random weights under :func:`kda_param_shapes` (tests): as
+    :func:`random_state`, with a float32 router, a selection bias uniform
+    in ``+-bias_range`` (NOT zero) and the decay pair's second matrix
+    ``lin_fb`` normal(0, ``gate_std``) so that the channels of ONE head
+    decay differently (else a decay a channel cannot be told from a decay
+    a head)."""
+    import jax.numpy as jnp
+
+    w = {}
+    for k, shp in kda_param_shapes(cfg, name, held).items():
+        if k.endswith(FLOAT32_PARAMS):
+            w[k] = _random_vector(rng, k, shp)
+        elif k.endswith("expert_bias"):
+            w[k] = rng.uniform(-bias_range, bias_range, shp).astype("float32")
+        elif k.endswith("router"):
+            w[k] = (rng.randn(*shp) * std).astype("float32")
+        else:
+            s = (1.0 if k.endswith("_emb") else gate_std
+                 if k.endswith("lin_fb") else std)
+            w[k] = jnp.asarray((rng.randn(*shp) * s).astype("float32"), dtype)
+    return w
+
+
+def channel_decay(x, w, p: str, d):
+    """``(alpha [N, H, dk], beta [N, H])`` float32 of a KDA layer: the
+    decay a key CHANNEL through the low-rank pair ``lin_fa`` / ``lin_fb``,
+    ``A_log`` a head and ``dt_bias`` a channel, and the write's step in
+    (0, 1), or (0, 2) where negative eigenvalues are allowed."""
+    import jax
+    import jax.numpy as jnp
+
+    n = x.shape[0]
+    a = linear(linear(x, w[p + "lin_fa"]), w[p + "lin_fb"])
+    dt = jax.nn.softplus(a + w[p + "lin_dt_bias"]).reshape(
+        n, d.lin_heads, d.dk)
+    alpha = jnp.exp(-jnp.exp(w[p + "lin_A_log"])[None, :, None] * dt)
+    beta = jax.nn.sigmoid(linear(x, w[p + "lin_b"]))
+    return alpha, beta * 2.0 if d.neg_eigval else beta
+
+
+def kda_layer_step(x, w, p: str, state, conv, ts, d):
+    """One token of a KDA layer for every row: ``x`` ``[N, d_model]``
+    (the NORMED residual), ``state`` / ``conv`` the row's leaves (as
+    :func:`delta_layer_step`'s).  Returns ``(out [N, d_model], state,
+    conv)``."""
+    import jax
+
+    n = x.shape[0]
+    q, k, v, conv = conv_qkv(x, w, p, conv, ts, d)
+    with jax.named_scope(CHANNEL_GATES_SCOPE):
+        alpha, beta = channel_decay(x, w, p, d)
+        gate = linear(linear(x, w[p + "lin_ga"]), w[p + "lin_gb"]).reshape(
+            n, d.lin_heads, d.dv)
+    o, state = gated_delta_step(q, k, v, alpha, beta, state, ts)
+    y = gated_output_norm(o, gate, w[p + "lin_norm"], d.eps, jax.nn.sigmoid)
+    return linear(y.reshape(n, d.d_value), w[p + "lin_o"]), state, conv
+
+
+def gated_attention_rows(x, w, p: str, pos, d):
+    """``(q, k, v, gate)`` of a G layer for the rows ``x`` (the NORMED
+    residual): the fresh rows ``[N, heads * head_dim]`` float32, rotated
+    at ``pos`` only where the configuration uses rotary (``solar_open2``:
+    ``use_rope`` false), and the sigmoid gate over the attention's output
+    lanes (None where ``use_gqa_gate`` is false)."""
+    import jax
+
+    n = x.shape[0]
+    q, k = linear(x, w[p + "attn_q"]), linear(x, w[p + "attn_k"])
+    if d.rope_theta is not None:
+        q = rotary(q.reshape(n, d.n_head, d.head_dim), pos,
+                   d.rope_theta).reshape(n, d.d_q)
+        k = rotary(k.reshape(n, d.n_kv_head, d.head_dim), pos,
+                   d.rope_theta).reshape(n, d.d_kv)
+    gate = (jax.nn.sigmoid(linear(x, w[p + "attn_gate"])) if d.attn_gate
+            else None)
+    return q, k, linear(x, w[p + "attn_v"]), gate

@@ -1302,8 +1302,11 @@ def test_counts_a_builder_makes_on_the_device_reach_the_four_counters(
 
     name = "chain-counts-%d" % traced
     step_fn, make_cache = counting_chain_model()
+    # ONE rung pair: with the default ladders the first turn may run at
+    # rung pair 1 x 8, whose view is 58 bytes and not the 312 held below
     srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=16,
-                       max_slots=4, steps_per_tick=2, name=name)
+                       max_slots=4, slot_ladder=[4], len_ladder=[16],
+                       steps_per_tick=2, name=name)
     srv.warmup(configure_cache=False)
     prompts = [[10, 11], [12], [10, 11, 12], [13]]
     if traced:

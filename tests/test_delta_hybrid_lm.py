@@ -11,6 +11,7 @@ before it is written, per-token decay and step gates, one short
 convolution over ``[q; k; v]``, a state leaf that lays several heads in
 one row of lanes, and full attention of one query head a K/V head.
 """
+import functools
 import importlib.util
 import os
 
@@ -313,6 +314,86 @@ def test_the_kernel_is_the_xla_form_and_the_rule(case, slots, first_ts, steps):
         assert np.array_equal(np.asarray(by_kernel)[~live],
                               np.asarray(start)[~live])
         ts = np.where(live, ts + 1, ts)
+
+
+#: Solar-Open2's head shape: dk = dv = 128, one head a row of lanes
+KDA_HEAD = (3, 128, 128)
+
+
+@pytest.mark.parametrize("case,layout,slots,first_ts,steps", [
+    ("kda_head_shape", KDA_HEAD, 8, [3, 9, 1, 500, 17, 2, 64, 5], 1),
+    ("fresh_and_idle_rows", KDA_HEAD, 8, [0, 4, -1, 0, -1, 0, 1, -1], 1),
+    ("mixed_over_steps", KDA_HEAD, 16, [0, 3, -1, 5] * 4, 3),
+    ("two_heads_a_row", OLMO_HEAD, 8, [0, 4, -1, 7, 2, 0, 1, -1], 2)])
+def test_a_decay_a_channel_is_the_kernel_the_xla_form_and_the_rule(
+        case, layout, slots, first_ts, steps):
+    """``alpha [N, H, dk]`` (``S <- Diag(alpha) S``) through both
+    lowerings of the one contract and the rule in numpy: the kernel takes
+    the decay as a third column beside k and q."""
+    import jax.numpy as jnp
+
+    heads, dk, dv = layout
+    g = dh.heads_per_tile(heads, dv)
+    rng = np.random.RandomState(len(case))
+    start = jnp.asarray(rng.randn(slots, heads // g, dk, g * dv),
+                        jnp.float32)
+    assert dh.lowering("tpu", start, dv) == "kernel"
+    ts = np.asarray(first_ts, np.int32)
+    by_kernel = by_xla = start
+    by_hand = _leaf_by_head(start, heads, dk, dv)
+    for _ in range(steps):
+        q, k, v, _, beta = _rule_inputs(rng, slots, heads, dk, dv)
+        alpha = jnp.asarray(rng.uniform(0.3, 1.0, (slots, heads, dk)),
+                            jnp.float32)
+        args = (q, k, v, alpha, beta)
+        o_k, by_kernel = dh.kernel_gated_delta_step(
+            *args, by_kernel, jnp.asarray(ts), interpret=True)
+        o_x, by_xla = dh.xla_gated_delta_step(*args, by_xla, jnp.asarray(ts))
+        o_n, by_hand = _numpy_rule(q, k, v, np.asarray(alpha)[..., None],
+                                   beta, by_hand, ts)
+        live = ts >= 0
+        np.testing.assert_allclose(np.asarray(o_k)[live],
+                                   np.asarray(o_x)[live], rtol=0, atol=2e-6)
+        np.testing.assert_allclose(np.asarray(o_k)[live], o_n[live], rtol=0,
+                                   atol=5e-6)
+        np.testing.assert_allclose(by_kernel, by_xla, rtol=0, atol=5e-6)
+        np.testing.assert_allclose(
+            _leaf_by_head(by_kernel, heads, dk, dv), by_hand, rtol=0,
+            atol=1e-5)
+        assert np.array_equal(np.asarray(by_kernel)[~live],
+                              np.asarray(start)[~live])
+        ts = np.where(live, ts + 1, ts)
+
+
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_one_value_a_head_in_every_channel_is_the_decay_a_head(form):
+    """The two contracts meet: ``alpha [N, H, dk]`` whose channels all
+    carry their head's one value gives what ``alpha [N, H]`` gives, bit
+    for bit in the XLA form (the same products in the same order) and to
+    a rounding in the kernel."""
+    import jax.numpy as jnp
+
+    heads, dk, dv = KDA_HEAD
+    rng = np.random.RandomState(9)
+    s = jnp.asarray(rng.randn(8, heads, dk, dv), jnp.float32)
+    ts = jnp.asarray([0, 4, -1, 7, 2, 0, 1, 30], jnp.int32)
+    q, k, v, alpha, beta = _rule_inputs(rng, 8, heads, dk, dv)
+    wide = jnp.broadcast_to(alpha[..., None], (8, heads, dk))
+    step = (dh.xla_gated_delta_step if form == "xla" else functools.partial(
+        dh.kernel_gated_delta_step, interpret=True))
+    o_head, s_head = step(q, k, v, alpha, beta, s, ts)
+    o_chan, s_chan = step(q, k, v, wide, beta, s, ts)
+    tol = 0 if form == "xla" else 1e-6
+    np.testing.assert_allclose(o_chan, o_head, rtol=0, atol=tol)
+    np.testing.assert_allclose(s_chan, s_head, rtol=0, atol=tol)
+
+
+def test_solar_widths_take_thirty_two_slots_a_block():
+    """At the ``solar_open2_250b`` cell's leaf (256 slots of 64 KB a
+    head) a block is 32 slots, 2 MB a buffer."""
+    assert dh._block_slots(256, 128, 128) == 32
+    assert dh.heads_per_tile(64, 128) == 1
+
 
 
 @pytest.mark.parametrize("why,backend,dtype,layout,slots,want", [

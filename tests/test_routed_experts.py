@@ -195,6 +195,31 @@ def test_the_shares_add_up():
     assert pairs == int(stats[0]) == 24 * d.top_k
 
 
+@pytest.mark.parametrize("n_expert,top_k,held,rows", [
+    (320, 8, (0, 40), 24),       # solar_open2_250b's router: ~0.6 rows a group
+    (320, 8, (280, 320), 24),    # the last chip's range
+    (128, 8, (16, 32), 6),       # more held experts than pairs that reach them
+    (64, 4, (60, 64), 3)])
+def test_a_held_eighth_of_a_wide_router_at_a_few_rows_a_group(n_expert, top_k,
+                                                              held, rows):
+    """A router far wider than the held range, groups far under a row
+    tile (most of them empty): the share is the per-token loop over the
+    held experts alone, and the counts are the held groups' own."""
+    cfg = tiny_cfg(n_expert=n_expert, top_k=top_k)
+    d = rx.dims(cfg)
+    w = weights(cfg, seed=9)
+    f, ts = _layer_inputs(cfg, n=rows, seed=3)
+    lo, hi = held
+    got, stats = rx.expert_layer(f, _held(w, P, lo, hi), P, ts, d, held=held)
+    want, sel = _per_token_loop(f, _held(w, P, lo, hi), P, np.asarray(ts), d,
+                                lo, hi)
+    np.testing.assert_allclose(np.asarray(got), want, atol=ATOL)
+    counts = np.bincount(sel.reshape(-1), minlength=n_expert)[lo:hi]
+    assert np.asarray(stats).tolist() == [
+        int(counts.sum()), int((counts > 0).sum()), int(counts.max()), 1]
+    assert counts.sum() < rows * top_k          # most pairs go elsewhere
+
+
 def test_an_idle_row_is_routed_nowhere_and_counted_nowhere():
     import jax.numpy as jnp
 

@@ -602,6 +602,82 @@ def test_olmo_hybrid_chunk_reads_the_full_layers_by_the_kernel_on_v5e(
     assert mem.alias_size_in_bytes >= 6 * 80 * 1024 * 3840 * 2
 
 
+@pytest.fixture(scope="module")
+def solar_chunk(one_chip):
+    """The slot pool's ``chunk`` of ``solar_open2_250b`` (its one rung
+    pair, the published widths, the whole 4-layer cut: G, K, K, K, 40
+    held experts of 320) as ``tools/decode_chunk_text.py`` builds it for
+    a TPU, compiled ONCE: ``(text, memory analysis, counted)``."""
+    import importlib.util
+    import os
+    import sys
+
+    import jax
+
+    from paddle_tpu import delta_hybrid_lm as dh
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "decode_chunk_text", os.path.join(root, "tools",
+                                          "decode_chunk_text.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+
+    def counts():
+        out = {("path", p): dh.LOWERED.labels(path=p).value
+               for p in ("kernel", "xla")}
+        out.update({("decay", k): dh.DECAY.labels(decay=k).value
+                    for k in ("head", "channel")})
+        return out
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", jax.default_backend)
+        patch.setattr(sys, "path", list(sys.path))
+        before = counts()
+        compiled = tool.lowered_chunk(root, "solar_open2_250b",
+                                      layers=4).compile()
+        counted = {k: v - before[k] for k, v in counts().items()}
+    return compiled.as_text(), compiled.memory_analysis(), counted
+
+
+def test_solar_open2_chunk_takes_the_kernel_with_a_decay_a_channel_on_v5e(
+        solar_chunk):
+    """Three calls of the delta rule's kernel a step, one a K layer, each
+    over a state leaf ``f32[256,64,128,128]`` as declared, with the decay
+    laid as a third column beside k and q (``f32[64,2,3,128,128]``); no
+    other instruction reads a state leaf, none copies one or a rung-long
+    K/V leaf; every leaf is aliased in place; the G layer's read is ONE
+    call of the grouped attention kernel over both bf16 leaves; the
+    experts are the grouped product; and the whole cut fits the chip."""
+    from paddle_tpu import delta_hybrid_lm as dh
+    from paddle_tpu import grouped_matmul as gm
+
+    text, mem, counted = solar_chunk
+    traced = counted["path", "kernel"]
+    assert traced >= 3 and traced % 3 == 0 and not counted["path", "xla"]
+    assert counted["decay", "channel"] == traced
+    assert not counted["decay", "head"]
+    reads = _reads_of(text, "f32[256,64,128,128]")
+    assert len(reads) == 3, reads
+    assert all("tpu_custom_call" in r and dh.KERNEL_NAME in r
+               and "f32[64,2,3,128,128]" in r for r in reads)
+    copies = [line for line in text.splitlines() if " copy(" in line]
+    assert not any(shape in line for line in copies for shape in (
+        "[256,64,128,128]", "[256,2048,1024]", "[256,3,24576]"))
+    calls = [line for line in text.splitlines()
+             if "grouped_decode_attention" in line and "custom-call(" in line]
+    assert len(calls) == 1 and calls[0].count("bf16[256,2048,1024]") >= 2
+    assert "f32[256,2048,1024]" not in text
+    assert sum(gm.KERNEL_NAME in line and "custom-call(" in line
+               for line in text.splitlines()) == 2 * 4
+    # three states, three conv windows, the G layer's K and V
+    pool = 3 * 4 * 256 * (64 * 128 * 128 + 3 * 24576) + 2 * 2 * 256 * 2048 * 1024
+    assert mem.alias_size_in_bytes >= pool
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.generated_code_size_in_bytes) < 13.5e9
+
+
 @pytest.mark.parametrize("lanes,copied", [(640, False), (576, True)],
                          ids=["whole_tiles", "as_wide_as_the_row"])
 def test_a_latent_leaf_of_whole_lane_tiles_is_not_copied_on_v5e(

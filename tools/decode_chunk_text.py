@@ -202,6 +202,23 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
         def build(w):
             return decoding.make_delta_hybrid_lm_pooled_step_fn(
                 w, cfg, kv_dtype=sv["kv_dtype"])
+    elif cfg["family"] == "pooled_kda_routed_lm":
+        from paddle_tpu import delta_hybrid_lm
+
+        # the first ``layers`` of the cut (2: the G layer and a K layer,
+        # each with its experts; 4: the whole cut, one period)
+        cfg["num_hidden_layers"] = layers
+        held = tuple(cfg["experts_held"])
+        # as the family makes them: matrices bf16, vectors, the conv
+        # kernel, the router and its bias fp32
+        weights = {n: sd(shp, jnp.float32 if n.endswith(
+            delta_hybrid_lm.KDA_FLOAT32_PARAMS) else jnp.bfloat16)
+            for n, shp in delta_hybrid_lm.kda_param_shapes(
+                cfg, held=held).items()}
+
+        def build(w):
+            return decoding.make_kda_routed_lm_pooled_step_fn(
+                w, cfg, kv_dtype=sv["kv_dtype"], held=held)
     elif cfg["family"] == "pooled_hybrid_ssm_lm":
         from paddle_tpu import hybrid_ssm
 
@@ -338,7 +355,8 @@ def main():
     ap.add_argument("--layers", type=int, default=2,
                     help="layers compiled (8: the whole minicpm_sala or "
                     "smallthinker_21b_a3b cut, 5: k_exaone_236b_a23b's or "
-                    "deepseek_v3_2's, 12: olmo_hybrid_7b's, to see that "
+                    "deepseek_v3_2's, 12: olmo_hybrid_7b's, 4: "
+                    "solar_open2_250b's, to see that "
                     "the real program fits the chip)")
     args = ap.parse_args()
     lowered = lowered_chunk(os.path.abspath(args.repo), args.config,
