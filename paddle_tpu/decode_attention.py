@@ -95,11 +95,13 @@ Three implementations of the read-what-is-live contract, chosen by
   the indicator is exact), accumulated in fp32.
 * :func:`grouped_decode_attention` — the Pallas TPU kernel for GROUPED
   heads (``rep > 1``) over unquantized sequence leaves, bf16 or fp32,
-  whose heads are whole lane tiles (``Dh`` a multiple of 128), ``K >=
-  1`` fresh rows a slot (``K`` is read from ``q``'s shape; at ``K = 1``
-  the program is the one-row kernel's) — and for ONE query head a K/V
-  head (``rep = 1``) over bf16 leaves of such heads at one fresh row
-  (``olmo_hybrid``'s full layers).  The same ragged read from the
+  whose heads a whole number of lane tiles holds (``Dh`` a multiple of
+  128, or of 64 with an even number of K/V heads: two 64-lane heads a
+  lane tile, ``lfm2``'s), ``K >= 1`` fresh rows a slot (``K`` is read
+  from ``q``'s shape; at ``K = 1`` the program is the one-row kernel's)
+  — and for ONE query head a K/V head (``rep = 1``) over bf16 leaves
+  of whole-lane-tile heads at one fresh row (``olmo_hybrid``'s full
+  layers).  The same ragged read from the
   same planner with its own sizes (:func:`step_read_sizes`: blocks of
   ``_GROUPED_BLOCK`` positions, fewer where a leaf's slab of them would
   pass ``_GROUPED_SLAB`` bytes, a slot's last one in
@@ -121,17 +123,21 @@ Three implementations of the read-what-is-live contract, chosen by
   unit and not once a head, each row's own ``Dh`` lanes filled by the
   kernel from a ``[heads, Dh]`` operand, the context written back a row
   a head): the same block-diagonal product at an eighth of the rows.
-  Heads narrower than a lane tile (two of 64 lanes a tile) are the same
-  kernel, not shipped yet.
+  Heads of 64 lanes are the same kernel at another parameter
+  (:func:`_heads_a_tile`): nothing in the q layout needs ``Dh`` = 128,
+  only a unit's lane slice and a context's output tile want whole lane
+  tiles, so a unit holds an EVEN number of such heads and two heads'
+  contexts leave the kernel side by side in one tile, split outside.
 * :func:`grouped_masked_decode_attention` — the contract whole, as plain
   XLA ops (scatter append + masked softmax over the whole T axis):
   products in the storage dtype (int8: dequantized to fp32 at the read),
   fp32 accumulation and softmax.  The CPU path, the path of every step
   no kernel covers (int8 leaves, ring leaves, ``K > 1`` over leaves of
   one query head a K/V head, and — at one row through
-  :func:`lane_masked_decode_attention` on a TPU — heads narrower than a
-  lane tile, grouped or over bf16 leaves one query head a K/V head: the
-  whole rung is read whatever of it is live;
+  :func:`lane_masked_decode_attention` on a TPU — what is left of heads
+  narrower than a lane tile: grouped ones in an odd number or over a
+  rung the block does not divide, and bf16 leaves of one query head a
+  K/V head: the whole rung is read whatever of it is live;
   ``decode_attention_grouped_lowered_total{path}`` and
   ``decode_attention_ungrouped_lowered_total{path}`` count the form),
   and the parity reference of tests/test_decode_attention.py.
@@ -332,6 +338,16 @@ def kernel_supported(seq_len: int, d_model: int, n_head: int) -> bool:
             and n_head <= _HEAD_LANES)
 
 
+def _heads_a_tile(d_head: int) -> int:
+    """Consecutive K/V heads of ``d_head`` lanes whose lanes together are
+    whole lane tiles, which is what the grouped kernel slices a slab by
+    and writes a context out in: 1 where a head is whole tiles itself, 2
+    where it is an odd number of half tiles (64 lanes: two heads a tile),
+    0 where no such pair is (no kernel)."""
+    half = _HEAD_LANES // 2
+    return 0 if d_head % half else 1 + d_head // half % 2
+
+
 def step_read_sizes(seq_len: int, width: int, dtype, *, n_head: int,
                     n_kv_head: int, backend=None):
     """``(block, tail)`` the grouped kernel reads a step's (one fresh
@@ -340,13 +356,18 @@ def step_read_sizes(seq_len: int, width: int, dtype, *, n_head: int,
     (a slot's blocks before its last whole, its last in classes of
     ``tail`` rows: :func:`kv_positions_read`), or None where that step
     is not the kernel's: the backend (``jax.default_backend()`` unsaid)
-    no TPU, a head's lanes no whole lane tiles, a dtype other than bf16
-    and fp32, a rung its block does not divide, or ONE query head a K/V
-    head over leaves that are not bf16 (fp32 ones are
-    :func:`ragged_decode_attention`'s: bit-exact fp32 products)."""
+    no TPU, heads that no whole number of :func:`_heads_a_tile` holds
+    (a head's lanes no multiple of 64, or an odd number of K/V heads of
+    an odd number of half tiles), a dtype other than bf16 and fp32, a
+    rung its block does not divide, or ONE query head a K/V head over
+    leaves that are not bf16 (fp32 ones are
+    :func:`ragged_decode_attention`'s: bit-exact fp32 products) or whose
+    heads are not whole lane tiles themselves."""
     import jax
     import jax.numpy as jnp
 
+    pair = (_heads_a_tile(width // n_kv_head)
+            if 0 < n_kv_head <= n_head and width % n_kv_head == 0 else 0)
     block = _GROUPED_BLOCK
     while (block * width * jnp.dtype(dtype).itemsize > _GROUPED_SLAB
            and block > 16 * _GROUPED_CLASSES):
@@ -354,10 +375,10 @@ def step_read_sizes(seq_len: int, width: int, dtype, *, n_head: int,
     block = min(int(seq_len), block)
     tail = block // _GROUPED_CLASSES
     if ((backend or jax.default_backend()) != "tpu"
-            or not 0 < n_kv_head <= n_head or n_head % n_kv_head
-            or width % n_kv_head or (width // n_kv_head) % _HEAD_LANES
+            or not pair or n_head % n_kv_head or n_kv_head % pair
             or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32)
-            or (n_kv_head == n_head and jnp.dtype(dtype) != jnp.bfloat16)
+            or (n_kv_head == n_head
+                and (jnp.dtype(dtype) != jnp.bfloat16 or pair > 1))
             or seq_len % block or block % _GROUPED_CLASSES or tail % 16):
         return None
     return block, tail
@@ -856,7 +877,10 @@ def lane_masked_decode_attention(q, k_new, v_new, kv, ts, *, n_head: int,
     For a head narrower than a lane tile (``Dh`` 64) that view is not a
     bitcast on a TPU: the compiler re-tiles the leaf — a copy of the
     whole rung, K and V, every layer and step (seen in the compiled
-    chunk of ``lfm2_24b_a2b``: four 537 MB copies a step, PR 40).  Here
+    chunk of ``lfm2_24b_a2b``: four 537 MB copies a step, PR 40; since
+    PR 57 that cell's even number of grouped 64-lane heads is the
+    grouped kernel's, and this form keeps what :func:`step_read_sizes`
+    refuses: see :func:`make_decode_attention`).  Here
     each query head is instead laid into its K/V head's ``Dh`` lanes of
     a row as wide as the leaf (zeros elsewhere), so the score product
     contracts the leaf's whole last axis and the context product yields
@@ -1496,7 +1520,7 @@ def _block_sparse(q, k_cache, v_cache, ts, blocks, valid, *, n_head,
 def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
                     ts_ref,                                     # SMEM
                     q_ref,                                      # VMEM
-                    *refs, block, tail, heads, head_rows, fresh):
+                    *refs, block, tail, heads, head_rows, fresh, pair):
     """The work list's items one after another, the reads of the next
     ``ahead`` in flight: an item is BOTH leaves' whole-width ``[rows,
     n_kv_head * Dh]`` slabs, one copy each, scored a UNIT of ``heads``
@@ -1509,7 +1533,10 @@ def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
     its own ``Dh`` lanes filled and the unit's other lanes zero, so one
     product scores the unit's heads and one more weighs their rows (the
     zeros add exact zeros; a head keeps its own lanes of the result).
-    ``head_rows`` (static, :func:`_head_rows`) is ``rep_p``.  At ONE row
+    ``head_rows`` (static, :func:`_head_rows`) is ``rep_p``; ``pair``
+    (static, :func:`_heads_a_tile`) the heads whose contexts go out as
+    one tile ``[rep_p, pair * Dh]``, each its own lanes of it (64-lane
+    heads: two a lane tile; else one).  At ONE row
     a head the unit's heads are consecutive rows and ``q_ref`` is ``[S,
     units, R, Dh]``: the rows are laid into their lanes here (a select
     over ``heads`` copies side by side: 60 vector selects an item at 30
@@ -1547,7 +1574,7 @@ def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
     (k_hbm, v_hbm,                                              # HBM (ANY)
      o_ref,                                                     # output
      kbuf, vbuf, m_ref, l_ref, acc_ref, sem) = refs[fresh > 1:]
-    rep_p, D = head_rows, o_ref.shape[-1]
+    rep_p, D = head_rows, o_ref.shape[-1] // pair
     _, units, R, _ = q_ref.shape
     L = heads * D
     T = k_hbm.shape[1]
@@ -1610,10 +1637,16 @@ def _grouped_kernel(n_items_ref, item_slot_ref, item_blk_ref, item_rows_ref,
             acc_ref[u] = acc
 
             def head_tiles():
-                for h in range(heads):  # a head's own rows and lanes
-                    rows = slice(h * rep_p, (h + 1) * rep_p)
-                    o_ref[n, u * heads + h] = (
-                        acc[rows, h * D:(h + 1) * D] / l[rows])
+                for h in range(0, heads, pair):
+                    # a head's own rows over its tile's lanes; of two
+                    # 64-lane heads a tile each keeps its own half
+                    own = [acc[rows, h * D:(h + pair) * D] / l[rows]
+                           for rows in (slice(j * rep_p, (j + 1) * rep_p)
+                                        for j in range(h, h + pair))]
+                    o_ref[n, u * (heads // pair) + h // pair] = (
+                        own[0] if pair == 1 else jnp.where(
+                            jax.lax.broadcasted_iota(
+                                jnp.int32, own[0].shape, 1) < D, *own))
 
             def unit_rows():        # one row a head: row h, its own lanes
                 row = jax.lax.broadcasted_iota(jnp.int32, (R, D), 0)
@@ -1656,7 +1689,9 @@ def grouped_decode_attention(q, k_cache, v_cache, ts, work, *, n_head: int,
                              tail: int, interpret=False):
     """The Pallas TPU kernel of read-what-is-live for grouped heads —
     and for ONE query head a K/V head at one fresh row — over
-    unquantized sequence leaves (see the module docstring).
+    unquantized sequence leaves whose heads a whole number of lane tiles
+    holds (see the module docstring; :func:`step_read_sizes` is the
+    rule).
 
     ``q`` ``[S, n_head * Dh]`` fp32 (one fresh row a slot, at ``ts``) or
     ``[S, K, n_head * Dh]`` (``K`` rows, row ``j`` at ``ts + j`` reading
@@ -1710,22 +1745,32 @@ def _head_rows(fresh: int, rep: int) -> int:
     return 1 if fresh * rep == 1 else -(-fresh * rep // 8) * 8
 
 
-def _unit_heads(n_kv_head: int, head_rows: int, heads: int = 0) -> int:
+def _unit_heads(n_kv_head: int, head_rows: int, heads: int = 0,
+                pair: int = 1) -> int:
     """K/V heads the grouped kernel scores in one product: ``heads``
     where it is said and divides them (the tool's experiments), else as
     many — a divisor of ``n_kv_head`` — as keep a unit's query rows
     (``head_rows`` a head, :func:`_head_rows`) within
-    :data:`_GROUPED_UNIT_ROWS`.  One-row heads: every head of up to 64
+    :data:`_GROUPED_UNIT_ROWS`; in whole ``pair`` s
+    (:func:`_heads_a_tile`), so that a unit's lanes are whole tiles.
+    Eight 64-lane heads of 8 rows are ONE unit of 64 rows x 512 lanes:
+    at ``[256,2048,512]`` 0.605 ms a call for 0.680 at four heads a unit
+    and 0.976 at two, and blocks of 512 for 0.738 at 256 and 0.606 at
+    1024 (chip run, PR 57, tools/time_grouped_decode.py --shape lfm2:
+    the copies alone 0.571, the arithmetic alone 0.488; the lane-masked
+    XLA form of the same read, over the whole rung: 1.78).
+    One-row heads: every head of up to 64
     in ONE unit — at 30 heads of 128 the copies set the pace whatever the
     unit (0.637-0.654 ms a call at 5 / 6 / 10 / 15 / 30 heads a unit) and
     the arithmetic alone falls with the units (0.573 / 0.502 / 0.401 /
     0.366 / 0.329: a block's chain once a unit), so the widest unit
     leaves the most room behind the copies (chip run, PR 53,
     tools/time_grouped_decode.py)."""
-    if heads and n_kv_head % heads == 0:
+    if heads and n_kv_head % heads == 0 and heads % pair == 0:
         return heads
-    return max(h for h in range(1, n_kv_head + 1) if n_kv_head % h == 0
-               and (h == 1 or h * head_rows <= _GROUPED_UNIT_ROWS))
+    return max(h for h in range(pair, n_kv_head + 1, pair)
+               if n_kv_head % h == 0
+               and (h == pair or h * head_rows <= _GROUPED_UNIT_ROWS))
 
 
 def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
@@ -1741,7 +1786,12 @@ def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
     dt, f32, i32 = k_cache.dtype, jnp.float32, jnp.int32
     size = jnp.dtype(dt).itemsize
     rep_p = _head_rows(K, rep)
-    heads = _unit_heads(G, rep_p, heads)                # K/V heads a unit
+    pair = _heads_a_tile(D)             # heads a context tile goes out in
+    if not pair or G % pair or (pair > 1 and rep_p == 1):
+        raise ValueError("grouped_decode_attention: %d K/V heads of %d "
+                         "lanes, %d query rows a head (step_read_sizes)"
+                         % (G, D, K * rep))
+    heads = _unit_heads(G, rep_p, heads, pair)          # K/V heads a unit
     units, L = G // heads, heads * D
     sub = 32 // size                    # rows of the leaves' sublane tile
     R = -(-heads * rep_p // sub) * sub
@@ -1765,14 +1815,16 @@ def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     nbuf = ahead + 1
     # the context: a head's rows, or at one row a head a unit's rows
-    out = (S, G, rep_p, D) if rep_p > 1 else (S, units, R, D)
+    out = ((S, G // pair, rep_p, pair * D) if rep_p > 1
+           else (S, units, R, D))
     # bytes the kernel keeps in VMEM: q and the context whole, the slabs
     # being read and scored, a block's scores a few times over
     resident = (qg.size * size + 4 * int(np.prod(out))
                 + 2 * nbuf * block * Dkv * size + 4 * 4 * R * block)
     ctx = pl.pallas_call(
         functools.partial(_grouped_kernel, block=block, tail=tail,
-                          heads=heads, head_rows=rep_p, fresh=K),
+                          heads=heads, head_rows=rep_p, fresh=K,
+                          pair=pair),
         out_shape=jax.ShapeDtypeStruct(out, f32),
         in_specs=[smem] * 5 + [vmem] * (1 + len(fresh_of)) + [hbm] * 2,
         out_specs=vmem,
@@ -1792,6 +1844,9 @@ def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
     )(*work, ts.astype(i32), qg, *fresh_of, k_cache, v_cache)
     if rep_p == 1:
         return ctx[:, :, :heads].reshape(q.shape)
+    if pair > 1:    # a tile's heads side by side: each its own rows
+        ctx = jnp.moveaxis(ctx.reshape(S, G // pair, rep_p, pair, D),
+                           3, 2).reshape(S, G, rep_p, D)
     ctx = ctx[:, :, :K * rep].reshape(S, G, K, rep, D)
     return jnp.moveaxis(ctx, 2, 1).reshape(q.shape)
 
@@ -1921,16 +1976,19 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
     exists for what the step is — the default backend a TPU, unquantized
     leaves, and either one query head per K/V head over fp32 leaves of a
     shape :func:`ragged_decode_attention` lowers for and one fresh row
-    per slot, or heads that are whole lane tiles
+    per slot, or heads that a whole number of lane tiles holds
     (:func:`step_read_sizes`: :func:`grouped_decode_attention` — grouped
-    heads over bf16 / fp32 leaves, one fresh row or ``K``; one query
-    head per K/V head over bf16 leaves, one fresh row) — and an XLA form
-    otherwise: on a TPU, for one row over unquantized leaves of grouped
-    heads narrower than a lane tile, or over bf16 leaves of one query
-    head per K/V head that the kernel's sizes do not fit, the one that
-    reads the leaves as they lie (:func:`lane_masked_decode_attention`),
-    else :func:`grouped_masked_decode_attention` (int8 leaves, ``K``
-    rows where no kernel takes them, every CPU run).  A grouped-head step
+    heads over bf16 / fp32 leaves, a head whole lane tiles or an even
+    number of heads of 64 lanes, one fresh row or ``K``; one query head
+    per K/V head over bf16 leaves of whole-lane-tile heads, one fresh
+    row) — and an XLA form otherwise: on a TPU, for one row over
+    unquantized leaves of grouped heads narrower than a lane tile that
+    the kernel does not take (an odd number of them, a rung its block
+    does not divide), or over bf16 leaves of one query head per K/V head
+    that it does not (64-lane heads, such a rung), the one that reads
+    the leaves as they lie (:func:`lane_masked_decode_attention`), else
+    :func:`grouped_masked_decode_attention` (int8 leaves, ``K`` rows
+    where no kernel takes them, every CPU run).  A grouped-head step
     over sequence leaves counts itself in
     ``decode_attention_grouped_lowered_total{path}``, a ``K``-row one
     also in ``decode_attention_rows_lowered_total{leaf}``, a step of one
@@ -1969,8 +2027,10 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
     if n_kv_head < n_head:
         one_row = xla
         if sizes is None and tpu and (width // n_kv_head) % _HEAD_LANES:
-            # narrower than a lane tile: a view of the leaf by heads
-            # would be a copy of the rung (lane_masked_decode_attention)
+            # narrower than a lane tile and not the kernel's (an odd
+            # number of heads, a rung its block does not divide): a view
+            # of the leaf by heads would be a copy of the rung
+            # (lane_masked_decode_attention)
             one_row = lane
 
         def attend(q, k_new, v_new, kv):
@@ -1992,8 +2052,8 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
         # with a head's ONE query row a row of a unit
         one_row = kernel
     elif tpu and kv["k"].dtype == jnp.bfloat16:
-        # no kernel's shape (heads narrower than a lane tile, a rung the
-        # block does not divide).  With ONE query row a K/V head the
+        # no kernel's shape (heads that are not whole lane tiles, a rung
+        # the block does not divide).  With ONE query row a K/V head the
         # compiler takes the score product of the per-head view off the
         # matrix unit and first copies each leaf to float32 in another
         # layout (2 x a leaf of temporaries a leaf and step): read the

@@ -986,7 +986,10 @@ def make_routed_conv_lm_pooled_step_fn(state, cfg, name: str = "lm",
     * an attention layer ``k``, ``v`` ``[N, T, n_kv_head * head_dim]``
       in ``kv_dtype`` (``k`` after its per-head norm and rotary),
       ``decode_attention``'s format through ``make_decode_attention``
-      (grouped heads: the XLA form), covered by write-before-read;
+      (grouped heads: on a TPU the kernel that reads what is live, two
+      64-lane heads a lane tile, else the XLA form;
+      ``make_cache.kv_positions_read`` tells the server its rounding),
+      covered by write-before-read;
     * a conv layer ``conv`` ``[N, conv_L_cache - 1, d_model]`` fp32, the
       row's last inputs of the depthwise convolution: RECURRENT (``-1``),
       read as zero for a row at ``ts == 0`` (``hybrid_ssm.starts_fresh``),
@@ -1009,7 +1012,8 @@ def make_routed_conv_lm_pooled_step_fn(state, cfg, name: str = "lm",
     import jax.numpy as jnp
 
     from paddle_tpu import routed_experts as rx
-    from paddle_tpu.decode_attention import kv_leaves, make_decode_attention
+    from paddle_tpu.decode_attention import (kv_leaves, make_decode_attention,
+                                             step_positions_read)
 
     d = rx.dims(cfg)
     kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
@@ -1035,6 +1039,9 @@ def make_routed_conv_lm_pooled_step_fn(state, cfg, name: str = "lm",
         "expert_stats": -1}
     make_cache.expert_stats = lambda cache: cache["expert_stats"]
     make_cache.n_expert = d.n_expert
+    make_cache.kv_positions_read = functools.partial(
+        step_positions_read, width=d.d_kv, dtype=kv, n_head=d.n_head,
+        n_kv_head=d.n_kv_head)
 
     def step_fn(cache, tokens, ts):
         n = tokens.shape[0]

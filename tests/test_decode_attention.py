@@ -612,22 +612,25 @@ def _grouped_counts():
 
 def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
         monkeypatch):
-    """On a TPU a one-row step of grouped heads over unquantized
-    sequence leaves takes the grouped kernel where the heads are whole
-    lane tiles (falcon_h1, smallthinker's global layers: bf16 and fp32)
-    and reads the leaves as they lie where they are 64 wide (lfm2); K
-    fresh rows over whole-lane-tile heads take the same kernel (a
-    self-drafting round's verify: k_exaone); one query head a K/V head
-    over bf16 leaves of whole-lane-tile heads (olmo_hybrid's full layers)
-    takes that kernel too at one row — a head one row of a unit — and
-    where the heads are 64 wide or the block does not divide the rung it
-    reads the leaves as they lie (the per-head view would be copied to
-    float32); int8 leaves, a rung the kernel's block does not divide, K
-    rows of 64-wide heads or of one query head a K/V head, ring leaves
-    and every CPU run keep the grouped XLA form.  Every grouped-head step
-    over sequence leaves counts itself by the path it took, a K-row one
-    also by its leaf, a step of one query head a K/V head in a counter of
-    its own; a ring step never counts a path."""
+    """On a TPU a step of grouped heads over unquantized sequence leaves
+    takes the grouped kernel where a whole number of lane tiles holds
+    the K/V heads: heads that are whole lane tiles (falcon_h1,
+    smallthinker's global layers: bf16 and fp32) and an EVEN number of
+    64-lane heads, two a lane tile (lfm2: bf16 and fp32) — one fresh row
+    a slot or K (a self-drafting round's verify: k_exaone); one query
+    head a K/V head over bf16 leaves of whole-lane-tile heads
+    (olmo_hybrid's full layers) takes that kernel too at one row — a
+    head one row of a unit.  What is left reads the leaves as they lie
+    at one row (the per-head view would be copied): grouped 64-lane heads
+    in an ODD number or over a rung the block does not divide, and one
+    query head a K/V head over bf16 leaves whose heads are 64 wide or
+    whose rung the block does not divide; int8 leaves, a rung the
+    kernel's block does not divide over whole-lane-tile heads, K rows
+    where no kernel takes them, ring leaves and every CPU run keep the
+    grouped XLA form.  Every grouped-head step over sequence leaves
+    counts itself by the path it took, a K-row one also by its leaf, a
+    step of one query head a K/V head in a counter of its own; a ring
+    step never counts a path."""
     import jax
     import jax.numpy as jnp
 
@@ -668,17 +671,30 @@ def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
                da.kv_leaves(2, 2 * da._GROUPED_BLOCK, 2, 128, jnp.bfloat16)):
         assert took(kv, (8, 2)) == (["kernel"], {"kernel": 1, "xla": 0})
         assert took(kv, (10, 2)) == (["kernel"], {"kernel": 1, "xla": 0})
-    assert took(narrow, (8, 2)) == (["lane"], {"kernel": 0, "xla": 1})
-    # K fresh rows: the kernel's where the heads are whole lane tiles
+    # 64-lane heads, two a lane tile: the same kernel (lfm2's 32 / 8)
+    for kv, heads in ((narrow, (8, 2)),
+                      (da.kv_leaves(2, 128, 2, 64, jnp.float32), (8, 2)),
+                      (da.kv_leaves(2, 2 * da._GROUPED_BLOCK, 8, 64,
+                                    jnp.bfloat16), (32, 8))):
+        assert took(kv, heads) == (["kernel"], {"kernel": 1, "xla": 0})
+    # ... where they are an odd number, or the block does not divide the
+    # rung, no unit is whole lane tiles: read as they lie
+    odd = da.kv_leaves(2, 128, 3, 64, jnp.bfloat16)
+    for kv, heads in ((odd, (12, 3)),
+                      (da.kv_leaves(2, da._GROUPED_BLOCK + 128, 2, 64,
+                                    jnp.bfloat16), (8, 2))):
+        assert took(kv, heads) == (["lane"], {"kernel": 0, "xla": 1})
+    # K fresh rows: the kernel's wherever one row is
     n_rows = rows_counted()
-    for kv in (wide, da.kv_leaves(2, 128, 2, 128, jnp.float32)):
+    for kv in (wide, da.kv_leaves(2, 128, 2, 128, jnp.float32), narrow):
         for rows in (2, 3):
             assert took(kv, (8, 2), rows=rows) == (
                 ["kernel"], {"kernel": 1, "xla": 0})
-    assert rows_counted() == n_rows + 4
+    assert rows_counted() == n_rows + 6
     for kv, heads, kw in (
-            (narrow, (8, 2), {"rows": 3}),
+            (odd, (12, 3), {"rows": 3}),
             (da.kv_leaves(2, 128, 2, 128, jnp.int8), (8, 2), {}),
+            (da.kv_leaves(2, 128, 2, 64, jnp.int8), (8, 2), {}),
             (da.kv_leaves(2, da._GROUPED_BLOCK + 128, 2, 128, jnp.bfloat16),
              (8, 2), {})):                          # no whole blocks
         assert took(kv, heads, **kw) == (["grouped"],
@@ -740,10 +756,13 @@ GROUPED_TS = {
     # a rung of ONE block: idle, 0, a class's edge, the rung's end
     "one_block_rung": [-1, 0, GT - 1, GT, GB - GT, GB - 1],
 }
-#: (query heads a K/V head, K/V heads): the grouped cells' groupings at
-#: four heads, and ONE query head a K/V head — a head one row of a unit —
-#: at 30 heads (olmo_hybrid's: no power of two), 2 and 6
-GROUPINGS = [(5, 4), (7, 4), (8, 4), (1, 30), (1, 2), (1, 6)]
+#: (query heads a K/V head, K/V heads, a head's lanes): the grouped
+#: cells' groupings at four heads of 128, ONE query head a K/V head — a
+#: head one row of a unit — at 30 heads (olmo_hybrid's: no power of two),
+#: 2 and 6, and heads of 64 lanes, two a lane tile: lfm2's 8 heads in one
+#: unit, one pair, and a head of an odd number of half tiles (192)
+GROUPINGS = [(5, 4, 128), (7, 4, 128), (8, 4, 128), (1, 30, 128),
+             (1, 2, 128), (1, 6, 128), (4, 8, 64), (4, 2, 64), (2, 2, 192)]
 
 
 def _grouped_case(rep, dtype, ts, g=4, dh=128, t=2 * GB):
@@ -761,17 +780,18 @@ def _grouped_case(rep, dtype, ts, g=4, dh=128, t=2 * GB):
 
 @pytest.mark.parametrize("case", sorted(GROUPED_TS))
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("rep,g", GROUPINGS)
-def test_grouped_kernel_matches_the_grouped_form(rep, g, dtype, case):
+@pytest.mark.parametrize("rep,g,dh", GROUPINGS)
+def test_grouped_kernel_matches_the_grouped_form(rep, g, dh, dtype, case):
     """The append and the kernel's read against the XLA form: the same
     context (fp32: the order of the sums differs; bf16: the weights are
     rounded before they are normalised, not after), bit-equal leaves,
-    zero rows for idle slots — for grouped heads and for ONE query head
-    a K/V head (the heads of a unit consecutive rows), over a rung of two
-    blocks and of one."""
+    zero rows for idle slots — for grouped heads, for ONE query head a
+    K/V head (the heads of a unit consecutive rows) and for 64-lane
+    heads (two heads' contexts leave the kernel as one lane tile), over
+    a rung of two blocks and of one."""
     t = GB if case == "one_block_rung" else 2 * GB
     q, kn, vn, kv, ts, kw = _grouped_case(rep, dtype, GROUPED_TS[case], g=g,
-                                          t=t)
+                                          dh=dh, t=t)
     want, kv_want = da.grouped_masked_decode_attention(q, kn, vn, kv, ts,
                                                        **kw)
     kv_got = da.append_rows(kv, kn, vn, ts)
@@ -787,16 +807,18 @@ def test_grouped_kernel_matches_the_grouped_form(rep, g, dtype, case):
     assert not np.asarray(got)[np.asarray(ts) < 0].any()
 
 
-@pytest.mark.parametrize("rep,g,heads", [(7, 4, 1), (7, 4, 2), (1, 6, 1),
-                                         (1, 6, 3)])
+@pytest.mark.parametrize("rep,g,heads,dh", [
+    (7, 4, 1, 128), (7, 4, 2, 128), (1, 6, 1, 128), (1, 6, 3, 128),
+    (4, 8, 2, 64), (4, 8, 4, 64)])
 def test_grouped_kernel_scores_any_number_of_heads_in_one_product(
-        rep, g, heads, monkeypatch):
+        rep, g, heads, dh, monkeypatch):
     """The K/V heads a unit holds are a parameter of the q layout alone
     (a head's lane offset and width): one, two or all four heads a
-    product give the same context, and so do one, three or all six where
-    a head is ONE row of its unit."""
+    product give the same context, so do one, three or all six where a
+    head is ONE row of its unit, and one pair, two or all four of 64-lane
+    heads (units that are whole lane tiles)."""
     q, kn, vn, kv, ts, kw = _grouped_case(rep, "bfloat16",
-                                          GROUPED_TS["edges"], g=g)
+                                          GROUPED_TS["edges"], g=g, dh=dh)
     kv = da.append_rows(kv, kn, vn, ts)
     args = (q, kv["k"], kv["v"], ts, da.decode_work_items(ts, 2 * GB, GB, GT))
     named = dict(block=GB, tail=GT, interpret=True, **kw)
@@ -846,17 +868,18 @@ def _grouped_rows_kernel(q, kn, vn, kv, ts, block=GB, tail=GT, **kw):
 
 @pytest.mark.parametrize("case", sorted(GROUPED_ROWS_TS))
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("rep", [7, 8])
+@pytest.mark.parametrize("rep,dh", [(7, 128), (8, 128), (4, 64)])
 @pytest.mark.parametrize("k", [2, 3])
-def test_grouped_kernel_takes_k_fresh_rows(k, rep, dtype, case):
+def test_grouped_kernel_takes_k_fresh_rows(k, rep, dh, dtype, case):
     """``K`` fresh rows a slot through the kernel against the XLA form
     of the same layout (``_grouped_rows_attention``): the same context
     for every row — those past the rung's end too, which read every
     position and are not written — bit-equal leaves, zero rows and
     untouched leaves for idle slots, and no NaN where a row's last block
-    is fully masked for it."""
+    is fully masked for it; over 64-lane heads too (the chooser sends
+    their K rows where it sends their one)."""
     ts = GROUPED_ROWS_TS[case](k)
-    q, kn, vn, kv, ts, kw = _grouped_rows_case(rep, dtype, ts, k)
+    q, kn, vn, kv, ts, kw = _grouped_rows_case(rep, dtype, ts, k, dh=dh)
     want, kv_want = da.grouped_masked_decode_attention(q, kn, vn, kv, ts,
                                                        **kw)
     got, kv_got = _grouped_rows_kernel(q, kn, vn, kv, ts, **kw)
@@ -946,6 +969,16 @@ def test_unit_width_follows_from_the_shape():
     assert da._unit_heads(30, da._head_rows(1, 1)) == 30
     assert da._unit_heads(2 * rows, 1) == rows
     assert da._unit_heads(30, 1, 6) == 6
+    # heads of 64 lanes go two a lane tile: a unit is whole pairs (lfm2's
+    # 8 heads of 8 rows one unit; a pair however many rows it holds)
+    assert [da._heads_a_tile(d) for d in (64, 128, 192, 256, 96, 32)] == [
+        2, 1, 2, 1, 0, 0]
+    assert da._unit_heads(8, 8, pair=2) == 8
+    assert da._unit_heads(6, 16, pair=2) == 2
+    assert da._unit_heads(10, 8, pair=2) == 2
+    assert da._unit_heads(8, 2 * rows, pair=2) == 2
+    assert da._unit_heads(8, 8, 4, pair=2) == 4
+    assert da._unit_heads(8, 8, 1, pair=2) == 8     # a knob no pair: the rule
 
 
 def test_block_follows_from_the_slab_a_leaf_holds():
@@ -971,16 +1004,21 @@ def test_block_follows_from_the_slab_a_leaf_holds():
     assert sizes(3840, 30, seq_len=128) == (128, 128 // classes)
 
 
-@pytest.mark.parametrize("seq_len", [1024, 16384, 512])
-def test_grouped_work_list_reads_what_kv_positions_read_says(seq_len):
+@pytest.mark.parametrize("n_head,n_kv_head", [(28, 4), (32, 8)],
+                         ids=["heads_of_128", "heads_of_64"])
+@pytest.mark.parametrize("seq_len", [1024, 16384, 512, 2048])
+def test_grouped_work_list_reads_what_kv_positions_read_says(
+        seq_len, n_head, n_kv_head):
     """The one rounding: with the grouped kernel's sizes a slot's items
     add up to ``kv_positions_read`` (whole blocks, then the last in
     classes of the tail), which is also what a builder's
     ``make_cache.kv_positions_read`` hands the server's counter; off the
-    TPU that rule says the whole rung."""
+    TPU that rule says the whole rung.  Leaves 512 wide as four heads of
+    128 and as eight of 64 (lfm2's, whose rung is 2,048) read alike."""
     import jax.numpy as jnp
 
-    leaves = dict(width=512, dtype="bfloat16", n_head=28, n_kv_head=4)
+    leaves = dict(width=512, dtype="bfloat16", n_head=n_head,
+                  n_kv_head=n_kv_head)
     assert da.step_read_sizes(seq_len, **leaves) is None        # the CPU
     block, tail = da.step_read_sizes(seq_len, backend="tpu", **leaves)
     assert seq_len % block == 0 and block % tail == 0 and tail % 16 == 0
@@ -999,18 +1037,26 @@ def test_grouped_work_list_reads_what_kv_positions_read_says(seq_len):
                                   **leaves).tolist() == want[1:].tolist()
     assert da.step_positions_read(live, seq_len, **leaves).tolist() == [
         seq_len] * len(live)
-    # no kernel for narrow heads, int8, a ragged rung, nor for ONE query
-    # head a K/V head over fp32 leaves (the ragged kernel's) — over bf16
-    # ones it is the same read
-    for change in (dict(width=256), dict(dtype="int8"),
-                   dict(n_head=4, dtype="float32"), dict(n_head=2)):
+    # no kernel for heads that no whole lane tiles hold (96 lanes; an odd
+    # number of 64-lane heads), int8, a ragged rung, nor for ONE query
+    # head a K/V head over fp32 leaves (the ragged kernel's) or of 64
+    # lanes — over bf16 ones of whole lane tiles it is the same read
+    one_each = dict(n_head=n_kv_head)
+    for change in (dict(width=96 * n_kv_head), dict(dtype="int8"),
+                   dict(width=192, n_head=12, n_kv_head=3),
+                   dict(dtype="float32", **one_each), dict(n_head=2),
+                   dict(width=64 * n_kv_head, **one_each)):
         assert da.step_read_sizes(seq_len, backend="tpu",
                                   **{**leaves, **change}) is None
-    assert da.step_read_sizes(seq_len, backend="tpu",
-                              **{**leaves, "n_head": 4}) == (block, tail)
+    whole_tiles = n_kv_head == 4
+    assert da.step_read_sizes(
+        seq_len, backend="tpu", **{**leaves, **one_each}) == (
+            (block, tail) if whole_tiles else None)
+    assert da.step_read_sizes(seq_len, backend="tpu", **{
+        **leaves, "dtype": "float32"}) is not None
     assert da.step_positions_read(
-        live, seq_len, backend="tpu",
-        **{**leaves, "n_head": 4}).tolist() == want[1:].tolist()
+        live, seq_len, backend="tpu", **{**leaves, **one_each}).tolist() == (
+            want[1:].tolist() if whole_tiles else [seq_len] * len(live))
     assert da.step_read_sizes(da._GROUPED_BLOCK + 128, backend="tpu",
                               **leaves) is None
 

@@ -252,6 +252,83 @@ def test_layers_that_hold_different_leaves_are_declared_leaf_by_leaf():
         2, 16) == 3 * 2 * (d.conv_len - 1) * d.d_model * 4 + 3 * 4 * 4
 
 
+def test_the_builder_declares_what_a_step_reads_of_its_leaves():
+    """``make_cache.kv_positions_read`` is ``step_positions_read`` for
+    this builder's leaves, so the server's read counter follows the
+    chooser: at the published widths (32 query heads over 8 K/V heads of
+    64 lanes, bf16) the grouped kernel's rounding on a TPU — blocks of
+    512, the last in classes of 64 — and the whole rung off it; over
+    heads no lane tiles hold (the rehearsal's 32 lanes) the whole rung
+    everywhere."""
+    import functools
+
+    from paddle_tpu import decode_attention as da
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "lfm2_24b_a2b.json")) as f:
+        cfg = json.load(f)
+    # the declaration needs the dimensions alone: no weight is read
+    rule = decoding.make_routed_conv_lm_pooled_step_fn(
+        {}, cfg, kv_dtype="bf16")[1].kv_positions_read
+    d = rx.dims(cfg)
+    assert (d.n_head, d.n_kv_head, d.head_dim, d.d_kv) == (32, 8, 64, 512)
+    want = functools.partial(da.step_positions_read, width=512,
+                             dtype="bfloat16", n_head=32, n_kv_head=8)
+    ts = np.asarray([0, 63, 64, 511, 512, 1000, 2047], np.int32)
+    assert rule(ts, 2048).tolist() == want(ts, 2048).tolist() == [2048] * 7
+    assert rule(ts, 2048, backend="tpu").tolist() == want(
+        ts, 2048, backend="tpu").tolist() == da.kv_positions_read(
+            ts, 512, 64).tolist() == [64, 64, 128, 512, 576, 1024, 2048]
+    tiny = decoding.make_routed_conv_lm_pooled_step_fn(
+        {}, rehearse_cfg(), kv_dtype="bf16")[1].kv_positions_read
+    assert tiny(ts[:3], 64, backend="tpu").tolist() == [64] * 3
+
+
+def test_a_chunk_of_steps_equals_the_steps_one_by_one_and_says_its_form():
+    """Heads of 64 lanes (8 query heads over 2 K/V heads), bf16 leaves:
+    four steps a chunk leave the tokens and every leaf that the same
+    steps one a chunk leave; on the CPU the step takes the XLA form of
+    the read and counts itself so (the kernel's path is a TPU's)."""
+    from paddle_tpu import decode_attention as da
+
+    cfg = rehearse_cfg(num_attention_heads=8, num_key_value_heads=2,
+                       head_dim=64)
+    w = weights(cfg, seed=6, dtype="bfloat16")
+
+    def counted():
+        return [da.GROUPED_LOWERED.labels(path=path).value
+                for path in ("kernel", "xla")]
+
+    def run(steps, chunks):
+        step, make_cache = decoding.make_routed_conv_lm_pooled_step_fn(
+            w, cfg, kv_dtype="bf16")
+        pool = KVSlotPool(step, make_cache, eos_id=V, max_slots=2,
+                          max_seq_len=32, slot_ladder=[2], len_ladder=[32],
+                          steps=steps, kv_dtype="bf16")
+        rng = np.random.RandomState(4)
+        state = pool.alloc(2, 32)
+        for slot, n in enumerate((5, 9)):
+            state = pool.admit(state, slot, rng.randint(0, V, n).astype(
+                np.int32), n, 30)
+        for _ in range(chunks):
+            state = pool.chunk(state)
+        return state
+
+    by_kernel, by_xla = counted()
+    together, apart = run(4, 3), run(1, 12)
+    assert counted()[0] == by_kernel and counted()[1] > by_xla
+    assert np.array_equal(np.asarray(together["tokens"]),
+                          np.asarray(apart["tokens"]))
+    for a, b in zip(together["cache"]["layers"], apart["cache"]["layers"]):
+        for name in a:
+            assert np.array_equal(np.asarray(a[name].astype("float32")),
+                                  np.asarray(b[name].astype("float32")))
+    k = np.asarray(together["cache"]["layers"][1]["k"].astype("float32"))
+    # twelve steps wrote positions 0 .. 11 of each live slot, no other
+    assert k.shape == (2, 32, 128) and np.abs(k[:, :12]).max(-1).min() > 0
+    assert not k[:, 12:].any()
+
+
 @pytest.mark.parametrize("tier", ["prefix", "speculative"])
 def test_prefix_and_speculation_are_refused_over_this_builder(tier):
     cfg = rehearse_cfg()

@@ -10,6 +10,8 @@ their own: under ``--dist loadfile`` a file is one worker's job, and the
 kernel tests of ``tests/test_decode_attention.py`` (interpret mode, 167
 cases) are another's.
 """
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -37,6 +39,41 @@ def one_chip():
     yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+@contextlib.contextmanager
+def _chunk_tool():
+    """``(tools/decode_chunk_text.py, the checkout's root)``.  The tool
+    answers "tpu" for the backend it compiles for and puts the checkout
+    on the path: both undone when the block ends."""
+    import importlib.util
+    import os
+    import sys
+
+    import jax
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "decode_chunk_text", os.path.join(root, "tools",
+                                          "decode_chunk_text.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", jax.default_backend)
+        patch.setattr(sys, "path", list(sys.path))
+        yield tool, root
+
+
+def _compiled_chunk(config, layers, counters):
+    """The slot pool's ``chunk`` of ``config`` (its one rung pair, the
+    published widths, the first ``layers`` of its cut) compiled ONCE:
+    ``(text, memory analysis, counted)`` — ``counted`` what the trace
+    added to ``counters`` (``{key: a counter's child}``)."""
+    with _chunk_tool() as (tool, root):
+        before = {k: c.value for k, c in counters.items()}
+        compiled = tool.lowered_chunk(root, config, layers=layers).compile()
+        counted = {k: c.value - before[k] for k, c in counters.items()}
+    return compiled.as_text(), compiled.memory_analysis(), counted
 
 
 def test_kernel_compiles_for_v5e_at_gpt1_widths(one_chip):
@@ -260,8 +297,7 @@ def test_hybrid_ssm_layer_compiles_for_v5e_at_falcon_h1_widths(one_chip):
     assert mem.temp_size_in_bytes < ssm_leaf // 2
 
 
-def test_gpt1_chunk_compiles_for_v5e_with_no_cast_of_a_weight(one_chip,
-                                                             monkeypatch):
+def test_gpt1_chunk_compiles_for_v5e_with_no_cast_of_a_weight(one_chip):
     """The slot pool's ``chunk`` of ``gpt1_117m`` (its one rung pair, the
     published widths, two layers) as ``tools/decode_chunk_text.py``
     builds it for a TPU: the builder holds bf16 copies of the matrices
@@ -269,24 +305,9 @@ def test_gpt1_chunk_compiles_for_v5e_with_no_cast_of_a_weight(one_chip,
     casts a whole weight-shaped matrix to bf16 (13 before the copies:
     six a layer and the head, run again on every call), and the ragged
     kernel is still the attention."""
-    import importlib.util
-    import os
-    import sys
-
-    import jax
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "decode_chunk_text", os.path.join(root, "tools",
-                                          "decode_chunk_text.py"))
-    tool = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(tool)
-    # the tool answers "tpu" for the backend it compiles for and puts
-    # the checkout on the path: both undone when the test ends
-    monkeypatch.setattr(jax, "default_backend", jax.default_backend)
-    monkeypatch.setattr(sys, "path", list(sys.path))
-    lowered = tool.lowered_chunk(root, "gpt1_117m")
-    text = lowered.compile().as_text()
+    with _chunk_tool() as (tool, root):
+        lowered = tool.lowered_chunk(root, "gpt1_117m")
+        text = lowered.compile().as_text()
     assert "ragged_decode_attention" in text
     assert tool.weight_casts(lowered, text) == 0
 
@@ -523,37 +544,12 @@ def olmo_chunk(one_chip):
     ``tools/decode_chunk_text.py`` builds it for a TPU, compiled ONCE for
     the tests below: ``(text, memory analysis, counted)`` — ``counted``
     what the trace added to the two lowering counters, by path."""
-    import importlib.util
-    import os
-    import sys
-
-    import jax
-
     from paddle_tpu import delta_hybrid_lm as dh
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "decode_chunk_text", os.path.join(root, "tools",
-                                          "decode_chunk_text.py"))
-    tool = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(tool)
-
-    def counts():
-        return {(name, path): c.labels(path=path).value
-                for name, c in (("delta", dh.LOWERED),
-                                ("full", da.UNGROUPED_LOWERED))
-                for path in ("kernel", "xla")}
-
-    # the tool answers "tpu" for the backend it compiles for and puts
-    # the checkout on the path: both undone when the chunk is compiled
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(jax, "default_backend", jax.default_backend)
-        patch.setattr(sys, "path", list(sys.path))
-        before = counts()
-        compiled = tool.lowered_chunk(root, "olmo_hybrid_7b",
-                                      layers=12).compile()
-        counted = {k: v - before[k] for k, v in counts().items()}
-    return compiled.as_text(), compiled.memory_analysis(), counted
+    return _compiled_chunk("olmo_hybrid_7b", 12, {
+        (name, path): c.labels(path=path)
+        for name, c in (("delta", dh.LOWERED), ("full", da.UNGROUPED_LOWERED))
+        for path in ("kernel", "xla")})
 
 
 def test_olmo_hybrid_chunk_holds_nine_kernel_calls_a_step_on_v5e(olmo_chunk):
@@ -608,36 +604,12 @@ def solar_chunk(one_chip):
     pair, the published widths, the whole 4-layer cut: G, K, K, K, 40
     held experts of 320) as ``tools/decode_chunk_text.py`` builds it for
     a TPU, compiled ONCE: ``(text, memory analysis, counted)``."""
-    import importlib.util
-    import os
-    import sys
-
-    import jax
-
     from paddle_tpu import delta_hybrid_lm as dh
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "decode_chunk_text", os.path.join(root, "tools",
-                                          "decode_chunk_text.py"))
-    tool = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(tool)
-
-    def counts():
-        out = {("path", p): dh.LOWERED.labels(path=p).value
-               for p in ("kernel", "xla")}
-        out.update({("decay", k): dh.DECAY.labels(decay=k).value
-                    for k in ("head", "channel")})
-        return out
-
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(jax, "default_backend", jax.default_backend)
-        patch.setattr(sys, "path", list(sys.path))
-        before = counts()
-        compiled = tool.lowered_chunk(root, "solar_open2_250b",
-                                      layers=4).compile()
-        counted = {k: v - before[k] for k, v in counts().items()}
-    return compiled.as_text(), compiled.memory_analysis(), counted
+    return _compiled_chunk("solar_open2_250b", 4, {
+        **{("path", p): dh.LOWERED.labels(path=p) for p in ("kernel", "xla")},
+        **{("decay", k): dh.DECAY.labels(decay=k)
+           for k in ("head", "channel")}})
 
 
 def test_solar_open2_chunk_takes_the_kernel_with_a_decay_a_channel_on_v5e(
@@ -676,6 +648,33 @@ def test_solar_open2_chunk_takes_the_kernel_with_a_decay_a_channel_on_v5e(
     assert mem.temp_size_in_bytes < 0.5e9
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.generated_code_size_in_bytes) < 13.5e9
+
+
+def test_lfm2_chunk_reads_its_64_lane_heads_by_the_kernel_on_v5e(one_chip):
+    """The slot pool's ``chunk`` of ``lfm2_24b_a2b`` at its published
+    widths (its one rung pair; two layers: the dense conv layer and the
+    first attention layer with its 64 experts): the attention's read is
+    ONE call of the grouped kernel a step over both ``bf16[256,2048,512]``
+    leaves as they lie — 32 query heads over 8 K/V heads of 64 lanes,
+    ONE unit of 64 rows x 512 lanes, a lane tile of context two heads —
+    no ``f32[256,32,2048]`` scores over the whole rung (the lane-masked
+    form's), no copy of a leaf, and the counter says ``kernel``."""
+    text, mem, counted = _compiled_chunk("lfm2_24b_a2b", 2, {
+        path: da.GROUPED_LOWERED.labels(path=path)
+        for path in ("kernel", "xla")})
+    assert counted["kernel"] >= 1 and not counted["xla"]
+    lines = text.splitlines()
+    calls = [line for line in lines
+             if "grouped_decode_attention" in line and "custom-call(" in line]
+    assert len(calls) == 1 and calls[0].count("bf16[256,2048,512]") >= 2
+    assert "bf16[256,1,64,512]" in calls[0]         # q: units, R, L
+    assert "f32[256,4,8,128]" in calls[0]           # two heads a lane tile
+    assert "f32[256,32,2048]" not in text and "f32[256,2048,512]" not in text
+    assert not [line for line in lines
+                if " copy(" in line and "[256,2048,512]" in line]
+    leaf = 256 * 2048 * 512 * 2
+    assert mem.alias_size_in_bytes >= 2 * leaf
+    assert mem.temp_size_in_bytes < leaf // 4
 
 
 @pytest.mark.parametrize("lanes,copied", [(640, False), (576, True)],

@@ -13,8 +13,14 @@ modules' run times; ``chiprun_out/<cell>.spans.json`` gets, per span
 name, how many were recorded and their seconds (a ``DecodeServer``
 turn's phases: seconds a ``serving/decode_tick``, and the share of the
 ticks their leaves cover), every device gap's name with its seconds,
-and the ``serving/...`` events found on the trace's own host lines.  A
-development aid: the driver never runs it.
+and the ``serving/...`` events found on the trace's own host lines;
+``chiprun_out/<cell>.counters.json`` gets what the process counted, from
+its start to its end, of which form a step's attention was lowered to
+(``decode_attention_*_lowered_total{path}``) and of the K/V positions
+its servers' steps read and found live
+(``serving_decode_kv_positions_{read,live}_total`` as each server had
+them when it stopped, and ``read_over_live``).
+A development aid: the driver never runs it.
 """
 import collections
 import json
@@ -80,6 +86,38 @@ def host_events(path):
     return dict(out)
 
 
+def keep_kv_counters(kept):
+    """Have every ``DecodeServer`` add its K/V position counters to
+    ``kept`` as it stops (it takes its series out of the registry
+    there)."""
+    from paddle_tpu.serving.decode import DecodeServer
+
+    stop = DecodeServer.stop
+
+    def stop_and_keep(self, *args, **kw):
+        seen = self.metrics()["decode"]
+        for key in ("kv_positions_read", "kv_positions_live"):
+            kept[key] = kept.get(key, 0) + seen[key]
+        return stop(self, *args, **kw)
+
+    DecodeServer.stop = stop_and_keep
+
+
+def counters(kept):
+    """The lowering counters by path as the process has them now, and
+    the K/V positions its servers counted (:func:`keep_kv_counters`)."""
+    from paddle_tpu import monitor
+
+    out = {"%s{path=%s}" % (name, path): monitor.counter_value(name, path=path)
+           for name in ("decode_attention_grouped_lowered_total",
+                        "decode_attention_ungrouped_lowered_total")
+           for path in ("kernel", "xla")}
+    read, live = (kept.get("kv_positions_" + kind, 0)
+                  for kind in ("read", "live"))
+    return dict(out, kv_positions_read=read, kv_positions_live=live,
+                read_over_live=read / live if live else None)
+
+
 def main():
     from benchmark import run as bench_run
     from benchmark.lib import harness, xplane
@@ -110,9 +148,17 @@ def main():
         cleanup(self)
 
     harness.Tracer.cleanup = keep
+    kept = {}
+    keep_kv_counters(kept)
     if "--trace" not in sys.argv:
         sys.argv += ["--trace", "1"]
-    return bench_run.main(sys.argv[1:])
+    try:
+        return bench_run.main(sys.argv[1:])
+    finally:
+        out = os.path.join(ROOT, "chiprun_out")
+        os.makedirs(out, exist_ok=True)
+        with open(os.path.join(out, cell + ".counters.json"), "w") as f:
+            json.dump(counters(kept), f, indent=1)
 
 
 if __name__ == "__main__":

@@ -5,7 +5,7 @@ chip: the Pallas kernel (``paddle_tpu/decode_attention.py``:
 (``grouped_masked_decode_attention``), turn and turn about in one
 process over the same leaves.
 
-Four shapes (``--shape``, all unless said), every slot's position drawn
+Five shapes (``--shape``, all unless said), every slot's position drawn
 as the cell's traffic file leaves them (a prompt or a document and a
 question, then a step drawn evenly over the answer's life):
 
@@ -22,7 +22,12 @@ question, then a step drawn evenly over the answer's life):
   slots x 1,024 x 3,840, 30 K/V heads of 128, ONE query head each (a
   head is one row of a unit: ``--heads`` 5 / 6 / 10 / 15 / 30 a unit);
   the comparison and parity reference is the form that reads the leaves
-  as they lie, ``lane_masked_decode_attention``, over the whole rung.
+  as they lie, ``lane_masked_decode_attention``, over the whole rung;
+* ``lfm2`` — ``lfm2_24b_a2b.long_answers_2k``'s attention layers: 256
+  slots x 2,048 x 512, 8 K/V heads of 64 lanes (two a lane tile), 4
+  query heads a K/V head; the comparison is that lane form too (a view
+  of 64-lane heads would copy the rung), and a checkout whose kernel
+  wants whole-lane-tile heads runs it alone.
 
 ``--rows K`` hands every slot of every shape ``K`` fresh rows (row ``j``
 at ``ts + j``) instead of its cell's: the XLA form of ``K`` rows is the
@@ -89,13 +94,16 @@ SHAPES = {
     "falcon": (80, 1024, 4, 128, 5, "long_answers_batch"),
     "k_exaone": (128, 4096, 8, 128, 8, "long_answers_mtp_4k"),
     "olmo": (80, 1024, 30, 128, 1, "long_answers_batch"),
+    "lfm2": (256, 2048, 8, 64, 4, "long_answers_2k"),
 }
 REHEARSAL = {"smallthinker": (4, 512, 4, 128, 7, "shared_docs_qa_16k"),
              "falcon": (6, 256, 4, 128, 5, "long_answers_batch"),
              "k_exaone": (4, 1024, 8, 128, 8, "long_answers_mtp_4k"),
-             "olmo": (6, 256, 6, 128, 1, "long_answers_batch")}
+             "olmo": (6, 256, 6, 128, 1, "long_answers_batch"),
+             "lfm2": (6, 256, 4, 64, 4, "long_answers_2k")}
 #: fresh rows a slot, as the shape's cell hands them
-ROWS = {"smallthinker": 1, "falcon": 1, "k_exaone": 2, "olmo": 1}
+ROWS = {"smallthinker": 1, "falcon": 1, "k_exaone": 2, "olmo": 1,
+        "lfm2": 1}
 KNOBS = ("block", "classes", "ahead", "heads", "slab")
 
 
@@ -143,10 +151,12 @@ def forms(mod, shape, interpret, rows=1):
     takes ``rows`` fresh rows a slot."""
     S, T, G, D, R, _ = shape
     kw = dict(n_head=G * R, n_kv_head=G, scale=D ** -0.5)
-    # one row of ONE query head a K/V head: the form that reads the
-    # leaves as they lie (the per-head view's is six float32 copies of a
-    # leaf a call at olmo's widths)
-    masked = (mod.lane_masked_decode_attention if R == 1 and rows == 1
+    # one row of ONE query head a K/V head, or of heads narrower than a
+    # lane tile: the form that reads the leaves as they lie (the per-head
+    # view's is six float32 copies of a leaf a call at olmo's widths, a
+    # re-tiled copy of each leaf at lfm2's)
+    masked = (mod.lane_masked_decode_attention
+              if (R == 1 or D % 128) and rows == 1
               and hasattr(mod, "lane_masked_decode_attention")
               else mod.grouped_masked_decode_attention)
     out = {"xla": lambda q, kn, vn, kv, ts: masked(q, kn, vn, kv, ts, **kw)}
@@ -390,7 +400,9 @@ def main(argv=None):
                 "unit_heads": (mod._unit_heads(
                     G, mod._head_rows(fresh, R)
                     if hasattr(mod, "_head_rows") else -(-fresh * R // 8) * 8,
-                    mod._GROUPED_HEADS)
+                    mod._GROUPED_HEADS,
+                    *([mod._heads_a_tile(D)]    # heads a lane tile
+                      if hasattr(mod, "_heads_a_tile") else []))
                     if form == "kernel" and hasattr(mod, "_unit_heads")
                     else None),
                 "live_bytes": live, "read_bytes": read,
