@@ -46,8 +46,8 @@ order, so the masked forms serve unchanged but for where they write).
 ``K`` fresh rows read the OLD ring and themselves before they overwrite
 it (:func:`ring_positions` says which position each old row holds).  A
 wrapped row cannot be sliced by positions or rolled back, so the pool
-carries a ring leaf whole (``make_cache.leaf_seq_windows`` declares it:
-``KVSlotPool``) and refuses what would slice it.
+carries a ring leaf whole (the builder declares its window:
+``decoding.Leaf``) and refuses what would slice it.
 
 **The contract.**  A step hands each slot ``n`` at position ``ts[n]``
 ``K >= 1`` fresh query / key / value rows (one decode step: ``K = 1``;
@@ -155,7 +155,8 @@ from paddle_tpu.monitor import registry as _registry
 
 __all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
            "kv_read_block", "kv_positions_read", "decode_work_items",
-           "step_read_sizes", "step_positions_read", "last_fresh_row",
+           "step_read_sizes", "step_positions_read", "ragged_positions_read",
+           "last_fresh_row",
            "ragged_decode_attention", "grouped_decode_attention",
            "grouped_masked_decode_attention",
            "lane_masked_decode_attention", "append_rows",
@@ -390,12 +391,21 @@ def step_positions_read(ts, seq_len: int, **leaves):
     takes after the rung): the grouped kernel's rounding where it serves
     them, else the whole rung (an XLA form).  What a builder of grouped
     heads, or of one query head a K/V head over bf16 leaves, declares as
-    ``make_cache.kv_positions_read`` for the server's counter (a
-    ``K``-row round: at :func:`last_fresh_row`)."""
+    its ``"kv"`` read (``decoding.PositionRead``) for the server's
+    counter (a ``K``-row round: at :func:`last_fresh_row`)."""
     sizes = step_read_sizes(seq_len, **leaves)
     if sizes is None:
         return np.full_like(ts, seq_len)
     return kv_positions_read(ts, *sizes)
+
+
+def ragged_positions_read(ts, seq_len: int):
+    """Positions a one-row step at ``ts >= 0`` reads of a slot's fp32
+    leaves where :func:`ragged_decode_attention` serves it: blocks of
+    :func:`kv_read_block`, the last in classes of :data:`KV_TAIL` rows.
+    Declared as the ``"kv"`` read over such leaves WHATEVER the backend
+    (the counter has said so since PR 37; tier-1 holds it on a CPU)."""
+    return kv_positions_read(ts, kv_read_block(seq_len))
 
 
 def decode_work_items(ts, seq_len: int, block: int, tail=None):

@@ -46,22 +46,21 @@ the same parts:
   all run it.  The scalar-``t`` builder keeps a cache and an attention
   of its own on purpose — it is the independent reference the tests
   hold the pooled path to.
-* every ``make_cache`` DECLARES its leaves' sequence axes
-  (``make_cache.leaf_seq_axes``, :func:`cache_leaf_seq_axes`) and, for
-  a leaf whose sequence axis advances one row per several positions,
-  the stride (``make_cache.leaf_seq_strides``); the pool infers nothing
-  from a shape.  A builder that can feed ``C`` prompt tokens of one
-  slot in one call declares that too (``make_cache.prefill_fn``), as
-  does one that can feed several slots their whole prompts from
-  position 0 in one call (``make_cache.prefill_rows_fn``), and what a
-  pool can do follows from the declarations: a request seated and
-  prefilled in one dispatch, chunked prefill, and prefix snapshots over
-  recurrent leaves.
+* every builder DECLARES what its ``make_cache`` builds and what else
+  it offers, in ONE :class:`CacheSpec` (:func:`declare`, read back by
+  :func:`spec_of`): each leaf's kind (:class:`Leaf`: sequence axis,
+  stride, ring window, slot axis — the pool infers nothing from a
+  shape), a chunked or a batched prefill, a verify forward and a
+  drafting module, what its steps read of a slot's positions
+  (:class:`PositionRead`) and the counts it keeps of routed experts.
+  What a pool can do follows from the declaration: a request seated and
+  prefilled in one dispatch, chunked prefill, prefix snapshots over
+  recurrent leaves, self-drafting rounds.
 """
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -82,9 +81,7 @@ __all__ = [
     "make_delta_hybrid_lm_pooled_step_fn",
     "make_kda_routed_lm_pooled_step_fn",
     "make_latent_sparse_lm_pooled_step_fn",
-    "cache_leaf_seq_axes", "cache_leaf_seq_strides", "cache_leaf_seq_windows",
-    "cache_leaf_slotless", "NO_SLOT_AXIS",
-    "recurrent_leaf_names", "ring_leaf_names",
+    "Leaf", "PositionRead", "CacheSpec", "READ_KINDS", "declare", "spec_of",
     "normalize_kv_dtype",
     "random_transformer_lm_state",
 ]
@@ -120,6 +117,150 @@ def normalize_kv_dtype(kv_dtype, supported=KV_DTYPES) -> str:
             "unsupported kv_dtype %r (supported: %s)"
             % (kv_dtype, list(supported)))
     return d
+
+
+# ---------------------------------------------------------------------------
+# What a builder tells the slot pool and the server: ONE declaration
+# ---------------------------------------------------------------------------
+class Leaf:
+    """What ONE cache leaf is to the slot pool (a plain object: a tree
+    of them flattens leaf for leaf like the cache it describes).
+
+    ``seq_axis``: the leaf's sequence axis (K/V rows: covered by the
+    write-before-read invariant, sliced by ``extract_kv`` /
+    ``admit_prefix``, counted by ``kv_rung_bytes``), or None for a
+    RECURRENT leaf (read and re-written whole each step: carried, never
+    sliced, started from zero by the step itself at position 0).
+    ``stride``: positions a row of the sequence axis stands for (a
+    prefix of ``P`` positions is its first ``P // stride`` rows).
+    ``window``: a RING leaf — ``min(rung, window)`` rows, position ``p``
+    in row ``p mod window`` (``decode_attention.kv_leaves(...,
+    window=W)``): carried whole, never sliced by positions nor rolled
+    back.  ``slot=False``: axis 0 is NOT the slot (counts kept for the
+    whole pool): no slot's row exists to snapshot, no sequence's state."""
+
+    __slots__ = ("seq_axis", "stride", "window", "slot")
+
+    def __init__(self, seq_axis: Optional[int] = None, stride: int = 1,
+                 window: Optional[int] = None, slot: bool = True):
+        self.seq_axis, self.stride = seq_axis, stride
+        self.window, self.slot = window or None, slot
+
+    def __repr__(self):
+        return "Leaf(seq_axis=%r, stride=%r, window=%r, slot=%r)" % (
+            self.seq_axis, self.stride, self.window, self.slot)
+
+
+#: the kinds of read a builder may declare a rule for; the server maps
+#: each to its two series (``serving.decode.POSITION_SERIES``)
+READ_KINDS = ("kv", "sparse", "window", "latent")
+
+
+class PositionRead(NamedTuple):
+    """What a step reads of a slot's positions, for the server's
+    counters, one a kind of read the builder has.  ``kind`` ``"kv"``:
+    ``rule(ts, seq_len)`` is what a one-row step at ``ts`` reads of the
+    slot's sequence leaves on a rung (a kernel's rounding, or the whole
+    rung: ``decode_attention.step_positions_read``); a ``K``-row round
+    counts it once a slot that advanced, at its last fresh row — unless
+    ``rounds`` is False: the builder's ``K``-row verify reads masked
+    over the whole pool.  A builder with no ``"kv"`` read is counted the
+    whole pool a step.  The other kinds (:data:`READ_KINDS`): ``rule(n)``
+    is what a query of context ``n`` reads in each of ``layers`` layers
+    that select what they read."""
+
+    kind: str
+    rule: Callable
+    layers: int = 1
+    rounds: bool = True
+
+
+class CacheSpec:
+    """Everything a builder tells ``KVSlotPool`` and ``DecodeServer``
+    beside ``make_cache`` itself; :func:`declare` hangs it on
+    ``make_cache``, :func:`spec_of` reads it back, and what a pool can
+    do follows from it.
+
+    ``leaves``: ONE pytree shaped like the cache, a :class:`Leaf` a
+    leaf.  ``prefill_fn(cache, row, tokens [C + lookahead], start, n)``
+    (``.chunk_tokens`` = ``C``): a CHUNKED prefill of one slot — the
+    pool compiles ``prefill`` and keeps prefixes as whole-row SNAPSHOTS
+    taken at its boundaries.  ``prefill_rows_fn(cache, rows [G], tokens
+    [G, C])``: a BATCHED prefill from position 0 — a turn's requests
+    are seated and prefilled in one dispatch.  ``verify_fn`` /
+    ``mtp_fn``: the ``K``-row forward and the module of a self-drafting
+    round (``serving.speculative.make_self_draft``).  ``reads``: a
+    :class:`PositionRead` a kind.  ``expert_stats(cache)``: the leaf of
+    counts a step of ``n_expert`` routed experts keeps on the device."""
+
+    def __init__(self, leaves, *, prefill_fn=None, prefill_rows_fn=None,
+                 verify_fn=None, mtp_fn=None, reads=(), expert_stats=None,
+                 n_expert: int = 0):
+        import jax
+
+        self.leaves = leaves
+        #: the leaves' :class:`Leaf` in tree-flatten order: what the host
+        #: side (``KVSlotPool``) and the traced side
+        #: (:func:`make_prefix_admit_fn`) both read
+        self.flat = tuple(jax.tree.leaves(leaves))
+        self.prefill_fn, self.prefill_rows_fn = prefill_fn, prefill_rows_fn
+        self.verify_fn, self.mtp_fn = verify_fn, mtp_fn
+        self.reads = tuple(reads)
+        self.expert_stats, self.n_expert = expert_stats, int(n_expert)
+
+    def names(self, which: Callable) -> list:
+        """Tree paths of the leaves ``which(leaf)`` holds of."""
+        import jax
+
+        return [jax.tree_util.keystr(path) for path, leaf in
+                jax.tree_util.tree_flatten_with_path(self.leaves)[0]
+                if which(leaf)]
+
+
+def declare(make_cache, spec: CacheSpec):
+    """Hang ``spec`` on ``make_cache`` after holding it to the cache
+    ``make_cache`` builds (its shapes only: nothing is allocated);
+    returns ``make_cache``.  The one place a declaration is checked."""
+    import jax
+
+    flat = spec.flat
+    built = jax.eval_shape(lambda: make_cache(1, 128))
+    cache = jax.tree.leaves(built)
+    if (jax.tree.structure(spec.leaves) != jax.tree.structure(built)
+            or not all(isinstance(leaf, Leaf) for leaf in flat)):
+        raise ValueError(
+            "CacheSpec.leaves declares %d leaves, the cache has %d: it is "
+            "a pytree shaped like the cache make_cache builds, a "
+            "decoding.Leaf a leaf" % (len(flat), len(cache)))
+    if any(leaf.stride < 1 for leaf in flat):
+        raise ValueError(
+            "CacheSpec.leaves must declare a stride >= 1 for each of the "
+            "cache's %d leaves" % len(cache))
+    if any((leaf.window or 0) < 0 for leaf in flat):
+        raise ValueError(
+            "CacheSpec.leaves must declare a window >= 0 (None: no "
+            "window) for each of the cache's %d leaves" % len(cache))
+    kinds = [read.kind for read in spec.reads]
+    if len(set(kinds)) < len(kinds) or not set(kinds) <= set(READ_KINDS):
+        raise ValueError(
+            "CacheSpec.reads declares the kinds %s: one PositionRead a "
+            "kind, of %s" % (kinds, list(READ_KINDS)))
+    make_cache.cache_spec = spec
+    return make_cache
+
+
+def spec_of(make_cache) -> CacheSpec:
+    """The :class:`CacheSpec` ``make_cache`` was declared with: the ONE
+    reader.  A ``make_cache`` that declares nothing is an error."""
+    spec = getattr(make_cache, "cache_spec", None)
+    if spec is None:
+        raise ValueError(
+            "make_cache declares nothing: call decoding.declare("
+            "make_cache, CacheSpec(leaves)) with a pytree shaped like the "
+            "cache it builds, holding each leaf's decoding.Leaf (its "
+            "sequence axis, None for a leaf with none); the slot pool "
+            "infers nothing from a shape")
+    return spec
 
 
 def random_transformer_lm_state(rng, vocab, d_model, n_layer, n_head,
@@ -456,6 +597,34 @@ def _stacked_layers(W, name, n_layer):
               for param in _BLOCK_PARAMS} for i in range(n_layer)])
 
 
+def _ragged_kv_reads(kv: str):
+    """The ``"kv"`` read of a builder with no rule of its own (the
+    transformer LM; the sparse-linear and latent-sparse builders, whose
+    selected reads have kinds of their own), by its leaves' storage
+    dtype: float32 as ``decode_attention.ragged_positions_read`` rounds
+    whatever the backend, a ``K``-row round masked over the whole pool
+    (what the server counted for every fp32 pool without a rule until
+    PR 58, kept to the count); any other storage no rule: the whole
+    pool a step."""
+    from paddle_tpu.decode_attention import ragged_positions_read
+
+    if kv != "float32":
+        return ()
+    return (PositionRead("kv", ragged_positions_read, rounds=False),)
+
+
+def _step_kv_read(d, kv: str) -> PositionRead:
+    """The ``"kv"`` read of a builder whose steps attend through
+    ``decode_attention.make_decode_attention`` over leaves of storage
+    dtype ``kv`` and ``d``'s head grouping: that chooser's host mirror,
+    ``decode_attention.step_positions_read``."""
+    from paddle_tpu.decode_attention import step_positions_read
+
+    return PositionRead("kv", functools.partial(
+        step_positions_read, width=d.d_kv, dtype=kv, n_head=d.n_head,
+        n_kv_head=d.n_kv_head))
+
+
 def _pooled_lm_parts(state, d_model, n_layer, n_head, name, kv_dtype):
     """What the pooled step and the K-wide verify forward of one model
     share: ``forward(cache, x, ts)`` — :func:`_lm_forward_one` over the
@@ -514,9 +683,6 @@ def _pooled_lm_parts(state, d_model, n_layer, n_head, name, kv_dtype):
     def make_cache(n_rows: int, seq_len: int):
         return [kv_leaves(n_rows, seq_len, n_head, d_head, kv)
                 for _ in range(n_layer)]
-
-    make_cache.leaf_seq_axes = jax.tree.map(
-        lambda _: KV_SEQ_AXIS, jax.eval_shape(lambda: make_cache(1, 1)))
 
     def forward(cache, x, ts):
         T = cache[0]["k"].shape[KV_SEQ_AXIS]
@@ -583,10 +749,11 @@ def make_transformer_lm_pooled_step_fn(
     The cache T axis is read from the cache arrays themselves, so one
     step fn serves every length rung of the slot pool's bucket ladder:
     ``make_cache(n_rows, seq_len)`` allocates the zeroed pytree for one
-    (slot-rung, length-rung) pair and DECLARES every leaf's sequence
-    axis (``make_cache.leaf_seq_axes``, see :func:`cache_leaf_seq_axes`),
-    so ``extract_kv`` / ``admit_prefix`` carry both dtypes unchanged and
-    prefix caching and speculative decode compose.  The block around
+    (slot-rung, length-rung) pair, and its :class:`CacheSpec` declares
+    every leaf's sequence axis, so ``extract_kv`` / ``admit_prefix``
+    carry both dtypes unchanged and prefix caching and speculative
+    decode compose.  What a step reads is declared by
+    :func:`_ragged_kv_reads`.  The block around
     the attention is the scalar-t builder's (:func:`_lm_forward_one`) —
     with all rows at the same position the two agree to rounding
     (parity-tested in tests/test_seq2seq_decode.py).
@@ -601,7 +768,7 @@ def make_transformer_lm_pooled_step_fn(
     whole each step) are outside it — see
     :func:`make_hybrid_ssm_lm_pooled_step_fn`.
 
-    A prompt need not walk that step.  ``make_cache.prefill_rows_fn(
+    A prompt need not walk that step.  The spec's ``prefill_rows_fn(
     cache, rows [G] int32, tokens [G, C] int32) -> cache`` is the
     builder's BATCHED PREFILL: ``tokens[g, j]`` is fed at position ``j``
     of slot ``rows[g]`` for every ``j`` in one ``C``-wide forward of the
@@ -617,7 +784,10 @@ def make_transformer_lm_pooled_step_fn(
     last token in ONE dispatch (``KVSlotPool.seat_prefill``), and the
     slot's first step eats that last token.
     """
+    import jax
     import jax.numpy as jnp
+
+    from paddle_tpu.decode_attention import KV_SEQ_AXIS
 
     forward, prefill, W, make_cache = _pooled_lm_parts(
         state, d_model, n_layer, n_head, name, kv_dtype)
@@ -634,7 +804,11 @@ def make_transformer_lm_pooled_step_fn(
              + W[name + "_pos_emb"][jnp.arange(tokens.shape[1])][None])
         return prefill(cache, rows, x, layers)
 
-    make_cache.prefill_rows_fn = prefill_rows_fn
+    declare(make_cache, CacheSpec(
+        jax.tree.map(lambda _: Leaf(KV_SEQ_AXIS),
+                     jax.eval_shape(lambda: make_cache(1, 1))),
+        prefill_rows_fn=prefill_rows_fn, reads=_ragged_kv_reads(
+            _KV_STORAGE[normalize_kv_dtype(kv_dtype, _LM_KV_DTYPES)])))
     return step_fn, make_cache
 
 
@@ -654,11 +828,11 @@ def make_hybrid_ssm_lm_pooled_step_fn(state, cfg, name: str = "lm",
     (bf16 as stored: no per-step conversion); ``cfg``: the published
     config keys (``hybrid_ssm.dims``).
 
-    One layer's cache is two kinds of leaf, and ``make_cache`` DECLARES
-    which is which (``make_cache.leaf_seq_axes``: a pytree shaped like
-    the cache holding each leaf's sequence axis, ``-1`` for none), so
-    the slot pool never guesses from a shape — a length rung equal to
-    ``d_state`` or ``head_dim`` is an ordinary rung:
+    One layer's cache is two kinds of leaf, and the builder DECLARES
+    which is which (:class:`CacheSpec`'s ``leaves``: a pytree shaped
+    like the cache holding each leaf's :class:`Leaf`), so the slot pool
+    never guesses from a shape — a length rung equal to ``d_state`` or
+    ``head_dim`` is an ordinary rung:
 
     * ``k``, ``v`` ``[N, T, n_kv_head * head_dim]`` in ``kv_dtype``
       (``k`` after rotary): appended in place at ``ts``, read
@@ -686,8 +860,7 @@ def make_hybrid_ssm_lm_pooled_step_fn(state, cfg, name: str = "lm",
     import jax.numpy as jnp
 
     from paddle_tpu import hybrid_ssm as hs
-    from paddle_tpu.decode_attention import (kv_leaves, make_decode_attention,
-                                             step_positions_read)
+    from paddle_tpu.decode_attention import kv_leaves, make_decode_attention
 
     d = hs.dims(cfg)
     kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
@@ -707,11 +880,10 @@ def make_hybrid_ssm_lm_pooled_step_fn(state, cfg, name: str = "lm",
             for _ in range(d.n_layer)
         ]
 
-    make_cache.leaf_seq_axes = [
-        {"k": 1, "v": 1, "ssm": -1, "conv": -1} for _ in range(d.n_layer)]
-    make_cache.kv_positions_read = functools.partial(
-        step_positions_read, width=d.d_kv, dtype=kv, n_head=d.n_head,
-        n_kv_head=d.n_kv_head)
+    declare(make_cache, CacheSpec(
+        [{"k": Leaf(1), "v": Leaf(1), "ssm": Leaf(), "conv": Leaf()}
+         for _ in range(d.n_layer)],
+        reads=[_step_kv_read(d, kv)]))
 
     def step_fn(cache, tokens, ts):
         n = tokens.shape[0]
@@ -771,20 +943,19 @@ def make_sparse_linear_lm_pooled_step_fn(state, cfg, name: str = "lm",
     they are given; ``cfg``: the published config keys plus
     ``sparse_config`` (``sparse_linear_lm.dims``).
 
-    ``make_cache`` declares three kinds of leaf:
+    The spec (:class:`CacheSpec`) declares three kinds of leaf:
 
     * per sparse layer ``k``, ``v`` ``[N, T, n_kv_head * head_dim]`` in
       ``kv_dtype`` (``decode_attention``'s format: appended in place at
       ``ts``, covered by write-before-read) ...
     * ... and ``ck`` ``[N, T // kernel_stride, n_kv_head * head_dim]``,
       the compressed keys the selection scores: a sequence leaf that
-      advances ONE ROW PER ``kernel_stride`` POSITIONS
-      (``make_cache.leaf_seq_strides`` says so beside
-      ``leaf_seq_axes``); row ``j`` is written by the step that appends
-      position ``kernel_stride * j + kernel_size - 1`` and read only by
+      advances ONE ROW PER ``kernel_stride`` POSITIONS (its
+      :class:`Leaf`'s ``stride``); row ``j`` is written by the step that
+      appends position ``kernel_stride * j + kernel_size - 1``, read only by
       queries past it, so write-before-read covers it too;
     * per lightning layer ``s`` ``[N, heads, d, d]`` in ``state_dtype``:
-      RECURRENT (``-1``), read as zero for a row at ``ts == 0``
+      RECURRENT (no sequence axis), read as zero for a row at ``ts == 0``
       (``hybrid_ssm.starts_fresh``), kept for an idle row.
 
     The sparse read is ``decode_attention.grouped_block_decode_attention``
@@ -800,13 +971,12 @@ def make_sparse_linear_lm_pooled_step_fn(state, cfg, name: str = "lm",
     chunk's K/V rows and the ``ck`` rows it completes, and every query
     attends by the step's rule (``chunk_attend``).  ``start`` must be a
     multiple of ``kernel_stride``.  It equals ``n_valid`` steps leaf for
-    leaf (tests/test_sparse_linear_lm.py).  ``make_cache.prefill_fn``
+    leaf (tests/test_sparse_linear_lm.py).  The spec's ``prefill_fn``
     declares it to the pool, which compiles it as one more executable a
     rung pair and, because a prefill can stop at a boundary, may keep
-    prefix SNAPSHOTS over these recurrent leaves (``KVSlotPool``).
-    ``make_cache.sparse_positions_read(n)`` is what the rule lets a
-    sparse query of context ``n`` read, per sparse layer
-    (``make_cache.sparse_layers`` of them), for the server's counters.
+    prefix SNAPSHOTS over these recurrent leaves (``KVSlotPool``).  Its
+    ``"sparse"`` read is what the rule lets a sparse query of context
+    ``n`` read in each sparse layer, for the server's counters.
     """
     import jax
     import jax.numpy as jnp
@@ -844,15 +1014,10 @@ def make_sparse_linear_lm_pooled_step_fn(state, cfg, name: str = "lm",
                                      d.d_kv), kv)})
         return out
 
-    make_cache.leaf_seq_axes = [
-        {"s": -1} if kind == sl.LIGHTNING else {"k": 1, "v": 1, "ck": 1}
+    leaves = [
+        {"s": Leaf()} if kind == sl.LIGHTNING else
+        {"k": Leaf(1), "v": Leaf(1), "ck": Leaf(1, stride=d.kernel_stride)}
         for kind in d.kinds]
-    make_cache.leaf_seq_strides = [
-        {"s": 1} if kind == sl.LIGHTNING
-        else {"k": 1, "v": 1, "ck": d.kernel_stride} for kind in d.kinds]
-    make_cache.sparse_layers = len(sparse_at)
-    make_cache.sparse_positions_read = (
-        lambda n: sl.selected_positions(n, d))
 
     def close_layer(h, u, o, p):
         """The gate, the out-projection and the MLP around a mixer's
@@ -955,7 +1120,10 @@ def make_sparse_linear_lm_pooled_step_fn(state, cfg, name: str = "lm",
             return new_cache
 
     prefill_fn.chunk_tokens = C
-    make_cache.prefill_fn = prefill_fn
+    declare(make_cache, CacheSpec(
+        leaves, prefill_fn=prefill_fn, reads=_ragged_kv_reads(kv) + (
+            PositionRead("sparse", lambda n: sl.selected_positions(n, d),
+                         layers=len(sparse_at)),)))
     return step_fn, make_cache, prefill_fn
 
 
@@ -979,29 +1147,28 @@ def make_routed_conv_lm_pooled_step_fn(state, cfg, name: str = "lm",
     every layer routes over all ``num_experts`` and adds what the held
     ones give.  The output head is the embedding (tied).
 
-    The cache is ``{"layers": [...], "expert_stats": ...}`` and
-    ``make_cache.leaf_seq_axes`` declares every leaf, layer by layer,
-    because the layers hold DIFFERENT leaves:
+    The cache is ``{"layers": [...], "expert_stats": ...}`` and the
+    spec's ``leaves`` declare every leaf, layer by layer, because the
+    layers hold DIFFERENT leaves:
 
     * an attention layer ``k``, ``v`` ``[N, T, n_kv_head * head_dim]``
       in ``kv_dtype`` (``k`` after its per-head norm and rotary),
       ``decode_attention``'s format through ``make_decode_attention``
       (grouped heads: on a TPU the kernel that reads what is live, two
-      64-lane heads a lane tile, else the XLA form;
-      ``make_cache.kv_positions_read`` tells the server its rounding),
-      covered by write-before-read;
+      64-lane heads a lane tile, else the XLA form; the spec's ``"kv"``
+      read tells the server its rounding), covered by write-before-read;
     * a conv layer ``conv`` ``[N, conv_L_cache - 1, d_model]`` fp32, the
-      row's last inputs of the depthwise convolution: RECURRENT (``-1``),
-      read as zero for a row at ``ts == 0`` (``hybrid_ssm.starts_fresh``),
-      kept for an idle row;
-    * ``expert_stats`` ``[expert layers, 4]`` int32 (``-1``: the pool
-      carries it and never slices it): what the steps so far counted on
-      the DEVICE, per expert layer, in ``routed_experts.STAT_NAMES``'
+      row's last inputs of the depthwise convolution: RECURRENT, read as
+      zero for a row at ``ts == 0`` (``hybrid_ssm.starts_fresh``), kept
+      for an idle row;
+    * ``expert_stats`` ``[expert layers, 4]`` int32 (``slot=False``: the
+      pool carries it and never slices it): what the steps so far counted
+      on the DEVICE, per expert layer, in ``routed_experts.STAT_NAMES``'
       order — (row, choice) pairs of live rows, experts that got at
       least one, the largest group, steps with a live row — summed over
       steps (it wraps as a uint32 does).  Which experts a step touches
-      is known only there; ``make_cache.expert_stats(cache)`` picks the
-      leaf, and a server that finds the attribute fetches it with the
+      is known only there; the spec's ``expert_stats(cache)`` picks the
+      leaf, and a server that finds it declared fetches it with the
       scheduler's view, in the same ``device_get``.
 
     Prompts walk the one-token step (no chunked prefill), so, as over
@@ -1012,8 +1179,7 @@ def make_routed_conv_lm_pooled_step_fn(state, cfg, name: str = "lm",
     import jax.numpy as jnp
 
     from paddle_tpu import routed_experts as rx
-    from paddle_tpu.decode_attention import (kv_leaves, make_decode_attention,
-                                             step_positions_read)
+    from paddle_tpu.decode_attention import kv_leaves, make_decode_attention
 
     d = rx.dims(cfg)
     kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
@@ -1033,15 +1199,13 @@ def make_routed_conv_lm_pooled_step_fn(state, cfg, name: str = "lm",
             "expert_stats": jnp.zeros((len(d.expert_layers), n_stats),
                                       jnp.int32)}
 
-    make_cache.leaf_seq_axes = {
-        "layers": [{"k": 1, "v": 1} if kind == rx.ATTENTION else {"conv": -1}
-                   for kind in d.kinds],
-        "expert_stats": -1}
-    make_cache.expert_stats = lambda cache: cache["expert_stats"]
-    make_cache.n_expert = d.n_expert
-    make_cache.kv_positions_read = functools.partial(
-        step_positions_read, width=d.d_kv, dtype=kv, n_head=d.n_head,
-        n_kv_head=d.n_kv_head)
+    declare(make_cache, CacheSpec(
+        {"layers": [{"k": Leaf(1), "v": Leaf(1)} if kind == rx.ATTENTION
+                    else {"conv": Leaf()} for kind in d.kinds],
+         "expert_stats": Leaf(slot=False)},
+        reads=[_step_kv_read(d, kv)],
+        expert_stats=lambda cache: cache["expert_stats"],
+        n_expert=d.n_expert))
 
     def step_fn(cache, tokens, ts):
         n = tokens.shape[0]
@@ -1119,8 +1283,8 @@ def make_windowed_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
     :func:`make_routed_conv_lm_pooled_step_fn`.
 
     The cache is ``{"layers": [...], "expert_stats": ...}`` and its
-    layers' leaves differ in LENGTH (``make_cache.leaf_seq_windows``
-    says so beside ``leaf_seq_axes``):
+    layers' leaves differ in LENGTH (their :class:`Leaf`'s ``window``
+    says so):
 
     * a global layer ``k``, ``v`` ``[N, T, n_kv_head * head_dim]`` in
       ``kv_dtype``: sequence leaves of the length rung (``k`` bare: a
@@ -1130,7 +1294,7 @@ def make_windowed_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
       rotary), position ``p`` in row ``p mod window``;
     * ``expert_stats`` ``[layers, 4]`` int32, as
       :func:`make_routed_conv_lm_pooled_step_fn` but declared
-      ``NO_SLOT_AXIS``: this pool keeps snapshots of a slot's row, and a
+      ``slot=False``: this pool keeps snapshots of a slot's row, and a
       leaf of counts has no slot's row (the prefill's chunks are not
       counted: the counts are of steps).
 
@@ -1149,9 +1313,8 @@ def make_windowed_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
     a multiple of ``C``, so that a chunk's rows lie in the ring without a
     wrap.  It equals ``n_valid`` steps leaf for leaf, but for the
     summation order (tests/test_windowed_routed_lm.py).
-    ``make_cache.window_positions_read(n)`` is what a window layer's
-    query of context ``n`` reads (``make_cache.window_layers`` of them),
-    for the server's counters.
+    The spec's ``"window"`` read is what a window layer's query of
+    context ``n`` reads, for the server's counters.
 
     Ring leaves cannot be sliced by positions: ``KVSlotPool`` serves
     ``prefix=True`` over this builder by SNAPSHOTS (it has a prefill)
@@ -1163,8 +1326,7 @@ def make_windowed_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
     from paddle_tpu import routed_experts as rx
     from paddle_tpu import windowed_routed_lm as wr
     from paddle_tpu.decode_attention import (kv_leaves, make_decode_attention,
-                                             ring_positions,
-                                             step_positions_read)
+                                             ring_positions)
 
     d = wr.dims(cfg)
     kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
@@ -1185,20 +1347,11 @@ def make_windowed_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
                 for kind in d.kinds],
             "expert_stats": jnp.zeros((d.n_layer, n_stats), jnp.int32)}
 
-    make_cache.leaf_seq_axes = {
-        "layers": [{"k": 1, "v": 1} for _ in d.kinds],
-        "expert_stats": NO_SLOT_AXIS}   # a snapshot must not carry counts
-    make_cache.leaf_seq_windows = {
-        "layers": [{"k": d.window, "v": d.window} if kind == wr.WINDOW
-                   else {"k": 0, "v": 0} for kind in d.kinds],
-        "expert_stats": 0}
-    make_cache.expert_stats = lambda cache: cache["expert_stats"]
-    make_cache.n_expert = d.n_expert
-    make_cache.window_layers = len(window_at)
-    make_cache.window_positions_read = lambda n: np.minimum(n, d.window)
-    make_cache.kv_positions_read = functools.partial(
-        step_positions_read, width=d.d_kv, dtype=kv, n_head=d.n_head,
-        n_kv_head=d.n_kv_head)
+    ring = Leaf(1, window=d.window)
+    leaves = {
+        "layers": [{"k": ring, "v": ring} if kind == wr.WINDOW
+                   else {"k": Leaf(1), "v": Leaf(1)} for kind in d.kinds],
+        "expert_stats": Leaf(slot=False)}  # a snapshot must not carry counts
 
     def close_layer(h, r, o, p, ts):
         """The out-projection and the expert layer (routed by the
@@ -1292,7 +1445,13 @@ def make_windowed_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
                     "expert_stats": cache["expert_stats"]}
 
     prefill_fn.chunk_tokens = C
-    make_cache.prefill_fn = prefill_fn
+    declare(make_cache, CacheSpec(
+        leaves, prefill_fn=prefill_fn,
+        reads=[_step_kv_read(d, kv), PositionRead(
+            "window", lambda n: np.minimum(n, d.window),
+            layers=len(window_at))],
+        expert_stats=lambda cache: cache["expert_stats"],
+        n_expert=d.n_expert))
     return step_fn, make_cache, prefill_fn
 
 
@@ -1317,14 +1476,14 @@ def make_mtp_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
     every sparse layer routes over all ``num_experts_all`` and adds what
     the held ones give, plus its shared expert.
 
-    What a self-drafting round needs rides ``make_cache`` (both ``None``
+    What a self-drafting round needs rides the spec (``mtp_fn`` ``None``
     where the configuration has no module):
 
-    * ``make_cache.verify_fn(cache, tokens [S, K], ts [S]) -> (logits
+    * ``verify_fn(cache, tokens [S, K], ts [S]) -> (logits
       [S, K, V], hidden [S, K, d_model], cache)``: IS the step at ``K``
       fresh rows a slot (all ``K`` written before any is read, row ``j``
       at position ``ts + j``), and also yields the last block's output;
-    * ``make_cache.mtp_fn(cache, hidden [S, K, d_model], next_tokens
+    * ``mtp_fn(cache, hidden [S, K, d_model], next_tokens
       [S, K], ts [S]) -> (logits [S, K, V], cache)``: the module at
       positions ``ts .. ts + K - 1``, row ``j`` fed ``hidden[:, j]`` and
       the embedding of ``next_tokens[:, j]`` (the token at ``ts + j +
@@ -1334,8 +1493,8 @@ def make_mtp_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
     The cache is ``{"layers": [...], "mtp": {...}, "expert_stats": ...}``:
     a window layer's ``k``, ``v`` are RING leaves of ``sliding_window``
     rows, a global layer's and the module's sequence leaves of the length
-    rung (``make_cache.leaf_seq_windows``); ``expert_stats`` ``[sparse
-    layers + 1, 4]`` int32 (``NO_SLOT_AXIS``), the module's expert layer
+    rung; ``expert_stats`` ``[sparse
+    layers + 1, 4]`` int32 (``slot=False``), the module's expert layer
     in the last row — a step or a verify counts every row it computed,
     drafted ones included; the plain step leaves the module's leaves and
     its row of counts alone.
@@ -1355,8 +1514,7 @@ def make_mtp_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
     from paddle_tpu import mtp_routed_lm as mr
     from paddle_tpu import routed_experts as rx
     from paddle_tpu.decode_attention import (kv_leaves, make_decode_attention,
-                                             ring_positions,
-                                             step_positions_read)
+                                             ring_positions)
 
     d = mr.dims(cfg)
     kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
@@ -1384,24 +1542,13 @@ def make_mtp_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
                                      d.head_dim, kv)
         return cache
 
-    make_cache.leaf_seq_axes = {
-        "layers": [{"k": 1, "v": 1} for _ in d.kinds],
-        "expert_stats": NO_SLOT_AXIS}
-    make_cache.leaf_seq_windows = {
-        "layers": [{"k": d.window, "v": d.window} if kind == mr.WINDOW
-                   else {"k": 0, "v": 0} for kind in d.kinds],
-        "expert_stats": 0}
+    ring, whole = Leaf(1, window=d.window), {"k": Leaf(1), "v": Leaf(1)}
+    leaves = {
+        "layers": [{"k": ring, "v": ring} if kind == mr.WINDOW else whole
+                   for kind in d.kinds],
+        "expert_stats": Leaf(slot=False)}
     if d.n_mtp:
-        make_cache.leaf_seq_axes["mtp"] = {"k": 1, "v": 1}
-        make_cache.leaf_seq_windows["mtp"] = {"k": 0, "v": 0}
-    make_cache.expert_stats = lambda cache: cache["expert_stats"]
-    make_cache.n_expert = (d.n_expert if held is None
-                           else int(held[1]) - int(held[0]))
-    make_cache.window_layers = len(window_at)
-    make_cache.window_positions_read = lambda n: np.minimum(n, d.window)
-    make_cache.kv_positions_read = functools.partial(
-        step_positions_read, width=d.d_kv, dtype=kv, n_head=d.n_head,
-        n_kv_head=d.n_kv_head)
+        leaves["mtp"] = whole
 
     def rung_of(cache):
         whole = cache["mtp"] if d.n_mtp else cache["layers"][global_at[0]]
@@ -1577,9 +1724,15 @@ def make_mtp_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
 
     prefill_fn.chunk_tokens = C
     prefill_fn.lookahead = 1 if d.n_mtp else 0
-    make_cache.prefill_fn = prefill_fn
-    make_cache.verify_fn = verify_fn
-    make_cache.mtp_fn = mtp_fn if d.n_mtp else None
+    declare(make_cache, CacheSpec(
+        leaves, prefill_fn=prefill_fn, verify_fn=verify_fn,
+        mtp_fn=mtp_fn if d.n_mtp else None,
+        reads=[_step_kv_read(d, kv), PositionRead(
+            "window", lambda n: np.minimum(n, d.window),
+            layers=len(window_at))],
+        expert_stats=lambda cache: cache["expert_stats"],
+        n_expert=(d.n_expert if held is None
+                  else int(held[1]) - int(held[0]))))
     return step_fn, make_cache, prefill_fn
 
 
@@ -1599,8 +1752,8 @@ def make_delta_hybrid_lm_pooled_step_fn(state, cfg, name: str = "lm",
     norms, gates' biases and the conv kernel float32); ``cfg``: the
     published config keys (``delta_hybrid_lm.dims``).
 
-    The cache is a list, a dict a layer, and ``make_cache.leaf_seq_axes``
-    declares every leaf, because the layers hold DIFFERENT leaves:
+    The cache is a list, a dict a layer, and the spec's ``leaves``
+    declare every leaf, because the layers hold DIFFERENT leaves:
 
     * a full layer ``k``, ``v`` ``[N, T, n_kv_head * head_dim]`` in
       ``kv_dtype`` (``k`` after its norm), ``decode_attention``'s format
@@ -1608,14 +1761,14 @@ def make_delta_hybrid_lm_pooled_step_fn(state, cfg, name: str = "lm",
       ``olmo_hybrid``: over bf16 leaves on a TPU the grouped kernel's
       read of what is live, a head one row of a unit (heads of whole
       lane tiles, a rung its block divides; the server's read counter
-      is told that rounding: ``make_cache.kv_positions_read``), else an
+      is told that rounding: the spec's ``"kv"`` read), else an
       XLA form that reads the whole rung
       (``decode_attention_ungrouped_lowered_total{path}`` says which
       form a program took); covered by write-before-read;
     * a linear layer ``state`` ``[N, H / g, dk, g * dv]`` float32 (``g``
       heads side by side in the lanes: ``delta_hybrid_lm.heads_per_
       tile``) and ``conv`` ``[N, K - 1, 2 H dk + H dv]`` float32:
-      RECURRENT (``-1``), read as zero for a row at ``ts == 0``
+      RECURRENT (no sequence axis), read as zero for a row at ``ts == 0``
       (``hybrid_ssm.starts_fresh``), kept for an idle row.
 
     Prompts walk the one-token step (no chunked prefill: the delta
@@ -1626,8 +1779,7 @@ def make_delta_hybrid_lm_pooled_step_fn(state, cfg, name: str = "lm",
     import jax.numpy as jnp
 
     from paddle_tpu import delta_hybrid_lm as dh
-    from paddle_tpu.decode_attention import (kv_leaves, make_decode_attention,
-                                             step_positions_read)
+    from paddle_tpu.decode_attention import kv_leaves, make_decode_attention
 
     d = dh.dims(cfg)
     kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
@@ -1644,12 +1796,10 @@ def make_delta_hybrid_lm_pooled_step_fn(state, cfg, name: str = "lm",
                                jnp.float32)}
             for kind in d.kinds]
 
-    make_cache.leaf_seq_axes = [
-        {"k": 1, "v": 1} if kind == dh.FULL else {"state": -1, "conv": -1}
-        for kind in d.kinds]
-    make_cache.kv_positions_read = functools.partial(
-        step_positions_read, width=d.d_kv, dtype=kv, n_head=d.n_head,
-        n_kv_head=d.n_kv_head)
+    declare(make_cache, CacheSpec(
+        [{"k": Leaf(1), "v": Leaf(1)} if kind == dh.FULL
+         else {"state": Leaf(), "conv": Leaf()} for kind in d.kinds],
+        reads=[_step_kv_read(d, kv)]))
 
     def step_fn(cache, tokens, ts):
         attend = None
@@ -1710,13 +1860,13 @@ def make_kda_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
       ``kv_dtype``, ``decode_attention``'s format through
       ``make_decode_attention`` (grouped heads of whole lane tiles over
       bf16 leaves on a TPU: the grouped kernel's read of what is live;
-      ``make_cache.kv_positions_read`` tells the server its rounding);
-      the sigmoid gate is applied to the read's output;
+      the spec's ``"kv"`` read tells the server its rounding); the
+      sigmoid gate is applied to the read's output;
     * a K layer ``state`` ``[N, H / g, dk, g * dv]`` float32 and ``conv``
-      ``[N, K - 1, 2 H dk + H dv]`` float32: RECURRENT (``-1``), as
+      ``[N, K - 1, 2 H dk + H dv]`` float32: RECURRENT (no sequence axis), as
       :func:`make_delta_hybrid_lm_pooled_step_fn`'s;
-    * ``expert_stats`` ``[layers, 4]`` int32 (``NO_SLOT_AXIS``), as
-      :func:`make_routed_conv_lm_pooled_step_fn`'s; ``make_cache.n_expert``
+    * ``expert_stats`` ``[layers, 4]`` int32 (``slot=False``), as
+      :func:`make_routed_conv_lm_pooled_step_fn`'s; the spec's ``n_expert``
       counts the experts HELD (what the counts' groups are over).
 
     Prompts walk the one-token step (the delta rule's chunkwise form is
@@ -1728,8 +1878,7 @@ def make_kda_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
 
     from paddle_tpu import delta_hybrid_lm as dh
     from paddle_tpu import routed_experts as rx
-    from paddle_tpu.decode_attention import (kv_leaves, make_decode_attention,
-                                             step_positions_read)
+    from paddle_tpu.decode_attention import kv_leaves, make_decode_attention
 
     d = dh.kda_dims(cfg)
     kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
@@ -1749,16 +1898,15 @@ def make_kda_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
                 for kind in d.kinds],
             "expert_stats": jnp.zeros((d.n_layer, n_stats), jnp.int32)}
 
-    make_cache.leaf_seq_axes = {
-        "layers": [{"k": 1, "v": 1} if kind == dh.FULL
-                   else {"state": -1, "conv": -1} for kind in d.kinds],
-        "expert_stats": NO_SLOT_AXIS}
-    make_cache.expert_stats = lambda cache: cache["expert_stats"]
-    make_cache.n_expert = (d.n_expert if held is None
-                           else int(held[1]) - int(held[0]))
-    make_cache.kv_positions_read = functools.partial(
-        step_positions_read, width=d.d_kv, dtype=kv, n_head=d.n_head,
-        n_kv_head=d.n_kv_head)
+    declare(make_cache, CacheSpec(
+        {"layers": [{"k": Leaf(1), "v": Leaf(1)} if kind == dh.FULL
+                    else {"state": Leaf(), "conv": Leaf()}
+                    for kind in d.kinds],
+         "expert_stats": Leaf(slot=False)},
+        reads=[_step_kv_read(d, kv)],
+        expert_stats=lambda cache: cache["expert_stats"],
+        n_expert=(d.n_expert if held is None
+                  else int(held[1]) - int(held[0]))))
 
     def step_fn(cache, tokens, ts):
         layers = cache["layers"]
@@ -1827,7 +1975,7 @@ def make_latent_sparse_lm_pooled_step_fn(state, cfg, name: str = "lm",
     + rope]`` and ``index_k`` ``[N, T, index_head_dim]`` in ``kv_dtype``,
     each zero-padded to whole 128-lane tiles (576 -> 640), both sequence
     leaves of the length rung; ``expert_stats`` ``[sparse
-    layers, 4]`` int32 (``NO_SLOT_AXIS``), as
+    layers, 4]`` int32 (``slot=False``), as
     :func:`make_windowed_routed_lm_pooled_step_fn`'s.
 
     The step is ABSORBED: a layer appends its row ``(c, kR)`` and its
@@ -1846,10 +1994,9 @@ def make_latent_sparse_lm_pooled_step_fn(state, cfg, name: str = "lm",
     equals ``n_valid`` steps leaf for leaf, but for the summation order
     (tests/test_latent_sparse_lm.py).
 
-    ``make_cache.latent_layers`` and
-    ``make_cache.latent_positions_selected(n)`` (what a query of context
-    ``n`` reads of a layer: the lesser of ``n`` and ``index_topk``; it
-    SCORES all ``n``) are for the server's counters.  All leaves are
+    The spec's ``"latent"`` read (what a query of context ``n`` reads
+    of a layer: the lesser of ``n`` and ``index_topk``; it SCORES all
+    ``n``) is for the server's counters.  All leaves are
     sequence leaves: ``KVSlotPool`` serves ``prefix=True`` over this
     builder by snapshots (it has a prefill).
     """
@@ -1876,15 +2023,10 @@ def make_latent_sparse_lm_pooled_step_fn(state, cfg, name: str = "lm",
             "expert_stats": jnp.zeros((len(d.expert_layers), n_stats),
                                       jnp.int32)}
 
-    make_cache.leaf_seq_axes = {
-        "layers": [{"latent": 1, "index_k": 1} for _ in range(d.n_layer)],
-        "expert_stats": NO_SLOT_AXIS}   # a snapshot must not carry counts
-    make_cache.expert_stats = lambda cache: cache["expert_stats"]
-    make_cache.n_expert = (d.n_expert if held is None
-                           else int(held[1]) - int(held[0]))
-    make_cache.latent_layers = d.n_layer
-    make_cache.latent_positions_selected = (
-        lambda n: np.minimum(n, d.index_topk))
+    leaves = {
+        "layers": [{"latent": Leaf(1), "index_k": Leaf(1)}
+                   for _ in range(d.n_layer)],
+        "expert_stats": Leaf(slot=False)}  # a snapshot must not carry counts
 
     def close_layer(h, o, p, dense, ts_rows):
         """The residual around a mixer's output ``o`` and the layer's
@@ -1974,7 +2116,13 @@ def make_latent_sparse_lm_pooled_step_fn(state, cfg, name: str = "lm",
                     "expert_stats": cache["expert_stats"]}
 
     prefill_fn.chunk_tokens = C
-    make_cache.prefill_fn = prefill_fn
+    declare(make_cache, CacheSpec(
+        leaves, prefill_fn=prefill_fn, reads=_ragged_kv_reads(kv) + (
+            PositionRead("latent", lambda n: np.minimum(n, d.index_topk),
+                         layers=d.n_layer),),
+        expert_stats=lambda cache: cache["expert_stats"],
+        n_expert=(d.n_expert if held is None
+                  else int(held[1]) - int(held[0]))))
     return step_fn, make_cache, prefill_fn
 
 
@@ -2065,14 +2213,14 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
     seated at ``pos = 0`` fills its cache inside the running batch — no
     separate prefill executable, no second compiled shape.  That is the
     whole truth for a builder that declares no prefill.  For one that
-    declares a BATCHED prefill (``make_cache.prefill_rows_fn``,
+    declares a BATCHED prefill (``CacheSpec.prefill_rows_fn``,
     :func:`make_transformer_lm_pooled_step_fn`) the pool compiles one
     more function, which takes ``admit``'s place at a turn's admission:
     it seats the turn's requests AND feeds each all of its prompt but
     the last token in one dispatch, so a slot enters ``chunk`` at ``pos
     = prompt_len - 1`` and its first step here produces its first token
     (``KVSlotPool.seat_prefill``).  For one that declares a CHUNKED
-    prefill (``make_cache.prefill_fn``,
+    prefill (``CacheSpec.prefill_fn``,
     :func:`make_sparse_linear_lm_pooled_step_fn`) the pool compiles one
     more function beside these three, which feeds ``C`` prompt tokens
     of ONE slot a dispatch while the slot is held inactive (so ``chunk``
@@ -2186,126 +2334,7 @@ def make_slot_decode_fns(step_fn, eos_id: int, steps: int,
 # ---------------------------------------------------------------------------
 # Prefix KV installation (serving.prefix_cache's device half)
 # ---------------------------------------------------------------------------
-def _declared_seq_axes(make_cache):
-    declared = getattr(make_cache, "leaf_seq_axes", None)
-    if declared is None:
-        raise ValueError(
-            "make_cache declares no leaf_seq_axes: set "
-            "make_cache.leaf_seq_axes to a pytree shaped like the cache "
-            "it builds, holding each leaf's sequence axis (-1 for a leaf "
-            "with none); the slot pool infers nothing from a shape")
-    return declared
-
-
-def cache_leaf_seq_axes(make_cache, leaves):
-    """The sequence axis of each of ``leaves`` (the flattened cache
-    ``make_cache`` builds, arrays or shape structs), or None for a leaf
-    with none.
-
-    Every builder DECLARES its leaves: ``make_cache.leaf_seq_axes`` is a
-    pytree shaped like the cache whose leaves are ints — the axis, or
-    ``-1`` for a leaf with no sequence axis (recurrent state, or
-    anything the pool should carry and never slice), or
-    :data:`NO_SLOT_AXIS` for a leaf that has no SLOT axis either (counts
-    a step keeps for the whole pool: carried, never sliced and never
-    part of a slot's snapshot: :func:`cache_leaf_slotless`) — and
-    nothing is inferred from a shape; a ``make_cache`` without the attribute is an
-    error.  ``KVSlotPool.extract_kv`` / ``admit_prefix`` /
-    ``kv_rung_bytes`` and :func:`make_prefix_admit_fn` all resolve the
-    axis through this one function, so the host side and the traced side
-    cannot disagree."""
-    import jax
-
-    axes = jax.tree.leaves(_declared_seq_axes(make_cache))
-    if len(axes) != len(leaves):
-        raise ValueError(
-            "make_cache.leaf_seq_axes declares %d leaves, the cache has %d"
-            % (len(axes), len(leaves)))
-    return [None if int(a) < 0 else int(a) for a in axes]
-
-
-#: in ``make_cache.leaf_seq_axes``: a leaf with neither a sequence axis
-#: nor a slot axis
-NO_SLOT_AXIS = -2
-
-
-def cache_leaf_slotless(make_cache, leaves):
-    """Whether each of ``leaves`` is declared :data:`NO_SLOT_AXIS`: its
-    axis 0 is NOT the slot, so no slot's row of it exists to snapshot or
-    to install."""
-    import jax
-
-    return [int(a) == NO_SLOT_AXIS
-            for a in jax.tree.leaves(_declared_seq_axes(make_cache))]
-
-
-def cache_leaf_seq_strides(make_cache, leaves):
-    """Positions one row of each leaf's sequence axis stands for: 1
-    unless ``make_cache.leaf_seq_strides`` (a pytree shaped like
-    ``leaf_seq_axes``) declares more — a leaf of compressed keys holds
-    one row per ``kernel_stride`` positions, and a prefix of ``P``
-    positions is its first ``P // stride`` rows."""
-    import jax
-
-    declared = getattr(make_cache, "leaf_seq_strides", None)
-    if declared is None:
-        return [1] * len(leaves)
-    strides = [int(x) for x in jax.tree.leaves(declared)]
-    if len(strides) != len(leaves) or min(strides) < 1:
-        raise ValueError(
-            "make_cache.leaf_seq_strides must declare a stride >= 1 for "
-            "each of the cache's %d leaves" % len(leaves))
-    return strides
-
-
-def cache_leaf_seq_windows(make_cache, leaves):
-    """The window of each leaf's sequence axis: ``None`` for a leaf as
-    long as the length rung, ``W`` for a RING leaf — ``min(rung, W)``
-    rows, position ``p`` in row ``p mod W``
-    (``decode_attention.kv_leaves(..., window=W)``).  Declared by the
-    builder as ``make_cache.leaf_seq_windows``, a pytree shaped like
-    ``leaf_seq_axes`` whose leaves are ints (``0``: no window); a
-    ``make_cache`` without the attribute has no ring leaves."""
-    import jax
-
-    declared = getattr(make_cache, "leaf_seq_windows", None)
-    if declared is None:
-        return [None] * len(leaves)
-    windows = [int(x) for x in jax.tree.leaves(declared)]
-    if len(windows) != len(leaves) or min(windows) < 0:
-        raise ValueError(
-            "make_cache.leaf_seq_windows must declare a window >= 0 (0: "
-            "none) for each of the cache's %d leaves" % len(leaves))
-    return [w or None for w in windows]
-
-
-def recurrent_leaf_names(make_cache, slotless: bool = True):
-    """Tree paths of the leaves ``make_cache`` declares recurrent (no
-    sequence axis: ``-1`` in ``make_cache.leaf_seq_axes``);
-    ``slotless=False`` leaves out those declared :data:`NO_SLOT_AXIS`
-    (counts a step keeps for the whole pool: no sequence's state)."""
-    import jax
-
-    return [jax.tree_util.keystr(path) for path, a in
-            jax.tree_util.tree_flatten_with_path(
-                _declared_seq_axes(make_cache))[0]
-            if int(a) < 0 and (slotless or int(a) != NO_SLOT_AXIS)]
-
-
-def ring_leaf_names(make_cache):
-    """Tree paths of the leaves ``make_cache`` declares RING leaves (a
-    window > 0 in ``make_cache.leaf_seq_windows``)."""
-    import jax
-
-    declared = getattr(make_cache, "leaf_seq_windows", None)
-    if declared is None:
-        return []
-    return [jax.tree_util.keystr(path) for path, w in
-            jax.tree_util.tree_flatten_with_path(declared)[0] if int(w) > 0]
-
-
-def make_prefix_admit_fn(admit_fn, seq_axes_of, seq_strides_of=None,
-                         whole_rows: bool = False):
+def make_prefix_admit_fn(admit_fn, kinds, whole_rows: bool = False):
     """Wrap a :func:`make_slot_decode_fns` ``admit`` with shared-prefix
     installation: ``admit_prefix(state, slot_mask, prompt,
     prompt_len, total_len, kv_leaves, prefix_len[, spec_flag])`` seats
@@ -2319,15 +2348,14 @@ def make_prefix_admit_fn(admit_fn, seq_axes_of, seq_strides_of=None,
     tree-flatten order), each leaf shaped like one slot's row of the
     state's leaf (sequence leaves padded to the length rung); a leaf
     that is not installed carries a ``(1,)`` dummy.  What is installed
-    is STATIC (``seq_axes_of(subtrees)`` over the ``{"cache": ...,
-    "draft_cache": ...}`` dict — the pool passes its builders'
-    declaration, :func:`cache_leaf_seq_axes` — and the leaf shapes), so
-    one compiled executable per rung pair serves every cached prefix
-    length — ``prefix_len`` stays a dynamic scalar:
+    is STATIC (``kinds``: the :class:`Leaf` of every one of those
+    leaves, as the pool's builders declared them — and the leaf
+    shapes), so one compiled
+    executable per rung pair serves every cached prefix length —
+    ``prefix_len`` stays a dynamic scalar:
 
     * a leaf WITH a sequence axis is installed under the position mask:
-      its rows below ``prefix_len // stride`` (``seq_strides_of``, 1
-      where nothing is declared: :func:`cache_leaf_seq_strides`);
+      its rows below ``prefix_len // stride``;
     * a RECURRENT leaf (no sequence axis) given whole is installed
       whole: the retained entry is then a SNAPSHOT, the state as it
       stood when exactly ``prefix_len`` positions had been consumed, and
@@ -2365,11 +2393,9 @@ def make_prefix_admit_fn(admit_fn, seq_axes_of, seq_strides_of=None,
         if "draft_cache" in out:
             sub["draft_cache"] = out["draft_cache"]
         leaves, treedef = jax.tree_util.tree_flatten(sub)
-        strides = (seq_strides_of(sub) if seq_strides_of is not None
-                   else [1] * len(leaves))
         new_leaves = []
-        for cur, pre, ax, stride in zip(leaves, kv_leaves,
-                                        seq_axes_of(sub), strides):
+        for cur, pre, kind in zip(leaves, kv_leaves, kinds):
+            ax, stride = kind.seq_axis, kind.stride
             if tuple(pre.shape) != tuple(cur.shape[1:]):
                 new_leaves.append(cur)
                 continue

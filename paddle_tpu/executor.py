@@ -50,6 +50,7 @@ __all__ = ["Executor", "AsyncExecutor"]
 # inc — real money against a ~200us cached dispatch).  The per-phase
 # spans gate on _mon_spans.recording(), one flag check each when no
 # trace session is active.
+import collections as _collections
 import threading as _threading
 import weakref as _weakref
 
@@ -63,21 +64,35 @@ _exec_retired = {
 }  # folded-in dead executors
 
 
+#: stats dicts of executors that were finalized and not folded in yet
+_exec_dead: "_collections.deque" = _collections.deque()
+
+
 def _retire_exec_stats(stats: Dict[str, int]) -> None:
-    # weakref.finalize callback: fold a dead executor's totals into the
-    # retired base so the counters stay monotonic without pinning every
-    # stats dict (and paying O(all-executors-ever) per scrape) forever
-    with _exec_stats_lock:
-        try:
-            _exec_stats.remove(stats)
-        except ValueError:
-            return
-        for k in _exec_retired:
-            _exec_retired[k] += stats.get(k, 0)
+    # weakref.finalize callback.  A collection starts wherever an
+    # allocation tips it, ALSO on a thread that holds _exec_stats_lock
+    # (the generator in _sum_exec_stats): taking the lock here stopped
+    # that thread for good, and every later scrape and Executor() with
+    # it (tier-1, PR 57).  So: no lock; the next holder folds it in.
+    _exec_dead.append(stats)
+
+
+def _fold_dead_exec_stats() -> None:
+    # under the lock: fold dead executors' totals into the retired base
+    # so the counters stay monotonic without pinning every stats dict
+    while _exec_dead:
+        stats = _exec_dead.popleft()
+        for i, live in enumerate(_exec_stats):
+            if live is stats:       # by identity: equal counts are common
+                del _exec_stats[i]
+                for k in _exec_retired:
+                    _exec_retired[k] += stats.get(k, 0)
+                break
 
 
 def _sum_exec_stats(key: str) -> int:
     with _exec_stats_lock:
+        _fold_dead_exec_stats()
         return _exec_retired[key] + sum(d.get(key, 0) for d in _exec_stats)
 
 

@@ -15,14 +15,14 @@ request-at-a-time regime with vLLM-style CONTINUOUS batching:
   teacher-forces its stored tokens through the shared step fn, filling
   its KV cache inside the running batch (no separate prefill
   executable, no second compiled shape).  A BATCHED PREFILL
-  (``make_cache.prefill_rows_fn``: the transformer LM): the turn's ONE
+  (``decoding.CacheSpec.prefill_rows_fn``: the transformer LM): the turn's ONE
   admission dispatch is ``KVSlotPool.seat_prefill``, which seats every
   request the turn popped AND feeds each all of its prompt but the last
   token, before the turn's decode chunk is dispatched; the slot joins
   that chunk at ``pos = prompt_len - 1`` and its first step produces
   its first token (a request seated over a retained prefix steps
   through its suffix; a server with a draft model attached does not
-  engage).  A CHUNKED PREFILL (``make_cache.prefill_fn``): a turn
+  engage).  A CHUNKED PREFILL (``CacheSpec.prefill_fn``): a turn
   runs at most one ``prefill`` dispatch (``C`` prompt tokens of the
   oldest seated request that still has a whole chunk to go, which is
   held out of the decode chunk until its last whole chunk is in)
@@ -110,8 +110,8 @@ import numpy as np
 from paddle_tpu import compile_cache
 from paddle_tpu import faults as _faults
 from paddle_tpu import monitor
-from paddle_tpu.decode_attention import (kv_positions_read, kv_read_block,
-                                         last_fresh_row)
+from paddle_tpu.decode_attention import last_fresh_row
+from paddle_tpu.decoding import spec_of
 from paddle_tpu.monitor import events as _events
 from paddle_tpu.monitor import spans as _mon_spans
 from paddle_tpu.serving.admission import PRIORITY_NORMAL
@@ -154,14 +154,15 @@ DECODE_KV_READ = monitor.counter(
     "serving_decode_kv_positions_read_total",
     "KV-cache positions the decode steps read: per step and active "
     "slot its live positions rounded up as the kernel that serves the "
-    "step rounds them (decode_attention.kv_positions_read: the slot's "
-    "last block in classes of KV_TAIL rows for the ragged kernel, of "
-    "the grouped kernel's tail where the builder declares "
-    "make_cache.kv_positions_read: a speculative round then counts that "
-    "rule ONCE a slot that advanced, at its last fresh row); the whole "
-    "rung for a step an XLA form serves (an int8 pool, grouped heads off "
-    "the TPU) and for a speculative round of a builder that declares no "
-    "rule, whose reads are masked, not ragged",
+    "step rounds them, by the rule the builder declares (its 'kv' "
+    "decoding.PositionRead: decode_attention.kv_positions_read with the "
+    "slot's last block in classes of KV_TAIL rows for the ragged "
+    "kernel, of the grouped kernel's tail for that one; a speculative "
+    "round counts the rule ONCE a slot that advanced, at its last fresh "
+    "row); the whole rung for a step an XLA form serves (grouped heads "
+    "off the TPU), and the whole pool where the builder declares no "
+    "rule (an int8 pool) or none for a round (a separate draft's "
+    "verify), whose reads are masked, not ragged",
     _LABELS)
 DECODE_KV_LIVE = monitor.counter(
     "serving_decode_kv_positions_live_total",
@@ -271,26 +272,26 @@ DECODE_SPARSE_LIVE = monitor.counter(
 DECODE_WINDOW_LIVE = monitor.counter(
     "serving_decode_window_positions_live_total",
     "K/V positions live for the window layers' decode reads: per step, "
-    "active slot and window layer (make_cache.window_layers) the slot's "
+    "active slot and window layer (the builder's 'window' read) the slot's "
     "context, from the pos the tick already fetched; 0 for a builder "
     "without window layers", _LABELS)
 DECODE_WINDOW_READ = monitor.counter(
     "serving_decode_window_positions_read_total",
     "K/V positions those reads may read: the lesser of the context and "
-    "the window (make_cache.window_positions_read) — read / live is how "
+    "the window (that read's rule) — read / live is how "
     "much of a context the window layers leave unread (and unheld)",
     _LABELS)
 DECODE_INDEX_SCORED = monitor.counter(
     "serving_decode_index_positions_scored_total",
     "index keys the latent layers' learned selection scored: per step, "
-    "active slot and latent layer (make_cache.latent_layers) the slot's "
+    "active slot and latent layer (the builder's 'latent' read) the slot's "
     "context — every live position is scored before any is read; 0 for "
     "a builder without latent layers", _LABELS)
 DECODE_LATENT_SELECTED = monitor.counter(
     "serving_decode_latent_positions_selected_total",
     "latent rows those steps' reads were told to read: the lesser of "
     "the context and the selection's top-k "
-    "(make_cache.latent_positions_selected) — selected / scored is how "
+    "(that read's rule) — selected / scored is how "
     "sparse the traffic makes the read", _LABELS)
 DECODE_KV_BYTES_HELD = monitor.gauge(
     "serving_decode_kv_bytes_held",
@@ -304,9 +305,23 @@ DECODE_KV_BYTES_ONE_LENGTH = monitor.gauge(
     "lengths in one pool save; equal where no leaf is a ring leaf",
     _LABELS)
 
+#: THE table from a kind of read a builder declares (a
+#: ``decoding.PositionRead`` of ``decoding.READ_KINDS``) to what counts
+#: it: (series of positions read, series of positions live, the
+#: ``deliver`` span's field for the first or None).  A new kind is a row
+#: here and a pair of series above: the scheduler names no kind but
+#: ``"kv"`` (its rule takes the rung; counted a slot, not a layer).
+#: ``metrics()`` keys: a series' name less ``serving_decode_``, ``_total``
+POSITION_SERIES = {
+    "kv": (DECODE_KV_READ, DECODE_KV_LIVE, None),
+    "sparse": (DECODE_SPARSE_READ, DECODE_SPARSE_LIVE, None),
+    "window": (DECODE_WINDOW_READ, DECODE_WINDOW_LIVE, "window_rows"),
+    "latent": (DECODE_LATENT_SELECTED, DECODE_INDEX_SCORED, None),
+}
+
 _EXPERT_STATS_HELP = (
     " — counted on the device by a step whose builder declares "
-    "make_cache.expert_stats, summed over its expert layers, fetched "
+    "CacheSpec.expert_stats, summed over its expert layers, fetched "
     "with the scheduler's view once a tick; 0 for a builder without "
     "routed experts")
 DECODE_EXPERT_COUNTERS = tuple(
@@ -535,8 +550,6 @@ class DecodeServer:
         self._tokens_c = DECODE_TOKENS.labels(**lbl)
         self._prefill_c = DECODE_PREFILL_TOKENS.labels(**lbl)
         self._ticks_c = DECODE_TICKS.labels(**lbl)
-        self._kv_read_c = DECODE_KV_READ.labels(**lbl)
-        self._kv_live_c = DECODE_KV_LIVE.labels(**lbl)
         self._kv_pool_c = DECODE_KV_POOL.labels(**lbl)
         self._ttft_h = DECODE_TTFT.labels(**lbl)
         self._occupancy_g = DECODE_OCCUPANCY.labels(**lbl)
@@ -551,37 +564,36 @@ class DecodeServer:
         self._prefill_chunks_c = DECODE_PREFILL_CHUNKS.labels(**lbl)
         self._prefill_chunk_tokens_c = DECODE_PREFILL_CHUNK_TOKENS.labels(
             **lbl)
-        self._sparse_read_c = DECODE_SPARSE_READ.labels(**lbl)
-        self._sparse_live_c = DECODE_SPARSE_LIVE.labels(**lbl)
-        # what the builder declares of a block-sparse read, for the two
-        # counters above: (positions a query of context n reads, layers)
-        self._sparse_rule = getattr(make_cache, "sparse_positions_read", None)
-        self._sparse_layers = int(getattr(make_cache, "sparse_layers", 0))
-        # what the builder declares of its window layers, for the two
-        # position counters: (positions a query of context n may read,
-        # layers); and what two cache lengths in one pool hold and save
-        self._window_read_c = DECODE_WINDOW_READ.labels(**lbl)
-        self._window_live_c = DECODE_WINDOW_LIVE.labels(**lbl)
-        self._window_rule = getattr(make_cache, "window_positions_read", None)
-        # what a one-row step at ts reads of a slot's sequence leaves
-        # on a rung, where the builder knows (grouped heads: a kernel's
-        # rounding or the whole rung, by what serves them)
-        self._kv_rule = getattr(make_cache, "kv_positions_read", None)
-        self._window_layers = int(getattr(make_cache, "window_layers", 0))
-        # what the builder declares of its latent layers' selected read:
-        # (positions a query of context n reads of a layer, layers)
-        self._index_scored_c = DECODE_INDEX_SCORED.labels(**lbl)
-        self._latent_selected_c = DECODE_LATENT_SELECTED.labels(**lbl)
-        self._latent_rule = getattr(make_cache, "latent_positions_selected",
-                                    None)
-        self._latent_layers = int(getattr(make_cache, "latent_layers", 0))
+        # every series of POSITION_SERIES (metrics() shows them all),
+        # then what the builder declares, bound once: the "kv" rule
+        # (None: the whole pool a step) and for each other read (rule,
+        # layers, read series, live series, the deliver span's field)
+        spec = spec_of(make_cache)
+        self._position_cs = {
+            kind: (read.labels(**lbl), live.labels(**lbl))
+            for kind, (read, live, _) in POSITION_SERIES.items()}
+        self._kv_read_c, self._kv_live_c = self._position_cs["kv"]
+        self._kv_rule, self._kv_rounds, self._layer_reads = None, False, []
+        for read in spec.reads:
+            if read.kind not in POSITION_SERIES:
+                raise ValueError(
+                    "make_cache declares a %r read: DecodeServer counts "
+                    "the kinds %s (serving.decode.POSITION_SERIES)"
+                    % (read.kind, sorted(POSITION_SERIES)))
+            if read.kind == "kv":
+                self._kv_rule, self._kv_rounds = read.rule, read.rounds
+            elif read.layers:
+                read_c, live_c = self._position_cs[read.kind]
+                self._layer_reads.append(
+                    (read.rule, read.layers, read_c, live_c,
+                     POSITION_SERIES[read.kind][2]))
         self._kv_held_g = DECODE_KV_BYTES_HELD.labels(**lbl)
         self._kv_one_length_g = DECODE_KV_BYTES_ONE_LENGTH.labels(**lbl)
         # what the builder's steps count on the device (routed experts):
         # the leaf rides the tick's one device_get, the deltas go to the
         # four counters, in routed_experts.STAT_NAMES' order
-        self._expert_stats_of = getattr(make_cache, "expert_stats", None)
-        self._n_expert = int(getattr(make_cache, "n_expert", 0))
+        self._expert_stats_of = spec.expert_stats
+        self._n_expert = spec.n_expert
         self._expert_cs = [c.labels(**lbl) for c in DECODE_EXPERT_COUNTERS]
         self._expert_seen = None     # the leaf as last fetched
         self._admit_seq = 0
@@ -695,8 +707,11 @@ class DecodeServer:
             "generated_tokens": int(self._tokens_c.value),
             "prefill_tokens": int(self._prefill_c.value),
             "ticks": int(self._ticks_c.value),
-            "kv_positions_read": int(self._kv_read_c.value),
-            "kv_positions_live": int(self._kv_live_c.value),
+            # kv_positions_read / _live, and each layer kind's pair
+            **{series.name[len("serving_decode_"):-len("_total")]:
+               int(c.value)
+               for kind, cs in self._position_cs.items()
+               for series, c in zip(POSITION_SERIES[kind], cs)},
             "kv_positions_pool": int(self._kv_pool_c.value),
             "slot_occupancy": float(self._occupancy_g.value),
             "steps_per_tick": self._pool.steps,
@@ -714,13 +729,6 @@ class DecodeServer:
             "prefill_chunk_tokens": self._pool.prefill_tokens,
             "prefill_chunk_tokens_total": int(
                 self._prefill_chunk_tokens_c.value),
-            "sparse_positions_read": int(self._sparse_read_c.value),
-            "sparse_positions_live": int(self._sparse_live_c.value),
-            "window_positions_read": int(self._window_read_c.value),
-            "window_positions_live": int(self._window_live_c.value),
-            "index_positions_scored": int(self._index_scored_c.value),
-            "latent_positions_selected": int(
-                self._latent_selected_c.value),
             "kv_bytes_held": int(self._kv_held_g.value),
             "kv_bytes_one_length": int(self._kv_one_length_g.value),
             "expert_assignments": int(self._expert_cs[0].value),
@@ -1327,9 +1335,7 @@ class DecodeServer:
         if stepped:
             if "expert_stats" in view:
                 fields = self._count_experts(view["expert_stats"])
-            window_rows = self._count_kv_positions(recs, view, use_spec)
-            if self._window_layers:
-                fields["window_rows"] = window_rows
+            fields.update(self._count_kv_positions(recs, view, use_spec))
             # after the position counters: whoever sees the tick counted
             # sees its positions counted too
             self._ticks_c.inc()
@@ -1503,14 +1509,15 @@ class DecodeServer:
             "peak_over_mean": (self._n_expert * peak / pairs
                                if pairs else 0.0)}
 
-    def _count_kv_positions(self, recs, view, use_spec: bool) -> int:
+    def _count_kv_positions(self, recs, view, use_spec: bool) -> dict:
         """Advance the KV read / live / pool position counters for the
         chunk just run, from the ``pos`` the tick already fetched: a slot
         that went from ``p0`` to ``p1`` ran steps at ``ts = p0..p1 - 1``,
         each with ``ts + 1`` live positions, of which the ragged kernel
-        reads what :func:`kv_positions_read` says.  Returns the positions
-        the chunk's window layers read (0 for a builder without them:
-        the ``deliver`` span's ``window_rows``)."""
+        reads what the builder's ``"kv"`` rule says; and for every other
+        read the builder declares (:data:`POSITION_SERIES`) its pair,
+        per step, active slot and layer.  Returns the ``deliver`` span's
+        fields: what was read of each kind that has one there."""
         s, t = view["tokens"].shape
         idx = np.fromiter((i for i, _ in recs), np.intp, len(recs))
         p1 = view["pos"][idx].astype(np.int64)
@@ -1519,52 +1526,36 @@ class DecodeServer:
         pool = s * t * steps
         # the steps this chunk ran, row by row: ts = p0 .. p1 - 1; a
         # speculative round COMPUTED all its k rows for every slot that
-        # advanced, whatever it kept (the sparse and window counters
+        # advanced, whatever it kept (the layer reads' counters
         # count reads, not commits)
         rows = self._speculative.k if use_spec else steps
         ts = p0[:, None] + np.arange(rows)[None, :]
         ran = (p1 > p0)[:, None] & (ts < t) if use_spec else ts < p1[:, None]
-        if use_spec and self._kv_rule is not None:
+        if self._kv_rule is None or (use_spec and not self._kv_rounds):
+            read = pool  # masked reads over the whole rung
+        elif use_spec:
             # ONE read a round, made for its last row (the earlier rows
             # see less of the same blocks): what the rule rounds that to
             last = last_fresh_row(p0, rows, t)
             read = int((self._kv_rule(last, t) * (p1 > p0)).sum())
-        elif use_spec:
-            read = pool  # masked reads over the whole rung
-        elif self._kv_rule is not None:
-            read = int((self._kv_rule(ts, t) * ran).sum())
-        elif self._pool.kv_dtype != "fp32":
-            read = pool
         else:
-            read = int((kv_positions_read(ts, kv_read_block(t)) * ran).sum())
-        if self._sparse_rule is not None and self._sparse_layers:
+            read = int((self._kv_rule(ts, t) * ran).sum())
+        fields = {}
+        if self._layer_reads:
             n = ts + 1                              # contexts p0 + 1 .. p1
-            self._sparse_live_c.inc(
-                int((n * ran).sum()) * self._sparse_layers)
-            self._sparse_read_c.inc(
-                int((self._sparse_rule(n) * ran).sum())
-                * self._sparse_layers)
-        window_rows = 0
-        if self._window_rule is not None and self._window_layers:
-            n = ts + 1
-            window_rows = (int((self._window_rule(n) * ran).sum())
-                           * self._window_layers)
-            self._window_live_c.inc(
-                int((n * ran).sum()) * self._window_layers)
-            self._window_read_c.inc(window_rows)
-        if self._latent_rule is not None and self._latent_layers:
-            n = ts + 1
-            self._index_scored_c.inc(
-                int((n * ran).sum()) * self._latent_layers)
-            self._latent_selected_c.inc(
-                int((self._latent_rule(n) * ran).sum())
-                * self._latent_layers)
+            live = int((n * ran).sum())
+            for rule, layers, read_c, live_c, field in self._layer_reads:
+                rows_read = int((rule(n) * ran).sum()) * layers
+                live_c.inc(live * layers)
+                read_c.inc(rows_read)
+                if field is not None:
+                    fields[field] = rows_read
         self._kv_live_c.inc(int((p1 * (p1 + 1) - p0 * (p0 + 1)).sum()) // 2)
         for (_, rec), p in zip(recs, p1.tolist()):
             rec.pos = p
         self._kv_pool_c.inc(pool)
         self._kv_read_c.inc(read)
-        return window_rows
+        return fields
 
     def _offer_prefix(self, slot: int, rec: _Slot, consumed: int) -> None:
         """Retain a freed slot's prefix KV in the cache (a control-plane

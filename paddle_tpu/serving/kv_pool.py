@@ -16,41 +16,25 @@ entirely on warmed executables — the pool's
 request-batching path.
 
 The pool state is one dict pytree (slot axis 0 on every leaf; the KV
-cache's T axis read by the step fn).  A cache leaf is one of three kinds
-(sequence, ring, recurrent), and the builder says which (``make_cache.leaf_seq_axes``, resolved by
-``decoding.cache_leaf_seq_axes``; a ``make_cache`` that declares nothing
-is refused): a leaf WITH a sequence axis (K/V rows) is covered
-by the write-before-read invariant — a reused slot is never zeroed,
-because a sequence reads only positions it wrote itself — and is what
-``extract_kv`` / ``admit_prefix`` slice and ``kv_rung_bytes`` counts; a
-RECURRENT leaf (no sequence axis: an SSM or conv state, read and
-re-written whole each step) is outside that invariant, is started from
-zero by the step itself for a row at position 0, is never sliced, and
-is counted by ``recurrent_rung_bytes``.  A sequence leaf may advance
-one row per several positions (``make_cache.leaf_seq_strides``: the
-compressed keys of a block-sparse layer).  A RING leaf
-(``make_cache.leaf_seq_windows`` declares its window ``W``, resolved by
-``decoding.cache_leaf_seq_windows``; the pool infers nothing) is a
-sequence leaf of ``min(rung, W)`` rows in which position ``p`` lives in
-row ``p mod W``: a window layer's K/V, so that the sequence leaves of
-ONE rung differ in length.  It is allocated, resized (cut or padded to
-``min(new rung, W)`` rows), counted by ``kv_rung_bytes`` and carried
-whole by ``snapshot`` / ``admit_prefix`` like any other; what it cannot
-be is SLICED by positions or rolled back (a wrapped row holds a later
-position than the one its index names), so ``extract_kv`` and a
-``prefix=True`` that would call it (a builder without a chunked
-prefill) are refused over ring leaves, as they are over recurrent ones;
-``speculative=`` is carried over ring leaves exactly where a rejected
-round cannot harm, at ``k <= 2``, and refused beyond (a round writes
-ring rows ``pos .. pos + k - 1`` over positions ``pos - W ..``; after a
-rejection at ``j = 1`` the next query, at ``pos + 1``, reads ``pos + 2
-- W ..``, which a third row would have overwritten: ``k > 2`` needs ``k
-- 2`` spare rows a ring does not have).
+cache's T axis read by the step fn).  What each cache leaf is — a
+sequence leaf, a RING leaf, a RECURRENT leaf, a leaf of pool-wide counts
+— the builder says in its ONE declaration (``decoding.CacheSpec``, a
+``decoding.Leaf`` a leaf, read through ``decoding.spec_of``: the kinds
+are explained there, once); the pool infers nothing from a shape and
+refuses a ``make_cache`` that declares nothing.  Sequence leaves are
+what ``extract_kv`` / ``admit_prefix`` slice and ``kv_rung_bytes``
+counts; recurrent ones ``recurrent_rung_bytes`` counts; a ring leaf is
+allocated, resized (cut or padded to ``min(new rung, W)`` rows), counted
+and carried whole by ``snapshot`` / ``admit_prefix`` like any other.
+What would slice a ring by positions or roll a state back
+(``extract_kv``, ``prefix=True`` without a chunked prefill,
+``speculative=`` over recurrent leaves or with ``k > 2`` over ring
+leaves) is refused at construction, each with its reason.
 
 What else a pool compiles follows from what the builder declares, and
 the two prefills are separate paths below that one chooser (their needs
 conflict: one-pass seating here, snapshot boundaries and held slots
-there).  A builder with a BATCHED prefill (``make_cache.prefill_rows_fn``:
+there).  A builder with a BATCHED prefill (``CacheSpec.prefill_rows_fn``:
 several slots' prompts from position 0 in one ``C``-wide forward) gets
 one more executable a rung pair, ``seat_prefill``: a scheduler turn's
 seats travel as compact rows, are seated by scatter and fed all of
@@ -59,7 +43,7 @@ their prompts but the last token in the same dispatch, in three widths
 as the turn's seats of that width need — so a prompt never walks the
 one-token step, and the slot's first step produces its first token.
 Not with a draft model attached (nothing would feed the draft's cache).
-A builder with a chunked prefill (``make_cache.prefill_fn``) gets one more
+A builder with a chunked prefill (``CacheSpec.prefill_fn``) gets one more
 executable a rung pair, ``prefill``: ``C`` prompt tokens of ONE slot in
 one dispatch, for a slot the scheduler holds out of the decode chunk
 until its last whole chunk is in.  Because such a prefill can stop at a
@@ -141,21 +125,20 @@ class KVSlotPool:
                  len_multiple: int = 1):
         from paddle_tpu.decoding import (make_prefix_admit_fn,
                                          make_slot_decode_fns,
-                                         normalize_kv_dtype,
-                                         recurrent_leaf_names,
-                                         ring_leaf_names)
+                                         normalize_kv_dtype, spec_of)
 
         self._make_cache = make_cache
+        spec = spec_of(make_cache)
         #: tree paths of the cache leaves declared recurrent (no
         #: sequence axis); empty for a K/V-only cache
-        self.recurrent_leaves = recurrent_leaf_names(make_cache)
+        self.recurrent_leaves = spec.names(lambda leaf: leaf.seq_axis is None)
         #: the builder's chunked prefill (None: prompts ride the step)
-        self._prefill = getattr(make_cache, "prefill_fn", None)
+        self._prefill = spec.prefill_fn
         #: the builder's batched prefill (None: as above).  Not taken
         #: with a draft attached: the plain chunk keeps ``draft_cache``
         #: position-synced by feeding it every consumed token, and
         #: nothing here feeds the draft a prompt
-        self._prefill_rows = (getattr(make_cache, "prefill_rows_fn", None)
+        self._prefill_rows = (spec.prefill_rows_fn
                               if speculative is None else None)
         # a leaf of counts with no slot axis is no sequence's state: a
         # rejected round has nothing of it to roll back
@@ -163,7 +146,8 @@ class KVSlotPool:
                 ("prefix=True", prefix and self._prefill is None,
                  self.recurrent_leaves),
                 ("speculative=", speculative is not None,
-                 recurrent_leaf_names(make_cache, slotless=False))):
+                 spec.names(lambda leaf: leaf.seq_axis is None
+                            and leaf.slot))):
             if on and leaves:
                 raise ValueError(
                     "KVSlotPool(%s) over a cache with recurrent leaves "
@@ -173,11 +157,11 @@ class KVSlotPool:
                     "tokens; a prefix over such leaves needs a state "
                     "SNAPSHOT taken at the boundary, which only a "
                     "builder with a chunked prefill "
-                    "(make_cache.prefill_fn) can stop at"
+                    "(CacheSpec.prefill_fn) can stop at"
                     % (what, leaves[0], len(leaves)))
-        #: tree paths of the cache leaves declared RING leaves (a window
-        #: in ``make_cache.leaf_seq_windows``); empty for most builders
-        self.ring_leaves = ring_leaf_names(make_cache)
+        #: tree paths of the cache leaves declared RING leaves; empty
+        #: for most builders
+        self.ring_leaves = spec.names(lambda leaf: leaf.window is not None)
         if prefix and self._prefill is None and self.ring_leaves:
             raise ValueError(
                 "KVSlotPool(prefix=True) over a cache with ring leaves (%s "
@@ -185,7 +169,7 @@ class KVSlotPool:
                 "mod its window, so a wrapped row cannot be sliced as a "
                 "prefix of positions; a prefix over such leaves needs a "
                 "whole-row SNAPSHOT taken at a boundary, which only a "
-                "builder with a chunked prefill (make_cache.prefill_fn) "
+                "builder with a chunked prefill (CacheSpec.prefill_fn) "
                 "can stop at" % (self.ring_leaves[0], len(self.ring_leaves)))
         if (speculative is not None and speculative.k > 2
                 and self.ring_leaves):
@@ -234,18 +218,24 @@ class KVSlotPool:
             draft_step_fn=(speculative.draft_step_fn
                            if speculative is not None else None))
         self._chunk_fn, self._seat_fn, self._release_fn = self._fns
-        #: prefix entries are device snapshots of a slot's whole cache
-        #: row (recurrent leaves included), taken at a prefill boundary
-        self.snapshots = self.prefix and self._prefill is not None
-        self._admit_prefix_fn = (
-            make_prefix_admit_fn(self._seat_fn, self._kv_seq_axes,
-                                 self._kv_seq_strides,
-                                 whole_rows=self.snapshots)
-            if self.prefix else None)
         #: the draft is the target's own module: its proposal and the
         #: proposals' record ride the state, no ``draft_cache`` does
         self._self_draft = (speculative is not None
                             and speculative.kind == "self")
+        #: the declared ``decoding.Leaf`` of each of
+        #: :meth:`_kv_subtree_leaves`: the target's leaves, then a draft
+        #: model's — known before anything compiles, and a ``make_cache``
+        #: (the draft's too) that declares nothing is refused here
+        self._kv_decl = spec.flat + (
+            () if speculative is None or self._self_draft
+            else spec_of(speculative.draft_make_cache).flat)
+        #: prefix entries are device snapshots of a slot's whole cache
+        #: row (recurrent leaves included), taken at a prefill boundary
+        self.snapshots = self.prefix and self._prefill is not None
+        self._admit_prefix_fn = (
+            make_prefix_admit_fn(self._seat_fn, self._kv_decl,
+                                 whole_rows=self.snapshots)
+            if self.prefix else None)
         if self._self_draft:
             from paddle_tpu.serving.speculative import (
                 make_self_draft_chunk_fn)
@@ -261,10 +251,9 @@ class KVSlotPool:
         else:
             self._spec_chunk_fn = None
         self._specs: Dict[Tuple[int, int], dict] = {}
-        # every leaf's kind is known before anything compiles: a
-        # make_cache (the draft's too) that declares no axes, or not as
-        # many as it builds leaves, is refused here
-        self._kv_seq_axes(self._state_spec(*self.rung_pairs()[0]))
+        # a leaf declared a ring leaf that is none is refused here,
+        # before anything compiles
+        self._state_spec(*self.rung_pairs()[0])
         self._exe: Dict[Tuple[str, int, int], object] = {}
         # host-born constants :meth:`_lower` hoisted: the pool's one
         # device copy of each distinct one, how many copies it made, and
@@ -342,17 +331,16 @@ class KVSlotPool:
         elif self.speculative is not None:
             spec["draft_cache"] = jax.eval_shape(
                 lambda: self.speculative.draft_make_cache(s, t))
-        for leaf, ax, window in zip(self._kv_subtree_leaves(spec),
-                                    self._kv_seq_axes(spec),
-                                    self._kv_seq_windows(spec)):
+        for leaf, decl in zip(self._kv_subtree_leaves(spec), self._kv_decl):
             # nothing is inferred: a declared ring leaf must BE one
-            if window is not None and (
-                    ax is None or leaf.shape[ax] != min(t, window)):
+            if decl.window is not None and (
+                    decl.seq_axis is None
+                    or leaf.shape[decl.seq_axis] != min(t, decl.window)):
                 raise ValueError(
-                    "make_cache.leaf_seq_windows declares a window of %d "
+                    "CacheSpec.leaves declares a window of %d "
                     "for a leaf shaped %s at length rung %d: a ring leaf "
                     "has min(rung, window) rows on its sequence axis"
-                    % (window, leaf.shape, t))
+                    % (decl.window, leaf.shape, t))
         self._specs[s, t] = spec
         return spec
 
@@ -367,50 +355,6 @@ class KVSlotPool:
             sub["draft_cache"] = state_or_spec["draft_cache"]
         leaves, _ = jax.tree_util.tree_flatten(sub)
         return leaves
-
-    def _declared(self, declared_of, state_or_spec):
-        """``declared_of(make_cache, leaves)`` over each of
-        :meth:`_kv_subtree_leaves` of ``state_or_spec``: the target's
-        leaves first, then the draft's."""
-        import jax
-
-        out = declared_of(self._make_cache,
-                          jax.tree.leaves(state_or_spec["cache"]))
-        if "draft_cache" in state_or_spec:
-            out += declared_of(
-                self.speculative.draft_make_cache,
-                jax.tree.leaves(state_or_spec["draft_cache"]))
-        return out
-
-    def _kv_seq_axes(self, state_or_spec):
-        """Sequence axis (or None) of each of :meth:`_kv_subtree_leaves`
-        of ``state_or_spec``, as the builders declare them
-        (``decoding.cache_leaf_seq_axes``)."""
-        from paddle_tpu.decoding import cache_leaf_seq_axes
-
-        return self._declared(cache_leaf_seq_axes, state_or_spec)
-
-    def _kv_seq_strides(self, state_or_spec):
-        """Positions per row of each of :meth:`_kv_subtree_leaves`'
-        sequence axes (``decoding.cache_leaf_seq_strides``)."""
-        from paddle_tpu.decoding import cache_leaf_seq_strides
-
-        return self._declared(cache_leaf_seq_strides, state_or_spec)
-
-    def _kv_slotless(self, state_or_spec):
-        """Whether each of :meth:`_kv_subtree_leaves` is declared to have
-        no slot axis (``decoding.cache_leaf_slotless``): such a leaf is
-        no part of a slot's snapshot."""
-        from paddle_tpu.decoding import cache_leaf_slotless
-
-        return self._declared(cache_leaf_slotless, state_or_spec)
-
-    def _kv_seq_windows(self, state_or_spec):
-        """The window (or None) of each of :meth:`_kv_subtree_leaves`'
-        sequence axes (``decoding.cache_leaf_seq_windows``)."""
-        from paddle_tpu.decoding import cache_leaf_seq_windows
-
-        return self._declared(cache_leaf_seq_windows, state_or_spec)
 
     def alloc(self, s: int, t: int) -> Dict[str, object]:
         """A fresh zeroed pool state for rung pair ``(s, t)``, HOST-side
@@ -466,16 +410,14 @@ class KVSlotPool:
         allocation."""
         spec = self._state_spec(s, t)
         seq = rec = whole = 0
-        for leaf, ax, stride, window in zip(
-                self._kv_subtree_leaves(spec), self._kv_seq_axes(spec),
-                self._kv_seq_strides(spec), self._kv_seq_windows(spec)):
+        for leaf, decl in zip(self._kv_subtree_leaves(spec), self._kv_decl):
             n = int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-            if ax is None:
+            if decl.seq_axis is None:
                 rec += n
                 continue
             seq += n
-            whole += (n if window is None
-                      else n // leaf.shape[ax] * (t // stride))
+            whole += (n if decl.window is None else
+                      n // leaf.shape[decl.seq_axis] * (t // decl.stride))
         return seq, rec, whole
 
     def kv_rung_bytes(self, s: int, t: int) -> int:
@@ -548,12 +490,12 @@ class KVSlotPool:
         args = [spec, mask, prompt, scalar, scalar]
         if kind == "admit_prefix":
             kv = []
-            for leaf, ax, slotless in zip(self._kv_subtree_leaves(spec),
-                                          self._kv_seq_axes(spec),
-                                          self._kv_slotless(spec)):
+            for leaf, decl in zip(self._kv_subtree_leaves(spec),
+                                  self._kv_decl):
                 # a snapshot carries every leaf of the slot's row; else
                 # only the leaves with positions, recurrent ones a dummy
-                whole = ax is not None or (self.snapshots and not slotless)
+                whole = decl.seq_axis is not None or (
+                    self.snapshots and decl.slot)
                 kv.append(jax.ShapeDtypeStruct(
                     leaf.shape[1:] if whole else (1,),
                     leaf.dtype if whole else np.dtype(np.float32)))
@@ -817,13 +759,13 @@ class KVSlotPool:
         buf[:n] = prompt[:n]
         shapes = self._state_spec(s, t)  # not ``spec``: that is the flag
         kv = []
-        for sd, ent, ax, slotless in zip(
-                self._kv_subtree_leaves(shapes), kv_leaves,
-                self._kv_seq_axes(shapes), self._kv_slotless(shapes)):
+        for sd, ent, decl in zip(self._kv_subtree_leaves(shapes), kv_leaves,
+                                 self._kv_decl):
+            ax = decl.seq_axis
             if self.snapshots:
                 # a snapshot's leaves are device arrays of this rung's
                 # row shapes already (:meth:`snapshot`): no host copy
-                if not slotless and tuple(ent.shape) != tuple(sd.shape[1:]):
+                if decl.slot and tuple(ent.shape) != tuple(sd.shape[1:]):
                     raise ValueError(
                         "snapshot leaf %s does not fit rung pair %s"
                         % (ent.shape, (s, t)))
@@ -864,7 +806,7 @@ class KVSlotPool:
     def seats_prefilled(self) -> bool:
         """Whether :meth:`seat_prefill` is how this pool seats a request
         that starts at position 0: the builder declares a batched
-        prefill (``make_cache.prefill_rows_fn``) and no draft model
+        prefill (``CacheSpec.prefill_rows_fn``) and no draft model
         rides along."""
         return self._prefill_rows is not None
 
@@ -1036,11 +978,10 @@ class KVSlotPool:
         import jax
         import jax.numpy as jnp
 
-        return [jnp.zeros((1,), jnp.float32) if slotless
-                else jax.lax.dynamic_index_in_dim(leaf, slot, 0,
-                                                  keepdims=False)
-                for leaf, slotless in zip(self._kv_subtree_leaves(state),
-                                          self._kv_slotless(state))]
+        return [jax.lax.dynamic_index_in_dim(leaf, slot, 0, keepdims=False)
+                if decl.slot else jnp.zeros((1,), jnp.float32)
+                for leaf, decl in zip(self._kv_subtree_leaves(state),
+                                      self._kv_decl)]
 
     def can_prefill(self, state, pos: int, prompt_len: int) -> bool:
         """Whether a slot at ``pos`` of a ``prompt_len``-token prompt
@@ -1075,7 +1016,7 @@ class KVSlotPool:
         if not self.snapshots:
             raise RuntimeError(
                 "pool keeps no snapshots (prefix=True over a builder "
-                "that declares make_cache.prefill_fn)")
+                "that declares CacheSpec.prefill_fn)")
         s, t = self.state_rungs(state)
         return self._get_exe("snapshot", s, t)(state, np.int32(slot))
 
@@ -1096,14 +1037,12 @@ class KVSlotPool:
                 "rows; copy the slot's whole row at a boundary instead "
                 "(snapshot)" % (self.ring_leaves[0], len(self.ring_leaves)))
         out = []
-        for leaf, ax, stride in zip(self._kv_subtree_leaves(state),
-                                    self._kv_seq_axes(state),
-                                    self._kv_seq_strides(state)):
-            if ax is None:
+        for leaf, decl in zip(self._kv_subtree_leaves(state), self._kv_decl):
+            if decl.seq_axis is None:
                 out.append(None)
                 continue
             sl = [slice(None)] * (leaf.ndim - 1)
-            sl[ax - 1] = slice(0, int(m) // stride)
+            sl[decl.seq_axis - 1] = slice(0, int(m) // decl.stride)
             out.append(np.asarray(leaf[slot][tuple(sl)]))
         return out
 

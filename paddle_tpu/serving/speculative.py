@@ -164,15 +164,16 @@ class SelfDraftConfig(SpeculativeConfig):
 
 def make_self_draft(make_cache) -> SelfDraftConfig:
     """The :class:`SelfDraftConfig` of a builder that declares its
-    verify and its module (``make_cache.verify_fn`` / ``.mtp_fn``:
-    ``decoding.make_mtp_routed_lm_pooled_step_fn``)."""
-    verify_fn = getattr(make_cache, "verify_fn", None)
-    module_fn = getattr(make_cache, "mtp_fn", None)
-    if verify_fn is None or module_fn is None:
+    verify and its module (``decoding.CacheSpec.verify_fn`` /
+    ``.mtp_fn``: ``decoding.make_mtp_routed_lm_pooled_step_fn``)."""
+    from paddle_tpu.decoding import spec_of
+
+    spec = spec_of(make_cache)
+    if spec.verify_fn is None or spec.mtp_fn is None:
         raise ValueError(
             "make_cache declares no verify_fn / mtp_fn: this builder has "
             "no multi-token-prediction module to draft with")
-    return SelfDraftConfig(verify_fn, module_fn,
+    return SelfDraftConfig(spec.verify_fn, spec.mtp_fn,
                            draft_meta={"kind": "self", "k": 2})
 
 

@@ -16,7 +16,7 @@ import pytest
 from conftest import WAIT
 
 import paddle_tpu as fluid
-from paddle_tpu import faults, framework, monitor
+from paddle_tpu import decoding, faults, framework, monitor
 from paddle_tpu.serving import InferenceServer, wire
 from paddle_tpu.serving.errors import DeadlineExceeded, ServingError
 
@@ -357,7 +357,8 @@ def _chain_decode_server(name):
     def make_cache(n_rows, seq_len):
         return {"z": jnp.zeros((n_rows, seq_len), "float32")}
 
-    make_cache.leaf_seq_axes = {"z": 1}
+    decoding.declare(make_cache, decoding.CacheSpec(
+        {"z": decoding.Leaf(1)}))
     srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=16,
                        max_slots=2, steps_per_tick=2, name=name)
     srv.warmup(configure_cache=False)
@@ -433,7 +434,8 @@ def _prefix_decode_server(name):
     def make_cache(n_rows, seq_len):
         return {"z": jnp.zeros((n_rows, seq_len), "float32")}
 
-    make_cache.leaf_seq_axes = {"z": 1}
+    decoding.declare(make_cache, decoding.CacheSpec(
+        {"z": decoding.Leaf(1)}))
     cache = PrefixKVCache(capacity_bytes=1 << 20, block_tokens=4,
                           name=name)
     srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=16,

@@ -8,6 +8,8 @@ import os
 
 import numpy as np
 
+from conftest import WAIT
+
 import paddle_tpu as fluid
 
 
@@ -815,6 +817,40 @@ def test_fleet_top_once_renders_a_live_fleet():
         fleet.stop()
         for sp in sps:
             sp.stop()
+
+
+def test_an_executor_finalized_inside_a_scrape_stops_nothing():
+    """A collection may start on a thread that is summing the executors'
+    counters under their lock (an allocation in the sum tips it), and a
+    dead ``Executor``'s finalizer then runs on THAT thread: it must not
+    wait for the lock (it did until PR 58, and stopped every later
+    scrape and every ``Executor()`` of the process — the one tier-1
+    failure of PR 57's run, 300 s in ``Executor.__init__`` of the test
+    below).  The dead executor's counts are folded in once."""
+    import gc
+    import threading
+
+    from paddle_tpu import executor as ex
+
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe._bump("runs", 3)
+    before = ex._sum_exec_stats("runs")
+    done = threading.Event()
+
+    box = [exe]
+    del exe
+
+    def finalized_under_the_lock():
+        with ex._exec_stats_lock:
+            box.clear()
+            gc.collect()
+        done.set()
+
+    threading.Thread(target=finalized_under_the_lock, daemon=True).start()
+    assert done.wait(WAIT), "the finalizer waits for a lock its thread holds"
+    assert ex._sum_exec_stats("runs") == before
+    fluid.Executor(fluid.CPUPlace())    # and the next one is not stopped
+    assert ex._sum_exec_stats("runs") == before
 
 
 def test_train_top_once_renders_a_live_training_run(tmp_path):

@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import compile_cache, monitor
+from paddle_tpu import compile_cache, decoding, monitor
 from paddle_tpu.monitor import spans
 from paddle_tpu.serving.decode import DecodeServer
 from paddle_tpu.serving.kv_pool import KVSlotPool
@@ -47,7 +47,8 @@ def chain_model(width=8):
     def make_cache(n_rows, seq_len):
         return {"k": jnp.zeros((n_rows, seq_len, width), "float32")}
 
-    make_cache.leaf_seq_axes = {"k": 1}
+    decoding.declare(make_cache, decoding.CacheSpec(
+        {"k": decoding.Leaf(1)}))
     return step_fn, make_cache
 
 

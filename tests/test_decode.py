@@ -19,9 +19,15 @@ import pytest
 from conftest import WAIT
 
 from paddle_tpu import monitor
+from paddle_tpu.decode_attention import ragged_positions_read
 from paddle_tpu.decoding import (
+    CacheSpec,
+    Leaf,
+    PositionRead,
+    declare,
     make_transformer_lm_pooled_step_fn,
     make_transformer_lm_step_fn,
+    spec_of,
 )
 from paddle_tpu.serving.client import Client
 from paddle_tpu.serving.decode import (
@@ -59,7 +65,9 @@ def chain_model():
     def make_cache(n_rows, seq_len):
         return {"z": jnp.zeros((n_rows, seq_len), "float32")}
 
-    make_cache.leaf_seq_axes = {"z": 1}
+    # counted as the transformer LM's fp32 leaves are
+    declare(make_cache, CacheSpec({"z": Leaf(1)}, reads=[PositionRead(
+        "kv", ragged_positions_read, rounds=False)]))
     return step_fn, make_cache
 
 
@@ -80,7 +88,7 @@ def slow_chain_model(work=320):
         return {"z": jnp.zeros((n_rows, seq_len), "float32"),
                 "w": jnp.zeros((work, work), "float32")}
 
-    make_cache.leaf_seq_axes = {"z": 1, "w": -1}
+    declare(make_cache, CacheSpec({"z": Leaf(1), "w": Leaf()}))
     return step_fn, make_cache
 
 
@@ -170,23 +178,23 @@ def test_default_len_ladder_shape():
 
 def test_a_make_cache_that_declares_no_leaf_axes_is_refused():
     """Nothing reads a leaf's sequence axis off its shape: a
-    ``make_cache`` without ``leaf_seq_axes`` is refused at construction,
-    by the pool and by the server, in words that name the attribute."""
+    ``make_cache`` that was never ``declare``d is refused at
+    construction, by the pool and by the server, in words that name the
+    call; a declaration that does not fit the cache, by ``declare``."""
     step_fn, make_cache = chain_model()
 
     def undeclared(n_rows, seq_len):
         return make_cache(n_rows, seq_len)
 
-    with pytest.raises(ValueError, match="make_cache.leaf_seq_axes"):
+    with pytest.raises(ValueError, match=r"decoding.declare\(make_cache"):
         KVSlotPool(step_fn, undeclared, eos_id=EOS, max_slots=4,
                    max_seq_len=32, steps=2)
-    with pytest.raises(ValueError, match="make_cache.leaf_seq_axes"):
+    with pytest.raises(ValueError, match=r"decoding.declare\(make_cache"):
         DecodeServer(step_fn, undeclared, eos_id=EOS, max_seq_len=16,
                      max_slots=2)
-    undeclared.leaf_seq_axes = {"z": 1, "extra": 1}
-    with pytest.raises(ValueError, match="declares 2 leaves"):
-        KVSlotPool(step_fn, undeclared, eos_id=EOS, max_slots=4,
-                   max_seq_len=32, steps=2)
+    with pytest.raises(ValueError, match="declares 2 leaves, the cache "
+                                         "has 1"):
+        declare(undeclared, CacheSpec({"z": Leaf(1), "extra": Leaf(1)}))
 
 
 def test_pool_alloc_resize_and_rungs():
@@ -1063,11 +1071,10 @@ def running_sum_model(with_prefill=True):
         return {"r": cache["r"].at[row].set(r0 + tokens.sum()), "z": z,
                 "zz": zz}
 
-    make_cache.leaf_seq_axes = {"r": -1, "z": 1, "zz": 1}
-    make_cache.leaf_seq_strides = {"r": 1, "z": 1, "zz": 2}
-    if with_prefill:
-        prefill_fn.chunk_tokens = SUM_C
-        make_cache.prefill_fn = prefill_fn
+    prefill_fn.chunk_tokens = SUM_C
+    declare(make_cache, CacheSpec(
+        {"r": Leaf(), "z": Leaf(1), "zz": Leaf(1, stride=2)},
+        prefill_fn=prefill_fn if with_prefill else None))
     return step_fn, make_cache
 
 
@@ -1113,7 +1120,7 @@ def test_a_builder_without_a_prefill_keeps_its_three_executables():
         assert got[0].tolist() == _sum_chain(prompt, 5)
         assert srv.metrics()["decode"]["prefill_chunks"] == 0
     step_fn, make_cache = running_sum_model(with_prefill=False)
-    with pytest.raises(ValueError, match="make_cache.prefill_fn"):
+    with pytest.raises(ValueError, match="CacheSpec.prefill_fn"):
         KVSlotPool(step_fn, make_cache, eos_id=SUM_V, max_slots=2,
                    max_seq_len=32, prefix=True)
 
@@ -1148,11 +1155,11 @@ def test_a_strided_leaf_is_installed_by_its_own_row_count():
     import jax
 
     step_fn, make_cache = running_sum_model(with_prefill=False)
-    del make_cache.leaf_seq_axes["r"], make_cache.leaf_seq_strides["r"]
     plain = lambda n, t: {k: v for k, v in make_cache(n, t).items()
                           if k != "r"}
-    plain.leaf_seq_axes = make_cache.leaf_seq_axes
-    plain.leaf_seq_strides = make_cache.leaf_seq_strides
+    declare(plain, CacheSpec({k: leaf for k, leaf in
+                              spec_of(make_cache).leaves.items()
+                              if k != "r"}))
     step = lambda cache, tok, ts: (
         jax.nn.one_hot(tok % SUM_V, SUM_V), cache)
     pool = KVSlotPool(step, plain, eos_id=SUM_V, max_slots=2,
@@ -1267,7 +1274,7 @@ def test_a_chunked_builders_turn_has_a_prefill_phase():
 
 def counting_chain_model(experts=8):
     """The chain model with counts made on the device, declared as a
-    routed-experts builder declares them (``make_cache.expert_stats``):
+    routed-experts builder declares them (``CacheSpec.expert_stats``):
     every step counts, in ONE "expert layer", two pairs a live row, as
     many experts touched as rows are live (at most ``experts``), a peak
     of 2 and itself."""
@@ -1285,9 +1292,9 @@ def counting_chain_model(experts=8):
         return {"z": jnp.zeros((n_rows, seq_len), "float32"),
                 "counts": jnp.zeros((1, 4), jnp.int32)}
 
-    make_cache.leaf_seq_axes = {"z": 1, "counts": -1}
-    make_cache.expert_stats = lambda cache: cache["counts"]
-    make_cache.n_expert = experts
+    declare(make_cache, CacheSpec(
+        {"z": Leaf(1), "counts": Leaf()},
+        expert_stats=lambda cache: cache["counts"], n_expert=experts))
     return step_fn, make_cache
 
 

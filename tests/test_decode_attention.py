@@ -923,7 +923,7 @@ def test_grouped_work_list_of_k_rows_is_the_last_rows(k):
     """The work list of a ``K``-row read is the one-row list at the
     slot's LAST fresh row (the rung's last where that passes the end):
     its items add up to ``kv_positions_read`` there — what a builder's
-    ``make_cache.kv_positions_read`` hands the server's counter for a
+    ``"kv"`` ``PositionRead`` hands the server's counter for a
     speculative round."""
     import jax.numpy as jnp
 
@@ -1012,7 +1012,7 @@ def test_grouped_work_list_reads_what_kv_positions_read_says(
     """The one rounding: with the grouped kernel's sizes a slot's items
     add up to ``kv_positions_read`` (whole blocks, then the last in
     classes of the tail), which is also what a builder's
-    ``make_cache.kv_positions_read`` hands the server's counter; off the
+    ``"kv"`` ``PositionRead`` hands the server's counter; off the
     TPU that rule says the whole rung.  Leaves 512 wide as four heads of
     128 and as eight of 64 (lfm2's, whose rung is 2,048) read alike."""
     import jax.numpy as jnp

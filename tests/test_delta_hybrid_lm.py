@@ -553,8 +553,8 @@ def test_an_idle_row_keeps_its_state_and_its_conv_window():
 
 def test_layers_that_hold_different_leaves_are_declared_leaf_by_leaf():
     """A full layer holds k and v and no state, a linear layer a state
-    and a conv window and no K/V: ``cache_leaf_seq_axes`` /
-    ``recurrent_leaf_names`` read the declarations, ``resize`` keeps the
+    and a conv window and no K/V: ``spec_of(make_cache)`` reads the
+    declarations, ``resize`` keeps the
     recurrent leaves whatever the length rung, and the bytes follow."""
     import jax
 
@@ -563,14 +563,15 @@ def test_layers_that_hold_different_leaves_are_declared_leaf_by_leaf():
     pool, make_cache = _pool(cfg, w, [16, 32])
     d = dh.dims(cfg)
     leaves = jax.tree.leaves(jax.eval_shape(lambda: make_cache(2, 16)))
-    with pytest.raises(ValueError, match="leaf_seq_axes"):
-        decoding.cache_leaf_seq_axes(lambda s, t: make_cache(s, t), leaves)
+    with pytest.raises(ValueError, match="make_cache declares nothing"):
+        decoding.spec_of(lambda s, t: make_cache(s, t))
+    spec = decoding.spec_of(make_cache)
+    assert len(spec.flat) == len(leaves)
     # flattened: (conv, state) x 3, then k, v
-    assert decoding.cache_leaf_seq_axes(make_cache, leaves) == (
+    assert [leaf.seq_axis for leaf in spec.flat] == (
         [None, None] * 3 + [1, 1])
-    assert decoding.recurrent_leaf_names(make_cache) == [
+    assert pool.recurrent_leaves == spec.names(lambda leaf: leaf.seq_axis is None) == [
         "[%d]['%s']" % (i, n) for i in range(3) for n in ("conv", "state")]
-    assert pool.recurrent_leaves == decoding.recurrent_leaf_names(make_cache)
 
     rng = np.random.RandomState(9)
     p0, p1 = (rng.randint(0, V, n).astype(np.int32) for n in (5, 3))
@@ -659,7 +660,8 @@ def test_the_full_layers_count_the_form_they_lowered(backend, monkeypatch):
                    np.zeros(2, np.int32))
     assert (count("xla") - before[0], count("kernel") - before[1]) == (
         (0, 2) if tpu else (2, 0))
-    read = make_cache.kv_positions_read(np.array([0, 7, 15, rung - 1]), rung)
+    read = decoding.spec_of(make_cache).reads[0].rule(
+        np.array([0, 7, 15, rung - 1]), rung)
     assert read.tolist() == ([16, 16, 16, 128] if tpu else [rung] * 4)
 
 

@@ -230,9 +230,11 @@ def test_a_rung_equal_to_a_state_width_is_an_ordinary_rung(rung_equals):
 
     leaves = jax.tree.leaves(jax.eval_shape(lambda: make_cache(2, 16)))
     assert leaves[2].shape == (2, d.ssm_heads, 16, d.d_state)
-    with pytest.raises(ValueError, match="leaf_seq_axes"):
-        decoding.cache_leaf_seq_axes(lambda s, t: make_cache(s, t), leaves)
-    axes = decoding.cache_leaf_seq_axes(make_cache, leaves)
+    with pytest.raises(ValueError, match="make_cache declares nothing"):
+        decoding.spec_of(lambda s, t: make_cache(s, t))
+    spec = decoding.spec_of(make_cache)
+    assert len(spec.flat) == len(leaves)
+    axes = [leaf.seq_axis for leaf in spec.flat]
     assert axes == [None, 1, None, 1] * d.n_layer   # conv, k, ssm, v
     assert len(pool.recurrent_leaves) == 2 * d.n_layer
 

@@ -117,7 +117,8 @@ def test_verify_rows_and_the_module_equal_the_full_forward():
     want = np.asarray(ref.forward(w, jt, cfg))
     want_mtp = np.asarray(ref.mtp_logits(w, jt, cfg))
     _, make_cache, _ = _build(cfg, w)
-    verify, module = jax.jit(make_cache.verify_fn), jax.jit(make_cache.mtp_fn)
+    spec = decoding.spec_of(make_cache)
+    verify, module = jax.jit(spec.verify_fn), jax.jit(spec.mtp_fn)
     cache = make_cache(3, 32)
     for t in range(0, 20, 2):
         ts = jnp.asarray([t, t, -1], jnp.int32)
@@ -160,7 +161,8 @@ def test_chunked_prefill_equals_steps_with_a_window_under_the_chunk(chunk):
         cache = jpre(cache, jnp.int32(1), jnp.asarray(
             toks[0, at:at + chunk + 1]), jnp.int32(at), jnp.int32(chunk))
     # what verify + module rounds write for the same positions
-    verify, module = jax.jit(make_cache.verify_fn), jax.jit(make_cache.mtp_fn)
+    spec = decoding.spec_of(make_cache)
+    verify, module = jax.jit(spec.verify_fn), jax.jit(spec.mtp_fn)
     by_rounds = make_cache(2, 32)
     for t in range(0, fed, 2):
         ts = jnp.asarray([-1, t], jnp.int32)

@@ -111,7 +111,7 @@ def test_prefill_then_decode_equals_the_full_forward(dtype, kv_dtype, worst,
     stats = np.asarray(cache["expert_stats"])
     assert stats.shape == (4, len(rx.STAT_NAMES))
     assert (stats[:, 3] == 12 + 2).all() and (stats[:, 0] > 0).all()
-    assert make_cache.n_expert == 4
+    assert decoding.spec_of(make_cache).n_expert == 4
 
 
 def test_the_decay_of_these_weights_differs_across_the_channels_of_a_head():
@@ -279,10 +279,11 @@ def test_an_idle_row_keeps_its_state_and_its_conv_window():
 def test_the_leaves_are_declared_leaf_by_leaf():
     cfg = tiny_cfg()
     step, make_cache = _build(cfg, weights(cfg))
-    names = decoding.recurrent_leaf_names(make_cache)
+    spec = decoding.spec_of(make_cache)
+    names = spec.names(lambda leaf: leaf.seq_axis is None)
     assert len(names) == 3 * 2 + 1         # state + conv a K layer, the counts
-    assert len(decoding.recurrent_leaf_names(make_cache,
-                                             slotless=False)) == 6
+    assert len(spec.names(lambda leaf: leaf.seq_axis is None
+                          and leaf.slot)) == 6
     assert not any("['layers'][0]" in n for n in names)    # the G layer
 
 

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu import monitor
+from paddle_tpu import decoding, monitor
 from paddle_tpu.serving.decode import DecodeServer
 from paddle_tpu.serving.kv_pool import KVSlotPool
 
@@ -45,7 +45,8 @@ def _toy_step():
     def make_cache(n_rows, seq_len):
         return {"k": jnp.zeros((n_rows, seq_len, 8), jnp.float32)}
 
-    make_cache.leaf_seq_axes = {"k": 1}
+    decoding.declare(make_cache, decoding.CacheSpec(
+        {"k": decoding.Leaf(1)}))
     return step, make_cache, dict(vocab=VOCAB, slots=4, rungs=[16, 32],
                                   steps=2, kv_dtype="fp32")
 
@@ -195,7 +196,8 @@ def test_a_step_over_device_arrays_alone_places_nothing():
     def make_cache(n_rows, seq_len):
         return {"k": jnp.zeros((n_rows, seq_len, 8), jnp.float32)}
 
-    make_cache.leaf_seq_axes = {"k": 1}
+    decoding.declare(make_cache, decoding.CacheSpec(
+        {"k": decoding.Leaf(1)}))
     pool = KVSlotPool(step, make_cache, eos_id=VOCAB, max_slots=2,
                       max_seq_len=8, slot_ladder=[2], len_ladder=[8],
                       steps=1)

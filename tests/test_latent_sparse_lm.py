@@ -114,7 +114,8 @@ def test_steps_and_prefill_equal_the_reference_forward(dtype, kv_dtype,
     w = weights(cfg, seed=3, dtype=dtype)
     step, make_cache, prefill = decoding.make_latent_sparse_lm_pooled_step_fn(
         w, cfg, kv_dtype=kv_dtype, prefill_tokens=CHUNK)
-    assert prefill.chunk_tokens == CHUNK and make_cache.prefill_fn is prefill
+    assert prefill.chunk_tokens == CHUNK
+    assert decoding.spec_of(make_cache).prefill_fn is prefill
     toks = np.random.RandomState(5).randint(0, V, (2, 48)).astype(np.int32)
     # the reference rounds the operands a bf16 run states as rounded
     rcfg = dict(cfg, matmul_inputs=None if dtype == "float32" else dtype)
@@ -350,7 +351,7 @@ def test_a_held_share_serves_what_the_references_share_gives():
     w = weights(cfg, seed=8, held=(4, 8))
     step, make_cache, prefill = decoding.make_latent_sparse_lm_pooled_step_fn(
         w, cfg, kv_dtype="fp32", held=(4, 8), prefill_tokens=CHUNK)
-    assert make_cache.n_expert == 4
+    assert decoding.spec_of(make_cache).n_expert == 4
     toks = np.random.RandomState(9).randint(0, V, (2, 32)).astype(np.int32)
     want = _reference_logits(w, toks, cfg, held=(4, 8))[:, 16:]
     got, _ = _prefill_then_decode(step, make_cache, prefill, toks, 2)
@@ -418,9 +419,10 @@ def test_the_pool_counts_and_declares_the_new_leaves():
     per_position = d.n_layer * (_lanes(d.d_c + d.d_rope)
                                 + _lanes(d.d_index)) * 4
     assert pool.kv_rung_bytes(2, 64) == 2 * 64 * per_position
-    assert make_cache.latent_layers == d.n_layer
-    assert make_cache.latent_positions_selected(
-        np.asarray([3, 16, 40])).tolist() == [3, 16, 16]
+    kv, latent = decoding.spec_of(make_cache).reads
+    assert (kv.kind, latent.kind, latent.layers) == (
+        "kv", "latent", d.n_layer)
+    assert latent.rule(np.asarray([3, 16, 40])).tolist() == [3, 16, 16]
 
 
 def test_a_snapshot_taken_and_seated_equals_the_prefilled_slot():

@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import framework, models, sharding
+from paddle_tpu import decoding, framework, models, sharding
 from paddle_tpu.inference import AnalysisConfig, create_paddle_predictor
 from paddle_tpu.parallel import mesh as mesh_lib
 from paddle_tpu.parallel.pipeline_predictor import PipelinePredictor
@@ -335,7 +335,8 @@ def test_kv_pool_len_multiple_rounds_rungs():
     def make_cache(n_rows, seq_len):
         return None
 
-    make_cache.leaf_seq_axes = ()   # a cache with no leaves declares none
+    # a cache with no leaves declares none
+    decoding.declare(make_cache, decoding.CacheSpec(None))
     pool = KVSlotPool(lambda *a: None, make_cache, eos_id=0,
                       max_slots=2, max_seq_len=50, len_multiple=4)
     rungs = list(pool.len_policy.ladder)

@@ -137,8 +137,9 @@ def test_the_counts_made_on_the_device_equal_a_recount_from_the_reference():
                 minlength=cfg["num_experts"])
             want[layer] += [counts.sum(), (counts > 0).sum(),
                             counts.max(), 1]
-    assert make_cache.n_expert == 16
-    got = np.asarray(make_cache.expert_stats(cache))
+    spec = decoding.spec_of(make_cache)
+    assert spec.n_expert == 16
+    got = np.asarray(spec.expert_stats(cache))
     assert got.tolist() == want.tolist()
     assert want[:, 0].tolist() == [B * S * 4] * 3
 
@@ -195,7 +196,7 @@ def test_a_reused_slot_starts_its_conv_state_from_zero(reset, monkeypatch):
 def test_layers_that_hold_different_leaves_are_declared_leaf_by_leaf():
     """An attention layer holds k and v and no state, a conv layer a
     window and no K/V, and the counts ride as one more undeclared-axis
-    leaf: ``cache_leaf_seq_axes`` / ``recurrent_leaf_names`` read the
+    leaf: ``spec_of(make_cache)`` reads the
     declarations, ``resize`` cuts and pads to the spec (state and counts
     kept whatever the length rung), ``extract_kv`` skips what has no
     positions, and the bytes follow."""
@@ -206,15 +207,16 @@ def test_layers_that_hold_different_leaves_are_declared_leaf_by_leaf():
     pool, make_cache = _pool(cfg, w, [16, 32])
     d = rx.dims(cfg)
     leaves = jax.tree.leaves(jax.eval_shape(lambda: make_cache(2, 16)))
-    with pytest.raises(ValueError, match="leaf_seq_axes"):
-        decoding.cache_leaf_seq_axes(lambda s, t: make_cache(s, t), leaves)
+    with pytest.raises(ValueError, match="make_cache declares nothing"):
+        decoding.spec_of(lambda s, t: make_cache(s, t))
+    spec = decoding.spec_of(make_cache)
+    assert len(spec.flat) == len(leaves)
     # flattened: expert_stats, then layers: conv | k, v | conv | conv
-    assert decoding.cache_leaf_seq_axes(make_cache, leaves) == [
+    assert [leaf.seq_axis for leaf in spec.flat] == [
         None, None, 1, 1, None, None]
-    assert decoding.recurrent_leaf_names(make_cache) == [
+    assert pool.recurrent_leaves == spec.names(lambda leaf: leaf.seq_axis is None) == [
         "['expert_stats']", "['layers'][0]['conv']",
         "['layers'][2]['conv']", "['layers'][3]['conv']"]
-    assert pool.recurrent_leaves == decoding.recurrent_leaf_names(make_cache)
 
     rng = np.random.RandomState(9)
     p0, p1 = (rng.randint(0, V, n).astype(np.int32) for n in (5, 3))
@@ -253,7 +255,7 @@ def test_layers_that_hold_different_leaves_are_declared_leaf_by_leaf():
 
 
 def test_the_builder_declares_what_a_step_reads_of_its_leaves():
-    """``make_cache.kv_positions_read`` is ``step_positions_read`` for
+    """The spec's ``"kv"`` read is ``step_positions_read`` for
     this builder's leaves, so the server's read counter follows the
     chooser: at the published widths (32 query heads over 8 K/V heads of
     64 lanes, bf16) the grouped kernel's rounding on a TPU — blocks of
@@ -268,8 +270,10 @@ def test_the_builder_declares_what_a_step_reads_of_its_leaves():
                            "lfm2_24b_a2b.json")) as f:
         cfg = json.load(f)
     # the declaration needs the dimensions alone: no weight is read
-    rule = decoding.make_routed_conv_lm_pooled_step_fn(
-        {}, cfg, kv_dtype="bf16")[1].kv_positions_read
+    (kv,) = decoding.spec_of(decoding.make_routed_conv_lm_pooled_step_fn(
+        {}, cfg, kv_dtype="bf16")[1]).reads
+    assert kv.kind == "kv" and kv.rounds
+    rule = kv.rule
     d = rx.dims(cfg)
     assert (d.n_head, d.n_kv_head, d.head_dim, d.d_kv) == (32, 8, 64, 512)
     want = functools.partial(da.step_positions_read, width=512,
@@ -279,8 +283,8 @@ def test_the_builder_declares_what_a_step_reads_of_its_leaves():
     assert rule(ts, 2048, backend="tpu").tolist() == want(
         ts, 2048, backend="tpu").tolist() == da.kv_positions_read(
             ts, 512, 64).tolist() == [64, 64, 128, 512, 576, 1024, 2048]
-    tiny = decoding.make_routed_conv_lm_pooled_step_fn(
-        {}, rehearse_cfg(), kv_dtype="bf16")[1].kv_positions_read
+    tiny = decoding.spec_of(decoding.make_routed_conv_lm_pooled_step_fn(
+        {}, rehearse_cfg(), kv_dtype="bf16")[1]).reads[0].rule
     assert tiny(ts[:3], 64, backend="tpu").tolist() == [64] * 3
 
 

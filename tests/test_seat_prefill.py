@@ -1,19 +1,23 @@
 """A prompt is prefilled in one pass at its admission (PR 45).
 
 The transformer-LM pooled builder declares a BATCHED PREFILL
-(``make_cache.prefill_rows_fn``); a pool that finds it seats a request
+(``CacheSpec.prefill_rows_fn``); a pool that finds it seats a request
 and feeds it all of its prompt but the last token in ONE
 ``seat_prefill`` dispatch, several seats a dispatch.  These tests hold
 the mechanism to the step-only path it replaces — the same builder with
 the declaration taken away — token for token, and count what it
 compiles, dispatches and reports.
 """
+import copy
+
 import numpy as np
 import pytest
 
 from paddle_tpu.decoding import (
+    declare,
     make_transformer_lm_pooled_step_fn,
     random_transformer_lm_state,
+    spec_of,
 )
 from paddle_tpu.serving.decode import DecodeServer
 from paddle_tpu.serving.kv_pool import KVSlotPool
@@ -35,7 +39,9 @@ def _builder(state, kv="fp32", prefill=True):
         state, V, DIMS["d_model"], DIMS["n_layer"], DIMS["n_head"],
         DIMS["d_inner"], kv_dtype=kv)
     if not prefill:     # the step-only path: the same builder, undeclared
-        del make_cache.prefill_rows_fn
+        spec = copy.copy(spec_of(make_cache))
+        spec.prefill_rows_fn = None
+        declare(make_cache, spec)
     return step_fn, make_cache
 
 
@@ -300,7 +306,7 @@ def test_the_prefill_program_holds_no_product_as_wide_as_the_vocabulary(
     import jax.numpy as jnp
 
     _, make_cache = _builder(lm_state)
-    text = jax.jit(make_cache.prefill_rows_fn).lower(
+    text = jax.jit(spec_of(make_cache).prefill_rows_fn).lower(
         make_cache(4, T), jnp.zeros((2,), jnp.int32),
         jnp.zeros((2, 8), jnp.int32)).compile().as_text()
     wide = {line.split(" = ")[1].split(" ")[0] for line in text.splitlines()

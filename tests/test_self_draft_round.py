@@ -9,6 +9,8 @@ with proposals that are sometimes right (a module built to be right: it
 reads the plain run's own tokens) and sometimes wrong, with speculative
 and plain slots in one pool.
 """
+import copy
+
 import numpy as np
 import pytest
 
@@ -85,7 +87,8 @@ def test_speculative_tokens_equal_plain_tokens_whatever_the_proposals(
     _, (step, make_cache, _) = _builder()
     prompts, total = _prompts(3), 48
     want = _plain_tokens(step, make_cache, prompts, total)
-    cfg = SelfDraftConfig(make_cache.verify_fn, _oracle(want, wrong_every))
+    cfg = SelfDraftConfig(decoding.spec_of(make_cache).verify_fn,
+                          _oracle(want, wrong_every))
     pool = _pool(step, make_cache, speculative=cfg)
     state = pool.alloc(3, RUNG)
     state = pool.admit(state, [0, 1, 2], prompts, [len(p) for p in prompts],
@@ -136,7 +139,8 @@ def test_a_rejected_round_leaves_every_ring_row_a_later_query_reads():
     _, (step, make_cache, _) = _builder()
     prompts, total = _prompts(1), 40
     want = _plain_tokens(step, make_cache, prompts, total)
-    cfg = SelfDraftConfig(make_cache.verify_fn, _oracle(want, 1))
+    cfg = SelfDraftConfig(decoding.spec_of(make_cache).verify_fn,
+                          _oracle(want, 1))
     spec_pool = _pool(step, make_cache, speculative=cfg, slots=1)
     plain_pool = _pool(step, make_cache, slots=1)
     args = ([0], prompts, [len(prompts[0])], [total])
@@ -174,8 +178,8 @@ def test_a_ring_carries_two_rows_a_round_and_refuses_three(k):
     def bare(s, t):
         return make_cache(s, t)["layers"]
 
-    bare.leaf_seq_axes = make_cache.leaf_seq_axes["layers"]
-    bare.leaf_seq_windows = make_cache.leaf_seq_windows["layers"]
+    spec = decoding.spec_of(make_cache)
+    decoding.declare(bare, decoding.CacheSpec(spec.leaves["layers"]))
     cfg = SpeculativeConfig(lambda c, t, ts: (None, c), step, bare, k=k)
     kw = dict(eos_id=V, max_slots=2, max_seq_len=RUNG, slot_ladder=[2],
               len_ladder=[RUNG])
@@ -186,7 +190,7 @@ def test_a_ring_carries_two_rows_a_round_and_refuses_three(k):
                        r"leaves.*k - 2 spare rows"):
         KVSlotPool(step, bare, speculative=cfg, **kw)
     with pytest.raises(ValueError, match="k = 2 rows"):
-        SelfDraftConfig(make_cache.verify_fn, make_cache.mtp_fn, k=3)
+        SelfDraftConfig(spec.verify_fn, spec.mtp_fn, k=3)
 
 
 def _server(step, make_cache, name, speculative=None, **kw):
@@ -253,7 +257,7 @@ def test_decode_server_serves_speculative_and_plain_requests_alike():
 
 def test_a_speculative_round_counts_its_read_by_the_builders_rule():
     """A self-drafting round reads the rung-long leaves ONCE for its K
-    rows: where the builder declares ``make_cache.kv_positions_read``
+    rows: where the builder declares a ``"kv"`` ``PositionRead``
     the server's read counter takes what that rule says at the slot's
     LAST fresh row (the rung's last where that passes the end), once a
     slot that advanced — under the pool's positions, at or over the live
@@ -269,8 +273,12 @@ def test_a_speculative_round_counts_its_read_by_the_builders_rule():
         return (ts // 8 + 1) * 8
 
     counted = {}
+    spec = copy.copy(decoding.spec_of(make_cache))
+    window = tuple(read for read in spec.reads if read.kind == "window")
     for ruled in (True, False):
-        make_cache.kv_positions_read = rule if ruled else None
+        spec.reads = window + (
+            (decoding.PositionRead("kv", rule),) if ruled else ())
+        decoding.declare(make_cache, spec)
         srv = _server(step, make_cache, "spec-kv-%s" % ruled,
                       speculative=make_self_draft(make_cache))
         try:

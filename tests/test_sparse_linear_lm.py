@@ -285,15 +285,18 @@ def _pool(built, **kw):
 
 def test_declarations(built):
     _, make_cache, _ = built
-    assert make_cache.prefill_fn.chunk_tokens == C
-    leaves = jax.tree.leaves(jax.eval_shape(lambda: make_cache(2, T)))
-    assert decoding.cache_leaf_seq_axes(make_cache, leaves) == [
+    spec = decoding.spec_of(make_cache)
+    assert spec.prefill_fn.chunk_tokens == C
+    assert len(spec.flat) == len(
+        jax.tree.leaves(jax.eval_shape(lambda: make_cache(2, T))))
+    assert [leaf.seq_axis for leaf in spec.flat] == [
         1, 1, 1, None, None, 1, 1, 1]
-    assert decoding.cache_leaf_seq_strides(make_cache, leaves) == [
+    assert [leaf.stride for leaf in spec.flat] == [
         2, 1, 1, 1, 1, 2, 1, 1]   # ck first: leaves sort by name
-    assert decoding.recurrent_leaf_names(make_cache) == [
+    assert spec.names(lambda leaf: leaf.seq_axis is None) == [
         "[1]['s']", "[2]['s']"]
-    assert make_cache.sparse_layers == 2
+    assert [(read.kind, read.layers) for read in spec.reads] == [
+        ("kv", 1), ("sparse", 2)]
     with pytest.raises(ValueError, match="multiple of block_size"):
         make_cache(2, 100)
 

@@ -12,6 +12,7 @@ rung), a chunked prefill through expert layers, a router that reads the
 block's input before attention, softmax over the chosen, ReLU gates, and
 whole-row snapshots over ring leaves.
 """
+import copy
 import importlib.util
 import json
 import os
@@ -101,7 +102,8 @@ def test_prefill_then_decode_equals_the_full_forward(dtype, kv_dtype, worst,
     w = weights(cfg, seed=3, dtype=dtype)
     step, make_cache, prefill = decoding.make_windowed_routed_lm_pooled_step_fn(
         w, cfg, kv_dtype=kv_dtype, prefill_tokens=CHUNK)
-    assert prefill.chunk_tokens == CHUNK and make_cache.prefill_fn is prefill
+    assert prefill.chunk_tokens == CHUNK
+    assert decoding.spec_of(make_cache).prefill_fn is prefill
     toks = np.random.RandomState(5).randint(0, V, (2, 56)).astype(np.int32)
     want = np.asarray(ref.forward(w, jnp.asarray(toks), cfg))[:, 24:]
     got, cache = _prefill_then_decode(step, make_cache, prefill, toks, 3)
@@ -294,14 +296,15 @@ def test_sequence_leaves_of_one_rung_differ_in_length():
     w = weights(cfg, seed=4)
     d = wr.dims(cfg)
     pool, make_cache = _pool(cfg, w, [8, 32, 64], prefix=True)
-    leaves = jax.tree.leaves(jax.eval_shape(lambda: make_cache(2, 32)))
+    told = decoding.spec_of(make_cache).flat
+    assert len(told) == len(
+        jax.tree.leaves(jax.eval_shape(lambda: make_cache(2, 32))))
     # flattened: expert_stats, then k, v of global | window x 3
-    assert decoding.cache_leaf_seq_windows(make_cache, leaves) == [
+    assert [leaf.window for leaf in told] == [
         None, None, None] + [WINDOW] * 6
-    assert decoding.cache_leaf_seq_axes(make_cache, leaves) == [None] + [1] * 8
-    assert decoding.cache_leaf_slotless(make_cache, leaves) == [True] + [
-        False] * 8
-    assert pool.ring_leaves == decoding.ring_leaf_names(make_cache) == [
+    assert [leaf.seq_axis for leaf in told] == [None] + [1] * 8
+    assert [leaf.slot for leaf in told] == [False] + [True] * 8
+    assert pool.ring_leaves == [
         "['layers'][%d]['%s']" % (i, n) for i in (1, 2, 3) for n in "kv"]
     for t, ring in ((8, 8), (32, 16), (64, 16)):
         lens = [c["k"].shape[1] for c in pool._state_spec(2, t)[
@@ -318,9 +321,10 @@ def test_sequence_leaves_of_one_rung_differ_in_length():
     def wrong(s, t):
         return mc(s, t)
 
-    wrong.leaf_seq_axes = mc.leaf_seq_axes
-    wrong.leaf_seq_windows = jax.tree.map(lambda x: 2 * x,
-                                          mc.leaf_seq_windows)
+    decoding.declare(wrong, decoding.CacheSpec(jax.tree.map(
+        lambda leaf: decoding.Leaf(
+            leaf.seq_axis, window=2 * (leaf.window or 0), slot=leaf.slot),
+        decoding.spec_of(mc).leaves)))
     with pytest.raises(ValueError, match="min\\(rung, window\\)"):
         KVSlotPool(step, wrong, eos_id=V, max_slots=2, max_seq_len=64,
                    slot_ladder=[2], len_ladder=[64])
@@ -394,8 +398,8 @@ def test_what_would_slice_or_roll_back_a_ring_is_refused(tier):
     def bare(s, t):
         return make_cache(s, t)["layers"]
 
-    bare.leaf_seq_axes = make_cache.leaf_seq_axes["layers"]
-    bare.leaf_seq_windows = make_cache.leaf_seq_windows["layers"]
+    decoding.declare(bare, decoding.CacheSpec(
+        decoding.spec_of(make_cache).leaves["layers"]))
     kw = dict(eos_id=V, max_slots=2, max_seq_len=64, slot_ladder=[2],
               len_ladder=[64])
     if tier.startswith("speculative"):
@@ -485,18 +489,21 @@ def test_decode_server_end_to_end_with_snapshots_and_the_window_counters():
 
 def test_the_server_counts_kv_reads_by_the_builders_rule():
     """The builder of grouped heads declares what a one-row step reads
-    of a slot's sequence leaves (``make_cache.kv_positions_read``: the
+    of a slot's sequence leaves (its ``"kv"`` ``PositionRead``: the
     grouped kernel's rounding where it serves them, the whole rung off
     the TPU), and the server's read counter follows that rule, whatever
     the pool's dtype."""
     cfg = rehearse_cfg()
     step, make_cache, _ = decoding.make_windowed_routed_lm_pooled_step_fn(
         weights(cfg, seed=7), cfg, kv_dtype="fp32", prefill_tokens=CHUNK)
-    assert make_cache.kv_positions_read(np.asarray([0, 40]), 64).tolist() \
-        == [64, 64]
+    spec = decoding.spec_of(make_cache)
+    kv, window = spec.reads
+    assert kv.rule(np.asarray([0, 40]), 64).tolist() == [64, 64]
     rungs = []
-    make_cache.kv_positions_read = lambda ts, t: (
-        rungs.append(t) or (ts // 8 + 1) * 8)
+    spec = copy.copy(spec)
+    spec.reads = (decoding.PositionRead("kv", lambda ts, t: (
+        rungs.append(t) or (ts // 8 + 1) * 8)), window)
+    decoding.declare(make_cache, spec)
     name = "windowed-kv-rule"
     srv = DecodeServer(step, make_cache, eos_id=V, max_seq_len=64,
                        max_slots=2, slot_ladder=(2,), len_ladder=(64,),

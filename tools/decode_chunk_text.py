@@ -259,7 +259,7 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
     def prefill(w, state):
         # one slot's next chunk of prompt tokens, as the pool runs it
         # (KVSlotPool._prefill_fn) for a builder that declares one
-        fn = build(w)[1].prefill_fn
+        fn = decoding.spec_of(build(w)[1]).prefill_fn
         c = fn.chunk_tokens
         return dict(state, cache=fn(
             state["cache"], jnp.int32(3),

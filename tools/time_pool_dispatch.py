@@ -64,7 +64,9 @@ and, on the host alone, ``PrefixKVCache.probe`` of a 30k-token prompt.
 
 ``--repo`` imports ``paddle_tpu`` and the benchmark's family from
 another checkout; ``--one-call-a-request`` seats a batch the way a
-checkout before PR 30 does, one ``admit`` call each.  ``--rehearse-cpu``
+checkout before PR 30 does, one ``admit`` call each (a checkout from
+before PR 58 has no ``decoding.spec_of``: run ITS copy of this tool
+from its own tree).  ``--rehearse-cpu``
 runs the cell's tiny rehearsal sizes on the CPU to prove the script, and
 prints no number a reader could take for the chip's.  The last line of
 output is one JSON object.
@@ -252,6 +254,7 @@ def main():
 
     from benchmark.lib import harness
     from paddle_tpu import monitor
+    from paddle_tpu.decoding import spec_of
     from paddle_tpu.serving.kv_pool import KVSlotPool
 
     harness.configure_jax(args.rehearse_cpu)
@@ -270,7 +273,7 @@ def main():
     weights_s = time.perf_counter() - t_build0
     sv, vocab = cfg["serving"], int(cfg["vocab_size"])
     s, t = sv["slot_ladder"][-1], sv["len_ladder"][-1]
-    snapshots = getattr(make_cache, "prefill_fn", None) is not None
+    snapshots = spec_of(make_cache).prefill_fn is not None
     pool = KVSlotPool(step_fn, make_cache, eos_id=vocab, max_slots=s,
                       max_seq_len=t, slot_ladder=[s], len_ladder=[t],
                       steps=sv["steps_per_tick"], kv_dtype=sv["kv_dtype"],
