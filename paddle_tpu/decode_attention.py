@@ -44,7 +44,10 @@ every row of its ring is live and none of them is older than the window
 (K was rotated at its own position and softmax does not care about
 order, so the masked forms serve unchanged but for where they write).
 ``K`` fresh rows read the OLD ring and themselves before they overwrite
-it (:func:`ring_positions` says which position each old row holds).  A
+it (:func:`ring_positions` says which position each old row holds): on
+a TPU through :func:`ring_rows_decode_attention`, a Pallas kernel that
+takes a slot's ring as ONE block a leaf, where the heads are whole lane
+tiles (:func:`ring_kernel_supported`), else as plain XLA ops.  A
 wrapped row cannot be sliced by positions or rolled back, so the pool
 carries a ring leaf whole (the builder declares its window:
 ``decoding.Leaf``) and refuses what would slice it.
@@ -132,8 +135,9 @@ Three implementations of the read-what-is-live contract, chosen by
   XLA ops (scatter append + masked softmax over the whole T axis):
   products in the storage dtype (int8: dequantized to fp32 at the read),
   fp32 accumulation and softmax.  The CPU path, the path of every step
-  no kernel covers (int8 leaves, ring leaves, ``K > 1`` over leaves of
-  one query head a K/V head, and — at one row through
+  no kernel covers (int8 leaves, ring leaves at one fresh row or of
+  heads narrower than a lane tile, ``K > 1`` over leaves of one query
+  head a K/V head, and — at one row through
   :func:`lane_masked_decode_attention` on a TPU — what is left of heads
   narrower than a lane tile: grouped ones in an odd number or over a
   rung the block does not divide, and bf16 leaves of one query head a
@@ -164,6 +168,7 @@ __all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
            "grouped_block_decode_attention", "block_sparse_decode_attention",
            "block_kernel_supported", "BLOCK_SPARSE_LOWERED",
            "kernel_supported", "make_decode_attention", "ring_positions",
+           "ring_rows_decode_attention", "ring_kernel_supported",
            "RING_LOWERED", "GROUPED_LOWERED", "UNGROUPED_LOWERED",
            "latent_leaves", "append_latent_rows",
            "selected_latent_attention", "masked_latent_attention",
@@ -729,15 +734,63 @@ def ring_positions(ts, rows: int):
     return last - (last - jnp.arange(rows, dtype=ts.dtype)) % rows
 
 
+def _beside_heads(x, n_kv_head: int, rep: int):
+    """``x`` ``[S, K, n_kv_head * rep * Dh]`` (``K`` rows a slot) as
+    ``[S, n_kv_head, K * rep, Dh]``: the rows of a slot beside the
+    ``rep`` query heads of their K/V head — the ONE layout of both K-row
+    forms, in which their products have the one-row form's shape and
+    read the leaves AS THEY LIE."""
+    import jax.numpy as jnp
+
+    S, K, width = x.shape
+    x = x.reshape(S, K, n_kv_head, rep, width // (n_kv_head * rep))
+    return jnp.swapaxes(x, 1, 2).reshape(S, n_kv_head, K * rep, -1)
+
+
+def _mask_beside_heads(ok, n_kv_head: int, rep: int):
+    """A ``[S, K, T]`` mask for scores ``[S, n_kv_head, K * rep, T]``."""
+    import jax.numpy as jnp
+
+    S, K, T = ok.shape
+    return jnp.broadcast_to(ok[:, None, :, None, :],
+                            (S, n_kv_head, K, rep, T)).reshape(
+                                S, n_kv_head, K * rep, T)
+
+
+def _rows_apart(ctx, K: int):
+    """:func:`_beside_heads` undone: a context ``[S, n_kv_head, K * rep,
+    Dh]`` as ``[S, K, n_head * Dh]``."""
+    import jax.numpy as jnp
+
+    S, g, R, Dh = ctx.shape
+    return jnp.swapaxes(ctx.reshape(S, g, K, R // K, Dh), 1, 2).reshape(
+        S, K, R // K * g * Dh)
+
+
 def _ring_rows_attention(q, k_new, v_new, kv, ts, *, n_head, n_kv_head,
-                         scale, window):
+                         scale, window, read=None):
     """``K`` fresh rows a slot over RING leaves: row ``j`` (position
     ``ts + j``) reads the old ring's rows that hold a position inside
-    its window and the fresh rows ``<= j`` inside it, scored as one run
-    of ``rows + K`` keys; then the fresh rows are written at their
-    positions modulo the ring (of more than ``rows`` fresh rows the last
-    ``rows`` stay).  Unquantized leaves only."""
-    import jax
+    its window and the fresh rows ``<= j`` inside it; then the fresh
+    rows are written at their positions modulo the ring (of more than
+    ``rows`` fresh rows the last ``rows`` stay).  Unquantized leaves
+    only.
+
+    The layout is :func:`_grouped_rows_attention`'s
+    (:func:`_beside_heads`).  The OLD ring is scored AS IT LIES and the
+    ``K`` fresh rows apart, under ONE float32 softmax (shared maximum
+    and sum; the weights rounded to the storage dtype after the joint
+    normalisation), and the two context products are added: no run of
+    ``rows + K`` keys is ever built.  Scored as one such run with the
+    rows on an axis of their own, every window layer of a round copied
+    both ring leaves, built the run, re-laid it by heads and took its
+    softmax over two lane tiles for 130 keys (seen in the compiled round
+    of ``k_exaone_236b_a23b``: 3.0 ms of a 21.6 ms round for four rings
+    that stream in 0.33, PR 59).
+
+    ``read(qg, fresh_k, fresh_v, kv, old_ok, new_ok) -> ctx [S,
+    n_kv_head, K * rep, Dh]``: :func:`_ring_rows_read` (plain XLA ops;
+    None) or the kernel's (:func:`ring_rows_decode_attention`)."""
     import jax.numpy as jnp
 
     if "k_scale" in kv:
@@ -754,22 +807,44 @@ def _ring_rows_attention(q, k_new, v_new, kv, ts, *, n_head, n_kv_head,
               & (pos[:, :, None] - held[:, None, :] < window))
     new_ok = ((pos[:, None, :] <= pos[:, :, None])
               & (pos[:, :, None] - pos[:, None, :] < window))
-    ok = jnp.concatenate([old_ok, new_ok], axis=-1)[:, :, None, None, :]
-    # the old ring, then the fresh rows as the leaf will hold them
-    keys, vals = (jnp.concatenate([kv[n], x.astype(dt)], axis=1).reshape(
-        (S, L + K) + heads) for n, x in (("k", k_new), ("v", v_new)))
-    qg = (q * scale).astype(dt).reshape(S, K, n_kv_head, rep, heads[1])
-    scores = jnp.einsum("skgrd,stgd->skgrt", qg, keys,
-                        preferred_element_type=jnp.float32)
-    w = jax.nn.softmax(jnp.where(ok, scores, -1e9), axis=-1)
-    ctx = jnp.einsum("skgrt,stgd->skgrd", w.astype(dt), vals,
-                     preferred_element_type=jnp.float32)
+    qg = _beside_heads((q * scale).astype(dt), n_kv_head, rep)
+    # the fresh rows as the leaf will hold them
+    fresh_k, fresh_v = (x.astype(dt).reshape((S, K) + heads)
+                        for x in (k_new, v_new))
+    ctx = (read or _ring_rows_read)(qg, fresh_k, fresh_v, kv, old_ok, new_ok)
     rows = jnp.arange(S)[:, None]
     keep = live[:, None] & (jnp.arange(K)[None, :] >= K - L)
     at = jnp.where(keep, pos % L, L)            # dropped: out of range
     kv = {**_append(kv, "k", k_new, rows, at, heads),
           **_append(kv, "v", v_new, rows, at, heads)}
-    return jnp.where(live[:, None, None], ctx.reshape(q.shape), 0.0), kv
+    return jnp.where(live[:, None, None], _rows_apart(ctx, K), 0.0), kv
+
+
+def _ring_rows_read(qg, fresh_k, fresh_v, kv, old_ok, new_ok):
+    """The read of :func:`_ring_rows_attention` as plain XLA ops: the
+    one-row form's product over the leaf viewed ``[S, rows, n_kv_head,
+    Dh]`` and the fresh rows' beside it.  The CPU's form and the
+    kernel's parity reference."""
+    import jax.numpy as jnp
+
+    S, g, R, _ = qg.shape
+    heads, dt = fresh_k.shape[2:], fresh_k.dtype
+    rep = R // fresh_k.shape[1]
+    s_old = jnp.where(_mask_beside_heads(old_ok, g, rep), jnp.einsum(
+        "sgrd,stgd->sgrt", qg, _read(kv, "k", heads),
+        preferred_element_type=jnp.float32), -1e9)
+    s_new = jnp.where(_mask_beside_heads(new_ok, g, rep), jnp.einsum(
+        "sgrd,skgd->sgrk", qg, fresh_k,
+        preferred_element_type=jnp.float32), -1e9)
+    top = jnp.maximum(s_old.max(-1, keepdims=True),
+                      s_new.max(-1, keepdims=True))
+    e_old, e_new = jnp.exp(s_old - top), jnp.exp(s_new - top)
+    total = e_old.sum(-1, keepdims=True) + e_new.sum(-1, keepdims=True)
+    return (jnp.einsum("sgrt,stgd->sgrd", (e_old / total).astype(dt),
+                       _read(kv, "v", heads),
+                       preferred_element_type=jnp.float32)
+            + jnp.einsum("sgrk,skgd->sgrd", (e_new / total).astype(dt),
+                         fresh_v, preferred_element_type=jnp.float32))
 
 
 def _grouped_rows_attention(q, k_new, v_new, kv, ts, *, n_head, n_kv_head,
@@ -777,13 +852,15 @@ def _grouped_rows_attention(q, k_new, v_new, kv, ts, *, n_head, n_kv_head,
     """``K`` fresh rows a slot of GROUPED heads over sequence leaves:
     the contract of :func:`grouped_masked_decode_attention`, with the
     ``K`` rows of a slot laid beside the ``rep`` query heads of their
-    K/V head (``[S, n_kv_head, K * rep, Dh]``), so that both products
-    have the one-row form's shape and read the leaves AS THEY LIE.  With
+    K/V head (``[S, n_kv_head, K * rep, Dh]``: :func:`_beside_heads`),
+    so that both products have the one-row form's shape and read the
+    leaves AS THEY LIE.  With
     the rows on an axis of their own the compiler re-lays the leaves out
     instead — a copy of the whole rung, K and V, every layer and round
     (seen in the compiled round of ``k_exaone_236b_a23b``: four 1.07 GB
     copies and 2.4 GB of temporaries a call where this form has 0.54
-    GB, PR 47)."""
+    GB, PR 47).  :func:`_ring_rows_attention` lays its rows out the same
+    way since PR 59: the two K-row forms share ONE layout."""
     import jax
     import jax.numpy as jnp
 
@@ -798,20 +875,15 @@ def _grouped_rows_attention(q, k_new, v_new, kv, ts, *, n_head, n_kv_head,
     rows = jnp.arange(S)[:, None]
     kv = {**_append(kv, "k", k_new, rows, at, heads),
           **_append(kv, "v", v_new, rows, at, heads)}
-    ok = jnp.arange(T)[None, None, :] <= pos[..., None]         # [S, K, T]
-    ok = jnp.broadcast_to(ok[:, None, :, None, :],
-                          (S, n_kv_head, K, rep, T)).reshape(
-                              S, n_kv_head, K * rep, T)
-    qg = (q * scale).astype(dt).reshape(S, K, n_kv_head, rep, heads[1])
-    qg = jnp.swapaxes(qg, 1, 2).reshape(S, n_kv_head, K * rep, heads[1])
+    ok = _mask_beside_heads(
+        jnp.arange(T)[None, None, :] <= pos[..., None], n_kv_head, rep)
+    qg = _beside_heads((q * scale).astype(dt), n_kv_head, rep)
     scores = jnp.einsum("sgrd,stgd->sgrt", qg, _read(kv, "k", heads),
                         preferred_element_type=jnp.float32)
     w = jax.nn.softmax(jnp.where(ok, scores, -1e9), axis=-1)
     ctx = jnp.einsum("sgrt,stgd->sgrd", w.astype(dt), _read(kv, "v", heads),
                      preferred_element_type=jnp.float32)
-    ctx = jnp.swapaxes(ctx.reshape(S, n_kv_head, K, rep, heads[1]),
-                       1, 2).reshape(q.shape)
-    return jnp.where(live[:, None, None], ctx, 0.0), kv
+    return jnp.where(live[:, None, None], _rows_apart(ctx, K), 0.0), kv
 
 
 def grouped_masked_decode_attention(q, k_new, v_new, kv, ts,
@@ -1861,6 +1933,155 @@ def _grouped(work, ts, q, k_cache, v_cache, *, n_head, n_kv_head, scale,
     return jnp.moveaxis(ctx, 2, 1).reshape(q.shape)
 
 
+#: bytes of ONE ring leaf a grid step of the ring kernel holds (its
+#: slots' rings whole; both leaves, twice for the copies in flight)
+_RING_STEP_BYTES = 1 << 20
+#: fresh rows the ring kernel scores on the vector unit, one after another
+_RING_FRESH = 8
+
+
+def ring_kernel_supported(kv, n_head: int, n_kv_head: int,
+                          fresh: int) -> bool:
+    """Whether :func:`ring_rows_decode_attention` takes ``fresh`` rows a
+    slot over the ring leaves ``kv``: unquantized, heads of whole lane
+    tiles, whole sublane tiles of ring rows, a slot's ring within a grid
+    step's bytes, a few fresh rows."""
+    if "k_scale" in kv or n_head % n_kv_head:
+        return False
+    _, rows, width = kv["k"].shape
+    size = np.dtype(kv["k"].dtype).itemsize
+    return ((width // n_kv_head) % _HEAD_LANES == 0
+            and rows % (32 // size) == 0
+            and rows * width * size <= _RING_STEP_BYTES
+            and 1 < fresh <= _RING_FRESH)
+
+
+def _ring_kernel(q_ref, ok_ref, kf_ref, vf_ref, k_ref, v_ref, o_ref, *,
+                 fresh, rep, window):
+    """A grid step's slots one after another, each K/V head from its own
+    lanes of the slot's ring, which is ONE block a leaf: ``q_ref`` ``[n,
+    G, R, Dh]`` (a head's ``fresh * rep`` query rows, fresh row major,
+    padded to a sublane tile), ``ok_ref`` ``[n, R, rows]`` (1.0 where
+    the query row may read the OLD ring's row), ``kf_ref`` / ``vf_ref``
+    ``[n, fresh, G * Dh]`` float32 (the fresh rows as the leaf will hold
+    them), ``k_ref`` / ``v_ref`` ``[n, rows, G * Dh]``, ``o_ref`` ``[n,
+    G, R, Dh]`` float32.  The old ring's two products ride the matrix
+    unit in the storage dtype; a fresh row is a multiply-and-sum over
+    lanes on the vector unit (exact: the products of storage-dtype values
+    in float32) under the static rule of which fresh rows a fresh row
+    sees; ONE float32 softmax over both."""
+    import jax
+    import jax.numpy as jnp
+
+    n, G, R, D = q_ref.shape
+    dt, f32 = k_ref.dtype, jnp.float32
+    # the fresh row each query row is (rows of padding: past the last)
+    of = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // rep
+    sees = [(of >= j) & (of - j < window) for j in range(fresh)]
+
+    def slot(i, carry):
+        ok = ok_ref[i] > 0.0
+        for g in range(G):
+            lanes = slice(g * D, (g + 1) * D)
+            q = q_ref[i, g]
+            s_old = jnp.where(ok, jax.lax.dot_general(
+                q, k_ref[i, :, lanes], (((1,), (1,)), ((), ())),
+                preferred_element_type=f32), _MASK)
+            qf = q.astype(f32)
+            s_new = [jnp.where(sees[j], jnp.sum(
+                qf * kf_ref[i, j:j + 1, lanes], axis=-1, keepdims=True),
+                _MASK) for j in range(fresh)]
+            top = functools.reduce(jnp.maximum, s_new,
+                                   s_old.max(-1, keepdims=True))
+            e_old = jnp.exp(s_old - top)
+            e_new = [jnp.exp(s - top) for s in s_new]
+            total = functools.reduce(jnp.add, e_new,
+                                     e_old.sum(-1, keepdims=True))
+            ctx = jnp.dot((e_old / total).astype(dt), v_ref[i, :, lanes],
+                          preferred_element_type=f32)
+            for j in range(fresh):
+                ctx = ctx + ((e_new[j] / total).astype(dt).astype(f32)
+                             * vf_ref[i, j:j + 1, lanes])
+            o_ref[i, g] = ctx
+        return carry
+
+    jax.lax.fori_loop(0, n, slot, 0)
+
+
+@functools.lru_cache(maxsize=None)
+def _ring_call():
+    import jax
+
+    return jax.jit(_ring_read, static_argnames=("window", "interpret"))
+
+
+def _ring_read(qg, fresh_k, fresh_v, k_ring, v_ring, old_ok, *, window,
+               interpret):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    S, G, rows_q, D = qg.shape
+    K, (_, L, Dkv) = fresh_k.shape[1], k_ring.shape
+    dt, f32 = k_ring.dtype, jnp.float32
+    size = jnp.dtype(dt).itemsize
+    rep = rows_q // K
+    sub = 32 // size
+    R = -(-rows_q // sub) * sub
+    pad = ((0, 0), (0, R - rows_q), (0, 0))
+    # slots a grid step: as many rings as its bytes hold
+    n = max(m for m in range(1, S + 1)
+            if S % m == 0
+            and (m == 1 or m * L * Dkv * size <= _RING_STEP_BYTES))
+    ok = jnp.pad(jnp.repeat(old_ok, rep, axis=1).astype(f32), pad)
+    by_slot = lambda *tail: pl.BlockSpec(
+        (n,) + tail, lambda i: (i,) + (0,) * len(tail))
+    return pl.pallas_call(
+        functools.partial(_ring_kernel, fresh=K, rep=rep, window=window),
+        out_shape=jax.ShapeDtypeStruct((S, G, R, D), f32),
+        grid=(S // n,),
+        in_specs=[by_slot(G, R, D), by_slot(R, L), by_slot(K, Dkv),
+                  by_slot(K, Dkv), by_slot(L, Dkv), by_slot(L, Dkv)],
+        out_specs=by_slot(G, R, D),
+        name="ring_rows_decode_attention",
+        interpret=interpret,
+    )(jnp.pad(qg, ((0, 0),) + pad), ok,
+      fresh_k.astype(f32).reshape(S, K, Dkv),
+      fresh_v.astype(f32).reshape(S, K, Dkv), k_ring, v_ring)[:, :, :rows_q]
+
+
+def ring_rows_decode_attention(q, k_new, v_new, kv, ts, *, n_head: int,
+                               n_kv_head: int, scale: float, window: int,
+                               interpret=False):
+    """The Pallas TPU kernel of ``K`` fresh rows a slot over RING leaves
+    (:func:`ring_kernel_supported` is the rule): the contract, the
+    layout and the mathematics of :func:`_ring_rows_attention`, whose
+    append it shares — the old ring of a slot read as ONE block a leaf
+    (``rows * n_kv_head * Dh``: 256 KB at ``k_exaone_236b_a23b``'s
+    widths), the leaves handed over AS THEY LIE.
+
+    Why a kernel where the plain XLA form copies nothing either: on the
+    chip the compiler fetches each leaf ahead in slices and its read
+    fusions take a 5-D view of that copy, so no instruction of the read
+    bears the leaf's shape — and by that shape the device trace's
+    readers find a window layer's work.  The kernel's call takes the two
+    leaves themselves.  It costs 0.166 ms a call at those widths (chip
+    run, PR 59: 404 GB/s, bound by the per-head products — a head's 128
+    x 128 keys meet 16 query rows; the XLA form's fusions took 0.05 ms a
+    layer and 0.015 of fetching).  One jitted entry point for every call
+    site (:func:`_kernel_call` says why)."""
+    RING_LOWERED.labels(form="rows").inc()
+    ROWS_LOWERED.labels(leaf="ring").inc()
+
+    def read(qg, fresh_k, fresh_v, kv, old_ok, new_ok):
+        return _ring_call()(qg, fresh_k, fresh_v, kv["k"], kv["v"], old_ok,
+                            window=int(window), interpret=interpret)
+
+    return _ring_rows_attention(
+        q, k_new, v_new, kv, ts, n_head=n_head, n_kv_head=n_kv_head,
+        scale=scale, window=int(window), read=read)
+
+
 def _gathered_block_attention(q, kv, ts, blocks, valid, *, n_head, n_kv_head,
                               scale, block):
     """The XLA form of the same read: a block gather, then the grouped
@@ -1980,7 +2201,10 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
     (``kv``: any one of those layers' leaves, all alike; a step whose
     layers hold sequence leaves AND ring leaves makes one ``attend`` for
     each).  ``window``: the leaves are RING leaves of that window (what
-    the builder allocated them as), read by the XLA form.  The one place
+    the builder allocated them as), read by the XLA form — but ``K``
+    fresh rows a slot on a TPU over leaves the ring kernel takes
+    (:func:`ring_kernel_supported`), which go through
+    :func:`ring_rows_decode_attention`.  The one place
     that chooses, from what it can observe (the backend, the leaves'
     dtype and shape, the head grouping, ``q.ndim``): a kernel when one
     exists for what the step is — the default backend a TPU, unquantized
@@ -2013,9 +2237,19 @@ def make_decode_attention(ts, kv, *, n_head: int, n_kv_head: int,
     # one fresh row over unquantized leaves, read as they lie
     lane = functools.partial(lane_masked_decode_attention, ts=ts,
                              n_head=n_head, n_kv_head=n_kv_head, scale=scale)
-    if window is not None:
-        return functools.partial(xla, window=int(window))
     tpu = jax.default_backend() == "tpu" and "k_scale" not in kv
+    if window is not None:
+        ring = functools.partial(xla, window=int(window))
+
+        def attend(q, k_new, v_new, kv):
+            if not (tpu and q.ndim == 3 and ring_kernel_supported(
+                    kv, n_head, n_kv_head, q.shape[1])):
+                return ring(q, k_new, v_new, kv)
+            return ring_rows_decode_attention(
+                q, k_new, v_new, kv, ts, n_head=n_head, n_kv_head=n_kv_head,
+                scale=scale, window=int(window))
+
+        return attend
     sizes = step_read_sizes(
         seq_len, width, kv["k"].dtype, n_head=n_head,
         n_kv_head=n_kv_head) if tpu else None

@@ -626,8 +626,9 @@ def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
     query head a K/V head over bf16 leaves whose heads are 64 wide or
     whose rung the block does not divide; int8 leaves, a rung the
     kernel's block does not divide over whole-lane-tile heads, K rows
-    where no kernel takes them, ring leaves and every CPU run keep the
-    grouped XLA form.  Every grouped-head step over sequence leaves
+    where no kernel takes them, ring leaves at one row (K rows over a
+    ring of whole-lane-tile heads take the ring's own kernel) and every
+    CPU run keep the grouped XLA form.  Every grouped-head step over sequence leaves
     counts itself by the path it took, a K-row one also by its leaf, a
     step of one query head a K/V head in a counter of its own; a ring
     step never counts a path."""
@@ -646,6 +647,11 @@ def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
     monkeypatch.setattr(
         da, "grouped_decode_attention",
         lambda *a, **k: seen.append("kernel") or kernel(
+            *a, interpret=True, **k))
+    ring_kernel = da.ring_rows_decode_attention
+    monkeypatch.setattr(
+        da, "ring_rows_decode_attention",
+        lambda *a, **k: seen.append("ring kernel") or ring_kernel(
             *a, interpret=True, **k))
 
     def rows_counted():
@@ -728,7 +734,12 @@ def test_make_decode_attention_takes_the_lane_form_only_where_it_pays(
     assert ragged == [1] and ungrouped() == [by_kernel + 3, by_xla + 4]
     ring = da.kv_leaves(2, 128, 2, 128, jnp.bfloat16, window=64)
     assert took(ring, (8, 2), window=64) == (["grouped"], nothing)
-    assert took(ring, (8, 2), rows=3, window=64)[1] == nothing
+    # K rows over a ring of whole-lane-tile heads: the ring's own kernel
+    assert took(ring, (8, 2), rows=3, window=64) == (["ring kernel"],
+                                                     nothing)
+    for kv in (da.kv_leaves(2, 128, 2, 64, jnp.bfloat16, window=64),
+               da.kv_leaves(2, 128, 2, 128, jnp.int8, window=64)):
+        assert took(kv, (8, 2), rows=3, window=64) == (["grouped"], nothing)
     # off the TPU a K-row call is the XLA form's, counted as such
     monkeypatch.undo()
     before, n_rows = _grouped_counts(), rows_counted()

@@ -3,7 +3,11 @@ is ``min(T, W)`` rows long, position ``p`` lives in row ``p mod W``, and
 its append-and-read equals a full-length sequence leaf read under a
 banded causal mask — below the window, at it, and twice past it; one
 fresh row and ``K`` fresh rows that straddle the wrap; idle rows; fp32
-and bf16 leaves."""
+and bf16 leaves; a toy geometry and ``k_exaone_236b_a23b``'s class of
+shape (eight query heads a K/V head of 128, ring = window = one lane
+tile)."""
+import collections
+
 import numpy as np
 import pytest
 
@@ -12,44 +16,49 @@ import jax.numpy as jnp
 
 from paddle_tpu import decode_attention as da
 
-S, G, REP, DH, W, T = 3, 2, 2, 8, 8, 32
+Geometry = collections.namedtuple("Geometry", "S G REP DH W T")
+TOY = Geometry(3, 2, 2, 8, 8, 32)
+#: the cell's class of shape: the ring one lane tile, heads of one too
+LANE_TILE = Geometry(3, 2, 8, 128, 128, 512)
+S, G, REP, DH, W, T = TOY
 H = G * REP
 SCALE = 1.0 / np.sqrt(DH)
 
 
-def banded_reference(q, k_all, v_all, pos, window):
+def banded_reference(q, k_all, v_all, pos, window, geo=TOY):
     """``q`` [S, K, H*DH] at positions ``pos`` [S, K] against the whole
     history ``k_all``, ``v_all`` [S, T, G*DH]: plain masked softmax, the
     query at p reading keys p - window + 1 .. p."""
     s, kq, _ = q.shape
     t = k_all.shape[1]
-    qh = q.reshape(s, kq, G, REP, DH) * SCALE
-    kh, vh = (x.reshape(s, t, G, DH) for x in (k_all, v_all))
+    qh = q.reshape(s, kq, geo.G, geo.REP, geo.DH) / np.sqrt(geo.DH)
+    kh, vh = (x.reshape(s, t, geo.G, geo.DH) for x in (k_all, v_all))
     sc = np.einsum("skgrd,stgd->skgrt", qh, kh)
     at = np.arange(t)[None, None, :]
     ok = (at <= pos[..., None]) & (pos[..., None] - at < window)
     sc = np.where(ok[:, :, None, None, :], sc, -1e9)
     w = np.exp(sc - sc.max(-1, keepdims=True))
     w = w / w.sum(-1, keepdims=True)
-    return np.einsum("skgrt,stgd->skgrd", w, vh).reshape(s, kq, H * DH)
+    return np.einsum("skgrt,stgd->skgrd", w, vh).reshape(s, kq, -1)
 
 
-def history(rng, dtype):
+def history(rng, dtype, geo=TOY):
     """K/V rows of every position, rounded as the leaf stores them."""
-    k, v = (rng.randn(S, T, G * DH).astype("float32") for _ in range(2))
+    k, v = (rng.randn(geo.S, geo.T, geo.G * geo.DH).astype("float32")
+            for _ in range(2))
     return tuple(np.asarray(jnp.asarray(x, dtype).astype(jnp.float32))
                  for x in (k, v))
 
 
-def ring_filled_to(k_all, v_all, ts, dtype):
+def ring_filled_to(k_all, v_all, ts, dtype, geo=TOY):
     """Ring leaves as ``ts`` one-token steps would have left them."""
-    kv = da.kv_leaves(S, T, G, DH, dtype, window=W)
-    assert kv["k"].shape == (S, W, G * DH)
-    k, v = np.zeros((S, W, G * DH), "float32"), np.zeros(
-        (S, W, G * DH), "float32")
-    for s in range(S):
+    kv = da.kv_leaves(geo.S, geo.T, geo.G, geo.DH, dtype, window=geo.W)
+    shape = (geo.S, geo.W, geo.G * geo.DH)
+    assert kv["k"].shape == shape
+    k, v = np.zeros(shape, "float32"), np.zeros(shape, "float32")
+    for s in range(geo.S):
         for p in range(max(ts[s], 0)):
-            k[s, p % W], v[s, p % W] = k_all[s, p], v_all[s, p]
+            k[s, p % geo.W], v[s, p % geo.W] = k_all[s, p], v_all[s, p]
     return {"k": jnp.asarray(k, dtype), "v": jnp.asarray(v, dtype)}
 
 
@@ -107,37 +116,145 @@ def test_one_fresh_row_equals_the_banded_mask_over_the_whole_history(
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
                                        (jnp.bfloat16, 3e-2)])
-@pytest.mark.parametrize("ts,rows", [([0, 3, 6], 4),      # below, to the wrap
-                                     ([5, 6, 13], 5),     # straddle the wrap
-                                     ([14, -1, 21], 6),   # past it; an idle row
-                                     ([2, 9, 17], 12)])   # more rows than ring
+@pytest.mark.parametrize("geo,ts,rows", [
+    (TOY, [0, 3, 6], 4),                # below, to the wrap
+    (TOY, [5, 6, 13], 5),               # straddle the wrap
+    (TOY, [14, -1, 21], 6),             # past it; an idle row
+    (TOY, [2, 9, 17], 12),              # more rows than ring
+    (LANE_TILE, [126, 127, 300], 2),    # to the wrap, over it, past it
+    (LANE_TILE, [255, -1, 383], 2),     # over the wrap twice; an idle row
+    (LANE_TILE, [5, 200, 130], 130)],   # more rows than ring
+    ids=["toy-below", "toy-straddle", "toy-idle", "toy-more_rows_than_ring",
+         "lane_tile-straddle", "lane_tile-idle",
+         "lane_tile-more_rows_than_ring"])
 def test_fresh_rows_read_the_old_ring_and_themselves_before_overwriting(
-        ts, rows, dtype, tol):
+        geo, ts, rows, dtype, tol):
     rng = np.random.RandomState(5)
-    k_all, v_all = history(rng, dtype)
+    k_all, v_all = history(rng, dtype, geo)
     ts = np.asarray(ts, np.int32)
-    q = rng.randn(S, rows, H * DH).astype("float32")
-    kv = ring_filled_to(k_all, v_all, ts, dtype)
+    q = rng.randn(geo.S, rows, geo.G * geo.REP * geo.DH).astype("float32")
+    kv = ring_filled_to(k_all, v_all, ts, dtype, geo)
     pos = np.maximum(ts, 0)[:, None] + np.arange(rows)[None, :]
-    take = lambda x: np.stack([x[s, pos[s]] for s in range(S)])
+    take = lambda x: np.stack([x[s, pos[s]] for s in range(geo.S)])
     before = da.RING_LOWERED.labels(form="rows").value
     ctx, out = da.grouped_masked_decode_attention(
         jnp.asarray(q), jnp.asarray(take(k_all)), jnp.asarray(take(v_all)),
-        kv, jnp.asarray(ts), n_head=H, n_kv_head=G, scale=SCALE, window=W)
+        kv, jnp.asarray(ts), n_head=geo.G * geo.REP, n_kv_head=geo.G,
+        scale=1.0 / np.sqrt(geo.DH), window=geo.W)
     assert da.RING_LOWERED.labels(form="rows").value == before + 1
-    want = banded_reference(q, k_all, v_all, pos, W)
-    for s in range(S):
+    want = banded_reference(q, k_all, v_all, pos, geo.W, geo)
+    for s in range(geo.S):
         if ts[s] < 0:
             assert not np.asarray(ctx[s]).any()
+            np.testing.assert_array_equal(np.asarray(out["k"][s], "float32"),
+                                          np.asarray(kv["k"][s], "float32"))
             continue
         np.testing.assert_allclose(np.asarray(ctx[s]), want[s], atol=tol,
                                    rtol=tol)
         # afterwards the ring holds the last W positions, each in its row
         end = ts[s] + rows
-        for p in range(max(end - W, 0), end):
+        for p in range(max(end - geo.W, 0), end):
             np.testing.assert_allclose(
-                np.asarray(out["k"][s, p % W], "float32"), k_all[s, p],
+                np.asarray(out["k"][s, p % geo.W], "float32"), k_all[s, p],
                 atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("ts,rows", [
+    ([126, 127, 300], 2),               # to the wrap, over it, past it
+    ([255, -1, 383], 2),                # over the wrap twice; an idle row
+    ([0, 1, 64], 2),                    # an empty ring, one row, half of it
+    ([127, -1, 500], 3)],               # three rows a slot: padded rows
+    ids=["straddle", "idle", "young", "three_rows"])
+def test_the_ring_kernel_equals_the_banded_mask_and_the_xla_read(
+        ts, rows, dtype, tol):
+    """:func:`ring_rows_decode_attention` (interpret mode) at the cell's
+    class of shape: the banded reference within the dtype's limit, the
+    XLA read of the same rows far closer (one mathematics, another order
+    of sums), the rings equal bit for bit."""
+    geo = LANE_TILE
+    rng = np.random.RandomState(11)
+    k_all, v_all = history(rng, dtype, geo)
+    ts = np.asarray(ts, np.int32)
+    q = rng.randn(geo.S, rows, geo.G * geo.REP * geo.DH).astype("float32")
+    kv = ring_filled_to(k_all, v_all, ts, dtype, geo)
+    pos = np.maximum(ts, 0)[:, None] + np.arange(rows)[None, :]
+    take = lambda x: np.stack([x[s, pos[s]] for s in range(geo.S)])
+    args = (jnp.asarray(q), jnp.asarray(take(k_all)),
+            jnp.asarray(take(v_all)), kv, jnp.asarray(ts))
+    kw = dict(n_head=geo.G * geo.REP, n_kv_head=geo.G,
+              scale=1.0 / np.sqrt(geo.DH), window=geo.W)
+    assert da.ring_kernel_supported(kv, kw["n_head"], geo.G, rows)
+    counted = [da.RING_LOWERED.labels(form="rows"),
+               da.ROWS_LOWERED.labels(leaf="ring")]
+    before = [c.value for c in counted]
+    ctx, out = da.ring_rows_decode_attention(*args, interpret=True, **kw)
+    assert [c.value for c in counted] == [b + 1 for b in before]
+    xla, xla_out = da.grouped_masked_decode_attention(*args, **kw)
+    np.testing.assert_allclose(np.asarray(ctx), np.asarray(xla), atol=2e-6)
+    for leaf in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(out[leaf], "float32"),
+                                      np.asarray(xla_out[leaf], "float32"))
+    want = banded_reference(q, k_all, v_all, pos, geo.W, geo)
+    for s in range(geo.S):
+        if ts[s] < 0:
+            assert not np.asarray(ctx[s]).any()
+            continue
+        np.testing.assert_allclose(np.asarray(ctx[s]), want[s], atol=tol,
+                                   rtol=tol)
+
+
+@pytest.mark.parametrize("what,geo,dtype,rows,takes", [
+    ("the cell's class of shape", LANE_TILE, jnp.bfloat16, 2, True),
+    ("float32 leaves", LANE_TILE, jnp.float32, 2, True),
+    ("one fresh row: the step form's", LANE_TILE, jnp.bfloat16, 1, False),
+    ("more fresh rows than the vector unit takes", LANE_TILE, jnp.bfloat16,
+     130, False),
+    ("heads narrower than a lane tile", TOY, jnp.float32, 2, False)],
+    ids=["lane_tile-bf16", "lane_tile-fp32", "one_row", "many_rows",
+         "toy"])
+def test_the_ring_kernel_takes_whole_lane_tile_heads_and_a_few_rows(
+        what, geo, dtype, rows, takes):
+    kv = da.kv_leaves(geo.S, geo.T, geo.G, geo.DH, dtype, window=geo.W)
+    assert da.ring_kernel_supported(
+        kv, geo.G * geo.REP, geo.G, rows) == takes, what
+
+
+def test_int8_ring_leaves_are_not_the_ring_kernels():
+    kv = da.kv_leaves(LANE_TILE.S, LANE_TILE.T, LANE_TILE.G, LANE_TILE.DH,
+                      jnp.int8, window=LANE_TILE.W)
+    assert not da.ring_kernel_supported(
+        kv, LANE_TILE.G * LANE_TILE.REP, LANE_TILE.G, 2)
+
+
+def test_a_rounds_window_layers_share_one_trace_of_the_ring_kernel(
+        monkeypatch):
+    """The layers of a round (``tools/time_ring_rows.py``'s program, at
+    its rehearsal's shape) go through ONE jitted entry point: the
+    kernel's body is traced once, whatever the layers."""
+    import functools
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "time_ring_rows", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "time_ring_rows.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    traced, real = [], da._ring_read
+    monkeypatch.setattr(da, "_ring_read", lambda *a, **k: (
+        traced.append(1), real(*a, **k))[1])
+    da._ring_call.cache_clear()
+    try:
+        shape = tool.REHEARSAL
+        assert shape[6] > 1                     # layers a pass
+        f, _ = tool.program(functools.partial(
+            da.ring_rows_decode_attention, interpret=True), shape, 2)
+        jax.make_jaxpr(f)(*tool.inputs(shape))
+        assert len(traced) == 1
+    finally:
+        da._ring_call.cache_clear()
 
 
 def test_steps_through_the_ring_equal_steps_through_a_full_leaf():

@@ -162,9 +162,12 @@ def test_a_rejected_round_leaves_every_ring_row_a_later_query_reads():
             for p in range(max(0, pos + 2 - WINDOW), pos + 1):
                 for leaf in ("k", "v"):
                     # two rows a call against one: another order of sums
+                    # (the verify scores the old ring and its fresh rows
+                    # apart since PR 59: the worst row reads 1.24e-5,
+                    # where one run of keys read 8.6e-6)
                     np.testing.assert_allclose(
                         np.asarray(la[leaf])[0, p % WINDOW],
-                        np.asarray(lb[leaf])[0, p % WINDOW], atol=1e-5)
+                        np.asarray(lb[leaf])[0, p % WINDOW], atol=2e-5)
                     checked += 1
     assert checked > 100
 

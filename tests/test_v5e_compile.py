@@ -64,16 +64,21 @@ def _chunk_tool():
         yield tool, root
 
 
-def _compiled_chunk(config, layers, counters):
+def _compiled_chunk(config, layers, counters, kind="chunk"):
     """The slot pool's ``chunk`` of ``config`` (its one rung pair, the
-    published widths, the first ``layers`` of its cut) compiled ONCE:
-    ``(text, memory analysis, counted)`` — ``counted`` what the trace
-    added to ``counters`` (``{key: a counter's child}``)."""
+    published widths, the first ``layers`` of its cut; ``kind``: another
+    of the tool's programs) compiled ONCE: ``(text, memory analysis,
+    counted)`` — ``counted`` what the trace added to ``counters``
+    (``{key: a counter's child}``) and, under ``"relayouts"``, the tool's
+    counts of copies of a matrix or a cache leaf (a step, once a call)."""
     with _chunk_tool() as (tool, root):
         before = {k: c.value for k, c in counters.items()}
-        compiled = tool.lowered_chunk(root, config, layers=layers).compile()
+        lowered = tool.lowered_chunk(root, config, kind, layers)
+        compiled = lowered.compile()
+        text = compiled.as_text()
         counted = {k: c.value - before[k] for k, c in counters.items()}
-    return compiled.as_text(), compiled.memory_analysis(), counted
+        counted["relayouts"] = tool.relayouts(lowered, text)
+    return text, compiled.memory_analysis(), counted
 
 
 def test_kernel_compiles_for_v5e_at_gpt1_widths(one_chip):
@@ -648,6 +653,44 @@ def test_solar_open2_chunk_takes_the_kernel_with_a_decay_a_channel_on_v5e(
     assert mem.temp_size_in_bytes < 0.5e9
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.generated_code_size_in_bytes) < 13.5e9
+
+
+def test_k_row_ring_read_copies_no_ring_and_no_weight_on_v5e(one_chip):
+    """The self-drafting round of ``k_exaone_236b_a23b`` at its published
+    widths and the cell's own depth (128 slots, rings of 128 rows, 64
+    query heads over 8 K/V heads of 128, ``d_model`` 6144, K = 2; the
+    whole 5-layer cut — four window layers around the global one — and
+    the module): each window block reads both ``bf16[128,128,1024]`` ring
+    leaves AS THEY LIE through ONE ``ring_rows_decode_attention`` custom
+    call that takes the two leaves themselves as operands — the K rows
+    beside the query heads of their K/V head, the old ring and the fresh
+    rows scored apart — and nothing else but the appends (and the
+    compiler's own fetch of a leaf ahead of its call) reads a ring leaf;
+    the compiled round holds no copy of a ring leaf or of an
+    ``attn_q`` (``[6144,8192]``) or ``attn_k`` (``[6144,1024]``) matrix
+    either way round (the tool's count, from the program's own shapes)
+    and no run of ring + K = 130 keys in any tensor.  Before PR 59 the
+    same program held two copies a window layer of the ring leaves, the
+    130-row run re-laid by heads, and a transposed copy of both matrices
+    in every layer fed by a block before it: 16."""
+    import re
+
+    text, _, counted = _compiled_chunk("k_exaone_236b_a23b", 5, {
+        "rows": da.RING_LOWERED.labels(form="rows"),
+        "ring": da.ROWS_LOWERED.labels(leaf="ring")}, kind="spec_chunk")
+    assert counted["rows"] == counted["ring"] == 4      # one a window layer
+    assert counted["relayouts"] == (0, 0)     # a step, once a call
+    assert not re.search(r"\[(?:\d+,)*130(?:,\d+)*\]", text)
+    reads = _reads_of(text, "bf16[128,128,1024]")
+    calls = [r for r in reads if "ring_rows_decode_attention" in r]
+    assert len(calls) == 4 and all(
+        "tpu_custom_call" in c and c.count("bf16[128,128,1024]") >= 2
+        for c in calls), calls
+    # the compiler may fetch a leaf ahead of its call (slices of it)
+    others = [r for r in reads if r not in calls and " slice-start(" not in r]
+    assert len(others) == 8 and all(    # the append: a row a leaf
+        " scatter(" in r or " fusion(" in r or "dynamic-update-slice(" in r
+        for r in others), others
 
 
 def test_lfm2_chunk_reads_its_64_lane_heads_by_the_kernel_on_v5e(one_chip):

@@ -34,6 +34,19 @@ step does not hold its weights in the dtype of their products.
 the tool builds THAT step outside the program and hoists what it closes
 over, as the pool does; ``falcon_h1_34b`` and ``minicpm_sala`` multiply
 weights as stored: 0, and their text was equal at PR 35 and its parent.
+
+It also counts the ``copy`` instructions whose operand is shaped like
+one of the program's matrices (either way round) or like a leaf of its
+cache (:func:`relayouts`), so that a comparison of two texts NAMES a
+relayout: those the program repeats every step (inside its step loop, or
+anywhere where it has none), and apart those the compiler hoisted out of
+the loop (once a call).  ``k_exaone_236b_a23b --kind spec_chunk
+--layers 5``: 16 a step at PR 59's parent (both ring leaves of each of
+four window layers, and ``attn_q`` ``[6144,8192]`` and ``attn_k``
+``[6144,1024]`` transposed in each of the four layers fed by a block
+before them) -> 0 since.  ``falcon_h1_34b``: 0 a step, 2 once a call;
+``gpt1_117m --kind seat_prefill``: 2 a step (inside the scanned body of
+its blocks), 1 once a call.
 """
 import argparse
 import base64
@@ -334,6 +347,52 @@ def weight_casts(lowered, text: str) -> int:
         r"= bf16\[(\d+),(\d+)\]\S* convert\(", text))
 
 
+def relayouts(lowered, text: str):
+    """``(a step, once a call)``: how many ``copy`` instructions of the
+    compiled program re-lay a whole operand shaped like one of the
+    program's matrices (either way round: a transposed copy bears the
+    dimensions swapped) or like a leaf of its cache — what a product or
+    a view costs that asks for another layout than the one the operand
+    is stored in.  ``a step``: inside the program's step loop (the body
+    of a ``while`` that carries the cache, and what it calls), or
+    anywhere in a program that has none — repeated every step; ``once a call``: hoisted out of the
+    loop by the compiler.  A ``copy`` inside a fusion is a relayout on
+    the fly and is counted too; ``temp_size_in_bytes`` says whether it
+    was materialised."""
+    import jax
+
+    consts, weights, state = lowered.args_info[0][:3]
+    shaped = {tuple(sorted(a.shape)) for a in jax.tree.leaves(
+        (consts, weights)) if len(a.shape) >= 2}
+    shaped |= {tuple(sorted(a.shape)) for a in jax.tree.leaves(
+        state["cache"]) if len(a.shape) >= 3}
+    copies, calls, inside = {}, {}, None    # by computation
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            inside = head.group(1)
+            copies[inside], calls[inside] = 0, set()
+        elif inside:
+            calls[inside].update(re.findall(r"%[\w.\-]+", line))
+            made = re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line)
+            copies[inside] += bool(made) and tuple(sorted(
+                int(n) for n in made.group(1).split(","))) in shaped
+    # the step loop: a ``while`` that carries the cache
+    carried = ["[%s]" % ",".join(map(str, a.shape))
+               for a in jax.tree.leaves(state["cache"]) if len(a.shape) >= 3]
+    looped = {body for typ, body in re.findall(
+        r"= (.*?) while\(.*?body=(%[\w.\-]+)", text)
+        if any(c in typ for c in carried)}
+    reach = list(looped)
+    for name in reach:      # what the loop's body calls, and so on
+        new = (calls[name] & copies.keys()) - looped
+        looped |= new
+        reach.extend(new)
+    step = sum(n for name, n in copies.items()
+               if name in looped or not looped)
+    return step, sum(copies.values()) - step
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("config", help="a name under benchmark/configs, "
@@ -368,12 +427,14 @@ def main():
         fh.write(text)
     kernels = [name for name in (
         "ragged_decode_attention", "grouped_decode_attention",
-        "block_sparse_decode_attention", "grouped_matmul",
+        "block_sparse_decode_attention", "ring_rows_decode_attention",
+        "grouped_matmul",
         "gated_delta_update") if name in text]
-    print("%s: %d bytes, %s, %d whole-matrix casts to bf16" % (
-        args.out, len(text),
-        " + ".join(kernels) + " kernel" if kernels else "no kernel",
-        weight_casts(lowered, text)))
+    print("%s: %d bytes, %s, %d whole-matrix casts to bf16, %d copies of a "
+          "matrix or a cache leaf a step (%d more once a call)" % (
+              (args.out, len(text),
+               " + ".join(kernels) + " kernel" if kernels else "no kernel",
+               weight_casts(lowered, text)) + relayouts(lowered, text)))
 
 
 if __name__ == "__main__":
