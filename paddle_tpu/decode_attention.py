@@ -172,7 +172,9 @@ __all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
            "RING_LOWERED", "GROUPED_LOWERED", "UNGROUPED_LOWERED",
            "latent_leaves", "append_latent_rows",
            "selected_latent_attention", "masked_latent_attention",
-           "pad_lanes", "LATENT_LOWERED", "INDEX_SELECT_LOWERED"]
+           "pad_lanes", "LATENT_LOWERED", "INDEX_SELECT_LOWERED",
+           "dense_latent_attention", "dense_latent_positions_touched",
+           "DENSE_LATENT_BLOCK", "divisor_block"]
 
 BLOCK_SPARSE_LOWERED = _registry.REGISTRY.counter(
     "block_sparse_lowered_total",
@@ -224,7 +226,10 @@ LATENT_LOWERED = _registry.REGISTRY.counter(
     "reads of the positions named for a row over a LATENT leaf lowered "
     "(traced into a program or run eagerly), by the lowering chosen: xla "
     "(a gather of the named rows and a masked softmax over them, "
-    "absorbed: the leaf is never expanded to heads)", ("path",))
+    "absorbed: the leaf is never expanded to heads); and DENSE reads of "
+    "every live position for K fresh rows (dense_latent_attention): "
+    "dense_xla (the rung walked in key blocks under one running softmax, "
+    "up to the pool's longest live context)", ("path",))
 
 INDEX_SELECT_LOWERED = _registry.REGISTRY.counter(
     "decode_attention_index_select_lowered_total",
@@ -1106,12 +1111,14 @@ def _whole_tiles(lanes: int) -> int:
     return -(-int(lanes) // _LANE_TILE) * _LANE_TILE
 
 
-def latent_leaves(n_rows: int, seq_len: int, d_latent: int, d_index: int,
+def latent_leaves(n_rows: int, seq_len: int, d_latent: int, d_index,
                   dtype):
     """One latent-attention layer's zeroed leaves: ``latent`` ``[n_rows,
     seq_len, lanes]`` (the compressed row all heads share, its rotated
-    lanes last) and ``index_k`` ``[n_rows, seq_len, lanes]`` (the
-    scorer's key), in ``dtype``; every leaf's sequence axis is
+    lanes last) and — unless ``d_index`` is None: a layer that reads
+    every live position keeps no scorer's key — ``index_k`` ``[n_rows,
+    seq_len, lanes]`` (the scorer's key), in ``dtype``; every leaf's
+    sequence axis is
     :data:`KV_SEQ_AXIS`.  ``lanes`` is the width rounded UP to whole
     128-lane tiles, the rest zeros (576 -> 640): the chip pads a row to
     whole tiles anyway, and a leaf DECLARED with a ragged last tile is
@@ -1123,10 +1130,12 @@ def latent_leaves(n_rows: int, seq_len: int, d_latent: int, d_index: int,
     the other's lanes."""
     import jax.numpy as jnp
 
-    return {"latent": jnp.zeros((n_rows, seq_len, _whole_tiles(d_latent)),
-                                dtype),
-            "index_k": jnp.zeros((n_rows, seq_len, _whole_tiles(d_index)),
-                                 dtype)}
+    out = {"latent": jnp.zeros((n_rows, seq_len, _whole_tiles(d_latent)),
+                               dtype)}
+    if d_index is not None:
+        out["index_k"] = jnp.zeros((n_rows, seq_len, _whole_tiles(d_index)),
+                                   dtype)
+    return out
 
 
 def pad_lanes(x, lanes: int):
@@ -1142,19 +1151,23 @@ def pad_lanes(x, lanes: int):
 
 def append_latent_rows(kv, latent_new, index_new, ts):
     """A latent layer's leaves with the fresh rows appended in place:
-    ``latent_new`` ``[S, d_latent]``, ``index_new`` ``[S, d_index]``, one
-    a slot at ``ts`` (idle slots, ``ts < 0``, are not written; a row at
-    or past the rung's end is dropped); each is zero-padded to its leaf's
-    whole tiles."""
+    ``latent_new`` ``[S, d_latent]``, ``index_new`` ``[S, d_index]``
+    (None where the layer keeps no ``index_k`` leaf), one a slot at
+    ``ts``; or ``[S, K, ...]``: row ``j`` at ``ts + j`` (idle slots,
+    ``ts < 0``, are not written; a row at or past the rung's end is
+    dropped); each is zero-padded to its leaf's whole tiles."""
     import jax.numpy as jnp
 
     S, T, _ = kv["latent"].shape
-    at = jnp.where(ts >= 0, ts, T)          # out of range: dropped
-    rows = jnp.arange(S)
+    rows, live, at = jnp.arange(S), ts >= 0, ts
+    if latent_new.ndim == 3:
+        rows, live = rows[:, None], live[:, None]
+        at = ts[:, None] + jnp.arange(latent_new.shape[1])[None, :]
+    at = jnp.where(live, at, T)             # out of range: dropped
     return {name: _append(kv, name, pad_lanes(new, kv[name].shape[2]),
                           rows, at, None)[name]
             for name, new in (("latent", latent_new),
-                              ("index_k", index_new))}
+                              ("index_k", index_new)) if new is not None}
 
 
 def _latent_softmax(s, ok, vals, d_value):
@@ -1207,9 +1220,10 @@ def selected_latent_attention(q, kv, ts, sel, valid, *, d_value: int,
 
 
 def masked_latent_attention(q, kv, allowed, *, d_value: int, scale: float):
-    """The selected read's contract as a plain masked softmax over the
-    WHOLE rung: slot ``s`` reads the positions ``allowed`` ``[S, T]``
-    marks.  The parity reference of :func:`selected_latent_attention`
+    """A latent read's contract as a plain masked softmax over the WHOLE
+    rung: slot ``s`` reads the positions ``allowed`` ``[S, T]`` marks.
+    The parity reference of :func:`selected_latent_attention` and, a row
+    at a time, of :func:`dense_latent_attention`
     (tests/test_latent_attention.py); no step takes it."""
     import jax.numpy as jnp
 
@@ -1218,6 +1232,96 @@ def masked_latent_attention(q, kv, allowed, *, d_value: int, scale: float):
     s = jnp.einsum("shd,std->sht", q, leaf,
                    preferred_element_type=jnp.float32)
     return _latent_softmax(s, allowed, leaf, d_value)
+
+
+#: positions of the rung the dense latent read takes a turn of its walk
+DENSE_LATENT_BLOCK = 512
+
+
+def dense_latent_positions_touched(longest, rung: int,
+                                   block: int = DENSE_LATENT_BLOCK):
+    """Positions of a slot's rung :func:`dense_latent_attention` reads
+    and multiplies when the pool's longest live context is ``longest``
+    (positions, the fresh rows among them): whole key blocks up to it,
+    the same for EVERY slot — its host mirror, for a benchmark's share
+    of what was touched that was live."""
+    kb = divisor_block(int(rung), block)
+    return min(-(-int(longest) // kb) * kb, int(rung))
+
+
+def divisor_block(n: int, block: int) -> int:
+    """The largest block of at most ``block`` positions that divides a
+    rung of ``n`` (``block`` itself at every real size)."""
+    kb = min(int(block), n)
+    while n % kb:
+        kb -= 1                        # tiny test rungs: a divisor
+    return kb
+
+
+def dense_latent_attention(q, kv, ts, *, d_value: int, scale: float,
+                           key_block: int = DENSE_LATENT_BLOCK):
+    """Read EVERY live position for ``K`` fresh rows a slot, ABSORBED:
+    ``q`` ``[S, K, H, d_latent]`` float32 (each head's query already in
+    the latent space, its rotated lanes last), ``kv`` a layer's latent
+    leaves with this round's ``K`` rows in at ``ts .. ts + K - 1``
+    (:func:`append_latent_rows`), ``ts`` ``[S]`` the first fresh row's
+    position (``< 0``: an idle slot).  Row ``j`` reads the positions
+    ``<= ts + j``; scores are ``scale * q . row`` over the whole row, the
+    context the weighed sum of the rows' first ``d_value`` lanes: ``[S,
+    K, H, d_value]`` float32, zeros for an idle slot.  Products in the
+    storage dtype, float32 accumulation and softmax.
+
+    The one shared row a position is read once for all ``K * H`` queries
+    of its slot (a ``[K * H, lanes] x [lanes, block]`` product a slot:
+    128 heads over 1,152 bytes, the one attention here whose least work
+    is arithmetic and not bytes).  The rung is walked ``key_block``
+    positions at a time under ONE running softmax, up to the pool's
+    longest live context and no further, so no ``[S, K, H, T]`` score
+    tensor is ever written: a turn holds ``[S, K * H, key_block]``.
+    What lies past a slot's own context inside those blocks is
+    multiplied and masked (:func:`dense_latent_positions_touched`); a
+    kernel that reads each slot's live blocks alone is ROADMAP Queue 2
+    A 7."""
+    import jax
+    import jax.numpy as jnp
+
+    LATENT_LOWERED.labels(path="dense_xla").inc()
+    f32 = jnp.float32
+    leaf = kv["latent"]
+    S, T, lanes = leaf.shape
+    K, H = q.shape[1], q.shape[2]
+    kb = divisor_block(T, key_block)
+    # the K rows beside the heads: one product a slot and block
+    qs = pad_lanes((q * scale).astype(leaf.dtype), lanes).reshape(
+        S, K * H, lanes)
+    # the last position each query may read; an idle slot's: none
+    last = jnp.where(ts[:, None] >= 0,
+                     ts[:, None] + jnp.arange(K)[None, :], -1)      # [S, K]
+    last = jnp.repeat(last, H, axis=1)[:, :, None]          # [S, K * H, 1]
+
+    def body(i, carry):
+        m, l, acc = carry
+        at = i * kb
+        block = jax.lax.dynamic_slice(leaf, (0, at, 0), (S, kb, lanes))
+        s = jnp.einsum("sqd,std->sqt", qs, block,
+                       preferred_element_type=f32)
+        ok = (at + jnp.arange(kb))[None, None, :] <= last
+        s = jnp.where(ok, s, _MASK)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        alpha = jnp.exp(m - m_new)
+        pr = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "sqt,stc->sqc", pr.astype(leaf.dtype), block[..., :d_value],
+            preferred_element_type=f32)
+        return m_new, alpha * l + pr.sum(axis=-1), acc
+
+    n_blocks = (jnp.minimum(jnp.max(ts) + K, T) + kb - 1) // kb
+    _, l, acc = jax.lax.fori_loop(
+        0, n_blocks, body,
+        (jnp.full((S, K * H), _MASK, f32), jnp.zeros((S, K * H), f32),
+         jnp.zeros((S, K * H, d_value), f32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.reshape(S, K, H, d_value)
 
 
 def block_kernel_supported(kv, n_head: int, n_kv_head: int,

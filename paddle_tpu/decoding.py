@@ -31,9 +31,10 @@ K-wide twin ``make_transformer_lm_pooled_verify_fn``,
 prefill), ``make_routed_conv_lm_pooled_step_fn`` (layers that hold
 different leaves, routed experts, counts made on the device) and
 ``make_delta_hybrid_lm_pooled_step_fn`` (a recurrent state that is read
-before it is written) and ``make_latent_sparse_lm_pooled_step_fn``
-(latent leaves read through a learned per-row selection) — are made of
-the same parts:
+before it is written), ``make_latent_sparse_lm_pooled_step_fn``
+(latent leaves read through a learned per-row selection) and
+``make_latent_mtp_lm_pooled_step_fn`` (latent leaves read densely, K
+rows a slot, under a self-drafting round) — are made of the same parts:
 
 * ONE cache format, whatever the storage dtype (fp32, bf16, int8):
   ``paddle_tpu.decode_attention`` says what a K/V leaf is, appends the
@@ -81,6 +82,7 @@ __all__ = [
     "make_delta_hybrid_lm_pooled_step_fn",
     "make_kda_routed_lm_pooled_step_fn",
     "make_latent_sparse_lm_pooled_step_fn",
+    "make_latent_mtp_lm_pooled_step_fn",
     "Leaf", "PositionRead", "CacheSpec", "READ_KINDS", "declare", "spec_of",
     "normalize_kv_dtype",
     "random_transformer_lm_state",
@@ -1455,6 +1457,34 @@ def make_windowed_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
     return step_fn, make_cache, prefill_fn
 
 
+def _fresh_rows(ts, shape):
+    """``(ts_rows [N], pos_rows [N])`` of a ``shape`` = ``[S]`` or ``[S,
+    K]`` of fresh rows: row ``j`` of a live slot sits at ``ts + j``; an
+    idle slot's rows are ``< 0`` in ``ts_rows``."""
+    import jax.numpy as jnp
+
+    pos = jnp.maximum(ts, 0)
+    if len(shape) == 2:
+        pos = pos[:, None] + jnp.arange(shape[1])[None, :]
+        ts = jnp.where(ts[:, None] >= 0, pos, -1)
+    return ts.reshape(-1), pos.reshape(-1)
+
+
+def _counted_experts(cache, stats=None, module=None, *, n_sparse: int,
+                     n_mtp: int):
+    """A drafting builder's ``expert_stats`` ``[n_sparse + n_mtp, 4]``
+    advanced by the sparse layers' ``stats`` and the module's (None: as
+    they were)."""
+    import jax.numpy as jnp
+
+    zero = jnp.zeros(cache["expert_stats"].shape[1], jnp.int32)
+    add = list(stats) if stats is not None else [zero] * n_sparse
+    if n_mtp:
+        add.append(zero if module is None else module)
+    return (cache["expert_stats"] + jnp.stack(add) if add
+            else cache["expert_stats"])
+
+
 def make_mtp_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
                                        kv_dtype: str = "bf16", held=None,
                                        prefill_tokens: int = 512):
@@ -1554,16 +1584,6 @@ def make_mtp_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
         whole = cache["mtp"] if d.n_mtp else cache["layers"][global_at[0]]
         return whole["k"].shape[1]
 
-    def rows_of(ts, shape):
-        """``(ts_rows [N], pos_rows [N])`` of a ``shape`` = ``[S]`` or
-        ``[S, K]`` of fresh rows: row ``j`` of a live slot sits at ``ts +
-        j``; an idle slot's rows are ``< 0`` in ``ts_rows``."""
-        pos = jnp.maximum(ts, 0)
-        if len(shape) == 2:
-            pos = pos[:, None] + jnp.arange(shape[1])[None, :]
-            ts = jnp.where(ts[:, None] >= 0, pos, -1)
-        return ts.reshape(-1), pos.reshape(-1)
-
     def block(h, c, p, kind, dense, attend, shape, ts_rows, pos_rows):
         """One block over the fresh rows ``h`` ``[N, d_model]`` (``N`` =
         the product of ``shape``); returns ``(h, leaves, stats)``."""
@@ -1589,16 +1609,8 @@ def make_mtp_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
         return mr.linear(mr.rms_norm(h, W[name + "_final_norm"], d.eps),
                          W[name + "_head"])
 
-    def counted(cache, stats=None, module=None):
-        """``expert_stats`` advanced by the sparse layers' ``stats`` and
-        the module's (None: as they were)."""
-        zero = jnp.zeros(n_stats, jnp.int32)
-        add = (list(stats) if stats is not None
-               else [zero] * len(d.expert_layers))
-        if d.n_mtp:
-            add.append(zero if module is None else module)
-        return (cache["expert_stats"] + jnp.stack(add) if add
-                else cache["expert_stats"])
+    counted = functools.partial(_counted_experts,
+                                n_sparse=len(d.expert_layers), n_mtp=d.n_mtp)
 
     def forward(cache, tokens, ts):
         """The layers over ``tokens`` ``[S]`` (one fresh row a slot) or
@@ -1615,7 +1627,7 @@ def make_mtp_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
             attend[mr.WINDOW] = make_decode_attention(
                 ts, layers[window_at[0]], n_head=d.n_head,
                 n_kv_head=d.n_kv_head, scale=scale, window=d.window)
-        ts_rows, pos_rows = rows_of(ts, tokens.shape)
+        ts_rows, pos_rows = _fresh_rows(ts, tokens.shape)
         h = W[name + "_emb"][tokens.reshape(-1)].astype(f32)
         new_layers, stats = [], []
         for i, kind in enumerate(d.kinds):
@@ -1642,7 +1654,7 @@ def make_mtp_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
         with jax.named_scope(mr.MTP_MODULE_SCOPE):
             ts = jnp.minimum(ts, rung_of(cache) - 1)
             shape = next_tokens.shape
-            ts_rows, pos_rows = rows_of(ts, shape)
+            ts_rows, pos_rows = _fresh_rows(ts, shape)
             u = mr.module_input(
                 hidden.reshape(-1, d.d_model),
                 W[name + "_emb"][next_tokens.reshape(-1)].astype(f32),
@@ -2120,6 +2132,218 @@ def make_latent_sparse_lm_pooled_step_fn(state, cfg, name: str = "lm",
         leaves, prefill_fn=prefill_fn, reads=_ragged_kv_reads(kv) + (
             PositionRead("latent", lambda n: np.minimum(n, d.index_topk),
                          layers=d.n_layer),),
+        expert_stats=lambda cache: cache["expert_stats"],
+        n_expert=(d.n_expert if held is None
+                  else int(held[1]) - int(held[0]))))
+    return step_fn, make_cache, prefill_fn
+
+
+def make_latent_mtp_lm_pooled_step_fn(state, cfg, name: str = "lm",
+                                       kv_dtype: str = "bf16", held=None,
+                                       prefill_tokens: int = 512):
+    """The slot-pooled step, the K-wide verify, the multi-token-
+    prediction module's K-wide pass and the chunked prefill of a decoder
+    whose every block is multi-head LATENT attention read DENSELY (every
+    live position: no indexer), each branch between two norms, then a
+    dense SwiGLU or routed experts beside a shared expert under an
+    ungrouped, bias-free sigmoid router, with ONE module that drafts for
+    the model from its own last hidden state (``model_type:
+    pangu_ultra_moe``; the parts and the equations are
+    ``paddle_tpu.latent_mtp_lm``, which takes the latent projections
+    from ``paddle_tpu.latent_sparse_lm`` and the module's input from
+    ``paddle_tpu.mtp_routed_lm``; the expert layer
+    ``paddle_tpu.routed_experts``, the leaves and the dense read
+    ``paddle_tpu.decode_attention``).
+
+    Returns ``(step_fn, make_cache, prefill_fn)`` with the contract of
+    :func:`make_mtp_routed_lm_pooled_step_fn`, ``verify_fn`` and
+    ``mtp_fn`` (None without a module) on the spec as there.  ``state``:
+    weights under ``latent_mtp_lm.param_shapes(cfg, held=held)``,
+    multiplied in the dtype they are given (routers and norms float32);
+    ``held``: the contiguous range of experts whose matrices ``state``
+    holds.
+
+    The cache is ``{"layers": [...], "mtp": {...}, "expert_stats":
+    ...}``: every layer and the module ONE ``latent`` leaf ``[N, T,
+    kv_lora_rank + rope]`` in ``kv_dtype``, zero-padded to whole 128-lane
+    tiles (576 -> 640), NO ``index_k``; ``expert_stats`` ``[sparse
+    layers + 1, 4]`` int32 (``slot=False``), the module's expert layer
+    in the last row, counted as
+    :func:`make_mtp_routed_lm_pooled_step_fn` counts.
+
+    Step, verify and module are ABSORBED through ONE read: the ``K``
+    fresh rows (1 or 2) are appended at ``ts .. ts + K - 1`` and row
+    ``j`` reads every position ``<= ts + j`` of the leaf as it lies
+    (``decode_attention.dense_latent_attention``): the cache is never
+    expanded to heads.  ``prefill_fn(cache, row, tokens [C + 1], start,
+    n_valid)`` feeds slot ``row`` ``C = prefill_tokens`` prompt tokens
+    through every layer AND the module (``prefill_fn.lookahead`` = 1),
+    EXPANDED, a key block at a time, causal membership in the place of a
+    selection (``latent_sparse_lm.chunk_attend_expanded``); the chunk's
+    rows go through the expert layer as a step's rows do.  It equals
+    ``n_valid`` steps leaf for leaf, but for the summation order
+    (tests/test_latent_mtp_lm.py).
+
+    The spec's ``"latent"`` read (a query of context ``n`` reads all
+    ``n`` positions in each of the layers and the module) is for the
+    server's counters.  All leaves are sequence leaves: ``KVSlotPool``
+    serves ``prefix=True`` over this builder by snapshots, and a
+    self-drafting round over them.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import latent_mtp_lm as lm
+    from paddle_tpu import routed_experts as rx
+    from paddle_tpu.decode_attention import (append_latent_rows,
+                                             dense_latent_attention,
+                                             latent_leaves, pad_lanes)
+
+    d = lm.dims(cfg)
+    kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
+    W = {k: jnp.asarray(v) for k, v in state.items()}
+    C = int(prefill_tokens)
+    n_stats = len(rx.STAT_NAMES)
+    stat_rows = len(d.expert_layers) + d.n_mtp
+    p_mtp = lm.layer_prefix(name, lm.MTP_LAYER)
+    f32 = jnp.float32
+
+    def make_cache(n_rows: int, seq_len: int):
+        leaf = lambda: latent_leaves(n_rows, seq_len, d.d_latent, None, kv)
+        cache = {"layers": [leaf() for _ in range(d.n_layer)],
+                 "expert_stats": jnp.zeros((stat_rows, n_stats), jnp.int32)}
+        if d.n_mtp:
+            cache["mtp"] = leaf()
+        return cache
+
+    leaves = {"layers": [{"latent": Leaf(1)} for _ in range(d.n_layer)],
+              "expert_stats": Leaf(slot=False)}
+    if d.n_mtp:
+        leaves["mtp"] = {"latent": Leaf(1)}
+
+    def block(h, c, p, dense, shape, ts, ts_rows, pos_rows):
+        """One block over the fresh rows ``h`` ``[S * K, d_model]``;
+        returns ``(h, stats, leaves)``."""
+        n = h.shape[0]
+        with jax.named_scope(lm.LATENT_PROJECT_SCOPE):
+            x = lm.rms_norm(h, W[p + "input_norm"], d.eps)
+            _, qc, qr, row = lm.latent_inputs(x, W, p, pos_rows, d)
+            q = lm.absorb_queries(qc, qr, W, p, d)
+            kvs = append_latent_rows(
+                c, row.reshape(tuple(shape) + (-1,)), None, ts)
+        with jax.named_scope(lm.LATENT_ATTEND_SCOPE):
+            u = dense_latent_attention(
+                q.reshape(tuple(shape) + q.shape[1:]), kvs, ts,
+                d_value=d.d_c, scale=d.scale)
+            o = lm.attend_out(u.reshape(n, d.n_head, d.d_c), W, p, d)
+        h = lm.close_attention(h, o, W, p, d)
+        return lm.ffn_branch(h, W, p, dense, ts_rows, d, held) + (kvs,)
+
+    def head(h):
+        return lm.linear(lm.rms_norm(h, W[name + "_final_norm"], d.eps),
+                         W[name + "_head"])
+
+    counted = functools.partial(_counted_experts,
+                                n_sparse=len(d.expert_layers), n_mtp=d.n_mtp)
+
+    def clamp(cache, ts):
+        return jnp.minimum(ts, cache["layers"][0]["latent"].shape[1] - 1)
+
+    def forward(cache, tokens, ts):
+        """The layers over ``tokens`` ``[S, K]``: ``(logits [S, K, V],
+        hidden [S, K, d_model], cache)``."""
+        ts = clamp(cache, ts)
+        shape = tokens.shape
+        ts_rows, pos_rows = _fresh_rows(ts, shape)
+        h = W[name + "_emb"][tokens.reshape(-1)].astype(f32)
+        new_layers, stats = [], []
+        for i in range(d.n_layer):
+            h, st, kvs = block(h, cache["layers"][i],
+                               lm.layer_prefix(name, i), d.dense[i], shape,
+                               ts, ts_rows, pos_rows)
+            new_layers.append(kvs)
+            if st is not None:
+                stats.append(st)
+        out = dict(cache, layers=new_layers,
+                   expert_stats=counted(cache, stats))
+        by = tuple(shape) + (-1,)
+        return head(h).reshape(by), h.reshape(by), out
+
+    def step_fn(cache, tokens, ts):
+        logits, _, cache = forward(cache, tokens[:, None], ts)
+        return logits[:, 0], cache
+
+    def verify_fn(cache, tokens, ts):
+        with jax.named_scope(lm.SPEC_VERIFY_SCOPE):
+            return forward(cache, tokens, ts)
+
+    def mtp_fn(cache, hidden, next_tokens, ts):
+        with jax.named_scope(lm.MTP_MODULE_SCOPE):
+            ts = clamp(cache, ts)
+            shape = next_tokens.shape
+            ts_rows, pos_rows = _fresh_rows(ts, shape)
+            u = lm.module_input(
+                hidden.reshape(-1, d.d_model),
+                W[name + "_emb"][next_tokens.reshape(-1)].astype(f32),
+                W, p_mtp, d)
+            u, st, kvs = block(u, cache["mtp"], p_mtp, False, shape, ts,
+                               ts_rows, pos_rows)
+            out = dict(cache, mtp=kvs,
+                       expert_stats=counted(cache, None, st))
+            return head(u).reshape(tuple(shape) + (-1,)), out
+
+    def prefill_layer(c, h, p, dense, row, start, n_valid, pos, ts_q):
+        with jax.named_scope(lm.LATENT_PROJECT_SCOPE):
+            x = lm.rms_norm(h, W[p + "input_norm"], d.eps)
+            _, qc, qr, fresh = lm.latent_inputs(x, W, p, pos, d)
+        leaf = c["latent"]
+        lanes = leaf.shape[2]               # whole tiles: zero-padded
+        old = jax.lax.dynamic_slice(leaf, (row, start, 0), (1, C, lanes))[0]
+        new = jax.lax.dynamic_update_slice(
+            leaf, jnp.where((ts_q >= 0)[:, None],
+                            pad_lanes(fresh, lanes).astype(kv), old)[None],
+            (row, start, 0))
+        mine = jax.lax.dynamic_index_in_dim(new, row, 0, False)
+        # every query reads the positions 0 .. its own: causal membership
+        # where the indexed form hands a selection
+        member = jnp.arange(leaf.shape[1])[None, :] <= ts_q[:, None]
+        with jax.named_scope(lm.LATENT_ATTEND_SCOPE):
+            o = lm.linear(lm.chunk_attend_expanded(
+                qc, qr, mine, member, start + n_valid, W, p, d),
+                W[p + "attn_o"])
+        h = lm.close_attention(h, o, W, p, d)
+        return lm.ffn_branch(h, W, p, dense, ts_q, d, held)[0], {
+            "latent": new}
+
+    def prefill_fn(cache, row, tokens, start, n_valid):
+        with jax.named_scope(lm.PREFILL_CHUNK_SCOPE):
+            pos = start + jnp.arange(C)
+            ts_q = jnp.where(jnp.arange(C) < n_valid, pos, -1)
+            emb = W[name + "_emb"]
+            h = emb[tokens[:C]].astype(f32)
+            new_layers = []
+            for i in range(d.n_layer):
+                h, new = prefill_layer(cache["layers"][i], h,
+                                       lm.layer_prefix(name, i), d.dense[i],
+                                       row, start, n_valid, pos, ts_q)
+                new_layers.append(new)
+            out = dict(cache, layers=new_layers)
+            if d.n_mtp:
+                with jax.named_scope(lm.MTP_MODULE_SCOPE):
+                    u = lm.module_input(h, emb[tokens[1:]].astype(f32), W,
+                                        p_mtp, d)
+                    _, out["mtp"] = prefill_layer(
+                        cache["mtp"], u, p_mtp, False, row, start, n_valid,
+                        pos, ts_q)
+            return out
+
+    prefill_fn.chunk_tokens = C
+    prefill_fn.lookahead = 1 if d.n_mtp else 0
+    declare(make_cache, CacheSpec(
+        leaves, prefill_fn=prefill_fn, verify_fn=verify_fn,
+        mtp_fn=mtp_fn if d.n_mtp else None,
+        reads=_ragged_kv_reads(kv) + (
+            PositionRead("latent", lambda n: n, layers=d.n_layer + d.n_mtp),),
         expert_stats=lambda cache: cache["expert_stats"],
         n_expert=(d.n_expert if held is None
                   else int(held[1]) - int(held[0]))))

@@ -54,8 +54,9 @@ import numpy as np
 from paddle_tpu.hybrid_ssm import linear, rms_norm, swiglu
 from paddle_tpu.routed_experts import SIGMOID_BIAS, SILU
 
-__all__ = ["dims", "param_shapes", "random_state", "yarn_inv_freq",
-           "softmax_scale", "rotate", "latent_inputs", "index_inputs",
+__all__ = ["dims", "latent_dims", "param_shapes", "random_state",
+           "yarn_inv_freq", "softmax_scale", "rotate", "latent_inputs",
+           "index_inputs",
            "index_scores", "top_members", "select_positions",
            "absorb_queries", "attend_out", "chunk_select",
            "chunk_attend_expanded",
@@ -117,11 +118,16 @@ def softmax_scale(cfg) -> float:
     return float(width ** -0.5 * m * m)
 
 
-def dims(cfg) -> SimpleNamespace:
-    """The block's sizes and scalars from a ``deepseek_v32`` config dict
-    (the published key names).  ``n_routed_experts`` may count the
-    experts HELD here; the router's width is then
-    ``n_routed_experts_all``."""
+def latent_dims(cfg) -> SimpleNamespace:
+    """What every decoder of multi-head LATENT attention over a dense
+    SwiGLU or routed experts beside a shared expert has (the published
+    key names of the DeepSeek-V3 lineage), whatever reads its latent
+    rows: the sizes, the rotary's frequencies, the softmax scale and
+    what ``routed_experts.route`` / ``expert_layer`` read.
+    ``n_routed_experts`` may count the experts HELD here; the router's
+    width is then ``n_routed_experts_all``.  :func:`dims` adds the
+    lightning indexer's; ``latent_mtp_lm.dims`` the sandwich norms' and
+    the drafting module's."""
     o = SimpleNamespace(
         vocab=int(cfg["vocab_size"]), d_model=int(cfg["hidden_size"]),
         n_layer=int(cfg["num_hidden_layers"]),
@@ -130,9 +136,6 @@ def dims(cfg) -> SimpleNamespace:
         q_rank=int(cfg["q_lora_rank"]), d_c=int(cfg["kv_lora_rank"]),
         d_nope=int(cfg["qk_nope_head_dim"]),
         d_rope=int(cfg["qk_rope_head_dim"]), d_v=int(cfg["v_head_dim"]),
-        n_index_head=int(cfg["index_n_heads"]),
-        d_index=int(cfg["index_head_dim"]),
-        index_topk=int(cfg["index_topk"]),
         d_mlp=int(cfg["intermediate_size"]),
         d_expert=int(cfg["moe_intermediate_size"]),
         n_expert=int(cfg.get("n_routed_experts_all",
@@ -141,7 +144,7 @@ def dims(cfg) -> SimpleNamespace:
         n_shared=int(cfg.get("n_shared_experts", 0)),
         n_group=int(cfg.get("n_group", 1)),
         topk_group=int(cfg.get("topk_group", 1)),
-        eps=float(cfg.get("rms_norm_eps", 1e-6)), ln_eps=_LN_EPS,
+        eps=float(cfg.get("rms_norm_eps", 1e-6)),
         norm_topk=bool(cfg.get("norm_topk_prob", True)),
         routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
         inv_freq=yarn_inv_freq(cfg), scale=softmax_scale(cfg),
@@ -157,21 +160,34 @@ def dims(cfg) -> SimpleNamespace:
         raise ValueError("attention_bias is not supported")
     if int(cfg.get("moe_layer_freq", 1)) != 1:
         raise ValueError("only moe_layer_freq = 1 is supported")
-    if int(cfg.get("num_nextn_predict_layers", 0)):
-        raise ValueError("a multi-token-prediction module is not held: "
-                         "num_nextn_predict_layers must be 0")
     if o.n_expert % o.n_group or o.topk_group > o.n_group:
         raise ValueError("n_group must divide the experts and hold "
                          "topk_group")
     if o.n_group > 1 and o.n_expert // o.n_group < 2:
         raise ValueError("a group is scored by its two best experts")
-    if o.d_rope % 2 or o.d_rope > o.d_index:
-        raise ValueError("the rotated lanes must be even and fit an "
-                         "index head")
+    if o.d_rope % 2:
+        raise ValueError("the rotated lanes must be even")
     o.d_latent = o.d_c + o.d_rope
     o.d_qk = o.d_nope + o.d_rope
     o.dense = tuple(i < o.n_dense for i in range(o.n_layer))
     o.expert_layers = tuple(i for i in range(o.n_layer) if not o.dense[i])
+    return o
+
+
+def dims(cfg) -> SimpleNamespace:
+    """The block's sizes and scalars from a ``deepseek_v32`` config dict
+    (the published key names): :func:`latent_dims` and the lightning
+    indexer's."""
+    o = latent_dims(cfg)
+    o.n_index_head = int(cfg["index_n_heads"])
+    o.d_index = int(cfg["index_head_dim"])
+    o.index_topk = int(cfg["index_topk"])
+    o.ln_eps = _LN_EPS
+    if int(cfg.get("num_nextn_predict_layers", 0)):
+        raise ValueError("a multi-token-prediction module is not held: "
+                         "num_nextn_predict_layers must be 0")
+    if o.d_rope > o.d_index:
+        raise ValueError("the rotated lanes must fit an index head")
     return o
 
 
@@ -462,34 +478,43 @@ def select_positions(scores, ts, top_k: int):
     return sel, listed <= ts[:, None]
 
 
+def _per_head(form: str, x, w):
+    """``einsum(form, x, w)`` a head a batch: ``x`` rounded to ``w``'s
+    dtype, the products in it, float32 accumulation.  On the CPU both are
+    first widened to float32 — the same products exactly (a bf16 pair's
+    product is a float32), and XLA's CPU runtime has no bf16 x bf16 =
+    f32 BATCHED dot outside a loop body (a K-row round's sixteen rows at
+    the rehearsal's sizes: "Unsupported element type for DotThunk")."""
+    import jax
+    import jax.numpy as jnp
+
+    x = x.astype(w.dtype)
+    if jax.default_backend() == "cpu":
+        x, w = x.astype(jnp.float32), w.astype(jnp.float32)
+    return jnp.einsum(form, x, w, preferred_element_type=jnp.float32)
+
+
 def absorb_queries(qc, qr, w, p: str, d):
     """The absorbed queries ``[N, heads, kv_lora_rank + rope]`` float32:
     ``qA_i = qC_i W_uk,i`` beside ``qR_i``, to be scored against cache
     rows ``(c, kR)`` as they lie."""
     import jax.numpy as jnp
 
-    uk = w[p + "attn_uk"]
-    qa = jnp.einsum("nhd,hdc->nhc", qc.astype(uk.dtype), uk,
-                    preferred_element_type=jnp.float32)
-    return jnp.concatenate([qa, qr], axis=-1)
+    return jnp.concatenate(
+        [_per_head("nhd,hdc->nhc", qc, w[p + "attn_uk"]), qr], axis=-1)
 
 
 def attend_out(u, w, p: str, d):
     """``concat_i(u_i W_uv,i) W_o`` of the absorbed contexts ``u`` ``[N,
     heads, kv_lora_rank]``: ``[N, d_model]`` float32."""
-    import jax.numpy as jnp
-
-    uv = w[p + "attn_uv"]
-    o = jnp.einsum("nhc,hcd->nhd", u.astype(uv.dtype), uv,
-                   preferred_element_type=jnp.float32)
+    o = _per_head("nhc,hcd->nhd", u, w[p + "attn_uv"])
     return linear(o.reshape(u.shape[0], -1), w[p + "attn_o"])
 
 
 def _blocks(n: int, block: int) -> int:
-    kb = min(int(block), n)
-    while n % kb:
-        kb -= 1                        # tiny test rungs: a divisor
-    return kb
+    from paddle_tpu.decode_attention import divisor_block
+
+    return divisor_block(n, block)
 
 
 def chunk_select(qi, wi, keys, q_pos, n_keys, top_k: int,

@@ -1111,6 +1111,9 @@ class DecodeServer:
                 if self._prefix is not None:
                     pre_len, pre_kv = self._prefix.lookup(req.prompt)
                     probes.append(pre_len > 0)
+                    # a snapshot's last rows may have looked ahead at
+                    # another request's tokens: seated short of them
+                    pre_len = max(pre_len - pool.snapshot_lookahead, 0)
                 new_t = max(new_t, pool.len_policy.bucket_for(req.total_len))
                 if None not in slots:
                     new_s = pool.slot_policy.bucket_for(new_s + 1)

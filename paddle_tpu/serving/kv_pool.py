@@ -232,6 +232,17 @@ class KVSlotPool:
         #: prefix entries are device snapshots of a slot's whole cache
         #: row (recurrent leaves included), taken at a prefill boundary
         self.snapshots = self.prefix and self._prefill is not None
+        #: positions at a snapshot's END that are not a prefix's to
+        #: install: a prefill that feeds a drafting module wrote the
+        #: module's row at the boundary's last position from the token
+        #: AFTER the boundary (``prefill_fn.lookahead``), which the next
+        #: request need not share — it is seated that many positions
+        #: short of the boundary and writes those rows again, every
+        #: layer's (write-before-read: the same rows where nothing
+        #: looked ahead)
+        self.snapshot_lookahead = (
+            int(getattr(self._prefill, "lookahead", 0))
+            if self.snapshots else 0)
         self._admit_prefix_fn = (
             make_prefix_admit_fn(self._seat_fn, self._kv_decl,
                                  whole_rows=self.snapshots)

@@ -15,7 +15,7 @@ from paddle_tpu.serving.decode import DecodeServer
 
 
 # ---------------------------------------------------------------------------
-# the nine builders at their toy configs
+# the ten builders at their toy configs
 # ---------------------------------------------------------------------------
 LM_RUNG = 256
 
@@ -99,12 +99,19 @@ def _latent_sparse():
         prefill_tokens=t.CHUNK), t.V, 64, "fp32"
 
 
+def _latent_mtp():
+    import test_latent_mtp_lm as t
+
+    cfg = t.tiny_cfg()
+    return t._build(cfg, t.weights(cfg, seed=7)), t.V, 64, "fp32"
+
+
 BUILDERS = {
     "transformer_lm": _lm, "hybrid_ssm": _hybrid_ssm,
     "sparse_linear": _sparse_linear, "routed_conv": _routed_conv,
     "windowed_routed": _windowed_routed, "mtp_routed": _mtp_routed,
     "delta_hybrid": _delta_hybrid, "kda_routed": _kda_routed,
-    "latent_sparse": _latent_sparse}
+    "latent_sparse": _latent_sparse, "latent_mtp": _latent_mtp}
 
 
 # ---------------------------------------------------------------------------
@@ -263,9 +270,17 @@ def _mtp_self_draft():
     return built, vocab, t, kv, make_self_draft(built[1])
 
 
+def _latent_mtp_self_draft():
+    from paddle_tpu.serving.speculative import make_self_draft
+
+    built, vocab, t, kv = _latent_mtp()
+    return built, vocab, t, kv, make_self_draft(built[1])
+
+
 STORMS = dict(BUILDERS, transformer_lm_int8=_lm_int8,
               transformer_lm_draft=_lm_with_draft,
-              mtp_routed_self_draft=_mtp_self_draft)
+              mtp_routed_self_draft=_mtp_self_draft,
+              latent_mtp_self_draft=_latent_mtp_self_draft)
 
 #: ``metrics()["decode"]`` after :func:`_storm`, key by key in
 #: :data:`COUNTED`'s order, as the parent of PR 58 read them
@@ -273,6 +288,10 @@ RECORDED = {
     'delta_hybrid': (16, 3232, 1031, 4608, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0),
     'hybrid_ssm': (16, 3232, 1031, 4608, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0),
     'kda_routed': (16, 3232, 1031, 4608, 0, 0, 0, 0, 0, 0, 342, 297, 205, 192),
+    # the tenth builder's, as PR 60 first read them (a plain step is
+    # counted the module's leaf too: the rule is the round's)
+    'latent_mtp': (22, 8448, 3352, 12672, 0, 0, 0, 0, 13408, 13408, 528, 434, 208, 130),
+    'latent_mtp_self_draft': (48, 9216, 3352, 9216, 0, 0, 0, 0, 25000, 25000, 1416, 793, 432, 144),
     'latent_sparse': (22, 8448, 3352, 12672, 0, 0, 0, 0, 10056, 5532, 1056, 897, 217, 130),
     'mtp_routed': (22, 8448, 3352, 12672, 0, 0, 2064, 13408, 0, 0, 1056, 872, 408, 260),
     'mtp_routed_self_draft': (49, 7616, 3352, 9408, 0, 0, 3760, 25396, 0, 0, 2380, 1215, 823, 245),

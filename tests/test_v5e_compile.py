@@ -775,3 +775,39 @@ def test_a_latent_leaf_of_whole_lane_tiles_is_not_copied_on_v5e(
         assert temp <= 75_700_000       # what the sorting form held
         # the per-head index products stay inside their fusion
         assert not _reads_of(text, "f32[24,64,32768]")
+
+
+@pytest.mark.parametrize("kind,layers", [("spec_chunk", 5), ("prefill", 5)])
+def test_dense_latent_round_and_prefill_fit_v5e_and_copy_no_leaf(
+        one_chip, kind, layers):
+    """The self-drafting round and the chunked prefill of
+    ``openpangu_ultra_moe_718b`` at its published widths and the cell's
+    own depth (32 slots x 16384 positions, 128 heads over one 640-lane
+    row a position, ``d_model`` 7680, K = 2; the whole 5-layer cut and
+    the module): both fit 16 GB beside the pool and the snapshots, no
+    ``copy`` bears a latent leaf's shape, the round walks the rung in key
+    blocks of 512 — its scores are ``f32[32,256,512]`` and no tensor
+    holds a rung-wide score (``[32,2,128,16384]`` / ``[32,256,16384]``,
+    537 MB a layer) — and the dense read was lowered once a leaf."""
+    text, mem, counted = _compiled_chunk("openpangu_ultra_moe_718b", layers, {
+        "dense": da.LATENT_LOWERED.labels(path="dense_xla"),
+        "selected": da.LATENT_LOWERED.labels(path="xla")}, kind=kind)
+    copies = [line for line in text.splitlines() if " copy(" in line]
+    assert not any("[32,16384,640]" in line for line in copies), copies
+    assert "[32,16384,640]{1," not in text      # never sequence-minor
+    for scores in ("[32,2,128,16384]", "[32,256,16384]", "[32,128,16384]",
+                   "[512,128,16384]"):
+        assert scores not in text, scores
+    snapshots = 8 * 6 * 16384 * 640 * 2
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.generated_code_size_in_bytes + snapshots) < 15.0e9
+    pool = 32 * 6 * 16384 * 640 * 2
+    assert mem.alias_size_in_bytes >= pool
+    assert not counted["selected"]
+    if kind == "spec_chunk":
+        assert counted["dense"] == layers + 1   # every layer and the module
+        assert "f32[32,256,512]" in text
+        assert mem.temp_size_in_bytes < 0.1e9
+    else:
+        assert not counted["dense"]             # expanded, by key blocks
+        assert mem.temp_size_in_bytes < 0.5e9

@@ -200,6 +200,23 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
             return decoding.make_latent_sparse_lm_pooled_step_fn(
                 w, cfg, kv_dtype=sv["kv_dtype"], held=held,
                 prefill_tokens=sv["prefill_tokens"])[:2]
+    elif cfg["family"] == "pooled_latent_mtp_lm":
+        from paddle_tpu import latent_mtp_lm
+
+        # the first ``layers`` of the cut (2: the dense layer and a sparse
+        # one, each latent attention read densely; 5: the whole cut) and
+        # the module
+        cfg["num_hidden_layers"] = layers
+        held = tuple(cfg["experts_held"])
+        # as the family makes them: matrices bf16, norms and routers fp32
+        weights = {n: sd(shp, jnp.float32 if n.endswith(
+            latent_mtp_lm.FLOAT32_PARAMS) else jnp.bfloat16)
+            for n, shp in latent_mtp_lm.param_shapes(cfg, held=held).items()}
+
+        def build(w):
+            return decoding.make_latent_mtp_lm_pooled_step_fn(
+                w, cfg, kv_dtype=sv["kv_dtype"], held=held,
+                prefill_tokens=sv["prefill_tokens"])[:2]
     elif cfg["family"] == "pooled_delta_hybrid_lm":
         from paddle_tpu import delta_hybrid_lm
 
@@ -289,6 +306,18 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
         return pool_of(w, speculative=make_self_draft(
             build(w)[1]))._spec_chunk_fn(state)
 
+    def admit_prefix(w, state, mask, prompt, prompt_len, total_len, kv,
+                     prefix_len, spec_flag):
+        # one request seated over a snapshot, as the pool runs it for a
+        # builder with a chunked prefill under a self-drafting round
+        # (openpangu_ultra_moe_718b)
+        from paddle_tpu.serving.speculative import make_self_draft
+
+        return pool_of(w, prefix=True, speculative=make_self_draft(
+            build(w)[1]))._admit_prefix_fn(
+                state, mask, prompt, prompt_len, total_len, kv, prefix_len,
+                spec_flag)
+
     def seat_prefill(w, state, packed):
         # a turn's seats seated and fed their prompts, as the pool runs
         # it (KVSlotPool._seat_prefill_fn) for a builder that declares a
@@ -312,7 +341,7 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
         "prompt_len": sd((slots,), i32), "total_len": sd((slots,), i32),
         "active": sd((slots,), flag), "finished": sd((slots,), flag),
         "n_gen": sd((slots,), i32)}
-    if kind == "spec_chunk":
+    if kind in ("spec_chunk", "admit_prefix"):
         state.update(spec=sd((slots,), flag), draft=sd((slots,), i32),
                      proposals=sd((slots, seq_len), i32))
     # what the program closes over — the step's own weights where it
@@ -321,9 +350,19 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
     # being baked into the text
     more = ([sd((pool_of(weights)._packed_seats_size(slots, seq_len),), i32)]
             if kind == "seat_prefill" else [])
+    if kind == "admit_prefix":
+        # the snapshot: a slot's whole row of every leaf that has one
+        decl = []
+        jax.eval_shape(lambda w: decl.extend(jax.tree.leaves(
+            decoding.spec_of(build(w)[1]).leaves)) or 0, weights)
+        more = [sd((slots,), flag), sd((seq_len,), i32), sd((), i32),
+                sd((), i32),
+                [sd(l.shape[1:], l.dtype) if d.slot else sd((1,))
+                 for l, d in zip(jax.tree.leaves(state["cache"]), decl)],
+                sd((), i32), sd((), flag)]
     closed, out = jax.make_jaxpr(
         {"chunk": chunk, "prefill": prefill, "seat_prefill": seat_prefill,
-         "spec_chunk": spec_chunk}[kind],
+         "spec_chunk": spec_chunk, "admit_prefix": admit_prefix}[kind],
         return_shape=True)(weights, state, *more)
 
     def hoisted(consts, w, st, *more):
@@ -402,7 +441,7 @@ def main():
         os.path.abspath(__file__))))
     ap.add_argument("--kind", default="chunk",
                     choices=("chunk", "prefill", "seat_prefill",
-                             "spec_chunk"),
+                             "spec_chunk", "admit_prefix"),
                     help="prefill: the chunked-prefill program of a "
                     "builder that has one (minicpm_sala, "
                     "smallthinker_21b_a3b, deepseek_v3_2); seat_prefill: "
@@ -410,11 +449,14 @@ def main():
                     "seat-and-prefill program of one with a batched "
                     "prefill (gpt1_117m); spec_chunk: the self-drafting "
                     "round of a builder with a multi-token-prediction "
-                    "module (k_exaone_236b_a23b)")
+                    "module (k_exaone_236b_a23b, "
+                    "openpangu_ultra_moe_718b); admit_prefix: a request "
+                    "seated over a snapshot under such a round "
+                    "(openpangu_ultra_moe_718b)")
     ap.add_argument("--layers", type=int, default=2,
                     help="layers compiled (8: the whole minicpm_sala or "
-                    "smallthinker_21b_a3b cut, 5: k_exaone_236b_a23b's or "
-                    "deepseek_v3_2's, 12: olmo_hybrid_7b's, 4: "
+                    "smallthinker_21b_a3b cut, 5: k_exaone_236b_a23b's, "
+                    "deepseek_v3_2's or openpangu_ultra_moe_718b's, 12: olmo_hybrid_7b's, 4: "
                     "solar_open2_250b's, to see that "
                     "the real program fits the chip)")
     args = ap.parse_args()
