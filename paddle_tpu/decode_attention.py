@@ -33,7 +33,12 @@ leaf is never expanded to heads.  One lowering today, plain XLA ops (a
 row gather told its list is sorted and unique, and a masked softmax:
 ``decode_attention_latent_lowered_total{path}``);
 :func:`masked_latent_attention` is the contract whole over the rung, the
-tests' parity reference.
+tests' parity reference.  A layer with no scorer (no ``index_k`` leaf)
+reads EVERY live position for its ``K`` fresh rows through
+:func:`dense_latent_attention`: on a TPU over bf16 leaves ONE Pallas
+kernel a leaf that walks each slot's own live key blocks from a work
+list (:func:`dense_latent_kernel_supported` is the rule), else the same
+walk as XLA ops batched over slots.
 
 **The ring leaf.**  A layer whose queries read only the last ``W``
 positions (a sliding window that counts the query's own) keeps
@@ -174,6 +179,9 @@ __all__ = ["KV_BLOCK", "KV_TAIL", "KV_SEQ_AXIS", "kv_leaves",
            "selected_latent_attention", "masked_latent_attention",
            "pad_lanes", "LATENT_LOWERED", "INDEX_SELECT_LOWERED",
            "dense_latent_attention", "dense_latent_positions_touched",
+           "dense_latent_kernel_attention", "dense_latent_kernel_supported",
+           "dense_latent_kernel_block",
+           "dense_latent_positions_read", "dense_latent_work_items",
            "DENSE_LATENT_BLOCK", "divisor_block"]
 
 BLOCK_SPARSE_LOWERED = _registry.REGISTRY.counter(
@@ -228,8 +236,11 @@ LATENT_LOWERED = _registry.REGISTRY.counter(
     "(a gather of the named rows and a masked softmax over them, "
     "absorbed: the leaf is never expanded to heads); and DENSE reads of "
     "every live position for K fresh rows (dense_latent_attention): "
+    "dense_kernel (Pallas TPU: each slot's OWN live key blocks from a "
+    "work list, the leaf read as it lies, the running softmax in VMEM) | "
     "dense_xla (the rung walked in key blocks under one running softmax, "
-    "up to the pool's longest live context)", ("path",))
+    "up to the pool's longest live context, for every slot: the CPU, "
+    "float32 leaves, a rung the block does not divide)", ("path",))
 
 INDEX_SELECT_LOWERED = _registry.REGISTRY.counter(
     "decode_attention_index_select_lowered_total",
@@ -1240,11 +1251,14 @@ DENSE_LATENT_BLOCK = 512
 
 def dense_latent_positions_touched(longest, rung: int,
                                    block: int = DENSE_LATENT_BLOCK):
-    """Positions of a slot's rung :func:`dense_latent_attention` reads
-    and multiplies when the pool's longest live context is ``longest``
-    (positions, the fresh rows among them): whole key blocks up to it,
-    the same for EVERY slot — its host mirror, for a benchmark's share
-    of what was touched that was live."""
+    """Positions of a slot's rung the XLA form of
+    :func:`dense_latent_attention` reads and multiplies when the pool's
+    longest live context is ``longest`` (positions, the fresh rows among
+    them): whole key blocks up to it, the same for EVERY slot — that
+    form's host mirror, for a benchmark's share of what was touched that
+    was live.  The kernel's path stops a slot at its own last fresh row:
+    :func:`dense_latent_positions_read` answers for the lowering in
+    force."""
     kb = divisor_block(int(rung), block)
     return min(-(-int(longest) // kb) * kb, int(rung))
 
@@ -1256,6 +1270,61 @@ def divisor_block(n: int, block: int) -> int:
     while n % kb:
         kb -= 1                        # tiny test rungs: a divisor
     return kb
+
+
+def dense_latent_kernel_block(seq_len: int) -> int:
+    """Positions of a key block of the dense latent kernel over a rung of
+    ``seq_len``: :data:`_DENSE_LATENT_KERNEL_BLOCK`, a shorter rung
+    itself."""
+    return min(_DENSE_LATENT_KERNEL_BLOCK, int(seq_len))
+
+
+def dense_latent_kernel_supported(seq_len: int, lanes: int, dtype, *,
+                                  n_head: int, d_value: int,
+                                  backend=None) -> bool:
+    """Whether :func:`dense_latent_attention` lowers to its Pallas kernel
+    over latent leaves ``[S, seq_len, lanes]`` of ``dtype`` under
+    ``n_head`` heads: a TPU (``jax.default_backend()`` where ``backend``
+    is unsaid), bf16 leaves, a row and the value part of it whole lane
+    tiles, whole blocks (:func:`dense_latent_kernel_block`) of whole
+    sublane tiles a rung, and the heads whole sublane tiles — so that ``K
+    * n_head`` query rows are for every ``K``, and the step and the
+    ``K``-row round of one builder take the same lowering."""
+    import jax
+    import jax.numpy as jnp
+
+    block = dense_latent_kernel_block(seq_len)
+    return ((backend or jax.default_backend()) == "tpu"
+            and jnp.dtype(dtype) == jnp.bfloat16
+            and lanes % _LANE_TILE == 0 and d_value % _LANE_TILE == 0
+            and 0 < d_value <= lanes and block % 16 == 0
+            and seq_len % block == 0 and n_head % 16 == 0)
+
+
+def dense_latent_positions_read(ts, seq_len: int, *, lanes: int,
+                                block: int = DENSE_LATENT_BLOCK, **leaves):
+    """Positions :func:`dense_latent_attention` reads of a slot's leaf
+    in a read whose (last) fresh row is at ``ts >= 0`` (a numpy integer
+    array, the slots first; ``lanes``: a row's width, which
+    :func:`latent_leaves` rounds up to whole tiles; ``leaves``: what else
+    :func:`dense_latent_kernel_supported` takes after the rung), for the
+    lowering in force: the kernel's whole key blocks
+    (:func:`dense_latent_kernel_block`) up to the slot's OWN last fresh
+    row, or the XLA form's whole blocks of ``block`` up to the LONGEST
+    context of the slots read together, for every one of them
+    (:func:`dense_latent_positions_touched`).  What the dense latent
+    builder declares as its ``"kv"`` read (``decoding.PositionRead``)
+    over bf16 leaves."""
+    ts = np.asarray(ts)
+    if dense_latent_kernel_supported(seq_len, _whole_tiles(lanes), **leaves):
+        mine = dense_latent_kernel_block(seq_len)
+        return kv_positions_read(ts, mine, mine)
+    if not ts.size:
+        return ts
+    kb = divisor_block(int(seq_len), block)
+    longest = ts.max(axis=0, keepdims=True) + 1
+    return np.broadcast_to(np.minimum(-(-longest // kb) * kb, seq_len),
+                           ts.shape)
 
 
 def dense_latent_attention(q, kv, ts, *, d_value: int, scale: float,
@@ -1274,26 +1343,63 @@ def dense_latent_attention(q, kv, ts, *, d_value: int, scale: float,
     The one shared row a position is read once for all ``K * H`` queries
     of its slot (a ``[K * H, lanes] x [lanes, block]`` product a slot:
     128 heads over 1,152 bytes, the one attention here whose least work
-    is arithmetic and not bytes).  The rung is walked ``key_block``
-    positions at a time under ONE running softmax, up to the pool's
-    longest live context and no further, so no ``[S, K, H, T]`` score
-    tensor is ever written: a turn holds ``[S, K * H, key_block]``.
-    What lies past a slot's own context inside those blocks is
-    multiplied and masked (:func:`dense_latent_positions_touched`); a
-    kernel that reads each slot's live blocks alone is ROADMAP Queue 2
-    A 7."""
-    import jax
-    import jax.numpy as jnp
+    is arithmetic and not bytes), a key block at a time under ONE running
+    softmax, so no ``[S, K, H, T]`` score tensor is ever written
+    (``key_block``: the XLA form's; the kernel's is its own,
+    :func:`dense_latent_kernel_block`).  ONE algorithm, two lowerings
+    chosen by what the code can observe
+    (:func:`dense_latent_kernel_supported`; no knob):
 
-    LATENT_LOWERED.labels(path="dense_xla").inc()
-    f32 = jnp.float32
+    - the Pallas TPU kernel (:func:`dense_latent_kernel_attention`): each
+      slot's OWN live key blocks from a work list, the leaf read as it
+      lies, the running softmax in VMEM;
+    - the XLA form (:func:`_dense_latent_xla`: the CPU, float32 leaves,
+      rungs the block does not divide; the kernel's parity reference):
+      batched over slots, so every slot walks the blocks of the pool's
+      LONGEST live context and what lies past its own is multiplied and
+      masked (:func:`dense_latent_positions_touched`).
+
+    Both take the queries as ``[S, K * H, lanes]`` in the storage dtype
+    and hand the context back ``[S, K * H, d_value]`` float32."""
     leaf = kv["latent"]
     S, T, lanes = leaf.shape
     K, H = q.shape[1], q.shape[2]
-    kb = divisor_block(T, key_block)
-    # the K rows beside the heads: one product a slot and block
-    qs = pad_lanes((q * scale).astype(leaf.dtype), lanes).reshape(
+    if dense_latent_kernel_supported(T, lanes, leaf.dtype, n_head=H,
+                                     d_value=d_value):
+        LATENT_LOWERED.labels(path="dense_kernel").inc()
+        return dense_latent_kernel_attention(
+            q, kv, ts, d_value=d_value, scale=scale)
+    LATENT_LOWERED.labels(path="dense_xla").inc()
+    return _dense_latent_xla(
+        _dense_latent_queries(q, leaf, scale), leaf, ts, heads=H,
+        d_value=d_value, key_block=key_block).reshape(S, K, H, d_value)
+
+
+def _dense_latent_queries(q, leaf, scale: float):
+    """``q`` ``[S, K, H, d_latent]`` float32 as both lowerings of the
+    dense read take it: scaled, in the leaf's dtype and lanes, the ``K``
+    rows beside the heads — ``[S, K * H, lanes]``, one product a slot and
+    block."""
+    S, K, H, _ = q.shape
+    lanes = leaf.shape[2]
+    return pad_lanes((q * scale).astype(leaf.dtype), lanes).reshape(
         S, K * H, lanes)
+
+
+def _dense_latent_xla(qs, leaf, ts, *, heads: int, d_value: int,
+                      key_block: int):
+    """The XLA form of :func:`dense_latent_attention`: ``qs`` ``[S, K *
+    H, lanes]`` in the leaf's dtype, the context back ``[S, K * H,
+    d_value]`` float32.  The rung is walked a block at a time for all
+    slots together, up to the pool's longest live context and no
+    further: a turn holds ``[S, K * H, key_block]`` scores."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    S, T, lanes = leaf.shape
+    K, H = qs.shape[1] // heads, heads
+    kb = divisor_block(T, key_block)
     # the last position each query may read; an idle slot's: none
     last = jnp.where(ts[:, None] >= 0,
                      ts[:, None] + jnp.arange(K)[None, :], -1)      # [S, K]
@@ -1320,8 +1426,249 @@ def dense_latent_attention(q, kv, ts, *, d_value: int, scale: float,
         0, n_blocks, body,
         (jnp.full((S, K * H), _MASK, f32), jnp.zeros((S, K * H), f32),
          jnp.zeros((S, K * H, d_value), f32)))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.reshape(S, K, H, d_value)
+    return acc / jnp.maximum(l, 1e-30)[..., None]
+
+
+#: positions of a (slot, key block) item of the dense latent kernel.  An
+#: item's chain — score product, float32 softmax, value product, the
+#: context's update — is serial and its fixed part (the queries re-packed
+#: for the matrix unit, the ``[K * H, d_value]`` context read and written)
+#: costs ~0.5 us whatever the block holds, so longer blocks pay it less
+#: often and round a slot's context up further.  At ``[32,16384,640]``
+#: bf16, K = 2, 128 heads, the cell's contexts (371,575 live positions in
+#: 31 slots), ms a call and what is touched over what is live: 256 2.19 /
+#: 1.012, 512 1.565 / 1.028, **1024 1.438 / 1.069 (77.4% of the bf16 peak
+#: over what is live, 82.8% over what it touches)**, 2048 1.399 / 1.108;
+#: the XLA form 2.425 in its blocks of 512 (62.7% over the 1.367 it
+#: touches, 45.9% over what is live), 2.323 in 1024 — chip runs, PR 61,
+#: tools/time_dense_latent.py.  1024 and not 2048: 2.7% for rounding
+#: twice as coarse, which shorter contexts than the cell's would pay first
+_DENSE_LATENT_KERNEL_BLOCK = 1024
+#: key blocks whose reads are in flight ahead of the one the dense latent
+#: kernel multiplies: a block's arithmetic outlasts its copy (blocks of
+#: 1024: 1.420 ms a call with the copies cut out, 0.703 with the
+#: arithmetic cut out — the copies alone run at 700 GB/s), so ONE read
+#: ahead hides it: 1.438 with one, 1.446 with two (512: 1.565 / 1.578).
+#: A form with an item's score product a turn ahead of its softmax in one
+#: straight-line block (for the scheduler to overlap the matrix and the
+#: vector unit) read 1.45 at 512 and 1.52 at 256 — better than the plain
+#: walk at 256, no better at 512 — and was not kept (chip runs, PR 61)
+_DENSE_LATENT_AHEAD = 1
+
+
+def dense_latent_work_items(ts, fresh: int, seq_len: int, block: int):
+    """The dense latent kernel's work list: the live ``(slot, key
+    block)`` pairs of a read of ``fresh`` rows a slot at ``ts .. ts +
+    fresh - 1``, slot-major — whole blocks up to the one that holds the
+    slot's OWN last fresh row (:func:`last_fresh_row`), none for an idle
+    slot.  ``(n_items [1], slot, blk)`` as :func:`decode_work_items`
+    gives them (entries past ``n_items`` are padding)."""
+    n_items, slot, blk, _ = decode_work_items(
+        last_fresh_row(ts, fresh, seq_len), seq_len, block, block)
+    return n_items, slot, blk
+
+
+def _dense_latent_kernel(n_items_ref, item_slot_ref, item_blk_ref, ts_ref,
+                         nth_ref,                               # SMEM
+                         q_hbm, leaf_hbm,                       # HBM (ANY)
+                         o_hbm,                                 # output
+                         qbuf, kbuf, m_ref, l_ref, acc_ref, obuf, sem, *,
+                         block, heads, d_value):
+    """The work list's items one after another, the reads of the next
+    ``ahead`` in flight: an item is ONE ``[block, lanes]`` slab of the
+    leaf as it lies, scored against the slot's ``[K * H, lanes]`` queries
+    in one product contracted over the lanes of both (:func:`_block_part`:
+    the slab is never transposed), its first ``d_value`` lanes weighed in
+    one more.  A slot's queries come in by a copy of their own, started
+    with its block 0's (``nth_ref``: the slot's number among the live
+    ones, which of the query buffers it takes); its max, sum and context
+    stay in VMEM from its block 0 to the block of its last fresh row,
+    where the context is divided and leaves by a copy that the next
+    slot's products hide.  Query row ``r`` is fresh row ``r // heads`` and
+    reads the positions ``<= ts + r // heads``: only the blocks from the
+    one that holds ``ts`` on are masked, the others traced without a
+    select.  A block an earlier row cannot see at all leaves its sums as
+    they were (:func:`_grouped_kernel` says why).  An idle slot is
+    written zeros."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows = qbuf.shape[1]
+    S, T, _ = leaf_hbm.shape
+    nbuf = kbuf.shape[0]
+    ahead, fresh = nbuf - 1, rows // heads
+    n_items = n_items_ref[0]
+
+    def context_out(n):
+        return pltpu.make_async_copy(obuf, o_hbm.at[n], sem.at[2, 0])
+
+    obuf[...] = jnp.zeros_like(obuf)
+
+    def idle(n, carry):
+        @pl.when(ts_ref[n] < 0)
+        def _():
+            context_out(n).start()
+            context_out(n).wait()
+
+        return carry
+
+    jax.lax.fori_loop(0, S, idle, 0)
+
+    def slab_read(i):
+        n, buf = item_slot_ref[i], jax.lax.rem(i, nbuf)
+        src = pl.ds(pl.multiple_of(item_blk_ref[i] * block, block), block)
+        return pltpu.make_async_copy(leaf_hbm.at[n, src], kbuf.at[buf],
+                                     sem.at[0, buf])
+
+    def query_read(n):
+        buf = jax.lax.rem(nth_ref[n], nbuf)
+        return pltpu.make_async_copy(q_hbm.at[n], qbuf.at[buf],
+                                     sem.at[1, buf])
+
+    def score(i):
+        n, b, buf = item_slot_ref[i], item_blk_ref[i], jax.lax.rem(i, nbuf)
+        t, qb = ts_ref[n], jax.lax.rem(nth_ref[n], nbuf)
+
+        @pl.when(b == 0)
+        def _():
+            query_read(n).wait()
+            m_ref[...] = jnp.full_like(m_ref, _MASK)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def take_in(masked):
+            def body():
+                ok = None
+                if masked:
+                    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+                    at = t + sum((row >= j * heads).astype(jnp.int32)
+                                 for j in range(1, fresh))
+                    ok = b * block + jax.lax.broadcasted_iota(
+                        jnp.int32, (rows, block), 1) <= at
+                # ONE load of the slab: a bf16 operand is re-packed from
+                # the leaf's HBM tiling to the matrix unit's as it is
+                # loaded, and the value lanes are the slab's own
+                slab = kbuf[buf]
+                m, l, acc = _block_part(
+                    qbuf[qb], slab, slab[:, :d_value], ok,
+                    m_ref[:, :1], l_ref[:, :1], acc_ref[...])
+                m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
+                l_ref[...] = jnp.broadcast_to(l, l_ref.shape)
+                acc_ref[...] = acc
+
+            return body
+
+        first_masked = t // block   # the first block a row sees part of
+        pl.when(b >= first_masked)(take_in(True))
+        pl.when(b < first_masked)(take_in(False))
+
+        @pl.when(b == jnp.minimum(t + fresh - 1, T - 1) // block)
+        def _():
+            @pl.when(nth_ref[n] > 0)
+            def _():
+                context_out(n).wait()   # the slot before's: long landed
+
+            obuf[...] = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+            context_out(n).start()
+
+    def turn(j, carry):
+        @pl.when(j < n_items)
+        def _():
+            slab_read(j).start()
+
+            @pl.when(item_blk_ref[j] == 0)
+            def _():
+                query_read(item_slot_ref[j]).start()
+
+        i = j - ahead
+
+        @pl.when(i >= 0)
+        def _():
+            slab_read(i).wait()
+            score(i)
+
+        return carry
+
+    jax.lax.fori_loop(0, n_items + ahead, turn, 0)
+
+    @pl.when(n_items > 0)
+    def _():
+        context_out(0).wait()
+
+
+def dense_latent_kernel_attention(q, kv, ts, *, d_value: int, scale: float,
+                                  key_block=None, interpret=False):
+    """:func:`dense_latent_attention`'s contract through the Pallas TPU
+    kernel whatever the backend (``interpret``: the CPU's tests; the
+    tools): what the chooser there takes for shapes
+    :func:`dense_latent_kernel_supported` accepts.  ``key_block`` unsaid
+    is :func:`dense_latent_kernel_block` of the rung; said (the tests'
+    small rungs, the tool's sweep), it divides the rung."""
+    leaf = kv["latent"]
+    S, K, H, _ = q.shape
+    return _dense_latent_call()(
+        ts, _dense_latent_queries(q, leaf, scale), leaf, heads=H,
+        d_value=d_value,
+        block=key_block or dense_latent_kernel_block(leaf.shape[1]),
+        ahead=_DENSE_LATENT_AHEAD, interpret=interpret).reshape(
+            S, K, H, d_value)
+
+
+@functools.lru_cache(maxsize=None)
+def _dense_latent_call():
+    """One jitted entry point for every call site (:func:`_kernel_call`
+    says why): a round's layers and its module share one trace."""
+    import jax
+
+    return jax.jit(_dense_latent, static_argnames=(
+        "heads", "d_value", "block", "ahead", "interpret"))
+
+
+def _dense_latent(ts, qs, leaf, *, heads, d_value, block, ahead, interpret):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, T, lanes = leaf.shape
+    rows = qs.shape[1]
+    dt, f32, i32 = leaf.dtype, jnp.float32, jnp.int32
+    size = jnp.dtype(dt).itemsize
+    ts = ts.astype(i32)
+    work = dense_latent_work_items(ts, rows // heads, T, block)
+    nth = jnp.cumsum((ts >= 0).astype(i32)) - 1
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    nbuf = ahead + 1
+    # bytes the kernel keeps in VMEM: the slabs and queries being read
+    # and scored, a slot's sums and context twice, a block's scores a few
+    # times over
+    resident = (nbuf * (rows + block) * lanes * size
+                + 2 * 4 * rows * (_HEAD_LANES + d_value)
+                + 4 * 4 * rows * block)
+    return pl.pallas_call(
+        functools.partial(_dense_latent_kernel, block=block, heads=heads,
+                          d_value=d_value),
+        out_shape=jax.ShapeDtypeStruct((S, rows, d_value), f32),
+        in_specs=[smem] * 5 + [hbm] * 2,
+        out_specs=hbm,
+        scratch_shapes=[
+            pltpu.VMEM((nbuf, rows, lanes), dt),
+            pltpu.VMEM((nbuf, block, lanes), dt),
+            pltpu.VMEM((rows, _HEAD_LANES), f32),       # a slot's max,
+            pltpu.VMEM((rows, _HEAD_LANES), f32),       # sum
+            pltpu.VMEM((rows, d_value), f32),           # and weighted rows
+            pltpu.VMEM((rows, d_value), f32),           # a context going out
+            pltpu.SemaphoreType.DMA((3, nbuf)),
+        ],
+        compiler_params=(pltpu.CompilerParams(
+            vmem_limit_bytes=min(100 << 20, 2 * resident))
+            if resident > _VMEM_DEFAULT * 3 // 4 else None),
+        name="dense_latent_attention",
+        interpret=interpret,
+    )(*work, ts, nth, qs, leaf)
 
 
 def block_kernel_supported(kv, n_head: int, n_kv_head: int,
@@ -1354,7 +1701,8 @@ def _score_chunks(rows: int, block: int, score_rows: int):
 def _block_part(q, k, v, ok, m, l, acc):
     """One chunk of keys into a (slot, K/V head)'s online softmax: ``q``
     ``[rep, Dh]`` against ``k``, ``v`` ``[N, Dh]`` in the storage dtype,
-    ``ok`` ``[rep, N]`` the positions that may be read; ``m``, ``l``
+    ``ok`` ``[rep, N]`` the positions that may be read (None: all of
+    them, and no select is traced); ``m``, ``l``
     ``[rep, 1]`` and ``acc`` ``[rep, Dh]`` fp32 the max, the sum and the
     weighted rows so far (``(_MASK, 0, 0)``: nothing yet).  Returns the
     three with the chunk taken in."""
@@ -1363,10 +1711,13 @@ def _block_part(q, k, v, ok, m, l, acc):
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    s = jnp.where(ok, s, _MASK)
+    if ok is not None:
+        s = jnp.where(ok, s, _MASK)
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m - m_new)
-    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+    p = jnp.exp(s - m_new)
+    if ok is not None:
+        p = jnp.where(ok, p, 0.0)
     return (m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True),
             alpha * acc + jnp.dot(p.astype(v.dtype), v,
                                   preferred_element_type=jnp.float32))

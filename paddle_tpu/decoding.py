@@ -615,6 +615,22 @@ def _ragged_kv_reads(kv: str):
     return (PositionRead("kv", ragged_positions_read, rounds=False),)
 
 
+def _dense_latent_kv_reads(d, kv: str):
+    """The ``"kv"`` read of the dense latent builder over leaves of
+    storage dtype ``kv`` and ``d``'s widths: over bf16 leaves the read's
+    own host mirror, ``decode_attention.dense_latent_positions_read``
+    (the kernel's whole key blocks up to a slot's own last fresh row, or
+    the XLA form's up to the longest context, whichever lowering is in
+    force); float32 leaves stay :func:`_ragged_kv_reads`'s."""
+    from paddle_tpu.decode_attention import dense_latent_positions_read
+
+    if kv != "bfloat16":
+        return _ragged_kv_reads(kv)
+    return (PositionRead("kv", functools.partial(
+        dense_latent_positions_read, lanes=d.d_latent, dtype=kv,
+        n_head=d.n_head, d_value=d.d_c)),)
+
+
 def _step_kv_read(d, kv: str) -> PositionRead:
     """The ``"kv"`` read of a builder whose steps attend through
     ``decode_attention.make_decode_attention`` over leaves of storage
@@ -2185,8 +2201,10 @@ def make_latent_mtp_lm_pooled_step_fn(state, cfg, name: str = "lm",
     (tests/test_latent_mtp_lm.py).
 
     The spec's ``"latent"`` read (a query of context ``n`` reads all
-    ``n`` positions in each of the layers and the module) is for the
-    server's counters.  All leaves are sequence leaves: ``KVSlotPool``
+    ``n`` positions in each of the layers and the module) and, over bf16
+    leaves, its ``"kv"`` read (what the read's lowering TOUCHES of a
+    slot's leaf: :func:`_dense_latent_kv_reads`) are for the server's
+    counters.  All leaves are sequence leaves: ``KVSlotPool``
     serves ``prefix=True`` over this builder by snapshots, and a
     self-drafting round over them.
     """
@@ -2342,7 +2360,7 @@ def make_latent_mtp_lm_pooled_step_fn(state, cfg, name: str = "lm",
     declare(make_cache, CacheSpec(
         leaves, prefill_fn=prefill_fn, verify_fn=verify_fn,
         mtp_fn=mtp_fn if d.n_mtp else None,
-        reads=_ragged_kv_reads(kv) + (
+        reads=_dense_latent_kv_reads(d, kv) + (
             PositionRead("latent", lambda n: n, layers=d.n_layer + d.n_mtp),),
         expert_stats=lambda cache: cache["expert_stats"],
         n_expert=(d.n_expert if held is None

@@ -277,10 +277,27 @@ def _latent_mtp_self_draft():
     return built, vocab, t, kv, make_self_draft(built[1])
 
 
+def _latent_mtp_bf16():
+    import test_latent_mtp_lm as t
+
+    cfg = t.tiny_cfg()
+    return (t._build(cfg, t.weights(cfg, seed=7), kv_dtype="bf16"), t.V, 64,
+            "bf16")
+
+
+def _latent_mtp_bf16_self_draft():
+    from paddle_tpu.serving.speculative import make_self_draft
+
+    built, vocab, t, kv = _latent_mtp_bf16()
+    return built, vocab, t, kv, make_self_draft(built[1])
+
+
 STORMS = dict(BUILDERS, transformer_lm_int8=_lm_int8,
               transformer_lm_draft=_lm_with_draft,
               mtp_routed_self_draft=_mtp_self_draft,
-              latent_mtp_self_draft=_latent_mtp_self_draft)
+              latent_mtp_self_draft=_latent_mtp_self_draft,
+              latent_mtp_bf16=_latent_mtp_bf16,
+              latent_mtp_bf16_self_draft=_latent_mtp_bf16_self_draft)
 
 #: ``metrics()["decode"]`` after :func:`_storm`, key by key in
 #: :data:`COUNTED`'s order, as the parent of PR 58 read them
@@ -292,6 +309,12 @@ RECORDED = {
     # counted the module's leaf too: the rule is the round's)
     'latent_mtp': (22, 8448, 3352, 12672, 0, 0, 0, 0, 13408, 13408, 528, 434, 208, 130),
     'latent_mtp_self_draft': (48, 9216, 3352, 9216, 0, 0, 0, 0, 25000, 25000, 1416, 793, 432, 144),
+    # bf16 leaves, as PR 61 first read them: the builder's "kv" rule is
+    # the dense read's host mirror (here the XLA form's: the rung of 64 is
+    # one key block), counted once a round a slot that ADVANCED — where
+    # the float32 pool's round is counted masked over the whole pool
+    'latent_mtp_bf16': (22, 8448, 3352, 12672, 0, 0, 0, 0, 13408, 13408, 528, 433, 208, 130),
+    'latent_mtp_bf16_self_draft': (48, 7552, 3352, 9216, 0, 0, 0, 0, 25000, 25000, 1416, 794, 432, 144),
     'latent_sparse': (22, 8448, 3352, 12672, 0, 0, 0, 0, 10056, 5532, 1056, 897, 217, 130),
     'mtp_routed': (22, 8448, 3352, 12672, 0, 0, 2064, 13408, 0, 0, 1056, 872, 408, 260),
     'mtp_routed_self_draft': (49, 7616, 3352, 9408, 0, 0, 3760, 25396, 0, 0, 2380, 1215, 823, 245),

@@ -1181,3 +1181,45 @@ def test_block_kernel_is_traced_once_and_its_body_stays_small():
     assert len(kernels) == 1
     body = equations(kernels[0].params["jaxpr"])
     assert 100 < body <= _BLOCK_KERNEL_EQUATIONS_MAX, body
+
+
+#: equations of the dense latent kernel's body at the cell's shapes: 183
+#: as written (PR 61), half as many again allowed (what a process pays to
+#: trace and lower a kernel grows with its body:
+#: _BLOCK_KERNEL_EQUATIONS_MAX)
+_DENSE_LATENT_KERNEL_EQUATIONS_MAX = 274
+
+
+def test_dense_latent_kernel_is_traced_once_and_its_body_stays_small():
+    """A round's six leaves (the layers' and the module's) at
+    ``openpangu_ultra_moe_718b``'s shapes share ONE traced function whose
+    body holds one loop over the items and the block's chain twice (the
+    masked blocks and the others), whatever the slots and the rung."""
+    import importlib.util
+    import os
+    import sys
+
+    import jax
+
+    tools = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools")
+    sys.path.insert(0, tools)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "time_dense_latent", os.path.join(tools, "time_dense_latent.py"))
+        tool = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(tool)
+    finally:
+        sys.path.remove(tools)
+    f, args = tool.round_program(da, tool.SHAPE)
+    calls = [e for e in jax.make_jaxpr(f)(*args).jaxpr.eqns
+             if "jaxpr" in e.params
+             and e.params.get("name") == "_dense_latent"]
+    assert len(calls) == 6      # one a leaf ...
+    # ... of one function traced once
+    assert all(c.params["jaxpr"] is calls[0].params["jaxpr"] for c in calls)
+    kernels = [e for e in calls[0].params["jaxpr"].jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 1
+    body = tool.equations(kernels[0].params["jaxpr"])
+    assert 60 < body <= _DENSE_LATENT_KERNEL_EQUATIONS_MAX, body

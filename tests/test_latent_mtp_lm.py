@@ -300,6 +300,157 @@ def test_the_dense_read_walks_no_block_past_the_longest_context():
     assert da.dense_latent_positions_touched(40, 48, 512) == 48
 
 
+# ---------------------------------------------------------------------------
+# the dense read's Pallas kernel (interpret mode) and its chooser
+# ---------------------------------------------------------------------------
+#: (K, rung, block, ts): what the kernel's walk must get right
+_KERNEL_CASES = {
+    "idle_and_inside_a_block": (1, 64, 16, [5, -1, 21, 40, -1]),
+    "ends_exactly_at_a_blocks_end": (1, 64, 16, [15, 31, 47, 63, 16]),
+    "one_position_and_the_rungs_last": (1, 64, 16, [0, 63, -1, 62, 1]),
+    "two_rows_across_a_boundary": (2, 96, 32, [31, 32, 63, 64, 30]),
+    "two_rows_on_the_rungs_end": (2, 64, 16, [0, 62, -1, 63, 14]),
+    "contexts_blocks_apart": (2, 128, 16, [3, 120, 40, -1, 77]),
+    "one_block_holds_the_rung": (2, 48, 48, [7, -1, -1, 3, 46]),
+    "nothing_live": (1, 48, 16, [-1, -1, -1, -1, -1]),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
+def test_the_dense_kernel_equals_the_masked_reference(case, dtype, tol):
+    """The kernel under interpret mode against the contract, a fresh row
+    at a time, and against the XLA form it is chosen beside (the same
+    blocks in the same order: the same sums)."""
+    import jax.numpy as jnp
+
+    k, rung, block, ts = _KERNEL_CASES[case]
+    rng = np.random.RandomState(len(case) + k * rung)
+    s, h, lanes, d_value = len(ts), 4, 20, 16
+    kv = _leaf(rng, s, rung, lanes, dtype)
+    q = jnp.asarray(rng.randn(s, k, h, lanes).astype(np.float32))
+    ts = jnp.asarray(ts, jnp.int32)
+    got = da.dense_latent_kernel_attention(
+        q, kv, ts, d_value=d_value, scale=0.3, key_block=block,
+        interpret=True)
+    assert got.shape == (s, k, h, d_value) and got.dtype == jnp.float32
+    for j in range(k):
+        allowed = (jnp.arange(rung)[None, :] <= (ts + j)[:, None]) & (
+            ts >= 0)[:, None]
+        want = da.masked_latent_attention(q[:, j], kv, allowed,
+                                          d_value=d_value, scale=0.3)
+        np.testing.assert_allclose(np.asarray(got[:, j]), np.asarray(want),
+                                   atol=tol, rtol=tol)
+    assert not np.asarray(got)[np.asarray(ts) < 0].any()
+    xla = da.dense_latent_attention(q, kv, ts, d_value=d_value, scale=0.3,
+                                    key_block=block)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(xla),
+                               atol=tol / 10, rtol=tol / 10)
+
+
+@pytest.mark.parametrize("k", [1, 2])
+def test_the_dense_kernels_work_list_stops_at_a_slots_own_last_row(k):
+    """No item past the block that holds a slot's own last fresh row, so
+    no dead block is read; an idle slot has none; the XLA form's mirror
+    beside it walks the longest context's blocks for every slot."""
+    ts = np.asarray([0, 1023, 1024, -1, 8191 - k, 16383, 16384 - k, 700],
+                    np.int32)
+    rung = 16384
+    block = da.dense_latent_kernel_block(rung)
+    assert block == 1024 and da.dense_latent_kernel_block(256) == 256
+    n_items, slot, blk = (np.asarray(x) for x in da.dense_latent_work_items(
+        ts, k, rung, block))
+    n = int(n_items[0])
+    last = np.where(ts >= 0, np.minimum(ts + k - 1, rung - 1), -1)
+    want = [(i, b) for i in range(len(ts))
+            for b in range(int(last[i]) // block + 1 if last[i] >= 0 else 0)]
+    assert list(zip(slot[:n].tolist(), blk[:n].tolist())) == want
+    assert all(b * block <= last[i] for i, b in want)
+    assert 3 not in slot[:n]
+    leaves = dict(lanes=640, dtype="bfloat16", n_head=128, d_value=512)
+    live = last[last >= 0]
+    kernel = da.dense_latent_positions_read(live, rung, backend="tpu",
+                                            **leaves)
+    assert kernel.tolist() == [
+        (int(x) // block + 1) * block for x in live]
+    assert int(kernel.sum()) == n * block
+    assert ((kernel - (live + 1) >= 0) & (kernel - (live + 1) < block)).all()
+    xla = da.dense_latent_positions_read(live, rung, backend="cpu", **leaves)
+    assert xla.tolist() == [rung] * len(live)
+    short = da.dense_latent_positions_read(np.asarray([3, 700, 40]), rung,
+                                           backend="cpu", **leaves)
+    assert short.tolist() == [da.dense_latent_positions_touched(
+        701, rung)] * 3 == [1024] * 3
+    # a chunk's steps side by side: each step its own longest context
+    # (the XLA form's blocks are DENSE_LATENT_BLOCK = 512 long)
+    steps = da.dense_latent_positions_read(
+        np.asarray([[3, 4], [510, 511], [40, 41]]) + 1, rung, backend="cpu",
+        **leaves)
+    assert steps.tolist() == [[512, 1024]] * 3
+
+
+@pytest.mark.parametrize("why,dtype,rung,block,backend,heads", [
+    ("float32_leaves", "float32", 64, 16, "tpu", 16),
+    ("a_rung_the_block_does_not_divide", "bfloat16", 1040, 16, "tpu", 16),
+    ("no_tpu", "bfloat16", 64, 16, "cpu", 16),
+    ("heads_no_whole_sublane_tile", "bfloat16", 64, 16, "tpu", 4),
+])
+def test_the_chooser_keeps_the_xla_form_where_the_kernel_is_not(
+        monkeypatch, why, dtype, rung, block, backend, heads):
+    """One algorithm, two lowerings, chosen by what the code can observe:
+    everything the kernel does not take counts ``dense_xla`` and equals
+    the contract."""
+    import jax
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert not da.dense_latent_kernel_supported(
+        rung, 128, dtype, n_head=heads, d_value=128)
+    assert da.dense_latent_kernel_supported(
+        64, 128, "bfloat16", n_head=16, d_value=128, backend="tpu")
+    rng = np.random.RandomState(len(why))
+    ts = jnp.asarray([5, -1, rung - 2], jnp.int32)
+    kv = _leaf(rng, 3, rung, 128, dtype)
+    q = jnp.asarray(rng.randn(3, 2, heads, 128).astype(np.float32))
+    counters = {path: da.LATENT_LOWERED.labels(path=path)
+                for path in ("dense_xla", "dense_kernel")}
+    before = {path: c.value for path, c in counters.items()}
+    got = da.dense_latent_attention(q, kv, ts, d_value=128, scale=0.1,
+                                    key_block=block)
+    assert counters["dense_xla"].value == before["dense_xla"] + 1
+    assert counters["dense_kernel"].value == before["dense_kernel"]
+    for j in range(2):
+        allowed = (jnp.arange(rung)[None, :] <= (ts + j)[:, None]) & (
+            ts >= 0)[:, None]
+        want = da.masked_latent_attention(q[:, j], kv, allowed, d_value=128,
+                                          scale=0.1)
+        np.testing.assert_allclose(np.asarray(got[:, j]), np.asarray(want),
+                                   atol=3e-2, rtol=3e-2)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "fp32"])
+def test_the_builder_declares_what_the_dense_read_touches(kv_dtype):
+    """Over bf16 leaves the ``"kv"`` rule is the read's own host mirror,
+    counted once a round at the slot's last fresh row; float32 leaves
+    keep the ragged rule, a round masked over the whole pool."""
+    cfg = tiny_cfg()
+    _, make_cache, _ = decoding.make_latent_mtp_lm_pooled_step_fn(
+        weights(cfg, seed=3), cfg, kv_dtype=kv_dtype, prefill_tokens=CHUNK)
+    reads = {r.kind: r for r in decoding.spec_of(make_cache).reads}
+    assert sorted(reads) == ["kv", "latent"]
+    rule, live = reads["kv"].rule, np.asarray([3, 40, 17])
+    if kv_dtype == "bf16":
+        assert reads["kv"].rounds
+        # tiny widths: the XLA form's mirror, whatever the backend
+        assert rule(live, 64).tolist() == [64] * 3
+        assert rule(live, 1024).tolist() == [512] * 3
+        assert rule.func is da.dense_latent_positions_read
+    else:
+        assert not reads["kv"].rounds
+        assert rule(live, 64).tolist() == da.ragged_positions_read(
+            live, 64).tolist()
+
+
 def test_appends_take_k_rows_a_slot_and_no_index_key():
     import jax.numpy as jnp
 

@@ -785,14 +785,25 @@ def test_dense_latent_round_and_prefill_fit_v5e_and_copy_no_leaf(
     own depth (32 slots x 16384 positions, 128 heads over one 640-lane
     row a position, ``d_model`` 7680, K = 2; the whole 5-layer cut and
     the module): both fit 16 GB beside the pool and the snapshots, no
-    ``copy`` bears a latent leaf's shape, the round walks the rung in key
-    blocks of 512 — its scores are ``f32[32,256,512]`` and no tensor
-    holds a rung-wide score (``[32,2,128,16384]`` / ``[32,256,16384]``,
-    537 MB a layer) — and the dense read was lowered once a leaf."""
+    ``copy`` bears a latent leaf's shape and no tensor holds a rung-wide
+    score (``[32,2,128,16384]`` / ``[32,256,16384]``, 537 MB a layer).
+    The round's dense read is the Pallas kernel's, once a leaf
+    (``dense_kernel`` counted ``layers + 1`` times, ``dense_xla`` never):
+    ONE ``dense_latent_attention`` custom call a leaf that takes the
+    ``bf16[32,16384,640]`` leaf as it lies and the queries
+    ``bf16[32,256,640]`` and returns ``f32[32,256,512]`` — the two shapes
+    the benchmark's readers find the read by
+    (``benchmark/families/pooled_latent_mtp_lm.py``:
+    ``dense_latent_shapes``), so ``dense_latent_roofline.serve`` and
+    ``latent_attention_time_share.serve`` go on reading — and no XLA
+    instruction holds a key block's scores any more.  The chunked prefill
+    is expanded and takes neither lowering."""
     text, mem, counted = _compiled_chunk("openpangu_ultra_moe_718b", layers, {
+        "kernel": da.LATENT_LOWERED.labels(path="dense_kernel"),
         "dense": da.LATENT_LOWERED.labels(path="dense_xla"),
         "selected": da.LATENT_LOWERED.labels(path="xla")}, kind=kind)
-    copies = [line for line in text.splitlines() if " copy(" in line]
+    lines = text.splitlines()
+    copies = [line for line in lines if " copy(" in line]
     assert not any("[32,16384,640]" in line for line in copies), copies
     assert "[32,16384,640]{1," not in text      # never sequence-minor
     for scores in ("[32,2,128,16384]", "[32,256,16384]", "[32,128,16384]",
@@ -803,11 +814,20 @@ def test_dense_latent_round_and_prefill_fit_v5e_and_copy_no_leaf(
             + mem.generated_code_size_in_bytes + snapshots) < 15.0e9
     pool = 32 * 6 * 16384 * 640 * 2
     assert mem.alias_size_in_bytes >= pool
-    assert not counted["selected"]
+    assert not counted["selected"] and not counted["dense"]
+    calls = [line for line in lines
+             if "dense_latent_attention" in line and "custom-call(" in line]
     if kind == "spec_chunk":
-        assert counted["dense"] == layers + 1   # every layer and the module
-        assert "f32[32,256,512]" in text
+        assert counted["kernel"] == layers + 1  # every layer and the module
+        assert len(calls) == layers + 1
+        for call in calls:
+            assert "tpu_custom_call" in call
+            assert " = f32[32,256,512]" in call             # the context
+            assert "bf16[32,256,640]" in call               # the queries
+            assert "bf16[32,16384,640]" in call             # the leaf
+        # a key block's slice of every slot's leaf was the XLA walk's
+        assert "[32,512,640]" not in text
         assert mem.temp_size_in_bytes < 0.1e9
     else:
-        assert not counted["dense"]             # expanded, by key blocks
+        assert not counted["kernel"] and not calls  # expanded, by key blocks
         assert mem.temp_size_in_bytes < 0.5e9

@@ -109,9 +109,12 @@ def counters(kept):
     from paddle_tpu import monitor
 
     out = {"%s{path=%s}" % (name, path): monitor.counter_value(name, path=path)
-           for name in ("decode_attention_grouped_lowered_total",
-                        "decode_attention_ungrouped_lowered_total")
-           for path in ("kernel", "xla")}
+           for name, paths in (
+               ("decode_attention_grouped_lowered_total", ("kernel", "xla")),
+               ("decode_attention_ungrouped_lowered_total", ("kernel", "xla")),
+               ("decode_attention_latent_lowered_total",
+                ("xla", "dense_kernel", "dense_xla")))
+           for path in paths}
     read, live = (kept.get("kv_positions_" + kind, 0)
                   for kind in ("read", "live"))
     return dict(out, kv_positions_read=read, kv_positions_live=live,
