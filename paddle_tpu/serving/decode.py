@@ -37,6 +37,18 @@ request-at-a-time regime with vLLM-style CONTINUOUS batching:
   **multi-step chunks** (``steps`` tokens per device call, a
   ``fori_loop`` inside the executable) to amortize host overhead, with
   buffer donation so the KV cache updates in place;
+* **run-ahead of depth one**: where the host could decide nothing from
+  the running chunk's view — the pool is full and its slot ladder
+  cannot grow, no seated slot is held for a chunked prefill, no live
+  request can reach its length inside the chunk by the tokens the host
+  has seen, and the device's free bytes hold a second set of the
+  executable's temporaries — the NEXT chunk is dispatched before the
+  turn waits for this one, so the device goes from one to the other
+  with no gap and the host's whole turn runs under the second
+  (:meth:`DecodeServer._tick`, :meth:`DecodeServer._why_serial`; the
+  chunk's view is an output of its own beside the donated state,
+  ``kv_pool.with_view``).  Nothing selects it: a lightly loaded or an
+  emptying server fails the first condition and runs the serial turn;
 * requests flow through the SAME admission front door as the batching
   server (``DynamicBatcher``/``AdmissionQueue``: EDF ordering, priority
   classes with weighted fair sharing, AIMD admit limit, retry hints).
@@ -61,7 +73,11 @@ dispatch carried: a turn seats everything it pops in one, and
 pool's lowering put on the device once so that no dispatch sends them
 again, and ``serving_decode_idle_drops_total`` beside the
 ``serving/pool_dropped`` event, one per drop of an idle server's pool
-state, and its twin ``serving/pool_placed`` beside
+state, and ``serving_decode_chunks_ahead_total`` with
+``serving_decode_sync_turns_total{reason}``, which add up to the tick
+counter: the chunks dispatched before the host waited for the one
+running, and the turns that waited with nothing queued, by the first
+reason that held, and its twin ``serving/pool_placed`` beside
 ``serving_pool_state_seconds_total``, one per fresh state brought to
 the device) on top of the standard ``ServingMetrics`` series; the
 ``decode.step`` fault point injects failures into the tick dispatch for
@@ -69,8 +85,10 @@ chaos coverage.  While a span sink is live every scheduler turn is ONE
 ``serving/decode_tick`` span (it carries the active requests' trace
 ids) tiled by leaf spans, one a phase and in this order:
 ``serving/decode/admit_plan``, ``/admit_dispatch``, ``/prefill``,
-``/dispatch``, ``/wait``, ``/copy``, ``/deliver``; an empty server's
-wait is ``serving/decode/idle_wait``, a turn of its own.  The leaves
+``/dispatch`` (``ahead``: whether a chunk was queued behind the one the
+turn waits for; ``chunks``: executables it launched), ``/wait`` (on the
+OLDER chunk where one is queued behind it), ``/copy``, ``/deliver``; an
+empty server's wait is ``serving/decode/idle_wait``, a turn of its own.  The leaves
 are on the device trace's clock too (``monitor.spans.open_span``); with
 no sink live a turn reads the sink's flag once and nothing else is
 different (:class:`_Turn`).
@@ -122,7 +140,7 @@ from paddle_tpu.serving.errors import (
     ServerOverloaded,
     ServingError,
 )
-from paddle_tpu.serving.kv_pool import KVSlotPool
+from paddle_tpu.serving.kv_pool import VIEW, KVSlotPool, unpack_view
 from paddle_tpu.serving.metrics import ServingMetrics
 from paddle_tpu.serving.prefix_cache import PrefixKVCache
 from paddle_tpu.serving.speculative import (
@@ -130,7 +148,6 @@ from paddle_tpu.serving.speculative import (
     SPEC_PROPOSED,
     SPEC_ROUNDS,
     SPEC_ROW_ROUNDS,
-    dispatch_spec_chunk,
 )
 
 __all__ = ["DecodeServer", "DecodeRequest", "save_decode_endpoint",
@@ -150,6 +167,27 @@ DECODE_TICKS = monitor.counter(
     "serving_decode_ticks_total",
     "decode scheduler ticks (one multi-step chunk dispatch each)",
     _LABELS)
+DECODE_CHUNKS_AHEAD = monitor.counter(
+    "serving_decode_chunks_ahead_total",
+    "chunks (speculative rounds) the scheduler dispatched BEFORE it "
+    "waited for the one running, so that the device went from one to "
+    "the next with no gap and the host's whole turn ran under the "
+    "second: a full pool whose last view could hold nothing to decide "
+    "(DecodeServer._why_serial); with serving_decode_sync_turns_total "
+    "it adds up to serving_decode_ticks_total", _LABELS)
+DECODE_SYNC_TURNS = monitor.counter(
+    "serving_decode_sync_turns_total",
+    "turns that waited for their chunk with nothing queued behind it, "
+    "by the first reason that held: free_seat (a seat is free or the "
+    "slot ladder can grow: an arrival is seated before the next chunk), "
+    "held (a chunked prefill decides slot by slot), length_finish (a "
+    "request can reach its length inside the chunk: its seat is handed "
+    "on before the next), memory (a second set of the executable's "
+    "temporaries may not fit beside the running chunk's)",
+    _LABELS + ("reason",))
+#: why a turn waits for its chunk with nothing queued behind it, in the
+#: order the rule asks (``DecodeServer._why_serial``)
+SYNC_REASONS = ("free_seat", "held", "length_finish", "memory")
 DECODE_KV_READ = monitor.counter(
     "serving_decode_kv_positions_read_total",
     "KV-cache positions the decode steps read: per step and active "
@@ -347,9 +385,6 @@ DECODE_EXPERT_COUNTERS = tuple(
 # that sees no arrival for this long is idle and drops its pool state
 _IDLE_WAIT_S = 0.5
 
-# what a turn fetches of the pool state: the scheduler's view
-_VIEW = ("tokens", "pos", "active", "finished", "n_gen")
-
 _END = ("end", None)
 _ERR = ("err", None)
 
@@ -445,6 +480,26 @@ class _Slot:
         self.seq = seq  # admission order: the oldest held slot goes first
 
 
+class _Flight:
+    """One dispatch whose view the host has not read yet: the view on
+    the device (a chunk's: ONE packed vector, an output of its own,
+    alive after the state it was copied from is donated on; a turn that
+    stepped nothing: the state's own leaves), the slot records the chunk was
+    dispatched with — a row of the view is a slot's only while the slot
+    still holds that record — and which executable ran (``"none"``: a
+    turn that stepped nothing)."""
+
+    __slots__ = ("view", "recs", "kind", "rungs")
+
+    def __init__(self, view, recs, kind: str, rungs):
+        self.view, self.recs, self.kind = view, recs, kind
+        self.rungs = rungs      # of the state the chunk ran over
+
+    @property
+    def spec(self) -> bool:
+        return self.kind == "spec_chunk"
+
+
 class _Turn:
     """The spans of ONE traced scheduler turn: the ``serving/decode_tick``
     parent and the phase that is open.  Made only while a span sink is
@@ -491,6 +546,19 @@ class _Turn:
                             active=self.active, steps=server._pool.steps)
 
 
+def _device_free_bytes(array) -> Optional[int]:
+    """The least ``bytes_limit - bytes_in_use`` over the devices that
+    hold ``array``, as their allocators report it now; None where the
+    backend reports none (the CPU's)."""
+    free = None
+    for device in array.devices():
+        stats = device.memory_stats()
+        if stats and "bytes_limit" in stats:
+            left = stats["bytes_limit"] - stats["bytes_in_use"]
+            free = left if free is None else min(free, left)
+    return free
+
+
 class _PoolPredictorView:
     """The predictor-shaped facade the admin/wire surfaces read
     (``statusz``/``healthz`` expect a ``_predictor`` with names +
@@ -525,6 +593,20 @@ class DecodeServer:
     Lifecycle mirrors ``InferenceServer``: construct (the tick thread
     starts parked) -> ``warmup()`` -> ``submit()``/client traffic ->
     ``stop(drain=True)``.
+
+    A turn keeps at most ONE chunk queued behind the one that runs, and
+    only where all four hold (:meth:`_why_serial`, asked once a turn
+    from what the host can observe — no argument, flag or key selects
+    it): (i) no seat is free and the slot ladder cannot grow, (ii) no
+    seated slot is held for a chunked prefill, (iii) no live request can
+    reach its ``total_len`` inside the running chunk by the tokens the
+    host has seen, (iv) the device's free bytes hold a second set of the
+    executable's temporaries.  Depth one because one queued chunk
+    already hides the host's whole turn, and a second would be
+    dispatched from a view two chunks old (:meth:`_tick`).
+    ``stop(drain=True)`` reads a queued chunk's view before its loop
+    returns; an abort, a failed dispatch and a failed read drop it with
+    the pool.
     """
 
     #: Client.infer_stream duck-types on this (an InferenceServer lacks it)
@@ -550,6 +632,9 @@ class DecodeServer:
         self._tokens_c = DECODE_TOKENS.labels(**lbl)
         self._prefill_c = DECODE_PREFILL_TOKENS.labels(**lbl)
         self._ticks_c = DECODE_TICKS.labels(**lbl)
+        self._ahead_c = DECODE_CHUNKS_AHEAD.labels(**lbl)
+        self._sync_cs = {reason: DECODE_SYNC_TURNS.labels(reason=reason, **lbl)
+                         for reason in SYNC_REASONS}
         self._kv_pool_c = DECODE_KV_POOL.labels(**lbl)
         self._ttft_h = DECODE_TTFT.labels(**lbl)
         self._occupancy_g = DECODE_OCCUPANCY.labels(**lbl)
@@ -650,6 +735,9 @@ class DecodeServer:
         self._stop = threading.Event()
         self._warmed = False
         self._state = None               # tick-thread owned pool state
+        # the chunk dispatched AHEAD of the one the last turn waited
+        # for: on the device (running or done), its view unread
+        self._flight: Optional[_Flight] = None
         # a fresh state not yet delivered over: (perf_counter at the end
         # of its alloc, the alloc's seconds, its bytes, the tick
         # thread's build seconds then); None once its birth is booked
@@ -707,6 +795,14 @@ class DecodeServer:
             "generated_tokens": int(self._tokens_c.value),
             "prefill_tokens": int(self._prefill_c.value),
             "ticks": int(self._ticks_c.value),
+            # of those ticks: chunks dispatched before the host waited
+            # for the one running / turns that waited with nothing
+            # queued, by reason (they add up to ``ticks``)
+            "chunks_ahead": int(self._ahead_c.value),
+            "ahead_share": (self._ahead_c.value / self._ticks_c.value
+                            if self._ticks_c.value else 0.0),
+            "sync_turns": {reason: int(c.value)
+                           for reason, c in self._sync_cs.items()},
             # kv_positions_read / _live, and each layer kind's pair
             **{series.name[len("serving_decode_"):-len("_total")]:
                int(c.value)
@@ -966,7 +1062,9 @@ class DecodeServer:
                 # below asks ``turn is not None``
                 turn = _Turn() if _mon_spans.recording() else None
                 self._admit_pending(turn)
-                if self._active_count() == 0:
+                # a chunk still queued is delivered before the server
+                # is idle (every slot it stepped freed a turn ago)
+                if self._active_count() == 0 and self._flight is None:
                     if turn is not None:
                         turn.close(self)
                     if self._stop.is_set() and (
@@ -1252,85 +1350,119 @@ class DecodeServer:
             else float(pool.kv_rung_bytes_one_length(*rungs)))
 
     def _tick(self, turn: Optional[_Turn]) -> None:
-        """One scheduler turn: dispatch a multi-step chunk, then
-        materialize the (small) scheduler view and stream/complete."""
+        """One scheduler turn: see that a multi-step chunk is on the
+        device, dispatch the NEXT one behind it where nothing could be
+        decided in between, then materialize the older chunk's (small)
+        scheduler view and stream/complete.
+
+        **Run-ahead of depth one.**  Serial, a turn is dispatch chunk N
+        -> ``wait`` -> ``copy`` -> ``deliver`` -> ``admit`` -> dispatch
+        N + 1, and the device has nothing queued from the instant N ends
+        until the next launch reaches it: the host's whole turn, 3 ms of
+        a 21 ms self-drafting round (v5e chip runs, PR 59 / PR 61).  So
+        before it waits for N the turn dispatches N + 1 wherever N's
+        view can hold nothing the host would act on
+        (:meth:`_why_serial`: a full pool, no held slot, no request
+        within reach of its length, room for a second set of
+        temporaries); the device goes from N to N + 1 with no gap and
+        everything from ``wait``'s wake-up to the next turn's launch
+        runs under N + 1.  Otherwise the turn is the serial one — the
+        serial turn IS the run-ahead turn with nothing queued.  The
+        depth is ONE: a second chunk queued would be dispatched from a
+        view two chunks old (a request within ``2 x steps`` tokens of
+        its length would keep the turn serial, and a seat would wait two
+        chunks), and one queued chunk already covers the whole turn.
+
+        What run-ahead costs: an EOS, an expired deadline or an
+        abandoned stream inside N is found one chunk late.  The slot is
+        inert on the device meanwhile (``active & ~newly_fin``; its
+        ``ts`` is -1), so it costs one slot-chunk, never a token.  Each
+        chunk in flight carries the slot records of its dispatch
+        (:class:`_Flight`), and a view's row is applied to a slot only
+        while the slot still holds that record: a request seated into a
+        slot freed while N + 1 was queued never reads N + 1's row.
+
+        A turn is still ONE ``serving/decode_tick``: ONE ``dispatch``
+        leaf (``ahead`` = whether a chunk was queued behind the one the
+        turn waits for, ``chunks`` = executables launched under it: 2
+        where a run of turns ahead begins, 0 where it ends), then
+        ``wait`` / ``copy`` / ``deliver`` of the OLDER chunk; every
+        counter moves once a view, when it is delivered."""
         import jax
 
+        flight, self._flight = self._flight, None
         recs = [(i, s) for i, s in enumerate(self._slots) if s is not None]
         tids = ()
         if turn is not None:
             tids = tuple(s.req.trace_id for _, s in recs if s.req.trace_id)
             turn.active, turn.tids = len(recs), tids
-        # a speculative round only when an opted-in slot is live: a pool
-        # with a draft attached but no speculative traffic ticks the
-        # plain chunk (one executable kind per tick, both warmed)
-        use_spec = self._speculative is not None and any(
-            s.spec for _, s in recs)
-        stepped = True
-        if self._chunked:
-            if any(s.held for _, s in recs) and not self._prefill_turn(
-                    recs, turn):
-                return
-            # with every seated slot held there is nothing for a decode
-            # chunk to advance: the turn was its prefill chunk
-            stepped = any(not s.held for _, s in recs)
+        if (flight is None and self._chunked
+                and any(s.held for _, s in recs)
+                and not self._prefill_turn(recs, turn)):
+            return
         if turn is not None:
-            kind = ("none" if not stepped
-                    else "spec_chunk" if use_spec else "chunk")
             turn.enter("dispatch")
+        launched = 0
         try:
             with contextlib.ExitStack() as stack:
                 if tids:
                     stack.enter_context(_mon_spans.trace_context(tids))
-                # hot-path: begin decode_tick (fault gate + the chunk
-                # dispatch; materialization happens OUTSIDE, after the
-                # async dispatch returns)
-                if _faults.active is not None:  # disarmed: one is-None gate
-                    _faults.active.faultpoint(
-                        "decode.step", server=self.name,
-                        active=len(recs))
-                if not stepped:
-                    state = self._state
-                elif use_spec:
-                    state = dispatch_spec_chunk(self._pool, self._state)
-                else:
-                    state = self._pool.chunk(self._state)
-                # hot-path: end decode_tick
+                if flight is None:
+                    flight = self._dispatch(recs)
+                    launched += flight.kind != "none"
+                serial = self._why_serial(flight)
+                if serial is None:
+                    self._flight = self._dispatch(recs)
+                    launched += 1
         except BaseException as exc:  # noqa: BLE001 — fail typed, keep serving
             if turn is not None:
-                turn.leave(error=True, kind=kind)
+                turn.leave(error=True, chunks=launched, kind=(
+                    "error" if flight is None else flight.kind))
             self._fail_and_drop_pool(exc)
             return
-        self._state = state
-        fetch = {k: state[k] for k in _VIEW}
-        if self._expert_stats_of is not None:
-            fetch["expert_stats"] = self._expert_stats_of(state["cache"])
+        kind, use_spec = flight.kind, flight.spec
+        stepped = kind != "none"
+        try:
+            if turn is not None:
+                turn.leave(kind=kind, ahead=int(serial is None),
+                           chunks=launched)
+                # tell the chunk's rest from the copies without delaying
+                # either: queue the five copies behind the chunk NOW, as
+                # the device_get below does in an untraced turn (waiting
+                # first and asking for them afterwards cost a traced
+                # chat tick 0.5 ms: v5e chip run, PR 36), then wait on
+                # the smallest of the five — outputs of one execution,
+                # ready together
+                turn.enter("wait")
+                for v in jax.tree.leaves(flight.view):
+                    v.copy_to_host_async()
+                jax.block_until_ready(jax.tree.leaves(flight.view)[-1])
+                turn.leave()
+                turn.enter("copy")
+            # the scheduler intervention: one d2h of the control-plane
+            # arrays (tokens/pos/flags — KBs, not the KV cache), which
+            # a chunk hands over packed into ONE vector
+            view = jax.device_get(flight.view)
+            copied = sum(v.nbytes for v in jax.tree.leaves(view))
+            if stepped:
+                view = unpack_view(view, *flight.rungs)
+        except BaseException as exc:  # noqa: BLE001 — an error of the
+            # chunk (or of the one queued behind it) surfaces where its
+            # outputs are first read: as a dispatch error does
+            if turn is not None and turn.leaf is not None:
+                turn.leave(error=True)
+            self._fail_and_drop_pool(exc)
+            return
         if turn is not None:
-            turn.leave(kind=kind)
-            # tell the chunk's rest from the copies without delaying
-            # either: queue the five copies behind the chunk NOW, as the
-            # device_get below does in an untraced turn (waiting first
-            # and asking for them afterwards cost a traced chat tick
-            # 0.5 ms: v5e chip run, PR 36), then wait on the smallest of
-            # the five — outputs of one execution, ready together
-            turn.enter("wait")
-            for v in fetch.values():
-                v.copy_to_host_async()
-            jax.block_until_ready(state["n_gen"])
-            turn.leave()
-            turn.enter("copy")
-        # the scheduler intervention: one d2h of the control-plane
-        # arrays (tokens/pos/flags — KBs, not the KV cache); device_get
-        # starts all five copies before it waits for the first, so the
-        # tick pays one transfer's latency, not five in a row
-        view = jax.device_get(fetch)
-        if turn is not None:
-            turn.leave(bytes=sum(v.nbytes for v in view.values()))
+            turn.leave(bytes=copied)
             turn.enter("deliver", cpu=True)
             tokens0 = self._tokens_c.value
+        # a row is its slot's only while the slot holds the record the
+        # chunk was dispatched with (a serial turn: every one of them)
+        recs = [(i, rec) for i, rec in flight.recs if self._slots[i] is rec]
         fields = {}     # of the deliver span: what the chunk counted
         self_draft = use_spec and self._speculative.kind == "self"
-        if use_spec and stepped:
+        if use_spec:
             proposed0 = self._spec_proposed_c.value
             accepted0 = self._spec_accepted_c.value
             # before the position counters move ``rec.pos``
@@ -1339,6 +1471,7 @@ class DecodeServer:
             if "expert_stats" in view:
                 fields = self._count_experts(view["expert_stats"])
             fields.update(self._count_kv_positions(recs, view, use_spec))
+            (self._ahead_c if serial is None else self._sync_cs[serial]).inc()
             # after the position counters: whoever sees the tick counted
             # sees its positions counted too
             self._ticks_c.inc()
@@ -1379,6 +1512,8 @@ class DecodeServer:
                 self._offer_prefix(i, rec, int(view["pos"][i]))
                 if rec.req.keep_drafts and "proposals" in self._state:
                     # this request alone pays the fetch, at its end
+                    # (``self._state`` may be a chunk on: the rows of a
+                    # slot that finished are what they were)
                     rec.req.draft_tokens = np.asarray(jax.device_get(
                         self._state["proposals"]))[
                             i, rec.prompt_len:rec.prompt_len + n_gen].copy()
@@ -1410,7 +1545,7 @@ class DecodeServer:
             self._occupancy_g.set(
                 self._active_count() / float(len(self._slots)))
         if turn is not None:
-            if use_spec and stepped:
+            if use_spec:
                 fields["proposed"] = int(
                     self._spec_proposed_c.value - proposed0)
                 fields["accepted"] = int(
@@ -1419,6 +1554,84 @@ class DecodeServer:
                 fresh_tokens=int(self._tokens_c.value - tokens0),
                 finished=sum(1 for i, _ in recs if self._slots[i] is None),
                 **fields)
+
+    def _takes_a_round(self, slots) -> bool:
+        """Whether a dispatch over ``slots`` is a speculative round: only
+        when an opted-in slot is live."""
+        return self._speculative is not None and any(s.spec for s in slots)
+
+    def _dispatch(self, recs) -> _Flight:
+        """Hand the device ONE chunk over the seated slots ``recs`` — a
+        speculative round where an opted-in slot is live (a pool with a
+        draft attached but no speculative traffic ticks the plain chunk:
+        one executable kind a dispatch, both warmed), nothing where
+        every seated slot is held (the turn was its prefill chunk) — and
+        return what is now in flight.  The ``decode.step`` fault point
+        fires once a dispatch."""
+        use_spec = self._takes_a_round(s for _, s in recs)
+        # hot-path: begin decode_tick (fault gate + the chunk dispatch;
+        # materialization happens OUTSIDE, after the async dispatch
+        # returns)
+        if _faults.active is not None:  # disarmed: one is-None gate
+            _faults.active.faultpoint(
+                "decode.step", server=self.name, active=len(recs))
+        if self._chunked and all(s.held for _, s in recs):
+            # nothing for a chunk to advance: the view is the state's
+            # own leaves, read before anything donates them
+            view = {k: self._state[k] for k in VIEW}
+            if self._expert_stats_of is not None:
+                view["expert_stats"] = self._expert_stats_of(
+                    self._state["cache"])
+            kind = "none"
+        else:
+            self._state, view = self._pool.chunk_view(
+                self._state, spec=use_spec)
+            kind = "spec_chunk" if use_spec else "chunk"
+        # hot-path: end decode_tick
+        return _Flight(view, recs, kind,
+                       self._pool.state_rungs(self._state))
+
+    def _why_serial(self, flight: _Flight) -> Optional[str]:
+        """THE rule of run-ahead, from what the host can observe: None
+        where the next chunk is dispatched BEFORE the turn waits for
+        ``flight`` (on the device, its view unread), else the first
+        reason (:data:`SYNC_REASONS`) the turn stays serial:
+
+        * ``free_seat`` — a seat is free or the slot ladder can grow: an
+          arrival is seated before the next chunk, not after it (what
+          keeps a lightly loaded and an emptying server on the serial
+          turn);
+        * ``held`` — a seated slot is held for a chunked prefill, whose
+          turns decide slot by slot (a turn that stepped nothing, too);
+        * ``length_finish`` — a live request can reach its ``total_len``
+          inside ``flight`` by the tokens the host has seen of it
+          (``steps`` more a plain chunk, ``k`` a round): its view may
+          finish a request, whose seat is handed on before the next
+          chunk;
+        * ``memory`` — the device's free bytes, read now with ``flight``
+          on it, do not hold TWO sets of the executable's temporaries
+          and view: the allocator's count shows neither the running
+          chunk's temporaries nor a queued one's (``bytes_in_use`` moved
+          by the view's 2.6 MB alone with a chunk of 147 MB of
+          temporaries running and a second queued: v5e chip run, PR 62,
+          ``tools/time_run_ahead.py``), so room is held for both.  A
+          backend that reports no limit: nothing to hold to."""
+        slots = self._slots
+        if None in slots or len(slots) < self._pool.max_slots:
+            return "free_seat"
+        if flight.kind == "none" or (
+                self._chunked and any(s.held for s in slots)):
+            return "held"
+        reach = self._speculative.k if flight.spec else self._pool.steps
+        if any(s.req.total_len - s.prompt_len - s.seen <= reach
+               for s in slots):
+            return "length_finish"
+        need = self._pool.queued_bytes(
+            self._state, spec=self._takes_a_round(slots))
+        free = _device_free_bytes(flight.view) if need else None
+        if free is not None and free < 2 * need:
+            return "memory"
+        return None
 
     def _prefill_turn(self, recs, turn: Optional[_Turn]) -> bool:
         """The turn's ONE prefill dispatch, before its decode chunk: the
@@ -1590,6 +1803,7 @@ class DecodeServer:
         self._set_pool_bytes(None)
 
     def _fail_in_flight(self, exc: BaseException) -> None:
+        self._flight = None     # a queued chunk's view is nobody's now
         n = 0
         for i, rec in enumerate(self._slots):
             if rec is not None:
@@ -1614,6 +1828,7 @@ class DecodeServer:
         if not self._worker.is_alive():
             self._fail_stragglers()
             # a stopped server holds no device memory, as an idle one
+            # (the loop's exit dropped a queued view: _fail_in_flight)
             self._state = None
             self._slots = []
         self._metrics.close()
@@ -1626,8 +1841,11 @@ class DecodeServer:
                        DECODE_ADMIT_DISPATCHES, DECODE_ADMITTED,
                        DECODE_IDLE_DROPS, POOL_CONSTANTS_PLACED,
                        DECODE_PREFILL_CHUNKS, DECODE_PREFILL_CHUNK_TOKENS,
-                       DECODE_SPARSE_READ, DECODE_SPARSE_LIVE):
+                       DECODE_SPARSE_READ, DECODE_SPARSE_LIVE,
+                       DECODE_CHUNKS_AHEAD):
             metric.remove_labels(**lbl)
+        for reason in SYNC_REASONS:
+            DECODE_SYNC_TURNS.remove_labels(reason=reason, **lbl)
         if self._speculative is not None:
             for metric in (SPEC_PROPOSED, SPEC_ACCEPTED, SPEC_ROUNDS,
                            SPEC_ROW_ROUNDS):

@@ -56,7 +56,11 @@ recurrent leaves whose builder has NO prefill still refuses
 ``prefix=True`` (a prefix of positions cannot rebuild a state, and
 nothing can stop at a boundary to copy one), and every pool over
 recurrent leaves refuses ``speculative=`` (a rejected round cannot be
-rolled back out of a state).  Buffer donation applies to the
+rolled back out of a state).  ``chunk`` and ``spec_chunk`` return the
+scheduler's view of the state beside it (:func:`with_view`: the few
+arrays a turn fetches, packed into one vector of its own), so a scheduler may dispatch
+the next chunk — which donates the state — before it has read this
+one's view.  Buffer donation applies to the
 state argument on every executable that returns a state — the multi-MB KV cache updates in
 place in device memory instead of being copied per tick — with the same
 CPU carve-out as the executor (``executor._donate_kwargs``: donation +
@@ -80,7 +84,71 @@ import numpy as np
 from paddle_tpu import compile_cache
 from paddle_tpu.serving.bucketing import BucketPolicy
 
-__all__ = ["KVSlotPool", "default_len_ladder"]
+__all__ = ["KVSlotPool", "default_len_ladder", "VIEW", "with_view",
+           "unpack_view"]
+
+#: the scheduler's view of a pool state: what a turn fetches of it
+VIEW = ("tokens", "pos", "active", "finished", "n_gen")
+
+
+def with_view(fn, expert_stats=None):
+    """``fn(state) -> state`` (a ``chunk``, a speculative round) as
+    ``state -> (state, view)``: the scheduler's view of the state it
+    returns — :data:`VIEW` and, where the builder declares one, its
+    ``expert_stats`` leaf — PACKED into ONE int32 vector
+    (:func:`unpack_view` is its reader), an output of its OWN that the
+    compiled program writes beside the state it updates in place.  The
+    state argument is donated whole, so every leaf of a returned state
+    dies with the next dispatch; the view does not, which is what lets a
+    scheduler queue the next chunk before it has read this one's
+    (``DecodeServer._tick``).  ONE array because every output that
+    aliases no argument costs a dispatch ~0.05 ms on a TPU (five of
+    them and the counts added 0.34 ms to every ``chunk`` call, 6% of a
+    chat tick: v5e chip runs, PR 62), and one transfer to the host
+    where five went.  Writing ``tokens`` ``[S, T]`` once more is 2-8 MB
+    on the device, microseconds."""
+    import jax.numpy as jnp
+
+    def viewed(state):
+        out = fn(state)
+        parts = [out[k].astype(jnp.int32).ravel() for k in VIEW]
+        if expert_stats is not None:
+            parts.append(expert_stats(out["cache"]).astype(jnp.int32).ravel())
+        return out, jnp.concatenate(parts)
+
+    return viewed
+
+
+def unpack_view(packed, s: int, t: int) -> Dict[str, np.ndarray]:
+    """The host's reading of :func:`with_view`'s vector for rung pair
+    ``(s, t)``: ``{name: array}`` over :data:`VIEW` (no copy: slices of
+    ``packed``; the two flags as bool), and under ``"expert_stats"``
+    whatever follows them, ``[-1, 4]`` (``routed_experts.STAT_NAMES``'
+    four sums a layer), where anything does."""
+    packed = np.asarray(packed)
+    view = {"tokens": packed[:s * t].reshape(s, t)}
+    at = s * t
+    for k in VIEW[1:]:
+        view[k] = packed[at:at + s]
+        at += s
+    for k in ("active", "finished"):
+        view[k] = view[k] != 0
+    if at < packed.size:
+        view["expert_stats"] = packed[at:].reshape(-1, 4)
+    return view
+
+
+def _bytes_beside_the_state(exe) -> Optional[int]:
+    """What one execution of the compiled ``exe`` allocates beside its
+    arguments: its temporaries and the outputs that alias no argument
+    (the view; on the CPU, where nothing is donated, the whole state).
+    None where the backend's executable cannot say."""
+    try:
+        m = exe.memory_analysis()
+        return int(m.temp_size_in_bytes + m.output_size_in_bytes
+                   - m.alias_size_in_bytes)
+    except Exception:  # noqa: BLE001 — no analysis: nothing to hold to
+        return None
 
 
 def default_len_ladder(max_seq_len: int, start: int = 8) -> List[int]:
@@ -129,6 +197,9 @@ class KVSlotPool:
 
         self._make_cache = make_cache
         spec = spec_of(make_cache)
+        #: the builder's leaf of device-made counts (None: it has none),
+        #: part of the view a ``chunk`` / ``spec_chunk`` returns
+        self._expert_stats = spec.expert_stats
         #: tree paths of the cache leaves declared recurrent (no
         #: sequence axis); empty for a K/V-only cache
         self.recurrent_leaves = spec.names(lambda leaf: leaf.seq_axis is None)
@@ -266,6 +337,9 @@ class KVSlotPool:
         # before anything compiles
         self._state_spec(*self.rung_pairs()[0])
         self._exe: Dict[Tuple[str, int, int], object] = {}
+        # per ``chunk`` / ``spec_chunk`` built: the device bytes one more
+        # execution of it takes beside the state (:meth:`queued_bytes`)
+        self._queued: Dict[Tuple[str, int, int], Optional[int]] = {}
         # host-born constants :meth:`_lower` hoisted: the pool's one
         # device copy of each distinct one, how many copies it made, and
         # per executable kind what it found (count, bytes)
@@ -561,12 +635,15 @@ class KVSlotPool:
         from paddle_tpu.executor import _donate_kwargs
 
         spec = arg_specs[0]  # the state's: its rung pair names the build
+        fn = getattr(self, "_%s_fn" % kind)
+        viewed = kind in ("chunk", "spec_chunk")
+        if viewed:
+            fn = with_view(fn, self._expert_stats)
         with compile_cache.build(
                 kind, rungs=list(spec["tokens"].shape)) as built:
             with compile_cache.build_stage(kind, "trace"):
                 closed, out_shape = jax.make_jaxpr(
-                    getattr(self, "_%s_fn" % kind),
-                    return_shape=True)(*arg_specs)
+                    fn, return_shape=True)(*arg_specs)
             out_tree = jax.tree.structure(out_shape)
 
             def hoisted(consts, *args):
@@ -581,6 +658,10 @@ class KVSlotPool:
                     closed.consts, *arg_specs)
             with compile_cache.build_stage(kind, "compile"):
                 exe = lowered.compile()
+            if viewed:
+                with self._lock:
+                    self._queued[(kind,) + tuple(spec["tokens"].shape)] = (
+                        _bytes_beside_the_state(exe))
             with compile_cache.build_stage(kind, "place"):
                 wanted = exe.input_shardings[0][0]  # of ``consts``, one each
                 host_born = [np.asarray(c).nbytes for c in closed.consts
@@ -667,14 +748,33 @@ class KVSlotPool:
         """Advance every active slot by up to ``steps`` tokens in ONE
         device dispatch (a prompt that was not prefilled at its
         admission steps through its tokens inside, beside the rows that
-        decode)."""
+        decode); the state alone (:meth:`chunk_view` also hands back the
+        scheduler's view)."""
+        return self.chunk_view(state)[0]
+
+    def chunk_view(self, state, spec: bool = False):
+        """The scheduler's dispatch: one ``chunk`` — or, ``spec``, one
+        speculative round (``spec_chunk``) — over ``state``; returns
+        ``(state, view)``, the view (:func:`with_view`) ONE device
+        vector of its own that outlives the returned state's donation to
+        the next dispatch (:func:`unpack_view` reads it on the host)."""
         s, t = self.state_rungs(state)
         # hot-path: begin kv_chunk (executable lookup + async dispatch;
         # the scheduler materializes results OUTSIDE this region)
-        exe = self._get_exe("chunk", s, t)
+        exe = self._get_exe("spec_chunk" if spec else "chunk", s, t)
         out = exe(state)
         # hot-path: end kv_chunk
         return out
+
+    def queued_bytes(self, state, spec: bool = False) -> Optional[int]:
+        """Device bytes one more ``chunk`` (``spec``: round) over
+        ``state``'s rung pair takes beside the state while it is queued
+        or runs — its temporaries and its view, by the compiled
+        executable's ``memory_analysis()``, read once where it was
+        built.  None where that is not known."""
+        with self._lock:
+            return self._queued.get(
+                ("spec_chunk" if spec else "chunk",) + self.state_rungs(state))
 
     def admit(self, state, slot, prompt, prompt_len, total_len,
               spec=False) -> Dict[str, object]:

@@ -373,13 +373,13 @@ def make_self_draft_chunk_fn(verify_fn, module_fn, eos_id: int):
 
 def dispatch_spec_chunk(pool, state):
     """Run one speculative round on ``state`` through the pool's warmed
-    ``spec_chunk`` executable for its current rung pair (the scheduler's
-    tick-path call — mirror of ``KVSlotPool.chunk``)."""
-    s, t = pool.state_rungs(state)
+    ``spec_chunk`` executable for its current rung pair — mirror of
+    ``KVSlotPool.chunk``: the state alone (the scheduler's tick-path
+    call, ``KVSlotPool.chunk_view(state, spec=True)``, also hands back
+    the round's view)."""
     # hot-path: begin spec_verify (executable lookup + async dispatch of
-    # the fused draft+verify round; the scheduler materializes results
+    # the fused draft+verify round; whoever reads results does so
     # OUTSIDE this region)
-    exe = pool._get_exe("spec_chunk", s, t)
-    out = exe(state)
+    out, _ = pool.chunk_view(state, spec=True)
     # hot-path: end spec_verify
     return out

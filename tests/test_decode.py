@@ -1346,9 +1346,10 @@ def test_counts_a_builder_makes_on_the_device_reach_the_four_counters(
     if traced:
         copied = {s["args"]["bytes"] for s in spans
                   if s["name"] == "serving/decode/copy"}
-        # the five arrays of the view and the 16 bytes of counts
+        # the five arrays of the view, packed as int32, and the 16 bytes
+        # of counts: ONE vector a turn
         s_, t_ = 4, 16
-        assert copied == {s_ * t_ * 4 + 2 * s_ * 4 + 2 * s_ + 16}
+        assert copied == {s_ * t_ * 4 + 4 * s_ * 4 + 16}
 
 
 def test_counts_start_again_with_a_fresh_pool_state(monkeypatch):
@@ -1529,3 +1530,406 @@ def test_an_untraced_turn_reads_the_sink_once_and_one_clock(monkeypatch):
     finally:
         srv.stop(drain=False)
     assert got == {"tick": n, "recording": n, "perf_counter": n}
+
+
+# ---------------------------------------------------------------------------
+# run-ahead of depth one: a full pool with nothing to decide keeps ONE
+# chunk queued behind the one that runs (DecodeServer._why_serial)
+# ---------------------------------------------------------------------------
+def _pin_serial(monkeypatch):
+    """Every turn waits for its chunk with nothing queued: the parent's
+    scheduler."""
+    monkeypatch.setattr(DecodeServer, "_why_serial",
+                        lambda self, flight: "free_seat")
+
+
+def _turns(srv):
+    """(ticks, chunks dispatched ahead, serial turns by reason), from the
+    series' own children: they outlive the server's ``stop``."""
+    return (int(srv._ticks_c.value), int(srv._ahead_c.value),
+            {r: int(c.value) for r, c in srv._sync_cs.items()})
+
+
+def _assert_turns_add_up(srv):
+    ticks, ahead, sync = _turns(srv)
+    assert ahead + sum(sync.values()) == ticks
+    d = srv.metrics()["decode"]
+    assert (d["ticks"], d["chunks_ahead"], d["sync_turns"]) == (
+        ticks, ahead, sync)
+    assert d["ahead_share"] == (ahead / ticks if ticks else 0.0)
+    return ticks, ahead, sync
+
+
+def _four_slot_chain(name, **kw):
+    step_fn, make_cache = chain_model()
+    srv = DecodeServer(step_fn, make_cache, eos_id=EOS, max_seq_len=16,
+                       max_slots=4, slot_ladder=[4], len_ladder=[16],
+                       steps_per_tick=2, name=name, **kw)
+    srv.warmup(configure_cache=False)
+    return srv
+
+
+def _ask(srv, prompt, n, **kw):
+    return srv.submit({"tokens": np.asarray(prompt, np.int32)},
+                      max_new_tokens=n, **kw)
+
+
+def _streamed(req):
+    """Every token pushed to ``req`` so far (a failed request's too)."""
+    out = []
+    while not req._chunks.empty():
+        kind, val = req._chunks.get_nowait()
+        if kind == "tokens":
+            out.extend(val.tolist())
+    return out
+
+
+def _lm_server(lm_state, name, speculative=None, eos=EOS, **kw):
+    step_fn, make_cache = make_transformer_lm_pooled_step_fn(
+        lm_state, LM_DIMS["vocab"], LM_DIMS["d_model"], LM_DIMS["n_layer"],
+        LM_DIMS["n_head"], LM_DIMS["d_inner"])
+    return DecodeServer(step_fn, make_cache, eos_id=eos, max_seq_len=32,
+                        max_slots=4, slot_ladder=[4], len_ladder=[32],
+                        steps_per_tick=2, name=name, speculative=speculative,
+                        **kw)
+
+
+def _draft_model_server(lm_state, name, eos=EOS):
+    from paddle_tpu.serving.speculative import make_lm_speculative
+
+    draft = dict(d_model=8, n_layer=1, n_head=1, d_inner=16)
+    dims = {k: LM_DIMS[k] for k in ("d_model", "n_layer", "n_head",
+                                    "d_inner")}
+    return _lm_server(lm_state, name, eos=eos, speculative=make_lm_speculative(
+        lm_state, vocab_size=V, draft_state=lm_weights(
+            np.random.RandomState(8), vocab=V, max_pos=LM_DIMS["max_pos"],
+            name="draft", **draft),
+        k=3, **dims, **{"draft_" + k: v for k, v in draft.items()}))
+
+
+def _self_draft_server(name, eos):
+    from paddle_tpu import decoding
+    from paddle_tpu.serving.speculative import make_self_draft
+    from test_k_exaone_lm import CHUNK, tiny_cfg, weights
+
+    cfg = tiny_cfg()
+    step, make_cache, _ = decoding.make_mtp_routed_lm_pooled_step_fn(
+        weights(cfg, seed=4), cfg, kv_dtype="fp32", prefill_tokens=CHUNK)
+    return DecodeServer(
+        step, make_cache, eos_id=eos, max_seq_len=64, max_slots=4,
+        slot_ladder=(4,), len_ladder=(64,), steps_per_tick=2,
+        queue_capacity=64, target_queue_wait_ms=600000.0, kv_dtype="fp32",
+        name=name, speculative=make_self_draft(make_cache))
+
+
+def _served(srv, prompts, caps, **kw):
+    """Every request's tokens and kept proposals, and the turns' counts."""
+    try:
+        srv.warmup(configure_cache=False)
+        reqs = [_ask(srv, p, c, **kw) for p, c in zip(prompts, caps)]
+        got = [np.concatenate(r.result(timeout=WAIT)).tolist() for r in reqs]
+        turns = _assert_turns_add_up(srv)
+    finally:
+        srv.stop(drain=False, timeout=WAIT)
+    return got, [None if r.draft_tokens is None else r.draft_tokens.tolist()
+                 for r in reqs], turns
+
+
+@pytest.mark.parametrize("kind", ["plain", "draft_model", "self_draft"])
+def test_run_ahead_serves_token_for_token_what_the_serial_turn_serves(
+        kind, lm_state, monkeypatch):
+    """Ten requests through four slots — lengths that finish at
+    different turns, an EOS here and there found one chunk late — under
+    run-ahead and under a server pinned serial: the same tokens and,
+    where a self-drafting round keeps them, the same proposals."""
+    rng = np.random.RandomState(3)
+    vocab = 97 if kind == "self_draft" else V
+    prompts = [rng.randint(0, vocab, int(n)).astype(np.int32)
+               for n in rng.randint(2, 11, 10)]
+    caps = [9, 14, 20, 11, 16, 7, 18, 12, 10, 15]
+    kw = {}
+    if kind == "plain":
+        make = lambda name, eos: _lm_server(lm_state, name, eos=eos)
+    elif kind == "draft_model":
+        make = lambda name, eos: _draft_model_server(lm_state, name, eos)
+        kw = dict(speculative=True)
+    else:
+        make = _self_draft_server
+        kw = dict(speculative=True, keep_drafts=True)
+    with monkeypatch.context() as pinned:
+        _pin_serial(pinned)
+        # no token ends a request of a random model by itself: take one
+        # the longest answer holds half-way
+        probe, _, _ = _served(make("ahead-probe-" + kind, vocab), prompts,
+                              caps, **kw)
+        eos = probe[2][len(probe[2]) // 2]
+        want, want_drafts, (ticks, ahead, sync) = _served(
+            make("ahead-%s-serial" % kind, eos), prompts, caps, **kw)
+        assert ahead == 0 and sync["free_seat"] == ticks > 0
+    got, got_drafts, (ticks, ahead, _) = _served(
+        make("ahead-%s" % kind, eos), prompts, caps, **kw)
+    assert got == want and got_drafts == want_drafts
+    assert 0 < ahead < ticks
+    # an EOS ended a request early: a finish no length predicts
+    assert any(len(g) < c for g, c in zip(got, caps))
+    if kind == "self_draft":
+        assert all(len(d) == len(g) for d, g in zip(got_drafts, got))
+
+
+@pytest.mark.parametrize("how", ["eos", "deadline", "abandoned"])
+def test_a_slot_freed_behind_a_queued_chunk_is_freed_a_turn_late_and_leaks_nothing(
+        how):
+    """Slot 0's request ends inside a chunk that has another queued
+    behind it — an EOS, an expired deadline, a stream given up: the host
+    finds it when the older view is read, the slot sat through the
+    queued chunk, and the request seated into it next is served ITS
+    tokens from the first chunk dispatched after its seat — never the
+    queued chunk's row, which is the old request's."""
+    srv = _four_slot_chain("ahead-freed-" + how)
+    try:
+        with turn_held(srv) as run:
+            first = _ask(srv, [5] if how == "eos" else [10], 10,
+                         timeout_ms=None if how == "eos" else 600000.0)
+            rest = [_ask(srv, [10], 10) for _ in range(3)]
+            late = _ask(srv, [12], 4)            # queued: no seat for it
+            run(1)          # chunk 1 read, chunk 2 queued behind it
+            assert srv._flight is not None and _turns(srv)[:2] == (1, 1)
+            if how == "deadline":
+                first.deadline = time.monotonic() - 1.0
+            elif how == "abandoned":
+                first.fail(ServingError("the caller went away"))
+            run(1)          # chunk 2 read (it ends the first), 3 queued
+            assert first.done() and srv._slots[0] is None
+            assert srv._flight is not None and _turns(srv)[:2] == (2, 2)
+            assert _streamed(first) == (
+                [6, 7, 8, 9] if how == "eos" else [11, 12, 13, 14])
+            run(1)          # late seated behind chunk 3; its row is old
+            assert srv._slots[0].req is late and late.first_token_t is None
+            assert _turns(srv)[:2] == (3, 3)
+            run(1)          # chunk 4: the first that stepped late
+            assert _streamed(late) == [13, 14]
+        assert late.result(timeout=WAIT)[0].tolist() == [13, 14, 15, 16]
+        for r in rest:
+            assert r.result(timeout=WAIT)[0].tolist() == expected_chain(
+                [10], 11)
+        if how == "eos":
+            assert first.result(timeout=WAIT)[0].tolist() == [6, 7, 8, 9]
+        else:
+            with pytest.raises(DeadlineExceeded if how == "deadline"
+                               else ServingError):
+                first.result(timeout=WAIT)
+        # what the first request's slot stepped in chunk 3 reached nobody
+        assert _streamed(first) == []
+        m = srv.metrics()["decode"]
+        assert m["generated_tokens"] == 3 * 10 + 4 + 4
+        _assert_turns_add_up(srv)
+    finally:
+        srv.stop(drain=False, timeout=WAIT)
+
+
+@pytest.mark.parametrize("where", ["dispatch", "materialisation"])
+def test_a_failure_with_a_chunk_queued_fails_every_seat_typed_and_heals(
+        where, monkeypatch):
+    """A fault at ``decode.step`` as the next chunk is dispatched behind
+    a running one, and an error that surfaces when a view is read with a
+    chunk queued behind it: every seated request fails typed, the queued
+    view is dropped with the pool, and the server serves on."""
+    import jax
+
+    from paddle_tpu import faults
+
+    srv = _four_slot_chain("ahead-fails-" + where)
+    real_get, boom = jax.device_get, []
+
+    def failing_get(x):
+        if boom and threading.current_thread() is srv._worker:
+            raise boom.pop()
+        return real_get(x)
+
+    monkeypatch.setattr(jax, "device_get", failing_get)
+    try:
+        with turn_held(srv) as run:
+            reqs = [_ask(srv, [10], 10) for _ in range(4)]
+            run(1)
+            assert srv._flight is not None
+            if where == "dispatch":
+                faults.arm("decode.step=error:RuntimeError,times=1")
+            else:
+                boom.append(RuntimeError("the chunk failed on the device"))
+            run(1)
+            assert srv._flight is None and srv._state is None
+        for r in reqs:
+            with pytest.raises(RuntimeError):
+                r.result(timeout=WAIT)
+            assert _streamed(r) == [11, 12]
+        assert srv.metrics()["failed"] == 4
+        assert _ask(srv, [4, 5], 8).result(timeout=WAIT)[0].tolist() == [
+            6, 7, 8, 9]
+        assert srv.metrics().get("recompiles", 0) == 0
+        _assert_turns_add_up(srv)
+    finally:
+        faults.disarm()
+        srv.stop(drain=False, timeout=WAIT)
+
+
+@pytest.mark.parametrize("drain", [True, False])
+def test_stop_with_a_chunk_queued_delivers_it_or_drops_it(drain):
+    """Four requests end by EOS in chunk 2 with chunk 3 queued behind
+    it: a draining stop reads chunk 3's view (nobody's rows: the turns
+    still add up) before its loop returns; an abort drops the queued
+    view and does not hang."""
+    srv = _four_slot_chain("ahead-stop-%d" % drain)
+    with turn_held(srv) as run:
+        reqs = [_ask(srv, [5], 10) for _ in range(4)]
+        run(2 if drain else 1)
+        assert srv._flight is not None
+    srv.stop(drain=drain, timeout=WAIT)
+    assert not srv._worker.is_alive() and srv._flight is None
+    if drain:
+        for r in reqs:
+            assert r.result(timeout=WAIT)[0].tolist() == [6, 7, 8, 9]
+        assert _turns(srv) == (3, 2, {"free_seat": 1, "held": 0,
+                                      "length_finish": 0, "memory": 0})
+    else:
+        for r in reqs:
+            with pytest.raises(ServerClosed):
+                r.result(timeout=WAIT)
+
+
+@pytest.mark.parametrize("reason", ["free_seat", "held", "length_finish",
+                                    "memory"])
+def test_each_reason_keeps_the_turn_serial_in_the_scenario_built_for_it(
+        reason, monkeypatch):
+    """One free seat; a held slot of a chunked builder; a request one
+    token from its length; no free byte on the device: the first turn of
+    each waits with nothing queued and says why, and the turns add up."""
+    from paddle_tpu.serving import decode as decode_mod
+
+    if reason == "held":
+        srv = _sum_server("ahead-why-held")
+        srv.warmup(configure_cache=False)
+        asks = [(np.arange(1, 3 * SUM_C + 3) % SUM_V, 12), ([1, 2, 3], 20)]
+    else:
+        srv = _four_slot_chain("ahead-why-" + reason)
+        asks = [([10], 10)] * 4
+        if reason == "free_seat":
+            asks = asks[:3]
+        elif reason == "length_finish":
+            asks = [([10], 3)] + asks[1:]     # its second chunk ends it
+        else:
+            monkeypatch.setattr(decode_mod, "_device_free_bytes",
+                                lambda array: 0)
+    others = [r for r in decode_mod.SYNC_REASONS if r != reason]
+    try:
+        with turn_held(srv) as run:
+            reqs = [_ask(srv, p, n) for p, n in asks]
+            run(2 if reason == "length_finish" else 1)
+            ticks, ahead, sync = _turns(srv)
+            if reason == "length_finish":
+                # three tokens to go: the first chunk could not end it
+                assert (ticks, ahead, sync[reason]) == (2, 1, 1)
+            else:
+                assert (ticks, ahead, sync[reason]) == (1, 0, 1)
+            assert srv._flight is None
+            assert not any(sync[r] for r in others)
+        for r in reqs:
+            r.result(timeout=WAIT)
+        ticks, ahead, sync = _assert_turns_add_up(srv)
+        if reason in ("free_seat", "memory"):
+            assert ahead == 0
+    finally:
+        srv.stop(drain=False, timeout=WAIT)
+
+
+def test_a_turn_ahead_is_one_tick_tiled_by_the_same_leaves():
+    """Four long answers through four slots under a span sink: every
+    turn is ONE ``serving/decode_tick`` tiled by ONE leaf a phase; its
+    ``dispatch`` says whether a chunk was queued behind the one the turn
+    waited for (``ahead``) and how many executables it launched — two
+    where a run of turns ahead begins, none where it ends, and one a
+    tick in all."""
+    srv = _four_slot_chain("ahead-spans")
+    spans = _served_under_recording(srv, [[10]] * 4, 11)
+    _assert_ticks_are_tiled(spans)
+    ticks, ahead, _ = _turns(srv)
+    launched = [s["args"] for s in spans
+                if s["name"] == "serving/decode/dispatch"]
+    assert len(launched) == ticks
+    assert sum(a["ahead"] for a in launched) == ahead > 0
+    assert sum(a["chunks"] for a in launched) == ticks
+    assert {a["chunks"] for a in launched} == {0, 1, 2}
+    assert {a["kind"] for a in launched} == {"chunk"}
+    # the view a turn ahead reads is the OLDER chunk's: its tokens reach
+    # the callers one turn after the chunk that made them was launched
+    fresh = [s["args"]["fresh_tokens"] for s in spans
+             if s["name"] == "serving/decode/deliver"]
+    assert sum(fresh) == 4 * 11 and max(fresh) == 4 * 2
+
+
+def test_a_prefix_kept_from_a_slot_freed_a_chunk_late_serves_the_same_tokens(
+        lm_state, monkeypatch):
+    """A stream given up with a chunk queued behind the one running: the
+    slot's prefix K/V is read out of a state one chunk on (rows below
+    the positions the older view counted are what they were), and the
+    request seated over it is served what a server with no prefix cache
+    serves."""
+    from paddle_tpu.serving.prefix_cache import PrefixKVCache
+
+    rng = np.random.RandomState(11)
+    head = rng.randint(10, V, 8).astype(np.int32)      # no EOS in it
+    asks = [(np.concatenate([head, [10 + i]]), 16) for i in range(4)]
+    again = (np.concatenate([head, [17, 18]]), 12)
+    with monkeypatch.context() as pinned:
+        _pin_serial(pinned)
+        want, _, _ = _served(_lm_server(lm_state, "ahead-prefix-serial"),
+                             [again[0]], [again[1]])
+    cache = PrefixKVCache(capacity_bytes=1 << 20, block_tokens=4,
+                          name="ahead-prefix")
+    srv = _lm_server(lm_state, "ahead-prefix", prefix_cache=cache)
+    srv.warmup(configure_cache=False)
+    try:
+        with turn_held(srv) as run:
+            reqs = [_ask(srv, p, n) for p, n in asks]
+            run(1)
+            for _ in range(8):       # until a chunk is queued behind one
+                if srv._flight is not None:
+                    break
+                run(1)
+            assert srv._flight is not None
+            reqs[0].fail(ServingError("the caller went away"))
+            run(1)
+            assert srv._slots[0] is None and cache.stats()["entries"] == 1
+            late = _ask(srv, *again)
+            run(1)
+            assert srv._slots[0].req is late
+        assert np.concatenate(late.result(timeout=WAIT)).tolist() == want[0]
+        assert cache.stats()["hits"] == 1
+        _assert_turns_add_up(srv)
+    finally:
+        srv.stop(drain=False, timeout=WAIT)
+
+
+def test_the_run_ahead_probe_rehearses_and_both_orders_read_the_same_views():
+    """``tools/time_run_ahead.py`` (what a chunk queued behind a running
+    one costs the device and saves the turn) runs to its end at a cell's
+    tiny sizes: the views read after the next dispatch are the serial
+    order's, and a rehearsal prints no time."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "tools", "time_run_ahead.py"),
+         "gpt1_117m", "--rehearse-cpu", "--rounds", "4"],
+        cwd=root, capture_output=True, text=True, timeout=240)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    lines = proc.stdout.strip().splitlines()
+    assert lines[-2].startswith("REHEARSAL")
+    out = json.loads(lines[-1])
+    assert out["views_equal_in_both_orders"] is True
+    assert set(out["orders"]) == {"serial", "ahead", "serial_again",
+                                  "ahead_again"}
+    assert all(set(row) == {"views_sha1"} for row in out["orders"].values())
+    assert out["memory"]["queued_bytes_by_memory_analysis"] > 0

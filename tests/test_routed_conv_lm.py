@@ -435,9 +435,10 @@ def test_decode_server_end_to_end_with_slot_reuse_and_expert_counters():
 
 def test_an_untraced_turn_fetches_once_and_dispatches_nothing_new(
         monkeypatch):
-    """The counts ride the tick's ONE ``device_get`` (six arrays where
-    five went) and no dispatch is added: a tick is still one ``chunk``
-    call; a builder that declares no counts fetches the five."""
+    """The counts ride the tick's ONE ``device_get`` (since PR 62 of ONE
+    vector a chunk packs: the five arrays of the view as int32, then the
+    counts) and no dispatch is added: a tick is still one ``chunk``
+    call; a builder that declares no counts fetches the five alone."""
     import jax
 
     cfg = rehearse_cfg()
@@ -447,11 +448,10 @@ def test_an_untraced_turn_fetches_once_and_dispatches_nothing_new(
     gets, chunks = [], []
     real_get = jax.device_get
     monkeypatch.setattr(jax, "device_get", lambda x: (
-        gets.append(sorted(x) if isinstance(x, dict) else type(x)),
-        real_get(x))[1])
-    real_chunk = KVSlotPool.chunk
-    monkeypatch.setattr(KVSlotPool, "chunk", lambda self, st: (
-        chunks.append(1), real_chunk(self, st))[1])
+        gets.append(getattr(x, "shape", type(x))), real_get(x))[1])
+    real_chunk = KVSlotPool.chunk_view     # the scheduler's dispatch
+    monkeypatch.setattr(KVSlotPool, "chunk_view", lambda self, st, **kw: (
+        chunks.append(1), real_chunk(self, st, **kw))[1])
     srv = DecodeServer(step, make_cache, eos_id=V, max_seq_len=16,
                        max_slots=2, slot_ladder=[2], len_ladder=[16],
                        steps_per_tick=2, kv_dtype="fp32", name="routed-get")
@@ -463,10 +463,12 @@ def test_an_untraced_turn_fetches_once_and_dispatches_nothing_new(
         ticks = srv.metrics()["decode"]["ticks"]
     finally:
         srv.stop(drain=False, timeout=30)
-    fetched = [g for g in gets if isinstance(g, list)]
+    fetched = [g for g in gets if isinstance(g, tuple)]
     assert len(fetched) == ticks == len(chunks)
-    assert all(g == ["active", "expert_stats", "finished", "n_gen", "pos",
-                     "tokens"] for g in fetched)
+    # tokens [2, 16], four [2]s, then four sums an expert layer
+    assert len(set(fetched)) == 1 and len(fetched[0]) == 1
+    counts = fetched[0][0] - (2 * 16 + 4 * 2)
+    assert counts > 0 and counts % 4 == 0
 
 
 def test_a_builder_without_counts_fetches_the_five_and_counts_nothing(
@@ -480,8 +482,7 @@ def test_a_builder_without_counts_fetches_the_five_and_counts_nothing(
     gets = []
     real_get = jax.device_get
     monkeypatch.setattr(jax, "device_get", lambda x: (
-        gets.append(sorted(x) if isinstance(x, dict) else type(x)),
-        real_get(x))[1])
+        gets.append(getattr(x, "shape", type(x))), real_get(x))[1])
     srv = DecodeServer(step, make_cache, eos_id=31, max_seq_len=16,
                        max_slots=2, slot_ladder=[2], len_ladder=[16],
                        steps_per_tick=2, name="no-counts")
@@ -492,7 +493,7 @@ def test_a_builder_without_counts_fetches_the_five_and_counts_nothing(
         m = srv.metrics()["decode"]
     finally:
         srv.stop(drain=False, timeout=30)
-    assert all(g == ["active", "finished", "n_gen", "pos", "tokens"]
-               for g in gets if isinstance(g, list))
+    fetched = [g for g in gets if isinstance(g, tuple)]
+    assert fetched and set(fetched) == {(2 * 16 + 4 * 2,)}
     assert m["expert_assignments"] == m["experts_touched"] == 0
     assert m["expert_peak_load"] == m["expert_layer_steps"] == 0

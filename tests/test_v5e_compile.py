@@ -831,3 +831,53 @@ def test_dense_latent_round_and_prefill_fit_v5e_and_copy_no_leaf(
     else:
         assert not counted["kernel"] and not calls  # expanded, by key blocks
         assert mem.temp_size_in_bytes < 0.5e9
+
+
+@pytest.mark.parametrize("config,kind", [
+    ("k_exaone_236b_a23b", "spec_chunk"), ("k_exaone_236b_a23b", "chunk"),
+    ("openpangu_ultra_moe_718b", "spec_chunk"),
+    ("openpangu_ultra_moe_718b", "chunk")])
+def test_a_chunk_returns_the_view_beside_a_state_aliased_whole_on_v5e(
+        one_chip, config, kind):
+    """The scheduler's view (``kv_pool.with_view``: ``tokens``, ``pos``,
+    ``active``, ``finished``, ``n_gen`` and the builder's
+    ``expert_stats``) leaves the ``chunk`` and the self-drafting round of
+    both cells whose tick is one round as ONE output of its own — a
+    packed int32 vector the program writes, alive after the state is
+    donated to the next dispatch — at the cells' own depth (the whole
+    5-layer cuts): every leaf of the state is still aliased to its
+    argument, in place, and no ``copy`` bears the shape of a cache leaf
+    (the vector is all the parent's text lacks:
+    ``tools/decode_chunk_text.py`` at both checkouts)."""
+    import re
+
+    import jax
+
+    with _chunk_tool() as (tool, root):
+        lowered = tool.lowered_chunk(root, config, kind, 5)
+        compiled = lowered.compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    state = lowered.args_info[0][2]
+    leaves = jax.tree.leaves(state)
+    header = text.splitlines()[0]
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", header)
+    assert aliased.group(1).count("-alias)") == len(leaves)
+    state_bytes = sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+                      for a in leaves)
+    assert mem.alias_size_in_bytes >= state_bytes
+    (entry,) = [line for line in text.splitlines()
+                if line.startswith("ENTRY ")]
+    outputs = entry.rsplit(") -> (", 1)[1]
+    assert len(re.findall(r"\w+\[[\d,]*\]", outputs)) == len(leaves) + 1
+    # what is not aliased: the view (tokens above all) and little else
+    s, t = state["tokens"].shape
+    assert 0 < mem.output_size_in_bytes - mem.alias_size_in_bytes < (
+        2 * s * t * 4)
+    stats = state["cache"]["expert_stats"].shape
+    assert "s32[%d]" % (s * t + 4 * s + stats[0] * stats[1]) in outputs
+    # (a ``copy-start`` of a leaf is the compiler's fetch ahead of a call)
+    copies = [line for line in text.splitlines() if " copy(" in line]
+    for leaf in jax.tree.leaves(state["cache"]):
+        if len(leaf.shape) >= 3:
+            shape = "[%s]" % ",".join(map(str, leaf.shape))
+            assert not any(shape in line for line in copies), shape

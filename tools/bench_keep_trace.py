@@ -11,8 +11,10 @@ printed as always; ``chiprun_out/<cell>.instructions.json`` gets
 ``[[self seconds, instruction text], ...]``, longest first, and the
 modules' run times; ``chiprun_out/<cell>.spans.json`` gets, per span
 name, how many were recorded and their seconds (a ``DecodeServer``
-turn's phases: seconds a ``serving/decode_tick``, and the share of the
-ticks their leaves cover), every device gap's name with its seconds,
+turn's phases: seconds a ``serving/decode_tick``, the share of the
+ticks their leaves cover, and ``turns_ahead_share``, the share of the
+turns read whose ``dispatch`` queued a chunk behind the one it waited
+for), every device gap's name with its seconds,
 and the ``serving/...`` events found on the trace's own host lines;
 ``chiprun_out/<cell>.counters.json`` gets what the process counted, from
 its start to its end, of which form a step's attention was lowered to
@@ -51,7 +53,16 @@ def spans_summary(spans, trace):
         if s.get("parent") in ticks:
             under[s["name"]] += s["dur"]
     n = max(len(ticks), 1)
+    # a turn's ``dispatch`` says whether it queued a chunk behind the one
+    # it waited for (PR 62; a checkout from before it says nothing)
+    launched = [s["args"] for s in spans if s.get("parent") in ticks
+                and s["name"] == "serving/decode/dispatch"
+                and "ahead" in s.get("args", {})]
     return {
+        "turns_ahead_share": (sum(a["ahead"] for a in launched)
+                              / len(launched) if launched else None),
+        "chunks_a_dispatch_leaf": dict(collections.Counter(
+            str(a["chunks"]) for a in launched)),
         "by_name": {k: {"count": v[0], "seconds": v[1], "cpu_s": v[2]}
                     for k, v in sorted(by.items())},
         "ticks": len(ticks),

@@ -281,10 +281,21 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
         def build(w):
             return parts
 
+    def viewed(fn, w):
+        # the scheduler's view as outputs of their own beside the state,
+        # as the pool compiles a ``chunk`` / ``spec_chunk`` since PR 62
+        # (``kv_pool.with_view``; a checkout from before it: the state)
+        from paddle_tpu.serving import kv_pool
+
+        with_view = getattr(kv_pool, "with_view", None)
+        return fn if with_view is None else with_view(
+            fn, decoding.spec_of(build(w)[1]).expert_stats)
+
     def chunk(w, state):
         step_fn, _ = build(w)
-        return decoding.make_slot_decode_fns(
-            step_fn, int(cfg["vocab_size"]), sv["steps_per_tick"])[0](state)
+        return viewed(decoding.make_slot_decode_fns(
+            step_fn, int(cfg["vocab_size"]), sv["steps_per_tick"])[0],
+                      w)(state)
 
     def prefill(w, state):
         # one slot's next chunk of prompt tokens, as the pool runs it
@@ -303,8 +314,8 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
         # that declares its verify and its module (k_exaone_236b_a23b)
         from paddle_tpu.serving.speculative import make_self_draft
 
-        return pool_of(w, speculative=make_self_draft(
-            build(w)[1]))._spec_chunk_fn(state)
+        return viewed(pool_of(w, speculative=make_self_draft(
+            build(w)[1]))._spec_chunk_fn, w)(state)
 
     def admit_prefix(w, state, mask, prompt, prompt_len, total_len, kv,
                      prefix_len, spec_flag):
