@@ -32,9 +32,12 @@ prefill), ``make_routed_conv_lm_pooled_step_fn`` (layers that hold
 different leaves, routed experts, counts made on the device) and
 ``make_delta_hybrid_lm_pooled_step_fn`` (a recurrent state that is read
 before it is written), ``make_latent_sparse_lm_pooled_step_fn``
-(latent leaves read through a learned per-row selection) and
+(latent leaves read through a learned per-row selection),
 ``make_latent_mtp_lm_pooled_step_fn`` (latent leaves read densely, K
-rows a slot, under a self-drafting round) — are made of the same parts:
+rows a slot, under a self-drafting round) and
+``make_kda_latent_lm_pooled_step_fn`` (a recurrent delta-rule state
+beside latent leaves, the rule's chunkwise form in its prefill) — are
+made of the same parts:
 
 * ONE cache format, whatever the storage dtype (fp32, bf16, int8):
   ``paddle_tpu.decode_attention`` says what a K/V leaf is, appends the
@@ -83,6 +86,7 @@ __all__ = [
     "make_kda_routed_lm_pooled_step_fn",
     "make_latent_sparse_lm_pooled_step_fn",
     "make_latent_mtp_lm_pooled_step_fn",
+    "make_kda_latent_lm_pooled_step_fn",
     "Leaf", "PositionRead", "CacheSpec", "READ_KINDS", "declare", "spec_of",
     "normalize_kv_dtype",
     "random_transformer_lm_state",
@@ -1897,8 +1901,10 @@ def make_kda_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
       :func:`make_routed_conv_lm_pooled_step_fn`'s; the spec's ``n_expert``
       counts the experts HELD (what the counts' groups are over).
 
-    Prompts walk the one-token step (the delta rule's chunkwise form is
-    not built), so ``KVSlotPool`` refuses ``prefix=True`` and
+    Prompts walk the one-token step (the delta rule's chunkwise form,
+    ``delta_hybrid_lm.gated_delta_chunk``, is not wired into this
+    builder: :func:`make_kda_latent_lm_pooled_step_fn` is the one that
+    prefills with it), so ``KVSlotPool`` refuses ``prefix=True`` and
     ``speculative=`` over this builder.
     """
     import jax
@@ -1909,6 +1915,10 @@ def make_kda_routed_lm_pooled_step_fn(state, cfg, name: str = "lm",
     from paddle_tpu.decode_attention import kv_leaves, make_decode_attention
 
     d = dh.kda_dims(cfg)
+    if d.n_dense:
+        raise ValueError("first_k_dense_replace: this builder follows every "
+                         "mixer by routed experts (leading dense layers are "
+                         "make_kda_latent_lm_pooled_step_fn's)")
     kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
     W = {k: jnp.asarray(v) for k, v in state.items()}
     scale = 1.0 / float(np.sqrt(d.head_dim))
@@ -2362,6 +2372,196 @@ def make_latent_mtp_lm_pooled_step_fn(state, cfg, name: str = "lm",
         mtp_fn=mtp_fn if d.n_mtp else None,
         reads=_dense_latent_kv_reads(d, kv) + (
             PositionRead("latent", lambda n: n, layers=d.n_layer + d.n_mtp),),
+        expert_stats=lambda cache: cache["expert_stats"],
+        n_expert=(d.n_expert if held is None
+                  else int(held[1]) - int(held[0]))))
+    return step_fn, make_cache, prefill_fn
+
+
+def make_kda_latent_lm_pooled_step_fn(state, cfg, name: str = "lm",
+                                      kv_dtype: str = "bf16", held=None,
+                                      prefill_tokens: int = 512):
+    """The slot-pooled step AND the chunked prefill of a decoder whose
+    layers are KIMI DELTA ATTENTION (a recurrent state and a conv window
+    a slot) or multi-head LATENT attention read densely and position-free
+    (one compressed row a position), pre-norm, the leading layers' FFN a
+    dense SwiGLU and every other layer's routed experts beside a shared
+    expert (``model_type: kimi_linear``; the sizes and the schema are
+    ``paddle_tpu.kda_latent_lm``, the K layer's parts
+    ``paddle_tpu.delta_hybrid_lm``'s ``kda_*``, the M layer's
+    ``paddle_tpu.latent_sparse_lm``'s, the expert layer
+    ``paddle_tpu.routed_experts``, the latent leaf and its dense read
+    ``paddle_tpu.decode_attention``).
+
+    Returns ``(step_fn, make_cache, prefill_fn)`` with the contract of
+    :func:`make_sparse_linear_lm_pooled_step_fn`.  ``state``: weights
+    under ``kda_latent_lm.param_shapes(cfg, held=held)``, multiplied in
+    the dtype they are given (router, bias, norms, the conv kernel,
+    ``A_log`` and ``dt_bias`` float32); ``held``: the contiguous range of
+    experts whose matrices ``state`` holds.
+
+    The cache is ``{"layers": [...], "expert_stats": ...}``: a K layer
+    ``state`` ``[N, H / g, dk, g * dv]`` and ``conv`` ``[N, K - 1, 2 H dk
+    + H dv]`` float32, RECURRENT; an M layer ONE ``latent`` leaf ``[N, T,
+    kv_lora_rank + shared]`` in ``kv_dtype``, zero-padded to whole
+    128-lane tiles (576 -> 640), a sequence leaf; ``expert_stats``
+    ``[sparse layers, 4]`` int32 (``slot=False``).
+
+    The step is one token a row: a K layer by ``kda_layer_step`` (the
+    rule's kernel on a TPU), an M layer ABSORBED through
+    ``decode_attention.dense_latent_attention`` at ``K`` = 1 (every live
+    position of the leaf as it lies).  ``prefill_fn(cache, row, tokens
+    [C], start, n_valid)`` feeds slot ``row`` ``C = prefill_tokens``
+    prompt tokens at ``start .. start + n_valid - 1`` through every layer
+    in ONE call and no logits: a K layer by the rule's CHUNKWISE form from
+    the slot's state and conv window (zero at ``start == 0``), leaving
+    both at ``start + n_valid``; an M layer EXPANDED, a key block at a
+    time under causal membership (``chunk_attend_expanded``); the chunk's
+    rows go through the expert layer as a step's rows do.  It equals
+    ``n_valid`` steps leaf for leaf, but for the summation order
+    (tests/test_kda_latent_lm.py).  Because a prefill can stop at a
+    boundary, ``KVSlotPool`` serves ``prefix=True`` over this builder by
+    whole-row SNAPSHOTS that carry the delta state, the conv window and
+    the latent rows together; it refuses ``speculative=`` (recurrent
+    leaves cannot be rolled back).
+
+    The spec's ``"latent"`` read (a query of context ``n`` reads all
+    ``n`` positions in each M layer) and, over bf16 leaves, its ``"kv"``
+    read (what the dense read's lowering TOUCHES of a slot's leaf) are
+    for the server's counters.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import delta_hybrid_lm as dh
+    from paddle_tpu import kda_latent_lm as kl
+    from paddle_tpu import latent_sparse_lm as ls
+    from paddle_tpu import routed_experts as rx
+    from paddle_tpu.decode_attention import (append_latent_rows,
+                                             dense_latent_attention,
+                                             latent_leaves, pad_lanes)
+
+    d = kl.dims(cfg)
+    kv = _KV_STORAGE[normalize_kv_dtype(kv_dtype, ("fp32", "bf16"))]
+    W = {k: jnp.asarray(v) for k, v in state.items()}
+    C = int(prefill_tokens)
+    n_stats = len(rx.STAT_NAMES)
+    latent_at = [i for i, kind in enumerate(d.kinds) if kind == kl.LATENT]
+    f32 = jnp.float32
+
+    def make_cache(n_rows: int, seq_len: int):
+        return {
+            "layers": [
+                latent_leaves(n_rows, seq_len, d.d_latent, None, kv)
+                if kind == kl.LATENT else
+                {"state": jnp.zeros((n_rows,) + d.state_shape, f32),
+                 "conv": jnp.zeros((n_rows, d.conv_len - 1, d.d_qkv), f32)}
+                for kind in d.kinds],
+            "expert_stats": jnp.zeros((len(d.expert_layers), n_stats),
+                                      jnp.int32)}
+
+    leaves = {
+        "layers": [{"latent": Leaf(1)} if kind == kl.LATENT
+                   else {"state": Leaf(), "conv": Leaf()}
+                   for kind in d.kinds],
+        "expert_stats": Leaf(slot=False)}  # a snapshot must not carry counts
+
+    def close_layer(h, o, p, dense, ts_rows):
+        """The residual around a mixer's output ``o`` and the layer's
+        FFN; ``(h, stats or None)``."""
+        h = h + o
+        f = dh.rms_norm(h, W[p + "ffn_norm"], d.eps)
+        if dense:
+            return h + dh.swiglu(f, W[p + "ffn_gate"], W[p + "ffn_up"],
+                                 W[p + "ffn_down"], 1.0, 1.0), None
+        y, st = rx.expert_layer(f, W, p, ts_rows, d, held)
+        return h + y, st
+
+    def step_fn(cache, tokens, ts):
+        layers = cache["layers"]
+        if latent_at:
+            ts = jnp.minimum(ts, layers[latent_at[0]]["latent"].shape[1] - 1)
+        pos = jnp.maximum(ts, 0)      # idle rows stay < 0 in ``ts``
+        n = tokens.shape[0]
+        h = W[name + "_emb"][tokens].astype(f32)
+        new_layers, stats = [], []
+        for i, kind in enumerate(d.kinds):
+            p = "%s_l%d_" % (name, i)
+            c = layers[i]
+            x = dh.rms_norm(h, W[p + "mixer_norm"], d.eps)
+            if kind == kl.KDA:
+                o, s, conv = dh.kda_layer_step(x, W, p, c["state"],
+                                               c["conv"], ts, d)
+                new_layers.append({"state": s, "conv": conv})
+            else:
+                with jax.named_scope(ls.LATENT_PROJECT_SCOPE):
+                    _, qc, qr, row = ls.latent_inputs(x, W, p, pos, d)
+                    q = ls.absorb_queries(qc, qr, W, p, d)
+                    kvs = append_latent_rows(c, row[:, None], None, ts)
+                with jax.named_scope(ls.LATENT_ATTEND_SCOPE):
+                    u = dense_latent_attention(
+                        q[:, None], kvs, ts, d_value=d.d_c, scale=d.scale)
+                    o = ls.attend_out(u.reshape(n, d.n_head, d.d_c), W, p, d)
+                new_layers.append(kvs)
+            h, st = close_layer(h, o, p, d.dense[i], ts)
+            if st is not None:
+                stats.append(st)
+        logits = dh.linear(dh.rms_norm(h, W[name + "_final_norm"], d.eps),
+                           W[name + "_head"])
+        counts = cache["expert_stats"]
+        return logits, {"layers": new_layers,
+                        "expert_stats": counts + jnp.stack(stats)
+                        if stats else counts}
+
+    def prefill_layer(c, kind, h, p, dense, row, start, n_valid, pos, ts_q):
+        x = dh.rms_norm(h, W[p + "mixer_norm"], d.eps)
+        if kind == kl.KDA:
+            mine = {leaf: jax.lax.dynamic_index_in_dim(c[leaf], row, 0, False)
+                    for leaf in ("state", "conv")}
+            o, s, conv = dh.kda_layer_chunk(x, W, p, mine["state"],
+                                            mine["conv"], start, n_valid, d)
+            new = {"state": jax.lax.dynamic_update_index_in_dim(
+                       c["state"], s, row, 0),
+                   "conv": jax.lax.dynamic_update_index_in_dim(
+                       c["conv"], conv, row, 0)}
+            return close_layer(h, o, p, dense, ts_q)[0], new
+        with jax.named_scope(ls.LATENT_PROJECT_SCOPE):
+            _, qc, qr, fresh = ls.latent_inputs(x, W, p, pos, d)
+        leaf = c["latent"]
+        lanes = leaf.shape[2]               # whole tiles: zero-padded
+        old = jax.lax.dynamic_slice(leaf, (row, start, 0), (1, C, lanes))[0]
+        new = jax.lax.dynamic_update_slice(
+            leaf, jnp.where((ts_q >= 0)[:, None],
+                            pad_lanes(fresh, lanes).astype(kv), old)[None],
+            (row, start, 0))
+        mine = jax.lax.dynamic_index_in_dim(new, row, 0, False)
+        # every query reads the positions 0 .. its own
+        member = jnp.arange(leaf.shape[1])[None, :] <= ts_q[:, None]
+        with jax.named_scope(ls.LATENT_ATTEND_SCOPE):
+            o = ls.linear(ls.chunk_attend_expanded(
+                qc, qr, mine, member, start + n_valid, W, p, d),
+                W[p + "attn_o"])
+        return close_layer(h, o, p, dense, ts_q)[0], {"latent": new}
+
+    def prefill_fn(cache, row, tokens, start, n_valid):
+        with jax.named_scope(ls.PREFILL_CHUNK_SCOPE):
+            pos = start + jnp.arange(C)
+            ts_q = jnp.where(jnp.arange(C) < n_valid, pos, -1)
+            h = W[name + "_emb"][tokens].astype(f32)
+            new_layers = []
+            for i, kind in enumerate(d.kinds):
+                h, new = prefill_layer(cache["layers"][i], kind, h,
+                                       "%s_l%d_" % (name, i), d.dense[i],
+                                       row, start, n_valid, pos, ts_q)
+                new_layers.append(new)
+            return {"layers": new_layers,
+                    "expert_stats": cache["expert_stats"]}
+
+    prefill_fn.chunk_tokens = C
+    declare(make_cache, CacheSpec(
+        leaves, prefill_fn=prefill_fn,
+        reads=_dense_latent_kv_reads(d, kv) + (
+            PositionRead("latent", lambda n: n, layers=len(latent_at)),),
         expert_stats=lambda cache: cache["expert_stats"],
         n_expert=(d.n_expert if held is None
                   else int(held[1]) - int(held[0]))))

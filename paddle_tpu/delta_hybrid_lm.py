@@ -96,6 +96,25 @@ for the output gate: d_model -> head_dim -> H dk; ``kda_use_full_proj``
 false) are two plain products each; the three convolutions are ONE
 (:func:`qkv_conv_step`) over the concatenated channels.
 ``decoding.make_kda_routed_lm_pooled_step_fn`` strings these parts.
+
+THE CHUNKWISE FORM (:func:`gated_delta_chunk`; the delta rule's WY / UT
+transform): ``C`` positions of ONE row in matrix products and one
+unit-lower-triangular solve a sub-chunk, equal to ``C`` calls of
+:func:`gated_delta_step` at float32 rounding, for both decays.  Per
+head, positions ``r = 1..C`` of a sub-chunk, state ``S_0`` in, ``g_r =
+sum_{i<=r} log alpha_i`` (a vector over the key channels, or a scalar)::
+
+    A[r, s] = beta_r sum_c k_r,c k_s,c e^{g_r,c - g_s,c}          (s < r)
+    W = (I + A)^-1 Diag(beta) (V - (K * e^G) S_0)
+    o_r = S_0^T (e^{g_r} * q_r) + sum_{s<=r} (sum_c q_r,c k_s,c e^{g_r,c - g_s,c}) w_s
+    S_C = Diag(e^{g_C}) S_0 + sum_s (e^{g_C - g_s} * k_s) w_s^T
+
+Every exponent is ``g_r - g_s`` with ``s <= r``: nothing is divided by a
+cumulative decay, nothing overflows.  :func:`kda_layer_chunk` is a KDA
+layer over a chunk (the convolution carried by the ``conv`` leaf);
+``decoding.make_kda_latent_lm_pooled_step_fn`` (``model_type:
+kimi_linear``: KDA beside multi-head LATENT attention) is the builder
+that prefills with it.
 """
 from __future__ import annotations
 
@@ -109,7 +128,9 @@ from paddle_tpu.hybrid_ssm import (linear, rms_norm, rotary, starts_fresh,
 from paddle_tpu.monitor import registry as _registry
 from paddle_tpu.routed_experts import SIGMOID_BIAS, SILU
 
-__all__ = ["LINEAR", "FULL", "DELTA_UPDATE_SCOPE", "SHORT_CONV_SCOPE",
+__all__ = ["LINEAR", "FULL", "DELTA_UPDATE_SCOPE", "DELTA_CHUNK_SCOPE",
+           "SHORT_CONV_SCOPE", "gated_delta_chunk", "qkv_conv_chunk",
+           "kda_layer_chunk", "kda_mixer_shapes", "kda_ffn_shapes",
            "FLOAT32_PARAMS", "dims", "param_shapes", "random_state",
            "heads_per_tile", "qkv_conv_step", "l2_norm", "decay_and_step_gates",
            "gated_delta_step", "xla_gated_delta_step",
@@ -126,6 +147,7 @@ LINEAR, FULL = "linear_attention", "full_attention"
 
 #: ``jax.named_scope`` names, for the device trace
 DELTA_UPDATE_SCOPE = "delta_state_update"
+DELTA_CHUNK_SCOPE = "delta_chunk_prefill"
 SHORT_CONV_SCOPE = "delta_short_conv"
 CHANNEL_GATES_SCOPE = "delta_channel_gates"
 FULL_ATTENTION_SCOPE = "gated_full_attention"
@@ -139,13 +161,19 @@ _L2_EPS = 1e-6
 KERNEL_NAME = "gated_delta_update"
 #: most bytes of the state one buffer of a grid step holds
 _BLOCK_BYTES = 5 << 19
+#: positions of a sub-chunk of the chunkwise form: its pairwise decay is
+#: ``[sub, sub, H, dk]`` float32 (64: 67 MB at 32 heads of 128 channels)
+_SUB_CHUNK = 64
+#: the least decay the chunkwise form takes the logarithm of
+_ALPHA_FLOOR = 1e-37
 
 LOWERED = _registry.REGISTRY.counter(
     "delta_update_lowered_total",
     "gated delta-rule state updates lowered (traced into a program or "
     "run eagerly), by the lowering chosen: kernel (Pallas TPU: each "
     "block of the state leaf read once and written once, in place) | "
-    "xla (two fusions: the state read twice and written once)", ("path",))
+    "xla (two fusions: the state read twice and written once) | chunk "
+    "(the chunkwise form of a prefill: C positions of one slot)", ("path",))
 DECAY = _registry.REGISTRY.counter(
     "delta_update_decay_total",
     "gated delta-rule state updates lowered, by the decay's contract: head "
@@ -289,6 +317,28 @@ def qkv_conv_step(x, w_conv, conv, ts):
         y = jax.nn.silu(jnp.sum(window * w_conv.astype(f32)[None], axis=1))
         conv_new = jnp.where(live[:, None, None], window[:, 1:],
                              conv.astype(f32)).astype(conv.dtype)
+    return y, conv_new
+
+
+def qkv_conv_chunk(x, w_conv, conv, start, n_valid):
+    """:func:`qkv_conv_step` over ``C`` positions of ONE row: ``x`` ``[C,
+    channels]`` the projected rows at ``start .. start + C - 1`` (the
+    first ``n_valid`` count), ``conv`` ``[K - 1, channels]`` the row's
+    window (read as zero where the chunk starts a sequence).  Returns
+    ``(activated [C, channels], conv)``, the window after ``n_valid``
+    positions."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    c, taps = x.shape[0], w_conv.shape[0]
+    with jax.named_scope(SHORT_CONV_SCOPE):
+        prev = jnp.where(starts_fresh(start), 0.0, conv.astype(f32))
+        ext = jnp.concatenate([prev, x], axis=0)        # [K - 1 + C, ch]
+        wf = w_conv.astype(f32)
+        y = jax.nn.silu(sum(ext[j:j + c] * wf[j] for j in range(taps)))
+        conv_new = jax.lax.dynamic_slice_in_dim(
+            ext, n_valid, taps - 1, axis=0).astype(conv.dtype)
     return y, conv_new
 
 
@@ -556,6 +606,109 @@ def _delta_update(q, k, v, alpha, beta, s, kinds, *, block, interpret):
     return o.transpose(1, 0, 2).reshape(n, h, dv), s_out
 
 
+def _heads_apart(s, dv: int):
+    """A state leaf's row ``[H / g, dk, g * dv]`` a head a matrix, ``[H,
+    dk, dv]`` (what :func:`heads_per_tile` laid side by side)."""
+    pairs, dk, lanes = s.shape
+    g = lanes // dv
+    if g == 1:
+        return s
+    return s.reshape(pairs, dk, g, dv).transpose(0, 2, 1, 3).reshape(
+        pairs * g, dk, dv)
+
+
+def _heads_beside(s, g: int):
+    """:func:`_heads_apart` undone: ``[H, dk, dv]`` to the leaf's row."""
+    h, dk, dv = s.shape
+    if g == 1:
+        return s
+    return s.reshape(h // g, g, dk, dv).transpose(0, 2, 1, 3).reshape(
+        h // g, dk, g * dv)
+
+
+def _decayed(state, g_last):
+    """``Diag(e^{g_C}) S_0``: the state a sub-chunk came in with, decayed
+    through all of it (``g_last`` ``[H, dk]`` or ``[H, 1]``)."""
+    import jax.numpy as jnp
+
+    return jnp.exp(g_last)[..., None] * state
+
+
+def gated_delta_chunk(q, k, v, alpha, beta, s, start, n_valid):
+    """``C`` positions of ONE row through the gated delta rule, in the
+    CHUNKWISE form (the module's docstring has the equations): equal to
+    ``n_valid`` calls of :func:`gated_delta_step`, at float32 rounding.
+
+    ``q``, ``k`` ``[C, H, dk]`` (normalised), ``v`` ``[C, H, dv]``,
+    ``beta`` ``[C, H]``, float32; ``alpha`` ``[C, H]`` or ``[C, H, dk]``,
+    the ONE contract :func:`gated_delta_step` has (read from its shape);
+    ``s`` ``[H / g, dk, g * dv]`` the row of the state leaf before the
+    chunk's first position (read as zero where ``start`` begins a
+    sequence: ``starts_fresh``); the first ``n_valid`` positions count.
+    Returns ``(o [C, H, dv], s)`` with ``s`` the state after ``n_valid``
+    positions (rows of ``o`` past them mean nothing).
+
+    Sub-chunks of at most :data:`_SUB_CHUNK` positions in sequence (their
+    pairwise decays are ``[sub, sub, H, dk]``), each ONE forward
+    substitution; plain ``jax.numpy``, the products at "highest" so that
+    a prefilled state is the stepped one."""
+    import jax
+    import jax.numpy as jnp
+
+    f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+    c, h, dv = v.shape
+    g = s.shape[-1] // dv
+    LOWERED.labels(path="chunk").inc()
+    DECAY.labels(decay="channel" if alpha.ndim == 3 else "head").inc()
+    with jax.named_scope(DELTA_CHUNK_SCOPE):
+        valid = jnp.arange(c) < n_valid
+        # a position that does not count neither decays nor writes
+        log_a = jnp.where(
+            valid[:, None, None], jnp.log(jnp.maximum(
+                alpha if alpha.ndim == 3 else alpha[..., None],
+                _ALPHA_FLOOR)), 0.0)                    # [C, H, dk | 1]
+        beta = jnp.where(valid[:, None], beta, 0.0)
+        sub = min(_SUB_CHUNK, c)
+        pad = -c % sub
+
+        def blocks(x):
+            x = jnp.pad(x.astype(f32), ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+            return x.reshape((-1, sub) + x.shape[1:])
+
+        at = jnp.arange(sub)
+        upto = (at[:, None] >= at[None, :])[..., None, None]        # s <= r
+
+        def one(state, xs):
+            qb, kb, vb, lb, bb = xs
+            gs = jnp.cumsum(lb, axis=0)                 # [sub, H, dk | 1]
+            # e^{g_r - g_s} for s <= r alone: no exponent above 0
+            pair = jnp.exp(jnp.where(upto, gs[:, None] - gs[None, :],
+                                     -jnp.inf))         # [r, s, H, dk | 1]
+            kk = jnp.sum(kb[:, None] * kb[None, :] * pair, axis=-1)
+            qk = jnp.sum(qb[:, None] * kb[None, :] * pair, axis=-1)
+            # A: what lies under the diagonal (the solve reads nothing else)
+            a = bb[:, None, :] * kk                                 # [r, s, H]
+            grow = jnp.exp(gs)
+            rhs = bb[..., None] * (vb - jnp.einsum(
+                "rhc,hcd->rhd", kb * grow, state, precision=hi))
+            w = jax.lax.linalg.triangular_solve(
+                a.transpose(2, 0, 1), rhs.transpose(1, 0, 2), left_side=True,
+                lower=True, unit_diagonal=True)                     # [H, r, dv]
+            o = (jnp.einsum("rhc,hcd->rhd", qb * grow, state, precision=hi)
+                 + jnp.einsum("rsh,hsd->rhd", qk, w, precision=hi))
+            left = jnp.exp(gs[-1][None] - gs)           # e^{g_C - g_s}
+            state = (_decayed(state, gs[-1])
+                     + jnp.einsum("shc,hsd->hcd", kb * left, w, precision=hi))
+            return state, o
+
+        s0 = jnp.where(starts_fresh(start), 0.0,
+                       _heads_apart(s.astype(f32), dv))
+        s_out, o = jax.lax.scan(one, s0, tuple(
+            blocks(x) for x in (q, k, v, log_a, beta)))
+        return (o.reshape(-1, h, dv)[:c],
+                _heads_beside(s_out, g).astype(s.dtype))
+
+
 def gated_output_norm(o, gate, w_norm, eps: float, act=None):
     """``RMSNorm_dv(o) * act(gate)`` per head: ``o``, ``gate`` ``[N, H,
     dv]``, ``w_norm`` ``[dv]`` (one weight for every head); ``act`` the
@@ -571,17 +724,30 @@ def conv_qkv(x, w, p: str, conv, ts, d):
     a linear layer's input rows ``x``: the three projections through the
     ONE short convolution, q and k L2-normed per head, q scaled by
     ``dk^-1/2`` (both kinds of delta-rule layer)."""
+    qkv, conv = qkv_conv_step(_project_qkv(x, w, p), w[p + "lin_conv_w"],
+                              conv, ts)
+    return _rule_inputs(qkv, d) + (conv,)
+
+
+def _project_qkv(x, w, p: str):
+    """``[W_q x; W_k x; W_v x]`` ``[N, 2 H dk + H dv]``: what the short
+    convolution runs over."""
     import jax.numpy as jnp
 
-    n = x.shape[0]
-    qkv = jnp.concatenate([linear(x, w[p + "lin_q"]), linear(x, w[p + "lin_k"]),
-                           linear(x, w[p + "lin_v"])], axis=-1)
-    qkv, conv = qkv_conv_step(qkv, w[p + "lin_conv_w"], conv, ts)
+    return jnp.concatenate([linear(x, w[p + "lin_q"]), linear(x, w[p + "lin_k"]),
+                            linear(x, w[p + "lin_v"])], axis=-1)
+
+
+def _rule_inputs(qkv, d):
+    """The convolved ``[q; k; v]`` rows ``[N, 2 H dk + H dv]`` as the rule
+    takes them: ``(q, k [N, H, dk], v [N, H, dv])``, q and k L2-normed per
+    head, q scaled by ``dk^-1/2``."""
+    n = qkv.shape[0]
     q = l2_norm(qkv[:, :d.d_key].reshape(n, d.lin_heads, d.dk)) \
         * float(d.dk) ** -0.5
     k = l2_norm(qkv[:, d.d_key:2 * d.d_key].reshape(n, d.lin_heads, d.dk))
     v = qkv[:, 2 * d.d_key:].reshape(n, d.lin_heads, d.dv)
-    return q, k, v, conv
+    return q, k, v
 
 
 def delta_layer_step(x, w, p: str, state, conv, ts, d):
@@ -619,20 +785,49 @@ def full_attention_rows(x, w, p: str, pos, d):
 KDA_FLOAT32_PARAMS = FLOAT32_PARAMS + ("router", "expert_bias")
 
 
+def _first(cfg, *keys, default=None):
+    """The value under the first of ``keys`` the config has (two
+    releases name the same thing differently)."""
+    for key in keys:
+        if cfg.get(key) is not None:
+            return cfg[key]
+    return default
+
+
 def kda_dims(cfg) -> SimpleNamespace:
-    """The decoder's sizes from a ``solar_open2`` config dict (the
-    published key names: ``linear_attn_config.*``, ``gqa_layers``,
-    ``n_routed_experts``, ...).  ``n_routed_experts`` may count the
-    experts HELD here; the router's width is then
-    ``n_routed_experts_all``.  Carries what ``routed_experts.route`` /
-    ``expert_layer`` / ``shared_expert`` read of a ``dims``."""
+    """The decoder's sizes from a config dict of a Kimi-Delta-Attention
+    decoder with routed experts, under either release's published key
+    names: ``solar_open2`` (``gqa_layers`` 0-indexed, ``n_routed_experts``,
+    ``num_experts_per_tok``, ``n_shared_experts``, ``norm_topk_prob``) or
+    ``kimi_linear`` (``linear_attn_config.kda_layers`` /
+    ``full_attn_layers`` 1-INDEXED, ``num_experts``,
+    ``num_experts_per_token``, ``num_shared_experts``,
+    ``moe_renormalize``; ``first_k_dense_replace`` leading layers whose
+    FFN is a dense SwiGLU of ``intermediate_size``).  The count of
+    experts may be of those HELD here; the router's width is then
+    ``n_routed_experts_all`` / ``num_experts_all``.  Carries what
+    ``routed_experts.route`` / ``expert_layer`` / ``shared_expert`` read
+    of a ``dims``.  What the layers that are not KDA are (gated GQA, or
+    latent attention: ``kda_latent_lm.dims``) is the builder's."""
     g, lin = cfg.get, cfg["linear_attn_config"]
     n_layer = int(cfg["num_hidden_layers"])
-    gqa = set(int(i) for i in cfg["gqa_layers"])
+    if lin.get("kda_layers") is not None:
+        kda = set(int(i) - 1 for i in lin["kda_layers"])
+        full = set(int(i) - 1 for i in lin["full_attn_layers"])
+        if kda & full or kda | full != set(range(n_layer)):
+            raise ValueError(
+                "kda_layers and full_attn_layers (1-indexed) must name each "
+                "of the %d layers once" % n_layer)
+    else:
+        full = set(int(i) for i in cfg["gqa_layers"])
+        if full - set(range(n_layer)):
+            raise ValueError("gqa_layers names a layer past "
+                             "num_hidden_layers")
+    n_dense = int(g("first_k_dense_replace", 0))
     o = SimpleNamespace(
         vocab=int(cfg["vocab_size"]), d_model=int(cfg["hidden_size"]),
         n_layer=n_layer,
-        kinds=tuple(FULL if i in gqa else LINEAR for i in range(n_layer)),
+        kinds=tuple(FULL if i in full else LINEAR for i in range(n_layer)),
         n_head=int(cfg["num_attention_heads"]),
         n_kv_head=int(cfg["num_key_value_heads"]),
         head_dim=int(cfg["head_dim"]),
@@ -643,38 +838,79 @@ def kda_dims(cfg) -> SimpleNamespace:
         attn_gate=bool(g("use_gqa_gate", False)),
         eps=float(g("rms_norm_eps", 1e-5)),
         rope_theta=float(cfg["rope_theta"]) if g("use_rope", True) else None,
+        n_dense=n_dense, d_mlp=int(g("intermediate_size", 0)),
         d_expert=int(cfg["moe_intermediate_size"]),
-        n_expert=int(g("n_routed_experts_all", cfg["n_routed_experts"])),
-        top_k=int(cfg["num_experts_per_tok"]),
-        n_shared=int(g("n_shared_experts", 0)),
-        norm_topk=bool(g("norm_topk_prob", True)),
+        n_expert=int(_first(cfg, "n_routed_experts_all", "num_experts_all",
+                            "n_routed_experts", "num_experts")),
+        top_k=int(_first(cfg, "num_experts_per_tok",
+                         "num_experts_per_token")),
+        n_shared=int(_first(cfg, "n_shared_experts", "num_shared_experts",
+                            default=0)),
+        norm_topk=bool(_first(cfg, "norm_topk_prob", "moe_renormalize",
+                              default=True)),
         routed_scale=float(g("routed_scaling_factor", 1.0)),
         scoring=SIGMOID_BIAS, gate_act=SILU, expert_bias=True)
-    if gqa - set(range(n_layer)):
-        raise ValueError("gqa_layers names a layer past num_hidden_layers")
     if lin.get("num_kv_heads") not in (None, o.lin_heads):
         raise ValueError("linear_attn_config.num_kv_heads: value heads "
                          "grouped over key heads are not built")
     if g("kda_use_full_proj", False):
         raise ValueError("kda_use_full_proj: only the low-rank gate "
                          "projections are built")
-    if int(g("first_k_dense_replace", 0)):
-        raise ValueError("first_k_dense_replace: leading dense layers are "
-                         "not built")
     if g("tie_word_embeddings", False):
         raise ValueError("a tied head is not supported")
     if float(g("partial_rotary_factor", 1)) != 1 and o.rope_theta is not None:
         raise ValueError("a partial rotary is not supported")
     if o.n_head % o.n_kv_head:
         raise ValueError("query heads must be a multiple of their KV heads")
+    if not 0 <= n_dense <= n_layer:
+        raise ValueError("first_k_dense_replace past num_hidden_layers")
     o.d_q, o.d_kv = o.n_head * o.head_dim, o.n_kv_head * o.head_dim
     o.d_key = o.d_value = o.lin_heads * o.dk
     o.d_qkv = 2 * o.d_key + o.d_value
     o.d_rank = o.dk            # the low rank of both gate pairs: head_dim
     o.tile_heads = heads_per_tile(o.lin_heads, o.dv)
     o.state_shape = (o.lin_heads // o.tile_heads, o.dk, o.tile_heads * o.dv)
-    o.expert_layers = tuple(range(n_layer))
+    o.dense = tuple(i < n_dense for i in range(n_layer))
+    o.expert_layers = tuple(i for i in range(n_layer) if not o.dense[i])
     return o
+
+
+def kda_mixer_shapes(d, p: str) -> dict:
+    """Names and shapes of ONE KDA layer's mixer under the prefix ``p``
+    (both builders of such layers hold them alike)."""
+    return {
+        p + "lin_q": (d.d_model, d.d_key),
+        p + "lin_k": (d.d_model, d.d_key),
+        p + "lin_v": (d.d_model, d.d_value),
+        p + "lin_conv_w": (d.conv_len, d.d_qkv),
+        p + "lin_fa": (d.d_model, d.d_rank),
+        p + "lin_fb": (d.d_rank, d.d_key),
+        p + "lin_b": (d.d_model, d.lin_heads),
+        p + "lin_A_log": (d.lin_heads,),
+        p + "lin_dt_bias": (d.d_key,),
+        p + "lin_ga": (d.d_model, d.d_rank),
+        p + "lin_gb": (d.d_rank, d.d_value),
+        p + "lin_norm": (d.dv,),
+        p + "lin_o": (d.d_value, d.d_model)}
+
+
+def kda_ffn_shapes(d, p: str, dense: bool, n_held: int) -> dict:
+    """Names and shapes of ONE layer's FFN under the prefix ``p``: a
+    dense SwiGLU, or the router (and its bias) at its whole width, the
+    ``n_held`` held experts and the shared expert."""
+    if dense:
+        return {p + "ffn_gate": (d.d_model, d.d_mlp),
+                p + "ffn_up": (d.d_model, d.d_mlp),
+                p + "ffn_down": (d.d_mlp, d.d_model)}
+    out = {p + "router": (d.d_model, d.n_expert),
+           p + "expert_bias": (d.n_expert,),
+           p + "experts_w13": (n_held, d.d_model, 2 * d.d_expert),
+           p + "experts_w2": (n_held, d.d_expert, d.d_model)}
+    if d.n_shared:
+        out.update({
+            p + "shared_w13": (d.d_model, 2 * d.n_shared * d.d_expert),
+            p + "shared_w2": (d.n_shared * d.d_expert, d.d_model)})
+    return out
 
 
 def kda_param_shapes(cfg, name: str = "lm", held=None) -> dict:
@@ -691,20 +927,7 @@ def kda_param_shapes(cfg, name: str = "lm", held=None) -> dict:
     for i, kind in enumerate(d.kinds):
         p = "%s_l%d_" % (name, i)
         if kind == LINEAR:
-            out.update({
-                p + "lin_q": (d.d_model, d.d_key),
-                p + "lin_k": (d.d_model, d.d_key),
-                p + "lin_v": (d.d_model, d.d_value),
-                p + "lin_conv_w": (d.conv_len, d.d_qkv),
-                p + "lin_fa": (d.d_model, d.d_rank),
-                p + "lin_fb": (d.d_rank, d.d_key),
-                p + "lin_b": (d.d_model, d.lin_heads),
-                p + "lin_A_log": (d.lin_heads,),
-                p + "lin_dt_bias": (d.d_key,),
-                p + "lin_ga": (d.d_model, d.d_rank),
-                p + "lin_gb": (d.d_rank, d.d_value),
-                p + "lin_norm": (d.dv,),
-                p + "lin_o": (d.d_value, d.d_model)})
+            out.update(kda_mixer_shapes(d, p))
         else:
             out.update({
                 p + "attn_q": (d.d_model, d.d_q),
@@ -714,15 +937,8 @@ def kda_param_shapes(cfg, name: str = "lm", held=None) -> dict:
             if d.attn_gate:
                 out[p + "attn_gate"] = (d.d_model, d.d_q)
         out.update({
-            p + "mixer_norm": (d.d_model,), p + "ffn_norm": (d.d_model,),
-            p + "router": (d.d_model, d.n_expert),
-            p + "expert_bias": (d.n_expert,),
-            p + "experts_w13": (n_held, d.d_model, 2 * d.d_expert),
-            p + "experts_w2": (n_held, d.d_expert, d.d_model)})
-        if d.n_shared:
-            out.update({
-                p + "shared_w13": (d.d_model, 2 * d.n_shared * d.d_expert),
-                p + "shared_w2": (d.n_shared * d.d_expert, d.d_model)})
+            p + "mixer_norm": (d.d_model,), p + "ffn_norm": (d.d_model,)})
+        out.update(kda_ffn_shapes(d, p, d.dense[i], n_held))
     return out
 
 
@@ -735,10 +951,17 @@ def kda_random_state(rng, cfg, name: str = "lm", std: float = 0.02,
     ``lin_fb`` normal(0, ``gate_std``) so that the channels of ONE head
     decay differently (else a decay a channel cannot be told from a decay
     a head)."""
+    return kda_random_weights(rng, kda_param_shapes(cfg, name, held), std,
+                              dtype, gate_std, bias_range)
+
+
+def kda_random_weights(rng, shapes, std, dtype, gate_std, bias_range) -> dict:
+    """:func:`kda_random_state`'s rules over ``shapes`` (name -> shape):
+    any schema whose mixers are KDA layers."""
     import jax.numpy as jnp
 
     w = {}
-    for k, shp in kda_param_shapes(cfg, name, held).items():
+    for k, shp in shapes.items():
         if k.endswith(FLOAT32_PARAMS):
             w[k] = _random_vector(rng, k, shp)
         elif k.endswith("expert_bias"):
@@ -785,6 +1008,28 @@ def kda_layer_step(x, w, p: str, state, conv, ts, d):
     o, state = gated_delta_step(q, k, v, alpha, beta, state, ts)
     y = gated_output_norm(o, gate, w[p + "lin_norm"], d.eps, jax.nn.sigmoid)
     return linear(y.reshape(n, d.d_value), w[p + "lin_o"]), state, conv
+
+
+def kda_layer_chunk(x, w, p: str, state, conv, start, n_valid, d):
+    """:func:`kda_layer_step` over ``C`` positions of ONE row, the rule in
+    its chunkwise form: ``x`` ``[C, d_model]`` (the NORMED residual at
+    ``start .. start + C - 1``, the first ``n_valid`` count), ``state``
+    ``[H / g, dk, g * dv]`` / ``conv`` ``[K - 1, channels]`` the row's
+    leaves before the chunk.  Returns ``(out [C, d_model], state, conv)``,
+    the leaves after ``n_valid`` positions."""
+    import jax
+
+    c = x.shape[0]
+    qkv, conv = qkv_conv_chunk(_project_qkv(x, w, p), w[p + "lin_conv_w"],
+                               conv, start, n_valid)
+    q, k, v = _rule_inputs(qkv, d)
+    with jax.named_scope(CHANNEL_GATES_SCOPE):
+        alpha, beta = channel_decay(x, w, p, d)
+        gate = linear(linear(x, w[p + "lin_ga"]), w[p + "lin_gb"]).reshape(
+            c, d.lin_heads, d.dv)
+    o, state = gated_delta_chunk(q, k, v, alpha, beta, state, start, n_valid)
+    y = gated_output_norm(o, gate, w[p + "lin_norm"], d.eps, jax.nn.sigmoid)
+    return linear(y.reshape(c, d.d_value), w[p + "lin_o"]), state, conv
 
 
 def gated_attention_rows(x, w, p: str, pos, d):

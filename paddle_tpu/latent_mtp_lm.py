@@ -81,6 +81,9 @@ def dims(cfg) -> SimpleNamespace:
                          "must be 1")
     if cfg.get("rope_scaling"):
         raise ValueError("plain rotary: rope_scaling is not supported")
+    if o.q_rank is None:
+        raise ValueError("the queries pass a low rank: q_lora_rank must be "
+                         "given")
     if o.n_mtp > 1:
         raise ValueError("one multi-token-prediction module is supported, "
                          "no chain of them")

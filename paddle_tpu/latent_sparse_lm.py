@@ -54,7 +54,8 @@ import numpy as np
 from paddle_tpu.hybrid_ssm import linear, rms_norm, swiglu
 from paddle_tpu.routed_experts import SIGMOID_BIAS, SILU
 
-__all__ = ["dims", "latent_dims", "param_shapes", "random_state",
+__all__ = ["dims", "latent_dims", "latent_attention_dims", "param_shapes",
+           "random_state",
            "yarn_inv_freq", "softmax_scale", "rotate", "latent_inputs",
            "index_inputs",
            "index_scores", "top_members", "select_positions",
@@ -118,24 +119,47 @@ def softmax_scale(cfg) -> float:
     return float(width ** -0.5 * m * m)
 
 
+def latent_attention_dims(cfg) -> SimpleNamespace:
+    """What multi-head LATENT attention itself has, whatever follows it
+    (the published key names of the DeepSeek-V3 lineage): the heads, the
+    query's low rank (``q_lora_rank`` null: ``q_rank`` None, ONE query
+    matrix and no ``q_a_norm``), the latent's widths, the rotary's
+    frequencies, the softmax scale, and ``rotary`` — False where the
+    configuration says ``mla_use_nope``: the lanes that would be rotated
+    are carried and NOT rotated, on either side."""
+    rank = cfg.get("q_lora_rank")
+    o = SimpleNamespace(
+        n_head=int(cfg["num_attention_heads"]),
+        q_rank=None if rank is None else int(rank),
+        d_c=int(cfg["kv_lora_rank"]),
+        d_nope=int(cfg["qk_nope_head_dim"]),
+        d_rope=int(cfg["qk_rope_head_dim"]), d_v=int(cfg["v_head_dim"]),
+        rotary=not cfg.get("mla_use_nope", False),
+        inv_freq=yarn_inv_freq(cfg), scale=softmax_scale(cfg))
+    if cfg.get("attention_bias", False):
+        raise ValueError("attention_bias is not supported")
+    if o.d_rope % 2:
+        raise ValueError("the rotated lanes must be even")
+    o.d_latent = o.d_c + o.d_rope
+    o.d_qk = o.d_nope + o.d_rope
+    return o
+
+
 def latent_dims(cfg) -> SimpleNamespace:
     """What every decoder of multi-head LATENT attention over a dense
     SwiGLU or routed experts beside a shared expert has (the published
     key names of the DeepSeek-V3 lineage), whatever reads its latent
-    rows: the sizes, the rotary's frequencies, the softmax scale and
-    what ``routed_experts.route`` / ``expert_layer`` read.
+    rows: :func:`latent_attention_dims`, the sizes and what
+    ``routed_experts.route`` / ``expert_layer`` read.
     ``n_routed_experts`` may count the experts HELD here; the router's
     width is then ``n_routed_experts_all``.  :func:`dims` adds the
     lightning indexer's; ``latent_mtp_lm.dims`` the sandwich norms' and
     the drafting module's."""
-    o = SimpleNamespace(
+    o = latent_attention_dims(cfg)
+    vars(o).update(
         vocab=int(cfg["vocab_size"]), d_model=int(cfg["hidden_size"]),
         n_layer=int(cfg["num_hidden_layers"]),
         n_dense=int(cfg["first_k_dense_replace"]),
-        n_head=int(cfg["num_attention_heads"]),
-        q_rank=int(cfg["q_lora_rank"]), d_c=int(cfg["kv_lora_rank"]),
-        d_nope=int(cfg["qk_nope_head_dim"]),
-        d_rope=int(cfg["qk_rope_head_dim"]), d_v=int(cfg["v_head_dim"]),
         d_mlp=int(cfg["intermediate_size"]),
         d_expert=int(cfg["moe_intermediate_size"]),
         n_expert=int(cfg.get("n_routed_experts_all",
@@ -147,7 +171,6 @@ def latent_dims(cfg) -> SimpleNamespace:
         eps=float(cfg.get("rms_norm_eps", 1e-6)),
         norm_topk=bool(cfg.get("norm_topk_prob", True)),
         routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
-        inv_freq=yarn_inv_freq(cfg), scale=softmax_scale(cfg),
         # what routed_experts.route / expert_layer read
         scoring=SIGMOID_BIAS, gate_act=SILU, expert_bias=True)
     if cfg.get("scoring_func", "sigmoid") != "sigmoid":
@@ -156,8 +179,6 @@ def latent_dims(cfg) -> SimpleNamespace:
         raise ValueError("only hidden_act = silu is supported")
     if cfg.get("tie_word_embeddings", False):
         raise ValueError("a tied head is not supported")
-    if cfg.get("attention_bias", False):
-        raise ValueError("attention_bias is not supported")
     if int(cfg.get("moe_layer_freq", 1)) != 1:
         raise ValueError("only moe_layer_freq = 1 is supported")
     if o.n_expert % o.n_group or o.topk_group > o.n_group:
@@ -165,10 +186,6 @@ def latent_dims(cfg) -> SimpleNamespace:
                          "topk_group")
     if o.n_group > 1 and o.n_expert // o.n_group < 2:
         raise ValueError("a group is scored by its two best experts")
-    if o.d_rope % 2:
-        raise ValueError("the rotated lanes must be even")
-    o.d_latent = o.d_c + o.d_rope
-    o.d_qk = o.d_nope + o.d_rope
     o.dense = tuple(i < o.n_dense for i in range(o.n_layer))
     o.expert_layers = tuple(i for i in range(o.n_layer) if not o.dense[i])
     return o
@@ -186,6 +203,9 @@ def dims(cfg) -> SimpleNamespace:
     if int(cfg.get("num_nextn_predict_layers", 0)):
         raise ValueError("a multi-token-prediction module is not held: "
                          "num_nextn_predict_layers must be 0")
+    if o.q_rank is None:
+        raise ValueError("the indexer reads the query's low rank: "
+                         "q_lora_rank must be given")
     if o.d_rope > o.d_index:
         raise ValueError("the rotated lanes must fit an index head")
     return o
@@ -286,18 +306,27 @@ def latent_inputs(x, w, p: str, pos, d):
     """What attention takes of the normed rows ``x`` ``[N, d_model]`` at
     positions ``pos``: ``(cq [N, q_rank], qC [N, heads, nope], qR [N,
     heads, rope], row [N, kv_lora_rank + rope])`` float32 — ``row`` is
-    the layer's cache row ``(c, kR)``: ``c`` normed, ``kR`` rotated."""
+    the layer's cache row ``(c, kR)``: ``c`` normed, ``kR`` rotated.
+    Without a query low rank (``d.q_rank`` None) the queries are ONE
+    product ``x W_q`` (``attn_q``) and ``cq`` is None; without rotary
+    (``d.rotary`` False: ``mla_use_nope``) ``qR`` and ``kR`` are carried
+    as projected."""
     import jax.numpy as jnp
 
     n = x.shape[0]
-    cq = rms_norm(linear(x, w[p + "attn_q_a"]), w[p + "q_a_norm"], d.eps)
-    q = linear(cq, w[p + "attn_q_b"]).reshape(n, d.n_head, d.d_qk)
+    if d.q_rank is None:
+        cq, q = None, linear(x, w[p + "attn_q"])
+    else:
+        cq = rms_norm(linear(x, w[p + "attn_q_a"]), w[p + "q_a_norm"], d.eps)
+        q = linear(cq, w[p + "attn_q_b"])
+    q = q.reshape(n, d.n_head, d.d_qk)
     ckr = linear(x, w[p + "attn_kv_a"])
+    turn = ((lambda t: rotate(t, pos, d.inv_freq)) if d.rotary
+            else (lambda t: t.astype(jnp.float32)))
     row = jnp.concatenate(
         [rms_norm(ckr[:, :d.d_c], w[p + "kv_a_norm"], d.eps),
-         rotate(ckr[:, d.d_c:], pos, d.inv_freq)], axis=-1)
-    return (cq, q[..., :d.d_nope],
-            rotate(q[..., d.d_nope:], pos, d.inv_freq), row)
+         turn(ckr[:, d.d_c:])], axis=-1)
+    return cq, q[..., :d.d_nope], turn(q[..., d.d_nope:]), row
 
 
 def _rotate_head(x, pos, d):

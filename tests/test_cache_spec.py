@@ -15,7 +15,7 @@ from paddle_tpu.serving.decode import DecodeServer
 
 
 # ---------------------------------------------------------------------------
-# the ten builders at their toy configs
+# the eleven builders at their toy configs
 # ---------------------------------------------------------------------------
 LM_RUNG = 256
 
@@ -106,12 +106,21 @@ def _latent_mtp():
     return t._build(cfg, t.weights(cfg, seed=7)), t.V, 64, "fp32"
 
 
+def _kda_latent(kv_dtype="fp32"):
+    import test_kda_latent_lm as t
+
+    cfg = t.tiny_cfg()
+    return (t._build(cfg, t.weights(cfg, seed=7), kv_dtype=kv_dtype), t.V,
+            64, kv_dtype)
+
+
 BUILDERS = {
     "transformer_lm": _lm, "hybrid_ssm": _hybrid_ssm,
     "sparse_linear": _sparse_linear, "routed_conv": _routed_conv,
     "windowed_routed": _windowed_routed, "mtp_routed": _mtp_routed,
     "delta_hybrid": _delta_hybrid, "kda_routed": _kda_routed,
-    "latent_sparse": _latent_sparse, "latent_mtp": _latent_mtp}
+    "latent_sparse": _latent_sparse, "latent_mtp": _latent_mtp,
+    "kda_latent": _kda_latent}
 
 
 # ---------------------------------------------------------------------------
@@ -292,7 +301,12 @@ def _latent_mtp_bf16_self_draft():
     return built, vocab, t, kv, make_self_draft(built[1])
 
 
+def _kda_latent_bf16():
+    return _kda_latent("bf16")
+
+
 STORMS = dict(BUILDERS, transformer_lm_int8=_lm_int8,
+              kda_latent_bf16=_kda_latent_bf16,
               transformer_lm_draft=_lm_with_draft,
               mtp_routed_self_draft=_mtp_self_draft,
               latent_mtp_self_draft=_latent_mtp_self_draft,
@@ -315,6 +329,12 @@ RECORDED = {
     # the float32 pool's round is counted masked over the whole pool
     'latent_mtp_bf16': (22, 8448, 3352, 12672, 0, 0, 0, 0, 13408, 13408, 528, 433, 208, 130),
     'latent_mtp_bf16_self_draft': (48, 7552, 3352, 9216, 0, 0, 0, 0, 25000, 25000, 1416, 794, 432, 144),
+    # the eleventh builder's, as PR 63 first read them: ONE latent layer
+    # of four (a position is read once a step), three expert layers of
+    # which 4 of 16 experts are held; bf16 leaves count the dense read's
+    # host mirror (here the XLA form's: the rung of 64 is one key block)
+    'kda_latent': (22, 8448, 3352, 12672, 0, 0, 0, 0, 3352, 3352, 179, 164, 142, 195),
+    'kda_latent_bf16': (22, 8448, 3352, 12672, 0, 0, 0, 0, 3352, 3352, 179, 164, 142, 195),
     'latent_sparse': (22, 8448, 3352, 12672, 0, 0, 0, 0, 10056, 5532, 1056, 897, 217, 130),
     'mtp_routed': (22, 8448, 3352, 12672, 0, 0, 2064, 13408, 0, 0, 1056, 872, 408, 260),
     'mtp_routed_self_draft': (49, 7616, 3352, 9408, 0, 0, 3760, 25396, 0, 0, 2380, 1215, 823, 245),

@@ -311,8 +311,14 @@ def test_prefix_and_speculation_are_refused_over_this_builder(tier):
     ("tie_word_embeddings", True, "tied head")])
 def test_a_config_this_builder_cannot_serve_is_refused_by_name(key, value,
                                                                match):
+    cfg = tiny_cfg(**{key: value})
     with pytest.raises(ValueError, match=match):
-        dh.kda_dims(tiny_cfg(**{key: value}))
+        if key == "first_k_dense_replace":
+            # the sizes take leading dense layers (kda_latent_lm serves
+            # them); THIS builder follows every mixer by experts
+            assert dh.kda_dims(cfg).dense == (True, False, False, False)
+            _build(cfg, weights(cfg))
+        dh.kda_dims(cfg)
 
 
 @pytest.mark.parametrize("backend,path", [("cpu", "xla"), ("tpu", "kernel")])

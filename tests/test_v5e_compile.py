@@ -833,6 +833,77 @@ def test_dense_latent_round_and_prefill_fit_v5e_and_copy_no_leaf(
         assert mem.temp_size_in_bytes < 0.5e9
 
 
+@pytest.mark.parametrize("kind", ["chunk", "prefill"])
+def test_kda_latent_chunk_and_prefill_fit_v5e_and_copy_no_leaf(one_chip,
+                                                               kind):
+    """The ``chunk`` and the chunked prefill of ``kimi_linear_48b_a3b`` at
+    its published widths and the cell's own depth (96 slots x 32768
+    positions; the whole 8-layer cut: K with the dense FFN, K, K, M, K, K,
+    K, M; 16 held experts of 256): both fit 16 GB beside the pool and the
+    eight whole-row snapshots, every leaf is aliased in place, no ``copy``
+    bears a latent or a state leaf's shape and no tensor holds a rung-wide
+    score a head.  A step is SIX calls of the delta rule's kernel, each
+    the only reader of its ``f32[96,32,128,128]`` leaf with the decay a
+    third column, and TWO ``dense_latent_attention`` custom calls at K =
+    1, each taking its ``bf16[96,32768,640]`` leaf as it lies and the
+    queries ``bf16[96,32,640]`` and returning ``f32[96,32,512]`` (the
+    shapes ``benchmark/families/pooled_kda_latent_lm.py`` finds the read
+    by).  The prefill takes neither kernel: SIX chunk forms (a decay a
+    channel; ``InvertDiagBlocksLowerTriangular``: the forward
+    substitution), their pairwise decays ``f32[64,64,32,128]`` inside
+    fusions, and the latent prefill expanded by key blocks."""
+    from paddle_tpu import delta_hybrid_lm as dh
+    from paddle_tpu import grouped_matmul as gm
+
+    text, mem, counted = _compiled_chunk("kimi_linear_48b_a3b", 8, {
+        **{("path", p): dh.LOWERED.labels(path=p)
+           for p in ("kernel", "xla", "chunk")},
+        **{("decay", k): dh.DECAY.labels(decay=k)
+           for k in ("head", "channel")},
+        "dense_kernel": da.LATENT_LOWERED.labels(path="dense_kernel"),
+        "dense_xla": da.LATENT_LOWERED.labels(path="dense_xla")}, kind=kind)
+    lines = text.splitlines()
+    copies = [line for line in lines if " copy(" in line]
+    assert not any(shape in line for line in copies for shape in (
+        "[96,32768,640]", "[96,32,128,128]", "[96,3,12288]")), copies
+    assert "[96,32768,640]{1," not in text      # never sequence-minor
+    for scores in ("[96,32,32768]", "[512,32,32768]", "[32,512,32768]"):
+        assert scores not in text, scores
+    slot = 2 * 32768 * 640 * 2 + 6 * 4 * (32 * 128 * 128 + 3 * 12288)
+    assert mem.alias_size_in_bytes >= 96 * slot
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.generated_code_size_in_bytes + 8 * slot) < 13.5e9
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert not counted["decay", "head"] and not counted["path", "xla"]
+    assert not counted["dense_xla"]
+    kernels = [line for line in lines if "tpu_custom_call" in line
+               and "custom-call(" in line]
+    delta = [line for line in kernels if dh.KERNEL_NAME in line]
+    dense = [line for line in kernels if "dense_latent_attention" in line]
+    # two grouped products a sparse layer; the prefill makes no logits, so
+    # its last layer's FFN feeds nothing and is not in the program
+    assert sum(gm.KERNEL_NAME in line for line in kernels) == 2 * (
+        7 if kind == "chunk" else 6)
+    if kind == "chunk":
+        assert counted["path", "kernel"] == counted["decay", "channel"] == 6
+        assert not counted["path", "chunk"]
+        assert counted["dense_kernel"] == 2
+        assert len(delta) == 6 and len(dense) == 2
+        assert [r.strip() for r in _reads_of(
+            text, "f32[96,32,128,128]")] == [r.strip() for r in delta]
+        assert all("f32[32,1,3,128,128]" in line for line in delta)
+        for call in dense:
+            assert " = f32[96,32,512]" in call              # the context
+            assert "bf16[96,32,640]" in call                # the queries
+            assert "bf16[96,32768,640]" in call             # the leaf
+    else:
+        assert counted["path", "chunk"] == counted["decay", "channel"] == 6
+        assert not counted["path", "kernel"] and not counted["dense_kernel"]
+        assert not delta and not dense
+        assert text.count("InvertDiagBlocksLowerTriangular") >= 6
+        assert "f32[64,64,32,128]" in text
+
+
 @pytest.mark.parametrize("config,kind", [
     ("k_exaone_236b_a23b", "spec_chunk"), ("k_exaone_236b_a23b", "chunk"),
     ("openpangu_ultra_moe_718b", "spec_chunk"),

@@ -217,6 +217,27 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
             return decoding.make_latent_mtp_lm_pooled_step_fn(
                 w, cfg, kv_dtype=sv["kv_dtype"], held=held,
                 prefill_tokens=sv["prefill_tokens"])[:2]
+    elif cfg["family"] == "pooled_kda_latent_lm":
+        from paddle_tpu import kda_latent_lm
+
+        # the first ``layers`` of the cut (4: the dense K layer, two K
+        # layers and an M layer; 8: the whole cut, two periods); the two
+        # layer lists are 1-indexed
+        lin = cfg["linear_attn_config"]
+        for key in ("kda_layers", "full_attn_layers"):
+            lin[key] = [i for i in lin[key] if i <= layers]
+        cfg["num_hidden_layers"] = layers
+        held = tuple(cfg["experts_held"])
+        # as the family makes them: matrices bf16, vectors, the conv
+        # kernel, the router and its bias fp32
+        weights = {n: sd(shp, jnp.float32 if n.endswith(
+            kda_latent_lm.FLOAT32_PARAMS) else jnp.bfloat16)
+            for n, shp in kda_latent_lm.param_shapes(cfg, held=held).items()}
+
+        def build(w):
+            return decoding.make_kda_latent_lm_pooled_step_fn(
+                w, cfg, kv_dtype=sv["kv_dtype"], held=held,
+                prefill_tokens=sv["prefill_tokens"])[:2]
     elif cfg["family"] == "pooled_delta_hybrid_lm":
         from paddle_tpu import delta_hybrid_lm
 
@@ -317,17 +338,28 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
         return viewed(pool_of(w, speculative=make_self_draft(
             build(w)[1]))._spec_chunk_fn, w)(state)
 
+    def self_drafts(w):
+        return decoding.spec_of(build(w)[1]).verify_fn is not None
+
     def admit_prefix(w, state, mask, prompt, prompt_len, total_len, kv,
-                     prefix_len, spec_flag):
+                     prefix_len, *spec_flag):
         # one request seated over a snapshot, as the pool runs it for a
-        # builder with a chunked prefill under a self-drafting round
-        # (openpangu_ultra_moe_718b)
+        # builder with a chunked prefill: under a self-drafting round
+        # where the builder declares one (openpangu_ultra_moe_718b),
+        # plain where it does not (kimi_linear_48b_a3b: the snapshot
+        # carries recurrent leaves)
         from paddle_tpu.serving.speculative import make_self_draft
 
-        return pool_of(w, prefix=True, speculative=make_self_draft(
-            build(w)[1]))._admit_prefix_fn(
-                state, mask, prompt, prompt_len, total_len, kv, prefix_len,
-                spec_flag)
+        kw = ({"speculative": make_self_draft(build(w)[1])}
+              if self_drafts(w) else {})
+        return pool_of(w, prefix=True, **kw)._admit_prefix_fn(
+            state, mask, prompt, prompt_len, total_len, kv, prefix_len,
+            *spec_flag)
+
+    def snapshot(w, state, slot):
+        # a slot's whole row of every leaf, copied, as the pool keeps a
+        # prefix over a builder with a chunked prefill
+        return pool_of(w, prefix=True)._snapshot_fn(state, slot)
 
     def seat_prefill(w, state, packed):
         # a turn's seats seated and fed their prompts, as the pool runs
@@ -352,7 +384,10 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
         "prompt_len": sd((slots,), i32), "total_len": sd((slots,), i32),
         "active": sd((slots,), flag), "finished": sd((slots,), flag),
         "n_gen": sd((slots,), i32)}
-    if kind in ("spec_chunk", "admit_prefix"):
+    drafting = []
+    jax.eval_shape(lambda w: drafting.append(self_drafts(w)) or 0, weights)
+    drafting = drafting[0]
+    if kind == "spec_chunk" or (kind == "admit_prefix" and drafting):
         state.update(spec=sd((slots,), flag), draft=sd((slots,), i32),
                      proposals=sd((slots, seq_len), i32))
     # what the program closes over — the step's own weights where it
@@ -370,17 +405,22 @@ def lowered_chunk(repo: str, config: str, kind: str = "chunk",
                 sd((), i32),
                 [sd(l.shape[1:], l.dtype) if d.slot else sd((1,))
                  for l, d in zip(jax.tree.leaves(state["cache"]), decl)],
-                sd((), i32), sd((), flag)]
+                sd((), i32)] + ([sd((), flag)] if drafting else [])
+    if kind == "snapshot":
+        more = [sd((), i32)]
     closed, out = jax.make_jaxpr(
         {"chunk": chunk, "prefill": prefill, "seat_prefill": seat_prefill,
-         "spec_chunk": spec_chunk, "admit_prefix": admit_prefix}[kind],
+         "spec_chunk": spec_chunk, "admit_prefix": admit_prefix,
+         "snapshot": snapshot}[kind],
         return_shape=True)(weights, state, *more)
 
     def hoisted(consts, w, st, *more):
         return jax.tree.unflatten(jax.tree.structure(out), jax.core.eval_jaxpr(
             closed.jaxpr, consts, *jax.tree.leaves((w, st, more))))
 
-    return jax.jit(hoisted, donate_argnums=(2,)).lower(
+    # a snapshot copies: the state it reads stays the pool's
+    return jax.jit(hoisted, donate_argnums=(
+        () if kind == "snapshot" else (2,))).lower(
         [sd(c.shape, c.dtype) for c in closed.consts], weights, state, *more)
 
 
@@ -452,7 +492,7 @@ def main():
         os.path.abspath(__file__))))
     ap.add_argument("--kind", default="chunk",
                     choices=("chunk", "prefill", "seat_prefill",
-                             "spec_chunk", "admit_prefix"),
+                             "spec_chunk", "admit_prefix", "snapshot"),
                     help="prefill: the chunked-prefill program of a "
                     "builder that has one (minicpm_sala, "
                     "smallthinker_21b_a3b, deepseek_v3_2); seat_prefill: "
@@ -462,13 +502,16 @@ def main():
                     "round of a builder with a multi-token-prediction "
                     "module (k_exaone_236b_a23b, "
                     "openpangu_ultra_moe_718b); admit_prefix: a request "
-                    "seated over a snapshot under such a round "
-                    "(openpangu_ultra_moe_718b)")
+                    "seated over a snapshot, under such a round where "
+                    "the builder declares one (openpangu_ultra_moe_718b; "
+                    "plain: kimi_linear_48b_a3b); snapshot: a slot's whole "
+                    "row copied (kimi_linear_48b_a3b)")
     ap.add_argument("--layers", type=int, default=2,
                     help="layers compiled (8: the whole minicpm_sala or "
                     "smallthinker_21b_a3b cut, 5: k_exaone_236b_a23b's, "
                     "deepseek_v3_2's or openpangu_ultra_moe_718b's, 12: olmo_hybrid_7b's, 4: "
-                    "solar_open2_250b's, to see that "
+                    "solar_open2_250b's, 8 again: kimi_linear_48b_a3b's, "
+                    "to see that "
                     "the real program fits the chip)")
     args = ap.parse_args()
     lowered = lowered_chunk(os.path.abspath(args.repo), args.config,
