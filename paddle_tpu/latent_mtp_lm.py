@@ -164,8 +164,19 @@ def random_state(rng, cfg, name: str = "lm", std: float = 0.02,
 
 def close_attention(h, o, w, p: str, d):
     """The residual around the attention branch's output ``o`` ``[N,
-    d_model]``, closed by its second norm: ``h + RMS(o; post_attn_norm)``."""
-    return h + rms_norm(o, w[p + "post_attn_norm"], d.eps)
+    d_model]``, closed by its second norm: ``h + RMS(o; post_attn_norm)``.
+
+    ``o`` — the ``attn_o`` product — is COMPLETE before the norm sees it
+    (the barrier): fused with the norm's sum of squares that product
+    streamed its ``bf16[16384,7680]`` at 661 GB/s in the compiled round of
+    ``openpangu_ultra_moe_718b``, alone at 734 (0.381 -> 0.343 ms a
+    block: PR 64).  The FFN's down products under ``post_mlp_norm`` and
+    the module's ``eh`` read the same either way on the chip and keep
+    their fusion (:func:`ffn_branch`)."""
+    import jax
+
+    return h + rms_norm(jax.lax.optimization_barrier(o),
+                        w[p + "post_attn_norm"], d.eps)
 
 
 def ffn_branch(h, w, p: str, dense: bool, ts, d, held=None):

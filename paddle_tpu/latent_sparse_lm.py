@@ -310,7 +310,17 @@ def latent_inputs(x, w, p: str, pos, d):
     Without a query low rank (``d.q_rank`` None) the queries are ONE
     product ``x W_q`` (``attn_q``) and ``cq`` is None; without rotary
     (``d.rotary`` False: ``mla_use_nope``) ``qR`` and ``kR`` are carried
-    as projected."""
+    as projected.
+
+    The ``attn_q_b`` product is COMPLETE before the reshape by heads sees
+    it (the barrier): laid by heads for the per-head ``attn_uk`` product
+    behind it, it reads its matrix the other way round, and the compiler
+    then copies the 75.5 MB ``bf16[1536,24576]`` whole inside the program
+    (seen in the compiled round of ``openpangu_ultra_moe_718b``: six
+    copies a round, 0.65 ms of 20.9; ten hoisted out of ``deepseek_v3_2``'s
+    step loop with :func:`index_inputs`', 0.5 GB of temporaries; PR 64).
+    Alone, the product reads the matrix as stored."""
+    import jax
     import jax.numpy as jnp
 
     n = x.shape[0]
@@ -318,7 +328,7 @@ def latent_inputs(x, w, p: str, pos, d):
         cq, q = None, linear(x, w[p + "attn_q"])
     else:
         cq = rms_norm(linear(x, w[p + "attn_q_a"]), w[p + "q_a_norm"], d.eps)
-        q = linear(cq, w[p + "attn_q_b"])
+        q = jax.lax.optimization_barrier(linear(cq, w[p + "attn_q_b"]))
     q = q.reshape(n, d.n_head, d.d_qk)
     ckr = linear(x, w[p + "attn_kv_a"])
     turn = ((lambda t: rotate(t, pos, d.inv_freq)) if d.rotary
@@ -341,11 +351,15 @@ def index_inputs(x, cq, w, p: str, pos, d):
     their query latents ``cq``: ``(qI [N, index heads, index dim], kI [N,
     index dim], wI [N, index heads])`` float32; ``kI`` is the row's INDEX
     KEY (LayerNorm with weight and bias, then the first rope lanes
-    rotated), ``wI`` carries both ``^-0.5`` factors."""
+    rotated), ``wI`` carries both ``^-0.5`` factors.  ``index_q``'s product
+    is complete before its reshape by heads, as ``attn_q_b``'s in
+    :func:`latent_inputs` and for its reason."""
+    import jax
     import jax.numpy as jnp
 
     n = x.shape[0]
-    q = linear(cq, w[p + "index_q"]).reshape(n, d.n_index_head, d.d_index)
+    q = jax.lax.optimization_barrier(linear(cq, w[p + "index_q"])).reshape(
+        n, d.n_index_head, d.d_index)
     k = linear(x, w[p + "index_k"])
     mu = jnp.mean(k, axis=-1, keepdims=True)
     var = jnp.mean((k - mu) ** 2, axis=-1, keepdims=True)

@@ -174,6 +174,48 @@ def test_verify_rows_and_the_module_equal_the_full_forward():
     assert stats[2, 0] == 10 * 2 * 2 * 2    # rounds x rows x slots x top_k
 
 
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "experts"])
+def test_the_closed_branches_equal_the_plain_formula_bit_for_bit(dense):
+    """``close_attention`` and ``ffn_branch`` are ``h + RMS(branch;
+    second norm)`` as the module's docstring writes it — whatever keeps a
+    product whole before its norm on the chip (PR 64) is the identity on
+    values: eager and jitted, bit-equal to the formula written plainly,
+    a dense layer and an expert layer with an idle row."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import routed_experts as rx
+
+    cfg = tiny_cfg()
+    w, d = weights(cfg, seed=9), lm.dims(cfg)
+    p = lm.layer_prefix("lm", 0 if dense else 2)
+    rng = np.random.RandomState(4)
+    h, o = (jnp.asarray(rng.randn(5, d.d_model), jnp.float32)
+            for _ in range(2))
+    ts = jnp.asarray([3, -1, 0, 7, 2], jnp.int32)
+
+    def plain(h, o, w):
+        h = h + lm.rms_norm(o, w[p + "post_attn_norm"], d.eps)
+        f = lm.rms_norm(h, w[p + "pre_mlp_norm"], d.eps)
+        y, st = ((lm.swiglu(f, w[p + "ffn_gate"], w[p + "ffn_up"],
+                            w[p + "ffn_down"], 1.0, 1.0), None) if dense
+                 else rx.expert_layer(f, w, p, ts, d, None))
+        return h + lm.rms_norm(y, w[p + "post_mlp_norm"], d.eps), st
+
+    def built(h, o, w):
+        return lm.ffn_branch(lm.close_attention(h, o, w, p, d), w, p, dense,
+                             ts, d, None)
+
+    for run in (lambda f: f(h, o, w), lambda f: jax.jit(f)(h, o, w)):
+        (want, st_want), (got, st_got) = run(plain), run(built)
+        assert got.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+        assert (st_want is None) == (st_got is None) == dense
+        if not dense:
+            np.testing.assert_array_equal(np.asarray(st_want),
+                                          np.asarray(st_got))
+
+
 @pytest.mark.parametrize("chunk", [8, 4])
 def test_chunked_prefill_equals_steps_leaf_for_leaf_then_decodes(chunk):
     """Two (four) chunks through every layer AND the module's leaf, then

@@ -219,6 +219,90 @@ def test_a_prefill_chunk_equals_its_steps_leaf_for_leaf():
                                atol=5e-5)
 
 
+def _plain_latent_inputs(x, w, p, pos, d):
+    """``latent_inputs`` as the module's docstring writes it: no barrier."""
+    import jax.numpy as jnp
+
+    if d.q_rank is None:
+        cq, q = None, ls.linear(x, w[p + "attn_q"])
+    else:
+        cq = ls.rms_norm(ls.linear(x, w[p + "attn_q_a"]), w[p + "q_a_norm"],
+                         d.eps)
+        q = ls.linear(cq, w[p + "attn_q_b"])
+    q = q.reshape(x.shape[0], d.n_head, d.d_qk)
+    ckr = ls.linear(x, w[p + "attn_kv_a"])
+    row = jnp.concatenate(
+        [ls.rms_norm(ckr[:, :d.d_c], w[p + "kv_a_norm"], d.eps),
+         ls.rotate(ckr[:, d.d_c:], pos, d.inv_freq)], axis=-1)
+    return cq, q[..., :d.d_nope], ls.rotate(q[..., d.d_nope:], pos,
+                                            d.inv_freq), row
+
+
+def _plain_index_inputs(x, cq, w, p, pos, d):
+    """``index_inputs`` as the module's docstring writes it: no barrier."""
+    import jax.numpy as jnp
+
+    def turned(t):
+        return jnp.concatenate([ls.rotate(t[..., :d.d_rope], pos, d.inv_freq),
+                                t[..., d.d_rope:]], axis=-1)
+
+    q = ls.linear(cq, w[p + "index_q"]).reshape(
+        x.shape[0], d.n_index_head, d.d_index)
+    k = ls.linear(x, w[p + "index_k"])
+    mu = jnp.mean(k, axis=-1, keepdims=True)
+    var = jnp.mean((k - mu) ** 2, axis=-1, keepdims=True)
+    k = ((k - mu) / jnp.sqrt(var + d.ln_eps) * w[p + "index_k_norm"]
+         + w[p + "index_k_norm_bias"])
+    wi = ls.linear(x, w[p + "index_w"]) * float(
+        d.n_index_head ** -0.5 * d.d_index ** -0.5)
+    return turned(q), turned(k), wi
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("low_rank", [True, False],
+                         ids=["q_lora_rank", "one_query_matrix"])
+def test_the_projections_equal_the_plain_formula_bit_for_bit(low_rank, dtype):
+    """A projection's product is COMPLETE before the reshape by heads
+    sees it (``optimization_barrier`` behind ``attn_q_b`` and
+    ``index_q``: the chip then reads both matrices as stored, PR 64): the
+    identity on values — ``latent_inputs`` and ``index_inputs``, eager
+    and jitted, are bit-equal to the formula written without it, with a
+    query low rank and (``d.q_rank`` None: ONE ``attn_q`` product, no
+    barrier taken) without."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg = rehearse_cfg()
+    w = weights(cfg, seed=11, dtype=dtype)
+    d = ls.dims(cfg)
+    p = "lm_l1_"
+    rng = np.random.RandomState(5)
+    x = jnp.asarray(rng.randn(6, d.d_model), jnp.float32)
+    pos = jnp.arange(6) + 17
+    if not low_rank:
+        d.q_rank = None
+        w[p + "attn_q"] = jnp.asarray(
+            0.15 * rng.randn(d.d_model, d.n_head * d.d_qk), dtype)
+
+    def both(fn_latent, fn_index):
+        def run(x, w):
+            cq, qc, qr, row = fn_latent(x, w, p, pos, d)
+            out = (qc, qr, row)
+            if low_rank:
+                out += (cq,) + tuple(fn_index(x, cq, w, p, pos, d))
+            return out
+        return run
+
+    plain = both(_plain_latent_inputs, _plain_index_inputs)
+    built = both(ls.latent_inputs, ls.index_inputs)
+    for run in (lambda f: f(x, w), lambda f: jax.jit(f)(x, w)):
+        want, got = run(plain), run(built)
+        assert len(got) == (7 if low_rank else 3)
+        for a, b in zip(want, got):
+            assert a.dtype == b.dtype == jnp.float32
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_the_chunks_selection_is_the_steps_list_as_a_mask():
     """``chunk_select`` (a threshold and the tie rule) marks exactly the
     positions ``select_positions`` lists, ties included."""

@@ -833,6 +833,44 @@ def test_dense_latent_round_and_prefill_fit_v5e_and_copy_no_leaf(
         assert mem.temp_size_in_bytes < 0.5e9
 
 
+@pytest.mark.parametrize("config,kind,rows,products,matrices", [
+    ("openpangu_ultra_moe_718b", "spec_chunk", 64, {24576: 6},
+     ((1536, 24576), (16384, 7680), (18432, 7680))),
+    ("deepseek_v3_2", "chunk", 24, {24576: 5, 8192: 5},
+     ((1536, 24576), (1536, 8192)))],
+    ids=["openpangu_round", "deepseek_chunk"])
+def test_a_latent_projection_is_multiplied_as_its_matrix_is_stored_on_v5e(
+        one_chip, config, kind, rows, products, matrices):
+    """The self-drafting round of ``openpangu_ultra_moe_718b`` and the
+    ``chunk`` of ``deepseek_v3_2`` at their published widths and the
+    cells' own depth (the whole 5-layer cuts, the module): the queries'
+    up projection ``attn_q_b`` ``[1536,24576]`` — and the indexer's
+    ``index_q`` ``[1536,8192]`` from the same low rank — is ONE plain
+    product a layer over the matrix as stored, complete before the
+    reshape by heads sees it (the barrier in
+    ``latent_sparse_lm.latent_inputs`` / ``index_inputs``), and the
+    compiled program holds no ``copy`` of a weight-shaped matrix either
+    way round, a step or once a call (the tool's count, from the
+    program's own shapes).  Before PR 64 the product was laid by heads
+    for the per-head ``attn_uk`` product behind it and read its matrix
+    the other way round: six 75.5 MB copies a round there (0.65 ms of
+    20.9), ten hoisted out of the step loop here (0.5 GB of temporaries
+    in a cell whose peak read 100.5% of the chip)."""
+    import re
+
+    text, mem, counted = _compiled_chunk(config, 5, {}, kind=kind)
+    assert counted["relayouts"] == (0, 0)     # a step, once a call
+    copies = [line for line in text.splitlines() if " copy(" in line]
+    for a, b in matrices:
+        for shape in ("[%d,%d]" % (a, b), "[%d,%d]" % (b, a)):
+            assert not any(shape in line for line in copies), shape
+    for width, n in products.items():
+        assert len(re.findall(
+            r"= f32\[%d,%d\]\{1,0[^}]*\} convolution\(" % (rows, width),
+            text)) == n, width
+    assert mem.temp_size_in_bytes < 0.1e9
+
+
 @pytest.mark.parametrize("kind", ["chunk", "prefill"])
 def test_kda_latent_chunk_and_prefill_fit_v5e_and_copy_no_leaf(one_chip,
                                                                kind):

@@ -44,7 +44,12 @@ the loop (once a call).  ``k_exaone_236b_a23b --kind spec_chunk
 --layers 5``: 16 a step at PR 59's parent (both ring leaves of each of
 four window layers, and ``attn_q`` ``[6144,8192]`` and ``attn_k``
 ``[6144,1024]`` transposed in each of the four layers fed by a block
-before them) -> 0 since.  ``falcon_h1_34b``: 0 a step, 2 once a call;
+before them) -> 0 since.  ``openpangu_ultra_moe_718b --kind spec_chunk
+--layers 5``: 6 a step at PR 64's parent (``attn_q_b`` ``[1536,24576]``
+re-laid for a product laid by heads, once a block) -> 0 since;
+``deepseek_v3_2 --layers 5``: 10 once a call (``attn_q_b`` and
+``index_q`` ``[1536,8192]``, a layer each) -> 0.  ``falcon_h1_34b``: 0 a
+step, 2 once a call;
 ``gpt1_117m --kind seat_prefill``: 2 a step (inside the scanned body of
 its blocks), 1 once a call.
 """
